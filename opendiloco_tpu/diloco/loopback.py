@@ -234,7 +234,7 @@ class LoopbackBackend(OuterBackend):
             return out, n
         w = self.world
         codec = w.codec
-        # per-worker stage spans mirror the TCP taxonomy: encode (codec
+        # per-worker stage spans mirror the TCP stage names: encode (codec
         # roundtrip), reduce_wait (park until the round mean publishes),
         # adopt (copy the published result)
         tr = obs.tracer()

@@ -323,7 +323,6 @@ def spawn_replica(
     with os.fdopen(fd, "w") as f:
         json.dump(spec, f)
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
     if env:
         child_env.update(env)
     proc = subprocess.Popen(
@@ -424,6 +423,15 @@ def build_fleet(
     import jax
     import numpy as np
 
+    if not fleet_cfg.inprocess and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "fleet replicas run as subprocesses, and this process holds the "
+            "TPU: a chip belongs to one process, so the children would fail "
+            "or hang reaching it, and putting them on the CPU instead would "
+            "serve off the chip without saying so. Use fleet.inprocess, or "
+            "start replicas on hosts with chips of their own (in-process "
+            "replicas on distinct devices are ROADMAP B6)."
+        )
     if diloco_opt is not None:
         snapshot_fn = diloco_opt.master_snapshot
     else:

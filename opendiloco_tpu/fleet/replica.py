@@ -21,8 +21,10 @@ Run in-process (tests, ``fleet.inprocess``) or as a subprocess::
     python -m opendiloco_tpu.fleet.replica --spec spec.json
 
 which prints one ready line of JSON (``replica_id``, bound
-``serve_port``/``push_port``, ``pid``) on stdout and serves until
-killed. Replica death is the router's problem, not ours: SIGKILL simply
+``serve_port``/``push_port``, ``pid``, and the ``platform`` /
+``device_kind`` JAX gave it) on stdout and serves until killed. The
+subprocess takes whatever platform its environment resolves to — a rig
+that means CPU says ``JAX_PLATFORMS=cpu``. Replica death is the router's problem, not ours: SIGKILL simply
 stops the sockets answering.
 """
 from __future__ import annotations
@@ -347,10 +349,12 @@ class Replica:
 
 
 def main(argv: Optional[list] = None) -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--spec", required=True, help="JSON replica spec file")
     args = ap.parse_args(argv)
+    from opendiloco_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     with open(args.spec) as f:
         spec = json.load(f)
 
@@ -382,6 +386,10 @@ def main(argv: Optional[list] = None) -> int:
                 "serve_port": replica.server.port,
                 "push_port": replica.push_port,
                 "pid": os.getpid(),
+                # where this replica really runs: the platform JAX gave
+                # it, never assumed by the parent
+                "platform": replica.engine.device.platform,
+                "device_kind": replica.engine.device.device_kind,
             }
         ),
         flush=True,

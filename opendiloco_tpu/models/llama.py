@@ -148,9 +148,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
     """Fresh init matching HF llama conventions: normal(0, initializer_range)
     for projections/embeddings, ones for norms (init_weights.py parity)."""
     shp = shapes(cfg)
-    # tree_util spelling: the jax.tree.flatten_with_path alias only exists
-    # in newer jax releases and this is the one call site that needs it
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(shp)
+    leaves, treedef = jax.tree.flatten_with_path(shp)
     keys = jax.random.split(rng, len(leaves))
     out = []
     for key, (path, leaf) in zip(keys, leaves):

@@ -18,6 +18,10 @@ from typing import Optional
 
 import numpy as np
 
+from opendiloco_tpu.utils.logger import get_text_logger
+
+log = get_text_logger(__name__)
+
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native",
@@ -29,15 +33,29 @@ _tried = False
 
 
 def _try_build() -> None:
+    """Build the library where it is missing. A failed build is logged,
+    not swallowed: the numpy fallback it leaves behind is correct but an
+    order of magnitude slower, and nobody should run it unknowingly."""
     try:
         subprocess.run(
             ["make", "-C", _NATIVE_DIR, "-s"],
             check=True,
             capture_output=True,
+            text=True,
             timeout=120,
         )
-    except Exception:
-        pass
+    except subprocess.CalledProcessError as e:
+        log.warning(
+            "native build failed (exit %d); using the numpy fallbacks:\n%s",
+            e.returncode,
+            (e.stderr or "").strip()[-2000:],
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        log.warning(
+            "native build did not run (%s: %s); using the numpy fallbacks",
+            type(e).__name__,
+            e,
+        )
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -54,7 +72,10 @@ def get_lib() -> Optional[ctypes.CDLL]:
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except OSError as e:
+        log.warning(
+            "could not load %s (%s); using the numpy fallbacks", _LIB_PATH, e
+        )
         return None
     u16p = ctypes.POINTER(ctypes.c_uint16)
     f32p = ctypes.POINTER(ctypes.c_float)

@@ -39,8 +39,9 @@ backend is TPU; off-TPU it always resolves to the XLA paths, so CPU rigs
 keep today's exact code. Forcing ``pallas`` off-TPU runs the kernels in
 Pallas interpret mode (slow, but semantically the kernel) — that is how
 the parity tests pin token-bit-exactness on a CPU rig. Shapes a kernel
-cannot tile (head_dim not a multiple of 8, odd N) fall back to the XLA
-path per call, mirroring ``flash_attention``'s fallback contract.
+cannot tile (head_dim not a multiple of 8, odd N, a verify tail too tall
+for VMEM) fall back to the XLA path per call, mirroring
+``flash_attention``'s fallback contract.
 """
 
 from __future__ import annotations
@@ -54,13 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from opendiloco_tpu.ops.attention import decode_attention, spec_tail_attention
-from opendiloco_tpu.ops.pallas_util import (
-    NEG_INF,
-    compiler_params,
-    out_vma,
-    sds,
-    pick_block,
-)
+from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
 W4_BLOCK = 4096  # diloco.compression._BLOCK (pinned by tests)
 
@@ -113,10 +108,12 @@ def _decode_attn_kernel(
     lens_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     scale, block_t, t, num_t, with_stats,
 ):
-    if with_stats:
-        stats_ref, m_scr, l_scr, acc_scr, cnt_scr = rest
-    else:
-        (m_scr, l_scr, acc_scr), cnt_scr = rest, None
+    # the stats block's index ignores the ring axis, so it stays resident
+    # across ti and doubles as the counter: a [1, 1] vector add, since
+    # Mosaic cannot store a scalar to VMEM
+    stats_ref, (m_scr, l_scr, acc_scr) = (
+        (rest[0], rest[1:]) if with_stats else (None, rest)
+    )
     rep, d = q_ref.shape
     si, ti = pl.program_id(0), pl.program_id(2)
 
@@ -126,7 +123,7 @@ def _decode_attn_kernel(
         l_scr[:] = jnp.zeros((rep, 1), jnp.float32)
         acc_scr[:] = jnp.zeros((rep, d), jnp.float32)
         if with_stats:
-            cnt_scr[:] = jnp.zeros((1, 1), jnp.int32)
+            stats_ref[:] = jnp.zeros((1, 1), jnp.int32)
 
     lens_s = lens_ref[si]
     # valid cache entries are idx <= lens (whole ring once lens >= t), so
@@ -158,15 +155,13 @@ def _decode_attn_kernel(
             preferred_element_type=jnp.float32,
         )
         if with_stats:
-            cnt_scr[0, 0] += 1
+            stats_ref[:] += 1
 
     @pl.when(ti == num_t - 1)
     def _finish():
         l = l_scr[:]
         l_safe = jnp.where(l == 0, 1.0, l)
         o_ref[:] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        if with_stats:
-            stats_ref[0, 0] = cnt_scr[0, 0]
 
 
 def paged_decode_attention(
@@ -214,7 +209,9 @@ def paged_decode_attention(
         return (si, hi, 0, 0)
 
     out_specs = [pl.BlockSpec((None, None, rep, d), q_map)]
-    out_shape = [sds((s_, nkv, rep, d), q.dtype, vma=out_vma(q))]
+    out_shape = [
+        jax.ShapeDtypeStruct((s_, nkv, rep, d), q.dtype, vma=jax.typeof(q).vma)
+    ]
     scratch = [
         pltpu.VMEM((rep, 1), jnp.float32),
         pltpu.VMEM((rep, 1), jnp.float32),
@@ -224,8 +221,7 @@ def paged_decode_attention(
         out_specs.append(
             pl.BlockSpec((None, None, 1, 1), lambda si, hi, ti, lr: (si, hi, 0, 0))
         )
-        out_shape.append(sds((s_, nkv, 1, 1), jnp.int32))
-        scratch.append(pltpu.VMEM((1, 1), jnp.int32))
+        out_shape.append(jax.ShapeDtypeStruct((s_, nkv, 1, 1), jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -246,7 +242,7 @@ def paged_decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
@@ -262,15 +258,23 @@ def paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
+# One grid step holds a whole GQA group's tail in VMEM: rep * Kq query rows
+# with f32 (m, l, acc) scratch, the [.., 1]-wide stats padded to 128 lanes.
+# Compiled deviceless for v5e (16 MiB scoped VMEM), head_dim 64 and 128,
+# bf16 and f32: 4096 rows fit, 8192 do not. Speculative tails (Kq = k + 1)
+# are nowhere near; the continued-prefill caller (Kq = a suffix bucket)
+# reaches it at GQA 32/4 with a 1024-token suffix, which keeps the XLA path.
+_SPEC_MAX_GROUP_ROWS = 4096
+
+
 def _spec_tail_kernel(
     lens_ref, q_ref, k_ref, v_ref, tk_ref, tv_ref, o_ref, *rest,
     scale, q_start, block_t, t, num_t, rep, with_stats,
 ):
-    if with_stats:
-        stats_ref, m_scr, l_scr, acc_scr, cnt_scr = rest
-    else:
-        (m_scr, l_scr, acc_scr), cnt_scr = rest, None
-    kq, _, d = q_ref.shape
+    stats_ref, (m_scr, l_scr, acc_scr) = (
+        (rest[0], rest[1:]) if with_stats else (None, rest)
+    )
+    _, kq, d = q_ref.shape
     kt = tk_ref.shape[0]
     si, ti = pl.program_id(0), pl.program_id(2)
 
@@ -280,7 +284,7 @@ def _spec_tail_kernel(
         l_scr[:] = jnp.zeros((rep, kq, 1), jnp.float32)
         acc_scr[:] = jnp.zeros((rep, kq, d), jnp.float32)
         if with_stats:
-            cnt_scr[:] = jnp.zeros((1, 1), jnp.int32)
+            stats_ref[:] = jnp.zeros((1, 1), jnp.int32)
 
     lens_s = lens_ref[si]
     # pre-tail ring liveness is idx < lens (strict: the tail's own K/V is
@@ -306,7 +310,7 @@ def _spec_tail_kernel(
         evicted = (disp <= j) & ((lens_s + disp) >= t)
         valid = base & ~evicted  # [kq, block_t], same for every q head
         for r in range(rep):
-            q_r = q_ref[:, r, :]  # [kq, d]
+            q_r = q_ref[r]  # [kq, d]
             s = scale * jax.lax.dot_general(
                 q_r, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -323,7 +327,7 @@ def _spec_tail_kernel(
                 preferred_element_type=jnp.float32,
             )
         if with_stats:
-            cnt_scr[0, 0] += 1
+            stats_ref[:] += 1
 
     @pl.when(ti == num_t)
     def _tail_step():
@@ -333,7 +337,7 @@ def _spec_tail_kernel(
         ki = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 1)
         valid = ki <= qi  # causal within the tail
         for r in range(rep):
-            q_r = q_ref[:, r, :]
+            q_r = q_ref[r]
             s = scale * jax.lax.dot_general(
                 q_r, tk_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -351,9 +355,7 @@ def _spec_tail_kernel(
             # the tail always holds at least the query's own position, so
             # l_new > 0; the guard mirrors the flash kernel's finish
             l_safe = jnp.where(l_new == 0, 1.0, l_new)
-            o_ref[:, r, :] = (acc / l_safe).astype(o_ref.dtype)
-        if with_stats:
-            stats_ref[0, 0] = cnt_scr[0, 0]
+            o_ref[r] = (acc / l_safe).astype(o_ref.dtype)
 
 
 def spec_tail_attention_fused(
@@ -375,7 +377,7 @@ def spec_tail_attention_fused(
     s_, t, nkv, d = cache_k.shape
     kq, h = q.shape[1], q.shape[2]
     kt = tail_k.shape[1]
-    if d % 8 != 0 or h % nkv != 0:
+    if d % 8 != 0 or h % nkv != 0 or (h // nkv) * kq > _SPEC_MAX_GROUP_ROWS:
         out = spec_tail_attention(
             q, cache_k, cache_v, tail_k, tail_v, lens, q_start=q_start
         )
@@ -387,13 +389,15 @@ def spec_tail_attention_fused(
 
     # same Mosaic tiling story as paged_decode_attention: kv-head and rep
     # axes are tiny, so they must be array dims of their own — caches and
-    # tail as [S, Kh, T|Kt, D], q as [S, Kq, Kh, rep, D]. Kernel refs keep
-    # the exact shapes the untransposed layout produced.
+    # tail as [S, Kh, T|Kt, D], q (and the output) as [S, Kh, rep, Kq, D].
+    # The head index r must be a LEADING dim of the q/o tiles: with 16-bit
+    # dtypes two rows share a sublane, and Mosaic refuses a per-head slice
+    # on the second-minor dim ("unsupported shape cast" for bf16 at D 64).
     ckt = cache_k.transpose(0, 2, 1, 3)
     cvt = cache_v.transpose(0, 2, 1, 3)
     tkt = tail_k.transpose(0, 2, 1, 3)
     tvt = tail_v.transpose(0, 2, 1, 3)
-    q5 = q.reshape(s_, kq, nkv, rep, d)
+    q5 = q.reshape(s_, kq, nkv, rep, d).transpose(0, 2, 3, 1, 4)
 
     def kv_map(si, hi, ti, lens_ref):
         last = jnp.where(
@@ -403,13 +407,17 @@ def spec_tail_attention_fused(
         return (si, hi, jnp.minimum(ti, last), 0)
 
     def q_map(si, hi, ti, lr):
-        return (si, 0, hi, 0, 0)
+        return (si, hi, 0, 0, 0)
 
     def tail_map(si, hi, ti, lr):
         return (si, hi, 0, 0)
 
-    out_specs = [pl.BlockSpec((None, kq, None, rep, d), q_map)]
-    out_shape = [sds((s_, kq, nkv, rep, d), q.dtype, vma=out_vma(q))]
+    out_specs = [pl.BlockSpec((None, None, rep, kq, d), q_map)]
+    out_shape = [
+        jax.ShapeDtypeStruct(
+            (s_, nkv, rep, kq, d), q.dtype, vma=jax.typeof(q).vma
+        )
+    ]
     scratch = [
         pltpu.VMEM((rep, kq, 1), jnp.float32),
         pltpu.VMEM((rep, kq, 1), jnp.float32),
@@ -419,14 +427,13 @@ def spec_tail_attention_fused(
         out_specs.append(
             pl.BlockSpec((None, None, 1, 1), lambda si, hi, ti, lr: (si, hi, 0, 0))
         )
-        out_shape.append(sds((s_, nkv, 1, 1), jnp.int32))
-        scratch.append(pltpu.VMEM((1, 1), jnp.int32))
+        out_shape.append(jax.ShapeDtypeStruct((s_, nkv, 1, 1), jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s_, nkv, num_t + 1),  # ring blocks, then the tail block
         in_specs=[
-            pl.BlockSpec((None, kq, None, rep, d), q_map),
+            pl.BlockSpec((None, None, rep, kq, d), q_map),
             pl.BlockSpec((None, None, bt, d), kv_map),
             pl.BlockSpec((None, None, bt, d), kv_map),
             pl.BlockSpec((None, None, kt, d), tail_map),
@@ -443,12 +450,12 @@ def spec_tail_attention_fused(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
     )(lens.astype(jnp.int32), q5, ckt, cvt, tkt, tvt)
-    out = res[0].reshape(s_, kq, h, d)
+    out = res[0].transpose(0, 3, 1, 2, 4).reshape(s_, kq, h, d)
     if return_stats:
         return out, res[1].reshape(s_, nkv)
     return out
@@ -574,14 +581,14 @@ def w4_matmul(
             pl.BlockSpec((bm, n_half), lambda mi, ki: (mi, 0)),
         ],
         out_shape=[
-            sds((M, n_half), dtype, vma=out_vma(x)),
-            sds((M, n_half), dtype, vma=out_vma(x)),
+            jax.ShapeDtypeStruct((M, n_half), dtype, vma=jax.typeof(x).vma),
+            jax.ShapeDtypeStruct((M, n_half), dtype, vma=jax.typeof(x).vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, n_half), jnp.float32),
             pltpu.VMEM((bm, n_half), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_interpret(interpret),

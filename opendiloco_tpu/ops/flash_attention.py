@@ -37,11 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from opendiloco_tpu.ops.pallas_util import (
     NEG_INF as _NEG_INF,
-    compiler_params as _compiler_params,
-    out_vma as _out_vma,
-    sds as _sds,
     pick_block as _pick_block,
-    shard_map as _shard_map,
 )
 
 
@@ -115,7 +111,7 @@ def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool, vma=None):
     When unset it is derived from q so the kernel types correctly in ANY
     manual region (e.g. flash_attention_sharded's batch/tp shard_map).
     """
-    vma = _out_vma(q, vma)
+    vma = jax.typeof(q).vma if vma is None else vma
     b, hq, t, d = q.shape
     hkv = k.shape[1]
     rep = hq // hkv
@@ -152,15 +148,15 @@ def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool, vma=None):
             ),
         ],
         out_shape=[
-            _sds(q.shape, q.dtype, vma=vma),
-            _sds((b, hq, 1, t), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b, hq, 1, t), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v)
@@ -301,7 +297,7 @@ def _bwd_impl(
     output dtype and ``vma`` annotates varying manual axes (both used by the
     ring-attention chunk path, which accumulates f32 inside shard_map);
     an unset vma is derived from q (see _fwd)."""
-    vma = _out_vma(q, vma)
+    vma = jax.typeof(q).vma if vma is None else vma
     b, hq, t, d = q.shape
     hkv = k.shape[1]
     rep = hq // hkv
@@ -339,9 +335,9 @@ def _bwd_impl(
         out_specs=pl.BlockSpec(
             (None, None, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
         ),
-        out_shape=_sds(q.shape, grad_dtype or q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct(q.shape, grad_dtype or q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v, dout, lse, delta)
@@ -398,14 +394,14 @@ def _bwd_impl(
             ),
         ],
         out_shape=[
-            _sds(k.shape, grad_dtype or k.dtype, vma=vma),
-            _sds(v.shape, grad_dtype or v.dtype, vma=vma),
+            jax.ShapeDtypeStruct(k.shape, grad_dtype or k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v.shape, grad_dtype or v.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(q_g, k, v, do_g, lse_g, delta_g)
@@ -519,7 +515,7 @@ def flash_attention_sharded(
         if hq % n_tp == 0 and hkv % n_tp == 0:
             head = tp_axis
     spec = P(tuple(batch_axes) or None, None, head, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda a, b, c: flash_attention(a, b, c, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

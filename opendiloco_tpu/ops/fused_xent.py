@@ -24,11 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from opendiloco_tpu.ops.pallas_util import (
-    compiler_params as _compiler_params,
-    shard_map as _shard_map,
-)
-
 IGNORE = -100
 
 
@@ -157,7 +152,7 @@ def _fwd(h, w, labels, block_n, block_v, true_v):
             pltpu.VMEM((block_n, 1), jnp.float32),
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_BUDGET,
         ),
     )(h, w, labels.reshape(1, n))
@@ -269,7 +264,7 @@ def _bwd_impl(h, w, labels, lse, g, block_n, block_v, true_v):
         # in_spec psums the partials outside the kernel
         out_shape=jax.ShapeDtypeStruct((n, d), h.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_BUDGET,
         ),
     )(*args)
@@ -286,7 +281,7 @@ def _bwd_impl(h, w, labels, lse, g, block_n, block_v, true_v):
         out_specs=pl.BlockSpec((d, block_v), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((d, v), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, block_v), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_BUDGET,
         ),
     )(*args)
@@ -421,7 +416,7 @@ def fused_linear_cross_entropy_sharded(
         c = jax.lax.psum(c, tuple(batch_axes))
         return s / jnp.maximum(c, 1)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(tuple(batch_axes), None), P(), P(tuple(batch_axes))),

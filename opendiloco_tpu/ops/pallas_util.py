@@ -3,17 +3,12 @@
 The house kernels (ops/flash_attention.py training attention, the
 ops/decode_kernels.py serving kernels) share the same plumbing: a block
 picker that snaps tile sizes to the TPU lane grid and falls back to XLA
-when nothing divides, a varying-manual-axes derivation so kernel outputs
-type correctly inside shard_map manual regions, and a compiler-params
-shim across the jax versions in play (``pltpu.CompilerParams`` was
-``TPUCompilerParams`` before jax 0.5). Keeping them here means one set
-of heuristics for every kernel instead of per-file copies.
+when nothing divides, and the finite -inf the online softmaxes mask with.
+Keeping them here means one set of heuristics for every kernel instead of
+per-file copies.
 """
 
 from __future__ import annotations
-
-import jax
-from jax.experimental.pallas import tpu as pltpu
 
 # finite stand-in for -inf inside kernels: exp(NEG_INF - m) underflows to
 # an exact 0.0 for any live m, so masked lanes never perturb the softmax
@@ -28,90 +23,3 @@ def pick_block(t: int, preferred: int = 512) -> int:
         if b <= preferred and t % b == 0:
             return b
     return 0
-
-
-def out_vma(x, vma=None):
-    """Varying-manual-axes annotation for kernel ``out_shape``s.
-
-    Required when a kernel runs inside a shard_map manual region (ring
-    attention chunks, the sharded flash entry): the outputs must carry
-    the same manual axes as the operands or the kernel types wrong. An
-    explicit ``vma`` wins; otherwise it is derived from ``x``."""
-    if vma is None:
-        typeof = getattr(jax, "typeof", None)  # newer-jax only, like vma
-        if typeof is not None:
-            vma = getattr(typeof(x), "vma", None) or None
-    return vma
-
-
-def compiler_params(*, dimension_semantics=None, **kwargs):
-    """``pltpu.CompilerParams`` across jax versions (older releases spell
-    it ``TPUCompilerParams``). Extra kwargs (``vmem_limit_bytes``, ...)
-    pass through to whichever class this release has."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = dimension_semantics
-    return cls(**kwargs)
-
-
-def pcast_varying(xs, vma):
-    """``jax.lax.pcast(..., to="varying")`` where available; earlier jax
-    has no varying-manual-axes typing, so the cast is the identity."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None or not vma:
-        return xs
-    return fn(xs, tuple(sorted(vma)), to="varying")
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, across jax versions
-    (``jax.lax.axis_size`` is newer jax; before that ``jax.core.
-    axis_frame`` resolves the bound — to a frame or the size itself)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=None):
-    """``jax.shard_map`` across jax versions. Older releases ship it as
-    ``jax.experimental.shard_map.shard_map``, where partial-manual is
-    spelled ``auto=`` (the complement of ``axis_names``) and the vma
-    checker is ``check_rep`` — which has no replication rules for the
-    custom calls our kernels lower to, so the old path always disables
-    it (the cross-shard semantics at every call site are explicit
-    psums/permutes; the check buys nothing, per the fused_xent note)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-
-    # no auto= on the old path: its eager impl raises NotImplementedError
-    # outright. Full-manual is equivalent here — axes outside the specs
-    # replicate into the region, the same gather auto partitioning emits
-    # (and none of our bodies run collectives over them).
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-try:  # vma= on out_shape structs only exists on newer jax
-    jax.ShapeDtypeStruct((), "float32", vma=None)
-    _SDS_HAS_VMA = True
-except TypeError:
-    _SDS_HAS_VMA = False
-
-
-def sds(shape, dtype, vma=None):
-    """``jax.ShapeDtypeStruct`` for kernel ``out_shape``s, attaching the
-    vma annotation only when this jax release understands it (older
-    releases predate varying-manual-axes and reject the kwarg)."""
-    if _SDS_HAS_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)

@@ -35,11 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from opendiloco_tpu.ops.pallas_util import (
-    axis_size as _axis_size,
-    pcast_varying as _pcast_varying,
-    shard_map as _shard_map,
-)
+from opendiloco_tpu.ops.pallas_util import pick_block as _pick_block
 
 _NEG_INF = float(-1e30)
 
@@ -104,18 +100,10 @@ def _block_attn(qg, k, v, q_pos, k_pos, m, l, acc, *, causal):
 def _ring_vma(axis_name: str, ref) -> frozenset:
     """Varying-manual-axes set for ring internals: the ring axis plus any
     OUTER manual axes ``ref`` already varies over. Standalone sp meshes get
-    {sp} exactly as before; nested inside the pp pipeline's partial-manual
-    region the inputs are also pp-varying, and fresh scan carriers /
-    kernel outputs must carry the full type from step 0 or the scan's
-    carry types mismatch."""
-    try:
-        typeof = getattr(jax, "typeof", None)  # newer-jax only
-        extra = (
-            getattr(typeof(ref), "vma", frozenset()) if typeof else frozenset()
-        ) or frozenset()
-    except Exception:  # pragma: no cover - tracing-context quirks
-        extra = frozenset()
-    return frozenset(extra) | {axis_name}
+    {sp}; nested inside the pp pipeline's partial-manual region the inputs
+    are also pp-varying, and fresh scan carriers / kernel outputs must
+    carry the full type from step 0 or the scan's carry types mismatch."""
+    return jax.typeof(ref).vma | {axis_name}
 
 
 def _ring_forward(q, k, v, axis_name, causal):
@@ -125,7 +113,7 @@ def _ring_forward(q, k, v, axis_name, causal):
     qg = _grouped(q, hkv)
 
     idx = jax.lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     q_pos = idx * tl + jnp.arange(tl, dtype=jnp.int32)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -149,7 +137,9 @@ def _ring_forward(q, k, v, axis_name, causal):
     # stats become device-varying after the first accumulation step; the scan
     # carry must have that type from the start (including any outer manual
     # axes when nested in the pp pipeline)
-    m0, l0, acc0 = _pcast_varying((m0, l0, acc0), _ring_vma(axis_name, q))
+    m0, l0, acc0 = jax.lax.pcast(
+        (m0, l0, acc0), tuple(sorted(_ring_vma(axis_name, q))), to="varying"
+    )
     (_, _, m, l, acc), _ = jax.lax.scan(
         step, (k, v, m0, l0, acc0), jnp.arange(n), length=n
     )
@@ -204,7 +194,7 @@ def _ring_bwd(axis_name, causal, res, dout):
     ).transpose(0, 2, 3, 1)[..., None]
 
     idx = jax.lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     q_pos = idx * tl + jnp.arange(tl, dtype=jnp.int32)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -248,7 +238,9 @@ def _ring_bwd(axis_name, causal, res, dout):
     dk0 = jnp.zeros((b, tl, hkv, d), jnp.float32)
     dv0 = jnp.zeros_like(dk0)
     dq0 = jnp.zeros((b, tl, hkv, hq // hkv, d), jnp.float32)
-    dk0, dv0, dq0 = _pcast_varying((dk0, dv0, dq0), _ring_vma(axis_name, q))
+    dk0, dv0, dq0 = jax.lax.pcast(
+        (dk0, dv0, dq0), tuple(sorted(_ring_vma(axis_name, q))), to="varying"
+    )
     (_, _, dk, dv, dq), _ = jax.lax.scan(
         step, (k, v, dk0, dv0, dq0), jnp.arange(n), length=n
     )
@@ -283,7 +275,7 @@ def _ring_flash_forward(q, k, v, axis_name, block):
     vma = _ring_vma(axis_name, q)
 
     idx = jax.lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     # step 0: own (diagonal) chunk, standard causal flash -- guarantees a
@@ -351,7 +343,7 @@ def _ring_flash_bwd(axis_name, block, res, dout):
     delta = _delta(doT, oT)
 
     idx = jax.lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     kwargs = dict(
@@ -413,8 +405,6 @@ def _flash_chunk_block(mesh, axis: str, q, causal: bool, local: bool = False) ->
         dev = mesh.devices.flat[0]
         if "tpu" not in getattr(dev, "device_kind", "").lower():
             return 0
-    from opendiloco_tpu.ops.pallas_util import pick_block as _pick_block
-
     n = mesh.shape[axis]
     tl = q.shape[1] // n if not local else q.shape[1]
     if q.shape[-1] % 8:
@@ -446,25 +436,11 @@ def ring_attention_auto(
     # an AbstractMesh with the outer axes Manual, and a concrete mesh would
     # be rejected. Nesting over a disjoint manual axis set is supported --
     # this is what composes sp ring attention with pipeline stages.
-    inside_manual = False
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        types = dict(
-            zip(getattr(ctx, "axis_names", ()), getattr(ctx, "axis_types", ()))
-        )
-        inside_manual = types.get(axis) == jax.sharding.AxisType.Manual
-    except Exception:  # pragma: no cover - older jax without abstract mesh
-        pass
-    if not inside_manual and not hasattr(jax.sharding, "get_abstract_mesh"):
-        # pre-AbstractMesh jax can't introspect the tracing context, but
-        # there the esm fallback binds regions FULL-manual — so the ring
-        # axis having a bound frame means we are already inside one and a
-        # nested shard_map would re-bind outer axes (rejected)
-        try:
-            jax.core.axis_frame(axis)
-            inside_manual = True
-        except Exception:
-            pass
+    ctx = jax.sharding.get_abstract_mesh()
+    inside_manual = (
+        dict(zip(ctx.axis_names, ctx.axis_types)).get(axis)
+        == jax.sharding.AxisType.Manual
+    )
     block = _flash_chunk_block(mesh, axis, q, causal=True, local=inside_manual)
     if block:
         body = lambda q, k, v: ring_flash_attention(q, k, v, axis, block)
@@ -478,7 +454,7 @@ def ring_attention_auto(
         # lower in the forward but has no jvp lowering (Shardy rejects
         # re-binding the outer axis; GSPMD check-fails)
         return body(q, k, v)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

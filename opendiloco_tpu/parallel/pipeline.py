@@ -34,8 +34,6 @@ from opendiloco_tpu.models.llama import (
     RematPolicy,
 )
 from opendiloco_tpu.ops.attention import xla_attention
-from opendiloco_tpu.ops.pallas_util import axis_size as _axis_size
-from opendiloco_tpu.ops.pallas_util import shard_map as _shard_map
 
 
 def pipeline_hidden(
@@ -95,7 +93,7 @@ def pipeline_hidden(
     pos_spec = P(None, None, sp_axis) if sp_axis else P()
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(layer_specs, hs_spec, pos_spec),
         out_specs=(hs_spec, P(axis)),
@@ -103,7 +101,7 @@ def pipeline_hidden(
     )
     def _pipeline(layers_local, hs, mb_positions):
         r = jax.lax.axis_index(axis)
-        n = _axis_size(axis)
+        n = jax.lax.axis_size(axis)
         perm = [(i, i + 1) for i in range(n - 1)]  # stage r -> r+1, no wrap
 
         def stage(x, pos):
@@ -113,9 +111,8 @@ def pipeline_hidden(
             )
             block = _maybe_remat(block, remat)
             y, (_, layer_auxs) = jax.lax.scan(block, x, layers_local)
-            # keep the aux rank-1 everywhere in this region: pre-vma
-            # shard_map cannot re-shard rank-0 residuals/outputs across
-            # the region boundary (MoE backward raises _SpecError)
+            # keep the aux rank-1 everywhere in this region: it leaves
+            # through a P(pp) out spec (see the export below)
             return y, jnp.sum(layer_auxs, keepdims=True)
 
         def tick(carry, t):
@@ -141,17 +138,14 @@ def pipeline_hidden(
         def to_varying(x):
             # only the axes x is not ALREADY varying over: zeros_like on the
             # sp-sharded hs inherits {V:sp}, and pcast rejects mixed states
-            typeof = getattr(jax, "typeof", None)
-            if typeof is None:  # pre-vma jax: no varying typing to establish
-                return x
-            vma = getattr(typeof(x), "vma", frozenset()) or frozenset()
+            vma = jax.typeof(x).vma
             missing = tuple(a for a in manual_axes if a not in vma)
             return jax.lax.pcast(x, missing, to="varying") if missing else x
 
         cur0 = to_varying(jnp.zeros_like(hs[0]))
         outs0 = to_varying(jnp.zeros_like(hs))
-        # [1]-shaped and derived from a traced input, not a hoisted
-        # constant — both matter for the pre-vma transpose (see stage)
+        # [1]-shaped (see stage) and derived from a traced input, not a
+        # hoisted constant
         aux0 = to_varying((hs[0, 0, 0, :1] * 0.0).astype(jnp.float32))
         (cur, outs, aux), _ = jax.lax.scan(
             tick, (cur0, outs0, aux0), jnp.arange(M + n - 1)
@@ -163,15 +157,13 @@ def pipeline_hidden(
         # each stage summed the aux of its own layers over its M valid
         # microbatch runs. Export it as a per-stage [1] slice (the P(pp)
         # out spec concatenates them to [n]) and reduce OUTSIDE the
-        # region: pre-vma shard_map cannot re-shard a rank-0 output in
-        # the pipeline's transpose (MoE backward raises _SpecError),
-        # while a pp-sharded vector transposes on every jax release.
-        # Summing the slices is the old psum.
+        # region: a pp-sharded vector transposes cleanly in the MoE
+        # backward, and summing the slices is the psum.
         aux = aux / (cfg.num_hidden_layers * M)
         if sp_axis is not None:
             # chunk-local router stats: mean over sequence chunks, and the
             # pp-only out_spec needs the value invariant over sp
-            aux = jax.lax.psum(aux, sp_axis) / _axis_size(sp_axis)
+            aux = jax.lax.psum(aux, sp_axis) / jax.lax.axis_size(sp_axis)
         return outs, aux
 
     outs, aux_vec = _pipeline(cparams["layers"], hs, mb_positions)

@@ -278,6 +278,11 @@ class ServeEngine:
         self._fetch_pages = jax.jit(_fetch_pages, static_argnums=(3,))
         self._install_pages = jax.jit(_install_pages, donate_argnums=(0, 1))
 
+    @property
+    def device(self):
+        """The device the engine's KV cache (and so its jits) live on."""
+        return next(iter(self.cache_k.devices()))
+
     # -- weight residency ---------------------------------------------------
 
     def _assemble(self, leaves):

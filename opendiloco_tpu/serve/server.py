@@ -262,6 +262,10 @@ class ServeServer:
                     "weights_epoch": self.batcher.engine.weights_epoch,
                     "staleness": self.batcher.engine.staleness(),
                     "free_slots": self.batcher.slots.num_free,
+                    # where and how decode really runs, as resolved
+                    "platform": self.batcher.engine.device.platform,
+                    "device_kind": self.batcher.engine.device.device_kind,
+                    "decode_kernel": self.batcher.engine.decode_kernel,
                     # cold-tier load rides health so pollers (router
                     # probe, odtp_top) see paging pressure without /stats
                     **(

@@ -26,6 +26,7 @@ from opendiloco_tpu.diloco.outer_optimizer import OuterSGD
 from opendiloco_tpu.models import hf_io
 from opendiloco_tpu.parallel.mesh import build_mesh
 from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
+from opendiloco_tpu.utils.compile_cache import enable_compile_cache
 from opendiloco_tpu.utils.logger import get_text_logger
 
 log = get_text_logger(__name__)
@@ -68,6 +69,7 @@ def main(argv=None) -> None:
     ap.add_argument("--eval-batches", type=int, default=8)
     ap.add_argument("--seed", type=int, default=42)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     model_cfg, params = hf_io.get_model(args.path_model)
     plan = build_mesh("NO_SHARD")
@@ -195,9 +197,4 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
-    import os
-
-    platform = os.environ.get("OPENDILOCO_TPU_PLATFORM")
-    if platform:
-        jax.config.update("jax_platforms", platform)
     main()

@@ -333,18 +333,20 @@ class InnerTrainer:
 
     def init_state(self, rng: jax.Array, params: Optional[dict] = None) -> dict:
         """Initialize (or adopt) params and optimizer state, sharded per plan."""
-        init_fn = functools.partial(init_params, cfg=self.model_cfg)
-
         if params is None:
-            # init UNSHARDED, then reshard: with non-partitionable
-            # threefry (this jax's default) a sharded out_shardings
-            # changes the RNG lowering and thus the drawn values, so the
-            # same seed would yield different weights on different
-            # meshes — breaking every cross-mesh equivalence guarantee
-            # (and DiLoCo's same-seed multi-worker init contract)
-            params = jax.device_put(
-                jax.jit(init_fn)(rng), self.state_shardings["params"]
-            )
+            # drawn straight into the plan's shardings, so no device ever
+            # holds the whole model. Partitionable threefry (the installed
+            # jax's default) makes the draws independent of the mesh: the
+            # same seed yields the same weights on every layout, which the
+            # cross-mesh equivalence tests and DiLoCo's same-seed
+            # multi-worker init contract rely on. jit of the module-level
+            # function with a static cfg: trainers of one config on one
+            # layout (the workers of an in-process galaxy) share the compile
+            params = jax.jit(
+                init_params,
+                static_argnames="cfg",
+                out_shardings=self.state_shardings["params"],
+            )(rng, cfg=self.model_cfg)
         else:
             params = jax.device_put(params, self.state_shardings["params"])
         opt_state = jax.jit(

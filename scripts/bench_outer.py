@@ -36,6 +36,10 @@ ALL_CODECS = [
     "none", "fp16", "scaled-fp16", "uniform8bit", "quantile8bit",
     "blockwise8bit", "blockwise4bit", "topk",
 ]
+# the wire-plane benches (sweep, hetero, hier, compress, gossip, async) do
+# no device work at all and their artifacts say so (HOST_ONLY); the
+# in-process benches (boundary, stream) stamp the devices JAX gave them
+from opendiloco_tpu.utils.device import HOST_ONLY  # noqa: E402
 # tests point this somewhere disposable; default is the banked artifact
 _OUT = os.environ.get("ODTP_OUTER_BENCH_OUT") or os.path.join(
     REPO, "OUTER_BENCH.json"
@@ -425,6 +429,8 @@ def _append_row(
     doc.setdefault("host", {}).update(
         cores=os.cpu_count(), loadavg=round(os.getloadavg()[0], 2)
     )
+    if out == _OUT:  # the wire sweep; boundary rows stamp themselves
+        doc.update(HOST_ONLY)
     with open(out, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
         f.write("\n")
@@ -514,6 +520,7 @@ def boundary_main(args) -> None:
 
     from opendiloco_tpu.diloco.outer_device import DeviceOuterPlane
     from opendiloco_tpu.diloco.outer_optimizer import OuterSGD
+    from opendiloco_tpu.utils.device import device_stamp
 
     leaves = make_leaves(args.model, 0)
     nbytes = sum(a.nbytes for a in leaves)
@@ -578,7 +585,7 @@ def boundary_main(args) -> None:
             "mean_total_ms": round(statistics.fmean(totals) * 1e3, 1),
             "best_total_ms": round(totals[0] * 1e3, 1),
             "rounds_ms": [round(sum(s) * 1e3, 1) for s in stages],
-            "backend": jax.default_backend(),
+            **device_stamp(),
         }
         note = ""
         if placement == "host":
@@ -711,7 +718,6 @@ def hetero_main(args) -> None:
     )
     base_env = dict(os.environ)
     base_env["PYTHONPATH"] = REPO + os.pathsep + base_env.get("PYTHONPATH", "")
-    base_env.setdefault("OPENDILOCO_TPU_PLATFORM", "cpu")
 
     results = {}
     server = RendezvousServer(host="127.0.0.1", port=0).start_in_thread()
@@ -754,6 +760,7 @@ def hetero_main(args) -> None:
         "host": {
             "cores": os.cpu_count(), "loadavg": round(os.getloadavg()[0], 2)
         },
+        **HOST_ONLY,
     }
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
@@ -905,7 +912,6 @@ def hier_main(args) -> None:
     )
     base_env = dict(os.environ)
     base_env["PYTHONPATH"] = REPO + os.pathsep + base_env.get("PYTHONPATH", "")
-    base_env.setdefault("OPENDILOCO_TPU_PLATFORM", "cpu")
 
     results = {}
     server = RendezvousServer(host="127.0.0.1", port=0).start_in_thread()
@@ -963,6 +969,7 @@ def hier_main(args) -> None:
         "host": {
             "cores": os.cpu_count(), "loadavg": round(os.getloadavg()[0], 2)
         },
+        **HOST_ONLY,
     }
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
@@ -1019,7 +1026,6 @@ def compress_main(args) -> None:
     )
     base_env = dict(os.environ)
     base_env["PYTHONPATH"] = REPO + os.pathsep + base_env.get("PYTHONPATH", "")
-    base_env.setdefault("OPENDILOCO_TPU_PLATFORM", "cpu")
 
     arms = [("uniform8bit", False), ("blockwise4bit", True), ("topk", True)]
     results: dict[str, dict] = {}
@@ -1080,6 +1086,7 @@ def compress_main(args) -> None:
         "host": {
             "cores": os.cpu_count(), "loadavg": round(os.getloadavg()[0], 2)
         },
+        **HOST_ONLY,
     }
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
@@ -1245,8 +1252,11 @@ def stream_main(args) -> None:
         ).strip()
     import jax
 
+    # a choice, not a fallback: the arms compare outer-overhead shares on
+    # N virtual CPU devices, one per worker thread; the artifact says so
     jax.config.update("jax_platforms", "cpu")
     from opendiloco_tpu.models.hf_io import get_model
+    from opendiloco_tpu.utils.device import device_stamp
 
     cfg_model, _ = get_model("2m")
     # WAN round-trip stand-in: the chaos plane sleeps every all-reduce
@@ -1342,6 +1352,7 @@ def stream_main(args) -> None:
         "host": {
             "cores": os.cpu_count(), "loadavg": round(os.getloadavg()[0], 2)
         },
+        **device_stamp(),
     }
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
@@ -1510,6 +1521,7 @@ def gossip_main(args) -> None:
         "host": {
             "cores": os.cpu_count(), "loadavg": round(os.getloadavg()[0], 2)
         },
+        **HOST_ONLY,
     }
     g = {r["peers"]: r for r in rows if r["mode"] == "gossip"}
     a = {r["peers"]: r for r in rows if r["mode"] == "allreduce"}
@@ -1761,6 +1773,7 @@ def async_main(args) -> None:
         "host": {
             "cores": os.cpu_count(), "loadavg": round(os.getloadavg()[0], 2)
         },
+        **HOST_ONLY,
     }
     if "standalone" in agg and "async" in agg and "lockstep" in agg:
         doc["async_vs_standalone_sum"] = round(
@@ -1903,11 +1916,6 @@ def main() -> None:
             os.environ["MALLOC_MMAP_THRESHOLD_"] = str(1 << 30)
             os.environ["MALLOC_TRIM_THRESHOLD_"] = str(1 << 30)
             os.execv(sys.executable, [sys.executable] + sys.argv)
-        platform = os.environ.get("OPENDILOCO_TPU_PLATFORM")
-        if platform:
-            import jax
-
-            jax.config.update("jax_platforms", platform)
         if args.fresh and os.path.exists(_BOUNDARY_OUT):
             os.remove(_BOUNDARY_OUT)
         if args.codecs == ",".join(ALL_CODECS):
@@ -1943,7 +1951,6 @@ def main() -> None:
 
     base_env = dict(os.environ)
     base_env["PYTHONPATH"] = REPO + os.pathsep + base_env.get("PYTHONPATH", "")
-    base_env.setdefault("OPENDILOCO_TPU_PLATFORM", "cpu")
 
     server = RendezvousServer(host="127.0.0.1", port=0).start_in_thread()
     try:

@@ -96,13 +96,24 @@ def hier_sites(workers: int) -> tuple[str, str]:
     return site_spec, agg_spec
 
 
+# a choice, not a fallback: the soak exercises the socket plane, and its
+# worker subprocesses train on a 4-device CPU mesh wherever it runs. Every
+# report this script writes carries the stamp.
+WORKER_DEVICES = {"platform": "cpu", "device_kind": "cpu", "device_count": 4}
+# the gossip/async legs are host-only: numpy payloads over loopback
+# backends, no device work at all
+from opendiloco_tpu.utils.device import HOST_ONLY  # noqa: E402
+
+
 def worker_env(
     rank: int, workers: int, obs_dir: str, straggle_rank: int, kill_rank: int
 ) -> dict:
     env = dict(os.environ)
-    env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = WORKER_DEVICES["platform"]
+    env["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count="
+        f"{WORKER_DEVICES['device_count']}"
+    )
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     spec = WORKER_CHAOS.format(seed=7 + rank)
     if rank == straggle_rank:
@@ -447,6 +458,7 @@ def gossip_leg(args) -> int:
     ok = all(gates.values())
     report = {
         "bench": "gossip_chaos_leg",
+        **HOST_ONLY,
         "workers": n,
         "rounds": rounds,
         "churn_epoch": churn_at,
@@ -626,6 +638,7 @@ def async_leg(args) -> int:
     ok = all(gates.values())
     report = {
         "bench": "async_chaos_leg",
+        **HOST_ONLY,
         "workers": n,
         "window": window,
         "patience_s": patience,
@@ -910,6 +923,7 @@ def main() -> int:
     )
     obs_report = {
         "bench": "obs_galaxy",
+        **WORKER_DEVICES,
         "model": args.model,
         "workers": args.workers,
         "rounds": args.rounds,
@@ -963,6 +977,7 @@ def main() -> int:
     daemon_faults = fault_counts(daemon_out)
     report = {
         "bench": "chaos_soak",
+        **WORKER_DEVICES,
         "model": args.model,
         "data": "fake ramp stream (learnable; loss gate is real descent)",
         "workers": args.workers,

@@ -9,17 +9,18 @@ rig it banks every claim that CAN be proven off-chip:
 - the dead-ring-block skip, measured by the kernels' own stats output
   (processed-block counters, not a model) against the dense-equivalent
   block count the XLA path always pays
-- Mosaic lowering of each kernel via deviceless PJRT topology AOT
-  (v5e:2x2, the scripts/aot_roofline.py idiom): the stablehlo must
-  contain tpu_custom_call — proof the kernels compile for real TPUs
-  from this exact tree, not just interpret
+- Mosaic COMPILE of each kernel via deviceless PJRT topology AOT
+  (v5e:2x2, the scripts/aot_roofline.py idiom), in bf16 at a serving
+  shape: the compiled program must contain tpu_custom_call — the chip's
+  compiler accepted the kernels from this exact tree. A compile that
+  passes is still not a chip run
 - XLA-arm reference timings (the baseline a TPU A/B runs against)
 
 The on-chip >=2x DECODE_BENCH gate stays a ROADMAP follow-up; this
 artifact is the CPU-rig half of the acceptance evidence.
 
 --selftest: small shapes, artifact to /tmp, hard-asserts parity/skip
-(CI decode-kernel job); lowering is asserted only when the topology
+(CI decode-kernel job); the compile is asserted only when the topology
 libraries are available.
 """
 
@@ -187,12 +188,17 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
     assert bitwise, "w4 identity probe diverged from dequant_w4"
 
 
-def _mosaic_lowering(doc: dict, *, small: bool) -> bool:
-    """Deviceless v5e AOT of each kernel: Mosaic shows up as
-    tpu_custom_call in the lowered stablehlo. Returns True when all
-    three kernels lowered (False = topology libs unavailable)."""
+def _mosaic_compile(doc: dict) -> bool:
+    """Deviceless v5e AOT of each kernel, all the way through Mosaic:
+    ``.lower().compile()`` at a serving shape in bf16 (the engine's
+    compute dtype), and ``tpu_custom_call`` must be in the compiled
+    program. Stopping at ``.lower()`` proves nothing — the layout and
+    VMEM checks that refuse a kernel run at compile. tests/
+    test_tpu_compile.py keeps the same check in tier-1 at both published
+    head layouts. Returns False when the topology libs are unavailable."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
 
     from opendiloco_tpu.diloco.compression import pack_blockwise4_stacked
     from opendiloco_tpu.ops.decode_kernels import (
@@ -212,19 +218,20 @@ def _mosaic_lowering(doc: dict, *, small: bool) -> bool:
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
-        dev = topo.devices[0]
+        on_dev = SingleDeviceSharding(topo.devices[0])
     except Exception as e:  # no TPU compiler libs on this rig
-        doc["mosaic_lowering"] = {
+        doc["mosaic_compile"] = {
             "error": f"topology unavailable: {type(e).__name__}: {e}"
         }
         return False
 
-    S, T, Nh, Nkv, D, Kq = (
-        (4, 64, 8, 4, 16, 3) if small else (8, 512, 16, 8, 64, 4)
-    )
-    K, N = (128, 128) if small else (2048, 2048)
-    f32 = jnp.float32
-    sds = jax.ShapeDtypeStruct
+    S, T, Nh, Nkv, D, Kq = 8, 512, 16, 8, 64, 4
+    K, N = 2048, 2048
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_dev)
+
     rng = np.random.default_rng(0)
     qw_np, sw_np = pack_blockwise4_stacked(
         rng.normal(size=(1, K, N)).astype(np.float32)
@@ -236,8 +243,8 @@ def _mosaic_lowering(doc: dict, *, small: bool) -> bool:
                 q, k, v, lens, interpret=False
             ),
             (
-                sds((S, Nh, D), f32), sds((S, T, Nkv, D), f32),
-                sds((S, T, Nkv, D), f32), sds((S,), jnp.int32),
+                sds((S, Nh, D), bf16), sds((S, T, Nkv, D), bf16),
+                sds((S, T, Nkv, D), bf16), sds((S,), jnp.int32),
             ),
         ),
         "spec_verify": (
@@ -245,17 +252,17 @@ def _mosaic_lowering(doc: dict, *, small: bool) -> bool:
                 q, ck, cv, tk, tv, lens, interpret=False
             ),
             (
-                sds((S, Kq, Nh, D), f32), sds((S, T, Nkv, D), f32),
-                sds((S, T, Nkv, D), f32), sds((S, Kq, Nkv, D), f32),
-                sds((S, Kq, Nkv, D), f32), sds((S,), jnp.int32),
+                sds((S, Kq, Nh, D), bf16), sds((S, T, Nkv, D), bf16),
+                sds((S, T, Nkv, D), bf16), sds((S, Kq, Nkv, D), bf16),
+                sds((S, Kq, Nkv, D), bf16), sds((S,), jnp.int32),
             ),
         ),
         "w4_matmul": (
             lambda x, q, s: w4_matmul(
-                x, q, s, (K, N), f32, interpret=False
+                x, q, s, (K, N), bf16, interpret=False
             ),
             (
-                sds((S, K), f32), sds(qw_np[0].shape, jnp.uint8),
+                sds((S, K), bf16), sds(qw_np[0].shape, jnp.uint8),
                 sds(sw_np[0].shape, jnp.uint16),
             ),
         ),
@@ -263,31 +270,21 @@ def _mosaic_lowering(doc: dict, *, small: bool) -> bool:
     rows = {}
     ok = True
     for name, (fn, args) in kernels.items():
-        _log(f"mosaic lowering: {name}")
+        _log(f"mosaic compile: {name}")
         try:
-            try:
-                lowered = jax.jit(fn).lower(*args, _device=dev)
-            except TypeError:
-                # older jax spells the AOT target differently
-                from jax.sharding import SingleDeviceSharding
-
-                lowered = jax.jit(
-                    fn,
-                    in_shardings=[SingleDeviceSharding(dev) for _ in args],
-                ).lower(*args)
+            text = jax.jit(fn).lower(*args).compile().as_text()
         except Exception as e:
-            rows[name] = {"lowered": False, "error": f"{type(e).__name__}: {e}"}
+            rows[name] = {"compiled": False, "error": f"{type(e).__name__}: {e}"}
             ok = False
             continue
-        text = lowered.as_text()
         is_mosaic = "tpu_custom_call" in text
-        rows[name] = {
-            "lowered": True,
-            "mosaic_tpu_custom_call": is_mosaic,
-            "stablehlo_bytes": len(text),
-        }
+        rows[name] = {"compiled": True, "mosaic_tpu_custom_call": is_mosaic}
         ok = ok and is_mosaic
-    doc["mosaic_lowering"] = {"target": "v5e:2x2 (deviceless PJRT AOT)", **rows}
+    doc["mosaic_compile"] = {
+        "target": "v5e:2x2 (deviceless PJRT AOT), bf16",
+        "shape": f"S{S} T{T} Hq{Nh} Hkv{Nkv} D{D} Kq{Kq}; w4 K{K} N{N}",
+        **rows,
+    }
     return ok
 
 
@@ -301,9 +298,9 @@ def main() -> int:
     args = ap.parse_args()
     import jax
 
+    from opendiloco_tpu.utils.device import device_stamp
     doc = {
-        "backend": jax.default_backend(),
-        "device": jax.devices()[0].device_kind,
+        **device_stamp(),
         "generated": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "note": (
             "CPU-rig arms run the Pallas kernels in interpret mode, so only "
@@ -313,18 +310,21 @@ def main() -> int:
         ),
     }
     _parity_and_skip(doc, small=args.selftest)
-    _log("parity/skip done; attempting deviceless Mosaic lowering")
-    lowered = _mosaic_lowering(doc, small=True)  # lowering shape-agnostic
+    _log("parity/skip done; attempting deviceless Mosaic compile")
+    compiled = _mosaic_compile(doc)
     _log("writing artifact")
     out = "/tmp/decode_kernel_bench_selftest.json" if args.selftest else args.out
     with open(out, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
         f.write("\n")
     print(json.dumps(doc, indent=1, sort_keys=True))
-    if args.selftest and not lowered:
+    if "error" in doc["mosaic_compile"]:
         # parity/skip asserts already passed; missing TPU compiler libs
         # must not fail CI, absence is recorded in the artifact
-        print("selftest: mosaic lowering skipped (no TPU compiler libs)")
+        print("mosaic compile skipped (no TPU compiler libs)")
+    elif not compiled:
+        print("MOSAIC COMPILE FAILED", file=sys.stderr)
+        return 1
     return 0
 
 

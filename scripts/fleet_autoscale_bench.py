@@ -347,19 +347,19 @@ def main() -> None:
             os.environ.get("TMPDIR", "/tmp"), "AUTOSCALE_BENCH.selftest.json"
         )
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("ODTP_OBS", "autoscale-bench")  # watchdogs armed
     # keep breach-exemplar traces resolvable: later traffic must not
     # evict them from the completed ring before the gates look them up
     os.environ.setdefault("ODTP_REQTRACE_CAP", "16384")
-    # replica subprocesses share one jit cache: a cold boot is a process
-    # start + cache hit, not a recompile (closer to a real image pull)
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.environ.get("TMPDIR", "/tmp"), "odtp-autoscale-jit"),
-    )
-
     import jax
+
+    from opendiloco_tpu.utils.compile_cache import enable_compile_cache
+    from opendiloco_tpu.utils.device import device_stamp
+
+    # replica subprocesses resolve the same directory (fleet.replica.main
+    # calls this too), so they share one jit cache: a cold boot is a
+    # process start + cache hit, not a recompile (closer to an image pull)
+    enable_compile_cache()
 
     from opendiloco_tpu import fleet, obs
     from opendiloco_tpu.config import FleetConfig
@@ -556,6 +556,7 @@ def main() -> None:
         "schema": 1,
         "selftest": bool(args.selftest),
         "host": {"node": os.uname().nodename, "cpus": os.cpu_count()},
+        **device_stamp(),
         "updated": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "slo": {
             "p99_ms": args.slo_p99_ms,

@@ -23,19 +23,15 @@ _OUT = os.path.join(_ROOT, "LIVE_CKPT.json")
 def main():
     import jax
 
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("OPENDILOCO_TPU_COMPILE_CACHE", "/tmp/odtp-jax-cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    from opendiloco_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from opendiloco_tpu.ckpt import load_checkpoint, save_checkpoint
     from opendiloco_tpu.models.hf_io import get_model
     from opendiloco_tpu.parallel.mesh import build_mesh
     from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
+    from opendiloco_tpu.utils.device import device_stamp
 
     cfg, _ = get_model("2m")
     tc = TrainerConfig(
@@ -72,7 +68,7 @@ def main():
 
     doc = {
         "device": jax.devices()[0].device_kind,
-        "platform": jax.devices()[0].platform,
+        **device_stamp(),
         "model": "2m",
         "remat": "dots_all",
         "steps_before_save": 20,

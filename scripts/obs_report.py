@@ -28,15 +28,22 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# the stage taxonomy the report guarantees per round; values are seconds
+# the stage names the report guarantees per round; values are seconds
 STAGES = ("rendezvous", "encode", "wire", "accumulate", "barrier_wait", "apply")
+
+
+# a choice, not a fallback: the report exercises the socket plane, and its
+# worker subprocesses train on a 4-device CPU mesh wherever it runs
+WORKER_DEVICES = {"platform": "cpu", "device_kind": "cpu", "device_count": 4}
 
 
 def worker_env(rank: int, trace_dir: str) -> dict:
     env = dict(os.environ)
-    env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = WORKER_DEVICES["platform"]
+    env["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count="
+        f"{WORKER_DEVICES['device_count']}"
+    )
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["ODTP_OBS"] = "1"
     env["ODTP_OBS_DIR"] = trace_dir
@@ -671,7 +678,6 @@ def reqtrace_main(args) -> int:
     import socket as socketlib
     import threading
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # the baseline arm must be genuinely unarmed
     for var in ("ODTP_OBS", "ODTP_OBS_DIR", "ODTP_REQTRACE_CAP",
                 "ODTP_REQTRACE_SAMPLE", "ODTP_REQTRACE_EXPORT"):
@@ -687,7 +693,7 @@ def reqtrace_main(args) -> int:
     from opendiloco_tpu.serve.engine import ServeEngine
     from opendiloco_tpu.serve.scheduler import ContinuousBatcher
     from opendiloco_tpu.serve.server import ServeServer
-
+    from opendiloco_tpu.utils.device import device_stamp
     t_start = time.time()
     n_requests = 16 if args.selftest else 64
     n_doomed = 3
@@ -835,7 +841,8 @@ def reqtrace_main(args) -> int:
 
     body = {
         "bench": "reqtrace",
-        "model": f"llama-{layers}L-h{hidden} (cpu)",
+        "model": f"llama-{layers}L-h{hidden}",
+        **device_stamp(),
         "requests_per_arm": n_requests,
         "clients": clients,
         "max_new_tokens": max_new,
@@ -995,6 +1002,7 @@ def main() -> int:
             losses.append((rows[0].get("Loss"), rows[-1].get("Loss")))
     report = {
         "bench": "obs_report",
+        **WORKER_DEVICES,
         "model": args.model,
         "workers": args.workers,
         "rounds": args.rounds,

@@ -17,19 +17,15 @@ if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
 import bench  # noqa: E402
+from opendiloco_tpu.obs.mfu import peak_flops  # noqa: E402
 
 
 def main():
     import jax
 
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("OPENDILOCO_TPU_COMPILE_CACHE", "/tmp/odtp-jax-cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    from opendiloco_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     with open(os.path.join(_ROOT, "PUSH40.json")) as f:
         push = json.load(f)
@@ -130,7 +126,8 @@ def main():
         "xla_flops_per_step": flops,
         "xla_hbm_bytes_per_step": bytes_hbm,
         "measured_step_s": round(step_s, 5),
-        "flops_bound_step_s": round(flops / bench.peak_flops_per_chip(), 5),
+        # against the peak of the device the sweep was MEASURED on
+        "flops_bound_step_s": round(flops / peak_flops(sweep["device"]), 5),
         "hbm_bound_step_s": round(bytes_hbm / 819e9, 5),
         "note": (
             "step time vs max(flops_bound, hbm_bound) attributes the gap; "

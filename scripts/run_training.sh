@@ -27,6 +27,24 @@ shift 2
 REPO_DIR="$(cd "$(dirname "$0")/.." && pwd)"
 export PYTHONPATH="$REPO_DIR${PYTHONPATH:+:$PYTHONPATH}"
 
+# A chip belongs to one process at a time, and a worker builds its mesh from
+# every device it can see. So on a TPU host worker i is shown chip i and no
+# other, and more workers than chips is refused (elsewhere, e.g. under
+# JAX_PLATFORMS=cpu, workers share the host as before). The probe is a
+# process of its own: it has let go of the chips before any worker starts.
+read -r PLATFORM NUM_DEVICES < <(
+  python -c 'import jax; d = jax.devices(); print(d[0].platform, len(d))'
+) || true
+if [ -z "${PLATFORM:-}" ]; then
+  echo "$0: could not ask JAX for its devices" >&2
+  exit 1
+fi
+if [ "$PLATFORM" = "tpu" ] && [ "$NUM_WORKERS" -gt "$NUM_DEVICES" ]; then
+  echo "$0: $NUM_WORKERS workers but $NUM_DEVICES TPU chips on this host:" \
+    "a chip belongs to one process, so each worker needs its own" >&2
+  exit 1
+fi
+
 RDV_PID=""
 if [ "$INITIAL_PEER" = "auto" ]; then
   INITIAL_PEER="127.0.0.1:29400"
@@ -47,7 +65,23 @@ PIDS=()
 for RANK in $(seq 0 $((NUM_WORKERS - 1))); do
   # secondary workers keep wandb quiet (reference run_training.sh:69)
   if [ "$RANK" -ne 0 ]; then export WANDB_MODE=${WANDB_MODE:-disabled}; fi
-  python -m opendiloco_tpu.train \
+  CHIP_ENV=()
+  if [ "$PLATFORM" = "tpu" ]; then
+    # one-chip process on a multi-chip host (libtpu's own variables, in
+    # both of their spellings): see only chip RANK, a 1x1x1 topology, and
+    # a controller port of its own
+    CHIP_ENV=(
+      TPU_VISIBLE_CHIPS="$RANK"
+      TPU_VISIBLE_DEVICES="$RANK"
+      TPU_CHIPS_PER_PROCESS_BOUNDS=1,1,1
+      TPU_CHIPS_PER_HOST_BOUNDS=1,1,1
+      TPU_PROCESS_BOUNDS=1,1,1
+      TPU_HOST_BOUNDS=1,1,1
+      TPU_MESH_CONTROLLER_ADDRESS="localhost:$((8476 + RANK))"
+      TPU_MESH_CONTROLLER_PORT="$((8476 + RANK))"
+    )
+  fi
+  env "${CHIP_ENV[@]}" python -m opendiloco_tpu.train \
     --diloco.initial-peers "$INITIAL_PEER" \
     --diloco.world-rank "$RANK" \
     --diloco.galaxy-size "$NUM_WORKERS" \

@@ -716,7 +716,7 @@ def main() -> None:
             os.environ.get("TMPDIR", "/tmp"), f"{name}.selftest.json"
         )
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from opendiloco_tpu.utils.device import device_stamp
     if args.longctx:
         if not args.selftest:
             args.slots = min(args.slots, 4)  # 4 device slots vs ~18 requests
@@ -730,6 +730,7 @@ def main() -> None:
             doc = {"schema": 1}
         doc["longctx"] = {
             "selftest": bool(args.selftest),
+            **device_stamp(),
             "updated": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
             **result,
         }
@@ -774,6 +775,7 @@ def main() -> None:
             "schema": 1,
             "selftest": bool(args.selftest),
             "host": {"node": os.uname().nodename, "cpus": os.cpu_count()},
+            **device_stamp(),
             "updated": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
             **result,
         }
@@ -811,6 +813,7 @@ def main() -> None:
             "node": os.uname().nodename,
             "cpus": os.cpu_count(),
         },
+        **device_stamp(),
         "updated": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         **result,
     }

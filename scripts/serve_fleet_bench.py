@@ -620,6 +620,9 @@ def run_arm(args, model_cfg, n_replicas, with_chaos) -> dict:
                 counters[cname] = counters.get(cname, 0) + v
         arm = {
             "replicas": n_replicas,
+            "replica_platforms": sorted(
+                {str(i.get("platform")) for i in infos.values()}
+            ),
             "clients": clients.n,
             "duration_s": round(elapsed, 3),
             "requests_per_s": round(clients.completed / elapsed, 3),
@@ -750,14 +753,13 @@ def main() -> None:
         )
     sizes = [int(x) for x in args.replicas.split(",") if x.strip()]
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("ODTP_OBS", "fleet-bench")  # chaos plane armed
     # big completed ring: post-kill traffic must not evict the SIGKILL
     # victims' traces before the gates inspect them
     os.environ.setdefault("ODTP_REQTRACE_CAP", "8192")
 
     from opendiloco_tpu.models.llama import LlamaConfig
-
+    from opendiloco_tpu.utils.device import device_stamp
     model_cfg = LlamaConfig(
         vocab_size=256,
         hidden_size=args.hidden,
@@ -809,6 +811,9 @@ def main() -> None:
         "schema": 1,
         "selftest": bool(args.selftest),
         "host": {"node": os.uname().nodename, "cpus": os.cpu_count()},
+        # this (router + simulated trainer) process; each arm records the
+        # platform its replica subprocesses reported on their ready lines
+        **device_stamp(),
         "updated": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "model": {
             "hidden": model_cfg.hidden_size,

@@ -452,6 +452,22 @@ def test_router_http_frontend_health_and_stats():
 # replica + manager end to end (jax)
 # ---------------------------------------------------------------------------
 
+
+def test_build_fleet_refuses_subprocess_replicas_beside_a_tpu(
+    tiny_cfg, monkeypatch
+):
+    """A chip belongs to one process: a trainer that holds a TPU must not
+    quietly get CPU replicas (nor children that hang reaching the chip)."""
+    import jax
+
+    from opendiloco_tpu.config import FleetConfig
+    from opendiloco_tpu.fleet import build_fleet
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="chip belongs to one process"):
+        build_fleet(FleetConfig(enabled=True, replicas=1), tiny_cfg, params=None)
+
+
 ENGINE_GEOM = dict(num_slots=4, max_context=64, prefill_buckets=(8, 16, 32))
 
 
@@ -538,6 +554,8 @@ def test_fleet_end_to_end_inprocess(tiny_cfg):
             health = json.loads(r.read())
         assert health["stale"] is True and health["staleness"] == 6
         assert health["replica"] == "r0"
+        # a replica names the platform it really runs on
+        assert health["platform"] == "cpu" and health["decode_kernel"] == "xla"
         assert wait(lambda: router.stats()["replicas"]["r0"]["stale"], 10)
     finally:
         mgr.stop()

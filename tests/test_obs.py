@@ -240,12 +240,16 @@ def test_prom_endpoint_serves_over_http(monkeypatch):
 
 
 def test_mfu_from_roofline_and_fallback():
-    per_tok, peak, source = mfu.flops_per_token("1b", n_params=1_000_000_000)
+    per_tok, source = mfu.flops_per_token("1b", n_params=1_000_000_000)
     assert source == "roofline"
     assert per_tok and per_tok > 1e9
+    peak = mfu.peak_flops("TPU v5 lite")
     assert peak == pytest.approx(1.97e14)
+    # a device that is not in the peaks table is an error, not a default
+    with pytest.raises(ValueError, match="no bf16 peak"):
+        mfu.peak_flops("cpu")
     # unknown model falls back to 6N
-    per_tok2, _, source2 = mfu.flops_per_token("nosuch", n_params=1000)
+    per_tok2, source2 = mfu.flops_per_token("nosuch", n_params=1000)
     assert source2 == "analytic_6n"
     assert per_tok2 == 6000
     u = mfu.mfu(1e5, per_tok, n_devices=8, peak_flops_per_device=peak)

@@ -463,6 +463,8 @@ def test_http_and_jsonl_frontend(tiny_cfg):
 
         health = json.loads(_http_get(srv.port, "/healthz"))
         assert health["ok"] is True
+        assert (health["platform"], health["device_kind"]) == ("cpu", "cpu")
+        assert health["decode_kernel"] == "xla"
         stats = json.loads(_http_get(srv.port, "/stats"))
         assert stats["completed"] >= 1 and stats["failed"] == 0
 

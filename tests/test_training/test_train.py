@@ -22,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 
 def run_cli(args: list[str], env_extra=None, timeout=600) -> subprocess.CompletedProcess:
     env = dict(os.environ)
-    env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.update(env_extra or {})
@@ -81,7 +81,7 @@ def spawn_worker(args) -> subprocess.Popen:
     """Launch one training worker process on the CPU mesh (multi-worker
     tests share this so env/launch changes happen in one place)."""
     env = dict(os.environ)
-    env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.Popen(
@@ -242,7 +242,6 @@ def test_graft_dryrun_multichip(tmp_path):
             [
                 sys.executable,
                 "-c",
-                "import jax; jax.config.update('jax_platforms', 'cpu');"
                 f"import __graft_entry__ as g; g.dryrun_multichip({n})",
             ],
             capture_output=True,
@@ -274,7 +273,7 @@ def test_run_training_sh_launcher(tmp_path):
     """The documented multi-worker launcher works end to end (auto
     rendezvous via the native daemon when built, else Python)."""
     env = dict(os.environ)
-    env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["WANDB_MODE"] = "disabled"
     r = subprocess.run(
@@ -325,7 +324,7 @@ def test_multi_worker_resume_deterministic(tmp_path):
             ],
         )
         env = dict(os.environ)
-        env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         return subprocess.Popen(
@@ -385,7 +384,7 @@ def test_multihost_two_process_train_and_resume(tmp_path):
 
     def launch(pid, logf, extra):
         env = dict(os.environ)
-        env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         args = [
@@ -492,7 +491,7 @@ def test_multihost_diloco_compose_hybrid(tmp_path):
 
     def launch_slice_proc(rank, pid, coord_port, logf, ckpt_dir, extra):
         env = dict(os.environ)
-        env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         args = worker_args(rank, logf, ckpt_dir) + [
@@ -646,7 +645,7 @@ def test_multihost_diloco_slice_modes(tmp_path, mode, extra):
 
     def launch(pid):
         env = dict(os.environ)
-        env["OPENDILOCO_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         args = [
