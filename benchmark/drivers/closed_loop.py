@@ -3,7 +3,8 @@ request when the last is answered. One thread drives them all. The clients
 start staggered (client i's first request is i/clients of the length), so that
 in the window their requests end evenly in time, as a long-running job's do,
 and not in waves. The window opens when every client's first request is done;
-a request counts in the window in which it ends."""
+a request counts in the window in which it ends. A ``--trace 2`` run closes
+that window untraced, then keeps the clients sending for a few traced seconds."""
 
 from __future__ import annotations
 
@@ -25,12 +26,27 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         serve_cell.warm_up(engine, batcher, cfg.vocab_size, seed)
         pool = iter(traffic.requests(mix, POOL, cfg.vocab_size, seed))
 
-        def send(share=1.0):
+        # a --trace 2 run's requests carry a trace in its traced stretch only
+        def send(share=1.0, mint=trace == 1):
             a = next(pool)
             return batcher.submit(
                 a.prompt, max_new_tokens=max(2, round(a.max_new_tokens * share)),
-                trace=serve_cell.mint_trace() if trace else None,
+                trace=serve_cell.mint_trace() if mint else None,
             )
+
+        def pump(until, ended=None, mint=trace == 1):
+            """The clients until ``until``: whoever has its answer sends its
+            next request. -> [(submit instant, request)] sent meanwhile"""
+            sent = []
+            while time.perf_counter() < until:
+                for i, req in enumerate(inflight):
+                    if req.t_done is not None:
+                        if ended is not None and req.t_done <= until:
+                            ended.append((req.t_submit, req))
+                        inflight[i] = send(mint=mint)
+                        sent.append((inflight[i].t_submit, inflight[i]))
+                time.sleep(POLL_S)
+            return sent
 
         # staggered start, outside the window
         inflight = [send((i + 1) / clients) for i in range(clients)]
@@ -50,25 +66,27 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         setup_s = time.perf_counter() - t_process
         t0 = time.perf_counter()
         t1 = t0 + seconds
-        tracer = serve_cell.start_tracer(cell, seconds, instrument) if trace else None
+        tracer = serve_cell.start_tracer(cell, seconds, instrument) if trace == 1 else None
         done = []  # (submit instant, request) of those that ended in the window
-        while time.perf_counter() < t1:
-            for i, req in enumerate(inflight):
-                if req.t_done is not None:
-                    if req.t_done <= t1:
-                        done.append((req.t_submit, req))
-                    inflight[i] = send()
-            time.sleep(POLL_S)
+        pump(t1, done)
         after = serve_cell.snapshot(engine, batcher)
         t_after = time.perf_counter()
         in_window = compiles.requests - requests_before
+        open_at_close = list(inflight)  # the traced stretch replaces them
+        traced = None
+        if trace == 2:
+            traced = serve_cell.traced_stretch(
+                cell, engine, batcher, compiles, report,
+                lambda until: pump(until, mint=True),
+                meanwhile=lambda: pump(time.perf_counter() + POLL_S),
+            )
     finally:
         batcher.stop()
 
     tail_facts = serve_cell.tails(done, report)
     # every output token produced inside the window: the decode steps' tokens
     # and the first token of each prefill that ended in it
-    prefills = sum(1 for r in [*[r for _, r in done], *inflight]
+    prefills = sum(1 for r in [*[r for _, r in done], *open_at_close]
                    if r.t_first is not None and t0 <= r.t_first <= t1)
     tokens = after["new_tokens"] - before["new_tokens"] + prefills
     rate = tokens / (t_after - t0)
@@ -85,5 +103,5 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         cell=cell, peak=peak, engine=engine, batcher=batcher, before=before, after=after,
         window_s=t_after - t0, reqs_due=done, in_window=in_window, check_ok=check_ok,
         e2e=e2e, tail_facts=tail_facts, trace=trace, tracer=tracer,
-        instrument=instrument, extra_counters={"clients": clients},
+        instrument=instrument, traced=traced, extra_counters={"clients": clients},
     )

@@ -27,7 +27,8 @@ from odbench import costs, program_obs, reference, traffic, xplane
 LOSS_RTOL = 5e-4
 GRAD_NORM_RTOL = 1e-2
 # the window holds whole rounds, as many as fit in --seconds and at least
-# this many; a traced run traces its second round (index 1)
+# this many; a --trace 1 run traces its second round (index 1); a --trace 2
+# run closes its window untraced and then traces one more whole round
 MIN_ROUNDS = 2
 TRACED_ROUND = 1
 
@@ -178,7 +179,7 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         t_start = round_start = time.perf_counter()
         rounds = 0
         while True:
-            tracing = trace and rounds == TRACED_ROUND
+            tracing = trace == 1 and rounds == TRACED_ROUND
             if tracing:
                 xplane.start(trace_dir)
                 round_start = time.perf_counter()
@@ -202,6 +203,19 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         jax.block_until_ready(state["params"])
         t_end = time.perf_counter()
         in_window = compiles.requests - before
+        # the window is closed: its losses and rows are the ones that count
+        win_losses, win_rows = list(losses), list(outer_rows)
+        stretch = None
+        if trace == 2:
+            # one more whole round of the same traffic, under the program's
+            # capture control (profiler, spans and counters together)
+            losses.clear()
+            outer_rows.clear()
+            with program_obs.Stretch(trace_dir, compiles) as stretch:
+                state, pending = one_round(state, None, [])
+                jax.block_until_ready(state["params"])
+            traced_walls.append(stretch.t1 - stretch.t0)
+            flush(pending)
     finally:
         prefetcher.stop()
         opt.drop_pending()
@@ -209,35 +223,41 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
     wall = t_end - t_start
     steps = rounds * steps_per_round
     tokens = steps * batch * seq
-    failed = sum(1 for x in losses if not math.isfinite(x))
+    failed = sum(1 for x in win_losses if not math.isfinite(x))
     rate = tokens / wall / cell.chips
     inner_s = statistics.median(step_dts)
     report.line(
         "window", rounds=rounds, steps=steps, tokens=tokens, wall_s=wall,
         round_walls_s=round_walls, traced_round_walls_s=traced_walls,
         inner_step_median_s=inner_s,
-        train_tokens_per_s_per_chip=rate, loss_first=losses[0], loss_last=losses[-1],
-        optimizer_outer_rows=outer_rows, compiles_in_window=in_window,
-        setup_s=setup_s,
+        train_tokens_per_s_per_chip=rate, loss_first=win_losses[0],
+        loss_last=win_losses[-1], optimizer_outer_rows=win_rows,
+        compiles_in_window=in_window, setup_s=setup_s,
+        **({"traced_outer_rows": outer_rows, "compiles_in_trace": stretch.compiles,
+            "traced_span_seconds": program_obs.seconds_by_name(stretch.capture, "outer/")}
+           if stretch else {}),
     )
     observations = {
         "counters": {
             "local_steps": steps_per_round, "round_walls_s": round_walls,
             "inner_step_dts_s": step_dts, "tokens_per_step": batch * seq,
             "seq_length": seq, "global_batch": batch, "chips": cell.chips,
-            "outer_rows": outer_rows,
+            "outer_rows": win_rows,
         },
     }
     if trace:
-        observations["trace"] = xplane.reduce(
-            trace_dir, program_obs.spans(), rehearsal=peak is None
+        observations["trace"] = (
+            stretch.reduce(rehearsal=peak is None) if stretch
+            else xplane.reduce(trace_dir, program_obs.spans(), rehearsal=peak is None)
         )
         observations["counters"]["traced_steps"] = steps_per_round
     return {
-        "correct": check_ok and failed == 0 and len(losses) == steps,
+        "correct": check_ok and failed == 0 and len(win_losses) == steps,
         "attempted": steps,
         "failed": failed,
         "compiles_in_window": in_window,
+        "compiles_in_trace": stretch.compiles if stretch else 0,
+        "trace_cost": stretch.cost if stretch else {},
         "end_to_end": {"train_tokens_per_s_per_chip": rate, "setup_s": setup_s},
         "observations": observations,
     }

@@ -1,6 +1,7 @@
 """Serving, independent users: arrivals on a schedule drawn from the seed,
 whatever the system does. Every request due inside the window is measured,
-and the run lasts until each has been answered."""
+and the run lasts until each has been answered. A ``--trace 2`` run then
+offers a few more seconds of the same mix, traced."""
 
 from __future__ import annotations
 
@@ -53,10 +54,22 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         requests_before = compiles.requests
         before = serve_cell.snapshot(engine, batcher)
         setup_s = time.perf_counter() - t_process
-        tracer = serve_cell.start_tracer(cell, seconds, instrument) if trace else None
-        reqs_due, backlog = window(batcher, arrivals, seconds, trace)
+        tracer = serve_cell.start_tracer(cell, seconds, instrument) if trace == 1 else None
+        reqs_due, backlog = window(batcher, arrivals, seconds, trace == 1)
         after = serve_cell.snapshot(engine, batcher)
         in_window = compiles.requests - requests_before
+        traced = None
+        if trace == 2:
+            more = traffic.open_loop(
+                cell.traffic, serve_cell.TRACED_SECONDS, cfg.vocab_size, seed + 1
+            )
+
+            def keep_sending(until):
+                return window(batcher, more, until - time.perf_counter(), True)[0]
+
+            traced = serve_cell.traced_stretch(
+                cell, engine, batcher, compiles, report, keep_sending
+            )
     finally:
         batcher.stop()
 
@@ -73,5 +86,6 @@ def run(*, cell, devices, peak, seed, seconds, trace, t_process, compiles, repor
         cell=cell, peak=peak, engine=engine, batcher=batcher, before=before,
         after=after, window_s=seconds + backlog["drain_s"], reqs_due=reqs_due,
         in_window=in_window, check_ok=check_ok, e2e=e2e, tail_facts=tail_facts,
-        trace=trace, tracer=tracer, instrument=instrument, extra_counters=backlog,
+        trace=trace, tracer=tracer, instrument=instrument, traced=traced,
+        extra_counters=backlog,
     )

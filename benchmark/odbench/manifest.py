@@ -137,9 +137,13 @@ def problems(m: Manifest) -> list:
     empty when sound."""
     raw, out = m.raw, []
     want = {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
-    if set(raw) != want:
-        out.append(f"keys {sorted(raw)} are not exactly {sorted(want)}")
+    # the one optional key: the per-layer metrics come from a traced stretch
+    # of the measuring run itself (--trace 2), not from a run of their own
+    if set(raw) - {"trace_in_run"} != want:
+        out.append(f"keys {sorted(raw)} are not exactly {sorted(want)} (and trace_in_run)")
         return out
+    if not isinstance(raw.get("trace_in_run", True), bool):
+        out.append("trace_in_run is true or false")
     configs = {c["name"]: c for c in raw["configs"]}
     cells = {w["name"]: w for w in raw["workloads"]}
     e2e = {x["name"]: x for x in raw["end_to_end"]}

@@ -69,13 +69,13 @@ def build(cell, devices, seed, report, t_process):
 
 def start(cell, devices, seed, trace, report, t_process):
     """What both serving drivers do first: the engine, the check against the
-    reference, the traced run's instrument, the batcher's loop started.
+    reference, the ``--trace 1`` run's instrument, the batcher's loop started.
     -> (model config, engine, check passed, instrument or None, batcher)"""
     from opendiloco_tpu.serve import ContinuousBatcher
 
     cfg, engine = build(cell, devices, seed, report, t_process)
     check_ok = check_logits(cell, engine, seed, report, t_process)
-    instrument = Instrument(engine) if trace else None
+    instrument = Instrument(engine) if trace == 1 else None
     batcher = ContinuousBatcher(engine).start()
     return cfg, engine, check_ok, instrument, batcher
 
@@ -228,6 +228,40 @@ def snapshot(engine, batcher) -> dict:
     }
 
 
+def traced_stretch(cell, engine, batcher, compiles, report, keep_sending,
+                   meanwhile=None) -> dict:
+    """A ``--trace 2`` run's second part, after its window has closed and its
+    numbers are taken: ``TRACED_SECONDS`` of the same traffic
+    (``keep_sending(until)``) under the program's capture control;
+    ``meanwhile()`` keeps the traffic up while the profiler's first start is
+    thrown away. -> the stretch, with what the program's ``serve_decode``
+    spans say each traced decode step read (its live cache rows plus the row
+    it wrote, as ``Instrument`` counts them from outside in a ``--trace 1``
+    run)."""
+    trace_dir = xplane.trace_dir(cell.root, cell.name)
+    with program_obs.Stretch(trace_dir, compiles, meanwhile) as stretch:
+        before = snapshot(engine, batcher)
+        sent = keep_sending(stretch.t0 + TRACED_SECONDS)
+        after = snapshot(engine, batcher)
+    steps = program_obs.span_args(stretch.capture, "serve_decode", stretch.t0, stretch.t1)
+    seconds = stretch.t1 - stretch.t0
+    counters = {
+        "traced_decode_steps": len(steps),
+        "traced_live_rows": sum(a["rows"] + a["slots"] for a in steps),
+        "traced_live_slots": sum(a["slots"] for a in steps),
+    }
+    report.line(
+        "traced", seconds=seconds, compiles_in_trace=stretch.compiles, **counters,
+        decode_step_ms=(after["decode_s"] - before["decode_s"])
+        / max(1, after["decode_steps"] - before["decode_steps"]) * 1e3,
+        decode_tokens_per_s=(after["new_tokens"] - before["new_tokens"]) / seconds,
+        span_seconds=program_obs.seconds_by_name(stretch.capture, "serve_"),
+        request_traces=len(stretch.capture.requests), spans=len(stretch.capture.spans),
+        spans_dropped=stretch.capture.dropped,
+    )
+    return {"stretch": stretch, "counters": counters, "sent": sent}
+
+
 def tails(reqs_due: list, report) -> dict:
     """Per-request first-token wait (from the due instant) and time per
     output token, failures as +inf; p95 by the rule, the median beside it."""
@@ -254,16 +288,19 @@ def tails(reqs_due: list, report) -> dict:
     return out
 
 
-def queue_waits_ms(reqs_due: list) -> list:
+def queue_waits_ms(reqs_due: list, traces=None) -> list:
     """Due instant to the slot, per traced request: the generator's lateness
-    plus the program's own ``queue`` span (``obs/reqtrace``)."""
+    plus the program's own ``queue`` span (``obs/reqtrace``). ``traces``: a
+    capture's completed request traces, else those the armed ring holds."""
     from opendiloco_tpu.obs import reqtrace
 
-    ring = reqtrace.ring()
-    if ring is None:
-        return []
+    if traces is None:
+        ring = reqtrace.ring()
+        if ring is None:
+            return []
+        traces = ring.traces()
     queue_ms = {}
-    for tr in ring.traces():
+    for tr in traces:
         for span in tr["spans"]:
             if span["stage"] == "queue":
                 queue_ms[tr["id"]] = span["ms"]
@@ -285,8 +322,10 @@ def mint_trace():
 
 
 def finish(*, cell, peak, engine, batcher, before, after, window_s, reqs_due, in_window,
-           check_ok, e2e, tail_facts, trace, tracer, instrument, extra_counters=None):
-    """The driver's return value, shared by the open and the closed loop."""
+           check_ok, e2e, tail_facts, trace, tracer, instrument, traced=None,
+           extra_counters=None):
+    """The driver's return value, shared by the open and the closed loop.
+    ``traced``: what ``traced_stretch`` returned, in a ``--trace 2`` run."""
     failed = sum(
         1 for _, r in reqs_due if r.error is not None or r.t_done is None
     )
@@ -302,7 +341,14 @@ def finish(*, cell, peak, engine, batcher, before, after, window_s, reqs_due, in
         **(extra_counters or {}),
     }
     observations = {"counters": counters}
-    if trace:
+    if traced is not None:
+        stretch = traced["stretch"]
+        observations["trace"] = stretch.reduce(rehearsal=peak is None)
+        counters.update(
+            traced["counters"],
+            queue_waits_ms=queue_waits_ms(traced["sent"], stretch.capture.requests),
+        )
+    elif trace:
         tracer.join(timeout=300.0)
         if tracer.error is not None:
             raise tracer.error
@@ -323,6 +369,8 @@ def finish(*, cell, peak, engine, batcher, before, after, window_s, reqs_due, in
         "attempted": len(reqs_due),
         "failed": failed,
         "compiles_in_window": in_window,
+        "compiles_in_trace": traced["stretch"].compiles if traced else 0,
+        "trace_cost": traced["stretch"].cost if traced else {},
         "end_to_end": e2e,
         "observations": observations,
     }

@@ -265,9 +265,11 @@ class LoopbackBackend(OuterBackend):
             my_round = slot["round"]
             slot["contrib"][self._peer_id] = compressed
             w.cond.notify_all()
+            t_reduce = None  # set by the one peer that computes the mean
             while slot["result_round"] < my_round:
                 if set(slot["contrib"]) >= w.live and slot["contrib"]:
                     # complete: first thread to notice publishes the mean
+                    t_reduce = time.perf_counter() if tr is not None else 0.0
                     contribs = list(slot["contrib"].values())
                     n = len(contribs)
                     slot["result"] = [
@@ -293,10 +295,19 @@ class LoopbackBackend(OuterBackend):
                     raise AllReduceError(f"{self._peer_id}: all-reduce timed out")
                 w.cond.wait(timeout=min(remaining, 0.1))
             if tr is not None:
+                # reduce_wait is waiting and nothing else: the publishing
+                # peer's own computation of the mean is outer/reduce
+                now = time.perf_counter()
                 tr.add_span(
-                    "outer/reduce_wait", t_wait, time.perf_counter(),
+                    "outer/reduce_wait", t_wait,
+                    now if t_reduce is None else t_reduce,
                     worker=self._peer_id, round=round_key,
                 )
+                if t_reduce is not None:
+                    tr.add_span(
+                        "outer/reduce", t_reduce, now,
+                        worker=self._peer_id, round=round_key, group=n,
+                    )
             t_adopt = time.perf_counter() if tr is not None else 0.0
             result = [a.copy() for a in slot["result"]]
             group = slot["result_group"]

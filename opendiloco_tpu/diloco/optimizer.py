@@ -66,6 +66,37 @@ class PeerDropError(RuntimeError):
     (reference: train_fsdp.py:452-457)."""
 
 
+class _BoundaryFetch(threading.Thread):
+    """The boundary's device-to-host fetch on a thread of its own, so that it
+    overlaps the straggler wait. It times itself: ``seconds`` is the fetch's
+    own interval (the row's ``outer_d2h_s``), and with an armed tracer the
+    ``outer/d2h`` span is recorded from this thread as the fetch ends."""
+
+    def __init__(self, tr, epoch: int, fetch):
+        super().__init__(name="outer-d2h")
+        self._tr, self._epoch, self._fetch = tr, epoch, fetch
+        self._result = self._error = None
+        self.seconds = 0.0
+
+    def run(self) -> None:
+        t0 = time.perf_counter()
+        try:
+            self._result = self._fetch()
+        except BaseException as e:  # re-raised by wait(), in the caller
+            self._error = e
+        t1 = time.perf_counter()
+        self.seconds = t1 - t0
+        if self._tr is not None:
+            self._tr.add_span("outer/d2h", t0, t1, epoch=self._epoch)
+
+    def wait(self):
+        """Join; -> what the fetch returned (its exception raised here)."""
+        self.join()
+        if self._error is not None:
+            raise self._error
+        return self._result
+
+
 def resolve_outer_placement(cfg: DilocoConfig, trainer, world) -> str:
     """Resolve ``outer_placement`` to 'host' or 'device'.
 
@@ -743,14 +774,10 @@ class DiLoCoOptimizer:
         # the blocking path): params are final at the boundary. Multihost:
         # the gather is a mesh collective issued by every process's fetcher
         # thread; the WAN launch below is messenger-only.
-        fetch_result: list = []
-
-        def _fetch():
-            fetch_result.append(
-                self.world.gather_params(jax.tree.leaves(state["params"]))
-            )
-
-        fetcher = threading.Thread(target=_fetch)
+        fetcher = _BoundaryFetch(
+            tr, self.epoch,
+            lambda: self.world.gather_params(jax.tree.leaves(state["params"])),
+        )
         fetcher.start()
         if self.world.is_messenger:
             wait_for_peers(
@@ -767,8 +794,7 @@ class DiLoCoOptimizer:
                 "outer/barrier_wait", t0p, time.perf_counter(),
                 epoch=self.epoch,
             )
-        fetcher.join()
-        boundary = fetch_result[0]
+        boundary = fetcher.wait()
         self._pg_slot ^= 1
         # the messenger puts the pseudo-gradient on the wire; in eager mode
         # every process also computes it (identical, from the replicated
@@ -833,6 +859,7 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
+            "outer_d2h_s": fetcher.seconds,
             "outer_wait_s": wait_s,
             "outer_overlapped": 1,
         }
@@ -873,18 +900,10 @@ class DiLoCoOptimizer:
         # ~1e3 ulps over a few rounds once inner AdamW amplifies it
         eager = self.cfg.overlap_comm == "eager"
         boundary_dev = plane.copy_leaves(device_leaves)
-        fetch_result: list = []
-
-        def _fetch():
-            fetch_result.append(
-                plane.pseudo_grad(
-                    device_leaves,
-                    with_norm=tr is not None,
-                    keep_device=eager,
-                )
-            )
-
-        fetcher = threading.Thread(target=_fetch)
+        fetcher = _BoundaryFetch(
+            tr, self.epoch,
+            lambda: plane.pseudo_grad(device_leaves, keep_device=eager),
+        )
         fetcher.start()
         wait_for_peers(
             self.backend,
@@ -900,9 +919,8 @@ class DiLoCoOptimizer:
                 "outer/barrier_wait", t0p, time.perf_counter(),
                 epoch=self.epoch,
             )
-        fetcher.join()
-        pg_host, pg_norm, pg_dev = fetch_result[0]
-        if tr is not None and pg_norm is not None:
+        pg_host, pg_norm, pg_dev = fetcher.wait()
+        if tr is not None:
             tr.gauge("pseudo_grad_norm", pg_norm)
         if self._ef is not None:
             # the plane's jit already added the residual (full-width D2H:
@@ -939,7 +957,9 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
+            "outer_d2h_s": fetcher.seconds,
             "outer_wait_s": wait_s,
+            "pseudo_grad_norm": pg_norm,
             "outer_overlapped": 1,
         }
         if tr is not None:
@@ -1101,7 +1121,7 @@ class DiLoCoOptimizer:
                 # delayed-mode followers, which never prepared)
                 self._ef.commit("main")
 
-            t_apply = time.perf_counter() if tr is not None else 0.0
+            t_apply = time.perf_counter()
             if "plane_pre" in pending:
                 # device placement: fused landing. plane.lock is held from
                 # the donating land op until the pending round is cleared —
@@ -1140,9 +1160,10 @@ class DiLoCoOptimizer:
                 with self._serve_lock:
                     self.outer_opt = opt
                     self.master = master
+            apply_s = time.perf_counter() - t_apply
             if tr is not None:
                 tr.add_span(
-                    "outer/apply", t_apply, time.perf_counter(),
+                    "outer/apply", t_apply, t_apply + apply_s,
                     epoch=pending["epoch"], group=group_size,
                 )
         except BaseException:
@@ -1160,6 +1181,7 @@ class DiLoCoOptimizer:
         # otherwise never see overlapped round size/latency)
         self._landed_metrics = {
             "outer_allreduce_s": landed_s,
+            "outer_apply_s": apply_s,
             "num_peers": group_size,
             **self._round_health_metrics(),
         }
@@ -1421,21 +1443,16 @@ class DiLoCoOptimizer:
         device_leaves = jax.tree.leaves(state["params"])
         if self._fragments is not None:
             frag = self._fragments[self.epoch % len(self._fragments)]
-        fetch_result: list = []
-
-        def _fetch():
-            # wire-width D2H of this boundary's fragment; the norm rides
-            # the same jit as one HBM reduction when the tracer is armed
-            fetch_result.append(
-                plane.pseudo_grad(
-                    device_leaves if frag is None
-                    else [device_leaves[i] for i in frag],
-                    frag,
-                    with_norm=tr is not None,
-                )
-            )
-
-        fetcher = threading.Thread(target=_fetch)
+        # wire-width D2H of this boundary's fragment; the norm rides the
+        # same jit as one HBM reduction, armed tracer or not
+        fetcher = _BoundaryFetch(
+            tr, self.epoch,
+            lambda: plane.pseudo_grad(
+                device_leaves if frag is None
+                else [device_leaves[i] for i in frag],
+                frag,
+            ),
+        )
         fetcher.start()
         if self.cfg.outer_mode != "gossip":
             # gossip skips the straggler wait: a pair round has no group
@@ -1455,18 +1472,17 @@ class DiLoCoOptimizer:
                 "outer/barrier_wait", t0p, time.perf_counter(),
                 epoch=self.epoch,
             )
-        fetcher.join()
+        pseudo_grad, pg_norm, _ = fetcher.wait()
+        d2h_s = fetcher.seconds
         if tr is not None:
-            tr.add_span("outer/d2h", t0p, time.perf_counter(), epoch=self.epoch)
-        pseudo_grad, pg_norm, _ = fetch_result[0]
-        if tr is not None and pg_norm is not None:
             tr.gauge("pseudo_grad_norm", pg_norm)
         if self.cfg.outer_mode == "gossip":
             # pair-mix on host (the wire encode is host-side anyway), then
             # land the mixed fragment back through the plane's donated jits
             return self._outer_step_device_gossip(
                 state, device_leaves, frag, pseudo_grad,
-                t0=t0, t0p=t0p, wait_s=wait_s,
+                t0=t0, t0p=t0p, wait_s=wait_s, d2h_s=d2h_s,
+                pg_norm=pg_norm,
             )
         if self._ef is not None:
             # residual already added in the plane's jit; stage the error
@@ -1495,7 +1511,7 @@ class DiLoCoOptimizer:
                 "outer/allreduce", t1p, time.perf_counter(),
                 epoch=self.epoch, group=group_size,
             )
-        t_apply = time.perf_counter() if tr is not None else 0.0
+        t_apply = time.perf_counter()
         log.info(
             "outer step %d: all-reduce over %d peers took %.3fs",
             self.epoch,
@@ -1548,8 +1564,11 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
+            "outer_d2h_s": d2h_s,
             "outer_allreduce_s": allreduce_s,
+            "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
+            "pseudo_grad_norm": pg_norm,
             "num_peers": group_size,
             **self._round_health_metrics(),
         }
@@ -1574,6 +1593,8 @@ class DiLoCoOptimizer:
         t0: float,
         t0p: float,
         wait_s: float,
+        d2h_s: float,
+        pg_norm: float,
     ) -> tuple[dict, dict]:
         """Gossip tail of the blocking device-placement round: the pair
         mix and NoLoCo step run on host f32 copies of this boundary's
@@ -1610,7 +1631,7 @@ class DiLoCoOptimizer:
                 "outer/allreduce", t1p, time.perf_counter(),
                 epoch=self.epoch, group=group_size,
             )
-        t_apply = time.perf_counter() if tr is not None else 0.0
+        t_apply = time.perf_counter()
         log.info(
             "outer step %d: gossip exchange over %d peers took %.3fs",
             self.epoch, group_size, allreduce_s,
@@ -1656,8 +1677,11 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
+            "outer_d2h_s": d2h_s,
             "outer_allreduce_s": allreduce_s,
+            "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
+            "pseudo_grad_norm": pg_norm,
             "num_peers": group_size,
             **self._round_health_metrics(),
         }
@@ -1713,20 +1737,16 @@ class DiLoCoOptimizer:
         device_leaves = jax.tree.leaves(state["params"])
         if self._fragments is not None:
             frag = self._fragments[self.epoch % len(self._fragments)]
-        fetch_result: list = []
-
-        def _fetch():
-            src = (
-                device_leaves
-                if frag is None
+        # multihost: a mesh all-gather — every process's fetcher thread
+        # issues the same collective, and each joins before the fan-out
+        # broadcast below, so the per-process collective order is fixed
+        fetcher = _BoundaryFetch(
+            tr, self.epoch,
+            lambda: self.world.gather_params(
+                device_leaves if frag is None
                 else [device_leaves[i] for i in frag]
-            )
-            # multihost: a mesh all-gather — every process's fetcher thread
-            # issues the same collective, and each joins before the fan-out
-            # broadcast below, so the per-process collective order is fixed
-            fetch_result.append(self.world.gather_params(src))
-
-        fetcher = threading.Thread(target=_fetch)
+            ),
+        )
         fetcher.start()
         if self.world.is_messenger and self.cfg.outer_mode != "gossip":
             # followers skip the straggler wait: they have no peer view,
@@ -1748,12 +1768,7 @@ class DiLoCoOptimizer:
                 "outer/barrier_wait", t0p, time.perf_counter(),
                 epoch=self.epoch,
             )
-        fetcher.join()
-        if tr is not None:
-            # D2H fetch runs concurrently with the straggler wait; the span
-            # covers wait+join, i.e. until the host copy is actually ready
-            tr.add_span("outer/d2h", t0p, time.perf_counter(), epoch=self.epoch)
-        device_flat = fetch_result[0]
+        device_flat = fetcher.wait()
 
         if frag is not None:
             # streaming sync: only this boundary's fragment forms a
@@ -1850,7 +1865,7 @@ class DiLoCoOptimizer:
                 "outer/allreduce", t1p, time.perf_counter(),
                 epoch=self.epoch, group=group_size,
             )
-        t_apply = time.perf_counter() if tr is not None else 0.0
+        t_apply = time.perf_counter()
         log.info(
             "outer step %d: %s over %d peers took %.3fs",
             self.epoch,
@@ -1929,7 +1944,9 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
+            "outer_d2h_s": fetcher.seconds,
             "outer_allreduce_s": allreduce_s,
+            "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
             "num_peers": group_size,
             **self._round_health_metrics(),

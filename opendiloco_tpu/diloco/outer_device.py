@@ -33,12 +33,14 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from opendiloco_tpu import obs
 from opendiloco_tpu.diloco.compression import device_wire_dtype
 
 
@@ -74,34 +76,37 @@ def _nesterov_step(masters, bufs, grads, lr, momentum, nesterov, has_mom):
 # small bounded set of executables that never recompiles across rounds.
 
 
-@functools.partial(jax.jit, static_argnames=("with_norm",))
-def _pg_f32(masters, params, with_norm):
+# The squared norm of the pseudo-gradient rides every one of these jits (one
+# reduction over an array the jit already holds, one scalar more to fetch),
+# whether a tracer is armed or not: arming the tracer in a running process
+# must not pick another program, or the first traced boundary compiles.
+
+
+@jax.jit
+def _pg_f32(masters, params):
     pg = [m - p for m, p in zip(masters, params)]
-    return pg, (_sqsum(pg) if with_norm else jnp.zeros((), jnp.float32))
+    return pg, _sqsum(pg)
 
 
-@functools.partial(jax.jit, static_argnames=("with_norm",))
-def _pg_f32_ef(masters, params, res, with_norm):
+@jax.jit
+def _pg_f32_ef(masters, params, res):
     """Error-feedback pseudo-gradient: the residual add is fused into the
     same dispatch (pg = master - params + residual). Error feedback forces
     full-width D2H (see __init__), so no wire-cast variant exists — the
     host must see the exact f32 values it will encode to measure the true
     roundtrip error."""
     pg = [m - p + r for m, p, r in zip(masters, params, res)]
-    return pg, (_sqsum(pg) if with_norm else jnp.zeros((), jnp.float32))
+    return pg, _sqsum(pg)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("wire_dtype", "with_norm", "keep32")
-)
-def _pg_wire(masters, params, wire_dtype, with_norm, keep32):
+@functools.partial(jax.jit, static_argnames=("wire_dtype", "keep32"))
+def _pg_wire(masters, params, wire_dtype, keep32):
     """Pseudo-gradient with the wire cast fused in: the D2H fetch of
     ``wire`` moves wire-width (half for f16) bytes. ``keep32`` retains the
     f32 pseudo-gradient on device for the overlap landing math."""
     pg = [m - p for m, p in zip(masters, params)]
     wire = [g.astype(wire_dtype) for g in pg]
-    sq = _sqsum(pg) if with_norm else jnp.zeros((), jnp.float32)
-    return (pg if keep32 else []), wire, sq
+    return (pg if keep32 else []), wire, _sqsum(pg)
 
 
 @functools.partial(
@@ -387,15 +392,18 @@ class DeviceOuterPlane:
         device_put already yields independent device memory and the
         pre-copy would just double the H2D cost — probed, not assumed."""
         sh = self._sel(self.shardings, frag)
-        if _device_put_copies():
-            return [
-                jax.device_put(np.asarray(a, dtype=np.float32), s)
-                for a, s in zip(host_leaves, sh)
-            ]
-        return [
-            jax.device_put(np.array(a, dtype=np.float32), s)
+        tr = obs.tracer()
+        t0 = time.perf_counter() if tr is not None else 0.0
+        own = np.asarray if _device_put_copies() else np.array
+        out = [
+            jax.device_put(own(a, dtype=np.float32), s)
             for a, s in zip(host_leaves, sh)
         ]
+        if tr is not None:
+            # the host's part of the transfer (staging and enqueue); the
+            # callers' apply/land spans hold this one and the jit's dispatch
+            tr.add_span("outer/h2d", t0, time.perf_counter(), leaves=len(out))
+        return out
 
     def _scalars(self):
         return np.float32(self.lr), np.float32(self.momentum)
@@ -411,30 +419,28 @@ class DeviceOuterPlane:
         param_leaves: Sequence[jax.Array],
         frag: Optional[list[int]] = None,
         *,
-        with_norm: bool = False,
         keep_device: bool = False,
-    ) -> tuple[list[np.ndarray], Optional[float], Optional[list[jax.Array]]]:
-        """(host f32 pseudo-gradient, ||pg|| or None, device f32 pg or None).
+    ) -> tuple[list[np.ndarray], float, Optional[list[jax.Array]]]:
+        """(host f32 pseudo-gradient, ||pg||, device f32 pg or None).
 
         The D2H fetch moves wire-width bytes when the codec has a device
         pre-cast (fp16); the host widens back to f32 for the backend. The
-        norm rides the same jit (one extra HBM reduction, only when the
-        tracer is armed) instead of a serial per-leaf host dot."""
+        norm rides the same jit in every run (one extra HBM reduction)
+        instead of a serial per-leaf host dot."""
         with self.lock:
             m = self._sel(self.masters, frag)
             p = list(param_leaves)
             if self._wire_dtype is not None:
                 pg32, wire, sq = _pg_wire(
-                    m, p, wire_dtype=self._wire_dtype,
-                    with_norm=with_norm, keep32=keep_device,
+                    m, p, wire_dtype=self._wire_dtype, keep32=keep_device,
                 )
             elif self.error_feedback:
                 self._ensure_ef()
                 r = self._sel(self.ef_res, frag)
-                pg32, sq = _pg_f32_ef(m, p, r, with_norm=with_norm)
+                pg32, sq = _pg_f32_ef(m, p, r)
                 wire = pg32
             else:
-                pg32, sq = _pg_f32(m, p, with_norm=with_norm)
+                pg32, sq = _pg_f32(m, p)
                 wire = pg32
             fetched = jax.device_get(wire)
         # the fetched views keep their device buffers alive, so no copy —
@@ -443,7 +449,7 @@ class DeviceOuterPlane:
         # all-reduce thread is still reading the host views
         aliased = keep_device and self._wire_dtype is None
         host = [(_own(x) if aliased else _host_f32(x)) for x in fetched]
-        norm = float(np.sqrt(float(sq))) if with_norm else None
+        norm = float(np.sqrt(float(sq)))
         return host, norm, (pg32 if keep_device else None)
 
     def apply_average(

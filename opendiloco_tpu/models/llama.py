@@ -308,26 +308,30 @@ def _decoder_block(
         rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     cos, sin = rope
 
-    x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-    q = (x @ layer["q_proj"]).reshape(B, T, Nh, Dh)
-    k = (x @ layer["k_proj"]).reshape(B, T, Nkv, Dh)
-    v = (x @ layer["v_proj"]).reshape(B, T, Nkv, Dh)
-    q = _rope_apply(q, cos, sin)
-    k = _rope_apply(k, cos, sin)
-    attn = attn_fn(q, k, v)
-    attn_out = attn.reshape(B, T, Nh * Dh) @ layer["o_proj"]
-    attn_norm = jnp.sqrt(jnp.sum(attn_out.astype(jnp.float32) ** 2))
-    h = h + attn_out
+    # the scopes name the device work in a profiler trace (an operation's
+    # op_name metadata); they change nothing that is computed
+    with jax.named_scope("odtp_attention"):
+        x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
+        q = (x @ layer["q_proj"]).reshape(B, T, Nh, Dh)
+        k = (x @ layer["k_proj"]).reshape(B, T, Nkv, Dh)
+        v = (x @ layer["v_proj"]).reshape(B, T, Nkv, Dh)
+        q = _rope_apply(q, cos, sin)
+        k = _rope_apply(k, cos, sin)
+        attn = attn_fn(q, k, v)
+        attn_out = attn.reshape(B, T, Nh * Dh) @ layer["o_proj"]
+        attn_norm = jnp.sqrt(jnp.sum(attn_out.astype(jnp.float32) ** 2))
+        h = h + attn_out
 
-    x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-    if cfg.num_experts:
-        ffn, aux = _switch_ffn(cfg, x, layer)
-    else:
-        ffn = (
-            jax.nn.silu(x @ layer["gate_proj"]) * (x @ layer["up_proj"])
-        ) @ layer["down_proj"]
-        aux = jnp.float32(0.0)
-    return h + ffn, (attn_norm, aux)
+    with jax.named_scope("odtp_mlp"):
+        x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
+        if cfg.num_experts:
+            ffn, aux = _switch_ffn(cfg, x, layer)
+        else:
+            ffn = (
+                jax.nn.silu(x @ layer["gate_proj"]) * (x @ layer["up_proj"])
+            ) @ layer["down_proj"]
+            aux = jnp.float32(0.0)
+        return h + ffn, (attn_norm, aux)
 
 
 def forward(
@@ -461,7 +465,8 @@ def forward(
         # composes with return_moe_aux so fused lm-head losses can thread
         # the router aux loss (trainer._loss_fn)
         return (h, head, moe_aux) if return_moe_aux else (h, head)
-    logits = (h @ head).astype(jnp.float32)
+    with jax.named_scope("odtp_lm_head_loss"):
+        logits = (h @ head).astype(jnp.float32)
     if return_aux:
         aux = {
             "attn_out_norm": attn_norms,

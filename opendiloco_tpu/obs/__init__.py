@@ -14,8 +14,10 @@ or, in plain synchronous code::
     with obs.span("outer/rendezvous", round=key):
         ...
 
-See ``obs/trace.py`` for the env knobs and ``obs/export.py`` for the
-Chrome-trace / Prometheus / JSONL exporters.
+See ``obs/trace.py`` for the env knobs, ``obs/export.py`` for the
+Chrome-trace / Prometheus / JSONL exporters, and ``obs/capture.py`` for the
+one switch that starts and stops profiler, tracer and request ring in a
+running process (``obs.capture.start(dir)`` .. ``obs.capture.stop()``).
 """
 from opendiloco_tpu.obs.trace import (  # noqa: F401
     StageTimes,
@@ -29,6 +31,7 @@ from opendiloco_tpu.obs.trace import (  # noqa: F401
 from opendiloco_tpu.obs import (  # noqa: F401
     anomaly,
     blackbox,
+    capture,
     export,
     mfu,
     overseer,
@@ -39,7 +42,9 @@ from opendiloco_tpu.obs import trace as _trace
 
 def reset() -> None:
     """Drop every cached obs singleton (tests / env changes): tracer,
-    flight recorder, request-trace ring, overseer, and watchdogs."""
+    flight recorder, request-trace ring, overseer, and watchdogs. An open
+    capture is abandoned (its profiler session, if any, is stopped)."""
+    capture.abandon()
     anomaly.reset()
     blackbox.reset()
     reqtrace.reset()
@@ -52,6 +57,7 @@ __all__ = [
     "Tracer",
     "anomaly",
     "blackbox",
+    "capture",
     "count",
     "enabled",
     "export",

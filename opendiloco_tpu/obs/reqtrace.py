@@ -41,6 +41,7 @@ from opendiloco_tpu.diloco.schema import (
     REQTRACE_STAGES,
     TRACE_CTX_KEY,
 )
+from opendiloco_tpu.obs import trace as _trace
 
 _ENV = "ODTP_OBS"
 _DIR_ENV = "ODTP_OBS_DIR"
@@ -393,10 +394,11 @@ _lock = threading.Lock()
 
 
 def ring() -> Optional[RequestTraceRing]:
-    """The process request-trace ring, or None when ODTP_OBS is unset
-    (zero-cost: one env lookup + cached string compare)."""
+    """The process request-trace ring, or None when ODTP_OBS is unset and
+    no capture is open (zero-cost: one env lookup + cached string
+    compare)."""
     global _ring, _spec
-    spec = os.environ.get(_ENV) or None
+    spec = os.environ.get(_ENV) or _trace._forced
     if spec == _spec:
         return _ring
     with _lock:

@@ -161,6 +161,23 @@ class Tracer:
             "args": attrs,
         })
 
+    def spans_since(self, first_event: int = 0) -> list:
+        """Completed spans recorded from event index ``first_event`` on, as
+        ``{"name", "t0", "t1", "tid", "args"}`` with ``perf_counter``
+        stamps (the clock ``add_span`` was given)."""
+        with self._lock:
+            events = self.events[first_event:]
+        return [
+            {
+                "name": ev["name"],
+                "t0": self.origin + ev["ts"] / 1e6,
+                "t1": self.origin + (ev["ts"] + ev["dur"]) / 1e6,
+                "tid": ev["tid"],
+                "args": ev["args"],
+            }
+            for ev in events if ev.get("ph") == "X"
+        ]
+
     def instant(self, name: str, **attrs: Any) -> None:
         self._record({
             "name": name,
@@ -320,12 +337,16 @@ def _flat_metrics(metrics: dict) -> dict:
 _tracer: Optional[Tracer] = None
 _spec: Optional[str] = None
 _lock = threading.Lock()
+# the spec ``obs.capture`` arms the plane with while a capture it started
+# is open in a process where ODTP_OBS is unset; the environment wins
+_forced: Optional[str] = None
 
 
 def tracer() -> Optional[Tracer]:
-    """The process tracer, or None when ODTP_OBS is unset (zero-cost)."""
+    """The process tracer, or None when ODTP_OBS is unset and no capture
+    is open (zero-cost)."""
     global _tracer, _spec
-    spec = os.environ.get(_ENV) or None
+    spec = os.environ.get(_ENV) or _forced
     if spec == _spec:
         return _tracer
     with _lock:

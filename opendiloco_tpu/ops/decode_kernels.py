@@ -240,6 +240,7 @@ def paged_decode_attention(
             scale=d**-0.5, block_t=bt, t=t, num_t=num_t,
             with_stats=return_stats,
         ),
+        name="odtp_paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -448,6 +449,7 @@ def spec_tail_attention_fused(
             scale=d**-0.5, q_start=int(q_start), block_t=bt, t=t,
             num_t=num_t, rep=rep, with_stats=return_stats,
         ),
+        name="odtp_spec_tail_attn",
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -569,6 +571,7 @@ def w4_matmul(
         functools.partial(
             _w4_kernel, num_k=num_k, n_sel=n_sel, n_half=n_half
         ),
+        name="odtp_w4_matmul",
         grid=(num_m, num_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ki: (mi, ki)),

@@ -131,6 +131,7 @@ def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool, vma=None):
     grid = (b, hq, t // block_q, num_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, num_k=num_k),
+        name="odtp_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(
@@ -315,6 +316,7 @@ def _bwd_impl(
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, num_k=num_k),
+        name="odtp_flash_dq",
         grid=(b, hq, num_q, num_k),
         in_specs=[
             pl.BlockSpec(
@@ -360,6 +362,7 @@ def _bwd_impl(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, rep=rep, num_q=num_q
         ),
+        name="odtp_flash_dkv",
         grid=(b, hkv, num_k, rep * num_q),
         in_specs=[
             pl.BlockSpec(

@@ -133,6 +133,7 @@ def _fwd(h, w, labels, block_n, block_v, true_v):
     grid = (n // block_n, v // block_v)
     nll, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_v=block_v, true_v=true_v),
+        name="odtp_fused_xent_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
@@ -248,6 +249,7 @@ def _bwd_impl(h, w, labels, lse, g, block_n, block_v, true_v):
     vec_spec_j = pl.BlockSpec((1, block_n), lambda j, i: (0, i))
     dh = pl.pallas_call(
         functools.partial(_dh_kernel, block_v=block_v, true_v=true_v),
+        name="odtp_fused_xent_dh",
         grid=(ni, nv),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
@@ -270,6 +272,7 @@ def _bwd_impl(h, w, labels, lse, g, block_n, block_v, true_v):
     )(*args)
     dw = pl.pallas_call(
         functools.partial(_dw_kernel, block_v=block_v, true_v=true_v),
+        name="odtp_fused_xent_dw",
         grid=(nv, ni),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),

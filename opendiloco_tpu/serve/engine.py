@@ -180,21 +180,27 @@ class ServeEngine:
         cd = compute_dtype
         dkn = self.decode_kernel
 
+        # one named scope per program: what a profiler trace calls the
+        # device work of a prefill and of a decode step
         def _prefill(p, ids, length):
-            logits, ks, vs = prefill_forward(
-                p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, ks, vs
+            with jax.named_scope("odtp_serve_prefill"):
+                logits, ks, vs = prefill_forward(
+                    p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn
+                )
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return tok, logits, ks, vs
 
         def _insert(ck, cv, ks, vs, slot):
             return cache_insert(ck, cv, ks, vs, slot)
 
         def _decode(p, tokens, lens, ck, cv):
-            logits, ck, cv = decode_forward(
-                p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
-                decode_kernel=dkn,
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, ck, cv
+            with jax.named_scope("odtp_serve_decode"):
+                logits, ck, cv = decode_forward(
+                    p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
+                    decode_kernel=dkn,
+                )
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return tok, logits, ck, cv
 
         # one compile per prompt bucket; insert/decode compile once
         self._prefill = jax.jit(_prefill)
@@ -464,8 +470,17 @@ class ServeEngine:
             self.cache_v,
         )
         tok = np.asarray(tok)
-        self.stage_seconds["decode"] += time.perf_counter() - t0
-        obs.count(f"serve_decode_kernel_{self.decode_kernel}")
+        t1 = time.perf_counter()
+        self.stage_seconds["decode"] += t1 - t0
+        tr = obs.tracer()
+        if tr is not None:
+            tr.count(f"serve_decode_kernel_{self.decode_kernel}")
+            # what the step's attention read: the cache rows of the live
+            # slots (``lens`` is 0 for an empty slot)
+            tr.add_span(
+                "serve_decode", t0, t1,
+                rows=int(np.sum(lens)), slots=int(np.count_nonzero(lens)),
+            )
         return tok, logits
 
     def _propose_draft(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:

@@ -148,6 +148,10 @@ class ContinuousBatcher:
         self._thread: Optional[threading.Thread] = None
         self._next_id = 0
         self.decode_steps = 0
+        # wall and count of the loop iterations that admitted or stepped
+        # (loop-thread only, always on, in the manner of stage_seconds)
+        self.loop_seconds = 0.0
+        self.loop_iterations = 0
         self._t_step_end: Optional[float] = None
         # stats (mutated only by the loop thread; read racily for gauges)
         self.completed = 0
@@ -351,7 +355,8 @@ class ContinuousBatcher:
                 # queue checks, a co-tenant's admission prefill, retires,
                 # gauges — is attributed to its decode residency and a
                 # trace's stage sums reconcile with its e2e latency
-                it0 = t_carry if t_carry is not None else time.perf_counter()
+                t_iter = time.perf_counter()
+                it0 = t_carry if t_carry is not None else t_iter
                 self._sweep_cancelled()
                 # page-outs started LAST iteration finalize here: their
                 # D2H copies overlapped the decode step in between, so
@@ -366,6 +371,19 @@ class ContinuousBatcher:
                     if self.decode_steps % self.gauge_every_steps == 0:
                         self._publish_gauges()
                 t_carry = self._t_step_end if stepped else None
+                if admitted or stepped:
+                    # the wall of every iteration that did work, beside the
+                    # engine's stage seconds: their difference is what the
+                    # loop itself costs (sweeps, admission, emit, retires)
+                    t_end = time.perf_counter()
+                    self.loop_seconds += t_end - t_iter
+                    self.loop_iterations += 1
+                    tr = obs.tracer()
+                    if tr is not None:
+                        tr.add_span(
+                            "serve_iteration", t_iter, t_end,
+                            admitted=bool(admitted), stepped=bool(stepped),
+                        )
                 if not admitted and not stepped:
                     # idle: still honor the staleness bound, then sleep
                     self._maybe_swap()
@@ -1008,6 +1026,8 @@ class ContinuousBatcher:
             "queued": len(self._queue),
             "active": self.slots.num_active,
             "decode_steps": self.decode_steps,
+            "loop_iterations": self.loop_iterations,
+            "loop_seconds": round(self.loop_seconds, 6),
             "new_tokens": self.total_new_tokens,
             "latency_ms": {
                 "p50": pct(lat, 50),

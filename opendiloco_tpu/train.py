@@ -84,6 +84,18 @@ def make_trainer_config(config: Config) -> TrainerConfig:
     )
 
 
+def _end_profile(profile_dir: str) -> None:
+    """Close the ``--profile-dir`` capture and lay the program's spans
+    (with the anchor that places them on the profiler's clock) beside the
+    trace it wrote."""
+    capture = obs.capture.stop()
+    path = capture.save(os.path.join(profile_dir, "odtp_capture.json"))
+    log.info(
+        "wrote profiler trace to %s (%d program spans in %s)",
+        profile_dir, len(capture.spans), path,
+    )
+
+
 def train(
     config: Config,
     backend: Optional[OuterBackend] = None,
@@ -379,7 +391,8 @@ def train(
             row["outer_epoch"] = diloco_opt.epoch
             # round-health fields ride along so the chaos soak can read
             # elastic rescale and aggregator re-election from the rows
-            for k in ("outer_step_s", "outer_allreduce_s", "outer_wait_s",
+            for k in ("outer_step_s", "outer_d2h_s", "outer_allreduce_s",
+                      "outer_apply_s", "outer_wait_s", "pseudo_grad_norm",
                       "elastic", "expected_peers", "round_retries",
                       "hier_plan", "hier_aggregators"):
                 if k in metrics:
@@ -399,12 +412,14 @@ def train(
     try:
         for step in range(start_step, config.total_steps):
             if config.profile_dir and step == start_step + config.profile_start:
-                jax.profiler.start_trace(config.profile_dir)
+                # profiler, span tracer and request ring start together;
+                # the program's spans land on the profiler's clock through
+                # the capture's anchor annotation
+                obs.capture.start(config.profile_dir)
                 profiling = True
             if profiling and step == start_step + config.profile_start + config.profile_steps:
-                jax.profiler.stop_trace()
+                _end_profile(config.profile_dir)
                 profiling = False
-                log.info("wrote profiler trace to %s", config.profile_dir)
             t0 = time.perf_counter()
             if prefetcher is not None:
                 host_batch, batch = next(prefetcher)
@@ -505,8 +520,7 @@ def train(
             # never let a trace-serialization failure mask the real error or
             # skip the remaining cleanup
             try:
-                jax.profiler.stop_trace()
-                log.info("wrote profiler trace to %s", config.profile_dir)
+                _end_profile(config.profile_dir)
             except Exception:
                 log.exception("failed to flush profiler trace")
         if prefetcher is not None:

@@ -467,18 +467,22 @@ class InnerTrainer:
                 return_moe_aux=moe,
                 **fwd_kwargs,
             )
-            if moe:
-                hidden, head, moe_aux = out
-                return self._fused_lm_loss(hidden, head, labels) + aux(moe_aux)
-            hidden, head = out
-            return self._fused_lm_loss(hidden, head, labels)
+            # same scope name as forward() gives its logits matmul: the
+            # lm head and the loss read as one piece of a traced step
+            with jax.named_scope("odtp_lm_head_loss"):
+                if moe:
+                    hidden, head, moe_aux = out
+                    return self._fused_lm_loss(hidden, head, labels) + aux(moe_aux)
+                hidden, head = out
+                return self._fused_lm_loss(hidden, head, labels)
         out = forward(
             params, input_ids, self.model_cfg, return_moe_aux=moe, **fwd_kwargs
         )
-        if moe:
-            logits, moe_aux = out
-            return causal_lm_loss(logits, labels) + aux(moe_aux)
-        return causal_lm_loss(out, labels)
+        with jax.named_scope("odtp_lm_head_loss"):
+            if moe:
+                logits, moe_aux = out
+                return causal_lm_loss(logits, labels) + aux(moe_aux)
+            return causal_lm_loss(out, labels)
 
     def _train_step_impl(self, state: dict, batch: dict):
         """batch arrays are [accum, global_microbatch, seq]."""
@@ -505,11 +509,12 @@ class InnerTrainer:
         grads = jax.tree.map(lambda g: g * inv, grad_sum)
         loss = loss_sum * inv
 
-        grad_norm = optax.global_norm(grads)
-        updates, opt_state = self.optimizer.update(
-            grads, state["opt_state"], params
-        )
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("odtp_optimizer_update"):
+            grad_norm = optax.global_norm(grads)
+            updates, opt_state = self.optimizer.update(
+                grads, state["opt_state"], params
+            )
+            new_params = optax.apply_updates(params, updates)
 
         if self.tc.use_loss_scaling:
             # GradScaler semantics (found_inf_grad, utils.py:124-135): on

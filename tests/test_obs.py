@@ -358,3 +358,269 @@ def test_dummy_logger_finish_is_atomic(tmp_path, monkeypatch):
 def test_unknown_logger_type_rejected():
     with pytest.raises(ValueError):
         get_logger("nosuch", "/tmp/x", config={})
+
+
+# -- the capture control (obs/capture.py) ---------------------------------------
+
+
+def _named(cap, name):
+    return [s for s in cap.spans if s["name"] == name]
+
+
+def test_capture_records_between_start_and_stop_and_nothing_after():
+    assert obs.tracer() is None
+    with obs.span("before"):
+        pass
+    obs.capture.start()
+    assert obs.tracer() is not None
+    with obs.span("inside", k=3):
+        pass
+    obs.count("things", 2, kind="a")
+    cap = obs.capture.stop()
+    assert obs.tracer() is None and obs.reqtrace.ring() is None
+    with obs.span("after"):
+        pass
+    assert [s["name"] for s in cap.spans] == ["inside"]
+    (span,) = _named(cap, "inside")
+    assert span["args"]["k"] == 3
+    assert cap.anchor_pc <= span["t0"] <= span["t1"] <= cap.t_stop
+    assert cap.counters == {"things{kind=a}": 2.0}
+    with pytest.raises(RuntimeError):
+        obs.capture.stop()
+
+
+def test_capture_refuses_a_second_start():
+    obs.capture.start()
+    try:
+        with pytest.raises(RuntimeError):
+            obs.capture.start()
+    finally:
+        obs.capture.stop()
+
+
+def test_capture_honours_the_ring_cap():
+    obs.capture.start(ring_cap=3)
+    ring = obs.reqtrace.ring()
+    assert ring.cap == 3
+    for _ in range(5):
+        ctx = ring.mint()
+        ring.span(ctx["id"], "queue", 0.0, 0.001)
+        ring.finish(ctx["id"])
+    cap = obs.capture.stop()
+    assert len(cap.requests) == 3 and ring.evicted == 2
+    assert all(t["spans"][0]["stage"] == "queue" for t in cap.requests)
+
+
+def test_capture_uses_the_tracer_odtp_obs_armed_and_leaves_it_armed(monkeypatch):
+    tr = _arm(monkeypatch, ODTP_REQTRACE_CAP=7)
+    ring = obs.reqtrace.ring()
+    with obs.span("operator"):
+        pass
+    obs.capture.start(ring_cap=50)
+    assert obs.tracer() is tr and obs.reqtrace.ring() is ring and ring.cap == 50
+    with obs.span("captured"):
+        pass
+    cap = obs.capture.stop()
+    assert [s["name"] for s in cap.spans] == ["captured"]
+    # the operator's plane is as it was: same tracer, still armed, its cap back
+    assert obs.tracer() is tr and obs.reqtrace.ring() is ring and ring.cap == 7
+    assert [e["name"] for e in tr.events] == ["operator", "captured"]
+
+
+def test_capture_profile_holds_the_anchor_annotation(tmp_path):
+    import glob
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    obs.capture.start(str(tmp_path))
+    with obs.span("work"):
+        jnp.arange(8).sum().block_until_ready()
+    cap = obs.capture.stop()
+    (pb,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    anchors = [
+        ev for plane in ProfileData.from_file(pb).planes for line in plane.lines
+        for ev in line.events if ev.name == obs.capture.ANCHOR
+    ]
+    assert len(anchors) == 1
+    assert float(dict(anchors[0].stats)["pc"]) == cap.anchor_pc
+    # one subtraction places a span on the profiler's clock
+    (span,) = _named(cap, "work")
+    on_trace_ns = anchors[0].start_ns + (span["t0"] - cap.anchor_pc) * 1e9
+    assert on_trace_ns > anchors[0].start_ns
+    saved = json.load(open(cap.save(str(tmp_path / "odtp_capture.json"))))
+    assert saved["anchor_pc"] == cap.anchor_pc and saved["spans"][0]["name"] == "work"
+
+
+def _diloco_worker(tiny_cfg, placement, local_steps=2):
+    import jax
+
+    from opendiloco_tpu.config import DilocoConfig
+    from opendiloco_tpu.diloco import DiLoCoOptimizer
+    from opendiloco_tpu.parallel.mesh import build_mesh
+    from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
+
+    tc = TrainerConfig(lr=1e-3, warmup_steps=2, total_steps=200, precision="fp32",
+                       remat=False)
+    trainer = InnerTrainer(tiny_cfg, tc, build_mesh("NO_SHARD", devices=jax.devices()[:1]))
+    state = trainer.init_state(jax.random.key(0))
+    (backend,) = LoopbackWorld(1).make_backends()
+    opt = DiLoCoOptimizer(
+        trainer, backend,
+        DilocoConfig(local_steps=local_steps, backend="loopback",
+                     outer_placement=placement, skip_load_from_peers=True),
+        state, 8,
+    )
+    rng = np.random.default_rng(0)
+
+    def one_round(state):
+        """``local_steps`` steps, the last over the boundary -> (state, row)"""
+        for _ in range(local_steps):
+            ids = ((rng.integers(0, tiny_cfg.vocab_size, (8, 1)) + np.arange(16))
+                   % tiny_cfg.vocab_size).astype(np.int32)
+            state, m = opt.step(state, trainer.shard_batch(ids, ids.copy(), accum=1))
+        jax.block_until_ready(state["params"])
+        return state, m
+
+    return state, one_round
+
+
+@pytest.mark.parametrize("placement", ["device", "host"])
+def test_arming_the_capture_compiles_nothing(tiny_cfg, placement):
+    """One boundary with the capture off, ``start``, one boundary with it on:
+    not one program goes to the compiler in between (the pseudo-gradient's
+    norm no longer follows the tracer into a static argument)."""
+    import jax
+
+    compiles = []
+
+    def on_duration(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    state, one_round = _diloco_worker(tiny_cfg, placement)
+    state, row_off = one_round(state)
+    state, _ = one_round(state)  # the momentum buffers' first armed step
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        compiles.clear()
+        obs.capture.start()
+        state, row_on = one_round(state)
+        cap = obs.capture.stop()
+        between = len(compiles)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert between == 0
+    assert {"outer/d2h", "outer/allreduce", "outer/apply", "outer/step"} <= {
+        s["name"] for s in cap.spans}
+    if placement == "device":
+        # carried in every run now, traced or not
+        assert row_off["pseudo_grad_norm"] > 0 and row_on["pseudo_grad_norm"] > 0
+        assert _named(cap, "outer/h2d")
+
+
+@pytest.mark.parametrize("placement", ["device", "host"])
+def test_boundary_row_splits_the_step(tiny_cfg, placement):
+    state, one_round = _diloco_worker(tiny_cfg, placement)
+    obs.capture.start()
+    _, row = one_round(state)
+    cap = obs.capture.stop()
+    parts = row["outer_d2h_s"] + row["outer_allreduce_s"] + row["outer_apply_s"]
+    assert min(row["outer_d2h_s"], row["outer_apply_s"]) > 0
+    assert parts <= row["outer_step_s"]
+    # the d2h span is the fetch's own interval, recorded in the fetch thread
+    (d2h,) = _named(cap, "outer/d2h")
+    (step,) = _named(cap, "outer/step")
+    assert d2h["t1"] - d2h["t0"] == pytest.approx(row["outer_d2h_s"], abs=1e-9)
+    assert d2h["tid"] != step["tid"]
+    assert step["t0"] <= d2h["t0"] and d2h["t1"] <= step["t1"]
+    if placement == "device":
+        # the H2D of the average is cut out of the apply that holds it
+        (h2d,) = _named(cap, "outer/h2d")
+        (apply_,) = _named(cap, "outer/apply")
+        assert apply_["t0"] <= h2d["t0"] and h2d["t1"] <= apply_["t1"]
+
+
+def test_reduce_wait_is_waiting_and_reduce_is_the_mean():
+    world = LoopbackWorld(3)
+    backends = world.make_backends()
+    data = [np.ones((64,), np.float32)]
+    obs.capture.start()
+    threads = [
+        threading.Thread(
+            target=lambda b=b: b.all_reduce(data, timeout=30.0, tag="g", epoch=0))
+        for b in backends
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30.0)
+    cap = obs.capture.stop()
+    (reduce_,) = _named(cap, "outer/reduce")  # one peer publishes the mean
+    assert reduce_["args"]["group"] == 3
+    waits = _named(cap, "outer/reduce_wait")
+    assert len(waits) == 3
+    (own,) = [w for w in waits if w["args"]["worker"] == reduce_["args"]["worker"]]
+    assert own["t1"] == reduce_["t0"]  # the publisher's wait ends where its sum starts
+    for w in waits:  # and no wait is charged for the computation
+        if w is not own:
+            assert w["t1"] >= reduce_["t1"] or w["t0"] >= reduce_["t0"]
+
+
+def _tiny_engine(tiny_cfg, **kw):
+    import jax
+    import jax.numpy as jnp
+
+    from opendiloco_tpu.models.llama import init_params
+    from opendiloco_tpu.serve import ServeEngine
+
+    return ServeEngine(
+        tiny_cfg, init_params(jax.random.key(1), tiny_cfg), num_slots=4, max_context=64,
+        prefill_buckets=(8, 16), compute_dtype=jnp.float32, **kw,
+    )
+
+
+def test_serve_decode_span_carries_the_live_rows(tiny_cfg):
+    engine = _tiny_engine(tiny_cfg)
+    tokens, lens = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    for slot, prompt in ((0, [5, 6, 7]), (2, [9, 8, 7, 6, 5])):
+        tokens[slot], _ = engine.admit(slot, prompt)
+        lens[slot] = len(prompt)
+    engine.decode_step(tokens, lens)  # untraced: no span, same program
+    obs.capture.start()
+    engine.decode_step(tokens, lens + (lens > 0))
+    cap = obs.capture.stop()
+    (span,) = _named(cap, "serve_decode")
+    assert span["args"] == {"rows": 4 + 6, "slots": 2}
+    assert not _named(cap, "serve_prefill")  # admitted before the capture
+
+
+def test_loop_seconds_hold_the_stage_seconds(tiny_cfg):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine = _tiny_engine(tiny_cfg)
+    batcher = ContinuousBatcher(engine).start()
+    try:
+        for r in [batcher.submit([3, 4, 5], max_new_tokens=4)]:
+            assert r.wait(120) and r.error is None  # compiles fall in here
+        loop0, stage0 = batcher.loop_seconds, sum(engine.stage_seconds.values())
+        its0, steps0 = batcher.loop_iterations, batcher.decode_steps
+        obs.capture.start()
+        reqs = [batcher.submit([7, 8, 9, i + 1], max_new_tokens=6) for i in range(6)]
+        for r in reqs:
+            assert r.wait(120) and r.error is None
+    finally:
+        batcher.stop()  # joins the loop: its last iteration is counted whole
+        cap = obs.capture.stop()
+    loop_s = batcher.loop_seconds - loop0
+    stage_s = sum(engine.stage_seconds.values()) - stage0
+    steps = batcher.decode_steps - steps0
+    assert steps > 0 and batcher.loop_iterations - its0 >= steps
+    assert loop_s >= stage_s > 0
+    assert batcher.stats()["loop_iterations"] == batcher.loop_iterations
+    # one span per working iteration, and each decode step inside one of them
+    iterations = _named(cap, "serve_iteration")
+    assert iterations and all(s["args"]["admitted"] or s["args"]["stepped"]
+                              for s in iterations)
+    for d in _named(cap, "serve_decode"):
+        assert any(i["t0"] <= d["t0"] and d["t1"] <= i["t1"] for i in iterations)

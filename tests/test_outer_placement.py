@@ -351,7 +351,7 @@ def test_plane_pseudo_grad_and_norm(tiny_cfg):
     plane, leaves = _make_plane(tiny_cfg)
     # perturb the params so the pseudo-gradient is non-zero
     moved = [x - 1e-3 for x in leaves]
-    pg, norm, _ = plane.pseudo_grad(moved, with_norm=True)
+    pg, norm, _ = plane.pseudo_grad(moved)
     ref = [
         np.asarray(m, np.float32) - np.asarray(p, np.float32)
         for m, p in zip(jax.device_get(plane.masters), jax.device_get(moved))

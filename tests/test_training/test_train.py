@@ -265,7 +265,15 @@ def test_profile_dir_writes_trace(tmp_path):
     )
     assert r.returncode == 0, r.stderr[-2000:]
     files = list(prof.rglob("*"))
-    assert any(f.is_file() for f in files), "no trace files written"
+    assert any(f.name.endswith(".xplane.pb") for f in files), "no trace written"
+    # the window goes through obs.capture: the program's spans of those
+    # steps lie beside the trace, with the anchor that places them on it
+    import json
+
+    capture = json.loads((prof / "odtp_capture.json").read_text())
+    assert capture["anchor_pc"] > 0
+    dispatches = [s for s in capture["spans"] if s["name"] == "inner/dispatch"]
+    assert len(dispatches) == 3
 
 
 @pytest.mark.slow
