@@ -10,10 +10,35 @@ Elastic semantics match the production backend: a round completes when every
 *live* peer has contributed; a peer that closes (drops) no longer blocks the
 group, and the returned group size is the number of actual contributions --
 so peer-drop detection (optimizer.py) is exercisable in tests.
+
+It is also what workers that share a host run in production: a TPU host is
+one process that owns all its chips, so its workers (or its one worker) form
+their galaxy over a ``LoopbackWorld``. A round's data path therefore touches
+each byte as few times as a mean needs (``_mean``): n contributions are read
+once each and the result is written once -- the first contribution is copied
+into the one output, the others are added in arrival order, the sum is
+divided in place. The bits are those of ``np.sum(contribs, axis=0) / n``.
+
+Who owns what:
+
+- A caller's input arrays are read, never written, and never part of a
+  result. With the identity codec they are contributed as they are (no round
+  trip through bytes: nothing is lost on that wire, so nothing is modelled).
+- A lossy codec's decode output is private to the round, so it may become
+  the result: the accumulator of n > 1 contributions, or, for one
+  contribution, the result itself with no pass at all.
+- A published result is immutable. Every collector but the last copies it,
+  outside the world's lock; the last one of a generation takes the arrays
+  themselves. What ``all_reduce`` returns is the caller's alone, to keep and
+  to write into, for as long as it keeps it.
+- The world keeps the output arrays it hands out (``_OutputPool``: new
+  pages are what a pass costs on a TPU host) and writes into one again only
+  once nothing else refers to it, so the line above holds unchanged.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Any, Callable, Optional
@@ -58,6 +83,10 @@ class LoopbackWorld:
         # its "result"), so two claimers can never grab the same partner
         self._offers: dict[int, dict[str, dict]] = {}
         self._async_seq = 0  # match-key nonce (repeat matches never collide)
+        # the rounds' output arrays, kept across rounds (under self.lock):
+        # a round hands out n per position, and a consumer may still hold
+        # the last round's while the next is written
+        self._outputs = _OutputPool(keep=2 * max(1, n_peers))
 
     def make_backends(self) -> list["LoopbackBackend"]:
         return [LoopbackBackend(self, f"peer-{i}") for i in range(self.n_peers)]
@@ -217,7 +246,16 @@ class LoopbackBackend(OuterBackend):
         peer has contributed; dropped peers stop blocking the group the
         moment they close(). Lossy codecs are applied to each contribution
         to model wire compression faithfully. ``group_cap`` partitions the
-        live peers into deterministic per-round groups (gossip mode)."""
+        live peers into deterministic per-round groups (gossip mode).
+
+        ``arrays`` are read and never written, and no result aliases them.
+        The returned arrays are float32 and the caller's own: it may keep
+        them across later rounds and write into them (the world writes into
+        one again only after the caller has let go of it). A round of n
+        contributions costs n reads and one write for the mean (``_mean``),
+        and one copy for each collector but the last, made outside the
+        world's lock; one peer with the identity codec pays one copy in all,
+        one peer with a lossy codec none."""
         self._chaos_gate()
         # TcpBackend key parity: epoch=None resolves to this peer's own
         # reported epoch (default 0). Rounds are KEYED now — a raw None in
@@ -228,21 +266,15 @@ class LoopbackBackend(OuterBackend):
             with self.world.lock:
                 own = self.world.progress.get(self._peer_id)
             epoch = own.epoch if own else 0
-        if group_cap:
-            out, n = self._group_reduce(arrays, tag, epoch, group_cap, timeout)
-            self._record_round_health(tag, epoch, n)
-            return out, n
         w = self.world
-        codec = w.codec
-        # per-worker stage spans mirror the TCP stage names: encode (codec
-        # roundtrip), reduce_wait (park until the round mean publishes),
-        # adopt (copy the published result)
+        # per-worker stage spans mirror the TCP stage names: encode (the
+        # codec's round trip; the identity codec has none), reduce_wait
+        # (park until the round mean publishes), reduce (the publishing
+        # peer's computation of the mean), adopt (copy the published result)
         tr = obs.tracer()
         round_key = f"{tag}-epoch-{epoch}"
         t0 = time.perf_counter() if tr is not None else 0.0
-        compressed = [
-            codec.decode(*_enc(codec, a)) for a in arrays
-        ]  # simulate wire roundtrip
+        mine = _contribution(w.codec, arrays)
         if tr is not None:
             tr.add_span(
                 "outer/encode", t0, time.perf_counter(),
@@ -251,138 +283,176 @@ class LoopbackBackend(OuterBackend):
         deadline = time.monotonic() + (timeout or 3600.0)
         t_wait = time.perf_counter() if tr is not None else 0.0
         with w.cond:
-            slot = w._rounds.setdefault(
-                round_key,
-                {
-                    "round": 0,
-                    "contrib": {},
-                    "result": None,
-                    "result_group": 0,
-                    "result_round": -1,
-                    "pending": set(),
-                },
-            )
-            my_round = slot["round"]
-            slot["contrib"][self._peer_id] = compressed
-            w.cond.notify_all()
-            t_reduce = None  # set by the one peer that computes the mean
-            while slot["result_round"] < my_round:
-                if set(slot["contrib"]) >= w.live and slot["contrib"]:
-                    # complete: first thread to notice publishes the mean
-                    t_reduce = time.perf_counter() if tr is not None else 0.0
-                    contribs = list(slot["contrib"].values())
-                    n = len(contribs)
-                    slot["result"] = [
-                        np.sum([c[i] for c in contribs], axis=0) / n
-                        for i in range(len(arrays))
-                    ]
-                    slot["result_group"] = n
-                    slot["result_round"] = my_round
-                    slot["round"] += 1
-                    # collectors of this generation (slot GC: the key's
-                    # state is dropped once every contributor -- or its
-                    # survivor set, if some died -- has copied the result)
-                    slot["pending"] = set(slot["contrib"])
-                    slot["contrib"] = {}
-                    w.cond.notify_all()
-                    break
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    # give up: retract our contribution so a later round
-                    # doesn't count a stale tensor from a dead peer
-                    slot["contrib"].pop(self._peer_id, None)
-                    w.cond.notify_all()
-                    raise AllReduceError(f"{self._peer_id}: all-reduce timed out")
-                w.cond.wait(timeout=min(remaining, 0.1))
-            if tr is not None:
-                # reduce_wait is waiting and nothing else: the publishing
-                # peer's own computation of the mean is outer/reduce
-                now = time.perf_counter()
-                tr.add_span(
-                    "outer/reduce_wait", t_wait,
-                    now if t_reduce is None else t_reduce,
-                    worker=self._peer_id, round=round_key,
+            if group_cap:
+                pub, last = self._group_round(
+                    mine, round_key, group_cap, deadline
                 )
-                if t_reduce is not None:
-                    tr.add_span(
-                        "outer/reduce", t_reduce, now,
-                        worker=self._peer_id, round=round_key, group=n,
-                    )
+            else:
+                pub, last = self._world_round(mine, round_key, deadline, t_wait)
+            # the result is immutable once published: take the reference
+            # here and copy after the lock is released, so that n peers copy
+            # side by side and other tags' rounds are not held up behind a
+            # gigabyte memcpy. The generation's last collector takes the
+            # arrays themselves, once no copy of them is still in flight.
             t_adopt = time.perf_counter() if tr is not None else 0.0
-            result = [a.copy() for a in slot["result"]]
-            group = slot["result_group"]
-            # GC: keys repeat across epochs (and tags multiply with
-            # streaming fragments) -- drop the slot once every live
-            # contributor has collected and no next generation has begun
-            slot["pending"] = {
-                p for p in slot["pending"]
-                if p != self._peer_id and p in w.live
-            }
-            if not slot["pending"] and not slot["contrib"]:
-                w._rounds.pop(round_key, None)
+            if last:
+                while pub.readers:
+                    w.cond.wait(timeout=0.1)
+                result = pub.arrays
+            else:
+                pub.readers += 1
+                result = [w._outputs.take(i, a) for i, a in enumerate(pub.arrays)]
+        if not last:
+            try:
+                for dst, src in zip(result, pub.arrays):
+                    np.copyto(dst, src)
+            finally:
+                with w.cond:
+                    pub.readers -= 1
+                    w.cond.notify_all()
         if tr is not None:
             tr.add_span(
                 "outer/adopt", t_adopt, time.perf_counter(),
+                worker=self._peer_id, round=round_key, copied=not last,
+            )
+        self._record_round_health(tag, epoch, pub.group)
+        return result, pub.group
+
+    def _publish(self, contribs, round_key) -> "_Published":
+        """Under world.lock: the mean of ``contribs`` (in the order given),
+        with the ``outer/reduce`` span of the peer that computes it."""
+        w = self.world
+        tr = obs.tracer()
+        t0 = time.perf_counter() if tr is not None else 0.0
+        arrays, path, nbytes = _mean(
+            contribs, not _is_identity(w.codec), w._outputs.take
+        )
+        if tr is not None:
+            tr.add_span(
+                "outer/reduce", t0, time.perf_counter(),
+                worker=self._peer_id, round=round_key, group=len(contribs),
+                path=path, bytes=nbytes,
+            )
+        return _Published(arrays, len(contribs), t0)
+
+    def _world_round(self, mine, round_key, deadline, t_wait):
+        """Under world.lock: contribute to the round of every live peer and
+        wait for its mean. -> (published result, whether this peer is the
+        last of its generation to collect it)."""
+        w = self.world
+        tr = obs.tracer()
+        slot = w._rounds.setdefault(
+            round_key,
+            {
+                "round": 0,
+                "contrib": {},
+                "result": None,
+                "result_round": -1,
+                "pending": set(),
+            },
+        )
+        my_round = slot["round"]
+        slot["contrib"][self._peer_id] = mine
+        w.cond.notify_all()
+        t_reduce = None  # set by the one peer that computes the mean
+        while slot["result_round"] < my_round:
+            if set(slot["contrib"]) >= w.live and slot["contrib"]:
+                # complete: first thread to notice publishes the mean, the
+                # contributions taken in arrival order
+                slot["result"] = self._publish(
+                    list(slot["contrib"].values()), round_key
+                )
+                t_reduce = slot["result"].t_reduce
+                slot["result_round"] = my_round
+                slot["round"] += 1
+                # collectors of this generation (slot GC: the key's
+                # state is dropped once every contributor -- or its
+                # survivor set, if some died -- has collected the result)
+                slot["pending"] = set(slot["contrib"])
+                slot["contrib"] = {}
+                w.cond.notify_all()
+                break
+            remaining = deadline - time.monotonic()
+            if remaining <= 0:
+                # give up: retract our contribution so a later round
+                # doesn't count a stale tensor from a dead peer
+                slot["contrib"].pop(self._peer_id, None)
+                w.cond.notify_all()
+                raise AllReduceError(f"{self._peer_id}: all-reduce timed out")
+            w.cond.wait(timeout=min(remaining, 0.1))
+        if tr is not None:
+            # reduce_wait is waiting and nothing else: the publishing
+            # peer's own computation of the mean is outer/reduce
+            tr.add_span(
+                "outer/reduce_wait", t_wait,
+                time.perf_counter() if t_reduce is None else t_reduce,
                 worker=self._peer_id, round=round_key,
             )
-        self._record_round_health(tag, epoch, group)
-        return result, group
+        pub = slot["result"]
+        # GC: keys repeat across epochs (and tags multiply with
+        # streaming fragments) -- drop the slot once every live
+        # contributor has collected and no next generation has begun
+        slot["pending"] = {
+            p for p in slot["pending"]
+            if p != self._peer_id and p in w.live
+        }
+        last = not slot["pending"]
+        if last and not slot["contrib"]:
+            w._rounds.pop(round_key, None)
+        return pub, last
 
-    def _group_reduce(self, arrays, tag, epoch, cap, timeout):
-        """Partition live peers into per-round groups of <= cap and average
-        within the group only (mirrors the rendezvous daemon's capped
-        matchmaking). The FIRST arriver freezes the partition for the round
-        so later joiners and membership churn can't split the groups."""
+    def _group_round(self, mine, key, cap, deadline):
+        """Under world.lock: partition live peers into per-round groups of
+        <= cap and average within the group only (mirrors the rendezvous
+        daemon's capped matchmaking). The FIRST arriver freezes the
+        partition for the round so later joiners and membership churn can't
+        split the groups. -> (published result, whether this peer is the
+        last of its group to collect it)."""
         import random
 
         w = self.world
-        codec = w.codec
-        key = f"{tag}-epoch-{epoch}"
-        compressed = [codec.decode(*_enc(codec, a)) for a in arrays]
-        deadline = time.monotonic() + (timeout or 3600.0)
-        with w.cond:
-            round_state = w._gossip.setdefault(key, {})
-            if "_partition" not in round_state:
-                members = sorted(w.live)
-                random.Random(key).shuffle(members)
-                round_state["_partition"] = [
-                    tuple(sorted(members[i : i + cap]))
-                    for i in range(0, len(members), cap)
-                ]
-            group = next(
-                (g for g in round_state["_partition"] if self._peer_id in g), None
-            )
-            if group is None:
-                # the partition was frozen before we were live: behave like
-                # the TCP client's "group does not contain self" retry path
-                raise AllReduceError(f"{self._peer_id}: not in gossip partition")
-            slot = round_state.setdefault(group, {"contrib": {}, "done": set()})
-            slot["contrib"][self._peer_id] = compressed
-            w.cond.notify_all()
-            while True:
-                live_members = [
-                    m for m in group if m in w.live or m in slot["contrib"]
-                ]
-                if set(slot["contrib"]) >= set(live_members):
-                    contribs = [slot["contrib"][m] for m in live_members]
-                    n = len(contribs)
-                    result = [
-                        np.sum([c[i] for c in contribs], axis=0) / n
-                        for i in range(len(arrays))
-                    ]
-                    slot["done"].add(self._peer_id)
-                    if slot["done"] >= set(live_members):
-                        round_state.pop(group, None)
-                        if not any(
-                            isinstance(k, tuple) for k in round_state
-                        ):
-                            w._gossip.pop(key, None)
-                    return [a.copy() for a in result], n
-                if time.monotonic() >= deadline:
-                    slot["contrib"].pop(self._peer_id, None)
-                    w.cond.notify_all()
-                    raise AllReduceError(f"{self._peer_id}: gossip round timed out")
-                w.cond.wait(timeout=0.1)
+        round_state = w._gossip.setdefault(key, {})
+        if "_partition" not in round_state:
+            members = sorted(w.live)
+            random.Random(key).shuffle(members)
+            round_state["_partition"] = [
+                tuple(sorted(members[i : i + cap]))
+                for i in range(0, len(members), cap)
+            ]
+        group = next(
+            (g for g in round_state["_partition"] if self._peer_id in g), None
+        )
+        if group is None:
+            # the partition was frozen before we were live: behave like
+            # the TCP client's "group does not contain self" retry path
+            raise AllReduceError(f"{self._peer_id}: not in gossip partition")
+        slot = round_state.setdefault(
+            group, {"contrib": {}, "done": set(), "result": None}
+        )
+        slot["contrib"][self._peer_id] = mine
+        w.cond.notify_all()
+        while True:
+            live_members = [
+                m for m in group if m in w.live or m in slot["contrib"]
+            ]
+            if set(slot["contrib"]) >= set(live_members):
+                if slot["result"] is None:
+                    # first member to notice publishes, in group order
+                    slot["result"] = self._publish(
+                        [slot["contrib"][m] for m in live_members], key
+                    )
+                slot["done"].add(self._peer_id)
+                last = slot["done"] >= set(live_members)
+                if last:
+                    round_state.pop(group, None)
+                    if not any(isinstance(k, tuple) for k in round_state):
+                        w._gossip.pop(key, None)
+                return slot["result"], last
+            if time.monotonic() >= deadline:
+                slot["contrib"].pop(self._peer_id, None)
+                w.cond.notify_all()
+                raise AllReduceError(f"{self._peer_id}: gossip round timed out")
+            w.cond.wait(timeout=0.1)
 
     def report_progress(self, progress: PeerProgress) -> None:
         with self.world.lock:
@@ -410,7 +480,130 @@ class LoopbackBackend(OuterBackend):
             self.world.cond.notify_all()
 
 
-def _enc(codec: Codec, a: np.ndarray):
-    payload, meta = codec.encode(a)
-    record_wire(codec.name, a.size * 4, len(payload))
-    return payload, a.shape, meta
+class _OutputPool:
+    """The rounds' output arrays, kept across rounds.
+
+    A round writes its mean, and each collector but the last its copy, into
+    a new array, and new pages are what that pass costs: on the TPU host a
+    copy of 1.45 GB takes 1.6 s into fresh memory and a twentieth of that
+    into memory the process has touched before (PERF.md, PR 25). So the
+    world keeps the arrays it has handed out and hands one out again once
+    nothing else refers to it: not its caller, not a view of it, not a
+    transfer still reading it -- each of those holds a reference, which is
+    what ``sys.getrefcount`` counts. An array somebody still holds is never
+    reused, so a result stays its caller's for as long as the caller keeps
+    it. Not thread-safe: callers hold the world's lock.
+    """
+
+    # references to a kept array that nobody else holds: the list's, the
+    # loop variable's in ``take``, and getrefcount's own argument
+    _FREE = 3
+
+    def __init__(self, keep: int):
+        self.keep = keep  # arrays remembered per position, shape and layout
+        self._arrays: dict[tuple, list[np.ndarray]] = {}
+
+    def take(self, i: int, like: np.ndarray) -> np.ndarray:
+        """A float32 array of ``like``'s shape and memory layout for the
+        ``i``-th array of a round, its contents undefined."""
+        kept = self._arrays.setdefault((i, like.shape, like.strides), [])
+        for a in kept:
+            if sys.getrefcount(a) == self._FREE:
+                return a
+        a = np.empty_like(like, dtype=np.float32)
+        kept.append(a)
+        del kept[: -self.keep]  # forget the oldest: its holder keeps it
+        return a
+
+
+class _Published:
+    """One generation's mean. Immutable from the moment it is published:
+    collectors copy it outside the world's lock, and ``readers`` counts the
+    copies in flight, so that the last collector is not handed ``arrays``
+    to write into while another still reads them."""
+
+    __slots__ = ("arrays", "group", "readers", "t_reduce")
+
+    def __init__(self, arrays: list[np.ndarray], group: int, t_reduce: float):
+        self.arrays = arrays
+        self.group = group
+        self.readers = 0
+        self.t_reduce = t_reduce  # where the publisher's waiting ended
+
+
+def _is_identity(codec: Codec) -> bool:
+    """The ``none`` codec (the base class): a memoryview out, a view back."""
+    return type(codec) is Codec
+
+
+def _contribution(codec: Codec, arrays) -> list[np.ndarray]:
+    """What one peer puts into a round. A lossy codec's round trip is the
+    modelled wire loss, and its decode output belongs to the round. The
+    identity codec loses nothing, so the caller's arrays go in as they are
+    (as float32), borrowed: ``_mean`` only reads them."""
+    identity = _is_identity(codec)
+    out = []
+    for a in arrays:
+        if identity:
+            a = np.asarray(a, np.float32)
+            wire_bytes = a.nbytes
+        else:
+            payload, meta = codec.encode(a)
+            wire_bytes = len(payload)
+            a = codec.decode(payload, a.shape, meta)
+        record_wire(codec.name, a.size * 4, wire_bytes)
+        out.append(a)
+    return out
+
+
+def _mean(
+    contribs: list[list[np.ndarray]], private: bool, take
+) -> tuple[list[np.ndarray], str, int]:
+    """The mean of n contributions, array by array, in n reads and one
+    write: -> (arrays, path, bytes written). Bit for bit
+    ``np.sum([c[i] for c in contribs], axis=0) / n``, which stacks the list
+    into a fresh (n, ...) array, reduces that into a second and divides
+    into a third (numpy adds the rows of such a stack one after another,
+    which is what the loop below does).
+
+    ``private`` says the contributions are the round's own (decode outputs
+    of a lossy codec): then the first one is the accumulator, and a single
+    one is the mean itself (path ``handover``: no pass at all). Borrowed
+    contributions (the identity codec's: the callers' arrays) are only
+    read: the first is copied into an output array that ``take(i, like)``
+    provides, laid out in memory as the contribution is, so that the copy
+    is a flat one (path ``copy`` when it is the only contribution,
+    ``accumulate`` otherwise)."""
+    n = len(contribs)
+    first = contribs[0]
+    if n == 1 and private and all(_accumulator(a) for a in first):
+        return first, "handover", 0
+    out = []
+    for i, a in enumerate(first):
+        parts = [c[i] for c in contribs]
+        if any(p.shape != a.shape for p in parts):
+            raise ValueError(
+                f"all-reduce array {i}: contributions differ in shape: "
+                f"{[p.shape for p in parts]}"
+            )
+        if a.size == 1:
+            # a sum over one element per row is the one case numpy reduces
+            # pairwise (from 8 rows on): keep the expression, it is free
+            out.append(np.asarray(np.sum(parts, axis=0) / n, np.float32))
+            continue
+        if private and _accumulator(a):
+            acc = a
+        else:
+            acc = take(i, a)
+            np.copyto(acc, a)
+        for p in parts[1:]:
+            np.add(acc, p, out=acc)
+        if n > 1:
+            acc /= n
+        out.append(acc)
+    return out, "copy" if n == 1 else "accumulate", sum(a.nbytes for a in out)
+
+
+def _accumulator(a: np.ndarray) -> bool:
+    """Whether a private array can be summed into and handed out."""
+    return a.dtype == np.float32 and a.flags.writeable
