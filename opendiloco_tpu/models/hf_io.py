@@ -61,11 +61,15 @@ def load_config(path_model: str) -> LlamaConfig:
 
 
 def _reject_moe(cfg: LlamaConfig, op: str) -> None:
-    if cfg.num_experts:
+    if cfg.num_experts or cfg.qk_norm:
         raise ValueError(
-            f"cannot {op} MoE weights as HF llama safetensors (the llama "
-            "architecture has no routed experts); use the framework "
-            "checkpointer (opendiloco_tpu.ckpt) for MoE models"
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no router, no per-expert gate/up/down projections "
+            "and no q/k norms, and HF's OLMoE layout (model.layers.N.mlp."
+            "experts.E.*, .mlp.gate, .self_attn.{q,k}_norm) is not mapped "
+            "here. Routed-expert and QK-norm models train, serve and "
+            "checkpoint through the framework checkpointer "
+            "(opendiloco_tpu.ckpt); only this import/export is refused"
         )
 
 

@@ -18,8 +18,8 @@ open_diloco/configs/*.json -- but designed for XLA, not translated:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
-import math
 import os
 from typing import Any, Literal, Optional, Union
 
@@ -56,11 +56,16 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     initializer_range: float = 0.02
     # Mixture-of-Experts (beyond the reference's dense-only zoo): 0 = dense
-    # FFN; > 0 = Switch-style top-1 routed experts in every layer, sharded
-    # over the "ep" mesh axis
+    # FFN; > 0 = routed experts in every layer, ``num_experts_per_tok`` per
+    # token and no token dropped, sharded over the "ep" mesh axis. The keys
+    # are those of the published OLMoE ``config.json``
     num_experts: int = 0
-    expert_capacity_factor: float = 1.25
-    router_aux_coef: float = 0.01
+    num_experts_per_tok: int = 1
+    norm_topk_prob: bool = False
+    router_aux_loss_coef: float = 0.01
+    router_z_loss_coef: float = 0.0
+    # RMSNorm over the whole q and k projections before the split into heads
+    qk_norm: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -79,7 +84,14 @@ class LlamaConfig:
     @classmethod
     def from_dict(cls, raw: dict[str, Any]) -> "LlamaConfig":
         fields = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in raw.items() if k in fields})
+        known = {k: v for k, v in raw.items() if k in fields}
+        if raw.get("model_type") == "olmoe":
+            # the published config.json has no key for either: OLMoE always
+            # normalises q and k, and its paper (arXiv 2409.02060) trains
+            # with a router z-loss of 0.001
+            known.setdefault("qk_norm", True)
+            known.setdefault("router_z_loss_coef", 0.001)
+        return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -126,6 +138,9 @@ def shapes(cfg: LlamaConfig) -> dict:
             "down_proj": s(L, F, D),
         }
     )
+    qk_norm = (
+        {"q_norm": s(L, Nh * Dh), "k_norm": s(L, Nkv * Dh)} if cfg.qk_norm else {}
+    )
     tree = {
         "embed_tokens": s(V, D),
         "layers": {
@@ -135,6 +150,7 @@ def shapes(cfg: LlamaConfig) -> dict:
             "k_proj": s(L, D, Nkv * Dh),
             "v_proj": s(L, D, Nkv * Dh),
             "o_proj": s(L, Nh * Dh, D),
+            **qk_norm,
             **ffn,
         },
         "final_norm": s(D),
@@ -246,49 +262,94 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return _rope_apply(x, cos, sin)
 
 
-def _switch_ffn(
-    cfg: LlamaConfig, x: jax.Array, layer: dict
-) -> tuple[jax.Array, jax.Array]:
-    """Switch-Transformer top-1 routed expert FFN -> (out, aux_loss).
+def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
+    """The attention block's projections of x [B, T, D]: q [B, T, Nh, Dh] and
+    k [B, T, Nkv, Dh] rotated by position, and v. ``mul(x, w)`` is the
+    caller's weight matmul. With ``cfg.qk_norm`` q and k pass an RMSNorm
+    over their whole width before they are split into heads (OLMoE)."""
+    B, T, _ = x.shape
+    Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    q = mul(x, layer["q_proj"])
+    k = mul(x, layer["k_proj"])
+    v = mul(x, layer["v_proj"]).reshape(B, T, Nkv, Dh)
+    if cfg.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+    q = _rope_apply(q.reshape(B, T, Nh, Dh), cos, sin)
+    k = _rope_apply(k.reshape(B, T, Nkv, Dh), cos, sin)
+    return q, k, v
 
-    Dispatch/combine are dense einsums over a [tokens, experts, capacity]
-    one-hot, so sharding the expert dim over the "ep" mesh axis is a pure
-    PartitionSpec concern -- pjit slices the expert matmuls per device, no
-    hand-written all-to-all. Over-capacity tokens pass through the residual
-    only (standard Switch semantics)."""
-    B, T, D = x.shape
-    E = cfg.num_experts
-    N = B * T
-    cap = max(1, math.ceil(N / E * cfg.expert_capacity_factor))
-    xf = x.reshape(N, D)
 
-    logits = (xf @ layer["router"]).astype(jnp.float32)  # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)  # [N]
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
-    onehot = jax.nn.one_hot(expert, E, dtype=jnp.float32)  # [N, E]
+def _routed_ffn(
+    cfg: LlamaConfig, x: jax.Array, layer: dict, live: Optional[jax.Array]
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Top-k routed expert FFN over x [..., D], no token dropped ->
+    (out, weighted aux loss, routing counts).
 
-    # load-balance aux (Switch eq. 4): density * router-probability mass
-    density = jnp.mean(onehot, axis=0)
-    density_proxy = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(density * density_proxy)
+    Router logits and softmax in float32; each token's k largest
+    probabilities are its experts' weights, renormalised only under
+    ``norm_topk_prob``. The token-expert pairs are sorted by expert, so that
+    each expert's rows are contiguous and the three projections are grouped
+    matmuls over them (``lax.ragged_dot``: its group dimension is the
+    weights' expert dimension, which the "ep" mesh axis shards); the pairs'
+    outputs go back to token order by the inverse permutation and are
+    summed under their weights.
 
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0  # slot within expert
-    # one_hot is already all-zero for pos = -1 (not routed here) and for
-    # pos >= cap (over capacity), so it doubles as the keep mask
-    dispatch = onehot[:, :, None] * jax.nn.one_hot(
-        pos.astype(jnp.int32), cap, dtype=jnp.float32
-    )  # [N, E, C]
+    Aux loss (OLMoE, arXiv 2409.02060): ``router_aux_loss_coef`` times the
+    load balance E * sum_e f_e P_e (f_e the share of tokens that chose e,
+    over all k places; P_e the mean router probability) plus
+    ``router_z_loss_coef`` times the mean squared logsumexp of the logits.
 
-    d = dispatch.astype(x.dtype)
-    expert_in = jnp.einsum("nec,nd->ecd", d, xf)  # [E, C, D]
-    h1 = jax.nn.silu(
-        jnp.einsum("ecd,edf->ecf", expert_in, layer["gate_proj"])
-    ) * jnp.einsum("ecd,edf->ecf", expert_in, layer["up_proj"])
-    out_e = jnp.einsum("ecf,efd->ecd", h1, layer["down_proj"])
-    combine = d * gate.astype(x.dtype)[:, None, None]
-    y = jnp.einsum("nec,ecd->nd", combine, out_e)
-    return y.reshape(B, T, D), aux
+    Counts, int32 [3]: token-expert pairs, experts with at least one pair,
+    the busiest expert's pairs -- of the ``live`` tokens ([N] bool; None:
+    all), so padding rows of a prefill bucket and empty slots do not count."""
+    D = x.shape[-1]
+    E, K = cfg.num_experts, cfg.num_experts_per_tok
+    xf = x.reshape(-1, D)
+    N = xf.shape[0]
+
+    logits = jnp.dot(xf, layer["router"], preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
+    gate, expert = jax.lax.top_k(probs, K)  # [N, K]; ties go to the lower index
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+
+    flat = expert.reshape(-1)  # pair p belongs to token p // K
+    sizes = jnp.bincount(flat, length=E)  # int32, one group per expert
+    order = jnp.argsort(flat, stable=True)  # pairs by expert
+    xs = xf[order // K]
+    h = jax.nn.silu(
+        jax.lax.ragged_dot(xs, layer["gate_proj"], sizes)
+    ) * jax.lax.ragged_dot(xs, layer["up_proj"], sizes)
+    ys = jax.lax.ragged_dot(h, layer["down_proj"], sizes)  # [N * K, D]
+    ys = ys[jnp.argsort(order)].reshape(N, K, D)
+    out = jnp.sum(ys.astype(jnp.float32) * gate[..., None], axis=1)
+
+    balance = E * jnp.sum(sizes / N * jnp.mean(probs, axis=0))
+    z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    aux = cfg.router_aux_loss_coef * balance + cfg.router_z_loss_coef * z
+
+    if live is not None:
+        sizes = jnp.bincount(
+            flat, weights=jnp.repeat(live.reshape(-1), K).astype(jnp.int32), length=E
+        )
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
+    return out.astype(x.dtype).reshape(x.shape), aux, counts
+
+
+def _ffn(
+    cfg: LlamaConfig, x: jax.Array, layer: dict, mul, live=None
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The block's feed-forward over x [..., D] -> (out, aux loss, routing
+    counts): SwiGLU through the caller's weight matmul ``mul(x, w)``, or the
+    routed experts; aux and counts are zero for a dense model."""
+    if cfg.num_experts:
+        return _routed_ffn(cfg, x, layer, live)
+    out = mul(
+        jax.nn.silu(mul(x, layer["gate_proj"])) * mul(x, layer["up_proj"]),
+        layer["down_proj"],
+    )
+    return out, jnp.float32(0.0), jnp.zeros((3,), jnp.int32)
 
 
 def _decoder_block(
@@ -303,34 +364,24 @@ def _decoder_block(
     the activation probe the reference attaches via forward hooks on
     ``self_attn`` (utils.py:43-67, train_fsdp.py:65)."""
     B, T, D = h.shape
-    Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     if rope is None:
         rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     cos, sin = rope
+    mul = jnp.matmul
 
     # the scopes name the device work in a profiler trace (an operation's
     # op_name metadata); they change nothing that is computed
     with jax.named_scope("odtp_attention"):
         x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q = (x @ layer["q_proj"]).reshape(B, T, Nh, Dh)
-        k = (x @ layer["k_proj"]).reshape(B, T, Nkv, Dh)
-        v = (x @ layer["v_proj"]).reshape(B, T, Nkv, Dh)
-        q = _rope_apply(q, cos, sin)
-        k = _rope_apply(k, cos, sin)
+        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
         attn = attn_fn(q, k, v)
-        attn_out = attn.reshape(B, T, Nh * Dh) @ layer["o_proj"]
+        attn_out = attn.reshape(B, T, -1) @ layer["o_proj"]
         attn_norm = jnp.sqrt(jnp.sum(attn_out.astype(jnp.float32) ** 2))
         h = h + attn_out
 
     with jax.named_scope("odtp_mlp"):
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        if cfg.num_experts:
-            ffn, aux = _switch_ffn(cfg, x, layer)
-        else:
-            ffn = (
-                jax.nn.silu(x @ layer["gate_proj"]) * (x @ layer["up_proj"])
-            ) @ layer["down_proj"]
-            aux = jnp.float32(0.0)
+        ffn, aux, _ = _ffn(cfg, x, layer, mul)
         return h + ffn, (attn_norm, aux)
 
 
@@ -362,7 +413,9 @@ def forward(
     return_hidden=True returns (final_hidden [B, T, D], head [D, V]) instead
     of logits -- the hook for fused lm-head losses (ops/fused_xent.py);
     with return_moe_aux=True it returns (final_hidden, head, moe_aux) so
-    those losses can thread the router aux term.
+    those losses can thread the router aux term: the mean over layers of
+    the routed FFN's aux loss, already weighted by the configuration's two
+    coefficients (``_routed_ffn``), to be added to the loss as it is.
 
     return_aux=True additionally returns activation-probe metrics
     {"attn_out_norm": [L], "lm_head_norm": scalar} (the reference's
@@ -481,8 +534,8 @@ def forward(
 
 # ---------------------------------------------------------------------------
 # serving: prefill / incremental decode over a slot-paged ring KV cache
-# (opendiloco_tpu/serve). Dense stacks only — routed-expert decode would
-# need capacity bookkeeping per step and no serving config uses MoE yet.
+# (opendiloco_tpu/serve). Every forward below shares ``_qkv`` and ``_ffn``
+# with the training block, so dense and routed-expert stacks both serve.
 # ---------------------------------------------------------------------------
 
 
@@ -586,11 +639,6 @@ def init_kv_cache(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _require_dense(cfg: LlamaConfig, what: str) -> None:
-    if cfg.num_experts:
-        raise NotImplementedError(f"{what} supports dense FFN stacks only")
-
-
 def prefill_forward(
     params: dict,
     input_ids: jax.Array,
@@ -599,43 +647,36 @@ def prefill_forward(
     *,
     compute_dtype: jnp.dtype = jnp.bfloat16,
     decode_kernel: str = "xla",
+    return_moe_counts: bool = False,
 ):
     """Prompt prefill for serving: ids [1, P] -> (last-token logits [1, V]
-    f32, per-layer K/V [L, P, Nkv, Dh] in compute dtype).
+    f32, per-layer K/V [L, P, Nkv, Dh] in compute dtype), and with
+    ``return_moe_counts`` the routed FFN's counts over the live prompt
+    tokens summed over layers (int32 [3], see ``_routed_ffn``).
 
     ``length`` (traced scalar) is the true prompt length; ``input_ids``
     may be right-padded to a compile-size bucket. Padding K/V rows do land
     in the returned stack (and hence the cache) but are never attended:
     the decode mask stops at the live length and every ring write
     overwrites index ``len % T`` before index ``len`` becomes visible."""
-    _require_dense(cfg, "prefill_forward")
     B, P = input_ids.shape
-    Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
     cparams = _cast_serving_params(params, compute_dtype)
-    cos, sin = _rope_tables(positions, Dh, cfg.rope_theta)
-    cd = compute_dtype
-    dkn = decode_kernel
+    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
+    live = positions < length
 
     def block(h, layer):
         x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q = _wmul(x, layer["q_proj"], cd, dkn).reshape(B, P, Nh, Dh)
-        k = _wmul(x, layer["k_proj"], cd, dkn).reshape(B, P, Nkv, Dh)
-        v = _wmul(x, layer["v_proj"], cd, dkn).reshape(B, P, Nkv, Dh)
-        q = _rope_apply(q, cos, sin)
-        k = _rope_apply(k, cos, sin)
+        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
         attn = xla_attention(q, k, v, causal=True)
-        h = h + _wmul(attn.reshape(B, P, Nh * Dh), layer["o_proj"], cd, dkn)
+        h = h + mul(attn.reshape(B, P, -1), layer["o_proj"])
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn = _wmul(
-            jax.nn.silu(_wmul(x, layer["gate_proj"], cd, dkn))
-            * _wmul(x, layer["up_proj"], cd, dkn),
-            layer["down_proj"], cd, dkn,
-        )
-        return h + ffn, (k[0], v[0])
+        ffn, _, counts = _ffn(cfg, x, layer, mul, live)
+        return h + ffn, (k[0], v[0], counts)
 
     h = jnp.take(cparams["embed_tokens"], input_ids, axis=0)
-    h, (ks, vs) = jax.lax.scan(block, h, cparams["layers"])
+    h, (ks, vs, counts) = jax.lax.scan(block, h, cparams["layers"])
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     h_last = _rms_norm(h_last, cparams["final_norm"], cfg.rms_norm_eps)
     head = (
@@ -644,6 +685,8 @@ def prefill_forward(
         else cparams["lm_head"]
     )
     logits = (h_last @ head).astype(jnp.float32)
+    if return_moe_counts:
+        return logits[:, 0], ks, vs, jnp.sum(counts, axis=0)
     return logits[:, 0], ks, vs
 
 
@@ -680,6 +723,7 @@ def decode_forward(
     *,
     compute_dtype: jnp.dtype = jnp.bfloat16,
     decode_kernel: str = "xla",
+    return_moe_counts: bool = False,
 ):
     """One incremental decode step over all S slots.
 
@@ -689,44 +733,36 @@ def decode_forward(
     f32, new_cache_k, new_cache_v): the new K/V is written at ring index
     ``lens % T`` and attention covers the last ``min(lens + 1, T)``
     positions. Callers jit this with the caches donated — the cache
-    update is in-place at HBM, never a fresh page copy."""
-    _require_dense(cfg, "decode_forward")
+    update is in-place at HBM, never a fresh page copy. With
+    ``return_moe_counts`` the routed FFN's counts over the slots that hold
+    a sequence (``lens > 0``), summed over layers, come fourth."""
     S = tokens.shape[0]
-    L, _, T, Nkv, Dh = cache_k.shape
-    Nh = cfg.num_attention_heads
+    T = cache_k.shape[2]
     cparams = _cast_serving_params(params, compute_dtype)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
-    cos, sin = _rope_tables(positions, Dh, cfg.rope_theta)
+    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     rows = jnp.arange(S)
     write_idx = jnp.mod(lens, T)
-    cd = compute_dtype
-    dkn = decode_kernel
+    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
+    live = lens > 0
 
     def block(h, xs):
         layer, ck, cv = xs  # ck/cv [S, T, Nkv, Dh]
         x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q = _wmul(x, layer["q_proj"], cd, dkn).reshape(S, 1, Nh, Dh)
-        k = _wmul(x, layer["k_proj"], cd, dkn).reshape(S, 1, Nkv, Dh)
-        v = _wmul(x, layer["v_proj"], cd, dkn).reshape(S, 1, Nkv, Dh)
-        q = _rope_apply(q, cos, sin)
-        k = _rope_apply(k, cos, sin)
+        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
         ck = ck.at[rows, write_idx].set(k[:, 0].astype(ck.dtype))
         cv = cv.at[rows, write_idx].set(v[:, 0].astype(cv.dtype))
-        if dkn == "pallas":
+        if decode_kernel == "pallas":
             attn = paged_decode_attention(q[:, 0], ck, cv, lens)
         else:
             attn = decode_attention(q[:, 0], ck, cv, lens)
-        h = h + _wmul(attn.reshape(S, 1, Nh * Dh), layer["o_proj"], cd, dkn)
+        h = h + mul(attn.reshape(S, 1, -1), layer["o_proj"])
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn = _wmul(
-            jax.nn.silu(_wmul(x, layer["gate_proj"], cd, dkn))
-            * _wmul(x, layer["up_proj"], cd, dkn),
-            layer["down_proj"], cd, dkn,
-        )
-        return h + ffn, (ck, cv)
+        ffn, _, counts = _ffn(cfg, x, layer, mul, live)
+        return h + ffn, (ck, cv, counts)
 
     h = jnp.take(cparams["embed_tokens"], tokens, axis=0)[:, None]  # [S, 1, D]
-    h, (new_ck, new_cv) = jax.lax.scan(
+    h, (new_ck, new_cv, counts) = jax.lax.scan(
         block, h, (cparams["layers"], cache_k, cache_v)
     )
     h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
@@ -736,6 +772,8 @@ def decode_forward(
         else cparams["lm_head"]
     )
     logits = (h @ head).astype(jnp.float32)
+    if return_moe_counts:
+        return logits[:, 0], new_ck, new_cv, jnp.sum(counts, axis=0)
     return logits[:, 0], new_ck, new_cv
 
 
@@ -764,35 +802,23 @@ def verify_forward(
     Also the continued-prefill primitive for shared-prefix KV reuse
     (S = 1, tail = the suffix tokens, lens = the reused prefix length).
     """
-    _require_dense(cfg, "verify_forward")
     S, K = tail.shape
-    L, _, T, Nkv, Dh = cache_k.shape
-    Nh = cfg.num_attention_heads
     cparams = _cast_serving_params(params, compute_dtype)
     positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
-    cos, sin = _rope_tables(positions, Dh, cfg.rope_theta)
-    cd = compute_dtype
-    dkn = decode_kernel
+    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
 
     def block(h, xs):
         layer, ck, cv = xs  # ck/cv [S, T, Nkv, Dh]
         x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q = _wmul(x, layer["q_proj"], cd, dkn).reshape(S, K, Nh, Dh)
-        k = _wmul(x, layer["k_proj"], cd, dkn).reshape(S, K, Nkv, Dh)
-        v = _wmul(x, layer["v_proj"], cd, dkn).reshape(S, K, Nkv, Dh)
-        q = _rope_apply(q, cos, sin)
-        k = _rope_apply(k, cos, sin)
-        if dkn == "pallas":
+        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
+        if decode_kernel == "pallas":
             attn = spec_tail_attention_fused(q, ck, cv, k, v, lens)
         else:
             attn = spec_tail_attention(q, ck, cv, k, v, lens)
-        h = h + _wmul(attn.reshape(S, K, Nh * Dh), layer["o_proj"], cd, dkn)
+        h = h + mul(attn.reshape(S, K, -1), layer["o_proj"])
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn = _wmul(
-            jax.nn.silu(_wmul(x, layer["gate_proj"], cd, dkn))
-            * _wmul(x, layer["up_proj"], cd, dkn),
-            layer["down_proj"], cd, dkn,
-        )
+        ffn, _, _ = _ffn(cfg, x, layer, mul)
         return h + ffn, (k, v)
 
     h = jnp.take(cparams["embed_tokens"], tail, axis=0)  # [S, K, D]
@@ -831,10 +857,8 @@ def draft_propose(
     the ring — the draft is a heuristic and dirties nothing; exactness
     is the verify pass's job. Returns proposals [S, k_steps] int32.
     """
-    _require_dense(cfg, "draft_propose")
     S = tokens.shape[0]
     L, _, T, Nkv, Dh = cache_k.shape
-    Nh = cfg.num_attention_heads
     Ld = int(draft_layers)
     if not 1 <= Ld <= L:
         raise ValueError(f"draft_layers {Ld} outside [1, {L}]")
@@ -842,7 +866,7 @@ def draft_propose(
     dlayers = jax.tree.map(lambda x: x[:Ld], cparams["layers"])
     dck, dcv = cache_k[:Ld], cache_v[:Ld]
     cd = compute_dtype
-    dkn = decode_kernel
+    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
     head = (
         cparams["embed_tokens"].T
         if cfg.tie_word_embeddings
@@ -860,26 +884,18 @@ def draft_propose(
         def block(h, xs, i=i, cos=cos, sin=sin):
             layer, ck, cv, tk, tv = xs
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-            q = _wmul(x, layer["q_proj"], cd, dkn).reshape(S, 1, Nh, Dh)
-            k = _wmul(x, layer["k_proj"], cd, dkn).reshape(S, 1, Nkv, Dh)
-            v = _wmul(x, layer["v_proj"], cd, dkn).reshape(S, 1, Nkv, Dh)
-            q = _rope_apply(q, cos, sin)
-            k = _rope_apply(k, cos, sin)
+            q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
             tk = tk.at[:, i].set(k[:, 0])
             tv = tv.at[:, i].set(v[:, 0])
-            if dkn == "pallas":
+            if decode_kernel == "pallas":
                 attn = spec_tail_attention_fused(
                     q, ck, cv, tk, tv, lens, q_start=i
                 )
             else:
                 attn = spec_tail_attention(q, ck, cv, tk, tv, lens, q_start=i)
-            h = h + _wmul(attn.reshape(S, 1, Nh * Dh), layer["o_proj"], cd, dkn)
+            h = h + mul(attn.reshape(S, 1, -1), layer["o_proj"])
             x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-            ffn = _wmul(
-                jax.nn.silu(_wmul(x, layer["gate_proj"], cd, dkn))
-                * _wmul(x, layer["up_proj"], cd, dkn),
-                layer["down_proj"], cd, dkn,
-            )
+            ffn, _, _ = _ffn(cfg, x, layer, mul)
             return h + ffn, (tk, tv)
 
         h = jnp.take(cparams["embed_tokens"], cur, axis=0)[:, None]  # [S, 1, D]

@@ -32,6 +32,8 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "final_norm": (None, -1),
     "input_norm": (None, -1),
     "post_attn_norm": (None, -1),
+    "q_norm": (None, -1),  # [Nh*Dh]: a norm vector, replicated
+    "k_norm": (None, -1),
     "q_proj": (1, 0),  # [D, Nh*Dh]
     "k_proj": (1, 0),
     "v_proj": (1, 0),

@@ -85,6 +85,13 @@ def _fresh_copy(leaves):
     return [x.astype(jnp.float32) + jnp.zeros((), jnp.float32) for x in leaves]
 
 
+def _with_counts(tok, counts):
+    """``tok`` with a routed model's FFN counts appended (``counts``: a list
+    holding the int32 [3], or empty for a dense model, whose program then
+    returns its tokens as they are)."""
+    return jnp.concatenate([tok, *counts]) if counts else tok
+
+
 # snapshot_fn contract: () -> (epoch, blobs, codec_name) with blobs[i] =
 # (payload, meta, shape) per master leaf in params-flatten order — exactly
 # what DiLoCoOptimizer.master_snapshot_wire returns.
@@ -161,9 +168,10 @@ class ServeEngine:
         ]
         self._shapes = [tuple(x.shape) for x in leaves]
         # w4-packable set: the stacked decoder matmuls ([L, in, out] leaves
-        # under "layers"); norms ([L, D]), embeddings and lm head stay fp32
+        # under "layers"); norms ([L, D]), embeddings, the lm head, and a
+        # routed FFN's router and [L, E, in, out] experts stay fp32
         self._packable = [
-            p[0] == "layers" and len(s) == 3
+            p[0] == "layers" and len(s) == 3 and p[-1] != "router"
             for p, s in zip(self._paths, self._shapes)
         ]
         self.params = self._assemble(leaves)
@@ -173,34 +181,45 @@ class ServeEngine:
         # wall-clock per decode stage (loop-thread only, mirrored to obs
         # spans when a tracer is armed; the bench reads this directly)
         self.stage_seconds = {k: 0.0 for k in _STAGES}
+        # what a routed FFN did in the prefills and decode steps so far, each
+        # summed over layers and calls (always on; stay 0 for a dense model):
+        # token-expert pairs, experts that received a token, and the busiest
+        # expert's pairs
+        self.moe_pairs = 0
+        self.moe_experts_hit = 0
+        self.moe_max_pairs = 0
 
         cache = init_kv_cache(cfg, self.num_slots, self.max_context, compute_dtype)
         self.cache_k, self.cache_v = cache["k"], cache["v"]
 
         cd = compute_dtype
         dkn = self.decode_kernel
+        moe = bool(cfg.num_experts)
 
         # one named scope per program: what a profiler trace calls the
-        # device work of a prefill and of a decode step
+        # device work of a prefill and of a decode step. A routed model's
+        # programs append the FFN's three counts to the tokens, so that one
+        # device-to-host read fetches both (``_split_counts``)
         def _prefill(p, ids, length):
             with jax.named_scope("odtp_serve_prefill"):
-                logits, ks, vs = prefill_forward(
-                    p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn
+                logits, ks, vs, *counts = prefill_forward(
+                    p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
+                    return_moe_counts=moe,
                 )
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return tok, logits, ks, vs
+            return _with_counts(tok, counts), logits, ks, vs
 
         def _insert(ck, cv, ks, vs, slot):
             return cache_insert(ck, cv, ks, vs, slot)
 
         def _decode(p, tokens, lens, ck, cv):
             with jax.named_scope("odtp_serve_decode"):
-                logits, ck, cv = decode_forward(
+                logits, ck, cv, *counts = decode_forward(
                     p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
-                    decode_kernel=dkn,
+                    decode_kernel=dkn, return_moe_counts=moe,
                 )
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return tok, logits, ck, cv
+            return _with_counts(tok, counts), logits, ck, cv
 
         # one compile per prompt bucket; insert/decode compile once
         self._prefill = jax.jit(_prefill)
@@ -343,6 +362,7 @@ class ServeEngine:
                 f"{self.prefill_buckets[-1]}"
             )
         t0 = time.perf_counter()
+        moe = {}  # a continued prefill's routing is not counted
         if host_prefix is not None and 0 < host_prefix[2] < n:
             hk, hv, plen = host_prefix
             self.cache_k, self.cache_v = self._install_pages(
@@ -363,13 +383,28 @@ class ServeEngine:
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v, ks, vs, jnp.int32(slot)
             )
-            tok, logits = int(tokd[0]), np.asarray(logitsd[0])
+            toks, moe = self._split_counts(np.asarray(tokd), 1)
+            tok, logits = int(toks[0]), np.asarray(logitsd[0])
         dt = time.perf_counter() - t0
         self.stage_seconds["prefill"] += dt
         tr = obs.tracer()
         if tr is not None:
-            tr.add_span("serve_prefill", t0, t0 + dt, tokens=n)
+            tr.add_span("serve_prefill", t0, t0 + dt, tokens=n, **moe)
         return tok, logits
+
+    def _split_counts(self, fetched: np.ndarray, n: int) -> tuple[np.ndarray, dict]:
+        """One program's fetched token output -> (its ``n`` tokens, span
+        attributes): a routed model's three counts follow the tokens and are
+        added to the engine's counters here."""
+        if fetched.size == n:
+            return fetched, {}
+        pairs, hit, busiest = (int(x) for x in fetched[n:])
+        self.moe_pairs += pairs
+        self.moe_experts_hit += hit
+        self.moe_max_pairs += busiest
+        return fetched[:n], {
+            "moe_pairs": pairs, "moe_experts_hit": hit, "moe_max_pairs": busiest,
+        }
 
     def _admit_suffix(
         self, slot: int, prompt: Sequence[int], src: int, plen: int
@@ -469,7 +504,7 @@ class ServeEngine:
             self.cache_k,
             self.cache_v,
         )
-        tok = np.asarray(tok)
+        tok, moe = self._split_counts(np.asarray(tok), self.num_slots)
         t1 = time.perf_counter()
         self.stage_seconds["decode"] += t1 - t0
         tr = obs.tracer()
@@ -479,7 +514,7 @@ class ServeEngine:
             # slots (``lens`` is 0 for an empty slot)
             tr.add_span(
                 "serve_decode", t0, t1,
-                rows=int(np.sum(lens)), slots=int(np.count_nonzero(lens)),
+                rows=int(np.sum(lens)), slots=int(np.count_nonzero(lens)), **moe,
             )
         return tok, logits
 

@@ -448,8 +448,8 @@ class InnerTrainer:
                 ring_mesh=self.plan.mesh,
                 ring_axis=self.plan.sp_axis or "sp",
             )
+        # the router's aux loss (load balance and z-loss) arrives weighted
         moe = bool(self.model_cfg.num_experts)
-        aux = lambda a: self.model_cfg.router_aux_coef * a
         fwd_kwargs.update(
             batch_axes=self.plan.batch_axes,
             tp_axis=self.plan.tp_axis,
@@ -472,7 +472,7 @@ class InnerTrainer:
             with jax.named_scope("odtp_lm_head_loss"):
                 if moe:
                     hidden, head, moe_aux = out
-                    return self._fused_lm_loss(hidden, head, labels) + aux(moe_aux)
+                    return self._fused_lm_loss(hidden, head, labels) + moe_aux
                 hidden, head = out
                 return self._fused_lm_loss(hidden, head, labels)
         out = forward(
@@ -481,7 +481,7 @@ class InnerTrainer:
         with jax.named_scope("odtp_lm_head_loss"):
             if moe:
                 logits, moe_aux = out
-                return causal_lm_loss(logits, labels) + aux(moe_aux)
+                return causal_lm_loss(logits, labels) + moe_aux
             return causal_lm_loss(out, labels)
 
     def _train_step_impl(self, state: dict, batch: dict):

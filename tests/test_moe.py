@@ -1,8 +1,10 @@
-"""Mixture-of-Experts (Switch top-1) + expert parallelism over the ep axis.
+"""Mixture-of-Experts (dropless top-k routing) + expert parallelism over
+the ep axis.
 
-The reference's zoo is dense-only (SURVEY §2.4: no EP); oracle for the
-routed FFN is the dense model: a single-expert MoE with sufficient capacity
-IS the dense network (router softmax over one logit = 1.0)."""
+The reference's zoo is dense-only (SURVEY §2.4: no EP); one oracle for the
+routed FFN is the dense model: a single-expert MoE IS the dense network
+(router softmax over one logit = 1.0). The float32 reference of the OLMoE
+block is the other (tests/test_olmoe.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -17,20 +19,20 @@ from opendiloco_tpu.parallel.mesh import build_mesh
 from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
 
 
-def _cfg(num_experts=0, layers=2, cf=1.25):
+def _cfg(num_experts=0, layers=2):
     return LlamaConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128,
         num_hidden_layers=layers, num_attention_heads=4,
         num_key_value_heads=2, max_position_embeddings=64,
-        num_experts=num_experts, expert_capacity_factor=cf,
+        num_experts=num_experts,
     )
 
 
 def test_single_expert_equals_dense():
-    """E=1, capacity >= tokens: the MoE forward is exactly the dense
-    forward with the same weights."""
+    """E=1: the MoE forward is exactly the dense forward with the same
+    weights."""
     dense_cfg = _cfg(0)
-    moe_cfg = _cfg(1, cf=2.0)
+    moe_cfg = _cfg(1)
     dense = init_params(jax.random.key(0), dense_cfg)
     moe = init_params(jax.random.key(0), moe_cfg)
     # graft the dense FFN weights into the single expert
@@ -51,7 +53,8 @@ def test_single_expert_equals_dense():
         return_moe_aux=True,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
-    np.testing.assert_allclose(float(aux), 1.0, atol=1e-5)  # E * 1 * 1
+    # load balance E * f * P = 1 under its coefficient; no z-loss by default
+    np.testing.assert_allclose(float(aux), moe_cfg.router_aux_loss_coef, atol=1e-7)
 
 
 def test_moe_trains_on_ep_mesh():
@@ -83,29 +86,13 @@ def test_moe_trains_on_ep_mesh():
     assert losses[-1] < losses[0]  # learns the sequential structure
 
 
-def test_moe_capacity_drop_passes_residual():
-    """Over-capacity tokens fall back to the residual stream (finite, and
-    different from the uncapped result)."""
-    ids = jnp.asarray(
-        np.random.default_rng(2).integers(0, 256, (2, 32)), jnp.int32
-    )
-    big = _cfg(2, cf=4.0)
-    tiny = _cfg(2, cf=0.05)  # capacity ~2 tokens per expert
-    params = init_params(jax.random.key(3), big)
-    out_big = forward(params, ids, big, compute_dtype=jnp.float32, remat=False)
-    out_tiny = forward(params, ids, tiny, compute_dtype=jnp.float32, remat=False)
-    assert np.all(np.isfinite(np.asarray(out_tiny)))
-    assert not np.allclose(np.asarray(out_big), np.asarray(out_tiny))
-
-
 def test_moe_pp_loss_matches_sequential():
     """MoE composes with pipeline parallelism: the router aux rides the
     pipeline's per-stage accumulators (parallel/pipeline.py). With
     microbatches=1 the total loss (xent + aux) is exactly the unpipelined
     value; with M>1 EVERY router batch statistic becomes microbatch-local
-    (standard GPipe semantics) — the aux, AND the expert capacity /
-    overflow-drop decisions, so hidden states match per-microbatch
-    unpipelined forwards rather than the joint-batch forward."""
+    (standard GPipe semantics), so the aux matches the mean of
+    per-microbatch unpipelined forwards rather than the joint-batch one."""
     cfg = _cfg(4)
     ids = np.random.default_rng(2).integers(
         0, cfg.vocab_size, (8, 32), dtype=np.int32
@@ -127,12 +114,10 @@ def test_moe_pp_loss_matches_sequential():
     # microbatches=1: per-batch router statistics identical -> exact
     np.testing.assert_allclose(one_loss(pp=2, mb=1), ref, atol=2e-5)
 
-    # microbatched pp x ep: each microbatch routes independently, so the
-    # oracle is the mean over per-microbatch UNPIPELINED forwards — for
-    # the xent too, because expert capacity (1.25 * tokens / E) and the
-    # resulting overflow drops are computed per routed batch and differ
-    # from the joint-batch forward's. Building both terms from halves
-    # also pins the aux normalization (/L/M, not /L)
+    # microbatched pp x ep: each microbatch's router statistics are its
+    # own, so the oracle is the mean over per-microbatch UNPIPELINED
+    # forwards. Building both terms from halves also pins the aux
+    # normalization (/L/M, not /L)
     from opendiloco_tpu.models.llama import causal_lm_loss
 
     tc = TrainerConfig(
@@ -150,7 +135,7 @@ def test_moe_pp_loss_matches_sequential():
         )
         xents.append(float(causal_lm_loss(logits, mb_ids)))
         auxs.append(float(aux))
-    ref2 = float(np.mean(xents)) + cfg.router_aux_coef * float(np.mean(auxs))
+    ref2 = float(np.mean(xents)) + float(np.mean(auxs))
     np.testing.assert_allclose(one_loss(pp=2, mb=2, ep=2), ref2, atol=1e-4)
 
 

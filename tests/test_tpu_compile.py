@@ -175,3 +175,102 @@ def test_w4_matmul(chip, model, proj, rows):
     )
     assert "tpu_custom_call" in text
 
+
+
+# ---------------------------------------------------------------------------
+# the OLMoE serving cell's programs, at the published widths and the depth
+# the cell runs (benchmark/configs/olmoe-1b-7b.json and its cell's file)
+# ---------------------------------------------------------------------------
+
+HBM_BYTES = 16e9  # what the cell's sizing counts against
+
+
+def _olmoe_cell():
+    import json
+
+    from opendiloco_tpu.models.llama import LlamaConfig
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "olmoe-1b-7b.json")) as f:
+        cfg = LlamaConfig.from_dict(json.load(f))
+    with open(os.path.join(bench, "workloads", "serve-olmoe-fewshot.json")) as f:
+        return cfg, json.load(f)["engine"]
+
+
+def _on_chip(chip, tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree
+    )
+
+
+def _program_bytes(compiled) -> float:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+
+
+@pytest.mark.parametrize("rows", [3072, 16])
+def test_olmoe_routed_ffn_is_the_grouped_matmul(chip, rows):
+    """One layer's routed FFN at a prefill's 3,072 rows and at a decode
+    step's 16: XLA's ``ragged_dot`` comes out as the TPU's own grouped-matmul
+    call, under the result name the benchmark's reader looks for."""
+    from opendiloco_tpu.models.llama import _routed_ffn, shapes
+
+    cfg, _ = _olmoe_cell()
+    layer = {
+        name: jax.ShapeDtypeStruct(leaf.shape[1:], BF16, sharding=chip)
+        for name, leaf in shapes(cfg)["layers"].items()
+        if name in ("router", "gate_proj", "up_proj", "down_proj")
+    }
+    x = jax.ShapeDtypeStruct((1, rows, cfg.hidden_size), BF16, sharding=chip)
+    text = (
+        jax.jit(lambda x, layer: _routed_ffn(cfg, x, layer, None))
+        .lower(x, layer).compile().as_text()
+    )
+    assert text.count("%ragged-dot") >= 3 and "tpu_custom_call" in text
+
+
+def test_olmoe_prefill_program_at_the_largest_bucket(chip):
+    from opendiloco_tpu.models.llama import prefill_forward, shapes
+
+    cfg, engine = _olmoe_cell()
+    bucket = max(engine["prefill_buckets"])
+    compiled = (
+        jax.jit(lambda p, ids, n: prefill_forward(
+            p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=True))
+        .lower(
+            _on_chip(chip, shapes(cfg)),
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
+        ).compile()
+    )
+    cache = engine["num_slots"] * engine["max_context"] * 2 * 2 * (
+        cfg.num_hidden_layers * cfg.kv_heads * cfg.head_dim
+    )
+    # the prefill runs beside the resident cache (the cell's ``sizing``)
+    assert _program_bytes(compiled) + cache < HBM_BYTES
+
+
+def test_olmoe_decode_program_at_16_slots(chip):
+    """16 slots, 16 KV heads of 128, the cell's rows a slot: the Pallas
+    decode kernel and the grouped matmuls in one program that fits."""
+    from opendiloco_tpu.models.llama import decode_forward, shapes
+
+    cfg, engine = _olmoe_cell()
+    slots, rows = engine["num_slots"], engine["max_context"]
+    cache = jax.ShapeDtypeStruct(
+        (cfg.num_hidden_layers, slots, rows, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip
+    )
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(
+            lambda p, tok, lens, ck, cv: decode_forward(
+                p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=True),
+            donate_argnums=(3, 4),
+        ).lower(_on_chip(chip, shapes(cfg)), vec, vec, cache, cache).compile()
+    )
+    text = compiled.as_text()
+    assert "odtp_paged_decode_attn" in text and "%ragged-dot" in text
+    assert _program_bytes(compiled) < HBM_BYTES
