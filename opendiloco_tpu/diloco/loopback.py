@@ -31,14 +31,13 @@ Who owns what:
   outside the world's lock; the last one of a generation takes the arrays
   themselves. What ``all_reduce`` returns is the caller's alone, to keep and
   to write into, for as long as it keeps it.
-- The world keeps the output arrays it hands out (``_OutputPool``: new
-  pages are what a pass costs on a TPU host) and writes into one again only
-  once nothing else refers to it, so the line above holds unchanged.
+- The world keeps the output arrays it hands out (``hostpool.OutputPool``:
+  new pages are what a pass costs on a TPU host) and writes into one again
+  only once nothing else refers to it, so the line above holds unchanged.
 """
 
 from __future__ import annotations
 
-import sys
 import threading
 import time
 from typing import Any, Callable, Optional
@@ -53,6 +52,7 @@ from opendiloco_tpu.diloco.backend import (
     PeerProgress,
 )
 from opendiloco_tpu.diloco.compression import Codec, get_codec, record_wire
+from opendiloco_tpu.diloco.hostpool import OutputPool
 
 
 class LoopbackWorld:
@@ -86,7 +86,7 @@ class LoopbackWorld:
         # the rounds' output arrays, kept across rounds (under self.lock):
         # a round hands out n per position, and a consumer may still hold
         # the last round's while the next is written
-        self._outputs = _OutputPool(keep=2 * max(1, n_peers))
+        self._outputs = OutputPool(keep=2 * max(1, n_peers))
 
     def make_backends(self) -> list["LoopbackBackend"]:
         return [LoopbackBackend(self, f"peer-{i}") for i in range(self.n_peers)]
@@ -478,42 +478,6 @@ class LoopbackBackend(OuterBackend):
             self.world.live.discard(self._peer_id)
             self.world.progress.pop(self._peer_id, None)
             self.world.cond.notify_all()
-
-
-class _OutputPool:
-    """The rounds' output arrays, kept across rounds.
-
-    A round writes its mean, and each collector but the last its copy, into
-    a new array, and new pages are what that pass costs: on the TPU host a
-    copy of 1.45 GB takes 1.6 s into fresh memory and a twentieth of that
-    into memory the process has touched before (PERF.md, PR 25). So the
-    world keeps the arrays it has handed out and hands one out again once
-    nothing else refers to it: not its caller, not a view of it, not a
-    transfer still reading it -- each of those holds a reference, which is
-    what ``sys.getrefcount`` counts. An array somebody still holds is never
-    reused, so a result stays its caller's for as long as the caller keeps
-    it. Not thread-safe: callers hold the world's lock.
-    """
-
-    # references to a kept array that nobody else holds: the list's, the
-    # loop variable's in ``take``, and getrefcount's own argument
-    _FREE = 3
-
-    def __init__(self, keep: int):
-        self.keep = keep  # arrays remembered per position, shape and layout
-        self._arrays: dict[tuple, list[np.ndarray]] = {}
-
-    def take(self, i: int, like: np.ndarray) -> np.ndarray:
-        """A float32 array of ``like``'s shape and memory layout for the
-        ``i``-th array of a round, its contents undefined."""
-        kept = self._arrays.setdefault((i, like.shape, like.strides), [])
-        for a in kept:
-            if sys.getrefcount(a) == self._FREE:
-                return a
-        a = np.empty_like(like, dtype=np.float32)
-        kept.append(a)
-        del kept[: -self.keep]  # forget the oldest: its holder keeps it
-        return a
 
 
 class _Published:

@@ -70,24 +70,40 @@ class _BoundaryFetch(threading.Thread):
     """The boundary's device-to-host fetch on a thread of its own, so that it
     overlaps the straggler wait. It times itself: ``seconds`` is the fetch's
     own interval (the row's ``outer_d2h_s``), and with an armed tracer the
-    ``outer/d2h`` span is recorded from this thread as the fetch ends."""
+    ``outer/d2h`` span is recorded from this thread as the fetch ends.
+    ``stats`` (the device plane's ``last_fetch``) is asked, once the fetch
+    has ended, what it did: ``bytes`` and ``shards`` assembled on the host
+    and ``new_bytes``, those of them written into arrays allocated in this
+    round -- attributes of the span, and ``outer_d2h_new_bytes`` in the row."""
 
-    def __init__(self, tr, epoch: int, fetch):
+    def __init__(self, tr, epoch: int, fetch, stats=None):
         super().__init__(name="outer-d2h")
-        self._tr, self._epoch, self._fetch = tr, epoch, fetch
+        self._tr, self._epoch, self._fetch, self._stats = tr, epoch, fetch, stats
         self._result = self._error = None
         self.seconds = 0.0
+        self.stats: dict = {}
 
     def run(self) -> None:
         t0 = time.perf_counter()
         try:
             self._result = self._fetch()
+            if self._stats is not None:
+                self.stats = dict(self._stats())
         except BaseException as e:  # re-raised by wait(), in the caller
             self._error = e
         t1 = time.perf_counter()
         self.seconds = t1 - t0
         if self._tr is not None:
-            self._tr.add_span("outer/d2h", t0, t1, epoch=self._epoch)
+            self._tr.add_span(
+                "outer/d2h", t0, t1, epoch=self._epoch, **self.stats
+            )
+
+    def row(self) -> dict:
+        """The fetch's part of the optimizer's row."""
+        out = {"outer_d2h_s": self.seconds}
+        if "new_bytes" in self.stats:
+            out["outer_d2h_new_bytes"] = self.stats["new_bytes"]
+        return out
 
     def wait(self):
         """Join; -> what the fetch returned (its exception raised here)."""
@@ -859,7 +875,7 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
-            "outer_d2h_s": fetcher.seconds,
+            **fetcher.row(),
             "outer_wait_s": wait_s,
             "outer_overlapped": 1,
         }
@@ -903,6 +919,7 @@ class DiLoCoOptimizer:
         fetcher = _BoundaryFetch(
             tr, self.epoch,
             lambda: plane.pseudo_grad(device_leaves, keep_device=eager),
+            stats=lambda: plane.last_fetch,
         )
         fetcher.start()
         wait_for_peers(
@@ -957,7 +974,7 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
-            "outer_d2h_s": fetcher.seconds,
+            **fetcher.row(),
             "outer_wait_s": wait_s,
             "pseudo_grad_norm": pg_norm,
             "outer_overlapped": 1,
@@ -1452,6 +1469,7 @@ class DiLoCoOptimizer:
                 else [device_leaves[i] for i in frag],
                 frag,
             ),
+            stats=lambda: plane.last_fetch,
         )
         fetcher.start()
         if self.cfg.outer_mode != "gossip":
@@ -1473,7 +1491,7 @@ class DiLoCoOptimizer:
                 epoch=self.epoch,
             )
         pseudo_grad, pg_norm, _ = fetcher.wait()
-        d2h_s = fetcher.seconds
+        d2h_row = fetcher.row()
         if tr is not None:
             tr.gauge("pseudo_grad_norm", pg_norm)
         if self.cfg.outer_mode == "gossip":
@@ -1481,7 +1499,7 @@ class DiLoCoOptimizer:
             # land the mixed fragment back through the plane's donated jits
             return self._outer_step_device_gossip(
                 state, device_leaves, frag, pseudo_grad,
-                t0=t0, t0p=t0p, wait_s=wait_s, d2h_s=d2h_s,
+                t0=t0, t0p=t0p, wait_s=wait_s, d2h_row=d2h_row,
                 pg_norm=pg_norm,
             )
         if self._ef is not None:
@@ -1564,7 +1582,7 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
-            "outer_d2h_s": d2h_s,
+            **d2h_row,
             "outer_allreduce_s": allreduce_s,
             "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
@@ -1593,7 +1611,7 @@ class DiLoCoOptimizer:
         t0: float,
         t0p: float,
         wait_s: float,
-        d2h_s: float,
+        d2h_row: dict,
         pg_norm: float,
     ) -> tuple[dict, dict]:
         """Gossip tail of the blocking device-placement round: the pair
@@ -1677,7 +1695,7 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
-            "outer_d2h_s": d2h_s,
+            **d2h_row,
             "outer_allreduce_s": allreduce_s,
             "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
@@ -1944,7 +1962,7 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
-            "outer_d2h_s": fetcher.seconds,
+            **fetcher.row(),
             "outer_allreduce_s": allreduce_s,
             "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,

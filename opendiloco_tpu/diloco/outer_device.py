@@ -31,6 +31,7 @@ too, so a snapshot is always epoch-consistent.
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 import threading
 import time
@@ -42,6 +43,7 @@ import numpy as np
 
 from opendiloco_tpu import obs
 from opendiloco_tpu.diloco.compression import device_wire_dtype
+from opendiloco_tpu.diloco.hostpool import OutputPool
 
 
 def _sqsum(leaves):
@@ -207,7 +209,7 @@ def _stream_launch_fused(
     until the fragment's all-reduce lands (``stream_land``), which is what
     lets N fragment rounds be in flight at once without tearing the
     served master. Every output is freshly computed (no input
-    pass-through), so the comm thread can ``device_get`` the wire arrays
+    pass-through), so the comm thread can fetch the wire arrays
     lock-free while train steps keep donating the live params.
 
     eager:   returns (wire, delta, est_m) — delta = est_m - params is the
@@ -301,6 +303,83 @@ def _host_f32(x: np.ndarray) -> np.ndarray:
     return x if x.dtype == np.float32 else x.astype(np.float32)
 
 
+# shards in flight during a sharded fetch (transfer, then copy): on a
+# four-chip host two read 3.3 GB/s, four 3.7 and eight no more (PERF.md, PR 27)
+_FETCH_THREADS = 4
+
+
+def _is_assembled(x: jax.Array) -> bool:
+    """Whether ``x``'s host value has to be put together from shards: not a
+    fully replicated array, nor one that lives on a single device."""
+    return not x.is_fully_replicated and len(x.addressable_shards) > 1
+
+
+def _distinct_shards(x: jax.Array) -> list:
+    """``x``'s addressable shards, one for each distinct index: a mesh axis
+    that replicates repeats a shard on several devices, and one copy of it is
+    enough (the de-duplication ``jax.Array``'s own host value makes)."""
+    seen, out = set(), []
+    for shard in x.addressable_shards:
+        key = tuple((sl.start, sl.stop) for sl in shard.index)
+        if key not in seen:
+            seen.add(key)
+            out.append(shard)
+    return out
+
+
+def _copy_shard(whole: np.ndarray, shard) -> None:
+    """One shard's transfer (awaited here), then its copy into its slice."""
+    np.copyto(whole[shard.index], np.asarray(shard.data))
+
+
+def _fetch_sharded(
+    leaves: Sequence[jax.Array], keys: Sequence[int], pool: OutputPool, lock
+) -> tuple[list[np.ndarray], dict]:
+    """Host copies of jit outputs, bit for bit ``jax.device_get(leaves)``'s,
+    with the sharded ones assembled into arrays from ``pool`` (position
+    ``keys[j]`` for ``leaves[j]``) -> (arrays, what the fetch did).
+
+    ``device_get`` starts every shard's transfer at once and then assembles
+    each array in the calling thread, shard after shard, into an ``np.empty``
+    of its own. On a four-chip TPU host both cost: 32 transfers of 50-400 MB
+    in flight together arrive at 1.3 GB/s and four at a time at 3.7, and the
+    assembly writes a model's size of new pages in every round (PERF.md,
+    PR 27). Here a few threads each take one shard at a time -- its transfer,
+    then its copy into its slice of an array the pool has kept since an
+    earlier round, in the shard's own memory order (numpy lets go of the GIL
+    in both) -- so a few transfers are in flight and the copies hide behind
+    them. A leaf that is fully replicated or lives on one device has nothing
+    to assemble and goes through ``device_get`` as before. ``lock`` guards
+    the pool: held while an array is taken, not while shards arrive."""
+    parts = [_distinct_shards(x) if _is_assembled(x) else None for x in leaves]
+    rest = [j for j, shards in enumerate(parts) if shards is None]
+    for j in rest:
+        leaves[j].copy_to_host_async()
+    out: list = [None] * len(leaves)
+    copied = n_shards = new = 0
+    with concurrent.futures.ThreadPoolExecutor(_FETCH_THREADS) as workers:
+        copies = []
+        for j, (x, shards) in enumerate(zip(leaves, parts)):
+            if shards is None:
+                continue
+            # the first shard's host array says which memory order the
+            # device hands over; the whole gets the same, so that every
+            # shard's copy runs along both arrays' memory
+            first = np.asarray(shards[0].data)
+            with lock:
+                before = pool.new_bytes
+                out[j] = whole = pool.take(keys[j], first, x.shape, x.dtype)
+                new += pool.new_bytes - before
+            copied += whole.nbytes
+            n_shards += len(shards)
+            copies += [workers.submit(_copy_shard, whole, s) for s in shards]
+        for j, a in zip(rest, jax.device_get([leaves[j] for j in rest])):
+            out[j] = a
+        for c in copies:
+            c.result()
+    return out, {"bytes": copied, "shards": n_shards, "new_bytes": new}
+
+
 @functools.lru_cache(maxsize=None)
 def _device_put_copies() -> bool:
     """Whether ``device_put`` copies host numpy memory on this backend.
@@ -351,6 +430,13 @@ class DeviceOuterPlane:
         # fresh f32 device copies — the master never aliases live params
         self.masters: list[jax.Array] = _copy_fused(list(param_leaves))
         self.bufs: Optional[list[jax.Array]] = None
+        # the host arrays sharded pseudo-gradients are assembled into, kept
+        # from round to round (taken under self.lock); two a leaf, because
+        # an overlapped round's all-reduce still reads one at the next boundary
+        self._fetched = OutputPool(keep=2)
+        # what the last pseudo_grad's fetch did (``_fetch_sharded``'s stats);
+        # empty when no leaf was sharded and ``device_get`` did it all
+        self.last_fetch: dict = {}
 
     # -- helpers -----------------------------------------------------------
 
@@ -414,6 +500,20 @@ class DeviceOuterPlane:
 
     # -- boundary ops ------------------------------------------------------
 
+    def fetch(
+        self, leaves: Sequence[jax.Array], frag: Optional[list[int]] = None
+    ) -> tuple[list[np.ndarray], dict]:
+        """Host copies of jit outputs over this plane's leaves (``frag``'s,
+        or all), bit for bit ``jax.device_get``'s -> (arrays, what the fetch
+        did: ``_fetch_sharded``'s stats, empty when no leaf was sharded and
+        ``device_get`` did it all, to the letter). An assembled array is the
+        caller's for as long as it keeps it; dropped, it is written again by
+        a later fetch of the same leaf."""
+        if not any(_is_assembled(x) for x in leaves):
+            return jax.device_get(leaves), {}
+        keys = frag if frag is not None else range(len(leaves))
+        return _fetch_sharded(leaves, keys, self._fetched, self.lock)
+
     def pseudo_grad(
         self,
         param_leaves: Sequence[jax.Array],
@@ -442,13 +542,18 @@ class DeviceOuterPlane:
             else:
                 pg32, sq = _pg_f32(m, p)
                 wire = pg32
-            fetched = jax.device_get(wire)
+            assembled = [_is_assembled(x) for x in wire]
+            fetched, self.last_fetch = self.fetch(wire, frag)
         # the fetched views keep their device buffers alive, so no copy —
         # EXCEPT the eager f32 case, where ``wire`` IS the kept-on-device
         # pseudo-gradient that ``_estimate_fused`` will DONATE while the
-        # all-reduce thread is still reading the host views
+        # all-reduce thread is still reading the host views. An assembled
+        # array is the pool's and owns its memory in either case.
         aliased = keep_device and self._wire_dtype is None
-        host = [(_own(x) if aliased else _host_f32(x)) for x in fetched]
+        host = [
+            _own(x) if aliased and not pooled else _host_f32(x)
+            for x, pooled in zip(fetched, assembled)
+        ]
         norm = float(np.sqrt(float(sq)))
         return host, norm, (pg32 if keep_device else None)
 

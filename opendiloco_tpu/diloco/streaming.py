@@ -298,7 +298,7 @@ class StreamScheduler:
             try:
                 arrays = pg
                 if arrays is None:
-                    fetched = jax.device_get(wire)
+                    fetched, _ = opt._plane.fetch(wire, ef_rec["frag"])
                     arrays = [
                         x if x.dtype == np.float32 else x.astype(np.float32)
                         for x in fetched

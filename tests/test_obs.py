@@ -453,6 +453,8 @@ def test_capture_profile_holds_the_anchor_annotation(tmp_path):
 
 
 def _diloco_worker(tiny_cfg, placement, local_steps=2):
+    """``placement`` "device-sharded": the device plane under FULL_SHARD over
+    four devices, whose fetch assembles shards on the host."""
     import jax
 
     from opendiloco_tpu.config import DilocoConfig
@@ -462,7 +464,11 @@ def _diloco_worker(tiny_cfg, placement, local_steps=2):
 
     tc = TrainerConfig(lr=1e-3, warmup_steps=2, total_steps=200, precision="fp32",
                        remat=False)
-    trainer = InnerTrainer(tiny_cfg, tc, build_mesh("NO_SHARD", devices=jax.devices()[:1]))
+    if placement == "device-sharded":
+        placement, plan = "device", build_mesh("FULL_SHARD", devices=jax.devices()[:4])
+    else:
+        plan = build_mesh("NO_SHARD", devices=jax.devices()[:1])
+    trainer = InnerTrainer(tiny_cfg, tc, plan)
     state = trainer.init_state(jax.random.key(0))
     (backend,) = LoopbackWorld(1).make_backends()
     opt = DiLoCoOptimizer(
@@ -485,7 +491,7 @@ def _diloco_worker(tiny_cfg, placement, local_steps=2):
     return state, one_round
 
 
-@pytest.mark.parametrize("placement", ["device", "host"])
+@pytest.mark.parametrize("placement", ["device", "host", "device-sharded"])
 def test_arming_the_capture_compiles_nothing(tiny_cfg, placement):
     """One boundary with the capture off, ``start``, one boundary with it on:
     not one program goes to the compiler in between (the pseudo-gradient's
@@ -513,13 +519,17 @@ def test_arming_the_capture_compiles_nothing(tiny_cfg, placement):
     assert between == 0
     assert {"outer/d2h", "outer/allreduce", "outer/apply", "outer/step"} <= {
         s["name"] for s in cap.spans}
-    if placement == "device":
+    if placement != "host":
         # carried in every run now, traced or not
         assert row_off["pseudo_grad_norm"] > 0 and row_on["pseudo_grad_norm"] > 0
         assert _named(cap, "outer/h2d")
+    if placement == "device-sharded":
+        # the first round allocated the host arrays; a traced one writes
+        # into them again
+        assert row_off["outer_d2h_new_bytes"] > 0 == row_on["outer_d2h_new_bytes"]
 
 
-@pytest.mark.parametrize("placement", ["device", "host"])
+@pytest.mark.parametrize("placement", ["device", "host", "device-sharded"])
 def test_boundary_row_splits_the_step(tiny_cfg, placement):
     state, one_round = _diloco_worker(tiny_cfg, placement)
     obs.capture.start()
@@ -534,11 +544,18 @@ def test_boundary_row_splits_the_step(tiny_cfg, placement):
     assert d2h["t1"] - d2h["t0"] == pytest.approx(row["outer_d2h_s"], abs=1e-9)
     assert d2h["tid"] != step["tid"]
     assert step["t0"] <= d2h["t0"] and d2h["t1"] <= step["t1"]
-    if placement == "device":
+    if placement != "host":
         # the H2D of the average is cut out of the apply that holds it
         (h2d,) = _named(cap, "outer/h2d")
         (apply_,) = _named(cap, "outer/apply")
         assert apply_["t0"] <= h2d["t0"] and h2d["t1"] <= apply_["t1"]
+    if placement == "device-sharded":
+        # what the fetch assembled on the host: on the span and in the row
+        args = d2h["args"]
+        assert args["shards"] > 0 and args["bytes"] == args["new_bytes"] > 0
+        assert row["outer_d2h_new_bytes"] == args["new_bytes"]
+    else:  # nothing to assemble: jax.device_get, and nothing to report
+        assert "new_bytes" not in d2h["args"] and "outer_d2h_new_bytes" not in row
 
 
 def test_reduce_wait_is_waiting_and_reduce_is_the_mean():
