@@ -21,17 +21,26 @@ import dataclasses
 import functools
 import json
 import os
-from typing import Any, Literal, Optional, Union
+from typing import Any, Literal, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
 
+from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
+    cache_insert,
+    init_kv_cache,
+    prefix_copy,
+    spec_cache_insert,
+    step_writer,
+    suffix_insert,
+)
 from opendiloco_tpu.ops.attention import (
     decode_attention,
     spec_tail_attention,
     xla_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
+    W4_BLOCK,
     paged_decode_attention,
     spec_tail_attention_fused,
     w4_matmul,
@@ -178,10 +187,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
     return jax.tree.unflatten(treedef, out)
 
 
-# rematerialization policy accepted everywhere a `remat` argument appears:
-# False/"none" saves all activations; True/"full" checkpoints per layer;
-# "dots" saves MXU outputs and recomputes the elementwise chain;
-# "dots_all" additionally saves batched dots (more memory, less recompute)
+# the rematerialization policy accepted everywhere a `remat` argument
+# appears (``_maybe_remat`` says what each value saves)
 RematPolicy = Union[bool, Literal["none", "full", "dots", "dots_all"]]
 
 
@@ -254,12 +261,6 @@ def _rope_apply(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     s = sin.astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1)
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over [B, T, H, D] with HF half-rotation layout."""
-    cos, sin = _rope_tables(positions, x.shape[-1], theta)
-    return _rope_apply(x, cos, sin)
 
 
 def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
@@ -352,37 +353,80 @@ def _ffn(
     return out, jnp.float32(0.0), jnp.zeros((3,), jnp.int32)
 
 
-def _decoder_block(
+class BlockOut(NamedTuple):
+    """What one layer leaves beside the hidden state."""
+
+    k: jax.Array  # this layer's keys, rotated [B, T, Nkv, Dh]
+    v: jax.Array  # and values
+    attn_out: jax.Array  # the attention branch after o_proj [B, T, D]
+    aux: jax.Array  # the routed FFN's weighted aux loss (0 for a dense one)
+    counts: jax.Array  # the routed FFN's counts, int32 [3] (``_routed_ffn``)
+
+
+def decoder_block(
     cfg: LlamaConfig,
-    attn_fn,
     h: jax.Array,
     layer: dict,
-    positions: jax.Array,
-    rope: Optional[tuple[jax.Array, jax.Array]] = None,
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
-    """Returns (hidden, (attn-output L2 norm, moe aux loss)). The norm is
-    the activation probe the reference attaches via forward hooks on
-    ``self_attn`` (utils.py:43-67, train_fsdp.py:65)."""
-    B, T, D = h.shape
-    if rope is None:
-        rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    cos, sin = rope
-    mul = jnp.matmul
-
+    cos: jax.Array,
+    sin: jax.Array,
+    *,
+    mul,
+    attend,
+    live: Optional[jax.Array] = None,
+) -> tuple[jax.Array, BlockOut]:
+    """One decoder layer over h [B, T, D], the only statement of its
+    skeleton: RMSNorm, q/k/v, attention, o_proj, residual; RMSNorm, FFN,
+    residual. Training and the four serving forwards differ in what they
+    pass: ``mul(x, w)`` is the caller's weight matmul, ``attend(q, k, v)``
+    its attention over this layer's q [B, T, Nh, Dh] and new k, v (a cache
+    it reads or writes is the caller's own), ``live`` the tokens a routed
+    FFN counts."""
+    B, T, _ = h.shape
     # the scopes name the device work in a profiler trace (an operation's
     # op_name metadata); they change nothing that is computed
     with jax.named_scope("odtp_attention"):
         x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-        attn = attn_fn(q, k, v)
-        attn_out = attn.reshape(B, T, -1) @ layer["o_proj"]
-        attn_norm = jnp.sqrt(jnp.sum(attn_out.astype(jnp.float32) ** 2))
+        attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
         h = h + attn_out
-
     with jax.named_scope("odtp_mlp"):
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn, aux, _ = _ffn(cfg, x, layer, mul)
-        return h + ffn, (attn_norm, aux)
+        ffn, aux, counts = _ffn(cfg, x, layer, mul, live)
+    return h + ffn, BlockOut(k, v, attn_out, aux, counts)
+
+
+def training_block(
+    cfg: LlamaConfig, attn_fn, positions: jax.Array, remat: RematPolicy
+):
+    """The body of training's scan over layers, ``(h, layer) -> (h, (attn-
+    output L2 norm, moe aux loss))``, under the rematerialization policy.
+    The norm is the activation probe the reference attaches via forward
+    hooks on ``self_attn`` (utils.py:43-67, train_fsdp.py:65)."""
+    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+
+    def body(h, layer):
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attn_fn
+        )
+        with jax.named_scope("odtp_attention"):
+            attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
+        return h, (attn_norm, out.aux)
+
+    return _maybe_remat(body, remat)
+
+
+def _final_norm_and_head(
+    cfg: LlamaConfig, cparams: dict, h: jax.Array
+) -> tuple[jax.Array, jax.Array]:
+    """-> (h under the final RMSNorm, the head [D, V]: the embedding's
+    transpose where the configuration ties them)."""
+    h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
+    head = (
+        cparams["embed_tokens"].T
+        if cfg.tie_word_embeddings
+        else cparams["lm_head"]
+    )
+    return h, head
 
 
 def forward(
@@ -488,11 +532,7 @@ def forward(
         )
         attn_norms = jnp.zeros((cfg.num_hidden_layers,), jnp.float32)
     else:
-        rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-        block = lambda h, layer: _decoder_block(
-            cfg, attn_fn, h, layer, positions, rope
-        )
-        block = _maybe_remat(block, remat)
+        block = training_block(cfg, attn_fn, positions, remat)
         # Unroll the layer scan N-wide (N >= num layers removes the while
         # loop entirely). The trainer auto-resolves scan_unroll to FULL
         # unroll on TPU for dense stacks (measured +6.8% tok/s on the
@@ -508,12 +548,7 @@ def forward(
         )
         moe_aux = jnp.mean(layer_auxs)
 
-    h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
-    head = (
-        cparams["embed_tokens"].T
-        if cfg.tie_word_embeddings
-        else cparams["lm_head"]
-    )
+    h, head = _final_norm_and_head(cfg, cparams, h)
     if return_hidden:
         # composes with return_moe_aux so fused lm-head losses can thread
         # the router aux loss (trainer._loss_fn)
@@ -534,12 +569,10 @@ def forward(
 
 # ---------------------------------------------------------------------------
 # serving: prefill / incremental decode over a slot-paged ring KV cache
-# (opendiloco_tpu/serve). Every forward below shares ``_qkv`` and ``_ffn``
-# with the training block, so dense and routed-expert stacks both serve.
+# (opendiloco_tpu/serve; the cache is models/ring_cache.py). Each forward
+# below drives ``decoder_block``: it builds the attention over its cache and
+# scans the layers, so dense and routed-expert stacks both serve.
 # ---------------------------------------------------------------------------
-
-
-W4_BLOCK = 4096  # matches diloco.compression._BLOCK (pinned by tests)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -619,24 +652,18 @@ def _cast_serving_params(params, dtype):
     )
 
 
-def init_kv_cache(
-    cfg: LlamaConfig,
-    num_slots: int,
-    max_context: int,
-    dtype: jnp.dtype = jnp.bfloat16,
-) -> dict:
-    """Zeroed {"k","v"} cache pages [L, S, T, Nkv, Dh]: one fixed-size ring
-    page per batch slot (the degenerate paged layout — page size == slot
-    context). Writes wrap at T, so a sequence that outgrows its page keeps
-    decoding with sliding-window attention over the last T tokens."""
-    shape = (
-        cfg.num_hidden_layers,
-        num_slots,
-        max_context,
-        cfg.kv_heads,
-        cfg.head_dim,
-    )
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+def _serving_boundary(params, compute_dtype, decode_kernel):
+    """What the four serving forwards do first -> (the weights cast to the
+    compute dtype, their weight matmul ``mul(x, w)``)."""
+    cparams = _cast_serving_params(params, compute_dtype)
+    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
+    return cparams, mul
+
+
+def _logits(cfg: LlamaConfig, cparams: dict, h: jax.Array) -> jax.Array:
+    """Final norm and lm head over h [..., D] -> float32 logits [..., V]."""
+    h, head = _final_norm_and_head(cfg, cparams, h)
+    return (h @ head).astype(jnp.float32)
 
 
 def prefill_forward(
@@ -661,56 +688,24 @@ def prefill_forward(
     overwrites index ``len % T`` before index ``len`` becomes visible."""
     B, P = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
-    cparams = _cast_serving_params(params, compute_dtype)
+    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
     live = positions < length
+    attend = lambda q, k, v: xla_attention(q, k, v, causal=True)
 
-    def block(h, layer):
-        x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-        attn = xla_attention(q, k, v, causal=True)
-        h = h + mul(attn.reshape(B, P, -1), layer["o_proj"])
-        x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn, _, counts = _ffn(cfg, x, layer, mul, live)
-        return h + ffn, (k[0], v[0], counts)
+    def body(h, layer):
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
+        )
+        return h, (out.k[0], out.v[0], out.counts)
 
     h = jnp.take(cparams["embed_tokens"], input_ids, axis=0)
-    h, (ks, vs, counts) = jax.lax.scan(block, h, cparams["layers"])
+    h, (ks, vs, counts) = jax.lax.scan(body, h, cparams["layers"])
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
-    h_last = _rms_norm(h_last, cparams["final_norm"], cfg.rms_norm_eps)
-    head = (
-        cparams["embed_tokens"].T
-        if cfg.tie_word_embeddings
-        else cparams["lm_head"]
-    )
-    logits = (h_last @ head).astype(jnp.float32)
+    logits = _logits(cfg, cparams, h_last)
     if return_moe_counts:
         return logits[:, 0], ks, vs, jnp.sum(counts, axis=0)
     return logits[:, 0], ks, vs
-
-
-def cache_insert(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    ks: jax.Array,
-    vs: jax.Array,
-    slot: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """Write a prefilled sequence's K/V [L, P, Nkv, Dh] into ``slot``
-    (traced scalar) of the cache [L, S, T, Nkv, Dh] at ring positions
-    [0, P). Stale entries from a previous tenant beyond P stay masked
-    until decode's per-step ring write overwrites them."""
-    L, P = ks.shape[0], ks.shape[1]
-    if P > cache_k.shape[2]:
-        raise ValueError(
-            f"prefill length {P} exceeds slot context {cache_k.shape[2]}"
-        )
-    zero = jnp.int32(0)
-    start = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
-    ck = jax.lax.dynamic_update_slice(cache_k, ks[:, None].astype(cache_k.dtype), start)
-    cv = jax.lax.dynamic_update_slice(cache_v, vs[:, None].astype(cache_v.dtype), start)
-    return ck, cv
 
 
 def decode_forward(
@@ -729,52 +724,52 @@ def decode_forward(
 
     tokens [S] int32 are each slot's current input token; lens [S] int32
     are the token counts already cached (== the new token's absolute
-    position); cache_{k,v} are [L, S, T, Nkv, Dh]. Returns (logits [S, V]
-    f32, new_cache_k, new_cache_v): the new K/V is written at ring index
-    ``lens % T`` and attention covers the last ``min(lens + 1, T)``
-    positions. Callers jit this with the caches donated — the cache
-    update is in-place at HBM, never a fresh page copy. With
-    ``return_moe_counts`` the routed FFN's counts over the slots that hold
-    a sequence (``lens > 0``), summed over layers, come fourth."""
-    S = tokens.shape[0]
-    T = cache_k.shape[2]
-    cparams = _cast_serving_params(params, compute_dtype)
+    position); cache_{k,v} are the ring pages (``ring_cache``). Returns
+    (logits [S, V] f32, new_cache_k, new_cache_v): the new K/V is written
+    at ring index ``lens % T`` and attention covers the last
+    ``min(lens + 1, T)`` positions. Callers jit this with the caches
+    donated -- the cache update is in-place at HBM, never a fresh page
+    copy. With ``return_moe_counts`` the routed FFN's counts over the
+    slots that hold a sequence (``lens > 0``), summed over layers, come
+    fourth."""
+    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
     cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    rows = jnp.arange(S)
-    write_idx = jnp.mod(lens, T)
-    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
+    write = step_writer(cache_k, lens)
     live = lens > 0
 
-    def block(h, xs):
-        layer, ck, cv = xs  # ck/cv [S, T, Nkv, Dh]
-        x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-        ck = ck.at[rows, write_idx].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[rows, write_idx].set(v[:, 0].astype(cv.dtype))
-        if decode_kernel == "pallas":
-            attn = paged_decode_attention(q[:, 0], ck, cv, lens)
-        else:
-            attn = decode_attention(q[:, 0], ck, cv, lens)
-        h = h + mul(attn.reshape(S, 1, -1), layer["o_proj"])
-        x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn, _, counts = _ffn(cfg, x, layer, mul, live)
-        return h + ffn, (ck, cv, counts)
+    def body(h, xs):
+        layer, ck, cv = xs  # one layer's pages
+
+        def attend(q, k, v):
+            nonlocal ck, cv
+            ck, cv = write(ck, cv, k, v)
+            if decode_kernel == "pallas":
+                return paged_decode_attention(q[:, 0], ck, cv, lens)
+            return decode_attention(q[:, 0], ck, cv, lens)
+
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
+        )
+        return h, (ck, cv, out.counts)
 
     h = jnp.take(cparams["embed_tokens"], tokens, axis=0)[:, None]  # [S, 1, D]
     h, (new_ck, new_cv, counts) = jax.lax.scan(
-        block, h, (cparams["layers"], cache_k, cache_v)
+        body, h, (cparams["layers"], cache_k, cache_v)
     )
-    h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
-    head = (
-        cparams["embed_tokens"].T
-        if cfg.tie_word_embeddings
-        else cparams["lm_head"]
-    )
-    logits = (h @ head).astype(jnp.float32)
+    logits = _logits(cfg, cparams, h)
     if return_moe_counts:
         return logits[:, 0], new_ck, new_cv, jnp.sum(counts, axis=0)
     return logits[:, 0], new_ck, new_cv
+
+
+def _tail_attention(decode_kernel: str):
+    """Attention of tail queries over ring pages plus the tail's own K/V."""
+    return (
+        spec_tail_attention_fused
+        if decode_kernel == "pallas"
+        else spec_tail_attention
+    )
 
 
 def verify_forward(
@@ -792,47 +787,33 @@ def verify_forward(
 
     tail [S, K] int32 are K unverified tokens per slot (the current
     token followed by the draft's proposals) at absolute positions
-    ``lens + i``; cache_{k,v} [L, S, T, Nkv, Dh] hold the ring pages as
-    of BEFORE the tail. Returns (logits [S, K, V] f32, tail_ks, tail_vs
-    [L, S, K, Nkv, Dh]): one full-depth greedy logit row per tail
-    position, plus the tail's K/V — kept OUT of the ring here so
-    rejected tokens need no rollback; the engine inserts only the
-    accepted prefix via :func:`spec_cache_insert`.
+    ``lens + i``; cache_{k,v} hold the ring pages as of BEFORE the tail.
+    Returns (logits [S, K, V] f32, tail_ks, tail_vs [L, S, K, Nkv, Dh]):
+    one full-depth greedy logit row per tail position, plus the tail's
+    K/V -- kept OUT of the ring here so rejected tokens need no rollback;
+    the engine inserts only the accepted prefix via
+    :func:`spec_cache_insert`.
 
     Also the continued-prefill primitive for shared-prefix KV reuse
     (S = 1, tail = the suffix tokens, lens = the reused prefix length).
     """
     S, K = tail.shape
-    cparams = _cast_serving_params(params, compute_dtype)
+    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
     cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
+    tail_attention = _tail_attention(decode_kernel)
 
-    def block(h, xs):
-        layer, ck, cv = xs  # ck/cv [S, T, Nkv, Dh]
-        x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-        if decode_kernel == "pallas":
-            attn = spec_tail_attention_fused(q, ck, cv, k, v, lens)
-        else:
-            attn = spec_tail_attention(q, ck, cv, k, v, lens)
-        h = h + mul(attn.reshape(S, K, -1), layer["o_proj"])
-        x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn, _, _ = _ffn(cfg, x, layer, mul)
-        return h + ffn, (k, v)
+    def body(h, xs):
+        layer, ck, cv = xs  # one layer's pages
+        attend = lambda q, k, v: tail_attention(q, ck, cv, k, v, lens)
+        h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, attend=attend)
+        return h, (out.k, out.v)
 
     h = jnp.take(cparams["embed_tokens"], tail, axis=0)  # [S, K, D]
     h, (tail_ks, tail_vs) = jax.lax.scan(
-        block, h, (cparams["layers"], cache_k, cache_v)
+        body, h, (cparams["layers"], cache_k, cache_v)
     )
-    h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
-    head = (
-        cparams["embed_tokens"].T
-        if cfg.tie_word_embeddings
-        else cparams["lm_head"]
-    )
-    logits = (h @ head).astype(jnp.float32)
-    return logits, tail_ks, tail_vs
+    return _logits(cfg, cparams, h), tail_ks, tail_vs
 
 
 def draft_propose(
@@ -854,136 +835,45 @@ def draft_propose(
 
     The truncated stack's K/V for the proposed tail lives in registers
     (a [Ld, S, k, Nkv, Dh] buffer threaded between token steps), never
-    the ring — the draft is a heuristic and dirties nothing; exactness
+    the ring -- the draft is a heuristic and dirties nothing; exactness
     is the verify pass's job. Returns proposals [S, k_steps] int32.
     """
     S = tokens.shape[0]
-    L, _, T, Nkv, Dh = cache_k.shape
-    Ld = int(draft_layers)
+    L, Ld = cfg.num_hidden_layers, int(draft_layers)
     if not 1 <= Ld <= L:
         raise ValueError(f"draft_layers {Ld} outside [1, {L}]")
-    cparams = _cast_serving_params(params, compute_dtype)
+    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     dlayers = jax.tree.map(lambda x: x[:Ld], cparams["layers"])
     dck, dcv = cache_k[:Ld], cache_v[:Ld]
-    cd = compute_dtype
-    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
-    head = (
-        cparams["embed_tokens"].T
-        if cfg.tie_word_embeddings
-        else cparams["lm_head"]
-    )
+    tail_attention = _tail_attention(decode_kernel)
 
-    tkb = jnp.zeros((Ld, S, k_steps, Nkv, Dh), cd)
-    tvb = jnp.zeros((Ld, S, k_steps, Nkv, Dh), cd)
+    tail_shape = (Ld, S, k_steps, cfg.kv_heads, cfg.head_dim)
+    tkb = jnp.zeros(tail_shape, compute_dtype)
+    tvb = jnp.zeros(tail_shape, compute_dtype)
     cur = tokens
     proposals = []
     for i in range(k_steps):
         positions = (lens + jnp.int32(i))[:, None]  # [S, 1]
-        cos, sin = _rope_tables(positions, Dh, cfg.rope_theta)
+        cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
-        def block(h, xs, i=i, cos=cos, sin=sin):
+        def body(h, xs, i=i, cos=cos, sin=sin):
             layer, ck, cv, tk, tv = xs
-            x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-            q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-            tk = tk.at[:, i].set(k[:, 0])
-            tv = tv.at[:, i].set(v[:, 0])
-            if decode_kernel == "pallas":
-                attn = spec_tail_attention_fused(
-                    q, ck, cv, tk, tv, lens, q_start=i
-                )
-            else:
-                attn = spec_tail_attention(q, ck, cv, tk, tv, lens, q_start=i)
-            h = h + mul(attn.reshape(S, 1, -1), layer["o_proj"])
-            x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-            ffn, _, _ = _ffn(cfg, x, layer, mul)
-            return h + ffn, (tk, tv)
+
+            def attend(q, k, v):
+                nonlocal tk, tv
+                tk = tk.at[:, i].set(k[:, 0])
+                tv = tv.at[:, i].set(v[:, 0])
+                return tail_attention(q, ck, cv, tk, tv, lens, q_start=i)
+
+            h, _ = decoder_block(cfg, h, layer, cos, sin, mul=mul, attend=attend)
+            return h, (tk, tv)
 
         h = jnp.take(cparams["embed_tokens"], cur, axis=0)[:, None]  # [S, 1, D]
-        h, (tkb, tvb) = jax.lax.scan(block, h, (dlayers, dck, dcv, tkb, tvb))
-        h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
-        logits = (h @ head).astype(jnp.float32)
+        h, (tkb, tvb) = jax.lax.scan(body, h, (dlayers, dck, dcv, tkb, tvb))
+        logits = _logits(cfg, cparams, h)
         cur = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
         proposals.append(cur)
     return jnp.stack(proposals, axis=1)  # [S, k_steps]
-
-
-def spec_cache_insert(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tail_ks: jax.Array,
-    tail_vs: jax.Array,
-    lens: jax.Array,
-    accept: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """Positioned ring insert of the ACCEPTED tail prefix: per slot,
-    tail tokens i <= accept[s] land at ring index ``(lens + i) % T``;
-    rejected positions write their current cache value back (the
-    no-copy rollback — the ring simply never learns about them).
-    Requires K <= T so a tail never collides with itself."""
-    L, S, T, Nkv, Dh = cache_k.shape
-    K = tail_ks.shape[2]
-    if K > T:
-        raise ValueError(f"tail width {K} exceeds ring context {T}")
-    rows = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, K))
-    pos = jnp.mod(lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None], T)
-    keep = (jnp.arange(K, dtype=jnp.int32)[None] <= accept[:, None])[
-        None, :, :, None, None
-    ]
-    old_k = cache_k[:, rows, pos]  # [L, S, K, Nkv, Dh]
-    old_v = cache_v[:, rows, pos]
-    new_k = jnp.where(keep, tail_ks.astype(cache_k.dtype), old_k)
-    new_v = jnp.where(keep, tail_vs.astype(cache_v.dtype), old_v)
-    ck = cache_k.at[:, rows, pos].set(new_k)
-    cv = cache_v.at[:, rows, pos].set(new_v)
-    return ck, cv
-
-
-def prefix_copy(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    src: jax.Array,
-    dst: jax.Array,
-    plen: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """Ring-copy the first ``plen`` cache rows of slot ``src`` into slot
-    ``dst`` (shared-prefix KV reuse). Rows >= plen keep dst's previous
-    bytes — stale and masked, same as any slot reuse."""
-    T = cache_k.shape[2]
-    keep = (jnp.arange(T) < plen)[:, None, None]
-    src_k = jnp.take(cache_k, src, axis=1)
-    src_v = jnp.take(cache_v, src, axis=1)
-    dst_k = jnp.take(cache_k, dst, axis=1)
-    dst_v = jnp.take(cache_v, dst, axis=1)
-    ck = cache_k.at[:, dst].set(jnp.where(keep, src_k, dst_k))
-    cv = cache_v.at[:, dst].set(jnp.where(keep, src_v, dst_v))
-    return ck, cv
-
-
-def suffix_insert(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    ks: jax.Array,
-    vs: jax.Array,
-    slot: jax.Array,
-    start: jax.Array,
-    count: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """Write a continued prefill's suffix K/V [L, P', Nkv, Dh] into
-    ``slot`` at rows [start, start + count) — the positioned counterpart
-    of :func:`cache_insert` (a prompt always fits its page, so no ring
-    wrap here; padding rows beyond ``count`` are dropped)."""
-    L, S, T, Nkv, Dh = cache_k.shape
-    P = ks.shape[1]
-    page_k = jnp.take(cache_k, slot, axis=1)  # [L, T, Nkv, Dh]
-    page_v = jnp.take(cache_v, slot, axis=1)
-    disp = jnp.arange(T, dtype=jnp.int32) - jnp.asarray(start, jnp.int32)
-    valid = ((disp >= 0) & (disp < count))[:, None, None]
-    gidx = jnp.clip(disp, 0, P - 1)
-    page_k = jnp.where(valid, ks[:, gidx].astype(cache_k.dtype), page_k)
-    page_v = jnp.where(valid, vs[:, gidx].astype(cache_v.dtype), page_v)
-    ck = cache_k.at[:, slot].set(page_k)
-    cv = cache_v.at[:, slot].set(page_v)
-    return ck, cv
 
 
 def causal_lm_loss(

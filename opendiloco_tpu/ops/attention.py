@@ -18,14 +18,13 @@ arrays with grouped-query support (num_q_heads % num_kv_heads == 0).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 
 def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
-    """Broadcast KV heads up to the query head count (GQA)."""
+    """Broadcast KV heads up to the query head count (GQA), over
+    [batch | slot, rows, kv heads, head_dim]."""
     b, t, nkv, d = k.shape
     if nkv == num_q_heads:
         return k
@@ -64,26 +63,6 @@ def xla_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def ring_live_rows(cache_len: int, t: int) -> int:
-    """Physically live ring rows for a sequence of ``cache_len`` cached
-    tokens in a T-row page — the KV-tier page-transfer contract.
-
-    This is the host-side mirror of the lens masks below: before the page
-    wraps, rows [0, cache_len) hold the sequence (``idx <= lens`` exposes
-    exactly them plus the current step's write); once ``cache_len >= t``
-    the whole ring is live at positions ``pos % t`` (the ``lens >= t``
-    branch). A tier eviction therefore pages out exactly these rows and a
-    restore writes them back at row 0 — ring layout is preserved in both
-    regimes, so the decode/spec-tail masks (and the Pallas kernel's
-    dead-block clamp, which derives from the same ``lens``) are already
-    exact over a restored page: rows beyond the restored count belong to
-    a previous tenant and stay masked until the sequence's own writes
-    reach them, the same invariant slot reuse has always relied on."""
-    if cache_len < 0:
-        raise ValueError(f"cache_len must be >= 0, got {cache_len}")
-    return min(int(cache_len), int(t))
-
-
 def decode_attention(
     q: jax.Array,
     k: jax.Array,
@@ -99,7 +78,7 @@ def decode_attention(
     indices <= lens until the sequence outgrows the page, after which the
     whole ring is live (sliding-window attention over the last T tokens).
     The same mask covers tier-restored slots: a page-in rewrites exactly
-    :func:`ring_live_rows` rows at row 0, so validity is still fully
+    ``ring_cache.ring_live_rows`` rows at row 0, so validity is still fully
     determined by ``lens``.
 
     Math matches :func:`xla_attention` row-for-row — f32 scores/softmax,
@@ -156,10 +135,10 @@ def spec_tail_attention(
     kq = q.shape[1]
     kt = tail_k.shape[1]
     h = q.shape[2]
-    ck = _repeat_kv_slots(cache_k, h)
-    cv = _repeat_kv_slots(cache_v, h)
-    tk = _repeat_kv_slots(tail_k, h)
-    tv = _repeat_kv_slots(tail_v, h)
+    ck = _repeat_kv(cache_k, h)
+    cv = _repeat_kv(cache_v, h)
+    tk = _repeat_kv(tail_k, h)
+    tv = _repeat_kv(tail_v, h)
     scale = d**-0.5
 
     # ring scores [S, H, Kq, T]
@@ -192,38 +171,3 @@ def spec_tail_attention(
     out = jnp.einsum("shqt,sthd->sqhd", probs[..., :t], cv)
     out = out + jnp.einsum("shqk,skhd->sqhd", probs[..., t:], tv)
     return out
-
-
-def _repeat_kv_slots(k: jax.Array, num_q_heads: int) -> jax.Array:
-    """GQA broadcast for slot-major [S, T, Kh, D] cache layouts."""
-    s, t, nkv, d = k.shape
-    if nkv == num_q_heads:
-        return k
-    assert num_q_heads % nkv == 0, (num_q_heads, nkv)
-    rep = num_q_heads // nkv
-    return jnp.broadcast_to(k[:, :, :, None, :], (s, t, nkv, rep, d)).reshape(
-        s, t, num_q_heads, d
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("impl", "causal"))
-def attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    impl: str = "xla",
-    causal: bool = True,
-) -> jax.Array:
-    if impl == "xla":
-        return xla_attention(q, k, v, causal=causal)
-    if impl == "pallas":
-        from opendiloco_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=causal)
-    if impl == "ring":
-        raise ValueError(
-            "ring attention needs a mesh context; call "
-            "opendiloco_tpu.ops.ring_attention.ring_attention inside shard_map"
-        )
-    raise ValueError(f"unknown attention impl {impl!r}")

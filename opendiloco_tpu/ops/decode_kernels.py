@@ -1,24 +1,23 @@
 """Pallas TPU serving kernels: ragged paged decode attention, fused W4
 dequant-matmul, fused speculative verify.
 
-PR 11's decode speedups were algorithmic (speculation, 4-bit residency,
-prefix reuse); the ops underneath stayed stock XLA: ``decode_attention``
-dense-masks the whole ring page per slot, ``spec_tail_attention``
-materializes full repeat-KV score tensors, and ``PackedW4`` leaves
-dequantize to full f32 weight matrices at every matmul site. These
-kernels move the decode hot path onto the MXU the way the inner loop's
-flash kernel did (PagedAttention-style cache-aware decode, arXiv
-2309.06180), token-bit-exact against the XLA paths:
+The XLA paths they stand in for (``decode_attention`` dense-masks the
+whole ring page per slot, ``spec_tail_attention`` materializes full
+repeat-KV score tensors, ``PackedW4`` leaves dequantize to full f32 weight
+matrices at every matmul site) stay as the off-TPU path and the reference:
+every kernel is token-bit-exact against them (PagedAttention-style
+cache-aware decode, arXiv 2309.06180).
 
-- :func:`paged_decode_attention` reads the slot-paged ring KV cache
-  ``[S, T, Kh, D]`` directly. The per-slot ``lens`` vector rides the
-  grid as a scalar-prefetch operand, so each slot's dead ring blocks are
-  skipped (``pl.when``) AND their DMAs elided (the BlockSpec index map
-  clamps to the last live block, an unchanged index reuses the resident
-  tile — same trick as the flash kernel's causal skip). GQA is handled
-  by block geometry: grid position (slot, kv-head) loads exactly that kv
-  head's ``rep`` query rows, never a ``_repeat_kv`` materialization.
-  Online softmax in f32 matches ``decode_attention`` row-for-row.
+- :func:`paged_decode_attention` reads one layer's pages of the ring KV
+  cache through ``ring_cache.kernel_view``. The per-slot ``lens`` vector
+  rides the grid as a scalar-prefetch operand, so each slot's dead ring
+  blocks are skipped (``pl.when``) AND their DMAs elided (the BlockSpec
+  index map clamps to the last live block, an unchanged index reuses the
+  resident tile — same trick as the flash kernel's causal skip). GQA is
+  handled by block geometry: grid position (slot, kv-head) loads exactly
+  that kv head's ``rep`` query rows, never a ``_repeat_kv``
+  materialization. Online softmax in f32 matches ``decode_attention``
+  row-for-row.
 - :func:`w4_matmul` fuses the blockwise-4-bit dequant into the matmul:
   packed nibbles dequantize in-registers per ``[block_k, N]`` tile with
   bit-for-bit the ``native._dequant4_numpy`` element order and per-4096-
@@ -54,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from opendiloco_tpu.models.ring_cache import kernel_view
 from opendiloco_tpu.ops.attention import decode_attention, spec_tail_attention
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
@@ -175,12 +175,18 @@ def paged_decode_attention(
     return_stats: bool = False,
 ):
     """Drop-in :func:`~opendiloco_tpu.ops.attention.decode_attention`:
-    q [S, H, D] over ring pages k/v [S, T, Kh, D] with per-slot ``lens``.
+    q [S, H, D] over one layer's ring pages k/v with per-slot ``lens``.
 
     ``return_stats`` additionally returns the measured per-(slot, kv-head)
     count of ring blocks the kernel actually processed — the dead-block
     skip evidence banked by scripts/decode_kernel_bench.py."""
-    s_, t, nkv, d = k.shape
+    # Mosaic requires the last two dims of every block to be (8, 128)-
+    # aligned OR equal to the array's own dims. rep and the kv-head axis
+    # are tiny and never 8-aligned, so they must BE array dims: the kernel
+    # reads the pages as [S, Kh, T, D] ([bt, d] tiles; the cache module's
+    # view) and q as [S, Kh, rep, D] ([rep, d] tiles, rep == its array dim)
+    kt_, vt_ = kernel_view(k), kernel_view(v)
+    s_, nkv, t, d = kt_.shape
     h = q.shape[1]
     if d % 8 != 0 or h % nkv != 0:
         out = decode_attention(q, k, v, lens)
@@ -189,15 +195,6 @@ def paged_decode_attention(
     bt = _ring_block(t, block_t)
     num_t = t // bt
     interp = _interpret(interpret)
-
-    # Mosaic requires the last two dims of every block to be (8, 128)-
-    # aligned OR equal to the array's own dims. rep and the kv-head axis
-    # are tiny and never 8-aligned, so they must BE array dims: view the
-    # cache as [S, Kh, T, D] ([bt, d] tiles) and q as [S, Kh, rep, D]
-    # ([rep, d] tiles, rep == its array dim). Kernel ref shapes are
-    # identical to the untransposed layout — only the DMA geometry moves.
-    kt_ = k.transpose(0, 2, 1, 3)
-    vt_ = v.transpose(0, 2, 1, 3)
     q4 = q.reshape(s_, nkv, rep, d)
 
     def kv_map(si, hi, ti, lens_ref):
@@ -373,11 +370,20 @@ def spec_tail_attention_fused(
     return_stats: bool = False,
 ):
     """Drop-in :func:`~opendiloco_tpu.ops.attention.spec_tail_attention`:
-    q [S, Kq, H, D] over ring pages plus tail K/V [S, Kt, Kh, D], one
+    q [S, Kq, H, D] over one layer's ring pages plus the tail's K/V, one
     online-softmax pass, exact ring-wrap eviction semantics."""
-    s_, t, nkv, d = cache_k.shape
+    # same Mosaic tiling story as paged_decode_attention: kv-head and rep
+    # axes are tiny, so they must be array dims of their own -- pages and
+    # tail as [S, Kh, T|Kt, D] (the cache module's view), q (and the output)
+    # as [S, Kh, rep, Kq, D]. The head index r must be a LEADING dim of the
+    # q/o tiles: with 16-bit dtypes two rows share a sublane, and Mosaic
+    # refuses a per-head slice on the second-minor dim ("unsupported shape
+    # cast" for bf16 at D 64).
+    ckt, cvt = kernel_view(cache_k), kernel_view(cache_v)
+    tkt, tvt = kernel_view(tail_k), kernel_view(tail_v)
+    s_, nkv, t, d = ckt.shape
     kq, h = q.shape[1], q.shape[2]
-    kt = tail_k.shape[1]
+    kt = tkt.shape[2]
     if d % 8 != 0 or h % nkv != 0 or (h // nkv) * kq > _SPEC_MAX_GROUP_ROWS:
         out = spec_tail_attention(
             q, cache_k, cache_v, tail_k, tail_v, lens, q_start=q_start
@@ -387,17 +393,6 @@ def spec_tail_attention_fused(
     bt = _ring_block(t, block_t)
     num_t = t // bt
     interp = _interpret(interpret)
-
-    # same Mosaic tiling story as paged_decode_attention: kv-head and rep
-    # axes are tiny, so they must be array dims of their own — caches and
-    # tail as [S, Kh, T|Kt, D], q (and the output) as [S, Kh, rep, Kq, D].
-    # The head index r must be a LEADING dim of the q/o tiles: with 16-bit
-    # dtypes two rows share a sublane, and Mosaic refuses a per-head slice
-    # on the second-minor dim ("unsupported shape cast" for bf16 at D 64).
-    ckt = cache_k.transpose(0, 2, 1, 3)
-    cvt = cache_v.transpose(0, 2, 1, 3)
-    tkt = tail_k.transpose(0, 2, 1, 3)
-    tvt = tail_v.transpose(0, 2, 1, 3)
     q5 = q.reshape(s_, kq, nkv, rep, d).transpose(0, 2, 3, 1, 4)
 
     def kv_map(si, hi, ti, lens_ref):

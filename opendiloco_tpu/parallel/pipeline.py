@@ -26,14 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from opendiloco_tpu.models.llama import (
-    LlamaConfig,
-    _decoder_block,
-    _maybe_remat,
-    _rope_tables,
-    RematPolicy,
-)
-from opendiloco_tpu.ops.attention import xla_attention
+from opendiloco_tpu.models.llama import LlamaConfig, RematPolicy, training_block
 
 
 def pipeline_hidden(
@@ -44,7 +37,7 @@ def pipeline_hidden(
     mesh,
     *,
     microbatches: int,
-    attn_fn=None,
+    attn_fn,
     remat: RematPolicy = True,
     axis: str = "pp",
     sp_axis: str | None = None,
@@ -78,8 +71,6 @@ def pipeline_hidden(
     M = microbatches
     if B % M:
         raise ValueError(f"batch {B} not divisible by {M} microbatches")
-    if attn_fn is None:
-        attn_fn = lambda q, k, v: xla_attention(q, k, v, causal=True)
 
     hs = h0.reshape(M, B // M, T, D)
     mb_positions = positions.reshape(M, B // M, T)
@@ -105,11 +96,7 @@ def pipeline_hidden(
         perm = [(i, i + 1) for i in range(n - 1)]  # stage r -> r+1, no wrap
 
         def stage(x, pos):
-            rope = _rope_tables(pos, cfg.head_dim, cfg.rope_theta)
-            block = lambda h, layer: _decoder_block(
-                cfg, attn_fn, h, layer, pos, rope
-            )
-            block = _maybe_remat(block, remat)
+            block = training_block(cfg, attn_fn, pos, remat)
             y, (_, layer_auxs) = jax.lax.scan(block, x, layers_local)
             # keep the aux rank-1 everywhere in this region: it leaves
             # through a P(pp) out spec (see the export below)

@@ -57,16 +57,21 @@ from opendiloco_tpu.diloco.compression import (
 from opendiloco_tpu.models.llama import (
     LlamaConfig,
     PackedW4,
-    cache_insert,
     decode_forward,
     dequant_w4,
     draft_propose,
-    init_kv_cache,
     prefill_forward,
+    verify_forward,
+)
+from opendiloco_tpu.models.ring_cache import (
+    cache_insert,
+    fetch_pages,
+    init_kv_cache,
+    layer_pages,
     prefix_copy,
+    slot_cache,
     spec_cache_insert,
     suffix_insert,
-    verify_forward,
 )
 from opendiloco_tpu.ops.attention import decode_attention, spec_tail_attention
 from opendiloco_tpu.ops.decode_kernels import (
@@ -209,9 +214,6 @@ class ServeEngine:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return _with_counts(tok, counts), logits, ks, vs
 
-        def _insert(ck, cv, ks, vs, slot):
-            return cache_insert(ck, cv, ks, vs, slot)
-
         def _decode(p, tokens, lens, ck, cv):
             with jax.named_scope("odtp_serve_decode"):
                 logits, ck, cv, *counts = decode_forward(
@@ -221,9 +223,10 @@ class ServeEngine:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return _with_counts(tok, counts), logits, ck, cv
 
-        # one compile per prompt bucket; insert/decode compile once
+        # one compile per prompt bucket; insert/decode compile once. The
+        # insert also takes a slot's pages back from the host tier
         self._prefill = jax.jit(_prefill)
-        self._insert = jax.jit(_insert, donate_argnums=(0, 1))
+        self._insert = jax.jit(cache_insert, donate_argnums=(0, 1))
         self._decode = jax.jit(_decode, donate_argnums=(3, 4))
 
         # speculative-decode jits (compiled only when spec_step runs)
@@ -243,65 +246,33 @@ class ServeEngine:
             )
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), tks, tvs
 
-        def _spec_insert(ck, cv, tks, tvs, lens, accept):
-            return spec_cache_insert(ck, cv, tks, tvs, lens, accept)
-
         self._draft = jax.jit(_draft)
         self._verify = jax.jit(_verify)
-        self._spec_insert = jax.jit(_spec_insert, donate_argnums=(0, 1))
+        self._spec_insert = jax.jit(spec_cache_insert, donate_argnums=(0, 1))
         # host hook: tests swap in adversarial proposers; returns [S, k] np
         self.propose_fn: Callable[[np.ndarray, np.ndarray], np.ndarray] = (
             self._propose_draft
         )
 
         # shared-prefix reuse jits (compiled only when the batcher asks)
-        def _pcopy(ck, cv, src, dst, plen):
-            return prefix_copy(ck, cv, src, dst, plen)
-
         def _suffix(p, ck, cv, slot, tail, plen):
             # continued prefill = the verify primitive over the one slot's
-            # gathered page: tail tokens at positions plen..plen+B-1
-            page_k = jnp.take(ck, slot, axis=1)[:, None]  # [L, 1, T, Kh, Dh]
-            page_v = jnp.take(cv, slot, axis=1)[:, None]
+            # pages: tail tokens at positions plen..plen+B-1
             logits, tks, tvs = verify_forward(
-                p, tail, plen[None], page_k, page_v, cfg, compute_dtype=cd,
-                decode_kernel=dkn,
+                p, tail, plen[None], *slot_cache(ck, cv, slot), cfg,
+                compute_dtype=cd, decode_kernel=dkn,
             )
             return logits[0], tks[:, 0], tvs[:, 0]
 
-        def _suffix_ins(ck, cv, ks, vs, slot, start, count):
-            return suffix_insert(ck, cv, ks, vs, slot, start, count)
-
-        self._prefix_copy = jax.jit(_pcopy, donate_argnums=(0, 1))
+        self._prefix_copy = jax.jit(prefix_copy, donate_argnums=(0, 1))
         self._suffix = jax.jit(_suffix)
-        self._suffix_insert = jax.jit(_suffix_ins, donate_argnums=(0, 1))
+        self._suffix_insert = jax.jit(suffix_insert, donate_argnums=(0, 1))
 
-        # KV-tier page transfers (compiled only when tiering is on): one
-        # slot's ring pages gathered for D2H eviction / scattered back on
-        # H2D restore. ``rows`` is static — padded to the prefill-bucket
-        # grid by :meth:`page_rows` so the compile family stays bounded.
-        def _fetch_pages(ck, cv, slot, rows):
-            pk = jax.lax.dynamic_slice_in_dim(
-                jnp.take(ck, slot, axis=1), 0, rows, axis=1
-            )
-            pv = jax.lax.dynamic_slice_in_dim(
-                jnp.take(cv, slot, axis=1), 0, rows, axis=1
-            )
-            return pk, pv
-
-        def _install_pages(ck, cv, pk, pv, slot):
-            zero = jnp.int32(0)
-            start = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
-            ck = jax.lax.dynamic_update_slice(
-                ck, pk[:, None].astype(ck.dtype), start
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, pv[:, None].astype(cv.dtype), start
-            )
-            return ck, cv
-
-        self._fetch_pages = jax.jit(_fetch_pages, static_argnums=(3,))
-        self._install_pages = jax.jit(_install_pages, donate_argnums=(0, 1))
+        # KV-tier page-out (compiled only when tiering is on): one slot's
+        # ring rows gathered for D2H eviction; ``_insert`` is the way back.
+        # ``rows`` is static -- padded to the prefill-bucket grid by
+        # :meth:`page_rows` so the compile family stays bounded.
+        self._fetch_pages = jax.jit(fetch_pages, static_argnums=(3,))
 
     @property
     def device(self):
@@ -365,7 +336,7 @@ class ServeEngine:
         moe = {}  # a continued prefill's routing is not counted
         if host_prefix is not None and 0 < host_prefix[2] < n:
             hk, hv, plen = host_prefix
-            self.cache_k, self.cache_v = self._install_pages(
+            self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v,
                 jnp.asarray(hk, self.compute_dtype),
                 jnp.asarray(hv, self.compute_dtype),
@@ -446,7 +417,7 @@ class ServeEngine:
         up the prefill-bucket grid (bounded compile family; padding rows
         carry a previous tenant's masked entries, which restore rewrites
         verbatim — harmless by the same lens-mask invariant, see
-        ``ops.attention.ring_live_rows``)."""
+        ``ring_cache.ring_live_rows``)."""
         if not 0 < rows <= self.max_context:
             raise ValueError(
                 f"rows {rows} outside (0, {self.max_context}]"
@@ -455,7 +426,7 @@ class ServeEngine:
 
     def fetch_slot_pages(self, slot: int, rows: int) -> tuple:
         """Start an async D2H gather of ``slot``'s leading ``rows`` ring
-        rows. Returns device arrays ([L, rows', Nkv, Dh] each, rows'
+        rows. Returns device arrays (the slot's pages cut to rows', rows'
         bucket-padded) with a host copy already in flight — the caller
         materializes them with ``np.asarray`` on a LATER scheduler
         iteration so the transfer overlaps the next decode step instead
@@ -479,7 +450,7 @@ class ServeEngine:
         async — the next decode step queues behind it on-stream, so the
         scheduler thread never blocks on the transfer."""
         t0 = time.perf_counter()
-        self.cache_k, self.cache_v = self._install_pages(
+        self.cache_k, self.cache_v = self._insert(
             self.cache_k, self.cache_v,
             jnp.asarray(k, self.compute_dtype),
             jnp.asarray(v, self.compute_dtype),
@@ -538,7 +509,9 @@ class ServeEngine:
         slot s emits ``g[s, :m[s]+1]`` — its next m[s]+1 greedy tokens,
         token-identical to m[s]+1 plain decode_steps — and its cache now
         holds the tail rows 0..m[s] (rejected proposals were never
-        inserted; that IS the rollback)."""
+        inserted; that IS the rollback). The insert is still in flight at
+        return and off the TPU reads ``lens`` where the caller keeps it:
+        hand over arrays that are not written again."""
         if not self.spec_k:
             raise RuntimeError("spec_step requires spec_k > 0")
         t0 = time.perf_counter()
@@ -589,7 +562,7 @@ class ServeEngine:
         Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
         key = jax.random.PRNGKey(0)
         q1 = jax.random.normal(key, (S, Nh, Dh), cd)
-        ck, cv = self.cache_k[0], self.cache_v[0]  # live layer-0 ring pages
+        ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
         lens = jnp.full((S,), T // 2, jnp.int32)
         kq = self.tail_width
         qt = jax.random.normal(key, (S, kq, Nh, Dh), cd)

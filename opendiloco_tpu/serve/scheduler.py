@@ -22,8 +22,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from opendiloco_tpu import obs
+from opendiloco_tpu.models.ring_cache import ring_live_rows
 from opendiloco_tpu.obs import reqtrace
-from opendiloco_tpu.ops.attention import ring_live_rows
 from opendiloco_tpu.serve.engine import ServeEngine
 from opendiloco_tpu.serve.kvcache import (
     HostKVTier,

@@ -341,7 +341,7 @@ def _spec_probe(engine, prompt, n):
     cur = np.zeros(engine.num_slots, np.int32)
     lens[0], cur[0] = len(prompt), tok
     while len(toks) < n:
-        g, m = engine.spec_step(cur, lens)
+        g, m = engine.spec_step(cur.copy(), lens.copy())  # not written again
         emit = [int(t) for t in g[0, : int(m[0]) + 1]]
         toks.extend(emit)
         lens[0] += len(emit)

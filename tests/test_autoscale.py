@@ -9,6 +9,7 @@ so every tick is deterministic.
 from __future__ import annotations
 
 import threading
+import types
 
 import pytest
 
@@ -174,14 +175,27 @@ def test_queue_depth_alone_breaches(fleet):
     assert [d["action"] for d in a.evaluate()] == ["scale_up"]
 
 
-def test_cooldown_spaces_actions(fleet):
+def test_cooldown_spaces_actions(fleet, monkeypatch):
+    # the autoscaler's clock, injected: it measures the cooldown from
+    # ``time.monotonic()``, whose zero is about the host's boot, so on a
+    # machine up for less than ``cooldown_s`` the wall clock itself put the
+    # FIRST action inside the window and this test failed for an hour
+    from opendiloco_tpu.fleet import autoscaler
+
+    clock = [10 * 3600.0]
+    monkeypatch.setattr(
+        autoscaler, "time", types.SimpleNamespace(monotonic=lambda: clock[0])
+    )
     fleet.manager.attach("r0", "h", 1, "h", 2)
     a = fleet.scaler(cooldown_s=3600.0)
     _load(fleet, "r0", p99_ms=500.0)
     assert [d["action"] for d in a.evaluate()] == ["scale_up"]
-    for _ in range(5):  # still breaching, but inside the cooldown window
-        assert a.evaluate() == []
     assert _until(lambda: len(fleet.router.replicas) == 2)
+    for _ in range(5):  # still breaching, but inside the cooldown window
+        clock[0] += 600.0
+        assert a.evaluate() == []
+    clock[0] += 600.0  # 3600 s after the first action: the next one is due
+    assert [d["action"] for d in a.evaluate()] == ["scale_up"]
 
 
 def test_max_replicas_bounds_growth(fleet):

@@ -129,3 +129,71 @@ def test_remat_rejects_unknown_policy(tiny_cfg):
     ids = jnp.zeros((1, 16), jnp.int32)
     with pytest.raises(ValueError, match="remat"):
         forward(params, ids, tiny_cfg, remat="bogus")
+
+
+def _dense_model():
+    cfg = LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128, tie_word_embeddings=True,
+    )
+    return cfg, init_params(jax.random.key(3), cfg)
+
+
+def _routed_qk_norm_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "olmoe", "vocab_size": 256, "hidden_size": 64,
+        "intermediate_size": 32, "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_experts": 8, "num_experts_per_tok": 2, "max_position_embeddings": 128,
+    })
+    assert cfg.qk_norm
+    params = init_params(jax.random.key(4), cfg)
+    # a router that spreads its probabilities: no top-k choice near a tie
+    params["layers"]["router"] = params["layers"]["router"] * 25.0
+    return cfg, params
+
+
+@pytest.mark.parametrize("model", [_dense_model, _routed_qk_norm_model])
+def test_the_five_forwards_agree(model):
+    """One block under five drivers: in float32 the training forward, the
+    prefill, the decode step, the verify pass and the draft (at full depth)
+    give one another's logits and greedy tokens. A change to one driver's
+    layer that the others do not get fails here."""
+    from opendiloco_tpu.models.llama import (
+        cache_insert, decode_forward, draft_propose, init_kv_cache,
+        prefill_forward, verify_forward,
+    )
+
+    cfg, params = model()
+    f32 = dict(compute_dtype=jnp.float32)
+    full = lambda ids: forward(params, jnp.asarray([ids], jnp.int32), cfg, remat=False, **f32)[0]
+    close = lambda got, want: np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    prompt = np.random.default_rng(5).integers(3, 256, 9).tolist()
+    P, K = len(prompt), 3
+
+    # prefill (padded to a bucket of 16) = the forward's last prompt row
+    padded = jnp.asarray([prompt + [0] * (16 - P)], jnp.int32)
+    logits, ks, vs = prefill_forward(params, padded, jnp.int32(P), cfg, **f32)
+    close(logits[0], full(prompt)[P - 1])
+    tok = int(jnp.argmax(logits[0]))
+
+    # slot 1 of two holds the prompt; one decode step = a verify pass over a
+    # tail of one = the forward's next row
+    cache = init_kv_cache(cfg, 2, 32, jnp.float32)
+    ck, cv = cache_insert(cache["k"], cache["v"], ks, vs, jnp.int32(1))
+    tokens, lens = jnp.asarray([0, tok], jnp.int32), jnp.asarray([0, P], jnp.int32)
+    want = full(prompt + [tok])[P]
+    step, _, _ = decode_forward(params, tokens, lens, ck, cv, cfg, **f32)
+    close(step[1], want)
+    verified, _, _ = verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32)
+    close(verified[1, 0], want)
+
+    # the draft over all the layers proposes the forward's greedy tokens
+    proposed = draft_propose(
+        params, tokens, lens, ck, cv, cfg,
+        k_steps=K, draft_layers=cfg.num_hidden_layers, **f32,
+    )
+    seq = prompt + [tok]
+    for _ in range(K):
+        seq.append(int(jnp.argmax(full(seq)[-1])))
+    assert np.asarray(proposed[1]).tolist() == seq[-K:]

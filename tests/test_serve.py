@@ -504,7 +504,12 @@ def spec_generate(engine, prompt, n, slot=0):
     cur = np.zeros((S,), np.int32)
     lens[slot], cur[slot] = len(prompt), tok
     while len(toks) < n:
-        g, m = engine.spec_step(cur, lens)
+        # fresh arrays every round, as the scheduler hands them over: on the
+        # CPU backend ``jnp.asarray`` aliases a host array, and spec_step
+        # returns with its accepted-tail insert still in flight, so updating
+        # ``lens`` in place below moved that insert's ring rows under it
+        # (the whole of what ROADMAP called near-tie argmax flakes)
+        g, m = engine.spec_step(cur.copy(), lens.copy())
         take = int(m[slot]) + 1
         toks.extend(int(t) for t in g[slot, :take])
         lens[slot] += take
@@ -563,7 +568,7 @@ def test_spec_zero_acceptance_adversarial(tiny_cfg):
     cur = np.zeros((spec.num_slots,), np.int32)
     lens[0], cur[0] = len(prompt), tok
     while len(toks) < n:
-        g, m = spec.spec_step(cur, lens)
+        g, m = spec.spec_step(cur.copy(), lens.copy())  # see spec_generate
         assert int(m[0]) == 0  # nothing agreed; verify floor
         toks.append(int(g[0, 0]))
         count["emitted"] += 1

@@ -233,9 +233,14 @@ def test_rendezvous_failover_allreduce():
     primary = RendezvousServer(host="127.0.0.1", port=0).start_in_thread()
     secondary = RendezvousServer(host="127.0.0.1", port=0).start_in_thread()
     peers = [primary.address, secondary.address]
+    # the swarm's size is declared: the secondary's registry may not hold
+    # the second worker yet when the first fails over to it, and without
+    # ``expect_peers`` it then closes a round of one the instant "every
+    # peer it knows" has joined (an elastic round, correct and not what
+    # this test is about; it failed one run in three on that race)
     backends = [
         TcpBackend(peers, peer_id=f"worker-{i}", matchmaking_time=1.0,
-                   rpc_timeout=5.0)
+                   rpc_timeout=5.0, expect_peers=2)
         for i in range(2)
     ]
     try:
