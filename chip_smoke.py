@@ -513,13 +513,16 @@ def phase_compare_decode_kernels(model, seq, devices, *, seed, slots=8, tail=5):
         init_params,
         verify_forward,
     )
+    from opendiloco_tpu.models.ring_cache import cache_shape
 
     cfg, _ = hf_io.get_model(model)
     facts: dict = {"tolerance": {"logits_rel_l2": LOGITS_REL_L2}}
     with jax.default_device(devices[0]):
         kp, kk, kv, kt = jax.random.split(jax.random.key(seed), 4)
         params = init_params(kp, cfg)
-        shape = (cfg.num_hidden_layers, slots, seq, cfg.kv_heads, cfg.head_dim)
+        shape = cache_shape(
+            cfg.num_hidden_layers, slots, seq, cfg.kv_heads, cfg.head_dim
+        )
         # ragged around half full, one empty slot
         lens = jnp.asarray(
             [0] + [seq // 2 + 7 * i for i in range(1, slots)], jnp.int32

@@ -31,11 +31,10 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     init_kv_cache,
     prefix_copy,
     spec_cache_insert,
-    step_writer,
     suffix_insert,
 )
 from opendiloco_tpu.ops.attention import (
-    decode_attention,
+    decode_step_attention,
     spec_tail_attention,
     xla_attention,
 )
@@ -727,35 +726,48 @@ def decode_forward(
     position); cache_{k,v} are the ring pages (``ring_cache``). Returns
     (logits [S, V] f32, new_cache_k, new_cache_v): the new K/V is written
     at ring index ``lens % T`` and attention covers the last
-    ``min(lens + 1, T)`` positions. Callers jit this with the caches
-    donated -- the cache update is in-place at HBM, never a fresh page
-    copy. With ``return_moe_counts`` the routed FFN's counts over the
-    slots that hold a sequence (``lens > 0``), summed over layers, come
-    fourth."""
+    ``min(lens + 1, T)`` positions. The scan over the layers *carries* the
+    caches, and on the Pallas path each layer's attention call reads its
+    pages from the whole cache and writes the step's row into it through
+    aliased outputs: jitted with the caches donated, the step reads each
+    live row once and writes one row a slot, layer and KV head into the
+    buffers the engine holds, and nothing of a layer's size is sliced,
+    re-laid-out or copied (pinned at the cells' shapes by
+    tests/test_tpu_compile.py). The XLA path (off the TPU, and the per-call
+    fallback for a shape the kernel cannot tile) scatters the row and
+    slices the layer's pages, with copies where the compiler wants them.
+    With ``return_moe_counts`` the routed FFN's counts over the slots that
+    hold a sequence (``lens > 0``), summed over layers, come fourth."""
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
     cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    write = step_writer(cache_k, lens)
     live = lens > 0
+    step_attention = (
+        paged_decode_attention
+        if decode_kernel == "pallas"
+        else decode_step_attention
+    )
 
-    def body(h, xs):
-        layer, ck, cv = xs  # one layer's pages
+    def body(carry, xs):
+        h, ck, cv = carry  # the whole caches
+        layer, li = xs
 
         def attend(q, k, v):
             nonlocal ck, cv
-            ck, cv = write(ck, cv, k, v)
-            if decode_kernel == "pallas":
-                return paged_decode_attention(q[:, 0], ck, cv, lens)
-            return decode_attention(q[:, 0], ck, cv, lens)
+            out, ck, cv = step_attention(
+                q[:, 0], k[:, 0], v[:, 0], ck, cv, lens, li
+            )
+            return out
 
         h, out = decoder_block(
             cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
         )
-        return h, (ck, cv, out.counts)
+        return (h, ck, cv), out.counts
 
     h = jnp.take(cparams["embed_tokens"], tokens, axis=0)[:, None]  # [S, 1, D]
-    h, (new_ck, new_cv, counts) = jax.lax.scan(
-        body, h, (cparams["layers"], cache_k, cache_v)
+    layer_ids = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
+    (h, new_ck, new_cv), counts = jax.lax.scan(
+        body, (h, cache_k, cache_v), (cparams["layers"], layer_ids)
     )
     logits = _logits(cfg, cparams, h)
     if return_moe_counts:

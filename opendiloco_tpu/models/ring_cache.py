@@ -1,14 +1,23 @@
 """The serving KV cache: slot-paged ring pages, and everything that knows
 how they are stored.
 
-Storage is a ``k`` and a ``v`` array of ``[L, S, T, Nkv, Dh]``: one
+Storage is a ``k`` and a ``v`` array of ``[L, S, Nkv, Dh, T]``: one
 fixed-size ring page of T rows per layer and batch slot (the degenerate
-paged layout -- page size == slot context). Two facts live here and nowhere
-else:
+paged layout -- page size == slot context), **rows minor-most**. That is
+the order the chip keeps whatever the program says (a head of 64 is under
+the 128 lanes, so the device puts T minor anyway and every reader that
+wanted another order paid a copy of the layer's pages) and the order the
+decode kernel's ``(Dh, block_t)`` tiles are cut from, so the decode step
+reads the pages where the engine holds them and writes one row into them.
+Two facts live here and nowhere else:
 
-- **the order of those axes.** The forwards scan the leading layer axis and
-  hand one layer's pages ``[S, T, Nkv, Dh]`` to the functions below; the
-  engine and the kernel wrappers hold pages without indexing them.
+- **the order of those axes.** Everything outside this module exchanges
+  K/V as rows, ``[L, rows, Nkv, Dh]`` (a prefill's output, a page on the
+  host tier, a verify pass's tail); the functions below convert at the
+  module's edge (a prompt's K/V is megabytes, the cache gigabytes). The
+  forwards hand one layer's pages ``[S, Nkv, Dh, T]`` to the attention
+  readers (:func:`layer_pages`, or a scan over the leading axis); the
+  engine and the scheduler hold pages without indexing them.
 - **the ring arithmetic.** Token ``p`` of a sequence lives at row ``p % T``.
   Until a sequence outgrows its page, rows ``[0, len)`` hold it and rows
   beyond are a previous tenant's: stale, and masked by every reader
@@ -34,9 +43,36 @@ def init_kv_cache(
 ) -> dict:
     """Zeroed {"k","v"} pages for ``cfg`` (its layers, KV heads and head
     size): ``num_slots`` rings of ``max_context`` rows a layer."""
-    L, Nkv, Dh = cfg.num_hidden_layers, cfg.kv_heads, cfg.head_dim
-    shape = (L, num_slots, max_context, Nkv, Dh)
+    shape = cache_shape(
+        cfg.num_hidden_layers, num_slots, max_context, cfg.kv_heads, cfg.head_dim
+    )
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def cache_shape(
+    layers: int, slots: int, rows: int, kv_heads: int, head_dim: int
+) -> tuple[int, ...]:
+    """The shape of ``k`` (and of ``v``) in storage order, for callers that
+    describe a cache without allocating one (compile tests, benches)."""
+    return (layers, slots, kv_heads, head_dim, rows)
+
+
+def ring_rows(cache: jax.Array) -> int:
+    """T, the rows of one ring page (of a cache, or of one layer's pages)."""
+    return cache.shape[-1]
+
+
+def _rows_minor(x: jax.Array) -> jax.Array:
+    """K/V as the module's callers exchange it, [..., rows, Nkv, Dh], in
+    storage order [..., Nkv, Dh, rows]."""
+    return jnp.moveaxis(x, -3, -1)
+
+
+def rows_first(pages: jax.Array) -> jax.Array:
+    """Pages in storage order [..., Nkv, Dh, rows] as rows [..., rows, Nkv,
+    Dh]: the exchange format, and what the XLA attention references read (a
+    copy of what it is given)."""
+    return jnp.moveaxis(pages, -1, -3)
 
 
 def cache_insert(
@@ -50,34 +86,39 @@ def cache_insert(
     at ring rows [0, P): a prefilled prompt, or one slot's pages coming back
     from the host tier (:func:`fetch_pages` is the way out). Rows beyond P
     keep the previous tenant's bytes, stale and masked."""
-    P, T = ks.shape[1], cache_k.shape[2]
+    P, T = ks.shape[1], ring_rows(cache_k)
     if P > T:
         raise ValueError(f"prefill length {P} exceeds slot context {T}")
     zero = jnp.int32(0)
     start = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
 
     def put(cache, x):
-        x = x[:, None].astype(cache.dtype)
+        x = _rows_minor(x)[:, None].astype(cache.dtype)  # [L, 1, Nkv, Dh, P]
         return jax.lax.dynamic_update_slice(cache, x, start)
 
     return put(cache_k, ks), put(cache_v, vs)
 
 
-def step_writer(cache_k: jax.Array, lens: jax.Array):
-    """The decode step's row write, for a scan over layers: ``write(pages_k,
-    pages_v, k, v)`` puts each slot's new K/V (k, v [S, 1, Nkv, Dh]) at ring
-    row ``lens % T`` of one layer's pages and returns them. The row index is
-    computed here, once, outside the scan."""
-    S, T = cache_k.shape[1], cache_k.shape[2]
-    rows = jnp.arange(S)
-    write_idx = jnp.mod(lens, T)
+def write_row(
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    layer: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    lens: jax.Array,
+) -> tuple[jax.Array, jax.Array]:
+    """The decode step's row write as XLA does it: each slot's new K/V (k, v
+    [S, Nkv, Dh]) lands at ring row ``lens % T`` of ``layer``'s pages. The
+    reference of the decode kernel's in-place write and the path off the
+    TPU; on the chip a scatter into rows-minor pages re-lays the whole cache
+    (ISSUE 29), which is why the kernel writes the row itself."""
+    rows = jnp.arange(cache_k.shape[1])
+    idx = jnp.mod(lens, ring_rows(cache_k))
 
-    def write(pages_k, pages_v, k, v):
-        pages_k = pages_k.at[rows, write_idx].set(k[:, 0].astype(pages_k.dtype))
-        pages_v = pages_v.at[rows, write_idx].set(v[:, 0].astype(pages_v.dtype))
-        return pages_k, pages_v
+    def put(cache, x):  # cache[layer, rows, :, :, idx] is [S, Nkv, Dh]
+        return cache.at[layer, rows, :, :, idx].set(x.astype(cache.dtype))
 
-    return write
+    return put(cache_k, k), put(cache_v, v)
 
 
 def spec_cache_insert(
@@ -93,18 +134,19 @@ def spec_cache_insert(
     row ``(lens + i) % T``; rejected positions write their current cache
     value back, so rejected tail tokens never reach the ring. Requires
     K <= T so a tail never collides with itself."""
-    S, T, K = cache_k.shape[1], cache_k.shape[2], tail_ks.shape[2]
+    S, T, K = cache_k.shape[1], ring_rows(cache_k), tail_ks.shape[2]
     if K > T:
         raise ValueError(f"tail width {K} exceeds ring context {T}")
     rows = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, K))
     pos = jnp.mod(lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None], T)
     keep = (jnp.arange(K, dtype=jnp.int32)[None] <= accept[:, None])[
-        None, :, :, None, None
+        :, :, None, None, None
     ]
 
-    def put(cache, tail):  # cache[:, rows, pos] is [L, S, K, Nkv, Dh]
-        new = jnp.where(keep, tail.astype(cache.dtype), cache[:, rows, pos])
-        return cache.at[:, rows, pos].set(new)
+    def put(cache, tail):  # cache[:, rows, :, :, pos] is [S, K, L, Nkv, Dh]
+        tail = jnp.moveaxis(tail, 0, 2).astype(cache.dtype)
+        new = jnp.where(keep, tail, cache[:, rows, :, :, pos])
+        return cache.at[:, rows, :, :, pos].set(new)
 
     return put(cache_k, tail_ks), put(cache_v, tail_vs)
 
@@ -119,7 +161,7 @@ def prefix_copy(
     """Copy the first ``plen`` rows of slot ``src`` into slot ``dst``
     (shared-prefix KV reuse). Rows >= plen keep dst's previous bytes --
     stale and masked, same as any slot reuse."""
-    keep = (jnp.arange(cache_k.shape[2]) < plen)[:, None, None]
+    keep = jnp.arange(ring_rows(cache_k)) < plen
 
     def copy(cache):
         page = jnp.where(
@@ -143,15 +185,15 @@ def suffix_insert(
     at rows [start, start + count) -- the positioned counterpart of
     :func:`cache_insert` (a prompt always fits its page, so no ring wrap
     here; padding rows beyond ``count`` are dropped)."""
-    T, P = cache_k.shape[2], ks.shape[1]
+    T, P = ring_rows(cache_k), ks.shape[1]
     disp = jnp.arange(T, dtype=jnp.int32) - jnp.asarray(start, jnp.int32)
-    valid = ((disp >= 0) & (disp < count))[:, None, None]
+    valid = (disp >= 0) & (disp < count)
     gidx = jnp.clip(disp, 0, P - 1)
 
     def put(cache, x):
-        page = jnp.take(cache, slot, axis=1)  # [L, T, Nkv, Dh]
-        page = jnp.where(valid, x[:, gidx].astype(cache.dtype), page)
-        return cache.at[:, slot].set(page)
+        page = jnp.take(cache, slot, axis=1)  # [L, Nkv, Dh, T]
+        x = _rows_minor(x[:, gidx]).astype(cache.dtype)
+        return cache.at[:, slot].set(jnp.where(valid, x, page))
 
     return put(cache_k, ks), put(cache_v, vs)
 
@@ -159,7 +201,7 @@ def suffix_insert(
 def slot_cache(
     cache_k: jax.Array, cache_v: jax.Array, slot: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    """One slot's pages as a cache of a single slot, [L, 1, T, Nkv, Dh] each:
+    """One slot's pages as a cache of a single slot, [L, 1, Nkv, Dh, T] each:
     what a forward over that slot alone (the continued prefill) reads."""
     return (
         jnp.take(cache_k, slot, axis=1)[:, None],
@@ -174,16 +216,17 @@ def fetch_pages(
     each, by value: the host tier's page-out. :func:`cache_insert` takes
     them back."""
     def cut(cache):
-        page = jnp.take(cache, slot, axis=1)
-        return jax.lax.dynamic_slice_in_dim(page, 0, rows, axis=1)
+        page = jnp.take(cache, slot, axis=1)  # [L, Nkv, Dh, T]
+        return rows_first(jax.lax.slice_in_dim(page, 0, rows, axis=-1))
 
     return cut(cache_k), cut(cache_v)
 
 
 def layer_pages(
-    cache_k: jax.Array, cache_v: jax.Array, layer: int
+    cache_k: jax.Array, cache_v: jax.Array, layer
 ) -> tuple[jax.Array, jax.Array]:
-    """One layer's pages, as a scan over the caches hands them out."""
+    """One layer's pages [S, Nkv, Dh, T], as a scan over the caches hands
+    them out; ``layer`` may be traced (a copy of the layer's pages then)."""
     return cache_k[layer], cache_v[layer]
 
 
@@ -197,11 +240,3 @@ def ring_live_rows(cache_len: int, t: int) -> int:
     if cache_len < 0:
         raise ValueError(f"cache_len must be >= 0, got {cache_len}")
     return min(int(cache_len), int(t))
-
-
-def kernel_view(pages: jax.Array) -> jax.Array:
-    """One layer's pages [S, T, Nkv, Dh] (or a tail's K/V [S, K, Nkv, Dh])
-    as the decode kernels read them: [S, Nkv, T, Dh], so that a (rows, Dh)
-    tile's two minor dimensions are array dimensions of their own. A copy of
-    the layer's pages while the storage keeps rows before heads."""
-    return pages.transpose(0, 2, 1, 3)

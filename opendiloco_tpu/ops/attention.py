@@ -21,6 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from opendiloco_tpu.models.ring_cache import layer_pages, rows_first, write_row
+
 
 def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
     """Broadcast KV heads up to the query head count (GQA), over
@@ -71,8 +73,10 @@ def decode_attention(
 ) -> jax.Array:
     """Single-token decode attention over a slot-paged ring KV cache.
 
-    q [S, H, D] is the current token per slot; k/v [S, T, Kh, D] are the
-    cache pages; lens [S] int32 is each slot's token count BEFORE this
+    q [S, H, D] is the current token per slot; k/v are one layer's cache
+    pages as ``ring_cache`` stores them ([S, Kh, D, T], read here as rows
+    through the module: a copy, this is the reference and the path off the
+    TPU); lens [S] int32 is each slot's token count BEFORE this
     step (== the current token's absolute position; its K/V has already
     been written at ring index ``lens % T``). Valid cache entries are
     indices <= lens until the sequence outgrows the page, after which the
@@ -85,6 +89,7 @@ def decode_attention(
     probabilities cast back to q.dtype — so incremental decode reproduces
     the training-mode forward (pinned by tests/test_serve.py).
     """
+    k, v = rows_first(k), rows_first(v)  # [S, T, Kh, D]
     s, t, nkv, d = k.shape
     h = q.shape[1]
     k = _repeat_kv(k, h)
@@ -97,6 +102,25 @@ def decode_attention(
     scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("sht,sthd->shd", probs, v)
+
+
+def decode_step_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    lens: jax.Array,
+    layer,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One layer's share of a decode step in XLA: the step's rows (k, v [S,
+    Kh, D]) written at ring row ``lens % T`` of ``layer``'s pages, then
+    :func:`decode_attention` over them -> (out, cache_k, cache_v). The
+    reference of ``decode_kernels.paged_decode_attention``, which has this
+    signature, and its per-call fallback."""
+    cache_k, cache_v = write_row(cache_k, cache_v, layer, k, v, lens)
+    out = decode_attention(q, *layer_pages(cache_k, cache_v, layer), lens)
+    return out, cache_k, cache_v
 
 
 def spec_tail_attention(
@@ -113,8 +137,9 @@ def spec_tail_attention(
     tail K/V — the verify/draft primitive for speculative decode.
 
     q [S, Kq, H, D] are unverified tail tokens per slot at absolute
-    positions ``lens + q_start + i``; cache_{k,v} [S, T, Kh, D] hold the
-    ring pages as of BEFORE the tail (positions <= lens - 1); tail_{k,v}
+    positions ``lens + q_start + i``; cache_{k,v} hold one layer's ring
+    pages ([S, Kh, D, T], ``ring_cache``'s order, read as rows through the
+    module) as of BEFORE the tail (positions <= lens - 1); tail_{k,v}
     [S, K, Kh, D] are the tail's own K/V, kept out of the ring until
     acceptance. ``q_start`` offsets the queries within the tail (the
     draft proposes one token at a time against a growing tail buffer;
@@ -131,6 +156,7 @@ def spec_tail_attention(
     live reductions (same invariant the prefill bucket-padding relies
     on).
     """
+    cache_k, cache_v = rows_first(cache_k), rows_first(cache_v)
     s, t, nkv, d = cache_k.shape
     kq = q.shape[1]
     kt = tail_k.shape[1]

@@ -8,12 +8,20 @@ matrices at every matmul site) stay as the off-TPU path and the reference:
 every kernel is token-bit-exact against them (PagedAttention-style
 cache-aware decode, arXiv 2309.06180).
 
-- :func:`paged_decode_attention` reads one layer's pages of the ring KV
-  cache through ``ring_cache.kernel_view``. The per-slot ``lens`` vector
-  rides the grid as a scalar-prefetch operand, so each slot's dead ring
-  blocks are skipped (``pl.when``) AND their DMAs elided (the BlockSpec
-  index map clamps to the last live block, an unchanged index reuses the
-  resident tile — same trick as the flash kernel's causal skip). GQA is
+- :func:`paged_decode_attention` is the decode step's whole traffic with
+  the ring KV cache. It is handed the cache as the engine holds it
+  (``ring_cache``: ``[L, S, Nkv, Dh, T]``, rows minor-most) and cuts its
+  ``(Dh, block_t)`` tiles straight from it, the layer index and the
+  per-slot ``lens`` vector riding the grid as scalar-prefetch operands:
+  nothing of a layer's size is sliced, transposed or copied on the way in.
+  Each slot's dead ring blocks are skipped (``pl.when``) AND their DMAs
+  elided (the BlockSpec index map clamps to the last live block, an
+  unchanged index reuses the resident tile — same trick as the flash
+  kernel's causal skip). The step's new K/V row is written by the same
+  call: the block that holds ring row ``lens % T`` is always one the slot
+  reads, so the kernel patches the row into the tile it has in VMEM and
+  hands that one tile back through an output aliased to the cache (an XLA
+  scatter into rows-minor pages re-lays the whole cache, ISSUE 29). GQA is
   handled by block geometry: grid position (slot, kv-head) loads exactly
   that kv head's ``rep`` query rows, never a ``_repeat_kv``
   materialization. Online softmax in f32 matches ``decode_attention``
@@ -38,9 +46,10 @@ backend is TPU; off-TPU it always resolves to the XLA paths, so CPU rigs
 keep today's exact code. Forcing ``pallas`` off-TPU runs the kernels in
 Pallas interpret mode (slow, but semantically the kernel) — that is how
 the parity tests pin token-bit-exactness on a CPU rig. Shapes a kernel
-cannot tile (head_dim not a multiple of 8, odd N, a verify tail too tall
-for VMEM) fall back to the XLA path per call, mirroring
-``flash_attention``'s fallback contract.
+cannot tile (head_dim not a multiple of 8, a ring whose rows are not a
+multiple of the 128 lanes, odd N, a verify tail too tall for VMEM) fall
+back to the XLA path per call, mirroring ``flash_attention``'s fallback
+contract.
 """
 
 from __future__ import annotations
@@ -53,8 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from opendiloco_tpu.models.ring_cache import kernel_view
-from opendiloco_tpu.ops.attention import decode_attention, spec_tail_attention
+from opendiloco_tpu.models.ring_cache import ring_rows
+from opendiloco_tpu.ops.attention import decode_step_attention, spec_tail_attention
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
 W4_BLOCK = 4096  # diloco.compression._BLOCK (pinned by tests)
@@ -86,17 +95,16 @@ def _interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
-def _ring_block(t: int, block_t: int | None) -> int:
-    """Ring-page tile size: explicit arg > ``ODTP_DECODE_BLOCK_T`` > the
-    shared block heuristic > the whole page (always tiles)."""
-    if block_t:
-        return block_t if t % block_t == 0 else t
-    env = os.environ.get("ODTP_DECODE_BLOCK_T")
-    if env:
-        b = int(env)
-        if b > 0 and t % b == 0:
-            return b
-    return pick_block(t, 256) or t
+def _ring_block(t: int, block_t: int | None, interpret: bool) -> int:
+    """Ring-page tile size, in rows: explicit arg > ``ODTP_DECODE_BLOCK_T``
+    > the shared block heuristic. Rows are the tiles' lane dimension, so on
+    the chip a tile is a multiple of 128 of them (interpreted, the tests
+    cut small rings into small tiles), and a ring that no such tile divides
+    has none: 0, and the caller keeps the XLA path."""
+    want = block_t or int(os.environ.get("ODTP_DECODE_BLOCK_T") or 0)
+    if want > 0 and t % want == 0 and (interpret or want % 128 == 0):
+        return want
+    return pick_block(t, 256)
 
 
 # ---------------------------------------------------------------------------
@@ -104,56 +112,102 @@ def _ring_block(t: int, block_t: int | None) -> int:
 # ---------------------------------------------------------------------------
 
 
+# One grid step costs the decode kernel about 0.6 us whatever it moves
+# (ISSUE 29: 40,960 steps of one 32 KB tile each took 21-25 ms of a step on
+# the v5e), so a step takes as many KV heads of a slot as fit this many
+# bytes of one tile: all 5 of SmolLM2-360M's, all 16 of OLMoE's. K and V, in
+# and out, double-buffered, hold eight such tiles in VMEM.
+_HEAD_TILE_BYTES = 512 * 1024
+
+
+def _heads_per_step(nkv: int, tile_bytes: int) -> int:
+    """The most KV heads (a divisor of ``nkv``) whose tiles of
+    ``tile_bytes`` each stay under :data:`_HEAD_TILE_BYTES`."""
+    fit = max(1, _HEAD_TILE_BYTES // tile_bytes)
+    return max(g for g in range(1, nkv + 1) if nkv % g == 0 and g <= fit)
+
+
+def _as_column(row, dtype):
+    """A K/V row [1, d] of the step as the column [d, 1] of a rows-minor
+    tile: the row spread over a diagonal and summed along the lanes, which
+    adds zeros and so is exact (Mosaic has no [1, d] -> [d, 1] reshape)."""
+    d = row.shape[1]
+    spread = jnp.broadcast_to(row.astype(jnp.float32), (d, d))
+    on_diag = jax.lax.broadcasted_iota(
+        jnp.int32, (d, d), 0
+    ) == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    col = jnp.sum(jnp.where(on_diag, spread, 0.0), axis=1, keepdims=True)
+    return col.astype(dtype)
+
+
 def _decode_attn_kernel(
-    lens_ref, q_ref, k_ref, v_ref, o_ref, *rest,
+    lens_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+    o_ref, ko_ref, vo_ref, *rest,
     scale, block_t, t, num_t, with_stats,
 ):
     # the stats block's index ignores the ring axis, so it stays resident
-    # across ti and doubles as the counter: a [1, 1] vector add, since
-    # Mosaic cannot store a scalar to VMEM
+    # across ti and doubles as the counter: a vector add, since Mosaic
+    # cannot store a scalar to VMEM
     stats_ref, (m_scr, l_scr, acc_scr) = (
         (rest[0], rest[1:]) if with_stats else (None, rest)
     )
-    rep, d = q_ref.shape
+    heads, rep, d = q_ref.shape  # the KV heads of this grid step
     si, ti = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ti == 0)
     def _init():
-        m_scr[:] = jnp.full((rep, 1), NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros((rep, 1), jnp.float32)
-        acc_scr[:] = jnp.zeros((rep, d), jnp.float32)
+        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
         if with_stats:
-            stats_ref[:] = jnp.zeros((1, 1), jnp.int32)
+            stats_ref[:] = jnp.zeros(stats_ref.shape, jnp.int32)
 
     lens_s = lens_ref[si]
     # valid cache entries are idx <= lens (whole ring once lens >= t), so
     # blocks past min(lens, t-1) hold no live rows for this slot
     last_live = jnp.minimum(lens_s, t - 1) // block_t
+    # the step's own row goes to ring row lens % t, in a block that is
+    # always live (it is the last live one until the ring wraps)
+    new_row = jax.lax.rem(lens_s, t)
 
     @pl.when(ti <= last_live)
     def _step():
-        q = q_ref[:]
-        k_blk = k_ref[:]
-        v_blk = v_ref[:]
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rep, block_t]
+        here = (
+            ti * block_t
+            + jax.lax.broadcasted_iota(jnp.int32, (d, block_t), 1)
+        ) == new_row
         idx = ti * block_t + jax.lax.broadcasted_iota(
             jnp.int32, (rep, block_t), 1
         )
         valid = (idx <= lens_s) | (lens_s >= t)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev, l_prev, acc = m_scr[:], l_scr[:], acc_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        for j in range(heads):
+            k_blk = jnp.where(
+                here, _as_column(kn_ref[j], k_ref.dtype), k_ref[j]
+            )
+            v_blk = jnp.where(
+                here, _as_column(vn_ref[j], v_ref.dtype), v_ref[j]
+            )
+
+            @pl.when(ti == new_row // block_t)
+            def _write():
+                ko_ref[j] = k_blk
+                vo_ref[j] = v_blk
+
+            s = scale * jax.lax.dot_general(
+                q_ref[j], k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [rep, block_t]
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev, l_prev, acc = m_scr[j], l_scr[j], acc_scr[j]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[j] = m_new
+            l_scr[j] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[j] = acc * corr + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
         if with_stats:
             stats_ref[:] += 1
 
@@ -168,65 +222,89 @@ def paged_decode_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
     lens: jax.Array,
+    layer,
     *,
     block_t: int | None = None,
     interpret: bool | None = None,
     return_stats: bool = False,
 ):
-    """Drop-in :func:`~opendiloco_tpu.ops.attention.decode_attention`:
-    q [S, H, D] over one layer's ring pages k/v with per-slot ``lens``.
+    """One layer's share of a decode step against the ring cache: write
+    each slot's new row (k, v [S, Nkv, D]) at ring row ``lens % T`` of
+    ``layer``'s pages, then attend q [S, H, D] over them under the per-slot
+    ``lens``. Returns (out [S, H, D], cache_k, cache_v): what
+    :func:`~opendiloco_tpu.ops.attention.decode_step_attention` gives,
+    with the whole cache ``[L, S, Nkv, D, T]`` read and written where it lies (the outputs alias the inputs;
+    callers donate them).
 
     ``return_stats`` additionally returns the measured per-(slot, kv-head)
     count of ring blocks the kernel actually processed — the dead-block
     skip evidence banked by scripts/decode_kernel_bench.py."""
     # Mosaic requires the last two dims of every block to be (8, 128)-
-    # aligned OR equal to the array's own dims. rep and the kv-head axis
-    # are tiny and never 8-aligned, so they must BE array dims: the kernel
-    # reads the pages as [S, Kh, T, D] ([bt, d] tiles; the cache module's
-    # view) and q as [S, Kh, rep, D] ([rep, d] tiles, rep == its array dim)
-    kt_, vt_ = kernel_view(k), kernel_view(v)
-    s_, nkv, t, d = kt_.shape
+    # aligned OR equal to the array's own dims. The cache's two minor dims
+    # are (D, T), so a (d, bt) tile is legal for bt a multiple of 128. rep
+    # and the single new row are tiny and never 8-aligned, so they must BE
+    # array dims: q as [S, Kh, rep, D] ([rep, d] tiles), the new rows as
+    # [S, Kh, 1, D] ([1, d] tiles); the KV heads of a grid step lead them
+    s_, nkv, d = k.shape
+    t = ring_rows(cache_k)
     h = q.shape[1]
-    if d % 8 != 0 or h % nkv != 0:
-        out = decode_attention(q, k, v, lens)
-        return (out, None) if return_stats else out
-    rep = h // nkv
-    bt = _ring_block(t, block_t)
-    num_t = t // bt
     interp = _interpret(interpret)
+    bt = _ring_block(t, block_t, interp)
+    if d % 8 != 0 or h % nkv != 0 or not bt:
+        res = decode_step_attention(q, k, v, cache_k, cache_v, lens, layer)
+        return (*res, None) if return_stats else res
+    rep = h // nkv
+    num_t = t // bt
+    hb = _heads_per_step(nkv, d * bt * cache_k.dtype.itemsize)
     q4 = q.reshape(s_, nkv, rep, d)
+    kn = k.reshape(s_, nkv, 1, d).astype(cache_k.dtype)
+    vn = v.reshape(s_, nkv, 1, d).astype(cache_v.dtype)
 
-    def kv_map(si, hi, ti, lens_ref):
+    def kv_map(si, gi, ti, lens_ref, layer_ref):
         # clamp dead blocks to the last live one: unchanged index = no DMA
         last = jnp.minimum(lens_ref[si], t - 1) // bt
-        return (si, hi, jnp.minimum(ti, last), 0)
+        return (layer_ref[0], si, gi, 0, jnp.minimum(ti, last))
 
-    def q_map(si, hi, ti, lr):
-        return (si, hi, 0, 0)
+    def written_map(si, gi, ti, lens_ref, layer_ref):
+        # the one block of (slot, head group) that goes back: the row's
+        return (layer_ref[0], si, gi, 0, jax.lax.rem(lens_ref[si], t) // bt)
 
-    out_specs = [pl.BlockSpec((None, None, rep, d), q_map)]
+    def q_map(si, gi, ti, lr, yr):
+        return (si, gi, 0, 0)
+
+    queries = pl.BlockSpec((None, hb, rep, d), q_map)
+    row = pl.BlockSpec((None, hb, 1, d), q_map)
+    out_specs = [
+        queries,
+        pl.BlockSpec((None, None, hb, d, bt), written_map),
+        pl.BlockSpec((None, None, hb, d, bt), written_map),
+    ]
     out_shape = [
-        jax.ShapeDtypeStruct((s_, nkv, rep, d), q.dtype, vma=jax.typeof(q).vma)
+        jax.ShapeDtypeStruct((s_, nkv, rep, d), q.dtype, vma=jax.typeof(q).vma),
+        jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
+        jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
     ]
     scratch = [
-        pltpu.VMEM((rep, 1), jnp.float32),
-        pltpu.VMEM((rep, 1), jnp.float32),
-        pltpu.VMEM((rep, d), jnp.float32),
+        pltpu.VMEM((hb, rep, 1), jnp.float32),
+        pltpu.VMEM((hb, rep, 1), jnp.float32),
+        pltpu.VMEM((hb, rep, d), jnp.float32),
     ]
     if return_stats:
-        out_specs.append(
-            pl.BlockSpec((None, None, 1, 1), lambda si, hi, ti, lr: (si, hi, 0, 0))
-        )
+        out_specs.append(pl.BlockSpec((None, hb, 1, 1), q_map))
         out_shape.append(jax.ShapeDtypeStruct((s_, nkv, 1, 1), jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s_, nkv, num_t),
+        num_scalar_prefetch=2,
+        grid=(s_, nkv // hb, num_t),
         in_specs=[
-            pl.BlockSpec((None, None, rep, d), q_map),
-            pl.BlockSpec((None, None, bt, d), kv_map),
-            pl.BlockSpec((None, None, bt, d), kv_map),
+            queries,
+            row,
+            row,
+            pl.BlockSpec((None, None, hb, d, bt), kv_map),
+            pl.BlockSpec((None, None, hb, d, bt), kv_map),
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -240,14 +318,20 @@ def paged_decode_attention(
         name="odtp_paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=out_shape,
+        # operands count the two scalar-prefetch vectors: the caches are
+        # inputs 5 and 6, and come back as outputs 1 and 2
+        input_output_aliases={5: 1, 6: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
-    )(lens.astype(jnp.int32), q4, kt_, vt_)
-    out = res[0].reshape(s_, h, d)
+    )(
+        lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        q4, kn, vn, cache_k, cache_v,
+    )
+    out = (res[0].reshape(s_, h, d), res[1], res[2])
     if return_stats:
-        return out, res[1].reshape(s_, nkv)
+        return (*out, res[3].reshape(s_, nkv))
     return out
 
 
@@ -294,7 +378,7 @@ def _spec_tail_kernel(
 
     @pl.when((ti < num_t) & ring_on)
     def _ring_step():
-        k_blk = k_ref[:]  # [block_t, d]
+        k_blk = k_ref[:]  # [d, block_t]
         v_blk = v_ref[:]
         idx = ti * block_t + jax.lax.broadcasted_iota(
             jnp.int32, (kq, block_t), 1
@@ -310,7 +394,7 @@ def _spec_tail_kernel(
         for r in range(rep):
             q_r = q_ref[r]  # [kq, d]
             s = scale * jax.lax.dot_general(
-                q_r, k_blk, (((1,), (1,)), ((), ())),
+                q_r, k_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             s = jnp.where(valid, s, NEG_INF)
@@ -321,7 +405,7 @@ def _spec_tail_kernel(
             m_scr[r] = m_new
             l_scr[r] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
             acc_scr[r] = acc * corr + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                p.astype(v_blk.dtype), v_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         if with_stats:
@@ -372,27 +456,29 @@ def spec_tail_attention_fused(
     """Drop-in :func:`~opendiloco_tpu.ops.attention.spec_tail_attention`:
     q [S, Kq, H, D] over one layer's ring pages plus the tail's K/V, one
     online-softmax pass, exact ring-wrap eviction semantics."""
-    # same Mosaic tiling story as paged_decode_attention: kv-head and rep
-    # axes are tiny, so they must be array dims of their own -- pages and
-    # tail as [S, Kh, T|Kt, D] (the cache module's view), q (and the output)
-    # as [S, Kh, rep, Kq, D]. The head index r must be a LEADING dim of the
-    # q/o tiles: with 16-bit dtypes two rows share a sublane, and Mosaic
-    # refuses a per-head slice on the second-minor dim ("unsupported shape
-    # cast" for bf16 at D 64).
-    ckt, cvt = kernel_view(cache_k), kernel_view(cache_v)
-    tkt, tvt = kernel_view(tail_k), kernel_view(tail_v)
-    s_, nkv, t, d = ckt.shape
+    # same Mosaic tiling story as paged_decode_attention: the pages are
+    # read where they lie, [S, Kh, D, T] in (d, bt) tiles; kv-head and rep
+    # axes are tiny, so they must be array dims of their own -- the tail as
+    # [S, Kh, Kt, D], q (and the output) as [S, Kh, rep, Kq, D]. The head
+    # index r must be a LEADING dim of the q/o tiles: with 16-bit dtypes two
+    # rows share a sublane, and Mosaic refuses a per-head slice on the
+    # second-minor dim ("unsupported shape cast" for bf16 at D 64).
+    s_, nkv, d, t = cache_k.shape
     kq, h = q.shape[1], q.shape[2]
-    kt = tkt.shape[2]
-    if d % 8 != 0 or h % nkv != 0 or (h // nkv) * kq > _SPEC_MAX_GROUP_ROWS:
+    kt = tail_k.shape[1]
+    interp = _interpret(interpret)
+    bt = _ring_block(t, block_t, interp)
+    if (
+        d % 8 != 0 or h % nkv != 0 or not bt
+        or (h // nkv) * kq > _SPEC_MAX_GROUP_ROWS
+    ):
         out = spec_tail_attention(
             q, cache_k, cache_v, tail_k, tail_v, lens, q_start=q_start
         )
         return (out, None) if return_stats else out
     rep = h // nkv
-    bt = _ring_block(t, block_t)
+    tkt, tvt = tail_k.transpose(0, 2, 1, 3), tail_v.transpose(0, 2, 1, 3)
     num_t = t // bt
-    interp = _interpret(interpret)
     q5 = q.reshape(s_, kq, nkv, rep, d).transpose(0, 2, 3, 1, 4)
 
     def kv_map(si, hi, ti, lens_ref):
@@ -400,7 +486,7 @@ def spec_tail_attention_fused(
             lens_ref[si] >= t, num_t - 1,
             jnp.maximum(lens_ref[si] - 1, 0) // bt,
         )
-        return (si, hi, jnp.minimum(ti, last), 0)
+        return (si, hi, 0, jnp.minimum(ti, last))
 
     def q_map(si, hi, ti, lr):
         return (si, hi, 0, 0, 0)
@@ -430,8 +516,8 @@ def spec_tail_attention_fused(
         grid=(s_, nkv, num_t + 1),  # ring blocks, then the tail block
         in_specs=[
             pl.BlockSpec((None, None, rep, kq, d), q_map),
-            pl.BlockSpec((None, None, bt, d), kv_map),
-            pl.BlockSpec((None, None, bt, d), kv_map),
+            pl.BlockSpec((None, None, d, bt), kv_map),
+            pl.BlockSpec((None, None, d, bt), kv_map),
             pl.BlockSpec((None, None, kt, d), tail_map),
             pl.BlockSpec((None, None, kt, d), tail_map),
         ],
@@ -451,7 +537,7 @@ def spec_tail_attention_fused(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
-    )(lens.astype(jnp.int32), q5, ckt, cvt, tkt, tvt)
+    )(lens.astype(jnp.int32), q5, cache_k, cache_v, tkt, tvt)
     out = res[0].transpose(0, 3, 1, 2, 4).reshape(s_, kq, h, d)
     if return_stats:
         return out, res[1].reshape(s_, nkv)

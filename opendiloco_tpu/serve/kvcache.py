@@ -1,7 +1,8 @@
 """Slot-paged ring KV cache bookkeeping for the serve plane.
 
-The device arrays live in ``models.llama.init_kv_cache`` ([L, S, T, Nkv,
-Dh]: one fixed ring page per batch slot); this module owns the host-side
+The device arrays live in ``models.ring_cache.init_kv_cache`` (one fixed
+ring page per layer and batch slot, in that module's storage order; pages
+cross to the host as rows [L, rows, Nkv, Dh]); this module owns the host-side
 bookkeeping — which slots are free, which compile-size bucket a prompt
 pads to — so the engine's jitted ops see only dense arrays and traced
 scalars.

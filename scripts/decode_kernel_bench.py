@@ -65,8 +65,9 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
 
     from opendiloco_tpu.diloco.compression import pack_blockwise4_stacked
     from opendiloco_tpu.models.llama import dequant_w4
+    from opendiloco_tpu.models.ring_cache import cache_shape, layer_pages
     from opendiloco_tpu.ops.attention import (
-        decode_attention,
+        decode_step_attention,
         spec_tail_attention,
     )
     from opendiloco_tpu.ops.decode_kernels import (
@@ -82,18 +83,26 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
     bt = 16 if small else 128
     rng = np.random.default_rng(0)
     q1 = jnp.asarray(rng.normal(size=(S, Nh, D)) * 0.5, jnp.float32)
-    ck = jnp.asarray(rng.normal(size=(S, T, Nkv, D)) * 0.5, jnp.float32)
-    cv = jnp.asarray(rng.normal(size=(S, T, Nkv, D)) * 0.5, jnp.float32)
+    # a cache of one layer in the cache module's order, and the step's row
+    one_layer = cache_shape(1, S, T, Nkv, D)
+    ck = jnp.asarray(rng.normal(size=one_layer) * 0.5, jnp.float32)
+    cv = jnp.asarray(rng.normal(size=one_layer) * 0.5, jnp.float32)
+    k1 = jnp.asarray(rng.normal(size=(S, Nkv, D)) * 0.5, jnp.float32)
+    v1 = jnp.asarray(rng.normal(size=(S, Nkv, D)) * 0.5, jnp.float32)
+
+    def xla_step(*args):
+        return decode_step_attention(*args, 0)[0]
+
     # ragged occupancy: empty, short, mid, nearly-full, wrapped...
     lens_list = [0, 3, T // 4, T - 1, 2 * T]
     lens_list += rng.integers(0, 2 * T, max(0, S - len(lens_list))).tolist()
     lens = jnp.asarray(lens_list[:S], jnp.int32)
 
     _log("decode_attention: xla reference")
-    ref = jax.jit(decode_attention)(q1, ck, cv, lens)
+    ref = jax.jit(xla_step)(q1, k1, v1, ck, cv, lens)
     _log("decode_attention: pallas interpret arm")
-    got, stats = paged_decode_attention(
-        q1, ck, cv, lens, block_t=bt, return_stats=True
+    got, _, _, stats = paged_decode_attention(
+        q1, k1, v1, ck, cv, lens, 0, block_t=bt, return_stats=True
     )
     err = float(jnp.max(jnp.abs(got - ref)))
     stats = np.asarray(stats)
@@ -107,13 +116,14 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
         "ring_blocks_processed": processed,
         "ring_blocks_dense_equiv": dense,
         "dead_block_skip_fraction": round(1.0 - processed / dense, 4),
-        "xla_us": _timeit(jax.jit(decode_attention), q1, ck, cv, lens),
+        "xla_us": _timeit(jax.jit(xla_step), q1, k1, v1, ck, cv, lens),
     }
     if on_tpu:
+        # not donated: the call's copy of the one-layer cache is in the time
         doc["decode_attention"]["pallas_us"] = _timeit(
             jax.jit(
-                lambda *a: paged_decode_attention(*a, block_t=bt)
-            ), q1, ck, cv, lens,
+                lambda *a: paged_decode_attention(*a, 0, block_t=bt)[0]
+            ), q1, k1, v1, ck, cv, lens,
         )
     assert err < 2e-6, f"paged decode parity: {err}"
     # the ragged lens above MUST leave dead blocks on the floor
@@ -122,6 +132,7 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
     qt = jnp.asarray(rng.normal(size=(S, Kq, Nh, D)) * 0.5, jnp.float32)
     tk = jnp.asarray(rng.normal(size=(S, Kq, Nkv, D)) * 0.5, jnp.float32)
     tv = jnp.asarray(rng.normal(size=(S, Kq, Nkv, D)) * 0.5, jnp.float32)
+    ck, cv = layer_pages(ck, cv, 0)  # the verify pass reads one layer's pages
     _log("spec_verify: xla reference")
     ref = jax.jit(spec_tail_attention)(qt, ck, cv, tk, tv, lens)
     _log("spec_verify: pallas interpret arm")
@@ -201,6 +212,7 @@ def _mosaic_compile(doc: dict) -> bool:
     from jax.sharding import SingleDeviceSharding
 
     from opendiloco_tpu.diloco.compression import pack_blockwise4_stacked
+    from opendiloco_tpu.models.ring_cache import cache_shape
     from opendiloco_tpu.ops.decode_kernels import (
         paged_decode_attention,
         spec_tail_attention_fused,
@@ -239,12 +251,13 @@ def _mosaic_compile(doc: dict) -> bool:
 
     kernels = {
         "decode_attention": (
-            lambda q, k, v, lens: paged_decode_attention(
-                q, k, v, lens, interpret=False
+            lambda q, k, v, ck, cv, lens: paged_decode_attention(
+                q, k, v, ck, cv, lens, 1, interpret=False
             ),
             (
-                sds((S, Nh, D), bf16), sds((S, T, Nkv, D), bf16),
-                sds((S, T, Nkv, D), bf16), sds((S,), jnp.int32),
+                sds((S, Nh, D), bf16), sds((S, Nkv, D), bf16),
+                sds((S, Nkv, D), bf16), sds(cache_shape(2, S, T, Nkv, D), bf16),
+                sds(cache_shape(2, S, T, Nkv, D), bf16), sds((S,), jnp.int32),
             ),
         ),
         "spec_verify": (
@@ -252,8 +265,8 @@ def _mosaic_compile(doc: dict) -> bool:
                 q, ck, cv, tk, tv, lens, interpret=False
             ),
             (
-                sds((S, Kq, Nh, D), bf16), sds((S, T, Nkv, D), bf16),
-                sds((S, T, Nkv, D), bf16), sds((S, Kq, Nkv, D), bf16),
+                sds((S, Kq, Nh, D), bf16), sds(cache_shape(1, S, T, Nkv, D)[1:], bf16),
+                sds(cache_shape(1, S, T, Nkv, D)[1:], bf16), sds((S, Kq, Nkv, D), bf16),
                 sds((S, Kq, Nkv, D), bf16), sds((S,), jnp.int32),
             ),
         ),

@@ -328,30 +328,48 @@ def decode_section():
     import jax
     import jax.numpy as jnp
 
-    from opendiloco_tpu.ops.attention import decode_attention, spec_tail_attention
+    from opendiloco_tpu.ops.attention import (
+        decode_step_attention,
+        spec_tail_attention,
+    )
     from opendiloco_tpu.ops.decode_kernels import (
         paged_decode_attention,
         spec_tail_attention_fused,
         w4_matmul,
     )
     from opendiloco_tpu.models.llama import dequant_w4
+    from opendiloco_tpu.models.ring_cache import cache_shape, layer_pages
     from opendiloco_tpu.diloco.compression import pack_blockwise4_stacked
 
     rng = np.random.default_rng(3)
     S, T, Nh, Nkv, D, Kq = 8, 512, 16, 8, 64, 4
     if _DOC.get("smoke"):
-        T = 64
+        T = 128  # one 128-row tile: the least ring the kernels take
     q1 = jnp.asarray(rng.normal(size=(S, Nh, D)) * 0.5, jnp.float32)
-    ck = jnp.asarray(rng.normal(size=(S, T, Nkv, D)) * 0.5, jnp.float32)
-    cv = jnp.asarray(rng.normal(size=(S, T, Nkv, D)) * 0.5, jnp.float32)
+    # a cache of one layer in the cache module's order, and the step's row
+    one_layer = cache_shape(1, S, T, Nkv, D)
+    ck = jnp.asarray(rng.normal(size=one_layer) * 0.5, jnp.float32)
+    cv = jnp.asarray(rng.normal(size=one_layer) * 0.5, jnp.float32)
+    k1 = jnp.asarray(rng.normal(size=(S, Nkv, D)) * 0.5, jnp.float32)
+    v1 = jnp.asarray(rng.normal(size=(S, Nkv, D)) * 0.5, jnp.float32)
+
+    def xla_step(*args):
+        return decode_step_attention(*args, 0)[0]
+
+    def pallas_step(*args):
+        # not donated: the call's copy of the one-layer cache is in the time
+        return paged_decode_attention(*args, 0)[0]
+
     # ragged occupancy incl. empty slot and wrapped sliding window
     lens = jnp.asarray(
         rng.integers(0, 2 * T, S).tolist()[: S - 2] + [0, 2 * T], jnp.int32
     )
     out = {"shape": f"S{S} T{T} Hq{Nh} Hkv{Nkv} D{D} Kq{Kq}"}
 
-    ref = jax.jit(decode_attention)(q1, ck, cv, lens)
-    got, stats = paged_decode_attention(q1, ck, cv, lens, return_stats=True)
+    ref = jax.jit(xla_step)(q1, k1, v1, ck, cv, lens)
+    got, _, _, stats = paged_decode_attention(
+        q1, k1, v1, ck, cv, lens, 0, return_stats=True
+    )
     err = float(jnp.max(jnp.abs(got - ref)))
     assert err < 2e-6, f"paged decode parity: max|err|={err}"
     # dense equivalent: every (slot, kv head) scoring the whole ring —
@@ -363,12 +381,12 @@ def decode_section():
         "ring_blocks_processed": processed,
         "ring_blocks_dense_equiv": dense,
         "dead_block_skip_fraction": round(1.0 - processed / max(1, dense), 4),
-        "pallas_us": _timeit(
-            jax.jit(paged_decode_attention), q1, ck, cv, lens
-        ),
-        "xla_us": _timeit(jax.jit(decode_attention), q1, ck, cv, lens),
+        "pallas_us": _timeit(jax.jit(pallas_step), q1, k1, v1, ck, cv, lens),
+        "xla_us": _timeit(jax.jit(xla_step), q1, k1, v1, ck, cv, lens),
     }
     _flush()
+
+    ck, cv = layer_pages(ck, cv, 0)  # the verify pass reads one layer's pages
 
     qt = jnp.asarray(rng.normal(size=(S, Kq, Nh, D)) * 0.5, jnp.float32)
     tk = jnp.asarray(rng.normal(size=(S, Kq, Nkv, D)) * 0.5, jnp.float32)

@@ -3,14 +3,22 @@
 The dispatch contract is token-bit-exact: `ODTP_DECODE_KERNEL=pallas`
 must emit exactly the token stream the stock XLA path emits. On this
 CPU rig the kernels run in Pallas interpret mode — slower, but it is the
-kernel's own dataflow (masks, online softmax, in-register dequant), so
-parity pinned here carries to the Mosaic lowering.
+kernel's own dataflow (masks, online softmax, in-register dequant, the
+in-place row write), so parity pinned here carries to the Mosaic lowering.
+Ring pages are built through ``models.ring_cache`` (rows minor-most), never
+spelled here.
 
 Oracles:
-- paged decode attention matches ``decode_attention`` over ragged lens
-  (empty slot, mid-page, last row, lens >= T sliding window) and every
-  GQA head ratio the configs use — and its stats variant proves dead
-  ring blocks are skipped, not masked
+- paged decode attention matches ``decode_step_attention`` (the XLA row
+  write, then ``decode_attention``) over ragged lens (empty slot, mid-page, last row,
+  lens >= T sliding window, both sides of a 128-row tile edge) and every
+  GQA head ratio the configs use, the caches coming back bit-equal (one
+  row written per slot, nothing else touched) — and its stats variant
+  proves dead ring blocks are skipped, not masked
+- a ring with no 128-row tile, and a head size off the sublanes, keep the
+  XLA path per call
+- a slot's pages survive the host tier's round trip (``fetch_pages`` ->
+  ``cache_insert``) bit for bit
 - the fused speculative verify matches ``spec_tail_attention``'s exact
   ring-wrap eviction mask, across ``q_start`` offsets and the draft's
   wide-tail (Kq=1) shape
@@ -31,7 +39,18 @@ import pytest
 
 from opendiloco_tpu.diloco.compression import pack_blockwise4_stacked
 from opendiloco_tpu.models.llama import PackedW4, _wmul, dequant_w4, init_params
-from opendiloco_tpu.ops.attention import decode_attention, spec_tail_attention
+from opendiloco_tpu.models.ring_cache import (
+    cache_insert,
+    cache_shape,
+    fetch_pages,
+    layer_pages,
+)
+from opendiloco_tpu.ops import decode_kernels
+from opendiloco_tpu.ops.attention import (
+    decode_attention,
+    decode_step_attention,
+    spec_tail_attention,
+)
 from opendiloco_tpu.ops.decode_kernels import (
     paged_decode_attention,
     resolve_decode_kernel,
@@ -50,33 +69,84 @@ def _randn(rng, *shape):
     return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
 
+def _ring(rng, L, S, Kh, D, T):
+    """A cache of L layers whose every ring row holds random K/V, built the
+    way the engine fills one: rows [L, T, Kh, D] inserted slot by slot."""
+    ck = cv = jnp.zeros(cache_shape(L, S, T, Kh, D), jnp.float32)
+    for slot in range(S):
+        ck, cv = cache_insert(
+            ck, cv, _randn(rng, L, T, Kh, D), _randn(rng, L, T, Kh, D), slot
+        )
+    return ck, cv
+
+
+def _pages(rng, S, Kh, D, T):
+    """One layer's pages, as the verify pass's scan hands them out."""
+    return layer_pages(*_ring(rng, 1, S, Kh, D, T), 0)
+
+
+def _assert_decode_parity(rng, S, H, Kh, D, T, lens, *, layers=2, **kw):
+    """Kernel == reference on the last layer of a ``layers``-deep cache: the
+    attention output to rounding, both caches bit for bit."""
+    ck, cv = _ring(rng, layers, S, Kh, D, T)
+    q, k, v = _randn(rng, S, H, D), _randn(rng, S, Kh, D), _randn(rng, S, Kh, D)
+    lens = jnp.asarray(lens, jnp.int32)
+    ref, rk, rv = decode_step_attention(q, k, v, ck, cv, lens, layers - 1)
+    out, ok, ov = paged_decode_attention(
+        q, k, v, ck, cv, lens, layers - 1, interpret=True, **kw
+    )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(ok), np.asarray(rk))
+    np.testing.assert_array_equal(np.asarray(ov), np.asarray(rv))
+    # and the write touched one row a slot: layer 0 is as it was
+    np.testing.assert_array_equal(np.asarray(ok[0]), np.asarray(ck[0]))
+
+
 # ---------------------------------------------------------------------------
-# (a) ragged paged decode attention
+# (a) ragged paged decode attention, and the step's row written in place
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("heads", [(8, 8), (8, 2), (4, 1), (8, 4)])
 def test_paged_decode_attention_parity(heads):
     H, Kh = heads
-    S, T, D = 5, 32, 16
-    rng = _rng(H * 31 + Kh)
-    q, k, v = _randn(rng, S, H, D), _randn(rng, S, T, Kh, D), _randn(rng, S, T, Kh, D)
+    T = 32
     # ragged: empty slot, mid-page, last live row, exactly T, wrapped
-    lens = jnp.asarray([0, 5, T - 1, T, 2 * T + 3], jnp.int32)
-    ref = decode_attention(q, k, v, lens)
-    out = paged_decode_attention(q, k, v, lens, block_t=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
+    _assert_decode_parity(
+        _rng(H * 31 + Kh), 5, H, Kh, 16, T, [0, 5, T - 1, T, 2 * T + 3],
+        block_t=8,
+    )
+
+
+@pytest.mark.parametrize("heads", [(15, 5), (4, 4)], ids=["gqa15_5", "mha"])
+def test_paged_decode_writes_the_row_in_place(heads):
+    """A 256-row ring in 128-row tiles, as on the chip: the written row at
+    ring row 0, at both sides of the tile edge, at the last row, and the
+    same again once the ring has wrapped."""
+    H, Kh = heads
+    T = 256
+    lens = [0, 127, 128, T - 1, T, T + 127, T + 128, 3 * T + 5]
+    _assert_decode_parity(_rng(H), len(lens), H, Kh, 8, T, lens, block_t=128)
+
+
+def test_paged_decode_attention_default_tile_and_head_groups(monkeypatch):
+    """No tile asked for: a 256-row ring is one tile, and a grid step takes
+    as many KV heads as the tile budget holds (here 2 of 4)."""
+    monkeypatch.setattr(decode_kernels, "_HEAD_TILE_BYTES", 2 * 8 * 256 * 4)
+    assert decode_kernels._heads_per_step(4, 8 * 256 * 4) == 2
+    _assert_decode_parity(_rng(3), 3, 8, 4, 8, 256, [0, 200, 300])
 
 
 def test_paged_decode_attention_skips_dead_blocks():
     S, T, H, Kh, D = 4, 32, 4, 2, 16
     rng = _rng(1)
-    q, k, v = _randn(rng, S, H, D), _randn(rng, S, T, Kh, D), _randn(rng, S, T, Kh, D)
+    ck, cv = _ring(rng, 1, S, Kh, D, T)
+    q, k, v = _randn(rng, S, H, D), _randn(rng, S, Kh, D), _randn(rng, S, Kh, D)
     lens = jnp.asarray([0, 5, 17, 64], jnp.int32)
-    out, stats = paged_decode_attention(
-        q, k, v, lens, block_t=8, interpret=True, return_stats=True
+    out, _, _, stats = paged_decode_attention(
+        q, k, v, ck, cv, lens, 0, block_t=8, interpret=True, return_stats=True
     )
-    ref = decode_attention(q, k, v, lens)
+    ref, _, _ = decode_step_attention(q, k, v, ck, cv, lens, 0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     # processed ring blocks per slot: ceil((min(lens, T-1)+1) / block_t),
     # the whole page only once lens covers it — dead blocks never ran
@@ -84,14 +154,63 @@ def test_paged_decode_attention_skips_dead_blocks():
     assert np.asarray(stats).tolist() == [[e] * Kh for e in expected]
 
 
-def test_paged_decode_attention_untileable_head_dim_falls_back():
-    S, T, H, Kh, D = 2, 16, 2, 2, 12  # D % 8 != 0: XLA fallback path
+@pytest.mark.parametrize(
+    "T,D", [(16, 12), (96, 16)], ids=["head_dim_12", "ring_of_96_rows"]
+)
+def test_paged_decode_attention_untileable_shape_falls_back(T, D):
+    """D % 8 != 0, or a ring that no 128-row tile divides: the XLA path,
+    per call, with the same results and the same caches."""
+    S, H, Kh = 2, 2, 2
     rng = _rng(2)
-    q, k, v = _randn(rng, S, H, D), _randn(rng, S, T, Kh, D), _randn(rng, S, T, Kh, D)
-    lens = jnp.asarray([3, 20], jnp.int32)
-    ref = decode_attention(q, k, v, lens)
-    out = paged_decode_attention(q, k, v, lens, interpret=True)
+    ck, cv = _ring(rng, 1, S, Kh, D, T)
+    q, k, v = _randn(rng, S, H, D), _randn(rng, S, Kh, D), _randn(rng, S, Kh, D)
+    lens = jnp.asarray([3, T + 4], jnp.int32)
+    ref, rk, rv = decode_step_attention(q, k, v, ck, cv, lens, 0)
+    out, ok, ov, stats = paged_decode_attention(
+        q, k, v, ck, cv, lens, 0, interpret=True, return_stats=True
+    )
+    assert stats is None  # no kernel ran
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(ok), np.asarray(rk))
+    np.testing.assert_array_equal(np.asarray(ov), np.asarray(rv))
+
+
+def test_ring_tile_is_a_multiple_of_128_rows_on_the_chip(monkeypatch):
+    tile = decode_kernels._ring_block
+    assert tile(256, None, False) == 256 and tile(3200, None, False) == 128
+    assert tile(96, None, False) == 0 and tile(24, None, True) == 0
+    assert tile(32, 8, True) == 8 and tile(32, 8, False) == 0
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "128")
+    assert tile(256, None, False) == 128 and tile(192, None, False) == 0
+
+
+def test_page_out_page_in_round_trip_is_bit_equal():
+    """The host tier's contract over the rows-minor storage: a slot's pages
+    after kernel-written decode steps, fetched as rows and inserted into
+    another slot, are the same bytes, and attend the same."""
+    S, H, Kh, D, T, L = 3, 4, 2, 8, 128, 2
+    rng = _rng(9)
+    ck, cv = _ring(rng, L, S, Kh, D, T)
+    lens = jnp.asarray([40, 0, 7], jnp.int32)
+    for _ in range(3):  # three steps' rows, written by the kernel
+        for layer in range(L):
+            _, ck, cv = paged_decode_attention(
+                _randn(rng, S, H, D), _randn(rng, S, Kh, D),
+                _randn(rng, S, Kh, D), ck, cv, lens, layer, interpret=True,
+            )
+        lens = lens + 1
+    rows = 64  # a page-out bucket beyond slot 0's 43 live rows
+    pk, pv = fetch_pages(ck, cv, jnp.int32(0), rows)
+    assert pk.shape == (L, rows, Kh, D)
+    ck2, cv2 = cache_insert(ck, cv, pk, pv, jnp.int32(1))
+    for a in (ck2, cv2):
+        np.testing.assert_array_equal(
+            np.asarray(a[:, 1, :, :, :rows]), np.asarray(a[:, 0, :, :, :rows])
+        )
+    q = _randn(rng, S, H, D)
+    both = jnp.asarray([43, 43, 0], jnp.int32)
+    out = decode_attention(q.at[1].set(q[0]), *layer_pages(ck2, cv2, 1), both)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out[1]))
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +226,7 @@ def test_spec_tail_fused_parity(heads, q_start):
     Kt = Kq + q_start  # tail holds earlier draft rows before the queries
     rng = _rng(q_start * 17 + H)
     q = _randn(rng, S, Kq, H, D)
-    ck, cv = _randn(rng, S, T, Kh, D), _randn(rng, S, T, Kh, D)
+    ck, cv = _pages(rng, S, Kh, D, T)
     tk, tv = _randn(rng, S, Kt, Kh, D), _randn(rng, S, Kt, Kh, D)
     lens = jnp.asarray([0, 5, T - 2, T, 2 * T + 1], jnp.int32)
     ref = spec_tail_attention(q, ck, cv, tk, tv, lens, q_start=q_start)
@@ -122,7 +241,7 @@ def test_spec_tail_fused_draft_shape():
     S, T, H, Kh, D, k_steps = 3, 16, 4, 2, 16, 3
     rng = _rng(7)
     q = _randn(rng, S, 1, H, D)
-    ck, cv = _randn(rng, S, T, Kh, D), _randn(rng, S, T, Kh, D)
+    ck, cv = _pages(rng, S, Kh, D, T)
     tk, tv = _randn(rng, S, k_steps, Kh, D), _randn(rng, S, k_steps, Kh, D)
     lens = jnp.asarray([0, 9, 2 * T], jnp.int32)
     for i in range(k_steps):
@@ -216,6 +335,22 @@ def test_auto_never_selects_pallas_off_tpu(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The engines below keep 24-row rings (cheap to wrap); interpreted, the
+    kernels cut them into 8-row tiles instead of leaving them to XLA."""
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")
+
+
+def _runs_the_decode_kernel(engine) -> bool:
+    S = engine.num_slots
+    vec = jnp.zeros((S,), jnp.int32)
+    jaxpr = jax.make_jaxpr(engine._decode)(
+        engine.params, vec, vec, engine.cache_k, engine.cache_v
+    )
+    return "odtp_paged_decode_attn" in str(jaxpr)
+
+
 def _make_engine(tiny_cfg, decode_kernel, **kw):
     params = init_params(jax.random.PRNGKey(0), tiny_cfg)
     kw.setdefault("num_slots", 2)
@@ -241,20 +376,21 @@ def _generate(engine, prompt, n, slot=0):
 
 
 @pytest.mark.parametrize("weight_format", ["fp32", "w4"])
-def test_engine_token_streams_identical(tiny_cfg, weight_format):
+def test_engine_token_streams_identical(tiny_cfg, weight_format, small_tiles):
     rng = _rng(11)
     # both prefill buckets, and enough new tokens to wrap the T=24 ring
     prompts = [rng.integers(1, 256, 5).tolist(), rng.integers(1, 256, 12).tolist()]
     e_x = _make_engine(tiny_cfg, "xla", weight_format=weight_format)
     e_p = _make_engine(tiny_cfg, "pallas", weight_format=weight_format)
     assert (e_x.decode_kernel, e_p.decode_kernel) == ("xla", "pallas")
+    assert _runs_the_decode_kernel(e_p) and not _runs_the_decode_kernel(e_x)
     for slot, prompt in enumerate(prompts):
         tx = _generate(e_x, prompt, 20, slot=slot)
         tp = _generate(e_p, prompt, 20, slot=slot)
         assert tx == tp
 
 
-def test_engine_spec_streams_identical(tiny_cfg):
+def test_engine_spec_streams_identical(tiny_cfg, small_tiles):
     e_x = _make_engine(tiny_cfg, "xla", spec_k=2, draft_layers=1)
     e_p = _make_engine(tiny_cfg, "pallas", spec_k=2, draft_layers=1)
     rng = _rng(13)
@@ -274,7 +410,7 @@ def test_engine_spec_streams_identical(tiny_cfg):
     assert streams[0] == streams[1]
 
 
-def test_batcher_token_streams_identical(tiny_cfg):
+def test_batcher_token_streams_identical(tiny_cfg, small_tiles):
     rng = _rng(17)
     prompts = [rng.integers(1, 256, n).tolist() for n in (4, 9, 14)]
     results = []

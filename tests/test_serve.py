@@ -37,6 +37,7 @@ import pytest
 from opendiloco_tpu import obs
 from opendiloco_tpu.config import DilocoConfig, ServeConfig
 from opendiloco_tpu.models.llama import forward, init_params
+from opendiloco_tpu.models.ring_cache import fetch_pages
 from opendiloco_tpu.serve import (
     ContinuousBatcher,
     ServeEngine,
@@ -756,14 +757,13 @@ def test_prefix_reuse_kv_bytes_identical(tiny_cfg):
     engine.admit(0, sysp + [30, 31])  # the live source slot
     tok, _ = engine.admit(1, p2, prefix_src=0, prefix_len=plen)
     assert tok == cold_toks[0]
-    for warm, ref in (
-        (engine.cache_k, cold.cache_k), (engine.cache_v, cold.cache_v)
-    ):
+    # slot 1's rows [L, len(p2), Nkv, Dh], read through the cache module
+    rows = lambda e: fetch_pages(e.cache_k, e.cache_v, jnp.int32(1), len(p2))
+    for warm, ref in zip(rows(engine), rows(cold)):
         warm, ref = np.asarray(warm), np.asarray(ref)
-        np.testing.assert_array_equal(warm[:, 1, :plen], ref[:, 1, :plen])
+        np.testing.assert_array_equal(warm[:, :plen], ref[:, :plen])
         np.testing.assert_allclose(
-            warm[:, 1, plen : len(p2)], ref[:, 1, plen : len(p2)],
-            atol=2e-6, rtol=2e-5,
+            warm[:, plen:], ref[:, plen:], atol=2e-6, rtol=2e-5
         )
 
     toks = [tok]  # and the continuation matches token-for-token

@@ -9,16 +9,22 @@ widths in bf16, and must come out as a ``tpu_custom_call`` — the kernel,
 not its XLA stand-in. A compile that passes is not a chip run; it only
 means the chip run will not die at its first compile.
 
-Whole-step compiles (a minute each) stay in the builder's rehearsal.
+The serving cells' decode step and insert are compiled whole (seconds each):
+what they must not hold is a copy of the ring cache (ISSUE 29), and only the
+compiled program says whether they do. Training's whole-step compiles (a
+minute each) stay in the builder's rehearsal.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from opendiloco_tpu.models.ring_cache import cache_shape
+from opendiloco_tpu.ops import decode_kernels
 from opendiloco_tpu.ops.decode_kernels import (
     paged_decode_attention,
     spec_tail_attention_fused,
@@ -102,16 +108,21 @@ def test_fused_xent_fwd_bwd(chip):
 @pytest.mark.parametrize("return_stats", [False, True])
 @pytest.mark.parametrize("model", list(HEADS))
 def test_paged_decode_attention(chip, model, return_stats):
+    """The kernel alone, handed a cache of two layers and the second's
+    index: the read and the row write in one ``tpu_custom_call``."""
     hq, hkv, d = HEADS[model]
     s = 8
+    cache = (cache_shape(2, s, SEQ, hkv, d), BF16)
     text = compiled_text(
         chip,
-        lambda q, k, v, lens: paged_decode_attention(
-            q, k, v, lens, interpret=False, return_stats=return_stats
+        lambda q, k, v, ck, cv, lens: paged_decode_attention(
+            q, k, v, ck, cv, lens, 1, interpret=False, return_stats=return_stats
         ),
         ((s, hq, d), BF16),
-        ((s, SEQ, hkv, d), BF16),
-        ((s, SEQ, hkv, d), BF16),
+        ((s, hkv, d), BF16),
+        ((s, hkv, d), BF16),
+        cache,
+        cache,
         ((s,), jnp.int32),
     )
     assert "tpu_custom_call" in text
@@ -125,8 +136,8 @@ def _spec_text(chip, model, slots, kq, return_stats=False):
             q, ck, cv, tk, tv, lens, interpret=False, return_stats=return_stats
         ),
         ((slots, kq, hq, d), BF16),
-        ((slots, SEQ, hkv, d), BF16),
-        ((slots, SEQ, hkv, d), BF16),
+        (cache_shape(1, slots, SEQ, hkv, d)[1:], BF16),  # one layer's pages
+        (cache_shape(1, slots, SEQ, hkv, d)[1:], BF16),
         ((slots, kq, hkv, d), BF16),
         ((slots, kq, hkv, d), BF16),
         ((slots,), jnp.int32),
@@ -185,16 +196,22 @@ def test_w4_matmul(chip, model, proj, rows):
 HBM_BYTES = 16e9  # what the cell's sizing counts against
 
 
-def _olmoe_cell():
+def _serve_cell(config, workload, **cut):
+    """-> (a benchmark configuration as ``LlamaConfig``, with ``cut`` laid
+    over the published values, and its cell's engine options)."""
     import json
 
     from opendiloco_tpu.models.llama import LlamaConfig
 
     bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "configs", "olmoe-1b-7b.json")) as f:
-        cfg = LlamaConfig.from_dict(json.load(f))
-    with open(os.path.join(bench, "workloads", "serve-olmoe-fewshot.json")) as f:
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
+        cfg = LlamaConfig.from_dict({**json.load(f), **cut})
+    with open(os.path.join(bench, "workloads", f"{workload}.json")) as f:
         return cfg, json.load(f)["engine"]
+
+
+def _olmoe_cell():
+    return _serve_cell("olmoe-1b-7b", "serve-olmoe-fewshot")
 
 
 def _on_chip(chip, tree):
@@ -253,15 +270,17 @@ def test_olmoe_prefill_program_at_the_largest_bucket(chip):
     assert _program_bytes(compiled) + cache < HBM_BYTES
 
 
-def test_olmoe_decode_program_at_16_slots(chip):
+def test_olmoe_decode_program_at_16_slots(chip, monkeypatch):
     """16 slots, 16 KV heads of 128, the cell's rows a slot: the Pallas
     decode kernel and the grouped matmuls in one program that fits."""
     from opendiloco_tpu.models.llama import decode_forward, shapes
 
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
     cfg, engine = _olmoe_cell()
     slots, rows = engine["num_slots"], engine["max_context"]
     cache = jax.ShapeDtypeStruct(
-        (cfg.num_hidden_layers, slots, rows, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip
+        cache_shape(cfg.num_hidden_layers, slots, rows, cfg.kv_heads, cfg.head_dim),
+        BF16, sharding=chip,
     )
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
     compiled = (
@@ -274,3 +293,113 @@ def test_olmoe_decode_program_at_16_slots(chip):
     text = compiled.as_text()
     assert "odtp_paged_decode_attn" in text and "%ragged-dot" in text
     assert _program_bytes(compiled) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the decode step and the insert of both serving cells: nothing of the ring
+# cache's size, or of one layer's pages, is produced on the way (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+
+def _batch_cell():
+    """SmolLM2-360M's widths (15/5 heads of 64) at 8 of 32 layers, on the batch
+    cell's engine: 256 slots of 256 rows, buckets 32 and 128."""
+    return _serve_cell("smollm2-360m", "serve-360m-batch", num_hidden_layers=8)
+
+
+CELLS = {"smollm2-360m": _batch_cell, "olmoe-1b-7b": _olmoe_cell}
+
+_RESULT = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]")  # no tuples
+# what may yield a cache- or layer-shaped array without moving one: the
+# parameters themselves and their passage through the scan's tuples, and the
+# kernel, whose cache results alias its operands
+_MOVES_NOTHING = ("parameter(", "get-tuple-element(", "tpu_custom_call", "bitcast(")
+
+
+def _cache_shaped_results(text: str, cache_shape: tuple) -> list[str]:
+    """Instructions of a compiled program whose result has the dimensions of
+    the cache or of one layer's pages, in any order (a copy, a transpose, a
+    slice, an update, a scatter, a fresh buffer), other than those of
+    ``_MOVES_NOTHING``."""
+    def dims(shape):
+        return sorted(d for d in shape if d != 1)
+
+    wanted = (dims(cache_shape), dims(cache_shape[1:]))
+    found = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m or any(k in line for k in _MOVES_NOTHING):
+            continue
+        if dims(int(d) for d in m.group(3).split(",") if d) in wanted:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _serving_shapes(chip, cell):
+    from opendiloco_tpu.models.llama import shapes
+
+    cfg, engine = CELLS[cell]()
+    slots, rows = engine["num_slots"], engine["max_context"]
+    cache = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_hidden_layers, slots, rows, cfg.kv_heads, cfg.head_dim),
+        BF16, sharding=chip,
+    )
+    return cfg, engine, _on_chip(chip, shapes(cfg)), cache
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_decode_step_moves_no_cache(chip, cell, monkeypatch):
+    """The engine's ``_decode`` (kernel ``pallas``, caches donated) at the
+    cell's slots and rows: the decode kernel is in it; its temporaries stay under the bf16
+    copy of the weights plus one layer's pages; and no copy, transpose,
+    scatter, slice, update or fresh buffer in it has the shape of the cache
+    or of one layer's pages."""
+    from opendiloco_tpu.models.llama import decode_forward
+
+    # off the TPU the wrappers would interpret the kernel; this is the chip's
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, params, cache = _serving_shapes(chip, cell)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    moe = bool(cfg.num_experts)
+    compiled = (
+        jax.jit(
+            lambda p, tok, lens, ck, cv: decode_forward(
+                p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=moe),
+            donate_argnums=(3, 4),
+        ).lower(params, vec, vec, cache, cache).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "tpu_custom_call" in text
+    weights_bf16 = 2 * sum(x.size for x in jax.tree.leaves(params))
+    layer_pages_bytes = 2 * 2 * cache.size // cache.shape[0]  # K and V, bf16
+    assert mem.temp_size_in_bytes < weights_bf16 + layer_pages_bytes
+    assert mem.alias_size_in_bytes >= 2 * 2 * cache.size  # both caches, in place
+    assert not _cache_shaped_results(text, cache.shape)
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_insert_does_not_relay_the_cache(chip, cell):
+    """``_insert`` at each of the cell's prefill buckets: a prompt's rows land
+    in the cache's lane dimension, and the program still only updates the
+    donated buffers (nothing page-sized but the update itself)."""
+    from opendiloco_tpu.models.ring_cache import cache_insert
+
+    cfg, engine, _, cache = _serving_shapes(chip, cell)
+    layer_pages_bytes = 2 * 2 * cache.size // cache.shape[0]
+    for bucket in engine["prefill_buckets"]:
+        rows = jax.ShapeDtypeStruct(
+            (cfg.num_hidden_layers, bucket, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip
+        )
+        slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+        compiled = (
+            jax.jit(cache_insert, donate_argnums=(0, 1))
+            .lower(cache, cache, rows, rows, slot).compile()
+        )
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 2 * 2 * cache.size
+        assert mem.temp_size_in_bytes < layer_pages_bytes, (bucket, mem.temp_size_in_bytes)
+        moved = [
+            line for line in _cache_shaped_results(compiled.as_text(), cache.shape)
+            if "dynamic-update-slice" not in line
+        ]
+        assert not moved, (bucket, moved)
