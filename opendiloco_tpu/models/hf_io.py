@@ -61,6 +61,17 @@ def load_config(path_model: str) -> LlamaConfig:
 
 
 def _reject_moe(cfg: LlamaConfig, op: str) -> None:
+    if cfg.hybrid or cfg.shared_intermediate_size:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no Mamba-2 mixer (in_proj, conv1d, dt_bias, A_log, D, "
+            "its gated norm, out_proj), no shared MLP beside the experts and "
+            "no multipliers, and HF's granitemoehybrid layout (model.layers.N."
+            "mamba.*, .shared_mlp.*, .block_sparse_moe.*) is not mapped here. "
+            "Hybrid models train, serve and checkpoint through the framework "
+            "checkpointer (opendiloco_tpu.ckpt); only this import/export is "
+            "refused"
+        )
     if cfg.num_experts or cfg.qk_norm:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

@@ -26,6 +26,7 @@ from typing import Any, Literal, NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from opendiloco_tpu.models import mamba
 from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     cache_insert,
     init_kv_cache,
@@ -74,10 +75,98 @@ class LlamaConfig:
     router_z_loss_coef: float = 0.0
     # RMSNorm over the whole q and k projections before the split into heads
     qk_norm: bool = False
+    # A hybrid stack (the keys of a published ``granitemoehybrid``
+    # ``config.json``): ``layer_types`` names each layer's mixer, "attention"
+    # or "mamba" (None: attention everywhere); the ``mamba_*`` keys size the
+    # Mamba-2 mixer (``models/mamba.py``); ``shared_intermediate_size`` > 0
+    # adds a SwiGLU of that width, for every token, to the routed FFN's
+    # output; the four multipliers scale the embedding, each residual
+    # branch, the attention scores (None: 1/sqrt(head_dim)) and divide the
+    # logits; ``position_embedding_type`` "nope" leaves q and k unrotated
+    layer_types: Optional[tuple] = None
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    shared_intermediate_size: int = 0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    position_embedding_type: str = "rope"
+    # One chip's share of an expert-parallel deployment: the router keeps its
+    # ``num_experts`` outputs and its experts per token, the layer holds the
+    # weights of ``num_local_experts`` experts from ``first_local_expert`` on
+    # and computes their part of the result; a token's other experts add
+    # nothing here (None: every expert is held)
+    num_local_experts: Optional[int] = None
+    first_local_expert: int = 0
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            object.__setattr__(self, "layer_types", kinds)
+            if len(kinds) != self.num_hidden_layers or set(kinds) - {"attention", "mamba"}:
+                raise ValueError(
+                    f"layer_types must name {self.num_hidden_layers} layers, each "
+                    f"'attention' or 'mamba'; got {len(kinds)}: {sorted(set(kinds))}"
+                )
+        if self.hybrid:
+            if self.mamba_n_groups != 1 or not self.mamba_conv_bias or self.mamba_proj_bias:
+                raise ValueError(
+                    "the Mamba-2 mixer is written for mamba_n_groups 1, a conv "
+                    "bias and no projection bias; got mamba_n_groups "
+                    f"{self.mamba_n_groups}, mamba_conv_bias {self.mamba_conv_bias}, "
+                    f"mamba_proj_bias {self.mamba_proj_bias}"
+                )
+            if self.mamba_n_heads * self.mamba_d_head != self.mamba_expand * self.hidden_size:
+                raise ValueError(
+                    f"mamba_n_heads x mamba_d_head ({self.mamba_n_heads} x "
+                    f"{self.mamba_d_head}) is not mamba_expand x hidden_size"
+                )
+        if self.position_embedding_type not in ("rope", "nope"):
+            raise ValueError(
+                f"position_embedding_type {self.position_embedding_type!r}: 'rope' or 'nope'"
+            )
+        held = self.num_local_experts
+        if held is not None and not (
+            self.num_experts and 0 < held and 0 <= self.first_local_expert
+            and self.first_local_expert + held <= self.num_experts
+        ):
+            raise ValueError(
+                f"experts [{self.first_local_expert}, {self.first_local_expert} + "
+                f"{held}) are not among the router's {self.num_experts}"
+            )
 
     @property
     def kv_heads(self) -> int:
         return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def layer_kinds(self) -> tuple:
+        return self.layer_types or ("attention",) * self.num_hidden_layers
+
+    @property
+    def hybrid(self) -> bool:
+        """Does any layer hold a Mamba-2 mixer (and so a recurrent state)?"""
+        return "mamba" in self.layer_kinds
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.layer_kinds.count("mamba")
+
+    @property
+    def num_attention_layers(self) -> int:
+        return self.layer_kinds.count("attention")
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_experts if self.num_local_experts is None else self.num_local_experts
 
     @property
     def head_dim(self) -> int:
@@ -99,6 +188,22 @@ class LlamaConfig:
             # with a router z-loss of 0.001
             known.setdefault("qk_norm", True)
             known.setdefault("router_z_loss_coef", 0.001)
+        if known.get("layer_types") is not None:
+            # a file cut in depth keeps the published pattern whole and runs
+            # its leading num_hidden_layers entries
+            depth = known.get("num_hidden_layers", cls.num_hidden_layers)
+            known["layer_types"] = tuple(known["layer_types"])[:depth]
+        if raw.get("model_type") == "granitemoehybrid":
+            # the published key counts the experts; a file cut to one chip's
+            # share gives the held count there and the router's width beside
+            # it. The 10 weights are a softmax over the chosen logits, which
+            # is the softmax over all of them renormalised over the chosen.
+            # router_aux_loss_coef is no key of the catalog's config: 0
+            known.setdefault("num_experts", raw.get("num_local_experts", 0))
+            if known.get("num_local_experts") == known["num_experts"]:
+                del known["num_local_experts"]
+            known.setdefault("norm_topk_prob", True)
+            known.setdefault("router_aux_loss_coef", 0.0)
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
@@ -111,6 +216,12 @@ class LlamaConfig:
             hidden_act="silu",
             use_cache=False,
         )
+        if self.hybrid:
+            d.update(
+                architectures=["GraniteMoeHybridForCausalLM"],
+                model_type="granitemoehybrid",
+                layer_types=list(self.layer_types),
+            )
         return d
 
     def num_params(self) -> int:
@@ -131,41 +242,107 @@ def shapes(cfg: LlamaConfig) -> dict:
     def s(*shape):
         return jax.ShapeDtypeStruct(shape, f32)
 
-    E = cfg.num_experts
+    E, Eh = cfg.num_experts, cfg.held_experts
     ffn = (
         {
-            "router": s(L, D, E),
-            "gate_proj": s(L, E, D, F),
-            "up_proj": s(L, E, D, F),
-            "down_proj": s(L, E, F, D),
+            "router": (D, E),
+            "gate_proj": (Eh, D, F),
+            "up_proj": (Eh, D, F),
+            "down_proj": (Eh, F, D),
         }
         if E
-        else {
-            "gate_proj": s(L, D, F),
-            "up_proj": s(L, D, F),
-            "down_proj": s(L, F, D),
-        }
+        else {"gate_proj": (D, F), "up_proj": (D, F), "down_proj": (F, D)}
     )
-    qk_norm = (
-        {"q_norm": s(L, Nh * Dh), "k_norm": s(L, Nkv * Dh)} if cfg.qk_norm else {}
-    )
-    tree = {
-        "embed_tokens": s(V, D),
-        "layers": {
-            "input_norm": s(L, D),
-            "post_attn_norm": s(L, D),
-            "q_proj": s(L, D, Nh * Dh),
-            "k_proj": s(L, D, Nkv * Dh),
-            "v_proj": s(L, D, Nkv * Dh),
-            "o_proj": s(L, Nh * Dh, D),
-            **qk_norm,
-            **ffn,
-        },
-        "final_norm": s(D),
+    if cfg.shared_intermediate_size:
+        Fs = cfg.shared_intermediate_size
+        ffn.update(
+            shared_gate_proj=(D, Fs), shared_up_proj=(D, Fs), shared_down_proj=(Fs, D)
+        )
+    norms = {"input_norm": (D,), "post_attn_norm": (D,)}
+    attention = {
+        "q_proj": (D, Nh * Dh),
+        "k_proj": (D, Nkv * Dh),
+        "v_proj": (D, Nkv * Dh),
+        "o_proj": (Nh * Dh, D),
     }
+    if cfg.qk_norm:
+        attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
+
+    def stack(n, *groups):
+        return {k: s(n, *v) for g in groups for k, v in g.items()}
+
+    if cfg.hybrid:
+        # one stack per kind of mixer, each layer with its norms and FFN: the
+        # forwards scan runs of like layers out of them (``layer_runs``)
+        H, P, _, K, C = mamba.sizes(cfg)
+        mixer = {
+            "in_proj": (D, mamba.in_proj_width(cfg)),
+            "conv_weight": (K, C),
+            "conv_bias": (C,),
+            "dt_bias": (H,),
+            "A_log": (H,),
+            "D": (H,),
+            "mixer_norm": (H * P,),
+            "out_proj": (H * P, D),
+        }
+        layers = {"mamba": stack(cfg.num_mamba_layers, norms, mixer, ffn)}
+        if cfg.num_attention_layers:
+            layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
+    else:
+        layers = stack(L, norms, attention, ffn)
+    tree = {"embed_tokens": s(V, D), "layers": layers, "final_norm": s(D)}
     if not cfg.tie_word_embeddings:
         tree["lm_head"] = s(D, V)
     return tree
+
+
+class Run(NamedTuple):
+    """Consecutive layers with one kind of mixer: layers ``start`` to
+    ``start + count`` of that kind's stack."""
+
+    kind: str  # "attention" or "mamba"
+    start: int
+    count: int
+
+
+def layer_runs(cfg: LlamaConfig) -> list[Run]:
+    """The stack as runs of like layers, in order: one run for a stack of
+    attention layers, 5 Mamba / 1 attention / 4 Mamba for a period of the
+    granite hybrid. Each forward scans each run."""
+    runs, seen = [], {"attention": 0, "mamba": 0}
+    for kind in cfg.layer_kinds:
+        if runs and runs[-1].kind == kind:
+            runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
+        else:
+            runs.append(Run(kind, seen[kind], 1))
+        seen[kind] += 1
+    return runs
+
+
+def scan_layers(cfg: LlamaConfig, body, carry, layers: dict, run: Run, unroll: int = 1):
+    """``lax.scan`` of ``body(carry, layer, li) -> (carry, ys)`` over one
+    run's layers: ``layer`` the layer's weights, ``li`` its index in its
+    kind's stack (which is also its index in that kind's cache or state).
+
+    A stack of like layers is the scan's ``xs``. A hybrid's run is part of
+    its kind's stack: the scan runs over the indices and the body cuts its
+    layer out of the whole stack, which is what a scan does with its ``xs``
+    anyway; a static slice of the stack would be a copy of the run's weights
+    in every call."""
+    ids = run.start + jnp.arange(run.count, dtype=jnp.int32)
+    if not cfg.hybrid:
+        return jax.lax.scan(
+            lambda c, xs: body(c, *xs), carry, (layers, ids), unroll=unroll
+        )
+    stack = layers[run.kind]
+
+    def indexed(c, li):
+        layer = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False), stack
+        )
+        return body(c, layer, li)
+
+    return jax.lax.scan(indexed, carry, ids, unroll=unroll)
 
 
 def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
@@ -177,13 +354,32 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
     out = []
     for key, (path, leaf) in zip(keys, leaves):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if "norm" in name:
+        if "norm" in name or name == "D":
             out.append(jnp.ones(leaf.shape, leaf.dtype))
+        elif name in ("A_log", "dt_bias", "conv_weight", "conv_bias"):
+            out.append(_init_mixer_leaf(name, key, leaf))
         else:
             out.append(
                 jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.initializer_range
             )
     return jax.tree.unflatten(treedef, out)
+
+
+def _init_mixer_leaf(name: str, key: jax.Array, leaf) -> jax.Array:
+    """The Mamba-2 reference initialisation (arXiv 2405.21060's code), so
+    that a fresh model's decays lie where a trained one's do: ``A`` uniform
+    in [1, 16], ``dt`` log-uniform in [1e-3, 1e-1] (``dt_bias`` its inverse
+    softplus), and the depthwise conv as ``torch.nn.Conv1d`` draws it,
+    uniform in +-1/sqrt(K)."""
+    if name == "A_log":
+        return jnp.log(jax.random.uniform(key, leaf.shape, leaf.dtype, 1.0, 16.0))
+    if name == "dt_bias":
+        dt = jnp.exp(
+            jax.random.uniform(key, leaf.shape, leaf.dtype, jnp.log(1e-3), jnp.log(1e-1))
+        )
+        return dt + jnp.log(-jnp.expm1(-dt))
+    bound = leaf.shape[-2] ** -0.5 if name == "conv_weight" else 0.5
+    return jax.random.uniform(key, leaf.shape, leaf.dtype, -bound, bound)
 
 
 # the rematerialization policy accepted everywhere a `remat` argument
@@ -262,11 +458,22 @@ def _rope_apply(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1)
 
 
+def _rope(cfg: LlamaConfig, positions: jax.Array):
+    """The layers' shared (cos, sin) tables, or (None, None) for a model
+    whose attention takes no positions (``position_embedding_type`` nope)."""
+    if cfg.position_embedding_type == "nope":
+        return None, None
+    return _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+
+
 def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
     """The attention block's projections of x [B, T, D]: q [B, T, Nh, Dh] and
-    k [B, T, Nkv, Dh] rotated by position, and v. ``mul(x, w)`` is the
-    caller's weight matmul. With ``cfg.qk_norm`` q and k pass an RMSNorm
-    over their whole width before they are split into heads (OLMoE)."""
+    k [B, T, Nkv, Dh] rotated by position (as they are where ``cos`` is
+    None), and v. ``mul(x, w)`` is the caller's weight matmul. With
+    ``cfg.qk_norm`` q and k pass an RMSNorm over their whole width before
+    they are split into heads (OLMoE). Every attention reader scales the
+    scores by 1/sqrt(Dh); a configuration that states another scale
+    (``attention_multiplier``) has the ratio put on q here."""
     B, T, _ = x.shape
     Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     q = mul(x, layer["q_proj"])
@@ -275,8 +482,11 @@ def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
     if cfg.qk_norm:
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-    q = _rope_apply(q.reshape(B, T, Nh, Dh), cos, sin)
-    k = _rope_apply(k.reshape(B, T, Nkv, Dh), cos, sin)
+    if cfg.attention_multiplier is not None:
+        q = q * jnp.asarray(cfg.attention_multiplier * Dh**0.5, q.dtype)
+    q, k = q.reshape(B, T, Nh, Dh), k.reshape(B, T, Nkv, Dh)
+    if cos is not None:
+        q, k = _rope_apply(q, cos, sin), _rope_apply(k, cos, sin)
     return q, k, v
 
 
@@ -302,7 +512,14 @@ def _routed_ffn(
 
     Counts, int32 [3]: token-expert pairs, experts with at least one pair,
     the busiest expert's pairs -- of the ``live`` tokens ([N] bool; None:
-    all), so padding rows of a prefill bucket and empty slots do not count."""
+    all), so padding rows of a prefill bucket and empty slots do not count.
+
+    A layer that holds a share of the experts (``num_local_experts`` from
+    ``first_local_expert`` on) routes over all of them all the same; the
+    pairs of its own experts sort first, by expert, and are the groups of
+    the matmuls, the other pairs lie behind the last group and are zeroed.
+    The aux loss is of the whole routing. The counts are then of the held
+    experts' pairs, and a fourth gives the pairs of all experts."""
     D = x.shape[-1]
     E, K = cfg.num_experts, cfg.num_experts_per_tok
     xf = x.reshape(-1, D)
@@ -315,26 +532,50 @@ def _routed_ffn(
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
 
     flat = expert.reshape(-1)  # pair p belongs to token p // K
-    sizes = jnp.bincount(flat, length=E)  # int32, one group per expert
-    order = jnp.argsort(flat, stable=True)  # pairs by expert
+    share = cfg.num_local_experts is not None
+    if share:
+        Eh = cfg.num_local_experts
+        group = flat - cfg.first_local_expert
+        held = (group >= 0) & (group < Eh)
+        group = jnp.where(held, group, Eh)  # the others sort behind the groups
+    else:
+        Eh, group = E, flat
+
+    def group_sizes(weights=None):  # int32, one group per held expert
+        return jnp.bincount(group, weights=weights, length=Eh + share)[:Eh]
+
+    sizes = group_sizes()
+    order = jnp.argsort(group, stable=True)  # pairs by expert
     xs = xf[order // K]
     h = jax.nn.silu(
         jax.lax.ragged_dot(xs, layer["gate_proj"], sizes)
     ) * jax.lax.ragged_dot(xs, layer["up_proj"], sizes)
     ys = jax.lax.ragged_dot(h, layer["down_proj"], sizes)  # [N * K, D]
-    ys = ys[jnp.argsort(order)].reshape(N, K, D)
-    out = jnp.sum(ys.astype(jnp.float32) * gate[..., None], axis=1)
+    ys = ys[jnp.argsort(order)]
+    if share:
+        ys = jnp.where(held[:, None], ys, 0)
+    out = jnp.sum(ys.reshape(N, K, D).astype(jnp.float32) * gate[..., None], axis=1)
 
-    balance = E * jnp.sum(sizes / N * jnp.mean(probs, axis=0))
+    chosen = jnp.bincount(flat, length=E) if share else sizes
+    balance = E * jnp.sum(chosen / N * jnp.mean(probs, axis=0))
     z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     aux = cfg.router_aux_loss_coef * balance + cfg.router_z_loss_coef * z
 
+    pairs_all = jnp.int32(N * K)
     if live is not None:
-        sizes = jnp.bincount(
-            flat, weights=jnp.repeat(live.reshape(-1), K).astype(jnp.int32), length=E
-        )
-    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
-    return out.astype(x.dtype).reshape(x.shape), aux, counts
+        counted = jnp.repeat(live.reshape(-1), K).astype(jnp.int32)
+        sizes, pairs_all = group_sizes(counted), jnp.sum(counted)
+    counts = [jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)]
+    if share:
+        counts.append(pairs_all)
+    return out.astype(x.dtype).reshape(x.shape), aux, jnp.stack(counts).astype(jnp.int32)
+
+
+def _swiglu(x, layer: dict, mul, prefix: str = ""):
+    return mul(
+        jax.nn.silu(mul(x, layer[prefix + "gate_proj"])) * mul(x, layer[prefix + "up_proj"]),
+        layer[prefix + "down_proj"],
+    )
 
 
 def _ffn(
@@ -342,22 +583,24 @@ def _ffn(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The block's feed-forward over x [..., D] -> (out, aux loss, routing
     counts): SwiGLU through the caller's weight matmul ``mul(x, w)``, or the
-    routed experts; aux and counts are zero for a dense model."""
+    routed experts, and beside those, where the configuration has one, a
+    shared SwiGLU that every token passes; aux and counts are zero for a
+    dense model."""
     if cfg.num_experts:
-        return _routed_ffn(cfg, x, layer, live)
-    out = mul(
-        jax.nn.silu(mul(x, layer["gate_proj"])) * mul(x, layer["up_proj"]),
-        layer["down_proj"],
-    )
-    return out, jnp.float32(0.0), jnp.zeros((3,), jnp.int32)
+        out, aux, counts = _routed_ffn(cfg, x, layer, live)
+    else:
+        out, aux, counts = _swiglu(x, layer, mul), jnp.float32(0.0), jnp.zeros((3,), jnp.int32)
+    if cfg.shared_intermediate_size:
+        out = out + _swiglu(x, layer, mul, "shared_")
+    return out, aux, counts
 
 
 class BlockOut(NamedTuple):
     """What one layer leaves beside the hidden state."""
 
-    k: jax.Array  # this layer's keys, rotated [B, T, Nkv, Dh]
-    v: jax.Array  # and values
-    attn_out: jax.Array  # the attention branch after o_proj [B, T, D]
+    k: Optional[jax.Array]  # this layer's keys, rotated [B, T, Nkv, Dh]
+    v: Optional[jax.Array]  # and values (None for a layer without attention)
+    attn_out: jax.Array  # the mixer's branch after its output projection [B, T, D]
     aux: jax.Array  # the routed FFN's weighted aux loss (0 for a dense one)
     counts: jax.Array  # the routed FFN's counts, int32 [3] (``_routed_ffn``)
 
@@ -366,46 +609,65 @@ def decoder_block(
     cfg: LlamaConfig,
     h: jax.Array,
     layer: dict,
-    cos: jax.Array,
-    sin: jax.Array,
+    cos: Optional[jax.Array],
+    sin: Optional[jax.Array],
     *,
     mul,
-    attend,
+    attend=None,
+    mix=None,
     live: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, BlockOut]:
     """One decoder layer over h [B, T, D], the only statement of its
-    skeleton: RMSNorm, q/k/v, attention, o_proj, residual; RMSNorm, FFN,
-    residual. Training and the four serving forwards differ in what they
-    pass: ``mul(x, w)`` is the caller's weight matmul, ``attend(q, k, v)``
-    its attention over this layer's q [B, T, Nh, Dh] and new k, v (a cache
-    it reads or writes is the caller's own), ``live`` the tokens a routed
-    FFN counts."""
+    skeleton: RMSNorm, the mixer, residual; RMSNorm, FFN, residual. The
+    mixer is attention (q/k/v, ``attend``, o_proj) or, where the caller
+    passes ``mix``, whatever that computes from the normed input (the
+    Mamba-2 mixer of a hybrid stack). Training and the serving forwards
+    differ in what they pass: ``mul(x, w)`` is the caller's weight matmul,
+    ``attend(q, k, v)`` its attention over this layer's q [B, T, Nh, Dh] and
+    new k, v, ``mix(x, layer)`` its mixer over x [B, T, D] (a cache or a
+    state either reads or writes is the caller's own), ``live`` the tokens a
+    routed FFN counts. Both branches enter the residual under the
+    configuration's ``residual_multiplier``."""
     B, T, _ = h.shape
+    scale = cfg.residual_multiplier
     # the scopes name the device work in a profiler trace (an operation's
     # op_name metadata); they change nothing that is computed
-    with jax.named_scope("odtp_attention"):
-        x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-        attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
-        h = h + attn_out
+    if mix is None:
+        with jax.named_scope("odtp_attention"):
+            x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
+            q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
+            attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
+    else:
+        k = v = None
+        with jax.named_scope("odtp_ssm"):
+            x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
+            attn_out = mix(x, layer)
+    h = h + (attn_out if scale == 1.0 else attn_out * jnp.asarray(scale, h.dtype))
     with jax.named_scope("odtp_mlp"):
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
         ffn, aux, counts = _ffn(cfg, x, layer, mul, live)
+    if scale != 1.0:
+        ffn = ffn * jnp.asarray(scale, h.dtype)
     return h + ffn, BlockOut(k, v, attn_out, aux, counts)
 
 
 def training_block(
-    cfg: LlamaConfig, attn_fn, positions: jax.Array, remat: RematPolicy
+    cfg: LlamaConfig, attn_fn, positions: jax.Array, remat: RematPolicy,
+    kind: str = "attention",
 ):
-    """The body of training's scan over layers, ``(h, layer) -> (h, (attn-
-    output L2 norm, moe aux loss))``, under the rematerialization policy.
-    The norm is the activation probe the reference attaches via forward
-    hooks on ``self_attn`` (utils.py:43-67, train_fsdp.py:65)."""
-    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    """The body of training's scan over a run of ``kind`` layers
+    (``scan_layers``), ``(h, layer, li) -> (h, (mixer-output L2 norm, moe
+    aux loss))``, under the rematerialization policy. The norm is the activation probe the reference
+    attaches via forward hooks on ``self_attn`` (utils.py:43-67,
+    train_fsdp.py:65)."""
+    cos, sin = _rope(cfg, positions)
+    mix = None
+    if kind == "mamba":
+        mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer, jnp.matmul)[0]
 
-    def body(h, layer):
+    def body(h, layer, li=None):
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attn_fn
+            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attn_fn, mix=mix
         )
         with jax.named_scope("odtp_attention"):
             attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
@@ -414,12 +676,23 @@ def training_block(
     return _maybe_remat(body, remat)
 
 
+def _embed(cfg: LlamaConfig, cparams: dict, ids: jax.Array) -> jax.Array:
+    """Token embeddings of ``ids``, under the ``embedding_multiplier``."""
+    h = jnp.take(cparams["embed_tokens"], ids, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
+    return h
+
+
 def _final_norm_and_head(
     cfg: LlamaConfig, cparams: dict, h: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    """-> (h under the final RMSNorm, the head [D, V]: the embedding's
-    transpose where the configuration ties them)."""
+    """-> (h under the final RMSNorm and divided by the configuration's
+    ``logits_scaling``, the head [D, V]: the embedding's transpose where the
+    configuration ties them)."""
     h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
+    if cfg.logits_scaling != 1.0:  # the logits are h @ head wherever taken
+        h = h / jnp.asarray(cfg.logits_scaling, h.dtype)
     head = (
         cparams["embed_tokens"].T
         if cfg.tie_word_embeddings
@@ -508,7 +781,7 @@ def forward(
     else:
         raise ValueError(f"unknown attn_impl {attn_impl!r}")
 
-    h = jnp.take(cparams["embed_tokens"], input_ids, axis=0)
+    h = _embed(cfg, cparams, input_ids)
 
     if pp_mesh is not None:
         # decoder stack staged over the pp mesh axis (parallel/pipeline.py);
@@ -531,7 +804,6 @@ def forward(
         )
         attn_norms = jnp.zeros((cfg.num_hidden_layers,), jnp.float32)
     else:
-        block = training_block(cfg, attn_fn, positions, remat)
         # Unroll the layer scan N-wide (N >= num layers removes the while
         # loop entirely). The trainer auto-resolves scan_unroll to FULL
         # unroll on TPU for dense stacks (measured +6.8% tok/s on the
@@ -542,8 +814,16 @@ def forward(
         # compiled-HLO cost model when the stack is unrolled.
         env_unroll = os.environ.get("ODTP_SCAN_UNROLL")
         unroll = int(env_unroll) if env_unroll else (scan_unroll or 1)
-        h, (attn_norms, layer_auxs) = jax.lax.scan(
-            block, h, cparams["layers"], unroll=max(1, unroll)
+        probes = []  # one scan per run of like layers (one, but for a hybrid)
+        for run in layer_runs(cfg):
+            h, probe = scan_layers(
+                cfg, training_block(cfg, attn_fn, positions, remat, run.kind),
+                h, cparams["layers"], run, unroll=max(1, unroll),
+            )
+            probes.append(probe)
+        attn_norms, layer_auxs = (
+            probes[0] if len(probes) == 1
+            else tuple(jnp.concatenate(p) for p in zip(*probes))
         )
         moe_aux = jnp.mean(layer_auxs)
 
@@ -652,17 +932,37 @@ def _cast_serving_params(params, dtype):
 
 
 def _serving_boundary(params, compute_dtype, decode_kernel):
-    """What the four serving forwards do first -> (the weights cast to the
+    """What the serving forwards do first -> (the weights cast to the
     compute dtype, their weight matmul ``mul(x, w)``)."""
     cparams = _cast_serving_params(params, compute_dtype)
     mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
     return cparams, mul
 
 
+def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
+    """A slot's past as rows that can be copied, cut or left out is what
+    ``what`` rests on; a Mamba-2 layer's past is one state, which is none
+    of those."""
+    if cfg.hybrid:
+        raise ValueError(
+            f"{what} is refused for a configuration with Mamba-2 layers "
+            f"({cfg.num_mamba_layers} of {cfg.num_hidden_layers}): it treats a "
+            "slot's past as cache rows, and a recurrent state cannot be cut at "
+            "a row or rolled back"
+        )
+
+
 def _logits(cfg: LlamaConfig, cparams: dict, h: jax.Array) -> jax.Array:
     """Final norm and lm head over h [..., D] -> float32 logits [..., V]."""
     h, head = _final_norm_and_head(cfg, cparams, h)
     return (h @ head).astype(jnp.float32)
+
+
+def _stacked(parts: list) -> Optional[jax.Array]:
+    """The runs' per-layer outputs as one stack, in layer order."""
+    if not parts:
+        return None
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def prefill_forward(
@@ -676,35 +976,61 @@ def prefill_forward(
     return_moe_counts: bool = False,
 ):
     """Prompt prefill for serving: ids [1, P] -> (last-token logits [1, V]
-    f32, per-layer K/V [L, P, Nkv, Dh] in compute dtype), and with
-    ``return_moe_counts`` the routed FFN's counts over the live prompt
-    tokens summed over layers (int32 [3], see ``_routed_ffn``).
+    f32, per-layer K/V [La, P, Nkv, Dh] in compute dtype over the attention
+    layers); for a hybrid stack then the Mamba-2 layers' recurrent states
+    [Lm, H, P, N] float32 and conv tails [Lm, K - 1, C], as the last real
+    token left them; and with ``return_moe_counts`` last the routed FFN's
+    counts over the live prompt tokens summed over layers (int32, see
+    ``_routed_ffn``).
 
     ``length`` (traced scalar) is the true prompt length; ``input_ids``
     may be right-padded to a compile-size bucket. Padding K/V rows do land
     in the returned stack (and hence the cache) but are never attended:
     the decode mask stops at the live length and every ring write
-    overwrites index ``len % T`` before index ``len`` becomes visible."""
+    overwrites index ``len % T`` before index ``len`` becomes visible. A
+    recurrent state has no rows to mask: the mixer itself stops at
+    ``length`` (``mamba.ssm_chunked``)."""
     B, P = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
-    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     live = positions < length
     attend = lambda q, k, v: xla_attention(q, k, v, causal=True)
 
-    def body(h, layer):
+    def attention_body(h, layer, li):
         h, out = decoder_block(
             cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
         )
         return h, (out.k[0], out.v[0], out.counts)
 
-    h = jnp.take(cparams["embed_tokens"], input_ids, axis=0)
-    h, (ks, vs, counts) = jax.lax.scan(body, h, cparams["layers"])
+    def mamba_body(h, layer, li):
+        left = []
+
+        def mix(x, layer):
+            out, state, tail = mamba.ssm_chunked(cfg, x, layer, mul, length)
+            left.extend((state[0], tail[0]))
+            return out
+
+        h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live)
+        return h, (*left, out.counts)
+
+    h = _embed(cfg, cparams, input_ids)
+    kept = {"attention": ([], []), "mamba": ([], [])}
+    counts = []
+    for run in layer_runs(cfg):
+        body = attention_body if run.kind == "attention" else mamba_body
+        h, (a, b, c) = scan_layers(cfg, body, h, cparams["layers"], run)
+        kept[run.kind][0].append(a)
+        kept[run.kind][1].append(b)
+        counts.append(c)
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = _logits(cfg, cparams, h_last)
+    out = [logits[:, 0], *map(_stacked, kept["attention"])]
+    if cfg.hybrid:
+        out.extend(map(_stacked, kept["mamba"]))
     if return_moe_counts:
-        return logits[:, 0], ks, vs, jnp.sum(counts, axis=0)
-    return logits[:, 0], ks, vs
+        out.append(jnp.sum(_stacked(counts), axis=0))
+    return tuple(out)
 
 
 def decode_forward(
@@ -718,6 +1044,8 @@ def decode_forward(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     decode_kernel: str = "xla",
     return_moe_counts: bool = False,
+    ssm_state: Optional[jax.Array] = None,
+    conv_state: Optional[jax.Array] = None,
 ):
     """One incremental decode step over all S slots.
 
@@ -736,11 +1064,19 @@ def decode_forward(
     tests/test_tpu_compile.py). The XLA path (off the TPU, and the per-call
     fallback for a shape the kernel cannot tile) scatters the row and
     slices the layer's pages, with copies where the compiler wants them.
+
+    A hybrid stack also takes the slots' recurrent states ``ssm_state``
+    [Lm, S, H, P, N] float32 and conv tails ``conv_state`` [Lm, S, K - 1, C]
+    (``ring_cache.init_ssm_state``) and returns both, updated, after the
+    caches; the caches then hold the attention layers alone. Each run of
+    Mamba-2 layers carries the two through its scan, reads a layer's part
+    and writes it back in place (jitted with both donated).
+
     With ``return_moe_counts`` the routed FFN's counts over the slots that
-    hold a sequence (``lens > 0``), summed over layers, come fourth."""
+    hold a sequence (``lens > 0``), summed over layers, come last."""
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
-    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     live = lens > 0
     step_attention = (
         paged_decode_attention
@@ -748,9 +1084,8 @@ def decode_forward(
         else decode_step_attention
     )
 
-    def body(carry, xs):
+    def attention_body(carry, layer, li):
         h, ck, cv = carry  # the whole caches
-        layer, li = xs
 
         def attend(q, k, v):
             nonlocal ck, cv
@@ -764,15 +1099,40 @@ def decode_forward(
         )
         return (h, ck, cv), out.counts
 
-    h = jnp.take(cparams["embed_tokens"], tokens, axis=0)[:, None]  # [S, 1, D]
-    layer_ids = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
-    (h, new_ck, new_cv), counts = jax.lax.scan(
-        body, (h, cache_k, cache_v), (cparams["layers"], layer_ids)
-    )
+    def mamba_body(carry, layer, li):
+        h, states, tails = carry  # every Mamba-2 layer's
+
+        def mix(x, layer):
+            nonlocal states, tails
+            out, state, tail = mamba.ssm_step(
+                cfg, x[:, 0], layer, mul, states[li], tails[li]
+            )
+            states = jax.lax.dynamic_update_index_in_dim(states, state, li, 0)
+            tails = jax.lax.dynamic_update_index_in_dim(tails, tail, li, 0)
+            return out[:, None]
+
+        h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live)
+        return (h, states, tails), out.counts
+
+    h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
+    counts = []
+    for run in layer_runs(cfg):
+        if run.kind == "attention":
+            (h, cache_k, cache_v), c = scan_layers(
+                cfg, attention_body, (h, cache_k, cache_v), cparams["layers"], run
+            )
+        else:
+            (h, ssm_state, conv_state), c = scan_layers(
+                cfg, mamba_body, (h, ssm_state, conv_state), cparams["layers"], run
+            )
+        counts.append(c)
     logits = _logits(cfg, cparams, h)
+    out = [logits[:, 0], cache_k, cache_v]
+    if cfg.hybrid:
+        out.extend((ssm_state, conv_state))
     if return_moe_counts:
-        return logits[:, 0], new_ck, new_cv, jnp.sum(counts, axis=0)
-    return logits[:, 0], new_ck, new_cv
+        out.append(jnp.sum(_stacked(counts), axis=0))
+    return tuple(out)
 
 
 def _tail_attention(decode_kernel: str):
@@ -809,10 +1169,11 @@ def verify_forward(
     Also the continued-prefill primitive for shared-prefix KV reuse
     (S = 1, tail = the suffix tokens, lens = the reused prefix length).
     """
+    refuse_recurrent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
     S, K = tail.shape
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
-    cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     tail_attention = _tail_attention(decode_kernel)
 
     def body(h, xs):
@@ -821,7 +1182,7 @@ def verify_forward(
         h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, attend=attend)
         return h, (out.k, out.v)
 
-    h = jnp.take(cparams["embed_tokens"], tail, axis=0)  # [S, K, D]
+    h = _embed(cfg, cparams, tail)  # [S, K, D]
     h, (tail_ks, tail_vs) = jax.lax.scan(
         body, h, (cparams["layers"], cache_k, cache_v)
     )
@@ -850,6 +1211,7 @@ def draft_propose(
     the ring -- the draft is a heuristic and dirties nothing; exactness
     is the verify pass's job. Returns proposals [S, k_steps] int32.
     """
+    refuse_recurrent(cfg, "the self-speculative draft")
     S = tokens.shape[0]
     L, Ld = cfg.num_hidden_layers, int(draft_layers)
     if not 1 <= Ld <= L:
@@ -866,7 +1228,7 @@ def draft_propose(
     proposals = []
     for i in range(k_steps):
         positions = (lens + jnp.int32(i))[:, None]  # [S, 1]
-        cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        cos, sin = _rope(cfg, positions)
 
         def body(h, xs, i=i, cos=cos, sin=sin):
             layer, ck, cv, tk, tv = xs
@@ -880,7 +1242,7 @@ def draft_propose(
             h, _ = decoder_block(cfg, h, layer, cos, sin, mul=mul, attend=attend)
             return h, (tk, tv)
 
-        h = jnp.take(cparams["embed_tokens"], cur, axis=0)[:, None]  # [S, 1, D]
+        h = _embed(cfg, cparams, cur)[:, None]  # [S, 1, D]
         h, (tkb, tvb) = jax.lax.scan(body, h, (dlayers, dck, dcv, tkb, tvb))
         logits = _logits(cfg, cparams, h)
         cur = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)

@@ -1,5 +1,6 @@
 """The serving KV cache: slot-paged ring pages, and everything that knows
-how they are stored.
+how they are stored; beside it, for a hybrid stack, the Mamba-2 layers'
+per-slot recurrent state (``init_ssm_state``, ``state_insert``).
 
 Storage is a ``k`` and a ``v`` array of ``[L, S, Nkv, Dh, T]``: one
 fixed-size ring page of T rows per layer and batch slot (the degenerate
@@ -34,6 +35,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from opendiloco_tpu.models import mamba
+
 
 def init_kv_cache(
     cfg,
@@ -41,12 +44,41 @@ def init_kv_cache(
     max_context: int,
     dtype: jnp.dtype = jnp.bfloat16,
 ) -> dict:
-    """Zeroed {"k","v"} pages for ``cfg`` (its layers, KV heads and head
-    size): ``num_slots`` rings of ``max_context`` rows a layer."""
+    """Zeroed {"k","v"} pages for ``cfg`` (its attention layers, KV heads
+    and head size): ``num_slots`` rings of ``max_context`` rows a layer."""
     shape = cache_shape(
-        cfg.num_hidden_layers, num_slots, max_context, cfg.kv_heads, cfg.head_dim
+        cfg.num_attention_layers, num_slots, max_context, cfg.kv_heads, cfg.head_dim
     )
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def init_ssm_state(cfg, num_slots: int, dtype: jnp.dtype = jnp.bfloat16) -> dict:
+    """Zeroed {"ssm","conv"} for ``cfg``'s Mamba-2 layers: per layer and
+    slot the recurrent state [H, P, N] (float32 always) and the conv tail
+    [K - 1, channels] (the last inputs of the depthwise conv, channels
+    minor-most as the chip tiles them)."""
+    ssm, conv = mamba.state_shapes(cfg, num_slots)
+    return {"ssm": jnp.zeros(ssm, jnp.float32), "conv": jnp.zeros(conv, dtype)}
+
+
+def state_insert(
+    ssm: jax.Array,
+    conv: jax.Array,
+    states: jax.Array,
+    tails: jax.Array,
+    slot: jax.Array,
+) -> tuple[jax.Array, jax.Array]:
+    """Write what a prefill left (states [Lm, H, P, N], tails [Lm, K - 1, C])
+    into ``slot`` (traced scalar), whole: unlike a ring page, a recurrent
+    state has no stale part to mask, so a slot's next tenant starts from its
+    own prefill and nothing else."""
+    slot = jnp.asarray(slot, jnp.int32)
+
+    def put(store, x):
+        start = (jnp.int32(0), slot) + (jnp.int32(0),) * (store.ndim - 2)
+        return jax.lax.dynamic_update_slice(store, x[:, None].astype(store.dtype), start)
+
+    return put(ssm, states), put(conv, tails)
 
 
 def cache_shape(

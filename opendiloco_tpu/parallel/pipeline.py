@@ -67,6 +67,12 @@ def pipeline_hidden(
     the standard GPipe semantics for batch-statistic losses. 0.0 for
     dense models.
     """
+    if cfg.hybrid:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration with Mamba-2 "
+            "layers: it stages one homogeneous [L, ...] stack, and a hybrid's "
+            "layers are stacked per kind of mixer (llama.layer_runs)"
+        )
     B, T, D = h0.shape
     M = microbatches
     if B % M:

@@ -42,6 +42,23 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "up_proj": (1, 0),
     "down_proj": (0, 1),  # [F, D] (or [E, F, D])
     "router": (None, 0),  # [D, E]: small, fsdp on D
+    # the shared SwiGLU beside a routed FFN (granite hybrid): as a dense FFN's
+    "shared_gate_proj": (1, 0),  # [D, Fs]
+    "shared_up_proj": (1, 0),
+    "shared_down_proj": (0, 1),  # [Fs, D]
+    # the Mamba-2 mixer (models/mamba.py). Its in_proj's output is z | xBC |
+    # dt side by side and its conv runs over all of xBC's channels, so tp
+    # has no dim that keeps a head's parts together: fsdp only. The decay
+    # vectors (dt_bias, A_log, D), conv_bias and mixer_norm are [H] or [C]
+    # vectors and replicate like the norms
+    "in_proj": (None, 0),  # [D, 2HP + 2N + H]
+    "out_proj": (None, 1),  # [HP, D]
+    "conv_weight": (None, 1),  # [K, C]
+    "conv_bias": (None, -1),
+    "dt_bias": (None, -1),
+    "A_log": (None, -1),
+    "D": (None, -1),
+    "mixer_norm": (None, -1),
 }
 
 # FFN leaves that gain a leading expert dim when num_experts > 0

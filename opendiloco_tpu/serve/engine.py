@@ -61,16 +61,19 @@ from opendiloco_tpu.models.llama import (
     dequant_w4,
     draft_propose,
     prefill_forward,
+    refuse_recurrent,
     verify_forward,
 )
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
     fetch_pages,
     init_kv_cache,
+    init_ssm_state,
     layer_pages,
     prefix_copy,
     slot_cache,
     spec_cache_insert,
+    state_insert,
     suffix_insert,
 )
 from opendiloco_tpu.ops.attention import decode_step_attention, spec_tail_attention
@@ -92,8 +95,8 @@ def _fresh_copy(leaves):
 
 def _with_counts(tok, counts):
     """``tok`` with a routed model's FFN counts appended (``counts``: a list
-    holding the int32 [3], or empty for a dense model, whose program then
-    returns its tokens as they are)."""
+    holding the int32 [3] or [4], or empty for a dense model, whose program
+    then returns its tokens as they are)."""
     return jnp.concatenate([tok, *counts]) if counts else tok
 
 
@@ -141,6 +144,13 @@ class ServeEngine:
         self.weight_format = str(weight_format)
         if self.weight_format not in ("fp32", "w4"):
             raise ValueError(f"unknown weight_format {weight_format!r}")
+        if cfg.hybrid and self.weight_format == "w4":
+            raise ValueError(
+                "weight_format=w4 is refused for a configuration with Mamba-2 "
+                "layers: the blockwise 4-bit packing is defined for the [L, in, "
+                "out] matmul leaves of one homogeneous stack, not for a mixer's "
+                "in_proj/out_proj, conv and decay leaves"
+            )
         # "auto"/None resolves to pallas only on TPU backends; tests force
         # "pallas" explicitly and the kernels run interpreted off-TPU
         self.decode_kernel = resolve_decode_kernel(decode_kernel)
@@ -148,6 +158,7 @@ class ServeEngine:
         if self.spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if self.spec_k:
+            refuse_recurrent(cfg, f"speculative decode (spec_k={self.spec_k})")
             L = cfg.num_hidden_layers
             ld = int(draft_layers) or max(1, L // 2)
             if not 1 <= ld < L:
@@ -193,41 +204,64 @@ class ServeEngine:
         self.moe_pairs = 0
         self.moe_experts_hit = 0
         self.moe_max_pairs = 0
+        # a layer that holds a share of the experts counts its own experts'
+        # pairs above, and here the pairs of all the router's experts
+        self.moe_pairs_all = 0
+        # what the Mamba-2 mixers did (always on; stay 0 for a model without
+        # them): tokens that passed them (a prompt's tokens, a decode step's
+        # live slots), and the bytes of recurrent state and conv tail the
+        # calls read and wrote (a prefill writes one slot's, a decode step
+        # reads and writes every slot's)
+        self.ssm_tokens = 0
+        self.ssm_state_bytes_moved = 0
 
         cache = init_kv_cache(cfg, self.num_slots, self.max_context, compute_dtype)
         self.cache_k, self.cache_v = cache["k"], cache["v"]
+        # the slots' second kind of state: empty for a stack of attention layers
+        self._ssm: tuple = ()
+        if cfg.hybrid:
+            state = init_ssm_state(cfg, self.num_slots, compute_dtype)
+            self._ssm = (state["ssm"], state["conv"])
+        self.ssm_state_resident_bytes = sum(x.nbytes for x in self._ssm)
 
         cd = compute_dtype
         dkn = self.decode_kernel
         moe = bool(cfg.num_experts)
+        n_ssm = len(self._ssm)
 
         # one named scope per program: what a profiler trace calls the
         # device work of a prefill and of a decode step. A routed model's
         # programs append the FFN's three counts to the tokens, so that one
         # device-to-host read fetches both (``_split_counts``)
+        # (a hybrid's programs hand the recurrent state and the conv tail
+        # on after the K/V: ``left`` is those two, or nothing)
         def _prefill(p, ids, length):
             with jax.named_scope("odtp_serve_prefill"):
-                logits, ks, vs, *counts = prefill_forward(
+                logits, ks, vs, *rest = prefill_forward(
                     p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
                     return_moe_counts=moe,
                 )
+                left, counts = rest[:n_ssm], rest[n_ssm:]
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return _with_counts(tok, counts), logits, ks, vs
+            return (_with_counts(tok, counts), logits, ks, vs, *left)
 
-        def _decode(p, tokens, lens, ck, cv):
+        def _decode(p, tokens, lens, ck, cv, *ssm):
             with jax.named_scope("odtp_serve_decode"):
-                logits, ck, cv, *counts = decode_forward(
+                state = dict(zip(("ssm_state", "conv_state"), ssm))
+                logits, ck, cv, *rest = decode_forward(
                     p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
-                    decode_kernel=dkn, return_moe_counts=moe,
+                    decode_kernel=dkn, return_moe_counts=moe, **state,
                 )
+                left, counts = rest[:n_ssm], rest[n_ssm:]
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return _with_counts(tok, counts), logits, ck, cv
+            return (_with_counts(tok, counts), logits, ck, cv, *left)
 
         # one compile per prompt bucket; insert/decode compile once. The
         # insert also takes a slot's pages back from the host tier
         self._prefill = jax.jit(_prefill)
         self._insert = jax.jit(cache_insert, donate_argnums=(0, 1))
-        self._decode = jax.jit(_decode, donate_argnums=(3, 4))
+        self._state_insert = jax.jit(state_insert, donate_argnums=(0, 1))
+        self._decode = jax.jit(_decode, donate_argnums=tuple(range(3, 5 + n_ssm)))
 
         # speculative-decode jits (compiled only when spec_step runs)
         kk, ld = self.spec_k, self.draft_layers
@@ -332,6 +366,10 @@ class ServeEngine:
                 f"prompt length {n} exceeds max bucket "
                 f"{self.prefill_buckets[-1]}"
             )
+        if (host_prefix is not None and 0 < host_prefix[2] < n) or (
+            prefix_src is not None and 0 < prefix_len < n
+        ):
+            refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         t0 = time.perf_counter()
         moe = {}  # a continued prefill's routing is not counted
         if host_prefix is not None and 0 < host_prefix[2] < n:
@@ -348,13 +386,16 @@ class ServeEngine:
         else:
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :n] = np.asarray(prompt, np.int32)
-            tokd, logitsd, ks, vs = self._prefill(
+            tokd, logitsd, ks, vs, *left = self._prefill(
                 self.params, jnp.asarray(ids), jnp.int32(n)
             )
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v, ks, vs, jnp.int32(slot)
             )
+            if left:  # the recurrent state the prompt left, whole
+                self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
             toks, moe = self._split_counts(np.asarray(tokd), 1)
+            moe.update(self._count_ssm(n, sum(x.nbytes for x in left)))
             tok, logits = int(toks[0]), np.asarray(logitsd[0])
         dt = time.perf_counter() - t0
         self.stage_seconds["prefill"] += dt
@@ -363,19 +404,32 @@ class ServeEngine:
             tr.add_span("serve_prefill", t0, t0 + dt, tokens=n, **moe)
         return tok, logits
 
+    def _count_ssm(self, tokens: int, state_bytes: int) -> dict:
+        """Add one call's Mamba-2 work to the engine's counters -> the same
+        as span attributes (nothing for a model without mixers)."""
+        if not self._ssm:
+            return {}
+        self.ssm_tokens += tokens
+        self.ssm_state_bytes_moved += state_bytes
+        return {"ssm_tokens": tokens, "ssm_state_bytes": state_bytes}
+
     def _split_counts(self, fetched: np.ndarray, n: int) -> tuple[np.ndarray, dict]:
         """One program's fetched token output -> (its ``n`` tokens, span
-        attributes): a routed model's three counts follow the tokens and are
-        added to the engine's counters here."""
+        attributes): a routed model's counts follow the tokens (three, and a
+        fourth where the layer holds a share of the experts) and are added to
+        the engine's counters here."""
         if fetched.size == n:
             return fetched, {}
-        pairs, hit, busiest = (int(x) for x in fetched[n:])
+        pairs, hit, busiest, *everywhere = (int(x) for x in fetched[n:])
         self.moe_pairs += pairs
         self.moe_experts_hit += hit
         self.moe_max_pairs += busiest
-        return fetched[:n], {
-            "moe_pairs": pairs, "moe_experts_hit": hit, "moe_max_pairs": busiest,
-        }
+        attrs = {"moe_pairs": pairs, "moe_experts_hit": hit, "moe_max_pairs": busiest}
+        # a layer that holds every expert reports three: all pairs are its own
+        self.moe_pairs_all += everywhere[0] if everywhere else pairs
+        if everywhere:
+            attrs["moe_pairs_all"] = everywhere[0]
+        return fetched[:n], attrs
 
     def _admit_suffix(
         self, slot: int, prompt: Sequence[int], src: int, plen: int
@@ -432,6 +486,7 @@ class ServeEngine:
         iteration so the transfer overlaps the next decode step instead
         of blocking the loop. The gather is by value: the slot can be
         re-tenanted immediately."""
+        refuse_recurrent(self.cfg, "the host tier's page-out")
         t0 = time.perf_counter()
         pk, pv = self._fetch_pages(
             self.cache_k, self.cache_v, jnp.int32(slot), self.page_rows(rows)
@@ -449,6 +504,7 @@ class ServeEngine:
         of ``slot`` are rewritten from the host arrays. Dispatch is
         async — the next decode step queues behind it on-stream, so the
         scheduler thread never blocks on the transfer."""
+        refuse_recurrent(self.cfg, "the host tier's page-in")
         t0 = time.perf_counter()
         self.cache_k, self.cache_v = self._insert(
             self.cache_k, self.cache_v,
@@ -468,14 +524,19 @@ class ServeEngine:
         masked positions and are overwritten on the slot's next tenancy).
         Returns (next tokens [S] np.int32, logits [S, V] on device)."""
         t0 = time.perf_counter()
-        tok, logits, self.cache_k, self.cache_v = self._decode(
+        tok, logits, self.cache_k, self.cache_v, *ssm = self._decode(
             self.params,
             jnp.asarray(tokens, jnp.int32),
             jnp.asarray(lens, jnp.int32),
             self.cache_k,
             self.cache_v,
+            *self._ssm,
         )
+        self._ssm = tuple(ssm)
         tok, moe = self._split_counts(np.asarray(tok), self.num_slots)
+        moe.update(
+            self._count_ssm(int(np.count_nonzero(lens)), 2 * self.ssm_state_resident_bytes)
+        )
         t1 = time.perf_counter()
         self.stage_seconds["decode"] += t1 - t0
         tr = obs.tracer()

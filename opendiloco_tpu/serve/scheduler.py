@@ -125,6 +125,13 @@ class ContinuousBatcher:
         tier_min_resident_steps: int = 2,
     ):
         self.engine = engine
+        if engine.cfg.hybrid and (prefix_cache or kv_tier is not None):
+            refused = "prefix_cache" if prefix_cache else "kv_tier"
+            raise ValueError(
+                f"{refused} is refused for a configuration with Mamba-2 layers: "
+                "prefix reuse and the host tier copy, cut and restore a slot's "
+                "past as cache rows, and a recurrent state is not rows"
+            )
         self.max_queue = int(max_queue)
         self.swap_every_steps = max(1, int(swap_every_steps))
         self.gauge_every_steps = max(1, int(gauge_every_steps))

@@ -403,3 +403,87 @@ def test_insert_does_not_relay_the_cache(chip, cell):
             if "dynamic-update-slice" not in line
         ]
         assert not moved, (bucket, moved)
+
+
+# ---------------------------------------------------------------------------
+# the granite-4.0-h cell (ISSUE 30): the prefill at its largest bucket and the
+# decode step at its slots, published widths, ten layers; they fit the chip
+# beside what the engine holds, and the recurrent state is updated where it is
+# ---------------------------------------------------------------------------
+
+
+def _granite_cell(chip):
+    """-> (configuration, engine options, parameters, one cache array, the
+    recurrent state, the conv tails), as shapes on the described chip."""
+    from opendiloco_tpu.models import mamba
+    from opendiloco_tpu.models.llama import shapes
+
+    cfg, engine = _serve_cell("granite-4.0-h-small", "serve-granite-h-docqa")
+    slots, rows = engine["num_slots"], engine["max_context"]
+    cache = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_attention_layers, slots, rows, cfg.kv_heads, cfg.head_dim),
+        BF16, sharding=chip,
+    )
+    ssm, conv = mamba.state_shapes(cfg, slots)
+    return (
+        cfg, engine, _on_chip(chip, shapes(cfg)), cache,
+        jax.ShapeDtypeStruct(ssm, jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct(conv, BF16, sharding=chip),
+    )
+
+
+def test_granite_prefill_program_at_the_largest_bucket(chip):
+    from opendiloco_tpu.models.llama import prefill_forward
+
+    cfg, engine, params, cache, ssm, conv = _granite_cell(chip)
+    assert (cfg.num_mamba_layers, cfg.num_attention_layers, cfg.held_experts) == (9, 1, 9)
+    bucket = max(engine["prefill_buckets"])
+    compiled = (
+        jax.jit(lambda p, ids, n: prefill_forward(
+            p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=True))
+        .lower(
+            params,
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
+        ).compile()
+    )
+    assert "%ragged-dot" in compiled.as_text()
+    # the prefill runs beside the resident ring, state and tails
+    resident = 2 * 2 * cache.size + 4 * ssm.size + 2 * conv.size
+    assert _program_bytes(compiled) + resident < HBM_BYTES
+
+
+def test_granite_decode_step_updates_the_state_in_place(chip, monkeypatch):
+    """32 slots: the Pallas decode kernel over the one attention layer's ring,
+    the grouped matmuls over the 9 held experts, and the two runs of Mamba-2
+    layers carrying 1.2 GB of recurrent state that is aliased to the output
+    and never copied: the temporaries stay under the bf16 copy of the weights
+    plus one layer's state, and no ``copy`` has the state's shape."""
+    from opendiloco_tpu.models.llama import decode_forward
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, params, cache, ssm, conv = _granite_cell(chip)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(
+            lambda p, tok, lens, ck, cv, s, c: decode_forward(
+                p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=True,
+                ssm_state=s, conv_state=c),
+            donate_argnums=(3, 4, 5, 6),
+        ).lower(params, vec, vec, cache, cache, ssm, conv).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "%ragged-dot" in text
+    assert _program_bytes(compiled) < HBM_BYTES
+    weights_bf16 = 2 * sum(x.size for x in jax.tree.leaves(params))
+    layer_state = 4 * ssm.size // ssm.shape[0]
+    assert mem.temp_size_in_bytes < weights_bf16 + layer_state
+    assert mem.alias_size_in_bytes >= 2 * 2 * cache.size + 4 * ssm.size + 2 * conv.size
+    state_dims = ",".join(str(d) for d in ssm.shape)
+    layer_dims = ",".join(str(d) for d in ssm.shape[1:])
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if " copy(" in line and (f"f32[{state_dims}]" in line or f"f32[{layer_dims}]" in line
+                                 or f"f32[1,{layer_dims}]" in line)
+    ]
+    assert not copies, copies
