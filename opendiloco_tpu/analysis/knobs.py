@@ -224,7 +224,8 @@ KNOBS: tuple[Knob, ...] = (
     Knob("ODTP_DECODE_WEIGHT_FORMAT", "str", "", "serve",
          "Replica weight residency override for the serve plane: `w4` keeps "
          "stacked matmul weights blockwise-4bit packed at rest (dequantized "
-         "per block inside the jit'd decode); `fp32` restores today's layout.",
+         "per block inside the jit'd decode); `fp32` leaves them unpacked (float32 "
+         "masters in, held in the compute dtype).",
          doc_default="config"),
     Knob("ODTP_KV_HOST_SLOTS", "int", "", "serve",
          "Host KV-tier budget: paused slot pages + prefix-store entries it "

@@ -233,9 +233,10 @@ class ServeConfig(BaseModel):
     spec_decode_k: int = 0
     # draft depth; 0 = auto (half the stack, min 1); must stay < num layers
     draft_layers: int = 0
-    # replica weight residency: "fp32" (today's layout) or "w4" (stacked
+    # replica weight residency: "fp32" (not packed: float32 masters come in
+    # and every leaf is held in the engine's compute dtype) or "w4" (stacked
     # matmul weights blockwise-4bit packed at rest, dequantized per block
-    # inside the jit'd decode; norms/embeddings/lm head stay fp32)
+    # inside the jit'd decode; norms/embeddings/lm head unpacked as above)
     weight_format: Literal["fp32", "w4"] = "fp32"
     # decode-path kernel dispatch: "auto" picks the Pallas serving kernels
     # (paged decode attention, fused W4 dequant-matmul, fused speculative

@@ -924,7 +924,10 @@ def _wmul(x, w, dtype, kernel="xla"):
 
 def _cast_serving_params(params, dtype):
     """The forward-boundary cast, w4-aware: packed uint8/uint16 leaves
-    stay packed (their dequant targets ``dtype`` at the matmul site)."""
+    stay packed (their dequant targets ``dtype`` at the matmul site). A
+    leaf that already has ``dtype`` emits nothing: ``ServeEngine`` binds
+    its weights in the compute dtype, so its programs hold no cast, and a
+    caller with a float32 tree gets the same rounding here, per call."""
     return jax.tree.map(
         lambda x: x if x.dtype in (jnp.uint8, jnp.uint16) else x.astype(dtype),
         params,
@@ -932,8 +935,9 @@ def _cast_serving_params(params, dtype):
 
 
 def _serving_boundary(params, compute_dtype, decode_kernel):
-    """What the serving forwards do first -> (the weights cast to the
-    compute dtype, their weight matmul ``mul(x, w)``)."""
+    """What the serving forwards do first -> (the weights in the compute
+    dtype, cast here unless they came in it, their weight matmul
+    ``mul(x, w)``)."""
     cparams = _cast_serving_params(params, compute_dtype)
     mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
     return cparams, mul

@@ -2,12 +2,19 @@
 hot-swap.
 
 The engine owns its OWN device copy of the weights plus the slot-paged
-ring KV cache, and exposes a handful of device operations to the
-scheduler loop — ``admit`` (prefill a prompt into a free slot, optionally
-continuing from a reused prefix), ``decode_step`` (one token for every
-live slot), ``spec_step`` (self-speculative draft + verify, several
-tokens per live slot), and ``maybe_swap`` (adopt a newer master snapshot
-from the outer plane). All are called from a single scheduler thread;
+ring KV cache. That copy, ``engine.params``, is the one tree its programs
+take as their argument, and it is held in ``compute_dtype``: the masters
+arrive in float32 (or whatever the caller trains in), are rounded to the
+compute dtype once as they are bound (``_bind``: construction,
+``install_params``, ``install_wire``) and the float32 values are not kept,
+so no prefill or decode program starts by casting the whole tree again
+(``weight_binds`` counts the bindings, ``weights_resident_bytes`` is what
+they left on the device). The engine exposes a handful of device
+operations to the scheduler loop — ``admit`` (prefill a prompt into a
+free slot, optionally continuing from a reused prefix), ``decode_step``
+(one token for every live slot), ``spec_step`` (self-speculative draft +
+verify, several tokens per live slot), and ``maybe_swap`` (adopt a newer
+master snapshot from the outer plane). All are called from a single scheduler thread;
 the engine is deliberately not thread-safe so the jits can donate the
 cache buffers without a lock.
 
@@ -23,11 +30,16 @@ bit-identical to the plain engine):
   given exactly the tokens before it.
 - ``weight_format="w4"``: the stacked decoder matmul weights stay
   blockwise-4bit packed at rest (PR 8 codec geometry, per layer) and
-  dequantize per block inside the jit'd forwards; norms, embeddings and
-  the lm head stay fp32. ~4x fewer weight bytes touched per decode step,
+  dequantize per block inside the jit'd forwards; norms, embeddings, the
+  lm head, and a routed FFN's router and experts are held in the compute
+  dtype like every leaf of the other format. ~4x fewer weight bytes
+  touched per decode step,
   and ``install_wire`` of a blockwise4bit snapshot re-slices the wire
   payload directly into the resident layout when block and layer grids
   align (no dequant/requantize round trip).
+- ``weight_format="fp32"`` (the default) means *not packed*: float32
+  masters come in and are held in the compute dtype, as above. The name is
+  what arrives, not what is resident.
 - prefix reuse (scheduler-driven): ``admit(..., prefix_src, prefix_len)``
   ring-copies a live slot's prefix K/V and prefills only the suffix.
 
@@ -41,6 +53,7 @@ weights may lag (DiLoCo-fresh serving, arXiv 2311.08105).
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Optional, Sequence
 
@@ -86,11 +99,13 @@ from opendiloco_tpu.ops.decode_kernels import (
 from opendiloco_tpu.serve.kvcache import accept_counts, pick_bucket
 
 
-@jax.jit
-def _fresh_copy(leaves):
-    # fresh f32 buffers: the caller may pass live train-state leaves that
-    # the next train_step donates (same add-zero idiom as the outer plane)
-    return [x.astype(jnp.float32) + jnp.zeros((), jnp.float32) for x in leaves]
+@functools.partial(jax.jit, static_argnums=1)
+def _fresh_copy(leaves, dtype):
+    # fresh buffers in the engine's dtype: the caller may pass live
+    # train-state leaves that the next train_step donates (same add-zero
+    # idiom as the outer plane). The rounding is ``astype``'s, the one the
+    # forwards' boundary applies to a tree that has not met it yet
+    return [x.astype(dtype) + jnp.zeros((), dtype) for x in leaves]
 
 
 def _with_counts(tok, counts):
@@ -185,14 +200,14 @@ class ServeEngine:
         self._shapes = [tuple(x.shape) for x in leaves]
         # w4-packable set: the stacked decoder matmuls ([L, in, out] leaves
         # under "layers"); norms ([L, D]), embeddings, the lm head, and a
-        # routed FFN's router and [L, E, in, out] experts stay fp32
+        # routed FFN's router and [L, E, in, out] experts stay unpacked
         self._packable = [
             p[0] == "layers" and len(s) == 3 and p[-1] != "router"
             for p, s in zip(self._paths, self._shapes)
         ]
-        self.params = self._assemble(leaves)
-        self.weights_epoch = int(epoch)
-        self.swap_count = 0
+        # bindings of a weight tree so far: 1 here, +1 a swap
+        self.weight_binds = 0
+        self._bind(leaves, epoch)
         self.swap_seconds = 0.0
         # wall-clock per decode stage (loop-thread only, mirrored to obs
         # spans when a tracer is armed; the bench reads this directly)
@@ -315,27 +330,42 @@ class ServeEngine:
 
     # -- weight residency ---------------------------------------------------
 
-    def _assemble(self, leaves):
-        """Rebuild the params tree from flat leaves (original flatten
-        order). ``weight_format=w4`` packs the stacked matmul leaves into
-        :class:`PackedW4` nodes (or adopts pre-packed ones from the
-        install_wire fast path); everything else lands as f32 buffers."""
-        if self.weight_format != "w4":
-            return jax.tree.unflatten(self._treedef, _fresh_copy(leaves))
-        out = []
-        for leaf, packable, shape in zip(leaves, self._packable, self._shapes):
-            if isinstance(leaf, PackedW4):
-                out.append(leaf)
-            elif packable:
-                q, s = pack_blockwise4_stacked(
-                    np.asarray(jax.device_get(leaf), np.float32)
-                )
-                out.append(
-                    PackedW4(jnp.asarray(q), jnp.asarray(s), tuple(shape[1:]))
-                )
-            else:
-                out.append(jnp.asarray(jax.device_get(leaf), jnp.float32))
-        return jax.tree.unflatten(self._treedef, out)
+    def _bind(self, leaves, epoch: int) -> None:
+        """The one door weights come through: flat leaves (original flatten
+        order; float32 masters on the device or the host, or pre-packed
+        :class:`PackedW4` nodes from the install_wire fast path) become
+        ``self.params``, the tree every program of the engine reads.
+
+        Every leaf lands in a fresh buffer in ``compute_dtype``, rounded here
+        once, and the engine keeps nothing else of it; ``weight_format=w4``
+        first packs the stacked matmul leaves, which stay packed."""
+        out = list(leaves)
+        if self.weight_format == "w4":
+            for i, (leaf, packable) in enumerate(zip(leaves, self._packable)):
+                if packable and not isinstance(leaf, PackedW4):
+                    q, s = pack_blockwise4_stacked(
+                        np.asarray(jax.device_get(leaf), np.float32)
+                    )
+                    out[i] = PackedW4(
+                        jnp.asarray(q), jnp.asarray(s), self._shapes[i][1:]
+                    )
+        fresh = iter(_fresh_copy(
+            [x for x in out if not isinstance(x, PackedW4)], self.compute_dtype
+        ))
+        out = [x if isinstance(x, PackedW4) else next(fresh) for x in out]
+        self.params = jax.tree.unflatten(self._treedef, out)
+        self.weights_epoch = int(epoch)
+        self.weight_binds += 1
+        self.weights_resident_bytes = sum(
+            x.nbytes for x in jax.tree.leaves(self.params)
+        )
+        obs.count("serve_weight_binds")
+        obs.gauge("serve_weights_resident_bytes", self.weights_resident_bytes)
+
+    @property
+    def swap_count(self) -> int:
+        """Weight trees adopted after the one the engine was built with."""
+        return self.weight_binds - 1
 
     # -- admission ---------------------------------------------------------
 
@@ -757,12 +787,8 @@ class ServeEngine:
                 codec.decode(payload, (size,), meta), np.float32
             ).reshape(shape)
             leaves.append(a)
-        self.params = self._assemble(leaves)
-        self.weights_epoch = int(epoch)
-        self.swap_count += 1
+        self._bind(leaves, epoch)
 
     def install_params(self, epoch: int, params) -> None:
         """Direct (uncompressed) rebind — tests and static-weight mode."""
-        self.params = self._assemble(jax.tree.leaves(params))
-        self.weights_epoch = int(epoch)
-        self.swap_count += 1
+        self._bind(jax.tree.leaves(params), epoch)

@@ -478,6 +478,7 @@ def test_fleet_end_to_end_inprocess(tiny_cfg):
     (pings only) its reported staleness crosses max_stale_rounds and
     /healthz flips stale — the acceptance staleness bound."""
     import jax
+    import jax.numpy as jnp
 
     from opendiloco_tpu.fleet import FleetManager
     from opendiloco_tpu.fleet.replica import Replica
@@ -509,14 +510,18 @@ def test_fleet_end_to_end_inprocess(tiny_cfg):
         assert wait(rep.ready), "replica never onboarded from a keyframe"
         assert rep.engine.weights_epoch == 0
 
-        # engine weights == decoded keyframe == publisher shadow, bit-exact
+        # engine weights == decoded keyframe == publisher shadow, to the bit
+        # at the dtype the engine holds them in
+        held = rep.engine.compute_dtype
         with rep._lock:
             mailbox = [lf.copy() for lf in rep._leaves]
         for got, want in zip(
             jax.tree.leaves(rep.engine.params), mailbox
         ):
+            assert got.dtype == held
             np.testing.assert_array_equal(
-                np.asarray(got, np.float32).reshape(-1), want
+                np.asarray(got, np.float32).reshape(-1),
+                np.asarray(jnp.asarray(want).astype(held), np.float32),
             )
 
         # follow staggered deltas for five outer epochs

@@ -338,7 +338,8 @@ def test_master_snapshot_wire_codec_override(tiny_cfg, monkeypatch):
 
 def test_snapshot_feeds_engine_swap(tiny_cfg):
     """The optimizer's wire snapshot installs cleanly into the engine and
-    the engine's weights then match the masters to fp16 precision."""
+    the engine's weights then match the masters to fp16 precision, as the
+    engine holds them: in its compute dtype (float32 here)."""
     opt, _, state = _make_opt(tiny_cfg)
     engine, _ = make_engine(tiny_cfg, seed=9)
     epoch, blobs, codec_name = opt.master_snapshot_wire()
@@ -346,8 +347,11 @@ def test_snapshot_feeds_engine_swap(tiny_cfg):
     _, masters = opt.master_snapshot()
     got = jax.tree.leaves(engine.params)
     for g, m in zip(got, masters):
+        assert g.dtype == engine.compute_dtype
         np.testing.assert_allclose(
-            np.asarray(g), np.asarray(m), atol=2e-3, rtol=2e-3
+            np.asarray(g, np.float32),
+            np.asarray(jnp.asarray(m).astype(engine.compute_dtype), np.float32),
+            atol=2e-3, rtol=2e-3,
         )
 
 

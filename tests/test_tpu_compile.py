@@ -487,3 +487,129 @@ def test_granite_decode_step_updates_the_state_in_place(chip, monkeypatch):
                                  or f"f32[1,{layer_dims}]" in line)
     ]
     assert not copies, copies
+
+
+# ---------------------------------------------------------------------------
+# the serving weights bound once (ISSUE 31): given the tree as the engine holds
+# it, the three cells' decode program and largest prefill cast no weight leaf
+# ---------------------------------------------------------------------------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)$"
+)
+
+
+def _leaf_shaped_casts(text: str, leaf_shapes: set, dtype: str = "bf16") -> list[str]:
+    """Instructions of a compiled program that run as an operation of their
+    own (outside the fused computations) and are a ``convert``, or a fusion
+    whose root is one, with a result in ``dtype`` of a weight leaf's shape:
+    what ``_serving_boundary`` emits for a leaf that did not come in the
+    compute dtype."""
+    roots, fused, top_level, name = {}, set(), [], None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line.strip())
+        if m:
+            name = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        root, _, result, dims, opcode, rest = m.groups()
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        called = re.search(r"calls=(%[\w.\-]+)", rest) if opcode == "fusion" else None
+        if called:
+            fused.add(called.group(1))
+        if root:
+            roots[name] = opcode
+        top_level.append((name, opcode, result, shape, called and called.group(1), line.strip()[:160]))
+    return [
+        line for comp, opcode, result, shape, called, line in top_level
+        if comp not in fused and result == dtype and shape in leaf_shapes
+        and (opcode == "convert" or roots.get(called) == "convert")
+    ]
+
+
+def _bound(chip, cfg):
+    """The parameters as ``ServeEngine._bind`` leaves them under bf16 compute
+    (every leaf through the engine's own ``_fresh_copy``), as shapes on the
+    described chip."""
+    from opendiloco_tpu.models.llama import shapes
+    from opendiloco_tpu.serve.engine import _fresh_copy
+
+    leaves, treedef = jax.tree.flatten(shapes(cfg))
+    held = jax.eval_shape(lambda xs: _fresh_copy(xs, BF16), leaves)
+    assert all(x.dtype == BF16 for x in held)
+    return _on_chip(chip, jax.tree.unflatten(treedef, held))
+
+
+SERVE_CELLS = {
+    "serve-360m-batch": "smollm2-360m",
+    "serve-olmoe-fewshot": "olmoe-1b-7b",
+    "serve-granite-h-docqa": "granite-4.0-h-small",
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("workload", list(SERVE_CELLS))
+def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
+    """Each serve cell whole, published widths, lowered as the engine lowers
+    it (kernel ``pallas``, counts where routed, caches and state donated)
+    with the bf16 tree the engine holds: no cast of a weight leaf is left,
+    the temporaries are a fraction of the weights (the per-call bf16 copy was
+    all of them: 0.73 / 3.63 / 3.83 GB in the three decode programs at the
+    parent), and a decode step still updates caches and state where they
+    are."""
+    from opendiloco_tpu.models import mamba
+    from opendiloco_tpu.models.llama import decode_forward, prefill_forward
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine = _serve_cell(SERVE_CELLS[workload], workload)
+    params = _bound(chip, cfg)
+    moe = bool(cfg.num_experts)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    if program == "prefill":
+        bucket = max(engine["prefill_buckets"])
+        compiled = (
+            jax.jit(lambda p, ids, n: prefill_forward(
+                p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=moe))
+            .lower(params, jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip), scalar)
+            .compile()
+        )
+        carried = 0
+    else:
+        slots, rows = engine["num_slots"], engine["max_context"]
+        layers = cfg.num_attention_layers if cfg.hybrid else cfg.num_hidden_layers
+        cache = jax.ShapeDtypeStruct(
+            cache_shape(layers, slots, rows, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip
+        )
+        state = []
+        if cfg.hybrid:
+            ssm, conv = mamba.state_shapes(cfg, slots)
+            state = [
+                jax.ShapeDtypeStruct(ssm, jnp.float32, sharding=chip),
+                jax.ShapeDtypeStruct(conv, BF16, sharding=chip),
+            ]
+        vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+
+        def step(p, tok, lens, ck, cv, *ssm):
+            return decode_forward(
+                p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=moe,
+                **dict(zip(("ssm_state", "conv_state"), ssm)),
+            )
+
+        compiled = (
+            jax.jit(step, donate_argnums=tuple(range(3, 5 + len(state))))
+            .lower(params, vec, vec, cache, cache, *state).compile()
+        )
+        carried = sum(x.size * x.dtype.itemsize for x in (cache, cache, *state))
+    leaves = jax.tree.leaves(params)
+    assert not _leaf_shaped_casts(compiled.as_text(), {tuple(x.shape) for x in leaves})
+    mem = compiled.memory_analysis()
+    weights = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert mem.argument_size_in_bytes >= weights + carried
+    # compiled here: decode 0.10 / 0.27 / 0.06 GB, prefill 0.10 / 1.24 / 0.62 GB
+    # (batch / OLMoE / granite), a prefill's being its attention scores
+    assert mem.temp_size_in_bytes < weights / (2 if program == "prefill" else 4)
+    assert mem.alias_size_in_bytes >= carried
+    assert _program_bytes(compiled) < HBM_BYTES
