@@ -61,6 +61,18 @@ def load_config(path_model: str) -> LlamaConfig:
 
 
 def _reject_moe(cfg: LlamaConfig, op: str) -> None:
+    if cfg.latent or cfg.leading_dense:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no latent attention (q_a_proj, q_a_layernorm, q_b_proj, "
+            "kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj), no selection bias "
+            "(mlp.gate.e_score_correction_bias), no shared experts and no dense "
+            "layer before the expert layers, and HF's glm4_moe_lite layout is "
+            "not mapped here (its rotated values are interleaved, these are in "
+            "halves). Such models train, serve and checkpoint through the "
+            "framework checkpointer (opendiloco_tpu.ckpt); only this "
+            "import/export is refused"
+        )
     if cfg.hybrid or cfg.shared_intermediate_size:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

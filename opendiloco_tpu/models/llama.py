@@ -36,11 +36,13 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
 )
 from opendiloco_tpu.ops.attention import (
     decode_step_attention,
+    latent_decode_step_attention,
     spec_tail_attention,
     xla_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     W4_BLOCK,
+    mla_decode_attention,
     paged_decode_attention,
     spec_tail_attention_fused,
     w4_matmul,
@@ -106,6 +108,34 @@ class LlamaConfig:
     # nothing here (None: every expert is held)
     num_local_experts: Optional[int] = None
     first_local_expert: int = 0
+    # Latent attention and the routed FFN of a published ``glm4_moe_lite``
+    # ``config.json`` (the DeepSeek-V2/V3 block, arXiv 2405.04434):
+    # ``kv_lora_rank`` > 0 makes every attention layer latent. q passes a
+    # low-rank pair (``q_lora_rank``, normed between); one row of
+    # ``kv_lora_rank`` normed values and ``qk_rope_head_dim`` rotated ones a
+    # token is all the cache keeps, and each head's ``qk_nope_head_dim``
+    # unrotated key values and ``v_head_dim`` values are rebuilt from it
+    # (training, prefill) or never built (decode, absorbed into q and the
+    # output). ``first_k_dense_replace`` leading layers keep a dense SwiGLU
+    # of ``intermediate_size`` where the others route over experts of
+    # ``moe_intermediate_size`` (0: ``intermediate_size``) beside
+    # ``n_shared_experts`` shared ones of that width; ``topk_method``
+    # "noaux_tc" scores by sigmoid, chooses under a bias it does not weigh
+    # by (``router_bias``) and scales the weights by
+    # ``routed_scaling_factor``; ``n_group``/``topk_group`` limit the choice
+    # to groups of experts (1: no limit, the only value written here)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    topk_method: str = "greedy"
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -133,6 +163,38 @@ class LlamaConfig:
             raise ValueError(
                 f"position_embedding_type {self.position_embedding_type!r}: 'rope' or 'nope'"
             )
+        if self.latent:
+            if not (self.q_lora_rank and self.qk_nope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                    "qk_nope_head_dim and v_head_dim; got "
+                    f"{self.q_lora_rank}, {self.qk_nope_head_dim}, {self.v_head_dim}"
+                )
+            if self.qk_rope_head_dim < 2 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"qk_rope_head_dim {self.qk_rope_head_dim}: the rotated part "
+                    "of a latent head is an even number of values"
+                )
+            if self.hybrid or self.qk_norm or self.position_embedding_type != "rope":
+                raise ValueError(
+                    "latent attention is written for a stack of rotated attention "
+                    "layers: no Mamba-2 layers, no qk_norm, no 'nope'"
+                )
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"topk_method {self.topk_method!r}: 'greedy' or 'noaux_tc'")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError(
+                "group-limited routing is not written: n_group and topk_group "
+                f"must be 1; got {self.n_group}, {self.topk_group}"
+            )
+        if self.leading_dense and (
+            self.layer_types is not None or self.leading_dense >= self.num_hidden_layers
+        ):
+            raise ValueError(
+                f"first_k_dense_replace {self.first_k_dense_replace} needs a stack "
+                f"of more attention layers than that ({self.num_hidden_layers}) and "
+                "no layer_types"
+            )
         held = self.num_local_experts
         if held is not None and not (
             self.num_experts and 0 < held and 0 <= self.first_local_expert
@@ -148,8 +210,26 @@ class LlamaConfig:
         return self.num_key_value_heads or self.num_attention_heads
 
     @property
+    def leading_dense(self) -> int:
+        """Leading layers whose FFN is a dense SwiGLU in a routed model."""
+        return self.first_k_dense_replace if self.num_experts else 0
+
+    @property
     def layer_kinds(self) -> tuple:
-        return self.layer_types or ("attention",) * self.num_hidden_layers
+        """Each layer's kind, which names its mixer and its FFN: "attention"
+        (attention and the configuration's FFN), "mamba" (a Mamba-2 mixer
+        and that FFN), "dense" (attention and a dense SwiGLU, the leading
+        layers of a routed model)."""
+        if self.layer_types:
+            return self.layer_types
+        k = self.leading_dense
+        return ("dense",) * k + ("attention",) * (self.num_hidden_layers - k)
+
+    @property
+    def layers_by_kind(self) -> bool:
+        """Are the layers' weights one stack per kind (a dict of stacks)
+        and not one stack of like layers?"""
+        return self.hybrid or bool(self.leading_dense)
 
     @property
     def hybrid(self) -> bool:
@@ -162,7 +242,36 @@ class LlamaConfig:
 
     @property
     def num_attention_layers(self) -> int:
-        return self.layer_kinds.count("attention")
+        return self.num_hidden_layers - self.num_mamba_layers
+
+    @property
+    def latent(self) -> bool:
+        """Is the attention latent (one low-rank row a token in the cache)?"""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row_dim(self) -> int:
+        """Values of a cached latent row: the normed latent, then the
+        rotated key part every head shares."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the SwiGLU every token passes beside the routed experts."""
+        return self.shared_intermediate_size or self.n_shared_experts * self.expert_width
+
+    @property
+    def moe_counts(self) -> int:
+        """Entries of a routed FFN's counts (``_routed_ffn``)."""
+        return 3 if self.num_local_experts is None else 4
 
     @property
     def held_experts(self) -> int:
@@ -204,6 +313,16 @@ class LlamaConfig:
                 del known["num_local_experts"]
             known.setdefault("norm_topk_prob", True)
             known.setdefault("router_aux_loss_coef", 0.0)
+        if raw.get("model_type") == "glm4_moe_lite":
+            # n_routed_experts counts the experts; a file cut to one chip's
+            # share gives the held count there and the router's width
+            # (num_experts) beside it. The published config trains without an
+            # aux loss (the selection bias balances the load): 0
+            held = raw.get("n_routed_experts", 0)
+            known.setdefault("num_experts", held)
+            if held != known["num_experts"]:
+                known.setdefault("num_local_experts", held)
+            known.setdefault("router_aux_loss_coef", 0.0)
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
@@ -221,6 +340,12 @@ class LlamaConfig:
                 architectures=["GraniteMoeHybridForCausalLM"],
                 model_type="granitemoehybrid",
                 layer_types=list(self.layer_types),
+            )
+        if self.latent:
+            d.update(
+                architectures=["Glm4MoeLiteForCausalLM"],
+                model_type="glm4_moe_lite",
+                n_routed_experts=self.held_experts,
             )
         return d
 
@@ -242,29 +367,42 @@ def shapes(cfg: LlamaConfig) -> dict:
     def s(*shape):
         return jax.ShapeDtypeStruct(shape, f32)
 
-    E, Eh = cfg.num_experts, cfg.held_experts
-    ffn = (
-        {
+    E, Eh, Fe = cfg.num_experts, cfg.held_experts, cfg.expert_width
+    dense_ffn = {"gate_proj": (D, F), "up_proj": (D, F), "down_proj": (F, D)}
+    ffn = dense_ffn
+    if E:
+        ffn = {
             "router": (D, E),
-            "gate_proj": (Eh, D, F),
-            "up_proj": (Eh, D, F),
-            "down_proj": (Eh, F, D),
+            "gate_proj": (Eh, D, Fe),
+            "up_proj": (Eh, D, Fe),
+            "down_proj": (Eh, Fe, D),
         }
-        if E
-        else {"gate_proj": (D, F), "up_proj": (D, F), "down_proj": (F, D)}
-    )
-    if cfg.shared_intermediate_size:
-        Fs = cfg.shared_intermediate_size
+        if cfg.topk_method == "noaux_tc":
+            ffn["router_bias"] = (E,)
+    if cfg.shared_width:
+        Fs = cfg.shared_width
         ffn.update(
             shared_gate_proj=(D, Fs), shared_up_proj=(D, Fs), shared_down_proj=(Fs, D)
         )
     norms = {"input_norm": (D,), "post_attn_norm": (D,)}
-    attention = {
-        "q_proj": (D, Nh * Dh),
-        "k_proj": (D, Nkv * Dh),
-        "v_proj": (D, Nkv * Dh),
-        "o_proj": (Nh * Dh, D),
-    }
+    if cfg.latent:
+        Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        attention = {
+            "q_a_proj": (D, Rq),
+            "q_a_norm": (Rq,),
+            "q_b_proj": (Rq, Nh * cfg.qk_head_dim),
+            "kv_a_proj": (D, cfg.latent_row_dim),
+            "kv_a_norm": (Rkv,),
+            "kv_b_proj": (Rkv, Nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "o_proj": (Nh * cfg.v_head_dim, D),
+        }
+    else:
+        attention = {
+            "q_proj": (D, Nh * Dh),
+            "k_proj": (D, Nkv * Dh),
+            "v_proj": (D, Nkv * Dh),
+            "o_proj": (Nh * Dh, D),
+        }
     if cfg.qk_norm:
         attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
 
@@ -272,7 +410,7 @@ def shapes(cfg: LlamaConfig) -> dict:
         return {k: s(n, *v) for g in groups for k, v in g.items()}
 
     if cfg.hybrid:
-        # one stack per kind of mixer, each layer with its norms and FFN: the
+        # one stack per kind of layer, each layer with its norms and FFN: the
         # forwards scan runs of like layers out of them (``layer_runs``)
         H, P, _, K, C = mamba.sizes(cfg)
         mixer = {
@@ -288,6 +426,11 @@ def shapes(cfg: LlamaConfig) -> dict:
         layers = {"mamba": stack(cfg.num_mamba_layers, norms, mixer, ffn)}
         if cfg.num_attention_layers:
             layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
+    elif cfg.leading_dense:
+        layers = {
+            "dense": stack(cfg.leading_dense, norms, attention, dense_ffn),
+            "attention": stack(L - cfg.leading_dense, norms, attention, ffn),
+        }
     else:
         layers = stack(L, norms, attention, ffn)
     tree = {"embed_tokens": s(V, D), "layers": layers, "final_norm": s(D)}
@@ -297,32 +440,47 @@ def shapes(cfg: LlamaConfig) -> dict:
 
 
 class Run(NamedTuple):
-    """Consecutive layers with one kind of mixer: layers ``start`` to
-    ``start + count`` of that kind's stack."""
+    """Consecutive layers of one kind: layers ``start`` to ``start + count``
+    of that kind's stack, whose first keeps its mixer's past at index
+    ``state`` of the cache (attention) or of the recurrent state (Mamba-2)."""
 
-    kind: str  # "attention" or "mamba"
+    kind: str  # "attention", "mamba" or "dense" (``LlamaConfig.layer_kinds``)
     start: int
     count: int
+    state: int
+
+    @property
+    def mixer(self) -> str:
+        return mixer_of(self.kind)
+
+
+def mixer_of(kind: str) -> str:
+    """A kind of layer's mixer: "mamba", or "attention" (the "dense" kind
+    differs from "attention" in its FFN alone)."""
+    return "mamba" if kind == "mamba" else "attention"
 
 
 def layer_runs(cfg: LlamaConfig) -> list[Run]:
     """The stack as runs of like layers, in order: one run for a stack of
     attention layers, 5 Mamba / 1 attention / 4 Mamba for a period of the
-    granite hybrid. Each forward scans each run."""
-    runs, seen = [], {"attention": 0, "mamba": 0}
+    granite hybrid, 1 dense / 23 attention for a routed stack behind a
+    leading dense layer. Each forward scans each run."""
+    runs, seen, past = [], {}, {"attention": 0, "mamba": 0}
     for kind in cfg.layer_kinds:
         if runs and runs[-1].kind == kind:
             runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
         else:
-            runs.append(Run(kind, seen[kind], 1))
-        seen[kind] += 1
+            runs.append(Run(kind, seen.get(kind, 0), 1, past[mixer_of(kind)]))
+        seen[kind] = seen.get(kind, 0) + 1
+        past[mixer_of(kind)] += 1
     return runs
 
 
 def scan_layers(cfg: LlamaConfig, body, carry, layers: dict, run: Run, unroll: int = 1):
     """``lax.scan`` of ``body(carry, layer, li) -> (carry, ys)`` over one
     run's layers: ``layer`` the layer's weights, ``li`` its index in its
-    kind's stack (which is also its index in that kind's cache or state).
+    mixer's cache or state (which is its index in its kind's stack, but
+    where two kinds of layer share one cache).
 
     A stack of like layers is the scan's ``xs``. A hybrid's run is part of
     its kind's stack: the scan runs over the indices and the body cuts its
@@ -330,17 +488,18 @@ def scan_layers(cfg: LlamaConfig, body, carry, layers: dict, run: Run, unroll: i
     anyway; a static slice of the stack would be a copy of the run's weights
     in every call."""
     ids = run.start + jnp.arange(run.count, dtype=jnp.int32)
-    if not cfg.hybrid:
+    if not cfg.layers_by_kind:
         return jax.lax.scan(
             lambda c, xs: body(c, *xs), carry, (layers, ids), unroll=unroll
         )
     stack = layers[run.kind]
+    ahead = run.state - run.start  # static; 0 where a kind has a cache to itself
 
     def indexed(c, li):
         layer = jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False), stack
         )
-        return body(c, layer, li)
+        return body(c, layer, li + ahead if ahead else li)
 
     return jax.lax.scan(indexed, carry, ids, unroll=unroll)
 
@@ -356,6 +515,10 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         if "norm" in name or name == "D":
             out.append(jnp.ones(leaf.shape, leaf.dtype))
+        elif name == "router_bias":
+            # a trained router's selection bias is not zero, and zero would
+            # leave the term untested: N(0, 0.1^2), beside scores in (0, 1)
+            out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * 0.1)
         elif name in ("A_log", "dt_bias", "conv_weight", "conv_bias"):
             out.append(_init_mixer_leaf(name, key, leaf))
         else:
@@ -463,7 +626,8 @@ def _rope(cfg: LlamaConfig, positions: jax.Array):
     whose attention takes no positions (``position_embedding_type`` nope)."""
     if cfg.position_embedding_type == "nope":
         return None, None
-    return _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    d = cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim
+    return _rope_tables(positions, d, cfg.rope_theta)
 
 
 def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
@@ -488,6 +652,83 @@ def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
     if cos is not None:
         q, k = _rope_apply(q, cos, sin), _rope_apply(k, cos, sin)
     return q, k, v
+
+
+def _latent_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
+    """The latent attention's projections of x [B, T, D] -> (q [B, T, Nh,
+    nope + rope], each head its unrotated part then its rotated one; the
+    token's latent row [B, T, kv_lora_rank + rope]: the latent under its
+    RMSNorm, then the one rotated key part all heads share). The row is what
+    the cache keeps; ``latent_keys_values`` rebuilds k and v from it and
+    ``latent_absorb`` / ``latent_expand`` compute the same attention without
+    them."""
+    B, T, _ = x.shape
+    Nh, Dn, Dr, R = (
+        cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.kv_lora_rank,
+    )
+    c_q = _rms_norm(mul(x, layer["q_a_proj"]), layer["q_a_norm"], cfg.rms_norm_eps)
+    q = mul(c_q, layer["q_b_proj"]).reshape(B, T, Nh, Dn + Dr)
+    q = jnp.concatenate((q[..., :Dn], _rope_apply(q[..., Dn:], cos, sin)), axis=-1)
+    row = mul(x, layer["kv_a_proj"])  # [B, T, R + Dr]
+    c_kv = _rms_norm(row[..., :R], layer["kv_a_norm"], cfg.rms_norm_eps)
+    k_r = _rope_apply(row[..., None, R:], cos, sin)[:, :, 0]  # one head
+    return q, jnp.concatenate((c_kv, k_r), axis=-1)
+
+
+def _kv_b_heads(cfg: LlamaConfig, w_kvb: jax.Array) -> jax.Array:
+    """``kv_b_proj`` [R, Nh * (nope + v)] as [R, Nh, nope + v]: head i's
+    columns rebuild its unrotated key part, then its values."""
+    return w_kvb.reshape(cfg.kv_lora_rank, cfg.num_attention_heads, -1)
+
+
+def latent_keys_values(cfg: LlamaConfig, row: jax.Array, w_kvb: jax.Array):
+    """Latent rows [B, T, R + rope] -> (k [B, T, Nh, nope + rope], v [B, T,
+    Nh, v]): the rebuilt form, in which latent attention is multi-head
+    attention (training, prefill, the reference)."""
+    Dn, R = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    kv = jnp.einsum("btr,rhd->bthd", row[..., :R], _kv_b_heads(cfg, w_kvb))
+    k_r = jnp.broadcast_to(
+        row[:, :, None, R:], (*kv.shape[:3], cfg.qk_rope_head_dim)
+    )
+    return jnp.concatenate((kv[..., :Dn], k_r), axis=-1), kv[..., Dn:]
+
+
+def rebuilt_attend(cfg: LlamaConfig, attn_fn):
+    """The ``attend(q, rows, kv_b_proj)`` of the rebuilt form: ``attn_fn(q, k,
+    v)`` over the keys and values that the rows give."""
+    return lambda q, rows, w_kvb: attn_fn(q, *latent_keys_values(cfg, rows, w_kvb))
+
+
+def latent_absorb(cfg: LlamaConfig, q: jax.Array, w_kvb: jax.Array) -> jax.Array:
+    """q [S, Nh, nope + rope] -> the query against a cached latent row, [S,
+    Nh, R + rope]: head i's unrotated part through W_UK_i^T (the key half of
+    ``kv_b_proj``), so that q_lat . c_kv = q_nope . k_nope, and the rotated
+    part as it is. The absorbed form, in which no key is rebuilt (decode)."""
+    Dn = cfg.qk_nope_head_dim
+    w_uk = _kv_b_heads(cfg, w_kvb)[..., :Dn]  # [R, Nh, nope]
+    q_lat = jnp.einsum("shn,rhn->shr", q[..., :Dn], w_uk)
+    return jnp.concatenate((q_lat.astype(q.dtype), q[..., Dn:]), axis=-1)
+
+
+def latent_expand(cfg: LlamaConfig, o_lat: jax.Array, w_kvb: jax.Array) -> jax.Array:
+    """The attention's weighted sum of latents [S, Nh, R] -> each head's
+    values [S, Nh, v], through W_UV_i (the value half of ``kv_b_proj``): the
+    sum commutes with the projection, so no value is rebuilt either."""
+    w_uv = _kv_b_heads(cfg, w_kvb)[..., cfg.qk_nope_head_dim:]  # [R, Nh, v]
+    return jnp.einsum("shr,rhv->shv", o_lat, w_uv).astype(o_lat.dtype)
+
+
+def refuse_latent(cfg: LlamaConfig, what: str) -> None:
+    """``what`` reads or writes a slot's past as a (k, v) pair of rows of one
+    head size; a latent cache is one array of latent rows and has neither."""
+    if cfg.latent:
+        raise ValueError(
+            f"{what} is refused for a configuration with latent attention "
+            f"(kv_lora_rank {cfg.kv_lora_rank}): it handles a slot's past as "
+            "(k, v) rows of one head size, and the latent ring holds one row of "
+            f"{cfg.latent_row_dim} values a token, from which k and v are not rebuilt"
+        )
 
 
 def _routed_ffn(
@@ -526,10 +767,19 @@ def _routed_ffn(
     N = xf.shape[0]
 
     logits = jnp.dot(xf, layer["router"], preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
-    gate, expert = jax.lax.top_k(probs, K)  # [N, K]; ties go to the lower index
-    if cfg.norm_topk_prob:
-        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if cfg.topk_method == "noaux_tc":
+        # scores by sigmoid; the selection bias chooses and does not weigh
+        probs = jax.nn.sigmoid(logits)  # [N, E]
+        _, expert = jax.lax.top_k(probs + layer["router_bias"].astype(jnp.float32), K)
+        gate = jnp.take_along_axis(probs, expert, axis=-1)
+        if cfg.norm_topk_prob:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        gate = gate * cfg.routed_scaling_factor
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
+        gate, expert = jax.lax.top_k(probs, K)  # [N, K]; ties go to the lower index
+        if cfg.norm_topk_prob:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
 
     flat = expert.reshape(-1)  # pair p belongs to token p // K
     share = cfg.num_local_experts is not None
@@ -584,13 +834,14 @@ def _ffn(
     """The block's feed-forward over x [..., D] -> (out, aux loss, routing
     counts): SwiGLU through the caller's weight matmul ``mul(x, w)``, or the
     routed experts, and beside those, where the configuration has one, a
-    shared SwiGLU that every token passes; aux and counts are zero for a
-    dense model."""
-    if cfg.num_experts:
+    shared SwiGLU that every token passes: the layer's leaves say which it
+    has. aux and counts are zero for a dense layer."""
+    if "router" in layer:
         out, aux, counts = _routed_ffn(cfg, x, layer, live)
-    else:
-        out, aux, counts = _swiglu(x, layer, mul), jnp.float32(0.0), jnp.zeros((3,), jnp.int32)
-    if cfg.shared_intermediate_size:
+    else:  # a dense model's layer, or a routed model's leading dense one
+        out, aux = _swiglu(x, layer, mul), jnp.float32(0.0)
+        counts = jnp.zeros((cfg.moe_counts,), jnp.int32)
+    if "shared_gate_proj" in layer:
         out = out + _swiglu(x, layer, mul, "shared_")
     return out, aux, counts
 
@@ -598,8 +849,11 @@ def _ffn(
 class BlockOut(NamedTuple):
     """What one layer leaves beside the hidden state."""
 
-    k: Optional[jax.Array]  # this layer's keys, rotated [B, T, Nkv, Dh]
-    v: Optional[jax.Array]  # and values (None for a layer without attention)
+    # this layer's keys, rotated [B, T, Nkv, Dh], and values; for latent
+    # attention k is the latent rows [B, T, R + rope] and v None; both None
+    # for a layer without attention
+    k: Optional[jax.Array]
+    v: Optional[jax.Array]
     attn_out: jax.Array  # the mixer's branch after its output projection [B, T, D]
     aux: jax.Array  # the routed FFN's weighted aux loss (0 for a dense one)
     counts: jax.Array  # the routed FFN's counts, int32 [3] (``_routed_ffn``)
@@ -626,13 +880,24 @@ def decoder_block(
     ``attend(q, k, v)`` its attention over this layer's q [B, T, Nh, Dh] and
     new k, v, ``mix(x, layer)`` its mixer over x [B, T, D] (a cache or a
     state either reads or writes is the caller's own), ``live`` the tokens a
-    routed FFN counts. Both branches enter the residual under the
-    configuration's ``residual_multiplier``."""
+    routed FFN counts. Latent attention enters the same way: the projection
+    returns q and the tokens' latent rows, and the caller's ``attend(q, rows,
+    kv_b_proj)`` is its attention over them, in the rebuilt form or the
+    absorbed one -> [B, T, Nh, v]. Both branches enter the residual under
+    the configuration's ``residual_multiplier``."""
     B, T, _ = h.shape
     scale = cfg.residual_multiplier
     # the scopes name the device work in a profiler trace (an operation's
     # op_name metadata); they change nothing that is computed
-    if mix is None:
+    if mix is None and cfg.latent:
+        with jax.named_scope("odtp_mla"):
+            x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
+            q, k = _latent_qkv(cfg, x, layer, cos, sin, mul)
+            v = None
+            attn_out = mul(
+                attend(q, k, layer["kv_b_proj"]).reshape(B, T, -1), layer["o_proj"]
+            )
+    elif mix is None:
         with jax.named_scope("odtp_attention"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
             q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
@@ -661,13 +926,15 @@ def training_block(
     attaches via forward hooks on ``self_attn`` (utils.py:43-67,
     train_fsdp.py:65)."""
     cos, sin = _rope(cfg, positions)
-    mix = None
+    mix, attend = None, attn_fn
     if kind == "mamba":
         mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer, jnp.matmul)[0]
+    elif cfg.latent:  # the rebuilt form: multi-head attention over k and v
+        attend = rebuilt_attend(cfg, attn_fn)
 
     def body(h, layer, li=None):
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attn_fn, mix=mix
+            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attend, mix=mix
         )
         with jax.named_scope("odtp_attention"):
             attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
@@ -742,6 +1009,13 @@ def forward(
 
     cparams = jax.tree.map(lambda x: x.astype(compute_dtype), params)
 
+    if cfg.latent and attn_impl != "xla":
+        raise ValueError(
+            f"attn_impl={attn_impl!r} is refused for a configuration with latent "
+            "attention: training runs it in the rebuilt form through XLA's "
+            f"attention (heads of {cfg.qk_head_dim}); the flash and ring kernels "
+            "have not been run at that head size"
+        )
     if attn_impl == "xla":
         attn_fn = lambda q, k, v: xla_attention(q, k, v, causal=True)
     elif attn_impl == "pallas":
@@ -963,8 +1237,9 @@ def _logits(cfg: LlamaConfig, cparams: dict, h: jax.Array) -> jax.Array:
 
 
 def _stacked(parts: list) -> Optional[jax.Array]:
-    """The runs' per-layer outputs as one stack, in layer order."""
-    if not parts:
+    """The runs' per-layer outputs as one stack, in layer order (None where
+    the layers leave none: a latent cache has no values)."""
+    if not parts or parts[0] is None:
         return None
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
@@ -981,7 +1256,9 @@ def prefill_forward(
 ):
     """Prompt prefill for serving: ids [1, P] -> (last-token logits [1, V]
     f32, per-layer K/V [La, P, Nkv, Dh] in compute dtype over the attention
-    layers); for a hybrid stack then the Mamba-2 layers' recurrent states
+    layers; for latent attention, computed here in the rebuilt form, the
+    latent rows [La, P, R + rope] in K's place and None in V's); for a
+    hybrid stack then the Mamba-2 layers' recurrent states
     [Lm, H, P, N] float32 and conv tails [Lm, K - 1, C], as the last real
     token left them; and with ``return_moe_counts`` last the routed FFN's
     counts over the live prompt tokens summed over layers (int32, see
@@ -1000,12 +1277,14 @@ def prefill_forward(
     cos, sin = _rope(cfg, positions)
     live = positions < length
     attend = lambda q, k, v: xla_attention(q, k, v, causal=True)
+    if cfg.latent:  # k and v rebuilt for the prompt; the rows are what is kept
+        attend = rebuilt_attend(cfg, attend)
 
     def attention_body(h, layer, li):
         h, out = decoder_block(
             cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
         )
-        return h, (out.k[0], out.v[0], out.counts)
+        return h, (out.k[0], None if out.v is None else out.v[0], out.counts)
 
     def mamba_body(h, layer, li):
         left = []
@@ -1022,10 +1301,10 @@ def prefill_forward(
     kept = {"attention": ([], []), "mamba": ([], [])}
     counts = []
     for run in layer_runs(cfg):
-        body = attention_body if run.kind == "attention" else mamba_body
+        body = attention_body if run.mixer == "attention" else mamba_body
         h, (a, b, c) = scan_layers(cfg, body, h, cparams["layers"], run)
-        kept[run.kind][0].append(a)
-        kept[run.kind][1].append(b)
+        kept[run.mixer][0].append(a)
+        kept[run.mixer][1].append(b)
         counts.append(c)
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = _logits(cfg, cparams, h_last)
@@ -1065,7 +1344,11 @@ def decode_forward(
     live row once and writes one row a slot, layer and KV head into the
     buffers the engine holds, and nothing of a layer's size is sliced,
     re-laid-out or copied (pinned at the cells' shapes by
-    tests/test_tpu_compile.py). The XLA path (off the TPU, and the per-call
+    tests/test_tpu_compile.py). Latent attention runs here in the absorbed
+    form against the one latent ring ``cache_k`` (``cache_v`` is None and
+    comes back None): the query goes through the key half of ``kv_b_proj``,
+    meets each cached row once, and the weighted sum of latents goes through
+    the value half (``ops.decode_kernels.mla_decode_attention``). The XLA path (off the TPU, and the per-call
     fallback for a shape the kernel cannot tile) scatters the row and
     slices the layer's pages, with copies where the compiler wants them.
 
@@ -1082,11 +1365,9 @@ def decode_forward(
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
     cos, sin = _rope(cfg, positions)
     live = lens > 0
-    step_attention = (
-        paged_decode_attention
-        if decode_kernel == "pallas"
-        else decode_step_attention
-    )
+    pallas = decode_kernel == "pallas"
+    step_attention = paged_decode_attention if pallas else decode_step_attention
+    latent_attention = mla_decode_attention if pallas else latent_decode_step_attention
 
     def attention_body(carry, layer, li):
         h, ck, cv = carry  # the whole caches
@@ -1098,8 +1379,17 @@ def decode_forward(
             )
             return out
 
+        def absorbed(q, rows, w_kvb):  # no key or value is rebuilt
+            nonlocal ck
+            o_lat, ck = latent_attention(
+                latent_absorb(cfg, q[:, 0], w_kvb), rows[:, 0], ck, lens, li,
+                scale=cfg.qk_head_dim**-0.5, value_dim=cfg.kv_lora_rank,
+            )
+            return latent_expand(cfg, o_lat, w_kvb)
+
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
+            cfg, h, layer, cos, sin, mul=mul, live=live,
+            attend=absorbed if cfg.latent else attend,
         )
         return (h, ck, cv), out.counts
 
@@ -1121,7 +1411,7 @@ def decode_forward(
     h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
     counts = []
     for run in layer_runs(cfg):
-        if run.kind == "attention":
+        if run.mixer == "attention":
             (h, cache_k, cache_v), c = scan_layers(
                 cfg, attention_body, (h, cache_k, cache_v), cparams["layers"], run
             )
@@ -1174,6 +1464,7 @@ def verify_forward(
     (S = 1, tail = the suffix tokens, lens = the reused prefix length).
     """
     refuse_recurrent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
+    refuse_latent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
     S, K = tail.shape
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
@@ -1216,6 +1507,7 @@ def draft_propose(
     is the verify pass's job. Returns proposals [S, k_steps] int32.
     """
     refuse_recurrent(cfg, "the self-speculative draft")
+    refuse_latent(cfg, "the self-speculative draft")
     S = tokens.shape[0]
     L, Ld = cfg.num_hidden_layers, int(draft_layers)
     if not 1 <= Ld <= L:

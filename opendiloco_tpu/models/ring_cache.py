@@ -28,6 +28,16 @@ Two facts live here and nowhere else:
 
 Plain functions over the ``(k, v)`` pair; every writer returns the new pair
 and callers jit them with both donated, so an update is in place at HBM.
+
+Latent attention keeps **one** array: a token's row is its latent
+(``kv_lora_rank`` normed values, then the rotated key part all heads share),
+from which keys and values are never rebuilt in decode, so the ring is
+``[L, S, 1, R + rope, T]`` in ``k``'s place and ``v`` is None. The row is one
+"head" of that size to everything here; rows cross the module's edge as
+``[L, rows, R + rope]``. :func:`init_kv_cache`, :func:`cache_insert` and
+:func:`write_row` take and return the pair with None in ``v``'s place; the
+other functions serve what is refused for such a configuration
+(``llama.refuse_latent``) and are not written for it.
 """
 
 from __future__ import annotations
@@ -45,7 +55,13 @@ def init_kv_cache(
     dtype: jnp.dtype = jnp.bfloat16,
 ) -> dict:
     """Zeroed {"k","v"} pages for ``cfg`` (its attention layers, KV heads
-    and head size): ``num_slots`` rings of ``max_context`` rows a layer."""
+    and head size): ``num_slots`` rings of ``max_context`` rows a layer. For
+    latent attention ``k`` is the one latent ring and ``v`` None."""
+    if cfg.latent:
+        shape = cache_shape(
+            cfg.num_attention_layers, num_slots, max_context, 1, cfg.latent_row_dim
+        )
+        return {"k": jnp.zeros(shape, dtype), "v": None}
     shape = cache_shape(
         cfg.num_attention_layers, num_slots, max_context, cfg.kv_heads, cfg.head_dim
     )
@@ -117,7 +133,8 @@ def cache_insert(
     """Write a sequence's K/V [L, P, Nkv, Dh] into ``slot`` (traced scalar)
     at ring rows [0, P): a prefilled prompt, or one slot's pages coming back
     from the host tier (:func:`fetch_pages` is the way out). Rows beyond P
-    keep the previous tenant's bytes, stale and masked."""
+    keep the previous tenant's bytes, stale and masked. A latent ring takes
+    its rows as [L, P, R + rope], with None for ``cache_v`` and ``vs``."""
     P, T = ks.shape[1], ring_rows(cache_k)
     if P > T:
         raise ValueError(f"prefill length {P} exceeds slot context {T}")
@@ -125,6 +142,10 @@ def cache_insert(
     start = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
 
     def put(cache, x):
+        if cache is None:
+            return None
+        if x.ndim == 3:  # latent rows: the row is the one head
+            x = x[:, :, None]
         x = _rows_minor(x)[:, None].astype(cache.dtype)  # [L, 1, Nkv, Dh, P]
         return jax.lax.dynamic_update_slice(cache, x, start)
 
@@ -143,11 +164,14 @@ def write_row(
     [S, Nkv, Dh]) lands at ring row ``lens % T`` of ``layer``'s pages. The
     reference of the decode kernel's in-place write and the path off the
     TPU; on the chip a scatter into rows-minor pages re-lays the whole cache
-    (ISSUE 29), which is why the kernel writes the row itself."""
+    (ISSUE 29), which is why the kernel writes the row itself. A latent
+    ring takes ``k`` as [S, 1, R + rope], with None for ``cache_v`` and ``v``."""
     rows = jnp.arange(cache_k.shape[1])
     idx = jnp.mod(lens, ring_rows(cache_k))
 
     def put(cache, x):  # cache[layer, rows, :, :, idx] is [S, Nkv, Dh]
+        if cache is None:
+            return None
         return cache.at[layer, rows, :, :, idx].set(x.astype(cache.dtype))
 
     return put(cache_k, k), put(cache_v, v)

@@ -123,6 +123,38 @@ def decode_step_attention(
     return out, cache_k, cache_v
 
 
+def latent_decode_step_attention(
+    q: jax.Array,
+    row: jax.Array,
+    cache: jax.Array,
+    lens: jax.Array,
+    layer,
+    *,
+    scale: float,
+    value_dim: int,
+) -> tuple[jax.Array, jax.Array]:
+    """One layer's share of a decode step of latent attention, absorbed
+    form, in XLA: each slot's new latent row [S, Dl] written at ring row
+    ``lens % T`` of ``layer``'s pages of the one latent ring ``cache`` [L, S,
+    1, Dl, T], then every head's absorbed query q [S, H, Dl]
+    (``llama.latent_absorb``) against the slot's live rows -- the scores over
+    all Dl values of a row, times ``scale``, the weighted sum over its first
+    ``value_dim`` (the normed latent; the rest is the shared rotated key)
+    -> (o_lat [S, H, value_dim], cache). Masks as :func:`decode_attention`.
+    The reference of ``decode_kernels.mla_decode_attention``, which has this
+    signature, and its per-call fallback."""
+    cache, _ = write_row(cache, None, layer, row[:, None], None, lens)
+    pages = cache[layer][:, 0]  # [S, Dl, T]
+    s, _, t = pages.shape
+    scores = jnp.einsum("shd,sdt->sht", q, pages, preferred_element_type=jnp.float32)
+    scores = scores * scale
+    idx = jax.lax.broadcasted_iota(jnp.int32, (s, t), 1)
+    valid = (idx <= lens[:, None]) | (lens[:, None] >= t)
+    scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("sht,sdt->shd", probs, pages[:, :value_dim]), cache
+
+
 def spec_tail_attention(
     q: jax.Array,
     cache_k: jax.Array,

@@ -1,4 +1,5 @@
-"""Pallas TPU serving kernels: ragged paged decode attention, fused W4
+"""Pallas TPU serving kernels: ragged paged decode attention (over a ``(k,
+v)`` ring, and over a latent ring in the absorbed form), fused W4
 dequant-matmul, fused speculative verify.
 
 The XLA paths they stand in for (``decode_attention`` dense-masks the
@@ -26,6 +27,12 @@ cache-aware decode, arXiv 2309.06180).
   that kv head's ``rep`` query rows, never a ``_repeat_kv``
   materialization. Online softmax in f32 matches ``decode_attention``
   row-for-row.
+- :func:`mla_decode_attention` is the same plan for latent attention: one
+  ring of latent rows and no value twin, every head of a slot against the
+  same ``(R + rope, block_t)`` tile, the values taken from the tile's
+  first ``R`` rows, so a live row is read once a layer and step. It checks
+  against ``latent_decode_step_attention`` to rounding, not to the bit (the
+  row reaches its tile through a one-hot product on the MXU).
 - :func:`w4_matmul` fuses the blockwise-4-bit dequant into the matmul:
   packed nibbles dequantize in-registers per ``[block_k, N]`` tile with
   bit-for-bit the ``native._dequant4_numpy`` element order and per-4096-
@@ -63,7 +70,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from opendiloco_tpu.models.ring_cache import ring_rows
-from opendiloco_tpu.ops.attention import decode_step_attention, spec_tail_attention
+from opendiloco_tpu.ops.attention import (
+    decode_step_attention,
+    latent_decode_step_attention,
+    spec_tail_attention,
+)
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
 W4_BLOCK = 4096  # diloco.compression._BLOCK (pinned by tests)
@@ -95,7 +106,9 @@ def _interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
-def _ring_block(t: int, block_t: int | None, interpret: bool) -> int:
+def _ring_block(
+    t: int, block_t: int | None, interpret: bool, preferred: int = 256
+) -> int:
     """Ring-page tile size, in rows: explicit arg > ``ODTP_DECODE_BLOCK_T``
     > the shared block heuristic. Rows are the tiles' lane dimension, so on
     the chip a tile is a multiple of 128 of them (interpreted, the tests
@@ -104,7 +117,7 @@ def _ring_block(t: int, block_t: int | None, interpret: bool) -> int:
     want = block_t or int(os.environ.get("ODTP_DECODE_BLOCK_T") or 0)
     if want > 0 and t % want == 0 and (interpret or want % 128 == 0):
         return want
-    return pick_block(t, 256)
+    return pick_block(t, preferred)
 
 
 # ---------------------------------------------------------------------------
@@ -333,6 +346,181 @@ def paged_decode_attention(
     if return_stats:
         return (*out, res[3].reshape(s_, nkv))
     return out
+
+
+# ---------------------------------------------------------------------------
+# (a') decode attention over a latent ring, absorbed form
+# ---------------------------------------------------------------------------
+
+
+def _mla_decode_kernel(
+    lens_ref, layer_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
+    m_scr, l_scr, acc_scr, *, scale, block_t, t, num_t, value_dim,
+):
+    heads, d = q_ref.shape  # every head of the slot: they share its rows
+    si, ti = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(ti == 0)
+    def _init():
+        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    lens_s = lens_ref[si]
+    last_live = jnp.minimum(lens_s, t - 1) // block_t
+    # the step's own row goes to ring row lens % t, in a block that is
+    # always live (the last live one until the ring wraps)
+    new_at = jax.lax.rem(lens_s, t) - ti * block_t  # its lane in this tile
+
+    def attend(tile):  # [d, block_t]: one read serves scores and values
+        idx = ti * block_t + jax.lax.broadcasted_iota(
+            jnp.int32, (heads, block_t), 1
+        )
+        valid = (idx <= lens_s) | (lens_s >= t)
+        s = scale * jax.lax.dot_general(
+            q_ref[:], tile, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [heads, block_t]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(tile.dtype), tile[:value_dim], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when((new_at >= 0) & (new_at < block_t))
+    def _write_and_attend():
+        # the new row as a column of the rows-minor tile. The rows of all
+        # slots arrive transposed, slots as lanes ([d, 128] here); picking
+        # this slot's lane and moving it to lane ``new_at`` is one product
+        # with a [block_t, 128] matrix that holds a single 1: exact, and on
+        # the MXU (a [1, d] -> [d, 1] reshape Mosaic has not, and
+        # ``_as_column``'s [d, d] diagonal is 1.3 MB at d 576)
+        lanes = new_ref.shape[1]
+        pick = (
+            jax.lax.broadcasted_iota(jnp.int32, (block_t, lanes), 0) == new_at
+        ) & (
+            jax.lax.broadcasted_iota(jnp.int32, (block_t, lanes), 1)
+            == jax.lax.rem(si, lanes)
+        )
+        moved = jax.lax.dot_general(
+            new_ref[:], pick.astype(new_ref.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [d, block_t]: column new_at is the row, the rest 0
+        here = jax.lax.broadcasted_iota(jnp.int32, (d, block_t), 1) == new_at
+        tile = jnp.where(here, moved.astype(c_ref.dtype), c_ref[:])
+        co_ref[:] = tile
+        attend(tile)
+
+    @pl.when((ti <= last_live) & ((new_at < 0) | (new_at >= block_t)))
+    def _attend():
+        attend(c_ref[:])
+
+    @pl.when(ti == num_t - 1)
+    def _finish():
+        l = l_scr[:]
+        o_ref[:] = (acc_scr[:] / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+
+
+def mla_decode_attention(
+    q: jax.Array,
+    row: jax.Array,
+    cache: jax.Array,
+    lens: jax.Array,
+    layer,
+    *,
+    scale: float,
+    value_dim: int,
+    block_t: int | None = None,
+    interpret: bool | None = None,
+):
+    """One layer's share of a decode step of latent attention in the absorbed
+    form, against the one latent ring ``cache`` [L, S, 1, Dl, T]: write each
+    slot's new latent row [S, Dl] at ring row ``lens % T`` of ``layer``'s
+    pages, then attend every head's absorbed query q [S, H, Dl] over the
+    slot's live rows -> (o_lat [S, H, value_dim], cache): what
+    :func:`~opendiloco_tpu.ops.attention.latent_decode_step_attention` gives,
+    with the cache read and written where it lies (the output aliases the
+    input; callers donate it).
+
+    :func:`paged_decode_attention`'s plan with one operand: a grid step takes
+    a ``(Dl, block_t)`` tile of one slot's page, cut from the whole cache
+    (layer and ``lens`` by scalar prefetch; dead blocks skipped and their
+    DMAs elided), scores all H heads against it and takes the values from
+    its first ``value_dim`` rows, so each live row is read once a layer and
+    step, not once for keys and once for values, and not once a head. The
+    tile that holds ring row ``lens % T`` gets the new row as a column and
+    goes back through the aliased output. A shape it cannot tile keeps the
+    XLA path per call."""
+    s_, h, d = q.shape
+    t = ring_rows(cache)
+    interp = _interpret(interpret)
+    bt = _ring_block(t, block_t, interp, preferred=512)
+    if d % 8 != 0 or value_dim % 8 != 0 or not bt:
+        return latent_decode_step_attention(
+            q, row, cache, lens, layer, scale=scale, value_dim=value_dim
+        )
+    num_t = t // bt
+    lanes = 128  # slots as lanes, so that a slot's new row is a column
+    new = jnp.pad(row.astype(cache.dtype).T, ((0, 0), (0, -s_ % lanes)))
+
+    def page_map(si, ti, lens_ref, layer_ref):
+        # clamp dead blocks to the last live one: unchanged index = no DMA
+        last = jnp.minimum(lens_ref[si], t - 1) // bt
+        return (layer_ref[0], si, 0, 0, jnp.minimum(ti, last))
+
+    def written_map(si, ti, lens_ref, layer_ref):
+        return (layer_ref[0], si, 0, 0, jax.lax.rem(lens_ref[si], t) // bt)
+
+    def slot_map(si, ti, lr, yr):
+        return (si, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s_, num_t),
+        in_specs=[
+            pl.BlockSpec((None, h, d), slot_map),
+            pl.BlockSpec((d, lanes), lambda si, ti, lr, yr: (0, si // lanes)),
+            pl.BlockSpec((None, None, None, d, bt), page_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, h, value_dim), slot_map),
+            pl.BlockSpec((None, None, None, d, bt), written_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, value_dim), jnp.float32),
+        ],
+    )
+    out, cache = pl.pallas_call(
+        functools.partial(
+            _mla_decode_kernel,
+            scale=float(scale), block_t=bt, t=t, num_t=num_t, value_dim=value_dim,
+        ),
+        name="odtp_mla_decode_attn",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((s_, h, value_dim), q.dtype, vma=jax.typeof(q).vma),
+            jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        ],
+        # operands count the two scalar-prefetch vectors: the cache is input
+        # 4, and comes back as output 1
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interp,
+    )(
+        lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        q, new, cache,
+    )
+    return out, cache
 
 
 # ---------------------------------------------------------------------------

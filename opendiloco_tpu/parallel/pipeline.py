@@ -73,6 +73,13 @@ def pipeline_hidden(
             "layers: it stages one homogeneous [L, ...] stack, and a hybrid's "
             "layers are stacked per kind of mixer (llama.layer_runs)"
         )
+    if cfg.layers_by_kind:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration with a leading dense "
+            f"layer before its expert layers (first_k_dense_replace "
+            f"{cfg.first_k_dense_replace}): it stages one homogeneous [L, ...] "
+            "stack, and these layers are stacked per kind (llama.layer_runs)"
+        )
     B, T, D = h0.shape
     M = microbatches
     if B % M:

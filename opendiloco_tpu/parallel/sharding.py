@@ -42,6 +42,17 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "up_proj": (1, 0),
     "down_proj": (0, 1),  # [F, D] (or [E, F, D])
     "router": (None, 0),  # [D, E]: small, fsdp on D
+    "router_bias": (None, -1),  # [E]: the selection bias, replicated
+    # latent attention: the two up-projections and o_proj are per head and
+    # take tp like q/k/v/o; the down-projections end in a norm over their
+    # whole width (and kv_a_proj's rotated tail is shared by every head),
+    # so they keep it whole: fsdp only
+    "q_a_proj": (None, 0),  # [D, Rq]
+    "q_a_norm": (None, -1),
+    "q_b_proj": (1, 0),  # [Rq, Nh*(nope+rope)]
+    "kv_a_proj": (None, 0),  # [D, R+rope]
+    "kv_a_norm": (None, -1),
+    "kv_b_proj": (1, 0),  # [R, Nh*(nope+v)]
     # the shared SwiGLU beside a routed FFN (granite hybrid): as a dense FFN's
     "shared_gate_proj": (1, 0),  # [D, Fs]
     "shared_up_proj": (1, 0),

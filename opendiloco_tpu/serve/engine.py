@@ -74,6 +74,7 @@ from opendiloco_tpu.models.llama import (
     dequant_w4,
     draft_propose,
     prefill_forward,
+    refuse_latent,
     refuse_recurrent,
     verify_forward,
 )
@@ -89,8 +90,13 @@ from opendiloco_tpu.models.ring_cache import (
     state_insert,
     suffix_insert,
 )
-from opendiloco_tpu.ops.attention import decode_step_attention, spec_tail_attention
+from opendiloco_tpu.ops.attention import (
+    decode_step_attention,
+    latent_decode_step_attention,
+    spec_tail_attention,
+)
 from opendiloco_tpu.ops.decode_kernels import (
+    mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
     spec_tail_attention_fused,
@@ -106,6 +112,23 @@ def _fresh_copy(leaves, dtype):
     # idiom as the outer plane). The rounding is ``astype``'s, the one the
     # forwards' boundary applies to a tree that has not met it yet
     return [x.astype(dtype) + jnp.zeros((), dtype) for x in leaves]
+
+
+def _best_us(fn, *argv, carried: int = 0, iters: int = 3) -> float:
+    """Best of ``iters`` timed calls of ``jit(fn)`` after one that compiles,
+    in microseconds; the last ``carried`` arguments are donated and taken
+    from the call's trailing outputs each time."""
+    keep = len(argv) - carried
+    f = jax.jit(fn, donate_argnums=tuple(range(keep, len(argv))))
+    best = float("inf")
+    for i in range(1 + max(1, int(iters))):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(f(*argv))
+        if i:  # the first call compiled
+            best = min(best, time.perf_counter() - t0)
+        if carried:
+            argv = (*argv[:keep], *out[-carried:])
+    return best * 1e6
 
 
 def _with_counts(tok, counts):
@@ -166,6 +189,11 @@ class ServeEngine:
                 "out] matmul leaves of one homogeneous stack, not for a mixer's "
                 "in_proj/out_proj, conv and decay leaves"
             )
+        if self.weight_format == "w4":
+            refuse_latent(
+                cfg, "weight_format=w4 (kv_b_proj is read in two halves, one absorbed "
+                "into q and one into the output, which the fused dequant-matmul does not do)"
+            )
         # "auto"/None resolves to pallas only on TPU backends; tests force
         # "pallas" explicitly and the kernels run interpreted off-TPU
         self.decode_kernel = resolve_decode_kernel(decode_kernel)
@@ -174,6 +202,7 @@ class ServeEngine:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if self.spec_k:
             refuse_recurrent(cfg, f"speculative decode (spec_k={self.spec_k})")
+            refuse_latent(cfg, f"speculative decode (spec_k={self.spec_k})")
             L = cfg.num_hidden_layers
             ld = int(draft_layers) or max(1, L // 2)
             if not 1 <= ld < L:
@@ -229,9 +258,22 @@ class ServeEngine:
         # reads and writes every slot's)
         self.ssm_tokens = 0
         self.ssm_state_bytes_moved = 0
+        # what the latent attention did with its ring (always on; stay 0 for
+        # a model whose cache is keys and values): the live latent rows the
+        # decode steps read, over layers (each once a layer and step), and
+        # the bytes of latent rows the calls moved (a prefill writes its
+        # prompt's rows, a decode step reads the live rows and writes one a
+        # slot)
+        self.latent_rows_read = 0
+        self.latent_bytes_moved = 0
 
+        # a latent cache is the one ring, in ``cache_k``; ``cache_v`` is None
         cache = init_kv_cache(cfg, self.num_slots, self.max_context, compute_dtype)
         self.cache_k, self.cache_v = cache["k"], cache["v"]
+        self.latent_cache_resident_bytes = self.cache_k.nbytes if cfg.latent else 0
+        self._latent_row_bytes = (
+            cfg.latent_row_dim * self.cache_k.dtype.itemsize if cfg.latent else 0
+        )
         # the slots' second kind of state: empty for a stack of attention layers
         self._ssm: tuple = ()
         if cfg.hybrid:
@@ -400,6 +442,7 @@ class ServeEngine:
             prefix_src is not None and 0 < prefix_len < n
         ):
             refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
+            refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         t0 = time.perf_counter()
         moe = {}  # a continued prefill's routing is not counted
         if host_prefix is not None and 0 < host_prefix[2] < n:
@@ -426,6 +469,7 @@ class ServeEngine:
                 self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
             toks, moe = self._split_counts(np.asarray(tokd), 1)
             moe.update(self._count_ssm(n, sum(x.nbytes for x in left)))
+            moe.update(self._count_latent(read=0, written=n))
             tok, logits = int(toks[0]), np.asarray(logitsd[0])
         dt = time.perf_counter() - t0
         self.stage_seconds["prefill"] += dt
@@ -442,6 +486,21 @@ class ServeEngine:
         self.ssm_tokens += tokens
         self.ssm_state_bytes_moved += state_bytes
         return {"ssm_tokens": tokens, "ssm_state_bytes": state_bytes}
+
+    def _count_latent(self, read: int, written: int) -> dict:
+        """Add one call's traffic with the latent ring to the engine's
+        counters: ``read`` and ``written`` rows of one layer's pages, the
+        same in every layer -> the same as span attributes, ``latent_rows``
+        the rows the call touched (a decode step writes one of those it
+        reads) and ``latent_bytes`` what it moved (nothing for a model
+        without a latent cache)."""
+        if not self._latent_row_bytes:
+            return {}
+        layers = self.cfg.num_attention_layers
+        moved = layers * (read + written) * self._latent_row_bytes
+        self.latent_rows_read += layers * read
+        self.latent_bytes_moved += moved
+        return {"latent_rows": layers * max(read, written), "latent_bytes": moved}
 
     def _split_counts(self, fetched: np.ndarray, n: int) -> tuple[np.ndarray, dict]:
         """One program's fetched token output -> (its ``n`` tokens, span
@@ -517,6 +576,7 @@ class ServeEngine:
         of blocking the loop. The gather is by value: the slot can be
         re-tenanted immediately."""
         refuse_recurrent(self.cfg, "the host tier's page-out")
+        refuse_latent(self.cfg, "the host tier's page-out")
         t0 = time.perf_counter()
         pk, pv = self._fetch_pages(
             self.cache_k, self.cache_v, jnp.int32(slot), self.page_rows(rows)
@@ -535,6 +595,7 @@ class ServeEngine:
         async — the next decode step queues behind it on-stream, so the
         scheduler thread never blocks on the transfer."""
         refuse_recurrent(self.cfg, "the host tier's page-in")
+        refuse_latent(self.cfg, "the host tier's page-in")
         t0 = time.perf_counter()
         self.cache_k, self.cache_v = self._insert(
             self.cache_k, self.cache_v,
@@ -567,6 +628,14 @@ class ServeEngine:
         moe.update(
             self._count_ssm(int(np.count_nonzero(lens)), 2 * self.ssm_state_resident_bytes)
         )
+        if self._latent_row_bytes:
+            # a live slot's rows [0, lens] (the ring's T once it has wrapped),
+            # the step's own among them
+            held = np.asarray(lens)
+            held = held[held > 0]
+            moe.update(self._count_latent(
+                read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size
+            ))
         t1 = time.perf_counter()
         self.stage_seconds["decode"] += t1 - t0
         tr = obs.tracer()
@@ -652,13 +721,29 @@ class ServeEngine:
         S, T = self.num_slots, self.max_context
         Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
         key = jax.random.PRNGKey(0)
+        pallas = self.decode_kernel == "pallas"
+        if cfg.latent:
+            # the one attention of a latent cache: the absorbed decode step
+            # (there is no verify pass to time, and no w4)
+            ql = jax.random.normal(key, (S, Nh, cfg.latent_row_dim), cd)
+
+            def _latent(ql, lens, cache):
+                step = mla_decode_attention if pallas else latent_decode_step_attention
+                return step(
+                    ql, ql[:, 0], cache, lens, 0,
+                    scale=cfg.qk_head_dim**-0.5, value_dim=cfg.kv_lora_rank,
+                )
+
+            return self._publish_probe({"decode_attn_us": _best_us(
+                _latent, ql, jnp.full((S,), T // 2, jnp.int32), self.cache_k[:1],
+                carried=1, iters=iters,
+            )})
         q1 = jax.random.normal(key, (S, Nh, Dh), cd)
         ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
         lens = jnp.full((S,), T // 2, jnp.int32)
         kq = self.tail_width
         qt = jax.random.normal(key, (S, kq, Nh, Dh), cd)
         tk = jax.random.normal(key, (S, kq, Nkv, Dh), cd)
-        pallas = self.decode_kernel == "pallas"
 
         def _attn(q1, k1, lens, ck, cv):
             # a decode step's attention over a cache of the one layer: the
@@ -671,27 +756,11 @@ class ServeEngine:
                 return spec_tail_attention_fused(qt, ck, cv, tk, tk, lens)
             return spec_tail_attention(qt, ck, cv, tk, tk, lens)
 
-        def _best(fn, *argv, carried=0):
-            """Best of ``iters`` timed calls after one that compiles; the
-            last ``carried`` arguments are donated and taken from the
-            call's trailing outputs each time."""
-            keep = len(argv) - carried
-            f = jax.jit(fn, donate_argnums=tuple(range(keep, len(argv))))
-            best = float("inf")
-            for i in range(1 + max(1, int(iters))):
-                t0 = time.perf_counter()
-                out = jax.block_until_ready(f(*argv))
-                if i:  # the first call compiled
-                    best = min(best, time.perf_counter() - t0)
-                if carried:
-                    argv = (*argv[:keep], *out[-carried:])
-            return best * 1e6
-
         out = {
-            "decode_attn_us": _best(
-                _attn, q1, tk[:, 0], lens, ck[None], cv[None], carried=2
+            "decode_attn_us": _best_us(
+                _attn, q1, tk[:, 0], lens, ck[None], cv[None], carried=2, iters=iters
             ),
-            "verify_attn_us": _best(_vattn, qt, ck, cv, tk, lens),
+            "verify_attn_us": _best_us(_vattn, qt, ck, cv, tk, lens, iters=iters),
         }
         packed = next(
             (
@@ -712,11 +781,14 @@ class ServeEngine:
                 def _wmm(x, q, s):
                     return x @ dequant_w4(q, s, packed.shape, cd)
             # stacked leaf: layer 0's slice is what one scan step sees
-            out["w4_matmul_us"] = _best(_wmm, x, packed.q[0], packed.s[0])
+            out["w4_matmul_us"] = _best_us(_wmm, x, packed.q[0], packed.s[0], iters=iters)
+        return self._publish_probe(out)
+
+    def _publish_probe(self, out: dict) -> dict:
         for name, us in out.items():
             obs.gauge(f"serve_{name}", us)
         obs.gauge(
-            "serve_decode_kernel_pallas", 1.0 if pallas else 0.0
+            "serve_decode_kernel_pallas", 1.0 if self.decode_kernel == "pallas" else 0.0
         )
         return out
 

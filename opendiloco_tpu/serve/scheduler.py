@@ -132,6 +132,14 @@ class ContinuousBatcher:
                 "prefix reuse and the host tier copy, cut and restore a slot's "
                 "past as cache rows, and a recurrent state is not rows"
             )
+        if engine.cfg.latent and (prefix_cache or kv_tier is not None):
+            refused = "prefix_cache" if prefix_cache else "kv_tier"
+            raise ValueError(
+                f"{refused} is refused for a configuration with latent attention: "
+                "prefix reuse and the host tier copy, cut and restore a slot's "
+                "past as (k, v) rows, and the latent ring is one array of latent "
+                "rows with no continued prefill over it"
+            )
         self.max_queue = int(max_queue)
         self.swap_every_steps = max(1, int(swap_every_steps))
         self.gauge_every_steps = max(1, int(gauge_every_steps))

@@ -157,6 +157,10 @@ def _resolve_perf_defaults(
                     "pallas" if on_tpu else "xla",
                 )
             changes["attn_impl"] = "pallas" if on_tpu else "xla"
+        if model_cfg.latent:
+            # latent attention trains in the rebuilt form through XLA's
+            # attention; ``forward`` refuses it the flash and ring kernels
+            changes["attn_impl"] = "xla"
     if tc.scan_unroll is None:
         # full unroll measured +6.8% tok/s on the HBM-bound 150m step (v5e
         # live window, round 5: 62.0k -> 66.2k at bs24+remat=dots); gated

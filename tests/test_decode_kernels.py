@@ -17,6 +17,10 @@ Oracles:
   proves dead ring blocks are skipped, not masked
 - a ring with no 128-row tile, and a head size off the sublanes, keep the
   XLA path per call
+- the latent decode kernel (one ring of latent rows, every head against the
+  same tile, values from the row's leading part) matches
+  ``latent_decode_step_attention`` over the same ragged lens, layers and
+  block sizes, the ring coming back with one row written per slot
 - a slot's pages survive the host tier's round trip (``fetch_pages`` ->
   ``cache_insert``) bit for bit
 - the fused speculative verify matches ``spec_tail_attention``'s exact
@@ -49,9 +53,11 @@ from opendiloco_tpu.ops import decode_kernels
 from opendiloco_tpu.ops.attention import (
     decode_attention,
     decode_step_attention,
+    latent_decode_step_attention,
     spec_tail_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
+    mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
     spec_tail_attention_fused,
@@ -173,6 +179,52 @@ def test_paged_decode_attention_untileable_shape_falls_back(T, D):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(ok), np.asarray(rk))
     np.testing.assert_array_equal(np.asarray(ov), np.asarray(rv))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("block_t", [8, 16, 32])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_mla_decode_attention_parity(layer, block_t, dtype):
+    """The latent kernel, interpreted, against the XLA absorbed path: slots
+    that are empty, mid-page, at a tile's edge on both sides, at the ring's
+    last row, and past the wrap (the row written at ``lens % T``, the whole
+    ring live); more slots than one lane-block's share is not needed, but the
+    slot's lane is picked by its index, so a few are enough to show it."""
+    L, S, H, Dl, Dv, T = 3, 7, 4, 24, 16, 32
+    keys = jax.random.split(jax.random.key(layer * 10 + block_t), 3)
+    cache = jax.random.normal(keys[0], cache_shape(L, S, T, 1, Dl), dtype)
+    q = jax.random.normal(keys[1], (S, H, Dl), dtype)
+    row = jax.random.normal(keys[2], (S, Dl), dtype)
+    lens = jnp.array([0, 5, 15, 16, 31, 40, 17], jnp.int32)
+    want, ring_x = latent_decode_step_attention(
+        q, row, cache, lens, layer, scale=0.25, value_dim=Dv)
+    got, ring_p = mla_decode_attention(
+        q, row, cache, lens, layer, scale=0.25, value_dim=Dv, block_t=block_t, interpret=True)
+    assert got.shape == (S, H, Dv)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(ring_p, np.float32), np.asarray(ring_x, np.float32))
+    # one row a slot, in the one layer: everything else as it was
+    changed = np.asarray(ring_p != cache)
+    assert not changed[[i for i in range(L) if i != layer]].any()
+    rows_written = np.asarray(jnp.mod(lens, T))
+    for s_ in range(S):
+        touched = np.flatnonzero(changed[layer, s_, 0].any(axis=0))
+        assert set(touched) <= {int(rows_written[s_])}
+
+
+def test_mla_decode_attention_untileable_shape_falls_back():
+    """A ring no tile divides (interpreted: 30 rows, tile 8), or a row off
+    the sublanes, keeps the XLA path: same results, no kernel."""
+    for T, Dl, Dv in ((30, 24, 16), (32, 20, 12)):
+        cache = jnp.zeros(cache_shape(2, 3, T, 1, Dl), jnp.float32)
+        q = jnp.ones((3, 2, Dl)); row = jnp.ones((3, Dl)); lens = jnp.array([0, 4, 9], jnp.int32)
+        fn = lambda *a: mla_decode_attention(*a, 1, scale=0.5, value_dim=Dv, block_t=8, interpret=True)
+        assert "odtp_mla_decode_attn" not in str(jax.make_jaxpr(fn)(q, row, cache, lens))
+        got, _ = fn(q, row, cache, lens)
+        want, _ = latent_decode_step_attention(q, row, cache, lens, 1, scale=0.5, value_dim=Dv)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
 def test_ring_tile_is_a_multiple_of_128_rows_on_the_chip(monkeypatch):

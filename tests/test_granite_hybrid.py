@@ -92,7 +92,9 @@ def tokens(seed: int, shape) -> np.ndarray:
 def test_published_keys_mean_the_hybrid():
     cfg = LlamaConfig.from_dict(published())
     assert cfg.hybrid and cfg.layer_types == tuple(PERIOD[:5])  # the pattern's leading part
-    assert layer_runs(cfg) == [Run("mamba", 0, 2), Run("attention", 0, 1), Run("mamba", 2, 2)]
+    # each run with its first layer's index in its mixer's state (PR 32): here its start
+    assert layer_runs(cfg) == [
+        Run("mamba", 0, 2, 0), Run("attention", 0, 1, 0), Run("mamba", 2, 2, 2)]
     assert (cfg.num_mamba_layers, cfg.num_attention_layers) == (4, 1)
     assert cfg.norm_topk_prob and cfg.router_aux_loss_coef == 0.0
     assert (cfg.num_experts, cfg.held_experts, cfg.first_local_expert) == (16, 8, 8)
@@ -104,7 +106,7 @@ def test_published_keys_mean_the_hybrid():
     assert LlamaConfig.from_dict(cfg.to_dict()) == cfg
     # a stack of attention layers has one run and no recurrent state
     dense = LlamaConfig(num_hidden_layers=3)
-    assert not dense.hybrid and layer_runs(dense) == [Run("attention", 0, 3)]
+    assert not dense.hybrid and layer_runs(dense) == [Run("attention", 0, 3, 0)]
     shapes = llama.shapes(LlamaConfig.from_dict(published()))["layers"]
     assert shapes["mamba"]["in_proj"].shape == (4, 32, 2 * 64 + 2 * 16 + 8)
     assert shapes["mamba"]["gate_proj"].shape == (4, 8, 32, 16)  # the held experts only

@@ -153,12 +153,32 @@ def _routed_qk_norm_model():
     return cfg, params
 
 
-@pytest.mark.parametrize("model", [_dense_model, _routed_qk_norm_model])
+def _latent_routed_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "glm4_moe_lite", "vocab_size": 256, "hidden_size": 64,
+        "intermediate_size": 96, "moe_intermediate_size": 32, "num_hidden_layers": 3,
+        "num_attention_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
+        "qk_nope_head_dim": 12, "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "first_k_dense_replace": 1, "n_routed_experts": 8, "n_shared_experts": 1,
+        "num_experts_per_tok": 2, "topk_method": "noaux_tc", "norm_topk_prob": True,
+        "routed_scaling_factor": 1.8, "rope_theta": 1e6, "max_position_embeddings": 128,
+    })
+    assert cfg.latent and cfg.leading_dense == 1
+    params = init_params(jax.random.key(5), cfg)
+    stack = params["layers"]["attention"]
+    stack["router"] = stack["router"] * 25.0  # no top-k choice near a tie
+    return cfg, params
+
+
+@pytest.mark.parametrize("model", [_dense_model, _routed_qk_norm_model, _latent_routed_model])
 def test_the_five_forwards_agree(model):
     """One block under five drivers: in float32 the training forward, the
     prefill, the decode step, the verify pass and the draft (at full depth)
     give one another's logits and greedy tokens. A change to one driver's
-    layer that the others do not get fails here."""
+    layer that the others do not get fails here. The latent block runs under
+    the three that support it (training and prefill rebuild k and v, the
+    decode step absorbs them: two formulas of one attention); the verify pass
+    and the draft handle (k, v) rows and refuse it."""
     from opendiloco_tpu.models.llama import (
         cache_insert, decode_forward, draft_propose, init_kv_cache,
         prefill_forward, verify_forward,
@@ -176,6 +196,7 @@ def test_the_five_forwards_agree(model):
     logits, ks, vs = prefill_forward(params, padded, jnp.int32(P), cfg, **f32)
     close(logits[0], full(prompt)[P - 1])
     tok = int(jnp.argmax(logits[0]))
+    assert (vs is None) == cfg.latent  # the latent rows alone are kept
 
     # slot 1 of two holds the prompt; one decode step = a verify pass over a
     # tail of one = the forward's next row
@@ -185,6 +206,15 @@ def test_the_five_forwards_agree(model):
     want = full(prompt + [tok])[P]
     step, _, _ = decode_forward(params, tokens, lens, ck, cv, cfg, **f32)
     close(step[1], want)
+    if cfg.latent:
+        for refused in (
+            lambda: verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32),
+            lambda: draft_propose(params, tokens, lens, ck, cv, cfg, k_steps=K,
+                                  draft_layers=cfg.num_hidden_layers, **f32),
+        ):
+            with pytest.raises(ValueError, match="refused for a configuration with latent"):
+                refused()
+        return
     verified, _, _ = verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32)
     close(verified[1, 0], want)
 

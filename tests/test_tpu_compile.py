@@ -613,3 +613,105 @@ def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
     assert mem.temp_size_in_bytes < weights / (2 if program == "prefill" else 4)
     assert mem.alias_size_in_bytes >= carried
     assert _program_bytes(compiled) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the GLM-4.7-Flash cell (ISSUE 32): the latent decode kernel alone, then the
+# cell's largest prefill and its decode step whole, published widths, 24
+# layers, with the bf16 tree the engine holds. They fit the chip beside what
+# the engine keeps; the decode step reads the one latent ring where it lies,
+# copies nothing of a layer's size and casts no weight
+# ---------------------------------------------------------------------------
+
+
+def _glm_cell(chip):
+    """-> (configuration, engine options, the bound parameters, the latent
+    ring), as shapes on the described chip."""
+    cfg, engine = _serve_cell("glm-4.7-flash", "serve-glm-flash-agent")
+    ring = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_hidden_layers, engine["num_slots"], engine["max_context"],
+                    1, cfg.latent_row_dim),
+        BF16, sharding=chip,
+    )
+    return cfg, engine, _bound(chip, cfg), ring
+
+
+@pytest.mark.parametrize("slots", [64, 8])
+def test_mla_decode_attention(chip, slots):
+    """The kernel at the published sizes (20 heads over rows of 512 + 64, a
+    ring of 2,048 rows, 24 layers): the Mosaic kernel and not its XLA
+    stand-in, the ring aliased to the output, no temporary."""
+    from opendiloco_tpu.ops.decode_kernels import mla_decode_attention
+
+    ring = cache_shape(24, slots, 2048, 1, 576)
+    compiled = jax.jit(
+        lambda q, row, cache, lens, layer: mla_decode_attention(
+            q, row, cache, lens, layer[0], scale=1 / 16, value_dim=512, interpret=False),
+        donate_argnums=(2,),
+    ).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in (
+            ((slots, 20, 576), BF16), ((slots, 576), BF16), (ring, BF16),
+            ((slots,), jnp.int32), ((1,), jnp.int32),
+        )
+    )).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_mla_decode_attn" in text and "tpu_custom_call" in text
+    ring_bytes = 2 * 24 * slots * 576 * 2048
+    assert mem.alias_size_in_bytes >= ring_bytes and mem.temp_size_in_bytes < ring_bytes // 24
+
+
+def test_glm_prefill_program_at_the_largest_bucket(chip):
+    """Bucket 1,792 in the rebuilt form: the grouped matmuls over the 8 held
+    experts are in it, it casts no weight, its temporaries (the scores of 20
+    heads over 1,792 x 1,792 among them) stay under half the weights, and it
+    fits beside the resident ring."""
+    from opendiloco_tpu.models.llama import prefill_forward
+
+    cfg, engine, params, ring = _glm_cell(chip)
+    assert (cfg.leading_dense, cfg.held_experts, cfg.num_experts, cfg.latent_row_dim) == (1, 8, 64, 576)
+    bucket = max(engine["prefill_buckets"])
+    compiled = (
+        jax.jit(lambda p, ids, n: prefill_forward(
+            p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=True))
+        .lower(
+            params,
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
+        ).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "%ragged-dot" in text
+    leaves = jax.tree.leaves(params)
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in leaves})
+    weights = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert mem.temp_size_in_bytes < weights / 2
+    assert _program_bytes(compiled) + 2 * ring.size < HBM_BYTES
+
+
+def test_glm_decode_step_reads_the_latent_ring_in_place(chip, monkeypatch):
+    """64 slots (or what the cell's file says): the latent kernel over the
+    one ring, the grouped matmuls, no cast of a weight, the ring aliased to
+    the output, and no copy, transpose, scatter, slice, update or fresh
+    buffer of the ring's shape or of one layer's pages."""
+    from opendiloco_tpu.models.llama import decode_forward
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, params, ring = _glm_cell(chip)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(
+            lambda p, tok, lens, ck: decode_forward(
+                p, tok, lens, ck, None, cfg, decode_kernel="pallas", return_moe_counts=True),
+            donate_argnums=(3,),
+        ).lower(params, vec, vec, ring).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_mla_decode_attn" in text and "%ragged-dot" in text
+    assert "odtp_paged_decode_attn" not in text
+    leaves = jax.tree.leaves(params)
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in leaves})
+    weights = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert mem.temp_size_in_bytes < weights / 4
+    assert mem.alias_size_in_bytes >= 2 * ring.size
+    assert _program_bytes(compiled) < HBM_BYTES
+    assert not _cache_shaped_results(text, ring.shape)
