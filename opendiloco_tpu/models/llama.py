@@ -476,7 +476,23 @@ def layer_runs(cfg: LlamaConfig) -> list[Run]:
     return runs
 
 
-def scan_layers(cfg: LlamaConfig, body, carry, layers: dict, run: Run, unroll: int = 1):
+# a routed layer's expert matrices, [Eh, in, out] each in a stack [L, Eh, in, out]
+EXPERT_LEAVES = ("gate_proj", "up_proj", "down_proj")
+
+
+class InStack(NamedTuple):
+    """Layer ``index``'s part of ``stack`` [L, ...], not cut out of it: what
+    ``scan_layers`` hands a serving body for each expert matrix of a routed
+    layer, and what ``_grouped_matmul`` reads in place."""
+
+    stack: jax.Array
+    index: jax.Array
+
+
+def scan_layers(
+    cfg: LlamaConfig, body, carry, layers: dict, run: Run, unroll: int = 1,
+    experts_in_place: bool = False,
+):
     """``lax.scan`` of ``body(carry, layer, li) -> (carry, ys)`` over one
     run's layers: ``layer`` the layer's weights, ``li`` its index in its
     mixer's cache or state (which is its index in its kind's stack, but
@@ -486,20 +502,40 @@ def scan_layers(cfg: LlamaConfig, body, carry, layers: dict, run: Run, unroll: i
     its kind's stack: the scan runs over the indices and the body cuts its
     layer out of the whole stack, which is what a scan does with its ``xs``
     anyway; a static slice of the stack would be a copy of the run's weights
-    in every call."""
+    in every call. A dynamic one is a copy too where its reader is a custom
+    call, whose operand has to be a buffer of its own and cannot be fused
+    with the slice: the TPU's grouped matmul (``lax.ragged_dot``) is one, so
+    a routed layer's three expert matrices, cut from their stack, are written
+    out in every layer of every call. With ``experts_in_place`` (the serving
+    forwards) those three leaves are left out of what is cut: the body gets
+    each as ``InStack(whole stack, the layer's index in its kind's stack)``,
+    and ``_grouped_matmul`` reads the layer's experts where they lie. Every
+    other leaf is cut all the same (XLA fuses those slices into the dots
+    that read them). Training keeps the cut for the experts too:
+    differentiated, each layer's weight gradient would be of the whole
+    stack's size."""
     ids = run.start + jnp.arange(run.count, dtype=jnp.int32)
+    stack = layers[run.kind] if cfg.layers_by_kind else layers
+    whole = {}
+    if experts_in_place and "router" in stack:
+        whole = {name: stack[name] for name in EXPERT_LEAVES}
+        stack = {name: x for name, x in stack.items() if name not in whole}
+
+    def in_place(layer, i):  # i: the layer's index in its kind's stack
+        return {**layer, **{name: InStack(x, i) for name, x in whole.items()}}
+
     if not cfg.layers_by_kind:
         return jax.lax.scan(
-            lambda c, xs: body(c, *xs), carry, (layers, ids), unroll=unroll
+            lambda c, xs: body(c, in_place(xs[0], xs[1]), xs[1]),
+            carry, (stack, ids), unroll=unroll,
         )
-    stack = layers[run.kind]
     ahead = run.state - run.start  # static; 0 where a kind has a cache to itself
 
     def indexed(c, li):
         layer = jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False), stack
         )
-        return body(c, layer, li + ahead if ahead else li)
+        return body(c, in_place(layer, li), li + ahead if ahead else li)
 
     return jax.lax.scan(indexed, carry, ids, unroll=unroll)
 
@@ -731,6 +767,26 @@ def refuse_latent(cfg: LlamaConfig, what: str) -> None:
         )
 
 
+def _grouped_matmul(xs: jax.Array, w, sizes: jax.Array) -> jax.Array:
+    """Rows ``xs`` [M, in], sorted by expert with ``sizes`` [Eh] rows each,
+    through each expert's own matrix -> [M, out]; rows behind the last group
+    come out zero. ``w`` is what the caller holds of the layer's experts: its
+    matrices [Eh, in, out], or ``InStack`` of the stack [L, Eh, in, out] they
+    lie in. Then the stack is read as L * Eh groups (a reshape of the whole
+    buffer, which moves nothing) of which all but the layer's own are empty:
+    an empty group gets no tile of the TPU's grouped matmul, whose weight
+    window follows the group's id, so the layer's experts are read where they
+    lie and no other layer's are touched. The groups with rows, their rows
+    and their matrices are the same either way, and so is the product."""
+    if isinstance(w, InStack):
+        L, Eh = w.stack.shape[:2]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * Eh,), sizes.dtype), sizes, (w.index * Eh,)
+        )
+        w = w.stack.reshape(L * Eh, *w.stack.shape[2:])
+    return jax.lax.ragged_dot(xs, w, sizes)
+
+
 def _routed_ffn(
     cfg: LlamaConfig, x: jax.Array, layer: dict, live: Optional[jax.Array]
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -744,7 +800,11 @@ def _routed_ffn(
     matmuls over them (``lax.ragged_dot``: its group dimension is the
     weights' expert dimension, which the "ep" mesh axis shards); the pairs'
     outputs go back to token order by the inverse permutation and are
-    summed under their weights.
+    summed under their weights. The three go through ``_grouped_matmul``,
+    which takes the layer's expert matrices as the caller holds them: cut
+    out of their stack (training, which differentiates them, and any call
+    with one layer's leaves) or in place in it (``InStack``, the serving
+    forwards: ``scan_layers``).
 
     Aux loss (OLMoE, arXiv 2409.02060): ``router_aux_loss_coef`` times the
     load balance E * sum_e f_e P_e (f_e the share of tokens that chose e,
@@ -798,9 +858,9 @@ def _routed_ffn(
     order = jnp.argsort(group, stable=True)  # pairs by expert
     xs = xf[order // K]
     h = jax.nn.silu(
-        jax.lax.ragged_dot(xs, layer["gate_proj"], sizes)
-    ) * jax.lax.ragged_dot(xs, layer["up_proj"], sizes)
-    ys = jax.lax.ragged_dot(h, layer["down_proj"], sizes)  # [N * K, D]
+        _grouped_matmul(xs, layer["gate_proj"], sizes)
+    ) * _grouped_matmul(xs, layer["up_proj"], sizes)
+    ys = _grouped_matmul(h, layer["down_proj"], sizes)  # [N * K, D]
     ys = ys[jnp.argsort(order)]
     if share:
         ys = jnp.where(held[:, None], ys, 0)
@@ -1302,7 +1362,9 @@ def prefill_forward(
     counts = []
     for run in layer_runs(cfg):
         body = attention_body if run.mixer == "attention" else mamba_body
-        h, (a, b, c) = scan_layers(cfg, body, h, cparams["layers"], run)
+        h, (a, b, c) = scan_layers(
+            cfg, body, h, cparams["layers"], run, experts_in_place=True
+        )
         kept[run.mixer][0].append(a)
         kept[run.mixer][1].append(b)
         counts.append(c)
@@ -1413,11 +1475,13 @@ def decode_forward(
     for run in layer_runs(cfg):
         if run.mixer == "attention":
             (h, cache_k, cache_v), c = scan_layers(
-                cfg, attention_body, (h, cache_k, cache_v), cparams["layers"], run
+                cfg, attention_body, (h, cache_k, cache_v), cparams["layers"], run,
+                experts_in_place=True,
             )
         else:
             (h, ssm_state, conv_state), c = scan_layers(
-                cfg, mamba_body, (h, ssm_state, conv_state), cparams["layers"], run
+                cfg, mamba_body, (h, ssm_state, conv_state), cparams["layers"], run,
+                experts_in_place=True,
             )
         counts.append(c)
     logits = _logits(cfg, cparams, h)

@@ -500,13 +500,12 @@ _INSTRUCTION = re.compile(
 )
 
 
-def _leaf_shaped_casts(text: str, leaf_shapes: set, dtype: str = "bf16") -> list[str]:
-    """Instructions of a compiled program that run as an operation of their
-    own (outside the fused computations) and are a ``convert``, or a fusion
-    whose root is one, with a result in ``dtype`` of a weight leaf's shape:
-    what ``_serving_boundary`` emits for a leaf that did not come in the
-    compute dtype."""
-    roots, fused, top_level, name = {}, set(), [], None
+def _top_level(text: str) -> tuple[list, dict]:
+    """A compiled program's instructions that run as operations of their own
+    (outside the fused computations), each as (opcode, result dtype, result
+    dimensions, the computation a fusion calls, the line's head), and every
+    computation's root opcode."""
+    roots, fused, found, name = {}, set(), [], None
     for line in text.splitlines():
         m = _COMPUTATION.match(line.strip())
         if m:
@@ -522,10 +521,19 @@ def _leaf_shaped_casts(text: str, leaf_shapes: set, dtype: str = "bf16") -> list
             fused.add(called.group(1))
         if root:
             roots[name] = opcode
-        top_level.append((name, opcode, result, shape, called and called.group(1), line.strip()[:160]))
+        found.append((name, opcode, result, shape, called and called.group(1), line.strip()[:160]))
+    return [x[1:] for x in found if x[0] not in fused], roots
+
+
+def _leaf_shaped_casts(text: str, leaf_shapes: set, dtype: str = "bf16") -> list[str]:
+    """Instructions of a compiled program that run as an operation of their
+    own and are a ``convert``, or a fusion whose root is one, with a result in
+    ``dtype`` of a weight leaf's shape: what ``_serving_boundary`` emits for a
+    leaf that did not come in the compute dtype."""
+    instructions, roots = _top_level(text)
     return [
-        line for comp, opcode, result, shape, called, line in top_level
-        if comp not in fused and result == dtype and shape in leaf_shapes
+        line for opcode, result, shape, called, line in instructions
+        if result == dtype and shape in leaf_shapes
         and (opcode == "convert" or roots.get(called) == "convert")
     ]
 
@@ -548,71 +556,148 @@ SERVE_CELLS = {
     "serve-olmoe-fewshot": "olmoe-1b-7b",
     "serve-granite-h-docqa": "granite-4.0-h-small",
 }
+ROUTED_CELLS = {
+    "serve-olmoe-fewshot": "olmoe-1b-7b",
+    "serve-granite-h-docqa": "granite-4.0-h-small",
+    "serve-glm-flash-agent": "glm-4.7-flash",
+}
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("workload", list(SERVE_CELLS))
-def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
-    """Each serve cell whole, published widths, lowered as the engine lowers
-    it (kernel ``pallas``, counts where routed, caches and state donated)
-    with the bf16 tree the engine holds: no cast of a weight leaf is left,
-    the temporaries are a fraction of the weights (the per-call bf16 copy was
-    all of them: 0.73 / 3.63 / 3.83 GB in the three decode programs at the
-    parent), and a decode step still updates caches and state where they
-    are."""
+def _engine_program(chip, config, workload, program):
+    """A serve cell's decode step or its largest prefill, published widths
+    and the cell's cuts, lowered as the engine lowers it (kernel ``pallas``,
+    counts where routed, caches and state donated) with the bf16 tree the
+    engine holds, compiled for the described chip -> (the compiled program,
+    the configuration, the parameters, the bytes the step carries)."""
     from opendiloco_tpu.models import mamba
     from opendiloco_tpu.models.llama import decode_forward, prefill_forward
 
-    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
-    cfg, engine = _serve_cell(SERVE_CELLS[workload], workload)
+    cfg, engine = _serve_cell(config, workload)
     params = _bound(chip, cfg)
     moe = bool(cfg.num_experts)
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
     if program == "prefill":
         bucket = max(engine["prefill_buckets"])
         compiled = (
             jax.jit(lambda p, ids, n: prefill_forward(
                 p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=moe))
-            .lower(params, jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip), scalar)
-            .compile()
+            .lower(
+                params,
+                jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
+            ).compile()
         )
-        carried = 0
-    else:
-        slots, rows = engine["num_slots"], engine["max_context"]
-        layers = cfg.num_attention_layers if cfg.hybrid else cfg.num_hidden_layers
-        cache = jax.ShapeDtypeStruct(
-            cache_shape(layers, slots, rows, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip
-        )
-        state = []
-        if cfg.hybrid:
-            ssm, conv = mamba.state_shapes(cfg, slots)
-            state = [
-                jax.ShapeDtypeStruct(ssm, jnp.float32, sharding=chip),
-                jax.ShapeDtypeStruct(conv, BF16, sharding=chip),
-            ]
-        vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+        return compiled, cfg, params, 0
+    slots, rows = engine["num_slots"], engine["max_context"]
+    width = (1, cfg.latent_row_dim) if cfg.latent else (cfg.kv_heads, cfg.head_dim)
+    ring = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_attention_layers, slots, rows, *width), BF16, sharding=chip
+    )
+    carried = [ring] if cfg.latent else [ring, ring]  # a latent ring has no values
+    if cfg.hybrid:
+        ssm, conv = mamba.state_shapes(cfg, slots)
+        carried += [
+            jax.ShapeDtypeStruct(ssm, jnp.float32, sharding=chip),
+            jax.ShapeDtypeStruct(conv, BF16, sharding=chip),
+        ]
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
 
-        def step(p, tok, lens, ck, cv, *ssm):
-            return decode_forward(
-                p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=moe,
-                **dict(zip(("ssm_state", "conv_state"), ssm)),
-            )
-
-        compiled = (
-            jax.jit(step, donate_argnums=tuple(range(3, 5 + len(state))))
-            .lower(params, vec, vec, cache, cache, *state).compile()
+    def step(p, tok, lens, ck, *rest):
+        cv, *state = (None, *rest) if cfg.latent else rest
+        return decode_forward(
+            p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=moe,
+            **dict(zip(("ssm_state", "conv_state"), state)),
         )
-        carried = sum(x.size * x.dtype.itemsize for x in (cache, cache, *state))
+
+    compiled = (
+        jax.jit(step, donate_argnums=tuple(range(3, 3 + len(carried))))
+        .lower(params, vec, vec, *carried).compile()
+    )
+    return compiled, cfg, params, sum(x.size * x.dtype.itemsize for x in carried)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("workload", list(SERVE_CELLS))
+def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
+    """Each serve cell whole (``_engine_program``): no cast of a weight leaf
+    is left, the temporaries are a fraction of the weights (the per-call bf16
+    copy was all of them: 0.73 / 3.63 / 3.83 GB in the three decode programs
+    at PR 30), and a decode step still updates caches and state where they
+    are."""
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    compiled, cfg, params, carried = _engine_program(
+        chip, SERVE_CELLS[workload], workload, program
+    )
     leaves = jax.tree.leaves(params)
     assert not _leaf_shaped_casts(compiled.as_text(), {tuple(x.shape) for x in leaves})
     mem = compiled.memory_analysis()
     weights = sum(x.size * x.dtype.itemsize for x in leaves)
     assert mem.argument_size_in_bytes >= weights + carried
-    # compiled here: decode 0.10 / 0.27 / 0.06 GB, prefill 0.10 / 1.24 / 0.62 GB
-    # (batch / OLMoE / granite), a prefill's being its attention scores
+    # compiled here: decode 0.10 / 0.001 / 0.003 GB, prefill 0.10 / 1.25 / 0.62 GB
+    # (batch / OLMoE / granite), a prefill's being its attention scores. Until
+    # ISSUE 33 the OLMoE decode step's 0.27 GB and granite's 0.06 were one
+    # layer's ``gate_proj``, cut out of its stack for the grouped matmul
+    # (``test_serving_programs_copy_no_experts``)
     assert mem.temp_size_in_bytes < weights / (2 if program == "prefill" else 4)
     assert mem.alias_size_in_bytes >= carried
     assert _program_bytes(compiled) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# a routed layer's experts read where they lie (ISSUE 33): the three routed
+# cells' decode step and largest prefill write out no layer's expert matrices,
+# and their grouped matmuls take the whole stack
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("workload", list(ROUTED_CELLS))
+def test_serving_programs_copy_no_experts(chip, workload, program, monkeypatch):
+    """``lax.ragged_dot`` is a custom call on the TPU, and a custom call's
+    operand is a buffer of its own: handed a layer's experts as a slice of
+    their stack, XLA wrote the slice out (``%dynamic-slice_bitcast_fusion``,
+    three a layer a call, 0.8 GB in an OLMoE layer). Handed the stack and the
+    layer's index (``llama.InStack``) each grouped matmul's weight operand is
+    the stack itself, read as ``L * Eh`` groups; nothing that runs as an
+    operation of its own yields an array of one layer's experts, in either
+    orientation, but what moves nothing; and a decode step's temporaries are
+    under one expert matrix of a layer."""
+    from opendiloco_tpu.models.llama import EXPERT_LEAVES
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    compiled, cfg, params, _ = _engine_program(
+        chip, ROUTED_CELLS[workload], workload, program
+    )
+    stacks = params["layers"] if cfg.layers_by_kind else {"attention": params["layers"]}
+    experts = [  # every routed kind's [L, Eh, in, out] stacks
+        stack[name].shape for stack in stacks.values() if "router" in stack
+        for name in EXPERT_LEAVES
+    ]
+    assert experts and all(shape[1] == cfg.held_experts for shape in experts)
+    text = compiled.as_text()
+    instructions, _ = _top_level(text)
+
+    of_a_layer = {tuple(sorted(shape[1:])) for shape in experts}
+    written = [
+        line for opcode, _, shape, _, line in instructions
+        if tuple(sorted(d for d in shape if d != 1)) in of_a_layer
+        and not any(k in line for k in _MOVES_NOTHING)
+    ]
+    assert not written, written
+
+    results = {m.group(1): tuple(int(d) for d in m.group(3).split(",") if d)
+               for m in map(_RESULT.match, text.splitlines()) if m}
+    stacks_as_groups = {(shape[0] * shape[1], *shape[2:]) for shape in experts}
+    calls = re.findall(
+        r"^\s*%ragged-dot-none[.\d]* = \S+ custom-call\((.*?)\), custom_call_target", text, re.M
+    )
+    assert len(calls) >= 3  # gate, up and down of a routed run's scan
+    for operands in calls:  # the weights come last
+        weight = operands.split(", ")[-1].split("*/")[-1]
+        assert results[weight] in stacks_as_groups, (operands, results[weight])
+
+    if program == "decode":
+        matrix = min(2 * shape[1] * shape[2] * shape[3] for shape in experts)
+        assert compiled.memory_analysis().temp_size_in_bytes < matrix
 
 
 # ---------------------------------------------------------------------------
@@ -625,15 +710,14 @@ def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
 
 
 def _glm_cell(chip):
-    """-> (configuration, engine options, the bound parameters, the latent
-    ring), as shapes on the described chip."""
+    """-> (configuration, the latent ring as a shape on the described chip)."""
     cfg, engine = _serve_cell("glm-4.7-flash", "serve-glm-flash-agent")
     ring = jax.ShapeDtypeStruct(
         cache_shape(cfg.num_hidden_layers, engine["num_slots"], engine["max_context"],
                     1, cfg.latent_row_dim),
         BF16, sharding=chip,
     )
-    return cfg, engine, _bound(chip, cfg), ring
+    return cfg, ring
 
 
 @pytest.mark.parametrize("slots", [64, 8])
@@ -665,19 +749,10 @@ def test_glm_prefill_program_at_the_largest_bucket(chip):
     experts are in it, it casts no weight, its temporaries (the scores of 20
     heads over 1,792 x 1,792 among them) stay under half the weights, and it
     fits beside the resident ring."""
-    from opendiloco_tpu.models.llama import prefill_forward
-
-    cfg, engine, params, ring = _glm_cell(chip)
+    cfg, ring = _glm_cell(chip)
     assert (cfg.leading_dense, cfg.held_experts, cfg.num_experts, cfg.latent_row_dim) == (1, 8, 64, 576)
-    bucket = max(engine["prefill_buckets"])
-    compiled = (
-        jax.jit(lambda p, ids, n: prefill_forward(
-            p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=True))
-        .lower(
-            params,
-            jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
-        ).compile()
+    compiled, _, params, _ = _engine_program(
+        chip, "glm-4.7-flash", "serve-glm-flash-agent", "prefill"
     )
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "%ragged-dot" in text
@@ -693,18 +768,12 @@ def test_glm_decode_step_reads_the_latent_ring_in_place(chip, monkeypatch):
     one ring, the grouped matmuls, no cast of a weight, the ring aliased to
     the output, and no copy, transpose, scatter, slice, update or fresh
     buffer of the ring's shape or of one layer's pages."""
-    from opendiloco_tpu.models.llama import decode_forward
-
     monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
-    cfg, engine, params, ring = _glm_cell(chip)
-    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
-    compiled = (
-        jax.jit(
-            lambda p, tok, lens, ck: decode_forward(
-                p, tok, lens, ck, None, cfg, decode_kernel="pallas", return_moe_counts=True),
-            donate_argnums=(3,),
-        ).lower(params, vec, vec, ring).compile()
+    _, ring = _glm_cell(chip)
+    compiled, _, params, carried = _engine_program(
+        chip, "glm-4.7-flash", "serve-glm-flash-agent", "decode"
     )
+    assert carried == 2 * ring.size
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "odtp_mla_decode_attn" in text and "%ragged-dot" in text
     assert "odtp_paged_decode_attn" not in text
