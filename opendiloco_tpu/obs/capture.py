@@ -11,6 +11,7 @@ state before it stay untraced), and starts the profiler with them::
     ...                                   # the steps or seconds to look at
     cap = obs.capture.stop()              # spans, counters, request traces
     cap.save("/tmp/prof/odtp_capture.json")
+    obs.capture.last() is cap             # until the next stop, for a late reader
 
 Where ``ODTP_OBS`` is set, the capture uses the operator's tracer and ring
 and leaves them armed at ``stop``; where it is not, hook sites see a tracer
@@ -78,6 +79,7 @@ class _Open:
 
 _lock = threading.Lock()
 _open: Optional[_Open] = None
+_last: Optional[Capture] = None
 
 
 def start(profile_dir: Optional[str] = None, *, ring_cap: Optional[int] = None) -> None:
@@ -124,14 +126,14 @@ def stop() -> Capture:
     """Stop what ``start`` started and hand over what was recorded. A
     plane this capture armed is disarmed (hook sites see ``None`` again);
     one that ``ODTP_OBS`` armed stays as it was."""
-    global _open
+    global _open, _last
     with _lock:
         if _open is None:
             raise RuntimeError("no capture is open in this process")
         o, _open = _open, None
         # recording ends here: writing the profiler's trace can take seconds,
         # and what the process does meanwhile is not part of the capture
-        out = _recorded(o)
+        out = _last = _recorded(o)
         o.ring.cap = o.ring_cap_before
         if o.own:
             _disarm()
@@ -162,10 +164,20 @@ def _disarm() -> None:
     reqtrace.ring()
 
 
+def last() -> Optional[Capture]:
+    """What the newest ``stop`` returned, the object itself: for a reader
+    that runs after whoever stopped the capture has let go of it. None
+    before any, and after ``obs.reset()``."""
+    return _last
+
+
 def abandon() -> None:
-    """Close an open capture and throw away what it recorded (tests)."""
+    """Close an open capture and throw away what it recorded, and the last
+    one kept (tests)."""
+    global _last
     if _open is not None:
         try:
             stop()
         except Exception:
             pass
+    _last = None

@@ -147,6 +147,10 @@ _STAGES = (
     "prefill", "draft", "verify", "insert", "decode", "swap",
     "page_out", "page_in",
 )
+# where a cold prefill and a decode step change hands: until the arguments of
+# the call's first program are device arrays, until its last jitted call has
+# returned to Python, until the tokens are on the host
+_PHASES = ("args", "dispatch", "fetch")
 
 
 class ServeEngine:
@@ -241,6 +245,17 @@ class ServeEngine:
         # wall-clock per decode stage (loop-thread only, mirrored to obs
         # spans when a tracer is armed; the bench reads this directly)
         self.stage_seconds = {k: 0.0 for k in _STAGES}
+        # the two stages a cell runs, cut into their phases (always on, from
+        # stamps the call takes itself; spans ``serve_args``, ``serve_dispatch``
+        # and ``serve_fetch`` from the same stamps while a tracer is armed).
+        # ``stage_seconds`` less a stage's three is the counting after the read
+        self.phase_seconds = {
+            stage: {k: 0.0 for k in _PHASES} for stage in ("prefill", "decode")
+        }
+        self.phase_calls = {"prefill": 0, "decode": 0}
+        # (t0, t1) of the last ``decode_step``, for the loop to tile its own
+        # phases against
+        self.decode_bounds = (0.0, 0.0)
         # what a routed FFN did in the prefills and decode steps so far, each
         # summed over layers and calls (always on; stay 0 for a dense model):
         # token-expert pairs, experts that received a token, and the busiest
@@ -445,6 +460,7 @@ class ServeEngine:
             refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         t0 = time.perf_counter()
         moe = {}  # a continued prefill's routing is not counted
+        cuts = None  # nor are its phases cut
         if host_prefix is not None and 0 < host_prefix[2] < n:
             hk, hv, plen = host_prefix
             self.cache_k, self.cache_v = self._insert(
@@ -459,24 +475,44 @@ class ServeEngine:
         else:
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :n] = np.asarray(prompt, np.int32)
-            tokd, logitsd, ks, vs, *left = self._prefill(
-                self.params, jnp.asarray(ids), jnp.int32(n)
-            )
+            idsd, nd = jnp.asarray(ids), jnp.int32(n)
+            t_args = time.perf_counter()
+            tokd, logitsd, ks, vs, *left = self._prefill(self.params, idsd, nd)
+            # the slot's scalar is made here, while the device runs the
+            # prompt: made with the others it holds every prefill's start back
+            # by its own host time (a third of a millisecond on the chip)
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v, ks, vs, jnp.int32(slot)
             )
             if left:  # the recurrent state the prompt left, whole
                 self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
-            toks, moe = self._split_counts(np.asarray(tokd), 1)
+            t_dispatch = time.perf_counter()
+            fetched, logits = np.asarray(tokd), np.asarray(logitsd[0])
+            cuts = (t_args, t_dispatch, time.perf_counter())
+            toks, moe = self._split_counts(fetched, 1)
             moe.update(self._count_ssm(n, sum(x.nbytes for x in left)))
             moe.update(self._count_latent(read=0, written=n))
-            tok, logits = int(toks[0]), np.asarray(logitsd[0])
+            tok = int(toks[0])
         dt = time.perf_counter() - t0
         self.stage_seconds["prefill"] += dt
         tr = obs.tracer()
         if tr is not None:
             tr.add_span("serve_prefill", t0, t0 + dt, tokens=n, **moe)
+        if cuts is not None:
+            self._count_phases("prefill", t0, cuts, tr)
         return tok, logits
+
+    def _count_phases(self, stage: str, t0: float, cuts: tuple, tr) -> None:
+        """One call's three phases, ``cuts`` the stamps that end them -> the
+        engine's counters, and spans that tile the front of the call's
+        ``serve_prefill`` / ``serve_decode`` where ``tr`` is an armed tracer."""
+        total = self.phase_seconds[stage]
+        for phase, t1 in zip(_PHASES, cuts):
+            total[phase] += t1 - t0
+            if tr is not None:
+                tr.add_span(f"serve_{phase}", t0, t1, stage=stage)
+            t0 = t1
+        self.phase_calls[stage] += 1
 
     def _count_ssm(self, tokens: int, state_bytes: int) -> dict:
         """Add one call's Mamba-2 work to the engine's counters -> the same
@@ -615,16 +651,16 @@ class ServeEngine:
         masked positions and are overwritten on the slot's next tenancy).
         Returns (next tokens [S] np.int32, logits [S, V] on device)."""
         t0 = time.perf_counter()
+        tokensd, lensd = jnp.asarray(tokens, jnp.int32), jnp.asarray(lens, jnp.int32)
+        t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *ssm = self._decode(
-            self.params,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(lens, jnp.int32),
-            self.cache_k,
-            self.cache_v,
-            *self._ssm,
+            self.params, tokensd, lensd, self.cache_k, self.cache_v, *self._ssm
         )
         self._ssm = tuple(ssm)
-        tok, moe = self._split_counts(np.asarray(tok), self.num_slots)
+        t_dispatch = time.perf_counter()
+        fetched = np.asarray(tok)
+        cuts = (t_args, t_dispatch, time.perf_counter())
+        tok, moe = self._split_counts(fetched, self.num_slots)
         moe.update(
             self._count_ssm(int(np.count_nonzero(lens)), 2 * self.ssm_state_resident_bytes)
         )
@@ -638,6 +674,7 @@ class ServeEngine:
             ))
         t1 = time.perf_counter()
         self.stage_seconds["decode"] += t1 - t0
+        self.decode_bounds = (t0, t1)
         tr = obs.tracer()
         if tr is not None:
             tr.count(f"serve_decode_kernel_{self.decode_kernel}")
@@ -647,6 +684,7 @@ class ServeEngine:
                 "serve_decode", t0, t1,
                 rows=int(np.sum(lens)), slots=int(np.count_nonzero(lens)), **moe,
             )
+        self._count_phases("decode", t0, cuts, tr)
         return tok, logits
 
     def _propose_draft(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:

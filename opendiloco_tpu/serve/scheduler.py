@@ -154,7 +154,11 @@ class ContinuousBatcher:
         self.tier_quantum_steps = max(1, int(tier_quantum_steps))
         self.tier_min_resident_steps = max(1, int(tier_min_resident_steps))
         self.spec_decode = engine.spec_k > 0
-        self._kernel_probed = False
+        # the one-time kernel probe feeds gauges alone: it runs only in a
+        # process whose tracer ODTP_OBS had armed by now, never because a
+        # capture arms one later (a probe compiles, and a capture is a stretch
+        # in which nothing may)
+        self._kernel_probed = obs.tracer() is None
         self.slots = SlotAllocator(engine.num_slots)
         self._active: dict[int, _Slot] = {}  # slot id -> state
         self._queue: collections.deque[Request] = collections.deque()
@@ -167,6 +171,10 @@ class ContinuousBatcher:
         # (loop-thread only, always on, in the manner of stage_seconds)
         self.loop_seconds = 0.0
         self.loop_iterations = 0
+        # the two parts of a step's iteration on either side of the engine
+        # call: batch assembly, and emit through the last retire
+        self.batch_seconds = 0.0
+        self.emit_seconds = 0.0
         self._t_step_end: Optional[float] = None
         # stats (mutated only by the loop thread; read racily for gauges)
         self.completed = 0
@@ -791,14 +799,16 @@ class ContinuousBatcher:
         # engine call, and token emit — so per-step scheduler time is
         # attributed to the requests it served, and a trace's stage sums
         # reconcile with its end-to-end latency
+        t_batch = time.perf_counter()
         if t0 is None:
-            t0 = time.perf_counter()
+            t0 = t_batch
         tokens = np.zeros((S,), np.int32)
         lens = np.zeros((S,), np.int32)
         for slot, st in self._active.items():
             tokens[slot] = st.last_token
             lens[slot] = st.cache_len
         next_tokens, _ = self.engine.decode_step(tokens, lens)
+        step_t0, step_t1 = self.engine.decode_bounds
         self.staleness_hist[self.engine.staleness()] += 1
         obs.count("serve_tokens_generated", len(self._active))
         batch = len(self._active)
@@ -830,6 +840,13 @@ class ContinuousBatcher:
         for slot in done_slots:
             self.slots.free(slot)
             self._retire(self._active.pop(slot))
+        t_emit = time.perf_counter()
+        self.batch_seconds += step_t0 - t_batch
+        self.emit_seconds += t_emit - step_t1
+        tr = obs.tracer()
+        if tr is not None:
+            tr.add_span("serve_batch", t_batch, step_t0)
+            tr.add_span("serve_emit", step_t1, t_emit)
         return True
 
     def _decode_spec(self, t0: Optional[float] = None) -> bool:
@@ -937,6 +954,23 @@ class ContinuousBatcher:
     # -- metrics -----------------------------------------------------------
 
     def _publish_gauges(self) -> None:
+        staleness = self.engine.staleness()
+        wd = obs.anomaly.watchdog()
+        if wd is not None:
+            # a breach here means maybe_swap() could NOT restore the bound
+            # (e.g. the trainer stalled and no fresh snapshot exists): the
+            # watchdog records it, serving continues on the stale snapshot
+            wd.serve_staleness(
+                staleness,
+                self.engine.max_stale_rounds,
+                exemplars=self._slo_exemplars(),
+            )
+        if obs.tracer() is None:
+            # every number below is read by a gauge alone, and with no
+            # tracer a gauge is nothing. A rate over the unarmed past would
+            # be no rate of now: the next armed call starts the mark anew
+            self._rate_mark = None
+            return
         if not self._kernel_probed:
             # one-time per-kernel isolation probe on the live shapes (the
             # path and shapes are fixed per process, so once is enough);
@@ -951,27 +985,17 @@ class ContinuousBatcher:
             obs.gauge("serve_p50_ms", float(np.percentile(lat, 50)) * 1e3)
             obs.gauge("serve_p99_ms", float(np.percentile(lat, 99)) * 1e3)
         now = time.perf_counter()
-        t0, n0 = self._rate_mark
-        if now > t0:
-            obs.gauge(
-                "serve_tokens_per_s", (self.total_new_tokens - n0) / (now - t0)
-            )
+        if self._rate_mark is not None:
+            t0, n0 = self._rate_mark
+            if now > t0:
+                obs.gauge(
+                    "serve_tokens_per_s", (self.total_new_tokens - n0) / (now - t0)
+                )
         self._rate_mark = (now, self.total_new_tokens)
         obs.gauge(
             "serve_batch_occupancy", self.slots.num_active / self.slots.num_slots
         )
-        staleness = self.engine.staleness()
         obs.gauge("serve_snapshot_staleness", staleness)
-        wd = obs.anomaly.watchdog()
-        if wd is not None:
-            # a breach here means maybe_swap() could NOT restore the bound
-            # (e.g. the trainer stalled and no fresh snapshot exists): the
-            # watchdog records it, serving continues on the stale snapshot
-            wd.serve_staleness(
-                staleness,
-                self.engine.max_stale_rounds,
-                exemplars=self._slo_exemplars(),
-            )
         if self.spec_proposed:
             obs.gauge(
                 "serve_spec_acceptance", self.spec_accepted / self.spec_proposed
@@ -1043,6 +1067,8 @@ class ContinuousBatcher:
             "decode_steps": self.decode_steps,
             "loop_iterations": self.loop_iterations,
             "loop_seconds": round(self.loop_seconds, 6),
+            "batch_seconds": round(self.batch_seconds, 6),
+            "emit_seconds": round(self.emit_seconds, 6),
             "new_tokens": self.total_new_tokens,
             "latency_ms": {
                 "p50": pct(lat, 50),
@@ -1063,6 +1089,11 @@ class ContinuousBatcher:
             "stages_s": {
                 k: round(v, 6) for k, v in self.engine.stage_seconds.items()
             },
+            "phase_seconds": {
+                stage: {k: round(v, 6) for k, v in phases.items()}
+                for stage, phases in self.engine.phase_seconds.items()
+            },
+            "phase_calls": dict(self.engine.phase_calls),
             "spec": {
                 "proposed": self.spec_proposed,
                 "accepted": self.spec_accepted,

@@ -641,3 +641,247 @@ def test_loop_seconds_hold_the_stage_seconds(tiny_cfg):
                               for s in iterations)
     for d in _named(cap, "serve_decode"):
         assert any(i["t0"] <= d["t0"] and d["t1"] <= i["t1"] for i in iterations)
+
+
+# -- the serving iteration from inside: phases of a call, phases of the loop ----
+
+_PHASE_SPANS = ("serve_args", "serve_dispatch", "serve_fetch")
+# spans_since rebuilds a stamp from microseconds after the tracer's origin
+_STAMP_EPS = 1e-6
+
+
+def _phases_total(engine):
+    return {
+        stage: (dict(engine.phase_seconds[stage]), engine.phase_calls[stage])
+        for stage in ("prefill", "decode")
+    }
+
+
+def _admit_two_and_step(engine, steps=3):
+    tokens, lens = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    for slot, prompt in ((0, [5, 6, 7]), (2, [9, 8, 7, 6, 5])):
+        tokens[slot], _ = engine.admit(slot, prompt)
+        lens[slot] = len(prompt)
+    for _ in range(steps):
+        nxt, _ = engine.decode_step(tokens, lens)
+        tokens, lens = np.where(lens > 0, nxt, 0).astype(np.int32), lens + (lens > 0)
+
+
+@pytest.mark.parametrize("parent, stage, calls", [
+    ("serve_prefill", "prefill", 2), ("serve_decode", "decode", 3),
+])
+def test_engine_call_is_tiled_by_its_three_phases(tiny_cfg, parent, stage, calls):
+    engine = _tiny_engine(tiny_cfg)
+    _admit_two_and_step(engine, steps=1)  # compiles fall in here, untraced
+    obs.capture.start()
+    _admit_two_and_step(engine)
+    cap = obs.capture.stop()
+    parents = _named(cap, parent)
+    assert len(parents) == calls
+    children = [s for s in cap.spans
+                if s["name"] in _PHASE_SPANS and s["args"] == {"stage": stage}]
+    assert len(children) == 3 * calls
+    for p in parents:
+        inside = sorted(
+            (c for c in children if p["t0"] - _STAMP_EPS <= c["t0"] < p["t1"]),
+            key=lambda c: c["t0"],
+        )
+        assert tuple(c["name"] for c in inside) == _PHASE_SPANS
+        assert inside[0]["t0"] == pytest.approx(p["t0"], abs=_STAMP_EPS)
+        for a, b in zip(inside, inside[1:]):
+            assert b["t0"] == pytest.approx(a["t1"], abs=_STAMP_EPS)
+        assert inside[-1]["t1"] <= p["t1"] + _STAMP_EPS
+    # the parents' attributes are what they were: no ``stage`` among them
+    assert all("stage" not in p["args"] for p in parents)
+
+
+def test_phase_counters_grow_by_the_spans_sums_and_counts(tiny_cfg):
+    engine = _tiny_engine(tiny_cfg)
+    _admit_two_and_step(engine, steps=1)
+    before = _phases_total(engine)
+    obs.capture.start()
+    _admit_two_and_step(engine)
+    cap = obs.capture.stop()
+    after = _phases_total(engine)
+    for stage, calls in (("prefill", 2), ("decode", 3)):
+        assert after[stage][1] - before[stage][1] == calls
+        for phase in ("args", "dispatch", "fetch"):
+            spans = [s for s in _named(cap, "serve_" + phase) if s["args"]["stage"] == stage]
+            grown = after[stage][0][phase] - before[stage][0][phase]
+            assert grown > 0
+            assert sum(s["t1"] - s["t0"] for s in spans) == pytest.approx(
+                grown, abs=2 * calls * _STAMP_EPS
+            )
+    # the three are inside the stage's wall, which ends after the counting
+    for stage in ("prefill", "decode"):
+        assert sum(engine.phase_seconds[stage].values()) <= engine.stage_seconds[stage]
+
+
+def test_phase_counters_grow_with_no_tracer_armed(tiny_cfg):
+    engine = _tiny_engine(tiny_cfg)
+    assert obs.tracer() is None
+    assert engine.phase_calls == {"prefill": 0, "decode": 0}
+    _admit_two_and_step(engine)
+    assert obs.tracer() is None
+    assert engine.phase_calls == {"prefill": 2, "decode": 3}
+    for stage in ("prefill", "decode"):
+        assert set(engine.phase_seconds[stage]) == {"args", "dispatch", "fetch"}
+        assert all(v > 0 for v in engine.phase_seconds[stage].values())
+
+
+def test_loop_phases_lie_on_either_side_of_the_step(tiny_cfg):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine = _tiny_engine(tiny_cfg)
+    # the capture spans the batcher's whole life (compiles and all), so that
+    # the counters and the spans hold the same steps with no race at an edge
+    obs.capture.start()
+    batcher = ContinuousBatcher(engine).start()
+    try:
+        reqs = [batcher.submit([7, 8, 9, i + 1], max_new_tokens=6) for i in range(6)]
+        for r in reqs:
+            assert r.wait(120) and r.error is None
+    finally:
+        batcher.stop()  # joins the loop: its last step is counted whole
+        cap = obs.capture.stop()
+    steps, iterations = _named(cap, "serve_decode"), _named(cap, "serve_iteration")
+    batches, emits = _named(cap, "serve_batch"), _named(cap, "serve_emit")
+    assert steps and len(batches) == len(emits) == len(steps)
+    in_order = (sorted(spans, key=lambda s: s["t0"]) for spans in (batches, steps, emits))
+    for b, d, e in zip(*in_order):
+        assert b["t1"] == pytest.approx(d["t0"], abs=_STAMP_EPS)
+        assert e["t0"] == pytest.approx(d["t1"], abs=_STAMP_EPS)
+        assert b["args"] == e["args"] == {}
+        assert any(i["t0"] - _STAMP_EPS <= b["t0"] and e["t1"] <= i["t1"] + _STAMP_EPS
+                   for i in iterations)
+    for name, total in (("serve_batch", batcher.batch_seconds),
+                        ("serve_emit", batcher.emit_seconds)):
+        assert total > 0
+        assert sum(s["t1"] - s["t0"] for s in _named(cap, name)) == pytest.approx(
+            total, abs=2 * len(steps) * _STAMP_EPS
+        )
+    assert batcher.batch_seconds + batcher.emit_seconds < batcher.loop_seconds
+    # five new names and no sixth
+    assert {s["name"] for s in cap.spans if s["name"].startswith("serve_")} == {
+        "serve_prefill", "serve_decode", "serve_iteration", "serve_batch", "serve_emit",
+        *_PHASE_SPANS,
+    }
+
+
+def test_stats_carry_the_phases(tiny_cfg):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine = _tiny_engine(tiny_cfg)
+    batcher = ContinuousBatcher(engine).start()
+    try:
+        r = batcher.submit([3, 4, 5], max_new_tokens=4)
+        assert r.wait(120) and r.error is None
+    finally:
+        batcher.stop()
+    stats = batcher.stats()
+    assert stats["phase_calls"] == {"prefill": 1, "decode": 3}
+    assert stats["phase_seconds"]["decode"] == {
+        k: round(v, 6) for k, v in engine.phase_seconds["decode"].items()
+    }
+    assert set(stats["phase_seconds"]["prefill"]) == {"args", "dispatch", "fetch"}
+    # always on: no tracer was armed while these steps ran
+    assert stats["batch_seconds"] == round(batcher.batch_seconds, 6) > 0
+    assert stats["emit_seconds"] == round(batcher.emit_seconds, 6) > 0
+    assert "loop_seconds" in stats and "stages_s" in stats
+    json.dumps(stats)  # what GET /stats serves
+
+
+def test_the_last_capture_stays_readable():
+    assert obs.capture.last() is None
+    obs.capture.start()
+    with obs.span("first"):
+        pass
+    assert obs.capture.last() is None  # an open capture is not yet one kept
+    first = obs.capture.stop()
+    assert obs.capture.last() is first  # the object itself, nothing copied
+    obs.capture.start()
+    assert obs.capture.last() is first
+    second = obs.capture.stop()
+    assert obs.capture.last() is second is not first
+    obs.reset()
+    assert obs.capture.last() is None
+
+
+class _CountedProbe:
+    """``engine.kernel_probe`` counted and not run."""
+
+    def __init__(self, engine):
+        self.calls = 0
+        engine.kernel_probe = self
+
+    def __call__(self, iters=3):
+        self.calls += 1
+        return {}
+
+
+def _decode_past_the_gauges(batcher, steps):
+    """One request of ``steps`` decode steps (its first token is the
+    prefill's); the wait returns from inside the last of them."""
+    r = batcher.submit([3, 4, 5], max_new_tokens=steps + 1)
+    assert r.wait(120) and r.error is None
+
+
+def test_no_tracer_at_start_means_no_kernel_probe_ever(tiny_cfg, monkeypatch):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine = _tiny_engine(tiny_cfg)
+    probe = _CountedProbe(engine)
+    batcher = ContinuousBatcher(engine, gauge_every_steps=4)
+    percentiles = []
+    real = np.percentile
+    monkeypatch.setattr(np, "percentile", lambda *a, **k: percentiles.append(1) or real(*a, **k))
+    batcher.start()
+    try:
+        _decode_past_the_gauges(batcher, 9)  # the gauges came due twice
+        assert probe.calls == 0 and not percentiles
+        # nor because a capture arms the tracer later: a probe compiles
+        obs.capture.start()
+        _decode_past_the_gauges(batcher, 18)
+        cap = obs.capture.stop()
+    finally:
+        batcher.stop()
+    assert probe.calls == 0
+    assert percentiles  # armed, the gauges are computed as they were
+    assert any(k.startswith("serve_tokens_generated") for k in cap.counters)
+
+
+def test_a_tracer_at_start_means_one_kernel_probe(tiny_cfg, monkeypatch):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    tr = _arm(monkeypatch)
+    engine = _tiny_engine(tiny_cfg)
+    probe = _CountedProbe(engine)
+    batcher = ContinuousBatcher(engine, gauge_every_steps=4).start()
+    try:
+        _decode_past_the_gauges(batcher, 9)
+    finally:
+        batcher.stop()
+    assert probe.calls == 1
+    # and the gauges are published as they were at the parent
+    assert {"serve_batch_occupancy", "serve_tokens_per_s", "serve_queue_depth"} <= {
+        name for name, _labels in tr.gauges()
+    }
+
+
+def test_the_staleness_watchdog_is_called_with_no_tracer(tiny_cfg, monkeypatch):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    seen = []
+
+    class _Watchdog:
+        def serve_staleness(self, staleness, bound, exemplars=()):
+            seen.append((staleness, bound))
+
+    monkeypatch.setattr(obs.anomaly, "watchdog", lambda: _Watchdog())
+    engine = _tiny_engine(tiny_cfg)
+    batcher = ContinuousBatcher(engine, gauge_every_steps=4).start()
+    try:
+        _decode_past_the_gauges(batcher, 9)
+    finally:
+        batcher.stop()
+    assert obs.tracer() is None and len(seen) >= 2
