@@ -12,21 +12,30 @@ cache-aware decode, arXiv 2309.06180).
 - :func:`paged_decode_attention` is the decode step's whole traffic with
   the ring KV cache. It is handed the cache as the engine holds it
   (``ring_cache``: ``[L, S, Nkv, Dh, T]``, rows minor-most) and cuts its
-  ``(Dh, block_t)`` tiles straight from it, the layer index and the
+  ``(heads, Dh, block_t)`` tiles straight from it, the layer index and the
   per-slot ``lens`` vector riding the grid as scalar-prefetch operands:
   nothing of a layer's size is sliced, transposed or copied on the way in.
   Each slot's dead ring blocks are skipped (``pl.when``) AND their DMAs
   elided (the BlockSpec index map clamps to the last live block, an
   unchanged index reuses the resident tile — same trick as the flash
-  kernel's causal skip). The step's new K/V row is written by the same
-  call: the block that holds ring row ``lens % T`` is always one the slot
-  reads, so the kernel patches the row into the tile it has in VMEM and
-  hands that one tile back through an output aliased to the cache (an XLA
-  scatter into rows-minor pages re-lays the whole cache, ISSUE 29). GQA is
-  handled by block geometry: grid position (slot, kv-head) loads exactly
-  that kv head's ``rep`` query rows, never a ``_repeat_kv``
-  materialization. Online softmax in f32 matches ``decode_attention``
-  row-for-row.
+  kernel's causal skip). A grid step is one pair of MXU calls over all the
+  KV heads it holds (:func:`decode_plan`): their tiles are one ``[heads *
+  Dh, block_t]`` operand (a free view), the queries lie block-diagonally
+  over it (head j's ``rep`` rows in columns ``j * Dh : (j + 1) * Dh``, built
+  once a slot), so every product is the per-head one and every added term
+  an exact zero; one online softmax in f32 over ``[heads * rep, block_t]``
+  matches ``decode_attention`` row-for-row, and GQA never materializes a
+  ``_repeat_kv``. The step's new K/V row is written by the same call, and
+  touches no tile it is not in: the attention reads the tiles as the cache
+  holds them, the row's own score (``q . k_new``, a row sum) is selected
+  into its column of the scores and its value term added to the
+  accumulator; what goes back, through an output aliased to the cache, is
+  the one 128-row block of K and of V that holds ring row ``lens % T`` (an
+  XLA scatter into rows-minor pages re-lays the whole cache, ISSUE 29), the
+  row rolled into it as a column from a slots-as-lanes copy of the rows.
+  Measured on a v5e (PERF.md, PR 35): 1.25 us a grid step of 5 heads of 64
+  over a 256-row tile where the per-head form took 2.4, against 0.8 us for
+  the tiles' DMAs alone.
 - :func:`mla_decode_attention` is the same plan for latent attention: one
   ring of latent rows and no value twin, every head of a slot against the
   same ``(R + rope, block_t)`` tile, the values taken from the tile's
@@ -63,6 +72,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -125,12 +135,17 @@ def _ring_block(
 # ---------------------------------------------------------------------------
 
 
-# One grid step costs the decode kernel about 0.6 us whatever it moves
-# (ISSUE 29: 40,960 steps of one 32 KB tile each took 21-25 ms of a step on
-# the v5e), so a step takes as many KV heads of a slot as fit this many
-# bytes of one tile: all 5 of SmolLM2-360M's, all 16 of OLMoE's. K and V, in
-# and out, double-buffered, hold eight such tiles in VMEM.
+# A grid step takes as many KV heads of a slot as fit this many bytes of one
+# tile: all 5 of SmolLM2-360M's, all 16 of OLMoE's, all 8 of granite's. What a
+# grid step costs on the v5e, measured (PERF.md, PR 35): about 0.35 us whatever
+# it moves, its K and V tiles at 0.7-0.8 TB/s beside that, and what the step
+# computes on top where that is not hidden: 1.25 us for 5 heads of 64 over a
+# 256-row tile (320 KB read, 160 KB written), 0.8 of it the DMAs. K and V
+# tiles double-buffered and the written 128-row blocks hold six such tiles in
+# VMEM.
 _HEAD_TILE_BYTES = 512 * 1024
+
+_LANES = 128  # what goes back to the cache: the 128-row block that holds the row
 
 
 def _heads_per_step(nkv: int, tile_bytes: int) -> int:
@@ -140,95 +155,191 @@ def _heads_per_step(nkv: int, tile_bytes: int) -> int:
     return max(g for g in range(1, nkv + 1) if nkv % g == 0 and g <= fit)
 
 
-def _as_column(row, dtype):
-    """A K/V row [1, d] of the step as the column [d, 1] of a rows-minor
-    tile: the row spread over a diagonal and summed along the lanes, which
-    adds zeros and so is exact (Mosaic has no [1, d] -> [d, 1] reshape)."""
-    d = row.shape[1]
-    spread = jnp.broadcast_to(row.astype(jnp.float32), (d, d))
-    on_diag = jax.lax.broadcasted_iota(
-        jnp.int32, (d, d), 0
-    ) == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
-    col = jnp.sum(jnp.where(on_diag, spread, 0.0), axis=1, keepdims=True)
-    return col.astype(dtype)
+class DecodePlan(NamedTuple):
+    """What a grid step of ``odtp_paged_decode_attn`` does, from what the call
+    can see (:func:`decode_plan`)."""
+
+    heads: int  # KV heads of one slot a grid step, under one pair of MXU calls
+    block_t: int  # ring rows a tile
+
+    @property
+    def block_diagonal(self) -> bool:
+        """Whether the queries of a grid step lie block-diagonally over
+        several heads' tiles (with one head they are the head's own)."""
+        return self.heads > 1
+
+
+def decode_plan(
+    nkv: int, d: int, t: int, itemsize: int,
+    *, block_t: int | None = None, interpret: bool | None = None,
+) -> DecodePlan | None:
+    """The kernel's plan for ``nkv`` KV heads of ``d``, whatever the query heads
+    over each, over a ring of ``t`` rows of ``itemsize`` bytes an element: a
+    pure function of those (and of the tile the caller or
+    ``ODTP_DECODE_BLOCK_T`` asks for), never of a model's name. None where
+    the kernel cannot tile the shape and the call keeps the XLA path."""
+    bt = _ring_block(t, block_t, _interpret(interpret))
+    if d % 8 != 0 or not bt:
+        return None
+    # the heads' tiles go to the MXU as one [heads * d, bt] operand: the
+    # (heads, d) axes merge freely where a head's rows are whole sublane
+    # tiles of the cache's dtype (8 rows of 32 bits); else one head a step
+    whole = d % (8 * 4 // itemsize) == 0
+    heads = _heads_per_step(nkv, d * bt * itemsize) if whole else 1
+    return DecodePlan(heads, bt)
+
+
+def _head_of(index, size: int, heads: int):
+    """``index // size`` for an iota under ``heads * size``, by comparisons
+    (no vector division on the chip)."""
+    return sum((index >= j * size).astype(jnp.int32) for j in range(1, heads))
+
+
+def _lanes32(x):
+    """A 16-bit array [n, lanes] as 32-bit words [n / 2, lanes] (two rows of
+    a lane in a word), so that lane rolls and lane selects move whole words
+    on a chip with no 16-bit vector unit; other widths as they are."""
+    return pltpu.bitcast(x, jnp.uint32) if x.dtype.itemsize == 2 else x
 
 
 def _decode_attn_kernel(
-    lens_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+    lens_ref, layer_ref, q_ref, kn_ref, vn_ref, knt_ref, vnt_ref, k_ref, v_ref,
     o_ref, ko_ref, vo_ref, *rest,
-    scale, block_t, t, num_t, with_stats,
+    scale, block_t, t, num_t, rep, with_stats,
 ):
     # the stats block's index ignores the ring axis, so it stays resident
     # across ti and doubles as the counter: a vector add, since Mosaic
     # cannot store a scalar to VMEM
-    stats_ref, (m_scr, l_scr, acc_scr) = (
+    stats_ref, (q_scr, snew_scr, m_scr, l_scr, acc_scr) = (
         (rest[0], rest[1:]) if with_stats else (None, rest)
     )
-    heads, rep, d = q_ref.shape  # the KV heads of this grid step
+    heads, d, _ = k_ref.shape  # the KV heads of this grid step
+    # all of them under one pair of MXU calls: their tiles as one
+    # [heads * d, bt] operand, their rep query rows each block-diagonal
+    rows, width = acc_scr.shape
     si, ti = pl.program_id(0), pl.program_id(2)
+    f32 = jnp.float32
+    row_head = _head_of(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), rep, heads
+    )
+
+    def own_block():  # [rows, width]: head j's rows x columns j*d : (j+1)*d
+        col_head = _head_of(
+            jax.lax.broadcasted_iota(jnp.int32, (1, width), 1), d, heads
+        )
+        return row_head == col_head
+
+    def side_by_side(ref):  # the heads' new rows [heads, 1, d] as [1, heads * d]
+        return jnp.concatenate([ref[j] for j in range(heads)], axis=1)
 
     @pl.when(ti == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, f32)
+        l_scr[:] = jnp.zeros(l_scr.shape, f32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, f32)
         if with_stats:
             stats_ref[:] = jnp.zeros(stats_ref.shape, jnp.int32)
+        # head j's rep query rows in columns j*d : (j+1)*d, zeros elsewhere
+        tiled = jnp.concatenate([q_ref[:]] * heads, axis=1)
+        q_bd = jnp.where(own_block(), tiled, jnp.zeros_like(tiled))
+        q_scr[:] = q_bd
+        # the step's own scores, q . k_new: a row sum, once a slot
+        snew_scr[:] = scale * jnp.sum(
+            q_bd.astype(f32) * side_by_side(kn_ref).astype(f32),
+            axis=1, keepdims=True,
+        )
 
     lens_s = lens_ref[si]
     # valid cache entries are idx <= lens (whole ring once lens >= t), so
     # blocks past min(lens, t-1) hold no live rows for this slot
     last_live = jnp.minimum(lens_s, t - 1) // block_t
     # the step's own row goes to ring row lens % t, in a block that is
-    # always live (it is the last live one until the ring wraps)
-    new_row = jax.lax.rem(lens_s, t)
+    # always live (it is the last live one until the ring wraps); new_at is
+    # its lane in this tile, if it lies here
+    new_at = jax.lax.rem(lens_s, t) - ti * block_t
 
     @pl.when(ti <= last_live)
     def _step():
-        here = (
-            ti * block_t
-            + jax.lax.broadcasted_iota(jnp.int32, (d, block_t), 1)
-        ) == new_row
-        idx = ti * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, (rep, block_t), 1
-        )
-        valid = (idx <= lens_s) | (lens_s >= t)
-        for j in range(heads):
-            k_blk = jnp.where(
-                here, _as_column(kn_ref[j], k_ref.dtype), k_ref[j]
-            )
-            v_blk = jnp.where(
-                here, _as_column(vn_ref[j], v_ref.dtype), v_ref[j]
-            )
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, block_t), 1)
+        valid = (ti * block_t + lane <= lens_s) | (lens_s >= t)
+        at_row = lane == new_at  # nowhere, in a tile that does not hold it
+        # the tiles as the cache holds them: the row's own score and value are
+        # patched into s and acc, never into a tile. What lies at the row's
+        # place is finite (zeros, or the row it evicts)
+        k_blk = k_ref[:].reshape(width, block_t)
+        v_blk = v_ref[:].reshape(width, block_t)
+        s = scale * jax.lax.dot_general(
+            q_scr[:], k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32,
+        )  # [rows, block_t]
+        s_new = snew_scr[:]
+        s = jnp.where(at_row, s_new, s)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            jnp.where(at_row, 0.0, p).astype(v_blk.dtype), v_blk,
+            (((1,), (1,)), ((), ())), preferred_element_type=f32,
+        )  # head j's values in columns j*d : (j+1)*d of its rows
 
-            @pl.when(ti == new_row // block_t)
-            def _write():
-                ko_ref[j] = k_blk
-                vo_ref[j] = v_blk
+        @pl.when((new_at >= 0) & (new_at < block_t))
+        def _own_value():
+            # p[:, new_at] x v_new, rounded as the MXU's operand is
+            p_new = jnp.exp(s_new - m_new).astype(v_blk.dtype)
+            acc_scr[:] += p_new.astype(f32) * side_by_side(vn_ref).astype(f32)
 
-            s = scale * jax.lax.dot_general(
-                q_ref[j], k_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [rep, block_t]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev, l_prev, acc = m_scr[j], l_scr[j], acc_scr[j]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            m_scr[j] = m_new
-            l_scr[j] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[j] = acc * corr + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
         if with_stats:
             stats_ref[:] += 1
 
+    # what goes back: the one block of min(block_t, 128) rows that holds the
+    # step's row. The rows of all slots arrive a second time transposed,
+    # slots as lanes, so this slot's is a column already: rolled from lane
+    # si % 128 to the row's lane and selected into the block (Mosaic has no
+    # [1, d] -> [d, 1] reshape)
+    back = ko_ref.shape[-1]
+    for b in range(block_t // back):
+        at = new_at - b * back
+
+        @pl.when((at >= 0) & (at < back))
+        def _write():
+            lanes = knt_ref.shape[-1]
+            shift = jax.lax.rem(at - jax.lax.rem(si, lanes) + lanes, lanes)
+            for new_ref, tile_ref, out_ref in (
+                (knt_ref, k_ref, ko_ref), (vnt_ref, v_ref, vo_ref)
+            ):
+                col = pltpu.roll(_lanes32(new_ref[:]), shift, 1)[:, :back]
+                old = _lanes32(
+                    tile_ref[:, :, b * back:(b + 1) * back].reshape(width, back)
+                )
+                here = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1) == at
+                patched = jnp.where(here, col, old)
+                if patched.dtype != out_ref.dtype:
+                    patched = pltpu.bitcast(patched, out_ref.dtype)
+                out_ref[:] = patched.reshape(heads, d, back)
+
     @pl.when(ti == num_t - 1)
     def _finish():
+        # head j keeps its own d columns of its rep rows: the others zeroed,
+        # the heads' columns are added onto each other (zeros, so exact), by
+        # whole vregs first where a head is narrower than one
+        own = jnp.where(own_block(), acc_scr[:], 0.0)
+        chunk = _LANES if _LANES % d == 0 else d
+        if width % chunk:
+            own = jnp.concatenate(
+                [own, jnp.zeros((rows, -width % chunk), f32)], axis=1
+            )
+        own = sum(
+            own[:, c * chunk:(c + 1) * chunk] for c in range(own.shape[1] // chunk)
+        )
+        while chunk > d:
+            chunk //= 2
+            own = own[:, :chunk] + own[:, chunk:]
         l = l_scr[:]
-        l_safe = jnp.where(l == 0, 1.0, l)
-        o_ref[:] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[:] = (own / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -257,24 +368,33 @@ def paged_decode_attention(
     skip evidence banked by scripts/decode_kernel_bench.py."""
     # Mosaic requires the last two dims of every block to be (8, 128)-
     # aligned OR equal to the array's own dims. The cache's two minor dims
-    # are (D, T), so a (d, bt) tile is legal for bt a multiple of 128. rep
-    # and the single new row are tiny and never 8-aligned, so they must BE
-    # array dims: q as [S, Kh, rep, D] ([rep, d] tiles), the new rows as
-    # [S, Kh, 1, D] ([1, d] tiles); the KV heads of a grid step lead them
+    # are (D, T), so a (d, bt) tile is legal for bt a multiple of 128. The
+    # query rows of a grid step and the single new row are few and never
+    # 8-aligned, so they must BE array dims: q as [S, Kh / hb, hb * rep, D]
+    # (the grid step's heads' rows as one tile), the new rows as [S, Kh, 1, D]
+    # ([1, d] tiles, the KV heads of a grid step leading them)
     s_, nkv, d = k.shape
     t = ring_rows(cache_k)
     h = q.shape[1]
     interp = _interpret(interpret)
-    bt = _ring_block(t, block_t, interp)
-    if d % 8 != 0 or h % nkv != 0 or not bt:
+    plan = h % nkv == 0 and decode_plan(
+        nkv, d, t, cache_k.dtype.itemsize,
+        block_t=block_t, interpret=interp,
+    )
+    if not plan:
         res = decode_step_attention(q, k, v, cache_k, cache_v, lens, layer)
         return (*res, None) if return_stats else res
+    hb, bt = plan
     rep = h // nkv
     num_t = t // bt
-    hb = _heads_per_step(nkv, d * bt * cache_k.dtype.itemsize)
-    q4 = q.reshape(s_, nkv, rep, d)
+    back = min(bt, _LANES)
+    rows, width = hb * rep, hb * d
+    q4 = q.reshape(s_, nkv // hb, rows, d)
     kn = k.reshape(s_, nkv, 1, d).astype(cache_k.dtype)
     vn = v.reshape(s_, nkv, 1, d).astype(cache_v.dtype)
+    # and slots as lanes, [Kh * D, S]: a slot's row as the column it becomes
+    knt = jnp.pad(kn.reshape(s_, nkv * d).T, ((0, 0), (0, -s_ % _LANES)))
+    vnt = jnp.pad(vn.reshape(s_, nkv * d).T, ((0, 0), (0, -s_ % _LANES)))
 
     def kv_map(si, gi, ti, lens_ref, layer_ref):
         # clamp dead blocks to the last live one: unchanged index = no DMA
@@ -283,27 +403,32 @@ def paged_decode_attention(
 
     def written_map(si, gi, ti, lens_ref, layer_ref):
         # the one block of (slot, head group) that goes back: the row's
-        return (layer_ref[0], si, gi, 0, jax.lax.rem(lens_ref[si], t) // bt)
+        return (layer_ref[0], si, gi, 0, jax.lax.rem(lens_ref[si], t) // back)
 
     def q_map(si, gi, ti, lr, yr):
         return (si, gi, 0, 0)
 
-    queries = pl.BlockSpec((None, hb, rep, d), q_map)
+    queries = pl.BlockSpec((None, None, rows, d), q_map)
     row = pl.BlockSpec((None, hb, 1, d), q_map)
+    column = pl.BlockSpec(
+        (width, _LANES), lambda si, gi, ti, lr, yr: (gi, si // _LANES)
+    )
     out_specs = [
         queries,
-        pl.BlockSpec((None, None, hb, d, bt), written_map),
-        pl.BlockSpec((None, None, hb, d, bt), written_map),
+        pl.BlockSpec((None, None, hb, d, back), written_map),
+        pl.BlockSpec((None, None, hb, d, back), written_map),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((s_, nkv, rep, d), q.dtype, vma=jax.typeof(q).vma),
+        jax.ShapeDtypeStruct(q4.shape, q.dtype, vma=jax.typeof(q).vma),
         jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
         jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
     ]
     scratch = [
-        pltpu.VMEM((hb, rep, 1), jnp.float32),
-        pltpu.VMEM((hb, rep, 1), jnp.float32),
-        pltpu.VMEM((hb, rep, d), jnp.float32),
+        pltpu.VMEM((rows, width), q.dtype),  # the block-diagonal queries
+        pltpu.VMEM((rows, 1), jnp.float32),  # the step's own scores
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, width), jnp.float32),
     ]
     if return_stats:
         out_specs.append(pl.BlockSpec((None, hb, 1, 1), q_map))
@@ -316,6 +441,8 @@ def paged_decode_attention(
             queries,
             row,
             row,
+            column,
+            column,
             pl.BlockSpec((None, None, hb, d, bt), kv_map),
             pl.BlockSpec((None, None, hb, d, bt), kv_map),
         ],
@@ -325,22 +452,22 @@ def paged_decode_attention(
     res = pl.pallas_call(
         functools.partial(
             _decode_attn_kernel,
-            scale=d**-0.5, block_t=bt, t=t, num_t=num_t,
+            scale=d**-0.5, block_t=bt, t=t, num_t=num_t, rep=rep,
             with_stats=return_stats,
         ),
         name="odtp_paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=out_shape,
         # operands count the two scalar-prefetch vectors: the caches are
-        # inputs 5 and 6, and come back as outputs 1 and 2
-        input_output_aliases={5: 1, 6: 2},
+        # inputs 7 and 8, and come back as outputs 1 and 2
+        input_output_aliases={7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
     )(
         lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        q4, kn, vn, cache_k, cache_v,
+        q4, kn, vn, knt, vnt, cache_k, cache_v,
     )
     out = (res[0].reshape(s_, h, d), res[1], res[2])
     if return_stats:

@@ -96,6 +96,8 @@ from opendiloco_tpu.ops.attention import (
     spec_tail_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
+    DecodePlan,
+    decode_plan,
     mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
@@ -750,7 +752,9 @@ class ServeEngine:
         """Time the decode-path kernels in isolation on the engine's live
         shapes and publish per-kernel gauges (serve_decode_attn_us,
         serve_verify_attn_us, serve_w4_matmul_us) so DECODE_BENCH
-        attribution shows where the kernel time went, per dispatch path.
+        attribution shows where the kernel time went, per dispatch path;
+        and, for a keys-and-values ring, the decode kernel's plan at those
+        shapes (serve_decode_plan_heads, _block_t, _block_diagonal).
 
         Best-of-``iters`` steady-state timings on the resolved path
         (``self.decode_kernel``); the w4 gauge only appears under
@@ -800,6 +804,12 @@ class ServeEngine:
             ),
             "verify_attn_us": _best_us(_vattn, qt, ck, cv, tk, lens, iters=iters),
         }
+        # which form of the decode kernel these shapes take (zeros: the XLA path)
+        plan = pallas and decode_plan(Nkv, Dh, T, self.cache_k.dtype.itemsize)
+        plan = plan or DecodePlan(0, 0)
+        out["decode_plan_heads"] = float(plan.heads)
+        out["decode_plan_block_t"] = float(plan.block_t)
+        out["decode_plan_block_diagonal"] = float(plan.block_diagonal)
         packed = next(
             (
                 w

@@ -22,9 +22,23 @@ artifact is the CPU-rig half of the acceptance evidence.
 --selftest: small shapes, artifact to /tmp, hard-asserts parity/skip
 (CI decode-kernel job); the compile is asserted only when the topology
 libraries are available.
+
+--slots/--heads/--kv-heads/--head-dim/--ring/--dtype/--lens: the decode
+attention kernel alone at a serving cell's shapes (the batch cell:
+``--slots 256 --heads 15 --kv-heads 5 --head-dim 64 --ring 256 --lens
+32:256``; OLMoE's: ``--slots 16 --heads 16 --kv-heads 16 --head-dim 128
+--ring 3200 --lens 1024:3080``). Prints the plan those shapes take
+(``decode_kernels.decode_plan``) and, on a TPU, checks the kernel against
+``decode_step_attention`` there (outputs to rounding, both caches bit for
+bit, half the slots wrapped) and gives the time of a call over a
+cache of one layer's size that the calls hand on (donated, as the engine's
+decode scan and ``ServeEngine.kernel_probe`` do): ``pallas_us`` and
+``xla_us``, each the difference of a program of 128 calls and one of 32,
+over 96, so that starting a program and waiting for it is not in the number.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -119,11 +133,14 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
         "xla_us": _timeit(jax.jit(xla_step), q1, k1, v1, ck, cv, lens),
     }
     if on_tpu:
-        # not donated: the call's copy of the one-layer cache is in the time
-        doc["decode_attention"]["pallas_us"] = _timeit(
-            jax.jit(
-                lambda *a: paged_decode_attention(*a, 0, block_t=bt)[0]
-            ), q1, k1, v1, ck, cv, lens,
+        # both arms alike: the caches donated and handed on, as kernel_probe does
+        shape = cache_shape(8, S, T, Nkv, D)
+        doc["decode_attention"]["pallas_us"] = _us_a_call(
+            functools.partial(paged_decode_attention, block_t=bt),
+            q1, k1, lens, shape, jnp.float32,
+        )
+        doc["decode_attention"]["xla_us"] = _us_a_call(
+            decode_step_attention, q1, k1, lens, shape, jnp.float32
         )
     assert err < 2e-6, f"paged decode parity: {err}"
     # the ragged lens above MUST leave dead blocks on the floor
@@ -197,6 +214,86 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
         )
     assert rel < 1e-5, f"w4 matmul parity: {rel}"
     assert bitwise, "w4 identity probe diverged from dequant_w4"
+
+
+def _us_a_call(step, q, k, lens, shape, dtype, *, layers=8, calls=(32, 128), iters=5):
+    """Microseconds a call of ``step(q, k, v, cache_k, cache_v, lens, layer)``:
+    programs of ``calls[0]`` and of ``calls[1]`` calls over a cache of
+    ``layers`` layers (layer = call % layers), caches donated; the difference
+    of their median wall times over the difference in calls."""
+    import jax
+    import jax.numpy as jnp
+
+    ck = jnp.zeros(shape, dtype)
+    cv = jnp.zeros(shape, dtype)
+    medians = []
+    for n in calls:
+        def program(q, k, lens, ck, cv, n=n):
+            def body(i, carry):
+                acc, ck, cv = carry
+                o, ck, cv = step(q, k, k, ck, cv, lens, jax.lax.rem(i, layers))
+                return acc + o.astype(jnp.float32), ck, cv
+
+            return jax.lax.fori_loop(
+                0, n, body, (jnp.zeros(q.shape, jnp.float32), ck, cv)
+            )
+
+        run = jax.jit(program, donate_argnums=(3, 4))
+        _, ck, cv = jax.block_until_ready(run(q, k, lens, ck, cv))
+        ts = []
+        for _ in range(iters):
+            t0 = time.perf_counter()
+            _, ck, cv = jax.block_until_ready(run(q, k, lens, ck, cv))
+            ts.append(time.perf_counter() - t0)
+        medians.append(float(np.median(ts)))
+    return round((medians[1] - medians[0]) / (calls[1] - calls[0]) * 1e6, 1)
+
+
+def _at_cell_shapes(doc: dict, args) -> None:
+    """The decode attention kernel at the shapes given on the command line:
+    its plan, always; on a TPU its time a call beside the XLA path's."""
+    import jax
+    import jax.numpy as jnp
+
+    from opendiloco_tpu.models.ring_cache import cache_shape
+    from opendiloco_tpu.ops.attention import decode_step_attention
+    from opendiloco_tpu.ops.decode_kernels import decode_plan, paged_decode_attention
+
+    S, H, Kh, D, T = args.slots, args.heads, args.kv_heads, args.head_dim, args.ring
+    dtype = jnp.dtype(args.dtype)
+    lo, hi = (int(x) for x in args.lens.split(":"))
+    on_tpu = jax.default_backend() == "tpu"
+    plan = decode_plan(Kh, D, T, dtype.itemsize, interpret=False)  # the chip's
+    row = {
+        "shape": f"S{S} Hq{H} Hkv{Kh} D{D} T{T} {dtype.name} lens {lo}:{hi}",
+        "plan": None if plan is None else {
+            **plan._asdict(), "block_diagonal": plan.block_diagonal,
+            "grid": [S, Kh // plan.heads, T // plan.block_t],
+        },
+    }
+    print(f"plan at {row['shape']}: {row['plan']}")
+    if on_tpu:
+        rng = np.random.default_rng(0)
+        q = jnp.asarray(rng.standard_normal((S, H, D)), dtype)
+        k = jnp.asarray(rng.standard_normal((S, Kh, D)), dtype)
+        lens = jnp.asarray(rng.integers(lo, hi, S), jnp.int32)
+        shape = cache_shape(8, S, T, Kh, D)
+        # the kernel against its XLA twin on this chip first, over full rings
+        # (a wrapped slot evicts a row): outputs to rounding, caches bit for bit
+        ck = jnp.asarray(0.5 * rng.standard_normal(shape[1:])[None], dtype)
+        wrapped = lens.at[: S // 2].add(T)
+        ref, rk, rv = jax.jit(decode_step_attention)(q, k, -k, ck, ck, wrapped, 0)
+        got, gk, gv = jax.jit(paged_decode_attention)(q, k, -k, ck, ck, wrapped, 0)
+        diff = np.asarray(got, np.float32) - np.asarray(ref, np.float32)
+        row["out_rel_l2"] = float(
+            np.linalg.norm(diff) / np.linalg.norm(np.asarray(ref, np.float32))
+        )
+        row["caches_bit_equal"] = bool(jnp.array_equal(gk, rk) & jnp.array_equal(gv, rv))
+        assert row["caches_bit_equal"] and row["out_rel_l2"] < 2e-2, row
+        row["pallas_us"] = _us_a_call(paged_decode_attention, q, k, lens, shape, dtype)
+        row["xla_us"] = _us_a_call(decode_step_attention, q, k, lens, shape, dtype)
+        print(f"a call: pallas {row['pallas_us']} us, xla {row['xla_us']} us")
+    doc["decode_attention_at"] = row
 
 
 def _mosaic_compile(doc: dict) -> bool:
@@ -308,6 +405,15 @@ def main() -> int:
         help="small shapes, artifact to /tmp, assert instead of bank",
     )
     ap.add_argument("--out", default=os.path.join(_ROOT, "DECODE_KERNEL_BENCH.json"))
+    # a serving cell's shapes for the decode attention kernel alone; with
+    # --slots the fixed-shape parity and compile phases are left out
+    ap.add_argument("--slots", type=int, default=0)
+    ap.add_argument("--heads", type=int, default=15)
+    ap.add_argument("--kv-heads", type=int, default=5)
+    ap.add_argument("--head-dim", type=int, default=64)
+    ap.add_argument("--ring", type=int, default=256)
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--lens", default="0:256", help="LO:HI, a slot's rows drawn from [LO, HI)")
     args = ap.parse_args()
     import jax
 
@@ -322,6 +428,10 @@ def main() -> int:
             "the on-chip follow-up recorded in ROADMAP.md."
         ),
     }
+    if args.slots:
+        _at_cell_shapes(doc, args)
+        print(json.dumps(doc, indent=1, sort_keys=True))
+        return 0
     _parity_and_skip(doc, small=args.selftest)
     _log("parity/skip done; attempting deviceless Mosaic compile")
     compiled = _mosaic_compile(doc)

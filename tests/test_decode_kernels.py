@@ -113,26 +113,94 @@ def _assert_decode_parity(rng, S, H, Kh, D, T, lens, *, layers=2, **kw):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("heads", [(8, 8), (8, 2), (4, 1), (8, 4)])
+@pytest.mark.parametrize(
+    "heads",
+    [(8, 8), (8, 2), (4, 1), (8, 4), (15, 5, 64), (6, 6, 8), (9, 3, 24), (4, 2, 128)],
+    ids=[
+        "mha8", "gqa8_2", "mqa4_1", "gqa8_4", "gqa15_5x64", "rep1_narrow", "width72",
+        "gqa4_2x128",
+    ],
+)
 def test_paged_decode_attention_parity(heads):
-    H, Kh = heads
+    """Every head layout the configs use. All the KV heads of a slot share a
+    grid step, their queries laid block-diagonally over their tiles: GQA, MHA
+    (rep 1 under a narrow head), one KV head (the diagonal is the head's own
+    rows), SmolLM2-360M's 15/5 of 64, a heads * d that is no multiple of the
+    128 lanes (heads of 24: nor does a head divide them), and heads of 128
+    (granite's, OLMoE's)."""
+    H, Kh, D = (*heads, 16)[:3]
     T = 32
+    plan = decode_kernels.decode_plan(Kh, D, T, 4, block_t=8, interpret=True)
+    assert plan == (Kh, 8) and plan.block_diagonal == (Kh > 1)
     # ragged: empty slot, mid-page, last live row, exactly T, wrapped
     _assert_decode_parity(
-        _rng(H * 31 + Kh), 5, H, Kh, 16, T, [0, 5, T - 1, T, 2 * T + 3],
+        _rng(H * 31 + Kh), 5, H, Kh, D, T, [0, 5, T - 1, T, 2 * T + 3],
         block_t=8,
     )
 
 
+@pytest.mark.parametrize("block_t", [128, None], ids=["tile128", "tile256"])
 @pytest.mark.parametrize("heads", [(15, 5), (4, 4)], ids=["gqa15_5", "mha"])
-def test_paged_decode_writes_the_row_in_place(heads):
-    """A 256-row ring in 128-row tiles, as on the chip: the written row at
-    ring row 0, at both sides of the tile edge, at the last row, and the
-    same again once the ring has wrapped."""
+def test_paged_decode_writes_the_row_in_place(heads, block_t):
+    """A 256-row ring in 128-row tiles, as on the chip, and as the one 256-row
+    tile of which only the 128-row block that holds the row goes back: the
+    written row at ring row 0, at both sides of the 128-row edge, at the last
+    row, and the same again once the ring has wrapped. Every ring row holds
+    something (``_ring``), so the row at ``lens % T`` is a stale one."""
     H, Kh = heads
     T = 256
     lens = [0, 127, 128, T - 1, T, T + 127, T + 128, 3 * T + 5]
-    _assert_decode_parity(_rng(H), len(lens), H, Kh, 8, T, lens, block_t=128)
+    _assert_decode_parity(_rng(H), len(lens), H, Kh, 8, T, lens, block_t=block_t)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_paged_decode_stale_row_does_not_leak(dtype):
+    """The attention reads the tile as the cache holds it and patches the
+    step's row into the scores and the values. A wrapped ring holds an old
+    row at ``lens % T``: made huge here, it must show nowhere in the output;
+    and in bf16 (16-bit rows move as 32-bit words) the caches still come back
+    bit for bit."""
+    S, H, Kh, D, T = 7, 6, 2, 16, 256
+    lens = jnp.asarray([0, 127, 128, T - 1, T, T + 128, 3 * T + 5], jnp.int32)
+    rng = _rng(23)
+    ck, cv = _ring(rng, 2, S, Kh, D, T)
+    at = (1, jnp.arange(S), slice(None), slice(None), jnp.mod(lens, T))
+    ck, cv = ck.at[at].set(1e4).astype(dtype), cv.at[at].set(-1e4).astype(dtype)
+    q, k, v = (_randn(rng, S, n, D).astype(dtype) for n in (H, Kh, Kh))
+    ref, rk, rv = decode_step_attention(q, k, v, ck, cv, lens, 1)
+    out, ok, ov = paged_decode_attention(q, k, v, ck, cv, lens, 1, interpret=True)
+    assert float(jnp.max(jnp.abs(ref.astype(jnp.float32)))) < 10  # the oracle has no trace of it
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=tol)
+    np.testing.assert_array_equal(np.asarray(ok, np.float32), np.asarray(rk, np.float32))
+    np.testing.assert_array_equal(np.asarray(ov, np.float32), np.asarray(rv, np.float32))
+
+
+@pytest.mark.parametrize(
+    "cell,shape,plan",
+    [
+        # (kv heads, head size, ring rows, bytes an element) -> (heads, tile)
+        ("serve-360m-batch", (5, 64, 256, 2), (5, 256)),
+        ("serve-olmoe-fewshot", (16, 128, 3200, 2), (16, 128)),
+        ("serve-granite-h-docqa", (8, 128, 2176, 2), (8, 128)),
+        ("serve-1.7b-chat", (32, 64, 2048, 2), (16, 256)),
+        ("float32", (5, 64, 256, 4), (5, 256)),
+        ("rows_off_the_sublanes", (4, 8, 256, 2), (1, 256)),
+        ("head_dim_12", (2, 12, 256, 4), None),
+        ("ring_of_96_rows", (2, 16, 96, 4), None),
+    ],
+    ids=lambda x: x if isinstance(x, str) else None,
+)
+def test_decode_plan_is_a_function_of_shapes(cell, shape, plan):
+    """The plan at the cells' shapes, on the chip (not interpreted): all the
+    KV heads of a slot that fit 512 KB of tile under one pair of MXU calls;
+    one head a step where the heads' rows do not merge into whole sublane
+    tiles; none (the XLA path) where the kernel cannot tile the shape."""
+    got = decode_kernels.decode_plan(*shape, interpret=False)
+    assert got == plan
+    if plan:
+        assert got.block_diagonal == (plan[0] > 1)
 
 
 def test_paged_decode_attention_default_tile_and_head_groups(monkeypatch):
@@ -485,5 +553,22 @@ def test_batcher_token_streams_identical(tiny_cfg, small_tiles):
 def test_engine_kernel_probe_gauges(tiny_cfg):
     eng = _make_engine(tiny_cfg, "xla", weight_format="w4")
     out = eng.kernel_probe(iters=1)
-    assert set(out) == {"decode_attn_us", "verify_attn_us", "w4_matmul_us"}
-    assert all(v > 0 for v in out.values())
+    plan = {"decode_plan_heads", "decode_plan_block_t", "decode_plan_block_diagonal"}
+    assert set(out) == {"decode_attn_us", "verify_attn_us", "w4_matmul_us"} | plan
+    assert all(out[k] > 0 for k in set(out) - plan)
+    assert all(out[k] == 0 for k in plan)  # the XLA path: no kernel, no plan
+
+
+def test_engine_kernel_probe_carries_the_plan(tiny_cfg, small_tiles):
+    """Under ``pallas`` the probe's result (and so the gauges behind ``GET
+    /stats``) says which form of the decode kernel the engine's shapes take."""
+    eng = _make_engine(tiny_cfg, "pallas")
+    assert _runs_the_decode_kernel(eng)
+    out = eng.kernel_probe(iters=1)
+    want = decode_kernels.decode_plan(
+        tiny_cfg.kv_heads, tiny_cfg.head_dim, eng.max_context, 4,
+    )
+    assert want is not None
+    assert out["decode_plan_heads"] == want.heads
+    assert out["decode_plan_block_t"] == want.block_t == 8
+    assert out["decode_plan_block_diagonal"] == float(want.block_diagonal)

@@ -105,19 +105,51 @@ def test_fused_xent_fwd_bwd(chip):
     assert "tpu_custom_call" in text
 
 
+# (query heads, kv heads, head_dim, ring rows) the decode kernel is compiled
+# at: the shipped configs over a 1,024-row ring, and the serve cells' own
+# (SmolLM2-360M's one 256-row tile; OLMoE's and granite's 128-row tiles under
+# MHA and GQA heads of 128; the held-back chat cell's 32 MHA heads of 64)
+DECODE = {
+    **{name: (*heads, SEQ) for name, heads in HEADS.items()},
+    "smollm2-360m": (15, 5, 64, 256),
+    "olmoe-1b-7b": (16, 16, 128, 3200),
+    "granite-4.0-h": (32, 8, 128, 2176),
+    "smollm2-1.7b": (32, 32, 64, 2048),
+}
+
+
+def _kernel_blocks(fn, *shapes):
+    """-> the block shapes of the one ``pallas_call`` in ``fn``: (of its
+    inputs, of its outputs), a squeezed dimension as None."""
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes]
+    (call,) = [
+        e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns if e.primitive.name == "pallas_call"
+    ]
+    grid = call.params["grid_mapping"]
+    blocks = [
+        tuple(getattr(b, "block_size", None) for b in m.block_shape)
+        for m in grid.block_mappings
+    ]
+    return blocks[:grid.num_inputs], blocks[grid.num_inputs:]
+
+
 @pytest.mark.parametrize("return_stats", [False, True])
-@pytest.mark.parametrize("model", list(HEADS))
+@pytest.mark.parametrize("model", list(DECODE))
 def test_paged_decode_attention(chip, model, return_stats):
     """The kernel alone, handed a cache of two layers and the second's
-    index: the read and the row write in one ``tpu_custom_call``."""
-    hq, hkv, d = HEADS[model]
+    index: the read and the row write in one ``tpu_custom_call``, under the
+    plan the shapes give; what it reads is a ``(heads, d, block_t)`` tile of
+    K and of V, what it hands back the 128-row block that holds the row."""
+    hq, hkv, d, rows = DECODE[model]
     s = 8
-    cache = (cache_shape(2, s, SEQ, hkv, d), BF16)
-    text = compiled_text(
-        chip,
-        lambda q, k, v, ck, cv, lens: paged_decode_attention(
+    cache = (cache_shape(2, s, rows, hkv, d), BF16)
+
+    def step(q, k, v, ck, cv, lens):
+        return paged_decode_attention(
             q, k, v, ck, cv, lens, 1, interpret=False, return_stats=return_stats
-        ),
+        )
+
+    shapes = (
         ((s, hq, d), BF16),
         ((s, hkv, d), BF16),
         ((s, hkv, d), BF16),
@@ -125,7 +157,13 @@ def test_paged_decode_attention(chip, model, return_stats):
         cache,
         ((s,), jnp.int32),
     )
+    text = compiled_text(chip, step, *shapes)
     assert "tpu_custom_call" in text
+    heads, block_t = decode_kernels.decode_plan(hkv, d, rows, 2, interpret=False)
+    ins, outs = _kernel_blocks(step, *shapes)
+    assert ins[-2:] == [(None, None, heads, d, block_t)] * 2
+    assert outs[1:3] == [(None, None, heads, d, 128)] * 2
+    assert outs[0] == (None, None, heads * (hq // hkv), d)
 
 
 def _spec_text(chip, model, slots, kq, return_stats=False):
