@@ -61,6 +61,16 @@ def load_config(path_model: str) -> LlamaConfig:
 
 
 def _reject_moe(cfg: LlamaConfig, op: str) -> None:
+    if cfg.cca or cfg.router_hidden_size or cfg.residual_scaling:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no CCA (the two convolutions over q and k, the second "
+            "value projection, k's temperature), no router MLP fed by the layer "
+            "before and no learned residual scaling, and HF's zaya layout is not "
+            "mapped here. Such models train, serve and checkpoint through the "
+            "framework checkpointer (opendiloco_tpu.ckpt); only this "
+            "import/export is refused"
+        )
     if cfg.latent or cfg.leading_dense:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

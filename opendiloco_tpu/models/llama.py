@@ -136,8 +136,64 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     n_group: int = 1
     topk_group: int = 1
+    # A head's size where it is not ``hidden_size // num_attention_heads``
+    # (None: that), and the share of a head's values, from its first on,
+    # that the rotation turns (the pairs are the halves of that part)
+    head_dim: Optional[int] = None
+    partial_rotary_factor: float = 1.0
+    # The block of a published ``zaya`` ``config.json`` (ZAYA1, arXiv
+    # 2511.17127; its attention is CCA, arXiv 2510.04476): ``cca_time0`` > 0
+    # makes every attention layer compressed convolutional attention. q and k
+    # are projected to their heads' own width, pass side by side a depthwise
+    # causal convolution over ``cca_time0`` tokens and one grouped by head
+    # over ``cca_time1``, are mixed with each other's means, normalised per
+    # head and, k, scaled by a learned temperature; half the KV heads hold
+    # the token's own values and half the token before's (``_cca_qkv``).
+    # ``router_hidden_size`` > 0 makes the router an MLP of that width over a
+    # down-projection of the FFN's input to which the layer before's is added
+    # (``_router_features``), choosing under a selection bias beside softmax
+    # scores; ``residual_scaling`` gives each sublayer a learned scale and
+    # bias per channel on the stream and on the branch
+    cca_time0: int = 0
+    cca_time1: int = 0
+    router_hidden_size: int = 0
+    residual_scaling: bool = False
 
     def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(
+                self, "head_dim", self.hidden_size // self.num_attention_heads
+            )
+        if self.rotary_dim < 2 or self.rotary_dim % 2:
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} of a head of "
+                f"{self.head_dim}: the rotated part is an even number of values"
+            )
+        if self.cca:
+            if (self.cca_time0, self.cca_time1) != (2, 2) or self.kv_heads % 2:
+                raise ValueError(
+                    "CCA is written for two convolutions over 2 tokens each and an "
+                    "even number of KV heads (half hold the token's own values, half "
+                    f"the token before's); got cca_time0 {self.cca_time0}, cca_time1 "
+                    f"{self.cca_time1}, {self.kv_heads} KV heads"
+                )
+            if (
+                self.latent or self.layer_types is not None or self.qk_norm
+                or self.num_attention_heads % self.kv_heads
+                or self.position_embedding_type != "rope"
+            ):
+                raise ValueError(
+                    "CCA is written for a stack of like rotated attention layers "
+                    "whose query heads divide over the KV heads: no latent "
+                    "attention, no layer_types, no qk_norm, no 'nope'"
+                )
+        if self.router_hidden_size and not (
+            self.num_experts and self.topk_method == "greedy"
+        ):
+            raise ValueError(
+                "router_hidden_size needs routed experts (num_experts > 0) under "
+                "softmax scores (topk_method 'greedy')"
+            )
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)
             object.__setattr__(self, "layer_types", kinds)
@@ -278,8 +334,23 @@ class LlamaConfig:
         return self.num_experts if self.num_local_experts is None else self.num_local_experts
 
     @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def rotary_dim(self) -> int:
+        """Values of a head, from its first on, that the rotation turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def cca(self) -> bool:
+        """Is the attention CCA (q and k through two causal convolutions, so a
+        per-slot state beside the ring)?"""
+        return self.cca_time0 > 0
+
+    @property
+    def cca_state_dim(self) -> int:
+        """Values a CCA layer keeps of a slot's last token: q and k before the
+        convolutions, the same between the two, and the values the next token
+        takes from this one (``_cca_qkv``)."""
+        z = (self.num_attention_heads + self.kv_heads) * self.head_dim
+        return 2 * z + self.kv_heads // 2 * self.head_dim
 
     @classmethod
     def from_json(cls, path: str) -> "LlamaConfig":
@@ -323,12 +394,31 @@ class LlamaConfig:
             if held != known["num_experts"]:
                 known.setdefault("num_local_experts", held)
             known.setdefault("router_aux_loss_coef", 0.0)
+        if raw.get("model_type") == "zaya":
+            # every layer is "hybrid": a CCA sublayer, then an MoE sublayer
+            # (one kind, so no layer_types here); the rotation's base is the
+            # "hybrid" entry of rope_parameters. The selection bias balances
+            # the load and the config has no key for an aux loss: 0
+            if set(raw.get("layer_types") or ()) - {"hybrid"}:
+                raise ValueError(
+                    "a zaya stack is written for 'hybrid' layers alone (CCA over "
+                    f"the whole context); got {sorted(set(raw['layer_types']))}"
+                )
+            known.pop("layer_types", None)
+            rope = (raw.get("rope_parameters") or {}).get("hybrid") or {}
+            known.setdefault("rope_theta", rope.get("rope_theta", cls.rope_theta))
+            known.setdefault("residual_scaling", True)
+            known.setdefault("router_aux_loss_coef", 0.0)
+            # the FFN is the routed one alone: ``intermediate_size`` is no key
+            known.setdefault("intermediate_size", raw.get("moe_intermediate_size", 0))
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
         d = dataclasses.asdict(self)
         if d["num_key_value_heads"] is None:
             d["num_key_value_heads"] = self.num_attention_heads
+        if self.head_dim == self.hidden_size // self.num_attention_heads:
+            del d["head_dim"]  # derived: a reader that changes a width derives it anew
         d.update(
             architectures=["LlamaForCausalLM"],
             model_type="llama",
@@ -346,6 +436,12 @@ class LlamaConfig:
                 architectures=["Glm4MoeLiteForCausalLM"],
                 model_type="glm4_moe_lite",
                 n_routed_experts=self.held_experts,
+            )
+        if self.cca:
+            d.update(
+                architectures=["ZayaForCausalLM"],
+                model_type="zaya",
+                layer_types=["hybrid"] * self.num_hidden_layers,
             )
         return d
 
@@ -377,14 +473,28 @@ def shapes(cfg: LlamaConfig) -> dict:
             "up_proj": (Eh, D, Fe),
             "down_proj": (Eh, Fe, D),
         }
-        if cfg.topk_method == "noaux_tc":
+        if cfg.topk_method == "noaux_tc" or cfg.router_hidden_size:
             ffn["router_bias"] = (E,)
+        if cfg.router_hidden_size:
+            # the router as an MLP (``_router_features``): its last map keeps
+            # the name a linear router has
+            R = cfg.router_hidden_size
+            ffn.update(
+                router=(R, E), router_down=(D, R), router_down_bias=(R,),
+                router_gamma=(R,), router_norm=(R,), router_fc1=(R, R),
+                router_fc1_bias=(R,), router_fc2=(R, R), router_fc2_bias=(R,),
+            )
     if cfg.shared_width:
         Fs = cfg.shared_width
         ffn.update(
             shared_gate_proj=(D, Fs), shared_up_proj=(D, Fs), shared_down_proj=(Fs, D)
         )
     norms = {"input_norm": (D,), "post_attn_norm": (D,)}
+    if cfg.residual_scaling:
+        norms.update({
+            f"{sub}_{part}_{what}": (D,) for sub in ("attn", "ffn")
+            for part in ("stream", "branch") for what in ("scale", "bias")
+        })
     if cfg.latent:
         Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         attention = {
@@ -405,6 +515,14 @@ def shapes(cfg: LlamaConfig) -> dict:
         }
     if cfg.qk_norm:
         attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
+    if cfg.cca:
+        Z = (Nh + Nkv) * Dh  # q and k side by side
+        attention.update(
+            v_proj=(D, Nkv // 2 * Dh), v_prev_proj=(D, Nkv // 2 * Dh),
+            cca_conv0_weight=(cfg.cca_time0, Z), cca_conv0_bias=(Z,),
+            cca_conv1_weight=(Nh + Nkv, cfg.cca_time1, Dh, Dh), cca_conv1_bias=(Z,),
+            cca_k_temp=(Nkv,),
+        )
 
     def stack(n, *groups):
         return {k: s(n, *v) for g in groups for k, v in g.items()}
@@ -553,10 +671,14 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
             out.append(jnp.ones(leaf.shape, leaf.dtype))
         elif name == "router_bias":
             # a trained router's selection bias is not zero, and zero would
-            # leave the term untested: N(0, 0.1^2), beside scores in (0, 1)
-            out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * 0.1)
+            # leave the term untested: N(0, 0.1^2), beside sigmoid scores in
+            # (0, 1); beside softmax scores, whose mean is 1/E, a fifth of that
+            sigma = 0.1 if cfg.topk_method == "noaux_tc" else 0.2 / cfg.num_experts
+            out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * sigma)
         elif name in ("A_log", "dt_bias", "conv_weight", "conv_bias"):
             out.append(_init_mixer_leaf(name, key, leaf))
+        elif name in _ZAYA_DRAWS or (name == "router" and cfg.router_hidden_size):
+            out.append(_init_zaya_leaf(name, key, leaf))
         else:
             out.append(
                 jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.initializer_range
@@ -579,6 +701,38 @@ def _init_mixer_leaf(name: str, key: jax.Array, leaf) -> jax.Array:
         return dt + jnp.log(-jnp.expm1(-dt))
     bound = leaf.shape[-2] ** -0.5 if name == "conv_weight" else 0.5
     return jax.random.uniform(key, leaf.shape, leaf.dtype, -bound, bound)
+
+
+# the leaves of a ZAYA block that are not drawn N(0, initializer_range)
+_ZAYA_DRAWS = (
+    "cca_conv0_weight", "cca_conv1_weight", "cca_k_temp", "router_gamma",
+    "router_fc1", "router_fc2",
+    *(f"{sub}_{part}_scale" for sub in ("attn", "ffn") for part in ("stream", "branch")),
+)
+
+
+def _init_zaya_leaf(name: str, key: jax.Array, leaf) -> jax.Array:
+    """The draws of a ZAYA block that no config key fixes, each away from the
+    value that would leave its term untested: the convolutions as
+    ``torch.nn.Conv1d`` draws them (uniform in +-1/sqrt(fan-in): taps for the
+    depthwise one, taps x a head's values for the grouped one); the learned
+    factors (k's temperature, the residual scales) 1 + N(0, 0.05^2); the
+    router's carry-over ``gamma`` 0.5 + N(0, 0.1^2); the router MLP's three
+    maps N(0, 2 / fan-in), so that its logits spread about as a trained
+    router's do (a standard deviation of 1.2 at the published widths, the
+    largest probability 0.3 on average: drawn N(0, 0.02^2) they would all but
+    vanish through two GELUs and the selection bias alone would choose, one
+    expert for every token)."""
+    if name.startswith("cca_conv"):  # [taps, channels] or [heads, taps, in, out]
+        fan_in = leaf.shape[-2] * (leaf.shape[-3] if name == "cca_conv1_weight" else 1)
+        bound = fan_in**-0.5
+        return jax.random.uniform(key, leaf.shape, leaf.dtype, -bound, bound)
+    noise = jax.random.normal(key, leaf.shape, leaf.dtype)
+    if name in ("router", "router_fc1", "router_fc2"):
+        return noise * (2.0 / leaf.shape[-2]) ** 0.5
+    if name == "router_gamma":
+        return 0.5 + 0.1 * noise
+    return 1.0 + 0.05 * noise
 
 
 # the rematerialization policy accepted everywhere a `remat` argument
@@ -662,8 +816,20 @@ def _rope(cfg: LlamaConfig, positions: jax.Array):
     whose attention takes no positions (``position_embedding_type`` nope)."""
     if cfg.position_embedding_type == "nope":
         return None, None
-    d = cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim
+    d = cfg.qk_rope_head_dim if cfg.latent else cfg.rotary_dim
     return _rope_tables(positions, d, cfg.rope_theta)
+
+
+def _rotate_heads(cfg: LlamaConfig, x: jax.Array, cos, sin) -> jax.Array:
+    """Heads x [B, T, H, Dh] rotated by position over their first
+    ``cfg.rotary_dim`` values (all of them, but under a
+    ``partial_rotary_factor``), or as they are where ``cos`` is None."""
+    if cos is None:
+        return x
+    rot = cfg.rotary_dim
+    if rot == x.shape[-1]:
+        return _rope_apply(x, cos, sin)
+    return jnp.concatenate((_rope_apply(x[..., :rot], cos, sin), x[..., rot:]), axis=-1)
 
 
 def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
@@ -685,9 +851,65 @@ def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
     if cfg.attention_multiplier is not None:
         q = q * jnp.asarray(cfg.attention_multiplier * Dh**0.5, q.dtype)
     q, k = q.reshape(B, T, Nh, Dh), k.reshape(B, T, Nkv, Dh)
-    if cos is not None:
-        q, k = _rope_apply(q, cos, sin), _rope_apply(k, cos, sin)
-    return q, k, v
+    return _rotate_heads(cfg, q, cos, sin), _rotate_heads(cfg, k, cos, sin), v
+
+
+def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul, past=None):
+    """CCA's projections of x [B, T, D] (arXiv 2510.04476) -> (q [B, T, Nh,
+    Dh], k and v [B, T, Nkv, Dh], tails [B, T, ``cfg.cca_state_dim``]).
+
+    q and k are projected to their heads' own width and pass, side by side as
+    z [.., (Nh + Nkv) Dh], two causal convolutions over 2 tokens: a depthwise
+    one, c_t = w0[0] z_{t-1} + w0[1] z_t + b0, and one grouped by head (each
+    head a Dh x Dh map a tap), d_t = W1[g, 0] c_{t-1} + W1[g, 1] c_t + b1.
+    To d are added the means of the projections themselves: a query head takes
+    (its own + its KV group's k) / 2, a KV head (the mean of its group's query
+    heads + its own) / 2. Each head is then L2-normalised to norm sqrt(Dh), k
+    further scaled by a learned temperature per KV head, and both rotated.
+    The first half of the KV heads hold the token's own values, the second
+    half the token before's (a projection of their own).
+
+    So position t reads t - 1 three times over: z, c and the values. ``past``
+    [B, cca_state_dim] holds the three of the token before x's first (a
+    decode step's slot state); None: x starts its sequence and they are zero.
+    ``tails`` is what each position would hand the next: a decode step stores
+    its one, a prefill the one at the prompt's true length."""
+    B, T, _ = x.shape
+    Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    rep, Zq = Nh // Nkv, Nh * Dh
+    z = jnp.concatenate((mul(x, layer["q_proj"]), mul(x, layer["k_proj"])), axis=-1)
+    u = mul(x, layer["v_prev_proj"])  # what the next token takes as its values
+    Z = z.shape[-1]
+    before = (None,) * 3 if past is None else (
+        past[:, :Z], past[:, Z : 2 * Z], past[:, 2 * Z :]
+    )
+
+    def back(a, first):  # a [B, T, W] one token back; ``first`` [B, W] before a[:, 0]
+        first = jnp.zeros_like(a[:, :1]) if first is None else first[:, None].astype(a.dtype)
+        return jnp.concatenate((first, a[:, :-1]), axis=1)
+
+    w0, w1 = layer["cca_conv0_weight"], layer["cca_conv1_weight"]
+    c = back(z, before[0]) * w0[0] + z * w0[1] + layer["cca_conv0_bias"]
+    heads = lambda a: a.reshape(B, T, Nh + Nkv, Dh)
+    d = (
+        jnp.einsum("bthi,hio->btho", heads(back(c, before[1])), w1[:, 0])
+        + jnp.einsum("bthi,hio->btho", heads(c), w1[:, 1])
+        + layer["cca_conv1_bias"].reshape(Nh + Nkv, Dh)
+    ).astype(x.dtype)
+    zq, zk = z[..., :Zq].reshape(B, T, Nkv, rep, Dh), z[..., Zq:].reshape(B, T, Nkv, 1, Dh)
+    half = jnp.asarray(0.5, x.dtype)
+    q = d[:, :, :Nh] + ((zq + zk) * half).reshape(B, T, Nh, Dh)
+    k = d[:, :, Nh:] + (jnp.mean(zq, axis=3) + zk[:, :, :, 0]) * half
+    # each head to norm sqrt(Dh): an RMSNorm of the head whose weight is one
+    # for q and the KV head's temperature for k
+    q = _rms_norm(q, jnp.ones((), x.dtype), cfg.rms_norm_eps)
+    k = _rms_norm(k, layer["cca_k_temp"][:, None], cfg.rms_norm_eps)
+    v = jnp.concatenate((mul(x, layer["v_proj"]), back(u, before[2])), axis=-1)
+    tails = jnp.concatenate((z, c.astype(x.dtype), u), axis=-1)
+    return (
+        _rotate_heads(cfg, q, cos, sin), _rotate_heads(cfg, k, cos, sin),
+        v.reshape(B, T, Nkv, Dh), tails,
+    )
 
 
 def _latent_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
@@ -787,8 +1009,31 @@ def _grouped_matmul(xs: jax.Array, w, sizes: jax.Array) -> jax.Array:
     return jax.lax.ragged_dot(xs, w, sizes)
 
 
+def _router_features(cfg: LlamaConfig, xf: jax.Array, layer: dict, carried):
+    """What the router's last map ``layer["router"]`` reads of the tokens xf
+    [N, D] -> (the features, what the next layer's router is handed): the
+    tokens themselves and nothing, for a linear router. ZAYA's router (arXiv
+    2511.17127; the layer holds ``router_down``) projects the tokens down to
+    ``router_hidden_size``, adds the layer before's such state ``carried`` [N,
+    R] under a learned factor per channel (None: this is the first layer),
+    hands that sum on as it is, and passes its RMSNorm through two maps of
+    that width with a GELU (erf) behind each, accumulated in float32."""
+    if "router_down" not in layer:
+        return xf, None
+    r = jnp.dot(xf, layer["router_down"]) + layer["router_down_bias"]
+    if carried is not None:
+        r = r + layer["router_gamma"] * carried.reshape(r.shape)
+    s = _rms_norm(r, layer["router_norm"], cfg.rms_norm_eps)
+    for fc in ("router_fc1", "router_fc2"):
+        s = jnp.dot(s, layer[fc], preferred_element_type=jnp.float32)
+        s = jax.nn.gelu(s + layer[fc + "_bias"].astype(jnp.float32), approximate=False)
+        s = s.astype(xf.dtype)
+    return s, r
+
+
 def _routed_ffn(
-    cfg: LlamaConfig, x: jax.Array, layer: dict, live: Optional[jax.Array]
+    cfg: LlamaConfig, x: jax.Array, layer: dict, live: Optional[jax.Array],
+    features: Optional[jax.Array] = None, chosen: Optional[list] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k routed expert FFN over x [..., D], no token dropped ->
     (out, weighted aux loss, routing counts).
@@ -820,13 +1065,23 @@ def _routed_ffn(
     pairs of its own experts sort first, by expert, and are the groups of
     the matmuls, the other pairs lie behind the last group and are zeroed.
     The aux loss is of the whole routing. The counts are then of the held
-    experts' pairs, and a fourth gives the pairs of all experts."""
+    experts' pairs, and a fourth gives the pairs of all experts.
+
+    ``features`` [N, R] are what the router's last map reads where that is not
+    the tokens themselves (``_router_features``; ``_ffn`` computes them, with
+    what the next layer is handed). A layer with a ``router_bias`` chooses its
+    experts under it and weighs them without it: beside sigmoid scores
+    (``noaux_tc``) or, ZAYA's, beside the softmax. A caller that wants the
+    choice itself passes a list as ``chosen``: each token's experts [N, K]
+    int32 are appended to it."""
     D = x.shape[-1]
     E, K = cfg.num_experts, cfg.num_experts_per_tok
     xf = x.reshape(-1, D)
     N = xf.shape[0]
+    if features is None:
+        features = _router_features(cfg, xf, layer, None)[0]
 
-    logits = jnp.dot(xf, layer["router"], preferred_element_type=jnp.float32)
+    logits = jnp.dot(features, layer["router"], preferred_element_type=jnp.float32)
     if cfg.topk_method == "noaux_tc":
         # scores by sigmoid; the selection bias chooses and does not weigh
         probs = jax.nn.sigmoid(logits)  # [N, E]
@@ -837,10 +1092,16 @@ def _routed_ffn(
         gate = gate * cfg.routed_scaling_factor
     else:
         probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
-        gate, expert = jax.lax.top_k(probs, K)  # [N, K]; ties go to the lower index
+        if "router_bias" in layer:
+            _, expert = jax.lax.top_k(probs + layer["router_bias"].astype(jnp.float32), K)
+            gate = jnp.take_along_axis(probs, expert, axis=-1)
+        else:
+            gate, expert = jax.lax.top_k(probs, K)  # [N, K]; ties go to the lower index
         if cfg.norm_topk_prob:
             gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
 
+    if chosen is not None:
+        chosen.append(expert.astype(jnp.int32))
     flat = expert.reshape(-1)  # pair p belongs to token p // K
     share = cfg.num_local_experts is not None
     if share:
@@ -889,15 +1150,18 @@ def _swiglu(x, layer: dict, mul, prefix: str = ""):
 
 
 def _ffn(
-    cfg: LlamaConfig, x: jax.Array, layer: dict, mul, live=None
+    cfg: LlamaConfig, x: jax.Array, layer: dict, mul, live=None, features=None,
+    chosen=None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The block's feed-forward over x [..., D] -> (out, aux loss, routing
     counts): SwiGLU through the caller's weight matmul ``mul(x, w)``, or the
-    routed experts, and beside those, where the configuration has one, a
-    shared SwiGLU that every token passes: the layer's leaves say which it
-    has. aux and counts are zero for a dense layer."""
+    routed experts (``features``: what their router reads, where that is not
+    x; ``chosen``: a list to take each token's experts, ``_routed_ffn``), and
+    beside those, where the configuration has one, a shared SwiGLU
+    that every token passes: the layer's leaves say which it has. aux and
+    counts are zero for a dense layer."""
     if "router" in layer:
-        out, aux, counts = _routed_ffn(cfg, x, layer, live)
+        out, aux, counts = _routed_ffn(cfg, x, layer, live, features, chosen)
     else:  # a dense model's layer, or a routed model's leading dense one
         out, aux = _swiglu(x, layer, mul), jnp.float32(0.0)
         counts = jnp.zeros((cfg.moe_counts,), jnp.int32)
@@ -917,6 +1181,13 @@ class BlockOut(NamedTuple):
     attn_out: jax.Array  # the mixer's branch after its output projection [B, T, D]
     aux: jax.Array  # the routed FFN's weighted aux loss (0 for a dense one)
     counts: jax.Array  # the routed FFN's counts, int32 [3] (``_routed_ffn``)
+    # what this layer's router hands the next one's [B, T, R]; None but for a
+    # router that reads the layer before (``_router_features``)
+    router: Optional[jax.Array] = None
+    # CCA: what each position hands the next [B, T, cca_state_dim] (``_cca_qkv``)
+    tails: Optional[jax.Array] = None
+    # a routed FFN's choice, each token's experts [B * T, K] int32 (None: dense)
+    experts: Optional[jax.Array] = None
 
 
 def decoder_block(
@@ -930,6 +1201,8 @@ def decoder_block(
     attend=None,
     mix=None,
     live: Optional[jax.Array] = None,
+    router_in: Optional[jax.Array] = None,
+    past: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, BlockOut]:
     """One decoder layer over h [B, T, D], the only statement of its
     skeleton: RMSNorm, the mixer, residual; RMSNorm, FFN, residual. The
@@ -943,10 +1216,26 @@ def decoder_block(
     routed FFN counts. Latent attention enters the same way: the projection
     returns q and the tokens' latent rows, and the caller's ``attend(q, rows,
     kv_b_proj)`` is its attention over them, in the rebuilt form or the
-    absorbed one -> [B, T, Nh, v]. Both branches enter the residual under
-    the configuration's ``residual_multiplier``."""
+    absorbed one -> [B, T, Nh, v]. CCA enters as attention does too, behind a
+    projection that reads the token before: ``past`` [B, cca_state_dim] is
+    what that token left (a decode step's slot state; None at a sequence's
+    start), and ``BlockOut.tails`` what each position leaves. ``router_in``
+    [B, T, R] is the state of the layer before's router, for a router that
+    reads it (None in the first layer), ``BlockOut.router`` this layer's. Both
+    branches enter the residual under the configuration's
+    ``residual_multiplier``, or, where the layer holds them, under a learned
+    scale and bias per channel on the stream and on the branch."""
     B, T, _ = h.shape
-    scale = cfg.residual_multiplier
+    tails = router_out = features = None
+    chosen: list = []
+
+    def residual(h, branch, sub):
+        if f"{sub}_stream_scale" in layer:
+            stream = (h + layer[f"{sub}_stream_bias"]) * layer[f"{sub}_stream_scale"]
+            return stream + (branch + layer[f"{sub}_branch_bias"]) * layer[f"{sub}_branch_scale"]
+        scale = cfg.residual_multiplier
+        return h + (branch if scale == 1.0 else branch * jnp.asarray(scale, h.dtype))
+
     # the scopes name the device work in a profiler trace (an operation's
     # op_name metadata); they change nothing that is computed
     if mix is None and cfg.latent:
@@ -957,6 +1246,12 @@ def decoder_block(
             attn_out = mul(
                 attend(q, k, layer["kv_b_proj"]).reshape(B, T, -1), layer["o_proj"]
             )
+    elif mix is None and cfg.cca:
+        with jax.named_scope("odtp_cca"):
+            x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
+            q, k, v, tails = _cca_qkv(cfg, x, layer, cos, sin, mul, past)
+        with jax.named_scope("odtp_attention"):
+            attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
     elif mix is None:
         with jax.named_scope("odtp_attention"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
@@ -967,13 +1262,19 @@ def decoder_block(
         with jax.named_scope("odtp_ssm"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
             attn_out = mix(x, layer)
-    h = h + (attn_out if scale == 1.0 else attn_out * jnp.asarray(scale, h.dtype))
+    h = residual(h, attn_out, "attn")
     with jax.named_scope("odtp_mlp"):
         x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
-        ffn, aux, counts = _ffn(cfg, x, layer, mul, live)
-    if scale != 1.0:
-        ffn = ffn * jnp.asarray(scale, h.dtype)
-    return h + ffn, BlockOut(k, v, attn_out, aux, counts)
+        if "router_down" in layer:
+            with jax.named_scope("odtp_router"):
+                features, router_out = _router_features(
+                    cfg, x.reshape(B * T, -1), layer, router_in
+                )
+                router_out = router_out.reshape(B, T, -1)
+        ffn, aux, counts = _ffn(cfg, x, layer, mul, live, features, chosen)
+    return residual(h, ffn, "ffn"), BlockOut(
+        k, v, attn_out, aux, counts, router_out, tails, chosen[0] if chosen else None
+    )
 
 
 def training_block(
@@ -981,8 +1282,10 @@ def training_block(
     kind: str = "attention",
 ):
     """The body of training's scan over a run of ``kind`` layers
-    (``scan_layers``), ``(h, layer, li) -> (h, (mixer-output L2 norm, moe
-    aux loss))``, under the rematerialization policy. The norm is the activation probe the reference
+    (``scan_layers``), ``((h, r), layer, li) -> ((h, r), (mixer-output L2
+    norm, moe aux loss))``, under the rematerialization policy; r is the
+    router's state that one layer hands the next (``router_carry``; None
+    but for a router that reads the layer before). The norm is the activation probe the reference
     attaches via forward hooks on ``self_attn`` (utils.py:43-67,
     train_fsdp.py:65)."""
     cos, sin = _rope(cfg, positions)
@@ -992,15 +1295,25 @@ def training_block(
     elif cfg.latent:  # the rebuilt form: multi-head attention over k and v
         attend = rebuilt_attend(cfg, attn_fn)
 
-    def body(h, layer, li=None):
+    def body(carry, layer, li=None):
+        h, r = carry
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attend, mix=mix
+            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attend, mix=mix, router_in=r
         )
         with jax.named_scope("odtp_attention"):
             attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
-        return h, (attn_norm, out.aux)
+        return (h, out.router), (attn_norm, out.aux)
 
     return _maybe_remat(body, remat)
+
+
+def router_carry(cfg: LlamaConfig, h: jax.Array) -> Optional[jax.Array]:
+    """What the first layer's router is handed beside h [..., D]: zeros [...,
+    router_hidden_size] for a router that reads the layer before, else None
+    (no member of a scan's carry)."""
+    if not cfg.router_hidden_size:
+        return None
+    return jnp.zeros((*h.shape[:-1], cfg.router_hidden_size), h.dtype)
 
 
 def _embed(cfg: LlamaConfig, cparams: dict, ids: jax.Array) -> jax.Array:
@@ -1149,10 +1462,11 @@ def forward(
         env_unroll = os.environ.get("ODTP_SCAN_UNROLL")
         unroll = int(env_unroll) if env_unroll else (scan_unroll or 1)
         probes = []  # one scan per run of like layers (one, but for a hybrid)
+        r = router_carry(cfg, h)
         for run in layer_runs(cfg):
-            h, probe = scan_layers(
+            (h, r), probe = scan_layers(
                 cfg, training_block(cfg, attn_fn, positions, remat, run.kind),
-                h, cparams["layers"], run, unroll=max(1, unroll),
+                (h, r), cparams["layers"], run, unroll=max(1, unroll),
             )
             probes.append(probe)
         attn_norms, layer_auxs = (
@@ -1280,7 +1594,16 @@ def _serving_boundary(params, compute_dtype, decode_kernel):
 def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
     """A slot's past as rows that can be copied, cut or left out is what
     ``what`` rests on; a Mamba-2 layer's past is one state, which is none
-    of those."""
+    of those, and so is what CCA keeps of a slot's last token beside its
+    rows."""
+    if cfg.cca:
+        raise ValueError(
+            f"{what} is refused for a configuration with CCA (cca_time0 "
+            f"{cfg.cca_time0}): it treats a slot's past as cache rows, and CCA's "
+            "projections read the token before through a per-slot state beside "
+            "the ring, which is not rows and which a rejected draft would have "
+            "to roll back"
+        )
     if cfg.hybrid:
         raise ValueError(
             f"{what} is refused for a configuration with Mamba-2 layers "
@@ -1313,16 +1636,20 @@ def prefill_forward(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     decode_kernel: str = "xla",
     return_moe_counts: bool = False,
+    return_expert_choices: bool = False,
 ):
     """Prompt prefill for serving: ids [1, P] -> (last-token logits [1, V]
     f32, per-layer K/V [La, P, Nkv, Dh] in compute dtype over the attention
     layers; for latent attention, computed here in the rebuilt form, the
-    latent rows [La, P, R + rope] in K's place and None in V's); for a
+    latent rows [La, P, R + rope] in K's place and None in V's); for CCA
+    then the layers' state [L, cca_state_dim] as the last real token left it
+    (``_cca_qkv``: a bucket's padding rows do not reach it); for a
     hybrid stack then the Mamba-2 layers' recurrent states
     [Lm, H, P, N] float32 and conv tails [Lm, K - 1, C], as the last real
     token left them; and with ``return_moe_counts`` last the routed FFN's
     counts over the live prompt tokens summed over layers (int32, see
-    ``_routed_ffn``).
+    ``_routed_ffn``), and with ``return_expert_choices`` after them each
+    position's experts in each layer [L, P, K] int32.
 
     ``length`` (traced scalar) is the true prompt length; ``input_ids``
     may be right-padded to a compile-size bucket. Padding K/V rows do land
@@ -1340,13 +1667,19 @@ def prefill_forward(
     if cfg.latent:  # k and v rebuilt for the prompt; the rows are what is kept
         attend = rebuilt_attend(cfg, attend)
 
-    def attention_body(h, layer, li):
+    def attention_body(carry, layer, li):
+        h, r = carry
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live
+            cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live, router_in=r
         )
-        return h, (out.k[0], None if out.v is None else out.v[0], out.counts)
+        kept = [out.k[0], None if out.v is None else out.v[0]]
+        if cfg.cca:  # what the prompt's last token leaves the first decode step
+            with jax.named_scope("odtp_cca"):
+                kept.append(jax.lax.dynamic_index_in_dim(out.tails[0], length - 1, 0, False))
+        return (h, out.router), (*kept, (out.counts, out.experts))
 
-    def mamba_body(h, layer, li):
+    def mamba_body(carry, layer, li):
+        h, r = carry
         left = []
 
         def mix(x, layer):
@@ -1354,28 +1687,49 @@ def prefill_forward(
             left.extend((state[0], tail[0]))
             return out
 
-        h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live)
-        return h, (*left, out.counts)
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live, router_in=r
+        )
+        return (h, out.router), (*left, (out.counts, out.experts))
 
     h = _embed(cfg, cparams, input_ids)
-    kept = {"attention": ([], []), "mamba": ([], [])}
-    counts = []
+    r = router_carry(cfg, h)
+    kept = {"attention": ([], [], []), "mamba": ([], [])}
+    counts, experts = [], []
     for run in layer_runs(cfg):
         body = attention_body if run.mixer == "attention" else mamba_body
-        h, (a, b, c) = scan_layers(
-            cfg, body, h, cparams["layers"], run, experts_in_place=True
+        (h, r), (*left, (c, e)) = scan_layers(
+            cfg, body, (h, r), cparams["layers"], run, experts_in_place=True
         )
-        kept[run.mixer][0].append(a)
-        kept[run.mixer][1].append(b)
+        for parts, part in zip(kept[run.mixer], left):
+            parts.append(part)
         counts.append(c)
+        experts.append(e)
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = _logits(cfg, cparams, h_last)
-    out = [logits[:, 0], *map(_stacked, kept["attention"])]
+    out = [logits[:, 0], *map(_stacked, kept["attention"][:2])]
+    if cfg.cca:
+        out.append(_stacked(kept["attention"][2]))
     if cfg.hybrid:
         out.extend(map(_stacked, kept["mamba"]))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
+    if return_expert_choices:
+        out.append(_chosen(cfg, experts))
     return tuple(out)
+
+
+def _chosen(cfg: LlamaConfig, experts: list) -> jax.Array:
+    """The runs' expert choices as one [L, N, K] int32, in layer order: -1 in
+    a layer whose FFN is not routed."""
+    if not cfg.num_experts:
+        raise ValueError("return_expert_choices needs routed experts (num_experts > 0)")
+    shape = next(e.shape[1:] for e in experts if e is not None)
+    runs = layer_runs(cfg)
+    return jnp.concatenate([
+        jnp.full((run.count, *shape), -1, jnp.int32) if e is None else e
+        for run, e in zip(runs, experts)
+    ])
 
 
 def decode_forward(
@@ -1389,8 +1743,10 @@ def decode_forward(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     decode_kernel: str = "xla",
     return_moe_counts: bool = False,
+    return_expert_choices: bool = False,
     ssm_state: Optional[jax.Array] = None,
     conv_state: Optional[jax.Array] = None,
+    cca_state: Optional[jax.Array] = None,
 ):
     """One incremental decode step over all S slots.
 
@@ -1421,8 +1777,16 @@ def decode_forward(
     Mamba-2 layers carries the two through its scan, reads a layer's part
     and writes it back in place (jitted with both donated).
 
+    CCA also takes ``cca_state`` [L, S, cca_state_dim]
+    (``ring_cache.init_cca_state``): what each slot's last token left each
+    layer's projection, which reads one token back (``_cca_qkv``). The scan
+    carries it beside the caches, each layer reads its part and writes the
+    step's own in its place, and it comes back after the caches.
+
     With ``return_moe_counts`` the routed FFN's counts over the slots that
-    hold a sequence (``lens > 0``), summed over layers, come last."""
+    hold a sequence (``lens > 0``), summed over layers, come last, and with
+    ``return_expert_choices`` after them each slot's experts in each layer [L,
+    S, K] int32."""
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
     cos, sin = _rope(cfg, positions)
@@ -1432,7 +1796,7 @@ def decode_forward(
     latent_attention = mla_decode_attention if pallas else latent_decode_step_attention
 
     def attention_body(carry, layer, li):
-        h, ck, cv = carry  # the whole caches
+        h, r, ck, cv, tails = carry  # the whole caches, and every layer's tails
 
         def attend(q, k, v):
             nonlocal ck, cv
@@ -1450,13 +1814,19 @@ def decode_forward(
             return latent_expand(cfg, o_lat, w_kvb)
 
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, live=live,
+            cfg, h, layer, cos, sin, mul=mul, live=live, router_in=r,
             attend=absorbed if cfg.latent else attend,
+            past=None if tails is None else tails[li],
         )
-        return (h, ck, cv), out.counts
+        if tails is not None:
+            with jax.named_scope("odtp_cca"):  # the state's write is CCA's work
+                tails = jax.lax.dynamic_update_index_in_dim(
+                    tails, out.tails[:, 0].astype(tails.dtype), li, 0
+                )
+        return (h, out.router, ck, cv, tails), (out.counts, out.experts)
 
     def mamba_body(carry, layer, li):
-        h, states, tails = carry  # every Mamba-2 layer's
+        h, r, states, tails = carry  # every Mamba-2 layer's
 
         def mix(x, layer):
             nonlocal states, tails
@@ -1467,29 +1837,37 @@ def decode_forward(
             tails = jax.lax.dynamic_update_index_in_dim(tails, tail, li, 0)
             return out[:, None]
 
-        h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live)
-        return (h, states, tails), out.counts
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live, router_in=r
+        )
+        return (h, out.router, states, tails), (out.counts, out.experts)
 
     h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
-    counts = []
+    r = router_carry(cfg, h)
+    counts, experts = [], []
     for run in layer_runs(cfg):
         if run.mixer == "attention":
-            (h, cache_k, cache_v), c = scan_layers(
-                cfg, attention_body, (h, cache_k, cache_v), cparams["layers"], run,
-                experts_in_place=True,
+            (h, r, cache_k, cache_v, cca_state), (c, e) = scan_layers(
+                cfg, attention_body, (h, r, cache_k, cache_v, cca_state),
+                cparams["layers"], run, experts_in_place=True,
             )
         else:
-            (h, ssm_state, conv_state), c = scan_layers(
-                cfg, mamba_body, (h, ssm_state, conv_state), cparams["layers"], run,
+            (h, r, ssm_state, conv_state), (c, e) = scan_layers(
+                cfg, mamba_body, (h, r, ssm_state, conv_state), cparams["layers"], run,
                 experts_in_place=True,
             )
         counts.append(c)
+        experts.append(e)
     logits = _logits(cfg, cparams, h)
     out = [logits[:, 0], cache_k, cache_v]
+    if cfg.cca:
+        out.append(cca_state)
     if cfg.hybrid:
         out.extend((ssm_state, conv_state))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
+    if return_expert_choices:
+        out.append(_chosen(cfg, experts))
     return tuple(out)
 
 

@@ -1,6 +1,8 @@
 """The serving KV cache: slot-paged ring pages, and everything that knows
 how they are stored; beside it, for a hybrid stack, the Mamba-2 layers'
-per-slot recurrent state (``init_ssm_state``, ``state_insert``).
+per-slot recurrent state (``init_ssm_state``, ``state_insert``), and for CCA
+what each layer's projection keeps of a slot's last token
+(``init_cca_state``, ``cca_state_insert``).
 
 Storage is a ``k`` and a ``v`` array of ``[L, S, Nkv, Dh, T]``: one
 fixed-size ring page of T rows per layer and batch slot (the degenerate
@@ -95,6 +97,22 @@ def state_insert(
         return jax.lax.dynamic_update_slice(store, x[:, None].astype(store.dtype), start)
 
     return put(ssm, states), put(conv, tails)
+
+
+def init_cca_state(cfg, num_slots: int, dtype: jnp.dtype = jnp.bfloat16) -> jax.Array:
+    """Zeroed [L, S, ``cfg.cca_state_dim``]: per layer and slot what CCA's
+    projection reads of the token before (``llama._cca_qkv``: q and k before
+    the convolutions, the same between the two, the values the next token
+    takes), one row, values minor-most. Zero is a sequence's start."""
+    return jnp.zeros((cfg.num_hidden_layers, num_slots, cfg.cca_state_dim), dtype)
+
+
+def cca_state_insert(state: jax.Array, rows: jax.Array, slot: jax.Array) -> jax.Array:
+    """Write what a prefill's last real token left (rows [L, cca_state_dim])
+    into ``slot`` (traced scalar), whole: as a recurrent state, the row has no
+    stale part to mask, so a slot's next tenant starts from its own prompt."""
+    start = (jnp.int32(0), jnp.asarray(slot, jnp.int32), jnp.int32(0))
+    return jax.lax.dynamic_update_slice(state, rows[:, None].astype(state.dtype), start)
 
 
 def cache_shape(

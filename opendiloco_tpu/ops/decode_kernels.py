@@ -224,10 +224,14 @@ def _decode_attn_kernel(
     )
 
     def own_block():  # [rows, width]: head j's rows x columns j*d : (j+1)*d
-        col_head = _head_of(
-            jax.lax.broadcasted_iota(jnp.int32, (1, width), 1), d, heads
-        )
-        return row_head == col_head
+        # by the columns' bounds, not by ``row_head == col_head``: with two
+        # heads a step each is one comparison widened to int32, the compiler
+        # folds the equality onto the two masks, and Mosaic has no comparison
+        # of masks ("failed to legalize arith.cmpi", first met at 2 KV heads
+        # of 128: ZAYA1's)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        first = row_head * d
+        return (col >= first) & (col < first + d)
 
     def side_by_side(ref):  # the heads' new rows [heads, 1, d] as [1, heads * d]
         return jnp.concatenate([ref[j] for j in range(heads)], axis=1)

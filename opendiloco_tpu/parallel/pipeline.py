@@ -73,6 +73,13 @@ def pipeline_hidden(
             "layers: it stages one homogeneous [L, ...] stack, and a hybrid's "
             "layers are stacked per kind of mixer (llama.layer_runs)"
         )
+    if cfg.router_hidden_size:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration whose router reads "
+            f"the layer before (router_hidden_size {cfg.router_hidden_size}): a "
+            "stage hands the next the hidden state alone, and that router's "
+            "state would have to cross with it"
+        )
     if cfg.layers_by_kind:
         raise ValueError(
             "the pp pipeline is refused for a configuration with a leading dense "
@@ -110,7 +117,7 @@ def pipeline_hidden(
 
         def stage(x, pos):
             block = training_block(cfg, attn_fn, pos, remat)
-            y, (_, layer_auxs) = jax.lax.scan(block, x, layers_local)
+            (y, _), (_, layer_auxs) = jax.lax.scan(block, (x, None), layers_local)
             # keep the aux rank-1 everywhere in this region: it leaves
             # through a P(pp) out spec (see the export below)
             return y, jnp.sum(layer_auxs, keepdims=True)

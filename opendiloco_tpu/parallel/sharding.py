@@ -70,6 +70,16 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "A_log": (None, -1),
     "D": (None, -1),
     "mixer_norm": (None, -1),
+    # CCA (llama._cca_qkv): the second value projection as v_proj; the two
+    # convolutions are small and run over q and k side by side: replicated
+    "v_prev_proj": (1, 0),  # [D, Nkv/2 * Dh]
+    "cca_conv0_weight": (None, -1),  # [taps, (Nh + Nkv) Dh]
+    "cca_conv1_weight": (None, -1),  # [Nh + Nkv, taps, Dh, Dh]
+    # ZAYA's router MLP (llama._router_features): the down-projection as a
+    # linear router, the two square maps replicated
+    "router_down": (None, 0),  # [D, R]
+    "router_fc1": (None, -1),  # [R, R]
+    "router_fc2": (None, -1),
 }
 
 # FFN leaves that gain a leading expert dim when num_experts > 0

@@ -80,7 +80,9 @@ from opendiloco_tpu.models.llama import (
 )
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
+    cca_state_insert,
     fetch_pages,
+    init_cca_state,
     init_kv_cache,
     init_ssm_state,
     layer_pages,
@@ -195,6 +197,13 @@ class ServeEngine:
                 "out] matmul leaves of one homogeneous stack, not for a mixer's "
                 "in_proj/out_proj, conv and decay leaves"
             )
+        if cfg.cca and self.weight_format == "w4":
+            raise ValueError(
+                "weight_format=w4 is refused for a configuration with CCA: the "
+                "blockwise 4-bit packing is defined for the [L, in, out] matmul "
+                "leaves of a plain attention block, not for the convolutions over "
+                "q and k that share their rank"
+            )
         if self.weight_format == "w4":
             refuse_latent(
                 cfg, "weight_format=w4 (kv_b_proj is read in two halves, one absorbed "
@@ -297,45 +306,70 @@ class ServeEngine:
             state = init_ssm_state(cfg, self.num_slots, compute_dtype)
             self._ssm = (state["ssm"], state["conv"])
         self.ssm_state_resident_bytes = sum(x.nbytes for x in self._ssm)
+        # CCA's: what each layer's projection keeps of a slot's last token
+        self._cca: tuple = ()
+        if cfg.cca:
+            self._cca = (init_cca_state(cfg, self.num_slots, compute_dtype),)
+        self.cca_state_resident_bytes = sum(x.nbytes for x in self._cca)
+        # what CCA did (always on; stay 0 without it): tokens that passed its
+        # projections, and the bytes of that state the calls read and wrote (a
+        # prefill writes one slot's, a decode step reads and writes every slot's)
+        self.cca_tokens = 0
+        self.cca_state_bytes_moved = 0
+
+        # after ``keep_expert_choices()``: each token's experts in each layer
+        # of the newest prefill or decode step, on the device, [L, tokens, K]
+        # int32 (the engine never reads them: a check against a reference does)
+        self.expert_choices: Optional[jax.Array] = None
+        self._keeps_choices = False
 
         cd = compute_dtype
         dkn = self.decode_kernel
         moe = bool(cfg.num_experts)
-        n_ssm = len(self._ssm)
+        n_state = len(self._ssm) + len(self._cca)
+        state_names = ("cca_state",) if cfg.cca else ("ssm_state", "conv_state")
 
         # one named scope per program: what a profiler trace calls the
         # device work of a prefill and of a decode step. A routed model's
         # programs append the FFN's three counts to the tokens, so that one
         # device-to-host read fetches both (``_split_counts``)
         # (a hybrid's programs hand the recurrent state and the conv tail
-        # on after the K/V: ``left`` is those two, or nothing)
-        def _prefill(p, ids, length):
-            with jax.named_scope("odtp_serve_prefill"):
-                logits, ks, vs, *rest = prefill_forward(
-                    p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
-                    return_moe_counts=moe,
-                )
-                left, counts = rest[:n_ssm], rest[n_ssm:]
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (_with_counts(tok, counts), logits, ks, vs, *left)
+        # on after the K/V, CCA's its one state: ``left`` is those, or nothing;
+        # with ``chosen`` each token's experts in each layer come last)
+        def programs(chosen: bool):
+            def _prefill(p, ids, length):
+                with jax.named_scope("odtp_serve_prefill"):
+                    logits, ks, vs, *rest = prefill_forward(
+                        p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
+                        return_moe_counts=moe, return_expert_choices=chosen,
+                    )
+                    left, counts = rest[:n_state], rest[n_state : n_state + 1]
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (_with_counts(tok, counts), logits, ks, vs, *left, *rest[n_state + 1 :])
 
-        def _decode(p, tokens, lens, ck, cv, *ssm):
-            with jax.named_scope("odtp_serve_decode"):
-                state = dict(zip(("ssm_state", "conv_state"), ssm))
-                logits, ck, cv, *rest = decode_forward(
-                    p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
-                    decode_kernel=dkn, return_moe_counts=moe, **state,
-                )
-                left, counts = rest[:n_ssm], rest[n_ssm:]
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (_with_counts(tok, counts), logits, ck, cv, *left)
+            def _decode(p, tokens, lens, ck, cv, *ssm):
+                with jax.named_scope("odtp_serve_decode"):
+                    state = dict(zip(state_names, ssm))
+                    logits, ck, cv, *rest = decode_forward(
+                        p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
+                        decode_kernel=dkn, return_moe_counts=moe,
+                        return_expert_choices=chosen, **state,
+                    )
+                    left, counts = rest[:n_state], rest[n_state : n_state + 1]
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (_with_counts(tok, counts), logits, ck, cv, *left, *rest[n_state + 1 :])
 
-        # one compile per prompt bucket; insert/decode compile once. The
-        # insert also takes a slot's pages back from the host tier
-        self._prefill = jax.jit(_prefill)
+            # one compile per prompt bucket; decode compiles once
+            return jax.jit(_prefill), jax.jit(
+                _decode, donate_argnums=tuple(range(3, 5 + n_state))
+            )
+
+        self._programs = programs
+        self._prefill, self._decode = programs(False)
+        # insert compiles once; it also takes a slot's pages back from the host tier
         self._insert = jax.jit(cache_insert, donate_argnums=(0, 1))
         self._state_insert = jax.jit(state_insert, donate_argnums=(0, 1))
-        self._decode = jax.jit(_decode, donate_argnums=tuple(range(3, 5 + n_ssm)))
+        self._cca_insert = jax.jit(cca_state_insert, donate_argnums=(0,))
 
         # speculative-decode jits (compiled only when spec_step runs)
         kk, ld = self.spec_k, self.draft_layers
@@ -381,6 +415,16 @@ class ServeEngine:
         # ``rows`` is static -- padded to the prefill-bucket grid by
         # :meth:`page_rows` so the compile family stays bounded.
         self._fetch_pages = jax.jit(fetch_pages, static_argnums=(3,))
+
+    def keep_expert_choices(self) -> None:
+        """From here on a routed model's prefill and decode programs also hand
+        back each token's experts in each layer, and the newest call's stay in
+        ``expert_choices``. Programs of their own: called before the first
+        request, nothing compiles twice."""
+        if not self.cfg.num_experts:
+            raise ValueError("keep_expert_choices needs routed experts (num_experts > 0)")
+        self._keeps_choices = True
+        self._prefill, self._decode = self._programs(True)
 
     @property
     def device(self):
@@ -480,19 +524,25 @@ class ServeEngine:
             idsd, nd = jnp.asarray(ids), jnp.int32(n)
             t_args = time.perf_counter()
             tokd, logitsd, ks, vs, *left = self._prefill(self.params, idsd, nd)
+            if self._keeps_choices:
+                self.expert_choices = left.pop()
             # the slot's scalar is made here, while the device runs the
             # prompt: made with the others it holds every prefill's start back
             # by its own host time (a third of a millisecond on the chip)
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v, ks, vs, jnp.int32(slot)
             )
-            if left:  # the recurrent state the prompt left, whole
+            if self._cca:  # what the prompt's last token left CCA's projections
+                self._cca = (self._cca_insert(*self._cca, *left, jnp.int32(slot)),)
+            elif left:  # the recurrent state the prompt left, whole
                 self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
             t_dispatch = time.perf_counter()
             fetched, logits = np.asarray(tokd), np.asarray(logitsd[0])
             cuts = (t_args, t_dispatch, time.perf_counter())
             toks, moe = self._split_counts(fetched, 1)
-            moe.update(self._count_ssm(n, sum(x.nbytes for x in left)))
+            moved = sum(x.nbytes for x in left)
+            moe.update(self._count_ssm(n, moved))
+            moe.update(self._count_cca(n, moved))
             moe.update(self._count_latent(read=0, written=n))
             tok = int(toks[0])
         dt = time.perf_counter() - t0
@@ -524,6 +574,15 @@ class ServeEngine:
         self.ssm_tokens += tokens
         self.ssm_state_bytes_moved += state_bytes
         return {"ssm_tokens": tokens, "ssm_state_bytes": state_bytes}
+
+    def _count_cca(self, tokens: int, state_bytes: int) -> dict:
+        """Add one call's CCA work to the engine's counters -> the same as
+        span attributes (nothing for a model without CCA)."""
+        if not self._cca:
+            return {}
+        self.cca_tokens += tokens
+        self.cca_state_bytes_moved += state_bytes
+        return {"cca_tokens": tokens, "cca_state_bytes": state_bytes}
 
     def _count_latent(self, read: int, written: int) -> dict:
         """Add one call's traffic with the latent ring to the engine's
@@ -655,16 +714,22 @@ class ServeEngine:
         t0 = time.perf_counter()
         tokensd, lensd = jnp.asarray(tokens, jnp.int32), jnp.asarray(lens, jnp.int32)
         t_args = time.perf_counter()
-        tok, logits, self.cache_k, self.cache_v, *ssm = self._decode(
-            self.params, tokensd, lensd, self.cache_k, self.cache_v, *self._ssm
+        tok, logits, self.cache_k, self.cache_v, *state = self._decode(
+            self.params, tokensd, lensd, self.cache_k, self.cache_v,
+            *self._ssm, *self._cca,
         )
-        self._ssm = tuple(ssm)
+        if self._keeps_choices:
+            self.expert_choices = state.pop()
+        self._ssm, self._cca = tuple(state[: len(self._ssm)]), tuple(state[len(self._ssm):])
         t_dispatch = time.perf_counter()
         fetched = np.asarray(tok)
         cuts = (t_args, t_dispatch, time.perf_counter())
         tok, moe = self._split_counts(fetched, self.num_slots)
         moe.update(
             self._count_ssm(int(np.count_nonzero(lens)), 2 * self.ssm_state_resident_bytes)
+        )
+        moe.update(
+            self._count_cca(int(np.count_nonzero(lens)), 2 * self.cca_state_resident_bytes)
         )
         if self._latent_row_bytes:
             # a live slot's rows [0, lens] (the ring's T once it has wrapped),

@@ -132,6 +132,14 @@ class ContinuousBatcher:
                 "prefix reuse and the host tier copy, cut and restore a slot's "
                 "past as cache rows, and a recurrent state is not rows"
             )
+        if engine.cfg.cca and (prefix_cache or kv_tier is not None):
+            refused = "prefix_cache" if prefix_cache else "kv_tier"
+            raise ValueError(
+                f"{refused} is refused for a configuration with CCA: prefix reuse "
+                "and the host tier copy, cut and restore a slot's past as cache "
+                "rows, and CCA keeps a state of the slot's last token beside them "
+                "that neither snapshots"
+            )
         if engine.cfg.latent and (prefix_cache or kv_tier is not None):
             refused = "prefix_cache" if prefix_cache else "kv_tier"
             raise ValueError(

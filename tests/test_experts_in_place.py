@@ -189,11 +189,12 @@ def test_training_keeps_the_layers_own_experts(shape):
         positions = jnp.broadcast_to(jnp.arange(12, dtype=jnp.int32), ids.shape)
         attn = lambda q, k, v: llama.xla_attention(q, k, v, causal=True)
         h, auxs = llama._embed(cfg, p, ids), []
+        r = llama.router_carry(cfg, h)  # the block's carry is (h, the router's state)
         for run in layer_runs(cfg):
             block = llama.training_block(cfg, attn, positions, False, run.kind)
             stack = p["layers"][run.kind] if cfg.layers_by_kind else p["layers"]
             for i in range(run.start, run.start + run.count):
-                h, (_, aux) = block(h, {name: leaf[i] for name, leaf in stack.items()})
+                (h, r), (_, aux) = block((h, r), {name: leaf[i] for name, leaf in stack.items()})
                 auxs.append(aux)
         h, head = llama._final_norm_and_head(cfg, p, h)
         return llama.causal_lm_loss((h @ head).astype(jnp.float32), ids) + jnp.mean(jnp.stack(auxs))

@@ -170,7 +170,22 @@ def _latent_routed_model():
     return cfg, params
 
 
-@pytest.mark.parametrize("model", [_dense_model, _routed_qk_norm_model, _latent_routed_model])
+def _zaya_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "zaya", "vocab_size": 256, "hidden_size": 64, "head_dim": 8,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "num_hidden_layers": 3,
+        "layer_types": ["hybrid"] * 3, "cca_time0": 2, "cca_time1": 2,
+        "partial_rotary_factor": 0.5, "router_hidden_size": 16, "num_experts": 8,
+        "num_experts_per_tok": 1, "moe_intermediate_size": 32, "tie_word_embeddings": True,
+        "rope_parameters": {"hybrid": {"rope_theta": 5e6}}, "max_position_embeddings": 128,
+    })
+    assert cfg.cca and cfg.head_dim * cfg.num_attention_heads != cfg.hidden_size
+    return cfg, init_params(jax.random.key(6), cfg)
+
+
+@pytest.mark.parametrize(
+    "model", [_dense_model, _routed_qk_norm_model, _latent_routed_model, _zaya_model]
+)
 def test_the_five_forwards_agree(model):
     """One block under five drivers: in float32 the training forward, the
     prefill, the decode step, the verify pass and the draft (at full depth)
@@ -178,7 +193,11 @@ def test_the_five_forwards_agree(model):
     layer that the others do not get fails here. The latent block runs under
     the three that support it (training and prefill rebuild k and v, the
     decode step absorbs them: two formulas of one attention); the verify pass
-    and the draft handle (k, v) rows and refuse it."""
+    and the draft handle (k, v) rows and refuse it. The CCA block runs under
+    the same three (its projections read the token before: a shift over the
+    sequence in training and prefill, a per-slot state in decode, written by
+    the prefill at the prompt's true length); the other two cannot roll that
+    state back and refuse it."""
     from opendiloco_tpu.models.llama import (
         cache_insert, decode_forward, draft_propose, init_kv_cache,
         prefill_forward, verify_forward,
@@ -193,7 +212,7 @@ def test_the_five_forwards_agree(model):
 
     # prefill (padded to a bucket of 16) = the forward's last prompt row
     padded = jnp.asarray([prompt + [0] * (16 - P)], jnp.int32)
-    logits, ks, vs = prefill_forward(params, padded, jnp.int32(P), cfg, **f32)
+    logits, ks, vs, *left = prefill_forward(params, padded, jnp.int32(P), cfg, **f32)
     close(logits[0], full(prompt)[P - 1])
     tok = int(jnp.argmax(logits[0]))
     assert (vs is None) == cfg.latent  # the latent rows alone are kept
@@ -204,15 +223,22 @@ def test_the_five_forwards_agree(model):
     ck, cv = cache_insert(cache["k"], cache["v"], ks, vs, jnp.int32(1))
     tokens, lens = jnp.asarray([0, tok], jnp.int32), jnp.asarray([0, P], jnp.int32)
     want = full(prompt + [tok])[P]
-    step, _, _ = decode_forward(params, tokens, lens, ck, cv, cfg, **f32)
+    state = {}
+    if cfg.cca:  # what the prompt's last token left, into slot 1 of the state
+        from opendiloco_tpu.models.ring_cache import cca_state_insert, init_cca_state
+
+        state["cca_state"] = cca_state_insert(
+            init_cca_state(cfg, 2, jnp.float32), left[0], jnp.int32(1))
+    step, *_ = decode_forward(params, tokens, lens, ck, cv, cfg, **state, **f32)
     close(step[1], want)
-    if cfg.latent:
+    if cfg.latent or cfg.cca:
+        what = "latent" if cfg.latent else "CCA"
         for refused in (
             lambda: verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32),
             lambda: draft_propose(params, tokens, lens, ck, cv, cfg, k_steps=K,
                                   draft_layers=cfg.num_hidden_layers, **f32),
         ):
-            with pytest.raises(ValueError, match="refused for a configuration with latent"):
+            with pytest.raises(ValueError, match=f"refused for a configuration with {what}"):
                 refused()
         return
     verified, _, _ = verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32)

@@ -115,6 +115,7 @@ DECODE = {
     "olmoe-1b-7b": (16, 16, 128, 3200),
     "granite-4.0-h": (32, 8, 128, 2176),
     "smollm2-1.7b": (32, 32, 64, 2048),
+    "zaya1-8b": (8, 2, 128, 1536),  # head_dim a key: 8 x 128 is not the hidden 2,048
 }
 
 
@@ -593,11 +594,13 @@ SERVE_CELLS = {
     "serve-360m-batch": "smollm2-360m",
     "serve-olmoe-fewshot": "olmoe-1b-7b",
     "serve-granite-h-docqa": "granite-4.0-h-small",
+    "serve-zaya1-reason": "zaya1-8b",
 }
 ROUTED_CELLS = {
     "serve-olmoe-fewshot": "olmoe-1b-7b",
     "serve-granite-h-docqa": "granite-4.0-h-small",
     "serve-glm-flash-agent": "glm-4.7-flash",
+    "serve-zaya1-reason": "zaya1-8b",
 }
 
 
@@ -637,13 +640,17 @@ def _engine_program(chip, config, workload, program):
             jax.ShapeDtypeStruct(ssm, jnp.float32, sharding=chip),
             jax.ShapeDtypeStruct(conv, BF16, sharding=chip),
         ]
+    if cfg.cca:  # what each layer's projection keeps of a slot's last token
+        carried.append(jax.ShapeDtypeStruct(
+            (cfg.num_hidden_layers, slots, cfg.cca_state_dim), BF16, sharding=chip))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    names = ("cca_state",) if cfg.cca else ("ssm_state", "conv_state")
 
     def step(p, tok, lens, ck, *rest):
         cv, *state = (None, *rest) if cfg.latent else rest
         return decode_forward(
             p, tok, lens, ck, cv, cfg, decode_kernel="pallas", return_moe_counts=moe,
-            **dict(zip(("ssm_state", "conv_state"), state)),
+            **dict(zip(names, state)),
         )
 
     compiled = (
@@ -671,7 +678,8 @@ def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
     weights = sum(x.size * x.dtype.itemsize for x in leaves)
     assert mem.argument_size_in_bytes >= weights + carried
     # compiled here: decode 0.10 / 0.001 / 0.003 GB, prefill 0.10 / 1.25 / 0.62 GB
-    # (batch / OLMoE / granite), a prefill's being its attention scores. Until
+    # (batch / OLMoE / granite; ZAYA1, PR 37: see its own tests below), a
+    # prefill's being its attention scores. Until
     # ISSUE 33 the OLMoE decode step's 0.27 GB and granite's 0.06 were one
     # layer's ``gate_proj``, cut out of its stack for the grouped matmul
     # (``test_serving_programs_copy_no_experts``)
@@ -822,3 +830,88 @@ def test_glm_decode_step_reads_the_latent_ring_in_place(chip, monkeypatch):
     assert mem.alias_size_in_bytes >= 2 * ring.size
     assert _program_bytes(compiled) < HBM_BYTES
     assert not _cache_shaped_results(text, ring.shape)
+
+
+# ---------------------------------------------------------------------------
+# the ZAYA1-8B cell (ISSUE 37): its largest prefill and its decode step whole,
+# published widths, 10 layers, with the bf16 tree the engine holds (the kernel
+# alone at (2 KV heads, 128, ring 1,536) is ``test_paged_decode_attention``'s
+# case; that neither program casts a weight or writes out a layer's experts
+# are ``test_serving_programs_cast_no_weights`` / ``_copy_no_experts``' cases).
+# The decode step moves no cache and carries CCA's per-slot state in place
+# ---------------------------------------------------------------------------
+
+
+def _zaya_cell(chip):
+    """-> (configuration, engine options, a ring and the state as shapes)."""
+    cfg, engine = _serve_cell("zaya1-8b", "serve-zaya1-reason")
+    slots, rows = engine["num_slots"], engine["max_context"]
+    ring = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_hidden_layers, slots, rows, cfg.kv_heads, cfg.head_dim),
+        BF16, sharding=chip,
+    )
+    state = jax.ShapeDtypeStruct(
+        (cfg.num_hidden_layers, slots, cfg.cca_state_dim), BF16, sharding=chip)
+    return cfg, engine, ring, state
+
+
+def test_zaya_prefill_program_at_the_largest_bucket(chip):
+    """Bucket 1,024: the grouped matmuls over all 16 experts are in it, its
+    temporaries (the scores of 8 heads over 1,024 x 1,024 and the head's
+    logits aside) stay under a quarter of the weights, and it fits beside the
+    resident rings and state."""
+    cfg, engine, ring, state = _zaya_cell(chip)
+    assert (cfg.head_dim, cfg.rotary_dim, cfg.kv_heads, cfg.held_experts) == (128, 64, 2, 16)
+    assert cfg.cca_state_dim == 2688 and ring.shape[-3:] == (2, 128, engine["max_context"])
+    compiled, _, params, _ = _engine_program(chip, "zaya1-8b", "serve-zaya1-reason", "prefill")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "%ragged-dot" in text
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 5_225_940_328
+    assert mem.temp_size_in_bytes < weights / 4
+    resident = 2 * 2 * ring.size + 2 * state.size
+    assert _program_bytes(compiled) + resident < HBM_BYTES
+
+
+def test_zaya_decode_step_carries_ring_and_state_in_place(chip, monkeypatch):
+    """128 slots (or what the cell's file says): the decode kernel under the
+    plan for (2, 128, 1,536), the grouped matmuls, both rings and the state
+    aliased to the outputs, no copy, transpose, scatter, slice, update or
+    fresh buffer of a ring's shape or of one layer's pages, and of the
+    state's shape nothing that runs as an operation of its own but the
+    in-place update of a layer's rows. The tied table: what the step says of
+    its re-order is recorded, not asserted away (ISSUE 37: removing it is a
+    ``perf_opt`` of its own)."""
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, ring, state = _zaya_cell(chip)
+    compiled, _, params, carried = _engine_program(chip, "zaya1-8b", "serve-zaya1-reason", "decode")
+    assert carried == 2 * 2 * ring.size + 2 * state.size
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "%ragged-dot" in text
+    plan = decode_kernels.decode_plan(2, 128, engine["max_context"], 2, interpret=False)
+    assert plan.heads == 2 and engine["max_context"] % plan.block_t == 0
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert mem.temp_size_in_bytes < weights / 4
+    assert mem.alias_size_in_bytes >= carried
+    assert _program_bytes(compiled) < HBM_BYTES
+    assert not _cache_shaped_results(text, ring.shape)
+    # the state: written where it lies (a dynamic-update-slice, alone or as a
+    # fusion's root), never copied, transposed or allocated anew
+    instructions, roots = _top_level(text)
+    of_the_state = [
+        (opcode, roots.get(called), line) for opcode, _, shape, called, line in instructions
+        if shape == state.shape and not any(k in line for k in _MOVES_NOTHING)
+    ]
+    assert all(
+        "dynamic-update-slice" in (opcode, root) for opcode, root, _ in of_the_state
+    ), of_the_state
+    # the tied table [262272, 2048]: a copy of its size in this program is the
+    # re-order PERF.md section 5 records for the batch cell (the head's matmul
+    # reads the table in the other order of dimensions than the token gather)
+    table = (cfg.vocab_size, cfg.hidden_size)
+    reordered = [
+        line for opcode, _, shape, _, line in instructions
+        if opcode in ("copy", "transpose", "fusion") and tuple(sorted(shape)) == tuple(sorted(table))
+    ]
+    print(f"tied table re-ordered in the decode step: {len(reordered)} operation(s): {reordered}")
+    assert len(reordered) <= 1
