@@ -18,6 +18,18 @@ master snapshot from the outer plane). All are called from a single scheduler th
 the engine is deliberately not thread-safe so the jits can donate the
 cache buffers without a lock.
 
+A cold admission comes in two halves, so that a loop can enqueue every
+program of an iteration before it reads any of them: ``admit_enqueue``
+makes the arguments and enqueues the prompt's programs, whose insert also
+writes the first token into a ``[S]`` vector on the device, and the next
+``decode_step`` takes the slot's token from that vector, reads the
+admission's token once the step is enqueued behind it (a wait for the
+prompt's own program, no more) and only then its own. ``admit_resolve``
+reads at once instead, for a caller that steps nothing there, and the
+blocking ``admit`` is the two halves and the logits row: the same jitted
+programs whichever way. ``admissions_deferred`` counts the admissions a
+step fed on the device.
+
 Fast-decode legs (each individually off by default, and off-path
 bit-identical to the plain engine):
 
@@ -53,6 +65,7 @@ weights may lag (DiLoCo-fresh serving, arXiv 2311.08105).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from typing import Callable, Optional, Sequence
@@ -142,6 +155,110 @@ def _with_counts(tok, counts):
     return jnp.concatenate([tok, *counts]) if counts else tok
 
 
+# what a slot whose first token is still on the device passes the decode
+# program in place of a token: the program takes the slot's entry of the
+# engine's first-token vector instead (no token id is negative)
+FIRST_TOKEN_ON_DEVICE = -1
+
+
+def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: bool = False):
+    """The three functions a cold admission and a decode step run, unjitted
+    -> (prefill, decode, admit_insert, carried): ``carried`` the number of
+    ``decode``'s trailing arguments (rings, then per-slot state) that it
+    updates and that its jit donates.
+
+    One named scope per program: what a profiler trace calls the device work
+    of a prefill and of a decode step. A routed model's programs append the
+    FFN's three counts to the tokens, so that one device-to-host read fetches
+    both (``ServeEngine._split_counts``). A hybrid's programs hand the
+    recurrent state and the conv tail on after the K/V, CCA's its one state:
+    ``left`` is those, or nothing; with ``chosen`` each token's experts in
+    each layer come last.
+
+    The first token never has to reach the host before the step that reads
+    it: ``admit_insert`` writes it at ``slot`` into the ``[S]`` vector
+    ``first`` beside the prompt's rows, and ``decode`` takes ``first[slot]``
+    wherever ``tokens[slot]`` is ``FIRST_TOKEN_ON_DEVICE``."""
+    cd, dkn = compute_dtype, decode_kernel
+    moe = bool(cfg.num_experts)
+    n_state = 1 if cfg.cca else 2 if cfg.hybrid else 0
+    state_names = ("cca_state",) if cfg.cca else ("ssm_state", "conv_state")
+
+    def prefill(p, ids, length):
+        with jax.named_scope("odtp_serve_prefill"):
+            logits, ks, vs, *rest = prefill_forward(
+                p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
+                return_moe_counts=moe, return_expert_choices=chosen,
+            )
+            left, counts = rest[:n_state], rest[n_state : n_state + 1]
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # the one row of logits goes back as a row: a caller that wants it
+        # reads it, and no second program has to cut it out after the first read
+        return (_with_counts(tok, counts), logits[0], ks, vs, *left, *rest[n_state + 1 :])
+
+    def decode(p, tokens, lens, first, ck, cv, *ssm):
+        with jax.named_scope("odtp_serve_decode"):
+            tokens = jnp.where(tokens == FIRST_TOKEN_ON_DEVICE, first, tokens)
+            state = dict(zip(state_names, ssm))
+            logits, ck, cv, *rest = decode_forward(
+                p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
+                decode_kernel=dkn, return_moe_counts=moe,
+                return_expert_choices=chosen, **state,
+            )
+            left, counts = rest[:n_state], rest[n_state : n_state + 1]
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (_with_counts(tok, counts), logits, ck, cv, *left, *rest[n_state + 1 :])
+
+    def admit_insert(ck, cv, first, ks, vs, tok, slot):
+        ck, cv = cache_insert(ck, cv, ks, vs, slot)
+        return ck, cv, first.at[slot].set(tok[0])
+
+    return prefill, decode, admit_insert, 2 + n_state
+
+
+class _DecodeProgram:
+    """``ServeEngine._decode``: the jitted decode program under the signature
+    it had before it took the first-token vector, ``(params, tokens, lens,
+    cache_k, cache_v, *state)``, for whoever lowers, traces or calls it from
+    outside the engine (the benchmark's drivers name a scope's instructions
+    from the text of ``_decode.lower(...)``, which has to be the program that
+    ran). The engine passes ``first``; without it no slot's token is there."""
+
+    def __init__(self, decode, carried: int):
+        self.jitted = jax.jit(decode, donate_argnums=tuple(range(4, 4 + carried)))
+
+    def __call__(self, p, tokens, lens, *carried, first=None):
+        if first is None:
+            first = jnp.zeros(np.shape(tokens), jnp.int32)
+        return self.jitted(p, tokens, lens, first, *carried)
+
+    def lower(self, p, tokens, lens, *carried):
+        first = jax.ShapeDtypeStruct(tokens.shape, jnp.int32)
+        return self.jitted.lower(p, tokens, lens, first, *carried)
+
+    def _cache_size(self) -> int:
+        return self.jitted._cache_size()
+
+
+@dataclasses.dataclass(eq=False)
+class Admission:
+    """A cold admission whose programs are enqueued (``ServeEngine.
+    admit_enqueue``) and whose first token may not have been read yet:
+    ``token`` and ``t_token``, the instant it reached the host, are filled by
+    ``admit_resolve`` or by the decode step that fed it on the device."""
+
+    slot: int
+    tokens: int  # the prompt's length
+    tokd: jax.Array  # the first token, then a routed model's counts
+    rowd: jax.Array  # the last position's logits [V], never read unasked
+    state_bytes: int
+    t0: float
+    t_args: float
+    t_dispatch: float
+    token: Optional[int] = None
+    t_token: Optional[float] = None
+
+
 # snapshot_fn contract: () -> (epoch, blobs, codec_name) with blobs[i] =
 # (payload, meta, shape) per master leaf in params-flatten order — exactly
 # what DiLoCoOptimizer.master_snapshot_wire returns.
@@ -153,7 +270,8 @@ _STAGES = (
 )
 # where a cold prefill and a decode step change hands: until the arguments of
 # the call's first program are device arrays, until its last jitted call has
-# returned to Python, until the tokens are on the host
+# returned to Python, and from where the host starts to wait for the call's
+# tokens (at once, or after the reads that come before it) until they are there
 _PHASES = ("args", "dispatch", "fetch")
 
 
@@ -259,7 +377,10 @@ class ServeEngine:
         # the two stages a cell runs, cut into their phases (always on, from
         # stamps the call takes itself; spans ``serve_args``, ``serve_dispatch``
         # and ``serve_fetch`` from the same stamps while a tracer is armed).
-        # ``stage_seconds`` less a stage's three is the counting after the read
+        # ``stage_seconds`` less a stage's three is the counting after the read.
+        # An admission that a decode step reads is a ``prefill`` fetch inside
+        # that step's wall and outside its seconds: ``stage_seconds["prefill"]``
+        # is the enqueue and that read, ``["decode"]`` the step's own three
         self.phase_seconds = {
             stage: {k: 0.0 for k in _PHASES} for stage in ("prefill", "decode")
         }
@@ -325,48 +446,31 @@ class ServeEngine:
 
         cd = compute_dtype
         dkn = self.decode_kernel
-        moe = bool(cfg.num_experts)
-        n_state = len(self._ssm) + len(self._cca)
-        state_names = ("cca_state",) if cfg.cca else ("ssm_state", "conv_state")
 
-        # one named scope per program: what a profiler trace calls the
-        # device work of a prefill and of a decode step. A routed model's
-        # programs append the FFN's three counts to the tokens, so that one
-        # device-to-host read fetches both (``_split_counts``)
-        # (a hybrid's programs hand the recurrent state and the conv tail
-        # on after the K/V, CCA's its one state: ``left`` is those, or nothing;
-        # with ``chosen`` each token's experts in each layer come last)
+        # the first token of each slot's newest admission, on the device: an
+        # admission's insert writes it, the decode step reads it where the
+        # host has not (``serving_programs``); the slots of admissions that
+        # are enqueued and not yet read, in the order the chip finishes them
+        self._first = jnp.zeros((self.num_slots,), jnp.int32)
+        self._unread: list[Admission] = []
+        # admissions whose first token a decode step took on the device,
+        # beside all cold admissions in ``phase_calls["prefill"]``
+        self.admissions_deferred = 0
+
         def programs(chosen: bool):
-            def _prefill(p, ids, length):
-                with jax.named_scope("odtp_serve_prefill"):
-                    logits, ks, vs, *rest = prefill_forward(
-                        p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
-                        return_moe_counts=moe, return_expert_choices=chosen,
-                    )
-                    left, counts = rest[:n_state], rest[n_state : n_state + 1]
-                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (_with_counts(tok, counts), logits, ks, vs, *left, *rest[n_state + 1 :])
-
-            def _decode(p, tokens, lens, ck, cv, *ssm):
-                with jax.named_scope("odtp_serve_decode"):
-                    state = dict(zip(state_names, ssm))
-                    logits, ck, cv, *rest = decode_forward(
-                        p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
-                        decode_kernel=dkn, return_moe_counts=moe,
-                        return_expert_choices=chosen, **state,
-                    )
-                    left, counts = rest[:n_state], rest[n_state : n_state + 1]
-                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (_with_counts(tok, counts), logits, ck, cv, *left, *rest[n_state + 1 :])
-
-            # one compile per prompt bucket; decode compiles once
-            return jax.jit(_prefill), jax.jit(
-                _decode, donate_argnums=tuple(range(3, 5 + n_state))
+            prefill, decode, admit_insert, carried = serving_programs(
+                cfg, compute_dtype=cd, decode_kernel=dkn, chosen=chosen
+            )
+            # one compile per prompt bucket (prefill, insert); decode compiles once
+            return (
+                jax.jit(prefill),
+                _DecodeProgram(decode, carried),
+                jax.jit(admit_insert, donate_argnums=(0, 1, 2)),
             )
 
         self._programs = programs
-        self._prefill, self._decode = programs(False)
-        # insert compiles once; it also takes a slot's pages back from the host tier
+        self._prefill, self._decode, self._admit_insert = programs(False)
+        # a slot's pages coming back from the host tier (one compile per row count)
         self._insert = jax.jit(cache_insert, donate_argnums=(0, 1))
         self._state_insert = jax.jit(state_insert, donate_argnums=(0, 1))
         self._cca_insert = jax.jit(cca_state_insert, donate_argnums=(0,))
@@ -424,7 +528,7 @@ class ServeEngine:
         if not self.cfg.num_experts:
             raise ValueError("keep_expert_choices needs routed experts (num_experts > 0)")
         self._keeps_choices = True
-        self._prefill, self._decode = self._programs(True)
+        self._prefill, self._decode, self._admit_insert = self._programs(True)
 
     @property
     def device(self):
@@ -493,21 +597,17 @@ class ServeEngine:
         cold-tier variant: the prefix K/V pages come from the host prefix
         store (H2D install) instead of a live slot's ring."""
         n = len(prompt)
-        bucket = pick_bucket(n, self.prefill_buckets)
-        if bucket is None:
-            raise ValueError(
-                f"prompt length {n} exceeds max bucket "
-                f"{self.prefill_buckets[-1]}"
-            )
-        if (host_prefix is not None and 0 < host_prefix[2] < n) or (
-            prefix_src is not None and 0 < prefix_len < n
-        ):
-            refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
-            refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
+        from_host = host_prefix is not None and 0 < host_prefix[2] < n
+        from_slot = prefix_src is not None and 0 < prefix_len < n
+        if not (from_host or from_slot):
+            adm = self.admit_enqueue(slot, prompt)
+            logits = self._read(adm, row=True)
+            return adm.token, logits
+        self._bucket_of(n)
+        refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
+        refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         t0 = time.perf_counter()
-        moe = {}  # a continued prefill's routing is not counted
-        cuts = None  # nor are its phases cut
-        if host_prefix is not None and 0 < host_prefix[2] < n:
+        if from_host:
             hk, hv, plen = host_prefix
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v,
@@ -516,54 +616,109 @@ class ServeEngine:
                 jnp.int32(slot),
             )
             tok, logits = self._run_suffix(slot, prompt, int(plen))
-        elif prefix_src is not None and 0 < prefix_len < n:
-            tok, logits = self._admit_suffix(slot, prompt, prefix_src, prefix_len)
         else:
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :n] = np.asarray(prompt, np.int32)
-            idsd, nd = jnp.asarray(ids), jnp.int32(n)
-            t_args = time.perf_counter()
-            tokd, logitsd, ks, vs, *left = self._prefill(self.params, idsd, nd)
-            if self._keeps_choices:
-                self.expert_choices = left.pop()
-            # the slot's scalar is made here, while the device runs the
-            # prompt: made with the others it holds every prefill's start back
-            # by its own host time (a third of a millisecond on the chip)
-            self.cache_k, self.cache_v = self._insert(
-                self.cache_k, self.cache_v, ks, vs, jnp.int32(slot)
-            )
-            if self._cca:  # what the prompt's last token left CCA's projections
-                self._cca = (self._cca_insert(*self._cca, *left, jnp.int32(slot)),)
-            elif left:  # the recurrent state the prompt left, whole
-                self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
-            t_dispatch = time.perf_counter()
-            fetched, logits = np.asarray(tokd), np.asarray(logitsd[0])
-            cuts = (t_args, t_dispatch, time.perf_counter())
-            toks, moe = self._split_counts(fetched, 1)
-            moved = sum(x.nbytes for x in left)
-            moe.update(self._count_ssm(n, moved))
-            moe.update(self._count_cca(n, moved))
-            moe.update(self._count_latent(read=0, written=n))
-            tok = int(toks[0])
+            tok, logits = self._admit_suffix(slot, prompt, prefix_src, prefix_len)
+        # a continued prefill's routing is not counted, nor are its phases cut
         dt = time.perf_counter() - t0
         self.stage_seconds["prefill"] += dt
         tr = obs.tracer()
         if tr is not None:
-            tr.add_span("serve_prefill", t0, t0 + dt, tokens=n, **moe)
-        if cuts is not None:
-            self._count_phases("prefill", t0, cuts, tr)
+            tr.add_span("serve_prefill", t0, t0 + dt, tokens=n)
         return tok, logits
 
-    def _count_phases(self, stage: str, t0: float, cuts: tuple, tr) -> None:
-        """One call's three phases, ``cuts`` the stamps that end them -> the
-        engine's counters, and spans that tile the front of the call's
-        ``serve_prefill`` / ``serve_decode`` where ``tr`` is an armed tracer."""
+    def _bucket_of(self, n: int) -> int:
+        bucket = pick_bucket(n, self.prefill_buckets)
+        if bucket is None:
+            raise ValueError(
+                f"prompt length {n} exceeds max bucket "
+                f"{self.prefill_buckets[-1]}"
+            )
+        return bucket
+
+    def admit_enqueue(self, slot: int, prompt: Sequence[int]) -> Admission:
+        """The front half of a cold admission: the arguments made and the
+        prompt's programs enqueued (prefill, the insert that also writes the
+        first token into the engine's device vector, a state's insert), and
+        nothing read. Until the :class:`Admission` is resolved, by
+        ``admit_resolve`` or by the next ``decode_step``, that step takes the
+        slot's token from the device, whatever ``tokens`` holds there."""
+        n = len(prompt)
+        bucket = self._bucket_of(n)
+        t0 = time.perf_counter()
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :n] = np.asarray(prompt, np.int32)
+        idsd, nd = jnp.asarray(ids), jnp.int32(n)
+        t_args = time.perf_counter()
+        tokd, rowd, ks, vs, *left = self._prefill(self.params, idsd, nd)
+        if self._keeps_choices:
+            self.expert_choices = left.pop()
+        # the slot's scalar is made here, while the device runs the
+        # prompt: made with the others it holds every prefill's start back
+        # by its own host time (a third of a millisecond on the chip)
+        self.cache_k, self.cache_v, self._first = self._admit_insert(
+            self.cache_k, self.cache_v, self._first, ks, vs, tokd, jnp.int32(slot)
+        )
+        if self._cca:  # what the prompt's last token left CCA's projections
+            self._cca = (self._cca_insert(*self._cca, *left, jnp.int32(slot)),)
+        elif left:  # the recurrent state the prompt left, whole
+            self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
+        adm = Admission(
+            slot=int(slot), tokens=n, tokd=tokd, rowd=rowd,
+            state_bytes=sum(x.nbytes for x in left),
+            t0=t0, t_args=t_args, t_dispatch=time.perf_counter(),
+        )
+        self._unread.append(adm)
+        return adm
+
+    def admit_resolve(self, adm: Admission) -> int:
+        """The back half, at once: wait for the admission's first token, count
+        what its prefill did -> the token, which the caller then passes the
+        decode step itself (``adm.t_token``: the instant it was on the host)."""
+        self._read(adm)
+        return adm.token
+
+    def _read(self, adm: Admission, *, row: bool = False, t_from: Optional[float] = None):
+        """Read ``adm``'s first token (and with ``row`` the logits it was taken
+        from -> those), add the prefill's work to the counters and its seconds
+        to stage ``prefill``: the enqueue, and this read with its counting.
+        ``t_from``: where a decode step that fed the token on the device starts
+        the wait; the ``serve_prefill`` span then ends with the enqueue, which
+        is all of it that is one stretch of time."""
+        self._unread.remove(adm)
+        t_fetch = adm.t_dispatch if t_from is None else t_from
+        fetched = np.asarray(adm.tokd)
+        logits = np.asarray(adm.rowd) if row else None
+        adm.t_token = time.perf_counter()
+        toks, attrs = self._split_counts(fetched, 1)
+        attrs.update(self._count_ssm(adm.tokens, adm.state_bytes))
+        attrs.update(self._count_cca(adm.tokens, adm.state_bytes))
+        attrs.update(self._count_latent(read=0, written=adm.tokens))
+        adm.token = int(toks[0])
+        t1 = time.perf_counter()
+        self.stage_seconds["prefill"] += (adm.t_dispatch - adm.t0) + (t1 - t_fetch)
+        tr = obs.tracer()
+        if tr is not None:
+            tr.add_span(
+                "serve_prefill", adm.t0, t1 if t_from is None else adm.t_dispatch,
+                tokens=adm.tokens, **attrs,
+            )
+        self._count_phases(
+            "prefill",
+            ((adm.t0, adm.t_args), (adm.t_args, adm.t_dispatch), (t_fetch, adm.t_token)),
+            tr,
+        )
+        return logits
+
+    def _count_phases(self, stage: str, bounds: tuple, tr) -> None:
+        """One call's three phases, ``bounds`` each one's start and end -> the
+        engine's counters, and spans where ``tr`` is an armed tracer: they tile
+        the front of a blocking call's ``serve_prefill`` / ``serve_decode``,
+        and leave between dispatch and fetch whatever else was read meanwhile."""
         total = self.phase_seconds[stage]
-        for phase, t1 in zip(_PHASES, cuts):
+        for phase, (t0, t1) in zip(_PHASES, bounds):
             total[phase] += t1 - t0
             if tr is not None:
                 tr.add_span(f"serve_{phase}", t0, t1, stage=stage)
-            t0 = t1
         self.phase_calls[stage] += 1
 
     def _count_ssm(self, tokens: int, state_bytes: int) -> dict:
@@ -710,20 +865,36 @@ class ServeEngine:
         """One greedy token per slot. ``tokens``/``lens`` are dense [S]
         host arrays (inactive slots pass 0s; their ring writes land in
         masked positions and are overwritten on the slot's next tenancy).
-        Returns (next tokens [S] np.int32, logits [S, V] on device)."""
+        Returns (next tokens [S] np.int32, logits [S, V] on device).
+
+        A slot admitted by ``admit_enqueue`` and not yet resolved takes its
+        token from the device, whatever ``tokens`` holds there, and its
+        admission is read here, between the step's dispatch and the step's own
+        read (``Admission.token``, ``t_token``): the step's ``fetch`` phase and
+        ``stage_seconds["decode"]`` start again after those reads."""
         t0 = time.perf_counter()
+        fed = self._unread[:]
+        if fed:  # their tokens are where the program finds them: on the device
+            tokens = np.array(tokens, np.int32)
+            tokens[[adm.slot for adm in fed]] = FIRST_TOKEN_ON_DEVICE
         tokensd, lensd = jnp.asarray(tokens, jnp.int32), jnp.asarray(lens, jnp.int32)
         t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *state = self._decode(
             self.params, tokensd, lensd, self.cache_k, self.cache_v,
-            *self._ssm, *self._cca,
+            *self._ssm, *self._cca, first=self._first,
         )
         if self._keeps_choices:
             self.expert_choices = state.pop()
         self._ssm, self._cca = tuple(state[: len(self._ssm)]), tuple(state[len(self._ssm):])
-        t_dispatch = time.perf_counter()
+        t_fetch = t_dispatch = time.perf_counter()
+        # with the step enqueued behind them, the admissions' tokens are read,
+        # each a wait for its own program and no more; then the step's
+        for adm in fed:
+            self._read(adm, t_from=t_fetch)
+            t_fetch = time.perf_counter()
+        self.admissions_deferred += len(fed)
         fetched = np.asarray(tok)
-        cuts = (t_args, t_dispatch, time.perf_counter())
+        t_fetched = time.perf_counter()
         tok, moe = self._split_counts(fetched, self.num_slots)
         moe.update(
             self._count_ssm(int(np.count_nonzero(lens)), 2 * self.ssm_state_resident_bytes)
@@ -740,9 +911,12 @@ class ServeEngine:
                 read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size
             ))
         t1 = time.perf_counter()
-        self.stage_seconds["decode"] += t1 - t0
+        # the step's own seconds: not those of the admissions read inside it
+        self.stage_seconds["decode"] += (t1 - t0) - (t_fetch - t_dispatch)
         self.decode_bounds = (t0, t1)
         tr = obs.tracer()
+        if fed:
+            obs.count("serve_admissions_deferred", len(fed))
         if tr is not None:
             tr.count(f"serve_decode_kernel_{self.decode_kernel}")
             # what the step's attention read: the cache rows of the live
@@ -751,7 +925,9 @@ class ServeEngine:
                 "serve_decode", t0, t1,
                 rows=int(np.sum(lens)), slots=int(np.count_nonzero(lens)), **moe,
             )
-        self._count_phases("decode", t0, cuts, tr)
+        self._count_phases(
+            "decode", ((t0, t_args), (t_args, t_dispatch), (t_fetch, t_fetched)), tr
+        )
         return tok, logits
 
     def _propose_draft(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:

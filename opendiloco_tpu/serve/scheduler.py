@@ -4,7 +4,11 @@ One daemon thread owns the engine and runs the classic continuous-
 batching cycle — retire finished sequences (slots free immediately),
 admit queued prompts into free slots (prefill joins them to the running
 batch), take one decode step for every live slot, and between decode
-steps give the engine a chance to hot-swap weights. Requests are queued
+steps give the engine a chance to hot-swap weights. An iteration's programs
+are all enqueued before the host reads any of them: a cold admission leaves
+its first token on the device, the step behind it takes it there, and the
+reads follow in the order the chip finishes them (each first token, stamped
+``t_first`` as it lands, then the step's tokens). Requests are queued
 by any thread via :meth:`ContinuousBatcher.submit` and signal completion
 through a per-request event; nothing is ever dropped by the scheduler —
 a request either completes, is rejected at submit time (prompt too long
@@ -24,7 +28,7 @@ import numpy as np
 from opendiloco_tpu import obs
 from opendiloco_tpu.models.ring_cache import ring_live_rows
 from opendiloco_tpu.obs import reqtrace
-from opendiloco_tpu.serve.engine import ServeEngine
+from opendiloco_tpu.serve.engine import Admission, ServeEngine
 from opendiloco_tpu.serve.kvcache import (
     HostKVTier,
     SlotAllocator,
@@ -98,6 +102,10 @@ class _Slot:
     # decode steps since this tenancy began (admit or tier restore): the
     # eviction policy's coldness signal AND its thrash guard
     resident_steps: int = 0
+    # a cold admission whose first token is still on the device: the next
+    # decode step feeds it there, and ``last_token`` is not one until then
+    admission: Optional[Admission] = None
+    t_slot: float = 0.0  # when the request got its slot
 
 
 @dataclasses.dataclass
@@ -169,6 +177,9 @@ class ContinuousBatcher:
         self._kernel_probed = obs.tracer() is None
         self.slots = SlotAllocator(engine.num_slots)
         self._active: dict[int, _Slot] = {}  # slot id -> state
+        # slots admitted since the last decode step whose first token that
+        # step will feed on the device (loop thread only)
+        self._awaiting: list[int] = []
         self._queue: collections.deque[Request] = collections.deque()
         self._cond = threading.Condition()
         self._stop = threading.Event()
@@ -621,13 +632,24 @@ class ContinuousBatcher:
         return admitted
 
     def _admit_into(self, slot: int, req: Request) -> None:
-        rt = reqtrace.ring()
-        t_slot = time.perf_counter()
+        """One admission path, whose first token is read at once or after the
+        next decode step is enqueued, by the kind of admission and of step: a
+        cold prefill's token stays on the device, where the step takes it,
+        unless nothing will step it there (speculation drafts from tokens on
+        the host; a request of one token needs no step); a continued prefill
+        (live prefix, host tier) reads as it always did."""
+        st = _Slot(
+            req=req, cache_len=len(req.prompt), last_token=0,
+            t_slot=time.perf_counter(),
+        )
         src, plen, host = None, 0, None
         if self.prefix_cache:
             src, plen = self._find_prefix(req.prompt)
             if src is None and self.kv_tier is not None:
                 host, plen = self._host_prefix_lookup(req.prompt)
+        # in the batch before a program can raise: the loop's failure handler
+        # then fails this request with the others
+        self._active[slot] = st
         if src is not None:
             tok, _ = self.engine.admit(
                 slot, req.prompt, prefix_src=src, prefix_len=plen
@@ -645,26 +667,39 @@ class ContinuousBatcher:
             obs.count("serve_host_prefix_hits")
             obs.count("serve_prefix_tokens_saved", plen)
         else:
-            tok, _ = self.engine.admit(slot, req.prompt)
+            adm = self.engine.admit_enqueue(slot, req.prompt)
             self._maybe_store_prefix(slot, req.prompt)
-        req.t_first = time.perf_counter()
+            if not self.spec_decode and req.max_new_tokens > 1:
+                st.admission = adm
+                self._awaiting.append(slot)
+                return
+            tok = self.engine.admit_resolve(adm)
+        self._first_token(slot, st, tok, time.perf_counter(), plen)
+
+    def _first_token(
+        self, slot: int, st: _Slot, tok: int, t_first: float, prefix_reused: int = 0
+    ) -> None:
+        """``st``'s first token has reached the host at ``t_first``: stamp and
+        append it; a request that it ends retires here, and its slot is free."""
+        req = st.req
+        req.t_first = t_first
+        rt = reqtrace.ring()
         if rt is not None and req.trace is not None:
             rt.span(
-                req.trace, "queue", req.t_submit, t_slot, slot=slot
+                req.trace, "queue", req.t_submit, st.t_slot, slot=slot
             )
             rt.span(
-                req.trace, "prefill", t_slot, req.t_first,
+                req.trace, "prefill", st.t_slot, req.t_first,
                 tokens=len(req.prompt),
                 bucket=pick_bucket(len(req.prompt), self.engine.prefill_buckets),
-                prefix_reused=plen,
+                prefix_reused=prefix_reused,
             )
         req.tokens.append(tok)
-        st = _Slot(req=req, cache_len=len(req.prompt), last_token=tok)
+        st.last_token, st.admission = tok, None
         if self._finished(st):
+            del self._active[slot]
             self._retire(st)
             self.slots.free(slot)
-        else:
-            self._active[slot] = st
 
     # -- KV tiering (evict / restore / host prefix store) --------------------
 
@@ -818,6 +853,14 @@ class ContinuousBatcher:
         next_tokens, _ = self.engine.decode_step(tokens, lens)
         step_t0, step_t1 = self.engine.decode_bounds
         self.staleness_hist[self.engine.staleness()] += 1
+        # the step fed this iteration's admissions their first tokens on the
+        # device and read them before its own: each is stamped with the
+        # instant it reached the host. A request its first token ends is
+        # gone here, and the row the step computed for its slot is dropped
+        for slot in self._awaiting:
+            st = self._active[slot]
+            self._first_token(slot, st, st.admission.token, st.admission.t_token)
+        self._awaiting.clear()
         obs.count("serve_tokens_generated", len(self._active))
         batch = len(self._active)
         done_slots = []
@@ -1102,6 +1145,9 @@ class ContinuousBatcher:
                 for stage, phases in self.engine.phase_seconds.items()
             },
             "phase_calls": dict(self.engine.phase_calls),
+            # of the cold admissions in phase_calls["prefill"], those whose
+            # first token a decode step took on the device
+            "admissions_deferred": self.engine.admissions_deferred,
             "spec": {
                 "proposed": self.spec_proposed,
                 "accepted": self.spec_accepted,

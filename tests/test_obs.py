@@ -791,6 +791,100 @@ def test_stats_carry_the_phases(tiny_cfg):
     json.dumps(stats)  # what GET /stats serves
 
 
+# -- an admission that does not block (ISSUE 38): its spans, where its read falls --
+
+
+def _inside(child, parent):
+    return (parent["t0"] - _STAMP_EPS <= child["t0"]
+            and child["t1"] <= parent["t1"] + _STAMP_EPS)
+
+
+@pytest.mark.parametrize("kind, attribute", [
+    ("dense", None), ("routed", "moe_pairs"), ("hybrid", "ssm_tokens"),
+    ("latent", "latent_rows"), ("cca", "cca_tokens"),
+])
+def test_a_deferred_admissions_span_starts_at_its_enqueue(tiny_cfg, kind, attribute):
+    import test_serve_deferred_admit as deferred
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine = deferred._engine(*deferred.KINDS[kind](tiny_cfg))
+    prompts = [[5, 6, 7], [9, 8, 7, 6, 5], [3, 4, 5, 6, 7, 8, 9]]  # told apart by length
+    obs.capture.start()
+    batcher = ContinuousBatcher(engine)
+    reqs = [batcher.submit(p, max_new_tokens=4) for p in prompts]
+    batcher.start()  # three in the queue: one iteration admits them all
+    try:
+        for r in reqs:
+            assert r.wait(300) and r.error is None
+    finally:
+        batcher.stop()
+        cap = obs.capture.stop()
+    prefills = sorted(_named(cap, "serve_prefill"), key=lambda s: s["t0"])
+    assert [p["args"]["tokens"] for p in prefills] == [3, 5, 7]
+    assert attribute is None or all(p["args"][attribute] > 0 for p in prefills)
+    phases = {
+        name: sorted((s for s in _named(cap, name) if s["args"] == {"stage": "prefill"}),
+                     key=lambda s: s["t0"])
+        for name in _PHASE_SPANS
+    }
+    steps = sorted(_named(cap, "serve_decode"), key=lambda s: s["t0"])
+    step_phases = {name: min((s for s in _named(cap, name) if s["args"] == {"stage": "decode"}),
+                             key=lambda s: s["t0"]) for name in _PHASE_SPANS}
+    # all three were enqueued, and the step behind them, before anything was read
+    assert prefills[-1]["t1"] <= steps[0]["t0"] + _STAMP_EPS
+    read_end = step_phases["serve_dispatch"]["t1"]
+    for p, args, dispatch, fetch, req in zip(prefills, *phases.values(), reqs):
+        # the span is the enqueue: it starts there and its two phases tile it
+        assert args["t0"] == pytest.approx(p["t0"], abs=_STAMP_EPS)
+        assert dispatch["t0"] == pytest.approx(args["t1"], abs=_STAMP_EPS)
+        assert dispatch["t1"] == pytest.approx(p["t1"], abs=_STAMP_EPS)
+        # its read is a wait inside the step, after the step's dispatch, each
+        # where the one before it ended, and before the step's own read
+        assert _inside(fetch, steps[0])
+        assert fetch["t0"] >= read_end - _STAMP_EPS
+        assert fetch["t1"] <= step_phases["serve_fetch"]["t0"] + _STAMP_EPS
+        # the first token's stamp is the instant that read returned
+        assert req.t_first == pytest.approx(fetch["t1"], abs=_STAMP_EPS)
+        assert dispatch["t1"] <= req.t_first <= step_phases["serve_fetch"]["t1"]
+        read_end = fetch["t1"]
+    assert _inside(step_phases["serve_fetch"], steps[0])
+    iterations = _named(cap, "serve_iteration")
+    assert all(any(_inside(s, i) for i in iterations) for s in prefills + steps)
+
+
+def test_counters_of_deferred_admissions_grow_as_with_blocking_calls(tiny_cfg):
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine, blocking = _tiny_engine(tiny_cfg), _tiny_engine(tiny_cfg)
+    prompts = [[5, 6, 7], [9, 8, 7, 6, 5], [3, 4, 5, 6, 7, 8, 9], [2, 3]]
+    batcher = ContinuousBatcher(engine)
+    reqs = [batcher.submit(p, max_new_tokens=4) for p in prompts]
+    batcher.start()
+    try:
+        for r in reqs:
+            assert r.wait(300) and r.error is None
+    finally:
+        batcher.stop()
+    # the same four requests by the blocking calls, batched as the loop batched them
+    tokens, lens = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    for slot, prompt in enumerate(prompts):
+        tokens[slot], _ = blocking.admit(slot, prompt)
+        lens[slot] = len(prompt)
+    for _ in range(3):
+        tokens, _ = blocking.decode_step(tokens, lens)
+        lens = lens + 1
+    assert engine.phase_calls == blocking.phase_calls == {"prefill": 4, "decode": 3}
+    assert engine.admissions_deferred == 4 and blocking.admissions_deferred == 0
+    for stage in ("prefill", "decode"):
+        assert all(v > 0 for v in engine.phase_seconds[stage].values())
+        # a stage's seconds hold its phases and never another stage's read
+        assert sum(engine.phase_seconds[stage].values()) <= engine.stage_seconds[stage]
+    assert sum(engine.stage_seconds.values()) <= batcher.loop_seconds
+    stats = batcher.stats()
+    assert stats["admissions_deferred"] == 4 == stats["phase_calls"]["prefill"]
+    json.dumps(stats)
+
+
 def test_the_last_capture_stays_readable():
     assert obs.capture.last() is None
     obs.capture.start()

@@ -444,6 +444,51 @@ def test_insert_does_not_relay_the_cache(chip, cell):
         assert not moved, (bucket, moved)
 
 
+def test_the_engines_own_programs_move_no_cache_either(chip, monkeypatch):
+    """What the engine jits at the batch cell's shapes (``serving_programs``,
+    ISSUE 38): the decode step that takes a fresh slot's token from the
+    first-token vector, and the admission's insert that writes it there
+    beside the prompt's rows. The select and the scalar write cost no copy:
+    both caches alias, nothing cache- or page-shaped is moved, and the vector
+    goes back in place."""
+    from opendiloco_tpu.serve.engine import serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, params, cache = _serving_shapes(chip, "smollm2-360m")
+    _, decode, admit_insert, carried = serving_programs(
+        cfg, compute_dtype=BF16, decode_kernel="pallas"
+    )
+    assert carried == 2
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(decode, donate_argnums=(4, 5))
+        .lower(params, vec, vec, vec, cache, cache).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "tpu_custom_call" in text
+    assert mem.alias_size_in_bytes >= 2 * 2 * cache.size  # both caches, in place
+    assert not _cache_shaped_results(text, cache.shape)
+    layer_pages_bytes = 2 * 2 * cache.size // cache.shape[0]
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    tok = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    for bucket in engine["prefill_buckets"]:
+        rows = jax.ShapeDtypeStruct(
+            (cfg.num_hidden_layers, bucket, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip
+        )
+        compiled = (
+            jax.jit(admit_insert, donate_argnums=(0, 1, 2))
+            .lower(cache, cache, vec, rows, rows, tok, slot).compile()
+        )
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 2 * 2 * cache.size + 4 * vec.size
+        assert mem.temp_size_in_bytes < layer_pages_bytes, (bucket, mem.temp_size_in_bytes)
+        moved = [
+            line for line in _cache_shaped_results(compiled.as_text(), cache.shape)
+            if "dynamic-update-slice" not in line
+        ]
+        assert not moved, (bucket, moved)
+
+
 # ---------------------------------------------------------------------------
 # the granite-4.0-h cell (ISSUE 30): the prefill at its largest bucket and the
 # decode step at its slots, published widths, ten layers; they fit the chip
