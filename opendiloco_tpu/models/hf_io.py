@@ -61,6 +61,15 @@ def load_config(path_model: str) -> LlamaConfig:
 
 
 def _reject_moe(cfg: LlamaConfig, op: str) -> None:
+    if cfg.eva or cfg.num_pred_heads > 1 or cfg.norm_add_unit_offset or cfg.fp32_skip_add:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no EVA attention (adaptive_phi, adaptive_mu_k), no head of "
+            "several vocabularies, no norm under 1 + w and no float32 residual "
+            "stream, and HF's evabyte layout is not mapped here. Such models "
+            "train, serve and checkpoint through the framework checkpointer "
+            "(opendiloco_tpu.ckpt); only this import/export is refused"
+        )
     if cfg.cca or cfg.router_hidden_size or cfg.residual_scaling:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from opendiloco_tpu.models import mamba
 from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     cache_insert,
+    eva_window_rows,
     init_kv_cache,
     prefix_copy,
     spec_cache_insert,
@@ -36,12 +37,17 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
 )
 from opendiloco_tpu.ops.attention import (
     decode_step_attention,
+    eva_attention,
+    eva_decode_step_attention,
+    eva_pool,
     latent_decode_step_attention,
     spec_tail_attention,
     xla_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     W4_BLOCK,
+    eva_decode_attention,
+    eva_prefill_attention,
     mla_decode_attention,
     paged_decode_attention,
     spec_tail_attention_fused,
@@ -158,6 +164,31 @@ class LlamaConfig:
     cca_time1: int = 0
     router_hidden_size: int = 0
     residual_scaling: bool = False
+    # The block of a published ``evabyte`` ``config.json`` (EvaByte; its
+    # attention is EVA, arXiv 2302.04542): ``attention_class`` "eva" cuts the
+    # positions into windows of ``window_size`` and chunks of ``chunk_size``.
+    # A query reads the rows of its own window exactly (causally, and never
+    # the window before's) and, under the same softmax, one pooled key and
+    # value per chunk of every earlier window: the chunk's keys under a
+    # softmax of their scores against a learned vector per head
+    # (``adaptive_phi``), a learned offset added (``adaptive_mu_k``), the
+    # values under the same weights (``ops.attention.eva_pool``). So a slot's
+    # past is two rings of two lifetimes (``ring_cache``): ``window_size`` rows
+    # that restart at a window's edge, and a pooled row per ``chunk_size``
+    # positions. ``num_pred_heads`` > 1 widens the head to that many
+    # vocabularies side by side, head i predicting the token i + 1 ahead
+    # (head 0 is the one sampled); ``norm_add_unit_offset`` scales every
+    # RMSNorm by 1 + w; ``fp32_skip_add`` carries the residual stream in
+    # float32 from the embedding to the final norm, the branches in the
+    # compute dtype; ``fp32_logits`` accumulates the head's matmul in float32
+    # (False: in the compute dtype, widened after)
+    attention_class: str = "mha"
+    chunk_size: int = 0
+    window_size: int = 0
+    num_pred_heads: int = 1
+    norm_add_unit_offset: bool = False
+    fp32_skip_add: bool = False
+    fp32_logits: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -187,6 +218,36 @@ class LlamaConfig:
                     "whose query heads divide over the KV heads: no latent "
                     "attention, no layer_types, no qk_norm, no 'nope'"
                 )
+        if self.attention_class not in ("mha", "eva"):
+            raise ValueError(
+                f"attention_class {self.attention_class!r}: 'mha' (every past token "
+                "a row) or 'eva' (a window of rows, pooled chunks before it)"
+            )
+        if self.eva:
+            if not (
+                self.chunk_size > 0 and self.window_size > 0
+                and self.window_size % self.chunk_size == 0
+            ):
+                raise ValueError(
+                    "EVA attention needs a chunk_size and a window_size of whole "
+                    f"chunks; got chunk_size {self.chunk_size}, window_size "
+                    f"{self.window_size}"
+                )
+            if (
+                self.latent or self.cca or self.layer_types is not None or self.qk_norm
+                or self.num_attention_heads % self.kv_heads
+                or self.position_embedding_type != "rope"
+            ):
+                raise ValueError(
+                    "EVA attention is written for a stack of like rotated attention "
+                    "layers whose query heads divide over the KV heads: no latent "
+                    "attention, no CCA, no layer_types, no qk_norm, no 'nope'"
+                )
+        if self.num_pred_heads < 1 or (self.num_pred_heads > 1 and self.tie_word_embeddings):
+            raise ValueError(
+                f"num_pred_heads {self.num_pred_heads}: at least one head, and more "
+                "than one only over an untied head (the embedding has one vocabulary)"
+            )
         if self.router_hidden_size and not (
             self.num_experts and self.topk_method == "greedy"
         ):
@@ -345,6 +406,17 @@ class LlamaConfig:
         return self.cca_time0 > 0
 
     @property
+    def eva(self) -> bool:
+        """Is the attention EVA (a window of rows read exactly, every earlier
+        window as pooled chunks: two rings a slot)?"""
+        return self.attention_class == "eva"
+
+    @property
+    def eva_chunks_per_window(self) -> int:
+        """Pooled rows that stand for one whole window."""
+        return self.window_size // self.chunk_size
+
+    @property
     def cca_state_dim(self) -> int:
         """Values a CCA layer keeps of a slot's last token: q and k before the
         convolutions, the same between the two, and the values the next token
@@ -411,6 +483,16 @@ class LlamaConfig:
             known.setdefault("router_aux_loss_coef", 0.0)
             # the FFN is the routed one alone: ``intermediate_size`` is no key
             known.setdefault("intermediate_size", raw.get("moe_intermediate_size", 0))
+        if raw.get("model_type") == "evabyte":
+            # the published draw's scale is ``init_std``; what the block is
+            # not written for is refused by name and never read past
+            for key, want in (("attention_class", "eva"), ("attention_bias", False),
+                              ("rope_scaling", None), ("hidden_act", "silu")):
+                if raw.get(key, want) != want:
+                    raise ValueError(
+                        f"an evabyte stack is written for {key} {want!r}; got {raw[key]!r}"
+                    )
+            known.setdefault("initializer_range", raw.get("init_std", cls.initializer_range))
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
@@ -442,6 +524,11 @@ class LlamaConfig:
                 architectures=["ZayaForCausalLM"],
                 model_type="zaya",
                 layer_types=["hybrid"] * self.num_hidden_layers,
+            )
+        if self.eva:
+            d.update(
+                architectures=["EvaByteForCausalLM"], model_type="evabyte",
+                init_std=self.initializer_range,
             )
         return d
 
@@ -515,6 +602,8 @@ def shapes(cfg: LlamaConfig) -> dict:
         }
     if cfg.qk_norm:
         attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
+    if cfg.eva:  # the pooling's learned query and the pooled key's offset, per head
+        attention.update(adaptive_phi=(Nkv, Dh), adaptive_mu_k=(Nkv, Dh))
     if cfg.cca:
         Z = (Nh + Nkv) * Dh  # q and k side by side
         attention.update(
@@ -553,7 +642,8 @@ def shapes(cfg: LlamaConfig) -> dict:
         layers = stack(L, norms, attention, ffn)
     tree = {"embed_tokens": s(V, D), "layers": layers, "final_norm": s(D)}
     if not cfg.tie_word_embeddings:
-        tree["lm_head"] = s(D, V)
+        # ``num_pred_heads`` vocabularies side by side, head-major
+        tree["lm_head"] = s(D, V * cfg.num_pred_heads)
     return tree
 
 
@@ -667,8 +757,17 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
     out = []
     for key, (path, leaf) in zip(keys, leaves):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if "norm" in name or name == "D":
+        if "norm" in name and cfg.norm_add_unit_offset:
+            # the norm scales by 1 + w: w about zero, and away from it, so that
+            # the offset is tested
+            out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * 0.02)
+        elif "norm" in name or name == "D":
             out.append(jnp.ones(leaf.shape, leaf.dtype))
+        elif name in ("adaptive_phi", "adaptive_mu_k"):
+            # a unit-scale vector per head, each value within +-1/sqrt(Dh): the
+            # pooling's scores phi . k then spread about as k's own norm does
+            noise = jnp.clip(jax.random.normal(key, leaf.shape, leaf.dtype), -1.0, 1.0)
+            out.append(noise * leaf.shape[-1] ** -0.5)
         elif name == "router_bias":
             # a trained router's selection bias is not zero, and zero would
             # leave the term untested: N(0, 0.1^2), beside sigmoid scores in
@@ -777,12 +876,24 @@ def _maybe_remat(block, remat: RematPolicy):
     raise ValueError(f"unknown remat policy {remat!r}")
 
 
-def _rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    # variance in float32 for stability (HF llama semantics)
+def _rms_norm(x: jax.Array, weight: jax.Array, eps: float, unit_offset: bool = False) -> jax.Array:
+    # variance in float32 for stability (HF llama semantics); with
+    # ``unit_offset`` the scale is 1 + weight (``norm_add_unit_offset``)
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     xf = xf * jax.lax.rsqrt(var + eps)
-    return (xf * weight.astype(jnp.float32)).astype(x.dtype)
+    scale = weight.astype(jnp.float32)
+    if unit_offset:
+        scale = 1.0 + scale
+    return (xf * scale).astype(x.dtype)
+
+
+def _block_norm(cfg: LlamaConfig, h: jax.Array, weight: jax.Array) -> jax.Array:
+    """A sublayer's (or the head's) RMSNorm of the stream h, handed on in the
+    weights' dtype: the compute dtype, which is h's own but under
+    ``fp32_skip_add``, where the stream is float32 and its branches are not."""
+    x = _rms_norm(h, weight, cfg.rms_norm_eps, cfg.norm_add_unit_offset)
+    return x.astype(weight.dtype) if cfg.fp32_skip_add else x
 
 
 def _rope_tables(
@@ -987,6 +1098,47 @@ def refuse_latent(cfg: LlamaConfig, what: str) -> None:
             "(k, v) rows of one head size, and the latent ring holds one row of "
             f"{cfg.latent_row_dim} values a token, from which k and v are not rebuilt"
         )
+
+
+def refuse_eva(cfg: LlamaConfig, what: str) -> None:
+    """``what`` takes a slot's ring for its context: rows that can be copied,
+    cut, paged out or extended by a tail. Under EVA attention the ring is one
+    window that restarts, and the past before it is pooled rows and the
+    pooling under way."""
+    if cfg.eva:
+        raise ValueError(
+            f"{what} is refused for a configuration with EVA attention "
+            f"(attention_class 'eva', window_size {cfg.window_size}, chunk_size "
+            f"{cfg.chunk_size}): it handles a slot's past as the rows of one ring, "
+            "and EVA keeps a window of rows that restarts beside a ring of pooled "
+            "chunks and the pooling of the chunk under way, which it neither "
+            "copies nor could un-pool"
+        )
+
+
+def eva_attend(cfg: LlamaConfig, length=None, kept: Optional[list] = None, prefill: bool = False):
+    """The ``attend(q, k, v, adaptive_phi, adaptive_mu_k)`` of EVA over a whole
+    sequence from position 0 (training, prefill): the chunks pooled (scope
+    ``odtp_eva``), then each query over its window's rows and the pooled rows
+    of the windows before. ``length`` (traced): the sequence's true length
+    under a bucket's padding, which then enters no chunk. A caller that keeps
+    the pooling passes a list as ``kept``: (kbar, vbar [B, J, Kh, D], stats
+    [B, J, Kh, 2 D + 2]) are appended to it. ``prefill`` (a serving prefill:
+    no gradient) takes ``eva_prefill_attention``, which on the chip runs each
+    window's own rows through the flash kernel and merges the pooled rows in
+    (``decode_kernels.eva_prefill_form``: by the platform and the tiling)."""
+    over_windows = eva_prefill_attention if prefill else eva_attention
+
+    def attend(q, k, v, phi, mu):
+        with jax.named_scope("odtp_eva"):
+            kbar, vbar, stats = eva_pool(k, v, phi, mu, cfg.chunk_size, length)
+        if kept is not None:
+            kept.extend((kbar, vbar, stats))
+        return over_windows(
+            q, k, v, kbar, vbar, window=cfg.window_size, chunk=cfg.chunk_size
+        )
+
+    return attend
 
 
 def _grouped_matmul(xs: jax.Array, w, sizes: jax.Array) -> jax.Array:
@@ -1216,7 +1368,11 @@ def decoder_block(
     routed FFN counts. Latent attention enters the same way: the projection
     returns q and the tokens' latent rows, and the caller's ``attend(q, rows,
     kv_b_proj)`` is its attention over them, in the rebuilt form or the
-    absorbed one -> [B, T, Nh, v]. CCA enters as attention does too, behind a
+    absorbed one -> [B, T, Nh, v]. EVA enters as attention too: the caller's
+    ``attend(q, k, v, adaptive_phi, adaptive_mu_k)`` pools k and v by chunk
+    under the layer's two vectors and attends over the query's window and the
+    pooled rows before it (``ops.attention.eva_attention``, or a decode step
+    over the slot's two rings). CCA enters as attention does too, behind a
     projection that reads the token before: ``past`` [B, cca_state_dim] is
     what that token left (a decode step's slot state; None at a sequence's
     start), and ``BlockOut.tails`` what each position leaves. ``router_in``
@@ -1254,9 +1410,11 @@ def decoder_block(
             attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
     elif mix is None:
         with jax.named_scope("odtp_attention"):
-            x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
+            x = _block_norm(cfg, h, layer["input_norm"])
             q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
-            attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
+            # EVA's attend also pools k and v: under the layer's two vectors
+            pool = (layer["adaptive_phi"], layer["adaptive_mu_k"]) if cfg.eva else ()
+            attn_out = mul(attend(q, k, v, *pool).reshape(B, T, -1), layer["o_proj"])
     else:
         k = v = None
         with jax.named_scope("odtp_ssm"):
@@ -1264,7 +1422,7 @@ def decoder_block(
             attn_out = mix(x, layer)
     h = residual(h, attn_out, "attn")
     with jax.named_scope("odtp_mlp"):
-        x = _rms_norm(h, layer["post_attn_norm"], cfg.rms_norm_eps)
+        x = _block_norm(cfg, h, layer["post_attn_norm"])
         if "router_down" in layer:
             with jax.named_scope("odtp_router"):
                 features, router_out = _router_features(
@@ -1294,6 +1452,8 @@ def training_block(
         mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer, jnp.matmul)[0]
     elif cfg.latent:  # the rebuilt form: multi-head attention over k and v
         attend = rebuilt_attend(cfg, attn_fn)
+    elif cfg.eva:  # its own attention over the sequence: ``attn_fn`` is not asked
+        attend = eva_attend(cfg)
 
     def body(carry, layer, li=None):
         h, r = carry
@@ -1321,7 +1481,8 @@ def _embed(cfg: LlamaConfig, cparams: dict, ids: jax.Array) -> jax.Array:
     h = jnp.take(cparams["embed_tokens"], ids, axis=0)
     if cfg.embedding_multiplier != 1.0:
         h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
-    return h
+    # ``fp32_skip_add``: the stream is float32 from here to the final norm
+    return h.astype(jnp.float32) if cfg.fp32_skip_add else h
 
 
 def _final_norm_and_head(
@@ -1330,7 +1491,7 @@ def _final_norm_and_head(
     """-> (h under the final RMSNorm and divided by the configuration's
     ``logits_scaling``, the head [D, V]: the embedding's transpose where the
     configuration ties them)."""
-    h = _rms_norm(h, cparams["final_norm"], cfg.rms_norm_eps)
+    h = _block_norm(cfg, h, cparams["final_norm"])
     if cfg.logits_scaling != 1.0:  # the logits are h @ head wherever taken
         h = h / jnp.asarray(cfg.logits_scaling, h.dtype)
     head = (
@@ -1339,6 +1500,15 @@ def _final_norm_and_head(
         else cparams["lm_head"]
     )
     return h, head
+
+
+def _head_matmul(cfg: LlamaConfig, h: jax.Array, head: jax.Array) -> jax.Array:
+    """Float32 logits [..., V * num_pred_heads] (head i's vocabulary in columns
+    [i V, (i + 1) V)): accumulated in float32 under ``fp32_logits``, else
+    computed in h's dtype and widened."""
+    if cfg.fp32_logits:
+        return jnp.matmul(h, head, preferred_element_type=jnp.float32)
+    return (h @ head).astype(jnp.float32)
 
 
 def forward(
@@ -1389,6 +1559,10 @@ def forward(
             f"attention (heads of {cfg.qk_head_dim}); the flash and ring kernels "
             "have not been run at that head size"
         )
+    if attn_impl != "xla":
+        refuse_eva(cfg, f"attn_impl={attn_impl!r} (a kernel of causal attention over one run of rows)")
+    if pp_mesh is not None:
+        refuse_eva(cfg, "the pp pipeline (its stages' attention is the caller's attn_fn over rows)")
     if attn_impl == "xla":
         attn_fn = lambda q, k, v: xla_attention(q, k, v, causal=True)
     elif attn_impl == "pallas":
@@ -1481,7 +1655,7 @@ def forward(
         # the router aux loss (trainer._loss_fn)
         return (h, head, moe_aux) if return_moe_aux else (h, head)
     with jax.named_scope("odtp_lm_head_loss"):
-        logits = (h @ head).astype(jnp.float32)
+        logits = _head_matmul(cfg, h, head)
     if return_aux:
         aux = {
             "attn_out_norm": attn_norms,
@@ -1616,7 +1790,7 @@ def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
 def _logits(cfg: LlamaConfig, cparams: dict, h: jax.Array) -> jax.Array:
     """Final norm and lm head over h [..., D] -> float32 logits [..., V]."""
     h, head = _final_norm_and_head(cfg, cparams, h)
-    return (h @ head).astype(jnp.float32)
+    return _head_matmul(cfg, h, head)
 
 
 def _stacked(parts: list) -> Optional[jax.Array]:
@@ -1644,6 +1818,12 @@ def prefill_forward(
     latent rows [La, P, R + rope] in K's place and None in V's); for CCA
     then the layers' state [L, cca_state_dim] as the last real token left it
     (``_cca_qkv``: a bucket's padding rows do not reach it); for a
+    EVA then the prompt's chunks pooled [L, ceil(P / chunk), Nkv, Dh] (keys,
+    then values; of these the ones that ended before ``length`` are final) and
+    the pooling under way of the chunk that ``length`` lies in [L, Nkv, 2 Dh +
+    2] float32 (``ops.attention.eva_pool``: a bucket's padding rows enter no
+    chunk), and the K/V are then the rows of the prompt's last window alone
+    [L, min(P, window_size), Nkv, Dh] (``ring_cache.eva_window_rows``); for a
     hybrid stack then the Mamba-2 layers' recurrent states
     [Lm, H, P, N] float32 and conv tails [Lm, K - 1, C], as the last real
     token left them; and with ``return_moe_counts`` last the routed FFN's
@@ -1669,13 +1849,24 @@ def prefill_forward(
 
     def attention_body(carry, layer, li):
         h, r = carry
+        pooling: list = []  # EVA: the chunks pooled, and each chunk's pooling as stats
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, attend=attend, live=live, router_in=r
+            cfg, h, layer, cos, sin, mul=mul, live=live, router_in=r,
+            attend=eva_attend(cfg, length, pooling, prefill=True) if cfg.eva else attend,
         )
         kept = [out.k[0], None if out.v is None else out.v[0]]
         if cfg.cca:  # what the prompt's last token leaves the first decode step
             with jax.named_scope("odtp_cca"):
                 kept.append(jax.lax.dynamic_index_in_dim(out.tails[0], length - 1, 0, False))
+        if cfg.eva:
+            # of the rows those of the prompt's last window (what a slot's ring
+            # takes), and of the stats those of the chunk the prompt ends in
+            with jax.named_scope("odtp_eva"):
+                kept = [eva_window_rows(x, length, cfg.window_size) for x in kept]
+                kbar, vbar, stats = pooling
+                kept += [kbar[0], vbar[0], jax.lax.dynamic_index_in_dim(
+                    stats[0], length // cfg.chunk_size, 0, False
+                )]
         return (h, out.router), (*kept, (out.counts, out.experts))
 
     def mamba_body(carry, layer, li):
@@ -1694,7 +1885,7 @@ def prefill_forward(
 
     h = _embed(cfg, cparams, input_ids)
     r = router_carry(cfg, h)
-    kept = {"attention": ([], [], []), "mamba": ([], [])}
+    kept = {"attention": ([], [], [], [], []), "mamba": ([], [])}
     counts, experts = [], []
     for run in layer_runs(cfg):
         body = attention_body if run.mixer == "attention" else mamba_body
@@ -1710,6 +1901,8 @@ def prefill_forward(
     out = [logits[:, 0], *map(_stacked, kept["attention"][:2])]
     if cfg.cca:
         out.append(_stacked(kept["attention"][2]))
+    if cfg.eva:
+        out.extend(map(_stacked, kept["attention"][2:]))
     if cfg.hybrid:
         out.extend(map(_stacked, kept["mamba"]))
     if return_moe_counts:
@@ -1747,6 +1940,7 @@ def decode_forward(
     ssm_state: Optional[jax.Array] = None,
     conv_state: Optional[jax.Array] = None,
     cca_state: Optional[jax.Array] = None,
+    eva_state: Optional[tuple] = None,
 ):
     """One incremental decode step over all S slots.
 
@@ -1783,6 +1977,15 @@ def decode_forward(
     carries it beside the caches, each layer reads its part and writes the
     step's own in its place, and it comes back after the caches.
 
+    EVA attention takes ``eva_state``, the slots' pooled ring and pooling
+    under way (``ring_cache.init_eva_state``: pool_k, pool_v, stats), and
+    ``cache_{k,v}`` are then rings of one window: the step's row goes to ring
+    row ``lens % window_size`` and rows [0, lens % window_size] are read, its
+    chunk as pooled so far to pooled row ``lens // chunk_size``, and the
+    pooled rows of the windows before are read under the same softmax
+    (``ops.decode_kernels.eva_decode_attention``). The scan carries the three
+    beside the caches and they come back after them; no program copies a ring.
+
     With ``return_moe_counts`` the routed FFN's counts over the slots that
     hold a sequence (``lens > 0``), summed over layers, come last, and with
     ``return_expert_choices`` after them each slot's experts in each layer [L,
@@ -1794,14 +1997,25 @@ def decode_forward(
     pallas = decode_kernel == "pallas"
     step_attention = paged_decode_attention if pallas else decode_step_attention
     latent_attention = mla_decode_attention if pallas else latent_decode_step_attention
+    eva_attention_step = eva_decode_attention if pallas else eva_decode_step_attention
+    eva_state = None if eva_state is None else tuple(eva_state)
 
     def attention_body(carry, layer, li):
-        h, r, ck, cv, tails = carry  # the whole caches, and every layer's tails
+        # the whole caches, every layer's tails, EVA's pooled ring and stats
+        h, r, ck, cv, tails, eva = carry
 
         def attend(q, k, v):
             nonlocal ck, cv
             out, ck, cv = step_attention(
                 q[:, 0], k[:, 0], v[:, 0], ck, cv, lens, li
+            )
+            return out
+
+        def over_two_rings(q, k, v, phi, mu):
+            nonlocal ck, cv, eva
+            out, ck, cv, *eva = eva_attention_step(
+                q[:, 0], k[:, 0], v[:, 0], phi, mu, ck, cv, *eva, lens, li,
+                window=cfg.window_size, chunk=cfg.chunk_size,
             )
             return out
 
@@ -1815,7 +2029,7 @@ def decode_forward(
 
         h, out = decoder_block(
             cfg, h, layer, cos, sin, mul=mul, live=live, router_in=r,
-            attend=absorbed if cfg.latent else attend,
+            attend=absorbed if cfg.latent else over_two_rings if cfg.eva else attend,
             past=None if tails is None else tails[li],
         )
         if tails is not None:
@@ -1823,7 +2037,8 @@ def decode_forward(
                 tails = jax.lax.dynamic_update_index_in_dim(
                     tails, out.tails[:, 0].astype(tails.dtype), li, 0
                 )
-        return (h, out.router, ck, cv, tails), (out.counts, out.experts)
+        eva = None if eva is None else tuple(eva)
+        return (h, out.router, ck, cv, tails, eva), (out.counts, out.experts)
 
     def mamba_body(carry, layer, li):
         h, r, states, tails = carry  # every Mamba-2 layer's
@@ -1847,8 +2062,8 @@ def decode_forward(
     counts, experts = [], []
     for run in layer_runs(cfg):
         if run.mixer == "attention":
-            (h, r, cache_k, cache_v, cca_state), (c, e) = scan_layers(
-                cfg, attention_body, (h, r, cache_k, cache_v, cca_state),
+            (h, r, cache_k, cache_v, cca_state, eva_state), (c, e) = scan_layers(
+                cfg, attention_body, (h, r, cache_k, cache_v, cca_state, eva_state),
                 cparams["layers"], run, experts_in_place=True,
             )
         else:
@@ -1862,6 +2077,8 @@ def decode_forward(
     out = [logits[:, 0], cache_k, cache_v]
     if cfg.cca:
         out.append(cca_state)
+    if cfg.eva:
+        out.extend(eva_state)
     if cfg.hybrid:
         out.extend((ssm_state, conv_state))
     if return_moe_counts:
@@ -1907,6 +2124,7 @@ def verify_forward(
     """
     refuse_recurrent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
     refuse_latent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
+    refuse_eva(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
     S, K = tail.shape
     cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
     positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
@@ -1950,6 +2168,7 @@ def draft_propose(
     """
     refuse_recurrent(cfg, "the self-speculative draft")
     refuse_latent(cfg, "the self-speculative draft")
+    refuse_eva(cfg, "the self-speculative draft")
     S = tokens.shape[0]
     L, Ld = cfg.num_hidden_layers, int(draft_layers)
     if not 1 <= Ld <= L:
@@ -1989,10 +2208,20 @@ def draft_propose(
 
 
 def causal_lm_loss(
-    logits: jax.Array, labels: jax.Array, ignore_index: int = -100
+    logits: jax.Array, labels: jax.Array, ignore_index: int = -100, pred_heads: int = 1
 ) -> jax.Array:
     """Shifted next-token cross-entropy, mean over non-ignored targets
-    (HF CausalLM loss semantics used by the reference drivers)."""
+    (HF CausalLM loss semantics used by the reference drivers). With
+    ``pred_heads`` > 1 the logits hold that many vocabularies side by side
+    (``LlamaConfig.num_pred_heads``) and head i is held to the token i + 1
+    ahead: the mean over the heads of each head's loss."""
+    if pred_heads > 1:
+        T = logits.shape[1]
+        by_head = logits.reshape(*logits.shape[:-1], pred_heads, -1)
+        return sum(
+            causal_lm_loss(by_head[:, : T - i, i], labels[:, i:], ignore_index)
+            for i in range(pred_heads)
+        ) / pred_heads
     shift_logits = logits[:, :-1]
     shift_labels = labels[:, 1:]
     mask = shift_labels != ignore_index

@@ -28,6 +28,24 @@ Two facts live here and nowhere else:
   them. Once ``len >= T`` the whole ring is live and attention slides over
   the last T tokens.
 
+EVA attention (``cfg.eva``) keeps **two rings of two lifetimes** a slot, and
+neither is the slot's context. The ``(k, v)`` ring above is ``window_size``
+rows and *restarts*: token ``p`` lives at row ``p % window_size`` and a reader
+takes rows ``[0, p % window_size]``, the tokens of p's own window up to p; it
+never slides over the window before. Beside it a **pooled ring**
+(:func:`init_eva_state`: ``pool_k``, ``pool_v``, same order of axes) holds
+one pooled key and value per ``chunk_size`` positions, chunk ``j`` at row ``j``
+for the sequence's whole life, of which a reader at ``p`` takes the first
+``(p // window_size) * (window_size // chunk_size)``: the chunks of the windows
+before p's own. Both lengths derive from the one position. A decode step
+writes row ``p // chunk_size`` at *every* position (the chunk as pooled so
+far, from the per-slot ``stats`` of the pooling under way): it is the chunk's
+own pooled row at the chunk's last position, and no reader reaches it before
+its window has ended. ``max_context`` bounds the positions and sizes the
+pooled ring alone. :func:`eva_insert` hands a prefill's current-window rows
+(:func:`eva_window_rows`), its pooled rows and the stats of the chunk the
+prompt ends in to a slot.
+
 Plain functions over the ``(k, v)`` pair; every writer returns the new pair
 and callers jit them with both donated, so an update is in place at HBM.
 
@@ -64,6 +82,8 @@ def init_kv_cache(
             cfg.num_attention_layers, num_slots, max_context, 1, cfg.latent_row_dim
         )
         return {"k": jnp.zeros(shape, dtype), "v": None}
+    if cfg.eva:  # the ring is a window, whatever the context
+        max_context = cfg.window_size
     shape = cache_shape(
         cfg.num_attention_layers, num_slots, max_context, cfg.kv_heads, cfg.head_dim
     )
@@ -113,6 +133,72 @@ def cca_state_insert(state: jax.Array, rows: jax.Array, slot: jax.Array) -> jax.
     stale part to mask, so a slot's next tenant starts from its own prompt."""
     start = (jnp.int32(0), jnp.asarray(slot, jnp.int32), jnp.int32(0))
     return jax.lax.dynamic_update_slice(state, rows[:, None].astype(state.dtype), start)
+
+
+def eva_pooled_rows(cfg, max_context: int) -> int:
+    """Rows of a slot's pooled ring: a pooled row per chunk of every window
+    that a context of ``max_context`` positions touches (whole windows, so
+    that the decode kernel's tiles, which are a window's pooled rows or a
+    divisor of them, cut the ring evenly)."""
+    return -(-int(max_context) // cfg.window_size) * cfg.eva_chunks_per_window
+
+
+def init_eva_state(cfg, num_slots: int, max_context: int, dtype: jnp.dtype = jnp.bfloat16) -> dict:
+    """Zeroed {"pool_k", "pool_v", "stats"} for EVA attention: the pooled ring
+    ([L, S, Nkv, Dh, :func:`eva_pooled_rows`] each, the ``(k, v)`` ring's order)
+    and, per layer, slot and KV head, the pooling under way of the chunk the
+    slot's position lies in ([L, S, Nkv, 2 Dh + 2] float32:
+    ``ops.attention.eva_pool``'s stats)."""
+    L, Nkv, Dh = cfg.num_hidden_layers, cfg.kv_heads, cfg.head_dim
+    shape = cache_shape(L, num_slots, eva_pooled_rows(cfg, max_context), Nkv, Dh)
+    return {
+        "pool_k": jnp.zeros(shape, dtype), "pool_v": jnp.zeros(shape, dtype),
+        "stats": jnp.zeros((L, num_slots, Nkv, 2 * Dh + 2), jnp.float32),
+    }
+
+
+def eva_window_rows(x: jax.Array, length, window: int) -> jax.Array:
+    """Of a prompt's rows x [P, ...] those of the window that ``length``
+    (traced) lies in, [min(P, window), ...]: positions [window * (length //
+    window), ...), which a slot's ring takes at row 0. Where the window runs
+    past P the rows beyond are zeros (a prompt that ends on a window's edge
+    hands over nothing that is read)."""
+    P = x.shape[0]
+    if P <= window:
+        return x
+    x = jnp.pad(x, ((0, -P % window), *((0, 0),) * (x.ndim - 1)))
+    first = jnp.asarray(length, jnp.int32) // window * window
+    return jax.lax.dynamic_slice_in_dim(x, first, window, axis=0)
+
+
+def eva_insert(
+    cache_k, cache_v, pool_k, pool_v, stats, ks, vs, pooled_ks, pooled_vs, chunk_stats, slot,
+):
+    """Hand a prefilled prompt to ``slot``: the K/V of its last window [L, R,
+    Nkv, Dh] (:func:`eva_window_rows`: the prompt's positions from its last
+    window's edge on) land at ring rows [0, R), of which those beyond the
+    prompt's length are stale and masked until the slot's own writes reach
+    them; its pooled rows [L, J, Nkv, Dh] at pooled rows [0, J) (those of
+    chunks that had not ended with the prompt are rewritten by the decode
+    steps before their window ends); and the pooling under way of the chunk
+    that the prompt ends in ([L, Nkv, 2 Dh + 2]) whole. -> the five, updated."""
+    for rows, ring, what in ((ks, cache_k, "window"), (pooled_ks, pool_k, "pooled")):
+        if rows.shape[1] > ring_rows(ring):
+            raise ValueError(
+                f"a prompt's {rows.shape[1]} {what} rows exceed the ring's {ring_rows(ring)}"
+            )
+    slot, zero = jnp.asarray(slot, jnp.int32), jnp.int32(0)
+
+    def put(cache, x):
+        x = _rows_minor(x)[:, None].astype(cache.dtype)
+        return jax.lax.dynamic_update_slice(cache, x, (zero, slot, zero, zero, zero))
+
+    stats = jax.lax.dynamic_update_slice(
+        stats, chunk_stats[:, None].astype(stats.dtype), (zero, slot, zero, zero)
+    )
+    return (
+        put(cache_k, ks), put(cache_v, vs), put(pool_k, pooled_ks), put(pool_v, pooled_vs), stats,
+    )
 
 
 def cache_shape(

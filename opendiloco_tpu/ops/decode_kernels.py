@@ -81,7 +81,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from opendiloco_tpu.models.ring_cache import ring_rows
 from opendiloco_tpu.ops.attention import (
+    _repeat_kv,
     decode_step_attention,
+    eva_accumulate,
+    eva_attention,
+    eva_decode_step_attention,
     latent_decode_step_attention,
     spec_tail_attention,
 )
@@ -205,14 +209,20 @@ def _lanes32(x):
 def _decode_attn_kernel(
     lens_ref, layer_ref, q_ref, kn_ref, vn_ref, knt_ref, vnt_ref, k_ref, v_ref,
     o_ref, ko_ref, vo_ref, *rest,
-    scale, block_t, t, num_t, rep, with_stats,
+    scale, block_t, t, num_t, rep, with_stats, eva_ring=None,
 ):
     # the stats block's index ignores the ring axis, so it stays resident
     # across ti and doubles as the counter: a vector add, since Mosaic
     # cannot store a scalar to VMEM
-    stats_ref, (q_scr, snew_scr, m_scr, l_scr, acc_scr) = (
-        (rest[0], rest[1:]) if with_stats else (None, rest)
-    )
+    rest = list(rest)
+    stats_ref = rest.pop(0) if with_stats else None
+    # one of EVA's two rings (``paged_decode_attention``'s ``eva_ring``): the
+    # softmax's running maximum and sum go out too, for the caller's merge of
+    # the two calls under one softmax; ``pooled`` rows a window of the pooled
+    # ring, 0 for the window's ring and for every other configuration
+    with_softmax, pooled = eva_ring is not None, eva_ring or 0
+    m_ref, l_ref = (rest.pop(0), rest.pop(0)) if with_softmax else (None, None)
+    q_scr, snew_scr, m_scr, l_scr, acc_scr = rest
     heads, d, _ = k_ref.shape  # the KV heads of this grid step
     # all of them under one pair of MXU calls: their tiles as one
     # [heads * d, bt] operand, their rep query rows each block-diagonal
@@ -247,22 +257,30 @@ def _decode_attn_kernel(
         tiled = jnp.concatenate([q_ref[:]] * heads, axis=1)
         q_bd = jnp.where(own_block(), tiled, jnp.zeros_like(tiled))
         q_scr[:] = q_bd
-        # the step's own scores, q . k_new: a row sum, once a slot
-        snew_scr[:] = scale * jnp.sum(
-            q_bd.astype(f32) * side_by_side(kn_ref).astype(f32),
-            axis=1, keepdims=True,
-        )
+        if not pooled:
+            # the step's own scores, q . k_new: a row sum, once a slot
+            snew_scr[:] = scale * jnp.sum(
+                q_bd.astype(f32) * side_by_side(kn_ref).astype(f32),
+                axis=1, keepdims=True,
+            )
 
     lens_s = lens_ref[si]
-    # valid cache entries are idx <= lens (whole ring once lens >= t), so
-    # blocks past min(lens, t-1) hold no live rows for this slot
-    last_live = jnp.minimum(lens_s, t - 1) // block_t
+    if pooled:
+        # EVA's pooled ring, ``pooled`` rows a window: row lens is where the
+        # step's row goes and is not read; the rows read are those of the
+        # windows before the one it lies in, whole tiles (block_t divides a
+        # window's rows), none of them for a slot in its first window
+        live_tiles = lens_s // pooled * (pooled // block_t)
+    else:
+        # valid cache entries are idx <= lens (whole ring once lens >= t), so
+        # blocks past min(lens, t-1) hold no live rows for this slot
+        last_live = jnp.minimum(lens_s, t - 1) // block_t
     # the step's own row goes to ring row lens % t, in a block that is
     # always live (it is the last live one until the ring wraps); new_at is
     # its lane in this tile, if it lies here
     new_at = jax.lax.rem(lens_s, t) - ti * block_t
 
-    @pl.when(ti <= last_live)
+    @pl.when(ti < live_tiles if pooled else ti <= last_live)
     def _step():
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, block_t), 1)
         valid = (ti * block_t + lane <= lens_s) | (lens_s >= t)
@@ -276,9 +294,10 @@ def _decode_attn_kernel(
             q_scr[:], k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=f32,
         )  # [rows, block_t]
-        s_new = snew_scr[:]
-        s = jnp.where(at_row, s_new, s)
-        s = jnp.where(valid, s, NEG_INF)
+        if not pooled:  # (a pooled tile that is read is live whole and unpatched)
+            s_new = snew_scr[:]
+            s = jnp.where(at_row, s_new, s)
+            s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -286,15 +305,16 @@ def _decode_attn_kernel(
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            jnp.where(at_row, 0.0, p).astype(v_blk.dtype), v_blk,
+            (p if pooled else jnp.where(at_row, 0.0, p)).astype(v_blk.dtype), v_blk,
             (((1,), (1,)), ((), ())), preferred_element_type=f32,
         )  # head j's values in columns j*d : (j+1)*d of its rows
 
-        @pl.when((new_at >= 0) & (new_at < block_t))
-        def _own_value():
-            # p[:, new_at] x v_new, rounded as the MXU's operand is
-            p_new = jnp.exp(s_new - m_new).astype(v_blk.dtype)
-            acc_scr[:] += p_new.astype(f32) * side_by_side(vn_ref).astype(f32)
+        if not pooled:
+            @pl.when((new_at >= 0) & (new_at < block_t))
+            def _own_value():
+                # p[:, new_at] x v_new, rounded as the MXU's operand is
+                p_new = jnp.exp(s_new - m_new).astype(v_blk.dtype)
+                acc_scr[:] += p_new.astype(f32) * side_by_side(vn_ref).astype(f32)
 
         if with_stats:
             stats_ref[:] += 1
@@ -344,6 +364,9 @@ def _decode_attn_kernel(
             own = own[:, :chunk] + own[:, chunk:]
         l = l_scr[:]
         o_ref[:] = (own / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+        if with_softmax:
+            m_ref[:] = m_scr[:]
+            l_ref[:] = l
 
 
 def paged_decode_attention(
@@ -358,6 +381,7 @@ def paged_decode_attention(
     block_t: int | None = None,
     interpret: bool | None = None,
     return_stats: bool = False,
+    eva_ring: int | None = None,
 ):
     """One layer's share of a decode step against the ring cache: write
     each slot's new row (k, v [S, Nkv, D]) at ring row ``lens % T`` of
@@ -369,7 +393,18 @@ def paged_decode_attention(
 
     ``return_stats`` additionally returns the measured per-(slot, kv-head)
     count of ring blocks the kernel actually processed — the dead-block
-    skip evidence banked by scripts/decode_kernel_bench.py."""
+    skip evidence banked by scripts/decode_kernel_bench.py.
+
+    ``eva_ring`` (None for every configuration but EVA's) says which of EVA's
+    two rings this call reads, and appends the softmax's running maximum and
+    sum over the rows read ([S, H] float32 each; the maximum is ``NEG_INF`` and
+    the sum 0 where none was), under which the caller merges the two calls'
+    outputs into one softmax. 0 is the window's ring, read as any ring is. n >
+    0 is the pooled ring of n rows a window: the row is written at ring row
+    ``lens`` and *not* read, and the rows read are those of the windows before
+    the one it lies in, [0, lens // n * n) (kernel name
+    ``odtp_eva_pooled_attn``). There is no XLA stand-in for either: no plan
+    raises. :func:`eva_decode_attention` is the caller of both."""
     # Mosaic requires the last two dims of every block to be (8, 128)-
     # aligned OR equal to the array's own dims. The cache's two minor dims
     # are (D, T), so a (d, bt) tile is legal for bt a multiple of 128. The
@@ -385,6 +420,12 @@ def paged_decode_attention(
         nkv, d, t, cache_k.dtype.itemsize,
         block_t=block_t, interpret=interp,
     )
+    if eva_ring is not None and (not plan or eva_ring % plan.block_t):
+        raise ValueError(
+            "the softmax's state and the pooled ring's reading are the kernel's "
+            f"alone, and it has no plan for {nkv} KV heads of {d} over {t} rows "
+            f"(windows of {eva_ring} pooled rows)"
+        )
     if not plan:
         res = decode_step_attention(q, k, v, cache_k, cache_v, lens, layer)
         return (*res, None) if return_stats else res
@@ -437,6 +478,9 @@ def paged_decode_attention(
     if return_stats:
         out_specs.append(pl.BlockSpec((None, hb, 1, 1), q_map))
         out_shape.append(jax.ShapeDtypeStruct((s_, nkv, 1, 1), jnp.int32))
+    if eva_ring is not None:  # a grid step's rows, as the queries are laid
+        out_specs += [pl.BlockSpec((None, None, rows, 1), q_map)] * 2
+        out_shape += [jax.ShapeDtypeStruct((s_, nkv // hb, rows, 1), jnp.float32)] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -458,8 +502,9 @@ def paged_decode_attention(
             _decode_attn_kernel,
             scale=d**-0.5, block_t=bt, t=t, num_t=num_t, rep=rep,
             with_stats=return_stats,
+            **({} if eva_ring is None else {"eva_ring": int(eva_ring)}),
         ),
-        name="odtp_paged_decode_attn",
+        name="odtp_eva_pooled_attn" if eva_ring else "odtp_paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=out_shape,
         # operands count the two scalar-prefetch vectors: the caches are
@@ -475,8 +520,139 @@ def paged_decode_attention(
     )
     out = (res[0].reshape(s_, h, d), res[1], res[2])
     if return_stats:
-        return (*out, res[3].reshape(s_, nkv))
+        out = (*out, res[3].reshape(s_, nkv))
+    if eva_ring is not None:
+        out = (*out, res[-2].reshape(s_, h), res[-1].reshape(s_, h))
     return out
+
+
+def eva_prefill_form(window: int, d: int, interpret: bool | None = None) -> str:
+    """Which form a serving prefill of EVA attention takes, from what can be
+    seen: "flash" on the chip (and where a test asks for the kernel
+    interpreted) if the flash kernel tiles a window of heads of ``d``, else
+    "xla". The engine reports it (``ServeEngine.eva_forms``)."""
+    wanted = bool(interpret) or not _interpret(interpret)
+    return "flash" if wanted and pick_block(window, 1024) and d % 8 == 0 else "xla"
+
+
+def eva_prefill_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, kbar: jax.Array, vbar: jax.Array,
+    *, window: int, chunk: int, interpret: bool | None = None,
+) -> jax.Array:
+    """EVA over a whole prompt (``ops.attention.eva_attention``'s signature
+    and result), in the form :func:`eva_prefill_form` names. "flash" holds no
+    window's scores in memory: each window's causal attention over its own
+    rows is the flash kernel's (the windows as a batch), which hands back its
+    softmax's log-sum-exp; the pooled rows of the windows before, a few
+    hundred columns, are scored in XLA; the two are merged under one softmax
+    (scope ``odtp_eva``). "xla" is ``eva_attention`` itself. No gradient."""
+    from opendiloco_tpu.ops.flash_attention import flash_attention_lse
+
+    b, t, h, d = q.shape
+    if eva_prefill_form(window, d, interpret) == "xla":
+        return eva_attention(q, k, v, kbar, vbar, window=window, chunk=chunk)
+    cpw = window // chunk
+    pad = -t % window
+    if pad:
+        rows = ((0, 0), (0, pad), (0, 0), (0, 0))
+        q, k, v = jnp.pad(q, rows), jnp.pad(k, rows), jnp.pad(v, rows)
+    nw = (t + pad) // window
+    as_batch = lambda x: x.reshape(b * nw, window, *x.shape[2:])
+    own = flash_attention_lse(
+        as_batch(q), as_batch(k), as_batch(v), causal=True, interpret=_interpret(interpret)
+    )
+    o_w = own[0].reshape(b, nw, window, h, d)
+    seen = (nw - 1) * cpw  # pooled rows that any query of the prompt reads
+    if not seen:
+        return o_w.reshape(b, nw * window, h, d)[:, :t]
+    with jax.named_scope("odtp_eva"):
+        f32 = jnp.float32
+        lse_w = own[1].reshape(b, nw, window, h)
+        qw = q.reshape(b, nw, window, h, d)
+        kb, vb = _repeat_kv(kbar[:, :seen], h), _repeat_kv(vbar[:, :seen], h)
+        s = jnp.einsum("bwqhd,bjhd->bwqhj", qw, kb, preferred_element_type=f32) * d**-0.5
+        w_of = jax.lax.broadcasted_iota(jnp.int32, (nw, 1, 1, seen), 0)
+        j_of = jax.lax.broadcasted_iota(jnp.int32, (nw, 1, 1, seen), 3)
+        s = jnp.where(j_of < w_of * cpw, s, NEG_INF)
+        m = jnp.maximum(lse_w, jnp.max(s, axis=-1))  # the window's own rows keep it finite
+        p = jnp.exp(s - m[..., None])  # exactly 0 where masked
+        w_w = jnp.exp(lse_w - m)
+        o_p = jnp.einsum("bwqhj,bjhd->bwqhd", p.astype(q.dtype), vb, preferred_element_type=f32)
+        out = (o_w.astype(f32) * w_w[..., None] + o_p) / (w_w + jnp.sum(p, axis=-1))[..., None]
+    return out.astype(q.dtype).reshape(b, nw * window, h, d)[:, :t]
+
+
+def eva_plans(
+    nkv: int, d: int, window: int, chunk: int, pooled_rows: int, itemsize: int,
+    *, interpret: bool | None = None,
+) -> tuple[DecodePlan, DecodePlan] | None:
+    """The decode kernel's plans for EVA's two rings (the window's, the pooled
+    one of ``pooled_rows`` rows), or None where either has none. The pooled
+    ring's tile divides a window's pooled rows, since the rows read of it are
+    whole windows: the widest the chip tiles, or, interpreted, whatever
+    ``ODTP_DECODE_BLOCK_T`` asks for."""
+    cpw = window // chunk
+    want = next((b for b in (256, 128) if cpw % b == 0), None)
+    local = decode_plan(nkv, d, window, itemsize, interpret=interpret)
+    pooled = local and decode_plan(nkv, d, pooled_rows, itemsize, block_t=want, interpret=interpret)
+    return (local, pooled) if pooled and cpw % pooled.block_t == 0 else None
+
+
+def eva_decode_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, phi: jax.Array, mu: jax.Array,
+    cache_k: jax.Array, cache_v: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
+    stats: jax.Array, lens: jax.Array, layer, *, window: int, chunk: int,
+    interpret: bool | None = None,
+):
+    """One layer's share of a decode step of EVA attention over a slot's two
+    rings, what ``ops.attention.eva_decode_step_attention`` gives (its
+    signature), with both rings read and written where they lie: the decode
+    kernel once over the window's ring under ``lens % window`` (the ring
+    restarts: the kernel's own mask, rows [0, lens % window], is the
+    window's), once over the pooled ring (``eva_ring`` > 0: the step's
+    pooled row written at ``lens // chunk`` and not read, the rows of the
+    windows before read), each handing back its softmax's maximum and sum,
+    and the two outputs merged under them: one softmax over both. The
+    pooling's step and the merge are XLA's, over [S, H, D] (scope
+    ``odtp_eva``). Two calls and not one kernel fed both: the rings differ in
+    what a step reads of them (its own row or not, a prefix by rows or by
+    windows) and in their tile (a window's ring takes the widest, a pooled
+    ring a window's 128 rows), and the merge is a few KB. Where either ring
+    has no plan it raises (:func:`eva_plans`; the engine asks at construction):
+    a step never takes the XLA form unasked."""
+    cpw = window // chunk
+    s_, nkv, d = k.shape
+    h = q.shape[1]
+    interp = _interpret(interpret)
+    plans = h % nkv == 0 and ring_rows(cache_k) == window and eva_plans(
+        nkv, d, window, chunk, ring_rows(pool_k), cache_k.dtype.itemsize, interpret=interp
+    )
+    if not plans:
+        raise ValueError(
+            f"the decode kernel has no plan for EVA's rings here ({nkv} KV heads of "
+            f"{d} under {h} query heads, a window of {ring_rows(cache_k)} rows for "
+            f"{window}, {ring_rows(pool_k)} pooled rows, {cpw} a window): "
+            "decode_kernel 'xla' runs ops.attention.eva_decode_step_attention instead"
+        )
+    lens = lens.astype(jnp.int32)
+    with jax.named_scope("odtp_eva"):
+        kbar, vbar, new = eva_accumulate(stats[layer], k, v, phi, mu, lens, chunk)
+        stats = jax.lax.dynamic_update_index_in_dim(stats, new, layer, 0)
+    o_w, cache_k, cache_v, m_w, l_w = paged_decode_attention(
+        q, k, v, cache_k, cache_v, jnp.mod(lens, window), layer,
+        interpret=interp, eva_ring=0,
+    )
+    o_p, pool_k, pool_v, m_p, l_p = paged_decode_attention(
+        q, kbar, vbar, pool_k, pool_v, lens // chunk, layer,
+        block_t=plans[1].block_t, interpret=interp, eva_ring=cpw,
+    )
+    with jax.named_scope("odtp_eva"):
+        m = jnp.maximum(m_w, m_p)
+        w_w, w_p = l_w * jnp.exp(m_w - m), l_p * jnp.exp(m_p - m)  # w_p 0: no pooled row yet
+        out = (
+            o_w.astype(jnp.float32) * w_w[..., None] + o_p.astype(jnp.float32) * w_p[..., None]
+        ) / (w_w + w_p)[..., None]
+    return out.astype(q.dtype), cache_k, cache_v, pool_k, pool_v, stats
 
 
 # ---------------------------------------------------------------------------

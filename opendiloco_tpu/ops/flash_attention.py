@@ -103,7 +103,8 @@ def _fwd_kernel(
         lse_ref[:] = (m_scr[:] + jnp.log(l_safe)).reshape(1, block_q)
 
 
-def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool, vma=None):
+def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool, vma=None,
+         interpret: bool = False):
     """q: [B, Hq, T, D]; k/v: [B, Hkv, T, D] -> (out [B, Hq, T, D], lse [B, Hq, 1, T]).
 
     ``vma``: varying-manual-axes annotation for the outputs, required when
@@ -160,8 +161,29 @@ def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool, vma=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
+        interpret=interpret,
     )(q, k, v)
     return out, lse
+
+
+def flash_attention_lse(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
+    block_q: int = 1024, block_k: int = 1024, interpret: bool = False,
+):
+    """The forward kernel alone, with the softmax's log-sum-exp beside the
+    output, for a caller that merges this attention with another under one
+    softmax (a serving prefill; no gradient is defined): q [B, T, H, D], k and
+    v [B, T, Hkv, D] -> (out [B, T, H, D], lse [B, T, H] float32, of the scaled
+    scores), or None where the kernel does not tile the shape."""
+    t, d = q.shape[1], q.shape[-1]
+    block_q, block_k = _pick_block(t, block_q), _pick_block(t, block_k)
+    if block_q == 0 or block_k == 0 or d % 8 != 0:
+        return None
+    out, lse = _fwd(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+        block_q=block_q, block_k=block_k, causal=causal, interpret=interpret,
+    )
+    return out.transpose(0, 2, 1, 3), lse[:, :, 0].transpose(0, 2, 1)
 
 
 # ---------------------------------------------------------------------------

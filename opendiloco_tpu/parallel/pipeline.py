@@ -73,6 +73,13 @@ def pipeline_hidden(
             "layers: it stages one homogeneous [L, ...] stack, and a hybrid's "
             "layers are stacked per kind of mixer (llama.layer_runs)"
         )
+    if cfg.eva:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration with EVA attention "
+            f"(window_size {cfg.window_size}, chunk_size {cfg.chunk_size}): a stage's "
+            "attention is the caller's attn_fn over one run of rows, and EVA pools "
+            "its chunks under each layer's own vectors"
+        )
     if cfg.router_hidden_size:
         raise ValueError(
             "the pp pipeline is refused for a configuration whose router reads "

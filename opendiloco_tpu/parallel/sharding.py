@@ -80,6 +80,9 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "router_down": (None, 0),  # [D, R]
     "router_fc1": (None, -1),  # [R, R]
     "router_fc2": (None, -1),
+    # EVA's pooling vectors (ops.attention.eva_pool), a head's size a head: replicated
+    "adaptive_phi": (None, -1),  # [Nkv, Dh]
+    "adaptive_mu_k": (None, -1),
 }
 
 # FFN leaves that gain a leading expert dim when num_experts > 0

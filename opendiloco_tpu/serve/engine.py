@@ -87,6 +87,7 @@ from opendiloco_tpu.models.llama import (
     dequant_w4,
     draft_propose,
     prefill_forward,
+    refuse_eva,
     refuse_latent,
     refuse_recurrent,
     verify_forward,
@@ -94,8 +95,11 @@ from opendiloco_tpu.models.llama import (
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
     cca_state_insert,
+    eva_insert,
+    eva_pooled_rows,
     fetch_pages,
     init_cca_state,
+    init_eva_state,
     init_kv_cache,
     init_ssm_state,
     layer_pages,
@@ -107,12 +111,16 @@ from opendiloco_tpu.models.ring_cache import (
 )
 from opendiloco_tpu.ops.attention import (
     decode_step_attention,
+    eva_decode_step_attention,
     latent_decode_step_attention,
     spec_tail_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     DecodePlan,
     decode_plan,
+    eva_decode_attention,
+    eva_plans,
+    eva_prefill_form,
     mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
@@ -171,9 +179,13 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     of a prefill and of a decode step. A routed model's programs append the
     FFN's three counts to the tokens, so that one device-to-host read fetches
     both (``ServeEngine._split_counts``). A hybrid's programs hand the
-    recurrent state and the conv tail on after the K/V, CCA's its one state:
-    ``left`` is those, or nothing; with ``chosen`` each token's experts in
-    each layer come last.
+    recurrent state and the conv tail on after the K/V, CCA's its one state,
+    EVA's the prompt's pooled rows and the pooling under way, which its
+    ``admit_insert`` takes with the K/V of the prompt's last window
+    (``ring_cache.eva_insert``: the slot's window ring, pooled ring and stats
+    in one program): ``left`` is those, or nothing; with ``chosen`` each token's
+    experts in each layer come last. Of a head of several vocabularies
+    (``num_pred_heads``) the first is the one sampled; the logits go back whole.
 
     The first token never has to reach the host before the step that reads
     it: ``admit_insert`` writes it at ``slot`` into the ``[S]`` vector
@@ -181,8 +193,13 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     wherever ``tokens[slot]`` is ``FIRST_TOKEN_ON_DEVICE``."""
     cd, dkn = compute_dtype, decode_kernel
     moe = bool(cfg.num_experts)
-    n_state = 1 if cfg.cca else 2 if cfg.hybrid else 0
+    n_state = 1 if cfg.cca else 3 if cfg.eva else 2 if cfg.hybrid else 0
     state_names = ("cca_state",) if cfg.cca else ("ssm_state", "conv_state")
+
+    def sample(logits):  # greedy, from the next token's head
+        if cfg.num_pred_heads > 1:
+            logits = logits[..., : cfg.vocab_size]
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def prefill(p, ids, length):
         with jax.named_scope("odtp_serve_prefill"):
@@ -191,7 +208,7 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
                 return_moe_counts=moe, return_expert_choices=chosen,
             )
             left, counts = rest[:n_state], rest[n_state : n_state + 1]
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok = sample(logits)
         # the one row of logits goes back as a row: a caller that wants it
         # reads it, and no second program has to cut it out after the first read
         return (_with_counts(tok, counts), logits[0], ks, vs, *left, *rest[n_state + 1 :])
@@ -199,19 +216,26 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     def decode(p, tokens, lens, first, ck, cv, *ssm):
         with jax.named_scope("odtp_serve_decode"):
             tokens = jnp.where(tokens == FIRST_TOKEN_ON_DEVICE, first, tokens)
-            state = dict(zip(state_names, ssm))
+            state = {"eva_state": ssm} if cfg.eva else dict(zip(state_names, ssm))
             logits, ck, cv, *rest = decode_forward(
                 p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
                 decode_kernel=dkn, return_moe_counts=moe,
                 return_expert_choices=chosen, **state,
             )
             left, counts = rest[:n_state], rest[n_state : n_state + 1]
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok = sample(logits)
         return (_with_counts(tok, counts), logits, ck, cv, *left, *rest[n_state + 1 :])
 
     def admit_insert(ck, cv, first, ks, vs, tok, slot):
         ck, cv = cache_insert(ck, cv, ks, vs, slot)
         return ck, cv, first.at[slot].set(tok[0])
+
+    def eva_admit_insert(ck, cv, first, pk, pv, stats, ks, vs, pks, pvs, chunk, tok, slot):
+        rings = eva_insert(ck, cv, pk, pv, stats, ks, vs, pks, pvs, chunk, slot)
+        return rings[0], rings[1], first.at[slot].set(tok[0]), *rings[2:]
+
+    if cfg.eva:
+        admit_insert = eva_admit_insert
 
     return prefill, decode, admit_insert, 2 + n_state
 
@@ -323,6 +347,10 @@ class ServeEngine:
                 "q and k that share their rank"
             )
         if self.weight_format == "w4":
+            refuse_eva(
+                cfg, "weight_format=w4 (the packing is defined for the matmul leaves "
+                "of a plain attention block and has not met adaptive_phi / adaptive_mu_k)"
+            )
             refuse_latent(
                 cfg, "weight_format=w4 (kv_b_proj is read in two halves, one absorbed "
                 "into q and one into the output, which the fused dequant-matmul does not do)"
@@ -334,6 +362,7 @@ class ServeEngine:
         if self.spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if self.spec_k:
+            refuse_eva(cfg, f"speculative decode (spec_k={self.spec_k})")
             refuse_recurrent(cfg, f"speculative decode (spec_k={self.spec_k})")
             refuse_latent(cfg, f"speculative decode (spec_k={self.spec_k})")
             L = cfg.num_hidden_layers
@@ -437,6 +466,49 @@ class ServeEngine:
         # prefill writes one slot's, a decode step reads and writes every slot's)
         self.cca_tokens = 0
         self.cca_state_bytes_moved = 0
+        # EVA's: the pooled ring and the pooling under way, beside a
+        # ``cache_k`` / ``cache_v`` of one window's rows. ``max_context``
+        # bounds the positions and sizes the pooled ring; the window's ring is
+        # ``window_size`` rows whatever it is
+        self._eva: tuple = ()
+        # which form each program's EVA attention takes ({} without it): the
+        # decode step's by ``decode_kernel`` ("pallas" | "xla"; the kernel has
+        # a plan for both rings or the engine is refused here, never a step
+        # that quietly takes the XLA form), the prefill's by the platform and
+        # the tiling ("flash" | "xla")
+        self.eva_forms: dict = {}
+        if cfg.eva:
+            window, chunk = cfg.window_size, cfg.chunk_size
+            pooled = eva_pooled_rows(cfg, self.max_context)
+            if self.decode_kernel == "pallas" and not eva_plans(
+                cfg.kv_heads, cfg.head_dim, window, chunk, pooled, self.cache_k.dtype.itemsize
+            ):
+                raise ValueError(
+                    "decode_kernel 'pallas' has no plan for EVA's rings at these shapes "
+                    f"({cfg.kv_heads} KV heads of {cfg.head_dim}, a window of {window} rows "
+                    f"and {pooled} pooled rows, {window // chunk} a window); decode_kernel "
+                    "'xla' runs the XLA form"
+                )
+            self.eva_forms = {
+                "decode": self.decode_kernel,
+                "prefill": eva_prefill_form(window, cfg.head_dim),
+            }
+            state = init_eva_state(cfg, self.num_slots, self.max_context, compute_dtype)
+            self._eva = (state["pool_k"], state["pool_v"], state["stats"])
+        self.eva_cache_resident_bytes = sum(x.nbytes for x in self._eva)
+        # what EVA attention did with its two rings (always on; stay 0 without
+        # it): the window's rows and the pooled rows the decode steps read,
+        # over layers (a step at position p reads p % window + 1 and p //
+        # window * chunks-a-window a layer); the chunks whose pooled row
+        # became final (a prefill's whole chunks, a step at a chunk's last
+        # position), a slot each; the slots whose position reached a window's
+        # edge, where the ring restarts; the bytes of rows and stats the calls
+        # moved
+        self.eva_local_rows_read = 0
+        self.eva_pooled_rows_read = 0
+        self.eva_chunks_pooled = 0
+        self.eva_window_restarts = 0
+        self.eva_cache_bytes_moved = 0
 
         # after ``keep_expert_choices()``: each token's experts in each layer
         # of the newest prefill or decode step, on the device, [L, tokens, K]
@@ -465,7 +537,7 @@ class ServeEngine:
             return (
                 jax.jit(prefill),
                 _DecodeProgram(decode, carried),
-                jax.jit(admit_insert, donate_argnums=(0, 1, 2)),
+                jax.jit(admit_insert, donate_argnums=tuple(range(3 + len(self._eva)))),
             )
 
         self._programs = programs
@@ -604,6 +676,7 @@ class ServeEngine:
             logits = self._read(adm, row=True)
             return adm.token, logits
         self._bucket_of(n)
+        refuse_eva(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         t0 = time.perf_counter()
@@ -655,12 +728,19 @@ class ServeEngine:
         # the slot's scalar is made here, while the device runs the
         # prompt: made with the others it holds every prefill's start back
         # by its own host time (a third of a millisecond on the chip)
-        self.cache_k, self.cache_v, self._first = self._admit_insert(
-            self.cache_k, self.cache_v, self._first, ks, vs, tokd, jnp.int32(slot)
-        )
+        if self._eva:  # both rings and the pooling under way
+            self.cache_k, self.cache_v, self._first, *eva = self._admit_insert(
+                self.cache_k, self.cache_v, self._first, *self._eva, ks, vs, *left,
+                tokd, jnp.int32(slot),
+            )
+            self._eva = tuple(eva)
+        else:
+            self.cache_k, self.cache_v, self._first = self._admit_insert(
+                self.cache_k, self.cache_v, self._first, ks, vs, tokd, jnp.int32(slot)
+            )
         if self._cca:  # what the prompt's last token left CCA's projections
             self._cca = (self._cca_insert(*self._cca, *left, jnp.int32(slot)),)
-        elif left:  # the recurrent state the prompt left, whole
+        elif self._ssm:  # the recurrent state the prompt left, whole
             self._ssm = self._state_insert(*self._ssm, *left, jnp.int32(slot))
         adm = Admission(
             slot=int(slot), tokens=n, tokd=tokd, rowd=rowd,
@@ -693,6 +773,7 @@ class ServeEngine:
         attrs.update(self._count_ssm(adm.tokens, adm.state_bytes))
         attrs.update(self._count_cca(adm.tokens, adm.state_bytes))
         attrs.update(self._count_latent(read=0, written=adm.tokens))
+        attrs.update(self._count_eva(prompt=adm.tokens))
         adm.token = int(toks[0])
         t1 = time.perf_counter()
         self.stage_seconds["prefill"] += (adm.t_dispatch - adm.t0) + (t1 - t_fetch)
@@ -738,6 +819,41 @@ class ServeEngine:
         self.cca_tokens += tokens
         self.cca_state_bytes_moved += state_bytes
         return {"cca_tokens": tokens, "cca_state_bytes": state_bytes}
+
+    def _count_eva(self, lens=None, prompt: int = 0) -> dict:
+        """Add one call's traffic with EVA's two rings to the engine's
+        counters -> the same as span attributes (nothing for a model without
+        EVA attention): ``eva_local_rows`` and ``eva_pooled_rows`` over layers,
+        ``eva_bytes`` what the call moved. A decode step over the live slots'
+        positions ``lens`` reads, a layer, rows [0, p % window] of the window's
+        ring (its own among them, which it wrote) and the pooled rows of the
+        windows before p, writes one pooled row and reads and writes the
+        slot's stats; a prefill of ``prompt`` tokens writes the rows of the
+        prompt's last window, its pooled rows and one slot's stats."""
+        if not self._eva:
+            return {}
+        cfg = self.cfg
+        layers, window, chunk = cfg.num_hidden_layers, cfg.window_size, cfg.chunk_size
+        row = 2 * cfg.kv_heads * cfg.head_dim * self.cache_k.dtype.itemsize  # a K and a V
+        stats = self._eva[2].nbytes // self.num_slots  # a slot's, every layer's
+        if lens is None:
+            local, pooled = prompt % window, -(-prompt // chunk)
+            self.eva_chunks_pooled += prompt // chunk
+            moved = layers * (local + pooled) * row + stats
+        else:
+            held = np.asarray(lens)
+            held = held[held > 0]
+            local = int((held % window + 1).sum())
+            pooled = int((held // window * cfg.eva_chunks_per_window).sum())
+            self.eva_local_rows_read += layers * local
+            self.eva_pooled_rows_read += layers * pooled
+            self.eva_chunks_pooled += int(np.count_nonzero(held % chunk == chunk - 1))
+            self.eva_window_restarts += int(np.count_nonzero(held % window == 0))
+            moved = layers * (local + pooled + held.size) * row + 2 * held.size * stats
+        self.eva_cache_bytes_moved += moved
+        return {
+            "eva_local_rows": layers * local, "eva_pooled_rows": layers * pooled, "eva_bytes": moved,
+        }
 
     def _count_latent(self, read: int, written: int) -> dict:
         """Add one call's traffic with the latent ring to the engine's
@@ -827,6 +943,7 @@ class ServeEngine:
         iteration so the transfer overlaps the next decode step instead
         of blocking the loop. The gather is by value: the slot can be
         re-tenanted immediately."""
+        refuse_eva(self.cfg, "the host tier's page-out")
         refuse_recurrent(self.cfg, "the host tier's page-out")
         refuse_latent(self.cfg, "the host tier's page-out")
         t0 = time.perf_counter()
@@ -846,6 +963,7 @@ class ServeEngine:
         of ``slot`` are rewritten from the host arrays. Dispatch is
         async — the next decode step queues behind it on-stream, so the
         scheduler thread never blocks on the transfer."""
+        refuse_eva(self.cfg, "the host tier's page-in")
         refuse_recurrent(self.cfg, "the host tier's page-in")
         refuse_latent(self.cfg, "the host tier's page-in")
         t0 = time.perf_counter()
@@ -881,11 +999,14 @@ class ServeEngine:
         t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *state = self._decode(
             self.params, tokensd, lensd, self.cache_k, self.cache_v,
-            *self._ssm, *self._cca, first=self._first,
+            *self._ssm, *self._cca, *self._eva, first=self._first,
         )
         if self._keeps_choices:
             self.expert_choices = state.pop()
-        self._ssm, self._cca = tuple(state[: len(self._ssm)]), tuple(state[len(self._ssm):])
+        if self._eva:
+            self._eva = tuple(state)
+        else:
+            self._ssm, self._cca = tuple(state[: len(self._ssm)]), tuple(state[len(self._ssm):])
         t_fetch = t_dispatch = time.perf_counter()
         # with the step enqueued behind them, the admissions' tokens are read,
         # each a wait for its own program and no more; then the step's
@@ -910,6 +1031,7 @@ class ServeEngine:
             moe.update(self._count_latent(
                 read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size
             ))
+        moe.update(self._count_eva(lens))
         t1 = time.perf_counter()
         # the step's own seconds: not those of the admissions read inside it
         self.stage_seconds["decode"] += (t1 - t0) - (t_fetch - t_dispatch)
@@ -1021,6 +1143,35 @@ class ServeEngine:
                 _latent, ql, jnp.full((S,), T // 2, jnp.int32), self.cache_k[:1],
                 carried=1, iters=iters,
             )})
+        if cfg.eva:
+            # a decode step's attention over both rings of the one layer, at
+            # a position half-way through the second window (there is no
+            # verify pass to time, and no w4), and the kernel's plan for each
+            q1 = jax.random.normal(key, (S, Nh, Dh), cd)
+            k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
+            step = eva_decode_attention if pallas else eva_decode_step_attention
+            window, chunk = cfg.window_size, cfg.chunk_size
+
+            def _eva(q1, k1, lens, ck, cv, pk, pv, stats):
+                return step(
+                    q1, k1, k1, self.params["layers"]["adaptive_phi"][0],
+                    self.params["layers"]["adaptive_mu_k"][0], ck, cv, pk, pv, stats,
+                    lens, 0, window=window, chunk=chunk,
+                )
+
+            at = min(T - 1, window + window // 2)
+            out = {"decode_attn_us": _best_us(
+                _eva, q1, k1, jnp.full((S,), at, jnp.int32), self.cache_k[:1],
+                self.cache_v[:1], *(x[:1] for x in self._eva), carried=5, iters=iters,
+            )}
+            plans = pallas and eva_plans(
+                Nkv, Dh, window, chunk, self._eva[0].shape[-1], self.cache_k.dtype.itemsize
+            )
+            for name, plan in zip(("decode_plan", "eva_pooled_plan"), plans or (DecodePlan(0, 0),) * 2):
+                out[f"{name}_heads"] = float(plan.heads)
+                out[f"{name}_block_t"] = float(plan.block_t)
+            out["eva_cache_resident_bytes"] = float(self.eva_cache_resident_bytes)
+            return self._publish_probe(out)
         q1 = jax.random.normal(key, (S, Nh, Dh), cd)
         ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
         lens = jnp.full((S,), T // 2, jnp.int32)

@@ -148,6 +148,15 @@ class ContinuousBatcher:
                 "rows, and CCA keeps a state of the slot's last token beside them "
                 "that neither snapshots"
             )
+        if engine.cfg.eva and (prefix_cache or kv_tier is not None):
+            refused = "prefix_cache" if prefix_cache else "kv_tier"
+            raise ValueError(
+                f"{refused} is refused for a configuration with EVA attention: "
+                "prefix reuse and the host tier copy, cut and restore a slot's "
+                "past as the rows of one ring, and EVA's ring is one window that "
+                "restarts, beside pooled chunks and the pooling of the chunk under "
+                "way that neither snapshots"
+            )
         if engine.cfg.latent and (prefix_cache or kv_tier is not None):
             refused = "prefix_cache" if prefix_cache else "kv_tier"
             raise ValueError(
@@ -291,6 +300,19 @@ class ContinuousBatcher:
         if req.max_new_tokens < 1:
             self.rejected += 1
             req.finish("max_new_tokens must be >= 1")
+            self._trace_terminal(req, "retire", "failed", error=req.error)
+            return req
+        if (
+            self.engine.cfg.eva
+            and len(req.prompt) + req.max_new_tokens > self.engine.max_context
+        ):
+            # a ring of rows slides past its context; EVA's pooled ring holds a
+            # row per chunk of max_context positions and none wraps
+            self.rejected += 1
+            req.finish(
+                f"prompt length {len(req.prompt)} and {req.max_new_tokens} new "
+                f"tokens exceed max_context {self.engine.max_context}"
+            )
             self._trace_terminal(req, "retire", "failed", error=req.error)
             return req
         with self._cond:
@@ -1148,6 +1170,14 @@ class ContinuousBatcher:
             # of the cold admissions in phase_calls["prefill"], those whose
             # first token a decode step took on the device
             "admissions_deferred": self.engine.admissions_deferred,
+            # what EVA attention did with its two rings (zeros without it)
+            "eva": {
+                **{name: getattr(self.engine, f"eva_{name}") for name in (
+                    "local_rows_read", "pooled_rows_read", "chunks_pooled",
+                    "window_restarts", "cache_bytes_moved", "cache_resident_bytes",
+                )},
+                "forms": self.engine.eva_forms,
+            },
             "spec": {
                 "proposed": self.spec_proposed,
                 "accepted": self.spec_accepted,

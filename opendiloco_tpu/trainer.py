@@ -157,9 +157,10 @@ def _resolve_perf_defaults(
                     "pallas" if on_tpu else "xla",
                 )
             changes["attn_impl"] = "pallas" if on_tpu else "xla"
-        if model_cfg.latent:
+        if model_cfg.latent or model_cfg.eva:
             # latent attention trains in the rebuilt form through XLA's
-            # attention; ``forward`` refuses it the flash and ring kernels
+            # attention, EVA over windows and pooled chunks; ``forward``
+            # refuses either the flash and ring kernels
             changes["attn_impl"] = "xla"
     if tc.scan_unroll is None:
         # full unroll measured +6.8% tok/s on the HBM-bound 150m step (v5e
@@ -197,6 +198,8 @@ def _resolve_perf_defaults(
             and attn == "pallas"
             and getattr(plan, "sp_axis", None) is None
             and unroll < model_cfg.num_hidden_layers
+            # the fused kernel holds one vocabulary to the next token
+            and model_cfg.num_pred_heads == 1
         )
     return dataclasses.replace(tc, **changes)
 
@@ -462,6 +465,13 @@ class InnerTrainer:
             remat=self.tc.remat,
             scan_unroll=self.tc.scan_unroll,
         )
+        heads = self.model_cfg.num_pred_heads
+        if self.tc.fused_loss and heads > 1:
+            raise ValueError(
+                f"fused_loss is refused for num_pred_heads {heads}: the fused "
+                "lm-head kernel holds one vocabulary to the next token, and head i "
+                "of several is held to the token i + 1 ahead (causal_lm_loss)"
+            )
         if self.tc.fused_loss:
             out = forward(
                 params,
@@ -485,8 +495,8 @@ class InnerTrainer:
         with jax.named_scope("odtp_lm_head_loss"):
             if moe:
                 logits, moe_aux = out
-                return causal_lm_loss(logits, labels) + moe_aux
-            return causal_lm_loss(out, labels)
+                return causal_lm_loss(logits, labels, pred_heads=heads) + moe_aux
+            return causal_lm_loss(out, labels, pred_heads=heads)
 
     def _train_step_impl(self, state: dict, batch: dict):
         """batch arrays are [accum, global_microbatch, seq]."""

@@ -183,8 +183,20 @@ def _zaya_model():
     return cfg, init_params(jax.random.key(6), cfg)
 
 
+def _eva_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "evabyte", "vocab_size": 256, "hidden_size": 64, "intermediate_size": 96,
+        "num_attention_heads": 4, "num_key_value_heads": 4, "num_hidden_layers": 3,
+        "attention_class": "eva", "chunk_size": 2, "window_size": 8, "num_pred_heads": 2,
+        "norm_add_unit_offset": True, "fp32_skip_add": True, "fp32_logits": True,
+        "rope_theta": 1e5, "init_std": 0.05, "max_position_embeddings": 128,
+    })
+    assert cfg.eva and cfg.eva_chunks_per_window == 4
+    return cfg, init_params(jax.random.key(7), cfg)
+
+
 @pytest.mark.parametrize(
-    "model", [_dense_model, _routed_qk_norm_model, _latent_routed_model, _zaya_model]
+    "model", [_dense_model, _routed_qk_norm_model, _latent_routed_model, _zaya_model, _eva_model]
 )
 def test_the_five_forwards_agree(model):
     """One block under five drivers: in float32 the training forward, the
@@ -197,7 +209,11 @@ def test_the_five_forwards_agree(model):
     the same three (its projections read the token before: a shift over the
     sequence in training and prefill, a per-slot state in decode, written by
     the prefill at the prompt's true length); the other two cannot roll that
-    state back and refuse it."""
+    state back and refuse it. The EVA block runs under the same three (a
+    window of rows and the pooled chunks before it: over the whole sequence
+    in training and prefill, over a slot's two rings in decode, the prompt of
+    9 ending in the second window of 8); the other two take the ring for the
+    context and refuse it. Of a head of two vocabularies the first samples."""
     from opendiloco_tpu.models.llama import (
         cache_insert, decode_forward, draft_propose, init_kv_cache,
         prefill_forward, verify_forward,
@@ -214,16 +230,24 @@ def test_the_five_forwards_agree(model):
     padded = jnp.asarray([prompt + [0] * (16 - P)], jnp.int32)
     logits, ks, vs, *left = prefill_forward(params, padded, jnp.int32(P), cfg, **f32)
     close(logits[0], full(prompt)[P - 1])
-    tok = int(jnp.argmax(logits[0]))
+    tok = int(jnp.argmax(logits[0, : cfg.vocab_size]))
     assert (vs is None) == cfg.latent  # the latent rows alone are kept
 
     # slot 1 of two holds the prompt; one decode step = a verify pass over a
     # tail of one = the forward's next row
     cache = init_kv_cache(cfg, 2, 32, jnp.float32)
-    ck, cv = cache_insert(cache["k"], cache["v"], ks, vs, jnp.int32(1))
+    state = {}
+    if cfg.eva:  # the last window's rows, the pooled rows and the pooling under way
+        from opendiloco_tpu.models.ring_cache import eva_insert, init_eva_state
+
+        eva = init_eva_state(cfg, 2, 32, jnp.float32)
+        ck, cv, *state["eva_state"] = eva_insert(
+            cache["k"], cache["v"], eva["pool_k"], eva["pool_v"], eva["stats"], ks, vs, *left,
+            jnp.int32(1))
+    else:
+        ck, cv = cache_insert(cache["k"], cache["v"], ks, vs, jnp.int32(1))
     tokens, lens = jnp.asarray([0, tok], jnp.int32), jnp.asarray([0, P], jnp.int32)
     want = full(prompt + [tok])[P]
-    state = {}
     if cfg.cca:  # what the prompt's last token left, into slot 1 of the state
         from opendiloco_tpu.models.ring_cache import cca_state_insert, init_cca_state
 
@@ -231,8 +255,8 @@ def test_the_five_forwards_agree(model):
             init_cca_state(cfg, 2, jnp.float32), left[0], jnp.int32(1))
     step, *_ = decode_forward(params, tokens, lens, ck, cv, cfg, **state, **f32)
     close(step[1], want)
-    if cfg.latent or cfg.cca:
-        what = "latent" if cfg.latent else "CCA"
+    if cfg.latent or cfg.cca or cfg.eva:
+        what = "latent" if cfg.latent else "CCA" if cfg.cca else "EVA"
         for refused in (
             lambda: verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32),
             lambda: draft_propose(params, tokens, lens, ck, cv, cfg, k_steps=K,

@@ -960,3 +960,128 @@ def test_zaya_decode_step_carries_ring_and_state_in_place(chip, monkeypatch):
     ]
     print(f"tied table re-ordered in the decode step: {len(reordered)} operation(s): {reordered}")
     assert len(reordered) <= 1
+
+
+# ---------------------------------------------------------------------------
+# the EvaByte cell (ISSUE 40): 8 of 32 layers at published widths, 24 slots
+# of a 2,048-row window ring beside a 384-row pooled ring, one bucket of
+# 4,096. The decode step runs the decode kernel over both rings and moves
+# neither; the prefill holds no score block wider than a window and the
+# pooled rows before it
+# ---------------------------------------------------------------------------
+
+
+def _eva_cell(chip):
+    """-> (configuration, engine options, the carried state as shapes: the
+    window's ring twice, the pooled ring twice, the pooling's stats)."""
+    from opendiloco_tpu.models.ring_cache import eva_pooled_rows
+
+    cfg, engine = _serve_cell("evabyte-6.5b", "serve-evabyte-complete")
+    L, slots = cfg.num_hidden_layers, engine["num_slots"]
+    ring = jax.ShapeDtypeStruct(
+        cache_shape(L, slots, cfg.window_size, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip)
+    pooled = jax.ShapeDtypeStruct(
+        cache_shape(L, slots, eva_pooled_rows(cfg, engine["max_context"]), cfg.kv_heads, cfg.head_dim),
+        BF16, sharding=chip)
+    stats = jax.ShapeDtypeStruct(
+        (L, slots, cfg.kv_heads, 2 * cfg.head_dim + 2), jnp.float32, sharding=chip)
+    return cfg, engine, (ring, ring, pooled, pooled, stats)
+
+
+def test_eva_decode_step_moves_neither_ring(chip, monkeypatch):
+    """The engine's own decode program (``serving_programs``) at 24 slots: the
+    decode kernel over the window's ring and its pooled form over the pooled
+    ring are both in it; both rings and the stats alias the outputs; no copy,
+    transpose, scatter, slice, update or fresh buffer has the shape of either
+    ring or of one layer's pages; no weight is cast; arguments and temporaries
+    fit the chip."""
+    from opendiloco_tpu.serve.engine import serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, carried = _eva_cell(chip)
+    assert (cfg.kv_heads, cfg.head_dim, cfg.window_size, cfg.eva_chunks_per_window) == (32, 128, 2048, 128)
+    assert carried[2].shape[-1] == 384
+    params = _bound(chip, cfg)
+    _, decode, _, n = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+    assert n == 5
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(decode, donate_argnums=tuple(range(4, 9)))
+        .lower(params, vec, vec, vec, *carried).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "odtp_eva_pooled_attn" in text
+    held = sum(x.size * x.dtype.itemsize for x in carried)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 3_261_865_984 and held == 7_656_751_104
+    print(f"eva decode: arguments {mem.argument_size_in_bytes} temporaries {mem.temp_size_in_bytes} "
+          f"aliased {mem.alias_size_in_bytes} program {_program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < weights / 4
+    assert _program_bytes(compiled) < HBM_BYTES
+    assert not _cache_shaped_results(text, carried[0].shape)
+    assert not _cache_shaped_results(text, carried[2].shape)
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+
+
+@pytest.mark.parametrize("form", ["flash", "xla"])
+def test_eva_prefill_holds_no_score_block_wider_than_a_window(chip, form, monkeypatch):
+    """The 4,096 prefill: no array in it spans the bucket's positions twice
+    over the heads (a [4096, 4096] score block a head). As the chip runs it
+    (``eva_prefill_form``: "flash" at these shapes, whatever the decode
+    kernel) each window's own rows go through the flash kernel and only the
+    pooled rows before it are scored in XLA (128 columns); in the XLA form (a
+    window no tile divides; forced here) the widest score block is a window's
+    2,048 queries against 2,048 + 128 columns. It fits beside the resident
+    rings, and its insert writes both rings in place."""
+    from opendiloco_tpu.serve.engine import serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, carried = _eva_cell(chip)
+    assert decode_kernels.eva_prefill_form(cfg.window_size, cfg.head_dim) == "flash"
+    if form == "xla":
+        monkeypatch.setattr(decode_kernels, "eva_prefill_form", lambda *a, **kw: "xla")
+    params = _bound(chip, cfg)
+    prefill, _, admit_insert, _ = serving_programs(cfg, compute_dtype=BF16, decode_kernel="xla")
+    bucket = max(engine["prefill_buckets"])
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(prefill)
+        .lower(params, jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip), scalar)
+        .compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    shapes_in = [
+        tuple(int(d) for d in m.group(3).split(",") if d)
+        for m in map(_RESULT.match, text.splitlines()) if m
+    ]
+    # a head's scores: an array over the 32 heads with two dimensions of
+    # positions (a window's 2,048 or more); none spans the bucket twice over
+    scores = [s for s in shapes_in if cfg.num_attention_heads in s and sum(d >= 2048 for d in s) >= 2]
+    assert all(max(s) <= cfg.window_size + cfg.eva_chunks_per_window for s in scores), scores
+    assert ("odtp_flash_fwd" in text) == (form == "flash") == (not scores)
+    held = sum(x.size * x.dtype.itemsize for x in carried)
+    print(f"eva prefill ({form}): arguments {mem.argument_size_in_bytes} temporaries {mem.temp_size_in_bytes} "
+          f"program {_program_bytes(compiled):.0f}")
+    assert _program_bytes(compiled) + held < HBM_BYTES
+    L, Nkv, Dh = cfg.num_hidden_layers, cfg.kv_heads, cfg.head_dim
+    rows = jax.ShapeDtypeStruct((L, cfg.window_size, Nkv, Dh), BF16, sharding=chip)
+    pooled = jax.ShapeDtypeStruct((L, bucket // cfg.chunk_size, Nkv, Dh), BF16, sharding=chip)
+    chunk = jax.ShapeDtypeStruct((L, Nkv, 2 * Dh + 2), jnp.float32, sharding=chip)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    tok = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(admit_insert, donate_argnums=tuple(range(6)))
+        .lower(carried[0], carried[1], vec, *carried[2:], rows, rows, pooled, pooled, chunk,
+               tok, scalar).compile()
+    )
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    layer_pages_bytes = 2 * 2 * carried[0].size // L
+    assert mem.temp_size_in_bytes < layer_pages_bytes, mem.temp_size_in_bytes
+    moved = [
+        line for shape in (carried[0].shape, carried[2].shape)
+        for line in _cache_shaped_results(compiled.as_text(), shape)
+        if "dynamic-update-slice" not in line
+    ]
+    assert not moved, moved
