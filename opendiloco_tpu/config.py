@@ -405,7 +405,12 @@ class Config(BaseModel):
     attn_implementation: Literal["auto", "xla", "pallas", "ring"] = "auto"
     path_model: str = "configs/config_150m.json"
     # rematerialization policy: false/"none" (save everything), true/"full"
-    # (reference-style per-layer checkpointing), or "dots" (save MXU outputs,
+    # (reference-style per-layer checkpointing: a layer's input is kept, and
+    # beside it the attention kernel's output and log-sum-exp where the
+    # attention is the flash or a ring kernel, so the backward does not run
+    # that kernel again: B x T x Hq x Dh in the compute dtype and
+    # B x Hq x T float32 a layer and device, the gauge
+    # ``train_attn_residual_bytes``), or "dots" (save MXU outputs too,
     # recompute elementwise -- near-full memory savings without the extra
     # matmul forward)
     remat: Union[bool, Literal["none", "full", "dots", "dots_all"]] = True

@@ -837,28 +837,45 @@ def _init_zaya_leaf(name: str, key: jax.Array, leaf) -> jax.Array:
 # the rematerialization policy accepted everywhere a `remat` argument
 # appears (``_maybe_remat`` says what each value saves)
 RematPolicy = Union[bool, Literal["none", "full", "dots", "dots_all"]]
+# the names an attention implementation gives its output and its softmax
+# statistic (``checkpoint_name``), which every checkpointing policy keeps
+ATTN_RESIDUALS = ("attn_out", "attn_lse")
 
 
 def _maybe_remat(block, remat: RematPolicy):
     """Apply the rematerialization policy to a per-layer block function.
 
     remat=False/"none": save all activations (no recompute -- fastest when
-    they fit); True/"full": save only layer boundaries (reference-style full
-    checkpointing); "dots": save matmul/MXU outputs and recompute the cheap
-    elementwise chain (norms, rope, silu) -- recovers most of full remat's
-    memory while skipping the extra forward through the matmuls, which is
-    where ~all the FLOPs are."""
+    they fit); True/"full": save the layer boundaries (reference-style full
+    checkpointing) and, where the attention in the block tagged them, its
+    output and log-sum-exp (``ATTN_RESIDUALS``); "dots": save matmul/MXU
+    outputs too and recompute the cheap elementwise chain (norms, rope,
+    silu) -- recovers most of full remat's memory while skipping the extra
+    forward through the matmuls, which is where ~all the FLOPs are.
+
+    The two names are what the attention kernels' own VJPs keep
+    (ops/flash_attention._flash_fwd, both forms of ops/ring_attention):
+    they are custom calls and whole rings of ppermutes, so a policy that
+    drops them reruns the forward kernel in the backward only to rebuild
+    them. Saved, the backward still recomputes norms, projections, rotary
+    and the FFN, and the kernel runs once. The price per device and layer
+    is B x T x Hq x Dh elements of the compute dtype plus B x Hq x T
+    float32 (``attn_residual_bytes``): as much again as the layer's input
+    where Hq x Dh is the hidden size, and about twice that in the compiled
+    step's temporaries on a v5e (PERF.md section 4: the two training
+    cells before and after), so a job that sat at the memory limit under
+    ``remat=True`` no longer fits. Where nothing carries the names (XLA's
+    attention, a Mamba-2 mixer, the latent and the EVA form) the policy
+    keeps nothing and the program is the bare checkpoint's."""
     if remat in (False, None, "none"):
         return block
+    names = jax.checkpoint_policies.save_only_these_names(*ATTN_RESIDUALS)
     if remat in (True, "full"):
-        return jax.checkpoint(block)
+        return jax.checkpoint(block, policy=names)
     if remat in ("dots", "dots_all"):
-        # also save the flash-attention outputs (tagged in
-        # ops/flash_attention._flash_fwd): they are custom-calls, not dots,
-        # so the dots policy alone would rerun the whole forward kernel
-        # during backward just to rebuild its residuals. "dots_all" saves
-        # batched dots too (the XLA-attention score/weighted-sum matmuls),
-        # trading more HBM for less backward recompute
+        # "dots_all" saves batched dots too (the XLA-attention
+        # score/weighted-sum matmuls), trading more HBM for less backward
+        # recompute
         dots = (
             jax.checkpoint_policies.dots_saveable
             if remat == "dots_all"
@@ -866,14 +883,19 @@ def _maybe_remat(block, remat: RematPolicy):
         )
         return jax.checkpoint(
             block,
-            policy=jax.checkpoint_policies.save_from_both_policies(
-                dots,
-                jax.checkpoint_policies.save_only_these_names(
-                    "attn_out", "attn_lse"
-                ),
-            ),
+            policy=jax.checkpoint_policies.save_from_both_policies(dots, names),
         )
     raise ValueError(f"unknown remat policy {remat!r}")
+
+
+def attn_residual_bytes(cfg: LlamaConfig, batch: int, seq: int, dtype) -> int:
+    """Bytes of ``ATTN_RESIDUALS`` over the stack for ``batch`` rows of
+    ``seq`` tokens: a layer with attention keeps its output
+    [B, Hq, T, Dh] in ``dtype`` and its log-sum-exp [B, Hq, T] in float32,
+    a Mamba-2 layer nothing."""
+    layers = sum(mixer_of(kind) == "attention" for kind in cfg.layer_kinds)
+    per_row = cfg.head_dim * jnp.dtype(dtype).itemsize + 4
+    return layers * batch * seq * cfg.num_attention_heads * per_row
 
 
 def _rms_norm(x: jax.Array, weight: jax.Array, eps: float, unit_offset: bool = False) -> jax.Array:

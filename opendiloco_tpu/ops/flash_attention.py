@@ -447,10 +447,15 @@ def _flash(q, k, v, block_q, block_k, causal):
 
 def _flash_fwd(q, k, v, block_q, block_k, causal):
     out, lse = _fwd(q, k, v, block_q=block_q, block_k=block_k, causal=causal)
-    # tag the kernel outputs so selective remat policies (llama._maybe_remat
-    # "dots") can save them -- without these names the backward pass reruns
-    # the whole forward kernel just to rebuild its residuals
-    out = checkpoint_name(out, "attn_out")
+    # tag the kernel outputs so the remat policies (llama._maybe_remat) can
+    # save them -- without these names the backward pass reruns the whole
+    # forward kernel just to rebuild its residuals. ``out`` is tagged as
+    # [B, T, H * D], the form the output projection reads: kept in the
+    # kernel's layout, a head of 64 fills half of the chip's 128 lanes and
+    # a saved copy takes twice its bytes
+    b, h, t, d = out.shape
+    kept = checkpoint_name(out.transpose(0, 2, 1, 3).reshape(b, t, h * d), "attn_out")
+    out = kept.reshape(b, t, h, d).transpose(0, 2, 1, 3)
     lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, out, lse)
 

@@ -169,8 +169,9 @@ def ring_attention(
 
 def _ring_fwd(q, k, v, axis_name, causal):
     out, lse = _ring_forward(q, k, v, axis_name, causal)
-    # tag residuals so selective remat ("dots") saves them -- otherwise the
-    # backward pass replays the whole ring forward, ppermutes included
+    # tag residuals so the remat policies (llama._maybe_remat) save them --
+    # otherwise the backward pass replays the whole ring forward, ppermutes
+    # included
     out = checkpoint_name(out, "attn_out")
     lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, out, lse)

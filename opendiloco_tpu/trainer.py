@@ -24,6 +24,7 @@ from opendiloco_tpu import obs
 from opendiloco_tpu.models.llama import (
     LlamaConfig,
     RematPolicy,
+    attn_residual_bytes,
     causal_lm_loss,
     forward,
     init_params,
@@ -269,6 +270,9 @@ class InnerTrainer:
         # rides this to launch/land mid-phase fragment rounds without the
         # driver loop ever knowing.
         self._post_dispatch_hooks: list = []
+        # what the newest build of the train step keeps of its attention
+        # (``attn_residual_bytes``); the gauge ``train_attn_residual_bytes``
+        self.attn_residual_bytes = 0
 
         self.p_specs = param_specs(model_cfg, plan, for_params=True)
         params_shapes = jax.eval_shape(
@@ -498,10 +502,48 @@ class InnerTrainer:
                 return causal_lm_loss(logits, labels, pred_heads=heads) + moe_aux
             return causal_lm_loss(out, labels, pred_heads=heads)
 
+    def attn_residual_bytes_of(self, global_microbatch: int, seq: int) -> int:
+        """Bytes per device the step's checkpointing policy keeps of its
+        attention, beside each layer's input, from a microbatch's forward
+        to its backward (``llama._maybe_remat``): the kernel's output and
+        log-sum-exp of every layer that has attention, cut as the mesh
+        cuts the batch, the sequence (ring attention), the heads (tp,
+        where it divides them) and the layers (pp, whose stage holds them
+        for every tick of the schedule). 0 where the resolved
+        ``attn_impl`` tags nothing (``xla``, which the latent and the EVA
+        form resolve to) and where ``remat`` is off and everything is
+        kept anyway."""
+        tc, cfg, plan = self.tc, self.model_cfg, self.plan
+        if tc.remat in (False, None, "none") or tc.attn_impl not in ("pallas", "ring"):
+            return 0
+        size = lambda axis: plan.mesh.shape[axis] if axis else 1
+        pp, sp, tp = size(plan.pp_axis), size(plan.sp_axis), size(plan.tp_axis)
+        if tc.attn_impl == "pallas" and pp > 1 and plan.mesh.size > pp * sp:
+            return 0  # ``forward`` falls back to XLA's attention there
+        shards = plan.data_parallel_size * pp
+        if tc.attn_impl == "ring":
+            shards *= sp
+        if cfg.num_attention_heads % tp == 0 and cfg.num_key_value_heads % tp == 0:
+            shards *= tp
+        rows = global_microbatch
+        if pp > 1:  # a stage keeps each tick's microbatch, fill and drain too
+            m = tc.pp_microbatches or pp
+            rows = rows // m * (m + pp - 1)
+        return attn_residual_bytes(cfg, rows, seq, tc.compute_dtype) // shards
+
     def _train_step_impl(self, state: dict, batch: dict):
         """batch arrays are [accum, global_microbatch, seq]."""
         params = state["params"]
-        accum = batch["input_ids"].shape[0]
+        accum, microbatch, seq = batch["input_ids"].shape
+        # while the step is traced: once a compiled shape
+        self.attn_residual_bytes = self.attn_residual_bytes_of(microbatch, seq)
+        obs.gauge("train_attn_residual_bytes", self.attn_residual_bytes)
+        log.info(
+            "train step for %d x %d x %d tokens: attn_impl=%s remat=%s "
+            "train_attn_residual_bytes=%d fused_loss=%s scan_unroll=%s",
+            accum, microbatch, seq, self.tc.attn_impl, self.tc.remat,
+            self.attn_residual_bytes, self.tc.fused_loss, self.tc.scan_unroll,
+        )
         scale = state["scaler"]["scale"]
 
         def scaled_loss(p, ids, labels):

@@ -124,6 +124,85 @@ def test_remat_policies_forward_and_grad_parity(tiny_cfg, remat):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
+def _kernel_calls(jaxpr, name: str) -> int:
+    """Pallas calls named ``name`` in a jaxpr, whatever they are nested in."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and name in str(eqn.params.get("name")):
+            n += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _kernel_calls(sub, name)
+    return n
+
+
+@pytest.fixture
+def bare_checkpoint(monkeypatch):
+    """``remat=True`` as the parent of ISSUE 41 had it: a checkpoint that
+    keeps the layer's input and nothing else."""
+    from opendiloco_tpu.models import llama
+
+    def arm():
+        monkeypatch.setattr(
+            llama, "_maybe_remat", lambda block, remat: jax.checkpoint(block)
+        )
+
+    return arm
+
+
+def test_full_remat_keeps_the_flash_kernels_output_and_runs_it_once(
+    tiny_cfg, monkeypatch, bare_checkpoint
+):
+    """Under ``remat=True`` the gradient holds ``odtp_flash_fwd`` once a
+    layer's body (the backward has the tagged ``attn_out`` / ``attn_lse``
+    at hand), twice under a bare ``jax.checkpoint``; and what it computes is
+    what no rematerialisation computes, bit for bit."""
+    import jax.experimental.pallas as pl
+    from opendiloco_tpu.ops import flash_attention as fa
+
+    orig = pl.pallas_call
+    monkeypatch.setattr(
+        fa.pl, "pallas_call", lambda *a, **kw: orig(*a, **{**kw, "interpret": True})
+    )
+    params = init_params(jax.random.key(0), tiny_cfg)
+    ids = jnp.arange(2 * 128, dtype=jnp.int32).reshape(2, 128) % tiny_cfg.vocab_size
+
+    def grad(remat):
+        return jax.grad(lambda p: causal_lm_loss(forward(
+            p, ids, tiny_cfg, compute_dtype=jnp.float32, attn_impl="pallas", remat=remat
+        ), ids))
+
+    calls = lambda remat, name: _kernel_calls(jax.make_jaxpr(grad(remat))(params).jaxpr, name)
+    assert calls(False, "odtp_flash_fwd") == 1
+    assert [calls(True, k) for k in ("odtp_flash_fwd", "odtp_flash_dq", "odtp_flash_dkv")] == [1, 1, 1]
+    kept, all_kept = jax.jit(grad(True))(params), jax.jit(grad(False))(params)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(all_kept)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    bare_checkpoint()
+    assert calls(True, "odtp_flash_fwd") == 2
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(jax.jit(grad(True))(params))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_full_remat_keeps_nothing_where_nothing_is_tagged(tiny_cfg, bare_checkpoint):
+    """XLA's attention names no value: under ``remat=True`` the gradient's
+    lowered program is the bare checkpoint's, letter for letter."""
+    params = init_params(jax.random.key(0), tiny_cfg)
+    ids = jnp.arange(2 * 16, dtype=jnp.int32).reshape(2, 16) % tiny_cfg.vocab_size
+
+    def lowered():
+        return jax.jit(jax.grad(lambda p: causal_lm_loss(forward(
+            p, ids, tiny_cfg, compute_dtype=jnp.float32, attn_impl="xla", remat=True
+        ), ids))).lower(params).as_text()
+
+    ours = lowered()
+    assert "optimization_barrier" in ours  # a checkpoint's mark: something is recomputed
+    bare_checkpoint()
+    assert ours == lowered()
+
+
 def test_remat_rejects_unknown_policy(tiny_cfg):
     params = init_params(jax.random.key(0), tiny_cfg)
     ids = jnp.zeros((1, 16), jnp.int32)

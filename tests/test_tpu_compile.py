@@ -11,8 +11,10 @@ means the chip run will not die at its first compile.
 
 The serving cells' decode step and insert are compiled whole (seconds each):
 what they must not hold is a copy of the ring cache (ISSUE 29), and only the
-compiled program says whether they do. Training's whole-step compiles (a
-minute each) stay in the builder's rehearsal.
+compiled program says whether they do. Training's step is compiled at the
+two training cells' block shapes over a cut of the layers (a quarter and
+half a minute): what it must hold is each attention kernel once (ISSUE 41).
+The whole-depth compiles (a minute each) stay in the builder's rehearsal.
 """
 
 import os
@@ -42,8 +44,8 @@ FFN = {"150m": (1024, 2688), "1b": (2048, 5632)}
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """A described (not attached) v5e chip, with the persistent compile
+def topo():
+    """A described (not attached) v5e:2x2 host, with the persistent compile
     cache off around the module: a deviceless executable can be written to
     the cache but not read back, and the next compile would warn."""
     from jax.experimental import topologies
@@ -62,9 +64,15 @@ def chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One chip of the described host."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def compiled_text(chip, fn, *shapes):
@@ -91,6 +99,49 @@ def test_flash_attention_fwd_bwd(chip, model):
     )
     # forward, dq and dk/dv kernels are all in the program
     assert text.count("tpu_custom_call") >= 3
+
+
+# the training cells' configuration, layout over the described chips and
+# global batch (benchmark/workloads/train-*.json), at seq 2,048
+TRAIN_CELLS = {
+    "train-360m-h16": ("smollm2-360m", "NO_SHARD", 1, 8),
+    "train-1.7b-fsdp4-h8": ("smollm2-1.7b", "FULL_SHARD", 4, 16),
+}
+
+
+@pytest.mark.parametrize("cell", list(TRAIN_CELLS))
+def test_train_step_runs_each_attention_kernel_once(topo, cell):
+    """The cell's train step under full remat, two of its layers under the
+    looped scan its depth resolves to: the forward scan's body holds
+    ``odtp_flash_fwd``, the backward scan's ``odtp_flash_dq`` and
+    ``odtp_flash_dkv``, and no second forward beside them (the kernel's
+    output and log-sum-exp come out of the forward scan). On four chips the
+    kernel runs through ``flash_attention_sharded`` under FULL_SHARD."""
+    import json
+    import pathlib
+
+    from opendiloco_tpu.models.llama import LlamaConfig
+    from opendiloco_tpu.parallel.mesh import build_mesh
+    from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
+
+    config, strategy, n, batch = TRAIN_CELLS[cell]
+    root = pathlib.Path(__file__).resolve().parents[1]
+    published = json.loads((root / "benchmark/configs" / f"{config}.json").read_text())
+    cfg = LlamaConfig.from_dict({**published, "num_hidden_layers": 2})
+    tc = TrainerConfig(precision="bf16-mixed", remat=True, attn_impl="pallas", scan_unroll=1)
+    trainer = InnerTrainer(cfg, tc, build_mesh(strategy, devices=list(topo.devices)[:n]))
+    text = trainer.lower_abstract(batch, 2048).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%(odtp_flash_\w+?)[.\d]* = .*custom-call\(", text, re.M)
+    assert sorted(calls) == ["odtp_flash_dkv", "odtp_flash_dq", "odtp_flash_fwd"]
+    rows = batch // n
+    heads = cfg.num_attention_heads
+    # what leaves the forward scan for the backward beside the layers' inputs:
+    # the log-sum-exp, and the output as [rows a chip, seq, heads x 64] (the
+    # shape of a layer's input here), not in the kernel's layout, whose 64
+    # lanes of 128 would double its bytes
+    assert f"f32[2,{rows},{heads},1,2048]" in text
+    assert f"bf16[2,{rows},{heads},2048,64]" not in text
+    assert trainer.attn_residual_bytes == 2 * rows * heads * 2048 * (64 * 2 + 4)
 
 
 def test_fused_xent_fwd_bwd(chip):

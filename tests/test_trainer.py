@@ -319,3 +319,60 @@ def test_scan_unroll_preserves_trajectory(tiny_cfg):
         return losses
 
     np.testing.assert_array_equal(run(1), run(4))
+
+
+# what full remat keeps of the attention kernel, per device and step, in the
+# two training cells of the benchmark (ISSUE 41): the cell's configuration,
+# strategy, devices, global batch -> layers x (out + lse) bytes a device
+ATTN_RESIDUALS_KEPT = {
+    # 32 x (8 x 2048 x 15 x 64 bf16 + 8 x 15 x 2048 float32)
+    "train-360m-h16": ("smollm2-360m", "NO_SHARD", 1, 8, 1_038_090_240),
+    # 24 x (4 x 2048 x 32 x 64 bf16 + 4 x 32 x 2048 float32)
+    "train-1.7b-fsdp4-h8": ("smollm2-1.7b", "FULL_SHARD", 4, 16, 830_472_192),
+}
+
+
+@pytest.mark.parametrize("cell", list(ATTN_RESIDUALS_KEPT))
+@pytest.mark.parametrize(
+    "remat,attn_impl,keeps",
+    [(True, "pallas", True), ("dots", "pallas", True), (True, "xla", False), (False, "pallas", False)],
+)
+def test_attn_residual_bytes_of_the_training_cells(cell, remat, attn_impl, keeps):
+    import json
+    import pathlib
+
+    from opendiloco_tpu.models.llama import LlamaConfig
+
+    config, strategy, n, batch, kept = ATTN_RESIDUALS_KEPT[cell]
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = LlamaConfig.from_dict(json.loads((root / "benchmark/configs" / f"{config}.json").read_text()))
+    tc = TrainerConfig(precision="bf16-mixed", remat=remat, attn_impl=attn_impl)
+    trainer = InnerTrainer(cfg, tc, build_mesh(strategy, devices=jax.devices()[:n]))
+    assert trainer.attn_residual_bytes_of(batch, 2048) == (kept if keeps else 0)
+
+
+def test_building_the_step_sets_the_attention_gauge(tiny_cfg, monkeypatch, caplog):
+    """Tracing the train step (which is when it is built) leaves what the
+    policy keeps for attention on the trainer, in the gauge and on the log
+    line beside ``remat``."""
+    from opendiloco_tpu import obs, trainer as trainer_module
+
+    monkeypatch.setenv("ODTP_OBS", "test")
+    obs.reset()
+    trainer_module.log.addHandler(caplog.handler)  # the text logger does not propagate
+    try:
+        tc = TrainerConfig(precision="bf16-mixed", remat=True, attn_impl="pallas")
+        trainer = InnerTrainer(tiny_cfg, tc, build_mesh("FULL_SHARD", devices=jax.devices()[:2]))
+        assert trainer.attn_residual_bytes == 0
+        jax.make_jaxpr(trainer._train_step_impl)(
+            jax.eval_shape(trainer.init_state, jax.random.key(0)),
+            {k: jax.ShapeDtypeStruct((2, 4, 128), np.int32) for k in ("input_ids", "labels")},
+        )
+        # 2 layers x 2 of 4 rows x 128 tokens x 4 heads x (16 bf16 + one float32)
+        kept = 2 * 2 * 128 * 4 * (16 * 2 + 4)
+        assert trainer.attn_residual_bytes == kept
+        assert obs.tracer().gauges()[("train_attn_residual_bytes", ())] == kept
+        assert f"remat=True train_attn_residual_bytes={kept}" in caplog.text
+    finally:
+        trainer_module.log.removeHandler(caplog.handler)
+        obs.reset()
