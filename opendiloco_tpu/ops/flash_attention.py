@@ -14,10 +14,19 @@ independent of T, so sequence length is bounded by HBM, not VMEM. GQA is
 handled in the BlockSpec index maps (q-head h reads kv-head h // rep) --
 KV is never materialized at q-head width.
 
-Causal blocks above the diagonal are skipped with pl.when, and their
+Causal tiles above the diagonal are skipped with pl.when, and their
 BlockSpec index maps clamp to the last needed tile so the revisited block
 index elides the DMA too -- a skipped step costs neither compute nor HBM
-traffic, only a grid step.
+traffic, only a grid step. Inside a grid step the ``[block_q, block_k]``
+DMA tile is classified again (``_tiles``): a tile wholly on or below the
+diagonal runs with no mask at all, and a tile the diagonal crosses is
+walked as 128 x 128 compute sub-tiles (``_diagonal_items``): those above
+the diagonal are not computed, those below run unmasked, and only the
+ones on it meet a mask, whose offsets are static. ``causal_plan`` counts
+what that comes to a head. The walk needs equal blocks; unequal ones
+(only reachable through OPENDILOCO_TPU_FLASH_BLOCKS) keep their crossed
+tiles whole under the mask, and full attention (``causal=False``: ring
+attention's off-diagonal chunks) has no crossed tile.
 
 Backward follows the standard FA2 recompute scheme: delta = rowsum(dO * O),
 one kernel for dq (streaming k blocks), one for dk/dv (streaming q blocks,
@@ -27,7 +36,9 @@ accumulating over the rep q-heads of each kv head).
 from __future__ import annotations
 
 import functools
+import math
 import os
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +50,158 @@ from opendiloco_tpu.ops.pallas_util import (
     NEG_INF as _NEG_INF,
     pick_block as _pick_block,
 )
+
+
+# ---------------------------------------------------------------------------
+# where the diagonal is
+# ---------------------------------------------------------------------------
+
+# rows (= columns) of the compute sub-tile a diagonal tile is walked in: the
+# 128 lanes of a vector register, whatever the block and the head size. On
+# the chip (PR 42, TPU v5e) 128 read fastest of 128 / 256 / 512 in all three
+# kernels at d = 64 and in the forward at d = 128
+_SUB_TILE = 128
+# query rows an unmasked tile's forward update takes at a time: two halves
+# of a 1,024-row tile run 10% faster than the whole (one half's matmuls
+# beside the other's softmax); the backward kernels read the same either way
+_FWD_ROWS = 512
+
+
+class CausalPlan(NamedTuple):
+    """What one head's ``[T, T]`` scores cost under ``causal_plan``'s
+    arguments, counted in units of ``unit[0] x unit[1]`` score elements:
+    the walk's ``c x c`` sub-tiles where diagonal tiles are walked, else
+    whole ``[block_q, block_k]`` tiles (``c`` = 0)."""
+
+    c: int
+    unit: tuple
+    computed: int  # matmuls, exps and accumulation run
+    masked: int  # of those, the units the diagonal crosses: under the mask
+    skipped: int  # no work at all (a skipped tile's grid step still passes)
+    computed_share: float  # score elements computed over T x T
+    masked_share: float
+
+    def __str__(self) -> str:  # the train step's log line
+        return (
+            f"computed_share={self.computed_share:.5f} masked_share={self.masked_share:.5f} "
+            f"sub_tile={self.c} computed={self.computed} masked={self.masked} "
+            f"skipped={self.skipped} of {self.unit[0]}x{self.unit[1]} a head"
+        )
+
+
+def causal_plan(t: int, block_q: int, block_k: int, causal: bool) -> CausalPlan:
+    """The three kernels' work a head, from shapes alone (they classify
+    tiles alike): ``InnerTrainer`` logs it and the tests count with it."""
+    nq, nk = t // block_q, t // block_k
+    if not causal:
+        return CausalPlan(0, (block_q, block_k), nq * nk, 0, 0, 1.0, 0.0)
+    below = crossed = 0
+    for q_lo in range(0, t, block_q):
+        for k_lo in range(0, t, block_k):
+            is_below, is_crossed = _tile_classes(q_lo, block_q, k_lo, block_k)
+            below += is_below
+            crossed += is_crossed
+    if block_q == block_k:  # crossed tiles are walked
+        c = _SUB_TILE  # divides every block: they are multiples of 128
+        n = block_q // c
+        unit = (c, c)
+        computed = below * n * n + crossed * n * (n + 1) // 2
+        masked = crossed * n
+        total = nq * nk * n * n
+    else:  # a crossed tile is computed and masked whole
+        c, unit = 0, (block_q, block_k)
+        computed, masked, total = below + crossed, crossed, nq * nk
+    return CausalPlan(
+        c, unit, computed, masked, total - computed,
+        computed / total, masked / total,
+    )
+
+
+def _scale_on_operand(scale: float) -> bool:
+    """A power of two (d = 64: 1/8) multiplies a ``[block, d]`` operand
+    instead of the ``[block_q, block_k]`` scores: every product is the same
+    bit for bit and one pass over the tile goes. Elsewhere (d = 128) the
+    scale stays on the float32 scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _tile_classes(q_lo, block_q, k_lo, block_k):
+    """(wholly on or below the diagonal, crossed by it) for the tile whose
+    first query row is ``q_lo`` and first key column ``k_lo``; Python ints
+    or traced grid indices."""
+    below = k_lo + block_k - 1 <= q_lo
+    reached = k_lo <= q_lo + block_q - 1  # true of every tile below
+    return below, reached != below
+
+
+def _lower_triangle(rows: int, cols: int, q_lo=0, k_lo=0, keys_first: bool = False):
+    """q_pos >= k_pos over a ``[rows, cols]`` tile of scores (query rows by
+    key columns; ``keys_first``: key rows by query columns). The offsets of
+    a walk's sub-tiles are static, and the compiler folds their masks."""
+    q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), int(keys_first))
+    k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), int(not keys_first))
+    return q_pos >= k_pos
+
+
+def _diagonal_items(block: int, keys_first: bool = False) -> list:
+    """The walk of a ``[block, block]`` tile on the diagonal, as (slice,
+    slice, mask) items. By query rows (forward, dq): sub-block i against the
+    key columns up to its own. ``keys_first`` (dkv): key sub-block j against
+    the query rows from its own on. Either way the sub-tiles above the
+    diagonal are in no item, and the mask touches the diagonal's alone."""
+    c = _SUB_TILE
+    items = []
+    for i in range(block // c):
+        own = slice(i * c, (i + 1) * c)
+        if keys_first:
+            mask = _lower_triangle(c, block - i * c, keys_first=True)
+            items.append((own, slice(i * c, block), mask))
+        else:
+            mask = _lower_triangle(c, (i + 1) * c, q_lo=i * c)
+            items.append((own, slice(0, (i + 1) * c), mask))
+    return items
+
+
+def _run(phases, items) -> None:
+    """Run the generator ``phases(*item)`` of every item phase by phase:
+    all items' first phase, then all items' second... The items are
+    independent and each of an item's phases waits for the one before, so
+    this order lets one item's matmuls run beside another's vector work
+    (the forward's walk, item by item: 1.88 ms a call; so: 1.45)."""
+    live, done = [phases(*item) for item in items], object()
+    while live:
+        live = [g for g in live if next(g, done) is not done]
+
+
+def _tiles(phases, causal, q_lo, block_q, k_lo, block_k, whole, keys_first=False):
+    """One grid step of a kernel whose update of one item is the generator
+    ``phases``: the ``whole`` items, unmasked, for a tile wholly on or below
+    the diagonal (and for every tile of full attention); for a tile the
+    diagonal crosses, the walk where the blocks are equal, else the whole
+    tile under its mask; nothing for a tile above the diagonal."""
+    below, crossed = _tile_classes(q_lo, block_q, k_lo, block_k)
+    pl.when(jnp.logical_or(not causal, below))(lambda: _run(phases, whole))
+    if not causal:
+        return
+
+    @pl.when(crossed)
+    def _diagonal():
+        if block_q == block_k:
+            return _run(phases, _diagonal_items(block_q, keys_first))
+        first, second = (block_k, block_q) if keys_first else (block_q, block_k)
+        mask = _lower_triangle(first, second, q_lo, k_lo, keys_first)
+        _run(phases, [(slice(0, first), slice(0, second), mask)])
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+_NT = (1, 1)  # a @ b.T
+_NN = (1, 0)  # a @ b
 
 
 # ---------------------------------------------------------------------------
@@ -54,6 +217,7 @@ def _fwd_kernel(
     block_q, d = q_ref.shape
     block_k = k_ref.shape[0]
     qi, ki = pl.program_id(2), pl.program_id(3)
+    on_q = _scale_on_operand(scale)
 
     @pl.when(ki == 0)
     def _init():
@@ -61,39 +225,33 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros((block_q, 1), jnp.float32)
         acc_scr[:] = jnp.zeros((block_q, d), jnp.float32)
 
-    # causal: tiles fully above the diagonal contribute nothing
-    diag_ok = (ki * block_k) <= (qi * block_q + block_q - 1)
-
-    @pl.when(jnp.logical_or(not causal, diag_ok))
-    def _step():
-        # matmul inputs stay in bf16 (f32 inputs run the MXU at ~1/8 rate on
-        # v5e); accumulation and softmax statistics are f32
-        q = q_ref[:]
-        k_blk = k_ref[:]
-        v_blk = v_ref[:]
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev, l_prev, acc = m_scr[:], l_scr[:], acc_scr[:]
+    def phases(rows, cols, mask):
+        """One online-softmax update of query rows ``rows`` against key
+        columns ``cols``. Matmul inputs stay in bf16 (f32 inputs run the MXU
+        at ~1/8 rate on v5e); accumulation and softmax statistics are f32."""
+        q = q_ref[rows, :]
+        q = q * scale if on_q else q
+        s = _dot(q, k_ref[cols, :], _NT)  # [rows, cols]
+        s = s if on_q else scale * s
+        s = s if mask is None else jnp.where(mask, s, _NEG_INF)
+        yield
+        m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_scr[rows, :] = m_new
         corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype),
-            v_blk,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        yield
+        p = jnp.exp(s - m_new)
+        yield
+        l_scr[rows, :] = l_scr[rows, :] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[rows, :] = acc_scr[rows, :] * corr + _dot(
+            p.astype(v_ref.dtype), v_ref[cols, :], _NN
         )
+
+    rows = block_q if block_q % _FWD_ROWS else _FWD_ROWS
+    _tiles(
+        phases, causal, qi * block_q, block_q, ki * block_k, block_k,
+        [(slice(i, i + rows), slice(0, block_k), None) for i in range(0, block_q, rows)],
+    )
 
     @pl.when(ki == num_k - 1)
     def _finish():
@@ -199,40 +357,32 @@ def _dq_kernel(
     block_q, d = q_ref.shape
     block_k = k_ref.shape[0]
     qi, ki = pl.program_id(2), pl.program_id(3)
+    on_q = _scale_on_operand(scale)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros((block_q, d), jnp.float32)
 
-    diag_ok = (ki * block_k) <= (qi * block_q + block_q - 1)
+    def phases(rows, cols, mask):
+        """dq of query rows ``rows`` from key columns ``cols``."""
+        n = rows.stop - rows.start
+        q = q_ref[rows, :]
+        q = q * scale if on_q else q
+        k_blk = k_ref[cols, :]
+        s = _dot(q, k_blk, _NT)
+        s = s if on_q else scale * s
+        dp = _dot(do_ref[rows, :], v_ref[cols, :], _NT)
+        yield
+        s = s if mask is None else jnp.where(mask, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[:, rows].reshape(n, 1))
+        ds = (p * (dp - delta_ref[:, rows].reshape(n, 1))).astype(k_blk.dtype)
+        yield
+        dq_scr[rows, :] = dq_scr[rows, :] + scale * _dot(ds, k_blk, _NN)
 
-    @pl.when(jnp.logical_or(not causal, diag_ok))
-    def _step():
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:].reshape(block_q, 1)
-        delta = delta_ref[:].reshape(block_q, 1)
-        k_blk = k_ref[:]
-        v_blk = v_ref[:]
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta)).astype(k_blk.dtype)
-        dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _tiles(
+        phases, causal, qi * block_q, block_q, ki * block_k, block_k,
+        [(slice(0, block_q), slice(0, block_k), None)],
+    )
 
     @pl.when(ki == num_k - 1)
     def _finish():
@@ -244,51 +394,43 @@ def _dkv_kernel(
     dk_scr, dv_scr, *, scale, causal, rep, num_q
 ):
     # grid point: (batch, kv-head, k-block, rep*q-block). q/do: [1, block_q, d]
-    # per step; k/v/dk/dv: [block_k, d]; lse/delta: [1, block_q]
+    # per step; k/v/dk/dv: [block_k, d]; lse/delta: [1, 1, block_q]
     block_k, d = k_ref.shape
     block_q = q_ref.shape[1]
     ki, step = pl.program_id(2), pl.program_id(3)
     qj = step % num_q  # q-block index within a head
+    on_q = _scale_on_operand(scale)
 
     @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros((block_k, d), jnp.float32)
         dv_scr[:] = jnp.zeros((block_k, d), jnp.float32)
 
-    # causal: only q blocks at or after this k block contribute
-    diag_ok = (qj * block_q + block_q - 1) >= (ki * block_k)
+    def phases(cols, rows, mask):
+        """dk and dv of key rows ``cols`` from query rows ``rows``. The
+        scores are computed transposed, ``[key rows, query rows]``: every
+        matmul then has the MXU's own forms (a @ b.T, a @ b), and the
+        lane-dense ``lse`` and ``delta`` rows are read as they lie."""
+        k_blk = k_ref[cols, :]
+        k_blk = k_blk * scale if on_q else k_blk
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        s = _dot(k_blk, q, _NT)  # [cols, rows]
+        s = s if on_q else scale * s
+        dp = _dot(v_ref[cols, :], do, _NT)
+        yield
+        s = s if mask is None else jnp.where(mask, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, :, rows])
+        ds = (p * (dp - delta_ref[0, :, rows])).astype(q.dtype)
+        yield
+        dv_scr[cols, :] = dv_scr[cols, :] + _dot(p.astype(do.dtype), do, _NN)
+        dk_scr[cols, :] = dk_scr[cols, :] + scale * _dot(ds, q, _NN)
 
-    @pl.when(jnp.logical_or(not causal, diag_ok))
-    def _step():
-        k_blk = k_ref[:]
-        v_blk = v_ref[:]
-        q_blk = q_ref[0]
-        do_blk = do_ref[0]
-        lse_blk = lse_ref[:].reshape(block_q, 1)
-        delta_blk = delta_ref[:].reshape(block_q, 1)
-        s = scale * jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            q_pos = qj * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse_blk)
-        pb = p.astype(do_blk.dtype)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            pb, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta_blk)).astype(q_blk.dtype)
-        dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    # causal: only q blocks at or after this k block contribute
+    _tiles(
+        phases, causal, qj * block_q, block_q, ki * block_k, block_k,
+        [(slice(0, block_k), slice(0, block_q), None)], keys_first=True,
+    )
 
     @pl.when(step == rep * num_q - 1)
     def _finish():
@@ -463,22 +605,10 @@ def _flash_fwd(q, k, v, block_q, block_k, causal):
 _flash.defvjp(_flash_fwd, _bwd)
 
 
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    block_q: int = 1024,
-    block_k: int = 1024,
-) -> jax.Array:
-    """[B, T, H, D] attention via the Pallas kernel; falls back to XLA for
-    shapes the kernel doesn't tile (T not a multiple of 128).
-
-    Blocks default large (1024x1024, on-chip-swept): per-grid-step fixed cost
-    dominates at small tiles on TPU, and VMEM per step is only O(block*d) +
-    the [bq, bk] f32 score tile, so these fit VMEM comfortably."""
-    b, t, hq, d = q.shape
+def _resolve_blocks(t: int, d: int, block_q: int = 1024, block_k: int = 1024) -> tuple:
+    """The blocks ``flash_attention`` runs a sequence of ``t`` rows in, under
+    OPENDILOCO_TPU_FLASH_BLOCKS if set; (0, 0) where the kernel does not tile
+    the shape and XLA's attention runs instead."""
     env = os.environ.get("OPENDILOCO_TPU_FLASH_BLOCKS")  # tuning: "bq,bk"
     if env:
         try:
@@ -496,6 +626,38 @@ def flash_attention(
     block_q = _pick_block(t, block_q)
     block_k = _pick_block(t, block_k)
     if block_q == 0 or block_k == 0 or d % 8 != 0:
+        return 0, 0
+    return block_q, block_k
+
+
+def plan_of(t: int, d: int, causal: bool = True) -> Optional[CausalPlan]:
+    """``causal_plan`` of what ``flash_attention`` does with ``t`` rows of
+    heads of ``d``; None where it hands the shape to XLA's attention."""
+    block_q, block_k = _resolve_blocks(t, d)
+    return causal_plan(t, block_q, block_k, causal) if block_q else None
+
+
+def flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = True,
+    block_q: int = 1024,
+    block_k: int = 1024,
+) -> jax.Array:
+    """[B, T, H, D] attention via the Pallas kernel; falls back to XLA for
+    shapes the kernel doesn't tile (T not a multiple of 128).
+
+    Blocks default large, 1024 x 1024: a grid step has a fixed cost, and VMEM
+    per step is only O(block*d) + the [bq, bk] f32 score tile. What that
+    rests on for these kernels (PR 42, TPU v5e, seq 2,048, 15/5 heads of 64,
+    batch 8, before the sub-tile walk): at 512 x 512 the forward read 2.59 ms
+    a call against 1.83, dq 1.93 against 1.82, dkv 2.41 against 2.23. No
+    other block shape was measured on this chip for this shape, and none
+    since the walk (PERF.md section 6, PR 42)."""
+    block_q, block_k = _resolve_blocks(q.shape[1], q.shape[3], block_q, block_k)
+    if block_q == 0:
         from opendiloco_tpu.ops.attention import xla_attention
 
         return xla_attention(q, k, v, causal=causal)

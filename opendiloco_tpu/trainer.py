@@ -273,6 +273,9 @@ class InnerTrainer:
         # what the newest build of the train step keeps of its attention
         # (``attn_residual_bytes``); the gauge ``train_attn_residual_bytes``
         self.attn_residual_bytes = 0
+        # and what its attention kernels compute of a head's scores
+        # (``attn_scores_plan_of``): the gauges ``train_attn_scores_*_share``
+        self.attn_scores_plan = None
 
         self.p_specs = param_specs(model_cfg, plan, for_params=True)
         params_shapes = jax.eval_shape(
@@ -518,7 +521,7 @@ class InnerTrainer:
             return 0
         size = lambda axis: plan.mesh.shape[axis] if axis else 1
         pp, sp, tp = size(plan.pp_axis), size(plan.sp_axis), size(plan.tp_axis)
-        if tc.attn_impl == "pallas" and pp > 1 and plan.mesh.size > pp * sp:
+        if tc.attn_impl == "pallas" and not self._flash_over_whole_rows():
             return 0  # ``forward`` falls back to XLA's attention there
         shards = plan.data_parallel_size * pp
         if tc.attn_impl == "ring":
@@ -531,6 +534,27 @@ class InnerTrainer:
             rows = rows // m * (m + pp - 1)
         return attn_residual_bytes(cfg, rows, seq, tc.compute_dtype) // shards
 
+    def _flash_over_whole_rows(self) -> bool:
+        """Whether a layer's attention is ``flash_attention`` over each
+        row's whole sequence: ``attn_impl=pallas``, but where ``forward``
+        falls back to XLA's attention under a composed pipeline."""
+        plan = self.plan
+        size = lambda axis: plan.mesh.shape[axis] if axis else 1
+        pp, sp = size(plan.pp_axis), size(plan.sp_axis)
+        return self.tc.attn_impl == "pallas" and not (pp > 1 and plan.mesh.size > pp * sp)
+
+    def attn_scores_plan_of(self, seq: int):
+        """What the step's attention kernels compute of a head's ``seq x
+        seq`` scores (``flash_attention.CausalPlan``: the sub-tile, and the
+        sub-tiles computed, masked and skipped); None where the attention
+        is not the flash kernel over whole rows (XLA's, the ring's chunks)
+        or the kernel does not tile ``seq``."""
+        if not self._flash_over_whole_rows():
+            return None
+        from opendiloco_tpu.ops.flash_attention import plan_of
+
+        return plan_of(seq, self.model_cfg.head_dim)
+
     def _train_step_impl(self, state: dict, batch: dict):
         """batch arrays are [accum, global_microbatch, seq]."""
         params = state["params"]
@@ -538,11 +562,17 @@ class InnerTrainer:
         # while the step is traced: once a compiled shape
         self.attn_residual_bytes = self.attn_residual_bytes_of(microbatch, seq)
         obs.gauge("train_attn_residual_bytes", self.attn_residual_bytes)
+        self.attn_scores_plan = scores = self.attn_scores_plan_of(seq)
+        if scores is not None:
+            obs.gauge("train_attn_scores_computed_share", scores.computed_share)
+            obs.gauge("train_attn_scores_masked_share", scores.masked_share)
         log.info(
             "train step for %d x %d x %d tokens: attn_impl=%s remat=%s "
-            "train_attn_residual_bytes=%d fused_loss=%s scan_unroll=%s",
+            "train_attn_residual_bytes=%d train_attn_scores=%s fused_loss=%s "
+            "scan_unroll=%s",
             accum, microbatch, seq, self.tc.attn_impl, self.tc.remat,
-            self.attn_residual_bytes, self.tc.fused_loss, self.tc.scan_unroll,
+            self.attn_residual_bytes, scores or "not the flash kernel's",
+            self.tc.fused_loss, self.tc.scan_unroll,
         )
         scale = state["scaler"]["scale"]
 

@@ -38,32 +38,157 @@ def interpret_pallas(monkeypatch):
     return patched
 
 
-def test_flash_forward_matches_xla(qkv, interpret_pallas):
+# the cases the kernels' tile walk distinguishes (flash_attention._tiles):
+# (batch, seq, heads, kv heads, head size, causal, OPENDILOCO_TPU_FLASH_BLOCKS).
+# The compute sub-tile is 128 rows, so a 256-row block walks 2 x 2 sub-tiles
+WALK_CASES = {
+    "one-diagonal-tile": (2, 256, 4, 2, 64, True, None),
+    "diagonal-and-full-tiles-2x-rep3": (1, 512, 3, 1, 64, True, "256,256"),
+    "diagonal-and-full-tiles-4x-rep1": (1, 1024, 2, 2, 64, True, "256,256"),
+    "block-is-one-sub-tile": (1, 256, 2, 1, 64, True, "128,128"),
+    "d128-scale-on-the-scores": (1, 512, 2, 1, 128, True, "256,256"),
+    "full-attention": (1, 512, 2, 1, 64, False, "256,256"),
+    "unequal-blocks-q-under-k": (1, 512, 2, 1, 64, True, "128,256"),
+    "unequal-blocks-q-over-k": (1, 512, 2, 1, 64, True, "256,128"),
+    "block-of-five-sub-tiles": (1, 1280, 1, 1, 64, True, "640,640"),  # no multiple of 512
+}
+
+
+@pytest.fixture(params=list(WALK_CASES))
+def walk_case(request, monkeypatch):
+    """-> (q, k, v, causal) of one of ``WALK_CASES``, its blocks in the
+    environment."""
+    b, t, h, hkv, d, causal, blocks = WALK_CASES[request.param]
+    if blocks:
+        monkeypatch.setenv("OPENDILOCO_TPU_FLASH_BLOCKS", blocks)
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, t, hkv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, t, hkv, d)), jnp.float32)
+    return q, k, v, causal
+
+
+def test_flash_forward_matches_xla(walk_case, interpret_pallas):
     from opendiloco_tpu.ops.flash_attention import flash_attention
 
-    q, k, v = qkv
-    ref = xla_attention(q, k, v, causal=True)
-    got = flash_attention(q, k, v, causal=True)
+    q, k, v, causal = walk_case
+    ref = xla_attention(q, k, v, causal=causal)
+    got = flash_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
 
 
-def test_flash_grads_match_xla(qkv, interpret_pallas):
+def _assert_grads_close(got, ref, atol):
+    for a, b in zip(ref, got):
+        scale = np.abs(np.asarray(a)).max()
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), atol=atol * max(scale, 1.0)
+        )
+
+
+def test_flash_grads_match_xla(walk_case, interpret_pallas):
     from opendiloco_tpu.ops.flash_attention import flash_attention
 
-    q, k, v = qkv
+    q, k, v, causal = walk_case
 
     def loss(fn, q, k, v):
-        return jnp.sum(fn(q, k, v, causal=True) ** 2)
+        return jnp.sum(fn(q, k, v, causal=causal) ** 2)
 
     gr = jax.grad(functools.partial(loss, xla_attention), argnums=(0, 1, 2))(q, k, v)
     gg = jax.grad(functools.partial(loss, flash_attention), argnums=(0, 1, 2))(
         q, k, v
     )
-    for a, b in zip(gr, gg):
-        scale = np.abs(np.asarray(a)).max()
-        np.testing.assert_allclose(
-            np.asarray(b), np.asarray(a), atol=2e-5 * max(scale, 1.0)
-        )
+    _assert_grads_close(gg, gr, 2e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_float32_grads_as_the_ring_asks(qkv, interpret_pallas, causal):
+    """``_bwd_impl(grad_dtype=float32)`` over bf16 operands, the ring's
+    chunk call: float32 gradients that match the bf16 ones to their
+    rounding, through the walk (the diagonal chunk) and without it."""
+    from opendiloco_tpu.ops import flash_attention as fa
+
+    qT, kT, vT = (x.astype(jnp.bfloat16).transpose(0, 2, 1, 3) for x in qkv)
+    out, lse = fa._fwd(qT, kT, vT, block_q=256, block_k=256, causal=causal)
+    dout = jnp.ones_like(out)
+    args = (qT, kT, vT, dout, lse, fa._delta(dout, out))
+    kwargs = dict(block_q=256, block_k=256, causal=causal)
+    wide = fa._bwd_impl(*args, grad_dtype=jnp.float32, **kwargs)
+    narrow = fa._bwd_impl(*args, **kwargs)
+    for w, n in zip(wide, narrow):
+        assert w.dtype == jnp.float32 and n.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(w.astype(jnp.bfloat16)), np.asarray(n))
+
+
+# NaN from row ``r`` on, ``r`` a multiple of the sub-tile that lies inside a
+# DMA tile: (seq, blocks, r)
+SKIPPED = {"inside-the-only-tile": (256, None, 128), "inside-the-second-tile": (512, "256,256", 384)}
+
+
+@pytest.mark.parametrize("case", list(SKIPPED))
+def test_flash_sub_tiles_above_the_diagonal_are_not_computed(interpret_pallas, monkeypatch, case):
+    """NaN in every K and V row at or after row r reaches no query row
+    before r, in the output or in dq: the sub-tiles above the diagonal are
+    skipped, not masked (a masked one gives p = 0 times a NaN of V, and
+    dO . V^T, which poison every row of the DMA tile)."""
+    from opendiloco_tpu.ops.flash_attention import flash_attention
+
+    t, blocks, r = SKIPPED[case]
+    if blocks:
+        monkeypatch.setenv("OPENDILOCO_TPU_FLASH_BLOCKS", blocks)
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(1, t, 2, 64)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, t, 1, 64)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, t, 1, 64)), jnp.float32)
+    k, v = k.at[:, r:].set(jnp.nan), v.at[:, r:].set(jnp.nan)
+
+    out = flash_attention(q, k, v, causal=True)
+    assert np.isfinite(np.asarray(out[:, :r])).all()
+    assert np.isnan(np.asarray(out[:, r:])).all()  # the NaN rows were read where they count
+    clean = xla_attention(q[:, :r], k[:, :r], v[:, :r], causal=True)
+    np.testing.assert_allclose(np.asarray(out[:, :r]), np.asarray(clean), atol=2e-5)
+
+    dq = jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, causal=True)[:, :r] ** 2))(q)
+    assert np.isfinite(np.asarray(dq[:, :r])).all()
+    ref = jax.grad(lambda q: jnp.sum(xla_attention(q, k[:, :r], v[:, :r], causal=True) ** 2))(q[:, :r])
+    _assert_grads_close([dq[:, :r]], [ref], 2e-5)
+
+
+# (seq, block_q, block_k, causal) -> (c, unit, computed, masked, skipped)
+PLANS = {
+    (2048, 1024, 1024, True): (128, (128, 128), 64 + 2 * 36, 16, 120),
+    (1024, 1024, 1024, True): (128, (128, 128), 36, 8, 28),
+    (4096, 1024, 1024, True): (128, (128, 128), 6 * 64 + 4 * 36, 32, 496),
+    (2048, 1024, 1024, False): (0, (1024, 1024), 4, 0, 0),
+    (2048, 512, 1024, True): (0, (512, 1024), 6, 4, 2),  # unequal: crossed tiles whole
+    (256, 128, 128, True): (128, (128, 128), 3, 2, 1),
+}
+
+
+@pytest.mark.parametrize("shape", list(PLANS), ids=lambda s: "-".join(map(str, s)))
+def test_causal_plan_counts(shape):
+    from opendiloco_tpu.ops.flash_attention import causal_plan
+
+    plan = causal_plan(*shape)
+    assert tuple(plan[:5]) == PLANS[shape]
+    t = shape[0]
+    area = plan.unit[0] * plan.unit[1]
+    assert plan.computed_share == plan.computed * area / t**2
+    assert plan.masked_share == plan.masked * area / t**2
+    assert (plan.computed + plan.skipped) * area == t**2
+
+
+def test_causal_plan_of_the_training_cells(monkeypatch):
+    """Seq 2,048 in 1,024-row blocks: 0.53125 of a head's scores computed
+    and 0.0625 under a mask; with no walk (the sub-tile the whole block)
+    the counts are the kernels' before it: 0.75 and 0.5."""
+    from opendiloco_tpu.ops import flash_attention as fa
+
+    plan = fa.plan_of(2048, 64)
+    assert (plan.computed_share, plan.masked_share) == (0.53125, 0.0625)
+    assert fa.plan_of(2048 + 8, 64) is None  # XLA's attention runs there
+    monkeypatch.setattr(fa, "_SUB_TILE", 1024)
+    plan = fa.plan_of(2048, 64)
+    assert (plan.computed_share, plan.masked_share) == (0.75, 0.5)
 
 
 def test_flash_fallback_small_seq(qkv):
@@ -243,13 +368,19 @@ def test_ring_attention_backward_no_repeat_gqa():
         )
 
 
+@pytest.mark.parametrize("blocks", [None, "256,256", "128,512", "512,256"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_streaming_multiblock_parity(interpret_pallas, causal):
-    """T=1024 -> 4 streamed k-blocks per q-block: exercises the scratch
-    carry across the sequential grid dimension (fwd + both bwd kernels),
-    both causal (clamped index maps) and full attention."""
+def test_flash_streaming_multiblock_parity(interpret_pallas, monkeypatch, causal, blocks):
+    """T=1024 in 256-row blocks -> 4 streamed k-blocks per q-block:
+    exercises the scratch carry across the sequential grid dimension (fwd +
+    both bwd kernels), both causal (clamped index maps; full tiles, walked
+    diagonal tiles and skipped ones in one row of the grid) and full
+    attention; in one 1,024-row tile, the default (the walk alone: 8 x 8
+    sub-tiles); and in unequal blocks (crossed tiles whole under the mask)."""
     from opendiloco_tpu.ops.flash_attention import flash_attention
 
+    if blocks:
+        monkeypatch.setenv("OPENDILOCO_TPU_FLASH_BLOCKS", blocks)
     rng = np.random.default_rng(5)
     B, T, H, HKV, D = 1, 1024, 4, 2, 32
     q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)

@@ -17,11 +17,13 @@ half a minute): what it must hold is each attention kernel once (ISSUE 41).
 The whole-depth compiles (a minute each) stay in the builder's rehearsal.
 """
 
+import functools
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -99,6 +101,70 @@ def test_flash_attention_fwd_bwd(chip, model):
     )
     # forward, dq and dk/dv kernels are all in the program
     assert text.count("tpu_custom_call") >= 3
+
+
+# (batch a chip, seq, query heads, kv heads, head size) the training kernels
+# meet in the benchmark: both train cells' shapes, and the forward alone at
+# EvaByte's prefill (two windows of 2,048 as a batch, heads of 128)
+FLASH_CELLS = {
+    "train-360m-h16": (8, 2048, 15, 5, 64),
+    "train-1.7b-fsdp4-h8": (4, 2048, 32, 32, 64),
+    "serve-evabyte-complete": (2, 2048, 32, 32, 128),
+}
+
+
+def _flash_kernels(text):
+    return sorted(re.findall(r"^\s*(?:ROOT )?%\w*?(odtp_flash_[a-z]+)[\w.]* = .*custom-call\(", text, re.M))
+
+
+@pytest.mark.parametrize("cell", list(FLASH_CELLS))
+def test_flash_kernels_compile_at_the_cells_shapes(chip, cell):
+    """The sub-tile walk at 1,024-row blocks (8 x 8 sub-tiles of 128 a
+    diagonal tile, unrolled) compiles for the chip in all three kernels at
+    the train cells' shapes, and in the forward, with its log-sum-exp, at
+    the serve cell's."""
+    from opendiloco_tpu.ops.flash_attention import flash_attention_lse
+
+    b, t, hq, hkv, d = FLASH_CELLS[cell]
+    shapes = (((b, t, hq, d), BF16), ((b, t, hkv, d), BF16), ((b, t, hkv, d), BF16))
+    if cell.startswith("serve"):
+        text = compiled_text(chip, functools.partial(flash_attention_lse, interpret=False), *shapes)
+        assert _flash_kernels(text) == ["odtp_flash_fwd"]
+        return
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    text = compiled_text(chip, jax.grad(loss, argnums=(0, 1, 2)), *shapes)
+    assert _flash_kernels(text) == ["odtp_flash_dkv", "odtp_flash_dq", "odtp_flash_fwd"]
+
+
+def test_ring_flash_chunks_compile(topo):
+    """Ring attention over four chips, the flash-chunk form: the diagonal
+    chunk through the causal kernels (the walk, float32 gradients, a
+    ``vma``), the chunks before it through the unmasked ones. Over a mesh
+    whose one axis is the ring's: under the trainer's four-axis mesh the
+    region is manual over ``sp`` alone and Mosaic refuses the kernel
+    ("cannot be automatically partitioned"), before PR 42 as after it
+    (PERF.md section 7)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from opendiloco_tpu.ops import ring_attention as ra
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("sp",))
+    assert ra._flash_chunk_block(mesh, "sp", jax.ShapeDtypeStruct((2, 8192, 4, 64), BF16), True) == 1024
+
+    def loss(q, k, v):
+        return ra.ring_attention_auto(q, k, v, mesh=mesh, axis="sp").astype(jnp.float32).sum()
+
+    on_ring = NamedSharding(mesh, P(None, "sp", None, None))
+    args = [
+        jax.ShapeDtypeStruct((2, 8192, h, 64), BF16, sharding=on_ring) for h in (4, 2, 2)
+    ]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+    # causal and full forms of each kernel: six calls, three names
+    assert sorted(set(_flash_kernels(text))) == ["odtp_flash_dkv", "odtp_flash_dq", "odtp_flash_fwd"]
+    assert len(_flash_kernels(text)) >= 6
 
 
 # the training cells' configuration, layout over the described chips and

@@ -351,6 +351,30 @@ def test_attn_residual_bytes_of_the_training_cells(cell, remat, attn_impl, keeps
     assert trainer.attn_residual_bytes_of(batch, 2048) == (kept if keeps else 0)
 
 
+@pytest.mark.parametrize("cell", list(ATTN_RESIDUALS_KEPT))
+@pytest.mark.parametrize("attn_impl", ["pallas", "xla", "ring"])
+def test_attn_scores_plan_of_the_training_cells(cell, attn_impl):
+    """Seq 2,048 through the flash kernel's 1,024-row blocks: 136 of a
+    head's 256 sub-tiles of 128 x 128 computed, 16 under a mask, 120
+    skipped; no plan where the attention is not that kernel's."""
+    import json
+    import pathlib
+
+    from opendiloco_tpu.models.llama import LlamaConfig
+
+    config, strategy, n, _, _ = ATTN_RESIDUALS_KEPT[cell]
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = LlamaConfig.from_dict(json.loads((root / "benchmark/configs" / f"{config}.json").read_text()))
+    tc = TrainerConfig(precision="bf16-mixed", remat=True, attn_impl=attn_impl)
+    trainer = InnerTrainer(cfg, tc, build_mesh(strategy, devices=jax.devices()[:n]))
+    plan = trainer.attn_scores_plan_of(2048)
+    if attn_impl != "pallas":
+        assert plan is None
+        return
+    assert plan[:5] == (128, (128, 128), 136, 16, 120)
+    assert (plan.computed_share, plan.masked_share) == (0.53125, 0.0625)
+
+
 def test_building_the_step_sets_the_attention_gauge(tiny_cfg, monkeypatch, caplog):
     """Tracing the train step (which is when it is built) leaves what the
     policy keeps for attention on the trainer, in the gauge and on the log
@@ -373,6 +397,12 @@ def test_building_the_step_sets_the_attention_gauge(tiny_cfg, monkeypatch, caplo
         assert trainer.attn_residual_bytes == kept
         assert obs.tracer().gauges()[("train_attn_residual_bytes", ())] == kept
         assert f"remat=True train_attn_residual_bytes={kept}" in caplog.text
+        # and what the kernels compute of a head's 128 x 128 scores: one
+        # sub-tile, on the diagonal
+        assert trainer.attn_scores_plan[:5] == (128, (128, 128), 1, 1, 0)
+        assert obs.tracer().gauges()[("train_attn_scores_computed_share", ())] == 1.0
+        assert obs.tracer().gauges()[("train_attn_scores_masked_share", ())] == 1.0
+        assert "train_attn_scores=computed_share=1.00000 masked_share=1.00000 sub_tile=128" in caplog.text
     finally:
         trainer_module.log.removeHandler(caplog.handler)
         obs.reset()
