@@ -217,15 +217,9 @@ KNOBS: tuple[Knob, ...] = (
          doc_default="auto"),
     Knob("ODTP_DECODE_KERNEL", "str", "", "serve",
          "Decode-path kernel dispatch: `auto` picks the Pallas serving "
-         "kernels (paged decode attention, fused W4 dequant-matmul, fused "
-         "speculative verify) on TPU and the stock XLA ops elsewhere; "
+         "kernels (paged decode attention, the continued prefill's tail "
+         "attention) on TPU and the stock XLA ops elsewhere; "
          "`pallas`/`xla` force a path. Token-bit-exact either way.",
-         doc_default="config"),
-    Knob("ODTP_DECODE_WEIGHT_FORMAT", "str", "", "serve",
-         "Replica weight residency override for the serve plane: `w4` keeps "
-         "stacked matmul weights blockwise-4bit packed at rest (dequantized "
-         "per block inside the jit'd decode); `fp32` leaves them unpacked (float32 "
-         "masters in, held in the compute dtype).",
          doc_default="config"),
     Knob("ODTP_KV_HOST_SLOTS", "int", "", "serve",
          "Host KV-tier budget: paused slot pages + prefix-store entries it "
@@ -240,10 +234,6 @@ KNOBS: tuple[Knob, ...] = (
          "Cold-page codec: `none` stores f32 (evict+restore bit-exact), "
          "`blockwise4bit` stores pages 8x smaller with a bounded, "
          "test-pinned restore error.", doc_default="config"),
-    Knob("ODTP_SPEC_K", "int", "", "serve",
-         "Self-speculative decode override: draft this many tokens per slot "
-         "per step and verify full-depth (token-exact vs the one-token "
-         "loop); `0` disables.", doc_default="config"),
     # -- transport ------------------------------------------------------------
     Knob("ODTP_BULK_BANDWIDTH_BPS", "float", "0", "transport",
          "Per-process egress cap in bytes/s (token bucket) emulating a "

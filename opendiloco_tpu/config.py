@@ -224,25 +224,11 @@ class ServeConfig(BaseModel):
     # max_stale_rounds outer rounds (0 = adopt every new round)
     swap_every_steps: int = 16
     max_stale_rounds: int = 0
-    # fast decode path (PR 11): each leg defaults OFF and the off path is
-    # bit-identical to the plain engine
-    # self-speculative decode: draft k tokens per slot per step from the
-    # first draft_layers of the same weights, verify full-depth, keep the
-    # longest agreeing greedy prefix (token-exact vs the one-token loop);
-    # 0 disables
-    spec_decode_k: int = 0
-    # draft depth; 0 = auto (half the stack, min 1); must stay < num layers
-    draft_layers: int = 0
-    # replica weight residency: "fp32" (not packed: float32 masters come in
-    # and every leaf is held in the engine's compute dtype) or "w4" (stacked
-    # matmul weights blockwise-4bit packed at rest, dequantized per block
-    # inside the jit'd decode; norms/embeddings/lm head unpacked as above)
-    weight_format: Literal["fp32", "w4"] = "fp32"
     # decode-path kernel dispatch: "auto" picks the Pallas serving kernels
-    # (paged decode attention, fused W4 dequant-matmul, fused speculative
-    # verify) on TPU backends and the stock XLA ops elsewhere; "pallas" /
-    # "xla" force a path (forced pallas off-TPU runs interpreted — test
-    # rigs only). Token-bit-exact either way.
+    # (paged decode attention, the continued prefill's tail attention) on
+    # TPU backends and the stock XLA ops elsewhere; "pallas" / "xla" force a
+    # path (forced pallas off-TPU runs interpreted — test rigs only).
+    # Token-bit-exact either way.
     decode_kernel: Literal["auto", "pallas", "xla"] = "auto"
     # shared-prefix KV reuse: prefill a common prompt prefix once and
     # ring-copy its K/V into joining slots
@@ -277,15 +263,6 @@ class ServeConfig(BaseModel):
             raise ValueError(
                 "largest prefill bucket exceeds serve.max_context "
                 "(a prompt must fit its slot's KV page)"
-            )
-        if self.spec_decode_k < 0:
-            raise ValueError("serve.spec_decode_k must be >= 0")
-        if self.draft_layers < 0:
-            raise ValueError("serve.draft_layers must be >= 0")
-        if self.spec_decode_k + 1 > self.max_context:
-            raise ValueError(
-                "serve.spec_decode_k + 1 exceeds serve.max_context "
-                "(a speculative tail must fit the ring)"
             )
         if self.kv_host_slots < 1:
             raise ValueError("serve.kv_host_slots must be >= 1")

@@ -474,52 +474,6 @@ def compress_roundtrip(arr: np.ndarray, codec: Codec) -> np.ndarray:
     return codec.decode(payload, arr.shape, meta)
 
 
-def pack_blockwise4_stacked(arr: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Pack a stacked [L, ...] weight into the serve plane's 4-bit-resident
-    layout: per LAYER blockwise-4bit quantization with the codec's exact
-    geometry (``_BLOCK`` absmax blocks, packed nibbles, fp16-rounded
-    scales, via the native kernels / bit-identical fallbacks).
-
-    Returns (q [L, ceil(n/2)] uint8, scales [L, nblocks] uint16) with
-    n = per-layer element count — stackable leaves, so the packed weight
-    rides the decode layer scan and dequantizes per block inside the jit
-    (``models.llama.dequant_w4``). Per-layer blocks rather than the wire
-    codec's whole-leaf blocks: the two grids coincide exactly when n is
-    a multiple of ``_BLOCK`` (see :func:`split_blockwise4_stacked`)."""
-    a = np.ascontiguousarray(arr, np.float32)
-    L = a.shape[0]
-    n = int(a[0].size)
-    nb = (n + _BLOCK - 1) // _BLOCK
-    q = np.empty((L, (n + 1) // 2), np.uint8)
-    s = np.empty((L, nb), np.uint16)
-    for i in range(L):
-        qb, sb = native.quantize_blockwise4(a[i].reshape(-1), _BLOCK)
-        q[i] = np.frombuffer(qb, np.uint8)
-        s[i] = np.frombuffer(sb, np.uint16)
-    return q, s
-
-
-def split_blockwise4_stacked(
-    payload: bytes, meta: dict, L: int, n_layer: int
-) -> tuple[np.ndarray, np.ndarray] | None:
-    """Re-slice a whole-leaf ``blockwise4bit`` wire payload into the
-    per-layer stacked layout of :func:`pack_blockwise4_stacked` WITHOUT a
-    dequantize/requantize round trip — the cheap hot-swap install for
-    w4-resident serving. Only exact when the wire codec's block grid
-    lands on layer boundaries (n_layer % _BLOCK == 0, which also makes
-    the nibble packing byte-aligned per layer); returns None otherwise
-    and the caller takes the decode-then-repack path."""
-    if n_layer <= 0 or n_layer % _BLOCK:
-        return None
-    nb_total = int(meta["nblocks"])
-    scales, q = payload[: nb_total * 2], payload[nb_total * 2 :]
-    if len(q) != (L * n_layer + 1) // 2 or nb_total != L * (n_layer // _BLOCK):
-        return None
-    qa = np.frombuffer(q, np.uint8).reshape(L, n_layer // 2)
-    sa = np.frombuffer(scales, np.uint16).reshape(L, n_layer // _BLOCK)
-    return qa.copy(), sa.copy()
-
-
 def device_wire_dtype(name: str) -> str | None:
     """Device-side encode hook for ``outer_placement=device``.
 

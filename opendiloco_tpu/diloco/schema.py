@@ -150,7 +150,7 @@ REQTRACE_FRAME_KIND = "reqtrace"
 #   queue       replica scheduler: submit -> slot admission wait
 #   prefill     engine prompt prefill (attrs: bucket, tokens)
 #   decode      one batched decode step touching this request (attrs:
-#               batch occupancy; spec path adds proposed/accepted)
+#               batch occupancy)
 #   swap        weight hot-swap pause overlapping this request
 #   page_out    KV-tier eviction: the slot's ring page copied D2H and
 #               encoded into the host tier (attrs: tokens, bytes)

@@ -18,7 +18,6 @@ open_diloco/configs/*.json -- but designed for XLA, not translated:
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
 import os
 from typing import Any, Literal, NamedTuple, Optional, Union
@@ -32,7 +31,6 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     eva_window_rows,
     init_kv_cache,
     prefix_copy,
-    spec_cache_insert,
     suffix_insert,
 )
 from opendiloco_tpu.ops.attention import (
@@ -41,18 +39,15 @@ from opendiloco_tpu.ops.attention import (
     eva_decode_step_attention,
     eva_pool,
     latent_decode_step_attention,
-    spec_tail_attention,
+    tail_attention,
     xla_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
-    W4_BLOCK,
     eva_decode_attention,
     eva_prefill_attention,
     mla_decode_attention,
     paged_decode_attention,
-    spec_tail_attention_fused,
-    w4_matmul,
-    w4_matmul_supported,
+    tail_attention_fused,
 )
 
 
@@ -965,19 +960,18 @@ def _rotate_heads(cfg: LlamaConfig, x: jax.Array, cos, sin) -> jax.Array:
     return jnp.concatenate((_rope_apply(x[..., :rot], cos, sin), x[..., rot:]), axis=-1)
 
 
-def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
+def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
     """The attention block's projections of x [B, T, D]: q [B, T, Nh, Dh] and
     k [B, T, Nkv, Dh] rotated by position (as they are where ``cos`` is
-    None), and v. ``mul(x, w)`` is the caller's weight matmul. With
-    ``cfg.qk_norm`` q and k pass an RMSNorm over their whole width before
-    they are split into heads (OLMoE). Every attention reader scales the
-    scores by 1/sqrt(Dh); a configuration that states another scale
-    (``attention_multiplier``) has the ratio put on q here."""
+    None), and v. With ``cfg.qk_norm`` q and k pass an RMSNorm over their
+    whole width before they are split into heads (OLMoE). Every attention
+    reader scales the scores by 1/sqrt(Dh); a configuration that states
+    another scale (``attention_multiplier``) has the ratio put on q here."""
     B, T, _ = x.shape
     Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    q = mul(x, layer["q_proj"])
-    k = mul(x, layer["k_proj"])
-    v = mul(x, layer["v_proj"]).reshape(B, T, Nkv, Dh)
+    q = x @ layer["q_proj"]
+    k = x @ layer["k_proj"]
+    v = (x @ layer["v_proj"]).reshape(B, T, Nkv, Dh)
     if cfg.qk_norm:
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
@@ -987,7 +981,7 @@ def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
     return _rotate_heads(cfg, q, cos, sin), _rotate_heads(cfg, k, cos, sin), v
 
 
-def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul, past=None):
+def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, past=None):
     """CCA's projections of x [B, T, D] (arXiv 2510.04476) -> (q [B, T, Nh,
     Dh], k and v [B, T, Nkv, Dh], tails [B, T, ``cfg.cca_state_dim``]).
 
@@ -1010,8 +1004,8 @@ def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul, past=No
     B, T, _ = x.shape
     Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     rep, Zq = Nh // Nkv, Nh * Dh
-    z = jnp.concatenate((mul(x, layer["q_proj"]), mul(x, layer["k_proj"])), axis=-1)
-    u = mul(x, layer["v_prev_proj"])  # what the next token takes as its values
+    z = jnp.concatenate((x @ layer["q_proj"], x @ layer["k_proj"]), axis=-1)
+    u = x @ layer["v_prev_proj"]  # what the next token takes as its values
     Z = z.shape[-1]
     before = (None,) * 3 if past is None else (
         past[:, :Z], past[:, Z : 2 * Z], past[:, 2 * Z :]
@@ -1037,7 +1031,7 @@ def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul, past=No
     # for q and the KV head's temperature for k
     q = _rms_norm(q, jnp.ones((), x.dtype), cfg.rms_norm_eps)
     k = _rms_norm(k, layer["cca_k_temp"][:, None], cfg.rms_norm_eps)
-    v = jnp.concatenate((mul(x, layer["v_proj"]), back(u, before[2])), axis=-1)
+    v = jnp.concatenate((x @ layer["v_proj"], back(u, before[2])), axis=-1)
     tails = jnp.concatenate((z, c.astype(x.dtype), u), axis=-1)
     return (
         _rotate_heads(cfg, q, cos, sin), _rotate_heads(cfg, k, cos, sin),
@@ -1045,7 +1039,7 @@ def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul, past=No
     )
 
 
-def _latent_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
+def _latent_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
     """The latent attention's projections of x [B, T, D] -> (q [B, T, Nh,
     nope + rope], each head its unrotated part then its rotated one; the
     token's latent row [B, T, kv_lora_rank + rope]: the latent under its
@@ -1058,10 +1052,10 @@ def _latent_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, mul):
         cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
         cfg.kv_lora_rank,
     )
-    c_q = _rms_norm(mul(x, layer["q_a_proj"]), layer["q_a_norm"], cfg.rms_norm_eps)
-    q = mul(c_q, layer["q_b_proj"]).reshape(B, T, Nh, Dn + Dr)
+    c_q = _rms_norm(x @ layer["q_a_proj"], layer["q_a_norm"], cfg.rms_norm_eps)
+    q = (c_q @ layer["q_b_proj"]).reshape(B, T, Nh, Dn + Dr)
     q = jnp.concatenate((q[..., :Dn], _rope_apply(q[..., Dn:], cos, sin)), axis=-1)
-    row = mul(x, layer["kv_a_proj"])  # [B, T, R + Dr]
+    row = x @ layer["kv_a_proj"]  # [B, T, R + Dr]
     c_kv = _rms_norm(row[..., :R], layer["kv_a_norm"], cfg.rms_norm_eps)
     k_r = _rope_apply(row[..., None, R:], cos, sin)[:, :, 0]  # one head
     return q, jnp.concatenate((c_kv, k_r), axis=-1)
@@ -1316,31 +1310,27 @@ def _routed_ffn(
     return out.astype(x.dtype).reshape(x.shape), aux, jnp.stack(counts).astype(jnp.int32)
 
 
-def _swiglu(x, layer: dict, mul, prefix: str = ""):
-    return mul(
-        jax.nn.silu(mul(x, layer[prefix + "gate_proj"])) * mul(x, layer[prefix + "up_proj"]),
-        layer[prefix + "down_proj"],
-    )
+def _swiglu(x, layer: dict, prefix: str = ""):
+    gated = jax.nn.silu(x @ layer[prefix + "gate_proj"]) * (x @ layer[prefix + "up_proj"])
+    return gated @ layer[prefix + "down_proj"]
 
 
 def _ffn(
-    cfg: LlamaConfig, x: jax.Array, layer: dict, mul, live=None, features=None,
-    chosen=None,
+    cfg: LlamaConfig, x: jax.Array, layer: dict, live=None, features=None, chosen=None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The block's feed-forward over x [..., D] -> (out, aux loss, routing
-    counts): SwiGLU through the caller's weight matmul ``mul(x, w)``, or the
-    routed experts (``features``: what their router reads, where that is not
-    x; ``chosen``: a list to take each token's experts, ``_routed_ffn``), and
-    beside those, where the configuration has one, a shared SwiGLU
-    that every token passes: the layer's leaves say which it has. aux and
-    counts are zero for a dense layer."""
+    counts): SwiGLU, or the routed experts (``features``: what their router
+    reads, where that is not x; ``chosen``: a list to take each token's
+    experts, ``_routed_ffn``), and beside those, where the configuration has
+    one, a shared SwiGLU that every token passes: the layer's leaves say
+    which it has. aux and counts are zero for a dense layer."""
     if "router" in layer:
         out, aux, counts = _routed_ffn(cfg, x, layer, live, features, chosen)
     else:  # a dense model's layer, or a routed model's leading dense one
-        out, aux = _swiglu(x, layer, mul), jnp.float32(0.0)
+        out, aux = _swiglu(x, layer), jnp.float32(0.0)
         counts = jnp.zeros((cfg.moe_counts,), jnp.int32)
     if "shared_gate_proj" in layer:
-        out = out + _swiglu(x, layer, mul, "shared_")
+        out = out + _swiglu(x, layer, "shared_")
     return out, aux, counts
 
 
@@ -1371,7 +1361,6 @@ def decoder_block(
     cos: Optional[jax.Array],
     sin: Optional[jax.Array],
     *,
-    mul,
     attend=None,
     mix=None,
     live: Optional[jax.Array] = None,
@@ -1383,14 +1372,14 @@ def decoder_block(
     mixer is attention (q/k/v, ``attend``, o_proj) or, where the caller
     passes ``mix``, whatever that computes from the normed input (the
     Mamba-2 mixer of a hybrid stack). Training and the serving forwards
-    differ in what they pass: ``mul(x, w)`` is the caller's weight matmul,
-    ``attend(q, k, v)`` its attention over this layer's q [B, T, Nh, Dh] and
-    new k, v, ``mix(x, layer)`` its mixer over x [B, T, D] (a cache or a
-    state either reads or writes is the caller's own), ``live`` the tokens a
-    routed FFN counts. Latent attention enters the same way: the projection
-    returns q and the tokens' latent rows, and the caller's ``attend(q, rows,
-    kv_b_proj)`` is its attention over them, in the rebuilt form or the
-    absorbed one -> [B, T, Nh, v]. EVA enters as attention too: the caller's
+    differ in what they pass: ``attend(q, k, v)`` is the caller's attention
+    over this layer's q [B, T, Nh, Dh] and new k, v, ``mix(x, layer)`` its
+    mixer over x [B, T, D] (a cache or a state either reads or writes is the
+    caller's own), ``live`` the tokens a routed FFN counts. Latent attention
+    enters the same way: the projection returns q and the tokens' latent
+    rows, and the caller's ``attend(q, rows, kv_b_proj)`` is its attention
+    over them, in the rebuilt form or the absorbed one -> [B, T, Nh, v]. EVA
+    enters as attention too: the caller's
     ``attend(q, k, v, adaptive_phi, adaptive_mu_k)`` pools k and v by chunk
     under the layer's two vectors and attends over the query's window and the
     pooled rows before it (``ops.attention.eva_attention``, or a decode step
@@ -1419,24 +1408,22 @@ def decoder_block(
     if mix is None and cfg.latent:
         with jax.named_scope("odtp_mla"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-            q, k = _latent_qkv(cfg, x, layer, cos, sin, mul)
+            q, k = _latent_qkv(cfg, x, layer, cos, sin)
             v = None
-            attn_out = mul(
-                attend(q, k, layer["kv_b_proj"]).reshape(B, T, -1), layer["o_proj"]
-            )
+            attn_out = attend(q, k, layer["kv_b_proj"]).reshape(B, T, -1) @ layer["o_proj"]
     elif mix is None and cfg.cca:
         with jax.named_scope("odtp_cca"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-            q, k, v, tails = _cca_qkv(cfg, x, layer, cos, sin, mul, past)
+            q, k, v, tails = _cca_qkv(cfg, x, layer, cos, sin, past)
         with jax.named_scope("odtp_attention"):
-            attn_out = mul(attend(q, k, v).reshape(B, T, -1), layer["o_proj"])
+            attn_out = attend(q, k, v).reshape(B, T, -1) @ layer["o_proj"]
     elif mix is None:
         with jax.named_scope("odtp_attention"):
             x = _block_norm(cfg, h, layer["input_norm"])
-            q, k, v = _qkv(cfg, x, layer, cos, sin, mul)
+            q, k, v = _qkv(cfg, x, layer, cos, sin)
             # EVA's attend also pools k and v: under the layer's two vectors
             pool = (layer["adaptive_phi"], layer["adaptive_mu_k"]) if cfg.eva else ()
-            attn_out = mul(attend(q, k, v, *pool).reshape(B, T, -1), layer["o_proj"])
+            attn_out = attend(q, k, v, *pool).reshape(B, T, -1) @ layer["o_proj"]
     else:
         k = v = None
         with jax.named_scope("odtp_ssm"):
@@ -1451,7 +1438,7 @@ def decoder_block(
                     cfg, x.reshape(B * T, -1), layer, router_in
                 )
                 router_out = router_out.reshape(B, T, -1)
-        ffn, aux, counts = _ffn(cfg, x, layer, mul, live, features, chosen)
+        ffn, aux, counts = _ffn(cfg, x, layer, live, features, chosen)
     return residual(h, ffn, "ffn"), BlockOut(
         k, v, attn_out, aux, counts, router_out, tails, chosen[0] if chosen else None
     )
@@ -1471,7 +1458,7 @@ def training_block(
     cos, sin = _rope(cfg, positions)
     mix, attend = None, attn_fn
     if kind == "mamba":
-        mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer, jnp.matmul)[0]
+        mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer)[0]
     elif cfg.latent:  # the rebuilt form: multi-head attention over k and v
         attend = rebuilt_attend(cfg, attn_fn)
     elif cfg.eva:  # its own attention over the sequence: ``attn_fn`` is not asked
@@ -1479,9 +1466,7 @@ def training_block(
 
     def body(carry, layer, li=None):
         h, r = carry
-        h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=jnp.matmul, attend=attend, mix=mix, router_in=r
-        )
+        h, out = decoder_block(cfg, h, layer, cos, sin, attend=attend, mix=mix, router_in=r)
         with jax.named_scope("odtp_attention"):
             attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
         return (h, out.router), (attn_norm, out.aux)
@@ -1698,93 +1683,12 @@ def forward(
 # ---------------------------------------------------------------------------
 
 
-@jax.tree_util.register_pytree_node_class
-class PackedW4:
-    """A matmul weight held blockwise-4-bit-packed at rest (serve
-    ``weight_format=w4``): ``q`` [..., ceil(n/2)] uint8 packed nibbles and
-    ``s`` [..., nblocks] uint16 fp16-bit scales per ``W4_BLOCK`` values —
-    the PR 8 ``blockwise4bit`` codec geometry, applied per layer so the
-    packed leaves keep the leading L axis and ride the decode layer scan.
-    ``shape`` is the per-layer unpacked shape (static aux data, so scan
-    reconstructs the node with it intact)."""
-
-    def __init__(self, q, s, shape):
-        self.q = q
-        self.s = s
-        self.shape = tuple(int(x) for x in shape)
-
-    def tree_flatten(self):
-        return (self.q, self.s), self.shape
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], aux)
-
-
-def dequant_w4(q: jax.Array, s: jax.Array, shape: tuple, dtype) -> jax.Array:
-    """Unpack one layer's 4-bit weight inside the jit'd forward.
-
-    Bit-for-bit the ``native._dequant4_numpy`` math at f32: element 2i is
-    the low nibble of byte i, value = (nibble - 8) * fp16(scale) / 7."""
-    n = 1
-    for x in shape:
-        n *= int(x)
-    nib = jnp.stack([q & jnp.uint8(0x0F), q >> 4], axis=-1).reshape(-1)[:n]
-    qv = nib.astype(jnp.float32) - jnp.float32(8.0)
-    sf = jax.lax.bitcast_convert_type(s, jnp.float16).astype(jnp.float32)
-    sf = sf / jnp.float32(7.0)
-    pad = (-n) % W4_BLOCK
-    qp = jnp.pad(qv, (0, pad)).reshape(-1, W4_BLOCK)
-    out = (qp * sf[:, None]).reshape(-1)[:n].reshape(shape)
-    return out.astype(dtype)
-
-
-def _wleaf(w, dtype):
-    """Materialize a weight leaf for a matmul: packed leaves dequantize
-    per-block here, inside the jit (fused dequant+matmul); plain arrays
-    pass through (already cast by ``_cast_serving_params``)."""
-    if isinstance(w, PackedW4):
-        return dequant_w4(w.q, w.s, w.shape, dtype)
-    return w
-
-
-def _wmul(x, w, dtype, kernel="xla"):
-    """One weight-matmul site: ``x @ materialized(w)``.
-
-    On the Pallas decode path a packed leaf routes through the fused
-    dequant-matmul kernel — nibbles dequantize in-registers per tile —
-    instead of materializing the full weight via ``_wleaf``. Dense
-    leaves and untileable packed shapes keep the XLA contraction."""
-    if (
-        kernel == "pallas"
-        and isinstance(w, PackedW4)
-        and w4_matmul_supported(w.shape)
-    ):
-        lead = x.shape[:-1]
-        out = w4_matmul(x.reshape(-1, x.shape[-1]), w.q, w.s, w.shape, dtype)
-        return out.reshape(*lead, w.shape[1])
-    return x @ _wleaf(w, dtype)
-
-
-def _cast_serving_params(params, dtype):
-    """The forward-boundary cast, w4-aware: packed uint8/uint16 leaves
-    stay packed (their dequant targets ``dtype`` at the matmul site). A
-    leaf that already has ``dtype`` emits nothing: ``ServeEngine`` binds
+def _serving_boundary(params, compute_dtype):
+    """What the serving forwards do first -> the weights in the compute
+    dtype. A leaf that already has it emits nothing: ``ServeEngine`` binds
     its weights in the compute dtype, so its programs hold no cast, and a
     caller with a float32 tree gets the same rounding here, per call."""
-    return jax.tree.map(
-        lambda x: x if x.dtype in (jnp.uint8, jnp.uint16) else x.astype(dtype),
-        params,
-    )
-
-
-def _serving_boundary(params, compute_dtype, decode_kernel):
-    """What the serving forwards do first -> (the weights in the compute
-    dtype, cast here unless they came in it, their weight matmul
-    ``mul(x, w)``)."""
-    cparams = _cast_serving_params(params, compute_dtype)
-    mul = functools.partial(_wmul, dtype=compute_dtype, kernel=decode_kernel)
-    return cparams, mul
+    return jax.tree.map(lambda x: x.astype(compute_dtype), params)
 
 
 def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
@@ -1797,15 +1701,14 @@ def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
             f"{what} is refused for a configuration with CCA (cca_time0 "
             f"{cfg.cca_time0}): it treats a slot's past as cache rows, and CCA's "
             "projections read the token before through a per-slot state beside "
-            "the ring, which is not rows and which a rejected draft would have "
-            "to roll back"
+            "the ring, which is not rows"
         )
     if cfg.hybrid:
         raise ValueError(
             f"{what} is refused for a configuration with Mamba-2 layers "
             f"({cfg.num_mamba_layers} of {cfg.num_hidden_layers}): it treats a "
             "slot's past as cache rows, and a recurrent state cannot be cut at "
-            "a row or rolled back"
+            "a row"
         )
 
 
@@ -1830,7 +1733,6 @@ def prefill_forward(
     cfg: LlamaConfig,
     *,
     compute_dtype: jnp.dtype = jnp.bfloat16,
-    decode_kernel: str = "xla",
     return_moe_counts: bool = False,
     return_expert_choices: bool = False,
 ):
@@ -1862,7 +1764,7 @@ def prefill_forward(
     ``length`` (``mamba.ssm_chunked``)."""
     B, P = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
-    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
+    cparams = _serving_boundary(params, compute_dtype)
     cos, sin = _rope(cfg, positions)
     live = positions < length
     attend = lambda q, k, v: xla_attention(q, k, v, causal=True)
@@ -1873,7 +1775,7 @@ def prefill_forward(
         h, r = carry
         pooling: list = []  # EVA: the chunks pooled, and each chunk's pooling as stats
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, live=live, router_in=r,
+            cfg, h, layer, cos, sin, live=live, router_in=r,
             attend=eva_attend(cfg, length, pooling, prefill=True) if cfg.eva else attend,
         )
         kept = [out.k[0], None if out.v is None else out.v[0]]
@@ -1896,13 +1798,11 @@ def prefill_forward(
         left = []
 
         def mix(x, layer):
-            out, state, tail = mamba.ssm_chunked(cfg, x, layer, mul, length)
+            out, state, tail = mamba.ssm_chunked(cfg, x, layer, length)
             left.extend((state[0], tail[0]))
             return out
 
-        h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live, router_in=r
-        )
+        h, out = decoder_block(cfg, h, layer, cos, sin, mix=mix, live=live, router_in=r)
         return (h, out.router), (*left, (out.counts, out.experts))
 
     h = _embed(cfg, cparams, input_ids)
@@ -2012,7 +1912,7 @@ def decode_forward(
     hold a sequence (``lens > 0``), summed over layers, come last, and with
     ``return_expert_choices`` after them each slot's experts in each layer [L,
     S, K] int32."""
-    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
+    cparams = _serving_boundary(params, compute_dtype)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
     cos, sin = _rope(cfg, positions)
     live = lens > 0
@@ -2050,7 +1950,7 @@ def decode_forward(
             return latent_expand(cfg, o_lat, w_kvb)
 
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, live=live, router_in=r,
+            cfg, h, layer, cos, sin, live=live, router_in=r,
             attend=absorbed if cfg.latent else over_two_rings if cfg.eva else attend,
             past=None if tails is None else tails[li],
         )
@@ -2067,16 +1967,12 @@ def decode_forward(
 
         def mix(x, layer):
             nonlocal states, tails
-            out, state, tail = mamba.ssm_step(
-                cfg, x[:, 0], layer, mul, states[li], tails[li]
-            )
+            out, state, tail = mamba.ssm_step(cfg, x[:, 0], layer, states[li], tails[li])
             states = jax.lax.dynamic_update_index_in_dim(states, state, li, 0)
             tails = jax.lax.dynamic_update_index_in_dim(tails, tail, li, 0)
             return out[:, None]
 
-        h, out = decoder_block(
-            cfg, h, layer, cos, sin, mul=mul, mix=mix, live=live, router_in=r
-        )
+        h, out = decoder_block(cfg, h, layer, cos, sin, mix=mix, live=live, router_in=r)
         return (h, out.router, states, tails), (out.counts, out.experts)
 
     h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
@@ -2110,16 +2006,7 @@ def decode_forward(
     return tuple(out)
 
 
-def _tail_attention(decode_kernel: str):
-    """Attention of tail queries over ring pages plus the tail's own K/V."""
-    return (
-        spec_tail_attention_fused
-        if decode_kernel == "pallas"
-        else spec_tail_attention
-    )
-
-
-def verify_forward(
+def continue_prefill(
     params: dict,
     tail: jax.Array,
     lens: jax.Array,
@@ -2130,33 +2017,30 @@ def verify_forward(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     decode_kernel: str = "xla",
 ):
-    """Batched multi-token verify pass for self-speculative decode.
+    """The continued prefill of shared-prefix KV reuse: a prompt's suffix
+    run over a slot whose ring already holds its prefix.
 
-    tail [S, K] int32 are K unverified tokens per slot (the current
-    token followed by the draft's proposals) at absolute positions
-    ``lens + i``; cache_{k,v} hold the ring pages as of BEFORE the tail.
-    Returns (logits [S, K, V] f32, tail_ks, tail_vs [L, S, K, Nkv, Dh]):
-    one full-depth greedy logit row per tail position, plus the tail's
-    K/V -- kept OUT of the ring here so rejected tokens need no rollback;
-    the engine inserts only the accepted prefix via
-    :func:`spec_cache_insert`.
-
-    Also the continued-prefill primitive for shared-prefix KV reuse
-    (S = 1, tail = the suffix tokens, lens = the reused prefix length).
-    """
-    refuse_recurrent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
-    refuse_latent(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
-    refuse_eva(cfg, "the multi-token verify pass (speculative decode, continued prefill)")
+    tail [S, K] int32 are K tokens per slot at absolute positions
+    ``lens + i`` (the engine calls it with S = 1, tail = the suffix tokens,
+    lens = the reused prefix length); cache_{k,v} hold the ring pages as of
+    BEFORE the tail. Returns (logits [S, K, V] f32, tail_ks, tail_vs
+    [L, S, K, Nkv, Dh]): one full-depth logit row per tail position, plus
+    the tail's K/V -- kept OUT of the ring here, so a bucket's padding rows
+    never land in it; the engine inserts the suffix's true rows
+    (``ring_cache.suffix_insert``)."""
+    for refuse in (refuse_recurrent, refuse_latent, refuse_eva):
+        refuse(cfg, "the continued prefill (prefix reuse)")
     S, K = tail.shape
-    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
+    cparams = _serving_boundary(params, compute_dtype)
     positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
     cos, sin = _rope(cfg, positions)
-    tail_attention = _tail_attention(decode_kernel)
+    # tail queries over ring pages plus the tail's own K/V
+    over_ring_and_tail = tail_attention_fused if decode_kernel == "pallas" else tail_attention
 
     def body(h, xs):
         layer, ck, cv = xs  # one layer's pages
-        attend = lambda q, k, v: tail_attention(q, ck, cv, k, v, lens)
-        h, out = decoder_block(cfg, h, layer, cos, sin, mul=mul, attend=attend)
+        attend = lambda q, k, v: over_ring_and_tail(q, ck, cv, k, v, lens)
+        h, out = decoder_block(cfg, h, layer, cos, sin, attend=attend)
         return h, (out.k, out.v)
 
     h = _embed(cfg, cparams, tail)  # [S, K, D]
@@ -2164,69 +2048,6 @@ def verify_forward(
         body, h, (cparams["layers"], cache_k, cache_v)
     )
     return _logits(cfg, cparams, h), tail_ks, tail_vs
-
-
-def draft_propose(
-    params: dict,
-    tokens: jax.Array,
-    lens: jax.Array,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    cfg: LlamaConfig,
-    *,
-    k_steps: int,
-    draft_layers: int,
-    compute_dtype: jnp.dtype = jnp.bfloat16,
-    decode_kernel: str = "xla",
-):
-    """Self-speculative draft: propose ``k_steps`` greedy tokens per slot
-    from the first ``draft_layers`` of the SAME weights (final norm and
-    lm head shared with the full stack).
-
-    The truncated stack's K/V for the proposed tail lives in registers
-    (a [Ld, S, k, Nkv, Dh] buffer threaded between token steps), never
-    the ring -- the draft is a heuristic and dirties nothing; exactness
-    is the verify pass's job. Returns proposals [S, k_steps] int32.
-    """
-    refuse_recurrent(cfg, "the self-speculative draft")
-    refuse_latent(cfg, "the self-speculative draft")
-    refuse_eva(cfg, "the self-speculative draft")
-    S = tokens.shape[0]
-    L, Ld = cfg.num_hidden_layers, int(draft_layers)
-    if not 1 <= Ld <= L:
-        raise ValueError(f"draft_layers {Ld} outside [1, {L}]")
-    cparams, mul = _serving_boundary(params, compute_dtype, decode_kernel)
-    dlayers = jax.tree.map(lambda x: x[:Ld], cparams["layers"])
-    dck, dcv = cache_k[:Ld], cache_v[:Ld]
-    tail_attention = _tail_attention(decode_kernel)
-
-    tail_shape = (Ld, S, k_steps, cfg.kv_heads, cfg.head_dim)
-    tkb = jnp.zeros(tail_shape, compute_dtype)
-    tvb = jnp.zeros(tail_shape, compute_dtype)
-    cur = tokens
-    proposals = []
-    for i in range(k_steps):
-        positions = (lens + jnp.int32(i))[:, None]  # [S, 1]
-        cos, sin = _rope(cfg, positions)
-
-        def body(h, xs, i=i, cos=cos, sin=sin):
-            layer, ck, cv, tk, tv = xs
-
-            def attend(q, k, v):
-                nonlocal tk, tv
-                tk = tk.at[:, i].set(k[:, 0])
-                tv = tv.at[:, i].set(v[:, 0])
-                return tail_attention(q, ck, cv, tk, tv, lens, q_start=i)
-
-            h, _ = decoder_block(cfg, h, layer, cos, sin, mul=mul, attend=attend)
-            return h, (tk, tv)
-
-        h = _embed(cfg, cparams, cur)[:, None]  # [S, 1, D]
-        h, (tkb, tvb) = jax.lax.scan(body, h, (dlayers, dck, dcv, tkb, tvb))
-        logits = _logits(cfg, cparams, h)
-        cur = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        proposals.append(cur)
-    return jnp.stack(proposals, axis=1)  # [S, k_steps]
 
 
 def causal_lm_loss(

@@ -52,10 +52,10 @@ def state_shapes(cfg, slots: int) -> tuple[tuple, tuple]:
     return (lm, slots, h, p, n), (lm, slots, k - 1, c)
 
 
-def _project(cfg, x, layer, mul):
+def _project(cfg, x, layer):
     """x [..., D] -> (z [..., HP], xBC [..., C], dt [..., H]) before the conv."""
     h, p, _, _, c = sizes(cfg)
-    zxbcdt = mul(x, layer["in_proj"])
+    zxbcdt = x @ layer["in_proj"]
     return (
         zxbcdt[..., : h * p],
         zxbcdt[..., h * p : h * p + c],
@@ -78,17 +78,17 @@ def _dt_and_decay(layer, dt):
     return dt, -jnp.exp(layer["A_log"].astype(jnp.float32))
 
 
-def _gated_out(cfg, y, z, layer, mul):
+def _gated_out(cfg, y, z, layer):
     """y float32 [..., HP] gated by z, normed over the whole width, projected."""
     g = y * jax.nn.silu(z.astype(jnp.float32))
     var = jnp.mean(g * g, axis=-1, keepdims=True)
     g = g * jax.lax.rsqrt(var + cfg.rms_norm_eps)
     g = (g * layer["mixer_norm"].astype(jnp.float32)).astype(z.dtype)
-    return mul(g, layer["out_proj"])
+    return g @ layer["out_proj"]
 
 
 def ssm_chunked(
-    cfg, x: jax.Array, layer: dict, mul, length: Optional[jax.Array] = None
+    cfg, x: jax.Array, layer: dict, length: Optional[jax.Array] = None
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The mixer over whole sequences x [B, T, D] -> (out [B, T, D], the
     state after the last token [B, H, P, N] float32, the conv tail
@@ -104,7 +104,7 @@ def ssm_chunked(
     b, t, _ = x.shape
     h, p, n, k, c = sizes(cfg)
     q = min(int(cfg.mamba_chunk_size), t)
-    z, xbc, dt = _project(cfg, x, layer, mul)
+    z, xbc, dt = _project(cfg, x, layer)
     live = None if length is None else (jnp.arange(t) < length)[None, :, None]
     if live is not None:
         xbc = jnp.where(live, xbc, 0)
@@ -170,15 +170,15 @@ def ssm_chunked(
     )
     y = y + layer["D"].astype(jnp.float32)[:, None] * u.astype(jnp.float32)
     y = y.reshape(b, nc * q, h * p)[:, :t]
-    return _gated_out(cfg, y, z, layer, mul), state, tail
+    return _gated_out(cfg, y, z, layer), state, tail
 
 
 def ssm_step(
-    cfg, x: jax.Array, layer: dict, mul, state: jax.Array, tail: jax.Array
+    cfg, x: jax.Array, layer: dict, state: jax.Array, tail: jax.Array
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One token a slot: x [S, D], the slots' states [S, H, P, N] float32 and
     conv tails [S, K - 1, C] -> (out [S, D], the new state, the new tail)."""
-    z, xbc, dt = _project(cfg, x, layer, mul)
+    z, xbc, dt = _project(cfg, x, layer)
     window = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)], axis=1)
     conv = jnp.sum(
         window.astype(jnp.float32) * layer["conv_weight"].astype(jnp.float32), axis=1
@@ -193,5 +193,5 @@ def ssm_step(
     )
     y = jnp.einsum("shpn,sn->shp", state, cm.astype(jnp.float32))
     y = y + layer["D"].astype(jnp.float32)[:, None] * uf
-    out = _gated_out(cfg, y.reshape(x.shape[0], -1), z, layer, mul)
+    out = _gated_out(cfg, y.reshape(x.shape[0], -1), z, layer)
     return out, state, window[:, 1:]

@@ -16,7 +16,7 @@ Two facts live here and nowhere else:
 
 - **the order of those axes.** Everything outside this module exchanges
   K/V as rows, ``[L, rows, Nkv, Dh]`` (a prefill's output, a page on the
-  host tier, a verify pass's tail); the functions below convert at the
+  host tier, a continued prefill's tail); the functions below convert at the
   module's edge (a prompt's K/V is megabytes, the cache gigabytes). The
   forwards hand one layer's pages ``[S, Nkv, Dh, T]`` to the attention
   readers (:func:`layer_pages`, or a scan over the leading axis); the
@@ -279,36 +279,6 @@ def write_row(
         return cache.at[layer, rows, :, :, idx].set(x.astype(cache.dtype))
 
     return put(cache_k, k), put(cache_v, v)
-
-
-def spec_cache_insert(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tail_ks: jax.Array,
-    tail_vs: jax.Array,
-    lens: jax.Array,
-    accept: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """Positioned ring insert of the ACCEPTED tail prefix: per slot, tail
-    tokens i <= accept[s] of tail_{ks,vs} [L, S, K, Nkv, Dh] land at ring
-    row ``(lens + i) % T``; rejected positions write their current cache
-    value back, so rejected tail tokens never reach the ring. Requires
-    K <= T so a tail never collides with itself."""
-    S, T, K = cache_k.shape[1], ring_rows(cache_k), tail_ks.shape[2]
-    if K > T:
-        raise ValueError(f"tail width {K} exceeds ring context {T}")
-    rows = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, K))
-    pos = jnp.mod(lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None], T)
-    keep = (jnp.arange(K, dtype=jnp.int32)[None] <= accept[:, None])[
-        :, :, None, None, None
-    ]
-
-    def put(cache, tail):  # cache[:, rows, :, :, pos] is [S, K, L, Nkv, Dh]
-        tail = jnp.moveaxis(tail, 0, 2).astype(cache.dtype)
-        new = jnp.where(keep, tail, cache[:, rows, :, :, pos])
-        return cache.at[:, rows, :, :, pos].set(new)
-
-    return put(cache_k, tail_ks), put(cache_v, tail_vs)
 
 
 def prefix_copy(

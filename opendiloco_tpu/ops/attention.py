@@ -155,27 +155,22 @@ def latent_decode_step_attention(
     return jnp.einsum("sht,sdt->shd", probs, pages[:, :value_dim]), cache
 
 
-def spec_tail_attention(
+def tail_attention(
     q: jax.Array,
     cache_k: jax.Array,
     cache_v: jax.Array,
     tail_k: jax.Array,
     tail_v: jax.Array,
     lens: jax.Array,
-    *,
-    q_start: int = 0,
 ) -> jax.Array:
     """Multi-token tail attention over a ring KV cache plus in-register
-    tail K/V — the verify/draft primitive for speculative decode.
+    tail K/V — the continued prefill's attention (prefix reuse).
 
-    q [S, Kq, H, D] are unverified tail tokens per slot at absolute
-    positions ``lens + q_start + i``; cache_{k,v} hold one layer's ring
-    pages ([S, Kh, D, T], ``ring_cache``'s order, read as rows through the
-    module) as of BEFORE the tail (positions <= lens - 1); tail_{k,v}
-    [S, K, Kh, D] are the tail's own K/V, kept out of the ring until
-    acceptance. ``q_start`` offsets the queries within the tail (the
-    draft proposes one token at a time against a growing tail buffer;
-    the verify pass runs the whole tail at q_start=0).
+    q [S, K, H, D] are the tail's tokens per slot at absolute positions
+    ``lens + i``; cache_{k,v} hold one layer's ring pages ([S, Kh, D, T],
+    ``ring_cache``'s order, read as rows through the module) as of BEFORE
+    the tail (positions <= lens - 1); tail_{k,v} [S, K, Kh, D] are the
+    tail's own K/V, kept out of the ring until the caller inserts them.
 
     The masking reproduces the sequential one-token loop exactly,
     including ring wrap: tail query i attends tail tokens <= i plus the
@@ -208,7 +203,7 @@ def spec_tail_attention(
     base = (idx < lens_) | (lens_ >= t)  # live pre-tail entries
     # disp = the i whose tail ring write lands on this slot ((lens+i) % T)
     disp = jnp.mod(idx - lens_, t)
-    j = q_start + jnp.arange(kq, dtype=jnp.int32)[None, :, None]  # [1, Kq, 1]
+    j = jnp.arange(kq, dtype=jnp.int32)[None, :, None]  # [1, Kq, 1]
     evicted = (disp[:, None, :] <= j) & (
         (lens_[:, None, :] + disp[:, None, :]) >= t
     )
@@ -220,7 +215,7 @@ def spec_tail_attention(
     tail_scores = jnp.einsum(
         "sqhd,skhd->shqk", q, tk, preferred_element_type=jnp.float32
     ) * scale
-    qi = q_start + jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 0)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 1)
     tail_scores = jnp.where((ki <= qi)[None, None], tail_scores, neg)
 

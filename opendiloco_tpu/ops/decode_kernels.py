@@ -1,12 +1,11 @@
 """Pallas TPU serving kernels: ragged paged decode attention (over a ``(k,
-v)`` ring, and over a latent ring in the absorbed form), fused W4
-dequant-matmul, fused speculative verify.
+v)`` ring, and over a latent ring in the absorbed form) and the continued
+prefill's tail attention.
 
 The XLA paths they stand in for (``decode_attention`` dense-masks the
-whole ring page per slot, ``spec_tail_attention`` materializes full
-repeat-KV score tensors, ``PackedW4`` leaves dequantize to full f32 weight
-matrices at every matmul site) stay as the off-TPU path and the reference:
-every kernel is token-bit-exact against them (PagedAttention-style
+whole ring page per slot, ``tail_attention`` materializes full repeat-KV
+score tensors) stay as the off-TPU path and the reference: every kernel is
+token-bit-exact against them (PagedAttention-style
 cache-aware decode, arXiv 2309.06180).
 
 - :func:`paged_decode_attention` is the decode step's whole traffic with
@@ -42,15 +41,7 @@ cache-aware decode, arXiv 2309.06180).
   first ``R`` rows, so a live row is read once a layer and step. It checks
   against ``latent_decode_step_attention`` to rounding, not to the bit (the
   row reaches its tile through a one-hot product on the MXU).
-- :func:`w4_matmul` fuses the blockwise-4-bit dequant into the matmul:
-  packed nibbles dequantize in-registers per ``[block_k, N]`` tile with
-  bit-for-bit the ``native._dequant4_numpy`` element order and per-4096-
-  block f16-scale math (pinned by an identity-matmul probe in tests),
-  instead of materializing the full f32 weight in HBM first. Nibble
-  interleave is resolved by splitting the output into even/odd column
-  planes (one [2, M, N/2] kernel output, re-interleaved by the caller's
-  reshape) so the kernel never needs an in-VMEM relayout.
-- :func:`spec_tail_attention_fused` implements ``spec_tail_attention``'s
+- :func:`tail_attention_fused` implements ``tail_attention``'s
   exact ring-wrap eviction mask over cache AND in-register tail K/V in
   one online-softmax pass — the ring blocks stream first (dead blocks
   skipped via ``lens`` like the decode kernel), the tail block runs
@@ -63,7 +54,7 @@ keep today's exact code. Forcing ``pallas`` off-TPU runs the kernels in
 Pallas interpret mode (slow, but semantically the kernel) — that is how
 the parity tests pin token-bit-exactness on a CPU rig. Shapes a kernel
 cannot tile (head_dim not a multiple of 8, a ring whose rows are not a
-multiple of the 128 lanes, odd N, a verify tail too tall for VMEM) fall
+multiple of the 128 lanes, a tail too tall for VMEM) fall
 back to the XLA path per call, mirroring ``flash_attention``'s fallback
 contract.
 """
@@ -87,11 +78,9 @@ from opendiloco_tpu.ops.attention import (
     eva_attention,
     eva_decode_step_attention,
     latent_decode_step_attention,
-    spec_tail_attention,
+    tail_attention,
 )
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
-
-W4_BLOCK = 4096  # diloco.compression._BLOCK (pinned by tests)
 
 DECODE_KERNELS = ("auto", "pallas", "xla")
 
@@ -831,26 +820,29 @@ def mla_decode_attention(
 
 
 # ---------------------------------------------------------------------------
-# (c) fused speculative verify (ring + in-register tail, one pass)
+# (b) the continued prefill's tail attention (ring + in-register tail, one pass)
 # ---------------------------------------------------------------------------
 
 
 # One grid step holds a whole GQA group's tail in VMEM: rep * Kq query rows
 # with f32 (m, l, acc) scratch, the [.., 1]-wide stats padded to 128 lanes.
 # Compiled deviceless for v5e (16 MiB scoped VMEM), head_dim 64 and 128,
-# bf16 and f32: 4096 rows fit, 8192 do not. Speculative tails (Kq = k + 1)
-# are nowhere near; the continued-prefill caller (Kq = a suffix bucket)
-# reaches it at GQA 32/4 with a 1024-token suffix, which keeps the XLA path.
-_SPEC_MAX_GROUP_ROWS = 4096
+# bf16 and f32: 4096 rows fit, 8192 do not. The continued prefill (Kq = a
+# suffix bucket) reaches it at GQA 32/4 with a 1024-token suffix, which
+# keeps the XLA path. The tail step also holds one head's [Kq, Kq]
+# probabilities and mask: 1024 x 1024 compile at head_dim 64 and 128 (and
+# are what tests/test_tpu_compile.py pins), a suffix bucket of 3072 (27 MB)
+# does not, and 1280 rows at GQA 15/5, head_dim 64 (3840 group rows) do not
+# either, so everything above 1024 x 1024 keeps the XLA path. A limit in
+# VMEM bytes of the whole step is ROADMAP C18's.
+_TAIL_MAX_GROUP_ROWS = 4096
+_TAIL_MAX_SCORES = 1024 * 1024
 
 
-def _spec_tail_kernel(
-    lens_ref, q_ref, k_ref, v_ref, tk_ref, tv_ref, o_ref, *rest,
-    scale, q_start, block_t, t, num_t, rep, with_stats,
+def _tail_kernel(
+    lens_ref, q_ref, k_ref, v_ref, tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, scale, block_t, t, num_t, rep,
 ):
-    stats_ref, (m_scr, l_scr, acc_scr) = (
-        (rest[0], rest[1:]) if with_stats else (None, rest)
-    )
     _, kq, d = q_ref.shape
     kt = tk_ref.shape[0]
     si, ti = pl.program_id(0), pl.program_id(2)
@@ -860,8 +852,6 @@ def _spec_tail_kernel(
         m_scr[:] = jnp.full((rep, kq, 1), NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros((rep, kq, 1), jnp.float32)
         acc_scr[:] = jnp.zeros((rep, kq, d), jnp.float32)
-        if with_stats:
-            stats_ref[:] = jnp.zeros((1, 1), jnp.int32)
 
     lens_s = lens_ref[si]
     # pre-tail ring liveness is idx < lens (strict: the tail's own K/V is
@@ -878,8 +868,7 @@ def _spec_tail_kernel(
         idx = ti * block_t + jax.lax.broadcasted_iota(
             jnp.int32, (kq, block_t), 1
         )
-        qi = jax.lax.broadcasted_iota(jnp.int32, (kq, block_t), 0)
-        j = q_start + qi
+        j = jax.lax.broadcasted_iota(jnp.int32, (kq, block_t), 0)
         base = (idx < lens_s) | (lens_s >= t)
         # disp = the i whose tail ring write ((lens+i) % T) lands on this
         # slot; query j has evicted it when that write precedes j and wraps
@@ -903,14 +892,12 @@ def _spec_tail_kernel(
                 p.astype(v_blk.dtype), v_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-        if with_stats:
-            stats_ref[:] += 1
 
     @pl.when(ti == num_t)
     def _tail_step():
         tk_blk = tk_ref[:]  # [kt, d]
         tv_blk = tv_ref[:]
-        qi = q_start + jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 0)
+        qi = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 1)
         valid = ki <= qi  # causal within the tail
         for r in range(rep):
@@ -935,7 +922,7 @@ def _spec_tail_kernel(
             o_ref[r] = (acc / l_safe).astype(o_ref.dtype)
 
 
-def spec_tail_attention_fused(
+def tail_attention_fused(
     q: jax.Array,
     cache_k: jax.Array,
     cache_v: jax.Array,
@@ -943,12 +930,10 @@ def spec_tail_attention_fused(
     tail_v: jax.Array,
     lens: jax.Array,
     *,
-    q_start: int = 0,
     block_t: int | None = None,
     interpret: bool | None = None,
-    return_stats: bool = False,
 ):
-    """Drop-in :func:`~opendiloco_tpu.ops.attention.spec_tail_attention`:
+    """Drop-in :func:`~opendiloco_tpu.ops.attention.tail_attention`:
     q [S, Kq, H, D] over one layer's ring pages plus the tail's K/V, one
     online-softmax pass, exact ring-wrap eviction semantics."""
     # same Mosaic tiling story as paged_decode_attention: the pages are
@@ -965,12 +950,10 @@ def spec_tail_attention_fused(
     bt = _ring_block(t, block_t, interp)
     if (
         d % 8 != 0 or h % nkv != 0 or not bt
-        or (h // nkv) * kq > _SPEC_MAX_GROUP_ROWS
+        or (h // nkv) * kq > _TAIL_MAX_GROUP_ROWS
+        or kq * kt > _TAIL_MAX_SCORES
     ):
-        out = spec_tail_attention(
-            q, cache_k, cache_v, tail_k, tail_v, lens, q_start=q_start
-        )
-        return (out, None) if return_stats else out
+        return tail_attention(q, cache_k, cache_v, tail_k, tail_v, lens)
     rep = h // nkv
     tkt, tvt = tail_k.transpose(0, 2, 1, 3), tail_v.transpose(0, 2, 1, 3)
     num_t = t // bt
@@ -989,23 +972,6 @@ def spec_tail_attention_fused(
     def tail_map(si, hi, ti, lr):
         return (si, hi, 0, 0)
 
-    out_specs = [pl.BlockSpec((None, None, rep, kq, d), q_map)]
-    out_shape = [
-        jax.ShapeDtypeStruct(
-            (s_, nkv, rep, kq, d), q.dtype, vma=jax.typeof(q).vma
-        )
-    ]
-    scratch = [
-        pltpu.VMEM((rep, kq, 1), jnp.float32),
-        pltpu.VMEM((rep, kq, 1), jnp.float32),
-        pltpu.VMEM((rep, kq, d), jnp.float32),
-    ]
-    if return_stats:
-        out_specs.append(
-            pl.BlockSpec((None, None, 1, 1), lambda si, hi, ti, lr: (si, hi, 0, 0))
-        )
-        out_shape.append(jax.ShapeDtypeStruct((s_, nkv, 1, 1), jnp.int32))
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s_, nkv, num_t + 1),  # ring blocks, then the tail block
@@ -1016,161 +982,26 @@ def spec_tail_attention_fused(
             pl.BlockSpec((None, None, kt, d), tail_map),
             pl.BlockSpec((None, None, kt, d), tail_map),
         ],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
+        out_specs=pl.BlockSpec((None, None, rep, kq, d), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((rep, kq, 1), jnp.float32),
+            pltpu.VMEM((rep, kq, 1), jnp.float32),
+            pltpu.VMEM((rep, kq, d), jnp.float32),
+        ],
     )
-    res = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _spec_tail_kernel,
-            scale=d**-0.5, q_start=int(q_start), block_t=bt, t=t,
-            num_t=num_t, rep=rep, with_stats=return_stats,
+            _tail_kernel,
+            scale=d**-0.5, block_t=bt, t=t, num_t=num_t, rep=rep,
         ),
         name="odtp_spec_tail_attn",
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(
+            (s_, nkv, rep, kq, d), q.dtype, vma=jax.typeof(q).vma
+        ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
     )(lens.astype(jnp.int32), q5, cache_k, cache_v, tkt, tvt)
-    out = res[0].transpose(0, 3, 1, 2, 4).reshape(s_, kq, h, d)
-    if return_stats:
-        return out, res[1].reshape(s_, nkv)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# (b) fused W4 dequant-matmul
-# ---------------------------------------------------------------------------
-
-
-def w4_matmul_supported(shape) -> bool:
-    """Shapes the fused kernel tiles: a 2-D weight with an even column
-    count (nibble pairs pack along rows). Others keep the XLA dequant."""
-    return len(shape) == 2 and int(shape[1]) % 2 == 0 and int(shape[1]) > 0
-
-
-def _w4_kernel(
-    x_ref, qb_ref, sarr_ref, hoff_ref, oe_ref, oo_ref, ae_scr, ao_scr,
-    *, num_k, n_sel, n_half,
-):
-    ki = pl.program_id(1)
-    bk = qb_ref.shape[0]
-
-    @pl.when(ki == 0)
-    def _init():
-        ae_scr[:] = jnp.zeros_like(ae_scr)
-        ao_scr[:] = jnp.zeros_like(ao_scr)
-
-    # [bk, N/2] packed bytes, widened to i32 — Mosaic has no u8 bitwise
-    # ops, and the values (0..255) are exact in any wider int
-    b = qb_ref[:].astype(jnp.int32)
-    # element 2j of a row is the LOW nibble of byte j (the
-    # native._dequant4_numpy order), value = (nibble - 8) * fp16(scale)/7
-    lo = (b & 0x0F).astype(jnp.float32) - 8.0
-    hi = (b >> 4).astype(jnp.float32) - 8.0
-    # scale of columns (2j, 2j+1) in row k: flat block (off_k + 2j) //
-    # 4096 == (hoff_k + j) // 2048 — a pair never straddles a boundary
-    # (offsets are even), so even/odd planes share one scale field
-    jidx = jax.lax.broadcasted_iota(jnp.int32, (bk, n_half), 1)
-    nj = (hoff_ref[:] + jidx) // (W4_BLOCK // 2)
-    scale = jnp.zeros((bk, n_half), jnp.float32)
-    for j in range(n_sel):
-        scale = jnp.where(nj == j, sarr_ref[:, j][:, None], scale)
-    x = x_ref[:]
-    we = (lo * scale).astype(x.dtype)
-    wo = (hi * scale).astype(x.dtype)
-    ae_scr[:] += jax.lax.dot_general(
-        x, we, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ao_scr[:] += jax.lax.dot_general(
-        x, wo, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(ki == num_k - 1)
-    def _finish():
-        oe_ref[:] = ae_scr[:].astype(oe_ref.dtype)
-        oo_ref[:] = ao_scr[:].astype(oo_ref.dtype)
-
-
-def w4_matmul(
-    x: jax.Array,
-    q: jax.Array,
-    s: jax.Array,
-    shape,
-    dtype,
-    *,
-    block_k: int | None = None,
-    block_m: int | None = None,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """``x [M, K] @ dequant(q, s, (K, N))`` without materializing the f32
-    weight: nibbles dequantize in-registers per [block_k, N] tile.
-
-    ``q`` is the per-layer packed stream ([K*N/2] uint8, row-major nibble
-    pairs) and ``s`` the [ceil(K*N/4096)] uint16 fp16-bit scales — the
-    PackedW4 leaf layout. The per-row scale candidates (each row of W
-    touches at most a couple of 4096-element flat blocks) are gathered
-    outside the kernel into a [K, n_sel] f32 side table, so the kernel
-    selects scales with a static chain of lane-wise wheres — no gather,
-    no relayout. With x = I the output is bit-for-bit ``dequant_w4``
-    (tests pin this), so the fused path inherits the codec's exactness."""
-    K, N = (int(v) for v in shape)
-    M = x.shape[0]
-    if not w4_matmul_supported(shape):
-        raise ValueError(f"w4_matmul cannot tile weight shape {shape}")
-    nb = s.shape[0]
-    n_half = N // 2
-    half_block = W4_BLOCK // 2
-    bk = block_k or pick_block(K, 256) or K
-    if K % bk:
-        bk = K
-    bm = block_m or pick_block(M, 256) or M
-    if M % bm:
-        bm = M
-    num_k, num_m = K // bk, M // bm
-    # host-side prep (tiny): per-row flat-block offsets + scale candidates
-    rows = jnp.arange(K, dtype=jnp.int32)
-    base = (rows * N) // W4_BLOCK
-    hoff = ((rows * N) % W4_BLOCK) // 2  # [K] half-offsets (pairs)
-    n_sel = (half_block - 1 + n_half - 1) // half_block + 1
-    sf = jax.lax.bitcast_convert_type(s, jnp.float16).astype(jnp.float32)
-    sf = sf / jnp.float32(7.0)
-    cand = jnp.clip(
-        base[:, None] + jnp.arange(n_sel, dtype=jnp.int32)[None], 0, nb - 1
-    )
-    sarr = sf[cand]  # [K, n_sel]
-    qb = q[: K * n_half].reshape(K, n_half)
-    x2 = x.astype(dtype)
-
-    oe, oo = pl.pallas_call(
-        functools.partial(
-            _w4_kernel, num_k=num_k, n_sel=n_sel, n_half=n_half
-        ),
-        name="odtp_w4_matmul",
-        grid=(num_m, num_k),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda mi, ki: (mi, ki)),
-            pl.BlockSpec((bk, n_half), lambda mi, ki: (ki, 0)),
-            pl.BlockSpec((bk, n_sel), lambda mi, ki: (ki, 0)),
-            pl.BlockSpec((bk, 1), lambda mi, ki: (ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, n_half), lambda mi, ki: (mi, 0)),
-            pl.BlockSpec((bm, n_half), lambda mi, ki: (mi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, n_half), dtype, vma=jax.typeof(x).vma),
-            jax.ShapeDtypeStruct((M, n_half), dtype, vma=jax.typeof(x).vma),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bm, n_half), jnp.float32),
-            pltpu.VMEM((bm, n_half), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        interpret=_interpret(interpret),
-    )(x2, qb, sarr, hoff[:, None])
-    # re-interleave the even/odd column planes: [M, N/2, 2] -> [M, N]
-    return jnp.stack([oe, oo], axis=-1).reshape(M, N)
+    return out.transpose(0, 3, 1, 2, 4).reshape(s_, kq, h, d)

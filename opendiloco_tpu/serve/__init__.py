@@ -86,11 +86,6 @@ def build_serving(
         snapshot_fn = diloco_opt.master_snapshot_wire
         epoch_fn = lambda: diloco_opt.epoch
         epoch = diloco_opt.epoch
-    # fast-decode knob overrides (experiments without a config edit)
-    env_k = os.environ.get("ODTP_SPEC_K")
-    spec_k = int(env_k) if env_k else serve_cfg.spec_decode_k
-    env_wf = os.environ.get("ODTP_DECODE_WEIGHT_FORMAT")
-    weight_format = env_wf if env_wf else serve_cfg.weight_format
     env_dk = os.environ.get("ODTP_DECODE_KERNEL")
     decode_kernel = env_dk if env_dk else serve_cfg.decode_kernel
     engine = ServeEngine(
@@ -104,9 +99,6 @@ def build_serving(
         snapshot_fn=snapshot_fn,
         epoch_fn=epoch_fn,
         max_stale_rounds=serve_cfg.max_stale_rounds,
-        spec_k=spec_k,
-        draft_layers=serve_cfg.draft_layers,
-        weight_format=weight_format,
         decode_kernel=decode_kernel,
     )
     env_tier = os.environ.get("ODTP_KV_TIER")

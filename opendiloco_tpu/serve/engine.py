@@ -12,11 +12,10 @@ so no prefill or decode program starts by casting the whole tree again
 they left on the device). The engine exposes a handful of device
 operations to the scheduler loop — ``admit`` (prefill a prompt into a
 free slot, optionally continuing from a reused prefix), ``decode_step``
-(one token for every live slot), ``spec_step`` (self-speculative draft +
-verify, several tokens per live slot), and ``maybe_swap`` (adopt a newer
-master snapshot from the outer plane). All are called from a single scheduler thread;
-the engine is deliberately not thread-safe so the jits can donate the
-cache buffers without a lock.
+(one token for every live slot), and ``maybe_swap`` (adopt a newer master
+snapshot from the outer plane). All are called from a single scheduler
+thread; the engine is deliberately not thread-safe so the jits can donate
+the cache buffers without a lock.
 
 A cold admission comes in two halves, so that a loop can enqueue every
 program of an iteration before it reads any of them: ``admit_enqueue``
@@ -30,30 +29,9 @@ blocking ``admit`` is the two halves and the logits row: the same jitted
 programs whichever way. ``admissions_deferred`` counts the admissions a
 step fed on the device.
 
-Fast-decode legs (each individually off by default, and off-path
-bit-identical to the plain engine):
-
-- ``spec_k > 0``: self-speculative decode. A draft over the first
-  ``draft_layers`` of the SAME weights proposes k greedy tokens per slot;
-  one batched full-depth verify pass accepts the longest agreeing prefix
-  plus the corrected token (Leviathan et al., arXiv 2211.17192 — greedy
-  case). Outputs are token-identical to the one-token loop by
-  construction: every emitted token is the full model's greedy argmax
-  given exactly the tokens before it.
-- ``weight_format="w4"``: the stacked decoder matmul weights stay
-  blockwise-4bit packed at rest (PR 8 codec geometry, per layer) and
-  dequantize per block inside the jit'd forwards; norms, embeddings, the
-  lm head, and a routed FFN's router and experts are held in the compute
-  dtype like every leaf of the other format. ~4x fewer weight bytes
-  touched per decode step,
-  and ``install_wire`` of a blockwise4bit snapshot re-slices the wire
-  payload directly into the resident layout when block and layer grids
-  align (no dequant/requantize round trip).
-- ``weight_format="fp32"`` (the default) means *not packed*: float32
-  masters come in and are held in the compute dtype, as above. The name is
-  what arrives, not what is resident.
-- prefix reuse (scheduler-driven): ``admit(..., prefix_src, prefix_len)``
-  ring-copies a live slot's prefix K/V and prefills only the suffix.
+Prefix reuse (scheduler-driven, off by default): ``admit(..., prefix_src,
+prefix_len)`` ring-copies a live slot's prefix K/V and prefills only the
+suffix (the continued prefill).
 
 Hot-swap pulls codec-encoded snapshots (``DiLoCoOptimizer.
 master_snapshot_wire``, the fp16 ``ODTP_STATE_CODEC`` path) and rebinds
@@ -75,22 +53,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from opendiloco_tpu import obs
-from opendiloco_tpu.diloco.compression import (
-    get_codec,
-    pack_blockwise4_stacked,
-    split_blockwise4_stacked,
-)
+from opendiloco_tpu.diloco.compression import get_codec
 from opendiloco_tpu.models.llama import (
     LlamaConfig,
-    PackedW4,
+    continue_prefill,
     decode_forward,
-    dequant_w4,
-    draft_propose,
     prefill_forward,
     refuse_eva,
     refuse_latent,
     refuse_recurrent,
-    verify_forward,
 )
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
@@ -105,7 +76,6 @@ from opendiloco_tpu.models.ring_cache import (
     layer_pages,
     prefix_copy,
     slot_cache,
-    spec_cache_insert,
     state_insert,
     suffix_insert,
 )
@@ -113,7 +83,6 @@ from opendiloco_tpu.ops.attention import (
     decode_step_attention,
     eva_decode_step_attention,
     latent_decode_step_attention,
-    spec_tail_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     DecodePlan,
@@ -124,10 +93,8 @@ from opendiloco_tpu.ops.decode_kernels import (
     mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
-    spec_tail_attention_fused,
-    w4_matmul,
 )
-from opendiloco_tpu.serve.kvcache import accept_counts, pick_bucket
+from opendiloco_tpu.serve.kvcache import pick_bucket
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -204,7 +171,7 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     def prefill(p, ids, length):
         with jax.named_scope("odtp_serve_prefill"):
             logits, ks, vs, *rest = prefill_forward(
-                p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
+                p, ids, length, cfg, compute_dtype=cd,
                 return_moe_counts=moe, return_expert_choices=chosen,
             )
             left, counts = rest[:n_state], rest[n_state : n_state + 1]
@@ -288,10 +255,7 @@ class Admission:
 # what DiLoCoOptimizer.master_snapshot_wire returns.
 SnapshotFn = Callable[[], tuple]
 
-_STAGES = (
-    "prefill", "draft", "verify", "insert", "decode", "swap",
-    "page_out", "page_in",
-)
+_STAGES = ("prefill", "decode", "swap", "page_out", "page_in")
 # where a cold prefill and a decode step change hands: until the arguments of
 # the call's first program are device arrays, until its last jitted call has
 # returned to Python, and from where the host starts to wait for the call's
@@ -300,6 +264,8 @@ _PHASES = ("args", "dispatch", "fetch")
 
 
 class ServeEngine:
+    weight_format = "fp32"  # benchmark/odbench/serve_cell.py:62 prints it and nothing else reads it (ROADMAP C-b19)
+
     def __init__(
         self,
         cfg: LlamaConfig,
@@ -313,9 +279,6 @@ class ServeEngine:
         snapshot_fn: Optional[SnapshotFn] = None,
         epoch_fn: Optional[Callable[[], int]] = None,
         max_stale_rounds: int = 0,
-        spec_k: int = 0,
-        draft_layers: int = 0,
-        weight_format: str = "fp32",
         decode_kernel: Optional[str] = None,
     ):
         self.cfg = cfg
@@ -329,73 +292,11 @@ class ServeEngine:
         self.epoch_fn = epoch_fn
         self.max_stale_rounds = int(max_stale_rounds)
 
-        self.weight_format = str(weight_format)
-        if self.weight_format not in ("fp32", "w4"):
-            raise ValueError(f"unknown weight_format {weight_format!r}")
-        if cfg.hybrid and self.weight_format == "w4":
-            raise ValueError(
-                "weight_format=w4 is refused for a configuration with Mamba-2 "
-                "layers: the blockwise 4-bit packing is defined for the [L, in, "
-                "out] matmul leaves of one homogeneous stack, not for a mixer's "
-                "in_proj/out_proj, conv and decay leaves"
-            )
-        if cfg.cca and self.weight_format == "w4":
-            raise ValueError(
-                "weight_format=w4 is refused for a configuration with CCA: the "
-                "blockwise 4-bit packing is defined for the [L, in, out] matmul "
-                "leaves of a plain attention block, not for the convolutions over "
-                "q and k that share their rank"
-            )
-        if self.weight_format == "w4":
-            refuse_eva(
-                cfg, "weight_format=w4 (the packing is defined for the matmul leaves "
-                "of a plain attention block and has not met adaptive_phi / adaptive_mu_k)"
-            )
-            refuse_latent(
-                cfg, "weight_format=w4 (kv_b_proj is read in two halves, one absorbed "
-                "into q and one into the output, which the fused dequant-matmul does not do)"
-            )
         # "auto"/None resolves to pallas only on TPU backends; tests force
         # "pallas" explicitly and the kernels run interpreted off-TPU
         self.decode_kernel = resolve_decode_kernel(decode_kernel)
-        self.spec_k = int(spec_k)
-        if self.spec_k < 0:
-            raise ValueError(f"spec_k must be >= 0, got {spec_k}")
-        if self.spec_k:
-            refuse_eva(cfg, f"speculative decode (spec_k={self.spec_k})")
-            refuse_recurrent(cfg, f"speculative decode (spec_k={self.spec_k})")
-            refuse_latent(cfg, f"speculative decode (spec_k={self.spec_k})")
-            L = cfg.num_hidden_layers
-            ld = int(draft_layers) or max(1, L // 2)
-            if not 1 <= ld < L:
-                raise ValueError(
-                    f"draft_layers {ld} outside [1, {L}) for spec decode"
-                )
-            if self.spec_k + 1 > self.max_context:
-                raise ValueError(
-                    f"spec_k {self.spec_k} + 1 exceeds max_context "
-                    f"{self.max_context}"
-                )
-            self.draft_layers = ld
-        else:
-            self.draft_layers = 0
-        # widest unverified tail a slot may carry: current token + k drafts.
-        # The scheduler uses it to bound ring headroom for prefix reuse.
-        self.tail_width = self.spec_k + 1
-
         leaves, self._treedef = jax.tree.flatten(params)
-        kp, _ = jax.tree_util.tree_flatten_with_path(params)
-        self._paths = [
-            tuple(getattr(k, "key", str(k)) for k in path) for path, _ in kp
-        ]
         self._shapes = [tuple(x.shape) for x in leaves]
-        # w4-packable set: the stacked decoder matmuls ([L, in, out] leaves
-        # under "layers"); norms ([L, D]), embeddings, the lm head, and a
-        # routed FFN's router and [L, E, in, out] experts stay unpacked
-        self._packable = [
-            p[0] == "layers" and len(s) == 3 and p[-1] != "router"
-            for p, s in zip(self._paths, self._shapes)
-        ]
         # bindings of a weight tree so far: 1 here, +1 a swap
         self.weight_binds = 0
         self._bind(leaves, epoch)
@@ -547,36 +448,10 @@ class ServeEngine:
         self._state_insert = jax.jit(state_insert, donate_argnums=(0, 1))
         self._cca_insert = jax.jit(cca_state_insert, donate_argnums=(0,))
 
-        # speculative-decode jits (compiled only when spec_step runs)
-        kk, ld = self.spec_k, self.draft_layers
-
-        def _draft(p, tokens, lens, ck, cv):
-            return draft_propose(
-                p, tokens, lens, ck, cv, cfg,
-                k_steps=kk, draft_layers=ld, compute_dtype=cd,
-                decode_kernel=dkn,
-            )
-
-        def _verify(p, tail, lens, ck, cv):
-            logits, tks, tvs = verify_forward(
-                p, tail, lens, ck, cv, cfg, compute_dtype=cd,
-                decode_kernel=dkn,
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), tks, tvs
-
-        self._draft = jax.jit(_draft)
-        self._verify = jax.jit(_verify)
-        self._spec_insert = jax.jit(spec_cache_insert, donate_argnums=(0, 1))
-        # host hook: tests swap in adversarial proposers; returns [S, k] np
-        self.propose_fn: Callable[[np.ndarray, np.ndarray], np.ndarray] = (
-            self._propose_draft
-        )
-
         # shared-prefix reuse jits (compiled only when the batcher asks)
         def _suffix(p, ck, cv, slot, tail, plen):
-            # continued prefill = the verify primitive over the one slot's
-            # pages: tail tokens at positions plen..plen+B-1
-            logits, tks, tvs = verify_forward(
+            # over the one slot's pages: tail tokens at positions plen..plen+B-1
+            logits, tks, tvs = continue_prefill(
                 p, tail, plen[None], *slot_cache(ck, cv, slot), cfg,
                 compute_dtype=cd, decode_kernel=dkn,
             )
@@ -611,28 +486,13 @@ class ServeEngine:
 
     def _bind(self, leaves, epoch: int) -> None:
         """The one door weights come through: flat leaves (original flatten
-        order; float32 masters on the device or the host, or pre-packed
-        :class:`PackedW4` nodes from the install_wire fast path) become
-        ``self.params``, the tree every program of the engine reads.
-
-        Every leaf lands in a fresh buffer in ``compute_dtype``, rounded here
-        once, and the engine keeps nothing else of it; ``weight_format=w4``
-        first packs the stacked matmul leaves, which stay packed."""
-        out = list(leaves)
-        if self.weight_format == "w4":
-            for i, (leaf, packable) in enumerate(zip(leaves, self._packable)):
-                if packable and not isinstance(leaf, PackedW4):
-                    q, s = pack_blockwise4_stacked(
-                        np.asarray(jax.device_get(leaf), np.float32)
-                    )
-                    out[i] = PackedW4(
-                        jnp.asarray(q), jnp.asarray(s), self._shapes[i][1:]
-                    )
-        fresh = iter(_fresh_copy(
-            [x for x in out if not isinstance(x, PackedW4)], self.compute_dtype
-        ))
-        out = [x if isinstance(x, PackedW4) else next(fresh) for x in out]
-        self.params = jax.tree.unflatten(self._treedef, out)
+        order; float32 masters on the device or the host) become
+        ``self.params``, the tree every program of the engine reads. Every
+        leaf lands in a fresh buffer in ``compute_dtype``, rounded here once,
+        and the engine keeps nothing else of it."""
+        self.params = jax.tree.unflatten(
+            self._treedef, _fresh_copy(list(leaves), self.compute_dtype)
+        )
         self.weights_epoch = int(epoch)
         self.weight_binds += 1
         self.weights_resident_bytes = sum(
@@ -1052,76 +912,16 @@ class ServeEngine:
         )
         return tok, logits
 
-    def _propose_draft(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
-        return np.asarray(
-            self._draft(
-                self.params,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(lens, jnp.int32),
-                self.cache_k,
-                self.cache_v,
-            )
-        )
-
-    def spec_step(
-        self, tokens: np.ndarray, lens: np.ndarray
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """One self-speculative round over all S slots: draft k proposals,
-        verify the [current, d_1..d_k] tail full-depth, keep the longest
-        agreeing prefix. Returns (g [S, k+1] np.int32, m [S] np.int32):
-        slot s emits ``g[s, :m[s]+1]`` — its next m[s]+1 greedy tokens,
-        token-identical to m[s]+1 plain decode_steps — and its cache now
-        holds the tail rows 0..m[s] (rejected proposals were never
-        inserted; that IS the rollback). The insert is still in flight at
-        return and off the TPU reads ``lens`` where the caller keeps it:
-        hand over arrays that are not written again."""
-        if not self.spec_k:
-            raise RuntimeError("spec_step requires spec_k > 0")
-        t0 = time.perf_counter()
-        props = np.asarray(self.propose_fn(tokens, lens), np.int32)  # [S, k]
-        t1 = time.perf_counter()
-        tail = np.concatenate(
-            [np.asarray(tokens, np.int32)[:, None], props], axis=1
-        )
-        g, tks, tvs = self._verify(
-            self.params,
-            jnp.asarray(tail),
-            jnp.asarray(lens, jnp.int32),
-            self.cache_k,
-            self.cache_v,
-        )
-        g = np.asarray(g)  # [S, k+1]
-        t2 = time.perf_counter()
-        m = accept_counts(props, g)
-        self.cache_k, self.cache_v = self._spec_insert(
-            self.cache_k, self.cache_v, tks, tvs,
-            jnp.asarray(lens, jnp.int32), jnp.asarray(m),
-        )
-        t3 = time.perf_counter()
-        self.stage_seconds["draft"] += t1 - t0
-        self.stage_seconds["verify"] += t2 - t1
-        self.stage_seconds["insert"] += t3 - t2
-        obs.count(f"serve_decode_kernel_{self.decode_kernel}")
-        tr = obs.tracer()
-        if tr is not None:
-            tr.add_span("serve_draft", t0, t1, k=self.spec_k)
-            tr.add_span("serve_verify", t1, t2)
-            tr.add_span("serve_spec_insert", t2, t3)
-        return g, m
-
     # -- kernel attribution -------------------------------------------------
 
     def kernel_probe(self, iters: int = 3) -> dict:
-        """Time the decode-path kernels in isolation on the engine's live
-        shapes and publish per-kernel gauges (serve_decode_attn_us,
-        serve_verify_attn_us, serve_w4_matmul_us) so DECODE_BENCH
-        attribution shows where the kernel time went, per dispatch path;
-        and, for a keys-and-values ring, the decode kernel's plan at those
-        shapes (serve_decode_plan_heads, _block_t, _block_diagonal).
+        """Time the decode step's attention in isolation on the engine's live
+        shapes and publish it as a gauge (serve_decode_attn_us), per dispatch
+        path; and, for a keys-and-values ring, the decode kernel's plan at
+        those shapes (serve_decode_plan_heads, _block_t, _block_diagonal).
 
         Best-of-``iters`` steady-state timings on the resolved path
-        (``self.decode_kernel``); the w4 gauge only appears under
-        ``weight_format=w4`` (there is no dequant-matmul otherwise)."""
+        (``self.decode_kernel``)."""
         cfg, cd = self.cfg, self.compute_dtype
         S, T = self.num_slots, self.max_context
         Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
@@ -1129,7 +929,6 @@ class ServeEngine:
         pallas = self.decode_kernel == "pallas"
         if cfg.latent:
             # the one attention of a latent cache: the absorbed decode step
-            # (there is no verify pass to time, and no w4)
             ql = jax.random.normal(key, (S, Nh, cfg.latent_row_dim), cd)
 
             def _latent(ql, lens, cache):
@@ -1145,8 +944,8 @@ class ServeEngine:
             )})
         if cfg.eva:
             # a decode step's attention over both rings of the one layer, at
-            # a position half-way through the second window (there is no
-            # verify pass to time, and no w4), and the kernel's plan for each
+            # a position half-way through the second window, and the kernel's
+            # plan for each
             q1 = jax.random.normal(key, (S, Nh, Dh), cd)
             k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
             step = eva_decode_attention if pallas else eva_decode_step_attention
@@ -1175,9 +974,7 @@ class ServeEngine:
         q1 = jax.random.normal(key, (S, Nh, Dh), cd)
         ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
         lens = jnp.full((S,), T // 2, jnp.int32)
-        kq = self.tail_width
-        qt = jax.random.normal(key, (S, kq, Nh, Dh), cd)
-        tk = jax.random.normal(key, (S, kq, Nkv, Dh), cd)
+        k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
 
         def _attn(q1, k1, lens, ck, cv):
             # a decode step's attention over a cache of the one layer: the
@@ -1185,16 +982,10 @@ class ServeEngine:
             step = paged_decode_attention if pallas else decode_step_attention
             return step(q1, k1, k1, ck, cv, lens, 0)
 
-        def _vattn(qt, ck, cv, tk, lens):
-            if pallas:
-                return spec_tail_attention_fused(qt, ck, cv, tk, tk, lens)
-            return spec_tail_attention(qt, ck, cv, tk, tk, lens)
-
         out = {
             "decode_attn_us": _best_us(
-                _attn, q1, tk[:, 0], lens, ck[None], cv[None], carried=2, iters=iters
+                _attn, q1, k1, lens, ck[None], cv[None], carried=2, iters=iters
             ),
-            "verify_attn_us": _best_us(_vattn, qt, ck, cv, tk, lens, iters=iters),
         }
         # which form of the decode kernel these shapes take (zeros: the XLA path)
         plan = pallas and decode_plan(Nkv, Dh, T, self.cache_k.dtype.itemsize)
@@ -1202,26 +993,6 @@ class ServeEngine:
         out["decode_plan_heads"] = float(plan.heads)
         out["decode_plan_block_t"] = float(plan.block_t)
         out["decode_plan_block_diagonal"] = float(plan.block_diagonal)
-        packed = next(
-            (
-                w
-                for w in jax.tree.leaves(
-                    self.params, is_leaf=lambda x: isinstance(x, PackedW4)
-                )
-                if isinstance(w, PackedW4) and len(w.shape) == 2
-            ),
-            None,
-        )
-        if packed is not None:
-            x = jax.random.normal(key, (S, packed.shape[0]), cd)
-            if pallas:
-                def _wmm(x, q, s):
-                    return w4_matmul(x, q, s, packed.shape, cd)
-            else:
-                def _wmm(x, q, s):
-                    return x @ dequant_w4(q, s, packed.shape, cd)
-            # stacked leaf: layer 0's slice is what one scan step sees
-            out["w4_matmul_us"] = _best_us(_wmm, x, packed.q[0], packed.s[0], iters=iters)
         return self._publish_probe(out)
 
     def _publish_probe(self, out: dict) -> dict:
@@ -1262,43 +1033,21 @@ class ServeEngine:
         return True
 
     def install_wire(self, epoch: int, blobs, codec_name: str) -> None:
-        """Decode a codec-encoded master snapshot and rebind the weights.
-
-        With ``weight_format=w4`` and a ``blockwise4bit`` snapshot the
-        packed leaves are re-sliced straight from the wire payload when
-        the codec's whole-leaf block grid lands on layer boundaries —
-        cheaper than decoding, AND exact where a dequantize/requantize
-        round trip is not bit-stable."""
+        """Decode a codec-encoded master snapshot and rebind the weights."""
         codec = get_codec(codec_name)
         if len(blobs) != len(self._shapes):
             raise ValueError(
                 f"snapshot has {len(blobs)} leaves, engine expects "
                 f"{len(self._shapes)}"
             )
-        fast = self.weight_format == "w4" and codec_name == "blockwise4bit"
         leaves = []
-        for (payload, meta, shape), want, packable in zip(
-            blobs, self._shapes, self._packable
-        ):
+        for (payload, meta, shape), want in zip(blobs, self._shapes):
             if tuple(shape) != want:
                 raise ValueError(f"snapshot leaf shape {shape} != {want}")
             size = int(np.prod(shape)) if shape else 1
-            if fast and packable:
-                res = split_blockwise4_stacked(
-                    payload, meta, int(shape[0]), size // int(shape[0])
-                )
-                if res is not None:
-                    q, s = res
-                    leaves.append(
-                        PackedW4(
-                            jnp.asarray(q), jnp.asarray(s), tuple(shape[1:])
-                        )
-                    )
-                    continue
-            a = np.asarray(
-                codec.decode(payload, (size,), meta), np.float32
-            ).reshape(shape)
-            leaves.append(a)
+            leaves.append(
+                np.asarray(codec.decode(payload, (size,), meta), np.float32).reshape(shape)
+            )
         self._bind(leaves, epoch)
 
     def install_params(self, epoch: int, params) -> None:

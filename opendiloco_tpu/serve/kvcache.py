@@ -63,31 +63,6 @@ class SlotAllocator:
         return self.num_slots - len(self._free)
 
 
-def accept_counts(draft: np.ndarray, verified: np.ndarray) -> np.ndarray:
-    """Speculative accept/reject bookkeeping (host side, exact).
-
-    draft [S, k] are the proposed tokens; verified [S, k+1] are the
-    full-depth greedy tokens, where verified[:, j] is the model's true
-    next token AFTER tail position j. Proposal j is accepted iff every
-    proposal before it was and ``draft[:, j] == verified[:, j]`` — the
-    longest agreeing prefix. Returns m [S] int32 in [0, k]: the slot
-    emits tokens ``verified[:, :m+1]`` (m accepted drafts plus the one
-    corrected/bonus token), and the ring keeps exactly tail entries
-    0..m — rejected tokens are never inserted, which IS the rollback."""
-    draft = np.asarray(draft)
-    verified = np.asarray(verified)
-    S, k = draft.shape
-    if verified.shape != (S, k + 1):
-        raise ValueError(
-            f"verified shape {verified.shape} != {(S, k + 1)}"
-        )
-    agree = draft == verified[:, :k]
-    # index of the first disagreement == count of accepted proposals
-    return np.where(
-        agree.all(axis=1), np.int32(k), np.argmin(agree, axis=1).astype(np.int32)
-    )
-
-
 def common_prefix_len(a: Sequence[int], b: Sequence[int]) -> int:
     """Length of the shared leading run of two prompts (prefix-cache
     detection). Pure host bookkeeping; O(min len)."""

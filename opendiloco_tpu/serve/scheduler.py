@@ -178,7 +178,6 @@ class ContinuousBatcher:
         self.kv_tier = kv_tier
         self.tier_quantum_steps = max(1, int(tier_quantum_steps))
         self.tier_min_resident_steps = max(1, int(tier_min_resident_steps))
-        self.spec_decode = engine.spec_k > 0
         # the one-time kernel probe feeds gauges alone: it runs only in a
         # process whose tracer ODTP_OBS had armed by now, never because a
         # capture arms one later (a probe compiles, and a capture is a stretch
@@ -219,9 +218,6 @@ class ContinuousBatcher:
         self.staleness_hist: collections.Counter = collections.Counter()
         self._rate_mark = (time.perf_counter(), 0)
         self.loop_error: Optional[str] = None
-        # speculative-decode accounting (loop thread only)
-        self.spec_proposed = 0
-        self.spec_accepted = 0
         # shared-prefix reuse accounting (live-slot ring copies + host
         # tier restores; host_prefix_hits is the tier subset)
         self.prefix_hits = 0
@@ -566,16 +562,13 @@ class ContinuousBatcher:
         """Longest usable shared prompt prefix among the live slots.
 
         A source qualifies while its ring has not wrapped (rows < plen
-        still hold the prefix K/V) — ``tail_width`` of headroom keeps the
-        next spec tail from wrapping before the copy lands. The reused
+        still hold the prefix K/V), its next decode step's row included: that
+        step must not wrap before the copy lands. The reused
         length is capped one short of the prompt so the suffix pass always
         has at least the final token to run (its logits seed decode)."""
         best_src, best = None, 0
         for slot, st in self._active.items():
-            if (
-                st.cache_len + self.engine.tail_width
-                > self.engine.max_context
-            ):
+            if st.cache_len >= self.engine.max_context:
                 continue
             p = common_prefix_len(prompt, st.req.prompt)
             p = min(p, len(prompt) - 1)
@@ -657,8 +650,8 @@ class ContinuousBatcher:
         """One admission path, whose first token is read at once or after the
         next decode step is enqueued, by the kind of admission and of step: a
         cold prefill's token stays on the device, where the step takes it,
-        unless nothing will step it there (speculation drafts from tokens on
-        the host; a request of one token needs no step); a continued prefill
+        unless nothing will step it there (a request of one token needs no
+        step); a continued prefill
         (live prefix, host tier) reads as it always did."""
         st = _Slot(
             req=req, cache_len=len(req.prompt), last_token=0,
@@ -691,7 +684,7 @@ class ContinuousBatcher:
         else:
             adm = self.engine.admit_enqueue(slot, req.prompt)
             self._maybe_store_prefix(slot, req.prompt)
-            if not self.spec_decode and req.max_new_tokens > 1:
+            if req.max_new_tokens > 1:
                 st.admission = adm
                 self._awaiting.append(slot)
                 return
@@ -857,8 +850,6 @@ class ContinuousBatcher:
     def _decode(self, t0: Optional[float] = None) -> bool:
         if not self._active:
             return False
-        if self.spec_decode:
-            return self._decode_spec(t0)
         S = self.engine.num_slots
         # the decode span covers the WHOLE step — batch assembly, the
         # engine call, and token emit — so per-step scheduler time is
@@ -920,64 +911,6 @@ class ContinuousBatcher:
         if tr is not None:
             tr.add_span("serve_batch", t_batch, step_t0)
             tr.add_span("serve_emit", step_t1, t_emit)
-        return True
-
-    def _decode_spec(self, t0: Optional[float] = None) -> bool:
-        """One speculative round: every live slot consumes its accepted
-        prefix + the corrected token, so a single engine call advances a
-        slot by 1..k+1 tokens — token-for-token what k+1 plain decode
-        steps would have produced (engine.spec_step docstring)."""
-        S = self.engine.num_slots
-        # span covers the whole round (assembly + engine + emit) — see
-        # the plain _decode comment
-        if t0 is None:
-            t0 = time.perf_counter()
-        tokens = np.zeros((S,), np.int32)
-        lens = np.zeros((S,), np.int32)
-        for slot, st in self._active.items():
-            tokens[slot] = st.last_token
-            lens[slot] = st.cache_len
-        g, m = self.engine.spec_step(tokens, lens)
-        self.staleness_hist[self.engine.staleness()] += 1
-        proposed = self.engine.spec_k * len(self._active)
-        accepted = sum(int(m[slot]) for slot in self._active)
-        self.spec_proposed += proposed
-        self.spec_accepted += accepted
-        obs.count("serve_spec_proposed", proposed)
-        obs.count("serve_spec_accepted", accepted)
-        batch = len(self._active)
-        done_slots = []
-        emitted = 0
-        emitted_by_slot = {}
-        for slot, st in self._active.items():
-            slot_emitted = 0
-            st.resident_steps += 1
-            for tok in g[slot, : int(m[slot]) + 1].tolist():
-                st.req.tokens.append(int(tok))
-                st.cache_len += 1
-                st.last_token = int(tok)
-                self.total_new_tokens += 1
-                emitted += 1
-                slot_emitted += 1
-                if self._finished(st):
-                    done_slots.append(slot)
-                    break
-            emitted_by_slot[slot] = slot_emitted
-        t1 = self._t_step_end = time.perf_counter()
-        rt = reqtrace.ring()
-        if rt is not None:
-            for slot, st in self._active.items():
-                if st.req.trace is not None:
-                    rt.span(
-                        st.req.trace, "decode", max(t0, st.req.t_first), t1,
-                        batch=batch, tokens=emitted_by_slot[slot],
-                        proposed=self.engine.spec_k, accepted=int(m[slot]),
-                        kernel=self.engine.decode_kernel,
-                    )
-        obs.count("serve_tokens_generated", emitted)
-        for slot in done_slots:
-            self.slots.free(slot)
-            self._retire(self._active.pop(slot))
         return True
 
     def _finished(self, st: _Slot) -> bool:
@@ -1069,10 +1002,6 @@ class ContinuousBatcher:
             "serve_batch_occupancy", self.slots.num_active / self.slots.num_slots
         )
         obs.gauge("serve_snapshot_staleness", staleness)
-        if self.spec_proposed:
-            obs.gauge(
-                "serve_spec_acceptance", self.spec_accepted / self.spec_proposed
-            )
         if self.kv_tier is not None:
             obs.gauge("serve_tier_occupancy", self.kv_tier.occupancy())
             obs.gauge("serve_tier_paused", len(self._paused))
@@ -1177,15 +1106,6 @@ class ContinuousBatcher:
                     "window_restarts", "cache_bytes_moved", "cache_resident_bytes",
                 )},
                 "forms": self.engine.eva_forms,
-            },
-            "spec": {
-                "proposed": self.spec_proposed,
-                "accepted": self.spec_accepted,
-                "acceptance_rate": (
-                    self.spec_accepted / self.spec_proposed
-                    if self.spec_proposed
-                    else None
-                ),
             },
             "prefix": {
                 "hits": self.prefix_hits,

@@ -225,8 +225,8 @@ def fragment_breakdown(events: list[dict]) -> dict[tuple[int, int], dict]:
 
 def serve_breakdown(events: list[dict]) -> dict[str, float]:
     """One worker's serve-plane decode-stage seconds, summed per span
-    name (``serve_prefill`` / ``serve_draft`` / ``serve_verify`` /
-    ``serve_spec_insert``). Empty when the worker never served."""
+    name (``serve_prefill`` / ``serve_decode`` / ...). Empty when the
+    worker never served."""
     out: dict[str, float] = {}
     for ev in events:
         name = ev.get("name") or ""
@@ -524,7 +524,7 @@ def merge_report(trace_dir: str) -> tuple[dict, dict]:
             counters[k] = counters.get(k, 0.0) + v
 
     # serve-plane surface (train+serve workers): per-worker decode-stage
-    # span totals plus the speculative-decode acceptance the counters imply
+    # span totals and the serve counters
     serve_stages: dict[str, dict[str, float]] = {}
     for wid, events, _meta in workers:
         b = serve_breakdown(events)
@@ -538,13 +538,8 @@ def merge_report(trace_dir: str) -> tuple[dict, dict]:
     serve: dict = {}
     if serve_stages or serve_counters:
         serve = {"stages_s": serve_stages, "counters": serve_counters}
-        proposed = serve_counters.get("serve_spec_proposed", 0)
-        if proposed:
-            serve["spec_acceptance"] = round(
-                serve_counters.get("serve_spec_accepted", 0) / proposed, 4
-            )
         # decode-kernel attribution: engine steps by dispatch path plus the
-        # batcher's one-shot per-kernel isolation probe (µs on live shapes)
+        # batcher's one-shot isolation probe (µs on live shapes)
         kernel_steps = {
             k[len("serve_decode_kernel_"):]: counters[k]
             for k in sorted(counters)
@@ -553,11 +548,7 @@ def merge_report(trace_dir: str) -> tuple[dict, dict]:
         probe_us: dict[str, float] = {}
         for _wid, _events, meta in workers:
             for k, v in (meta.get("gauges") or {}).items():
-                if k in (
-                    "serve_decode_attn_us",
-                    "serve_verify_attn_us",
-                    "serve_w4_matmul_us",
-                ):
+                if k == "serve_decode_attn_us":
                     probe_us[k[len("serve_"):]] = round(float(v), 2)
         if kernel_steps or probe_us:
             serve["decode_kernel"] = {

@@ -17,16 +17,12 @@ and the drop count (must be 0 — no request is dropped across a swap).
 The acceptance line (full runs only): at least one hot-swap observed and
 zero dropped/failed requests.
 
-``--decode`` instead runs the fast-decode A/B (PR 11): three arms over
-static weights — plain, self-speculative (``--spec-k``/``--draft-layers``),
-and speculative over 4-bit-resident weights — banking DECODE_BENCH.json
-with per-arm tokens/s, acceptance rate, and the per-stage breakdown
-(prefill/draft/verify/insert/decode/swap) sourced from obs spans. Two
-gates ride the bench: speculative outputs must be token-bit-exact vs the
-plain greedy path (direct engine probes, always), and the best arm must
-clear 2x the banked SERVE_BENCH.json tokens/s (full runs only).
+``--decode`` instead runs plain decode over static weights (no trainer in
+the process), writing DECODE_BENCH.json with tokens/s and the per-stage
+breakdown (prefill/decode/swap) sourced from obs spans. One gate rides it:
+the arm must clear 2x the banked SERVE_BENCH.json tokens/s (full runs only).
 
-    python scripts/serve_bench.py --decode            # banks DECODE_BENCH.json
+    python scripts/serve_bench.py --decode            # writes DECODE_BENCH.json
     python scripts/serve_bench.py --decode --selftest # tiny CI run
 
 ``--longctx`` runs the KV-tiering A/B (PR 20): the same open-loop long-
@@ -35,7 +31,7 @@ all-resident arm with one device slot per request, and a tiered arm with
 4x fewer slots plus the host cold tier (``ODTP_KV_TIER`` machinery)
 paging paused sequences D2H/H2D between decode steps. Banks a
 ``longctx`` section into DECODE_BENCH.json (read-modify-write; the
-decode arms are preserved). Gates: the tiered arm serves an aggregate
+decode arm is preserved). Gates: the tiered arm serves an aggregate
 context >= 4x its device ring capacity, drops nothing, streams token-
 bit-identical outputs (codec none), and its TTFT p50 stays within 1.5x
 of the all-resident arm.
@@ -238,28 +234,13 @@ def run_bench(args) -> dict:
     }
 
 
-# -- fast-decode A/B (--decode) ---------------------------------------------
+# -- plain decode over static weights (--decode) -----------------------------
 
 
-def _pattern_prompt(r, n, vocab):
-    """Templated traffic: arithmetic cycles over the vocabulary. The decode
-    bench trains the tiny model on this family so its greedy continuations
-    are learned structure, not random-init noise — self-speculation's
-    acceptance rate measures something real (a random-init model's draft
-    and full stacks agree near-never; see DECODE_BENCH.json)."""
-    start = int(r.integers(1, 200))
-    step = int(r.integers(1, 4))
-    return ((start + step * np.arange(n)) % (vocab - 12) + 1).tolist()
-
-
-def _decode_model(args, train_steps):
+def _decode_model(args):
     import jax
-    import jax.numpy as jnp
-    import optax
 
-    from opendiloco_tpu.models.llama import (
-        LlamaConfig, causal_lm_loss, forward, init_params,
-    )
+    from opendiloco_tpu.models.llama import LlamaConfig, init_params
 
     model_cfg = LlamaConfig(
         vocab_size=512,
@@ -270,108 +251,7 @@ def _decode_model(args, train_steps):
         num_key_value_heads=2,
         max_position_embeddings=512,
     )
-    params = init_params(jax.random.PRNGKey(0), model_cfg)
-    if not train_steps:
-        return model_cfg, params
-
-    opt = optax.adam(3e-3)
-    ost = opt.init(params)
-    rng = np.random.default_rng(5)
-
-    @jax.jit
-    def train_step(p, o, ids):
-        def loss_fn(p):
-            logits = forward(p, ids, model_cfg, compute_dtype=jnp.float32,
-                             remat=False)
-            return causal_lm_loss(logits, ids)
-
-        loss, g = jax.value_and_grad(loss_fn)(p)
-        up, o = opt.update(g, o)
-        return optax.apply_updates(p, up), o, loss
-
-    t0 = time.perf_counter()
-    for i in range(train_steps):
-        ids = np.stack(
-            [_pattern_prompt(rng, 64, model_cfg.vocab_size) for _ in range(8)]
-        ).astype(np.int32)
-        params, ost, loss = train_step(params, ost, jnp.asarray(ids))
-    print(
-        f"pre-trained {train_steps} steps on patterned data: "
-        f"loss {float(loss):.3f} ({time.perf_counter() - t0:.0f}s)"
-    )
-    return model_cfg, jax.device_get(params)
-
-
-def _probe_engine(args, model_cfg, params, *, spec_k=0, weight_format="fp32"):
-    import jax.numpy as jnp
-
-    from opendiloco_tpu.serve import ServeEngine
-
-    return ServeEngine(
-        model_cfg,
-        params,
-        num_slots=2,
-        max_context=args.max_context,
-        prefill_buckets=(16, 64),
-        compute_dtype=jnp.float32,
-        spec_k=spec_k,
-        draft_layers=args.draft_layers,
-        weight_format=weight_format,
-    )
-
-
-def _greedy_probe(engine, prompt, n):
-    tok, _ = engine.admit(0, prompt)
-    toks = [tok]
-    lens = np.zeros(engine.num_slots, np.int32)
-    cur = np.zeros(engine.num_slots, np.int32)
-    lens[0], cur[0] = len(prompt), tok
-    while len(toks) < n:
-        nt, _ = engine.decode_step(cur, lens)
-        toks.append(int(nt[0]))
-        lens[0] += 1
-        cur[0] = toks[-1]
-    return toks
-
-
-def _spec_probe(engine, prompt, n):
-    tok, _ = engine.admit(0, prompt)
-    toks = [tok]
-    lens = np.zeros(engine.num_slots, np.int32)
-    cur = np.zeros(engine.num_slots, np.int32)
-    lens[0], cur[0] = len(prompt), tok
-    while len(toks) < n:
-        g, m = engine.spec_step(cur.copy(), lens.copy())  # not written again
-        emit = [int(t) for t in g[0, : int(m[0]) + 1]]
-        toks.extend(emit)
-        lens[0] += len(emit)
-        cur[0] = toks[-1]
-    return toks[:n]
-
-
-def _parity_gate(args, model_cfg, params, weight_format):
-    """Token-bit-exact gate: speculative greedy == plain greedy on direct
-    engine probes at the SAME weight residency. Returns probe count."""
-    plain = _probe_engine(args, model_cfg, params, weight_format=weight_format)
-    spec = _probe_engine(
-        args, model_cfg, params,
-        spec_k=args.spec_k, weight_format=weight_format,
-    )
-    rng = np.random.default_rng(11)
-    prompts = [
-        rng.integers(1, model_cfg.vocab_size, n).tolist()
-        for n in (3, 9, 16, min(40, args.max_context // 2))
-    ]
-    n_new = min(24, args.max_new * 2)
-    for prompt in prompts:
-        ref = _greedy_probe(plain, prompt, n_new)
-        got = _spec_probe(spec, prompt, n_new)
-        if got != ref:
-            raise SystemExit(
-                f"spec-vs-plain parity FAILED ({weight_format}): "
-                f"prompt len {len(prompt)}: {got} != {ref}"
-            )
-    return len(prompts)
+    return model_cfg, init_params(jax.random.PRNGKey(0), model_cfg)
 
 
 def _span_totals():
@@ -387,7 +267,7 @@ def _span_totals():
     return {k: round(v, 6) for k, v in sorted(totals.items())}
 
 
-def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> dict:
+def run_decode_arm(args, name, model_cfg, params) -> dict:
     import jax.numpy as jnp
 
     from opendiloco_tpu import obs
@@ -399,15 +279,11 @@ def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> d
         max_batch=args.slots,
         max_context=args.max_context,
         prefill_buckets=[16, 64],
-        spec_decode_k=spec_k,
-        draft_layers=args.draft_layers,
-        weight_format=weight_format,
     )
     plane = build_serving(
         scfg, model_cfg, params, None,
         compute_dtype=jnp.float32, start_server=False,
     )
-    resolved_draft = plane.engine.draft_layers
 
     stop_clients = threading.Event()
     errors = []
@@ -417,7 +293,7 @@ def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> d
     def client_loop(cid):
         r = np.random.default_rng(1000 + cid)
         while not stop_clients.is_set():
-            prompt = _pattern_prompt(r, int(r.integers(3, 15)), model_cfg.vocab_size)
+            prompt = r.integers(1, model_cfg.vocab_size, int(r.integers(3, 15))).tolist()
             req = plane.batcher.submit(
                 prompt, max_new_tokens=int(r.integers(4, args.max_new + 1))
             )
@@ -429,7 +305,7 @@ def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> d
             if req.error is not None:
                 errors.append(req.error)
 
-    # warm every compile (prefill buckets + decode/spec jits) before timing
+    # warm every compile (prefill buckets, decode) before timing
     for b in [3] + list(scfg.prefill_buckets):
         w = plane.batcher.submit(list(range(1, b + 1)), max_new_tokens=2)
         w.wait(300)
@@ -458,9 +334,6 @@ def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> d
     completed = stats["completed"] - base_completed
     new_tokens = stats["new_tokens"] - base_tokens
     arm = {
-        "spec_k": spec_k,
-        "draft_layers": resolved_draft,
-        "weight_format": weight_format,
         "tokens_per_s": round(new_tokens / elapsed, 3),
         "requests_per_s": round(completed / elapsed, 3),
         "completed": completed,
@@ -474,7 +347,6 @@ def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> d
             k: round(v - base_stages.get(k, 0.0), 6)
             for k, v in stats["stages_s"].items()
         },
-        "spec": stats["spec"],
         "client_errors": errors[:5],
         "loop_error": stats["loop_error"],
         "dropped": stats["failed"],
@@ -482,9 +354,7 @@ def run_decode_arm(args, name, model_cfg, params, *, spec_k, weight_format) -> d
     if spans is not None:
         arm["stages_from_spans_s"] = spans
     print(
-        f"[{name}] tokens/s={arm['tokens_per_s']} "
-        f"acceptance={arm['spec']['acceptance_rate']} "
-        f"stages={arm['stages_s']}"
+        f"[{name}] tokens/s={arm['tokens_per_s']} stages={arm['stages_s']}"
     )
     return arm
 
@@ -561,7 +431,7 @@ def _longctx_arm(args, name, model_cfg, params, *, num_slots, kv_tier,
 
 
 def run_longctx(args) -> dict:
-    model_cfg, params = _decode_model(args, 0)
+    model_cfg, params = _decode_model(args)
     rng = np.random.default_rng(3)
     max_new = args.max_new
     prompt_len = args.max_context - max_new  # final context fills the ring
@@ -612,24 +482,8 @@ def run_longctx(args) -> dict:
 
 
 def run_decode(args) -> dict:
-    model_cfg, params = _decode_model(args, args.train_steps)
-    probes = _parity_gate(args, model_cfg, params, "fp32")
-    probes += _parity_gate(args, model_cfg, params, "w4")
-    print(f"parity gate OK ({probes} probes, fp32 + w4 residency)")
-
-    arms = {
-        "plain": run_decode_arm(
-            args, "plain", model_cfg, params, spec_k=0, weight_format="fp32"
-        ),
-        "spec": run_decode_arm(
-            args, "spec", model_cfg, params,
-            spec_k=args.spec_k, weight_format="fp32",
-        ),
-        "spec_w4": run_decode_arm(
-            args, "spec_w4", model_cfg, params,
-            spec_k=args.spec_k, weight_format="w4",
-        ),
-    }
+    model_cfg, params = _decode_model(args)
+    arms = {"plain": run_decode_arm(args, "plain", model_cfg, params)}
     baseline = None
     try:
         with open(_OUT) as f:
@@ -650,9 +504,7 @@ def run_decode(args) -> dict:
             "slots": args.slots,
             "max_new_tokens": args.max_new,
             "duration_s_per_arm": args.duration,
-            "pretrain_steps": args.train_steps,
         },
-        "parity": {"token_bit_exact": True, "probes": probes},
         "arms": arms,
         "baseline_tokens_per_s": baseline,
         "best_arm": best_name,
@@ -668,8 +520,8 @@ def main() -> None:
     ap.add_argument("--selftest", action="store_true",
                     help="tiny CI run; artifact under $TMPDIR, no acceptance line")
     ap.add_argument("--decode", action="store_true",
-                    help="fast-decode A/B: plain vs spec vs spec+w4 arms over "
-                         "static weights; banks DECODE_BENCH.json")
+                    help="plain decode over static weights; writes "
+                         "DECODE_BENCH.json")
     ap.add_argument("--longctx", action="store_true",
                     help="KV-tiering A/B: all-resident vs host-cold-tier arms "
                          "at equal per-request context; banks a `longctx` "
@@ -678,16 +530,8 @@ def main() -> None:
                     choices=("none", "blockwise4bit"),
                     help="cold-page codec for the --longctx tiered arm "
                          "(bit-exactness is only gated with `none`)")
-    ap.add_argument("--spec-k", type=int, default=4,
-                    help="draft tokens per slot per step in the spec arms")
-    ap.add_argument("--draft-layers", type=int, default=0,
-                    help="draft depth for the spec arms (0 = half the stack)")
-    ap.add_argument("--train-steps", type=int, default=1500,
-                    help="pre-train the decode-bench model this many steps on "
-                         "patterned data (templated traffic; gives the draft "
-                         "stack learned structure to agree with)")
     ap.add_argument("--duration", type=float, default=45.0,
-                    help="seconds of sustained load (per arm with --decode)")
+                    help="seconds of sustained load")
     ap.add_argument("--clients", type=int, default=8)
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--hidden", type=int, default=128)
@@ -707,7 +551,6 @@ def main() -> None:
         args.hidden = min(args.hidden, 64)
         args.layers = min(args.layers, 2)
         args.max_new = min(args.max_new, 8 if not args.longctx else 16)
-        args.train_steps = min(args.train_steps, 150)
         args.local_steps = min(args.local_steps, 5)
         if args.longctx:
             args.max_context = min(args.max_context, 64)
@@ -722,7 +565,7 @@ def main() -> None:
             args.slots = min(args.slots, 4)  # 4 device slots vs ~18 requests
         result = run_longctx(args)
         # read-modify-write: the longctx section rides DECODE_BENCH.json
-        # next to the fast-decode arms without clobbering them
+        # next to the decode arm without clobbering it
         try:
             with open(out_path) as f:
                 doc = json.load(f)
@@ -800,7 +643,7 @@ def main() -> None:
                 raise SystemExit("no banked SERVE_BENCH.json baseline to gate on")
             if doc["speedup_vs_baseline"] < 2.0:
                 raise SystemExit(
-                    f"fast decode {doc['best_tokens_per_s']} tok/s is "
+                    f"plain decode {doc['best_tokens_per_s']} tok/s is "
                     f"{doc['speedup_vs_baseline']}x the banked baseline — "
                     "acceptance is >= 2x"
                 )

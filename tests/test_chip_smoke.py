@@ -65,8 +65,7 @@ def test_compare_decode_kernels_phase_on_one_cpu_device():
         MODEL, SEQ, jax.devices()[:1], seed=0, slots=4
     )
     for dtype, tol in chip_smoke.LOGITS_REL_L2.items():
-        for what in ("decode", "verify"):
-            assert facts[dtype][what]["rel_l2"] <= tol
+        assert facts[dtype]["decode"]["rel_l2"] <= tol
 
 
 def test_compare_engines_phase_on_one_cpu_device():
@@ -74,8 +73,7 @@ def test_compare_engines_phase_on_one_cpu_device():
         MODEL, SEQ, jax.devices()[:1], seed=0, buckets=(8, 32), new_tokens=12
     )
     for dtype in ("float32", "bfloat16"):
-        assert facts[dtype]["spec_proposed"] > 0 and facts[dtype]["prefix_hits"] > 0
-    assert facts["float32"]["spec_identical_to_plain"]
+        assert facts[dtype]["prefix_hits"] > 0
     assert facts["float32"]["prefix_identical_to_plain"]
     assert jax.config.jax_default_matmul_precision is None  # restored
 

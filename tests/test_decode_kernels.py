@@ -23,16 +23,12 @@ Oracles:
   block sizes, the ring coming back with one row written per slot
 - a slot's pages survive the host tier's round trip (``fetch_pages`` ->
   ``cache_insert``) bit for bit
-- the fused speculative verify matches ``spec_tail_attention``'s exact
-  ring-wrap eviction mask, across ``q_start`` offsets and the draft's
-  wide-tail (Kq=1) shape
-- the fused W4 matmul with x = I is bit-for-bit ``dequant_w4`` (element
-  order + per-4096-block f16-scale math), odd-N shapes fall back to the
-  XLA dequant, and partial tail scale blocks dequantize correctly
+- the continued prefill's fused tail attention matches
+  ``tail_attention``'s exact ring-wrap eviction mask
 - ``auto`` never selects Pallas off-TPU
 - engine-level: identical token streams xla vs pallas(interpret) across
-  prefill buckets, ring wrap, w4 residency, and speculative decode —
-  including under the continuous batcher
+  prefill buckets and ring wrap, for every architecture family the
+  benchmark's cells serve — including under the continuous batcher
 """
 import time
 
@@ -41,8 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from opendiloco_tpu.diloco.compression import pack_blockwise4_stacked
-from opendiloco_tpu.models.llama import PackedW4, _wmul, dequant_w4, init_params
+import test_evabyte
+import test_serve_deferred_admit
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
     cache_shape,
@@ -54,15 +50,13 @@ from opendiloco_tpu.ops.attention import (
     decode_attention,
     decode_step_attention,
     latent_decode_step_attention,
-    spec_tail_attention,
+    tail_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
-    spec_tail_attention_fused,
-    w4_matmul,
-    w4_matmul_supported,
+    tail_attention_fused,
 )
 from opendiloco_tpu.serve import ContinuousBatcher, ServeEngine
 
@@ -334,102 +328,22 @@ def test_page_out_page_in_round_trip_is_bit_equal():
 
 
 # ---------------------------------------------------------------------------
-# (c) fused speculative verify
+# (b) the continued prefill's tail attention
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("q_start", [0, 1, 3])
 @pytest.mark.parametrize("heads", [(8, 2), (4, 1), (4, 4)])
-def test_spec_tail_fused_parity(heads, q_start):
+def test_tail_attention_fused_parity(heads):
     H, Kh = heads
-    S, T, Kq, D = 5, 32, 3, 16
-    Kt = Kq + q_start  # tail holds earlier draft rows before the queries
-    rng = _rng(q_start * 17 + H)
-    q = _randn(rng, S, Kq, H, D)
+    S, T, K, D = 5, 32, 3, 16
+    rng = _rng(H)
+    q = _randn(rng, S, K, H, D)
     ck, cv = _pages(rng, S, Kh, D, T)
-    tk, tv = _randn(rng, S, Kt, Kh, D), _randn(rng, S, Kt, Kh, D)
+    tk, tv = _randn(rng, S, K, Kh, D), _randn(rng, S, K, Kh, D)
     lens = jnp.asarray([0, 5, T - 2, T, 2 * T + 1], jnp.int32)
-    ref = spec_tail_attention(q, ck, cv, tk, tv, lens, q_start=q_start)
-    out = spec_tail_attention_fused(
-        q, ck, cv, tk, tv, lens, q_start=q_start, block_t=8, interpret=True
-    )
+    ref = tail_attention(q, ck, cv, tk, tv, lens)
+    out = tail_attention_fused(q, ck, cv, tk, tv, lens, block_t=8, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
-
-
-def test_spec_tail_fused_draft_shape():
-    # the draft calls with one query against a k_steps-wide tail buffer
-    S, T, H, Kh, D, k_steps = 3, 16, 4, 2, 16, 3
-    rng = _rng(7)
-    q = _randn(rng, S, 1, H, D)
-    ck, cv = _pages(rng, S, Kh, D, T)
-    tk, tv = _randn(rng, S, k_steps, Kh, D), _randn(rng, S, k_steps, Kh, D)
-    lens = jnp.asarray([0, 9, 2 * T], jnp.int32)
-    for i in range(k_steps):
-        ref = spec_tail_attention(q, ck, cv, tk, tv, lens, q_start=i)
-        out = spec_tail_attention_fused(
-            q, ck, cv, tk, tv, lens, q_start=i, block_t=8, interpret=True
-        )
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
-
-
-# ---------------------------------------------------------------------------
-# (b) fused W4 dequant-matmul
-# ---------------------------------------------------------------------------
-
-
-def _pack2d(rng, K, N):
-    w = rng.standard_normal((1, K, N)).astype(np.float32)
-    q, s = pack_blockwise4_stacked(w)
-    return jnp.asarray(q[0]), jnp.asarray(s[0])
-
-
-@pytest.mark.parametrize(
-    "shape",
-    [
-        (64, 64),     # single scale block
-        (64, 66),     # partial tail scale block (K*N % 4096 != 0)
-        (32, 4128),   # row straddles scale blocks (N > 4096)
-        (8, 8192),    # multiple whole blocks per row
-    ],
-)
-def test_w4_matmul_parity(shape):
-    K, N = shape
-    rng = _rng(K + N)
-    q, s = _pack2d(rng, K, N)
-    x = _randn(rng, 8, K)
-    ref = x @ dequant_w4(q, s, (K, N), jnp.float32)
-    out = w4_matmul(x, q, s, (K, N), jnp.float32, interpret=True)
-    scale = float(jnp.max(jnp.abs(ref))) or 1.0
-    np.testing.assert_allclose(
-        np.asarray(out) / scale, np.asarray(ref) / scale, atol=1e-6
-    )
-
-
-def test_w4_matmul_identity_is_bitwise_dequant():
-    # x = I makes the fused kernel AN implementation of dequant_w4: every
-    # element order / scale-math divergence would show as a bit flip
-    for K, N in [(64, 64), (64, 66), (32, 4128)]:
-        rng = _rng(K * N)
-        q, s = _pack2d(rng, K, N)
-        ref = dequant_w4(q, s, (K, N), jnp.float32)
-        out = w4_matmul(jnp.eye(K, dtype=jnp.float32), q, s, (K, N),
-                        jnp.float32, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_w4_odd_tail_falls_back_to_xla_dequant():
-    # odd N leaves a half-used tail byte; the kernel cannot split such a
-    # weight into even/odd nibble planes, so _wmul keeps the XLA dequant
-    K, N = 16, 7
-    assert not w4_matmul_supported((K, N))
-    rng = _rng(3)
-    w = rng.standard_normal((1, K, N)).astype(np.float32)
-    q, s = pack_blockwise4_stacked(w)
-    leaf = PackedW4(jnp.asarray(q[0]), jnp.asarray(s[0]), (K, N))
-    x = _randn(rng, 4, K)
-    ref = x @ dequant_w4(leaf.q, leaf.s, (K, N), jnp.float32)
-    out = _wmul(x, leaf, jnp.float32, "pallas")
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------
@@ -456,28 +370,42 @@ def test_auto_never_selects_pallas_off_tpu(monkeypatch):
 
 
 @pytest.fixture
-def small_tiles(monkeypatch):
+def small_tiles(request, monkeypatch):
     """The engines below keep 24-row rings (cheap to wrap); interpreted, the
-    kernels cut them into 8-row tiles instead of leaving them to XLA."""
-    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")
+    kernels cut them into 8-row tiles instead of leaving them to XLA (EVA's
+    two rings, a window of 16 rows and 24 pooled ones, into tiles of 4)."""
+    family = getattr(request.node, "callspec", None) and request.node.callspec.params.get("family")
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "4" if family == "eva" else "8")
 
 
 def _runs_the_decode_kernel(engine) -> bool:
     S = engine.num_slots
     vec = jnp.zeros((S,), jnp.int32)
-    jaxpr = jax.make_jaxpr(engine._decode)(
-        engine.params, vec, vec, engine.cache_k, engine.cache_v
-    )
-    return "odtp_paged_decode_attn" in str(jaxpr)
+    jaxpr = str(jax.make_jaxpr(engine._decode)(
+        engine.params, vec, vec, engine.cache_k, engine.cache_v,
+        *engine._ssm, *engine._cca, *engine._eva,
+    ))
+    return "odtp_paged_decode_attn" in jaxpr or "odtp_mla_decode_attn" in jaxpr
 
 
-def _make_engine(tiny_cfg, decode_kernel, **kw):
-    params = init_params(jax.random.PRNGKey(0), tiny_cfg)
+# the architecture families the benchmark's cells serve, each the tiny
+# configuration its own suite builds: (configuration, parameters)
+FAMILIES = {
+    **test_serve_deferred_admit.KINDS,
+    "eva": lambda _: test_evabyte.model()[1:],
+}
+_EVA_GEOMETRY = dict(max_context=6 * test_evabyte.WINDOW, prefill_buckets=(16, 32))
+
+
+def _make_engine(tiny_cfg, decode_kernel, family="dense", **kw):
+    cfg, params = FAMILIES[family](tiny_cfg)
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_context", 24)
     kw.setdefault("prefill_buckets", (8, 16))
+    if cfg.eva:
+        kw.update(_EVA_GEOMETRY)
     kw.setdefault("compute_dtype", jnp.float32)
-    return ServeEngine(tiny_cfg, params, decode_kernel=decode_kernel, **kw)
+    return ServeEngine(cfg, params, decode_kernel=decode_kernel, **kw)
 
 
 def _generate(engine, prompt, n, slot=0):
@@ -495,13 +423,15 @@ def _generate(engine, prompt, n, slot=0):
     return toks
 
 
-@pytest.mark.parametrize("weight_format", ["fp32", "w4"])
-def test_engine_token_streams_identical(tiny_cfg, weight_format, small_tiles):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_engine_token_streams_identical(tiny_cfg, family, small_tiles):
     rng = _rng(11)
-    # both prefill buckets, and enough new tokens to wrap the T=24 ring
-    prompts = [rng.integers(1, 256, 5).tolist(), rng.integers(1, 256, 12).tolist()]
-    e_x = _make_engine(tiny_cfg, "xla", weight_format=weight_format)
-    e_p = _make_engine(tiny_cfg, "pallas", weight_format=weight_format)
+    # both prefill buckets, and enough new tokens to wrap the T=24 ring (EVA:
+    # to restart its window of 16 and read the pooled ring)
+    vocab = FAMILIES[family](tiny_cfg)[0].vocab_size
+    prompts = [rng.integers(1, vocab, 5).tolist(), rng.integers(1, vocab, 12).tolist()]
+    e_x = _make_engine(tiny_cfg, "xla", family)
+    e_p = _make_engine(tiny_cfg, "pallas", family)
     assert (e_x.decode_kernel, e_p.decode_kernel) == ("xla", "pallas")
     assert _runs_the_decode_kernel(e_p) and not _runs_the_decode_kernel(e_x)
     for slot, prompt in enumerate(prompts):
@@ -510,32 +440,14 @@ def test_engine_token_streams_identical(tiny_cfg, weight_format, small_tiles):
         assert tx == tp
 
 
-def test_engine_spec_streams_identical(tiny_cfg, small_tiles):
-    e_x = _make_engine(tiny_cfg, "xla", spec_k=2, draft_layers=1)
-    e_p = _make_engine(tiny_cfg, "pallas", spec_k=2, draft_layers=1)
-    rng = _rng(13)
-    prompt = rng.integers(1, 256, 6).tolist()
-    streams = []
-    for eng in (e_x, e_p):
-        tok, _ = eng.admit(0, prompt)
-        toks, lens = [tok], np.asarray([len(prompt), 0], np.int32)
-        cur = np.asarray([tok, 0], np.int32)
-        for _ in range(5):
-            g, m = eng.spec_step(cur, lens)
-            emitted = g[0, : int(m[0]) + 1].tolist()
-            toks.extend(emitted)
-            lens = lens + len(emitted)
-            cur = np.asarray([toks[-1], 0], np.int32)
-        streams.append(toks)
-    assert streams[0] == streams[1]
-
-
-def test_batcher_token_streams_identical(tiny_cfg, small_tiles):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_batcher_token_streams_identical(tiny_cfg, family, small_tiles):
     rng = _rng(17)
-    prompts = [rng.integers(1, 256, n).tolist() for n in (4, 9, 14)]
+    vocab = FAMILIES[family](tiny_cfg)[0].vocab_size
+    prompts = [rng.integers(1, vocab, n).tolist() for n in (4, 9, 14)]
     results = []
     for kernel in ("xla", "pallas"):
-        engine = _make_engine(tiny_cfg, kernel, num_slots=4)
+        engine = _make_engine(tiny_cfg, kernel, family, num_slots=4)
         batcher = ContinuousBatcher(engine).start()
         try:
             reqs = []
@@ -551,10 +463,10 @@ def test_batcher_token_streams_identical(tiny_cfg, small_tiles):
 
 
 def test_engine_kernel_probe_gauges(tiny_cfg):
-    eng = _make_engine(tiny_cfg, "xla", weight_format="w4")
+    eng = _make_engine(tiny_cfg, "xla")
     out = eng.kernel_probe(iters=1)
     plan = {"decode_plan_heads", "decode_plan_block_t", "decode_plan_block_diagonal"}
-    assert set(out) == {"decode_attn_us", "verify_attn_us", "w4_matmul_us"} | plan
+    assert set(out) == {"decode_attn_us"} | plan
     assert all(out[k] > 0 for k in set(out) - plan)
     assert all(out[k] == 0 for k in plan)  # the XLA path: no kernel, no plan
 

@@ -28,8 +28,8 @@ import numpy as np
 import pytest
 
 from opendiloco_tpu.models.llama import (
-    LlamaConfig, causal_lm_loss, decode_forward, draft_propose, forward, init_params,
-    prefill_forward, verify_forward,
+    LlamaConfig, causal_lm_loss, continue_prefill, decode_forward, forward, init_params,
+    prefill_forward,
 )
 from opendiloco_tpu.models.ring_cache import eva_insert, eva_pooled_rows, init_eva_state, init_kv_cache
 from opendiloco_tpu.ops import attention, decode_kernels
@@ -428,19 +428,15 @@ def test_faults_the_tolerance_catches(what):
 
 def test_each_refusal_by_name():
     """What takes a slot's ring for its context is refused for EVA, each by
-    its name: prefix reuse, the host tier, speculative decode (spec_k, the
-    verify pass, the draft), w4, the flash and ring kernels, the pp pipeline,
-    the HF llama layout; and a request that its pooled ring cannot hold."""
+    its name: prefix reuse and its continued prefill, the host tier, the
+    flash and ring kernels, the pp pipeline, the HF llama layout; and a
+    request that its pooled ring cannot hold."""
     from opendiloco_tpu.models.hf_io import save_params
     from opendiloco_tpu.serve.kvcache import HostKVTier
 
     _, cfg, params = model()
     match = "refused for a configuration with EVA attention"
     make = lambda **kw: _engine(cfg, params, "xla", **kw)
-    with pytest.raises(ValueError, match=match):
-        ServeEngine(cfg, params, spec_k=2, compute_dtype=jnp.float32)
-    with pytest.raises(ValueError, match=match):
-        ServeEngine(cfg, params, weight_format="w4", compute_dtype=jnp.float32)
     engine = make()
     for kw in ({"prefix_cache": True}, {"kv_tier": HostKVTier(host_slots=2)}):
         with pytest.raises(ValueError, match=match):
@@ -455,10 +451,7 @@ def test_each_refusal_by_name():
     ids = jnp.zeros((1, 8), jnp.int32)
     vec = jnp.zeros((3,), jnp.int32)
     with pytest.raises(ValueError, match=match):
-        verify_forward(params, vec[:, None], vec, engine.cache_k, engine.cache_v, cfg, **F32)
-    with pytest.raises(ValueError, match=match):
-        draft_propose(params, vec, vec, engine.cache_k, engine.cache_v, cfg,
-                      k_steps=2, draft_layers=1, **F32)
+        continue_prefill(params, vec[:, None], vec, engine.cache_k, engine.cache_v, cfg, **F32)
     for impl in ("pallas", "ring"):
         with pytest.raises(ValueError, match=match):
             forward(params, ids, cfg, attn_impl=impl, **F32)

@@ -144,7 +144,7 @@ def test_the_latent_projection_alone():
     w = expert_layer(params)
     x = jax.random.normal(jax.random.key(2), (2, 19, cfg.hidden_size), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(19), (2, 19))
-    q, rows = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos), jnp.matmul)
+    q, rows = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos))
     want_q, want_rows = reference.latent_rows(x, w, raw)
     assert q.shape == (2, 19, 4, 20) and rows.shape == (2, 19, 24)
     assert rel_l2(q, want_q) < REL_L2 and rel_l2(rows, want_rows) < REL_L2
@@ -165,7 +165,7 @@ def test_absorbed_against_rebuilt_attention_on_the_same_rows(kernel, monkeypatch
     n = 21
     x = jax.random.normal(jax.random.key(4), (1, n, cfg.hidden_size), jnp.float32)
     pos = jnp.arange(n)[None]
-    q, rows = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos), jnp.matmul)
+    q, rows = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos))
     rebuilt = xla_attention(q, *llama.latent_keys_values(cfg, rows, w["kv_b_proj"]), causal=True)
     # rows 0..n-2 in slot 2 of a ring, layer 1; the last row arrives with the step
     ring = init_kv_cache(cfg, 3, RING, jnp.float32)["k"]
@@ -410,7 +410,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert cfg.num_local_experts is None  # every expert held: the uncut layer
     w = expert_layer(params)
     m = jax.random.normal(jax.random.key(24), (2, 19, cfg.hidden_size), jnp.float32)
-    whole, _, counts = llama._ffn(cfg, m, w, jnp.matmul)
+    whole, _, counts = llama._ffn(cfg, m, w)
     want = reference.routed_part(m, w, raw) + reference.shared_experts(m, w)
     assert rel_l2(whole, want) < REL_L2
 
@@ -427,7 +427,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             m, held, {**raw, "n_routed_experts": 2, "first_local_expert": first})
         assert rel_l2(out, ref_share) < REL_L2
     assert pairs == int(counts[0]) == 2 * 19 * 3  # every pair is some share's
-    total = sum(parts) + llama._swiglu(m, w, jnp.matmul, "shared_")
+    total = sum(parts) + llama._swiglu(m, w, "shared_")
     assert rel_l2(total, whole) < REL_L2 and rel_l2(total, want) < REL_L2
 
 
@@ -441,7 +441,7 @@ def test_the_five_forwards_agree_on_this_block(kernel, monkeypatch):
     ids = tokens(26, (1, 13))
     full = forward(params, ids, cfg, compute_dtype=jnp.float32, remat=False)[0]
     pre = prefill_forward(params, jnp.asarray(ids[:, :12]), jnp.int32(12), cfg,
-                          compute_dtype=jnp.float32, decode_kernel=kernel, return_moe_counts=True)
+                          compute_dtype=jnp.float32, return_moe_counts=True)
     logits, rows, none, counts = pre
     assert none is None and counts.shape == (4,)
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(full[11]), rtol=2e-4, atol=2e-5)
@@ -465,10 +465,6 @@ def test_what_cannot_hold_a_latent_row_says_so(tmp_path):
     from opendiloco_tpu.serve.kvcache import HostKVTier
 
     _, cfg, params = model(seed=27)
-    with pytest.raises(ValueError, match=f"speculative decode.*{REFUSED}"):
-        engine_for(cfg, params, spec_k=2)
-    with pytest.raises(ValueError, match=f"weight_format=w4.*{REFUSED}"):
-        engine_for(cfg, params, weight_format="w4")
     engine = engine_for(cfg, params)
     with pytest.raises(ValueError, match=f"prefix_cache is {REFUSED}"):
         ContinuousBatcher(engine, prefix_cache=True)
@@ -482,10 +478,8 @@ def test_what_cannot_hold_a_latent_row_says_so(tmp_path):
     with pytest.raises(ValueError, match=f"page-in is {REFUSED}"):
         engine.install_slot_pages(0, np.zeros((4, 16, 1, 24)), np.zeros((4, 16, 1, 24)))
     vec = jnp.zeros((4,), jnp.int32)
-    with pytest.raises(ValueError, match=f"verify pass.*{REFUSED}"):
-        llama.verify_forward(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k, None, cfg)
-    with pytest.raises(ValueError, match=f"draft is {REFUSED}"):
-        llama.draft_propose(params, vec, vec, engine.cache_k, None, cfg, k_steps=2, draft_layers=1)
+    with pytest.raises(ValueError, match=f"continued prefill.*{REFUSED}"):
+        llama.continue_prefill(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k, None, cfg)
     with pytest.raises(ValueError, match="pp pipeline is refused for a configuration with a leading dense"):
         pipeline_hidden(params, jnp.zeros((2, 8, 32)), None, cfg, None, microbatches=2, attn_fn=None)
     with pytest.raises(ValueError, match="no latent attention"):

@@ -121,12 +121,12 @@ def test_mixer_chunked_against_the_sequential_recurrence(length):
     raw, cfg, params = model(seed=1, mamba_chunk_size=256)
     w = {name: leaf[1] for name, leaf in params["layers"]["mamba"].items()}
     x = jax.random.normal(jax.random.key(length), (2, length, cfg.hidden_size), jnp.float32)
-    out, state, tail = jax.jit(lambda x: mamba.ssm_chunked(cfg, x, w, jnp.matmul))(x)
+    out, state, tail = jax.jit(lambda x: mamba.ssm_chunked(cfg, x, w))(x)
     want, want_state = jax.jit(lambda x: reference.mamba_mixer(x, w, raw))(x)
     assert rel_l2(out, want) < REL_L2 and rel_l2(state, want_state) < REL_L2
 
     def step(carry, x_t):
-        y, s, t = mamba.ssm_step(cfg, x_t, w, jnp.matmul, *carry)
+        y, s, t = mamba.ssm_step(cfg, x_t, w, *carry)
         return (s, t), y
 
     zero = (jnp.zeros_like(state), jnp.zeros_like(tail))
@@ -306,7 +306,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert cfg.num_local_experts is None  # every expert held: the uncut layer
     w = {name: leaf[0] for name, leaf in params["layers"]["mamba"].items()}
     m = jax.random.normal(jax.random.key(15), (2, 19, cfg.hidden_size), jnp.float32)
-    whole, _, counts = llama._ffn(cfg, m, w, jnp.matmul)
+    whole, _, counts = llama._ffn(cfg, m, w)
     want = reference.routed_part(m, w, raw) + reference.shared_mlp(m, w)
     assert rel_l2(whole, want) < REL_L2
 
@@ -323,7 +323,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             m, held, {**raw, "num_local_experts": 4, "first_local_expert": first})
         assert rel_l2(out, ref_share) < REL_L2
     assert pairs == int(counts[0]) == 2 * 19 * 3  # every pair is some share's
-    total = sum(parts) + llama._swiglu(m, w, jnp.matmul, "shared_")
+    total = sum(parts) + llama._swiglu(m, w, "shared_")
     assert rel_l2(total, whole) < REL_L2 and rel_l2(total, want) < REL_L2
 
 
@@ -336,10 +336,6 @@ def test_what_cannot_hold_a_recurrent_state_says_so(tmp_path):
     from opendiloco_tpu.serve.kvcache import HostKVTier
 
     _, cfg, params = model(seed=16)
-    with pytest.raises(ValueError, match=f"speculative decode.*{REFUSED}"):
-        engine_for(cfg, params, spec_k=2)
-    with pytest.raises(ValueError, match=f"weight_format=w4 is {REFUSED}"):
-        engine_for(cfg, params, weight_format="w4")
     engine = engine_for(cfg, params)
     with pytest.raises(ValueError, match=f"prefix_cache is {REFUSED}"):
         ContinuousBatcher(engine, prefix_cache=True)
@@ -353,12 +349,9 @@ def test_what_cannot_hold_a_recurrent_state_says_so(tmp_path):
     with pytest.raises(ValueError, match=f"page-in is {REFUSED}"):
         engine.install_slot_pages(0, np.zeros((1, 16, 2, 8)), np.zeros((1, 16, 2, 8)))
     vec = jnp.zeros((4,), jnp.int32)
-    with pytest.raises(ValueError, match=f"verify pass.*{REFUSED}"):
-        llama.verify_forward(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k,
-                             engine.cache_v, cfg)
-    with pytest.raises(ValueError, match=f"draft is {REFUSED}"):
-        llama.draft_propose(params, vec, vec, engine.cache_k, engine.cache_v, cfg,
-                            k_steps=2, draft_layers=1)
+    with pytest.raises(ValueError, match=f"continued prefill.*{REFUSED}"):
+        llama.continue_prefill(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k,
+                               engine.cache_v, cfg)
     with pytest.raises(ValueError, match=f"pp pipeline is {REFUSED}"):
         pipeline_hidden(params, jnp.zeros((2, 8, 32)), None, cfg, None, microbatches=2,
                         attn_fn=None)

@@ -232,6 +232,25 @@ def _routed_qk_norm_model():
     return cfg, params
 
 
+def _hybrid_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "granitemoehybrid", "vocab_size": 256, "hidden_size": 32,
+        "intermediate_size": 16, "shared_intermediate_size": 24, "num_hidden_layers": 4,
+        "layer_types": ["mamba", "mamba", "attention", "mamba"], "num_attention_heads": 4,
+        "num_key_value_heads": 2, "attention_multiplier": 0.125, "embedding_multiplier": 12,
+        "residual_multiplier": 0.22, "logits_scaling": 16, "position_embedding_type": "nope",
+        "mamba_n_heads": 8, "mamba_d_head": 8, "mamba_d_state": 16, "mamba_d_conv": 4,
+        "mamba_n_groups": 1, "mamba_chunk_size": 8, "mamba_expand": 2, "mamba_conv_bias": True,
+        "mamba_proj_bias": False, "num_experts": 8, "num_experts_per_tok": 2,
+        "max_position_embeddings": 128, "tie_word_embeddings": True,
+    })
+    assert cfg.hybrid and cfg.num_mamba_layers == 3
+    params = init_params(jax.random.key(8), cfg)
+    for stack in params["layers"].values():  # no top-k choice near a tie
+        stack["router"] = stack["router"] * 25.0
+    return cfg, params
+
+
 def _latent_routed_model():
     cfg = LlamaConfig.from_dict({
         "model_type": "glm4_moe_lite", "vocab_size": 256, "hidden_size": 64,
@@ -275,27 +294,32 @@ def _eva_model():
 
 
 @pytest.mark.parametrize(
-    "model", [_dense_model, _routed_qk_norm_model, _latent_routed_model, _zaya_model, _eva_model]
+    "model",
+    [_dense_model, _routed_qk_norm_model, _hybrid_model, _latent_routed_model, _zaya_model,
+     _eva_model],
 )
-def test_the_five_forwards_agree(model):
-    """One block under five drivers: in float32 the training forward, the
-    prefill, the decode step, the verify pass and the draft (at full depth)
-    give one another's logits and greedy tokens. A change to one driver's
-    layer that the others do not get fails here. The latent block runs under
-    the three that support it (training and prefill rebuild k and v, the
-    decode step absorbs them: two formulas of one attention); the verify pass
-    and the draft handle (k, v) rows and refuse it. The CCA block runs under
-    the same three (its projections read the token before: a shift over the
-    sequence in training and prefill, a per-slot state in decode, written by
-    the prefill at the prompt's true length); the other two cannot roll that
-    state back and refuse it. The EVA block runs under the same three (a
-    window of rows and the pooled chunks before it: over the whole sequence
-    in training and prefill, over a slot's two rings in decode, the prompt of
-    9 ending in the second window of 8); the other two take the ring for the
-    context and refuse it. Of a head of two vocabularies the first samples."""
+def test_the_four_forwards_agree(model):
+    """One block under four drivers: in float32 the training forward, the
+    prefill, the decode step and the continued prefill give one another's
+    logits and greedy tokens. A change to one driver's layer that the others
+    do not get fails here. The latent block runs under the three that support
+    it (training and prefill rebuild k and v, the decode step absorbs them:
+    two formulas of one attention); the continued prefill handles (k, v) rows
+    and refuses it. The CCA block runs under the same three (its projections
+    read the token before: a shift over the sequence in training and prefill,
+    a per-slot state in decode, written by the prefill at the prompt's true
+    length); the continued prefill has no such state to start from and
+    refuses it. The EVA block runs under the same three (a window of rows and
+    the pooled chunks before it: over the whole sequence in training and
+    prefill, over a slot's two rings in decode, the prompt of 9 ending in the
+    second window of 8); the continued prefill takes the ring for the context
+    and refuses it. The hybrid stack runs under the same three (its Mamba-2
+    layers in chunks over the sequence in training and prefill, a step over
+    the slot's recurrent state in decode, which the prefill leaves at the
+    prompt's true length); the continued prefill has rows to start from and
+    no state, and refuses it. Of a head of two vocabularies the first samples."""
     from opendiloco_tpu.models.llama import (
-        cache_insert, decode_forward, draft_propose, init_kv_cache,
-        prefill_forward, verify_forward,
+        cache_insert, continue_prefill, decode_forward, init_kv_cache, prefill_forward,
     )
 
     cfg, params = model()
@@ -312,8 +336,8 @@ def test_the_five_forwards_agree(model):
     tok = int(jnp.argmax(logits[0, : cfg.vocab_size]))
     assert (vs is None) == cfg.latent  # the latent rows alone are kept
 
-    # slot 1 of two holds the prompt; one decode step = a verify pass over a
-    # tail of one = the forward's next row
+    # slot 1 of two holds the prompt; one decode step = a continued prefill
+    # over a tail of one = the forward's next row
     cache = init_kv_cache(cfg, 2, 32, jnp.float32)
     state = {}
     if cfg.eva:  # the last window's rows, the pooled rows and the pooling under way
@@ -332,27 +356,26 @@ def test_the_five_forwards_agree(model):
 
         state["cca_state"] = cca_state_insert(
             init_cca_state(cfg, 2, jnp.float32), left[0], jnp.int32(1))
+    if cfg.hybrid:  # the recurrent states and conv tails the prompt left
+        from opendiloco_tpu.models.ring_cache import init_ssm_state, state_insert
+
+        held = init_ssm_state(cfg, 2, jnp.float32)
+        state["ssm_state"], state["conv_state"] = state_insert(
+            held["ssm"], held["conv"], *left, jnp.int32(1))
     step, *_ = decode_forward(params, tokens, lens, ck, cv, cfg, **state, **f32)
     close(step[1], want)
-    if cfg.latent or cfg.cca or cfg.eva:
-        what = "latent" if cfg.latent else "CCA" if cfg.cca else "EVA"
-        for refused in (
-            lambda: verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32),
-            lambda: draft_propose(params, tokens, lens, ck, cv, cfg, k_steps=K,
-                                  draft_layers=cfg.num_hidden_layers, **f32),
-        ):
-            with pytest.raises(ValueError, match=f"refused for a configuration with {what}"):
-                refused()
+    if cfg.latent or cfg.cca or cfg.eva or cfg.hybrid:
+        what = "latent" if cfg.latent else "CCA" if cfg.cca else "EVA" if cfg.eva else "Mamba-2"
+        with pytest.raises(ValueError, match=f"refused for a configuration with {what}"):
+            continue_prefill(params, tokens[:, None], lens, ck, cv, cfg, **f32)
         return
-    verified, _, _ = verify_forward(params, tokens[:, None], lens, ck, cv, cfg, **f32)
-    close(verified[1, 0], want)
+    continued, _, _ = continue_prefill(params, tokens[:, None], lens, ck, cv, cfg, **f32)
+    close(continued[1, 0], want)
 
-    # the draft over all the layers proposes the forward's greedy tokens
-    proposed = draft_propose(
-        params, tokens, lens, ck, cv, cfg,
-        k_steps=K, draft_layers=cfg.num_hidden_layers, **f32,
-    )
+    # over a tail of K tokens it gives the forward's rows at those positions
     seq = prompt + [tok]
-    for _ in range(K):
+    for _ in range(K - 1):
         seq.append(int(jnp.argmax(full(seq)[-1])))
-    assert np.asarray(proposed[1]).tolist() == seq[-K:]
+    tail = jnp.asarray([[0] * K, seq[P:]], jnp.int32)
+    continued, _, _ = continue_prefill(params, tail, lens, ck, cv, cfg, **f32)
+    close(continued[1], full(seq)[P:])

@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from opendiloco_tpu.models import llama
-from opendiloco_tpu.models.llama import LlamaConfig, forward, init_params, verify_forward
+from opendiloco_tpu.models.llama import LlamaConfig, continue_prefill, forward, init_params
 from opendiloco_tpu.parallel.mesh import build_mesh
 from opendiloco_tpu.serve import ServeEngine
 from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
@@ -174,23 +174,23 @@ def test_engine_prefill_then_decode_against_the_reference(top_k):
 
 
 @pytest.mark.parametrize("top_k", [2, 8])
-def test_verify_forward_logits_against_forward(top_k):
-    """The speculative verify pass over a cached prefix gives the rows of the
-    full forward (and ``draft_propose`` runs through the same two helpers)."""
+def test_continue_prefill_logits_against_forward(top_k):
+    """The continued prefill over a cached prefix gives the rows of the full
+    forward, through the engine's own prefix-reuse admission too: its first
+    token is the forward's greedy one at the prompt's end."""
     _, cfg, params = model(top_k, seed=7)
     prompt, tail = tokens(8, 12), tokens(9, (1, 4))
-    engine = ServeEngine(cfg, params, num_slots=1, max_context=32, prefill_buckets=(16,),
-                         compute_dtype=jnp.float32, decode_kernel="xla",
-                         spec_k=3, draft_layers=1)
+    engine = ServeEngine(cfg, params, num_slots=2, max_context=32, prefill_buckets=(16,),
+                         compute_dtype=jnp.float32, decode_kernel="xla")
     engine.admit(0, prompt.tolist())
     lens = jnp.asarray([len(prompt)], jnp.int32)
-    got, _, _ = verify_forward(engine.params, jnp.asarray(tail), lens, engine.cache_k,
-                               engine.cache_v, cfg, compute_dtype=jnp.float32)
+    got, _, _ = continue_prefill(engine.params, jnp.asarray(tail), lens, engine.cache_k[:, :1],
+                                 engine.cache_v[:, :1], cfg, compute_dtype=jnp.float32)
     ids = np.concatenate([prompt[None], tail], axis=1)
     want = forward(params, ids, cfg, compute_dtype=jnp.float32, remat=False)
     assert rel_l2(got[0], want[0, len(prompt):]) < REL_L2
-    g, m = engine.spec_step(np.asarray([tail[0, 0]], np.int32), np.asarray(lens))
-    assert g.shape == (1, 4) and 0 <= int(m[0]) <= 3
+    tok, row = engine.admit(1, ids[0].tolist(), prefix_src=0, prefix_len=len(prompt))
+    assert rel_l2(row, want[0, -1]) < REL_L2 and tok == int(np.argmax(want[0, -1]))
 
 
 def test_top_1_is_the_old_top_1_gate():

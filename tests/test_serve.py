@@ -13,15 +13,14 @@ Oracles:
 - one obs registry serves trainer AND server gauges; port collisions
   downgrade to ephemeral instead of killing the process
 
-Fast-decode oracles (PR 11):
-- self-speculative decode is token-bit-exact vs the one-token loop —
-  across prefill buckets, across ring wrap, and under an adversarial
-  draft that is ALWAYS wrong (acceptance floors at the verify token)
-- w4-resident weights change bytes at rest, not behavior: logits track
-  the fp32-resident engine to quantization tolerance, and the packed
-  bits are identical whether the native kernel or the numpy fallback
-  produced them
-- prefix reuse writes the SAME prefix K/V bytes a cold prefill writes
+Prefix-reuse oracles:
+- prefix reuse writes the SAME prefix K/V bytes a cold prefill writes,
+  whichever bucket the suffix pads to (to a rounding only where another
+  bucket's program prefilled the source slot), and the stream stays the
+  cold admission's while the ring wraps
+- the continued prefill is the one-token loop: over a tail that crosses
+  the ring's end its logits are the decode steps' (the eviction rule of
+  ``ops.attention.tail_attention``)
 """
 import json
 import socket
@@ -218,10 +217,24 @@ def _wire_blobs(params, codec_name="fp16"):
     return blobs
 
 
-def test_swap_mid_decode_changes_no_kv_entries(tiny_cfg):
+WIRE_CODECS = (
+    "none", "fp16", "scaled-fp16", "uniform8bit", "quantile8bit", "blockwise8bit",
+    "blockwise4bit", "topk",
+)
+
+
+@pytest.mark.parametrize("codec_name", WIRE_CODECS)
+def test_swap_mid_decode_changes_no_kv_entries(tiny_cfg, codec_name):
     """Regression (satellite 2): installing a snapshot between decode
     steps must leave every in-flight KV cache byte unchanged — and the
-    generation continues under the new weights without error."""
+    generation continues under the new weights without error. Whatever
+    codec the snapshot rides (``ODTP_STATE_CODEC`` may name any the outer
+    plane has), ``install_wire`` binds the tree that codec decodes to: the
+    weights and the next step's logits are those of ``install_params`` of
+    the decoded tree, bit for bit."""
+    from opendiloco_tpu.diloco.compression import _CODECS, compress_roundtrip, get_codec
+
+    assert set(WIRE_CODECS) == set(_CODECS)  # a new codec gets its case here
     engine, _ = make_engine(tiny_cfg)
     _, params2 = make_engine(tiny_cfg, seed=123)
 
@@ -234,7 +247,11 @@ def test_swap_mid_decode_changes_no_kv_entries(tiny_cfg):
     ck_before = np.asarray(engine.cache_k)
     cv_before = np.asarray(engine.cache_v)
     old = engine.params
-    engine.install_wire(1, _wire_blobs(params2), "fp16")
+    # a twin in the same state, to take the decoded tree uncompressed
+    twin, _ = make_engine(tiny_cfg)
+    twin.admit(0, [4, 8, 15, 16])
+    twin.decode_step(tokens, lens)
+    engine.install_wire(1, _wire_blobs(params2, codec_name), codec_name)
     assert engine.weights_epoch == 1 and engine.swap_count == 1
     np.testing.assert_array_equal(np.asarray(engine.cache_k), ck_before)
     np.testing.assert_array_equal(np.asarray(engine.cache_v), cv_before)
@@ -246,6 +263,16 @@ def test_swap_mid_decode_changes_no_kv_entries(tiny_cfg):
     tokens[0], lens[0] = int(nxt[0]), 5
     nxt2, logits2 = engine.decode_step(tokens, lens)
     assert np.isfinite(np.asarray(logits2[0])).all()
+    codec = get_codec(codec_name)
+    twin.install_params(1, jax.tree.map(
+        lambda x: compress_roundtrip(np.asarray(x, np.float32).reshape(-1), codec).reshape(x.shape),
+        params2,
+    ))
+    for got, want in zip(jax.tree.leaves(engine.params), jax.tree.leaves(twin.params)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(logits2[0]), np.asarray(twin.decode_step(tokens, lens)[1][0])
+    )
 
 
 def test_hot_swap_under_load_drops_nothing(tiny_cfg):
@@ -495,280 +522,74 @@ def test_http_and_jsonl_frontend(tiny_cfg):
 
 
 # ---------------------------------------------------------------------------
-# fast decode, leg a: self-speculative parity (PR 11 tentpole)
+# shared-prefix KV reuse: the continued prefill
 # ---------------------------------------------------------------------------
 
 
-def spec_generate(engine, prompt, n, slot=0):
-    """Drive the spec engine directly: admit + spec rounds, one slot.
-    Returns the first n greedy tokens."""
-    tok, _ = engine.admit(slot, prompt)
-    toks = [tok]
-    S = engine.num_slots
-    lens = np.zeros((S,), np.int32)
-    cur = np.zeros((S,), np.int32)
-    lens[slot], cur[slot] = len(prompt), tok
-    while len(toks) < n:
-        # fresh arrays every round, as the scheduler hands them over: on the
-        # CPU backend ``jnp.asarray`` aliases a host array, and spec_step
-        # returns with its accepted-tail insert still in flight, so updating
-        # ``lens`` in place below moved that insert's ring rows under it
-        # (the whole of what ROADMAP called near-tie argmax flakes)
-        g, m = engine.spec_step(cur.copy(), lens.copy())
-        take = int(m[slot]) + 1
-        toks.extend(int(t) for t in g[slot, :take])
-        lens[slot] += take
-        cur[slot] = toks[-1]
-    return toks[:n]
-
-
-@pytest.mark.parametrize("buckets", [(8,), (32,)])
-def test_spec_decode_token_parity(tiny_cfg, buckets):
-    """Spec decode emits the exact token stream of the plain loop, for
-    every draft width, regardless of prefill bucket padding."""
-    plain, _ = make_engine(tiny_cfg, prefill_buckets=buckets)
-    ref = greedy_generate(plain, [5, 1, 4, 1, 5], 20)[0]
-    for k in (1, 3):
-        spec, _ = make_engine(tiny_cfg, prefill_buckets=buckets, spec_k=k)
-        assert spec_generate(spec, [5, 1, 4, 1, 5], 20) == ref
-
-
-def test_spec_decode_parity_across_ring_wrap(tiny_cfg):
-    """Parity holds while the ring wraps (3 + 24 tokens on a 16-wide
-    page): draft/verify tail K/V never touches the ring before
-    acceptance, and the tail-aware eviction mask reproduces the sliding
-    window the one-token loop sees."""
-    plain, _ = make_engine(tiny_cfg, max_context=16, prefill_buckets=(8,))
-    ref = greedy_generate(plain, [1, 2, 3], 24)[0]
-    spec, _ = make_engine(
-        tiny_cfg, max_context=16, prefill_buckets=(8,), spec_k=3
-    )
-    assert spec_generate(spec, [1, 2, 3], 24) == ref
-
-
-def test_spec_zero_acceptance_adversarial(tiny_cfg):
-    """A draft that is ALWAYS wrong: every proposal disagrees with the
-    full model's greedy choice, so every round accepts zero drafts and
-    emits exactly the verify pass's corrected token. Output stays
-    token-identical — a bad draft can cost throughput, never change the
-    stream (rejected tokens never enter the ring)."""
-    prompt, n = [2, 4, 6], 12
-    plain, _ = make_engine(tiny_cfg)
-    ref = greedy_generate(plain, prompt, n)[0]
-
-    spec, _ = make_engine(tiny_cfg, spec_k=2)
-    V = tiny_cfg.vocab_size
-    count = {"emitted": 1}  # admit already produced ref[0]
-
-    def adversary(tokens, lens):
-        # ref[emitted] is the true greedy next token; propose anything else
-        wrong = (ref[count["emitted"]] + 1) % V
-        return np.full((spec.num_slots, spec.spec_k), wrong, np.int32)
-
-    spec.propose_fn = adversary
-    tok, _ = spec.admit(0, prompt)
-    assert tok == ref[0]
-    toks = [tok]
-    lens = np.zeros((spec.num_slots,), np.int32)
-    cur = np.zeros((spec.num_slots,), np.int32)
-    lens[0], cur[0] = len(prompt), tok
-    while len(toks) < n:
-        g, m = spec.spec_step(cur.copy(), lens.copy())  # see spec_generate
-        assert int(m[0]) == 0  # nothing agreed; verify floor
-        toks.append(int(g[0, 0]))
-        count["emitted"] += 1
-        lens[0] += 1
-        cur[0] = toks[-1]
-    assert toks == ref
-
-
-def test_spec_batcher_matches_isolated(tiny_cfg):
-    """Continuous batching + spec decode: staggered requests sharing two
-    slots still match the same requests decoded alone and plain."""
-    rng = np.random.default_rng(11)
-    prompts = [rng.integers(1, 256, int(n)).tolist() for n in (3, 7, 5, 12)]
-    lengths = [6, 9, 4, 7]
-    engine, params = make_engine(tiny_cfg, num_slots=2, spec_k=3)
-    batcher = ContinuousBatcher(engine).start()
-    try:
-        reqs = []
-        for p, n in zip(prompts, lengths):
-            reqs.append(batcher.submit(p, max_new_tokens=n))
-            time.sleep(0.01)
-        for r in reqs:
-            assert r.wait(60) and r.error is None
-    finally:
-        batcher.stop()
-    for req, p, n in zip(reqs, prompts, lengths):
-        solo = ServeEngine(
-            tiny_cfg, params, num_slots=1, max_context=64,
-            prefill_buckets=(8, 16, 32), compute_dtype=jnp.float32,
-        )
-        assert req.tokens == greedy_generate(solo, p, n)[0]
-    assert batcher.spec_proposed > 0
-    assert 0 <= batcher.spec_accepted <= batcher.spec_proposed
-    assert batcher.failed == 0
-
-
-# ---------------------------------------------------------------------------
-# fast decode, leg b: 4-bit-resident replica weights (PR 11 tentpole)
-# ---------------------------------------------------------------------------
-
-
-def _packed_leaves(engine):
-    from opendiloco_tpu.models.llama import PackedW4
-
-    return [
-        x
-        for x in jax.tree.leaves(
-            engine.params, is_leaf=lambda x: isinstance(x, PackedW4)
-        )
-        if isinstance(x, PackedW4)
-    ]
-
-
-def test_w4_resident_logits_track_fp32(tiny_cfg):
-    """w4 residency is a storage change, not a model change: the stacked
-    matmul leaves really are packed (uint8 nibbles + uint16 scales), and
-    the in-jit per-block dequant reproduces an fp32-resident engine
-    running the SAME quantized values — identical tokens, logits equal
-    to reduction-order noise. (How far quant(W) drifts from W is the
-    codec's accuracy contract, pinned by the PR 8 compression tests.)"""
-    from opendiloco_tpu.models.llama import dequant_w4
-
-    w4, params = make_engine(tiny_cfg, weight_format="w4")
-
-    packed = _packed_leaves(w4)
-    assert packed  # the residency actually engaged
-    assert all(
-        p.q.dtype == jnp.uint8 and p.s.dtype == jnp.uint16 for p in packed
-    )
-    # norms ([L, D]) / embeddings / lm head stayed f32
-    assert any(
-        not hasattr(x, "q") and x.dtype == jnp.float32
-        for x in jax.tree.leaves(w4.params)
-    )
-
-    # fp32 engine over the explicitly-dequantized weights = the oracle
-    ref_params = jax.tree.map(
-        lambda x: (
-            np.stack([
-                np.asarray(dequant_w4(x.q[i], x.s[i], x.shape, jnp.float32))
-                for i in range(x.q.shape[0])
-            ])
-            if hasattr(x, "q")
-            else x
-        ),
-        w4.params,
-        is_leaf=lambda x: hasattr(x, "q"),
-    )
-    plain, _ = make_engine(tiny_cfg)
-    plain.install_params(0, ref_params)
-
-    ref_toks, ref_logits = greedy_generate(plain, [3, 1, 4, 1], 6)
-    toks, logits = greedy_generate(w4, [3, 1, 4, 1], 6)
-    assert toks == ref_toks
-    np.testing.assert_allclose(logits, ref_logits, atol=2e-5, rtol=2e-4)
-
-
-def test_w4_pack_native_and_numpy_fallback_agree(tiny_cfg, monkeypatch):
-    """The packed-at-rest bits are the codec's bits: quantizing through
-    the native kernel and through the numpy fallback yields identical
-    payloads, so a w4 engine is reproducible across hosts with and
-    without the built library."""
-    from opendiloco_tpu import native
-
-    w4_native, params = make_engine(tiny_cfg, weight_format="w4")
-    monkeypatch.setattr(native, "get_lib", lambda: None)
-    w4_np = ServeEngine(
-        tiny_cfg, params, num_slots=4, max_context=64,
-        prefill_buckets=(8, 16, 32), compute_dtype=jnp.float32,
-        weight_format="w4",
-    )
-    pn, pf = _packed_leaves(w4_native), _packed_leaves(w4_np)
-    assert pn and len(pn) == len(pf)
-    for a, b in zip(pn, pf):
-        np.testing.assert_array_equal(np.asarray(a.q), np.asarray(b.q))
-        np.testing.assert_array_equal(np.asarray(a.s), np.asarray(b.s))
-    # same bits at rest -> same tokens out
-    assert (
-        greedy_generate(w4_np, [7, 6, 5], 5)[0]
-        == greedy_generate(w4_native, [7, 6, 5], 5)[0]
-    )
-
-
-def test_install_wire_w4_fast_path(tiny_cfg):
-    """A blockwise4bit snapshot installs into a w4 engine without a
-    dequant/requantize round trip where the codec's whole-leaf block
-    grid lands on layer boundaries: the resident packed leaves dequant
-    to EXACTLY the codec's own reconstruction."""
-    from opendiloco_tpu.diloco.compression import get_codec
-    from opendiloco_tpu.models.llama import W4_BLOCK, dequant_w4
-
-    engine, _ = make_engine(tiny_cfg, weight_format="w4")
-    _, params2 = make_engine(tiny_cfg, seed=77)
-    blobs = _wire_blobs(params2, "blockwise4bit")
-    engine.install_wire(1, blobs, "blockwise4bit")
-    assert engine.weights_epoch == 1
-
-    codec = get_codec("blockwise4bit")
-    leaves = jax.tree.leaves(
-        engine.params, is_leaf=lambda x: hasattr(x, "q")
-    )
-    aligned = 0
-    for leaf, (payload, meta, shape) in zip(leaves, blobs):
-        if not hasattr(leaf, "q"):
-            continue
-        size = int(np.prod(shape))
-        per_layer = size // shape[0]
-        want = codec.decode(payload, (size,), meta).reshape(shape)
-        got = np.stack([
-            np.asarray(dequant_w4(leaf.q[i], leaf.s[i], leaf.shape, jnp.float32))
-            for i in range(shape[0])
-        ])
-        if per_layer % W4_BLOCK == 0:
-            aligned += 1
-            np.testing.assert_array_equal(got, want)  # re-sliced, bit-exact
-        else:
-            # fallback repack: one extra quantization of grid values
-            np.testing.assert_allclose(got, want, atol=2e-2, rtol=0)
-    assert aligned  # the fast path actually ran on this geometry
-    toks, logits = greedy_generate(engine, [1, 2, 3], 4)
-    assert np.isfinite(logits).all()
-
-
-# ---------------------------------------------------------------------------
-# fast decode, leg c: shared-prefix KV reuse (PR 11 tentpole)
-# ---------------------------------------------------------------------------
-
-
-def test_prefix_reuse_kv_bytes_identical(tiny_cfg):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize(
+    "suffix, src_tail, max_context, n_new, exact",
+    [
+        (3, 2, 64, 8, True),
+        (12, 12, 64, 8, True),
+        (20, 20, 64, 8, True),
+        (3, 2, 16, 14, True),
+        (20, 2, 64, 8, False),
+    ],
+    ids=[
+        "suffix-bucket-8", "suffix-bucket-16", "suffix-bucket-32", "ring-wraps",
+        "source-of-another-bucket",
+    ],
+)
+def test_prefix_reuse_kv_bytes_identical(
+    tiny_cfg, suffix, src_tail, max_context, n_new, exact, kernel, monkeypatch
+):
     """Reusing a live slot's prefix writes the SAME K/V bytes a cold
     prefill writes (causal attention makes prefix rows independent of
     the suffix), the suffix rows agree to float tolerance, and the
-    generated stream is token-identical to a cold admit."""
-    engine, params = make_engine(tiny_cfg)
+    generated stream is token-identical to a cold admit: for a suffix
+    padded to each prefill bucket, and while the slot's ring wraps under
+    the decode steps that follow (9 + 14 tokens on 16 rows). Under
+    ``pallas`` the continued prefill runs the tail kernel (interpreted,
+    the ring in tiles of 8 rows) and the decode steps theirs. The one case
+    that is not ``exact`` has the source slot's prompt prefilled by the
+    bucket-8 program and the cold prompt by the bucket-32 one: the copy is
+    still the source's bytes, and those are the cold rows to a rounding."""
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")
+    buckets = tuple(b for b in (8, 16, 32) if b <= max_context)
+    engine, params = make_engine(
+        tiny_cfg, max_context=max_context, prefill_buckets=buckets, decode_kernel=kernel
+    )
     sysp = [9, 8, 7, 6, 5, 4]
-    p2 = sysp + [20, 21, 22]
-    plen, n_new = len(sysp), 8
+    p2 = sysp + list(range(20, 20 + suffix))
+    plen = len(sysp)
+    assert pick_bucket(suffix, buckets) == {3: 8, 12: 16, 20: 32}[suffix]
 
     cold = ServeEngine(
-        tiny_cfg, params, num_slots=4, max_context=64,
-        prefill_buckets=(8, 16, 32), compute_dtype=jnp.float32,
+        tiny_cfg, params, num_slots=4, max_context=max_context,
+        prefill_buckets=buckets, compute_dtype=jnp.float32, decode_kernel=kernel,
     )
+    suffix_program = jax.make_jaxpr(engine._suffix)(
+        engine.params, engine.cache_k, engine.cache_v, jnp.int32(1),
+        jnp.zeros((1, buckets[0]), jnp.int32), jnp.int32(plen),
+    )
+    assert ("odtp_spec_tail_attn" in str(suffix_program)) == (kernel == "pallas")
+    cold.admit(1, p2)
+    # slot 1's rows [L, len(p2), Nkv, Dh], read through the cache module
+    rows = lambda e, slot=1, n=len(p2): [
+        np.asarray(x) for x in fetch_pages(e.cache_k, e.cache_v, jnp.int32(slot), n)
+    ]
+    cold_rows = rows(cold)  # before its decode steps wrap the ring over them
     cold_toks, _ = greedy_generate(cold, p2, n_new, slot=1)
 
-    engine.admit(0, sysp + [30, 31])  # the live source slot
+    engine.admit(0, sysp + list(range(30, 30 + src_tail)))  # the live source slot
     tok, _ = engine.admit(1, p2, prefix_src=0, prefix_len=plen)
     assert tok == cold_toks[0]
-    # slot 1's rows [L, len(p2), Nkv, Dh], read through the cache module
-    rows = lambda e: fetch_pages(e.cache_k, e.cache_v, jnp.int32(1), len(p2))
-    for warm, ref in zip(rows(engine), rows(cold)):
-        warm, ref = np.asarray(warm), np.asarray(ref)
-        np.testing.assert_array_equal(warm[:, :plen], ref[:, :plen])
-        np.testing.assert_allclose(
-            warm[:, plen:], ref[:, plen:], atol=2e-6, rtol=2e-5
-        )
+    for warm, src, ref in zip(rows(engine), rows(engine, 0, plen), cold_rows):
+        np.testing.assert_array_equal(warm[:, :plen], src)
+        if exact:
+            np.testing.assert_array_equal(warm[:, :plen], ref[:, :plen])
+        np.testing.assert_allclose(warm, ref, atol=2e-6, rtol=2e-5)
 
     toks = [tok]  # and the continuation matches token-for-token
     lens = np.zeros((engine.num_slots,), np.int32)
@@ -780,6 +601,38 @@ def test_prefix_reuse_kv_bytes_identical(tiny_cfg):
         lens[1] += 1
         cur[1] = toks[-1]
     assert toks == cold_toks
+
+
+@pytest.mark.parametrize("start", [5, 13, 16, 27])
+def test_continued_prefill_is_the_one_token_loop_across_the_rings_end(tiny_cfg, start):
+    """The continued prefill over a tail of 6 tokens from position ``start``
+    gives the logits of 6 decode steps over the same ring of 16 rows: with
+    the tail inside the ring, crossing its end, starting where it is just
+    full, and crossing it a second time. A ring row is dropped for tail
+    query i exactly when the step of a tail token j <= i would have
+    overwritten it (``ops.attention.tail_attention``), and only this
+    comparison holds that rule to the loop it stands for."""
+    from opendiloco_tpu.models.llama import continue_prefill, decode_forward, init_kv_cache
+
+    T, K, f32 = 16, 6, dict(compute_dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(3), tiny_cfg)
+    ids = np.random.default_rng(start).integers(1, tiny_cfg.vocab_size, start + K)
+    step = jax.jit(lambda tok, pos, ck, cv: decode_forward(
+        params, jnp.asarray([0, tok], jnp.int32), jnp.asarray([0, pos], jnp.int32),
+        ck, cv, tiny_cfg, **f32))
+    cache = init_kv_cache(tiny_cfg, 2, T, jnp.float32)
+    ck, cv = cache["k"], cache["v"]
+    for pos in range(start):  # slot 1 holds the sequence, slot 0 idles
+        _, ck, cv = step(ids[pos], pos, ck, cv)
+    want, sk, sv = [], ck, cv
+    for i in range(K):
+        logits, sk, sv = step(ids[start + i], start + i, sk, sv)
+        want.append(np.asarray(logits[1]))
+    tail = jnp.asarray([[0] * K, ids[start:]], jnp.int32)
+    got, _, _ = continue_prefill(
+        params, tail, jnp.asarray([0, start], jnp.int32), ck, cv, tiny_cfg, **f32)
+    np.testing.assert_allclose(np.asarray(got[1]), np.stack(want), atol=2e-5, rtol=2e-4)
+    assert np.argmax(got[1], axis=-1).tolist() == np.argmax(want, axis=-1).tolist()
 
 
 def test_prefix_batcher_hits_and_parity(tiny_cfg):
@@ -805,6 +658,34 @@ def test_prefix_batcher_hits_and_parity(tiny_cfg):
         assert req.tokens == greedy_generate(solo, p, n)[0]
     assert batcher.prefix_hits >= 1
     assert batcher.prefix_tokens_saved >= len(sysp)
+
+
+@pytest.mark.parametrize("where, name", [
+    ("config", "spec_decode_k"), ("config", "draft_layers"), ("config", "weight_format"),
+    ("engine", "spec_k"), ("engine", "draft_layers"), ("engine", "weight_format"),
+    ("env", "ODTP_SPEC_K"), ("env", "ODTP_DECODE_WEIGHT_FORMAT"),
+])
+def test_the_removed_decode_options_are_unknown_names(tiny_cfg, where, name):
+    """Speculative decode and 4-bit resident weights went with their eight
+    settable values (PR 44): each is refused as any unknown key or argument
+    is, and the two environment variables are declared and read nowhere."""
+    import pathlib
+
+    import pydantic
+
+    import opendiloco_tpu
+    from opendiloco_tpu.analysis import knobs
+
+    if where == "config":
+        with pytest.raises(pydantic.ValidationError, match=name):
+            ServeConfig(**{name: 0 if name != "weight_format" else "fp32"})
+    elif where == "engine":
+        with pytest.raises(TypeError, match=name):
+            make_engine(tiny_cfg, **{name: 0 if name != "weight_format" else "fp32"})
+    else:
+        assert name not in {k.name for k in knobs.KNOBS}
+        package = pathlib.Path(opendiloco_tpu.__file__).parent
+        assert not [p for p in package.rglob("*.py") if name in p.read_text()]
 
 
 def test_build_serving_with_diloco_swaps_live(tiny_cfg):

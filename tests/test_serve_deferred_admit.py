@@ -187,18 +187,6 @@ def test_a_prefill_that_raises_fails_the_loop_loudly(tiny_cfg, where):
     assert batcher.failed == len(reqs) and batcher.slots.num_active == 0
 
 
-def test_admissions_under_speculation_are_read_at_once(tiny_cfg):
-    cfg, params = _dense(tiny_cfg)
-    engine, second = _engine(cfg, params, spec_k=2, draft_layers=1), _engine(cfg, params)
-    prompts = _prompts(cfg, 5, seed=23)
-    batcher = ContinuousBatcher(engine)
-    reqs = _serve(batcher, [((p, 6), {}) for p in prompts])
-    for req, prompt in zip(reqs, prompts):
-        assert req.error is None and req.tokens == by_hand(second, prompt, 6)
-    assert batcher.spec_proposed > 0
-    assert engine.admissions_deferred == 0 and engine.phase_calls["prefill"] == 5
-
-
 def test_a_continued_prefill_is_read_at_once_beside_a_cold_one_that_is_not(tiny_cfg):
     cfg, params = _dense(tiny_cfg)
     engine, second = _engine(cfg, params), _engine(cfg, params)

@@ -7,7 +7,7 @@ It is the same arithmetic, so the test is equality of bits, not a tolerance:
 the engine's own programs, given the tree the engine holds, against the same
 programs given the float32 tree (the parent's path: ``_serving_boundary``
 casts inside the program). Masters are seeded random float32, which no bf16
-holds exactly; each kind of block the program has is a case.
+holds exactly; each kind of block the cells' programs have is a case.
 """
 
 import jax
@@ -15,9 +15,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import test_evabyte
+import test_glm_flash
+import test_zaya
 from opendiloco_tpu import obs
 from opendiloco_tpu.diloco.compression import get_codec
-from opendiloco_tpu.models.llama import LlamaConfig, PackedW4, init_params
+from opendiloco_tpu.models.llama import LlamaConfig, init_params
 from opendiloco_tpu.serve import ServeEngine
 
 DENSE = {
@@ -45,11 +48,15 @@ HYBRID = {
     "num_experts_per_tok": 3, "vocab_size": 128, "max_position_embeddings": 256,
     "rms_norm_eps": 1e-5, "tie_word_embeddings": True,
 }
+# the last three as their own suites publish them at a tiny size. EVA's two
+# rings: a window of 16 rows and 12 pooled rows, in tiles of 4
 CASES = {
-    "dense": (DENSE, "fp32"),
-    "routed-qk-norm": (ROUTED_QK_NORM, "fp32"),
-    "hybrid": (HYBRID, "fp32"),
-    "w4": (DENSE, "w4"),
+    "dense": DENSE,
+    "routed-qk-norm": ROUTED_QK_NORM,
+    "hybrid": HYBRID,
+    "latent": test_glm_flash.published(),
+    "cca": test_zaya.published(),
+    "eva": test_evabyte.published(),
 }
 SLOTS, BUCKET = 2, 16
 
@@ -67,21 +74,10 @@ def _masters(cfg, seed):
     return jax.tree.unflatten(treedef, leaves)
 
 
-def _parents_tree(engine, masters):
-    """What the parent's engine handed its programs: the float32 masters,
-    and under ``w4`` the packed leaves as the engine holds them."""
-    held, treedef = jax.tree.flatten(
-        engine.params, is_leaf=lambda x: isinstance(x, PackedW4)
-    )
-    mixed = [
-        h if isinstance(h, PackedW4) else m
-        for h, m in zip(held, jax.tree.leaves(masters))
-    ]
-    return jax.tree.unflatten(treedef, mixed)
-
-
 def _same_bits(got, want):
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
         assert g.dtype == w.dtype and g.shape == w.shape
         np.testing.assert_array_equal(
             np.asarray(g).view(np.uint8), np.asarray(w).view(np.uint8)
@@ -90,24 +86,25 @@ def _same_bits(got, want):
 
 def _programs_agree(engine, masters, seed):
     """One prefill and two decode steps, the engine's jitted programs on the
-    engine's tree against the same programs on the parent's: logits, the
-    rows for the cache, the recurrent state and the routed FFN's counts."""
-    parent = _parents_tree(engine, masters)
+    engine's tree against the same programs on the float32 masters (what the
+    parent's engine handed its programs): logits, the rows for the cache, the
+    per-slot state and the routed FFN's counts."""
     rng = np.random.default_rng(seed)
     n = 11
     ids = np.zeros((1, BUCKET), np.int32)
     ids[0, :n] = rng.integers(1, engine.cfg.vocab_size, n)
     args = (jnp.asarray(ids), jnp.int32(n))
-    _same_bits(engine._prefill(engine.params, *args), engine._prefill(parent, *args))
+    _same_bits(engine._prefill(engine.params, *args), engine._prefill(masters, *args))
 
     tok, _ = engine.admit(0, ids[0, :n].tolist())
     tokens, lens = np.zeros(SLOTS, np.int32), np.zeros(SLOTS, np.int32)
     tokens[0], lens[0] = tok, n
     for _ in range(2):
         # ``_decode`` donates the caches and the state: each side gets its own
-        state = lambda: [jnp.array(x) for x in (engine.cache_k, engine.cache_v, *engine._ssm)]
+        state = lambda: [None if x is None else jnp.array(x) for x in (
+            engine.cache_k, engine.cache_v, *engine._ssm, *engine._cca, *engine._eva)]
         step = (jnp.asarray(tokens), jnp.asarray(lens))
-        want = engine._decode(parent, *step, *state())
+        want = engine._decode(masters, *step, *state())
         got = engine._decode(engine.params, *step, *state())
         _same_bits(got, want)
         nxt, logits = engine.decode_step(tokens, lens)
@@ -121,25 +118,20 @@ def _resident_bytes(tree) -> int:
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_bound_weights_give_the_bits_of_the_per_call_cast(case, monkeypatch):
-    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")  # the kernel over a 24-row ring
-    raw, weight_format = CASES[case]
-    cfg = LlamaConfig.from_dict(raw)
+    cfg = LlamaConfig.from_dict(CASES[case])
+    # the kernel over a 24-row ring in tiles of 8; EVA's over both its rings
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "4" if cfg.eva else "8")
     masters = _masters(cfg, seed=1)
     engine = ServeEngine(
-        cfg, masters, num_slots=SLOTS, max_context=24, prefill_buckets=(BUCKET,),
-        compute_dtype=jnp.bfloat16, weight_format=weight_format, decode_kernel="pallas",
+        cfg, masters, num_slots=SLOTS, max_context=48 if cfg.eva else 24,
+        prefill_buckets=(BUCKET,), compute_dtype=jnp.bfloat16, decode_kernel="pallas",
     )
     # one tree, in the compute dtype, and the masters are still the caller's
-    plain = [x for x in jax.tree.leaves(engine.params) if x.dtype not in (jnp.uint8, jnp.uint16)]
-    assert plain and all(x.dtype == jnp.bfloat16 for x in plain)
-    assert (len(plain) < len(jax.tree.leaves(engine.params))) == (weight_format == "w4")
+    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(engine.params))
     assert all(x.dtype == jnp.float32 for x in jax.tree.leaves(masters))
     assert engine.weight_binds == 1 and engine.swap_count == 0
     assert engine.weights_resident_bytes == _resident_bytes(engine.params)
-    if weight_format == "fp32":
-        assert engine.weights_resident_bytes == _resident_bytes(masters) // 2
-    else:
-        assert engine.weights_resident_bytes < _resident_bytes(masters) // 2
+    assert engine.weights_resident_bytes == _resident_bytes(masters) // 2
     _programs_agree(engine, masters, seed=2)
 
     second = _masters(cfg, seed=3)

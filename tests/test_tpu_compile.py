@@ -31,8 +31,7 @@ from opendiloco_tpu.models.ring_cache import cache_shape
 from opendiloco_tpu.ops import decode_kernels
 from opendiloco_tpu.ops.decode_kernels import (
     paged_decode_attention,
-    spec_tail_attention_fused,
-    w4_matmul,
+    tail_attention_fused,
 )
 from opendiloco_tpu.ops.flash_attention import flash_attention
 from opendiloco_tpu.ops.fused_xent import fused_linear_cross_entropy
@@ -41,8 +40,9 @@ BF16 = jnp.bfloat16
 SEQ = 1024
 # (query heads, kv heads, head_dim) of the configs the repo ships
 HEADS = {"150m": (16, 16, 64), "1b": (32, 4, 64)}
-# (hidden, intermediate): the gate and down projections PackedW4 holds
-FFN = {"150m": (1024, 2688), "1b": (2048, 5632)}
+# and of the two serving cells whose configurations the continued prefill
+# takes (keys-and-values rows, no state beside them)
+TAIL_HEADS = {**HEADS, "360m": (15, 5, 64), "olmoe": (16, 16, 128)}
 
 
 @pytest.fixture(scope="module")
@@ -284,30 +284,20 @@ def test_paged_decode_attention(chip, model, return_stats):
     assert outs[0] == (None, None, heads * (hq // hkv), d)
 
 
-def _spec_text(chip, model, slots, kq, return_stats=False):
-    hq, hkv, d = HEADS[model]
+def _tail_text(chip, model, kq):
+    hq, hkv, d = TAIL_HEADS[model]
     return compiled_text(
         chip,
-        lambda q, ck, cv, tk, tv, lens: spec_tail_attention_fused(
-            q, ck, cv, tk, tv, lens, interpret=False, return_stats=return_stats
+        lambda q, ck, cv, tk, tv, lens: tail_attention_fused(
+            q, ck, cv, tk, tv, lens, interpret=False
         ),
-        ((slots, kq, hq, d), BF16),
-        (cache_shape(1, slots, SEQ, hkv, d)[1:], BF16),  # one layer's pages
-        (cache_shape(1, slots, SEQ, hkv, d)[1:], BF16),
-        ((slots, kq, hkv, d), BF16),
-        ((slots, kq, hkv, d), BF16),
-        ((slots,), jnp.int32),
+        ((1, kq, hq, d), BF16),
+        (cache_shape(1, 1, SEQ, hkv, d)[1:], BF16),  # one layer's pages
+        (cache_shape(1, 1, SEQ, hkv, d)[1:], BF16),
+        ((1, kq, hkv, d), BF16),
+        ((1, kq, hkv, d), BF16),
+        ((1,), jnp.int32),
     )
-
-
-@pytest.mark.parametrize("return_stats", [False, True])
-@pytest.mark.parametrize("model", list(HEADS))
-def test_spec_verify_tail(chip, model, return_stats):
-    """The speculative tail (current token + 4 drafts) over 8 slots: bf16 at
-    head_dim 64 is what Mosaic refused before the per-head slice moved to a
-    leading dim."""
-    text = _spec_text(chip, model, slots=8, kq=5, return_stats=return_stats)
-    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize(
@@ -318,30 +308,21 @@ def test_spec_verify_tail(chip, model, return_stats):
         # a whole GQA group's 8 x 1024 rows outgrow VMEM: the shape rule
         # hands this one to XLA instead of failing at compile
         ("1b", 1024, False),
+        # the serving cells' own heads at their own prefill buckets: three
+        # query heads a KV head at head_dim 64, and heads of 128
+        ("360m", 32, True),
+        ("360m", 128, True),
+        ("olmoe", 1024, True),
+        # its largest bucket: one head's 3072 x 3072 scores outgrow VMEM
+        ("olmoe", 3072, False),
     ],
 )
-def test_spec_continued_prefill(chip, model, kq, kernel):
-    """The same kernel as the prefix-cache continued prefill calls it: one
-    slot, the tail a whole suffix bucket."""
-    text = _spec_text(chip, model, slots=1, kq=kq)
+def test_continued_prefill_tail(chip, model, kq, kernel):
+    """The tail kernel as the prefix-cache continued prefill calls it: one
+    slot, the tail a whole suffix bucket; bf16 at head_dim 64 is what Mosaic
+    refused before the per-head slice moved to a leading dim."""
+    text = _tail_text(chip, model, kq)
     assert ("tpu_custom_call" in text) == kernel
-
-
-@pytest.mark.parametrize("rows", [8, 512])
-@pytest.mark.parametrize("proj", ["gate", "down"])
-@pytest.mark.parametrize("model", list(FFN))
-def test_w4_matmul(chip, model, proj, rows):
-    hidden, inter = FFN[model]
-    k, n = (hidden, inter) if proj == "gate" else (inter, hidden)
-    text = compiled_text(
-        chip,
-        lambda x, q, s: w4_matmul(x, q, s, (k, n), BF16, interpret=False),
-        ((rows, k), BF16),
-        ((k * n // 2,), jnp.uint8),
-        ((-(-k * n // 4096),), jnp.uint16),
-    )
-    assert "tpu_custom_call" in text
-
 
 
 # ---------------------------------------------------------------------------
@@ -412,7 +393,7 @@ def test_olmoe_prefill_program_at_the_largest_bucket(chip):
     bucket = max(engine["prefill_buckets"])
     compiled = (
         jax.jit(lambda p, ids, n: prefill_forward(
-            p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=True))
+            p, ids, n, cfg, return_moe_counts=True))
         .lower(
             _on_chip(chip, shapes(cfg)),
             jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
@@ -641,7 +622,7 @@ def test_granite_prefill_program_at_the_largest_bucket(chip):
     bucket = max(engine["prefill_buckets"])
     compiled = (
         jax.jit(lambda p, ids, n: prefill_forward(
-            p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=True))
+            p, ids, n, cfg, return_moe_counts=True))
         .lower(
             params,
             jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
@@ -782,7 +763,7 @@ def _engine_program(chip, config, workload, program):
         bucket = max(engine["prefill_buckets"])
         compiled = (
             jax.jit(lambda p, ids, n: prefill_forward(
-                p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=moe))
+                p, ids, n, cfg, return_moe_counts=moe))
             .lower(
                 params,
                 jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),

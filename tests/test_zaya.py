@@ -162,7 +162,7 @@ def test_the_cca_projection_alone():
     w = one_layer(params)
     x = jax.random.normal(jax.random.key(2), (2, 19, cfg.hidden_size), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(19), (2, 19))
-    q, k, v, tails = llama._cca_qkv(cfg, x, w, *llama._rope(cfg, pos), jnp.matmul)
+    q, k, v, tails = llama._cca_qkv(cfg, x, w, *llama._rope(cfg, pos))
     want = reference.cca_qkv(x, w, raw)
     assert q.shape == (2, 19, 4, 16) and k.shape == v.shape == (2, 19, 2, 16)
     assert tails.shape == (2, 19, cfg.cca_state_dim)
@@ -177,7 +177,7 @@ def test_the_cca_projection_alone():
     past, rows = None, []
     for t in range(19):
         qt, kt, vt, tail = llama._cca_qkv(
-            cfg, x[:, t : t + 1], w, *llama._rope(cfg, pos[:, t : t + 1]), jnp.matmul, past)
+            cfg, x[:, t : t + 1], w, *llama._rope(cfg, pos[:, t : t + 1]), past)
         past = tail[:, 0]
         rows.append((qt, kt, vt))
     for i, full in enumerate((q, k, v)):
@@ -477,10 +477,6 @@ def test_what_cannot_follow_the_state_says_so(tmp_path):
     from opendiloco_tpu.serve.kvcache import HostKVTier
 
     _, cfg, params = model(seed=27)
-    with pytest.raises(ValueError, match=f"speculative decode.*{REFUSED}"):
-        engine_for(cfg, params, spec_k=2)
-    with pytest.raises(ValueError, match=f"weight_format=w4 is {REFUSED}"):
-        engine_for(cfg, params, weight_format="w4")
     engine = engine_for(cfg, params)
     with pytest.raises(ValueError, match=f"prefix_cache is {REFUSED}"):
         ContinuousBatcher(engine, prefix_cache=True)
@@ -494,10 +490,8 @@ def test_what_cannot_follow_the_state_says_so(tmp_path):
     with pytest.raises(ValueError, match=f"page-in is {REFUSED}"):
         engine.install_slot_pages(0, np.zeros((LAYERS, 16, 2, 16)), np.zeros((LAYERS, 16, 2, 16)))
     vec = jnp.zeros((4,), jnp.int32)
-    with pytest.raises(ValueError, match=f"verify pass.*{REFUSED}"):
-        llama.verify_forward(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k, engine.cache_v, cfg)
-    with pytest.raises(ValueError, match=f"draft is {REFUSED}"):
-        llama.draft_propose(params, vec, vec, engine.cache_k, engine.cache_v, cfg, k_steps=2, draft_layers=1)
+    with pytest.raises(ValueError, match=f"continued prefill.*{REFUSED}"):
+        llama.continue_prefill(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k, engine.cache_v, cfg)
     with pytest.raises(ValueError, match="pp pipeline is refused for a configuration whose router reads"):
         pipeline_hidden(params, jnp.zeros((2, 8, 32)), None, cfg, None, microbatches=2, attn_fn=None)
     with pytest.raises(ValueError, match="no CCA"):
