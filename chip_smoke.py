@@ -380,15 +380,15 @@ def phase_train_serve(
         ),
     )
 
-    # one outer round per boundary; each waits for the serving wave before it
+    # one outer round per boundary (an all-reduce a piece, all under the
+    # round's epoch); each waits for the serving wave before it
     (backend,) = LoopbackWorld(1).make_backends()
     holds = [threading.Event(), threading.Event()]
-    rounds = iter(holds)
     all_reduce = backend.all_reduce
 
     def held_all_reduce(arrays, **kw):
-        hold = next(rounds, None)
-        if hold is not None and not hold.wait(HOLD_TIMEOUT_S):
+        epoch = kw.get("epoch") or 0
+        if epoch < len(holds) and not holds[epoch].wait(HOLD_TIMEOUT_S):
             raise TimeoutError("the serving wave before this boundary never ended")
         return all_reduce(arrays, **kw)
 

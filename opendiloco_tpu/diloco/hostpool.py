@@ -38,14 +38,16 @@ class OutputPool:
 
     def take(
         self,
-        i: int,
+        i,
         like: np.ndarray,
         shape: Optional[tuple] = None,
         dtype=np.float32,
     ) -> np.ndarray:
-        """An array of ``dtype`` in ``like``'s memory order for the ``i``-th
-        array of a round, its contents undefined. Of ``like``'s shape, or of
-        ``shape`` (same rank) when ``like`` is one part of the whole."""
+        """An array of ``dtype`` in ``like``'s memory order for position ``i``
+        of a round, its contents undefined. Of ``like``'s shape, or of
+        ``shape`` (same rank) when ``like`` is one part of the whole. ``i`` is
+        whatever names the position from round to round (hashable): a leaf's
+        index in the plane, a round's tag with an array's index in its call."""
         shape = like.shape if shape is None else tuple(shape)
         kept = self._arrays.setdefault(
             (i, shape, like.strides, np.dtype(dtype)), []

@@ -85,7 +85,11 @@ class LoopbackWorld:
         self._async_seq = 0  # match-key nonce (repeat matches never collide)
         # the rounds' output arrays, kept across rounds (under self.lock):
         # a round hands out n per position, and a consumer may still hold
-        # the last round's while the next is written
+        # the last round's while the next is written. A position is a
+        # round's tag and an array's index in its call: the blocking
+        # boundary calls once a piece (``grads-p0``, ``grads-p1``, ...), each
+        # call's arrays starting at index 0, and by index alone pieces that
+        # hold same-shaped leaves would push each other's arrays out
         self._outputs = OutputPool(keep=2 * max(1, n_peers))
 
     def make_backends(self) -> list["LoopbackBackend"]:
@@ -255,7 +259,11 @@ class LoopbackBackend(OuterBackend):
         contributions costs n reads and one write for the mean (``_mean``),
         and one copy for each collector but the last, made outside the
         world's lock; one peer with the identity codec pays one copy in all,
-        one peer with a lossy codec none."""
+        one peer with a lossy codec none. The arrays written are ones the
+        world keeps from round to round under ``tag`` and the array's index
+        in the call: a caller that cuts a round into several calls (the
+        blocking boundary's pieces) gives each its own tag, and from the
+        second round on no call touches a new page."""
         self._chaos_gate()
         # TcpBackend key parity: epoch=None resolves to this peer's own
         # reported epoch (default 0). Rounds are KEYED now — a raw None in
@@ -282,13 +290,19 @@ class LoopbackBackend(OuterBackend):
             )
         deadline = time.monotonic() + (timeout or 3600.0)
         t_wait = time.perf_counter() if tr is not None else 0.0
+
+        def take(i, like):  # under w.lock
+            return w._outputs.take((tag, i), like)
+
         with w.cond:
             if group_cap:
                 pub, last = self._group_round(
-                    mine, round_key, group_cap, deadline
+                    mine, round_key, group_cap, deadline, take
                 )
             else:
-                pub, last = self._world_round(mine, round_key, deadline, t_wait)
+                pub, last = self._world_round(
+                    mine, round_key, deadline, t_wait, take
+                )
             # the result is immutable once published: take the reference
             # here and copy after the lock is released, so that n peers copy
             # side by side and other tags' rounds are not held up behind a
@@ -301,7 +315,7 @@ class LoopbackBackend(OuterBackend):
                 result = pub.arrays
             else:
                 pub.readers += 1
-                result = [w._outputs.take(i, a) for i, a in enumerate(pub.arrays)]
+                result = [take(i, a) for i, a in enumerate(pub.arrays)]
         if not last:
             try:
                 for dst, src in zip(result, pub.arrays):
@@ -318,15 +332,14 @@ class LoopbackBackend(OuterBackend):
         self._record_round_health(tag, epoch, pub.group)
         return result, pub.group
 
-    def _publish(self, contribs, round_key) -> "_Published":
-        """Under world.lock: the mean of ``contribs`` (in the order given),
-        with the ``outer/reduce`` span of the peer that computes it."""
+    def _publish(self, contribs, round_key, take) -> "_Published":
+        """Under world.lock: the mean of ``contribs`` (in the order given)
+        in arrays from ``take(i, like)``, with the ``outer/reduce`` span of
+        the peer that computes it."""
         w = self.world
         tr = obs.tracer()
         t0 = time.perf_counter() if tr is not None else 0.0
-        arrays, path, nbytes = _mean(
-            contribs, not _is_identity(w.codec), w._outputs.take
-        )
+        arrays, path, nbytes = _mean(contribs, not _is_identity(w.codec), take)
         if tr is not None:
             tr.add_span(
                 "outer/reduce", t0, time.perf_counter(),
@@ -335,7 +348,7 @@ class LoopbackBackend(OuterBackend):
             )
         return _Published(arrays, len(contribs), t0)
 
-    def _world_round(self, mine, round_key, deadline, t_wait):
+    def _world_round(self, mine, round_key, deadline, t_wait, take):
         """Under world.lock: contribute to the round of every live peer and
         wait for its mean. -> (published result, whether this peer is the
         last of its generation to collect it)."""
@@ -360,7 +373,7 @@ class LoopbackBackend(OuterBackend):
                 # complete: first thread to notice publishes the mean, the
                 # contributions taken in arrival order
                 slot["result"] = self._publish(
-                    list(slot["contrib"].values()), round_key
+                    list(slot["contrib"].values()), round_key, take
                 )
                 t_reduce = slot["result"].t_reduce
                 slot["result_round"] = my_round
@@ -401,7 +414,7 @@ class LoopbackBackend(OuterBackend):
             w._rounds.pop(round_key, None)
         return pub, last
 
-    def _group_round(self, mine, key, cap, deadline):
+    def _group_round(self, mine, key, cap, deadline, take):
         """Under world.lock: partition live peers into per-round groups of
         <= cap and average within the group only (mirrors the rendezvous
         daemon's capped matchmaking). The FIRST arriver freezes the
@@ -439,7 +452,7 @@ class LoopbackBackend(OuterBackend):
                 if slot["result"] is None:
                     # first member to notice publishes, in group order
                     slot["result"] = self._publish(
-                        [slot["contrib"][m] for m in live_members], key
+                        [slot["contrib"][m] for m in live_members], key, take
                     )
                 slot["done"].add(self._peer_id)
                 last = slot["done"] >= set(live_members)

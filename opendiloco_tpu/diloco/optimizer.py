@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import functools
+import queue
 import threading
 import time
 from typing import TYPE_CHECKING, Any, Optional
@@ -29,12 +30,12 @@ import numpy as np
 
 from opendiloco_tpu import native, obs
 from opendiloco_tpu.config import DilocoConfig
-from opendiloco_tpu.diloco import planner
+from opendiloco_tpu.diloco import outer_device, planner
 from opendiloco_tpu.diloco.backend import OuterBackend, PeerProgress, wait_for_peers
 from opendiloco_tpu.diloco.compression import get_codec
 from opendiloco_tpu.diloco.error_feedback import ErrorFeedback
 from opendiloco_tpu.diloco.gossip import GossipPlane
-from opendiloco_tpu.diloco.outer_device import DeviceOuterPlane
+from opendiloco_tpu.diloco.outer_device import DeviceOuterPlane, PutBack
 from opendiloco_tpu.diloco.outer_optimizer import OuterSGD, noloco_step
 from opendiloco_tpu.diloco.streaming import StreamScheduler
 from opendiloco_tpu.parallel.world import HostWorld
@@ -66,6 +67,91 @@ class PeerDropError(RuntimeError):
     (reference: train_fsdp.py:452-457)."""
 
 
+def _piece_tag(k: int, n: int) -> str:
+    """The all-reduce tag of piece ``k`` of a blocking round cut into ``n``:
+    a tag a piece, not one tag called n times, because a backend's results
+    are views it reclaims at the next call under the SAME tag
+    (``TcpBackend.all_reduce``), and the way back is still reading piece k's
+    when piece k + 1's round opens. A round in one piece keeps ``grads``."""
+    return "grads" if n == 1 else f"grads-p{k}"
+
+
+class _WireRound:
+    """One blocking round's all-reduces, the same under either placement: a
+    call a piece under the piece's own tag (``_piece_tag``), all of them
+    under ONE deadline (``averaging_timeout`` from the first call's start,
+    so a round cut into n pieces cannot take n times as long), their seconds
+    summed and their health rows folded into one.
+
+    A peer that drops between two pieces leaves the later ones a smaller
+    group. Every survivor sees the same groups (the rendezvous decides them),
+    each leaf is the mean of those that contributed it, and the round
+    completes as the elastic round it is: ``group_size()`` is the smallest
+    piece's, which is what ``_check_group_size`` (and ``fail_rank_drop``)
+    then sees. A piece that raises fails the round, as a failed all-reduce
+    always did."""
+
+    def __init__(self, opt: "DiLoCoOptimizer", n_pieces: int):
+        self._opt, self._n = opt, n_pieces
+        self._deadline: Optional[float] = None
+        self._seen: Any = None
+        self.seconds = 0.0
+        self.sizes: list[int] = []
+        self.health: dict = {}
+
+    def reduce(self, k: int, arrays: list[np.ndarray]) -> list[np.ndarray]:
+        opt = self._opt
+        now = time.monotonic()
+        if self._deadline is None:
+            self._deadline = now + opt.cfg.averaging_timeout
+        # what is left of the round's time, and never nothing: the backend
+        # does the timing out (under multihost it is the messenger's failure
+        # that the whole slice raises on, in lockstep)
+        left = max(self._deadline - now, 1.0)
+        t1 = time.perf_counter()
+        averaged, n, _ = opt._wan_all_reduce(
+            arrays, timeout=left, epoch=opt.epoch, tag=_piece_tag(k, self._n)
+        )
+        t2 = time.perf_counter()
+        self.seconds += t2 - t1
+        self.sizes.append(n)
+        self._fold(getattr(opt.backend, "last_round_health", None))
+        tr = obs.tracer()
+        if tr is not None:
+            tr.add_span(
+                "outer/allreduce", t1, t2, epoch=opt.epoch, group=n,
+                piece=k, bytes=sum(a.nbytes for a in arrays),
+            )
+        return averaged
+
+    def _fold(self, health: Optional[dict]) -> None:
+        if not health or health is self._seen:
+            return  # the backend keeps no health, or wrote none for this call
+        self._seen = health
+        was = self.health
+        # the plan fields (link_plan, link_shares, hier) are the last piece's
+        self.health = {
+            **health,
+            "elastic": bool(was.get("elastic") or health.get("elastic")),
+            "expected": max(was.get("expected", 0), health.get("expected", 0)),
+            "retries": was.get("retries", 0) + (health.get("retries") or 0),
+        }
+
+    def group_size(self) -> int:
+        n = min(self.sizes)
+        if n != max(self.sizes):
+            log.warning(
+                "outer step %d: a peer dropped between the round's pieces "
+                "(group sizes %s); the round completes with %d",
+                self._opt.epoch, self.sizes, n,
+            )
+            self.health["elastic"] = True
+            self.health["expected"] = max(
+                self.health.get("expected", 0), max(self.sizes)
+            )
+        return n
+
+
 class _BoundaryFetch(threading.Thread):
     """The boundary's device-to-host fetch on a thread of its own, so that it
     overlaps the straggler wait. It times itself: ``seconds`` is the fetch's
@@ -74,35 +160,82 @@ class _BoundaryFetch(threading.Thread):
     ``stats`` (the device plane's ``last_fetch``) is asked, once the fetch
     has ended, what it did: ``bytes`` and ``shards`` assembled on the host
     and ``new_bytes``, those of them written into arrays allocated in this
-    round -- attributes of the span, and ``outer_d2h_new_bytes`` in the row."""
+    round -- attributes of the span, and ``outer_d2h_new_bytes`` in the row.
 
-    def __init__(self, tr, epoch: int, fetch, stats=None):
+    ``piecewise`` (the blocking device-placement boundary): ``fetch`` is
+    called with a ``deliver(k, arrays)`` to hand each piece on as it lands,
+    and ``arrivals()`` yields them to the boundary's next stage while later
+    pieces are still on their way. The fetch's interval is then cut into one
+    ``outer/d2h`` span a piece (from the piece before's landing to its own),
+    each with ``piece`` and ``bytes``, the last with the whole fetch's
+    ``shards`` and ``new_bytes`` as well."""
+
+    def __init__(self, tr, epoch: int, fetch, stats=None, piecewise: bool = False):
         super().__init__(name="outer-d2h")
         self._tr, self._epoch, self._fetch, self._stats = tr, epoch, fetch, stats
         self._result = self._error = None
         self.seconds = 0.0
         self.stats: dict = {}
+        self._arrived: Optional[queue.SimpleQueue] = (
+            queue.SimpleQueue() if piecewise else None
+        )
+        self._landings: list[tuple] = []  # (piece, when, bytes)
+
+    def _deliver(self, k: int, arrays: list) -> None:
+        self._landings.append(
+            (k, time.perf_counter(), sum(a.nbytes for a in arrays))
+        )
+        self._arrived.put((k, arrays))
 
     def run(self) -> None:
         t0 = time.perf_counter()
         try:
-            self._result = self._fetch()
+            if self._arrived is None:
+                self._result = self._fetch()
+            else:
+                self._result = self._fetch(self._deliver)
             if self._stats is not None:
                 self.stats = dict(self._stats())
         except BaseException as e:  # re-raised by wait(), in the caller
             self._error = e
         t1 = time.perf_counter()
+        if self._landings:
+            t1 = self._landings[-1][1]  # the last piece's landing
         self.seconds = t1 - t0
-        if self._tr is not None:
+        if self._arrived is not None:
+            self._arrived.put(None)  # the fetch has ended, well or not
+        if self._tr is None:
+            return
+        if self._arrived is None:
             self._tr.add_span(
                 "outer/d2h", t0, t1, epoch=self._epoch, **self.stats
             )
+            return
+        # what the whole fetch assembled rides the last piece's span
+        whole = {k: v for k, v in self.stats.items() if k != "bytes"}
+        for k, when, nbytes in self._landings:
+            last = (k, when, nbytes) == self._landings[-1]
+            self._tr.add_span(
+                "outer/d2h", t0, when, epoch=self._epoch, piece=k, bytes=nbytes,
+                **(whole if last else {}),
+            )
+            t0 = when
+
+    def arrivals(self):
+        """The pieces as they land, in their order: ``(k, host arrays)``.
+        Ends when the fetch has; a fetch that raised raises here."""
+        while (item := self._arrived.get()) is not None:
+            yield item
+        if self._error is not None:
+            raise self._error
 
     def row(self) -> dict:
         """The fetch's part of the optimizer's row."""
         out = {"outer_d2h_s": self.seconds}
         if "new_bytes" in self.stats:
             out["outer_d2h_new_bytes"] = self.stats["new_bytes"]
+        if self._arrived is not None:
+            out["outer_pieces"] = len(self._landings)
         return out
 
     def wait(self):
@@ -263,6 +396,8 @@ class DiLoCoOptimizer:
         self._abandoned: Optional[Any] = None  # dropped round still running
         self._landed_metrics: Optional[dict[str, Any]] = None
         self._apply_delta = None
+        # fragment (None: all leaves) -> the pieces its blocking round runs in
+        self._piece_cache: dict = {}
         # persistent pseudo-gradient buffers (reference: hivemind averages
         # into the outer optimizer's persistent grad buffers,
         # hivemind_diloco.py:68-119). Fresh model-sized allocations every
@@ -1219,11 +1354,14 @@ class DiLoCoOptimizer:
         )
         return state
 
-    def _round_health_metrics(self) -> dict:
-        """Elastic-round fields from the backend's health ledger, merged
-        into the metrics row of every landed outer round: dashboards and
-        the chaos soak read partial groups as data, not as errors."""
-        health = getattr(self.backend, "last_round_health", None) or {}
+    def _round_health_metrics(self, health: Optional[dict] = None) -> dict:
+        """Elastic-round fields from the backend's health ledger (or from
+        ``health``: a blocking round's rows folded over its pieces,
+        ``_WireRound``), merged into the metrics row of every landed outer
+        round: dashboards and the chaos soak read partial groups as data,
+        not as errors."""
+        if health is None:
+            health = getattr(self.backend, "last_round_health", None) or {}
         out = {}
         if "elastic" in health:
             out["elastic"] = bool(health["elastic"])
@@ -1321,6 +1459,30 @@ class DiLoCoOptimizer:
     # ------------------------------------------------------------------
     # outer step (reference: _update_global_epoch, hivemind_diloco.py:570-679)
     # ------------------------------------------------------------------
+
+    def _pieces(self, frag: Optional[list[int]]) -> list[list[int]]:
+        """The pieces this epoch's blocking round crosses the wire in, as
+        positions into the round's own list of leaves: ``cut_pieces`` of the
+        leaves' float32 bytes and of nothing else -- not the placement, not
+        the mesh -- fixed the first time a fragment is asked for, so that
+        every worker of a galaxy opens the same rounds under the same tags.
+        One piece, the leaves in their own order, for a round that needs the
+        whole list in hand before the wire: error feedback, gossip, a
+        state-averaging epoch."""
+        masters = self.master if self._plane is None else self._plane.masters
+        idxs = range(len(masters)) if frag is None else frag
+        if (
+            self.cfg.outer_mode == "gossip"
+            or self._ef is not None
+            or self._is_state_avg_epoch()
+        ):
+            return [list(range(len(idxs)))]
+        key = None if frag is None else tuple(frag)
+        if key not in self._piece_cache:
+            self._piece_cache[key] = outer_device.cut_pieces(
+                [masters[i].size * 4 for i in idxs]
+            )
+        return self._piece_cache[key]
 
     def _wan_all_reduce(
         self,
@@ -1424,13 +1586,34 @@ class DiLoCoOptimizer:
 
     def _outer_step_device(self, state: dict) -> tuple[dict, dict]:
         """Blocking outer round, device placement: the pseudo-gradient and
-        the Nesterov apply are fused, donated jit ops; D2H moves wire-width
-        bytes and H2D returns only the averaged pseudo-gradient. No
-        clone-then-rebind and no pre-round host snapshot for normal rounds
-        — donation makes the apply atomic under plane.lock, which the
+        the Nesterov apply are fused, donated jit ops over all the round's
+        leaves; in between, the round crosses the host in pieces
+        (``_pieces``) and in three stages that run side by side: the
+        fetch (``_BoundaryFetch``'s thread; wire-width D2H) hands each piece
+        on as it lands, this thread all-reduces it under the piece's own tag
+        (``_WireRound``), and ``PutBack``'s thread has the average back on the
+        devices while the next piece is still arriving. The fetch starts
+        before the straggler wait and overlaps it; no piece's all-reduce
+        starts before the wait has returned. A round one of whose pieces
+        raises has failed as a failed all-reduce fails -- the stage threads
+        joined, the puts dropped, masters, momentum and parameters untouched,
+        because the apply has not been dispatched; a peer that drops between
+        two pieces makes it an elastic round (``_WireRound``). Rounds that
+        need the whole list in hand before the wire (error feedback, gossip,
+        a state-averaging epoch) are the same code with one piece.
+
+        No clone-then-rebind and no pre-round host snapshot for normal
+        rounds — donation makes the apply atomic under plane.lock, which the
         serve thread's device path also takes. State-averaging rounds do
         pre-publish a host snapshot (their WAN leg would otherwise stall
-        onboarding fetches behind plane.lock)."""
+        onboarding fetches behind plane.lock).
+
+        The row's three parts overlap and no longer add up to
+        ``outer_step_s``: ``outer_d2h_s`` the fetch's own start to its last
+        piece's landing, ``outer_allreduce_s`` the sum of the pieces'
+        all-reduce calls, ``outer_apply_s`` from the last piece's average in
+        hand to the return; ``outer_h2d_s`` the first put's start to the last
+        piece resident, ``outer_pieces`` how many pieces the round ran in."""
         plane = self._plane
         if self._pending is not None:  # a blocking round supersedes overlap
             state = self._poll_pending(state, block=True)
@@ -1460,19 +1643,22 @@ class DiLoCoOptimizer:
         device_leaves = jax.tree.leaves(state["params"])
         if self._fragments is not None:
             frag = self._fragments[self.epoch % len(self._fragments)]
+        gossip = self.cfg.outer_mode == "gossip"
+        pieces = self._pieces(frag)
         # wire-width D2H of this boundary's fragment; the norm rides the
         # same jit as one HBM reduction, armed tracer or not
         fetcher = _BoundaryFetch(
             tr, self.epoch,
-            lambda: plane.pseudo_grad(
+            lambda deliver: plane.pseudo_grad(
                 device_leaves if frag is None
                 else [device_leaves[i] for i in frag],
-                frag,
+                frag, pieces=pieces, deliver=deliver,
             ),
             stats=lambda: plane.last_fetch,
+            piecewise=True,
         )
         fetcher.start()
-        if self.cfg.outer_mode != "gossip":
+        if not gossip:
             # gossip skips the straggler wait: a pair round has no group
             # to assemble (no global barrier); the pair push-pull itself
             # bounds how long a fast worker waits on its partner
@@ -1490,51 +1676,52 @@ class DiLoCoOptimizer:
                 "outer/barrier_wait", t0p, time.perf_counter(),
                 epoch=self.epoch,
             )
-        pseudo_grad, pg_norm, _ = fetcher.wait()
-        d2h_row = fetcher.row()
-        if tr is not None:
-            tr.gauge("pseudo_grad_norm", pg_norm)
-        if self.cfg.outer_mode == "gossip":
+        if gossip:
             # pair-mix on host (the wire encode is host-side anyway), then
             # land the mixed fragment back through the plane's donated jits
+            ((_, pseudo_grad),) = fetcher.arrivals()
+            _, pg_norm, _ = fetcher.wait()
+            if tr is not None:
+                tr.gauge("pseudo_grad_norm", pg_norm)
             return self._outer_step_device_gossip(
                 state, device_leaves, frag, pseudo_grad,
-                t0=t0, t0p=t0p, wait_s=wait_s, d2h_row=d2h_row,
+                t0=t0, t0p=t0p, wait_s=wait_s, d2h_row=fetcher.row(),
                 pg_norm=pg_norm,
             )
-        if self._ef is not None:
-            # residual already added in the plane's jit; stage the error
-            self._ef.prepare(
-                "main",
-                frag if frag is not None else range(len(pseudo_grad)),
-                pseudo_grad,
-            )
 
-        t1 = time.monotonic()
-        t1p = time.perf_counter() if tr is not None else 0.0
+        back = PutBack(plane, frag, pieces)
+        back.start()
+        wire = _WireRound(self, len(pieces))
         try:
-            averaged, group_size, _ = self._wan_all_reduce(
-                pseudo_grad, timeout=self.cfg.averaging_timeout, epoch=self.epoch
-            )
+            for k, piece in fetcher.arrivals():
+                if self._ef is not None:
+                    # residual already added in the plane's jit; stage the
+                    # error (one piece: the whole list)
+                    self._ef.prepare(
+                        "main",
+                        frag if frag is not None else range(len(piece)),
+                        piece,
+                    )
+                back.submit(k, wire.reduce(k, piece))
+            group_size = wire.group_size()
             self._check_group_size(group_size)
+            t_apply = time.perf_counter()
+            averaged = back.wait()
         except BaseException:
+            back.drop()
+            fetcher.join()
             if self._ef is not None:
                 self._ef.abort("main")
             raise
+        _, pg_norm, _ = fetcher.wait()
+        if tr is not None:
+            tr.gauge("pseudo_grad_norm", pg_norm)
         if self._ef is not None:
             self._ef.commit("main")
-        allreduce_s = time.monotonic() - t1
-        if tr is not None:
-            tr.add_span(
-                "outer/allreduce", t1p, time.perf_counter(),
-                epoch=self.epoch, group=group_size,
-            )
-        t_apply = time.perf_counter()
+        allreduce_s = wire.seconds
         log.info(
-            "outer step %d: all-reduce over %d peers took %.3fs",
-            self.epoch,
-            group_size,
-            allreduce_s,
+            "outer step %d: all-reduce over %d peers took %.3fs in %d pieces",
+            self.epoch, group_size, allreduce_s, len(pieces),
         )
 
         if state_avg:
@@ -1582,13 +1769,14 @@ class DiLoCoOptimizer:
         self._epoch_t0 = time.monotonic()
         outer_metrics = {
             "outer_step_s": time.monotonic() - t0,
-            **d2h_row,
+            **fetcher.row(),
             "outer_allreduce_s": allreduce_s,
+            "outer_h2d_s": back.seconds,
             "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
             "pseudo_grad_norm": pg_norm,
             "num_peers": group_size,
-            **self._round_health_metrics(),
+            **self._round_health_metrics(wire.health),
         }
         if tr is not None:
             tr.add_span(
@@ -1864,12 +2052,18 @@ class DiLoCoOptimizer:
             # (incl. fail_rank_drop) runs on the live-peer count instead
             self._check_group_size(live_peers)
         else:
+            # the wire is the device placement's, piece for piece and tag
+            # for tag (``_pieces``), so that workers of either placement
+            # meet in the same rounds; here the pieces go one after another
+            pieces = self._pieces(frag)
+            wire = _WireRound(self, len(pieces))
+            averaged = [None] * len(pseudo_grad)
             try:
-                averaged, group_size, _ = self._wan_all_reduce(
-                    pseudo_grad,
-                    timeout=self.cfg.averaging_timeout,
-                    epoch=self.epoch,
-                )
+                for k, piece in enumerate(pieces):
+                    got = wire.reduce(k, [pseudo_grad[j] for j in piece])
+                    for j, a in zip(piece, got):
+                        averaged[j] = a
+                group_size = wire.group_size()
                 self._check_group_size(group_size)
             except BaseException:
                 if self._ef is not None:
@@ -1878,7 +2072,7 @@ class DiLoCoOptimizer:
             if self._ef is not None:
                 self._ef.commit("main")
         allreduce_s = time.monotonic() - t1
-        if tr is not None:
+        if tr is not None and gossip:
             tr.add_span(
                 "outer/allreduce", t1p, time.perf_counter(),
                 epoch=self.epoch, group=group_size,
@@ -1967,7 +2161,7 @@ class DiLoCoOptimizer:
             "outer_apply_s": time.perf_counter() - t_apply,
             "outer_wait_s": wait_s,
             "num_peers": group_size,
-            **self._round_health_metrics(),
+            **self._round_health_metrics(None if gossip else wire.health),
         }
         if tr is not None:
             tr.add_span(

@@ -20,6 +20,13 @@ is idempotent, so the bytes that later ride the wire are unchanged — see
 bytes. The H2D return carries only the averaged pseudo-gradient; the
 apply runs on device.
 
+A blocking boundary crosses the host in *pieces* (``cut_pieces``: runs of
+whole leaves, large ones first): the fetch hands each piece on as its last
+shard lands (``pseudo_grad(deliver=...)``), the optimizer all-reduces it, and
+``PutBack`` has its average back on the devices while later pieces are still
+arriving. The two device programs -- one pseudo-gradient jit before, one
+donated apply after -- see all leaves at once, as they always did.
+
 Thread contract: every mutating entry point takes ``self.lock`` (an
 RLock) around the donating jit call AND the rebind, and the serve
 thread's lazy host snapshot (``host_state``) holds the same lock while
@@ -33,6 +40,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import functools
+import queue
 import threading
 import time
 from typing import Optional, Sequence
@@ -327,13 +335,67 @@ def _distinct_shards(x: jax.Array) -> list:
     return out
 
 
-def _copy_shard(whole: np.ndarray, shard) -> None:
+def cut_pieces(nbytes: Sequence[int]) -> list[list[int]]:
+    """A round's leaves, as positions into the round's own list, cut into the
+    pieces of the boundary's pipeline, from their bytes and nothing else.
+
+    Large leaves first, each a piece of its own; a leaf of less than a
+    ``_RIDES_BELOW``-th of the round's bytes (the norms) rides with the piece
+    before it. The last piece's all-reduce and way back are the tail nothing
+    hides, so the small leaves come last. Every peer of a round cuts the same
+    model alike, which is what lets a piece be a round of its own on the wire."""
+    order = sorted(range(len(nbytes)), key=lambda j: -nbytes[j])
+    total = sum(nbytes)
+    pieces: list[list[int]] = []
+    for j in order:
+        if pieces and nbytes[j] * _RIDES_BELOW < total:
+            pieces[-1].append(j)
+        else:
+            pieces.append([j])
+    return pieces
+
+
+_RIDES_BELOW = 64
+
+
+class _Landing:
+    """Counts a fetch's pieces down and hands each on, in the pieces' order,
+    from whichever thread lands the last part of the next one due."""
+
+    def __init__(self, leaves: list, out: list, pieces, units, deliver):
+        self._leaves, self._out, self._pieces = leaves, out, pieces
+        self._left = list(units)  # parts still under way, piece by piece
+        self._deliver = deliver
+        self._due = 0
+        self._lock = threading.Lock()
+
+    def landed(self, k: int, parts: int = 1) -> None:
+        with self._lock:
+            self._left[k] -= parts
+            while self._due < len(self._pieces) and self._left[self._due] == 0:
+                piece = self._pieces[self._due]
+                for j in piece:
+                    # the piece is on the host: let go of its device arrays
+                    # (a view that device_get made keeps its own buffer)
+                    self._leaves[j] = None
+                self._deliver(self._due, [self._out[j] for j in piece])
+                self._due += 1
+
+
+def _copy_shard(whole: np.ndarray, shard, landing=None, k: int = 0) -> None:
     """One shard's transfer (awaited here), then its copy into its slice."""
     np.copyto(whole[shard.index], np.asarray(shard.data))
+    if landing is not None:
+        landing.landed(k)
 
 
 def _fetch_sharded(
-    leaves: Sequence[jax.Array], keys: Sequence[int], pool: OutputPool, lock
+    leaves: Sequence[jax.Array],
+    keys: Sequence[int],
+    pool: OutputPool,
+    lock,
+    pieces: Optional[Sequence[Sequence[int]]] = None,
+    deliver=None,
 ) -> tuple[list[np.ndarray], dict]:
     """Host copies of jit outputs, bit for bit ``jax.device_get(leaves)``'s,
     with the sharded ones assembled into arrays from ``pool`` (position
@@ -350,34 +412,76 @@ def _fetch_sharded(
     in both) -- so a few transfers are in flight and the copies hide behind
     them. A leaf that is fully replicated or lives on one device has nothing
     to assemble and goes through ``device_get`` as before. ``lock`` guards
-    the pool: held while an array is taken, not while shards arrive."""
+    the pool: held while an array is taken, not while shards arrive.
+
+    With ``deliver``, the leaves are fetched in the order of ``pieces``
+    (positions into ``leaves``) and ``deliver(k, arrays of piece k)`` is
+    called as piece ``k``'s last shard lands, piece after piece in order,
+    from the thread that landed it; ``leaves`` (a list) is emptied piece by
+    piece, so that a piece's device arrays are let go once it is on the host."""
+    n = len(leaves)
+    if pieces is None:
+        pieces = [range(n)]
     parts = [_distinct_shards(x) if _is_assembled(x) else None for x in leaves]
-    rest = [j for j, shards in enumerate(parts) if shards is None]
-    for j in rest:
-        leaves[j].copy_to_host_async()
-    out: list = [None] * len(leaves)
+    out: list = [None] * n
+    landing = None
+    if deliver is not None:
+        units = [
+            sum(1 if parts[j] is None else len(parts[j]) for j in piece)
+            for piece in pieces
+        ]
+        landing = _Landing(leaves, out, pieces, units, deliver)
+    for piece in pieces:
+        for j in piece:
+            if parts[j] is None:
+                leaves[j].copy_to_host_async()
     copied = n_shards = new = 0
     with concurrent.futures.ThreadPoolExecutor(_FETCH_THREADS) as workers:
         copies = []
-        for j, (x, shards) in enumerate(zip(leaves, parts)):
-            if shards is None:
-                continue
-            # the first shard's host array says which memory order the
-            # device hands over; the whole gets the same, so that every
-            # shard's copy runs along both arrays' memory
-            first = np.asarray(shards[0].data)
-            with lock:
-                before = pool.new_bytes
-                out[j] = whole = pool.take(keys[j], first, x.shape, x.dtype)
-                new += pool.new_bytes - before
-            copied += whole.nbytes
-            n_shards += len(shards)
-            copies += [workers.submit(_copy_shard, whole, s) for s in shards]
-        for j, a in zip(rest, jax.device_get([leaves[j] for j in rest])):
-            out[j] = a
+        for k, piece in enumerate(pieces):
+            rest = []
+            for j in piece:
+                x, shards = leaves[j], parts[j]
+                if shards is None:
+                    rest.append(j)
+                    continue
+                # the first shard's host array says which memory order the
+                # device hands over; the whole gets the same, so that every
+                # shard's copy runs along both arrays' memory
+                first = np.asarray(shards[0].data)
+                with lock:
+                    before = pool.new_bytes
+                    out[j] = whole = pool.take(keys[j], first, x.shape, x.dtype)
+                    new += pool.new_bytes - before
+                copied += whole.nbytes
+                n_shards += len(shards)
+                copies += [
+                    workers.submit(_copy_shard, whole, s, landing, k) for s in shards
+                ]
+                parts[j] = None  # the shards are the work items' now
+            if rest:
+                got = jax.device_get([leaves[j] for j in rest])
+                for j, a in zip(rest, got):
+                    out[j] = a
+                if landing is not None:
+                    landing.landed(k, len(rest))
         for c in copies:
             c.result()
     return out, {"bytes": copied, "shards": n_shards, "new_bytes": new}
+
+
+def _put_leaves(host_arrays, shardings) -> list[jax.Array]:
+    """``device_put`` leaf after leaf, each resident on its devices before the
+    next is put. Put all at once, as a list comprehension puts them, three
+    and more sharded leaves are under way together and the four chips' way
+    back runs at 3-4 GB/s; one at a time it runs at 24 (PERF.md section 6,
+    PR 47: 1.7-2.3 s against 0.30 s for the 1.7B model's 6.8 GB). No caller
+    has anything to do before the last leaf is there."""
+    out = []
+    for a, s in zip(host_arrays, shardings):
+        out.append(jax.device_put(a, s))
+        out[-1].block_until_ready()
+    return out
 
 
 @functools.lru_cache(maxsize=None)
@@ -459,36 +563,46 @@ class DeviceOuterPlane:
         if self.momentum != 0.0 and self.bufs is None:
             # zeros for ALL leaves at the first armed step (OuterSGD
             # semantics: untouched fragments keep their momentum frozen)
-            self.bufs = [
-                jax.device_put(np.zeros(m.shape, np.float32), s)
-                for m, s in zip(self.masters, self.shardings)
-            ]
+            self.bufs = _put_leaves(
+                (np.zeros(m.shape, np.float32) for m in self.masters),
+                self.shardings,
+            )
 
     def _ensure_ef(self) -> None:
         if self.error_feedback and self.ef_res is None:
-            self.ef_res = [
-                jax.device_put(np.zeros(m.shape, np.float32), s)
-                for m, s in zip(self.masters, self.shardings)
-            ]
+            self.ef_res = _put_leaves(
+                (np.zeros(m.shape, np.float32) for m in self.masters),
+                self.shardings,
+            )
 
-    def _h2d(self, host_leaves, frag: Optional[list[int]]) -> list[jax.Array]:
+    def _h2d(
+        self,
+        host_leaves,
+        frag: Optional[list[int]],
+        piece: Optional[int] = None,
+    ) -> list[jax.Array]:
         """Averaged pseudo-gradient H2D. all_reduce results are views into
         pooled backend buffers the next call reclaims, so a zero-copy CPU
         device_put (which would ALIAS them) needs a pre-copy; a copying
         device_put already yields independent device memory and the
-        pre-copy would just double the H2D cost — probed, not assumed."""
+        pre-copy would just double the H2D cost — probed, not assumed.
+
+        Leaf after leaf (``_put_leaves``), so it returns with the arrays
+        resident and the ``outer/h2d`` span is the whole way back, with
+        ``bytes`` (and ``piece``: the boundary's pipeline puts a piece a
+        call). Takes no lock (the shardings never change), so it can run
+        beside a fetch."""
         sh = self._sel(self.shardings, frag)
         tr = obs.tracer()
         t0 = time.perf_counter() if tr is not None else 0.0
         own = np.asarray if _device_put_copies() else np.array
-        out = [
-            jax.device_put(own(a, dtype=np.float32), s)
-            for a, s in zip(host_leaves, sh)
-        ]
+        out = _put_leaves((own(a, dtype=np.float32) for a in host_leaves), sh)
         if tr is not None:
-            # the host's part of the transfer (staging and enqueue); the
-            # callers' apply/land spans hold this one and the jit's dispatch
-            tr.add_span("outer/h2d", t0, time.perf_counter(), leaves=len(out))
+            tr.add_span(
+                "outer/h2d", t0, time.perf_counter(), leaves=len(out),
+                bytes=sum(a.nbytes for a in out),
+                **({} if piece is None else {"piece": piece}),
+            )
         return out
 
     def _scalars(self):
@@ -501,18 +615,37 @@ class DeviceOuterPlane:
     # -- boundary ops ------------------------------------------------------
 
     def fetch(
-        self, leaves: Sequence[jax.Array], frag: Optional[list[int]] = None
+        self,
+        leaves: Sequence[jax.Array],
+        frag: Optional[list[int]] = None,
+        *,
+        pieces: Optional[Sequence[Sequence[int]]] = None,
+        deliver=None,
     ) -> tuple[list[np.ndarray], dict]:
         """Host copies of jit outputs over this plane's leaves (``frag``'s,
         or all), bit for bit ``jax.device_get``'s -> (arrays, what the fetch
         did: ``_fetch_sharded``'s stats, empty when no leaf was sharded and
         ``device_get`` did it all, to the letter). An assembled array is the
         caller's for as long as it keeps it; dropped, it is written again by
-        a later fetch of the same leaf."""
-        if not any(_is_assembled(x) for x in leaves):
-            return jax.device_get(leaves), {}
-        keys = frag if frag is not None else range(len(leaves))
-        return _fetch_sharded(leaves, keys, self._fetched, self.lock)
+        a later fetch of the same leaf.
+
+        With ``deliver``, ``deliver(k, arrays of pieces[k])`` is called piece
+        after piece as each is whole on the host, and ``leaves`` (a list) is
+        emptied as they go (``_fetch_sharded``)."""
+        if any(_is_assembled(x) for x in leaves):
+            keys = frag if frag is not None else range(len(leaves))
+            return _fetch_sharded(
+                leaves, keys, self._fetched, self.lock, pieces, deliver
+            )
+        # nothing to assemble: device_get's, to the letter; the pieces are
+        # all in hand when it returns and go on one after another
+        out = jax.device_get(leaves)
+        if deliver is not None:
+            for k, piece in enumerate(pieces or [range(len(leaves))]):
+                for j in piece:
+                    leaves[j] = None
+                deliver(k, [out[j] for j in piece])
+        return out, {}
 
     def pseudo_grad(
         self,
@@ -520,13 +653,29 @@ class DeviceOuterPlane:
         frag: Optional[list[int]] = None,
         *,
         keep_device: bool = False,
+        pieces: Optional[Sequence[Sequence[int]]] = None,
+        deliver=None,
     ) -> tuple[list[np.ndarray], float, Optional[list[jax.Array]]]:
         """(host f32 pseudo-gradient, ||pg||, device f32 pg or None).
 
         The D2H fetch moves wire-width bytes when the codec has a device
         pre-cast (fp16); the host widens back to f32 for the backend. The
         norm rides the same jit in every run (one extra HBM reduction)
-        instead of a serial per-leaf host dot."""
+        instead of a serial per-leaf host dot.
+
+        With ``deliver`` (the blocking boundary's stage 1) the one jit runs
+        over all leaves as ever, and ``deliver(k, host f32 arrays of
+        pieces[k])`` is called piece after piece as each lands, from the
+        thread that landed it; a piece's device pseudo-gradient is let go
+        there. Not with ``keep_device``."""
+        hand_on = None
+        if deliver is not None:
+            if keep_device:
+                raise ValueError("a piece's device arrays are let go as it lands")
+
+            def hand_on(k, arrays):
+                deliver(k, [_host_f32(x) for x in arrays])
+
         with self.lock:
             m = self._sel(self.masters, frag)
             p = list(param_leaves)
@@ -543,7 +692,9 @@ class DeviceOuterPlane:
                 pg32, sq = _pg_f32(m, p)
                 wire = pg32
             assembled = [_is_assembled(x) for x in wire]
-            fetched, self.last_fetch = self.fetch(wire, frag)
+            fetched, self.last_fetch = self.fetch(
+                wire, frag, pieces=pieces, deliver=hand_on
+            )
         # the fetched views keep their device buffers alive, so no copy —
         # EXCEPT the eager f32 case, where ``wire`` IS the kept-on-device
         # pseudo-gradient that ``_estimate_fused`` will DONATE while the
@@ -563,15 +714,19 @@ class DeviceOuterPlane:
         frag: Optional[list[int]] = None,
         sync: Optional[Sequence[jax.Array]] = None,
     ) -> Optional[list[jax.Array]]:
-        """Blocking apply: H2D the averaged pseudo-gradient and run the
-        fused, donated Nesterov step; masters/momentum rebind in place
-        under the lock. With ``sync`` (the live param leaves), the
-        params <- master overwrite rides the SAME jit — the synced leaves'
-        old buffers are donated — and the merged fresh leaves are
-        returned, saving ``sync_params``'s extra full-model pass."""
+        """Blocking apply: the fused, donated Nesterov step over the averaged
+        pseudo-gradient; masters/momentum rebind in place under the lock.
+        ``averaged`` is the round's leaves in their own order: device arrays
+        the boundary's pipeline has put back already (``PutBack``), or host
+        arrays, which are put here first. With ``sync`` (the live param
+        leaves), the params <- master overwrite rides the SAME jit — the
+        synced leaves' old buffers are donated — and the merged fresh leaves
+        are returned, saving ``sync_params``'s extra full-model pass."""
         with self.lock:
             self._ensure_bufs()
-            avg = self._h2d(averaged, frag)
+            avg = list(averaged)
+            if avg and not isinstance(avg[0], jax.Array):
+                avg = self._h2d(avg, frag)
             m = self._sel(self.masters, frag)
             b = self._sel(self.bufs, frag)
             lr, mom = self._scalars()
@@ -812,17 +967,12 @@ class DeviceOuterPlane:
         through the same two shapes above (the staleness-weighted mix
         happened host-side in gossip.py before noloco_step)."""
         with self.lock:
-            new_m = [
-                jax.device_put(np.asarray(m, np.float32), s)
-                for m, s in zip(masters_np, self._sel(self.shardings, frag))
-            ]
+            sh = self._sel(self.shardings, frag)
+            new_m = _put_leaves((np.asarray(m, np.float32) for m in masters_np), sh)
             self._put_back("masters", frag, new_m)
             if self._has_mom and bufs_np is not None:
                 self._ensure_bufs()
-                new_b = [
-                    jax.device_put(np.asarray(b, np.float32), s)
-                    for b, s in zip(bufs_np, self._sel(self.shardings, frag))
-                ]
+                new_b = _put_leaves((np.asarray(b, np.float32) for b in bufs_np), sh)
                 self._put_back("bufs", frag, new_b)
             if sync is not None:
                 p = self._sel(list(sync), frag)
@@ -845,10 +995,12 @@ class DeviceOuterPlane:
         with self.lock:
             self._ensure_ef()
             merged = list(self.ef_res)
-            for i, e in zip(idxs, host_errs):
-                merged[i] = jax.device_put(
-                    np.asarray(e, np.float32), self.shardings[i]
-                )
+            put = _put_leaves(
+                (np.asarray(e, np.float32) for e in host_errs),
+                [self.shardings[i] for i in idxs],
+            )
+            for i, d in zip(idxs, put):
+                merged[i] = d
             self.ef_res = merged
 
     # -- host boundary (serve / checkpoint / state averaging) --------------
@@ -871,15 +1023,15 @@ class DeviceOuterPlane:
             if residuals_np is None:
                 self.ef_res = None
                 return
-            self.ef_res = [
-                jax.device_put(
+            self.ef_res = _put_leaves(
+                (
                     np.zeros(m.shape, np.float32)
                     if r is None
-                    else np.asarray(r, np.float32),
-                    s,
-                )
-                for r, m, s in zip(residuals_np, self.masters, self.shardings)
-            ]
+                    else np.asarray(r, np.float32)
+                    for r, m in zip(residuals_np, self.masters)
+                ),
+                self.shardings,
+            )
 
     def host_state(
         self, refs: Optional[tuple] = None
@@ -938,23 +1090,81 @@ class DeviceOuterPlane:
                 self.momentum = float(momentum)
             if nesterov is not None:
                 self.nesterov = bool(nesterov)
-            self.masters = [
-                jax.device_put(np.array(m, dtype=np.float32), s)
-                for m, s in zip(masters_np, self.shardings)
-            ]
+            self.masters = _put_leaves(
+                (np.array(m, dtype=np.float32) for m in masters_np), self.shardings
+            )
             if bufs_np is None or self.momentum == 0.0:
                 self.bufs = None
             else:
-                self.bufs = [
-                    jax.device_put(np.array(b, dtype=np.float32), s)
-                    for b, s in zip(bufs_np, self.shardings)
-                ]
+                self.bufs = _put_leaves(
+                    (np.array(b, dtype=np.float32) for b in bufs_np), self.shardings
+                )
 
     def load_masters(self, masters_np: Sequence[np.ndarray]) -> None:
         """Adopt averaged full-state masters (average_state_every leg);
         momentum is untouched, matching the host path."""
         with self.lock:
-            self.masters = [
-                jax.device_put(np.array(m, dtype=np.float32), s)
-                for m, s in zip(masters_np, self.shardings)
-            ]
+            self.masters = _put_leaves(
+                (np.array(m, dtype=np.float32) for m in masters_np), self.shardings
+            )
+
+
+class PutBack(threading.Thread):
+    """Stage 3 of the blocking boundary's pipeline: a piece's average goes
+    back to the devices as soon as the all-reduce has returned it, while
+    later pieces are still arriving on the host.
+
+    ``submit(k, averaged)`` from the all-reduce's thread, piece after piece;
+    ``wait()`` -> the round's averaged leaves on the devices, in the leaves'
+    own order (``apply_average``'s argument), once the last piece is
+    resident there; ``drop()`` after a failed round: joined, the puts let
+    go. ``seconds``: the first put's start to the last piece resident."""
+
+    def __init__(
+        self,
+        plane: DeviceOuterPlane,
+        frag: Optional[list[int]],
+        pieces: Sequence[Sequence[int]],
+    ):
+        super().__init__(name="outer-h2d")
+        idxs = range(len(plane.masters)) if frag is None else frag
+        self._plane, self._pieces = plane, pieces
+        # the plane's leaves each piece holds (``pieces`` counts within the round)
+        self._idxs = [[idxs[j] for j in piece] for piece in pieces]
+        self._todo: queue.SimpleQueue = queue.SimpleQueue()
+        self._put: list = [None] * len(idxs)
+        self._error: Optional[BaseException] = None
+        self._t0: Optional[float] = None
+        self.seconds = 0.0
+
+    def submit(self, k: int, averaged: Sequence[np.ndarray]) -> None:
+        self._todo.put((k, averaged))
+
+    def _put_piece(self, k: int, averaged) -> None:
+        if self._t0 is None:
+            self._t0 = time.perf_counter()
+        for j, d in zip(self._pieces[k], self._plane._h2d(averaged, self._idxs[k], k)):
+            self._put[j] = d
+        self.seconds = time.perf_counter() - self._t0
+
+    def run(self) -> None:
+        try:
+            # the queue's item is let go before the next is waited for: a
+            # piece's host average goes back to its pool once it is resident
+            while (item := self._todo.get()) is not None:
+                self._put_piece(*item)
+                item = None
+        except BaseException as e:  # re-raised by wait(), in the caller
+            self._error = e
+
+    def wait(self) -> list[jax.Array]:
+        self._todo.put(None)
+        self.join()
+        if self._error is not None:
+            raise self._error
+        return self._put
+
+    def drop(self) -> None:
+        self._todo.put(None)
+        self.join()
+        self._put = []

@@ -893,7 +893,8 @@ def main() -> int:
     grads_epochs = sorted({
         int(m.group(1))
         for row in timeline if row["workers_completed"]
-        for m in [re.match(r"grads-epoch-(\d+)$", row["round"])] if m
+        # a blocking round is an all-reduce a piece: grads-p0-epoch-N, ...
+        for m in [re.match(r"grads(?:-p\d+)?-epoch-(\d+)$", row["round"])] if m
     })
     rounds_covered = bool(grads_epochs) and (
         len(grads_epochs) >= args.rounds

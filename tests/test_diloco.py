@@ -24,6 +24,7 @@ from opendiloco_tpu.diloco import (
     get_codec,
 )
 from opendiloco_tpu.diloco.compression import compress_roundtrip
+from opendiloco_tpu.diloco.optimizer import _piece_tag
 from opendiloco_tpu.parallel.mesh import build_mesh
 from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
 
@@ -253,7 +254,8 @@ def test_streaming_fragments_sync_one_fragment_per_boundary(tiny_cfg):
     world = LoopbackWorld(n_workers)
     backends = world.make_backends()
     results = [None] * n_workers
-    wire_bytes: list[list[int]] = [[] for _ in range(n_workers)]
+    # bytes a round (keyed by its epoch), in however many pieces it crossed
+    wire_bytes: list[dict[int, int]] = [{} for _ in range(n_workers)]
     errors = []
 
     def worker(rank):
@@ -272,7 +274,10 @@ def test_streaming_fragments_sync_one_fragment_per_boundary(tiny_cfg):
             inner_all_reduce = be.all_reduce
 
             def spy_all_reduce(arrays, **kw):
-                wire_bytes[rank].append(sum(a.nbytes for a in arrays))
+                seen = wire_bytes[rank]
+                seen[kw["epoch"]] = seen.get(kw["epoch"], 0) + sum(
+                    a.nbytes for a in arrays
+                )
                 return inner_all_reduce(arrays, **kw)
 
             be.all_reduce = spy_all_reduce
@@ -318,9 +323,9 @@ def test_streaming_fragments_sync_one_fragment_per_boundary(tiny_cfg):
         sum(opt0.master[i].nbytes for i in f) for f in frags
     ]
     for rank in range(n_workers):
-        assert wire_bytes[rank] == [
-            frag_bytes[e % 2] for e in range(4)
-        ], wire_bytes[rank]
+        assert wire_bytes[rank] == {
+            e: frag_bytes[e % 2] for e in range(4)
+        }, wire_bytes[rank]
 
     # final boundary (epoch 3) synced fragment 1: those device leaves sit
     # exactly on the shared master; fragment 0's leaves kept local progress
@@ -585,9 +590,12 @@ def test_fail_rank_drop_raises(tiny_cfg):
     opt = DiLoCoOptimizer(trainer, b0, cfg, state, batch_size=8)
 
     def peer1_one_round():
-        b1.all_reduce(
-            [np.zeros_like(m) for m in opt.master], timeout=30
-        )
+        pieces = opt._pieces(None)
+        for k, piece in enumerate(pieces):
+            b1.all_reduce(
+                [np.zeros_like(opt.master[j]) for j in piece],
+                tag=_piece_tag(k, len(pieces)), epoch=0, timeout=30,
+            )
         b1.close()
 
     t = threading.Thread(target=peer1_one_round)
@@ -671,6 +679,264 @@ def test_wait_for_all_times_out_and_proceeds():
     )
     dt = _time.monotonic() - t0
     assert 0.9 <= dt < 3.0  # gave up at the timeout, did not hang
+
+
+def test_no_piece_is_reduced_before_the_straggler_wait_returns(tiny_cfg, monkeypatch):
+    """Device placement: the fetch starts before ``wait_for_peers`` and
+    overlaps it, but no piece's all-reduce is entered until it has returned,
+    however early the first pieces are in hand."""
+    from opendiloco_tpu.diloco import optimizer as optimizer_mod
+    from opendiloco_tpu.diloco.backend import PeerProgress
+
+    world = LoopbackWorld(2)
+    mine, slow = world.make_backends()
+    entered, returned = [], []
+    real_all_reduce, real_wait = mine.all_reduce, optimizer_mod.wait_for_peers
+
+    def all_reduce(arrays, **kw):
+        entered.append((kw["tag"], time.monotonic()))
+        return real_all_reduce(arrays, **kw)
+
+    at_the_wait = threading.Event()
+
+    def wait_for_peers(*a, **kw):
+        at_the_wait.set()
+        real_wait(*a, **kw)
+        returned.append(time.monotonic())
+
+    mine.all_reduce = all_reduce
+    monkeypatch.setattr(optimizer_mod, "wait_for_peers", wait_for_peers)
+    trainer = make_trainer(tiny_cfg)
+    state = trainer.init_state(jax.random.key(7))
+    opt = DiLoCoOptimizer(
+        trainer, mine,
+        DilocoConfig(local_steps=1, backend="loopback", outer_placement="device",
+                     skip_load_from_peers=True, timeout_waiting_for_peers=30.0,
+                     averaging_timeout=30.0),
+        state, batch_size=8,
+    )
+    pieces = opt._pieces(None)
+    shapes = [m.shape for m in opt._plane.masters]
+
+    def slow_peer():
+        # behind for a while, then at the boundary and into every piece's round
+        assert at_the_wait.wait(60.0)
+        time.sleep(0.4)
+        slow.report_progress(PeerProgress(slow.peer_id, 0, 8, 1.0, time.time()))
+        for k, piece in enumerate(pieces):
+            slow.all_reduce([np.zeros(shapes[j], np.float32) for j in piece],
+                            tag=_piece_tag(k, len(pieces)), epoch=0, timeout=30.0)
+
+    slow.report_progress(PeerProgress(slow.peer_id, 0, 0, 1.0, time.time()))
+    peer = threading.Thread(target=slow_peer)
+    peer.start()
+    (ids, labels), = batches(0, tiny_cfg.vocab_size, 1)
+    state, row = opt.step(state, trainer.shard_batch(ids, labels, accum=1))
+    peer.join(timeout=30.0)
+    assert not peer.is_alive()
+    assert row["num_peers"] == 2 and row["outer_pieces"] == len(pieces) > 2
+    assert row["outer_wait_s"] >= 0.3  # it did wait for the peer
+    assert [t for t, _ in entered] == [f"grads-p{k}" for k in range(len(pieces))]
+    assert min(when for _, when in entered) >= returned[0]
+
+
+class _InRankOrder:
+    """A loopback backend that contributes to a round only once every lower
+    rank has: the mean's sum runs in arrival order, so pinned arrivals make
+    a galaxy's bits repeatable."""
+
+    def __init__(self, backend, rank):
+        self._b, self._rank = backend, rank
+
+    def __getattr__(self, name):
+        return getattr(self._b, name)
+
+    def all_reduce(self, arrays, *, tag="grads", epoch=None, **kw):
+        world, key = self._b.world, f"{tag}-epoch-{epoch}"
+        deadline = time.monotonic() + 60.0
+        while time.monotonic() < deadline:
+            with world.lock:
+                arrived = len(world._rounds.get(key, {}).get("contrib", {}))
+            if arrived >= self._rank:
+                break
+            time.sleep(0.0005)
+        return self._b.all_reduce(arrays, tag=tag, epoch=epoch, **kw)
+
+
+@pytest.mark.parametrize("frags", [0, 2], ids=["whole-model", "fragments"])
+def test_three_peers_pipelined_rounds_leave_the_one_piece_rounds_bits(
+    tiny_cfg, frags, monkeypatch
+):
+    """Three workers on their own data over three rounds with momentum: each
+    one's parameters, masters and momentum are bit for bit those of the same
+    galaxy held to one piece a round."""
+    from opendiloco_tpu.diloco import outer_device
+
+    trainers = [make_trainer(tiny_cfg) for _ in range(3)]
+
+    def galaxy():
+        world = LoopbackWorld(3)
+        backends = [_InRankOrder(b, r) for r, b in enumerate(world.make_backends())]
+        results, errors = [None] * 3, []
+
+        def worker(rank):
+            try:
+                trainer = trainers[rank]
+                state = trainer.init_state(jax.random.key(7))
+                opt = DiLoCoOptimizer(
+                    trainer, backends[rank],
+                    DilocoConfig(local_steps=1, backend="loopback",
+                                 outer_placement="device", streaming_fragments=frags,
+                                 timeout_waiting_for_peers=30.0, averaging_timeout=60.0),
+                    state, batch_size=8,
+                )
+                pieces = []
+                for ids, labels in batches(1000 + rank, tiny_cfg.vocab_size, 3):
+                    state, m = opt.step(state, trainer.shard_batch(ids, labels, accum=1))
+                    pieces.append(m["outer_pieces"])
+                masters, bufs = opt._plane.host_state()
+                results[rank] = (
+                    masters + bufs + jax.device_get(jax.tree.leaves(state["params"])),
+                    pieces,
+                )
+            except Exception as e:  # pragma: no cover
+                errors.append(e)
+
+        threads = [threading.Thread(target=worker, args=(r,)) for r in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert not errors, errors
+        assert all(r is not None for r in results)
+        return results, world
+
+    pipelined, world = galaxy()
+    assert world._outputs.keep == 6
+    monkeypatch.setattr(
+        outer_device, "cut_pieces", lambda nbytes: [list(range(len(nbytes)))]
+    )
+    one_piece, _ = galaxy()
+    for (got, pieces), (want, pieces_1) in zip(pipelined, one_piece):
+        assert pieces_1 == [1, 1, 1] and min(pieces) > 2
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+class _Killed(Exception):
+    pass
+
+
+@pytest.mark.parametrize("case", ["healthy", "a-peer-dies-between-pieces"])
+def test_device_rounds_over_tcp(tiny_cfg, case, monkeypatch):
+    """The boundary's pieces over the wire a deployment runs: real sockets, a
+    rendezvous, a matchmaking a piece. Healthy (two workers, two rounds):
+    every piece finds both, the health row says nothing happened, and each
+    worker is left the bits of the same galaxy held to one piece -- which
+    also says that no piece's result (a view of a buffer the backend reclaims
+    by tag) was written while the way back still read it. A peer that dies
+    after the first piece of a round (three workers): the survivors finish
+    the round with the smaller group for the later pieces, as the elastic
+    round it is, bit-equal to each other, and run the next round as two."""
+    from opendiloco_tpu.diloco import outer_device
+    from opendiloco_tpu.diloco.rendezvous import RendezvousServer
+    from opendiloco_tpu.diloco.tcp import TcpBackend
+
+    dies = case != "healthy"
+    n = 3 if dies else 2
+    trainers = [make_trainer(tiny_cfg) for _ in range(n)]
+
+    def galaxy():
+        server = RendezvousServer(host="127.0.0.1", port=0).start_in_thread()
+        backends = [
+            TcpBackend([server.address], peer_id=f"worker-{i}", matchmaking_time=2.0)
+            for i in range(n)
+        ]
+        results, errors = [None] * n, []
+
+        def worker(rank):
+            backend = backends[rank]
+            try:
+                if dies and rank == n - 1:
+                    all_reduce, calls = backend.all_reduce, []
+
+                    def mortal(arrays, **kw):
+                        if calls:  # the first piece went through
+                            backend.close()
+                            raise _Killed()
+                        calls.append(kw["tag"])
+                        return all_reduce(arrays, **kw)
+
+                    backend.all_reduce = mortal
+                trainer = trainers[rank]
+                state = trainer.init_state(jax.random.key(7))
+                opt = DiLoCoOptimizer(
+                    trainer, backend,
+                    DilocoConfig(local_steps=1, backend="tcp",
+                                 outer_placement="device", skip_load_from_peers=True,
+                                 timeout_waiting_for_peers=30.0, averaging_timeout=30.0),
+                    state, batch_size=8,
+                )
+                rows = []
+                for ids, labels in batches(1000 + rank, tiny_cfg.vocab_size, 2):
+                    state, m = opt.step(state, trainer.shard_batch(ids, labels, accum=1))
+                    rows.append(m)
+                masters, bufs = opt._plane.host_state()
+                results[rank] = (
+                    masters + bufs + jax.device_get(jax.tree.leaves(state["params"])),
+                    rows, list(backend.round_ledger), opt.epoch,
+                )
+            except _Killed:
+                results[rank] = "killed"
+            except Exception as e:  # pragma: no cover
+                errors.append(e)
+            finally:
+                backend.close()
+
+        threads = [threading.Thread(target=worker, args=(r,)) for r in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        server.stop()
+        assert not errors, errors
+        assert all(r is not None for r in results)
+        return results
+
+    cut = galaxy()
+    n_pieces = cut[0][1][0]["outer_pieces"]
+    assert n_pieces > 2
+    tags = [_piece_tag(k, n_pieces) for k in range(n_pieces)]
+    if dies:
+        assert cut[-1] == "killed"
+        (bits_a, rows_a, ledger_a, epoch_a), (bits_b, rows_b, _, epoch_b) = cut[:2]
+        assert epoch_a == epoch_b == 2
+        for rows in (rows_a, rows_b):
+            assert [r["num_peers"] for r in rows] == [2, 2]
+            assert rows[0]["elastic"] is True and rows[0]["expected_peers"] == 3
+        # the round the peer died in: three for the first piece, two after
+        assert [h["group_size"] for h in ledger_a[:n_pieces]] == [3] + [2] * (n_pieces - 1)
+        for a, b in zip(bits_a, bits_b):
+            assert a.tobytes() == b.tobytes()
+        return
+    for _, rows, ledger, epoch in cut:
+        assert epoch == 2
+        assert [r["num_peers"] for r in rows] == [2, 2]
+        assert not any(r.get("elastic") or r.get("round_retries") for r in rows)
+        # a matchmaking a piece, each under the piece's own tag
+        assert [h["round"] for h in ledger] == [
+            f"{t}-epoch-{e}" for e in range(2) for t in tags
+        ]
+    monkeypatch.setattr(
+        outer_device, "cut_pieces", lambda nbytes: [list(range(len(nbytes)))]
+    )
+    whole = galaxy()
+    for (got, _, _, _), (want, rows, ledger, _) in zip(cut, whole):
+        assert [r["outer_pieces"] for r in rows] == [1, 1]
+        assert [h["round"] for h in ledger] == ["grads-epoch-0", "grads-epoch-1"]
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
 
 
 def test_hash_pytree_and_schema():

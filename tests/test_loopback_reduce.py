@@ -402,3 +402,45 @@ def test_stress_many_peers_rounds_and_held_results():
     assert not errors, errors[:3]
     assert not any(t.is_alive() for t in threads)
     assert not world._rounds
+
+
+@pytest.mark.parametrize("n", (1, 3))
+def test_a_rounds_pieces_keep_arrays_of_their_own(n):
+    """The blocking boundary calls once a piece, a tag a piece, every call's
+    arrays starting at index 0 and several of one shape: more calls a round
+    than the pool keeps a position. While the way back (or the caller) holds
+    a piece's result no later piece's call and no later round writes it, and
+    from the second round on nothing new is allocated."""
+    world = LoopbackWorld(n)
+    backends = world.make_backends()
+    pieces = world._outputs.keep + 3
+
+    def one_round(r, held):
+        results = []
+        for k in range(pieces):
+            inputs = [[np.full((4096,), 100.0 * r + 10 * k + i, np.float32)]
+                      for i in range(n)]
+            results.append(_run_round(backends, inputs, ordered=False,
+                                      tag=f"grads-p{k}", epoch=r))
+        # every piece's result still holds its own mean at the round's end
+        for k, out in enumerate(results):
+            mean = np.float32(100.0 * r + 10 * k + (n - 1) / 2)
+            for arrays, group in out:
+                assert group == n and (arrays[0] == mean).all()
+        for arrays, snapshot in held:
+            assert arrays[0].tobytes() == snapshot
+        return results
+
+    first = one_round(0, [])
+    after_first = world._outputs.new_bytes
+    assert after_first == pieces * n * 4096 * 4
+    held = [(arrays, arrays[0].tobytes()) for arrays, _ in first[1]]  # piece 1's
+    del first
+    for r in (1, 2, 3):
+        one_round(r, held)
+    # the held piece cost its tag one more array a collector, once
+    assert world._outputs.new_bytes == after_first + n * 4096 * 4
+    del held
+    grown = world._outputs.new_bytes
+    one_round(4, [])
+    assert world._outputs.new_bytes == grown

@@ -531,31 +531,52 @@ def test_arming_the_capture_compiles_nothing(tiny_cfg, placement):
 
 @pytest.mark.parametrize("placement", ["device", "host", "device-sharded"])
 def test_boundary_row_splits_the_step(tiny_cfg, placement):
+    import jax
+
     state, one_round = _diloco_worker(tiny_cfg, placement)
     obs.capture.start()
-    _, row = one_round(state)
+    state, row = one_round(state)
     cap = obs.capture.stop()
-    parts = row["outer_d2h_s"] + row["outer_allreduce_s"] + row["outer_apply_s"]
     assert min(row["outer_d2h_s"], row["outer_apply_s"]) > 0
-    assert parts <= row["outer_step_s"]
-    # the d2h span is the fetch's own interval, recorded in the fetch thread
-    (d2h,) = _named(cap, "outer/d2h")
     (step,) = _named(cap, "outer/step")
-    assert d2h["t1"] - d2h["t0"] == pytest.approx(row["outer_d2h_s"], abs=1e-9)
-    assert d2h["tid"] != step["tid"]
-    assert step["t0"] <= d2h["t0"] and d2h["t1"] <= step["t1"]
-    if placement != "host":
-        # the H2D of the average is cut out of the apply that holds it
-        (h2d,) = _named(cap, "outer/h2d")
-        (apply_,) = _named(cap, "outer/apply")
-        assert apply_["t0"] <= h2d["t0"] and h2d["t1"] <= apply_["t1"]
+    d2h = _named(cap, "outer/d2h")
+    # the d2h spans are the fetch's own interval, recorded in the fetch thread
+    assert d2h[-1]["t1"] - d2h[0]["t0"] == pytest.approx(row["outer_d2h_s"], abs=1e-9)
+    assert all(s["tid"] != step["tid"] for s in d2h)
+    assert step["t0"] <= d2h[0]["t0"] and d2h[-1]["t1"] <= step["t1"]
+    if placement == "host":
+        # one stage after another: the parts add up to less than the step
+        parts = row["outer_d2h_s"] + row["outer_allreduce_s"] + row["outer_apply_s"]
+        assert parts <= row["outer_step_s"] and len(d2h) == 1
+        assert "new_bytes" not in d2h[0]["args"] and "outer_d2h_new_bytes" not in row
+        assert "outer_pieces" not in row
+        return
+    # the device boundary's three stages run side by side, a piece at a time:
+    # one span a piece and stage, the fetch's tiling its interval
+    n = row["outer_pieces"]
+    assert n > 1 and row["outer_h2d_s"] > 0
+    reduces, puts = _named(cap, "outer/allreduce"), _named(cap, "outer/h2d")
+    (apply_,) = _named(cap, "outer/apply")
+    for spans in (d2h, reduces, puts):
+        assert [s["args"]["piece"] for s in spans] == list(range(n))
+        assert all(s["args"]["bytes"] > 0 for s in spans)
+    assert all(a["t1"] == b["t0"] for a, b in zip(d2h, d2h[1:]))
+    model_bytes = sum(x.nbytes for x in jax.tree.leaves(state["params"]))
+    for spans in (d2h, reduces, puts):
+        assert sum(s["args"]["bytes"] for s in spans) == model_bytes
+    for got, reduced, put in zip(d2h, reduces, puts):
+        # a piece is reduced once it has landed and put once it is reduced
+        assert got["t1"] <= reduced["t0"] and reduced["t1"] <= put["t0"]
+    assert row["outer_allreduce_s"] == pytest.approx(
+        sum(s["t1"] - s["t0"] for s in reduces), abs=1e-9)
+    assert row["outer_h2d_s"] == pytest.approx(puts[-1]["t1"] - puts[0]["t0"], abs=1e-3)
+    # the apply starts with the last piece's average in hand
+    assert reduces[-1]["t1"] <= apply_["t0"] and puts[-1]["t1"] <= apply_["t1"]
     if placement == "device-sharded":
-        # what the fetch assembled on the host: on the span and in the row
-        args = d2h["args"]
-        assert args["shards"] > 0 and args["bytes"] == args["new_bytes"] > 0
-        assert row["outer_d2h_new_bytes"] == args["new_bytes"]
+        # what the fetch assembled on the host, all of it new in a first round
+        assert 0 < row["outer_d2h_new_bytes"] <= model_bytes
     else:  # nothing to assemble: jax.device_get, and nothing to report
-        assert "new_bytes" not in d2h["args"] and "outer_d2h_new_bytes" not in row
+        assert "outer_d2h_new_bytes" not in row
 
 
 def test_reduce_wait_is_waiting_and_reduce_is_the_mean():

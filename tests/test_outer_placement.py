@@ -233,6 +233,79 @@ def test_multiworker_parity(tiny_cfg):
             np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("frags", [0, 2], ids=["whole-model", "fragments"])
+def test_a_galaxy_of_both_placements_meets_piece_for_piece(tiny_cfg, frags, monkeypatch):
+    """One host worker and one device worker: every blocking round finds both
+    in every piece's all-reduce, under the same tags in the same order, and
+    each worker is left the bits of the same galaxy held to one piece a round
+    (the wire either placement had before it was cut)."""
+    from opendiloco_tpu.diloco import outer_device
+
+    trainers = [make_trainer(tiny_cfg) for _ in range(2)]
+
+    def galaxy():
+        world = LoopbackWorld(2)
+        backends = world.make_backends()
+        results, tags, errors = [None, None], [[], []], []
+
+        def worker(rank):
+            try:
+                trainer, backend = trainers[rank], backends[rank]
+                all_reduce = backend.all_reduce
+
+                def recorded(arrays, *, tag="grads", **kw):
+                    tags[rank].append((tag, [a.shape for a in arrays]))
+                    return all_reduce(arrays, tag=tag, **kw)
+
+                backend.all_reduce = recorded
+                state = trainer.init_state(jax.random.key(7))
+                opt = DiLoCoOptimizer(
+                    trainer, backend,
+                    DilocoConfig(local_steps=1, backend="loopback",
+                                 outer_placement=("host", "device")[rank],
+                                 streaming_fragments=frags,
+                                 timeout_waiting_for_peers=30.0, averaging_timeout=20.0),
+                    state, 8,
+                )
+                rows = []
+                for ids, labels in batches(1000 + rank, tiny_cfg.vocab_size, 3):
+                    state, m = opt.step(state, trainer.shard_batch(ids, labels, accum=1))
+                    rows.append(m)
+                sd = opt.state_dict()
+                results[rank] = (
+                    list(sd["master"]) + list(sd["outer_opt"]["bufs"])
+                    + jax.device_get(jax.tree.leaves(state["params"])),
+                    rows,
+                )
+            except Exception as e:  # pragma: no cover
+                errors.append(e)
+
+        threads = [threading.Thread(target=worker, args=(r,)) for r in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert not errors, errors
+        assert all(r is not None for r in results)
+        return results, tags
+
+    cut, tags = galaxy()
+    assert tags[0] == tags[1] and len({t for t, _ in tags[0]}) > 2
+    for _, rows in cut:
+        assert [r["num_peers"] for r in rows] == [2, 2, 2]
+        assert not any(r.get("elastic") for r in rows)
+    monkeypatch.setattr(
+        outer_device, "cut_pieces", lambda nbytes: [list(range(len(nbytes)))]
+    )
+    whole, tags_1 = galaxy()
+    assert {t for t, _ in tags_1[0] + tags_1[1]} == {"grads"}
+    for (got, _), (want, _) in zip(cut, whole):
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
 # ---------------------------------------------------------------------------
 # state_dict / serve / checkpoint interop across placements
 # ---------------------------------------------------------------------------
