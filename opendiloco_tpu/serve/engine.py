@@ -29,6 +29,18 @@ blocking ``admit`` is the two halves and the logits row: the same jitted
 programs whichever way. ``admissions_deferred`` counts the admissions a
 step fed on the device.
 
+A decode step comes the same way. ``decode_step`` blocks: it enqueues the
+step and reads it. ``step_ahead`` is what a loop calls once an iteration: it
+enqueues the next step first and then reads the one enqueued by the call
+before it, so the chip finds the next step queued when the one before ends.
+A slot that rides both takes its token from the earlier step's output, which
+stays on the device (``prev``, as ``first`` for an admission's token): the
+same jitted program whichever way, and ``steps_ahead`` counts the steps that
+were enqueued while the step before them was unread. Either call reads the
+admissions that the step it reads fed on the device, before that step's tokens:
+the prompts enqueued between two steps are read by the call after the one
+that reads the first of the two.
+
 Prefix reuse (scheduler-driven, off by default): ``admit(..., prefix_src,
 prefix_len)`` ring-copies a live slot's prefix K/V and prefills only the
 suffix (the continued prefill).
@@ -134,6 +146,9 @@ def _with_counts(tok, counts):
 # program in place of a token: the program takes the slot's entry of the
 # engine's first-token vector instead (no token id is negative)
 FIRST_TOKEN_ON_DEVICE = -1
+# and of a slot whose newest token is the output of a decode step that has not
+# been read: the program takes the slot's entry of that step's tokens
+PREV_TOKEN_ON_DEVICE = -2
 
 
 def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: bool = False):
@@ -157,7 +172,12 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     The first token never has to reach the host before the step that reads
     it: ``admit_insert`` writes it at ``slot`` into the ``[S]`` vector
     ``first`` beside the prompt's rows, and ``decode`` takes ``first[slot]``
-    wherever ``tokens[slot]`` is ``FIRST_TOKEN_ON_DEVICE``."""
+    wherever ``tokens[slot]`` is ``FIRST_TOKEN_ON_DEVICE``. Nor does a step's
+    token before the next step reads it: ``decode`` takes ``prev[slot]``, the
+    token output of the step before as that step returned it (a routed model's
+    counts still behind its ``[S]`` tokens), wherever ``tokens[slot]`` is
+    ``PREV_TOKEN_ON_DEVICE``. A slot's token may come from the host, from
+    ``first`` or from ``prev`` in one and the same step."""
     cd, dkn = compute_dtype, decode_kernel
     moe = bool(cfg.num_experts)
     n_state = 1 if cfg.cca else 3 if cfg.eva else 2 if cfg.hybrid else 0
@@ -180,8 +200,11 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
         # reads it, and no second program has to cut it out after the first read
         return (_with_counts(tok, counts), logits[0], ks, vs, *left, *rest[n_state + 1 :])
 
-    def decode(p, tokens, lens, first, ck, cv, *ssm):
+    def decode(p, tokens, lens, first, ck, cv, *ssm, prev=None):
         with jax.named_scope("odtp_serve_decode"):
+            if prev is not None:
+                held = prev[: tokens.shape[0]]
+                tokens = jnp.where(tokens == PREV_TOKEN_ON_DEVICE, held, tokens)
             tokens = jnp.where(tokens == FIRST_TOKEN_ON_DEVICE, first, tokens)
             state = {"eva_state": ssm} if cfg.eva else dict(zip(state_names, ssm))
             logits, ck, cv, *rest = decode_forward(
@@ -213,19 +236,26 @@ class _DecodeProgram:
     cache_k, cache_v, *state)``, for whoever lowers, traces or calls it from
     outside the engine (the benchmark's drivers name a scope's instructions
     from the text of ``_decode.lower(...)``, which has to be the program that
-    ran). The engine passes ``first``; without it no slot's token is there."""
+    ran). The engine passes ``first`` and ``prev``, the token output of the
+    step before (``counts`` entries longer than the slots for a routed model);
+    without them no slot's token is there."""
 
-    def __init__(self, decode, carried: int):
+    def __init__(self, decode, carried: int, counts: int = 0):
         self.jitted = jax.jit(decode, donate_argnums=tuple(range(4, 4 + carried)))
+        self.counts = counts
 
-    def __call__(self, p, tokens, lens, *carried, first=None):
+    def __call__(self, p, tokens, lens, *carried, first=None, prev=None):
+        slots = np.shape(tokens)[0]
         if first is None:
-            first = jnp.zeros(np.shape(tokens), jnp.int32)
-        return self.jitted(p, tokens, lens, first, *carried)
+            first = jnp.zeros((slots,), jnp.int32)
+        if prev is None:
+            prev = jnp.zeros((slots + self.counts,), jnp.int32)
+        return self.jitted(p, tokens, lens, first, *carried, prev=prev)
 
     def lower(self, p, tokens, lens, *carried):
         first = jax.ShapeDtypeStruct(tokens.shape, jnp.int32)
-        return self.jitted.lower(p, tokens, lens, first, *carried)
+        prev = jax.ShapeDtypeStruct((tokens.shape[0] + self.counts,), jnp.int32)
+        return self.jitted.lower(p, tokens, lens, first, *carried, prev=prev)
 
     def _cache_size(self) -> int:
         return self.jitted._cache_size()
@@ -248,6 +278,19 @@ class Admission:
     t_dispatch: float
     token: Optional[int] = None
     t_token: Optional[float] = None
+    fed: bool = False  # a decode step that takes the token on the device is enqueued
+
+
+@dataclasses.dataclass(eq=False)
+class _Step:
+    """A decode step whose program is enqueued and whose tokens are not read."""
+
+    tokd: jax.Array  # its tokens, then a routed model's counts
+    lens: np.ndarray  # the positions it was enqueued with: what its counters count
+    fed: list  # the admissions whose first tokens it took on the device
+    t0: float
+    t_args: float
+    t_dispatch: float
 
 
 # snapshot_fn contract: () -> (epoch, blobs, codec_name) with blobs[i] =
@@ -315,8 +358,8 @@ class ServeEngine:
             stage: {k: 0.0 for k in _PHASES} for stage in ("prefill", "decode")
         }
         self.phase_calls = {"prefill": 0, "decode": 0}
-        # (t0, t1) of the last ``decode_step``, for the loop to tile its own
-        # phases against
+        # (t0, t1) of the last ``decode_step`` or ``step_ahead``, for the loop
+        # to tile its own phases against
         self.decode_bounds = (0.0, 0.0)
         # what a routed FFN did in the prefills and decode steps so far, each
         # summed over layers and calls (always on; stay 0 for a dense model):
@@ -429,6 +472,15 @@ class ServeEngine:
         # admissions whose first token a decode step took on the device,
         # beside all cold admissions in ``phase_calls["prefill"]``
         self.admissions_deferred = 0
+        # the token output of the newest decode step, on the device, where the
+        # next step finds the tokens of the slots that ride both; the step that
+        # ``step_ahead`` enqueued and has not read; and the steps it enqueued
+        # while the step before them was unread, beside all steps in
+        # ``phase_calls["decode"]``
+        counts = cfg.moe_counts if cfg.num_experts else 0
+        self._prev = jnp.zeros((self.num_slots + counts,), jnp.int32)
+        self._ahead: Optional[_Step] = None
+        self.steps_ahead = 0
 
         def programs(chosen: bool):
             prefill, decode, admit_insert, carried = serving_programs(
@@ -437,7 +489,7 @@ class ServeEngine:
             # one compile per prompt bucket (prefill, insert); decode compiles once
             return (
                 jax.jit(prefill),
-                _DecodeProgram(decode, carried),
+                _DecodeProgram(decode, carried, counts),
                 jax.jit(admit_insert, donate_argnums=tuple(range(3 + len(self._eva)))),
             )
 
@@ -651,16 +703,20 @@ class ServeEngine:
         return logits
 
     def _count_phases(self, stage: str, bounds: tuple, tr) -> None:
-        """One call's three phases, ``bounds`` each one's start and end -> the
-        engine's counters, and spans where ``tr`` is an armed tracer: they tile
-        the front of a blocking call's ``serve_prefill`` / ``serve_decode``,
-        and leave between dispatch and fetch whatever else was read meanwhile."""
+        """One call's three phases, ``bounds`` each one's start and end (None:
+        the call had no such phase; it counts as a call where it fetched) ->
+        the engine's counters, and spans where ``tr`` is an armed tracer: they
+        tile the front of a blocking call's ``serve_prefill`` /
+        ``serve_decode``, and leave between dispatch and fetch whatever else
+        was read meanwhile."""
         total = self.phase_seconds[stage]
-        for phase, (t0, t1) in zip(_PHASES, bounds):
-            total[phase] += t1 - t0
+        for phase, bound in zip(_PHASES, bounds):
+            if bound is None:
+                continue
+            total[phase] += bound[1] - bound[0]
             if tr is not None:
-                tr.add_span(f"serve_{phase}", t0, t1, stage=stage)
-        self.phase_calls[stage] += 1
+                tr.add_span(f"serve_{phase}", *bound, stage=stage)
+        self.phase_calls[stage] += bounds[-1] is not None
 
     def _count_ssm(self, tokens: int, state_bytes: int) -> dict:
         """Add one call's Mamba-2 work to the engine's counters -> the same
@@ -850,8 +906,58 @@ class ServeEngine:
         admission is read here, between the step's dispatch and the step's own
         read (``Admission.token``, ``t_token``): the step's ``fetch`` phase and
         ``stage_seconds["decode"]`` start again after those reads."""
+        step, logits = self._enqueue_step(tokens, lens)
+        return self._finish_step(step, step), logits
+
+    def step_ahead(
+        self, tokens: Optional[np.ndarray] = None, lens: Optional[np.ndarray] = None
+    ) -> Optional[np.ndarray]:
+        """A loop's one decode call an iteration: ``decode_step`` whose read
+        lags a step. It enqueues the step over ``tokens`` / ``lens`` (None: no
+        step), *then* reads the step the call before this one enqueued -> that
+        step's tokens [S], or None where no step was waiting to be read. The
+        chip finds the new step queued when the earlier one ends.
+
+        ``tokens[slot]`` is ``PREV_TOKEN_ON_DEVICE`` for a slot whose token the
+        unread step is computing: the program takes it from that step's output
+        on the device. The admissions enqueued since the last step are fed
+        theirs as in ``decode_step``; they lie, on the device, behind the step
+        this call reads and before the one it enqueues, and are read by the
+        *next* call, before the tokens of the step that fed them and after
+        those of the step they lie behind: every read in the order the chip
+        finishes, none a wait for a program enqueued after what it reads.
+
+        The call's ``serve_decode`` span and ``stage_seconds["decode"]`` hold
+        the ``args`` and ``dispatch`` of the step enqueued and the ``fetch`` of
+        the step read; the span's attributes, the engine's counters and
+        ``phase_calls["decode"]`` are the step's that was read (a call that
+        reads none has its two phases, no span and no count)."""
+        read = self._ahead
+        self._ahead = None
+        if tokens is None:
+            return None if read is None else self._finish_step(read, None)
+        # a loop reads tokens: the logits need not outlive the program
+        self._ahead, _ = self._enqueue_step(tokens, lens)
+        if read is not None:
+            self.steps_ahead += 1
+            obs.count("serve_steps_ahead")
+            return self._finish_step(read, self._ahead)
+        step = self._ahead
+        self.stage_seconds["decode"] += step.t_dispatch - step.t0
+        self.decode_bounds = (step.t0, step.t_dispatch)
+        self._count_phases(
+            "decode", ((step.t0, step.t_args), (step.t_args, step.t_dispatch), None),
+            obs.tracer(),
+        )
+        return None
+
+    def _enqueue_step(self, tokens: np.ndarray, lens: np.ndarray) -> tuple[_Step, jax.Array]:
+        """Make a decode step's arguments and enqueue its program; nothing is
+        read -> (the step, its logits [S, V] on the device). The slots of the
+        admissions enqueued since the step before take their tokens from
+        ``first``."""
         t0 = time.perf_counter()
-        fed = self._unread[:]
+        fed = [adm for adm in self._unread if not adm.fed]
         if fed:  # their tokens are where the program finds them: on the device
             tokens = np.array(tokens, np.int32)
             tokens[[adm.slot for adm in fed]] = FIRST_TOKEN_ON_DEVICE
@@ -859,35 +965,54 @@ class ServeEngine:
         t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *state = self._decode(
             self.params, tokensd, lensd, self.cache_k, self.cache_v,
-            *self._ssm, *self._cca, *self._eva, first=self._first,
+            *self._ssm, *self._cca, *self._eva, first=self._first, prev=self._prev,
         )
+        self._prev = tok
         if self._keeps_choices:
             self.expert_choices = state.pop()
         if self._eva:
             self._eva = tuple(state)
         else:
             self._ssm, self._cca = tuple(state[: len(self._ssm)]), tuple(state[len(self._ssm):])
-        t_fetch = t_dispatch = time.perf_counter()
-        # with the step enqueued behind them, the admissions' tokens are read,
-        # each a wait for its own program and no more; then the step's
-        for adm in fed:
-            self._read(adm, t_from=t_fetch)
-            t_fetch = time.perf_counter()
-        self.admissions_deferred += len(fed)
-        fetched = np.asarray(tok)
+        if fed:
+            for adm in fed:
+                adm.fed = True
+            self.admissions_deferred += len(fed)
+            obs.count("serve_admissions_deferred", len(fed))
+        step = _Step(
+            tokd=tok, lens=np.array(lens, np.int32), fed=fed,
+            t0=t0, t_args=t_args, t_dispatch=time.perf_counter(),
+        )
+        return step, logits
+
+    def _finish_step(self, read: _Step, enqueued: Optional[_Step]) -> np.ndarray:
+        """The back of a decode call whose front enqueued ``enqueued`` (None:
+        no step): read the admissions ``read`` fed on the device, which lie
+        before it there, each a wait for its own programs and no more
+        (``Admission.token``, ``t_token``), then ``read``'s tokens; count what
+        that step did, and close the call's ``serve_decode`` span over
+        ``enqueued``'s ``args`` and ``dispatch`` and the step's own ``fetch``,
+        which starts after the admissions' reads, as ``stage_seconds["decode"]``
+        does -> the tokens [S]."""
+        if enqueued is None:
+            t0 = t_dispatch = time.perf_counter()
+        else:
+            t0, t_dispatch = enqueued.t0, enqueued.t_dispatch
+        t_fetch = t_dispatch
+        for adm in read.fed:
+            if adm.token is None:  # else ``admit_resolve`` has read it
+                self._read(adm, t_from=t_fetch)
+                t_fetch = time.perf_counter()
+        fetched = np.asarray(read.tokd)
         t_fetched = time.perf_counter()
         tok, moe = self._split_counts(fetched, self.num_slots)
-        moe.update(
-            self._count_ssm(int(np.count_nonzero(lens)), 2 * self.ssm_state_resident_bytes)
-        )
-        moe.update(
-            self._count_cca(int(np.count_nonzero(lens)), 2 * self.cca_state_resident_bytes)
-        )
+        lens = read.lens
+        held = lens[lens > 0]
+        moe.update(self._count_ssm(held.size, 2 * self.ssm_state_resident_bytes))
+        moe.update(self._count_cca(held.size, 2 * self.cca_state_resident_bytes))
         if self._latent_row_bytes:
             # a live slot's rows [0, lens] (the ring's T once it has wrapped),
             # the step's own among them
-            held = np.asarray(lens)
-            held = held[held > 0]
             moe.update(self._count_latent(
                 read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size
             ))
@@ -897,20 +1022,16 @@ class ServeEngine:
         self.stage_seconds["decode"] += (t1 - t0) - (t_fetch - t_dispatch)
         self.decode_bounds = (t0, t1)
         tr = obs.tracer()
-        if fed:
-            obs.count("serve_admissions_deferred", len(fed))
         if tr is not None:
             tr.count(f"serve_decode_kernel_{self.decode_kernel}")
             # what the step's attention read: the cache rows of the live
             # slots (``lens`` is 0 for an empty slot)
-            tr.add_span(
-                "serve_decode", t0, t1,
-                rows=int(np.sum(lens)), slots=int(np.count_nonzero(lens)), **moe,
-            )
-        self._count_phases(
-            "decode", ((t0, t_args), (t_args, t_dispatch), (t_fetch, t_fetched)), tr
+            tr.add_span("serve_decode", t0, t1, rows=int(lens.sum()), slots=held.size, **moe)
+        front = (None, None) if enqueued is None else (
+            (enqueued.t0, enqueued.t_args), (enqueued.t_args, enqueued.t_dispatch)
         )
-        return tok, logits
+        self._count_phases("decode", (*front, (t_fetch, t_fetched)), tr)
+        return tok
 
     # -- kernel attribution -------------------------------------------------
 

@@ -7,8 +7,21 @@ batch), take one decode step for every live slot, and between decode
 steps give the engine a chance to hot-swap weights. An iteration's programs
 are all enqueued before the host reads any of them: a cold admission leaves
 its first token on the device, the step behind it takes it there, and the
-reads follow in the order the chip finishes them (each first token, stamped
-``t_first`` as it lands, then the step's tokens). Requests are queued
+reads follow in the order the chip finishes them. The loop also keeps one
+decode step ahead of the host: an iteration enqueues the next step, fed the
+tokens of the step before on the device, *before* it reads that step's tokens,
+so the chip has the next step (and, before it, this iteration's prefills)
+queued when a step ends. The first tokens of the prompts a step fed lie before
+that step on the device and are read with it, each stamped ``t_first`` as it
+lands, just before the step's own tokens; the iteration then emits the step,
+retiring what it finished, and waits for nothing that was enqueued after it.
+The next iteration's admission pass follows the emit at once, as it followed
+a blocking step's. Who rides the next
+step is said before the tokens are in hand: a request that the token in flight
+ends by length does not, one that may end on an ``eos_id`` does and has its
+row dropped if it did. Whatever needs the newest tokens on the host, or a
+cache that no program is writing, first reads and emits the step in flight
+(``_drain``). Requests are queued
 by any thread via :meth:`ContinuousBatcher.submit` and signal completion
 through a per-request event; nothing is ever dropped by the scheduler —
 a request either completes, is rejected at submit time (prompt too long
@@ -28,7 +41,7 @@ import numpy as np
 from opendiloco_tpu import obs
 from opendiloco_tpu.models.ring_cache import ring_live_rows
 from opendiloco_tpu.obs import reqtrace
-from opendiloco_tpu.serve.engine import Admission, ServeEngine
+from opendiloco_tpu.serve.engine import PREV_TOKEN_ON_DEVICE, Admission, ServeEngine
 from opendiloco_tpu.serve.kvcache import (
     HostKVTier,
     SlotAllocator,
@@ -109,6 +122,19 @@ class _Slot:
 
 
 @dataclasses.dataclass
+class _Rows:
+    """A decode step that is enqueued and unread: the tenant each of its rows
+    was enqueued for. A row goes to that ``_Slot`` object if it still holds the
+    slot when the tokens arrive, and to no other."""
+
+    tenants: dict  # slot id -> _Slot
+    # of those, the ones admitted since the step before: their first tokens lie
+    # before this step on the device and reach the host with its tokens
+    first: list  # [(slot id, _Slot)]
+    epoch: int  # the weights the step was enqueued under
+
+
+@dataclasses.dataclass
 class _Paused:
     """A live request whose ring page lives in the host tier: everything
     needed to resume decode exactly where it stopped, minus the K/V
@@ -185,9 +211,15 @@ class ContinuousBatcher:
         self._kernel_probed = obs.tracer() is None
         self.slots = SlotAllocator(engine.num_slots)
         self._active: dict[int, _Slot] = {}  # slot id -> state
-        # slots admitted since the last decode step whose first token that
-        # step will feed on the device (loop thread only)
-        self._awaiting: list[int] = []
+        # (slot, tenant) admitted since the last decode step was enqueued, whose
+        # first token the next step will feed on the device (loop thread only)
+        self._awaiting: list[tuple[int, _Slot]] = []
+        # the decode step in flight: enqueued, its tokens not read (loop thread only)
+        self._ahead: Optional[_Rows] = None
+        # times the loop had to read and emit the step in flight before it
+        # could act (always on), by what asked: "evict", "resume",
+        # "continued_prefill", "stop", "failure"
+        self.step_drains: collections.Counter = collections.Counter()
         self._queue: collections.deque[Request] = collections.deque()
         self._cond = threading.Condition()
         self._stop = threading.Event()
@@ -202,7 +234,9 @@ class ContinuousBatcher:
         # call: batch assembly, and emit through the last retire
         self.batch_seconds = 0.0
         self.emit_seconds = 0.0
-        self._t_step_end: Optional[float] = None
+        # where the request-ring spans of the next step emitted start: the end
+        # of the emit before it while iterations follow one another
+        self._t_window: Optional[float] = None
         # stats (mutated only by the loop thread; read racily for gauges)
         self.completed = 0
         self.rejected = 0
@@ -388,8 +422,8 @@ class ContinuousBatcher:
         self._paused.clear()
 
     def drain(self, timeout: float = 60.0) -> bool:
-        """Block until queue, batch, and cold tier are empty (bench
-        teardown)."""
+        """Block until queue, batch, and cold tier are empty and no decode
+        step is in flight (bench teardown)."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             with self._cond:
@@ -398,6 +432,7 @@ class ContinuousBatcher:
                     and not self._active
                     and not self._paused
                     and not self._pending_evict
+                    and self._ahead is None
                 ):
                     return True
             time.sleep(0.01)
@@ -407,7 +442,6 @@ class ContinuousBatcher:
 
     def _run(self) -> None:
         try:
-            t_carry = None
             while not self._stop.is_set():
                 # consecutive decode spans TILE: each starts where the
                 # previous iteration's accounting ended, so everything an
@@ -416,21 +450,23 @@ class ContinuousBatcher:
                 # gauges — is attributed to its decode residency and a
                 # trace's stage sums reconcile with its e2e latency
                 t_iter = time.perf_counter()
-                it0 = t_carry if t_carry is not None else t_iter
+                if self._t_window is None:
+                    self._t_window = t_iter
+                steps = self.decode_steps
                 self._sweep_cancelled()
                 # page-outs started LAST iteration finalize here: their
                 # D2H copies overlapped the decode step in between, so
                 # the np materialization below is (near-)free
                 self._finish_pageouts()
                 admitted = self._admit()
-                stepped = self._decode(it0)
-                if stepped:
-                    self.decode_steps += 1
+                stepped = self._decode()
+                # a step counts when its tokens are emitted: at most one an
+                # iteration, none in the one that enqueues a busy period's first
+                if self.decode_steps > steps:
                     if self.decode_steps % self.swap_every_steps == 0:
                         self._maybe_swap()
                     if self.decode_steps % self.gauge_every_steps == 0:
                         self._publish_gauges()
-                t_carry = self._t_step_end if stepped else None
                 if admitted or stepped:
                     # the wall of every iteration that did work, beside the
                     # engine's stage seconds: their difference is what the
@@ -444,18 +480,28 @@ class ContinuousBatcher:
                             "serve_iteration", t_iter, t_end,
                             admitted=bool(admitted), stepped=bool(stepped),
                         )
+                if not stepped:
+                    self._t_window = None
                 if not admitted and not stepped:
                     # idle: still honor the staleness bound, then sleep
                     self._maybe_swap()
                     with self._cond:
                         if not self._queue and not self._stop.is_set():
                             self._cond.wait(timeout=0.05)
+            self._drain("stop")
         except Exception as e:  # noqa: BLE001 — fail loudly, never hang clients
             self.loop_error = f"{type(e).__name__}: {e}"
+            try:
+                # what the step in flight finished is finished, where it can
+                # still be read; everything else fails below
+                self._drain("failure")
+            except Exception:  # noqa: BLE001 — the step is lost with the rest
+                self._ahead = None
             for slot, st in list(self._active.items()):
                 self._retire(st, error=self.loop_error)
                 self.slots.free(slot)
             self._active.clear()
+            self._awaiting.clear()
             self._fail_cold(self.loop_error)
             with self._cond:
                 pending = list(self._queue)
@@ -568,7 +614,8 @@ class ContinuousBatcher:
         has at least the final token to run (its logits seed decode)."""
         best_src, best = None, 0
         for slot, st in self._active.items():
-            if st.cache_len >= self.engine.max_context:
+            # the row a step in flight is writing counts as written
+            if st.cache_len + self._rides(slot, st) >= self.engine.max_context:
                 continue
             p = common_prefix_len(prompt, st.req.prompt)
             p = min(p, len(prompt) - 1)
@@ -577,6 +624,16 @@ class ContinuousBatcher:
         if best >= MIN_PREFIX_TOKENS:
             return best_src, best
         return None, 0
+
+    def _prefix_for(self, prompt: list) -> tuple:
+        """-> (live source slot, reused length, host-tier pages): the prefix a
+        continued prefill of ``prompt`` would start from, or (None, 0, None)."""
+        src, plen, host = None, 0, None
+        if self.prefix_cache:
+            src, plen = self._find_prefix(prompt)
+            if src is None and self.kv_tier is not None:
+                host, plen = self._host_prefix_lookup(prompt)
+        return src, plen, host
 
     def _pop_next(self) -> Optional[Request]:
         """Most urgent queued request: lowest priority tier first, then
@@ -647,21 +704,23 @@ class ContinuousBatcher:
         return admitted
 
     def _admit_into(self, slot: int, req: Request) -> None:
-        """One admission path, whose first token is read at once or after the
-        next decode step is enqueued, by the kind of admission and of step: a
-        cold prefill's token stays on the device, where the step takes it,
-        unless nothing will step it there (a request of one token needs no
-        step); a continued prefill
-        (live prefix, host tier) reads as it always did."""
+        """One admission path, whose first token is read at once or with the
+        step that takes it on the device (an iteration after that step is
+        enqueued), by the kind of admission and of step: a cold prefill's
+        token stays on the device, where the step takes it, unless nothing
+        will step it there (a request of one token needs no step: its read
+        waits for the step in flight, which lies before it on the device, and
+        no longer); a continued prefill (live prefix, host tier) reads as it
+        always did, behind a drained step."""
         st = _Slot(
             req=req, cache_len=len(req.prompt), last_token=0,
             t_slot=time.perf_counter(),
         )
-        src, plen, host = None, 0, None
-        if self.prefix_cache:
-            src, plen = self._find_prefix(req.prompt)
-            if src is None and self.kv_tier is not None:
-                host, plen = self._host_prefix_lookup(req.prompt)
+        src, plen, host = self._prefix_for(req.prompt)
+        # a continued prefill copies a live slot's rows and is read at once:
+        # behind a drained step, which may have retired the source
+        if (src is not None or host is not None) and self._drain("continued_prefill"):
+            src, plen, host = self._prefix_for(req.prompt)
         # in the batch before a program can raise: the loop's failure handler
         # then fails this request with the others
         self._active[slot] = st
@@ -686,7 +745,7 @@ class ContinuousBatcher:
             self._maybe_store_prefix(slot, req.prompt)
             if req.max_new_tokens > 1:
                 st.admission = adm
-                self._awaiting.append(slot)
+                self._awaiting.append((slot, st))
                 return
             tok = self.engine.admit_resolve(adm)
         self._first_token(slot, st, tok, time.perf_counter(), plen)
@@ -733,16 +792,18 @@ class ContinuousBatcher:
             >= self.kv_tier.host_slots
         ):
             return False
-        best_slot = None
-        for slot, st in self._active.items():
-            if st.resident_steps < min_resident:
-                continue
-            if best_slot is None or (
-                st.resident_steps > self._active[best_slot].resident_steps
-            ):
-                best_slot = slot
+        best_slot = self._coldest(min_resident)
         if best_slot is None:
             return False
+        # a page-out reads the slot's rows and its newest token: both are the
+        # step's in flight until that is read. What the step ended may have
+        # made the room itself; else the choice is made again on what is left
+        if self._drain("evict"):
+            if self.slots.num_free:
+                return True
+            best_slot = self._coldest(min_resident)
+            if best_slot is None:
+                return False
         st = self._active.pop(best_slot)
         t0 = time.perf_counter()
         rows = ring_live_rows(st.cache_len, self.engine.max_context)
@@ -752,6 +813,19 @@ class ContinuousBatcher:
         self.evictions += 1
         obs.count("serve_tier_evictions")
         return True
+
+    def _coldest(self, min_resident: int) -> Optional[int]:
+        """The evictable slot longest in the batch (a step in flight counts
+        as taken), or None."""
+        best_slot, best = None, min_resident - 1
+        for slot, st in self._active.items():
+            rides = self._rides(slot, st)
+            if st.admission is not None and not rides:
+                continue  # its first token is nobody's to read yet
+            resident = st.resident_steps + rides
+            if resident > best:
+                best_slot, best = slot, resident
+        return best_slot
 
     def _finish_pageouts(self) -> None:
         if not self._pending_evict:
@@ -780,6 +854,7 @@ class ContinuousBatcher:
         """Page the oldest paused request back in and rejoin the batch
         exactly where it stopped (tokens, cache_len, last_token are the
         request's own; the ring rows come back from the tier)."""
+        self._drain("resume")
         rid, p = self._paused.popitem(last=False)
         t0 = time.perf_counter()
         k, v = self.kv_tier.pop_paused(rid)
@@ -847,37 +922,91 @@ class ContinuousBatcher:
             return []
         return self.kv_tier.resident_prefixes(self.engine.weights_epoch)
 
-    def _decode(self, t0: Optional[float] = None) -> bool:
-        if not self._active:
+    def _rides(self, slot: int, st: _Slot) -> bool:
+        """Whether the step in flight is computing a token for ``st``."""
+        return self._ahead is not None and self._ahead.tenants.get(slot) is st
+
+    def _decode(self) -> bool:
+        """One iteration's decode work, a step ahead of the host: enqueue the
+        next step, then read the one in flight (behind the first tokens of the
+        prompts it fed, which lie before it on the device) and emit it
+        -> whether there was any."""
+        if not self._active and self._ahead is None:
             return False
         S = self.engine.num_slots
-        # the decode span covers the WHOLE step — batch assembly, the
-        # engine call, and token emit — so per-step scheduler time is
-        # attributed to the requests it served, and a trace's stage sums
-        # reconcile with its end-to-end latency
         t_batch = time.perf_counter()
-        if t0 is None:
-            t0 = t_batch
+        # who is in the next step, said before the tokens in flight are in
+        # hand: a slot whose request that token ends by length is not (nothing
+        # is computed for it); one that may end on an eos is, and its row is
+        # dropped if it did. A slot's token is on the host, or the step's in
+        # flight, or its admission's: the last two stay on the device
         tokens = np.zeros((S,), np.int32)
         lens = np.zeros((S,), np.int32)
+        tenants = {}
+        riding = self._ahead.tenants if self._ahead is not None else {}
         for slot, st in self._active.items():
-            tokens[slot] = st.last_token
-            lens[slot] = st.cache_len
-        next_tokens, _ = self.engine.decode_step(tokens, lens)
+            if riding.get(slot) is st:
+                # its tokens so far: those on the host, a first token that the
+                # step in flight took on the device, and that step's own
+                have = len(st.req.tokens) + (st.admission is not None) + 1
+                if have >= st.req.max_new_tokens:
+                    continue
+                tokens[slot], lens[slot] = PREV_TOKEN_ON_DEVICE, st.cache_len + 1
+            else:
+                tokens[slot], lens[slot] = st.last_token, st.cache_len
+            tenants[slot] = st
+        read, rows = self._ahead, None
+        if tenants:
+            # counted under the weights the step is enqueued with
+            rows = _Rows(tenants, self._awaiting, self.engine.weights_epoch)
+            self._awaiting = []
+            self.staleness_hist[self.engine.staleness()] += 1
+            next_tokens = self._step_ahead(tokens, lens)
+            self._ahead = rows
+        else:
+            next_tokens = self._step_ahead()
         step_t0, step_t1 = self.engine.decode_bounds
-        self.staleness_hist[self.engine.staleness()] += 1
-        # the step fed this iteration's admissions their first tokens on the
-        # device and read them before its own: each is stamped with the
-        # instant it reached the host. A request its first token ends is
-        # gone here, and the row the step computed for its slot is dropped
-        for slot in self._awaiting:
-            st = self._active[slot]
-            self._first_token(slot, st, st.admission.token, st.admission.t_token)
-        self._awaiting.clear()
-        obs.count("serve_tokens_generated", len(self._active))
-        batch = len(self._active)
-        done_slots = []
-        for slot, st in self._active.items():
+        self.batch_seconds += step_t0 - t_batch
+        tr = obs.tracer()
+        if tr is not None:
+            tr.add_span("serve_batch", t_batch, step_t0)
+        if read is not None:
+            self._emit(read, next_tokens, step_t1)
+            if rows is None:
+                self._ahead = None  # read, and emitted too: ``drain`` may return
+        return True
+
+    def _step_ahead(self, tokens=None, lens=None) -> Optional[np.ndarray]:
+        """The engine's call. If it raises, the rows it was reading and those
+        it was enqueuing are lost with the loop: nobody's to drain."""
+        try:
+            return self.engine.step_ahead(tokens, lens)
+        except BaseException:
+            self._ahead = None
+            raise
+
+    def _emit(self, step: _Rows, next_tokens: np.ndarray, t_read: float) -> None:
+        """A step's tokens have reached the host (the engine's call returned at
+        ``t_read``): each row goes to the tenant it was enqueued for, unless
+        that one has left its slot since (cancelled, shed, or ended on the
+        token before); finished requests retire here. The span covers batch
+        assembly, the engine call, and token emit, so per-step scheduler time
+        is attributed to the requests it served, and a trace's stage sums
+        reconcile with its end-to-end latency."""
+        t0 = self._t_window if self._t_window is not None else t_read
+        # the prompts this step fed were read before it, each stamped with the
+        # instant its first token reached the host. A request that token ends
+        # is gone here, and the rows computed for its slot are dropped
+        for slot, st in step.first:
+            if self._active.get(slot) is st:
+                self._first_token(slot, st, st.admission.token, st.admission.t_token)
+        live = [
+            (slot, st) for slot, st in step.tenants.items() if self._active.get(slot) is st
+        ]
+        self.decode_steps += 1
+        obs.count("serve_tokens_generated", len(live))
+        done = []
+        for slot, st in live:
             tok = int(next_tokens[slot])
             st.req.tokens.append(tok)
             st.cache_len += 1
@@ -885,32 +1014,43 @@ class ContinuousBatcher:
             st.resident_steps += 1
             self.total_new_tokens += 1
             if self._finished(st):
-                done_slots.append(slot)
-        # the next iteration's window starts HERE, so span recording,
-        # retires, and swap/gauge checks below are attributed to the
-        # step that pays for them
-        t1 = self._t_step_end = time.perf_counter()
+                done.append((slot, st))
+        # the next step's window starts HERE, so span recording, retires,
+        # and swap/gauge checks below are attributed to the step that pays
+        # for them
+        t1 = self._t_window = time.perf_counter()
         rt = reqtrace.ring()
         if rt is not None:
-            for st in self._active.values():
+            for _, st in live:
                 if st.req.trace is not None:
                     # a just-admitted slot's window starts where its own
                     # prefill ended, never before (no self double-count)
                     rt.span(
                         st.req.trace, "decode", max(t0, st.req.t_first), t1,
-                        batch=batch, tokens=1,
+                        batch=len(live), tokens=1,
                         kernel=self.engine.decode_kernel,
                     )
-        for slot in done_slots:
+        for slot, st in done:
+            del self._active[slot]
             self.slots.free(slot)
-            self._retire(self._active.pop(slot))
+            self._retire(st, epoch=step.epoch)
         t_emit = time.perf_counter()
-        self.batch_seconds += step_t0 - t_batch
-        self.emit_seconds += t_emit - step_t1
+        self.emit_seconds += t_emit - t_read
         tr = obs.tracer()
         if tr is not None:
-            tr.add_span("serve_batch", t_batch, step_t0)
-            tr.add_span("serve_emit", step_t1, t_emit)
+            tr.add_span("serve_emit", t_read, t_emit)
+
+    def _drain(self, reason: str) -> bool:
+        """Read and emit the step in flight, for an operation that needs the
+        newest tokens on the host or a cache no program is writing -> whether
+        there was one (counted by ``reason``)."""
+        if self._ahead is None:
+            return False
+        tokens = self._step_ahead()
+        self._emit(self._ahead, tokens, self.engine.decode_bounds[1])
+        self._ahead = None
+        self.step_drains[reason] += 1
+        obs.count("serve_step_drains", reason=reason)
         return True
 
     def _finished(self, st: _Slot) -> bool:
@@ -919,11 +1059,15 @@ class ContinuousBatcher:
             return True
         return req.eos_id is not None and st.last_token == req.eos_id
 
-    def _retire(self, st: _Slot, error: Optional[str] = None) -> None:
+    def _retire(
+        self, st: _Slot, error: Optional[str] = None, epoch: Optional[int] = None
+    ) -> None:
+        """``epoch``: the weights its last step was enqueued under, where a
+        step ended it (a swap may have come between that and the read)."""
         req = st.req
         if req.eos_id is not None and req.tokens and req.tokens[-1] == req.eos_id:
             req.tokens.pop()  # eos terminates, is not part of the text
-        req.epoch = self.engine.weights_epoch
+        req.epoch = self.engine.weights_epoch if epoch is None else epoch
         req.finish(error)
         self._trace_terminal(
             req,
@@ -1099,6 +1243,11 @@ class ContinuousBatcher:
             # of the cold admissions in phase_calls["prefill"], those whose
             # first token a decode step took on the device
             "admissions_deferred": self.engine.admissions_deferred,
+            # of the steps in phase_calls["decode"], those enqueued while the
+            # step before them was unread; and the times the loop had to read
+            # the step in flight before it could act, by what asked
+            "steps_ahead": self.engine.steps_ahead,
+            "step_drains": dict(self.step_drains),
             # what EVA attention did with its two rings (zeros without it)
             "eva": {
                 **{name: getattr(self.engine, f"eva_{name}") for name in (

@@ -767,19 +767,24 @@ def test_loop_phases_lie_on_either_side_of_the_step(tiny_cfg):
         cap = obs.capture.stop()
     steps, iterations = _named(cap, "serve_decode"), _named(cap, "serve_iteration")
     batches, emits = _named(cap, "serve_batch"), _named(cap, "serve_emit")
-    assert steps and len(batches) == len(emits) == len(steps)
-    in_order = (sorted(spans, key=lambda s: s["t0"]) for spans in (batches, steps, emits))
-    for b, d, e in zip(*in_order):
-        assert b["t1"] == pytest.approx(d["t0"], abs=_STAMP_EPS)
+    # a step is emitted by the call that read it; a batch is assembled for
+    # every call, the one that only enqueues a busy period's first step too
+    assert steps and len(emits) == len(steps) == batcher.decode_steps <= len(batches)
+    for d, e in zip(*(sorted(spans, key=lambda s: s["t0"]) for spans in (steps, emits))):
         assert e["t0"] == pytest.approx(d["t1"], abs=_STAMP_EPS)
-        assert b["args"] == e["args"] == {}
-        assert any(i["t0"] - _STAMP_EPS <= b["t0"] and e["t1"] <= i["t1"] + _STAMP_EPS
-                   for i in iterations)
+    calls = [s["t0"] for s in steps] + [
+        s["t0"] for s in _named(cap, "serve_args") if s["args"] == {"stage": "decode"}
+    ]
+    for b in batches:  # ends where the engine's call starts
+        assert min(abs(b["t1"] - t0) for t0 in calls) <= _STAMP_EPS
+    for span in batches + emits:
+        assert span["args"] == {}
+        assert any(_inside(span, i) for i in iterations)
     for name, total in (("serve_batch", batcher.batch_seconds),
                         ("serve_emit", batcher.emit_seconds)):
         assert total > 0
         assert sum(s["t1"] - s["t0"] for s in _named(cap, name)) == pytest.approx(
-            total, abs=2 * len(steps) * _STAMP_EPS
+            total, abs=2 * len(batches) * _STAMP_EPS
         )
     assert batcher.batch_seconds + batcher.emit_seconds < batcher.loop_seconds
     # five new names and no sixth
@@ -820,6 +825,11 @@ def _inside(child, parent):
             and child["t1"] <= parent["t1"] + _STAMP_EPS)
 
 
+def _stage(cap, name, stage):
+    return sorted((s for s in _named(cap, name) if s["args"] == {"stage": stage}),
+                  key=lambda s: s["t0"])
+
+
 @pytest.mark.parametrize("kind, attribute", [
     ("dense", None), ("routed", "moe_pairs"), ("hybrid", "ssm_tokens"),
     ("latent", "latent_rows"), ("cca", "cca_tokens"),
@@ -843,34 +853,129 @@ def test_a_deferred_admissions_span_starts_at_its_enqueue(tiny_cfg, kind, attrib
     prefills = sorted(_named(cap, "serve_prefill"), key=lambda s: s["t0"])
     assert [p["args"]["tokens"] for p in prefills] == [3, 5, 7]
     assert attribute is None or all(p["args"][attribute] > 0 for p in prefills)
-    phases = {
-        name: sorted((s for s in _named(cap, name) if s["args"] == {"stage": "prefill"}),
-                     key=lambda s: s["t0"])
-        for name in _PHASE_SPANS
-    }
+    phases = {name: _stage(cap, name, "prefill") for name in _PHASE_SPANS}
     steps = sorted(_named(cap, "serve_decode"), key=lambda s: s["t0"])
-    step_phases = {name: min((s for s in _named(cap, name) if s["args"] == {"stage": "decode"}),
-                             key=lambda s: s["t0"]) for name in _PHASE_SPANS}
-    # all three were enqueued, and the step behind them, before anything was read
-    assert prefills[-1]["t1"] <= steps[0]["t0"] + _STAMP_EPS
-    read_end = step_phases["serve_dispatch"]["t1"]
+    step_phases = {name: _stage(cap, name, "decode") for name in _PHASE_SPANS}
+    # all three were enqueued, and the step behind them, before anything was
+    # read; that call read nothing and has no span
+    assert prefills[-1]["t1"] <= step_phases["serve_args"][0]["t0"] + _STAMP_EPS
+    assert step_phases["serve_dispatch"][0]["t1"] <= steps[0]["t0"] + _STAMP_EPS
+    # the call after it enqueued the second step and then read the first,
+    # behind the admissions the first was fed
+    read_end = step_phases["serve_dispatch"][1]["t1"]
+    own_read = step_phases["serve_fetch"][0]
     for p, args, dispatch, fetch, req in zip(prefills, *phases.values(), reqs):
         # the span is the enqueue: it starts there and its two phases tile it
         assert args["t0"] == pytest.approx(p["t0"], abs=_STAMP_EPS)
         assert dispatch["t0"] == pytest.approx(args["t1"], abs=_STAMP_EPS)
         assert dispatch["t1"] == pytest.approx(p["t1"], abs=_STAMP_EPS)
-        # its read is a wait inside the step, after the step's dispatch, each
-        # where the one before it ended, and before the step's own read
+        # its read is a wait inside the call that reads the step it fed, after
+        # that call's dispatch, each where the one before it ended, and before
+        # the step's own read
         assert _inside(fetch, steps[0])
         assert fetch["t0"] >= read_end - _STAMP_EPS
-        assert fetch["t1"] <= step_phases["serve_fetch"]["t0"] + _STAMP_EPS
+        assert fetch["t1"] <= own_read["t0"] + _STAMP_EPS
         # the first token's stamp is the instant that read returned
         assert req.t_first == pytest.approx(fetch["t1"], abs=_STAMP_EPS)
-        assert dispatch["t1"] <= req.t_first <= step_phases["serve_fetch"]["t1"]
+        assert dispatch["t1"] <= req.t_first <= own_read["t1"]
         read_end = fetch["t1"]
-    assert _inside(step_phases["serve_fetch"], steps[0])
-    iterations = _named(cap, "serve_iteration")
+    assert _inside(own_read, steps[0])
+    iterations = sorted(_named(cap, "serve_iteration"), key=lambda s: s["t0"])
     assert all(any(_inside(s, i) for i in iterations) for s in prefills + steps)
+    # the busy period's first iteration enqueues and reads nothing
+    assert all(_inside(s, iterations[0]) for s in
+               (*prefills, step_phases["serve_args"][0], step_phases["serve_dispatch"][0]))
+    assert _inside(steps[0], iterations[1])
+    assert len(steps) == 3 == batcher.decode_steps and engine.steps_ahead == 2
+
+
+def test_a_step_aheads_span_holds_one_steps_dispatch_and_the_read_of_the_step_before(tiny_cfg):
+    """ISSUE 48: the ``serve_decode`` span of an iteration holds the ``args``
+    and ``dispatch`` of the step it enqueues and the ``fetch`` of the step it
+    reads, in its ``serve_iteration``; ``stage_seconds["decode"]`` covers them
+    and no admission's read; its attributes are the step's that was read."""
+    from opendiloco_tpu.serve import ContinuousBatcher
+
+    engine = _tiny_engine(tiny_cfg)
+    lengths = [3, 4, 6, 6, 5, 4]  # two end a step apart, and the queue takes their slots
+    obs.capture.start()
+    batcher = ContinuousBatcher(engine)
+    reqs = [batcher.submit([7, 8, 9, i + 1][: 2 + i % 3], max_new_tokens=n)
+            for i, n in enumerate(lengths)]
+    batcher.start()
+    try:
+        for r in reqs:
+            assert r.wait(300) and r.error is None
+    finally:
+        batcher.stop()
+        cap = obs.capture.stop()
+    steps = sorted(_named(cap, "serve_decode"), key=lambda s: s["t0"])
+    iterations = _named(cap, "serve_iteration")
+    phases = {name: _stage(cap, name, "decode") for name in _PHASE_SPANS}
+    assert len(steps) == batcher.decode_steps == len(phases["serve_fetch"])
+    assert engine.steps_ahead == len(steps) - 1 == len(phases["serve_args"]) - 1
+    for d in steps:
+        (iteration,) = [i for i in iterations if _inside(d, i)]
+        assert [s for s in steps if _inside(s, iteration)] == [d]  # one a working iteration
+        inside = sorted((c for name in _PHASE_SPANS for c in phases[name] if _inside(c, d)),
+                        key=lambda c: c["t0"])
+        names = tuple(c["name"] for c in inside)
+        # the last call of the busy period enqueues nothing
+        assert names == _PHASE_SPANS or (d is steps[-1] and names == ("serve_fetch",))
+        assert inside[0]["t0"] == pytest.approx(d["t0"], abs=_STAMP_EPS)
+        # they tile the call's front, but for the admissions read before the
+        # step's own tokens
+        fed = [r for r in _stage(cap, "serve_fetch", "prefill") if _inside(r, d)]
+        for a, b in zip(inside, inside[1:]):
+            if fed and b["name"] == "serve_fetch":
+                assert a["t1"] <= fed[0]["t0"] + _STAMP_EPS and fed[-1]["t1"] <= b["t0"] + _STAMP_EPS
+            else:
+                assert b["t0"] == pytest.approx(a["t1"], abs=_STAMP_EPS)
+    # the attributes are the step's whose tokens the call read: the first
+    # step's four admissions (prompt lengths 2, 3, 4, 2) came back in the second call
+    assert steps[0]["args"] == {"rows": 2 + 3 + 4 + 2, "slots": 4}
+    assert sum(s["args"]["slots"] for s in steps) == batcher.total_new_tokens
+    # the stage's seconds: every call's span less the admissions read inside
+    # it (its own fetch starts where the last of them returned), and the two
+    # phases of the one call that read nothing
+    outside = [c for name in ("serve_args", "serve_dispatch") for c in phases[name]
+               if not any(_inside(c, d) for d in steps)]
+    assert len(outside) == 2
+    walls = sum(s["t1"] - s["t0"] for s in steps + outside)
+    reads = _stage(cap, "serve_fetch", "prefill")
+    assert len(reads) == len(reqs)
+    for d in steps:
+        (own,) = [f for f in phases["serve_fetch"] if _inside(f, d)]
+        dispatched = [c["t1"] for c in phases["serve_dispatch"] if _inside(c, d)] or [d["t0"]]
+        fed = [r for r in reads if _inside(r, d)]
+        assert all(dispatched[0] - _STAMP_EPS <= r["t0"] and r["t1"] <= own["t0"] + _STAMP_EPS
+                   for r in fed)
+        if fed:
+            walls -= own["t0"] - dispatched[0]
+    assert engine.stage_seconds["decode"] == pytest.approx(walls, abs=4 * len(steps) * _STAMP_EPS)
+    assert sum(engine.phase_seconds["decode"].values()) <= engine.stage_seconds["decode"]
+    # an admission's first token is stamped as its own read returns
+    firsts = sorted(r.t_first for r in reqs)
+    assert [r["t1"] for r in reads] == pytest.approx(firsts, abs=_STAMP_EPS)
+    # a step's tokens wait for nothing that was enqueued after the step: the
+    # admissions a call reads before the step's tokens were all enqueued before
+    # that step was (they lie before it on the device), and those enqueued
+    # behind it are read by the next call
+    prefills = sorted(_named(cap, "serve_prefill"), key=lambda s: s["t0"])
+    enqueues = phases["serve_args"]  # one a step, in the order the steps were enqueued
+    behind = 0
+    for admission, r in zip(prefills, reads):
+        (k,) = [k for k, d in enumerate(steps) if _inside(r, d)]  # the call that read step k
+        assert admission["t1"] <= enqueues[k]["t0"] + _STAMP_EPS
+        behind += k > 0 and admission["t0"] >= enqueues[k - 1]["t1"] - _STAMP_EPS
+    assert behind == 2  # the two that took the slots of the first to end
+    # and a finished request is stamped when its last step's tokens are emitted,
+    # before the loop goes on to the next iteration
+    for r in reqs:
+        (d,) = [d for d in steps if d["t1"] <= r.t_done and
+                not any(d["t1"] < e["t1"] <= r.t_done for e in steps)]
+        (iteration,) = [i for i in iterations if _inside(d, i)]
+        assert r.t_done <= iteration["t1"]
 
 
 def test_counters_of_deferred_admissions_grow_as_with_blocking_calls(tiny_cfg):

@@ -587,6 +587,40 @@ def test_the_engines_own_programs_move_no_cache_either(chip, monkeypatch):
         assert not moved, (bucket, moved)
 
 
+def test_the_decode_step_takes_the_token_in_flight_without_a_copy(chip, monkeypatch):
+    """The decode program as the engine jits it since ISSUE 48, at the batch
+    cell's shapes: ``tokens`` selects between the host's token, the slot's
+    entry of ``first`` and its entry of ``prev``, the step before's output.
+    Both caches still alias, nothing cache- or page-shaped is moved, and the
+    two vectors are read where they lie: no copy of a slots-long int32 but the
+    prefetch of each argument into fast memory."""
+    from opendiloco_tpu.serve.engine import serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, params, cache = _serving_shapes(chip, "smollm2-360m")
+    _, decode, _, carried = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(decode, donate_argnums=tuple(range(4, 4 + carried)))
+        .lower(params, vec, vec, vec, cache, cache, prev=vec).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "tpu_custom_call" in text
+    assert mem.alias_size_in_bytes >= 2 * 2 * cache.size  # both caches, in place
+    assert not _cache_shaped_results(text, cache.shape)
+    entry = text[text.index("ENTRY "):]
+    slots_long = [
+        line.strip()[:160] for line in entry.splitlines()
+        if (m := _RESULT.match(line)) and m.group(2) == "s32"
+        and m.group(3) == str(engine["num_slots"])
+    ]
+    assert sum("parameter(" in line for line in slots_long) == 4  # tokens, lens, first, prev
+    # each is prefetched into fast memory (``S(1)``) as ``tokens`` and ``first``
+    # were, and nothing else of that length is copied anywhere
+    moved = [line for line in slots_long if "copy" in line]
+    assert len(moved) == 4 and all("S(1)} copy-done(" in line for line in moved), moved
+
+
 # ---------------------------------------------------------------------------
 # the granite-4.0-h cell (ISSUE 30): the prefill at its largest bucket and the
 # decode step at its slots, published widths, ten layers; they fit the chip
