@@ -217,8 +217,7 @@ KNOBS: tuple[Knob, ...] = (
          doc_default="auto"),
     Knob("ODTP_DECODE_KERNEL", "str", "", "serve",
          "Decode-path kernel dispatch: `auto` picks the Pallas serving "
-         "kernels (paged decode attention, the continued prefill's tail "
-         "attention) on TPU and the stock XLA ops elsewhere; "
+         "kernels (paged decode attention) on TPU and the stock XLA ops elsewhere; "
          "`pallas`/`xla` force a path. Token-bit-exact either way.",
          doc_default="config"),
     Knob("ODTP_KV_HOST_SLOTS", "int", "", "serve",

@@ -225,7 +225,7 @@ class ServeConfig(BaseModel):
     swap_every_steps: int = 16
     max_stale_rounds: int = 0
     # decode-path kernel dispatch: "auto" picks the Pallas serving kernels
-    # (paged decode attention, the continued prefill's tail attention) on
+    # (paged decode attention over a slot's rings) on
     # TPU backends and the stock XLA ops elsewhere; "pallas" / "xla" force a
     # path (forced pallas off-TPU runs interpreted — test rigs only).
     # Token-bit-exact either way.

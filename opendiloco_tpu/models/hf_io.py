@@ -70,6 +70,15 @@ def _reject_moe(cfg: LlamaConfig, op: str) -> None:
             "train, serve and checkpoint through the framework checkpointer "
             "(opendiloco_tpu.ckpt); only this import/export is refused"
         )
+    if cfg.sparse or cfg.qk_norm_per_head or cfg.mrope_section is not None:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no indexer (its queries, its one key under a LayerNorm, "
+            "its head weights), no QK-norm per head and no rotation in sections, "
+            "and HF's KeyeVL2 layout is not mapped here. Such models train, serve "
+            "and checkpoint through the framework checkpointer "
+            "(opendiloco_tpu.ckpt); only this import/export is refused"
+        )
     if cfg.cca or cfg.router_hidden_size or cfg.residual_scaling:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

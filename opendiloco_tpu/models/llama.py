@@ -17,6 +17,7 @@ open_diloco/configs/*.json -- but designed for XLA, not translated:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -30,8 +31,12 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     cache_insert,
     eva_window_rows,
     init_kv_cache,
+    index_chunk_insert,
+    index_write_rows,
+    layer_rows_insert,
     prefix_copy,
-    suffix_insert,
+    ring_rows,
+    slot_layer_pages,
 )
 from opendiloco_tpu.ops.attention import (
     decode_step_attention,
@@ -39,15 +44,20 @@ from opendiloco_tpu.ops.attention import (
     eva_decode_step_attention,
     eva_pool,
     latent_decode_step_attention,
-    tail_attention,
+    causal_selection,
+    chunk_selection,
+    decode_selection,
+    sparse_attention,
+    sparse_decode_step_attention,
+    tiled_sparse_attention,
     xla_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     eva_decode_attention,
     eva_prefill_attention,
+    index_ring_write,
     mla_decode_attention,
     paged_decode_attention,
-    tail_attention_fused,
 )
 
 
@@ -67,6 +77,11 @@ class LlamaConfig:
     rope_theta: float = 10_000.0
     tie_word_embeddings: bool = False
     initializer_range: float = 0.02
+    # a fresh norm's weight is 1 + N(0, norm_init_std^2) and a norm's bias
+    # N(0, norm_init_std^2): 0 leaves them at 1 and 0, as a fresh model has
+    # them; a benchmark or a test that has to see every norm's weight act
+    # (a trained model's are not 1) asks for a spread
+    norm_init_std: float = 0.0
     # Mixture-of-Experts (beyond the reference's dense-only zoo): 0 = dense
     # FFN; > 0 = routed experts in every layer, ``num_experts_per_tok`` per
     # token and no token dropped, sharded over the "ep" mesh axis. The keys
@@ -184,6 +199,32 @@ class LlamaConfig:
     norm_add_unit_offset: bool = False
     fp32_skip_add: bool = False
     fp32_logits: bool = False
+    # Learned sparse attention, the block of a published ``KeyeVL2``
+    # ``config.json`` (a Qwen3-MoE block under DeepSeek sparse attention's
+    # lightning indexer; the ``index_*`` and chunk keys are its ``sa_config``'s):
+    # ``index_topk`` > 0 gives every attention layer an indexer. Beside K and V
+    # a token keeps one index key of ``index_head_dim`` values (a LayerNorm
+    # with bias over its projection, rotated whole); a query's
+    # ``index_n_heads`` index queries score every row before it, I = sum_j w_j
+    # relu(q_j . k) with w a projection of the query's token, and its attention
+    # reads the ``index_topk`` rows of largest score, one set for all its
+    # heads (ties to the lower position). ``q_chunk_size`` is the serving
+    # prefill's chunk: a prompt longer than every bucket is admitted that many
+    # queries at a time over the rows before them (``chunk_prefill_forward``);
+    # the published ``kv_chunk_size`` tiles its kernel's scoring, changes no
+    # equation and is held by no field (``to_dict`` writes the query chunk in
+    # its place, as the published file has it). ``qk_norm_per_head``: q and k pass an RMSNorm per head, its
+    # weight [head_dim] shared by the heads (the Qwen3 family's; ``qk_norm`` is
+    # OLMoE's, over the whole projection). ``mrope_section``: the rotation's
+    # frequency pairs in three runs, each turned by its own row of positions
+    # [3, B, T] (temporal, height, width); token ids have three equal rows, for
+    # which this is plain RoPE
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    q_chunk_size: int = 0
+    qk_norm_per_head: bool = False
+    mrope_section: Optional[tuple] = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -212,6 +253,34 @@ class LlamaConfig:
                     "CCA is written for a stack of like rotated attention layers "
                     "whose query heads divide over the KV heads: no latent "
                     "attention, no layer_types, no qk_norm, no 'nope'"
+                )
+        if self.mrope_section is not None:
+            object.__setattr__(self, "mrope_section", tuple(int(n) for n in self.mrope_section))
+            if len(self.mrope_section) != 3 or sum(self.mrope_section) != self.rotary_dim // 2:
+                raise ValueError(
+                    f"mrope_section {self.mrope_section}: three runs of frequency pairs "
+                    f"that make up the {self.rotary_dim // 2} pairs of a head"
+                )
+        if self.sparse:
+            if not (
+                self.index_n_heads > 0 and self.index_head_dim >= 2
+                and self.index_head_dim % 2 == 0 and self.q_chunk_size > 0
+            ):
+                raise ValueError(
+                    "learned sparse attention (index_topk > 0) needs index_n_heads, an "
+                    "even index_head_dim and a q_chunk_size; got "
+                    f"{self.index_n_heads}, {self.index_head_dim}, {self.q_chunk_size}"
+                )
+            if (
+                self.latent or self.cca or self.eva or self.layer_types is not None
+                or self.qk_norm or self.num_attention_heads % self.kv_heads
+                or self.position_embedding_type != "rope"
+            ):
+                raise ValueError(
+                    "learned sparse attention is written for a stack of like rotated "
+                    "attention layers whose query heads divide over the KV heads: no "
+                    "latent attention, no CCA, no EVA, no layer_types, no qk_norm over "
+                    "the whole projection, no 'nope'"
                 )
         if self.attention_class not in ("mha", "eva"):
             raise ValueError(
@@ -407,6 +476,12 @@ class LlamaConfig:
         return self.attention_class == "eva"
 
     @property
+    def sparse(self) -> bool:
+        """Does a learned indexer choose the rows each query's attention reads
+        (so an index-key ring beside K and V)?"""
+        return self.index_topk > 0
+
+    @property
     def eva_chunks_per_window(self) -> int:
         """Pooled rows that stand for one whole window."""
         return self.window_size // self.chunk_size
@@ -488,6 +563,43 @@ class LlamaConfig:
                         f"an evabyte stack is written for {key} {want!r}; got {raw[key]!r}"
                     )
             known.setdefault("initializer_range", raw.get("init_std", cls.initializer_range))
+        if raw.get("model_type") == "KeyeVL2":
+            # the language model of a published Keye-VL-2.0 config: a Qwen3-MoE
+            # block (every layer routed, QK-norm per head: the family's, no key
+            # states it) under ``sa_config``'s indexer. What the block is not
+            # written for is refused by name and never read past. The catalog's
+            # config has no key for an aux loss: 0
+            sa = raw.get("sa_config") or {}
+            for key, want, got in (
+                ("sa_config.indexer_num_kv_heads", 1, sa.get("indexer_num_kv_heads", 1)),
+                ("attention_bias", False, raw.get("attention_bias", False)),
+                ("decoder_sparse_step", 1, raw.get("decoder_sparse_step", 1)),
+                ("mlp_only_layers", [], list(raw.get("mlp_only_layers") or [])),
+                ("use_sliding_window", False, raw.get("use_sliding_window", False)),
+                ("hidden_act", "silu", raw.get("hidden_act", "silu")),
+            ):
+                if got != want:
+                    raise ValueError(
+                        f"a KeyeVL2 stack is written for {key} {want!r}; got {got!r}"
+                    )
+            scaling = raw.get("rope_scaling") or {}
+            if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+                raise ValueError(
+                    "a KeyeVL2 stack is written for rope_scaling of type 'default' (sectioned "
+                    f"rotation, no stretching); got {scaling!r}"
+                )
+            known.setdefault("index_n_heads", sa.get("indexer_num_heads", 0))
+            known.setdefault("index_head_dim", sa.get("indexer_head_dim", 0))
+            known.setdefault("index_topk", sa.get("topk", 0))
+            known.setdefault("q_chunk_size", sa.get("q_chunk_size", 0))
+            if scaling.get("mrope_section") is not None:
+                known.setdefault("mrope_section", tuple(scaling["mrope_section"]))
+            known.setdefault("qk_norm_per_head", True)
+            known.setdefault("router_aux_loss_coef", 0.0)
+            # the published key repeats the experts' count; a file cut to one
+            # chip's share gives the held count there, the router's width beside it
+            if known.get("num_local_experts") == known.get("num_experts"):
+                known.pop("num_local_experts", None)
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
@@ -524,6 +636,21 @@ class LlamaConfig:
             d.update(
                 architectures=["EvaByteForCausalLM"], model_type="evabyte",
                 init_std=self.initializer_range,
+            )
+        if self.sparse:
+            d.update(
+                architectures=["KeyeVL2ForConditionalGeneration"], model_type="KeyeVL2",
+                sa_config={
+                    "indexer_num_heads": self.index_n_heads,
+                    "indexer_head_dim": self.index_head_dim, "indexer_num_kv_heads": 1,
+                    "topk": self.index_topk, "q_chunk_size": self.q_chunk_size,
+                    "kv_chunk_size": self.q_chunk_size,
+                },
+                rope_scaling={
+                    "rope_type": "default", "type": "default",
+                    **({} if self.mrope_section is None
+                       else {"mrope_section": list(self.mrope_section)}),
+                },
             )
         return d
 
@@ -597,6 +724,14 @@ def shapes(cfg: LlamaConfig) -> dict:
         }
     if cfg.qk_norm:
         attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
+    if cfg.qk_norm_per_head:  # one weight a head's value, shared by the heads
+        attention.update(q_norm=(Dh,), k_norm=(Dh,))
+    if cfg.sparse:  # the indexer: its queries, its one key (LayerNorm with bias), its head weights
+        Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+        attention.update(
+            index_q=(D, Hi * Di), index_k=(D, Di), index_k_norm=(Di,),
+            index_k_norm_bias=(Di,), index_w=(D, Hi),
+        )
     if cfg.eva:  # the pooling's learned query and the pooled key's offset, per head
         attention.update(adaptive_phi=(Nkv, Dh), adaptive_mu_k=(Nkv, Dh))
     if cfg.cca:
@@ -756,6 +891,12 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
             # the norm scales by 1 + w: w about zero, and away from it, so that
             # the offset is tested
             out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * 0.02)
+        elif "norm" in name and cfg.norm_init_std:
+            # away from the values that would leave a norm's weight untested
+            noise = jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.norm_init_std
+            out.append(noise if name.endswith("bias") else 1.0 + noise)
+        elif "norm" in name and name.endswith("bias"):
+            out.append(jnp.zeros(leaf.shape, leaf.dtype))
         elif "norm" in name or name == "D":
             out.append(jnp.ones(leaf.shape, leaf.dtype))
         elif name in ("adaptive_phi", "adaptive_mu_k"):
@@ -914,15 +1055,22 @@ def _block_norm(cfg: LlamaConfig, h: jax.Array, weight: jax.Array) -> jax.Array:
 
 
 def _rope_tables(
-    positions: jax.Array, d: int, theta: float
+    positions: jax.Array, d: int, theta: float, sections: Optional[tuple] = None
 ) -> tuple[jax.Array, jax.Array]:
     """(cos, sin) [B, T, 1, D/2] float32 for the given positions.
 
     Hoisted out of the layer scan: the tables are shared by every layer's
     q and k, so the cos/sin transcendentals run once per step instead of
-    2*num_layers times."""
+    2*num_layers times.
+
+    ``positions`` [3, B, T] with ``sections`` (``mrope_section``): frequency
+    pair i turns by the first row for i < sections[0], by the second for the
+    next sections[1] pairs, by the third for the rest."""
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, T, D/2]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [(3,) B, T, D/2]
+    if positions.ndim == 3:
+        row = jnp.asarray([r for r, n in enumerate(sections) for _ in range(n)], jnp.int32)
+        angles = jnp.take_along_axis(angles, row[None, None, None, :], axis=0)[0]
     return jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
 
 
@@ -945,7 +1093,22 @@ def _rope(cfg: LlamaConfig, positions: jax.Array):
     if cfg.position_embedding_type == "nope":
         return None, None
     d = cfg.qk_rope_head_dim if cfg.latent else cfg.rotary_dim
-    return _rope_tables(positions, d, cfg.rope_theta)
+    if positions.ndim == 3 and cfg.mrope_section is None:
+        raise ValueError(
+            "three rows of positions [3, B, T] need a configuration with an mrope_section"
+        )
+    return _rope_tables(positions, d, cfg.rope_theta, cfg.mrope_section)
+
+
+def _index_rope(cfg: LlamaConfig, positions: jax.Array):
+    """The indexer's (cos, sin): all ``index_head_dim`` values of an index
+    query and key turn by the temporal row of the positions; None without an
+    indexer."""
+    if not cfg.sparse:
+        return None
+    if positions.ndim == 3:
+        positions = positions[0]
+    return _rope_tables(positions, cfg.index_head_dim, cfg.rope_theta)
 
 
 def _rotate_heads(cfg: LlamaConfig, x: jax.Array, cos, sin) -> jax.Array:
@@ -978,7 +1141,27 @@ def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
     if cfg.attention_multiplier is not None:
         q = q * jnp.asarray(cfg.attention_multiplier * Dh**0.5, q.dtype)
     q, k = q.reshape(B, T, Nh, Dh), k.reshape(B, T, Nkv, Dh)
+    if cfg.qk_norm_per_head:
+        q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     return _rotate_heads(cfg, q, cos, sin), _rotate_heads(cfg, k, cos, sin), v
+
+
+def _index_qkw(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
+    """The indexer's projections of the layer's normed input x [B, T, D] ->
+    (index queries [B, T, Hi, Di], the tokens' index keys [B, T, Di], the
+    queries' head weights [B, T, Hi]): queries and the one key rotated whole by
+    position, the key under a LayerNorm with bias before it (mean and variance
+    in float32)."""
+    B, T, _ = x.shape
+    Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+    qi = _rope_apply((x @ layer["index_q"]).reshape(B, T, Hi, Di), cos, sin)
+    kf = (x @ layer["index_k"]).astype(jnp.float32)
+    kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
+    kf = kf * jax.lax.rsqrt(jnp.mean(kf * kf, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+    kf = kf * layer["index_k_norm"].astype(jnp.float32) + layer["index_k_norm_bias"].astype(jnp.float32)
+    ki = _rope_apply(kf.astype(x.dtype)[:, :, None], cos, sin)[:, :, 0]
+    return qi, ki, x @ layer["index_w"]
 
 
 def _cca_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, past=None):
@@ -1130,6 +1313,46 @@ def refuse_eva(cfg: LlamaConfig, what: str) -> None:
             "chunks and the pooling of the chunk under way, which it neither "
             "copies nor could un-pool"
         )
+
+
+def refuse_sparse(cfg: LlamaConfig, what: str) -> None:
+    """``what`` handles a slot's past as (k, v) rows alone, or runs an attention
+    that reads every row before a query; under learned sparse attention a token
+    also keeps an index key, and a query reads the rows its indexer chose."""
+    if cfg.sparse:
+        raise ValueError(
+            f"{what} is refused for a configuration with learned sparse attention "
+            f"(index_topk {cfg.index_topk}, {cfg.index_n_heads} index heads of "
+            f"{cfg.index_head_dim}): a token keeps an index key beside its K and V, in "
+            "a ring of its own that this neither copies nor snapshots, and a query's "
+            "attention reads the rows its indexer chose, which this does not compute"
+        )
+
+
+INDEXER_LEAVES = ("index_q", "index_k", "index_k_norm", "index_k_norm_bias", "index_w")
+
+
+def untrained_by_the_lm_loss(cfg: LlamaConfig) -> tuple:
+    """The layer leaves that the next-token loss gives no gradient: an
+    indexer's (it chooses under ``stop_gradient``; its own alignment loss is
+    in no config key and is not built). A trainer says so by name."""
+    return INDEXER_LEAVES if cfg.sparse else ()
+
+
+def sparse_attend(cfg: LlamaConfig):
+    """The ``attend(q, k, v, qi, ki, wi)`` of learned sparse attention over a
+    whole sequence from position 0 (training, evaluation, a whole-prompt
+    prefill): the indexer's scores and the selection (scope
+    ``odtp_dsa_index``; under ``stop_gradient``: the LM loss trains no
+    indexer), then attention over the chosen rows (``odtp_dsa_attn``)."""
+
+    def attend(q, k, v, qi, ki, wi):
+        with jax.named_scope("odtp_dsa_index"):
+            rows = jax.lax.stop_gradient(causal_selection(qi, wi, ki, cfg.index_topk))
+        with jax.named_scope("odtp_dsa_attn"):
+            return sparse_attention(q, k, v, rows)
+
+    return attend
 
 
 def eva_attend(cfg: LlamaConfig, length=None, kept: Optional[list] = None, prefill: bool = False):
@@ -1352,6 +1575,8 @@ class BlockOut(NamedTuple):
     tails: Optional[jax.Array] = None
     # a routed FFN's choice, each token's experts [B * T, K] int32 (None: dense)
     experts: Optional[jax.Array] = None
+    # learned sparse attention: the tokens' index keys [B, T, index_head_dim]
+    index_k: Optional[jax.Array] = None
 
 
 def decoder_block(
@@ -1366,6 +1591,7 @@ def decoder_block(
     live: Optional[jax.Array] = None,
     router_in: Optional[jax.Array] = None,
     past: Optional[jax.Array] = None,
+    index_rope: Optional[tuple] = None,
 ) -> tuple[jax.Array, BlockOut]:
     """One decoder layer over h [B, T, D], the only statement of its
     skeleton: RMSNorm, the mixer, residual; RMSNorm, FFN, residual. The
@@ -1378,7 +1604,12 @@ def decoder_block(
     caller's own), ``live`` the tokens a routed FFN counts. Latent attention
     enters the same way: the projection returns q and the tokens' latent
     rows, and the caller's ``attend(q, rows, kv_b_proj)`` is its attention
-    over them, in the rebuilt form or the absorbed one -> [B, T, Nh, v]. EVA
+    over them, in the rebuilt form or the absorbed one -> [B, T, Nh, v].
+    Learned sparse attention enters as attention too: beside q, k, v the
+    layer's indexer projects index queries, the tokens' index keys and the
+    queries' head weights (``_index_qkw``, rotated by ``index_rope``), and the
+    caller's ``attend(q, k, v, qi, ki, wi)`` scores, chooses and attends over
+    the chosen rows; ``BlockOut.index_k`` is what a cache keeps of them. EVA
     enters as attention too: the caller's
     ``attend(q, k, v, adaptive_phi, adaptive_mu_k)`` pools k and v by chunk
     under the layer's two vectors and attends over the query's window and the
@@ -1393,7 +1624,7 @@ def decoder_block(
     ``residual_multiplier``, or, where the layer holds them, under a learned
     scale and bias per channel on the stream and on the branch."""
     B, T, _ = h.shape
-    tails = router_out = features = None
+    tails = router_out = features = index_k = None
     chosen: list = []
 
     def residual(h, branch, sub):
@@ -1423,6 +1654,9 @@ def decoder_block(
             q, k, v = _qkv(cfg, x, layer, cos, sin)
             # EVA's attend also pools k and v: under the layer's two vectors
             pool = (layer["adaptive_phi"], layer["adaptive_mu_k"]) if cfg.eva else ()
+            if cfg.sparse:  # its attend also scores and chooses: by the indexer's three
+                pool = _index_qkw(cfg, x, layer, *index_rope)
+                index_k = pool[1]
             attn_out = attend(q, k, v, *pool).reshape(B, T, -1) @ layer["o_proj"]
     else:
         k = v = None
@@ -1440,7 +1674,8 @@ def decoder_block(
                 router_out = router_out.reshape(B, T, -1)
         ffn, aux, counts = _ffn(cfg, x, layer, live, features, chosen)
     return residual(h, ffn, "ffn"), BlockOut(
-        k, v, attn_out, aux, counts, router_out, tails, chosen[0] if chosen else None
+        k, v, attn_out, aux, counts, router_out, tails, chosen[0] if chosen else None,
+        index_k,
     )
 
 
@@ -1456,7 +1691,10 @@ def training_block(
     attaches via forward hooks on ``self_attn`` (utils.py:43-67,
     train_fsdp.py:65)."""
     cos, sin = _rope(cfg, positions)
+    index_rope = _index_rope(cfg, positions)
     mix, attend = None, attn_fn
+    if cfg.sparse:  # its own attention over the sequence: ``attn_fn`` is not asked
+        attend = sparse_attend(cfg)
     if kind == "mamba":
         mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer)[0]
     elif cfg.latent:  # the rebuilt form: multi-head attention over k and v
@@ -1466,7 +1704,10 @@ def training_block(
 
     def body(carry, layer, li=None):
         h, r = carry
-        h, out = decoder_block(cfg, h, layer, cos, sin, attend=attend, mix=mix, router_in=r)
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, attend=attend, mix=mix, router_in=r,
+            index_rope=index_rope,
+        )
         with jax.named_scope("odtp_attention"):
             attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
         return (h, out.router), (attn_norm, out.aux)
@@ -1541,7 +1782,9 @@ def forward(
     tp_axis: Optional[str] = None,
     scan_unroll: Optional[int] = None,
 ):
-    """input_ids [B, T] int32 -> logits [B, T, V] float32.
+    """input_ids [B, T] int32 -> logits [B, T, V] float32. ``positions`` [B,
+    T], or [3, B, T] for a configuration whose rotation runs in sections
+    (``mrope_section``: temporal, height and width rows; equal for token ids).
 
     return_hidden=True returns (final_hidden [B, T, D], head [D, V]) instead
     of logits -- the hook for fused lm-head losses (ops/fused_xent.py);
@@ -1568,8 +1811,10 @@ def forward(
         )
     if attn_impl != "xla":
         refuse_eva(cfg, f"attn_impl={attn_impl!r} (a kernel of causal attention over one run of rows)")
+        refuse_sparse(cfg, f"attn_impl={attn_impl!r} (a kernel of causal attention over every row)")
     if pp_mesh is not None:
         refuse_eva(cfg, "the pp pipeline (its stages' attention is the caller's attn_fn over rows)")
+        refuse_sparse(cfg, "the pp pipeline (its stages' attention is the caller's attn_fn over rows)")
     if attn_impl == "xla":
         attn_fn = lambda q, k, v: xla_attention(q, k, v, causal=True)
     elif attn_impl == "pallas":
@@ -1747,7 +1992,10 @@ def prefill_forward(
     the pooling under way of the chunk that ``length`` lies in [L, Nkv, 2 Dh +
     2] float32 (``ops.attention.eva_pool``: a bucket's padding rows enter no
     chunk), and the K/V are then the rows of the prompt's last window alone
-    [L, min(P, window_size), Nkv, Dh] (``ring_cache.eva_window_rows``); for a
+    [L, min(P, window_size), Nkv, Dh] (``ring_cache.eva_window_rows``); for learned sparse attention then the
+    tokens' index keys [L, P, index_head_dim] (and at P <= index_topk every
+    causal row is read: the indexer scores nothing, its keys are kept all the
+    same); for a
     hybrid stack then the Mamba-2 layers' recurrent states
     [Lm, H, P, N] float32 and conv tails [Lm, K - 1, C], as the last real
     token left them; and with ``return_moe_counts`` last the routed FFN's
@@ -1766,19 +2014,24 @@ def prefill_forward(
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
     cparams = _serving_boundary(params, compute_dtype)
     cos, sin = _rope(cfg, positions)
+    index_rope = _index_rope(cfg, positions)
     live = positions < length
     attend = lambda q, k, v: xla_attention(q, k, v, causal=True)
     if cfg.latent:  # k and v rebuilt for the prompt; the rows are what is kept
         attend = rebuilt_attend(cfg, attend)
+    if cfg.sparse:
+        attend = sparse_attend(cfg)
 
     def attention_body(carry, layer, li):
         h, r = carry
         pooling: list = []  # EVA: the chunks pooled, and each chunk's pooling as stats
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, live=live, router_in=r,
+            cfg, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
             attend=eva_attend(cfg, length, pooling, prefill=True) if cfg.eva else attend,
         )
         kept = [out.k[0], None if out.v is None else out.v[0]]
+        if cfg.sparse:
+            kept.append(out.index_k[0])
         if cfg.cca:  # what the prompt's last token leaves the first decode step
             with jax.named_scope("odtp_cca"):
                 kept.append(jax.lax.dynamic_index_in_dim(out.tails[0], length - 1, 0, False))
@@ -1821,7 +2074,7 @@ def prefill_forward(
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = _logits(cfg, cparams, h_last)
     out = [logits[:, 0], *map(_stacked, kept["attention"][:2])]
-    if cfg.cca:
+    if cfg.cca or cfg.sparse:
         out.append(_stacked(kept["attention"][2]))
     if cfg.eva:
         out.extend(map(_stacked, kept["attention"][2:]))
@@ -1863,6 +2116,8 @@ def decode_forward(
     conv_state: Optional[jax.Array] = None,
     cca_state: Optional[jax.Array] = None,
     eva_state: Optional[tuple] = None,
+    index_cache: Optional[jax.Array] = None,
+    return_row_choices: bool = False,
 ):
     """One incremental decode step over all S slots.
 
@@ -1908,6 +2163,19 @@ def decode_forward(
     (``ops.decode_kernels.eva_decode_attention``). The scan carries the three
     beside the caches and they come back after them; no program copies a ring.
 
+    Learned sparse attention takes ``index_cache``, the slots' index-key ring
+    (``ring_cache.init_index_cache``) beside ``cache_{k,v}``: the step's index
+    row is written at ring row ``lens % T``, the slot's min(lens + 1, T) live
+    index rows scored and ``index_topk`` of them chosen exactly
+    (``ops.attention.decode_selection``; the ring is read as the step found it
+    and the layers' keys are written behind the scan,
+    ``decode_kernels.index_ring_write``), and the decode kernel writes the
+    step's K and V and attends over the slot's pages under that selection
+    (``paged_decode_attention``'s ``chosen``; off the TPU
+    ``ops.attention.sparse_decode_step_attention``). The ring comes back after
+    the caches; no program copies a ring. With ``return_row_choices`` each slot's chosen rows in each layer [L,
+    S, T] bool come last of all.
+
     With ``return_moe_counts`` the routed FFN's counts over the slots that
     hold a sequence (``lens > 0``), summed over layers, come last, and with
     ``return_expert_choices`` after them each slot's experts in each layer [L,
@@ -1915,6 +2183,7 @@ def decode_forward(
     cparams = _serving_boundary(params, compute_dtype)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
     cos, sin = _rope(cfg, positions)
+    index_rope = _index_rope(cfg, positions)
     live = lens > 0
     pallas = decode_kernel == "pallas"
     step_attention = paged_decode_attention if pallas else decode_step_attention
@@ -1925,6 +2194,33 @@ def decode_forward(
     def attention_body(carry, layer, li):
         # the whole caches, every layer's tails, EVA's pooled ring and stats
         h, r, ck, cv, tails, eva = carry
+        rows_chosen: list = []
+        index_keys: list = []
+
+        def over_chosen_rows(q, k, v, qi, ki, wi):
+            # the slot's live index rows and the step's own key scored and
+            # chosen (the index ring is read as the step found it: the keys
+            # are written behind the layers), then the kernel (or its XLA
+            # stand-in) over the slot's pages under that selection: it writes
+            # the step's K and V
+            nonlocal ck, cv
+            index_keys.append(ki[:, 0])
+            with jax.named_scope("odtp_dsa_index"):
+                rows = decode_selection(
+                    qi[:, 0], wi[:, 0], ki[:, 0], index_cache[li], lens, cfg.index_topk
+                )
+            if return_row_choices:
+                rows_chosen.append(rows)
+            with jax.named_scope("odtp_dsa_attn"):
+                if pallas:
+                    out, ck, cv = paged_decode_attention(
+                        q[:, 0], k[:, 0], v[:, 0], ck, cv, lens, li, chosen=rows
+                    )
+                else:
+                    out, ck, cv = sparse_decode_step_attention(
+                        q[:, 0], k[:, 0], v[:, 0], rows, ck, cv, lens, li
+                    )
+            return out[:, None]
 
         def attend(q, k, v):
             nonlocal ck, cv
@@ -1950,8 +2246,9 @@ def decode_forward(
             return latent_expand(cfg, o_lat, w_kvb)
 
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, live=live, router_in=r,
-            attend=absorbed if cfg.latent else over_two_rings if cfg.eva else attend,
+            cfg, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
+            attend=absorbed if cfg.latent else over_two_rings if cfg.eva
+            else over_chosen_rows if cfg.sparse else attend,
             past=None if tails is None else tails[li],
         )
         if tails is not None:
@@ -1960,7 +2257,10 @@ def decode_forward(
                     tails, out.tails[:, 0].astype(tails.dtype), li, 0
                 )
         eva = None if eva is None else tuple(eva)
-        return (h, out.router, ck, cv, tails, eva), (out.counts, out.experts)
+        return (h, out.router, ck, cv, tails, eva), (
+            out.counts, out.experts, rows_chosen[0] if rows_chosen else None,
+            index_keys[0] if index_keys else None,
+        )
 
     def mamba_body(carry, layer, li):
         h, r, states, tails = carry  # every Mamba-2 layer's
@@ -1973,19 +2273,19 @@ def decode_forward(
             return out[:, None]
 
         h, out = decoder_block(cfg, h, layer, cos, sin, mix=mix, live=live, router_in=r)
-        return (h, out.router, states, tails), (out.counts, out.experts)
+        return (h, out.router, states, tails), (out.counts, out.experts, None, None)
 
     h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
     r = router_carry(cfg, h)
-    counts, experts = [], []
+    counts, experts, rows = [], [], None
     for run in layer_runs(cfg):
         if run.mixer == "attention":
-            (h, r, cache_k, cache_v, cca_state, eva_state), (c, e) = scan_layers(
+            (h, r, cache_k, cache_v, cca_state, eva_state), (c, e, rows, keys) = scan_layers(
                 cfg, attention_body, (h, r, cache_k, cache_v, cca_state, eva_state),
                 cparams["layers"], run, experts_in_place=True,
             )
         else:
-            (h, r, ssm_state, conv_state), (c, e) = scan_layers(
+            (h, r, ssm_state, conv_state), (c, e, _, _) = scan_layers(
                 cfg, mamba_body, (h, r, ssm_state, conv_state), cparams["layers"], run,
                 experts_in_place=True,
             )
@@ -1997,57 +2297,138 @@ def decode_forward(
         out.append(cca_state)
     if cfg.eva:
         out.extend(eva_state)
+    if cfg.sparse:  # the step's index keys, every layer's, behind the layers
+        with jax.named_scope("odtp_dsa_index"):
+            write = index_ring_write if pallas else index_write_rows
+            out.append(write(index_cache, keys, lens))
     if cfg.hybrid:
         out.extend((ssm_state, conv_state))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_expert_choices:
         out.append(_chosen(cfg, experts))
+    if return_row_choices:
+        out.append(rows)
     return tuple(out)
 
 
-def continue_prefill(
+_SUFFIX_TILE = 512  # ring rows a tile of a run's attention where no ``q_chunk_size`` says
+
+
+def chunk_prefill_forward(
     params: dict,
-    tail: jax.Array,
-    lens: jax.Array,
+    ids: jax.Array,
+    plen: jax.Array,
+    count: jax.Array,
+    slot: jax.Array,
     cache_k: jax.Array,
     cache_v: jax.Array,
+    index_cache: Optional[jax.Array],
     cfg: LlamaConfig,
     *,
     compute_dtype: jnp.dtype = jnp.bfloat16,
-    decode_kernel: str = "xla",
+    return_moe_counts: bool = False,
+    return_row_choices: bool = False,
 ):
-    """The continued prefill of shared-prefix KV reuse: a prompt's suffix
-    run over a slot whose ring already holds its prefix.
+    """A run of a prompt's tokens over a slot that holds the rows before them:
+    a chunk of a prompt admitted in chunks, or the suffix behind a reused
+    prefix. ids [1, C] are the tokens at positions ``plen + i``, of which the
+    first ``count`` are real (a last chunk, a suffix in its bucket, is padded),
+    over ``slot``'s rings, which hold the prompt's rows [0, plen). -> (logits
+    [1, V] float32 of the last real token, cache_k, cache_v, index_cache).
 
-    tail [S, K] int32 are K tokens per slot at absolute positions
-    ``lens + i`` (the engine calls it with S = 1, tail = the suffix tokens,
-    lens = the reused prefix length); cache_{k,v} hold the ring pages as of
-    BEFORE the tail. Returns (logits [S, K, V] f32, tail_ks, tail_vs
-    [L, S, K, Nkv, Dh]): one full-depth logit row per tail position, plus
-    the tail's K/V -- kept OUT of the ring here, so a bucket's padding rows
-    never land in it; the engine inserts the suffix's true rows
-    (``ring_cache.suffix_insert``)."""
+    One program for every prompt length: ``plen``, ``count`` and ``slot`` are
+    traced. Layer by layer the run's own K and V rows go into the slot's
+    pages at [plen, plen + count) (``ring_cache.layer_rows_insert``: a padding
+    row is never written), and its C queries attend over the slot's rows a
+    tile of ring rows at a time under an online softmax
+    (``ops.attention.tiled_sparse_attention``): no [C, T] block of attention
+    scores is held where the ring is whole tiles, and tiles past the run's
+    last row are not visited. The scan carries the rings; jitted with them
+    donated the rows are written in place. ``plen + count`` lies within the
+    ring: a prompt fits its slot, and nothing wraps.
+
+    Which rows a query reads: every row up to its own, or, under learned
+    sparse attention (``index_cache``: the slot's index ring beside K and V,
+    None elsewhere), the ``index_topk`` of them that its index queries score
+    highest (``odtp_dsa_index``; the index ring is read as the run found it
+    and every layer's keys are written behind the scan, ``index_chunk_insert``;
+    the attention under the selection is ``odtp_dsa_attn``). There the tile is
+    ``q_chunk_size``, the ring is whole chunks and a prompt goes in from row 0
+    in whole chunks, so ``plen + C`` lies within the ring too. A prompt's first
+    chunk, ``plen`` 0, is the whole-prompt prefill's equations over a ring
+    that holds nothing of it yet; at ``plen + count <= index_topk`` every row
+    is chosen.
+
+    With ``return_moe_counts`` the routed FFN's counts over the real tokens
+    come after, and with ``return_row_choices`` then the rows the last real
+    token read in each layer [L, T] bool."""
     for refuse in (refuse_recurrent, refuse_latent, refuse_eva):
-        refuse(cfg, "the continued prefill (prefix reuse)")
-    S, K = tail.shape
+        refuse(cfg, "the continued prefill (a prompt's chunks, the suffix behind a reused prefix)")
+    if cfg.sparse != (index_cache is not None):
+        raise ValueError("the index ring goes with learned sparse attention, and only with it")
+    B, C = ids.shape
+    plen, count = jnp.asarray(plen, jnp.int32), jnp.asarray(count, jnp.int32)
+    positions = plen + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (B, C))
     cparams = _serving_boundary(params, compute_dtype)
-    positions = lens[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S, K]
     cos, sin = _rope(cfg, positions)
-    # tail queries over ring pages plus the tail's own K/V
-    over_ring_and_tail = tail_attention_fused if decode_kernel == "pallas" else tail_attention
+    index_rope = _index_rope(cfg, positions) if cfg.sparse else None
+    live = jnp.arange(C)[None] < count
+    T = ring_rows(cache_k)
+    tile = min(cfg.q_chunk_size or _SUFFIX_TILE, T)
+    tile = tile if T % tile == 0 else T  # a ring of no whole tiles: one tile
+    seen = jnp.arange(T)[None] <= positions[0][:, None]  # [C, T]: the rows up to a query's own
+    dsa = jax.named_scope if cfg.sparse else (lambda name: contextlib.nullcontext())
 
-    def body(h, xs):
-        layer, ck, cv = xs  # one layer's pages
-        attend = lambda q, k, v: over_ring_and_tail(q, ck, cv, k, v, lens)
-        h, out = decoder_block(cfg, h, layer, cos, sin, attend=attend)
-        return h, (out.k, out.v)
+    def body(carry, layer, li):
+        h, ck, cv = carry
+        last_row: list = []
+        own_keys: list = []
 
-    h = _embed(cfg, cparams, tail)  # [S, K, D]
-    h, (tail_ks, tail_vs) = jax.lax.scan(
-        body, h, (cparams["layers"], cache_k, cache_v)
+        def attend(q, k, v, qi=None, ki=None, wi=None):
+            nonlocal ck, cv
+            ck, cv = layer_rows_insert(
+                ck, cv, li, slot, k[0], v[0], plen, count, whole_chunks=cfg.sparse
+            )
+            rows = seen
+            if cfg.sparse:
+                own_keys.append(ki[0])
+                with dsa("odtp_dsa_index"):
+                    rows = chunk_selection(
+                        qi[0], wi[0], ki[0], slot_layer_pages(ci, li, slot), plen, cfg.index_topk
+                    )
+            if return_row_choices:
+                last_row.append(jax.lax.dynamic_index_in_dim(rows, count - 1, 0, False))
+            with dsa("odtp_dsa_attn"):
+                out = tiled_sparse_attention(
+                    q[0], slot_layer_pages(ck, li, slot), slot_layer_pages(cv, li, slot),
+                    rows, plen + count, tile,
+                )
+            return out[None]
+
+        h, out = decoder_block(
+            cfg, h, layer, cos, sin, live=live, attend=attend, index_rope=index_rope
+        )
+        return (h, ck, cv), (
+            out.counts, last_row[0] if last_row else None, own_keys[0] if own_keys else None
+        )
+
+    ci = index_cache  # read by every layer as the run found it
+    h = _embed(cfg, cparams, ids)
+    (run,) = layer_runs(cfg)
+    (h, cache_k, cache_v), (counts, rows, keys) = scan_layers(
+        cfg, body, (h, cache_k, cache_v), cparams["layers"], run, experts_in_place=True,
     )
-    return _logits(cfg, cparams, h), tail_ks, tail_vs
+    if cfg.sparse:
+        with jax.named_scope("odtp_dsa_index"):
+            index_cache = index_chunk_insert(index_cache, slot, keys, plen, count)
+    h_last = jax.lax.dynamic_slice_in_dim(h, count - 1, 1, axis=1)
+    out = [_logits(cfg, cparams, h_last)[:, 0], cache_k, cache_v, index_cache]
+    if return_moe_counts:
+        out.append(jnp.sum(counts, axis=0))
+    if return_row_choices:
+        out.append(rows)
+    return tuple(out)
 
 
 def causal_lm_loss(

@@ -16,7 +16,7 @@ Two facts live here and nowhere else:
 
 - **the order of those axes.** Everything outside this module exchanges
   K/V as rows, ``[L, rows, Nkv, Dh]`` (a prefill's output, a page on the
-  host tier, a continued prefill's tail); the functions below convert at the
+  host tier); the functions below convert at the
   module's edge (a prompt's K/V is megabytes, the cache gigabytes). The
   forwards hand one layer's pages ``[S, Nkv, Dh, T]`` to the attention
   readers (:func:`layer_pages`, or a scan over the leading axis); the
@@ -45,6 +45,40 @@ its window has ended. ``max_context`` bounds the positions and sizes the
 pooled ring alone. :func:`eva_insert` hands a prefill's current-window rows
 (:func:`eva_window_rows`), its pooled rows and the stats of the chunk the
 prompt ends in to a slot.
+
+Learned sparse attention (``cfg.sparse``) keeps **three rings of one
+lifetime** a slot: K, V and, beside them, each token's index key, written with
+the step's row and inserted with a prompt's or a chunk's rows. Token ``p``
+lives at row ``p % T`` of all three and the ``lens`` masks are the ring's
+above. **K and V keep this module's order, rows minor-most**: that is what the
+chip showed (PERF.md, PR 49). A decode step's attention reads a slot's live
+rows in order under the selection's mask, 1.13 to 1.19 ms a layer of 12 slots
+from either order, where gathering the 2,048 chosen rows of 1 KB from
+row-contiguous pages took 1.49 ms (49,152 transfers of 1 KB a layer cost more
+than seven times the bytes in order): nothing reads scattered rows, so nothing
+wants a row contiguous, and the compiler itself re-laid row-contiguous pages
+rows minor-most wherever a program's heads are a matmul's batch (3.1 GB of
+copies a ring in the chunk program, compiled for a described v5e). So the
+decode kernel reads and writes these pages as it does every other
+configuration's, under one more operand, the selection. The **index ring**
+(:func:`init_index_cache`) is ``[L, S, Di, T]``, rows minor-most too: a key of
+64 values is under the 128 lanes (this docstring's first paragraph), and the
+scoring is one ``[Hi, Di] x [Di, T]`` product a slot with the rows on the
+lanes. **No program writes it inside its scan over the layers**: compiled for
+a described v5e, a row written there (a slice update a slot, in any order of
+the axes, padded to 128 lanes or not) made the compiler keep the ring in
+another order inside the scan than at the program's edge and copy the whole
+ring in and out, 415 MB each way a decode step, and twice a layer in the chunk
+program. So the layers read the ring as the program found it, the step's (or
+the chunk's) own keys enter the scores beside it (``ops.attention``), and the
+new keys of all layers are written once, behind the scan: a chunk's as one
+block of whole lanes (:func:`index_chunk_insert`), a decode step's as a column
+a slot and layer, by a kernel that takes the 128-row block that holds it
+through an aliased output as the decode kernel does for K and V
+(``decode_kernels.index_ring_write``; :func:`index_write_rows` off the TPU).
+:func:`layer_rows_insert` writes a chunk's K and V rows into one layer's pages
+of one slot, :func:`slot_layer_pages` hands a chunk's attention that slot's
+pages.
 
 Plain functions over the ``(k, v)`` pair; every writer returns the new pair
 and callers jit them with both donated, so an update is in place at HBM.
@@ -201,6 +235,107 @@ def eva_insert(
     )
 
 
+def init_index_cache(
+    cfg, num_slots: int, max_context: int, dtype: jnp.dtype = jnp.bfloat16
+) -> jax.Array:
+    """Zeroed index-key ring for learned sparse attention, ``[L, S, Di, T]``:
+    ``num_slots`` rings of ``max_context`` rows a layer, rows minor-most."""
+    return jnp.zeros(
+        (cfg.num_hidden_layers, num_slots, cfg.index_head_dim, int(max_context)), dtype
+    )
+
+
+def index_write_rows(cache_i: jax.Array, keys: jax.Array, lens: jax.Array) -> jax.Array:
+    """A decode step's index keys of all layers, keys [L, S, Di], at ring row
+    ``lens % T`` of each slot, as XLA does it: the reference of
+    ``decode_kernels.index_ring_write`` and the path off the TPU (on the chip
+    a scatter into rows-minor pages re-lays the whole ring). A slot at
+    ``lens`` 0 holds no sequence that decodes (a prompt has a token) and is
+    written nothing: it may be a slot whose prompt is arriving in chunks, and
+    its row 0 is that prompt's."""
+    S, T = cache_i.shape[1], cache_i.shape[-1]
+    at = jnp.where(lens > 0, jnp.mod(lens, T), T)  # row T: dropped
+    return cache_i.at[:, jnp.arange(S), :, at].set(
+        jnp.moveaxis(keys, 0, 1).astype(cache_i.dtype), mode="drop"
+    )
+
+
+def index_insert(cache_i: jax.Array, iks: jax.Array, slot) -> jax.Array:
+    """A whole prompt's index keys [L, P, Di] into ``slot`` (traced) at ring
+    rows [0, P); rows beyond the prompt's length (a bucket's padding) land too,
+    stale and masked as any slot's rows beyond its length are."""
+    if iks.shape[1] > cache_i.shape[-1]:
+        raise ValueError(f"prefill length {iks.shape[1]} exceeds slot context {cache_i.shape[-1]}")
+    zero = jnp.int32(0)
+    rows = jnp.swapaxes(iks, 1, 2)[:, None].astype(cache_i.dtype)  # [L, 1, Di, P]
+    return jax.lax.dynamic_update_slice(
+        cache_i, rows, (zero, jnp.asarray(slot, jnp.int32), zero, zero)
+    )
+
+
+def index_chunk_insert(cache_i, slot, iks: jax.Array, start, count) -> jax.Array:
+    """A prefill chunk's index keys of all layers, iks [L, C, Di], into
+    ``slot`` at ring rows [start, start + C) (``slot``, ``start``, ``count``
+    traced), of which only the first ``count`` are the chunk's own: the rows
+    behind them (a last chunk's padding) keep what the ring held. ``start +
+    C`` lies within the ring (the engine holds ``max_context`` to whole
+    chunks): one block of whole lanes where the chunk is a multiple of 128."""
+    L, C, Di = iks.shape
+    zero = jnp.int32(0)
+    where = (zero, jnp.asarray(slot, jnp.int32), zero, jnp.asarray(start, jnp.int32))
+    old = jax.lax.dynamic_slice(cache_i, where, (L, 1, Di, C))
+    own = jnp.arange(C) < count
+    new = jnp.where(own, jnp.swapaxes(iks, 1, 2)[:, None].astype(cache_i.dtype), old)
+    return jax.lax.dynamic_update_slice(cache_i, new, where)
+
+
+def layer_rows_insert(cache_k, cache_v, layer, slot, k, v, start, count, whole_chunks=True):
+    """One layer's K and V rows of a prefill chunk, k and v [C, Nkv, Dh], into
+    ``slot``'s pages at ring rows [start, start + C), of which only the first
+    ``count`` are the chunk's own (as :func:`index_chunk_insert`): a block of
+    whole lanes where the chunk is a multiple of 128 rows. ``whole_chunks``
+    (static) says that ``start + C`` lies within the ring, as it does where
+    the ring is whole chunks and a prompt goes in from row 0; without it (a
+    suffix behind a prefix of any length, padded to a bucket) only ``start +
+    count`` does, and the block is the ring's last C rows where the padding
+    would pass its end, the chunk's rows moved down within it."""
+    C = k.shape[0]
+    zero = jnp.int32(0)
+    start = jnp.asarray(start, jnp.int32)
+    own = jnp.arange(C) < count
+    if not whole_chunks:  # the block's row i holds the chunk's row i - down
+        down = jnp.maximum(start + C - ring_rows(cache_k), 0)
+        start, own = start - down, jnp.roll(own, down) & (jnp.arange(C) >= down)
+    where = (jnp.asarray(layer, jnp.int32), jnp.asarray(slot, jnp.int32), zero, zero, start)
+
+    def put(cache, x):
+        old = jax.lax.dynamic_slice(cache, where, (1, 1, *cache.shape[2:4], C))
+        if not whole_chunks:
+            x = jnp.roll(x, down, axis=0)
+        new = jnp.where(own, _rows_minor(x).astype(cache.dtype)[None, None], old)
+        return jax.lax.dynamic_update_slice(cache, new, where)
+
+    return put(cache_k, k), put(cache_v, v)
+
+
+def slot_layer_pages(cache: jax.Array, layer, slot) -> jax.Array:
+    """One slot's part of one layer's pages (both traced): K or V [Nkv, Dh,
+    T], or the index ring's [Di, T]."""
+    return jax.lax.dynamic_index_in_dim(
+        jax.lax.dynamic_index_in_dim(cache, layer, 0, False), slot, 0, False
+    )
+
+
+def write_live_row(cache_k, cache_v, layer, k, v, lens):
+    """:func:`write_row` that writes nothing for a slot at ``lens`` 0 (the
+    decode step of a configuration whose prompts may be arriving in chunks: a
+    prefilling slot rides no step, and its row 0 is its prompt's)."""
+    rows = jnp.arange(cache_k.shape[1])
+    idx = jnp.where(lens > 0, jnp.mod(lens, ring_rows(cache_k)), ring_rows(cache_k))
+    put = lambda cache, x: cache.at[layer, rows, :, :, idx].set(x.astype(cache.dtype), mode="drop")
+    return put(cache_k, k), put(cache_v, v)
+
+
 def cache_shape(
     layers: int, slots: int, rows: int, kv_heads: int, head_dim: int
 ) -> tuple[int, ...]:
@@ -300,43 +435,6 @@ def prefix_copy(
         return cache.at[:, dst].set(page)
 
     return copy(cache_k), copy(cache_v)
-
-
-def suffix_insert(
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    ks: jax.Array,
-    vs: jax.Array,
-    slot: jax.Array,
-    start: jax.Array,
-    count: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """Write a continued prefill's suffix K/V [L, P', Nkv, Dh] into ``slot``
-    at rows [start, start + count) -- the positioned counterpart of
-    :func:`cache_insert` (a prompt always fits its page, so no ring wrap
-    here; padding rows beyond ``count`` are dropped)."""
-    T, P = ring_rows(cache_k), ks.shape[1]
-    disp = jnp.arange(T, dtype=jnp.int32) - jnp.asarray(start, jnp.int32)
-    valid = (disp >= 0) & (disp < count)
-    gidx = jnp.clip(disp, 0, P - 1)
-
-    def put(cache, x):
-        page = jnp.take(cache, slot, axis=1)  # [L, Nkv, Dh, T]
-        x = _rows_minor(x[:, gidx]).astype(cache.dtype)
-        return cache.at[:, slot].set(jnp.where(valid, x, page))
-
-    return put(cache_k, ks), put(cache_v, vs)
-
-
-def slot_cache(
-    cache_k: jax.Array, cache_v: jax.Array, slot: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """One slot's pages as a cache of a single slot, [L, 1, Nkv, Dh, T] each:
-    what a forward over that slot alone (the continued prefill) reads."""
-    return (
-        jnp.take(cache_k, slot, axis=1)[:, None],
-        jnp.take(cache_v, slot, axis=1)[:, None],
-    )
 
 
 def fetch_pages(

@@ -21,7 +21,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from opendiloco_tpu.models.ring_cache import layer_pages, rows_first, write_row
+from opendiloco_tpu.models.ring_cache import (
+    layer_pages,
+    rows_first,
+    write_live_row,
+    write_row,
+)
 
 
 def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
@@ -70,6 +75,7 @@ def decode_attention(
     k: jax.Array,
     v: jax.Array,
     lens: jax.Array,
+    chosen: jax.Array | None = None,
 ) -> jax.Array:
     """Single-token decode attention over a slot-paged ring KV cache.
 
@@ -85,6 +91,9 @@ def decode_attention(
     ``ring_cache.ring_live_rows`` rows at row 0, so validity is still fully
     determined by ``lens``.
 
+    ``chosen`` [S, T] bool (learned sparse attention): of the valid entries
+    only these enter the softmax.
+
     Math matches :func:`xla_attention` row-for-row — f32 scores/softmax,
     probabilities cast back to q.dtype — so incremental decode reproduces
     the training-mode forward (pinned by tests/test_serve.py).
@@ -99,6 +108,8 @@ def decode_attention(
     scores = scores * scale
     idx = jax.lax.broadcasted_iota(jnp.int32, (s, t), 1)
     valid = (idx <= lens[:, None]) | (lens[:, None] >= t)
+    if chosen is not None:
+        valid = valid & chosen
     scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("sht,sthd->shd", probs, v)
@@ -153,77 +164,6 @@ def latent_decode_step_attention(
     scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("sht,sdt->shd", probs, pages[:, :value_dim]), cache
-
-
-def tail_attention(
-    q: jax.Array,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tail_k: jax.Array,
-    tail_v: jax.Array,
-    lens: jax.Array,
-) -> jax.Array:
-    """Multi-token tail attention over a ring KV cache plus in-register
-    tail K/V — the continued prefill's attention (prefix reuse).
-
-    q [S, K, H, D] are the tail's tokens per slot at absolute positions
-    ``lens + i``; cache_{k,v} hold one layer's ring pages ([S, Kh, D, T],
-    ``ring_cache``'s order, read as rows through the module) as of BEFORE
-    the tail (positions <= lens - 1); tail_{k,v} [S, K, Kh, D] are the
-    tail's own K/V, kept out of the ring until the caller inserts them.
-
-    The masking reproduces the sequential one-token loop exactly,
-    including ring wrap: tail query i attends tail tokens <= i plus the
-    ring entries the sequential path would still hold at its step — a
-    ring slot is dropped for query i when the write of tail token j <= i
-    would have overwritten it (that is, when ``(lens + j) % T`` lands on
-    it with ``lens + j >= T``), which is precisely the sliding-window
-    eviction the per-step ring write performs. Softmax terms for masked
-    entries are exact zeros, so extra masked slots never perturb the
-    live reductions (same invariant the prefill bucket-padding relies
-    on).
-    """
-    cache_k, cache_v = rows_first(cache_k), rows_first(cache_v)
-    s, t, nkv, d = cache_k.shape
-    kq = q.shape[1]
-    kt = tail_k.shape[1]
-    h = q.shape[2]
-    ck = _repeat_kv(cache_k, h)
-    cv = _repeat_kv(cache_v, h)
-    tk = _repeat_kv(tail_k, h)
-    tv = _repeat_kv(tail_v, h)
-    scale = d**-0.5
-
-    # ring scores [S, H, Kq, T]
-    ring_scores = jnp.einsum(
-        "sqhd,sthd->shqt", q, ck, preferred_element_type=jnp.float32
-    ) * scale
-    idx = jax.lax.broadcasted_iota(jnp.int32, (s, t), 1)
-    lens_ = lens[:, None].astype(jnp.int32)
-    base = (idx < lens_) | (lens_ >= t)  # live pre-tail entries
-    # disp = the i whose tail ring write lands on this slot ((lens+i) % T)
-    disp = jnp.mod(idx - lens_, t)
-    j = jnp.arange(kq, dtype=jnp.int32)[None, :, None]  # [1, Kq, 1]
-    evicted = (disp[:, None, :] <= j) & (
-        (lens_[:, None, :] + disp[:, None, :]) >= t
-    )
-    ring_valid = base[:, None, :] & ~evicted  # [S, Kq, T]
-    neg = jnp.finfo(jnp.float32).min
-    ring_scores = jnp.where(ring_valid[:, None], ring_scores, neg)
-
-    # tail scores [S, H, Kq, Kt], causal within the tail
-    tail_scores = jnp.einsum(
-        "sqhd,skhd->shqk", q, tk, preferred_element_type=jnp.float32
-    ) * scale
-    qi = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 0)
-    ki = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 1)
-    tail_scores = jnp.where((ki <= qi)[None, None], tail_scores, neg)
-
-    scores = jnp.concatenate([ring_scores, tail_scores], axis=-1)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("shqt,sthd->sqhd", probs[..., :t], cv)
-    out = out + jnp.einsum("shqk,skhd->sqhd", probs[..., t:], tv)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -409,3 +349,208 @@ def eva_decode_step_attention(
     out = jnp.einsum("sht,sthd->shd", probs[..., :t], _repeat_kv(lv, h))
     out = out + jnp.einsum("sht,sthd->shd", probs[..., t:], _repeat_kv(pv, h))
     return out, cache_k, cache_v, pool_k, pool_v, stats
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention (DeepSeek sparse attention's lightning indexer, in
+# the form Keye-VL-2.0 publishes under ``sa_config``): beside K and V a token
+# keeps one index key; a query's ``index_n_heads`` index queries score every
+# live row, I = sum_j w_j relu(q_j . k), the ``index_topk`` largest are the
+# rows its attention reads, one set for all its heads. The plain forms:
+# scoring, the exact selection, attention under the selection over a whole
+# sequence (training, a whole-prompt prefill), over a slot's ring in row
+# tiles (a prefill chunk) and a decode step's over a slot's rings
+# (``ring_cache``: the K and V rings as every configuration's, rows minor-most;
+# the index ring too, and never written inside a scan over the layers: the
+# new keys enter the scores beside it). The decode kernel's form is
+# ``decode_kernels.paged_decode_attention`` under its ``chosen`` operand.
+# ---------------------------------------------------------------------------
+
+# index queries scored in one product while its float32 result stays under
+# this many bytes; beyond it head by head, one [.., Q, Tk] block at a time
+_INDEX_AT_ONCE_BYTES = 64 * 1024 * 1024
+
+
+def index_scores(qi: jax.Array, wi: jax.Array, keys_t: jax.Array) -> jax.Array:
+    """The indexer's scores: index queries qi [B, Q, Hi, Di] under the
+    queries' head weights wi [B, Q, Hi] against index keys ``keys_t`` [B, Di,
+    Tk] (rows minor-most, as the index ring holds them) -> [B, Q, Tk]
+    float32, I[q, s] = sum_j w[q, j] relu(qi[q, j] . k[s]). Products
+    accumulated in float32, the ReLU and the weighted sum over heads in
+    float32. The positive constants Di^-1/2 and Hi^-1/2 of the published form
+    change no order and are left out."""
+    f32 = jnp.float32
+    b, q, hi, _ = qi.shape
+    tk = keys_t.shape[-1]
+    if b * q * hi * tk * 4 <= _INDEX_AT_ONCE_BYTES:
+        # the queries' heads as rows of one product a batch entry
+        s = jnp.einsum(
+            "bmd,bdt->bmt", qi.reshape(b, q * hi, -1), keys_t, preferred_element_type=f32
+        ).reshape(b, q, hi, tk)
+        return jnp.sum(jax.nn.relu(s) * wi.astype(f32)[..., None], axis=2)
+
+    def head(acc, xs):
+        qj, wj = xs  # [B, Q, Di], [B, Q]
+        s = jnp.einsum("bqd,bdt->bqt", qj, keys_t, preferred_element_type=f32)
+        return acc + jax.nn.relu(s) * wj.astype(f32)[..., None], None
+
+    acc, _ = jax.lax.scan(
+        head, jnp.zeros((b, q, tk), f32), (jnp.moveaxis(qi, 2, 0), jnp.moveaxis(wi, 2, 0))
+    )
+    return acc
+
+
+def _ordered_bits(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 whose unsigned order is the floats' order, -0.0 (a
+    negative head weight times a ReLU's zero) taken for the +0.0 it equals."""
+    x = x.astype(jnp.float32)
+    i = jax.lax.bitcast_convert_type(jnp.where(x == 0, jnp.float32(0), x), jnp.int32)
+    i = i ^ ((i >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def select_rows(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
+    """The selection, exactly: of each query's ``valid`` rows (scores, valid
+    [..., Tk]) the min(k, their number) of largest score, ties to the lower
+    index -> bool [..., Tk]. No sort: the k-th largest score is found bit by
+    bit (32 counts over the rows: the largest value that at least k rows
+    reach), every row above it is chosen, and of the rows equal to it the
+    first as many as are still missing (a running count over the rows, made
+    only where some query has such a tie). With fewer than k valid rows every
+    valid row is chosen."""
+    u = jnp.where(valid, jnp.maximum(_ordered_bits(scores), jnp.uint32(1)), jnp.uint32(0))
+
+    def bit(b, prefix):
+        cand = prefix | (jnp.uint32(1) << jnp.asarray(31 - b, jnp.uint32))
+        reach = jnp.sum((u >= cand[..., None]).astype(jnp.int32), axis=-1)
+        return jnp.where(reach >= k, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = u > kth[..., None]
+    equal = (u == kth[..., None]) & valid
+    missing = k - jnp.sum(above.astype(jnp.int32), axis=-1)
+
+    def first_of_the_ties():  # more rows equal the k-th score than are missing
+        first = jnp.cumsum(equal.astype(jnp.int32), axis=-1) <= missing[..., None]
+        return above | (equal & first)
+
+    tied = jnp.any(jnp.sum(equal.astype(jnp.int32), axis=-1) > missing)
+    return jax.lax.cond(tied, first_of_the_ties, lambda: above | equal)
+
+
+def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array, chosen: jax.Array) -> jax.Array:
+    """Attention under a selection over a whole sequence: q [B, Tq, H, D], k
+    and v [B, Tk, Kh, D], ``chosen`` [B, Tq, Tk] bool, one set of rows a query
+    for all its heads -> [B, Tq, H, D]. Scores and softmax in float32 as
+    :func:`xla_attention`'s; a query head reads its KV head's rows in place
+    (no repeated K or V)."""
+    b, tq, h, d = q.shape
+    kh = k.shape[2]
+    qg = q.reshape(b, tq, kh, h // kh, d)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=jnp.float32)
+    scores = jnp.where(chosen[:, None, None], scores * d**-0.5, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(b, tq, h, d)
+
+
+def causal_selection(qi, wi, ki, topk: int) -> jax.Array:
+    """The rows each position of a whole sequence from position 0 reads: index
+    queries qi [B, T, Hi, Di] and weights wi [B, T, Hi] over the sequence's
+    own index keys ki [B, T, Di], s <= t -> bool [B, T, T]. A sequence no
+    longer than ``topk`` keeps every causal row and scores nothing."""
+    b, t = ki.shape[:2]
+    causal = jnp.tril(jnp.ones((t, t), bool))[None]
+    if t <= topk:
+        return jnp.broadcast_to(causal, (b, t, t))
+    return select_rows(index_scores(qi, wi, jnp.swapaxes(ki, 1, 2)), causal, topk)
+
+
+def tiled_sparse_attention(
+    q: jax.Array, pages_k: jax.Array, pages_v: jax.Array, chosen: jax.Array, live_rows, tile: int,
+) -> jax.Array:
+    """A prefill chunk's attention under its selection over one slot's pages,
+    ``tile`` rows at a time under an online softmax, so that no [C, T] score
+    block a head is ever held: q [C, H, D], pages_k and pages_v [Kh, D, T]
+    (one layer's pages of the slot, the chunk's own rows in them), ``chosen``
+    [C, T] bool -> [C, H, D]. Tiles from ``live_rows`` (traced) on hold nothing
+    chosen and are not visited. A query with no chosen row (a bucket's
+    padding) comes out zero."""
+    c, h, d = q.shape
+    kh, _, t = pages_k.shape
+    f32, neg = jnp.float32, jnp.finfo(jnp.float32).min
+    qg = jnp.moveaxis(q.reshape(c, kh, h // kh, d), 0, 2)  # [Kh, rep, C, D]
+
+    def visit(i, carry):
+        m, l, acc = carry
+        at = i * tile
+        kt = jax.lax.dynamic_slice_in_dim(pages_k, at, tile, 2)  # [Kh, D, tile]
+        vt = jax.lax.dynamic_slice_in_dim(pages_v, at, tile, 2)
+        ct = jax.lax.dynamic_slice_in_dim(chosen, at, tile, 1)[None, None]
+        s = jnp.einsum("grcd,gdt->grct", qg, kt, preferred_element_type=f32) * d**-0.5
+        s = jnp.where(ct, s, neg)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(ct, jnp.exp(s - m_new[..., None]), 0.0)
+        keep = jnp.exp(m - m_new)
+        l = l * keep + jnp.sum(p, axis=-1)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "grct,gdt->grcd", p.astype(q.dtype), vt, preferred_element_type=f32
+        )
+        return m_new, l, acc
+
+    shape = (kh, h // kh, c)
+    init = (jnp.full(shape, neg, f32), jnp.zeros(shape, f32), jnp.zeros((*shape, d), f32))
+    tiles = (jnp.asarray(live_rows, jnp.int32) + tile - 1) // tile
+    _, l, acc = jax.lax.fori_loop(0, jnp.minimum(tiles, t // tile), visit, init)
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return jnp.moveaxis(out, 2, 0).reshape(c, h, d).astype(q.dtype)
+
+
+def decode_selection(qi, wi, ki, keys_t, lens, topk: int) -> jax.Array:
+    """The rows a decode step's queries read: each slot's index queries qi [S,
+    Hi, Di] under their weights wi [S, Hi] over the slot's index rows
+    ``keys_t`` [S, Di, T] **as they were before the step** and the step's own
+    key ki [S, Di], which stands at ring row ``lens % T`` whatever the ring
+    holds there (it is written behind the layers); min(lens + 1, T) rows are
+    live -> bool [S, T], the ``topk`` largest, exactly."""
+    s, t = keys_t.shape[0], keys_t.shape[-1]
+    scores = index_scores(qi[:, None], wi[:, None], keys_t)[:, 0]
+    # the one key a slot by products and a sum in float32: exact products, and
+    # no matmul of a single column
+    f32 = jnp.float32
+    own = jnp.sum(qi.astype(f32) * ki.astype(f32)[:, None], axis=-1)  # [S, Hi]
+    own = jnp.sum(jax.nn.relu(own) * wi.astype(f32), axis=-1)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (s, t), 1)
+    scores = jnp.where(idx == jnp.mod(lens, t)[:, None], own[:, None], scores)
+    live = (idx <= lens[:, None]) | (lens[:, None] >= t)
+    return select_rows(scores, live, topk)
+
+
+def chunk_selection(qi, wi, ki, keys_t, plen, topk: int) -> jax.Array:
+    """The rows a prefill chunk's queries read: the chunk's index queries qi
+    [C, Hi, Di] under their weights wi [C, Hi], at positions ``plen + i``, over
+    the slot's index rows ``keys_t`` [Di, T] as they were before the chunk
+    (rows [0, plen) are the prompt's) and the chunk's own keys ki [C, Di],
+    which stand at rows [plen, plen + C) whatever the ring holds there; query
+    i sees rows [0, plen + i] -> bool [C, T], the ``topk`` largest, exactly."""
+    c, t = qi.shape[0], keys_t.shape[-1]
+    scores = index_scores(qi[None], wi[None], keys_t[None])[0]
+    own = index_scores(qi[None], wi[None], ki.T[None])[0]  # [C, C]
+    scores = jax.lax.dynamic_update_slice(scores, own, (jnp.int32(0), jnp.asarray(plen, jnp.int32)))
+    seen = jnp.arange(t)[None] <= (plen + jnp.arange(c))[:, None]
+    return select_rows(scores, seen, topk)
+
+
+def sparse_decode_step_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, chosen: jax.Array,
+    cache_k: jax.Array, cache_v: jax.Array, lens: jax.Array, layer,
+):
+    """One layer's share of a decode step of learned sparse attention in XLA:
+    the step's rows (k, v [S, Kh, D]) written at ring row ``lens % T`` of
+    ``layer``'s pages (nothing for a slot at ``lens`` 0), then q [S, H, D] over
+    the slot's rows under the selection ``chosen`` [S, T] (which the ``lens``
+    masks already bound) -> (out, cache_k, cache_v). The reference of
+    ``decode_kernels.paged_decode_attention`` under its ``chosen`` operand,
+    which has this signature, and its per-call fallback."""
+    cache_k, cache_v = write_live_row(cache_k, cache_v, layer, k, v, lens)
+    out = decode_attention(q, *layer_pages(cache_k, cache_v, layer), lens, chosen)
+    return out, cache_k, cache_v

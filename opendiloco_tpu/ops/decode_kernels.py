@@ -1,11 +1,9 @@
 """Pallas TPU serving kernels: ragged paged decode attention (over a ``(k,
-v)`` ring, and over a latent ring in the absorbed form) and the continued
-prefill's tail attention.
+v)`` ring, and over a latent ring in the absorbed form).
 
 The XLA paths they stand in for (``decode_attention`` dense-masks the
-whole ring page per slot, ``tail_attention`` materializes full repeat-KV
-score tensors) stay as the off-TPU path and the reference: every kernel is
-token-bit-exact against them (PagedAttention-style
+whole ring page per slot) stay as the off-TPU path and the reference:
+every kernel is token-bit-exact against them (PagedAttention-style
 cache-aware decode, arXiv 2309.06180).
 
 - :func:`paged_decode_attention` is the decode step's whole traffic with
@@ -41,12 +39,6 @@ cache-aware decode, arXiv 2309.06180).
   first ``R`` rows, so a live row is read once a layer and step. It checks
   against ``latent_decode_step_attention`` to rounding, not to the bit (the
   row reaches its tile through a one-hot product on the MXU).
-- :func:`tail_attention_fused` implements ``tail_attention``'s
-  exact ring-wrap eviction mask over cache AND in-register tail K/V in
-  one online-softmax pass — the ring blocks stream first (dead blocks
-  skipped via ``lens`` like the decode kernel), the tail block runs
-  last, and no concat-mask score tensor is ever built.
-
 Dispatch: ``ODTP_DECODE_KERNEL=auto|pallas|xla`` (``ServeConfig.
 decode_kernel``). ``auto`` — the default — selects Pallas only when the
 backend is TPU; off-TPU it always resolves to the XLA paths, so CPU rigs
@@ -54,7 +46,7 @@ keep today's exact code. Forcing ``pallas`` off-TPU runs the kernels in
 Pallas interpret mode (slow, but semantically the kernel) — that is how
 the parity tests pin token-bit-exactness on a CPU rig. Shapes a kernel
 cannot tile (head_dim not a multiple of 8, a ring whose rows are not a
-multiple of the 128 lanes, a tail too tall for VMEM) fall
+multiple of the 128 lanes) fall
 back to the XLA path per call, mirroring ``flash_attention``'s fallback
 contract.
 """
@@ -70,7 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from opendiloco_tpu.models.ring_cache import ring_rows
+from opendiloco_tpu.models.ring_cache import index_write_rows, ring_rows
 from opendiloco_tpu.ops.attention import (
     _repeat_kv,
     decode_step_attention,
@@ -78,7 +70,7 @@ from opendiloco_tpu.ops.attention import (
     eva_attention,
     eva_decode_step_attention,
     latent_decode_step_attention,
-    tail_attention,
+    sparse_decode_step_attention,
 )
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
@@ -196,14 +188,22 @@ def _lanes32(x):
 
 
 def _decode_attn_kernel(
-    lens_ref, layer_ref, q_ref, kn_ref, vn_ref, knt_ref, vnt_ref, k_ref, v_ref,
-    o_ref, ko_ref, vo_ref, *rest,
-    scale, block_t, t, num_t, rep, with_stats, eva_ring=None,
+    lens_ref, layer_ref, *rest,
+    scale, block_t, t, num_t, rep, with_stats, eva_ring=None, with_selection=False,
 ):
+    rest = list(rest)
+    # under a selection (learned sparse attention): a third prefetched vector,
+    # the ring row each slot's new row goes to (-1: the slot holds no sequence
+    # and is written nothing), and behind the caches one more operand, the
+    # rows of this tile that the slot's indexer chose
+    at_ref = rest.pop(0) if with_selection else None
+    q_ref, kn_ref, vn_ref, knt_ref, vnt_ref, k_ref, v_ref = rest[:7]
+    sel_ref = rest[7] if with_selection else None
+    o_ref, ko_ref, vo_ref = rest[7 + with_selection : 10 + with_selection]
     # the stats block's index ignores the ring axis, so it stays resident
     # across ti and doubles as the counter: a vector add, since Mosaic
     # cannot store a scalar to VMEM
-    rest = list(rest)
+    rest = rest[10 + with_selection :]
     stats_ref = rest.pop(0) if with_stats else None
     # one of EVA's two rings (``paged_decode_attention``'s ``eva_ring``): the
     # softmax's running maximum and sum go out too, for the caller's merge of
@@ -267,7 +267,8 @@ def _decode_attn_kernel(
     # the step's own row goes to ring row lens % t, in a block that is
     # always live (it is the last live one until the ring wraps); new_at is
     # its lane in this tile, if it lies here
-    new_at = jax.lax.rem(lens_s, t) - ti * block_t
+    row_at = jax.lax.rem(lens_s, t) if at_ref is None else at_ref[si]
+    new_at = row_at - ti * block_t
 
     @pl.when(ti < live_tiles if pooled else ti <= last_live)
     def _step():
@@ -286,10 +287,14 @@ def _decode_attn_kernel(
         if not pooled:  # (a pooled tile that is read is live whole and unpatched)
             s_new = snew_scr[:]
             s = jnp.where(at_row, s_new, s)
+            if with_selection:  # of the live rows, those the indexer chose
+                valid = valid & (sel_ref[:] > 0)
             s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if with_selection:  # a live tile may hold no chosen row: its maximum stays NEG_INF
+            p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
@@ -303,6 +308,11 @@ def _decode_attn_kernel(
             def _own_value():
                 # p[:, new_at] x v_new, rounded as the MXU's operand is
                 p_new = jnp.exp(s_new - m_new).astype(v_blk.dtype)
+                if with_selection:  # the step's own row may not be among the chosen
+                    own = jnp.sum(
+                        jnp.where(at_row[:1], sel_ref[:], 0), axis=1, keepdims=True
+                    )
+                    p_new = jnp.where(own > 0, p_new, jnp.zeros_like(p_new))
                 acc_scr[:] += p_new.astype(f32) * side_by_side(vn_ref).astype(f32)
 
         if with_stats:
@@ -316,8 +326,12 @@ def _decode_attn_kernel(
     back = ko_ref.shape[-1]
     for b in range(block_t // back):
         at = new_at - b * back
+        here_it_lies = (at >= 0) & (at < back)
+        if with_selection and b == 0:
+            # a slot that is written nothing hands its first block back as it was
+            here_it_lies = here_it_lies | ((row_at < 0) & (ti == 0))
 
-        @pl.when((at >= 0) & (at < back))
+        @pl.when(here_it_lies)
         def _write():
             lanes = knt_ref.shape[-1]
             shift = jax.lax.rem(at - jax.lax.rem(si, lanes) + lanes, lanes)
@@ -371,6 +385,7 @@ def paged_decode_attention(
     interpret: bool | None = None,
     return_stats: bool = False,
     eva_ring: int | None = None,
+    chosen: jax.Array | None = None,
 ):
     """One layer's share of a decode step against the ring cache: write
     each slot's new row (k, v [S, Nkv, D]) at ring row ``lens % T`` of
@@ -393,7 +408,16 @@ def paged_decode_attention(
     ``lens`` and *not* read, and the rows read are those of the windows before
     the one it lies in, [0, lens // n * n) (kernel name
     ``odtp_eva_pooled_attn``). There is no XLA stand-in for either: no plan
-    raises. :func:`eva_decode_attention` is the caller of both."""
+    raises. :func:`eva_decode_attention` is the caller of both.
+
+    ``chosen`` (None for every configuration but one with learned sparse
+    attention) [S, T] bool is a selection: of each slot's live rows the kernel
+    reads the tiles as ever and lets only the chosen rows into the softmax
+    (the step's own row too only if it is among them), a ``(1, block_t)`` tile
+    of the selection beside each ``(heads, Dh, block_t)`` tile of K and V. A
+    slot at ``lens`` 0 is then written nothing (its first block goes back as
+    it was): it may be one whose prompt is arriving in chunks. The XLA
+    stand-in is ``sparse_decode_step_attention``."""
     # Mosaic requires the last two dims of every block to be (8, 128)-
     # aligned OR equal to the array's own dims. The cache's two minor dims
     # are (D, T), so a (d, bt) tile is legal for bt a multiple of 128. The
@@ -415,6 +439,8 @@ def paged_decode_attention(
             f"alone, and it has no plan for {nkv} KV heads of {d} over {t} rows "
             f"(windows of {eva_ring} pooled rows)"
         )
+    if not plan and chosen is not None:
+        return sparse_decode_step_attention(q, k, v, chosen, cache_k, cache_v, lens, layer)
     if not plan:
         res = decode_step_attention(q, k, v, cache_k, cache_v, lens, layer)
         return (*res, None) if return_stats else res
@@ -430,22 +456,28 @@ def paged_decode_attention(
     knt = jnp.pad(kn.reshape(s_, nkv * d).T, ((0, 0), (0, -s_ % _LANES)))
     vnt = jnp.pad(vn.reshape(s_, nkv * d).T, ((0, 0), (0, -s_ % _LANES)))
 
-    def kv_map(si, gi, ti, lens_ref, layer_ref):
+    # the prefetched vectors: lens, layer and, under a selection, the row written
+    def kv_map(si, gi, ti, lens_ref, layer_ref, *_):
         # clamp dead blocks to the last live one: unchanged index = no DMA
         last = jnp.minimum(lens_ref[si], t - 1) // bt
         return (layer_ref[0], si, gi, 0, jnp.minimum(ti, last))
 
-    def written_map(si, gi, ti, lens_ref, layer_ref):
+    def written_map(si, gi, ti, lens_ref, layer_ref, *at_ref):
         # the one block of (slot, head group) that goes back: the row's
-        return (layer_ref[0], si, gi, 0, jax.lax.rem(lens_ref[si], t) // back)
+        layer = layer_ref[0]
+        at = jnp.maximum(at_ref[0][si], 0) if at_ref else jax.lax.rem(lens_ref[si], t)
+        return (layer, si, gi, 0, at // back)
 
-    def q_map(si, gi, ti, lr, yr):
+    def q_map(si, gi, ti, *_):
         return (si, gi, 0, 0)
+
+    def chosen_map(si, gi, ti, lens_ref, *_):
+        return (si, 0, jnp.minimum(ti, jnp.minimum(lens_ref[si], t - 1) // bt))
 
     queries = pl.BlockSpec((None, None, rows, d), q_map)
     row = pl.BlockSpec((None, hb, 1, d), q_map)
     column = pl.BlockSpec(
-        (width, _LANES), lambda si, gi, ti, lr, yr: (gi, si // _LANES)
+        (width, _LANES), lambda si, gi, ti, *_: (gi, si // _LANES)
     )
     out_specs = [
         queries,
@@ -471,8 +503,13 @@ def paged_decode_attention(
         out_specs += [pl.BlockSpec((None, None, rows, 1), q_map)] * 2
         out_shape += [jax.ShapeDtypeStruct((s_, nkv // hb, rows, 1), jnp.float32)] * 2
 
+    prefetched = [lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1)]
+    selection = []
+    if chosen is not None:
+        prefetched.append(jnp.where(lens > 0, jax.lax.rem(lens, t), -1).astype(jnp.int32))
+        selection = [chosen.astype(jnp.int32).reshape(s_, 1, t)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetched),
         grid=(s_, nkv // hb, num_t),
         in_specs=[
             queries,
@@ -482,6 +519,7 @@ def paged_decode_attention(
             column,
             pl.BlockSpec((None, None, hb, d, bt), kv_map),
             pl.BlockSpec((None, None, hb, d, bt), kv_map),
+            *([pl.BlockSpec((None, 1, bt), chosen_map)] if selection else []),
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -492,27 +530,69 @@ def paged_decode_attention(
             scale=d**-0.5, block_t=bt, t=t, num_t=num_t, rep=rep,
             with_stats=return_stats,
             **({} if eva_ring is None else {"eva_ring": int(eva_ring)}),
+            **({"with_selection": True} if selection else {}),
         ),
         name="odtp_eva_pooled_attn" if eva_ring else "odtp_paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=out_shape,
-        # operands count the two scalar-prefetch vectors: the caches are
-        # inputs 7 and 8, and come back as outputs 1 and 2
-        input_output_aliases={7: 1, 8: 2},
+        # operands count the scalar-prefetch vectors (two, three under a
+        # selection): the caches follow five inputs behind them, and come back
+        # as outputs 1 and 2
+        input_output_aliases={len(prefetched) + 5: 1, len(prefetched) + 6: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interp,
-    )(
-        lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        q4, kn, vn, knt, vnt, cache_k, cache_v,
-    )
+    )(*prefetched, q4, kn, vn, knt, vnt, cache_k, cache_v, *selection)
     out = (res[0].reshape(s_, h, d), res[1], res[2])
     if return_stats:
         out = (*out, res[3].reshape(s_, nkv))
     if eva_ring is not None:
         out = (*out, res[-2].reshape(s_, h), res[-1].reshape(s_, h))
     return out
+
+
+def _index_write_kernel(at_ref, key_ref, ring_ref, out_ref):
+    at = at_ref[pl.program_id(1)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, ring_ref.shape, 1)
+    here = (lane == jax.lax.rem(jnp.maximum(at, 0), ring_ref.shape[1])) & (at >= 0)
+    f32 = jnp.float32  # the select in 32 bits: exact both ways
+    out_ref[:] = jnp.where(here, key_ref[:].astype(f32), ring_ref[:].astype(f32)).astype(out_ref.dtype)
+
+
+def index_ring_write(
+    cache_i: jax.Array, keys: jax.Array, lens: jax.Array, *, interpret: bool | None = None
+) -> jax.Array:
+    """A decode step's index keys of all layers, keys [L, S, Di], into the
+    index ring ``cache_i`` [L, S, Di, T] at ring row ``lens % T`` of each slot
+    (nothing for a slot at ``lens`` 0): what ``ring_cache.index_write_rows``
+    gives, with the ring written where it lies. A grid step a layer and slot
+    takes the one 128-row block that holds the row, the key as a column
+    selected into it, and hands it back through an output aliased to the ring
+    (an XLA scatter or slice update into rows-minor pages re-lays the whole
+    ring: ``ring_cache``). A ring that 128 rows do not divide keeps the XLA
+    path."""
+    L, S, di, t = cache_i.shape
+    if t % _LANES or di % 8:
+        return index_write_rows(cache_i, keys, lens)
+    at = jnp.where(lens > 0, jax.lax.rem(lens, t), -1).astype(jnp.int32)
+    block = lambda li, si, at_ref: (li, si, 0, jnp.maximum(at_ref[si], 0) // _LANES)
+    return pl.pallas_call(
+        _index_write_kernel,
+        name="odtp_index_ring_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(L, S),
+            in_specs=[
+                pl.BlockSpec((None, None, di, 1), lambda li, si, at_ref: (li, si, 0, 0)),
+                pl.BlockSpec((None, None, di, _LANES), block),
+            ],
+            out_specs=pl.BlockSpec((None, None, di, _LANES), block),
+        ),
+        out_shape=jax.ShapeDtypeStruct(cache_i.shape, cache_i.dtype),
+        input_output_aliases={2: 0},
+        interpret=_interpret(interpret),
+    )(at, keys.astype(cache_i.dtype)[..., None], cache_i)
 
 
 def eva_prefill_form(window: int, d: int, interpret: bool | None = None) -> str:
@@ -817,191 +897,3 @@ def mla_decode_attention(
         q, new, cache,
     )
     return out, cache
-
-
-# ---------------------------------------------------------------------------
-# (b) the continued prefill's tail attention (ring + in-register tail, one pass)
-# ---------------------------------------------------------------------------
-
-
-# One grid step holds a whole GQA group's tail in VMEM: rep * Kq query rows
-# with f32 (m, l, acc) scratch, the [.., 1]-wide stats padded to 128 lanes.
-# Compiled deviceless for v5e (16 MiB scoped VMEM), head_dim 64 and 128,
-# bf16 and f32: 4096 rows fit, 8192 do not. The continued prefill (Kq = a
-# suffix bucket) reaches it at GQA 32/4 with a 1024-token suffix, which
-# keeps the XLA path. The tail step also holds one head's [Kq, Kq]
-# probabilities and mask: 1024 x 1024 compile at head_dim 64 and 128 (and
-# are what tests/test_tpu_compile.py pins), a suffix bucket of 3072 (27 MB)
-# does not, and 1280 rows at GQA 15/5, head_dim 64 (3840 group rows) do not
-# either, so everything above 1024 x 1024 keeps the XLA path. A limit in
-# VMEM bytes of the whole step is ROADMAP C18's.
-_TAIL_MAX_GROUP_ROWS = 4096
-_TAIL_MAX_SCORES = 1024 * 1024
-
-
-def _tail_kernel(
-    lens_ref, q_ref, k_ref, v_ref, tk_ref, tv_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, scale, block_t, t, num_t, rep,
-):
-    _, kq, d = q_ref.shape
-    kt = tk_ref.shape[0]
-    si, ti = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(ti == 0)
-    def _init():
-        m_scr[:] = jnp.full((rep, kq, 1), NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros((rep, kq, 1), jnp.float32)
-        acc_scr[:] = jnp.zeros((rep, kq, d), jnp.float32)
-
-    lens_s = lens_ref[si]
-    # pre-tail ring liveness is idx < lens (strict: the tail's own K/V is
-    # in-register, not the ring) — or the whole ring once lens >= t
-    last_ring = jnp.where(
-        lens_s >= t, num_t - 1, jnp.maximum(lens_s - 1, 0) // block_t
-    )
-    ring_on = (lens_s >= t) | ((lens_s > 0) & (ti <= last_ring))
-
-    @pl.when((ti < num_t) & ring_on)
-    def _ring_step():
-        k_blk = k_ref[:]  # [d, block_t]
-        v_blk = v_ref[:]
-        idx = ti * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, (kq, block_t), 1
-        )
-        j = jax.lax.broadcasted_iota(jnp.int32, (kq, block_t), 0)
-        base = (idx < lens_s) | (lens_s >= t)
-        # disp = the i whose tail ring write ((lens+i) % T) lands on this
-        # slot; query j has evicted it when that write precedes j and wraps
-        disp = jnp.mod(idx - lens_s, t)
-        evicted = (disp <= j) & ((lens_s + disp) >= t)
-        valid = base & ~evicted  # [kq, block_t], same for every q head
-        for r in range(rep):
-            q_r = q_ref[r]  # [kq, d]
-            s = scale * jax.lax.dot_general(
-                q_r, k_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev, l_prev, acc = m_scr[r], l_scr[r], acc_scr[r]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            m_scr[r] = m_new
-            l_scr[r] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[r] = acc * corr + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
-    @pl.when(ti == num_t)
-    def _tail_step():
-        tk_blk = tk_ref[:]  # [kt, d]
-        tv_blk = tv_ref[:]
-        qi = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 0)
-        ki = jax.lax.broadcasted_iota(jnp.int32, (kq, kt), 1)
-        valid = ki <= qi  # causal within the tail
-        for r in range(rep):
-            q_r = q_ref[r]
-            s = scale * jax.lax.dot_general(
-                q_r, tk_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev, l_prev, acc = m_scr[r], l_scr[r], acc_scr[r]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc * corr + jax.lax.dot_general(
-                p.astype(tv_blk.dtype), tv_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            # the tail always holds at least the query's own position, so
-            # l_new > 0; the guard mirrors the flash kernel's finish
-            l_safe = jnp.where(l_new == 0, 1.0, l_new)
-            o_ref[r] = (acc / l_safe).astype(o_ref.dtype)
-
-
-def tail_attention_fused(
-    q: jax.Array,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tail_k: jax.Array,
-    tail_v: jax.Array,
-    lens: jax.Array,
-    *,
-    block_t: int | None = None,
-    interpret: bool | None = None,
-):
-    """Drop-in :func:`~opendiloco_tpu.ops.attention.tail_attention`:
-    q [S, Kq, H, D] over one layer's ring pages plus the tail's K/V, one
-    online-softmax pass, exact ring-wrap eviction semantics."""
-    # same Mosaic tiling story as paged_decode_attention: the pages are
-    # read where they lie, [S, Kh, D, T] in (d, bt) tiles; kv-head and rep
-    # axes are tiny, so they must be array dims of their own -- the tail as
-    # [S, Kh, Kt, D], q (and the output) as [S, Kh, rep, Kq, D]. The head
-    # index r must be a LEADING dim of the q/o tiles: with 16-bit dtypes two
-    # rows share a sublane, and Mosaic refuses a per-head slice on the
-    # second-minor dim ("unsupported shape cast" for bf16 at D 64).
-    s_, nkv, d, t = cache_k.shape
-    kq, h = q.shape[1], q.shape[2]
-    kt = tail_k.shape[1]
-    interp = _interpret(interpret)
-    bt = _ring_block(t, block_t, interp)
-    if (
-        d % 8 != 0 or h % nkv != 0 or not bt
-        or (h // nkv) * kq > _TAIL_MAX_GROUP_ROWS
-        or kq * kt > _TAIL_MAX_SCORES
-    ):
-        return tail_attention(q, cache_k, cache_v, tail_k, tail_v, lens)
-    rep = h // nkv
-    tkt, tvt = tail_k.transpose(0, 2, 1, 3), tail_v.transpose(0, 2, 1, 3)
-    num_t = t // bt
-    q5 = q.reshape(s_, kq, nkv, rep, d).transpose(0, 2, 3, 1, 4)
-
-    def kv_map(si, hi, ti, lens_ref):
-        last = jnp.where(
-            lens_ref[si] >= t, num_t - 1,
-            jnp.maximum(lens_ref[si] - 1, 0) // bt,
-        )
-        return (si, hi, 0, jnp.minimum(ti, last))
-
-    def q_map(si, hi, ti, lr):
-        return (si, hi, 0, 0, 0)
-
-    def tail_map(si, hi, ti, lr):
-        return (si, hi, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s_, nkv, num_t + 1),  # ring blocks, then the tail block
-        in_specs=[
-            pl.BlockSpec((None, None, rep, kq, d), q_map),
-            pl.BlockSpec((None, None, d, bt), kv_map),
-            pl.BlockSpec((None, None, d, bt), kv_map),
-            pl.BlockSpec((None, None, kt, d), tail_map),
-            pl.BlockSpec((None, None, kt, d), tail_map),
-        ],
-        out_specs=pl.BlockSpec((None, None, rep, kq, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((rep, kq, 1), jnp.float32),
-            pltpu.VMEM((rep, kq, 1), jnp.float32),
-            pltpu.VMEM((rep, kq, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _tail_kernel,
-            scale=d**-0.5, block_t=bt, t=t, num_t=num_t, rep=rep,
-        ),
-        name="odtp_spec_tail_attn",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (s_, nkv, rep, kq, d), q.dtype, vma=jax.typeof(q).vma
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interp,
-    )(lens.astype(jnp.int32), q5, cache_k, cache_v, tkt, tvt)
-    return out.transpose(0, 3, 1, 2, 4).reshape(s_, kq, h, d)

@@ -80,6 +80,13 @@ def pipeline_hidden(
             "attention is the caller's attn_fn over one run of rows, and EVA pools "
             "its chunks under each layer's own vectors"
         )
+    if cfg.sparse:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration with learned sparse "
+            f"attention (index_topk {cfg.index_topk}): a stage's attention is the "
+            "caller's attn_fn over every row before a query, and here each layer's "
+            "indexer chooses the rows its attention reads"
+        )
     if cfg.router_hidden_size:
         raise ValueError(
             "the pp pipeline is refused for a configuration whose router reads "

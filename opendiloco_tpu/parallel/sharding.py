@@ -80,6 +80,14 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "router_down": (None, 0),  # [D, R]
     "router_fc1": (None, -1),  # [R, R]
     "router_fc2": (None, -1),
+    # the indexer of learned sparse attention (llama._index_qkw): its queries
+    # are per index head and take tp like q_proj; its one key and its head
+    # weights are small and end in a norm or a sum over heads: fsdp only
+    "index_q": (1, 0),  # [D, Hi*Di]
+    "index_k": (None, 0),  # [D, Di]
+    "index_k_norm": (None, -1),
+    "index_k_norm_bias": (None, -1),
+    "index_w": (None, 0),  # [D, Hi]
     # EVA's pooling vectors (ops.attention.eva_pool), a head's size a head: replicated
     "adaptive_phi": (None, -1),  # [Nkv, Dh]
     "adaptive_mu_k": (None, -1),

@@ -41,6 +41,15 @@ admissions that the step it reads fed on the device, before that step's tokens:
 the prompts enqueued between two steps are read by the call after the one
 that reads the first of the two.
 
+A prompt longer than every bucket, under learned sparse attention, is
+admitted in chunks of ``q_chunk_size`` tokens: ``admit_begin`` names the slot
+and enqueues nothing, each ``admit_chunk`` enqueues one program over the
+slot's rows so far (one compile for every prompt length), and the last chunk's
+leaves the first token on the device as a cold admission's insert does, so the
+:class:`Admission` is from there on like any other: fed by the next step, read
+behind it. A loop gives a prefilling slot a chunk an iteration, between two
+decode steps; ``admit`` runs them back to back.
+
 Prefix reuse (scheduler-driven, off by default): ``admit(..., prefix_src,
 prefix_len)`` ring-copies a live slot's prefix K/V and prefills only the
 suffix (the continued prefill).
@@ -68,12 +77,13 @@ from opendiloco_tpu import obs
 from opendiloco_tpu.diloco.compression import get_codec
 from opendiloco_tpu.models.llama import (
     LlamaConfig,
-    continue_prefill,
+    chunk_prefill_forward,
     decode_forward,
     prefill_forward,
     refuse_eva,
     refuse_latent,
     refuse_recurrent,
+    refuse_sparse,
 )
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
@@ -83,18 +93,20 @@ from opendiloco_tpu.models.ring_cache import (
     fetch_pages,
     init_cca_state,
     init_eva_state,
+    index_insert,
+    init_index_cache,
     init_kv_cache,
     init_ssm_state,
     layer_pages,
     prefix_copy,
-    slot_cache,
     state_insert,
-    suffix_insert,
 )
 from opendiloco_tpu.ops.attention import (
     decode_step_attention,
     eva_decode_step_attention,
+    decode_selection,
     latent_decode_step_attention,
+    sparse_decode_step_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     DecodePlan,
@@ -151,7 +163,9 @@ FIRST_TOKEN_ON_DEVICE = -1
 PREV_TOKEN_ON_DEVICE = -2
 
 
-def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: bool = False):
+def serving_programs(
+    cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: bool = False, rows: bool = False,
+):
     """The three functions a cold admission and a decode step run, unjitted
     -> (prefill, decode, admit_insert, carried): ``carried`` the number of
     ``decode``'s trailing arguments (rings, then per-slot state) that it
@@ -166,7 +180,11 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     ``admit_insert`` takes with the K/V of the prompt's last window
     (``ring_cache.eva_insert``: the slot's window ring, pooled ring and stats
     in one program): ``left`` is those, or nothing; with ``chosen`` each token's
-    experts in each layer come last. Of a head of several vocabularies
+    experts in each layer come last. Learned sparse attention's programs hand
+    the prompt's index keys on after the K/V and its ``admit_insert`` writes the
+    three rings; its decode step carries the index ring behind the K and V
+    rings; with ``rows`` the rows each slot's step chose in each layer come last
+    of all. Of a head of several vocabularies
     (``num_pred_heads``) the first is the one sampled; the logits go back whole.
 
     The first token never has to reach the host before the step that reads
@@ -180,8 +198,11 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
     ``first`` or from ``prev`` in one and the same step."""
     cd, dkn = compute_dtype, decode_kernel
     moe = bool(cfg.num_experts)
-    n_state = 1 if cfg.cca else 3 if cfg.eva else 2 if cfg.hybrid else 0
-    state_names = ("cca_state",) if cfg.cca else ("ssm_state", "conv_state")
+    n_state = 1 if cfg.cca or cfg.sparse else 3 if cfg.eva else 2 if cfg.hybrid else 0
+    state_names = (
+        ("cca_state",) if cfg.cca else ("index_cache",) if cfg.sparse
+        else ("ssm_state", "conv_state")
+    )
 
     def sample(logits):  # greedy, from the next token's head
         if cfg.num_pred_heads > 1:
@@ -211,6 +232,7 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
                 p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
                 decode_kernel=dkn, return_moe_counts=moe,
                 return_expert_choices=chosen, **state,
+                **({"return_row_choices": True} if rows else {}),
             )
             left, counts = rest[:n_state], rest[n_state : n_state + 1]
             tok = sample(logits)
@@ -224,10 +246,41 @@ def serving_programs(cfg: LlamaConfig, *, compute_dtype, decode_kernel, chosen: 
         rings = eva_insert(ck, cv, pk, pv, stats, ks, vs, pks, pvs, chunk, slot)
         return rings[0], rings[1], first.at[slot].set(tok[0]), *rings[2:]
 
+    def sparse_admit_insert(ck, cv, first, ci, ks, vs, iks, tok, slot):
+        ck, cv = cache_insert(ck, cv, ks, vs, slot)
+        return ck, cv, first.at[slot].set(tok[0]), index_insert(ci, iks, slot)
+
     if cfg.eva:
         admit_insert = eva_admit_insert
+    if cfg.sparse:
+        admit_insert = sparse_admit_insert
 
     return prefill, decode, admit_insert, 2 + n_state
+
+
+def chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
+    """The one function a prompt admitted in chunks runs, unjitted:
+    ``chunk(params, ids [1, C], plen, count, slot, last, first, ck, cv, ci) ->
+    (the chunk's last real token's greedy successor [1] and a routed model's
+    counts, its logits row, first, ck, cv, ci)``; with ``rows`` then the rows
+    that token read in each layer. Everything but the ids' shape is traced: one
+    compile serves every prompt and every chunk of it. ``last`` says whether
+    the token is the prompt's first (then it goes into ``first[slot]``, where
+    the next decode step takes it); the trailing four arguments are updated
+    and a jit donates them."""
+    moe = bool(cfg.num_experts)
+
+    def chunk(p, ids, plen, count, slot, last, first, ck, cv, ci):
+        with jax.named_scope("odtp_serve_prefill"):
+            logits, ck, cv, ci, *rest = chunk_prefill_forward(
+                p, ids, plen, count, slot, ck, cv, ci, cfg, compute_dtype=compute_dtype,
+                return_moe_counts=moe, return_row_choices=rows,
+            )
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            first = jnp.where(last, first.at[slot].set(tok[0]), first)
+        return (_with_counts(tok, rest[:moe]), logits[0], first, ck, cv, ci, *rest[moe:])
+
+    return chunk
 
 
 class _DecodeProgram:
@@ -279,6 +332,30 @@ class Admission:
     token: Optional[int] = None
     t_token: Optional[float] = None
     fed: bool = False  # a decode step that takes the token on the device is enqueued
+    # a prompt admitted in chunks (``ServeEngine.admit_begin``): its tokens, the
+    # rows enqueued so far, and the newest chunk, whose counts are unread.
+    # ``tokd``, ``rowd`` and the stamps are the last chunk's once it is enqueued
+    prompt: Optional[np.ndarray] = None
+    rows_done: int = 0
+    chunk: Optional["_Chunk"] = None
+
+    @property
+    def prefilling(self) -> bool:
+        """Has the prompt chunks that are not enqueued yet?"""
+        return self.prompt is not None and self.rows_done < self.tokens
+
+
+@dataclasses.dataclass(eq=False)
+class _Chunk:
+    """One enqueued chunk of a prompt admitted in chunks."""
+
+    tokd: jax.Array  # the chunk's token, then a routed model's counts
+    index: int  # which chunk of its prompt
+    rows_before: int  # the slot's rows the chunk found
+    count: int  # its real tokens
+    t0: float
+    t_args: float
+    t_dispatch: float
 
 
 @dataclasses.dataclass(eq=False)
@@ -387,9 +464,38 @@ class ServeEngine:
         self.latent_rows_read = 0
         self.latent_bytes_moved = 0
 
+        # learned sparse attention: the index-key ring beside K and V
+        # (``ring_cache``); a prompt longer than every bucket goes in chunks of
+        # ``q_chunk_size``, each written as one aligned block, so the ring is
+        # whole chunks
+        self._index: tuple = ()
+        if cfg.sparse and self.max_context % cfg.q_chunk_size:
+            raise ValueError(
+                f"max_context {self.max_context} is not whole chunks of q_chunk_size "
+                f"{cfg.q_chunk_size}: a prompt admitted in chunks writes each as one "
+                "block of ring rows"
+            )
         # a latent cache is the one ring, in ``cache_k``; ``cache_v`` is None
+        if cfg.sparse:
+            self._index = (
+                init_index_cache(cfg, self.num_slots, self.max_context, compute_dtype),
+            )
         cache = init_kv_cache(cfg, self.num_slots, self.max_context, compute_dtype)
         self.cache_k, self.cache_v = cache["k"], cache["v"]
+        self.index_cache_resident_bytes = sum(x.nbytes for x in self._index)
+        # what the indexer and the attention under its selection did (always
+        # on; stay 0 without them), over layers, decode steps and prefills
+        # alike: the index rows scored (each query's live rows), the rows
+        # chosen (min(index_topk, live) a query), the bytes of index keys and
+        # of K and V rows the programs read (each live row of a slot once a
+        # step or a chunk: the attention reads a slot's rows in order under
+        # the selection's mask), and the chunks of prompts admitted in chunks
+        self.dsa_rows_scored = 0
+        self.dsa_rows_selected = 0
+        self.dsa_index_bytes_read = 0
+        self.dsa_kv_bytes_read = 0
+        self.prefill_chunks = 0
+        self.prefill_chunk_tokens = 0
         self.latent_cache_resident_bytes = self.cache_k.nbytes if cfg.latent else 0
         self._latent_row_bytes = (
             cfg.latent_row_dim * self.cache_k.dtype.itemsize if cfg.latent else 0
@@ -459,6 +565,11 @@ class ServeEngine:
         # int32 (the engine never reads them: a check against a reference does)
         self.expert_choices: Optional[jax.Array] = None
         self._keeps_choices = False
+        # after ``keep_row_choices()``: the rows the newest call's indexer chose,
+        # on the device: a decode step's [L, S, T] bool, a chunk's last real
+        # token's [L, T] (a whole-prompt prefill leaves None)
+        self.row_choices: Optional[jax.Array] = None
+        self._keeps_rows = False
 
         cd = compute_dtype
         dkn = self.decode_kernel
@@ -482,36 +593,43 @@ class ServeEngine:
         self._ahead: Optional[_Step] = None
         self.steps_ahead = 0
 
-        def programs(chosen: bool):
+        def programs(chosen: bool, rows: bool = False):
             prefill, decode, admit_insert, carried = serving_programs(
-                cfg, compute_dtype=cd, decode_kernel=dkn, chosen=chosen
+                cfg, compute_dtype=cd, decode_kernel=dkn, chosen=chosen, rows=rows
             )
+            beside = len(self._eva) + len(self._index)
             # one compile per prompt bucket (prefill, insert); decode compiles once
             return (
                 jax.jit(prefill),
                 _DecodeProgram(decode, carried, counts),
-                jax.jit(admit_insert, donate_argnums=tuple(range(3 + len(self._eva)))),
+                jax.jit(admit_insert, donate_argnums=tuple(range(3 + beside))),
             )
 
         self._programs = programs
         self._prefill, self._decode, self._admit_insert = programs(False)
+        # a prompt admitted in chunks: one program, compiled once
+        self._chunk = None
+        if cfg.sparse:
+            self._chunk_programs = lambda rows: jax.jit(
+                chunk_program(cfg, compute_dtype=cd, rows=rows), donate_argnums=(6, 7, 8, 9)
+            )
+            self._chunk = self._chunk_programs(False)
         # a slot's pages coming back from the host tier (one compile per row count)
         self._insert = jax.jit(cache_insert, donate_argnums=(0, 1))
         self._state_insert = jax.jit(state_insert, donate_argnums=(0, 1))
         self._cca_insert = jax.jit(cca_state_insert, donate_argnums=(0,))
 
-        # shared-prefix reuse jits (compiled only when the batcher asks)
-        def _suffix(p, ck, cv, slot, tail, plen):
-            # over the one slot's pages: tail tokens at positions plen..plen+B-1
-            logits, tks, tvs = continue_prefill(
-                p, tail, plen[None], *slot_cache(ck, cv, slot), cfg,
-                compute_dtype=cd, decode_kernel=dkn,
+        # shared-prefix reuse jits (compiled only when the batcher asks): the
+        # suffix behind a reused prefix runs the forward a chunk of a prompt
+        # runs, over the slot's rows [0, plen), its own rows written in place
+        def _suffix(p, tail, plen, count, slot, ck, cv):
+            logits, ck, cv, _ = chunk_prefill_forward(
+                p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=cd
             )
-            return logits[0], tks[:, 0], tvs[:, 0]
+            return logits[0], ck, cv
 
         self._prefix_copy = jax.jit(prefix_copy, donate_argnums=(0, 1))
-        self._suffix = jax.jit(_suffix)
-        self._suffix_insert = jax.jit(suffix_insert, donate_argnums=(0, 1))
+        self._suffix = jax.jit(_suffix, donate_argnums=(5, 6))
 
         # KV-tier page-out (compiled only when tiering is on): one slot's
         # ring rows gathered for D2H eviction; ``_insert`` is the way back.
@@ -527,7 +645,19 @@ class ServeEngine:
         if not self.cfg.num_experts:
             raise ValueError("keep_expert_choices needs routed experts (num_experts > 0)")
         self._keeps_choices = True
-        self._prefill, self._decode, self._admit_insert = self._programs(True)
+        self._prefill, self._decode, self._admit_insert = self._programs(True, self._keeps_rows)
+
+    def keep_row_choices(self) -> None:
+        """From here on the decode program and the chunk program of a
+        configuration with learned sparse attention also hand back the rows
+        their indexer chose, and the newest call's stay in ``row_choices``.
+        Programs of their own: called before the first request, nothing
+        compiles twice."""
+        if not self.cfg.sparse:
+            raise ValueError("keep_row_choices needs learned sparse attention (index_topk > 0)")
+        self._keeps_rows = True
+        self._prefill, self._decode, self._admit_insert = self._programs(self._keeps_choices, True)
+        self._chunk = self._chunk_programs(True)
 
     @property
     def device(self):
@@ -584,10 +714,16 @@ class ServeEngine:
         from_host = host_prefix is not None and 0 < host_prefix[2] < n
         from_slot = prefix_src is not None and 0 < prefix_len < n
         if not (from_host or from_slot):
-            adm = self.admit_enqueue(slot, prompt)
+            if self.needs_chunks(n):  # the chunks back to back, then the read
+                adm = self.admit_begin(slot, prompt)
+                while not self.admit_chunk(adm):
+                    pass
+            else:
+                adm = self.admit_enqueue(slot, prompt)
             logits = self._read(adm, row=True)
             return adm.token, logits
         self._bucket_of(n)
+        refuse_sparse(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         refuse_eva(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
@@ -620,13 +756,15 @@ class ServeEngine:
             )
         return bucket
 
-    def admit_enqueue(self, slot: int, prompt: Sequence[int]) -> Admission:
+    def admit_enqueue(self, slot: int, prompt: Sequence[int], positions=None) -> Admission:
         """The front half of a cold admission: the arguments made and the
         prompt's programs enqueued (prefill, the insert that also writes the
         first token into the engine's device vector, a state's insert), and
         nothing read. Until the :class:`Admission` is resolved, by
         ``admit_resolve`` or by the next ``decode_step``, that step takes the
-        slot's token from the device, whatever ``tokens`` holds there."""
+        slot's token from the device, whatever ``tokens`` holds there.
+        ``positions``: refused (token ids alone are served)."""
+        self._refuse_positions(positions)
         n = len(prompt)
         bucket = self._bucket_of(n)
         t0 = time.perf_counter()
@@ -640,12 +778,15 @@ class ServeEngine:
         # the slot's scalar is made here, while the device runs the
         # prompt: made with the others it holds every prefill's start back
         # by its own host time (a third of a millisecond on the chip)
-        if self._eva:  # both rings and the pooling under way
-            self.cache_k, self.cache_v, self._first, *eva = self._admit_insert(
-                self.cache_k, self.cache_v, self._first, *self._eva, ks, vs, *left,
-                tokd, jnp.int32(slot),
+        if self._eva or self._index:  # the rings beside K and V in the one insert
+            self.cache_k, self.cache_v, self._first, *beside = self._admit_insert(
+                self.cache_k, self.cache_v, self._first, *self._eva, *self._index, ks, vs,
+                *left, tokd, jnp.int32(slot),
             )
-            self._eva = tuple(eva)
+            if self._eva:
+                self._eva = tuple(beside)
+            else:
+                self._index = tuple(beside)
         else:
             self.cache_k, self.cache_v, self._first = self._admit_insert(
                 self.cache_k, self.cache_v, self._first, ks, vs, tokd, jnp.int32(slot)
@@ -661,6 +802,94 @@ class ServeEngine:
         )
         self._unread.append(adm)
         return adm
+
+    def _refuse_positions(self, positions) -> None:
+        if positions is not None:
+            raise ValueError(
+                "positions are refused by the serving engine: it admits token ids, whose "
+                "three position rows (temporal, height, width) are equal; an image span's "
+                "rows differ, and neither the prefills nor the decode step carry them"
+            )
+
+    def needs_chunks(self, n: int) -> bool:
+        """Is a prompt of ``n`` tokens admitted in chunks (learned sparse
+        attention, and no bucket holds it)?"""
+        return self._chunk is not None and pick_bucket(n, self.prefill_buckets) is None
+
+    def admit_begin(self, slot: int, prompt: Sequence[int], positions=None) -> Admission:
+        """A prompt that goes in chunks is given ``slot``; nothing is enqueued.
+        Each ``admit_chunk`` then enqueues the next ``q_chunk_size`` tokens."""
+        self._refuse_positions(positions)
+        n = len(prompt)
+        if not self.needs_chunks(n) or n > self.max_context:
+            raise ValueError(
+                f"prompt length {n}: admitted in chunks only past every bucket and "
+                f"within max_context {self.max_context}, under learned sparse attention"
+            )
+        now = time.perf_counter()
+        return Admission(
+            slot=int(slot), tokens=n, tokd=None, rowd=None, state_bytes=0,
+            t0=now, t_args=now, t_dispatch=now, prompt=np.asarray(prompt, np.int32),
+        )
+
+    def admit_chunk(self, adm: Admission) -> bool:
+        """Enqueue the next chunk of ``adm``'s prompt over the slot's rows so
+        far; nothing of it is read -> whether that was the last. A chunk before
+        the last counts its enqueue's seconds to stage ``prefill`` at once; the
+        chunk before this one, finished long since (a step has been read
+        meanwhile, or this chunk is queued behind it), has its counts read and
+        its ``serve_prefill`` span closed here. The last chunk makes ``adm`` an
+        admission like any cold one: the next decode step takes its token on
+        the device, and ``admit_resolve`` or that step's read finishes it."""
+        C, n, plen = self.cfg.q_chunk_size, adm.tokens, adm.rows_done
+        count = min(C, n - plen)
+        last = plen + count == n
+        t0 = time.perf_counter()
+        ids = np.zeros((1, C), np.int32)
+        ids[0, :count] = adm.prompt[plen : plen + count]
+        args = (
+            jnp.asarray(ids), jnp.int32(plen), jnp.int32(count), jnp.int32(adm.slot),
+            jnp.asarray(last),
+        )
+        t_args = time.perf_counter()
+        tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
+            self.params, *args, self._first, self.cache_k, self.cache_v, *self._index
+        )
+        self._index = (rest[0],)
+        if self._keeps_rows:
+            self.row_choices = rest[1]
+        t_dispatch = time.perf_counter()
+        before, index = adm.chunk, 0 if adm.chunk is None else adm.chunk.index + 1
+        adm.chunk = _Chunk(tokd, index, plen, count, t0, t_args, t_dispatch)
+        adm.rows_done += count
+        if before is not None:
+            self._close_chunk(before)
+        if last:
+            adm.tokd, adm.rowd = tokd, rowd
+            adm.t0, adm.t_args, adm.t_dispatch = t0, t_args, t_dispatch
+            self._unread.append(adm)
+        else:
+            self.stage_seconds["prefill"] += t_dispatch - t0
+            self._count_phases("prefill", ((t0, t_args), (t_args, t_dispatch), None), obs.tracer())
+        return last
+
+    def _close_chunk(self, chunk: _Chunk, t_end: Optional[float] = None) -> dict:
+        """Read what a chunk left (a routed model's counts; the token is only
+        the last chunk's to use), count its work, and close its
+        ``serve_prefill`` span over its enqueue (to ``t_end`` for the chunk whose
+        token a blocking caller waited for) -> the span's attributes."""
+        _, attrs = self._split_counts(np.asarray(chunk.tokd), 1)
+        attrs.update(self._count_dsa(rows_before=chunk.rows_before, count=chunk.count))
+        attrs.update(chunk=chunk.index, rows_before=chunk.rows_before)
+        self.prefill_chunks += 1
+        self.prefill_chunk_tokens += chunk.count
+        tr = obs.tracer()
+        if tr is not None:
+            tr.add_span(
+                "serve_prefill", chunk.t0, chunk.t_dispatch if t_end is None else t_end,
+                tokens=chunk.count, **attrs,
+            )
+        return attrs
 
     def admit_resolve(self, adm: Admission) -> int:
         """The back half, at once: wait for the admission's first token, count
@@ -681,16 +910,22 @@ class ServeEngine:
         fetched = np.asarray(adm.tokd)
         logits = np.asarray(adm.rowd) if row else None
         adm.t_token = time.perf_counter()
-        toks, attrs = self._split_counts(fetched, 1)
-        attrs.update(self._count_ssm(adm.tokens, adm.state_bytes))
-        attrs.update(self._count_cca(adm.tokens, adm.state_bytes))
-        attrs.update(self._count_latent(read=0, written=adm.tokens))
-        attrs.update(self._count_eva(prompt=adm.tokens))
-        adm.token = int(toks[0])
+        adm.token = int(fetched[0])
+        tr = obs.tracer()
+        if adm.chunk is not None:  # a prompt admitted in chunks: its last chunk's span
+            self._close_chunk(adm.chunk, adm.t_token if t_from is None else None)
+            adm.chunk = None
+        else:
+            _, attrs = self._split_counts(fetched, 1)
+            attrs.update(self._count_ssm(adm.tokens, adm.state_bytes))
+            attrs.update(self._count_cca(adm.tokens, adm.state_bytes))
+            attrs.update(self._count_latent(read=0, written=adm.tokens))
+            attrs.update(self._count_eva(prompt=adm.tokens))
+            if self._index:
+                attrs.update(self._count_dsa(rows_before=0, count=adm.tokens, whole=True))
         t1 = time.perf_counter()
         self.stage_seconds["prefill"] += (adm.t_dispatch - adm.t0) + (t1 - t_fetch)
-        tr = obs.tracer()
-        if tr is not None:
+        if tr is not None and adm.prompt is None:
             tr.add_span(
                 "serve_prefill", adm.t0, t1 if t_from is None else adm.t_dispatch,
                 tokens=adm.tokens, **attrs,
@@ -771,6 +1006,43 @@ class ServeEngine:
             "eva_local_rows": layers * local, "eva_pooled_rows": layers * pooled, "eva_bytes": moved,
         }
 
+    def _count_dsa(self, lens=None, rows_before: int = 0, count: int = 0, whole: bool = False) -> dict:
+        """Add one call's indexing and attention under the selection to the
+        engine's counters -> the same as span attributes (nothing without
+        learned sparse attention): ``dsa_rows_scored`` and ``dsa_rows_selected``
+        over layers. A decode step over the live slots' positions ``lens``
+        scores each slot's min(p + 1, T) live rows and chooses min(index_topk,
+        those); a prefill of ``count`` tokens behind ``rows_before`` rows (a
+        chunk, or with ``whole`` a whole prompt in its bucket, which scores
+        nothing where the bucket holds no more than ``index_topk`` rows) does
+        the same for each of its queries, rows_before + i + 1 live rows for the
+        i-th. The bytes are what the programs read of the rings: a slot's live
+        index rows and live K and V rows once a step or a chunk."""
+        if not self._index:
+            return {}
+        cfg = self.cfg
+        layers, topk = cfg.num_hidden_layers, cfg.index_topk
+        if lens is None:
+            live = rows_before + 1 + np.arange(count, dtype=np.int64)
+            rows_read = rows_before + count
+            if whole and pick_bucket(count, self.prefill_buckets) <= topk:
+                scored = 0
+            else:
+                scored = int(live.sum())
+        else:
+            held = np.asarray(lens, np.int64)
+            live = np.minimum(held[held > 0] + 1, self.max_context)
+            scored = rows_read = int(live.sum())
+        selected = int(np.minimum(live, topk).sum())
+        item = self.cache_k.dtype.itemsize
+        index_bytes = layers * (rows_read if scored else 0) * cfg.index_head_dim * item
+        kv_bytes = layers * rows_read * 2 * cfg.kv_heads * cfg.head_dim * item
+        self.dsa_rows_scored += layers * scored
+        self.dsa_rows_selected += layers * selected
+        self.dsa_index_bytes_read += index_bytes
+        self.dsa_kv_bytes_read += kv_bytes
+        return {"dsa_rows_scored": layers * scored, "dsa_rows_selected": layers * selected}
+
     def _count_latent(self, read: int, written: int) -> dict:
         """Add one call's traffic with the latent ring to the engine's
         counters: ``read`` and ``written`` rows of one layer's pages, the
@@ -823,18 +1095,16 @@ class ServeEngine:
         sb = pick_bucket(ns, self.prefill_buckets)
         tail = np.zeros((1, sb), np.int32)
         tail[0, :ns] = suffix
-        logits, tks, tvs = self._suffix(
-            self.params, self.cache_k, self.cache_v,
-            jnp.int32(slot), jnp.asarray(tail), jnp.int32(plen),
+        logits, self.cache_k, self.cache_v = self._suffix(
+            self.params, jnp.asarray(tail), jnp.int32(plen), jnp.int32(ns), jnp.int32(slot),
+            self.cache_k, self.cache_v,
         )
-        self.cache_k, self.cache_v = self._suffix_insert(
-            self.cache_k, self.cache_v, tks, tvs,
-            jnp.int32(slot), jnp.int32(plen), jnp.int32(ns),
-        )
-        row = np.asarray(logits[ns - 1])
+        row = np.asarray(logits)
         return int(row.argmax()), row
 
     def prompt_fits(self, n: int) -> bool:
+        if self._chunk is not None:  # past the buckets it goes in chunks
+            return 0 < n <= self.max_context
         return pick_bucket(n, self.prefill_buckets) is not None
 
     # -- KV-tier page transfers ---------------------------------------------
@@ -859,6 +1129,7 @@ class ServeEngine:
         iteration so the transfer overlaps the next decode step instead
         of blocking the loop. The gather is by value: the slot can be
         re-tenanted immediately."""
+        refuse_sparse(self.cfg, "the host tier's page-out")
         refuse_eva(self.cfg, "the host tier's page-out")
         refuse_recurrent(self.cfg, "the host tier's page-out")
         refuse_latent(self.cfg, "the host tier's page-out")
@@ -879,6 +1150,7 @@ class ServeEngine:
         of ``slot`` are rewritten from the host arrays. Dispatch is
         async — the next decode step queues behind it on-stream, so the
         scheduler thread never blocks on the transfer."""
+        refuse_sparse(self.cfg, "the host tier's page-in")
         refuse_eva(self.cfg, "the host tier's page-in")
         refuse_recurrent(self.cfg, "the host tier's page-in")
         refuse_latent(self.cfg, "the host tier's page-in")
@@ -965,13 +1237,18 @@ class ServeEngine:
         t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *state = self._decode(
             self.params, tokensd, lensd, self.cache_k, self.cache_v,
-            *self._ssm, *self._cca, *self._eva, first=self._first, prev=self._prev,
+            *self._ssm, *self._cca, *self._eva, *self._index,
+            first=self._first, prev=self._prev,
         )
         self._prev = tok
+        if self._keeps_rows:
+            self.row_choices = state.pop()
         if self._keeps_choices:
             self.expert_choices = state.pop()
         if self._eva:
             self._eva = tuple(state)
+        elif self._index:
+            self._index = tuple(state)
         else:
             self._ssm, self._cca = tuple(state[: len(self._ssm)]), tuple(state[len(self._ssm):])
         if fed:
@@ -1017,6 +1294,8 @@ class ServeEngine:
                 read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size
             ))
         moe.update(self._count_eva(lens))
+        if self._index:
+            moe.update(self._count_dsa(lens))
         t1 = time.perf_counter()
         # the step's own seconds: not those of the admissions read inside it
         self.stage_seconds["decode"] += (t1 - t0) - (t_fetch - t_dispatch)
@@ -1063,6 +1342,35 @@ class ServeEngine:
                 _latent, ql, jnp.full((S,), T // 2, jnp.int32), self.cache_k[:1],
                 carried=1, iters=iters,
             )})
+        if cfg.sparse:
+            # a decode step's indexing and attention over the three rings of one
+            # layer, every slot three quarters full
+            q1 = jax.random.normal(key, (S, Nh, Dh), cd)
+            k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
+            qi = jax.random.normal(key, (S, cfg.index_n_heads, cfg.index_head_dim), cd)
+
+            def _sparse(q1, k1, qi, lens, ck, cv, ci):
+                rows = decode_selection(qi, qi[..., 0], qi[:, 0], ci[0], lens, cfg.index_topk)
+                if pallas:
+                    out = paged_decode_attention(q1, k1, k1, ck, cv, lens, 0, chosen=rows)
+                else:
+                    out = sparse_decode_step_attention(q1, k1, k1, rows, ck, cv, lens, 0)
+                return (*out, ci)
+
+            return self._publish_probe({
+                "decode_attn_us": _best_us(
+                    _sparse, q1, k1, qi, jnp.full((S,), 3 * T // 4, jnp.int32),
+                    self.cache_k[:1], self.cache_v[:1], self._index[0][:1],
+                    carried=3, iters=iters,
+                ),
+                "index_cache_resident_bytes": float(self.index_cache_resident_bytes),
+                "dsa_rows_scored": float(self.dsa_rows_scored),
+                "dsa_rows_selected": float(self.dsa_rows_selected),
+                "dsa_index_bytes_read": float(self.dsa_index_bytes_read),
+                "dsa_kv_bytes_read": float(self.dsa_kv_bytes_read),
+                "prefill_chunks": float(self.prefill_chunks),
+                "prefill_chunk_tokens": float(self.prefill_chunk_tokens),
+            })
         if cfg.eva:
             # a decode step's attention over both rings of the one layer, at
             # a position half-way through the second window, and the kernel's

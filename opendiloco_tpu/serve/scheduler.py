@@ -16,7 +16,14 @@ that step on the device and are read with it, each stamped ``t_first`` as it
 lands, just before the step's own tokens; the iteration then emits the step,
 retiring what it finished, and waits for nothing that was enqueued after it.
 The next iteration's admission pass follows the emit at once, as it followed
-a blocking step's. Who rides the next
+a blocking step's. A prompt that goes in chunks (learned sparse attention, a
+prompt longer than every bucket) makes its slot *prefilling* until its last
+chunk is enqueued: an iteration enqueues one chunk of one prefilling slot, the
+oldest first, before the decode step it enqueues, and with no slot decoding
+the chunks run back to back. A prefilling slot rides no step: no token of it
+is emitted and no row of its prompt is overwritten; its last chunk leaves the
+first token on the device, and from there it is an admission like any other.
+Who rides the next
 step is said before the tokens are in hand: a request that the token in flight
 ends by length does not, one that may end on an ``eos_id`` does and has its
 row dropped if it did. Whatever needs the newest tokens on the host, or a
@@ -183,6 +190,14 @@ class ContinuousBatcher:
                 "restarts, beside pooled chunks and the pooling of the chunk under "
                 "way that neither snapshots"
             )
+        if engine.cfg.sparse and (prefix_cache or kv_tier is not None):
+            refused = "prefix_cache" if prefix_cache else "kv_tier"
+            raise ValueError(
+                f"{refused} is refused for a configuration with learned sparse "
+                "attention: prefix reuse and the host tier copy, cut and restore a "
+                "slot's past as (k, v) rows, and a token here also keeps an index key, "
+                "in a ring of its own that neither snapshots"
+            )
         if engine.cfg.latent and (prefix_cache or kv_tier is not None):
             refused = "prefix_cache" if prefix_cache else "kv_tier"
             raise ValueError(
@@ -214,6 +229,9 @@ class ContinuousBatcher:
         # (slot, tenant) admitted since the last decode step was enqueued, whose
         # first token the next step will feed on the device (loop thread only)
         self._awaiting: list[tuple[int, _Slot]] = []
+        # (slot, tenant) whose prompts go in chunks and have chunks left, oldest
+        # first: one chunk of the first an iteration (loop thread only)
+        self._prefilling: list[tuple[int, _Slot]] = []
         # the decode step in flight: enqueued, its tokens not read (loop thread only)
         self._ahead: Optional[_Rows] = None
         # times the loop had to read and emit the step in flight before it
@@ -459,6 +477,8 @@ class ContinuousBatcher:
                 # the np materialization below is (near-)free
                 self._finish_pageouts()
                 admitted = self._admit()
+                if self._prefilling:
+                    admitted = self._prefill_chunk() or admitted
                 stepped = self._decode()
                 # a step counts when its tokens are emitted: at most one an
                 # iteration, none in the one that enqueues a busy period's first
@@ -502,6 +522,7 @@ class ContinuousBatcher:
                 self.slots.free(slot)
             self._active.clear()
             self._awaiting.clear()
+            self._prefilling.clear()
             self._fail_cold(self.loop_error)
             with self._cond:
                 pending = list(self._queue)
@@ -740,6 +761,11 @@ class ContinuousBatcher:
             obs.count("serve_prefix_hits")
             obs.count("serve_host_prefix_hits")
             obs.count("serve_prefix_tokens_saved", plen)
+        elif self.engine.needs_chunks(len(req.prompt)):
+            # its chunks follow, one an iteration (``_prefill_chunk``)
+            st.admission = self.engine.admit_begin(slot, req.prompt)
+            self._prefilling.append((slot, st))
+            return
         else:
             adm = self.engine.admit_enqueue(slot, req.prompt)
             self._maybe_store_prefix(slot, req.prompt)
@@ -749,6 +775,26 @@ class ContinuousBatcher:
                 return
             tok = self.engine.admit_resolve(adm)
         self._first_token(slot, st, tok, time.perf_counter(), plen)
+
+    def _prefill_chunk(self) -> bool:
+        """One chunk of the oldest prefilling slot's prompt, enqueued before this
+        iteration's decode step -> whether there was one. With its last chunk
+        the slot awaits its first token as a cold admission does: the next step
+        feeds it on the device (a request of one token is read at once)."""
+        while self._prefilling:
+            slot, st = self._prefilling[0]
+            if self._active.get(slot) is not st:  # cancelled or shed meanwhile
+                self._prefilling.pop(0)
+                continue
+            if self.engine.admit_chunk(st.admission):
+                self._prefilling.pop(0)
+                if st.req.max_new_tokens > 1:
+                    self._awaiting.append((slot, st))
+                else:
+                    tok = self.engine.admit_resolve(st.admission)
+                    self._first_token(slot, st, tok, time.perf_counter())
+            return True
+        return False
 
     def _first_token(
         self, slot: int, st: _Slot, tok: int, t_first: float, prefix_reused: int = 0
@@ -931,8 +977,13 @@ class ContinuousBatcher:
         next step, then read the one in flight (behind the first tokens of the
         prompts it fed, which lie before it on the device) and emit it
         -> whether there was any."""
-        if not self._active and self._ahead is None:
-            return False
+        # the tenants whose prompts are still arriving in chunks ride no step
+        # (none, and nothing made for them, but under learned sparse attention)
+        prefilling = {id(st) for _, st in self._prefilling} if self._prefilling else ()
+        if self._ahead is None and (not self._active or (
+            prefilling and all(id(st) in prefilling for st in self._active.values())
+        )):
+            return False  # nobody decodes: no slot, or every prompt still arriving
         S = self.engine.num_slots
         t_batch = time.perf_counter()
         # who is in the next step, said before the tokens in flight are in
@@ -945,6 +996,8 @@ class ContinuousBatcher:
         tenants = {}
         riding = self._ahead.tenants if self._ahead is not None else {}
         for slot, st in self._active.items():
+            if id(st) in prefilling:
+                continue  # rides no step until its last chunk is enqueued
             if riding.get(slot) is st:
                 # its tokens so far: those on the host, a first token that the
                 # step in flight took on the device, and that step's own
@@ -1248,6 +1301,15 @@ class ContinuousBatcher:
             # the step in flight before it could act, by what asked
             "steps_ahead": self.engine.steps_ahead,
             "step_drains": dict(self.step_drains),
+            # what the indexer and the attention under its selection did, and the
+            # prompts admitted in chunks (zeros without learned sparse attention)
+            "dsa": {
+                name: getattr(self.engine, name) for name in (
+                    "dsa_rows_scored", "dsa_rows_selected", "dsa_index_bytes_read",
+                    "dsa_kv_bytes_read", "index_cache_resident_bytes", "prefill_chunks",
+                    "prefill_chunk_tokens",
+                )
+            },
             # what EVA attention did with its two rings (zeros without it)
             "eva": {
                 **{name: getattr(self.engine, f"eva_{name}") for name in (

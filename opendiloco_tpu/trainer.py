@@ -28,6 +28,7 @@ from opendiloco_tpu.models.llama import (
     causal_lm_loss,
     forward,
     init_params,
+    untrained_by_the_lm_loss,
 )
 from opendiloco_tpu.parallel.mesh import MeshPlan
 from opendiloco_tpu.parallel.sharding import optstate_specs, param_specs
@@ -158,10 +159,11 @@ def _resolve_perf_defaults(
                     "pallas" if on_tpu else "xla",
                 )
             changes["attn_impl"] = "pallas" if on_tpu else "xla"
-        if model_cfg.latent or model_cfg.eva:
+        if model_cfg.latent or model_cfg.eva or model_cfg.sparse:
             # latent attention trains in the rebuilt form through XLA's
-            # attention, EVA over windows and pooled chunks; ``forward``
-            # refuses either the flash and ring kernels
+            # attention, EVA over windows and pooled chunks, learned sparse
+            # attention under its selection's mask; ``forward`` refuses each
+            # the flash and ring kernels
             changes["attn_impl"] = "xla"
     if tc.scan_unroll is None:
         # full unroll measured +6.8% tok/s on the HBM-bound 150m step (v5e
@@ -235,6 +237,17 @@ class InnerTrainer:
         self.model_cfg = model_cfg
         self.tc = tc
         self.plan = plan
+        # leaves the loss built here does not reach, said by name and not
+        # left as a silent zero in the gradient (an indexer's, under the LM loss)
+        self.untrained_leaves = untrained_by_the_lm_loss(model_cfg)
+        if self.untrained_leaves:
+            log.warning(
+                "the leaves %s get no gradient here: they are the indexer's, which "
+                "chooses rows under stop_gradient and learns from an alignment loss "
+                "(its scores against the attention's own distribution) that is not "
+                "built; the LM loss trains everything else",
+                ", ".join(self.untrained_leaves),
+            )
         if plan.pp_axis:
             pp_n = plan.mesh.shape[plan.pp_axis]
             if model_cfg.num_hidden_layers % pp_n:

@@ -23,8 +23,9 @@ Oracles:
   block sizes, the ring coming back with one row written per slot
 - a slot's pages survive the host tier's round trip (``fetch_pages`` ->
   ``cache_insert``) bit for bit
-- the continued prefill's fused tail attention matches
-  ``tail_attention``'s exact ring-wrap eviction mask
+- the continued prefill's attention, a tile of ring rows at a time under an
+  online softmax, matches one softmax over the slot's rows, and its rows go
+  into the ring where a bucket's padding would pass the ring's end
 - ``auto`` never selects Pallas off-TPU
 - engine-level: identical token streams xla vs pallas(interpret) across
   prefill buckets and ring wrap, for every architecture family the
@@ -44,19 +45,19 @@ from opendiloco_tpu.models.ring_cache import (
     cache_shape,
     fetch_pages,
     layer_pages,
+    layer_rows_insert,
 )
 from opendiloco_tpu.ops import decode_kernels
 from opendiloco_tpu.ops.attention import (
     decode_attention,
     decode_step_attention,
     latent_decode_step_attention,
-    tail_attention,
+    tiled_sparse_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
     mla_decode_attention,
     paged_decode_attention,
     resolve_decode_kernel,
-    tail_attention_fused,
 )
 from opendiloco_tpu.serve import ContinuousBatcher, ServeEngine
 
@@ -328,22 +329,56 @@ def test_page_out_page_in_round_trip_is_bit_equal():
 
 
 # ---------------------------------------------------------------------------
-# (b) the continued prefill's tail attention
+# (b) the continued prefill's attention and its rows
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("heads", [(8, 2), (4, 1), (4, 4)])
-def test_tail_attention_fused_parity(heads):
+def test_tiled_attention_is_one_softmax_over_the_slots_rows(heads):
+    """3 queries at positions 13, 14, 15 of a slot of 32 rows, every row up
+    to a query's own chosen: tiles of 8 rows under an online softmax (the
+    last two never visited) against one softmax over the rows; and under a
+    selection that leaves some tiles with no chosen row."""
     H, Kh = heads
-    S, T, K, D = 5, 32, 3, 16
+    T, C, D, plen = 32, 3, 16, 13
     rng = _rng(H)
-    q = _randn(rng, S, K, H, D)
-    ck, cv = _pages(rng, S, Kh, D, T)
-    tk, tv = _randn(rng, S, K, Kh, D), _randn(rng, S, K, Kh, D)
-    lens = jnp.asarray([0, 5, T - 2, T, 2 * T + 1], jnp.int32)
-    ref = tail_attention(q, ck, cv, tk, tv, lens)
-    out = tail_attention_fused(q, ck, cv, tk, tv, lens, block_t=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
+    q = _randn(rng, C, H, D)
+    (ck, cv) = (x[0] for x in _pages(rng, 1, Kh, D, T))  # [Kh, D, T]
+    seen = jnp.arange(T)[None] <= plen + jnp.arange(C)[:, None]
+    few = seen & (jnp.arange(T)[None] % 9 < 2)
+
+    def plain(chosen):
+        qg = q.reshape(C, Kh, H // Kh, D)
+        s = jnp.einsum("cgrd,gdt->cgrt", qg, ck) * D**-0.5
+        p = jax.nn.softmax(jnp.where(chosen[:, None, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("cgrt,gdt->cgrd", p, cv).reshape(C, H, D)
+
+    for chosen in (seen, few):
+        out = tiled_sparse_attention(q, ck, cv, chosen, plen + C, 8)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(plain(chosen)), atol=2e-6)
+        whole = tiled_sparse_attention(q, ck, cv, chosen, plen + C, T)  # a ring of one tile
+        np.testing.assert_allclose(np.asarray(whole), np.asarray(out), atol=2e-6)
+
+
+@pytest.mark.parametrize("start, count", [(0, 5), (20, 8), (27, 5), (29, 3), (31, 1)])
+def test_a_runs_rows_land_where_its_bucket_would_pass_the_rings_end(start, count):
+    """A run of ``count`` rows in a bucket of 8 into rows [start, start +
+    count) of a ring of 32: the block written is the ring's last 8 rows where
+    ``start + 8`` would pass its end, the run's rows moved down within it;
+    nothing else changes, in this slot, layer or any other."""
+    L, S, Kh, D, T, C = 2, 3, 2, 8, 32, 8
+    rng = _rng(start)
+    ck, cv = _ring(rng, L, S, Kh, D, T)
+    k, v = _randn(rng, C, Kh, D), _randn(rng, C, Kh, D)
+    gk, gv = jax.jit(lambda *a: layer_rows_insert(*a, whole_chunks=False))(
+        ck, cv, 1, 2, k, v, start, count)
+    for got, before, rows in ((gk, ck, k), (gv, cv, v)):
+        want = np.array(before)
+        want[1, 2, :, :, start : start + count] = np.moveaxis(np.asarray(rows[:count]), 0, -1)
+        np.testing.assert_array_equal(np.asarray(got), want)
+    if start + C <= T:  # whole chunks: the same block, by the path a prompt's chunks take
+        wk, _ = layer_rows_insert(ck, cv, 1, 2, k, v, start, count)
+        np.testing.assert_array_equal(np.asarray(wk), np.asarray(gk))
 
 
 # ---------------------------------------------------------------------------
@@ -383,7 +418,7 @@ def _runs_the_decode_kernel(engine) -> bool:
     vec = jnp.zeros((S,), jnp.int32)
     jaxpr = str(jax.make_jaxpr(engine._decode)(
         engine.params, vec, vec, engine.cache_k, engine.cache_v,
-        *engine._ssm, *engine._cca, *engine._eva,
+        *engine._ssm, *engine._cca, *engine._eva, *engine._index,
     ))
     return "odtp_paged_decode_attn" in jaxpr or "odtp_mla_decode_attn" in jaxpr
 

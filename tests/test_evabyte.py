@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from opendiloco_tpu.models.llama import (
-    LlamaConfig, causal_lm_loss, continue_prefill, decode_forward, forward, init_params,
+    LlamaConfig, causal_lm_loss, chunk_prefill_forward, decode_forward, forward, init_params,
     prefill_forward,
 )
 from opendiloco_tpu.models.ring_cache import eva_insert, eva_pooled_rows, init_eva_state, init_kv_cache
@@ -451,7 +451,7 @@ def test_each_refusal_by_name():
     ids = jnp.zeros((1, 8), jnp.int32)
     vec = jnp.zeros((3,), jnp.int32)
     with pytest.raises(ValueError, match=match):
-        continue_prefill(params, vec[:, None], vec, engine.cache_k, engine.cache_v, cfg, **F32)
+        chunk_prefill_forward(params, vec[None, :], 0, 3, 0, engine.cache_k, engine.cache_v, None, cfg, **F32)
     for impl in ("pallas", "ring"):
         with pytest.raises(ValueError, match=match):
             forward(params, ids, cfg, attn_impl=impl, **F32)

@@ -477,9 +477,8 @@ def test_what_cannot_hold_a_latent_row_says_so(tmp_path):
         engine.fetch_slot_pages(0, 12)
     with pytest.raises(ValueError, match=f"page-in is {REFUSED}"):
         engine.install_slot_pages(0, np.zeros((4, 16, 1, 24)), np.zeros((4, 16, 1, 24)))
-    vec = jnp.zeros((4,), jnp.int32)
     with pytest.raises(ValueError, match=f"continued prefill.*{REFUSED}"):
-        llama.continue_prefill(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k, None, cfg)
+        llama.chunk_prefill_forward(params, jnp.zeros((1, 2), jnp.int32), 0, 2, 0, engine.cache_k, None, None, cfg)
     with pytest.raises(ValueError, match="pp pipeline is refused for a configuration with a leading dense"):
         pipeline_hidden(params, jnp.zeros((2, 8, 32)), None, cfg, None, microbatches=2, attn_fn=None)
     with pytest.raises(ValueError, match="no latent attention"):

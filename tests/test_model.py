@@ -304,7 +304,7 @@ def test_the_four_forwards_agree(model):
     logits and greedy tokens. A change to one driver's layer that the others
     do not get fails here. The latent block runs under the three that support
     it (training and prefill rebuild k and v, the decode step absorbs them:
-    two formulas of one attention); the continued prefill handles (k, v) rows
+    two formulas of one attention); the run over a slot's rows handles (k, v) rows
     and refuses it. The CCA block runs under the same three (its projections
     read the token before: a shift over the sequence in training and prefill,
     a per-slot state in decode, written by the prefill at the prompt's true
@@ -319,7 +319,7 @@ def test_the_four_forwards_agree(model):
     prompt's true length); the continued prefill has rows to start from and
     no state, and refuses it. Of a head of two vocabularies the first samples."""
     from opendiloco_tpu.models.llama import (
-        cache_insert, continue_prefill, decode_forward, init_kv_cache, prefill_forward,
+        cache_insert, chunk_prefill_forward, decode_forward, init_kv_cache, prefill_forward,
     )
 
     cfg, params = model()
@@ -367,15 +367,22 @@ def test_the_four_forwards_agree(model):
     if cfg.latent or cfg.cca or cfg.eva or cfg.hybrid:
         what = "latent" if cfg.latent else "CCA" if cfg.cca else "EVA" if cfg.eva else "Mamba-2"
         with pytest.raises(ValueError, match=f"refused for a configuration with {what}"):
-            continue_prefill(params, tokens[:, None], lens, ck, cv, cfg, **f32)
+            chunk_prefill_forward(params, tokens[1:, None], P, 1, 1, ck, cv, None, cfg, **f32)
         return
-    continued, _, _ = continue_prefill(params, tokens[:, None], lens, ck, cv, cfg, **f32)
-    close(continued[1, 0], want)
+    continued, *_ = chunk_prefill_forward(params, tokens[1:, None], P, 1, 1, ck, cv, None, cfg, **f32)
+    close(continued[0], want)
 
-    # over a tail of K tokens it gives the forward's rows at those positions
+    # over a tail of K tokens (in a bucket of K + 2) it gives the forward's row
+    # at each of their positions, and leaves the tail's rows where decode steps would
     seq = prompt + [tok]
     for _ in range(K - 1):
         seq.append(int(jnp.argmax(full(seq)[-1])))
-    tail = jnp.asarray([[0] * K, seq[P:]], jnp.int32)
-    continued, _, _ = continue_prefill(params, tail, lens, ck, cv, cfg, **f32)
-    close(continued[1], full(seq)[P:])
+    tail = jnp.asarray([seq[P:] + [0, 0]], jnp.int32)
+    for count in range(1, K + 1):
+        continued, sk, sv, _ = chunk_prefill_forward(
+            params, tail, P, count, 1, ck, cv, None, cfg, **f32)
+        close(continued[0], full(seq)[P + count - 1])
+    step, dk, dv = decode_forward(params, tokens, lens, ck, cv, cfg, **f32)
+    np.testing.assert_allclose(sk[:, 1, ..., P], dk[:, 1, ..., P], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(sk[:, 1, ..., P + K:], ck[:, 1, ..., P + K:])  # no padding row lands
+    np.testing.assert_array_equal(sk[:, 0], ck[:, 0])  # nor anything in another slot

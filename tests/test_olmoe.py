@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from opendiloco_tpu.models import llama
-from opendiloco_tpu.models.llama import LlamaConfig, continue_prefill, forward, init_params
+from opendiloco_tpu.models.llama import LlamaConfig, chunk_prefill_forward, forward, init_params
 from opendiloco_tpu.parallel.mesh import build_mesh
 from opendiloco_tpu.serve import ServeEngine
 from opendiloco_tpu.trainer import InnerTrainer, TrainerConfig
@@ -175,7 +175,7 @@ def test_engine_prefill_then_decode_against_the_reference(top_k):
 
 @pytest.mark.parametrize("top_k", [2, 8])
 def test_continue_prefill_logits_against_forward(top_k):
-    """The continued prefill over a cached prefix gives the rows of the full
+    """A suffix run over a cached prefix gives the rows of the full
     forward, through the engine's own prefix-reuse admission too: its first
     token is the forward's greedy one at the prompt's end."""
     _, cfg, params = model(top_k, seed=7)
@@ -183,12 +183,13 @@ def test_continue_prefill_logits_against_forward(top_k):
     engine = ServeEngine(cfg, params, num_slots=2, max_context=32, prefill_buckets=(16,),
                          compute_dtype=jnp.float32, decode_kernel="xla")
     engine.admit(0, prompt.tolist())
-    lens = jnp.asarray([len(prompt)], jnp.int32)
-    got, _, _ = continue_prefill(engine.params, jnp.asarray(tail), lens, engine.cache_k[:, :1],
-                                 engine.cache_v[:, :1], cfg, compute_dtype=jnp.float32)
     ids = np.concatenate([prompt[None], tail], axis=1)
     want = forward(params, ids, cfg, compute_dtype=jnp.float32, remat=False)
-    assert rel_l2(got[0], want[0, len(prompt):]) < REL_L2
+    for count in range(1, tail.shape[1] + 1):  # the row of each of the tail's positions
+        got, *_ = chunk_prefill_forward(
+            engine.params, jnp.asarray(tail), len(prompt), count, 0, engine.cache_k, engine.cache_v,
+            None, cfg, compute_dtype=jnp.float32)
+        assert rel_l2(got[0], want[0, len(prompt) + count - 1]) < REL_L2
     tok, row = engine.admit(1, ids[0].tolist(), prefix_src=0, prefix_len=len(prompt))
     assert rel_l2(row, want[0, -1]) < REL_L2 and tok == int(np.argmax(want[0, -1]))
 

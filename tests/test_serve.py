@@ -18,9 +18,9 @@ Prefix-reuse oracles:
   whichever bucket the suffix pads to (to a rounding only where another
   bucket's program prefilled the source slot), and the stream stays the
   cold admission's while the ring wraps
-- the continued prefill is the one-token loop: over a tail that crosses
-  the ring's end its logits are the decode steps' (the eviction rule of
-  ``ops.attention.tail_attention``)
+- the continued prefill is the one-token loop: over a tail behind a
+  prefix of any length, padded to a bucket that may pass the ring's end,
+  its logits and the rows it leaves are the decode steps'
 """
 import json
 import socket
@@ -550,8 +550,8 @@ def test_prefix_reuse_kv_bytes_identical(
     generated stream is token-identical to a cold admit: for a suffix
     padded to each prefill bucket, and while the slot's ring wraps under
     the decode steps that follow (9 + 14 tokens on 16 rows). Under
-    ``pallas`` the continued prefill runs the tail kernel (interpreted,
-    the ring in tiles of 8 rows) and the decode steps theirs. The one case
+    ``pallas`` the decode steps run their kernel (interpreted, the ring in
+    tiles of 8 rows); the continued prefill is XLA's under either. The one case
     that is not ``exact`` has the source slot's prompt prefilled by the
     bucket-8 program and the cold prompt by the bucket-32 one: the copy is
     still the source's bytes, and those are the cold rows to a rounding."""
@@ -569,11 +569,6 @@ def test_prefix_reuse_kv_bytes_identical(
         tiny_cfg, params, num_slots=4, max_context=max_context,
         prefill_buckets=buckets, compute_dtype=jnp.float32, decode_kernel=kernel,
     )
-    suffix_program = jax.make_jaxpr(engine._suffix)(
-        engine.params, engine.cache_k, engine.cache_v, jnp.int32(1),
-        jnp.zeros((1, buckets[0]), jnp.int32), jnp.int32(plen),
-    )
-    assert ("odtp_spec_tail_attn" in str(suffix_program)) == (kernel == "pallas")
     cold.admit(1, p2)
     # slot 1's rows [L, len(p2), Nkv, Dh], read through the cache module
     rows = lambda e, slot=1, n=len(p2): [
@@ -603,16 +598,17 @@ def test_prefix_reuse_kv_bytes_identical(
     assert toks == cold_toks
 
 
-@pytest.mark.parametrize("start", [5, 13, 16, 27])
-def test_continued_prefill_is_the_one_token_loop_across_the_rings_end(tiny_cfg, start):
-    """The continued prefill over a tail of 6 tokens from position ``start``
-    gives the logits of 6 decode steps over the same ring of 16 rows: with
-    the tail inside the ring, crossing its end, starting where it is just
-    full, and crossing it a second time. A ring row is dropped for tail
-    query i exactly when the step of a tail token j <= i would have
-    overwritten it (``ops.attention.tail_attention``), and only this
-    comparison holds that rule to the loop it stands for."""
-    from opendiloco_tpu.models.llama import continue_prefill, decode_forward, init_kv_cache
+@pytest.mark.parametrize("start, bucket", [(0, 8), (5, 8), (9, 8), (10, 6)])
+def test_continued_prefill_is_the_one_token_loop_up_to_the_rings_end(tiny_cfg, start, bucket):
+    """The continued prefill over a tail of 6 tokens from position ``start``,
+    padded to ``bucket``, gives the logits of the 6 decode steps over the same
+    ring of 16 rows, one count of real tokens at a time, and leaves the rows
+    they leave: from an empty slot, inside the ring, with the bucket's padding
+    passing the ring's end (rows 9-14 of a block that would be 9-16: the block
+    is the ring's last 8 and the tail's rows move down in it), and with the
+    tail ending on the ring's last row. A padding row is never written, and
+    another slot's rows are never touched."""
+    from opendiloco_tpu.models.llama import chunk_prefill_forward, decode_forward, init_kv_cache
 
     T, K, f32 = 16, 6, dict(compute_dtype=jnp.float32)
     params = init_params(jax.random.PRNGKey(3), tiny_cfg)
@@ -621,18 +617,28 @@ def test_continued_prefill_is_the_one_token_loop_across_the_rings_end(tiny_cfg, 
         params, jnp.asarray([0, tok], jnp.int32), jnp.asarray([0, pos], jnp.int32),
         ck, cv, tiny_cfg, **f32))
     cache = init_kv_cache(tiny_cfg, 2, T, jnp.float32)
-    ck, cv = cache["k"], cache["v"]
+    ck, cv = cache["k"] + 7.0, cache["v"] - 7.0  # what a padding row must not overwrite
     for pos in range(start):  # slot 1 holds the sequence, slot 0 idles
         _, ck, cv = step(ids[pos], pos, ck, cv)
     want, sk, sv = [], ck, cv
     for i in range(K):
         logits, sk, sv = step(ids[start + i], start + i, sk, sv)
         want.append(np.asarray(logits[1]))
-    tail = jnp.asarray([[0] * K, ids[start:]], jnp.int32)
-    got, _, _ = continue_prefill(
-        params, tail, jnp.asarray([0, start], jnp.int32), ck, cv, tiny_cfg, **f32)
-    np.testing.assert_allclose(np.asarray(got[1]), np.stack(want), atol=2e-5, rtol=2e-4)
-    assert np.argmax(got[1], axis=-1).tolist() == np.argmax(want, axis=-1).tolist()
+    tail = np.zeros((1, bucket), np.int32)
+    tail[0, :K] = ids[start:]
+    run = jax.jit(lambda count: chunk_prefill_forward(
+        params, jnp.asarray(tail), start, count, 1, ck, cv, None, tiny_cfg, **f32))
+    for count in range(1, K + 1):
+        got, gk, gv, _ = run(count)
+        np.testing.assert_allclose(np.asarray(got[0]), want[count - 1], atol=2e-5, rtol=2e-4)
+        assert int(np.argmax(got[0])) == int(np.argmax(want[count - 1]))
+        for mine, loops, before in ((gk, sk, ck), (gv, sv, cv)):
+            np.testing.assert_allclose(
+                mine[:, 1, ..., start : start + count], loops[:, 1, ..., start : start + count],
+                atol=2e-5, rtol=2e-4)
+            np.testing.assert_array_equal(mine[:, 1, ..., start + count :], before[:, 1, ..., start + count :])
+            np.testing.assert_array_equal(mine[:, 1, ..., :start], before[:, 1, ..., :start])
+            np.testing.assert_array_equal(mine[:, 0], before[:, 0])
 
 
 def test_prefix_batcher_hits_and_parity(tiny_cfg):

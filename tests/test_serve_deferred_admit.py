@@ -12,6 +12,7 @@ import pytest
 
 import test_glm_flash
 import test_granite_hybrid
+import test_keye
 import test_olmoe
 import test_zaya
 from opendiloco_tpu.models.llama import init_params
@@ -32,11 +33,15 @@ KINDS = {
     "hybrid": lambda _: test_granite_hybrid.model()[1:],
     "latent": lambda _: test_glm_flash.model()[1:],
     "cca": lambda _: test_zaya.model()[1:],
+    # learned sparse attention: a prompt past the one bucket of 8 goes in chunks
+    # of 8, its slot prefilling meanwhile (ISSUE 49)
+    "sparse": lambda _: test_keye.model()[1:],
 }
 
 
 def _engine(cfg, params, **kw):
-    kw = {"num_slots": SLOTS, "max_context": 128, "prefill_buckets": BUCKETS,
+    kw = {"num_slots": SLOTS, "max_context": 128,
+          "prefill_buckets": (8,) if cfg.sparse else BUCKETS,
           "compute_dtype": jnp.float32, "decode_kernel": "xla", **kw}
     return ServeEngine(cfg, params, **kw)
 
@@ -91,10 +96,11 @@ def test_batcher_outputs_equal_blocking_calls_driven_by_hand(tiny_cfg, kind):
     assert batcher.stats()["admissions_deferred"] == len(reqs)
     assert not engine._unread and not batcher._awaiting
     # the same work counted on both sides
-    for name in ("moe_pairs", "ssm_tokens", "cca_tokens", "latent_rows_read"):
+    for name in ("moe_pairs", "ssm_tokens", "cca_tokens", "latent_rows_read",
+                 "dsa_rows_scored", "dsa_rows_selected", "prefill_chunks"):
         assert getattr(engine, name) == getattr(second, name), name
     own = {"routed": "moe_pairs", "hybrid": "ssm_tokens", "latent": "latent_rows_read",
-           "cca": "cca_tokens"}
+           "cca": "cca_tokens", "sparse": "prefill_chunks"}
     assert kind == "dense" or getattr(engine, own[kind]) > 0
 
 

@@ -47,10 +47,21 @@ class Steps:
 
     def __init__(self, engine, after=None):
         self.tokens, self.lens, self.reads = [], [], 0
-        call = engine.step_ahead
+        # the steps enqueued with no step in flight (a busy period's first), and
+        # the chunks of prompts that go in chunks enqueued before each step
+        self.firsts, self.chunks, self.chunks_before = 0, [], []
+        call, chunk = engine.step_ahead, engine.admit_chunk
+
+        def admit_chunk(adm):
+            self.chunks.append((adm.slot, len(self.lens)))
+            return chunk(adm)
+
+        engine.admit_chunk = admit_chunk
 
         def step_ahead(tokens=None, lens=None):
             if tokens is not None:
+                self.firsts += engine._ahead is None
+                self.chunks_before.append(len(self.chunks))
                 self.tokens.append(np.array(tokens))
                 self.lens.append(np.array(lens))
             out = call(tokens, lens)
@@ -100,9 +111,12 @@ def test_outputs_equal_blocking_calls_and_every_step_but_the_first_is_ahead(tiny
         assert req.tokens == by_hand(second, prompt, n, slot=i % SLOTS), (kind, i)
         assert req.t_submit < req.t_first < req.t_done
     # one busy period and nothing that drains: every step but its first was
-    # enqueued while the step before it was unread
+    # enqueued while the step before it was unread (prompts that go in chunks
+    # may leave nobody decoding for a while: a busy period more, each with its
+    # first)
     assert batcher.decode_steps == len(steps.lens) == steps.reads > 1
-    assert engine.steps_ahead == batcher.decode_steps - 1
+    assert engine.steps_ahead == batcher.decode_steps - steps.firsts
+    assert steps.firsts == 1 or kind == "sparse"
     assert engine.phase_calls["decode"] == batcher.decode_steps
     stats = batcher.stats()
     assert stats["steps_ahead"] == engine.steps_ahead and stats["step_drains"] == {}
@@ -113,9 +127,47 @@ def test_outputs_equal_blocking_calls_and_every_step_but_the_first_is_ahead(tiny
     assert engine._ahead is None and batcher._ahead is None and not engine._unread
     # the same work counted on both sides, by one decode program
     for name in ("moe_pairs", "ssm_tokens", "cca_tokens", "latent_rows_read",
-                 "eva_local_rows_read", "eva_pooled_rows_read"):
+                 "eva_local_rows_read", "eva_pooled_rows_read", "dsa_rows_scored",
+                 "prefill_chunks"):
         assert getattr(engine, name) == getattr(second, name), name
     assert engine._decode._cache_size() == second._decode._cache_size() == 1
+    if kind == "sparse":  # a chunk an iteration at most, and one program for all of them
+        assert engine.prefill_chunks == len(steps.chunks) > len(lengths)
+        assert engine._chunk._cache_size() == 1
+
+
+def test_a_prefilling_slot_rides_no_step_and_its_first_token_is_fed_on_the_device(tiny_cfg):
+    """ISSUE 49: a prompt of five chunks arrives while another slot decodes.
+    Between two of its chunks lies a step; until its last chunk is enqueued its
+    slot is in no step (no token of it is emitted, no row of its prompt is
+    overwritten); the step behind its last chunk takes its first token on the
+    device, and what it then decodes is what blocking calls give."""
+    cfg, engine, second = _pair("sparse", tiny_cfg)
+    rng = np.random.default_rng(11)
+    short, long_ = rng.integers(3, cfg.vocab_size, 5).tolist(), rng.integers(3, cfg.vocab_size, 37).tolist()
+    batcher, steps = ContinuousBatcher(engine), Steps(engine)
+    first = batcher.submit(short, 40)
+    batcher.start()
+    try:
+        _wait(lambda: len(first.tokens) >= 2)
+        late = batcher.submit(long_, 6)
+        for r in (first, late):
+            assert r.wait(300) and r.error is None, r.error
+    finally:
+        batcher.stop()
+    assert batcher.loop_error is None and batcher.stats()["step_drains"] == {}
+    assert first.tokens == by_hand(second, short, 40, slot=0)
+    assert late.tokens == by_hand(second, long_, 6, slot=1)
+    slot = next(s for s, _ in steps.chunks)
+    mine = [at for s, at in steps.chunks if s == slot]
+    assert len(mine) == 5 and all(b - a == 1 for a, b in zip(mine, mine[1:]))  # a step between two chunks
+    for done, lens in zip(steps.chunks_before, steps.lens):
+        if 0 < done < 5:  # prefilling: in no step, while the other slot decodes on
+            assert lens[slot] == 0 and np.count_nonzero(lens) == 1
+    rode = next(lens for done, lens in zip(steps.chunks_before, steps.lens) if done == 5)
+    assert rode[slot] == len(long_)  # the step behind the last chunk takes it along
+    assert engine.admissions_deferred == 2 and engine.prefill_chunks == 5
+    assert late.t_first > first.t_first
 
 
 @pytest.mark.parametrize("kind", ["dense", "hybrid", "cca", "eva"])

@@ -27,12 +27,10 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from opendiloco_tpu.models.ring_cache import cache_shape
+from opendiloco_tpu.models.ring_cache import cache_shape, layer_rows_insert, slot_layer_pages
 from opendiloco_tpu.ops import decode_kernels
-from opendiloco_tpu.ops.decode_kernels import (
-    paged_decode_attention,
-    tail_attention_fused,
-)
+from opendiloco_tpu.ops.attention import tiled_sparse_attention
+from opendiloco_tpu.ops.decode_kernels import paged_decode_attention
 from opendiloco_tpu.ops.flash_attention import flash_attention
 from opendiloco_tpu.ops.fused_xent import fused_linear_cross_entropy
 
@@ -284,45 +282,63 @@ def test_paged_decode_attention(chip, model, return_stats):
     assert outs[0] == (None, None, heads * (hq // hkv), d)
 
 
-def _tail_text(chip, model, kq):
+def _suffix_memory(chip, model, kq, rows):
+    """A layer of the continued prefill as prefix reuse runs it: a suffix
+    bucket's K and V rows into slot 1's pages behind a prefix of any length,
+    then its queries over the slot's rows in tiles of 512."""
     hq, hkv, d = TAIL_HEADS[model]
-    return compiled_text(
-        chip,
-        lambda q, ck, cv, tk, tv, lens: tail_attention_fused(
-            q, ck, cv, tk, tv, lens, interpret=False
-        ),
-        ((1, kq, hq, d), BF16),
-        (cache_shape(1, 1, SEQ, hkv, d)[1:], BF16),  # one layer's pages
-        (cache_shape(1, 1, SEQ, hkv, d)[1:], BF16),
-        ((1, kq, hkv, d), BF16),
-        ((1, kq, hkv, d), BF16),
-        ((1,), jnp.int32),
-    )
+
+    def layer(q, k, v, ck, cv, plen, count):
+        ck, cv = layer_rows_insert(ck, cv, 0, 1, k, v, plen, count, whole_chunks=False)
+        seen = jnp.arange(rows)[None] <= plen + jnp.arange(kq)[:, None]
+        pages = lambda c: slot_layer_pages(c, 0, 1)
+        return tiled_sparse_attention(q, pages(ck), pages(cv), seen, plen + count, 512), ck, cv
+
+    ring = (cache_shape(1, 2, rows, hkv, d), BF16)
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in (((kq, hq, d), BF16), ((kq, hkv, d), BF16), ((kq, hkv, d), BF16),
+                             ring, ring, ((), jnp.int32), ((), jnp.int32))
+    ]
+    compiled = jax.jit(layer, donate_argnums=(3, 4)).lower(*args).compile()
+    return compiled.memory_analysis(), compiled.as_text()
 
 
 @pytest.mark.parametrize(
-    "model,kq,kernel",
+    "model,kq",
     [
-        ("150m", 1024, True),
-        ("1b", 512, True),
-        # a whole GQA group's 8 x 1024 rows outgrow VMEM: the shape rule
-        # hands this one to XLA instead of failing at compile
-        ("1b", 1024, False),
+        ("150m", 1024),
+        ("1b", 512),
+        # a whole GQA group's 8 x 1024 rows, which the tail kernel this path
+        # replaced could not hold in VMEM
+        ("1b", 1024),
         # the serving cells' own heads at their own prefill buckets: three
         # query heads a KV head at head_dim 64, and heads of 128
-        ("360m", 32, True),
-        ("360m", 128, True),
-        ("olmoe", 1024, True),
-        # its largest bucket: one head's 3072 x 3072 scores outgrow VMEM
-        ("olmoe", 3072, False),
+        ("360m", 32),
+        ("360m", 128),
+        ("olmoe", 1024),
+        # its largest bucket, whose 3072 x 3072 scores a head the kernel refused
+        ("olmoe", 3072),
     ],
 )
-def test_continued_prefill_tail(chip, model, kq, kernel):
-    """The tail kernel as the prefix-cache continued prefill calls it: one
-    slot, the tail a whole suffix bucket; bf16 at head_dim 64 is what Mosaic
-    refused before the per-head slice moved to a leading dim."""
-    text = _tail_text(chip, model, kq)
-    assert ("tpu_custom_call" in text) == kernel
+def test_continued_prefill_layer(chip, model, kq):
+    """The continued prefill's layer as prefix reuse calls it, for a described
+    v5e: one slot of a ring of 4,096 rows, the suffix a whole bucket. It
+    compiles at every bucket, the ring is updated in place (aliased, and the
+    temporaries hold no second ring), and the scores it holds are a tile's:
+    [heads, bucket, 512] float32 and what the softmax keeps beside them, never
+    [heads, bucket, ring]."""
+    rows = 4096
+    hq, hkv, d = TAIL_HEADS[model]
+    mem, text = _suffix_memory(chip, model, kq, rows)
+    ring = 2 * rows * hkv * d * 2
+    assert mem.alias_size_in_bytes == 2 * ring
+    scores = hq * kq * 512 * 4  # one tile's, float32
+    print(f"suffix layer {model} {kq}: temporaries {mem.temp_size_in_bytes}, a tile's scores {scores}")
+    assert mem.temp_size_in_bytes < 6 * scores + 2 * 2**20
+    if kq >= 512:  # where a [heads, bucket, ring] block would be far more
+        assert 6 * scores + 2 * 2**20 < hq * kq * rows * 4
+    assert f"f32[{hkv},{hq // hkv},{kq},{rows}]" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -1217,3 +1233,137 @@ def test_eva_prefill_holds_no_score_block_wider_than_a_window(chip, form, monkey
         if "dynamic-update-slice" not in line
     ]
     assert not moved, moved
+
+
+# ---------------------------------------------------------------------------
+# Keye-VL-2.0 (PR 49): learned sparse attention. The decode step at 12 slots and
+# the chunk program behind 15,872 rows (``plen`` is traced: one program for
+# every chunk), published widths, 16 layers.
+# ---------------------------------------------------------------------------
+
+
+def _keye_cell(chip):
+    """-> (configuration, engine options, the three rings as shapes: K and V
+    rows minor-most as every configuration's, the index ring beside them)."""
+    cfg, engine = _serve_cell("keye-vl-2.0-30b-a3b", "serve-keye-videoqa")
+    L, slots, rows = cfg.num_hidden_layers, engine["num_slots"], engine["max_context"]
+    kv = jax.ShapeDtypeStruct(
+        cache_shape(L, slots, rows, cfg.kv_heads, cfg.head_dim), BF16, sharding=chip)
+    index = jax.ShapeDtypeStruct((L, slots, cfg.index_head_dim, rows), BF16, sharding=chip)
+    return cfg, engine, (kv, kv, index)
+
+
+def _ring_copies(text: str, shape: tuple) -> list[str]:
+    """Instructions that make an array of a ring's shape anew: everything
+    ``_cache_shaped_results`` finds of the whole ring's dimensions but the
+    updates in place (a slice update or the kernels' aliased outputs write
+    into the ring they are given)."""
+    whole = sorted(d for d in shape if d != 1)
+    found = []
+    for line in _cache_shaped_results(text, shape):
+        m = _RESULT.match(line)
+        if sorted(int(d) for d in m.group(3).split(",") if int(d) != 1) != whole:
+            continue  # one layer's pages: judged by its caller
+        if "dynamic-update-slice(" in line or "dynamic-update-slice_fusion" in line:
+            continue
+        found.append(line)
+    return found
+
+
+def _f32_blocks_over(text: str, nbytes: float) -> list[str]:
+    found = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(2) == "f32":
+            size = 4
+            for d in m.group(3).split(","):
+                size *= int(d) if d else 1
+            if size > nbytes and "parameter(" not in line:
+                found.append(line.strip()[:160])
+    return found
+
+
+def test_keye_decode_step_moves_no_ring(chip, monkeypatch):
+    """The engine's own decode program at 12 slots of 16,896 rows: the decode
+    kernel under its selection operand and the index ring's column write are in
+    it; the three rings alias the outputs; nothing has the shape of the K and V
+    rings or of a layer's pages of them, nothing copies the index ring (a
+    layer's 26 MB of index keys may be cut out for the scoring); no weight is
+    cast; no float32 block over 256 MB; temporaries are a few megabytes."""
+    from opendiloco_tpu.serve.engine import serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, rings = _keye_cell(chip)
+    assert (cfg.kv_heads, cfg.head_dim, cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk) == (
+        4, 128, 16, 64, 2048)
+    params = _bound(chip, cfg)
+    _, decode, _, n = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+    assert n == 3
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(decode, donate_argnums=(4, 5, 6)).lower(params, vec, vec, vec, *rings).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_paged_decode_attn" in text and "odtp_index_ring_write" in text
+    held = sum(x.size * x.dtype.itemsize for x in rings)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 3_256_369_152 and held == 7_059_013_632
+    print(f"keye decode: arguments {mem.argument_size_in_bytes} temporaries {mem.temp_size_in_bytes} "
+          f"aliased {mem.alias_size_in_bytes} program {_program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 64e6
+    assert _program_bytes(compiled) < HBM_BYTES
+    assert not _cache_shaped_results(text, rings[0].shape)
+    assert not _ring_copies(text, rings[2].shape)
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+    assert not _f32_blocks_over(text, 256e6)
+
+
+def test_keye_chunk_program_writes_its_rows_in_place(chip):
+    """The chunk program (512 queries; ``plen``, ``count`` and ``slot``
+    traced, so this is the program behind 15,872 rows too): the three rings
+    alias the outputs and are updated by slice updates alone, no copy of a
+    ring's size (a first form whose K and V pages kept a row contiguous was
+    re-laid rows minor-most by the compiler, 3.09 GB a ring, and did not fit
+    the chip); the index scores and the attention's tiles are the only large
+    float32 blocks and stay under 256 MB; no weight is cast."""
+    from opendiloco_tpu.serve.engine import chunk_program
+
+    cfg, engine, rings = _keye_cell(chip)
+    params = _bound(chip, cfg)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
+    last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
+    compiled = (
+        jax.jit(chunk_program(cfg, compute_dtype=BF16), donate_argnums=(6, 7, 8, 9))
+        .lower(params, ids, scalar, scalar, scalar, last, vec, *rings).compile()
+    )
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in rings)
+    print(f"keye chunk: arguments {mem.argument_size_in_bytes} temporaries {mem.temp_size_in_bytes} "
+          f"aliased {mem.alias_size_in_bytes} program {_program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held == 7_059_013_632
+    assert mem.temp_size_in_bytes < 512e6
+    assert _program_bytes(compiled) < HBM_BYTES
+    assert not _ring_copies(text, rings[0].shape) and not _ring_copies(text, rings[2].shape)
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+    assert not _f32_blocks_over(text, 256e6)
+    assert "f32[512,16896]" in text  # a chunk's index scores: 35 MB, never a block a head
+
+
+def test_keye_index_ring_write_kernel(chip, monkeypatch):
+    """``odtp_index_ring_write`` alone at the cell's shapes: a grid step a
+    layer and slot, the ring aliased through."""
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    _, engine, rings = _keye_cell(chip)
+    L, S, di, _ = rings[2].shape
+    keys = jax.ShapeDtypeStruct((L, S, di), BF16, sharding=chip)
+    lens = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=chip)
+    compiled = (
+        jax.jit(decode_kernels.index_ring_write, donate_argnums=(0,))
+        .lower(rings[2], keys, lens).compile()
+    )
+    assert "odtp_index_ring_write" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= rings[2].size * 2
+    assert not _ring_copies(compiled.as_text(), rings[2].shape)

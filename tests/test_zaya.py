@@ -489,9 +489,8 @@ def test_what_cannot_follow_the_state_says_so(tmp_path):
         engine.fetch_slot_pages(0, 12)
     with pytest.raises(ValueError, match=f"page-in is {REFUSED}"):
         engine.install_slot_pages(0, np.zeros((LAYERS, 16, 2, 16)), np.zeros((LAYERS, 16, 2, 16)))
-    vec = jnp.zeros((4,), jnp.int32)
     with pytest.raises(ValueError, match=f"continued prefill.*{REFUSED}"):
-        llama.continue_prefill(params, jnp.zeros((4, 2), jnp.int32), vec, engine.cache_k, engine.cache_v, cfg)
+        llama.chunk_prefill_forward(params, jnp.zeros((1, 2), jnp.int32), 0, 2, 0, engine.cache_k, engine.cache_v, None, cfg)
     with pytest.raises(ValueError, match="pp pipeline is refused for a configuration whose router reads"):
         pipeline_hidden(params, jnp.zeros((2, 8, 32)), None, cfg, None, microbatches=2, attn_fn=None)
     with pytest.raises(ValueError, match="no CCA"):
