@@ -32,7 +32,12 @@ cache-aware decode, arXiv 2309.06180).
   row rolled into it as a column from a slots-as-lanes copy of the rows.
   Measured on a v5e (PERF.md, PR 35): 1.25 us a grid step of 5 heads of 64
   over a 256-row tile where the per-head form took 2.4, against 0.8 us for
-  the tiles' DMAs alone.
+  the tiles' DMAs alone. So a grid step is sized to a tile budget in all
+  three directions it has (:func:`decode_plan`, PR 50): heads, then 512
+  rows a tile where few heads leave it small, then, where a slot's whole
+  ring is one tile, several consecutive slots, their bodies run one after
+  another over one ``(slots, heads, Dh, block_t)`` tile of K and of V, each
+  slot's 128-row block handed back by a copy of its own.
 - :func:`mla_decode_attention` is the same plan for latent attention: one
   ring of latent rows and no value twin, every head of a slot against the
   same ``(R + rope, block_t)`` tile, the values taken from the tile's
@@ -101,18 +106,22 @@ def _interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
+def _asked_block(t: int, block_t: int | None, interpret: bool) -> int:
+    """The ring-page tile the caller or ``ODTP_DECODE_BLOCK_T`` asks for, in
+    rows, if the ring can be cut so; else 0. Rows are the tiles' lane
+    dimension, so on the chip a tile is a multiple of 128 of them
+    (interpreted, the tests cut small rings into small tiles)."""
+    want = block_t or int(os.environ.get("ODTP_DECODE_BLOCK_T") or 0)
+    return want if want > 0 and t % want == 0 and (interpret or want % 128 == 0) else 0
+
+
 def _ring_block(
     t: int, block_t: int | None, interpret: bool, preferred: int = 256
 ) -> int:
     """Ring-page tile size, in rows: explicit arg > ``ODTP_DECODE_BLOCK_T``
-    > the shared block heuristic. Rows are the tiles' lane dimension, so on
-    the chip a tile is a multiple of 128 of them (interpreted, the tests
-    cut small rings into small tiles), and a ring that no such tile divides
+    > the shared block heuristic. A ring that no tile of 128 rows divides
     has none: 0, and the caller keeps the XLA path."""
-    want = block_t or int(os.environ.get("ODTP_DECODE_BLOCK_T") or 0)
-    if want > 0 and t % want == 0 and (interpret or want % 128 == 0):
-        return want
-    return pick_block(t, preferred)
+    return _asked_block(t, block_t, interpret) or pick_block(t, preferred)
 
 
 # ---------------------------------------------------------------------------
@@ -120,15 +129,29 @@ def _ring_block(
 # ---------------------------------------------------------------------------
 
 
-# A grid step takes as many KV heads of a slot as fit this many bytes of one
-# tile: all 5 of SmolLM2-360M's, all 16 of OLMoE's, all 8 of granite's. What a
-# grid step costs on the v5e, measured (PERF.md, PR 35): about 0.35 us whatever
-# it moves, its K and V tiles at 0.7-0.8 TB/s beside that, and what the step
-# computes on top where that is not hidden: 1.25 us for 5 heads of 64 over a
-# 256-row tile (320 KB read, 160 KB written), 0.8 of it the DMAs. K and V
-# tiles double-buffered and the written 128-row blocks hold six such tiles in
-# VMEM.
+# What a grid step moves is decided here, in the three directions it has: as
+# many KV heads of a slot as fit this many bytes of one K tile (all 5 of
+# SmolLM2-360M's over 256 rows, all 16 of OLMoE's over its 128-row tiles, all 8
+# of granite's, 8 of EvaByte's 32 over 256 rows); then, where the heads leave
+# the tile under it, 512 rows a tile (ZAYA1's 2 heads of 128, Keye's 4); then,
+# where the whole ring of all the heads is one tile, several slots
+# (:data:`_SLOTS_TILE_BYTES`). What a grid step costs on the v5e, measured
+# (PERF.md, PR 35): about 0.35 us whatever it moves, its K and V tiles at
+# 0.7-0.8 TB/s beside that, and what the step computes on top where that is
+# not hidden: 1.25 us for 5 heads of 64 over a 256-row tile (320 KB read, 160
+# KB written), 0.8 of it the DMAs. K and V tiles double-buffered and the
+# written 128-row blocks hold six such tiles in VMEM.
 _HEAD_TILE_BYTES = 512 * 1024
+
+# The K tile of a grid step that holds several slots, and the most slots in
+# one (their bodies are unrolled one after another). Measured on the v5e at the
+# batch cell's shapes (PERF.md, PR 50; us a call of 256 slots x 5 heads of 64
+# x 256 rows): 1 slot a step 291.7, 2 263.3, 4 237.8, 8 227.1, 16 222.7: the
+# 0.35 us a step goes with the steps, and what is left is the tiles' DMAs at
+# 0.55 TB/s whatever their size. 8 slots of that cell are 1.25 MB of K tile:
+# with V, both double-buffered, and the written blocks, 6.3 MB of VMEM.
+_SLOTS_TILE_BYTES = 1280 * 1024
+_MAX_SLOTS_A_STEP = 8
 
 _LANES = 128  # what goes back to the cache: the 128-row block that holds the row
 
@@ -140,12 +163,22 @@ def _heads_per_step(nkv: int, tile_bytes: int) -> int:
     return max(g for g in range(1, nkv + 1) if nkv % g == 0 and g <= fit)
 
 
+def _slots_per_step(num_slots: int, tile_bytes: int) -> int:
+    """The most consecutive slots, :data:`_MAX_SLOTS_A_STEP` at most, whose
+    tiles of ``tile_bytes`` each stay under :data:`_SLOTS_TILE_BYTES`: a
+    divisor of the slot count, and of the 128 lanes, so that a step's slots
+    lie in one block of the slots-as-lanes copy of the new rows."""
+    fit = min(max(1, _SLOTS_TILE_BYTES // tile_bytes), _MAX_SLOTS_A_STEP)
+    return max(n for n in range(1, fit + 1) if num_slots % n == 0 and _LANES % n == 0)
+
+
 class DecodePlan(NamedTuple):
     """What a grid step of ``odtp_paged_decode_attn`` does, from what the call
     can see (:func:`decode_plan`)."""
 
     heads: int  # KV heads of one slot a grid step, under one pair of MXU calls
     block_t: int  # ring rows a tile
+    slots: int = 1  # consecutive slots a grid step, one after another
 
     @property
     def block_diagonal(self) -> bool:
@@ -153,17 +186,34 @@ class DecodePlan(NamedTuple):
         several heads' tiles (with one head they are the head's own)."""
         return self.heads > 1
 
+    def grid(self, num_slots: int, nkv: int, t: int) -> tuple[int, int, int]:
+        """The kernel's grid over ``num_slots`` slots of ``nkv`` KV heads and
+        ``t`` ring rows: (steps of slots, head groups, tiles)."""
+        return (num_slots // self.slots, nkv // self.heads, t // self.block_t)
+
 
 def decode_plan(
     nkv: int, d: int, t: int, itemsize: int,
-    *, block_t: int | None = None, interpret: bool | None = None,
+    *, num_slots: int = 1, block_t: int | None = None, interpret: bool | None = None,
 ) -> DecodePlan | None:
     """The kernel's plan for ``nkv`` KV heads of ``d``, whatever the query heads
-    over each, over a ring of ``t`` rows of ``itemsize`` bytes an element: a
-    pure function of those (and of the tile the caller or
+    over each, over ``num_slots`` rings of ``t`` rows of ``itemsize`` bytes an
+    element: a pure function of those (and of the tile the caller or
     ``ODTP_DECODE_BLOCK_T`` asks for), never of a model's name. None where
-    the kernel cannot tile the shape and the call keeps the XLA path."""
-    bt = _ring_block(t, block_t, _interpret(interpret))
+    the kernel cannot tile the shape and the call keeps the XLA path.
+
+    A grid step is sized to the tile budget in the three directions it has,
+    in this order. Heads: as many of a slot's as fit, over the tile asked for
+    or 256 rows. Rows: where no tile was asked for and the heads' tile of 512
+    rows still fits, 512 rows (a ring that 512 does not divide keeps its
+    tile). Slots: where that leaves the whole ring of all the heads as one
+    tile, as many consecutive slots as fit (:func:`_slots_per_step`); the
+    caller passes ``num_slots`` 1 under ``eva_ring`` and ``chosen``, whose
+    rings are never one tile in any configuration, and keeps the one-slot
+    step."""
+    interp = _interpret(interpret)
+    asked = _asked_block(t, block_t, interp)
+    bt = asked or pick_block(t, 256)
     if d % 8 != 0 or not bt:
         return None
     # the heads' tiles go to the MXU as one [heads * d, bt] operand: the
@@ -171,7 +221,12 @@ def decode_plan(
     # tiles of the cache's dtype (8 rows of 32 bits); else one head a step
     whole = d % (8 * 4 // itemsize) == 0
     heads = _heads_per_step(nkv, d * bt * itemsize) if whole else 1
-    return DecodePlan(heads, bt)
+    if not asked and heads * d * 512 * itemsize <= _HEAD_TILE_BYTES:
+        bt = pick_block(t, 512)
+    slots = 1
+    if bt == t and heads == nkv:
+        slots = _slots_per_step(num_slots, heads * d * bt * itemsize)
+    return DecodePlan(heads, bt, slots)
 
 
 def _head_of(index, size: int, heads: int):
@@ -187,9 +242,81 @@ def _lanes32(x):
     return pltpu.bitcast(x, jnp.uint32) if x.dtype.itemsize == 2 else x
 
 
-def _decode_attn_kernel(
+class _SlotOf:
+    """Slot ``j``'s part of a grid step's block or scratch that holds several
+    slots' on its leading dimension, read and written as the one-slot step
+    reads and writes its own. (An index on every access and not a ``.at[j]``
+    view: Mosaic slices no view out of a block whose minor dimensions are
+    padded to its tiling, and a slot's rows and columns mostly are.)"""
+
+    def __init__(self, ref, j: int):
+        self.ref, self.j = ref, j
+        self.shape, self.dtype = ref.shape[1:], ref.dtype
+
+    def _at(self, idx) -> tuple:
+        return (self.j, *(idx if isinstance(idx, tuple) else (idx,)))
+
+    def __getitem__(self, idx):
+        return self.ref[self._at(idx)]
+
+    def __setitem__(self, idx, value):
+        self.ref[self._at(idx)] = value
+
+
+def _decode_attn_kernel(lens_ref, layer_ref, *rest, slots=1, interpreted=False, **static):
+    """A grid step: one slot's (:func:`_decode_slot_step`), or, under a plan
+    of several slots a step (a ring of one tile, so the step is a slot's
+    whole attention), theirs one after another, each over its own part of the
+    step's blocks and scratch. The caches then come back as whole arrays in
+    ``pl.ANY`` (a slot's written block lies where its own ``lens`` says, which
+    one output block cannot name): each slot's patched 128-row blocks go from
+    VMEM scratch to their place by a copy of their own, started as its body
+    ends and waited for at the step's end. (Read slower on the chip, PERF.md,
+    PR 50: the copies waited for a step later, before the scratch is written
+    again.)"""
+    if slots == 1:
+        return _decode_slot_step(lens_ref, layer_ref, *rest, **static)
+    *rest, back_k, back_v, sems = rest
+    ko_ref, vo_ref = rest[8:10]
+    # the step's and not a slot's: the slots-as-lanes rows, the two caches
+    whole = (3, 4, 8, 9)
+    first = pl.program_id(0) * slots
+
+    def one(j):
+        def hand_back(c, lane0, block):
+            scr = (back_k, back_v)[c].at[j]
+            scr[:] = block
+            place = (ko_ref, vo_ref)[c].at[
+                layer_ref[0], first + j, :, :, pl.ds(lane0, block.shape[-1])
+            ]
+            pltpu.make_async_copy(scr, place, sems.at[c, j]).start()
+
+        _decode_slot_step(
+            lens_ref, layer_ref,
+            *(r if i in whole else _SlotOf(r, j) for i, r in enumerate(rest)),
+            slot=first + j, hand_back=hand_back, **static,
+        )
+
+    # unrolled (as a loop the slots read a tenth slower), but on the chip
+    # traced once: eight traces of the body cost a serving process a second of
+    # set-up (PERF.md, PR 50). Interpreted, Python's own loop: XLA compiles a
+    # loop's body apart from the code around it and rounds it otherwise, and
+    # the parity tests hold the several-slot step to the one-slot step's bits
+    if interpreted:
+        for j in range(slots):
+            one(j)
+    else:
+        jax.lax.fori_loop(0, slots, lambda j, carry: (one(j), carry)[1], 0, unroll=True)
+    for j in range(slots):  # every slot wrote one block of each cache
+        for c, (scr, out_ref) in enumerate(((back_k, ko_ref), (back_v, vo_ref))):
+            place = out_ref.at[0, 0, :, :, pl.ds(0, scr.shape[-1])]
+            pltpu.make_async_copy(scr.at[j], place, sems.at[c, j]).wait()
+
+
+def _decode_slot_step(
     lens_ref, layer_ref, *rest,
     scale, block_t, t, num_t, rep, with_stats, eva_ring=None, with_selection=False,
+    slot=None, hand_back=None,
 ):
     rest = list(rest)
     # under a selection (learned sparse attention): a third prefetched vector,
@@ -217,6 +344,8 @@ def _decode_attn_kernel(
     # [heads * d, bt] operand, their rep query rows each block-diagonal
     rows, width = acc_scr.shape
     si, ti = pl.program_id(0), pl.program_id(2)
+    if slot is not None:  # one of the step's several
+        si = slot
     f32 = jnp.float32
     row_head = _head_of(
         jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), rep, heads
@@ -323,7 +452,7 @@ def _decode_attn_kernel(
     # slots as lanes, so this slot's is a column already: rolled from lane
     # si % 128 to the row's lane and selected into the block (Mosaic has no
     # [1, d] -> [d, 1] reshape)
-    back = ko_ref.shape[-1]
+    back = min(block_t, _LANES)
     for b in range(block_t // back):
         at = new_at - b * back
         here_it_lies = (at >= 0) & (at < back)
@@ -335,8 +464,8 @@ def _decode_attn_kernel(
         def _write():
             lanes = knt_ref.shape[-1]
             shift = jax.lax.rem(at - jax.lax.rem(si, lanes) + lanes, lanes)
-            for new_ref, tile_ref, out_ref in (
-                (knt_ref, k_ref, ko_ref), (vnt_ref, v_ref, vo_ref)
+            for c, (new_ref, tile_ref, out_ref) in enumerate(
+                ((knt_ref, k_ref, ko_ref), (vnt_ref, v_ref, vo_ref))
             ):
                 col = pltpu.roll(_lanes32(new_ref[:]), shift, 1)[:, :back]
                 old = _lanes32(
@@ -346,7 +475,10 @@ def _decode_attn_kernel(
                 patched = jnp.where(here, col, old)
                 if patched.dtype != out_ref.dtype:
                     patched = pltpu.bitcast(patched, out_ref.dtype)
-                out_ref[:] = patched.reshape(heads, d, back)
+                if hand_back is None:
+                    out_ref[:] = patched.reshape(heads, d, back)
+                else:  # (a ring of one tile: the block's first lane is b * back)
+                    hand_back(c, b * back, patched.reshape(heads, d, back))
 
     @pl.when(ti == num_t - 1)
     def _finish():
@@ -429,9 +561,10 @@ def paged_decode_attention(
     t = ring_rows(cache_k)
     h = q.shape[1]
     interp = _interpret(interpret)
+    one_slot_a_step = eva_ring is not None or chosen is not None
     plan = h % nkv == 0 and decode_plan(
         nkv, d, t, cache_k.dtype.itemsize,
-        block_t=block_t, interpret=interp,
+        num_slots=1 if one_slot_a_step else s_, block_t=block_t, interpret=interp,
     )
     if eva_ring is not None and (not plan or eva_ring % plan.block_t):
         raise ValueError(
@@ -444,10 +577,13 @@ def paged_decode_attention(
     if not plan:
         res = decode_step_attention(q, k, v, cache_k, cache_v, lens, layer)
         return (*res, None) if return_stats else res
-    hb, bt = plan
+    hb, bt, n = plan
     rep = h // nkv
     num_t = t // bt
     back = min(bt, _LANES)
+    # the slots of a grid step: theirs is a leading dimension of its blocks
+    # and scratch where they are several, and none where it is the one
+    ns = None if n == 1 else n
     rows, width = hb * rep, hb * d
     q4 = q.reshape(s_, nkv // hb, rows, d)
     kn = k.reshape(s_, nkv, 1, d).astype(cache_k.dtype)
@@ -458,6 +594,8 @@ def paged_decode_attention(
 
     # the prefetched vectors: lens, layer and, under a selection, the row written
     def kv_map(si, gi, ti, lens_ref, layer_ref, *_):
+        if n > 1:  # the whole rings of the step's slots
+            return (layer_ref[0], si, gi, 0, 0)
         # clamp dead blocks to the last live one: unchanged index = no DMA
         last = jnp.minimum(lens_ref[si], t - 1) // bt
         return (layer_ref[0], si, gi, 0, jnp.minimum(ti, last))
@@ -474,30 +612,39 @@ def paged_decode_attention(
     def chosen_map(si, gi, ti, lens_ref, *_):
         return (si, 0, jnp.minimum(ti, jnp.minimum(lens_ref[si], t - 1) // bt))
 
-    queries = pl.BlockSpec((None, None, rows, d), q_map)
-    row = pl.BlockSpec((None, hb, 1, d), q_map)
+    queries = pl.BlockSpec((ns, None, rows, d), q_map)
+    row = pl.BlockSpec((ns, hb, 1, d), q_map)
     column = pl.BlockSpec(
-        (width, _LANES), lambda si, gi, ti, *_: (gi, si // _LANES)
+        (width, _LANES), lambda si, gi, ti, *_: (gi, (si if n == 1 else si * n) // _LANES)
     )
-    out_specs = [
-        queries,
-        pl.BlockSpec((None, None, hb, d, back), written_map),
-        pl.BlockSpec((None, None, hb, d, back), written_map),
-    ]
+    # the block that goes back: the pipeline's own output where a step is one
+    # slot; the kernel's own copies into the whole cache where it is several
+    written = (
+        pl.BlockSpec((None, None, hb, d, back), written_map) if n == 1
+        else pl.BlockSpec(memory_space=pl.ANY)
+    )
+    out_specs = [queries, written, written]
     out_shape = [
         jax.ShapeDtypeStruct(q4.shape, q.dtype, vma=jax.typeof(q).vma),
         jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
         jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
     ]
+    slot = () if n == 1 else (n,)
     scratch = [
-        pltpu.VMEM((rows, width), q.dtype),  # the block-diagonal queries
-        pltpu.VMEM((rows, 1), jnp.float32),  # the step's own scores
-        pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, width), jnp.float32),
+        pltpu.VMEM((*slot, rows, width), q.dtype),  # the block-diagonal queries
+        pltpu.VMEM((*slot, rows, 1), jnp.float32),  # the step's own scores
+        pltpu.VMEM((*slot, rows, 1), jnp.float32),
+        pltpu.VMEM((*slot, rows, 1), jnp.float32),
+        pltpu.VMEM((*slot, rows, width), jnp.float32),
     ]
+    if n > 1:  # each slot's written blocks on their way back, and their copies' semaphores
+        scratch += [
+            pltpu.VMEM((n, hb, d, back), cache_k.dtype),
+            pltpu.VMEM((n, hb, d, back), cache_v.dtype),
+            pltpu.SemaphoreType.DMA((2, n)),
+        ]
     if return_stats:
-        out_specs.append(pl.BlockSpec((None, hb, 1, 1), q_map))
+        out_specs.append(pl.BlockSpec((ns, hb, 1, 1), q_map))
         out_shape.append(jax.ShapeDtypeStruct((s_, nkv, 1, 1), jnp.int32))
     if eva_ring is not None:  # a grid step's rows, as the queries are laid
         out_specs += [pl.BlockSpec((None, None, rows, 1), q_map)] * 2
@@ -510,15 +657,15 @@ def paged_decode_attention(
         selection = [chosen.astype(jnp.int32).reshape(s_, 1, t)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetched),
-        grid=(s_, nkv // hb, num_t),
+        grid=plan.grid(s_, nkv, t),
         in_specs=[
             queries,
             row,
             row,
             column,
             column,
-            pl.BlockSpec((None, None, hb, d, bt), kv_map),
-            pl.BlockSpec((None, None, hb, d, bt), kv_map),
+            pl.BlockSpec((None, ns, hb, d, bt), kv_map),
+            pl.BlockSpec((None, ns, hb, d, bt), kv_map),
             *([pl.BlockSpec((None, 1, bt), chosen_map)] if selection else []),
         ],
         out_specs=out_specs,
@@ -531,6 +678,7 @@ def paged_decode_attention(
             with_stats=return_stats,
             **({} if eva_ring is None else {"eva_ring": int(eva_ring)}),
             **({"with_selection": True} if selection else {}),
+            **({} if n == 1 else {"slots": n, "interpreted": interp}),
         ),
         name="odtp_eva_pooled_attn" if eva_ring else "odtp_paged_decode_attn",
         grid_spec=grid_spec,

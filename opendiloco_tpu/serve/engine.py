@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Callable, Optional, Sequence
 
@@ -1318,10 +1319,12 @@ class ServeEngine:
         """Time the decode step's attention in isolation on the engine's live
         shapes and publish it as a gauge (serve_decode_attn_us), per dispatch
         path; and, for a keys-and-values ring, the decode kernel's plan at
-        those shapes (serve_decode_plan_heads, _block_t, _block_diagonal).
+        those shapes (serve_decode_plan_heads, _block_t, _block_diagonal,
+        _slots) and the grid steps it makes a decode step
+        (serve_decode_grid_steps).
 
         Best-of-``iters`` steady-state timings on the resolved path
-        (``self.decode_kernel``)."""
+        (``self.decode_kernel``), beside :meth:`decode_plan_stats`."""
         cfg, cd = self.cfg, self.compute_dtype
         S, T = self.num_slots, self.max_context
         Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
@@ -1341,7 +1344,7 @@ class ServeEngine:
             return self._publish_probe({"decode_attn_us": _best_us(
                 _latent, ql, jnp.full((S,), T // 2, jnp.int32), self.cache_k[:1],
                 carried=1, iters=iters,
-            )})
+            ), **self.decode_plan_stats()})
         if cfg.sparse:
             # a decode step's indexing and attention over the three rings of one
             # layer, every slot three quarters full
@@ -1370,6 +1373,7 @@ class ServeEngine:
                 "dsa_kv_bytes_read": float(self.dsa_kv_bytes_read),
                 "prefill_chunks": float(self.prefill_chunks),
                 "prefill_chunk_tokens": float(self.prefill_chunk_tokens),
+                **self.decode_plan_stats(),
             })
         if cfg.eva:
             # a decode step's attention over both rings of the one layer, at
@@ -1392,14 +1396,8 @@ class ServeEngine:
                 _eva, q1, k1, jnp.full((S,), at, jnp.int32), self.cache_k[:1],
                 self.cache_v[:1], *(x[:1] for x in self._eva), carried=5, iters=iters,
             )}
-            plans = pallas and eva_plans(
-                Nkv, Dh, window, chunk, self._eva[0].shape[-1], self.cache_k.dtype.itemsize
-            )
-            for name, plan in zip(("decode_plan", "eva_pooled_plan"), plans or (DecodePlan(0, 0),) * 2):
-                out[f"{name}_heads"] = float(plan.heads)
-                out[f"{name}_block_t"] = float(plan.block_t)
             out["eva_cache_resident_bytes"] = float(self.eva_cache_resident_bytes)
-            return self._publish_probe(out)
+            return self._publish_probe({**out, **self.decode_plan_stats()})
         q1 = jax.random.normal(key, (S, Nh, Dh), cd)
         ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
         lens = jnp.full((S,), T // 2, jnp.int32)
@@ -1416,13 +1414,42 @@ class ServeEngine:
                 _attn, q1, k1, lens, ck[None], cv[None], carried=2, iters=iters
             ),
         }
-        # which form of the decode kernel these shapes take (zeros: the XLA path)
-        plan = pallas and decode_plan(Nkv, Dh, T, self.cache_k.dtype.itemsize)
-        plan = plan or DecodePlan(0, 0)
-        out["decode_plan_heads"] = float(plan.heads)
-        out["decode_plan_block_t"] = float(plan.block_t)
-        out["decode_plan_block_diagonal"] = float(plan.block_diagonal)
-        return self._publish_probe(out)
+        return self._publish_probe({**out, **self.decode_plan_stats()})
+
+    def decode_plan_stats(self) -> dict:
+        """Which form of ``odtp_paged_decode_attn`` the engine's shapes take
+        (``decode_kernels.decode_plan``: KV heads, ring rows and slots a grid
+        step; EVA's pooled ring's beside the window's) and the grid steps
+        that makes a decode step over all attention layers. From shapes
+        alone, so always there (``GET /stats``, ``kernel_probe``); zeros where
+        that kernel does not run: the XLA path, a latent ring."""
+        cfg = self.cfg
+        layers, S, Nkv, Dh, T = self.cache_k.shape
+        size = self.cache_k.dtype.itemsize
+        none = DecodePlan(0, 0, 0)
+        rings = [(none, T)]
+        if self.decode_kernel == "pallas" and cfg.eva:
+            pooled_rows = self._eva[0].shape[-1]
+            plans = eva_plans(Nkv, Dh, cfg.window_size, cfg.chunk_size, pooled_rows, size)
+            rings = list(zip(plans or (none, none), (T, pooled_rows)))
+        elif self.decode_kernel == "pallas" and not cfg.latent:
+            # under a selection (learned sparse attention) a step is one slot's
+            plan = decode_plan(Nkv, Dh, T, size, num_slots=1 if cfg.sparse else S)
+            rings = [(plan or none, T)]
+        plan = rings[0][0]
+        out = {
+            "decode_plan_heads": float(plan.heads),
+            "decode_plan_block_t": float(plan.block_t),
+            "decode_plan_block_diagonal": float(plan.block_diagonal),
+            "decode_plan_slots": float(plan.slots),
+            "decode_grid_steps": float(layers * sum(
+                math.prod(p.grid(S, Nkv, rows)) for p, rows in rings if p.heads
+            )),
+        }
+        if cfg.eva:
+            out["eva_pooled_plan_heads"] = float(rings[-1][0].heads)
+            out["eva_pooled_plan_block_t"] = float(rings[-1][0].block_t)
+        return out
 
     def _publish_probe(self, out: dict) -> dict:
         for name, us in out.items():

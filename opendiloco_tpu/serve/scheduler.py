@@ -1301,6 +1301,13 @@ class ContinuousBatcher:
             # the step in flight before it could act, by what asked
             "steps_ahead": self.engine.steps_ahead,
             "step_drains": dict(self.step_drains),
+            # what a grid step of the decode kernel holds at the engine's shapes,
+            # and the grid steps that makes a decode step (zeros: no such kernel),
+            # under the names of ``kernel_probe``'s gauges
+            "decode_plan": {
+                f"serve_{name}": int(value)
+                for name, value in self.engine.decode_plan_stats().items()
+            },
             # what the indexer and the attention under its selection did, and the
             # prompts admitted in chunks (zeros without learned sparse attention)
             "dsa": {

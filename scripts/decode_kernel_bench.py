@@ -25,13 +25,23 @@ attention kernel alone at a serving cell's shapes (the batch cell:
 ``--slots 256 --heads 15 --kv-heads 5 --head-dim 64 --ring 256 --lens
 32:256``; OLMoE's: ``--slots 16 --heads 16 --kv-heads 16 --head-dim 128
 --ring 3200 --lens 1024:3080``). Prints the plan those shapes take
-(``decode_kernels.decode_plan``) and, on a TPU, checks the kernel against
+(``decode_kernels.decode_plan``: heads, rows and slots a grid step, and the
+grid) and, on a TPU, checks the kernel against
 ``decode_step_attention`` there (outputs to rounding, both caches bit for
 bit, half the slots wrapped) and gives the time of a call over a
 cache of one layer's size that the calls hand on (donated, as the engine's
 decode scan and ``ServeEngine.kernel_probe`` do): ``pallas_us`` and
 ``xla_us``, each the difference of a program of 128 calls and one of 32,
 over 96, so that starting a program and waiting for it is not in the number.
+
+--step-slots 1,2,4,8 / --tiles 256,512: beside the plan the shapes take, the
+same check and time under each of these slots a grid step (the batch cell's
+shapes: a ring of one tile) or rows a tile (ZAYA1's: ``--slots 128 --heads 8
+--kv-heads 2 --head-dim 128 --ring 1536 --lens 256:1536``; Keye's: ``--slots
+12 --heads 32 --kv-heads 4 --head-dim 128 --ring 16896 --lens 12288:16640``),
+one command a sweep. (``--slots`` is the batch's slot count, as ever; a sweep
+sets the slots' tile budget or passes ``block_t``: the program has no option
+for either.)
 """
 
 import argparse
@@ -168,11 +178,13 @@ def _us_a_call(step, q, k, lens, shape, dtype, *, layers=8, calls=(32, 128), ite
 
 def _at_cell_shapes(doc: dict, args) -> None:
     """The decode attention kernel at the shapes given on the command line:
-    its plan, always; on a TPU its time a call beside the XLA path's."""
+    its plan, always; on a TPU its time a call beside the XLA path's, and
+    under each plan of the sweeps asked for."""
     import jax
     import jax.numpy as jnp
 
     from opendiloco_tpu.models.ring_cache import cache_shape
+    from opendiloco_tpu.ops import decode_kernels
     from opendiloco_tpu.ops.attention import decode_step_attention
     from opendiloco_tpu.ops.decode_kernels import decode_plan, paged_decode_attention
 
@@ -180,36 +192,56 @@ def _at_cell_shapes(doc: dict, args) -> None:
     dtype = jnp.dtype(args.dtype)
     lo, hi = (int(x) for x in args.lens.split(":"))
     on_tpu = jax.default_backend() == "tpu"
-    plan = decode_plan(Kh, D, T, dtype.itemsize, interpret=False)  # the chip's
-    row = {
-        "shape": f"S{S} Hq{H} Hkv{Kh} D{D} T{T} {dtype.name} lens {lo}:{hi}",
-        "plan": None if plan is None else {
-            **plan._asdict(), "block_diagonal": plan.block_diagonal,
-            "grid": [S, Kh // plan.heads, T // plan.block_t],
-        },
-    }
-    print(f"plan at {row['shape']}: {row['plan']}")
+    row = {"shape": f"S{S} Hq{H} Hkv{Kh} D{D} T{T} {dtype.name} lens {lo}:{hi}"}
     if on_tpu:
         rng = np.random.default_rng(0)
         q = jnp.asarray(rng.standard_normal((S, H, D)), dtype)
         k = jnp.asarray(rng.standard_normal((S, Kh, D)), dtype)
         lens = jnp.asarray(rng.integers(lo, hi, S), jnp.int32)
         shape = cache_shape(8, S, T, Kh, D)
-        # the kernel against its XLA twin on this chip first, over full rings
-        # (a wrapped slot evicts a row): outputs to rounding, caches bit for bit
         ck = jnp.asarray(0.5 * rng.standard_normal(shape[1:])[None], dtype)
         wrapped = lens.at[: S // 2].add(T)
         ref, rk, rv = jax.jit(decode_step_attention)(q, k, -k, ck, ck, wrapped, 0)
-        got, gk, gv = jax.jit(paged_decode_attention)(q, k, -k, ck, ck, wrapped, 0)
-        diff = np.asarray(got, np.float32) - np.asarray(ref, np.float32)
-        row["out_rel_l2"] = float(
-            np.linalg.norm(diff) / np.linalg.norm(np.asarray(ref, np.float32))
-        )
-        row["caches_bit_equal"] = bool(jnp.array_equal(gk, rk) & jnp.array_equal(gv, rv))
-        assert row["caches_bit_equal"] and row["out_rel_l2"] < 2e-2, row
-        row["pallas_us"] = _us_a_call(paged_decode_attention, q, k, lens, shape, dtype)
+
+    def measure(**kw) -> dict:
+        """The plan the shapes take (under ``block_t``, if given) and, on a
+        TPU, the kernel under it against its XLA twin on this chip, over full
+        rings (a wrapped slot evicts a row): outputs to rounding, caches bit
+        for bit; then its time a call."""
+        plan = decode_plan(Kh, D, T, dtype.itemsize, num_slots=S, interpret=False, **kw)
+        grid = plan and plan.grid(S, Kh, T)
+        out = {"plan": plan and {
+            **plan._asdict(), "block_diagonal": plan.block_diagonal,
+            "grid": list(grid), "grid_steps": int(np.prod(grid)),
+        }}
+        if on_tpu:
+            step = functools.partial(paged_decode_attention, **kw)
+            got, gk, gv = jax.jit(step)(q, k, -k, ck, ck, wrapped, 0)
+            diff = np.asarray(got, np.float32) - np.asarray(ref, np.float32)
+            out["out_rel_l2"] = float(
+                np.linalg.norm(diff) / np.linalg.norm(np.asarray(ref, np.float32))
+            )
+            out["caches_bit_equal"] = bool(jnp.array_equal(gk, rk) & jnp.array_equal(gv, rv))
+            assert out["caches_bit_equal"] and out["out_rel_l2"] < 2e-2, out
+            out["pallas_us"] = _us_a_call(step, q, k, lens, shape, dtype)
+        return out
+
+    row.update(measure())
+    print(f"plan at {row['shape']}: {row['plan']}")
+    if on_tpu:
         row["xla_us"] = _us_a_call(decode_step_attention, q, k, lens, shape, dtype)
         print(f"a call: pallas {row['pallas_us']} us, xla {row['xla_us']} us")
+    # the sweeps: each plan the program could take here, forced from outside
+    budget = decode_kernels._SLOTS_TILE_BYTES, decode_kernels._MAX_SLOTS_A_STEP
+    for n in args.step_slots:
+        decode_kernels._SLOTS_TILE_BYTES = n * Kh * D * T * dtype.itemsize
+        decode_kernels._MAX_SLOTS_A_STEP = n
+        row[f"step_slots_{n}"] = measure()
+        print(f"{n} slots a grid step: {row[f'step_slots_{n}']}")
+    decode_kernels._SLOTS_TILE_BYTES, decode_kernels._MAX_SLOTS_A_STEP = budget
+    for bt in args.tiles:
+        row[f"tile_{bt}"] = measure(block_t=bt)
+        print(f"{bt} rows a tile: {row[f'tile_{bt}']}")
     doc["decode_attention_at"] = row
 
 
@@ -294,6 +326,9 @@ def main() -> int:
     ap.add_argument("--ring", type=int, default=256)
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--lens", default="0:256", help="LO:HI, a slot's rows drawn from [LO, HI)")
+    ints = lambda text: [int(x) for x in text.split(",") if x]
+    ap.add_argument("--step-slots", type=ints, default=[], help="sweep: slots a grid step, e.g. 1,2,4,8")
+    ap.add_argument("--tiles", type=ints, default=[], help="sweep: ring rows a tile, e.g. 256,512")
     args = ap.parse_args()
     import jax
 

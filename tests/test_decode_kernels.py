@@ -125,8 +125,8 @@ def test_paged_decode_attention_parity(heads):
     (granite's, OLMoE's)."""
     H, Kh, D = (*heads, 16)[:3]
     T = 32
-    plan = decode_kernels.decode_plan(Kh, D, T, 4, block_t=8, interpret=True)
-    assert plan == (Kh, 8) and plan.block_diagonal == (Kh > 1)
+    plan = decode_kernels.decode_plan(Kh, D, T, 4, num_slots=5, block_t=8, interpret=True)
+    assert plan == (Kh, 8, 1) and plan.block_diagonal == (Kh > 1)
     # ragged: empty slot, mid-page, last live row, exactly T, wrapped
     _assert_decode_parity(
         _rng(H * 31 + Kh), 5, H, Kh, D, T, [0, 5, T - 1, T, 2 * T + 3],
@@ -175,27 +175,139 @@ def test_paged_decode_stale_row_does_not_leak(dtype):
 @pytest.mark.parametrize(
     "cell,shape,plan",
     [
-        # (kv heads, head size, ring rows, bytes an element) -> (heads, tile)
-        ("serve-360m-batch", (5, 64, 256, 2), (5, 256)),
-        ("serve-olmoe-fewshot", (16, 128, 3200, 2), (16, 128)),
-        ("serve-granite-h-docqa", (8, 128, 2176, 2), (8, 128)),
-        ("serve-1.7b-chat", (32, 64, 2048, 2), (16, 256)),
-        ("float32", (5, 64, 256, 4), (5, 256)),
-        ("rows_off_the_sublanes", (4, 8, 256, 2), (1, 256)),
-        ("head_dim_12", (2, 12, 256, 4), None),
-        ("ring_of_96_rows", (2, 16, 96, 4), None),
+        # (kv heads, head size, ring rows, bytes an element, slots) ->
+        # (heads, rows, slots) a grid step; the eight configurations first
+        ("serve-360m-batch", (5, 64, 256, 2, 256), (5, 256, 8)),
+        ("serve-olmoe-fewshot", (16, 128, 3200, 2, 16), (16, 128, 1)),
+        ("serve-granite-h-docqa", (8, 128, 2176, 2, 32), (8, 128, 1)),
+        ("serve-glm-flash-agent", (1, 576, 2048, 2, 64), (1, 256, 1)),  # (its ring takes another kernel)
+        ("serve-zaya1-reason", (2, 128, 1536, 2, 128), (2, 512, 1)),
+        ("serve-evabyte-complete", (32, 128, 2048, 2, 24), (8, 256, 1)),
+        ("serve-keye-videoqa", (4, 128, 16896, 2, 1), (4, 512, 1)),
+        ("serve-1.7b-chat", (32, 64, 2048, 2, 64), (16, 256, 1)),
+        ("float32", (5, 64, 256, 4, 1), (5, 256, 1)),
+        ("a_ring_of_one_512_row_tile", (2, 128, 512, 2, 1), (2, 512, 1)),
+        ("a_ring_512_does_not_divide", (2, 128, 768, 2, 64), (2, 256, 1)),
+        ("rows_off_the_sublanes", (4, 8, 256, 2, 64), (1, 256, 1)),
+        ("head_dim_12", (2, 12, 256, 4, 64), None),
+        ("ring_of_96_rows", (2, 16, 96, 4, 64), None),
     ],
     ids=lambda x: x if isinstance(x, str) else None,
 )
 def test_decode_plan_is_a_function_of_shapes(cell, shape, plan):
-    """The plan at the cells' shapes, on the chip (not interpreted): all the
-    KV heads of a slot that fit 512 KB of tile under one pair of MXU calls;
-    one head a step where the heads' rows do not merge into whole sublane
-    tiles; none (the XLA path) where the kernel cannot tile the shape."""
-    got = decode_kernels.decode_plan(*shape, interpret=False)
+    """The plan at the cells' shapes, on the chip (not interpreted): a grid
+    step filled to the tile budget by heads (all the KV heads of a slot that
+    fit 512 KB of K tile under one pair of MXU calls), then by rows (512 a
+    tile where the heads leave room and 512 divides the ring), then by slots
+    (where the whole ring of all the heads is one tile); one head a step where
+    the heads' rows do not merge into whole sublane tiles; none (the XLA path)
+    where the kernel cannot tile the shape."""
+    *shape, num_slots = shape
+    got = decode_kernels.decode_plan(*shape, num_slots=num_slots, interpret=False)
     assert got == plan
     if plan:
         assert got.block_diagonal == (plan[0] > 1)
+        assert num_slots % got.slots == 0 and 128 % got.slots == 0
+
+
+@pytest.mark.parametrize(
+    "num_slots,fit,slots",
+    [(256, 3, 2), (256, 4, 4), (256, 9, 8), (24, 8, 8), (12, 8, 4), (7, 8, 1), (256, 0, 1), (384, 200, 8)],
+)
+def test_slots_a_step_divide_the_slots_and_the_lanes(monkeypatch, num_slots, fit, slots):
+    """As many slots a grid step as the budget holds tiles, 8 at most, of the
+    divisors of the slot count that divide the 128 lanes too (a step's slots
+    lie in one block of the slots-as-lanes rows)."""
+    monkeypatch.setattr(decode_kernels, "_SLOTS_TILE_BYTES", fit * 1024)
+    assert decode_kernels._slots_per_step(num_slots, 1024) == slots
+
+
+def _several_slots(monkeypatch, slots, Kh, D, T, itemsize=4):
+    """Hold the plan of a one-tile ring to ``slots`` slots a grid step."""
+    monkeypatch.setattr(decode_kernels, "_SLOTS_TILE_BYTES", slots * Kh * D * T * itemsize)
+
+
+@pytest.mark.parametrize("heads", [(15, 5), (4, 4), (4, 1)], ids=["gqa15_5", "mha", "mqa"])
+@pytest.mark.parametrize("slots", [2, 4])
+def test_paged_decode_several_slots_a_grid_step(monkeypatch, slots, heads):
+    """A ring of one 256-row tile under a plan of 2 and of 4 slots a grid
+    step, against ``decode_step_attention`` and against the one-slot plan to
+    the bit: ragged ``lens`` (0, mid-tile, ``T - 1``, wrapped past ``T``),
+    neighbours whose rows fall in different 128-row blocks within one grid
+    step (each goes back by a copy of its own), and 12 slots, which 4 divides
+    and 8 would not."""
+    H, Kh = heads
+    S, D, T = 12, 8, 256
+    lens = [0, 200, 127, 128, T - 1, 3, T, T + 127, T + 128, 3 * T + 5, 64, 255]
+    _several_slots(monkeypatch, slots, Kh, D, T)
+    plan = decode_kernels.decode_plan(Kh, D, T, 4, num_slots=S, interpret=True)
+    assert plan == (Kh, T, slots) and plan.grid(S, Kh, T) == (S // slots, 1, 1)
+    rng = _rng(slots * 7 + H)
+    ck, cv = _ring(rng, 2, S, Kh, D, T)
+    q, k, v = _randn(rng, S, H, D), _randn(rng, S, Kh, D), _randn(rng, S, Kh, D)
+    lens = jnp.asarray(lens, jnp.int32)
+    got = paged_decode_attention(q, k, v, ck, cv, lens, 1, interpret=True, return_stats=True)
+    ref = decode_step_attention(q, k, v, ck, cv, lens, 1)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), atol=2e-6)
+    monkeypatch.setattr(decode_kernels, "_SLOTS_TILE_BYTES", 0)
+    one = paged_decode_attention(q, k, v, ck, cv, lens, 1, interpret=True, return_stats=True)
+    for a, b, r in zip(got, one, (*ref, None)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if r is not None and a.ndim == 5:  # both caches: the reference's, bit for bit
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+    np.testing.assert_array_equal(np.asarray(got[1][0]), np.asarray(ck[0]))  # layer 0 as it was
+
+
+def test_paged_decode_several_slots_in_16_bit_rows(monkeypatch):
+    """The same in bf16, where a row moves into its block as 32-bit words:
+    heads of 16 merge (whole sublane tiles of 16 rows), so 3 KV heads and 4
+    slots share a grid step, and the caches come back bit for bit."""
+    S, H, Kh, D, T = 8, 6, 3, 16, 256
+    _several_slots(monkeypatch, 4, Kh, D, T, itemsize=2)
+    assert decode_kernels.decode_plan(Kh, D, T, 2, num_slots=S, interpret=True) == (Kh, T, 4)
+    rng = _rng(50)
+    ck, cv = (c.astype(jnp.bfloat16) for c in _ring(rng, 2, S, Kh, D, T))
+    q, k, v = (_randn(rng, S, n, D).astype(jnp.bfloat16) for n in (H, Kh, Kh))
+    lens = jnp.asarray([0, 127, 128, T - 1, T, T + 128, 3 * T + 5, 77], jnp.int32)
+    ref, rk, rv = decode_step_attention(q, k, v, ck, cv, lens, 1)
+    out, ok, ov = paged_decode_attention(q, k, v, ck, cv, lens, 1, interpret=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=2e-2)
+    np.testing.assert_array_equal(np.asarray(ok, np.float32), np.asarray(rk, np.float32))
+    np.testing.assert_array_equal(np.asarray(ov, np.float32), np.asarray(rv, np.float32))
+
+
+@pytest.mark.parametrize("heads", [(8, 2), (4, 4)], ids=["gqa8_2", "mha"])
+def test_paged_decode_512_row_tiles_match_256_row_tiles(heads):
+    """A ring of 1,024 rows under the plan's own tile (512 rows: the heads
+    leave room) and under 256 asked for, ``lens`` in the first tile and the
+    last, at a tile's two edges, empty and wrapped: both caches bit for bit,
+    and the reference's; the outputs to float32 rounding of each other and of
+    the reference (one softmax either way, but an online softmax over wider
+    tiles adds its terms in another order), and to the bit where the live rows
+    are one tile under both."""
+    H, Kh = heads
+    S, D, T = 8, 8, 1024
+    assert decode_kernels.decode_plan(Kh, D, T, 4, num_slots=S, interpret=True) == (Kh, 512, 1)
+    assert decode_kernels.decode_plan(Kh, D, T, 4, block_t=256, interpret=True) == (Kh, 256, 1)
+    rng = _rng(H + 512)
+    ck, cv = _ring(rng, 2, S, Kh, D, T)
+    q, k, v = _randn(rng, S, H, D), _randn(rng, S, Kh, D), _randn(rng, S, Kh, D)
+    lens = jnp.asarray([0, 100, 511, 512, 700, T - 1, T, 2 * T + 300], jnp.int32)
+    wide = paged_decode_attention(q, k, v, ck, cv, lens, 1, interpret=True, return_stats=True)
+    narrow = paged_decode_attention(
+        q, k, v, ck, cv, lens, 1, interpret=True, return_stats=True, block_t=256
+    )
+    for a, b in zip(wide[1:3], narrow[1:3]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(np.asarray(wide[0]), np.asarray(narrow[0]), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(wide[0][:2]), np.asarray(narrow[0][:2]))
+    ref = decode_step_attention(q, k, v, ck, cv, lens, 1)
+    np.testing.assert_allclose(np.asarray(wide[0]), np.asarray(ref[0]), atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(wide[1]), np.asarray(ref[1]))
+    np.testing.assert_array_equal(np.asarray(wide[2]), np.asarray(ref[2]))
+    # live tiles walked: half as many, rounded up
+    assert np.asarray(wide[3])[:, 0].tolist() == [1, 1, 1, 2, 2, 2, 2, 2]
+    assert np.asarray(narrow[3])[:, 0].tolist() == [1, 1, 2, 3, 3, 4, 4, 4]
 
 
 def test_paged_decode_attention_default_tile_and_head_groups(monkeypatch):
@@ -500,7 +612,10 @@ def test_batcher_token_streams_identical(tiny_cfg, family, small_tiles):
 def test_engine_kernel_probe_gauges(tiny_cfg):
     eng = _make_engine(tiny_cfg, "xla")
     out = eng.kernel_probe(iters=1)
-    plan = {"decode_plan_heads", "decode_plan_block_t", "decode_plan_block_diagonal"}
+    plan = {
+        "decode_plan_heads", "decode_plan_block_t", "decode_plan_block_diagonal",
+        "decode_plan_slots", "decode_grid_steps",
+    }
     assert set(out) == {"decode_attn_us"} | plan
     assert all(out[k] > 0 for k in set(out) - plan)
     assert all(out[k] == 0 for k in plan)  # the XLA path: no kernel, no plan
@@ -519,3 +634,31 @@ def test_engine_kernel_probe_carries_the_plan(tiny_cfg, small_tiles):
     assert out["decode_plan_heads"] == want.heads
     assert out["decode_plan_block_t"] == want.block_t == 8
     assert out["decode_plan_block_diagonal"] == float(want.block_diagonal)
+    # three tiles a ring: a grid step is one slot's, and a decode step makes
+    # slots x head groups x tiles of them a layer
+    assert out["decode_plan_slots"] == want.slots == 1
+    steps = tiny_cfg.num_hidden_layers * 2 * (tiny_cfg.kv_heads // want.heads) * 3
+    assert out["decode_grid_steps"] == steps
+    # always on, with no probe run: what ``GET /stats`` carries
+    assert ContinuousBatcher(eng).stats()["decode_plan"] == {
+        "serve_decode_plan_heads": want.heads, "serve_decode_plan_block_t": 8,
+        "serve_decode_plan_block_diagonal": int(want.block_diagonal),
+        "serve_decode_plan_slots": 1, "serve_decode_grid_steps": steps,
+    }
+
+
+def test_engine_plan_of_several_slots_a_step(tiny_cfg, monkeypatch):
+    """An engine whose ring is one tile: the probe and ``GET /stats`` say how
+    many slots share a grid step and how many grid steps that leaves, and the
+    engine's tokens under that plan are the XLA path's."""
+    kw = dict(num_slots=4, max_context=128, prefill_buckets=(8,))
+    row = tiny_cfg.kv_heads * tiny_cfg.head_dim * 128 * 4
+    monkeypatch.setattr(decode_kernels, "_SLOTS_TILE_BYTES", 2 * row)
+    eng = _make_engine(tiny_cfg, "pallas", **kw)
+    stats = eng.decode_plan_stats()
+    assert stats["decode_plan_slots"] == 2 and stats["decode_plan_block_t"] == 128
+    assert stats["decode_grid_steps"] == tiny_cfg.num_hidden_layers * 2
+    prompt = list(range(3, 9))
+    assert _generate(eng, prompt, 6, slot=1) == _generate(
+        _make_engine(tiny_cfg, "xla", **kw), prompt, 6, slot=1
+    )

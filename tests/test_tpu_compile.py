@@ -255,7 +255,10 @@ def test_paged_decode_attention(chip, model, return_stats):
     """The kernel alone, handed a cache of two layers and the second's
     index: the read and the row write in one ``tpu_custom_call``, under the
     plan the shapes give; what it reads is a ``(heads, d, block_t)`` tile of
-    K and of V, what it hands back the 128-row block that holds the row."""
+    K and of V, what it hands back the 128-row block that holds the row.
+    Where the plan puts several slots in a grid step (SmolLM2-360M's ring of
+    one tile) the tile holds theirs, and the caches come back whole, in no
+    block: each slot's 128-row block by the kernel's own copy."""
     hq, hkv, d, rows = DECODE[model]
     s = 8
     cache = (cache_shape(2, s, rows, hkv, d), BF16)
@@ -273,13 +276,27 @@ def test_paged_decode_attention(chip, model, return_stats):
         cache,
         ((s,), jnp.int32),
     )
-    text = compiled_text(chip, step, *shapes)
-    assert "tpu_custom_call" in text
-    heads, block_t = decode_kernels.decode_plan(hkv, d, rows, 2, interpret=False)
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        *(jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in shapes)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    heads, block_t, slots = decode_kernels.decode_plan(
+        hkv, d, rows, 2, num_slots=s, interpret=False
+    )
+    assert (slots > 1) == (model == "smollm2-360m") and s % slots == 0
     ins, outs = _kernel_blocks(step, *shapes)
-    assert ins[-2:] == [(None, None, heads, d, block_t)] * 2
-    assert outs[1:3] == [(None, None, heads, d, 128)] * 2
-    assert outs[0] == (None, None, heads * (hq // hkv), d)
+    if slots == 1:
+        assert ins[-2:] == [(None, None, heads, d, block_t)] * 2
+        assert outs[1:3] == [(None, None, heads, d, 128)] * 2
+        assert outs[0] == (None, None, heads * (hq // hkv), d)
+    else:
+        assert ins[-2:] == [(None, slots, heads, d, block_t)] * 2
+        assert outs[1:3] == [cache[0]] * 2  # the whole array: ``pl.ANY``
+        assert outs[0] == (slots, None, heads * (hq // hkv), d)
+    # the (donated) caches go back where they lie under either
+    cache_bytes = 2 * np.prod(cache[0])
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * cache_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 2
 
 
 def _suffix_memory(chip, model, kq, rows):
@@ -506,12 +523,18 @@ def test_decode_step_moves_no_cache(chip, cell, monkeypatch):
     cell's slots and rows: the decode kernel is in it; its temporaries stay under the bf16
     copy of the weights plus one layer's pages; and no copy, transpose,
     scatter, slice, update or fresh buffer in it has the shape of the cache
-    or of one layer's pages."""
+    or of one layer's pages. The batch cell's plan holds several slots a grid
+    step (ISSUE 50): its caches come back in ``pl.ANY``, written by the
+    kernel's own copies, and alias their inputs all the same."""
     from opendiloco_tpu.models.llama import decode_forward
 
     # off the TPU the wrappers would interpret the kernel; this is the chip's
     monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
     cfg, engine, params, cache = _serving_shapes(chip, cell)
+    plan = decode_kernels.decode_plan(
+        cfg.kv_heads, cfg.head_dim, engine["max_context"], 2, num_slots=engine["num_slots"]
+    )
+    assert (plan.slots > 1) == (cell == "smollm2-360m")
     vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
     moe = bool(cfg.num_experts)
     compiled = (
