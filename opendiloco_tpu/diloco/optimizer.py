@@ -396,6 +396,12 @@ class DiLoCoOptimizer:
         self._abandoned: Optional[Any] = None  # dropped round still running
         self._landed_metrics: Optional[dict[str, Any]] = None
         self._apply_delta = None
+        # how to lower the outer programs again (``program_texts``): one
+        # list with the device plane's
+        self._recipes = (
+            self._plane.recipes if self._plane is not None else obs.programs.Recipes()
+        )
+        obs.programs.register(self)
         # fragment (None: all leaves) -> the pieces its blocking round runs in
         self._piece_cache: dict = {}
         # persistent pseudo-gradient buffers (reference: hivemind averages
@@ -1452,9 +1458,24 @@ class DiLoCoOptimizer:
                 out_shardings=sh,
             )
         delta = self._leaves_to_device(delta_flat)
+        if "outer/apply_delta" not in self._recipes:
+            shapes, add = obs.programs.abstract((state["params"], delta)), self._apply_delta
+            self._recipes.note("outer/apply_delta", None, lambda: add.lower(*shapes))
         state = dict(state)
         state["params"] = self._apply_delta(state["params"], delta)
         return state
+
+    def program_recipes(self):
+        """How to lower the boundary's device programs that have run, under
+        ``outer/...``: the pseudo-gradient and the apply of a device plane, the
+        delta's add where the state comes back as a delta (``obs.programs``
+        names their operations, so that a trace's reader does not take them for
+        the inner step's). Not the overlapped modes' landings."""
+        return self._recipes
+
+    def program_texts(self) -> dict:
+        """{program name: compiled text} of ``program_recipes``."""
+        return self._recipes.texts()
 
     # ------------------------------------------------------------------
     # outer step (reference: _update_global_epoch, hivemind_diloco.py:570-679)

@@ -541,6 +541,9 @@ class DeviceOuterPlane:
         # what the last pseudo_grad's fetch did (``_fetch_sharded``'s stats);
         # empty when no leaf was sharded and ``device_get`` did it all
         self.last_fetch: dict = {}
+        # how to lower the pseudo-gradient and the apply again at the shapes
+        # they ran at (``DiLoCoOptimizer.program_recipes``)
+        self.recipes = obs.programs.Recipes()
 
     # -- helpers -----------------------------------------------------------
 
@@ -607,6 +610,16 @@ class DeviceOuterPlane:
 
     def _scalars(self):
         return np.float32(self.lr), np.float32(self.momentum)
+
+    def _ran(self, name: str, frag: Optional[list[int]], fn, *args, **static) -> None:
+        """Remember how to lower ``fn`` at ``args``' shapes, under ``name``
+        (a fragment's under its first leaf and count); the first call of a
+        name does the work."""
+        if frag:
+            name = f"{name}/{frag[0]}+{len(frag)}"
+        if name not in self.recipes:
+            shapes = obs.programs.abstract(args)
+            self.recipes.note(name, None, lambda: fn.lower(*shapes, **static))
 
     @property
     def _has_mom(self) -> bool:
@@ -680,15 +693,17 @@ class DeviceOuterPlane:
             m = self._sel(self.masters, frag)
             p = list(param_leaves)
             if self._wire_dtype is not None:
-                pg32, wire, sq = _pg_wire(
-                    m, p, wire_dtype=self._wire_dtype, keep32=keep_device,
-                )
+                static = dict(wire_dtype=self._wire_dtype, keep32=keep_device)
+                self._ran("outer/pseudo_grad", frag, _pg_wire, m, p, **static)
+                pg32, wire, sq = _pg_wire(m, p, **static)
             elif self.error_feedback:
                 self._ensure_ef()
                 r = self._sel(self.ef_res, frag)
+                self._ran("outer/pseudo_grad", frag, _pg_f32_ef, m, p, r)
                 pg32, sq = _pg_f32_ef(m, p, r)
                 wire = pg32
             else:
+                self._ran("outer/pseudo_grad", frag, _pg_f32, m, p)
                 pg32, sq = _pg_f32(m, p)
                 wire = pg32
             assembled = [_is_assembled(x) for x in wire]
@@ -730,18 +745,15 @@ class DeviceOuterPlane:
             m = self._sel(self.masters, frag)
             b = self._sel(self.bufs, frag)
             lr, mom = self._scalars()
+            static = dict(nesterov=self.nesterov, has_mom=self._has_mom)
             if sync is None:
-                new_m, new_b = _apply_fused(
-                    m, b, avg, lr, mom,
-                    nesterov=self.nesterov, has_mom=self._has_mom,
-                )
+                self._ran("outer/apply", frag, _apply_fused, m, b, avg, lr, mom, **static)
+                new_m, new_b = _apply_fused(m, b, avg, lr, mom, **static)
                 new_p = None
             else:
                 p = self._sel(list(sync), frag)
-                new_m, new_b, new_p = _apply_sync_fused(
-                    m, b, avg, p, lr, mom,
-                    nesterov=self.nesterov, has_mom=self._has_mom,
-                )
+                self._ran("outer/apply", frag, _apply_sync_fused, m, b, avg, p, lr, mom, **static)
+                new_m, new_b, new_p = _apply_sync_fused(m, b, avg, p, lr, mom, **static)
             self._put_back("masters", frag, new_m)
             if self._has_mom:
                 self._put_back("bufs", frag, new_b)

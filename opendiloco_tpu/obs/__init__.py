@@ -17,7 +17,9 @@ or, in plain synchronous code::
 See ``obs/trace.py`` for the env knobs, ``obs/export.py`` for the
 Chrome-trace / Prometheus / JSONL exporters, and ``obs/capture.py`` for the
 one switch that starts and stops profiler, tracer and request ring in a
-running process (``obs.capture.start(dir)`` .. ``obs.capture.stop()``).
+running process (``obs.capture.start(dir)`` .. ``obs.capture.stop()``), and
+``obs/programs.py`` for what a trace's device events are: each compiled
+program's instructions by scope, pass and opcode (``obs.programs.tables()``).
 """
 from opendiloco_tpu.obs.trace import (  # noqa: F401
     StageTimes,
@@ -35,6 +37,7 @@ from opendiloco_tpu.obs import (  # noqa: F401
     export,
     mfu,
     overseer,
+    programs,
     reqtrace,
 )
 from opendiloco_tpu.obs import trace as _trace
@@ -43,7 +46,9 @@ from opendiloco_tpu.obs import trace as _trace
 def reset() -> None:
     """Drop every cached obs singleton (tests / env changes): tracer,
     flight recorder, request-trace ring, overseer, and watchdogs. An open
-    capture is abandoned (its profiler session, if any, is stopped)."""
+    capture is abandoned (its profiler session, if any, is stopped). The
+    owners of compiled programs stay registered (``programs.reset`` forgets
+    them): they outlive a tracer."""
     capture.abandon()
     anomaly.reset()
     blackbox.reset()
@@ -64,6 +69,7 @@ __all__ = [
     "gauge",
     "mfu",
     "overseer",
+    "programs",
     "reqtrace",
     "reset",
     "span",

@@ -36,7 +36,7 @@ import threading
 import time
 from typing import Optional
 
-from opendiloco_tpu.obs import reqtrace
+from opendiloco_tpu.obs import programs, reqtrace
 from opendiloco_tpu.obs import trace as _trace
 
 ANCHOR = "odtp/capture"
@@ -134,6 +134,9 @@ def stop() -> Capture:
         # recording ends here: writing the profiler's trace can take seconds,
         # and what the process does meanwhile is not part of the capture
         out = _last = _recorded(o)
+        # what the live owners of compiled programs would lower, for a reader
+        # of this capture's trace that comes after they are gone
+        programs.keep()
         o.ring.cap = o.ring_cap_before
         if o.own:
             _disarm()
@@ -181,3 +184,4 @@ def abandon() -> None:
         except Exception:
             pass
     _last = None
+    programs.forget_kept()

@@ -638,6 +638,68 @@ class ServeEngine:
         # :meth:`page_rows` so the compile family stays bounded.
         self._fetch_pages = jax.jit(fetch_pages, static_argnums=(3,))
 
+        # the prompt buckets a cold admission has run (and "chunk"), and how
+        # to lower the engine's programs again (``program_texts``)
+        self._ran: set = set()
+        self._recipes = obs.programs.Recipes()
+        obs.programs.register(self)
+
+    def program_recipes(self):
+        """How to lower again, at the engine's own shapes, the programs a cold
+        admission and a decode step have run so far: ``decode``,
+        ``prefill/<bucket>`` and ``insert/<bucket>`` for each bucket a prompt
+        has gone through, ``chunk``, and a state's insert (``insert/state``,
+        ``insert/cca``); ``obs.programs`` reads each instruction's scope and
+        opcode from their texts. Not the continued prefill behind a reused
+        prefix, nor the page tier's programs. What is noted holds the jitted
+        functions and shapes, not the engine."""
+        # shapes alone: the engine's arrays lie on one device, and lowered so
+        # a program is the one its jit holds (a compile of its own otherwise)
+        shaped = functools.partial(obs.programs.abstract, placed=False)
+        sds, note = jax.ShapeDtypeStruct, self._recipes.note
+        vec, scalar = sds((self.num_slots,), jnp.int32), sds((), jnp.int32)
+        params, first = shaped(self.params), shaped(self._first)
+        rings = shaped((self.cache_k, self.cache_v))
+        beside = shaped((*self._eva, *self._index))
+        state = shaped((*self._ssm, *self._cca))
+        prefill, decode, insert, chunk = (
+            self._prefill, self._decode, self._admit_insert, self._chunk
+        )
+        state_insert = self._cca_insert if self._cca else self._state_insert
+        drop = int(self._keeps_choices)  # each token's experts come last
+
+        def lower_insert(ids):
+            # what a prompt leaves beside its K and V goes into the rings
+            # beside them in the one insert, or into a state's own
+            tokd, _, ks, vs, *left = jax.eval_shape(prefill, params, ids, scalar)
+            left = left[: len(left) - drop] if beside else []
+            return insert.lower(*rings, first, *beside, ks, vs, *left, tokd, scalar)
+
+        def lower_state_insert(ids):
+            left = jax.eval_shape(prefill, params, ids, scalar)[4:]
+            return state_insert.lower(*state, *left[: len(left) - drop], scalar)
+
+        if self.phase_calls["decode"]:
+            note("decode", id(decode),
+                 lambda: decode.lower(params, vec, vec, *rings, *state, *beside))
+        if "chunk" in self._ran:
+            ids = sds((1, self.cfg.q_chunk_size), jnp.int32)
+            note("chunk", id(chunk), lambda: chunk.lower(
+                params, ids, scalar, scalar, scalar, sds((), jnp.bool_), first, *rings, *beside))
+        for bucket in sorted(b for b in self._ran if b != "chunk"):
+            ids = sds((1, bucket), jnp.int32)
+            note(f"prefill/{bucket}", id(prefill),
+                 lambda ids=ids: prefill.lower(params, ids, scalar))
+            if state:
+                note("insert/cca" if self._cca else "insert/state", id(state_insert),
+                     lambda ids=ids: lower_state_insert(ids))
+            note(f"insert/{bucket}", id(insert), lambda ids=ids: lower_insert(ids))
+        return self._recipes
+
+    def program_texts(self) -> dict:
+        """{program name: compiled text} of ``program_recipes``."""
+        return self.program_recipes().texts()
+
     def keep_expert_choices(self) -> None:
         """From here on a routed model's prefill and decode programs also hand
         back each token's experts in each layer, and the newest call's stay in
@@ -768,6 +830,7 @@ class ServeEngine:
         self._refuse_positions(positions)
         n = len(prompt)
         bucket = self._bucket_of(n)
+        self._ran.add(bucket)
         t0 = time.perf_counter()
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :n] = np.asarray(prompt, np.int32)
@@ -845,6 +908,7 @@ class ServeEngine:
         C, n, plen = self.cfg.q_chunk_size, adm.tokens, adm.rows_done
         count = min(C, n - plen)
         last = plen + count == n
+        self._ran.add("chunk")
         t0 = time.perf_counter()
         ids = np.zeros((1, C), np.int32)
         ids[0, :count] = adm.prompt[plen : plen + count]

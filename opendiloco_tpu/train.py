@@ -85,14 +85,19 @@ def make_trainer_config(config: Config) -> TrainerConfig:
 
 
 def _end_profile(profile_dir: str) -> None:
-    """Close the ``--profile-dir`` capture and lay the program's spans
-    (with the anchor that places them on the profiler's clock) beside the
-    trace it wrote."""
+    """Close the ``--profile-dir`` capture and lay beside the trace it wrote
+    the program's spans (with the anchor that places them on the profiler's
+    clock) and what the trace's device events are: each compiled program's
+    instructions by scope, pass and opcode (``odtp_programs.json``; the
+    programs are lowered again here, which the compile cache answers)."""
     capture = obs.capture.stop()
     path = capture.save(os.path.join(profile_dir, "odtp_capture.json"))
+    named = obs.programs.save(os.path.join(profile_dir, "odtp_programs.json"))
     log.info(
-        "wrote profiler trace to %s (%d program spans in %s)",
-        profile_dir, len(capture.spans), path,
+        "wrote profiler trace to %s (%d program spans in %s; the instructions "
+        "of %s in odtp_programs.json%s)",
+        profile_dir, len(capture.spans), path, ", ".join(named["programs"]) or "no program",
+        f", missing: {named['missing']}" if named["missing"] else "",
     )
 
 

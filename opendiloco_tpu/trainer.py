@@ -289,6 +289,11 @@ class InnerTrainer:
         # and what its attention kernels compute of a head's scores
         # (``attn_scores_plan_of``): the gauges ``train_attn_scores_*_share``
         self.attn_scores_plan = None
+        # how to lower the train step again at the shape it was traced at
+        # last (``program_texts``), and the steps dispatched so far
+        self._recipes = obs.programs.Recipes()
+        self.steps_dispatched = 0
+        obs.programs.register(self)
 
         self.p_specs = param_specs(model_cfg, plan, for_params=True)
         params_shapes = jax.eval_shape(
@@ -355,6 +360,18 @@ class InnerTrainer:
             for k in ("input_ids", "labels")
         }
         return self._train_step.lower(state_sds, batch_sds)
+
+    def program_recipes(self):
+        """How to lower ``train_step`` again at the shape it was traced at
+        last (the one that is running), for ``obs.programs``; empty before a
+        step."""
+        return self._recipes
+
+    def program_texts(self) -> dict:
+        """{"train_step": the compiled text of the train step}, lowered and
+        compiled again through ``lower_abstract``: ``obs.programs`` reads each
+        instruction's scope, pass and opcode from it."""
+        return self._recipes.texts()
 
     # -- state ------------------------------------------------------------
 
@@ -573,6 +590,10 @@ class InnerTrainer:
         params = state["params"]
         accum, microbatch, seq = batch["input_ids"].shape
         # while the step is traced: once a compiled shape
+        self._recipes.note(
+            "train_step", (accum, microbatch, seq),
+            functools.partial(self.lower_abstract, accum * microbatch, seq, accum),
+        )
         self.attn_residual_bytes = self.attn_residual_bytes_of(microbatch, seq)
         obs.gauge("train_attn_residual_bytes", self.attn_residual_bytes)
         self.attn_scores_plan = scores = self.attn_scores_plan_of(seq)
@@ -709,15 +730,23 @@ class InnerTrainer:
 
     def train_step(self, state: dict, batch: dict):
         tr = obs.tracer()
+        self.steps_dispatched += 1
         if tr is None:
             state, metrics = self._train_step(state, batch)
         else:
-            # dispatch wall only: the jit'd step is async, device time
-            # surfaces in the driver's step gap (train.py logs the synced
-            # step time)
+            # the host's seconds in the dispatch alone (the jitted step runs
+            # on asynchronously), on the profiler's clock under a capture, and
+            # the per-step hook: a trace's reader divides the device seconds
+            # of ``train_step``'s operations (``obs.programs``) by the
+            # dispatches here (the benchmark's ``readers/scope_ms.py``; an
+            # operator with ``odtp_programs.json`` and ``odtp_capture.json``)
+            ids = batch["input_ids"]
             t0 = tr.now()
             state, metrics = self._train_step(state, batch)
-            tr.add_span("inner/dispatch", t0, tr.now())
+            tr.add_span(
+                "inner/dispatch", t0, tr.now(),
+                step=self.steps_dispatched, tokens=int(ids.size), accum=int(ids.shape[0]),
+            )
             tr.count("inner_steps")
         for hook in self._post_dispatch_hooks:
             state = hook(state)
