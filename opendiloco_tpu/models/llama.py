@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 from typing import Any, Literal, NamedTuple, Optional, Union
@@ -993,8 +994,9 @@ def _maybe_remat(block, remat: RematPolicy):
     (ops/flash_attention._flash_fwd, both forms of ops/ring_attention):
     they are custom calls and whole rings of ppermutes, so a policy that
     drops them reruns the forward kernel in the backward only to rebuild
-    them. Saved, the backward still recomputes norms, projections, rotary
-    and the FFN, and the kernel runs once. The price per device and layer
+    them. Saved, the backward still recomputes norms, projections and the
+    FFN (rotary too, where it is not inside the kernels: ``RowsAttend``),
+    and the kernel runs once. The price per device and layer
     is B x T x Hq x Dh elements of the compute dtype plus B x Hq x T
     float32 (``attn_residual_bytes``): as much again as the layer's input
     where Hq x Dh is the hidden size, and about twice that in the compiled
@@ -1026,8 +1028,8 @@ def _maybe_remat(block, remat: RematPolicy):
 
 def attn_residual_bytes(cfg: LlamaConfig, batch: int, seq: int, dtype) -> int:
     """Bytes of ``ATTN_RESIDUALS`` over the stack for ``batch`` rows of
-    ``seq`` tokens: a layer with attention keeps its output
-    [B, Hq, T, Dh] in ``dtype`` and its log-sum-exp [B, Hq, T] in float32,
+    ``seq`` tokens: a layer with attention keeps its output, rows
+    [B, T, Hq * Dh] in ``dtype``, and its log-sum-exp [B, Hq, T] in float32,
     a Mamba-2 layer nothing."""
     layers = sum(mixer_of(kind) == "attention" for kind in cfg.layer_kinds)
     per_row = cfg.head_dim * jnp.dtype(dtype).itemsize + 4
@@ -1123,28 +1125,77 @@ def _rotate_heads(cfg: LlamaConfig, x: jax.Array, cos, sin) -> jax.Array:
     return jnp.concatenate((_rope_apply(x[..., :rot], cos, sin), x[..., rot:]), axis=-1)
 
 
-def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
-    """The attention block's projections of x [B, T, D]: q [B, T, Nh, Dh] and
-    k [B, T, Nkv, Dh] rotated by position (as they are where ``cos`` is
-    None), and v. With ``cfg.qk_norm`` q and k pass an RMSNorm over their
-    whole width before they are split into heads (OLMoE). Every attention
-    reader scales the scores by 1/sqrt(Dh); a configuration that states
-    another scale (``attention_multiplier``) has the ratio put on q here."""
-    B, T, _ = x.shape
-    Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+def _qkv_rows(cfg: LlamaConfig, x: jax.Array, layer: dict):
+    """The attention block's projections of x [B, T, D] as the matmuls leave
+    them: q [B, T, Nh * Dh], k and v [B, T, Nkv * Dh], unrotated. With
+    ``cfg.qk_norm`` q and k pass an RMSNorm over their whole width (OLMoE).
+    Every attention reader scales the scores by 1/sqrt(Dh); a configuration
+    that states another scale (``attention_multiplier``) has the ratio put on
+    q here."""
     q = x @ layer["q_proj"]
     k = x @ layer["k_proj"]
-    v = (x @ layer["v_proj"]).reshape(B, T, Nkv, Dh)
+    v = x @ layer["v_proj"]
     if cfg.qk_norm:
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     if cfg.attention_multiplier is not None:
-        q = q * jnp.asarray(cfg.attention_multiplier * Dh**0.5, q.dtype)
-    q, k = q.reshape(B, T, Nh, Dh), k.reshape(B, T, Nkv, Dh)
+        q = q * jnp.asarray(cfg.attention_multiplier * cfg.head_dim**0.5, q.dtype)
+    return q, k, v
+
+
+def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
+    """``_qkv_rows`` split into heads: q [B, T, Nh, Dh] and k [B, T, Nkv, Dh]
+    rotated by position (as they are where ``cos`` is None), and v; q and k
+    under an RMSNorm per head first where the configuration has one
+    (``qk_norm_per_head``)."""
+    B, T, _ = x.shape
+    Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    q, k, v = _qkv_rows(cfg, x, layer)
+    q, k, v = q.reshape(B, T, Nh, Dh), k.reshape(B, T, Nkv, Dh), v.reshape(B, T, Nkv, Dh)
     if cfg.qk_norm_per_head:
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     return _rotate_heads(cfg, q, cos, sin), _rotate_heads(cfg, k, cos, sin), v
+
+
+@dataclasses.dataclass(frozen=True)
+class RowsAttend:
+    """The ``attend`` of an attention that takes the projections' own rows:
+    ``fn(q [B, T, Nh * Dh], k, v [B, T, Nkv * Dh], head_dim=, rope=)`` -> [B,
+    T, Nh * Dh], q and k unrotated and ``rope`` the kernels' tables
+    (``ops.flash_attention.rope_rows``; None: no positions). Handed one,
+    ``decoder_block`` splits nothing into heads between the projections and
+    ``o_proj``."""
+
+    fn: Any
+    head_dim: int
+    rope: Any
+
+    def __call__(self, q, k, v):
+        return self.fn(q, k, v, head_dim=self.head_dim, rope=self.rope)
+
+
+def takes_rows(cfg: LlamaConfig) -> bool:
+    """Whether training's attention can take this configuration's q, k, v as
+    rows: they are ``_qkv``'s (no latent rows, no CCA, no EVA pooling, no
+    indexer beside them) and need nothing a head at a time but the rotation
+    (no ``qk_norm_per_head``)."""
+    return not (cfg.latent or cfg.cca or cfg.eva or cfg.sparse or cfg.qk_norm_per_head)
+
+
+def rows_attend(cfg: LlamaConfig, attn_fn, cos, sin) -> Optional[RowsAttend]:
+    """``RowsAttend`` over an ``attn_fn`` marked ``takes_rows`` (the flash
+    kernels' entries: what ``forward`` builds under ``attn_impl=pallas``) for
+    a configuration that ``takes_rows``; None for an ``attn_fn`` over heads
+    alone."""
+    if not getattr(attn_fn, "takes_rows", False) or not takes_rows(cfg):
+        return None
+    rope = None
+    if cos is not None:
+        from opendiloco_tpu.ops.flash_attention import rope_rows
+
+        rope = rope_rows(cos, sin, cfg.head_dim)
+    return RowsAttend(attn_fn, cfg.head_dim, rope)
 
 
 def _index_qkw(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
@@ -1599,7 +1650,10 @@ def decoder_block(
     passes ``mix``, whatever that computes from the normed input (the
     Mamba-2 mixer of a hybrid stack). Training and the serving forwards
     differ in what they pass: ``attend(q, k, v)`` is the caller's attention
-    over this layer's q [B, T, Nh, Dh] and new k, v, ``mix(x, layer)`` its
+    over this layer's q [B, T, Nh, Dh] and new k, v (a ``RowsAttend``:
+    training's flash kernels, over the projections' own rows [B, T, Nh * Dh],
+    which then are split and rotated nowhere outside the kernels),
+    ``mix(x, layer)`` its
     mixer over x [B, T, D] (a cache or a state either reads or writes is the
     caller's own), ``live`` the tokens a routed FFN counts. Latent attention
     enters the same way: the projection returns q and the tokens' latent
@@ -1651,7 +1705,10 @@ def decoder_block(
     elif mix is None:
         with jax.named_scope("odtp_attention"):
             x = _block_norm(cfg, h, layer["input_norm"])
-            q, k, v = _qkv(cfg, x, layer, cos, sin)
+            if isinstance(attend, RowsAttend):  # rows in, rows out: no head is split off
+                q, k, v = _qkv_rows(cfg, x, layer)
+            else:
+                q, k, v = _qkv(cfg, x, layer, cos, sin)
             # EVA's attend also pools k and v: under the layer's two vectors
             pool = (layer["adaptive_phi"], layer["adaptive_mu_k"]) if cfg.eva else ()
             if cfg.sparse:  # its attend also scores and chooses: by the indexer's three
@@ -1701,6 +1758,8 @@ def training_block(
         attend = rebuilt_attend(cfg, attn_fn)
     elif cfg.eva:  # its own attention over the sequence: ``attn_fn`` is not asked
         attend = eva_attend(cfg)
+    else:  # as rows where the configuration and ``attn_fn`` allow
+        attend = rows_attend(cfg, attn_fn, cos, sin) or attend
 
     def body(carry, layer, li=None):
         h, r = carry
@@ -1827,11 +1886,11 @@ def forward(
             # multi-device mesh: Mosaic kernels cannot be auto-partitioned,
             # so the kernel runs manual over the sharded activation axes
             # (flash_attention_sharded).
-            mesh_ = ring_mesh
-            attn_fn = lambda q, k, v: flash_attention_sharded(
-                q, k, v, mesh=mesh_, batch_axes=batch_axes, tp_axis=tp_axis,
-                causal=True,
+            attn_fn = functools.partial(
+                flash_attention_sharded, mesh=ring_mesh, batch_axes=batch_axes,
+                tp_axis=tp_axis, causal=True,
             )
+            attn_fn.takes_rows = True  # rows [B, T, H * D] under ``head_dim=`` (``rows_attend``)
         elif pp_mesh is not None and any(
             s > 1 for a, s in pp_mesh.shape.items() if a not in (pp_axis, ring_axis)
         ):
@@ -1844,7 +1903,8 @@ def forward(
             # the pallas win is single-stage-measured ~+5-20%).
             attn_fn = lambda q, k, v: xla_attention(q, k, v, causal=True)
         else:
-            attn_fn = lambda q, k, v: flash_attention(q, k, v, causal=True)
+            attn_fn = functools.partial(flash_attention, causal=True)
+            attn_fn.takes_rows = True  # rows [B, T, H * D] under ``head_dim=`` (``rows_attend``)
     elif attn_impl == "ring":
         from opendiloco_tpu.ops.ring_attention import ring_attention_auto
 

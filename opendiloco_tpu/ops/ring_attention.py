@@ -269,11 +269,14 @@ ring_attention.defvjp(_ring_fwd, _ring_bwd)
 
 
 def _ring_flash_forward(q, k, v, axis_name, block):
-    """q [B,Tl,Hq,D], k/v [B,Tl,Hkv,D] -> (out [B,Tl,Hq,D], lse [B,Hq,1,Tl])."""
-    from opendiloco_tpu.ops.flash_attention import _fwd
+    """q [B,Tl,Hq,D], k/v [B,Tl,Hkv,D] -> (out [B,Tl,Hq,D], lse [B,Hq,1,Tl]).
+    The kernels take the chunks as rows [B,Tl,H*D]."""
+    from opendiloco_tpu.ops.flash_attention import _fwd, _rows
 
-    qT, kT, vT = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    b, tl, hq, d = q.shape
+    qR, kR, vR = _rows(q), _rows(k), _rows(v)
     vma = _ring_vma(axis_name, q)
+    fwd = functools.partial(_fwd, d=d, block_q=block, block_k=block, vma=vma)
 
     idx = jax.lax.axis_index(axis_name)
     n = jax.lax.axis_size(axis_name)
@@ -281,8 +284,8 @@ def _ring_flash_forward(q, k, v, axis_name, block):
 
     # step 0: own (diagonal) chunk, standard causal flash -- guarantees a
     # finite lse for every query row before any merge
-    o, lse = _fwd(qT, kT, vT, block_q=block, block_k=block, causal=True, vma=vma)
-    o = o.astype(jnp.float32)
+    o, lse = fwd(qR, kR, vR, causal=True)
+    o = o.astype(jnp.float32).reshape(b, tl, hq, d)
 
     def step(carry, i):
         k_c, v_c, o, lse = carry
@@ -292,10 +295,8 @@ def _ring_flash_forward(q, k, v, axis_name, block):
 
         def live(ops):
             kk, vv = ops
-            oi, lsei = _fwd(
-                qT, kk, vv, block_q=block, block_k=block, causal=False, vma=vma
-            )
-            return oi.astype(jnp.float32), lsei
+            oi, lsei = fwd(qR, kk, vv, causal=False)
+            return oi.astype(jnp.float32).reshape(b, tl, hq, d), lsei
 
         def dead(ops):
             # future chunk: contributes nothing (lse=-inf merges to a no-op)
@@ -303,15 +304,14 @@ def _ring_flash_forward(q, k, v, axis_name, block):
 
         oi, lsei = jax.lax.cond(src < idx, live, dead, (k_c, v_c))
         lse_new = jnp.logaddexp(lse, lsei)
-        # weights are [B,Hq,1,Tl]; swap to [B,Hq,Tl,1] to scale the outputs
-        w = jnp.swapaxes(jnp.exp(lse - lse_new), -1, -2)
-        wi = jnp.swapaxes(jnp.exp(lsei - lse_new), -1, -2)
+        # weights are [B,Hq,1,Tl]; as [B,Tl,Hq,1] they scale the outputs' heads
+        w = jnp.exp(lse - lse_new).transpose(0, 3, 1, 2)
+        wi = jnp.exp(lsei - lse_new).transpose(0, 3, 1, 2)
         o = o * w + oi * wi
         return (k_c, v_c, o, lse_new), None
 
-    (_, _, o, lse), _ = jax.lax.scan(step, (kT, vT, o, lse), jnp.arange(1, n))
-    out = o.transpose(0, 2, 1, 3).astype(q.dtype)
-    return out, lse
+    (_, _, o, lse), _ = jax.lax.scan(step, (kR, vR, o, lse), jnp.arange(1, n))
+    return o.astype(q.dtype), lse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -335,25 +335,22 @@ def _ring_flash_fwd(q, k, v, axis_name, block):
 def _ring_flash_bwd(axis_name, block, res, dout):
     """Flash backward per chunk with the global lse; dK/dV accumulators
     (f32) rotate with their chunks, one extra rotation brings them home."""
-    from opendiloco_tpu.ops.flash_attention import _bwd_impl, _delta
+    from opendiloco_tpu.ops.flash_attention import _bwd_impl, _delta, _rows
 
     q, k, v, out, lse = res
-    qT, kT, vT, oT, doT = (
-        x.transpose(0, 2, 1, 3) for x in (q, k, v, out, dout)
-    )
-    delta = _delta(doT, oT)
+    d = q.shape[-1]
+    qR, kR, vR, oR, doR = (_rows(x) for x in (q, k, v, out, dout))
+    delta = _delta(doR, oR, d)
 
     idx = jax.lax.axis_index(axis_name)
     n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
-    kwargs = dict(
-        block_q=block,
-        block_k=block,
-        grad_dtype=jnp.float32,
+    bwd = functools.partial(
+        _bwd_impl, d=d, block_q=block, block_k=block, grad_dtype=jnp.float32,
         vma=_ring_vma(axis_name, q),
     )
-    dq, dk, dv = _bwd_impl(qT, kT, vT, doT, lse, delta, causal=True, **kwargs)
+    dq, dk, dv = bwd(qR, kR, vR, None, doR, lse, delta, causal=True)
 
     def step(carry, i):
         k_c, v_c, dk, dv, dq = carry
@@ -364,7 +361,7 @@ def _ring_flash_bwd(axis_name, block, res, dout):
 
         def live(ops):
             kk, vv = ops
-            return _bwd_impl(qT, kk, vv, doT, lse, delta, causal=False, **kwargs)
+            return bwd(qR, kk, vv, None, doR, lse, delta, causal=False)
 
         def dead(ops):
             return jnp.zeros_like(dq), jnp.zeros_like(dk), jnp.zeros_like(dv)
@@ -373,15 +370,15 @@ def _ring_flash_bwd(axis_name, block, res, dout):
         return (k_c, v_c, dk + dki, dv + dvi, dq + dqi), None
 
     (_, _, dk, dv, dq), _ = jax.lax.scan(
-        step, (kT, vT, dk, dv, dq), jnp.arange(1, n)
+        step, (kR, vR, dk, dv, dq), jnp.arange(1, n)
     )
     # n-1 in-scan rotations + this one = full revolution: grads are home
     dk = jax.lax.ppermute(dk, axis_name, perm)
     dv = jax.lax.ppermute(dv, axis_name, perm)
-    dq = dq.transpose(0, 2, 1, 3).astype(q.dtype)
-    dk = dk.transpose(0, 2, 1, 3).astype(k.dtype)
-    dv = dv.transpose(0, 2, 1, 3).astype(v.dtype)
-    return dq, dk, dv
+    return (
+        dq.astype(q.dtype).reshape(q.shape), dk.astype(k.dtype).reshape(k.shape),
+        dv.astype(v.dtype).reshape(v.shape),
+    )
 
 
 ring_flash_attention.defvjp(_ring_flash_fwd, _ring_flash_bwd)

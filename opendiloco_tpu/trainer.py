@@ -28,6 +28,7 @@ from opendiloco_tpu.models.llama import (
     causal_lm_loss,
     forward,
     init_params,
+    takes_rows,
     untrained_by_the_lm_loss,
 )
 from opendiloco_tpu.parallel.mesh import MeshPlan
@@ -289,6 +290,9 @@ class InnerTrainer:
         # and what its attention kernels compute of a head's scores
         # (``attn_scores_plan_of``): the gauges ``train_attn_scores_*_share``
         self.attn_scores_plan = None
+        # and what it hands the kernels (``attn_layout_of``): ``rows ...`` or
+        # ``heads ...``; the gauge ``train_attn_rows`` (1 / 0) carries it
+        self.attn_layout = None
         # how to lower the train step again at the shape it was traced at
         # last (``program_texts``), and the steps dispatched so far
         self._recipes = obs.programs.Recipes()
@@ -585,6 +589,25 @@ class InnerTrainer:
 
         return plan_of(seq, self.model_cfg.head_dim)
 
+    def attn_layout_of(self, seq: int) -> Optional[str]:
+        """What the step's attention kernels are handed between the
+        projections (``flash_attention``): ``rows`` ([B, T, H * D] as the
+        matmuls leave them, rotary inside the kernels) or ``heads`` ([B, T, H,
+        D], where the configuration works on q and k a head at a time:
+        ``llama.takes_rows``), with the query and KV heads a grid step holds
+        of those a chip has; None where ``attn_scores_plan_of`` is."""
+        if self.attn_scores_plan_of(seq) is None:
+            return None
+        from opendiloco_tpu.ops.flash_attention import heads_a_step
+
+        cfg, plan = self.model_cfg, self.plan
+        hq, hkv = cfg.num_attention_heads, cfg.kv_heads
+        tp = plan.mesh.shape[plan.tp_axis] if plan.tp_axis else 1
+        if hq % tp == 0 and hkv % tp == 0:  # ``flash_attention_sharded`` cuts the heads
+            hq, hkv = hq // tp, hkv // tp
+        held = "%d,%d of %d,%d" % (*heads_a_step(hq, hkv, cfg.head_dim), hq, hkv)
+        return f"{'rows' if takes_rows(cfg) else 'heads'} heads_a_step={held}"
+
     def _train_step_impl(self, state: dict, batch: dict):
         """batch arrays are [accum, global_microbatch, seq]."""
         params = state["params"]
@@ -600,12 +623,16 @@ class InnerTrainer:
         if scores is not None:
             obs.gauge("train_attn_scores_computed_share", scores.computed_share)
             obs.gauge("train_attn_scores_masked_share", scores.masked_share)
+        self.attn_layout = layout = self.attn_layout_of(seq)
+        if layout is not None:
+            obs.gauge("train_attn_rows", float(layout.startswith("rows")))
         log.info(
             "train step for %d x %d x %d tokens: attn_impl=%s remat=%s "
-            "train_attn_residual_bytes=%d train_attn_scores=%s fused_loss=%s "
-            "scan_unroll=%s",
+            "train_attn_residual_bytes=%d train_attn_scores=%s "
+            "train_attn_layout=%s fused_loss=%s scan_unroll=%s",
             accum, microbatch, seq, self.tc.attn_impl, self.tc.remat,
             self.attn_residual_bytes, scores or "not the flash kernel's",
+            layout or "not the flash kernel's",
             self.tc.fused_loss, self.tc.scan_unroll,
         )
         scale = state["scaler"]["scale"]

@@ -107,16 +107,157 @@ def test_flash_float32_grads_as_the_ring_asks(qkv, interpret_pallas, causal):
     rounding, through the walk (the diagonal chunk) and without it."""
     from opendiloco_tpu.ops import flash_attention as fa
 
-    qT, kT, vT = (x.astype(jnp.bfloat16).transpose(0, 2, 1, 3) for x in qkv)
-    out, lse = fa._fwd(qT, kT, vT, block_q=256, block_k=256, causal=causal)
+    d = qkv[0].shape[-1]
+    qR, kR, vR = (fa._rows(x.astype(jnp.bfloat16)) for x in qkv)
+    kwargs = dict(d=d, block_q=256, block_k=256, causal=causal)
+    out, lse = fa._fwd(qR, kR, vR, **kwargs)
     dout = jnp.ones_like(out)
-    args = (qT, kT, vT, dout, lse, fa._delta(dout, out))
-    kwargs = dict(block_q=256, block_k=256, causal=causal)
+    args = (qR, kR, vR, None, dout, lse, fa._delta(dout, out, d))
     wide = fa._bwd_impl(*args, grad_dtype=jnp.float32, **kwargs)
     narrow = fa._bwd_impl(*args, **kwargs)
     for w, n in zip(wide, narrow):
         assert w.dtype == jnp.float32 and n.dtype == jnp.bfloat16
         np.testing.assert_array_equal(np.asarray(w.astype(jnp.bfloat16)), np.asarray(n))
+
+
+# the kernels' own interface (PR 52): rows [B, T, H * D] as the projections
+# leave them, unrotated, with the rotary tables. (query heads, KV heads, head
+# size, rotated lanes): the two train cells', the 1b's groups of eight, a head
+# of 128, and a head half of whose lanes turn
+ROWS_CASES = {
+    "15-5-64": (15, 5, 64, 64),
+    "32-32-64": (32, 32, 64, 64),
+    "32-4-64": (32, 4, 64, 64),
+    "16-16-128": (16, 16, 128, 128),
+    "4-2-64-half-rotated": (4, 2, 64, 32),
+}
+
+
+def _rows_case(heads, b=2, t=256):
+    """-> (q, k, v rows float32, the model's (cos, sin) tables for positions
+    that differ by batch row, a configuration with the case's heads)."""
+    from opendiloco_tpu.models.llama import LlamaConfig, _rope_tables
+
+    hq, hkv, d, rot = ROWS_CASES[heads]
+    cfg = LlamaConfig(
+        hidden_size=hq * d, num_attention_heads=hq, num_key_value_heads=hkv,
+        head_dim=d, partial_rotary_factor=rot / d,
+    )
+    rng = np.random.default_rng(0)
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(b, t, h * d)), jnp.float32) for h in (hq, hkv, hkv)
+    )
+    positions = jnp.arange(t, dtype=jnp.int32)[None] + 7 * jnp.arange(b, dtype=jnp.int32)[:, None]
+    return q, k, v, _rope_tables(positions, rot, cfg.rope_theta), cfg
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("heads", list(ROWS_CASES))
+def test_flash_rows_match_xla_over_rotated_heads(interpret_pallas, monkeypatch, heads, causal):
+    """Forward and the three gradients of ``flash_attention`` over rows, rotary
+    inside the kernels, against ``xla_attention`` over heads that
+    ``_rotate_heads`` turned: two tiles a side, so every index map of the
+    rows, the statistics and the tables is walked."""
+    from opendiloco_tpu.models.llama import _rotate_heads
+    from opendiloco_tpu.ops import flash_attention as fa
+
+    monkeypatch.setenv("OPENDILOCO_TPU_FLASH_BLOCKS", "128,128")
+    q, k, v, (cos, sin), cfg = _rows_case(heads)
+    d = cfg.head_dim
+    split = lambda x: x.reshape(*x.shape[:2], -1, d)
+
+    def ref(q, k, v):
+        turned = (_rotate_heads(cfg, split(x), cos, sin) for x in (q, k))
+        return xla_attention(*turned, split(v), causal=causal).reshape(q.shape)
+
+    def got(q, k, v):
+        return fa.flash_attention(
+            q, k, v, head_dim=d, rope=fa.rope_rows(cos, sin, d), causal=causal
+        )
+
+    np.testing.assert_allclose(np.asarray(got(q, k, v)), np.asarray(ref(q, k, v)), atol=2e-5)
+    loss = lambda fn, q, k, v: jnp.sum(fn(q, k, v) ** 2)
+    gr = jax.grad(functools.partial(loss, ref), argnums=(0, 1, 2))(q, k, v)
+    gg = jax.grad(functools.partial(loss, got), argnums=(0, 1, 2))(q, k, v)
+    _assert_grads_close(gg, gr, 2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", ["15-5-64", "4-2-64-half-rotated"])
+def test_rotate_rows_is_rotate_heads(heads, dtype):
+    """Rotary's flat form on its own (what a tile meets in VMEM, in XLA):
+    ``rotate_rows`` under ``rope_rows``' tables against the model's
+    ``_rotate_heads``, a head's lanes all turned and half of them; in bf16 to
+    the one rounding the float32 form spares."""
+    from opendiloco_tpu.models.llama import _rotate_heads
+    from opendiloco_tpu.ops import flash_attention as fa
+
+    q, _, _, (cos, sin), cfg = _rows_case(heads, t=32)
+    q, d = q.astype(dtype), cfg.head_dim
+    rope = fa.rope_rows(cos, sin, d)
+    assert rope.rot == cfg.rotary_dim and rope.cos.shape == (2, 32, fa.lanes_of(d)[1])
+    ref = _rotate_heads(cfg, q.reshape(2, 32, -1, d), cos, sin).reshape(q.shape)
+    got = fa.rotate_rows(q, rope, d)
+    assert got.dtype == dtype
+    atol = 1e-6 if dtype == jnp.float32 else 4e-2  # bf16: an ulp of |x| <= 5
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32), atol=atol
+    )
+
+
+@pytest.mark.parametrize("heads", ["15-5-64", "16-16-128"])
+def test_flash_attention_lse_contract(interpret_pallas, heads):
+    """``flash_attention_lse`` (a serving prefill's): heads in, heads out, the
+    log-sum-exp of the scaled scores [B, T, H] beside them; None where the
+    kernel does not tile the rows."""
+    from opendiloco_tpu.ops.flash_attention import flash_attention_lse
+
+    q, k, v, _, cfg = _rows_case(heads, b=1)
+    d = cfg.head_dim
+    q, k, v = (x.reshape(1, 256, -1, d) for x in (q, k, v))
+    out, lse = flash_attention_lse(q, k, v, block_q=128, block_k=128, interpret=True)
+    assert out.shape == q.shape and lse.shape == q.shape[:3] and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(xla_attention(q, k, v, causal=True)), atol=2e-5
+    )
+    rep = q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bqhk", q, jnp.repeat(k, rep, axis=2)) * d**-0.5
+    s = jnp.where(jnp.tril(jnp.ones((256, 256), bool))[None, :, None, :], s, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(s, axis=-1)), atol=2e-5
+    )
+    assert flash_attention_lse(q[:, :100], k[:, :100], v[:, :100], interpret=True) is None
+
+
+def test_flash_rows_fall_back_where_the_kernel_does_not_tile():
+    """T = 48 tiles by nothing: rows and tables go through ``rotate_rows`` and
+    XLA's attention, and come back as rows."""
+    from opendiloco_tpu.models.llama import _rotate_heads
+    from opendiloco_tpu.ops import flash_attention as fa
+
+    q, k, v, (cos, sin), cfg = _rows_case("4-2-64-half-rotated", t=48)
+    d = cfg.head_dim
+    split = lambda x: x.reshape(*x.shape[:2], -1, d)
+    turned = (_rotate_heads(cfg, split(x), cos, sin) for x in (q, k))
+    ref = xla_attention(*turned, split(v), causal=True).reshape(q.shape)
+    got = fa.flash_attention(q, k, v, head_dim=d, rope=fa.rope_rows(cos, sin, d))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "heads,held",
+    [((15, 5, 64), (15, 5)), ((32, 32, 64), (2, 2)), ((32, 4, 64), (16, 2)),
+     ((16, 16, 128), (1, 1)), ((32, 32, 128), (1, 1)), ((4, 2, 64), (4, 2)), ((9, 3, 64), (9, 3))],
+    ids=lambda x: "-".join(map(str, x)),
+)
+def test_heads_a_step_is_read_from_the_shapes(heads, held):
+    """Whole GQA groups that fill 128-lane blocks of the K rows, else the row."""
+    from opendiloco_tpu.ops.flash_attention import heads_a_step
+
+    assert heads_a_step(*heads) == held
+    gq, gkv = held
+    assert heads[0] % gq == 0 and gq // gkv == heads[0] // heads[1]
+    assert gkv == heads[1] or gkv * heads[2] % 128 == 0
 
 
 # NaN from row ``r`` on, ``r`` a multiple of the sub-tile that lies inside a

@@ -117,24 +117,43 @@ def _flash_kernels(text):
 
 @pytest.mark.parametrize("cell", list(FLASH_CELLS))
 def test_flash_kernels_compile_at_the_cells_shapes(chip, cell):
-    """The sub-tile walk at 1,024-row blocks (8 x 8 sub-tiles of 128 a
-    diagonal tile, unrolled) compiles for the chip in all three kernels at
-    the train cells' shapes, and in the forward, with its log-sum-exp, at
-    the serve cell's."""
-    from opendiloco_tpu.ops.flash_attention import flash_attention_lse
+    """The kernels over rows ``[B, T, H * D]`` (the heads a grid step holds cut
+    out of a tile in VMEM, rotary on the way, the sub-tile walk at 1,024-row
+    blocks) compile for the chip, all three at the train cells' shapes, and
+    the forward, with its log-sum-exp, at the serve cell's; no operand or
+    result of a call has fewer than 128 minor lanes but the rotary tables."""
+    from opendiloco_tpu.ops.flash_attention import Rope, flash_attention_lse, lanes_of
 
     b, t, hq, hkv, d = FLASH_CELLS[cell]
-    shapes = (((b, t, hq, d), BF16), ((b, t, hkv, d), BF16), ((b, t, hkv, d), BF16))
     if cell.startswith("serve"):
+        shapes = (((b, t, hq, d), BF16), ((b, t, hkv, d), BF16), ((b, t, hkv, d), BF16))
         text = compiled_text(chip, functools.partial(flash_attention_lse, interpret=False), *shapes)
         assert _flash_kernels(text) == ["odtp_flash_fwd"]
         return
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v).astype(jnp.float32).sum()
+    def loss(q, k, v, cos, sin):
+        out = flash_attention(q, k, v, head_dim=d, rope=Rope(cos, sin, d))
+        return out.astype(jnp.float32).sum()
 
-    text = compiled_text(chip, jax.grad(loss, argnums=(0, 1, 2)), *shapes)
+    shapes = (((b, t, hq * d), BF16), ((b, t, hkv * d), BF16), ((b, t, hkv * d), BF16))
+    tables = (((b, t, lanes_of(d)[1]), jnp.float32),) * 2
+    text = compiled_text(chip, jax.grad(loss, argnums=(0, 1, 2)), *shapes, *tables)
     assert _flash_kernels(text) == ["odtp_flash_dkv", "odtp_flash_dq", "odtp_flash_fwd"]
+    assert _narrow_kernel_arrays(text) == []
+
+
+def _narrow_kernel_arrays(text):
+    """The operands and results of the flash kernels' calls in a compiled
+    text whose minor dimension is under 128 (the rotary tables are a unit of
+    128 lanes wide too: ``flash_attention.lanes_of``)."""
+    narrow = []
+    for line in text.splitlines():
+        if not re.match(r"\s*(?:ROOT )?%\w*odtp_flash_[a-z]+[\w.]* = .*custom-call\(", line):
+            continue
+        for shape in re.findall(r"\b[a-z]+\d+\[[\d,]+\]", line.split(", custom_call_target")[0]):
+            if int(shape[:-1].rsplit("[", 1)[1].split(",")[-1]) < 128:
+                narrow.append(shape)
+    return narrow
 
 
 def test_ring_flash_chunks_compile(topo):
@@ -200,12 +219,27 @@ def test_train_step_runs_each_attention_kernel_once(topo, cell):
     rows = batch // n
     heads = cfg.num_attention_heads
     # what leaves the forward scan for the backward beside the layers' inputs:
-    # the log-sum-exp, and the output as [rows a chip, seq, heads x 64] (the
-    # shape of a layer's input here), not in the kernel's layout, whose 64
-    # lanes of 128 would double its bytes
+    # the log-sum-exp, and the kernel's own output, rows [rows a chip, seq,
+    # heads x 64] (the shape of a layer's input here): nothing head-major,
+    # whose 64 lanes of 128 would double its bytes
     assert f"f32[2,{rows},{heads},1,2048]" in text
+    assert f"bf16[2,{rows},2048,{heads * 64}]" in text
     assert f"bf16[2,{rows},{heads},2048,64]" not in text
     assert trainer.attn_residual_bytes == 2 * rows * heads * 2048 * (64 * 2 + 4)
+    # between the projections and ``o_proj``, in the forward, the remat pass
+    # and the backward: the kernels read and write rows, and no instruction
+    # under ``odtp_attention`` makes an activation (an array over the chip's
+    # rows and the sequence) of fewer than 128 minor lanes
+    assert _narrow_kernel_arrays(text) == []
+    narrow = []
+    for line in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \(?([a-z]+\d+\[([\d,]+)\])", line)
+        if made is None or "odtp_attention" not in line:
+            continue
+        dims = [int(x) for x in made.group(3).split(",")]
+        if len(dims) >= 3 and dims[0] == rows and 2048 in dims and dims[-1] < 128:
+            narrow.append(made.group(1, 2))
+    assert narrow == []
 
 
 def test_fused_xent_fwd_bwd(chip):

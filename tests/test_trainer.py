@@ -375,6 +375,35 @@ def test_attn_scores_plan_of_the_training_cells(cell, attn_impl):
     assert (plan.computed_share, plan.masked_share) == (0.53125, 0.0625)
 
 
+@pytest.mark.parametrize("cell", list(ATTN_RESIDUALS_KEPT))
+@pytest.mark.parametrize("attn_impl", ["pallas", "xla", "ring"])
+def test_attn_layout_of_the_training_cells(cell, attn_impl):
+    """What the flash kernels are handed in the two train cells: rows, the
+    360M's whole (15 and 5 heads of 64 fill no 128-lane block), the 1.7B's two
+    heads a grid step; nothing where the attention is not that kernel's, or
+    where it does not tile the sequence; heads under ``qk_norm_per_head``."""
+    import dataclasses
+    import json
+    import pathlib
+
+    from opendiloco_tpu.models.llama import LlamaConfig
+
+    config, strategy, n, _, _ = ATTN_RESIDUALS_KEPT[cell]
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = LlamaConfig.from_dict(json.loads((root / "benchmark/configs" / f"{config}.json").read_text()))
+    tc = TrainerConfig(precision="bf16-mixed", remat=True, attn_impl=attn_impl)
+    trainer = InnerTrainer(cfg, tc, build_mesh(strategy, devices=jax.devices()[:n]))
+    if attn_impl != "pallas":
+        assert trainer.attn_layout_of(2048) is None
+        return
+    held = {"smollm2-360m": "15,5 of 15,5", "smollm2-1.7b": "2,2 of 32,32"}[config]
+    assert trainer.attn_layout_of(2048) == f"rows heads_a_step={held}"
+    assert trainer.attn_layout_of(100) is None
+    per_head = dataclasses.replace(cfg, qk_norm_per_head=True)
+    trainer = InnerTrainer(per_head, tc, build_mesh(strategy, devices=jax.devices()[:n]))
+    assert trainer.attn_layout_of(2048) == f"heads heads_a_step={held}"
+
+
 def test_building_the_step_sets_the_attention_gauge(tiny_cfg, monkeypatch, caplog):
     """Tracing the train step (which is when it is built) leaves what the
     policy keeps for attention on the trainer, in the gauge and on the log
@@ -403,6 +432,10 @@ def test_building_the_step_sets_the_attention_gauge(tiny_cfg, monkeypatch, caplo
         assert obs.tracer().gauges()[("train_attn_scores_computed_share", ())] == 1.0
         assert obs.tracer().gauges()[("train_attn_scores_masked_share", ())] == 1.0
         assert "train_attn_scores=computed_share=1.00000 masked_share=1.00000 sub_tile=128" in caplog.text
+        # and that they take the projections' rows, its four query heads and two KV heads a grid step
+        assert trainer.attn_layout == "rows heads_a_step=4,2 of 4,2"
+        assert obs.tracer().gauges()[("train_attn_rows", ())] == 1.0
+        assert "train_attn_layout=rows heads_a_step=4,2 of 4,2 fused_loss" in caplog.text
     finally:
         trainer_module.log.removeHandler(caplog.handler)
         obs.reset()
