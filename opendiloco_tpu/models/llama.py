@@ -40,6 +40,9 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     slot_layer_pages,
 )
 from opendiloco_tpu.ops.attention import (
+    ring_window_rows,
+    tiled_latent_attention,
+    window_attention,
     decode_step_attention,
     eva_attention,
     eva_decode_step_attention,
@@ -60,6 +63,10 @@ from opendiloco_tpu.ops.decode_kernels import (
     mla_decode_attention,
     paged_decode_attention,
 )
+
+
+# what ``LlamaConfig.layer_types`` may name (``LlamaConfig.layer_kinds``)
+_LAYER_KINDS = ("attention", "mamba", "dense", "sliding")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -226,6 +233,35 @@ class LlamaConfig:
     q_chunk_size: int = 0
     qk_norm_per_head: bool = False
     mrope_section: Optional[tuple] = None
+    # Two kinds of latent attention in one stack, the block of a published
+    # ``dots3_note`` ``config.json``: ``layer_types`` names each layer
+    # "full_attention" or "sliding_attention" (held here as the kinds "dense" /
+    # "attention" and "sliding", ``layer_kinds``). A full layer is the latent
+    # attention above at the top-level sizes, and with ``index_topk`` > 0 under
+    # the indexer above, whose queries then come from the query's latent
+    # (``q_lora_rank`` -> ``index_n_heads`` x ``index_head_dim``) and whose
+    # queries and key turn over their first ``qk_rope_head_dim`` values alone,
+    # by the layer's own tables. A sliding layer is the same attention at the
+    # ``swa_*`` sizes (its own head count, ranks, head sizes and rope base: a
+    # latent row of ``swa_kv_lora_rank + swa_qk_rope_head_dim`` values), no
+    # indexer, and query t reads rows s with 0 <= t - s < ``sliding_window_size``;
+    # its ring is ``ring_cache.sliding_ring_rows`` long whatever the context
+    # and wraps. ``kind_view`` gives each kind's sizes under the top-level
+    # names. ``attention_gate_type`` / ``swa_attention_gate_type`` "headwise":
+    # each head's output is scaled by sigmoid(x W_g), one value a head, before
+    # ``o_proj``. ``apply_mla_qkv_lora_rescale``: the normed latents are scaled
+    # by (hidden_size / rank)^1/2
+    swa_num_attention_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10_000.0
+    sliding_window_size: int = 0
+    attention_gate_type: str = "none"
+    swa_attention_gate_type: str = "none"
+    apply_mla_qkv_lora_rescale: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -273,15 +309,16 @@ class LlamaConfig:
                     f"{self.index_n_heads}, {self.index_head_dim}, {self.q_chunk_size}"
                 )
             if (
-                self.latent or self.cca or self.eva or self.layer_types is not None
+                self.cca or self.eva or self.hybrid
+                or (self.layer_types is not None and not self.latent)
                 or self.qk_norm or self.num_attention_heads % self.kv_heads
                 or self.position_embedding_type != "rope"
             ):
                 raise ValueError(
                     "learned sparse attention is written for a stack of like rotated "
-                    "attention layers whose query heads divide over the KV heads: no "
-                    "latent attention, no CCA, no EVA, no layer_types, no qk_norm over "
-                    "the whole projection, no 'nope'"
+                    "attention layers whose query heads divide over the KV heads, or for "
+                    "the full layers of a latent stack: no CCA, no EVA, no Mamba-2 "
+                    "layers, no qk_norm over the whole projection, no 'nope'"
                 )
         if self.attention_class not in ("mha", "eva"):
             raise ValueError(
@@ -323,11 +360,34 @@ class LlamaConfig:
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)
             object.__setattr__(self, "layer_types", kinds)
-            if len(kinds) != self.num_hidden_layers or set(kinds) - {"attention", "mamba"}:
+            if len(kinds) != self.num_hidden_layers or set(kinds) - set(_LAYER_KINDS):
                 raise ValueError(
-                    f"layer_types must name {self.num_hidden_layers} layers, each "
-                    f"'attention' or 'mamba'; got {len(kinds)}: {sorted(set(kinds))}"
+                    f"layer_types must name {self.num_hidden_layers} layers, each one of "
+                    f"{_LAYER_KINDS}; got {len(kinds)}: {sorted(set(kinds))}"
                 )
+            if set(kinds) & {"sliding", "dense"} and not (self.latent and "mamba" not in kinds):
+                raise ValueError(
+                    "layer_types 'sliding' and 'dense' are the kinds of a latent stack "
+                    "(kv_lora_rank > 0, no Mamba-2 layers)"
+                )
+        if self.sliding:
+            if not (
+                self.swa_num_attention_heads and self.swa_q_lora_rank and self.swa_kv_lora_rank
+                and self.swa_qk_nope_head_dim and self.swa_v_head_dim
+                and self.swa_qk_rope_head_dim >= 2 and self.swa_qk_rope_head_dim % 2 == 0
+                and self.sliding_window_size > 0
+            ):
+                raise ValueError(
+                    "sliding latent layers need the swa_* sizes (heads, both ranks, the "
+                    "three head sizes, an even rotated part) and a sliding_window_size"
+                )
+        for gate in (self.attention_gate_type, self.swa_attention_gate_type):
+            if gate not in ("none", "headwise"):
+                raise ValueError(f"attention gate {gate!r}: 'none' or 'headwise'")
+        if (self.attention_gate_type != "none" or self.apply_mla_qkv_lora_rescale) and not self.latent:
+            raise ValueError(
+                "the head-wise gate and the latents' rescale are written for latent attention"
+            )
         if self.hybrid:
             if self.mamba_n_groups != 1 or not self.mamba_conv_bias or self.mamba_proj_bias:
                 raise ValueError(
@@ -369,7 +429,7 @@ class LlamaConfig:
                 "group-limited routing is not written: n_group and topk_group "
                 f"must be 1; got {self.n_group}, {self.topk_group}"
             )
-        if self.leading_dense and (
+        if self.first_k_dense_replace and self.num_experts and not self.sliding and (
             self.layer_types is not None or self.leading_dense >= self.num_hidden_layers
         ):
             raise ValueError(
@@ -394,6 +454,8 @@ class LlamaConfig:
     @property
     def leading_dense(self) -> int:
         """Leading layers whose FFN is a dense SwiGLU in a routed model."""
+        if self.layer_types:  # the kinds are named layer by layer
+            return 0
         return self.first_k_dense_replace if self.num_experts else 0
 
     @property
@@ -411,7 +473,22 @@ class LlamaConfig:
     def layers_by_kind(self) -> bool:
         """Are the layers' weights one stack per kind (a dict of stacks)
         and not one stack of like layers?"""
-        return self.hybrid or bool(self.leading_dense)
+        return self.hybrid or bool(self.leading_dense) or self.sliding
+
+    @property
+    def sliding(self) -> bool:
+        """Does any layer hold latent attention of the second geometry, under
+        a window (so a second latent ring, which wraps)?"""
+        return "sliding" in self.layer_kinds
+
+    @property
+    def num_sliding_layers(self) -> int:
+        return self.layer_kinds.count("sliding")
+
+    @property
+    def num_full_layers(self) -> int:
+        """Attention layers whose ring is as long as the context."""
+        return self.num_attention_layers - self.num_sliding_layers
 
     @property
     def hybrid(self) -> bool:
@@ -436,6 +513,11 @@ class LlamaConfig:
         """Values of a cached latent row: the normed latent, then the
         rotated key part every head shares."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def sliding_row_dim(self) -> int:
+        """Values of a sliding layer's cached latent row."""
+        return self.swa_kv_lora_rank + self.swa_qk_rope_head_dim
 
     @property
     def qk_head_dim(self) -> int:
@@ -601,6 +683,43 @@ class LlamaConfig:
             # chip's share gives the held count there, the router's width beside it
             if known.get("num_local_experts") == known.get("num_experts"):
                 known.pop("num_local_experts", None)
+        if raw.get("model_type") == "dots3_note":
+            # the language model of a published dots3-note config: the layers'
+            # kinds from ``layer_types`` and ``first_k_dense_replace`` (a leading
+            # layer is a full layer over a dense SwiGLU). What the block is not
+            # written for is refused by name and never read past.
+            # ``n_routed_experts`` counts the experts; a file cut to one chip's
+            # share gives the held count under ``num_local_experts``. No key for
+            # an aux loss (the selection bias balances the load): 0
+            for key, want in (("attention_bias", False), ("rope_scaling", None),
+                              ("hidden_act", "silu"), ("moe_layer_freq", 1),
+                              ("scoring_func", "sigmoid")):
+                if raw.get(key, want) != want:
+                    raise ValueError(
+                        f"a dots3_note stack is written for {key} {want!r}; got {raw[key]!r}"
+                    )
+            depth = known.get("num_hidden_layers", cls.num_hidden_layers)
+            dense = int(raw.get("first_k_dense_replace", 0))
+            kinds = []
+            for i, name in enumerate(tuple(raw.get("layer_types") or ())[:depth]):
+                if name not in ("full_attention", "sliding_attention") or (
+                    i < dense and name != "full_attention"
+                ):
+                    raise ValueError(
+                        f"a dots3_note stack is written for 'full_attention' and "
+                        f"'sliding_attention' layers, the leading dense ones full; got "
+                        f"{name!r} in layer {i}"
+                    )
+                kinds.append(
+                    "dense" if i < dense else "sliding" if name == "sliding_attention"
+                    else "attention"
+                )
+            known["layer_types"] = tuple(kinds)
+            known.setdefault("num_experts", raw.get("n_routed_experts", 0))
+            if known.get("num_local_experts") == known["num_experts"]:
+                known.pop("num_local_experts", None)
+            known.setdefault("q_chunk_size", raw.get("q_chunk_size", 512))
+            known.setdefault("router_aux_loss_coef", 0.0)
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
@@ -627,6 +746,16 @@ class LlamaConfig:
                 model_type="glm4_moe_lite",
                 n_routed_experts=self.held_experts,
             )
+        if self.sliding:
+            names = {"sliding": "sliding_attention"}
+            d.update(
+                architectures=["Dots3NoteForConditionalGeneration"], model_type="dots3_note",
+                n_routed_experts=self.num_experts,
+                layer_types=[names.get(k, "full_attention") for k in self.layer_kinds],
+                first_k_dense_replace=self.layer_kinds.count("dense"),
+                rope_scaling=None, moe_layer_freq=1, scoring_func="sigmoid",
+            )
+            return d
         if self.cca:
             d.update(
                 architectures=["ZayaForCausalLM"],
@@ -657,6 +786,56 @@ class LlamaConfig:
 
     def num_params(self) -> int:
         return sum(x.size for x in jax.tree.leaves(shapes(self)))
+
+
+@functools.lru_cache(maxsize=None)
+def kind_view(cfg: LlamaConfig, kind: str) -> LlamaConfig:
+    """The configuration as the layers of ``kind`` see it: latent geometry is
+    a function of the kind. For a stack without sliding layers that is ``cfg``
+    itself. For one with them, a "sliding" layer's view holds the ``swa_*``
+    sizes under the top-level names (heads, ranks, head sizes, rope base, gate),
+    keeps ``sliding_window_size`` and has no indexer; a full layer's ("dense",
+    "attention") keeps the top-level sizes and the indexer and has no window. So
+    ``_latent_qkv``, ``latent_keys_values``, ``latent_absorb`` and
+    ``latent_expand`` serve both, and ``view.sparse`` / ``view.sliding_window_size``
+    say what the layer's attention reads. A view names no kinds of its own
+    (``layer_types`` None): it sizes one layer, not a stack."""
+    if not cfg.sliding:
+        return cfg
+    own = dict(layer_types=None, first_k_dense_replace=0)
+    if kind == "sliding":
+        return dataclasses.replace(
+            cfg, **own,
+            num_attention_heads=cfg.swa_num_attention_heads, num_key_value_heads=None,
+            q_lora_rank=cfg.swa_q_lora_rank, kv_lora_rank=cfg.swa_kv_lora_rank,
+            qk_nope_head_dim=cfg.swa_qk_nope_head_dim,
+            qk_rope_head_dim=cfg.swa_qk_rope_head_dim, v_head_dim=cfg.swa_v_head_dim,
+            rope_theta=cfg.swa_rope_theta, attention_gate_type=cfg.swa_attention_gate_type,
+            index_topk=0, index_n_heads=0, index_head_dim=0,
+        )
+    return dataclasses.replace(cfg, **own, sliding_window_size=0)
+
+
+def latent_rescale(cfg: LlamaConfig) -> tuple[float, float]:
+    """(s_q, s_kv): what the normed query and key-value latents are scaled by
+    under ``apply_mla_qkv_lora_rescale``, (hidden_size / rank)^1/2 each; (1, 1)
+    without it."""
+    if not cfg.apply_mla_qkv_lora_rescale:
+        return 1.0, 1.0
+    return (cfg.hidden_size / cfg.q_lora_rank) ** 0.5, (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
+
+
+def _of_the_runs_kind(cfg: LlamaConfig, body, run, positions, rope):
+    """A serving forward's ``body(carry, layer, li, view=, rope=)`` for one
+    run: as it is for a stack without sliding layers (its defaults are the
+    configuration and the shared tables ``rope``); else under the run's kind's
+    view, and for a sliding run under that kind's own rope tables."""
+    if not cfg.sliding:
+        return body
+    view = kind_view(cfg, run.kind)
+    if run.kind == "sliding":
+        rope = _rope(view, positions)
+    return functools.partial(body, view=view, rope=rope)
 
 
 def shapes(cfg: LlamaConfig) -> dict:
@@ -705,17 +884,29 @@ def shapes(cfg: LlamaConfig) -> dict:
             f"{sub}_{part}_{what}": (D,) for sub in ("attn", "ffn")
             for part in ("stream", "branch") for what in ("scale", "bias")
         })
-    if cfg.latent:
-        Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
-        attention = {
+    def latent_leaves(view):  # one kind's latent attention, its indexer and gate
+        Rq, Rkv, H = view.q_lora_rank, view.kv_lora_rank, view.num_attention_heads
+        leaves = {
             "q_a_proj": (D, Rq),
             "q_a_norm": (Rq,),
-            "q_b_proj": (Rq, Nh * cfg.qk_head_dim),
-            "kv_a_proj": (D, cfg.latent_row_dim),
+            "q_b_proj": (Rq, H * view.qk_head_dim),
+            "kv_a_proj": (D, view.latent_row_dim),
             "kv_a_norm": (Rkv,),
-            "kv_b_proj": (Rkv, Nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-            "o_proj": (Nh * cfg.v_head_dim, D),
+            "kv_b_proj": (Rkv, H * (view.qk_nope_head_dim + view.v_head_dim)),
+            "o_proj": (H * view.v_head_dim, D),
         }
+        if view.attention_gate_type == "headwise":
+            leaves["attn_gate"] = (D, H)
+        if view.sparse:  # its queries come from the query's latent
+            Hi, Di = view.index_n_heads, view.index_head_dim
+            leaves.update(
+                index_q=(Rq, Hi * Di), index_k=(D, Di), index_k_norm=(Di,),
+                index_k_norm_bias=(Di,), index_w=(D, Hi),
+            )
+        return leaves
+
+    if cfg.latent:
+        attention = latent_leaves(kind_view(cfg, "attention"))
     else:
         attention = {
             "q_proj": (D, Nh * Dh),
@@ -727,7 +918,7 @@ def shapes(cfg: LlamaConfig) -> dict:
         attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
     if cfg.qk_norm_per_head:  # one weight a head's value, shared by the heads
         attention.update(q_norm=(Dh,), k_norm=(Dh,))
-    if cfg.sparse:  # the indexer: its queries, its one key (LayerNorm with bias), its head weights
+    if cfg.sparse and not cfg.latent:  # the indexer: its queries, its one key (LayerNorm with bias), its head weights
         Hi, Di = cfg.index_n_heads, cfg.index_head_dim
         attention.update(
             index_q=(D, Hi * Di), index_k=(D, Di), index_k_norm=(Di,),
@@ -764,6 +955,14 @@ def shapes(cfg: LlamaConfig) -> dict:
         layers = {"mamba": stack(cfg.num_mamba_layers, norms, mixer, ffn)}
         if cfg.num_attention_layers:
             layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
+    elif cfg.sliding:
+        kinds = cfg.layer_kinds
+        sliding = latent_leaves(kind_view(cfg, "sliding"))
+        layers = {"sliding": stack(kinds.count("sliding"), norms, sliding, ffn)}
+        if "dense" in kinds:
+            layers["dense"] = stack(kinds.count("dense"), norms, attention, dense_ffn)
+        if "attention" in kinds:
+            layers["attention"] = stack(kinds.count("attention"), norms, attention, ffn)
     elif cfg.leading_dense:
         layers = {
             "dense": stack(cfg.leading_dense, norms, attention, dense_ffn),
@@ -794,9 +993,10 @@ class Run(NamedTuple):
 
 
 def mixer_of(kind: str) -> str:
-    """A kind of layer's mixer: "mamba", or "attention" (the "dense" kind
-    differs from "attention" in its FFN alone)."""
-    return "mamba" if kind == "mamba" else "attention"
+    """A kind of layer's mixer, which is what names its past's store: "mamba",
+    "sliding" (latent attention over a ring of its own that wraps) or
+    "attention" (the "dense" kind differs from "attention" in its FFN alone)."""
+    return kind if kind in ("mamba", "sliding") else "attention"
 
 
 def layer_runs(cfg: LlamaConfig) -> list[Run]:
@@ -804,7 +1004,7 @@ def layer_runs(cfg: LlamaConfig) -> list[Run]:
     attention layers, 5 Mamba / 1 attention / 4 Mamba for a period of the
     granite hybrid, 1 dense / 23 attention for a routed stack behind a
     leading dense layer. Each forward scans each run."""
-    runs, seen, past = [], {}, {"attention": 0, "mamba": 0}
+    runs, seen, past = [], {}, {"attention": 0, "mamba": 0, "sliding": 0}
     for kind in cfg.layer_kinds:
         if runs and runs[-1].kind == kind:
             runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
@@ -882,44 +1082,64 @@ def scan_layers(
 def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
     """Fresh init matching HF llama conventions: normal(0, initializer_range)
     for projections/embeddings, ones for norms (init_weights.py parity)."""
-    shp = shapes(cfg)
-    leaves, treedef = jax.tree.flatten_with_path(shp)
+    leaves, treedef = jax.tree.flatten_with_path(shapes(cfg))
     keys = jax.random.split(rng, len(leaves))
-    out = []
-    for key, (path, leaf) in zip(keys, leaves):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if "norm" in name and cfg.norm_add_unit_offset:
-            # the norm scales by 1 + w: w about zero, and away from it, so that
-            # the offset is tested
-            out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * 0.02)
-        elif "norm" in name and cfg.norm_init_std:
-            # away from the values that would leave a norm's weight untested
-            noise = jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.norm_init_std
-            out.append(noise if name.endswith("bias") else 1.0 + noise)
-        elif "norm" in name and name.endswith("bias"):
-            out.append(jnp.zeros(leaf.shape, leaf.dtype))
-        elif "norm" in name or name == "D":
-            out.append(jnp.ones(leaf.shape, leaf.dtype))
-        elif name in ("adaptive_phi", "adaptive_mu_k"):
-            # a unit-scale vector per head, each value within +-1/sqrt(Dh): the
-            # pooling's scores phi . k then spread about as k's own norm does
-            noise = jnp.clip(jax.random.normal(key, leaf.shape, leaf.dtype), -1.0, 1.0)
-            out.append(noise * leaf.shape[-1] ** -0.5)
-        elif name == "router_bias":
-            # a trained router's selection bias is not zero, and zero would
-            # leave the term untested: N(0, 0.1^2), beside sigmoid scores in
-            # (0, 1); beside softmax scores, whose mean is 1/E, a fifth of that
-            sigma = 0.1 if cfg.topk_method == "noaux_tc" else 0.2 / cfg.num_experts
-            out.append(jax.random.normal(key, leaf.shape, leaf.dtype) * sigma)
-        elif name in ("A_log", "dt_bias", "conv_weight", "conv_bias"):
-            out.append(_init_mixer_leaf(name, key, leaf))
-        elif name in _ZAYA_DRAWS or (name == "router" and cfg.router_hidden_size):
-            out.append(_init_zaya_leaf(name, key, leaf))
-        else:
-            out.append(
-                jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.initializer_range
-            )
-    return jax.tree.unflatten(treedef, out)
+    return jax.tree.unflatten(
+        treedef, [_init_leaf(cfg, _leaf_name(path), key, leaf)
+                  for key, (path, leaf) in zip(keys, leaves)]
+    )
+
+
+def init_params_leafwise(rng: jax.Array, cfg: LlamaConfig, dtype) -> dict:
+    """``init_params`` cast to ``dtype``, leaf for leaf the same values, each
+    leaf drawn and cast in a program of its own: the device never holds the
+    float32 tree, only one leaf's draw beside what is already cast (a
+    configuration whose float32 tree does not fit a chip is drawn so)."""
+    leaves, treedef = jax.tree.flatten_with_path(shapes(cfg))
+    keys = jax.random.split(rng, len(leaves))
+    draw = jax.jit(
+        lambda key, name, leaf: _init_leaf(cfg, name, key, leaf).astype(dtype),
+        static_argnums=(1, 2),
+    )
+    return jax.tree.unflatten(
+        treedef, [draw(key, _leaf_name(path), leaf) for key, (path, leaf) in zip(keys, leaves)]
+    )
+
+
+def _leaf_name(path) -> str:
+    return path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+
+
+def _init_leaf(cfg: LlamaConfig, name: str, key: jax.Array, leaf) -> jax.Array:
+    """One leaf's fresh draw, by its name (``init_params``)."""
+    if "norm" in name and cfg.norm_add_unit_offset:
+        # the norm scales by 1 + w: w about zero, and away from it, so that
+        # the offset is tested
+        return jax.random.normal(key, leaf.shape, leaf.dtype) * 0.02
+    if "norm" in name and cfg.norm_init_std:
+        # away from the values that would leave a norm's weight untested
+        noise = jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.norm_init_std
+        return noise if name.endswith("bias") else 1.0 + noise
+    if "norm" in name and name.endswith("bias"):
+        return jnp.zeros(leaf.shape, leaf.dtype)
+    if "norm" in name or name == "D":
+        return jnp.ones(leaf.shape, leaf.dtype)
+    if name in ("adaptive_phi", "adaptive_mu_k"):
+        # a unit-scale vector per head, each value within +-1/sqrt(Dh): the
+        # pooling's scores phi . k then spread about as k's own norm does
+        noise = jnp.clip(jax.random.normal(key, leaf.shape, leaf.dtype), -1.0, 1.0)
+        return noise * leaf.shape[-1] ** -0.5
+    if name == "router_bias":
+        # a trained router's selection bias is not zero, and zero would
+        # leave the term untested: N(0, 0.1^2), beside sigmoid scores in
+        # (0, 1); beside softmax scores, whose mean is 1/E, a fifth of that
+        sigma = 0.1 if cfg.topk_method == "noaux_tc" else 0.2 / cfg.num_experts
+        return jax.random.normal(key, leaf.shape, leaf.dtype) * sigma
+    if name in ("A_log", "dt_bias", "conv_weight", "conv_bias"):
+        return _init_mixer_leaf(name, key, leaf)
+    if name in _ZAYA_DRAWS or (name == "router" and cfg.router_hidden_size):
+        return _init_zaya_leaf(name, key, leaf)
+    return jax.random.normal(key, leaf.shape, leaf.dtype) * cfg.initializer_range
 
 
 def _init_mixer_leaf(name: str, key: jax.Array, leaf) -> jax.Array:
@@ -1106,7 +1326,7 @@ def _index_rope(cfg: LlamaConfig, positions: jax.Array):
     """The indexer's (cos, sin): all ``index_head_dim`` values of an index
     query and key turn by the temporal row of the positions; None without an
     indexer."""
-    if not cfg.sparse:
+    if not cfg.sparse or cfg.latent:  # a latent layer's indexer turns by the layer's own
         return None
     if positions.ndim == 3:
         positions = positions[0]
@@ -1198,20 +1418,31 @@ def rows_attend(cfg: LlamaConfig, attn_fn, cos, sin) -> Optional[RowsAttend]:
     return RowsAttend(attn_fn, cfg.head_dim, rope)
 
 
-def _index_qkw(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
+def _index_qkw(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, c_q=None):
     """The indexer's projections of the layer's normed input x [B, T, D] ->
     (index queries [B, T, Hi, Di], the tokens' index keys [B, T, Di], the
-    queries' head weights [B, T, Hi]): queries and the one key rotated whole by
+    queries' head weights [B, T, Hi]): queries and the one key rotated by
     position, the key under a LayerNorm with bias before it (mean and variance
-    in float32)."""
+    in float32). The tables say how much turns: whole where they span
+    ``index_head_dim`` (``_index_rope``), the first values alone where they
+    are a latent layer's own (``qk_rope_head_dim``). ``c_q`` [B, T, Rq]: the
+    query's latent, from which a latent layer's index queries are projected
+    (None: from x)."""
     B, T, _ = x.shape
     Hi, Di = cfg.index_n_heads, cfg.index_head_dim
-    qi = _rope_apply((x @ layer["index_q"]).reshape(B, T, Hi, Di), cos, sin)
+    rot = 2 * cos.shape[-1]
+
+    def turn(a):  # [B, T, H, Di]
+        if rot == Di:
+            return _rope_apply(a, cos, sin)
+        return jnp.concatenate((_rope_apply(a[..., :rot], cos, sin), a[..., rot:]), axis=-1)
+
+    qi = turn(((x if c_q is None else c_q) @ layer["index_q"]).reshape(B, T, Hi, Di))
     kf = (x @ layer["index_k"]).astype(jnp.float32)
     kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
     kf = kf * jax.lax.rsqrt(jnp.mean(kf * kf, axis=-1, keepdims=True) + cfg.rms_norm_eps)
     kf = kf * layer["index_k_norm"].astype(jnp.float32) + layer["index_k_norm_bias"].astype(jnp.float32)
-    ki = _rope_apply(kf.astype(x.dtype)[:, :, None], cos, sin)[:, :, 0]
+    ki = turn(kf.astype(x.dtype)[:, :, None])[:, :, 0]
     return qi, ki, x @ layer["index_w"]
 
 
@@ -1280,19 +1511,26 @@ def _latent_qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
     RMSNorm, then the one rotated key part all heads share). The row is what
     the cache keeps; ``latent_keys_values`` rebuilds k and v from it and
     ``latent_absorb`` / ``latent_expand`` compute the same attention without
-    them."""
+    them; and the query's latent c_q [B, T, q_lora_rank], which a layer's
+    indexer reads). ``cfg`` is the layer's kind's view (``kind_view``); both
+    normed latents are scaled under ``apply_mla_qkv_lora_rescale``."""
     B, T, _ = x.shape
     Nh, Dn, Dr, R = (
         cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
         cfg.kv_lora_rank,
     )
+    s_q, s_kv = latent_rescale(cfg)
     c_q = _rms_norm(x @ layer["q_a_proj"], layer["q_a_norm"], cfg.rms_norm_eps)
+    if s_q != 1.0:
+        c_q = c_q * jnp.asarray(s_q, c_q.dtype)
     q = (c_q @ layer["q_b_proj"]).reshape(B, T, Nh, Dn + Dr)
     q = jnp.concatenate((q[..., :Dn], _rope_apply(q[..., Dn:], cos, sin)), axis=-1)
     row = x @ layer["kv_a_proj"]  # [B, T, R + Dr]
     c_kv = _rms_norm(row[..., :R], layer["kv_a_norm"], cfg.rms_norm_eps)
+    if s_kv != 1.0:
+        c_kv = c_kv * jnp.asarray(s_kv, c_kv.dtype)
     k_r = _rope_apply(row[..., None, R:], cos, sin)[:, :, 0]  # one head
-    return q, jnp.concatenate((c_kv, k_r), axis=-1)
+    return q, jnp.concatenate((c_kv, k_r), axis=-1), c_q
 
 
 def _kv_b_heads(cfg: LlamaConfig, w_kvb: jax.Array) -> jax.Array:
@@ -1317,6 +1555,34 @@ def rebuilt_attend(cfg: LlamaConfig, attn_fn):
     """The ``attend(q, rows, kv_b_proj)`` of the rebuilt form: ``attn_fn(q, k,
     v)`` over the keys and values that the rows give."""
     return lambda q, rows, w_kvb: attn_fn(q, *latent_keys_values(cfg, rows, w_kvb))
+
+
+def latent_attend(cfg: LlamaConfig, attn_fn):
+    """The ``attend`` of one kind of latent layer (``cfg`` its ``kind_view``)
+    in the rebuilt form over a whole sequence from position 0 (training,
+    evaluation, a whole-prompt prefill): ``attn_fn(q, k, v)`` over the keys and
+    values that the rows give; under an indexer ``attend(q, rows, kv_b_proj,
+    qi, ki, wi)`` scores and chooses first (``odtp_dsa_index``, under
+    ``stop_gradient``) and attends over the chosen rows (``odtp_dsa_attn``);
+    under a window each query over the rows of its last ``sliding_window_size``
+    positions."""
+    if cfg.sparse:
+        def attend(q, rows, w_kvb, qi, ki, wi):
+            with jax.named_scope("odtp_dsa_index"):
+                chosen = jax.lax.stop_gradient(causal_selection(qi, wi, ki, cfg.index_topk))
+            with jax.named_scope("odtp_dsa_attn"):
+                return sparse_attention(q, *latent_keys_values(cfg, rows, w_kvb), chosen)
+
+        return attend
+    if cfg.sliding_window_size:
+        def under_window(q, rows, w_kvb):
+            with jax.named_scope("odtp_swa"):
+                return window_attention(
+                    q, *latent_keys_values(cfg, rows, w_kvb), cfg.sliding_window_size
+                )
+
+        return under_window
+    return rebuilt_attend(cfg, attn_fn)
 
 
 def latent_absorb(cfg: LlamaConfig, q: jax.Array, w_kvb: jax.Array) -> jax.Array:
@@ -1691,11 +1957,21 @@ def decoder_block(
     # the scopes name the device work in a profiler trace (an operation's
     # op_name metadata); they change nothing that is computed
     if mix is None and cfg.latent:
+        # ``cfg`` is the layer's kind's view (``kind_view``)
         with jax.named_scope("odtp_mla"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
-            q, k = _latent_qkv(cfg, x, layer, cos, sin)
+            q, k, c_q = _latent_qkv(cfg, x, layer, cos, sin)
             v = None
-            attn_out = attend(q, k, layer["kv_b_proj"]).reshape(B, T, -1) @ layer["o_proj"]
+            pool = ()
+            if cfg.sparse:  # its attend also scores and chooses: by the indexer's three
+                pool = _index_qkw(cfg, x, layer, cos, sin, c_q)
+                index_k = pool[1]
+            o = attend(q, k, layer["kv_b_proj"], *pool)
+            if "attn_gate" in layer:  # one value a head, from the layer's normed input
+                with jax.named_scope("odtp_attn_gate"):
+                    gate = jax.nn.sigmoid(x @ layer["attn_gate"])  # [B, T, Nh]
+                    o = o.reshape(B, T, cfg.num_attention_heads, -1) * gate[..., None].astype(o.dtype)
+            attn_out = o.reshape(B, T, -1) @ layer["o_proj"]
     elif mix is None and cfg.cca:
         with jax.named_scope("odtp_cca"):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
@@ -1747,19 +2023,21 @@ def training_block(
     but for a router that reads the layer before). The norm is the activation probe the reference
     attaches via forward hooks on ``self_attn`` (utils.py:43-67,
     train_fsdp.py:65)."""
-    cos, sin = _rope(cfg, positions)
-    index_rope = _index_rope(cfg, positions)
+    view = kind_view(cfg, kind)  # the kind's own latent geometry and rope base
+    cos, sin = _rope(view, positions)
+    index_rope = _index_rope(view, positions)
     mix, attend = None, attn_fn
-    if cfg.sparse:  # its own attention over the sequence: ``attn_fn`` is not asked
-        attend = sparse_attend(cfg)
     if kind == "mamba":
         mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer)[0]
-    elif cfg.latent:  # the rebuilt form: multi-head attention over k and v
-        attend = rebuilt_attend(cfg, attn_fn)
+    elif view.latent:  # the rebuilt form: multi-head attention over k and v
+        attend = latent_attend(view, attn_fn)
+    elif cfg.sparse:  # its own attention over the sequence: ``attn_fn`` is not asked
+        attend = sparse_attend(cfg)
     elif cfg.eva:  # its own attention over the sequence: ``attn_fn`` is not asked
         attend = eva_attend(cfg)
     else:  # as rows where the configuration and ``attn_fn`` allow
         attend = rows_attend(cfg, attn_fn, cos, sin) or attend
+    cfg = view
 
     def body(carry, layer, li=None):
         h, r = carry
@@ -2073,24 +2351,26 @@ def prefill_forward(
     B, P = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
     cparams = _serving_boundary(params, compute_dtype)
-    cos, sin = _rope(cfg, positions)
+    rope = _rope(kind_view(cfg, "attention"), positions)
     index_rope = _index_rope(cfg, positions)
     live = positions < length
-    attend = lambda q, k, v: xla_attention(q, k, v, causal=True)
-    if cfg.latent:  # k and v rebuilt for the prompt; the rows are what is kept
-        attend = rebuilt_attend(cfg, attend)
-    if cfg.sparse:
-        attend = sparse_attend(cfg)
+    causal = lambda q, k, v: xla_attention(q, k, v, causal=True)
 
-    def attention_body(carry, layer, li):
+    def attention_body(carry, layer, li, view=cfg, rope=rope):  # the run's kind's
         h, r = carry
+        cos, sin = rope
+        attend = causal
+        if view.latent:  # k and v rebuilt for the prompt; the rows are what is kept
+            attend = latent_attend(view, causal)
+        elif cfg.sparse:
+            attend = sparse_attend(cfg)
         pooling: list = []  # EVA: the chunks pooled, and each chunk's pooling as stats
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
+            view, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
             attend=eva_attend(cfg, length, pooling, prefill=True) if cfg.eva else attend,
         )
         kept = [out.k[0], None if out.v is None else out.v[0]]
-        if cfg.sparse:
+        if view.sparse:
             kept.append(out.index_k[0])
         if cfg.cca:  # what the prompt's last token leaves the first decode step
             with jax.named_scope("odtp_cca"):
@@ -2115,15 +2395,17 @@ def prefill_forward(
             left.extend((state[0], tail[0]))
             return out
 
-        h, out = decoder_block(cfg, h, layer, cos, sin, mix=mix, live=live, router_in=r)
+        h, out = decoder_block(cfg, h, layer, *rope, mix=mix, live=live, router_in=r)
         return (h, out.router), (*left, (out.counts, out.experts))
 
     h = _embed(cfg, cparams, input_ids)
     r = router_carry(cfg, h)
-    kept = {"attention": ([], [], [], [], []), "mamba": ([], [])}
+    kept = {"attention": ([], [], [], [], []), "mamba": ([], []), "sliding": ([], [])}
     counts, experts = [], []
     for run in layer_runs(cfg):
-        body = attention_body if run.mixer == "attention" else mamba_body
+        body = mamba_body if run.mixer == "mamba" else _of_the_runs_kind(
+            cfg, attention_body, run, positions, rope
+        )
         (h, r), (*left, (c, e)) = scan_layers(
             cfg, body, (h, r), cparams["layers"], run, experts_in_place=True
         )
@@ -2134,6 +2416,8 @@ def prefill_forward(
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = _logits(cfg, cparams, h_last)
     out = [logits[:, 0], *map(_stacked, kept["attention"][:2])]
+    if cfg.sliding:  # the sliding layers' rows, in the values' place
+        out[2] = _stacked(kept["sliding"][0])
     if cfg.cca or cfg.sparse:
         out.append(_stacked(kept["attention"][2]))
     if cfg.eva:
@@ -2242,7 +2526,7 @@ def decode_forward(
     S, K] int32."""
     cparams = _serving_boundary(params, compute_dtype)
     positions = lens[:, None].astype(jnp.int32)  # [S, 1]
-    cos, sin = _rope(cfg, positions)
+    rope = _rope(kind_view(cfg, "attention"), positions)
     index_rope = _index_rope(cfg, positions)
     live = lens > 0
     pallas = decode_kernel == "pallas"
@@ -2250,10 +2534,14 @@ def decode_forward(
     latent_attention = mla_decode_attention if pallas else latent_decode_step_attention
     eva_attention_step = eva_decode_attention if pallas else eva_decode_step_attention
     eva_state = None if eva_state is None else tuple(eva_state)
+    # a latent stack whose prompts arrive in chunks: a slot at ``lens`` 0 may be
+    # one of those, and is written nothing
+    chunked = {"live_only": True} if cfg.latent and cfg.q_chunk_size else {}
 
-    def attention_body(carry, layer, li):
+    def attention_body(carry, layer, li, view=cfg, rope=rope):  # the run's kind's
         # the whole caches, every layer's tails, EVA's pooled ring and stats
         h, r, ck, cv, tails, eva = carry
+        cos, sin = rope
         rows_chosen: list = []
         index_keys: list = []
 
@@ -2297,16 +2585,35 @@ def decode_forward(
             )
             return out
 
-        def absorbed(q, rows, w_kvb):  # no key or value is rebuilt
-            nonlocal ck
-            o_lat, ck = latent_attention(
-                latent_absorb(cfg, q[:, 0], w_kvb), rows[:, 0], ck, lens, li,
-                scale=cfg.qk_head_dim**-0.5, value_dim=cfg.kv_lora_rank,
-            )
-            return latent_expand(cfg, o_lat, w_kvb)
+        def absorbed(q, rows, w_kvb, qi=None, ki=None, wi=None):  # no key or value is rebuilt
+            nonlocal ck, cv
+            q_lat = latent_absorb(view, q[:, 0], w_kvb)
+            sizes = dict(scale=view.qk_head_dim**-0.5, value_dim=view.kv_lora_rank, **chunked)
+            if view.sliding_window_size:  # the sliding layers' ring, which wraps
+                with jax.named_scope("odtp_swa"):
+                    o_lat, cv = latent_attention(
+                        q_lat, rows[:, 0], cv, lens, li, window=view.sliding_window_size, **sizes
+                    )
+            elif view.sparse:
+                # as ``over_chosen_rows``: the index ring as the step found it
+                # and the step's own key, then the latent ring under the selection
+                index_keys.append(ki[:, 0])
+                with jax.named_scope("odtp_dsa_index"):
+                    chosen = decode_selection(
+                        qi[:, 0], wi[:, 0], ki[:, 0], index_cache[li], lens, view.index_topk
+                    )
+                if return_row_choices:
+                    rows_chosen.append(chosen)
+                with jax.named_scope("odtp_dsa_attn"):
+                    o_lat, ck = latent_attention(
+                        q_lat, rows[:, 0], ck, lens, li, chosen=chosen, **sizes
+                    )
+            else:
+                o_lat, ck = latent_attention(q_lat, rows[:, 0], ck, lens, li, **sizes)
+            return latent_expand(view, o_lat, w_kvb)
 
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
+            view, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
             attend=absorbed if cfg.latent else over_two_rings if cfg.eva
             else over_chosen_rows if cfg.sparse else attend,
             past=None if tails is None else tails[li],
@@ -2332,18 +2639,21 @@ def decode_forward(
             tails = jax.lax.dynamic_update_index_in_dim(tails, tail, li, 0)
             return out[:, None]
 
-        h, out = decoder_block(cfg, h, layer, cos, sin, mix=mix, live=live, router_in=r)
+        h, out = decoder_block(cfg, h, layer, *rope, mix=mix, live=live, router_in=r)
         return (h, out.router, states, tails), (out.counts, out.experts, None, None)
 
     h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
     r = router_carry(cfg, h)
-    counts, experts, rows = [], [], None
+    counts, experts, rows, keys = [], [], [], []
     for run in layer_runs(cfg):
-        if run.mixer == "attention":
-            (h, r, cache_k, cache_v, cca_state, eva_state), (c, e, rows, keys) = scan_layers(
-                cfg, attention_body, (h, r, cache_k, cache_v, cca_state, eva_state),
+        if run.mixer != "mamba":
+            (h, r, cache_k, cache_v, cca_state, eva_state), (c, e, chose, wrote) = scan_layers(
+                cfg, _of_the_runs_kind(cfg, attention_body, run, positions, rope),
+                (h, r, cache_k, cache_v, cca_state, eva_state),
                 cparams["layers"], run, experts_in_place=True,
             )
+            rows.append(chose)
+            keys.append(wrote)
         else:
             (h, r, ssm_state, conv_state), (c, e, _, _) = scan_layers(
                 cfg, mamba_body, (h, r, ssm_state, conv_state), cparams["layers"], run,
@@ -2360,15 +2670,15 @@ def decode_forward(
     if cfg.sparse:  # the step's index keys, every layer's, behind the layers
         with jax.named_scope("odtp_dsa_index"):
             write = index_ring_write if pallas else index_write_rows
-            out.append(write(index_cache, keys, lens))
+            out.append(write(index_cache, _stacked([k for k in keys if k is not None]), lens))
     if cfg.hybrid:
         out.extend((ssm_state, conv_state))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_expert_choices:
         out.append(_chosen(cfg, experts))
-    if return_row_choices:
-        out.append(rows)
+    if return_row_choices:  # the layers with an indexer, in order
+        out.append(_stacked([x for x in rows if x is not None]))
     return tuple(out)
 
 
@@ -2423,15 +2733,21 @@ def chunk_prefill_forward(
     With ``return_moe_counts`` the routed FFN's counts over the real tokens
     come after, and with ``return_row_choices`` then the rows the last real
     token read in each layer [L, T] bool."""
-    for refuse in (refuse_recurrent, refuse_latent, refuse_eva):
+    for refuse in (refuse_recurrent, refuse_eva):
         refuse(cfg, "the continued prefill (a prompt's chunks, the suffix behind a reused prefix)")
+    if cfg.latent and not cfg.q_chunk_size:
+        raise ValueError(
+            "the continued prefill (a prompt's chunks, the suffix behind a reused prefix) is "
+            "refused for a configuration with latent attention that states no q_chunk_size: "
+            "over latent rows it goes in whole chunks from row 0 (a prompt admitted in chunks)"
+        )
     if cfg.sparse != (index_cache is not None):
         raise ValueError("the index ring goes with learned sparse attention, and only with it")
     B, C = ids.shape
     plen, count = jnp.asarray(plen, jnp.int32), jnp.asarray(count, jnp.int32)
     positions = plen + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (B, C))
     cparams = _serving_boundary(params, compute_dtype)
-    cos, sin = _rope(cfg, positions)
+    cos, sin = _rope(kind_view(cfg, "attention"), positions)
     index_rope = _index_rope(cfg, positions) if cfg.sparse else None
     live = jnp.arange(C)[None] < count
     T = ring_rows(cache_k)
@@ -2473,21 +2789,77 @@ def chunk_prefill_forward(
             out.counts, last_row[0] if last_row else None, own_keys[0] if own_keys else None
         )
 
+    def latent_body(carry, layer, li, view, rope):
+        # one kind of latent layer over its own ring, in the absorbed form: the
+        # chunk's rows go in, then its queries over the slot's page a tile at a
+        # time, under the selection (a full layer) or the window's rows of a
+        # ring that wraps (a sliding one); no key or value is rebuilt
+        h, ck, cv = carry
+        last_row: list = []
+        own_keys: list = []
+
+        def attend(q, rows, w_kvb, qi=None, ki=None, wi=None):
+            nonlocal ck, cv
+            q_lat = latent_absorb(view, q[0], w_kvb)  # [C, Nh, Dl]
+            sizes = dict(scale=view.qk_head_dim**-0.5, value_dim=view.kv_lora_rank)
+            if view.sliding_window_size:
+                Tw = ring_rows(cv)
+                cv, _ = layer_rows_insert(
+                    cv, None, li, slot, rows[0][:, None], None, jnp.mod(plen, Tw), count
+                )
+                with jax.named_scope("odtp_swa"):
+                    o_lat = tiled_latent_attention(
+                        q_lat, slot_layer_pages(cv, li, slot)[0],
+                        ring_window_rows(positions[0], Tw, view.sliding_window_size),
+                        Tw, tile if Tw % tile == 0 else Tw, **sizes,
+                    )
+                return latent_expand(view, o_lat, w_kvb)[None]
+            ck, _ = layer_rows_insert(ck, None, li, slot, rows[0][:, None], None, plen, count)
+            reads = seen
+            if view.sparse:
+                own_keys.append(ki[0])
+                with jax.named_scope("odtp_dsa_index"):
+                    reads = chunk_selection(
+                        qi[0], wi[0], ki[0], slot_layer_pages(ci, li, slot), plen, view.index_topk
+                    )
+                if return_row_choices:
+                    last_row.append(jax.lax.dynamic_index_in_dim(reads, count - 1, 0, False))
+            with dsa("odtp_dsa_attn"):
+                o_lat = tiled_latent_attention(
+                    q_lat, slot_layer_pages(ck, li, slot)[0], reads, plen + count, tile, **sizes
+                )
+            return latent_expand(view, o_lat, w_kvb)[None]
+
+        h, out = decoder_block(view, h, layer, *rope, live=live, attend=attend)
+        return (h, ck, cv), (
+            out.counts, last_row[0] if last_row else None, own_keys[0] if own_keys else None
+        )
+
     ci = index_cache  # read by every layer as the run found it
     h = _embed(cfg, cparams, ids)
-    (run,) = layer_runs(cfg)
-    (h, cache_k, cache_v), (counts, rows, keys) = scan_layers(
-        cfg, body, (h, cache_k, cache_v), cparams["layers"], run, experts_in_place=True,
-    )
+    counts, rows, keys = [], [], []
+    for run in layer_runs(cfg):
+        of_kind = body
+        if cfg.latent:  # each run under its kind's view and rope tables
+            view = kind_view(cfg, run.kind)
+            rope = _rope(view, positions) if run.kind == "sliding" else (cos, sin)
+            of_kind = functools.partial(latent_body, view=view, rope=rope)
+        (h, cache_k, cache_v), (c, chose, wrote) = scan_layers(
+            cfg, of_kind, (h, cache_k, cache_v), cparams["layers"], run, experts_in_place=True,
+        )
+        counts.append(c)
+        rows.append(chose)
+        keys.append(wrote)
     if cfg.sparse:
         with jax.named_scope("odtp_dsa_index"):
-            index_cache = index_chunk_insert(index_cache, slot, keys, plen, count)
+            wrote = _stacked([k for k in keys if k is not None])
+            index_cache = index_chunk_insert(index_cache, slot, wrote, plen, count)
     h_last = jax.lax.dynamic_slice_in_dim(h, count - 1, 1, axis=1)
     out = [_logits(cfg, cparams, h_last)[:, 0], cache_k, cache_v, index_cache]
     if return_moe_counts:
-        out.append(jnp.sum(counts, axis=0))
-    if return_row_choices:
-        out.append(rows)
+        out.append(jnp.sum(_stacked(counts), axis=0))
+    if return_row_choices:  # the layers with an indexer, in order
+        out.append(_stacked([x for x in rows if x is not None]))
     return tuple(out)
 
 

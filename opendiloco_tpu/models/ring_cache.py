@@ -110,7 +110,15 @@ def init_kv_cache(
 ) -> dict:
     """Zeroed {"k","v"} pages for ``cfg`` (its attention layers, KV heads
     and head size): ``num_slots`` rings of ``max_context`` rows a layer. For
-    latent attention ``k`` is the one latent ring and ``v`` None."""
+    latent attention ``k`` is the one latent ring and ``v`` None; for a latent
+    stack with sliding layers ``k`` is the full layers' ring and ``v`` the
+    sliding layers' (:func:`sliding_ring_rows` rows of their own row width)."""
+    if cfg.latent and cfg.sliding:  # rings by kind: the sliding one in ``v``'s place
+        full = cache_shape(cfg.num_full_layers, num_slots, max_context, 1, cfg.latent_row_dim)
+        sliding = cache_shape(
+            cfg.num_sliding_layers, num_slots, sliding_ring_rows(cfg), 1, cfg.sliding_row_dim
+        )
+        return {"k": jnp.zeros(full, dtype), "v": jnp.zeros(sliding, dtype)}
     if cfg.latent:
         shape = cache_shape(
             cfg.num_attention_layers, num_slots, max_context, 1, cfg.latent_row_dim
@@ -122,6 +130,16 @@ def init_kv_cache(
         cfg.num_attention_layers, num_slots, max_context, cfg.kv_heads, cfg.head_dim
     )
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def sliding_ring_rows(cfg) -> int:
+    """Rows of a sliding layer's ring, whatever the context: a prefill chunk
+    of ``q_chunk_size`` rows beside the ``sliding_window_size - 1`` rows before
+    it that its first query reads, in whole chunks, so that a chunk written as
+    one aligned block overwrites only rows that no window reaches any more
+    (1,024 for a window of 513 under chunks of 512)."""
+    chunk = cfg.q_chunk_size
+    return chunk * (1 + -(-(cfg.sliding_window_size - 1) // chunk))
 
 
 def init_ssm_state(cfg, num_slots: int, dtype: jnp.dtype = jnp.bfloat16) -> dict:
@@ -240,8 +258,8 @@ def init_index_cache(
 ) -> jax.Array:
     """Zeroed index-key ring for learned sparse attention, ``[L, S, Di, T]``:
     ``num_slots`` rings of ``max_context`` rows a layer, rows minor-most."""
-    return jnp.zeros(
-        (cfg.num_hidden_layers, num_slots, cfg.index_head_dim, int(max_context)), dtype
+    return jnp.zeros(  # beside the layers whose ring is the context's: the full ones
+        (cfg.num_full_layers, num_slots, cfg.index_head_dim, int(max_context)), dtype
     )
 
 
@@ -298,7 +316,10 @@ def layer_rows_insert(cache_k, cache_v, layer, slot, k, v, start, count, whole_c
     the ring is whole chunks and a prompt goes in from row 0; without it (a
     suffix behind a prefix of any length, padded to a bucket) only ``start +
     count`` does, and the block is the ring's last C rows where the padding
-    would pass its end, the chunk's rows moved down within it."""
+    would pass its end, the chunk's rows moved down within it. A latent ring
+    takes its rows as k [C, 1, Dl] with None for ``cache_v`` and ``v``; a ring
+    that wraps takes the chunk at ``start`` modulo its rows (whole chunks: the
+    block does not pass its end)."""
     C = k.shape[0]
     zero = jnp.int32(0)
     start = jnp.asarray(start, jnp.int32)
@@ -309,6 +330,8 @@ def layer_rows_insert(cache_k, cache_v, layer, slot, k, v, start, count, whole_c
     where = (jnp.asarray(layer, jnp.int32), jnp.asarray(slot, jnp.int32), zero, zero, start)
 
     def put(cache, x):
+        if cache is None:  # a latent ring is the one array
+            return None
         old = jax.lax.dynamic_slice(cache, where, (1, 1, *cache.shape[2:4], C))
         if not whole_chunks:
             x = jnp.roll(x, down, axis=0)
@@ -332,7 +355,12 @@ def write_live_row(cache_k, cache_v, layer, k, v, lens):
     prefilling slot rides no step, and its row 0 is its prompt's)."""
     rows = jnp.arange(cache_k.shape[1])
     idx = jnp.where(lens > 0, jnp.mod(lens, ring_rows(cache_k)), ring_rows(cache_k))
-    put = lambda cache, x: cache.at[layer, rows, :, :, idx].set(x.astype(cache.dtype), mode="drop")
+
+    def put(cache, x):
+        if cache is None:  # a latent ring is the one array
+            return None
+        return cache.at[layer, rows, :, :, idx].set(x.astype(cache.dtype), mode="drop")
+
     return put(cache_k, k), put(cache_v, v)
 
 

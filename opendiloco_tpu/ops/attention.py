@@ -143,6 +143,9 @@ def latent_decode_step_attention(
     *,
     scale: float,
     value_dim: int,
+    chosen: jax.Array | None = None,
+    window: int = 0,
+    live_only: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """One layer's share of a decode step of latent attention, absorbed
     form, in XLA: each slot's new latent row [S, Dl] written at ring row
@@ -151,19 +154,95 @@ def latent_decode_step_attention(
     (``llama.latent_absorb``) against the slot's live rows -- the scores over
     all Dl values of a row, times ``scale``, the weighted sum over its first
     ``value_dim`` (the normed latent; the rest is the shared rotated key)
-    -> (o_lat [S, H, value_dim], cache). Masks as :func:`decode_attention`.
-    The reference of ``decode_kernels.mla_decode_attention``, which has this
-    signature, and its per-call fallback."""
-    cache, _ = write_row(cache, None, layer, row[:, None], None, lens)
+    -> (o_lat [S, H, value_dim], cache). Masks as :func:`decode_attention`;
+    under ``chosen`` [S, T] bool (an indexer's selection) only those of the
+    live rows enter the softmax, and under ``window`` the ring wraps and a slot
+    reads the rows of its last ``window`` positions (:func:`ring_window_rows`);
+    with ``live_only`` a slot at ``lens`` 0 is written nothing
+    (``ring_cache.write_live_row``: it may be a slot whose prompt is arriving
+    in chunks). The reference of
+    ``decode_kernels.mla_decode_attention``, which has this signature, and its
+    per-call fallback."""
+    write = write_live_row if live_only else write_row
+    cache, _ = write(cache, None, layer, row[:, None], None, lens)
     pages = cache[layer][:, 0]  # [S, Dl, T]
     s, _, t = pages.shape
     scores = jnp.einsum("shd,sdt->sht", q, pages, preferred_element_type=jnp.float32)
     scores = scores * scale
     idx = jax.lax.broadcasted_iota(jnp.int32, (s, t), 1)
-    valid = (idx <= lens[:, None]) | (lens[:, None] >= t)
+    if window:
+        valid = ring_window_rows(lens, t, window)
+    else:
+        valid = (idx <= lens[:, None]) | (lens[:, None] >= t)
+    if chosen is not None:
+        valid = valid & chosen
     scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("sht,sdt->shd", probs, pages[:, :value_dim]), cache
+
+
+def ring_window_rows(at: jax.Array, t: int, window: int) -> jax.Array:
+    """Which rows of a ring of ``t`` rows that wraps (position p at row p % t)
+    a query at position ``at`` [N] reads under a window: the rows of positions
+    s with 0 <= at - s < ``window``, s >= 0 -> bool [N, t]. The ring holds
+    positions up to ``at``: row r holds the newest position <= ``at`` that is
+    r modulo t, ``at - ((at - r) mod t)``."""
+    at = at.astype(jnp.int32)[:, None]
+    back = jnp.mod(at - jax.lax.broadcasted_iota(jnp.int32, (at.shape[0], t), 1), t)
+    return back < jnp.minimum(window, at + 1)
+
+
+def window_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int) -> jax.Array:
+    """Causal attention under a window over a whole sequence from position 0:
+    q [B, T, H, D], k [B, T, H, D], v [B, T, H, Dv]; query t reads rows s with
+    0 <= t - s < ``window`` -> [B, T, H, Dv]. Scores and softmax in float32 as
+    :func:`xla_attention`'s."""
+    t, d = q.shape[1], q.shape[-1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * d**-0.5
+    back = jnp.arange(t)[:, None] - jnp.arange(t)[None]
+    scores = jnp.where((back >= 0) & (back < window), scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def tiled_latent_attention(
+    q: jax.Array, page: jax.Array, reads: jax.Array, live_rows, tile: int,
+    *, scale: float, value_dim: int,
+) -> jax.Array:
+    """A prefill chunk's latent attention in the absorbed form over one slot's
+    page of latent rows, ``tile`` rows at a time under an online softmax: q [C,
+    H, Dl] (``llama.latent_absorb``), ``page`` [Dl, T] (one layer's page of
+    the slot, the chunk's own rows in it), ``reads`` [C, T] bool, the rows each
+    query reads (an indexer's selection, or a window's rows of a ring that
+    wraps), one set a query for all its heads -> o_lat [C, H, value_dim], the
+    weighted sum over a row's first ``value_dim`` values. No key or value is
+    rebuilt and no [C, T] block of scores a head is held; tiles from
+    ``live_rows`` (traced) on hold nothing read and are not visited. A query
+    that reads no row (a bucket's padding) comes out zero."""
+    c, h, dl = q.shape
+    t = page.shape[-1]
+    f32, neg = jnp.float32, jnp.finfo(jnp.float32).min
+
+    def visit(i, carry):
+        m, l, acc = carry
+        at = i * tile
+        rows = jax.lax.dynamic_slice_in_dim(page, at, tile, 1)  # [Dl, tile]
+        rt = jax.lax.dynamic_slice_in_dim(reads, at, tile, 1)[:, None]  # [C, 1, tile]
+        s = jnp.einsum("chd,dt->cht", q, rows, preferred_element_type=f32) * scale
+        s = jnp.where(rt, s, neg)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(rt, jnp.exp(s - m_new[..., None]), 0.0)
+        keep = jnp.exp(m - m_new)
+        l = l * keep + jnp.sum(p, axis=-1)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "cht,dt->chd", p.astype(q.dtype), rows[:value_dim], preferred_element_type=f32
+        )
+        return m_new, l, acc
+
+    init = (jnp.full((c, h), neg, f32), jnp.zeros((c, h), f32), jnp.zeros((c, h, value_dim), f32))
+    tiles = (jnp.asarray(live_rows, jnp.int32) + tile - 1) // tile
+    _, l, acc = jax.lax.fori_loop(0, jnp.minimum(tiles, t // tile), visit, init)
+    return (acc / jnp.where(l > 0, l, 1.0)[..., None]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -450,7 +529,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array, chosen: jax.Array
     scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=jnp.float32)
     scores = jnp.where(chosen[:, None, None], scores * d**-0.5, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(b, tq, h, d)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(b, tq, h, v.shape[-1])
 
 
 def causal_selection(qi, wi, ki, topk: int) -> jax.Array:

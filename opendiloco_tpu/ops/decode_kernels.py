@@ -878,9 +878,13 @@ def eva_decode_attention(
 
 
 def _mla_decode_kernel(
-    lens_ref, layer_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
-    m_scr, l_scr, acc_scr, *, scale, block_t, t, num_t, value_dim,
+    lens_ref, layer_ref, q_ref, new_ref, c_ref, *rest,
+    scale, block_t, t, num_t, value_dim, window=0, with_selection=False, live_only=False,
 ):
+    # under a selection (an indexer over latent rows): one more operand behind
+    # the cache, the rows of this tile that the slot's indexer chose
+    sel_ref = rest[0] if with_selection else None
+    o_ref, co_ref, m_scr, l_scr, acc_scr = rest[with_selection:]
     heads, d = q_ref.shape  # every head of the slot: they share its rows
     si, ti = pl.program_id(0), pl.program_id(1)
 
@@ -900,7 +904,17 @@ def _mla_decode_kernel(
         idx = ti * block_t + jax.lax.broadcasted_iota(
             jnp.int32, (heads, block_t), 1
         )
-        valid = (idx <= lens_s) | (lens_s >= t)
+        if window:
+            # a ring that wraps under a window: row r holds the newest position
+            # r modulo t, lens - ((lens - r) mod t), and the slot reads the rows
+            # of its last ``window`` positions
+            back = jax.lax.rem(lens_s, t) - idx
+            back = jnp.where(back < 0, back + t, back)
+            valid = back < jnp.minimum(window, lens_s + 1)
+        else:
+            valid = (idx <= lens_s) | (lens_s >= t)
+        if with_selection:  # of the live rows, those the indexer chose
+            valid = valid & (sel_ref[:] > 0)
         s = scale * jax.lax.dot_general(
             q_ref[:], tile, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -909,6 +923,8 @@ def _mla_decode_kernel(
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if with_selection or window:  # a live tile may hold no row that is read
+            p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
@@ -917,7 +933,17 @@ def _mla_decode_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when((new_at >= 0) & (new_at < block_t))
+    # ``live_only``: a slot at lens 0 holds no sequence that decodes (it may be
+    # one whose prompt is arriving in chunks); it is written nothing, reads
+    # nothing, and hands the block the output maps to back as it was
+    holds = (lambda cond: cond & (lens_s > 0)) if live_only else (lambda cond: cond)
+
+    if live_only:
+        @pl.when((lens_s == 0) & (ti == 0))
+        def _hand_back():
+            co_ref[:] = c_ref[:]
+
+    @pl.when(holds((new_at >= 0) & (new_at < block_t)))
     def _write_and_attend():
         # the new row as a column of the rows-minor tile. The rows of all
         # slots arrive transposed, slots as lanes ([d, 128] here); picking
@@ -941,7 +967,7 @@ def _mla_decode_kernel(
         co_ref[:] = tile
         attend(tile)
 
-    @pl.when((ti <= last_live) & ((new_at < 0) | (new_at >= block_t)))
+    @pl.when(holds((ti <= last_live) & ((new_at < 0) | (new_at >= block_t))))
     def _attend():
         attend(c_ref[:])
 
@@ -949,6 +975,17 @@ def _mla_decode_kernel(
     def _finish():
         l = l_scr[:]
         o_ref[:] = (acc_scr[:] / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+
+
+def mla_decode_plan(
+    d: int, value_dim: int, t: int, *, block_t: int | None = None, interpret: bool | None = None,
+) -> int:
+    """The ring rows a tile of ``odtp_mla_decode_attn`` over a latent ring of
+    ``t`` rows of ``d`` values, the first ``value_dim`` of them the values: 0
+    where the kernel cannot tile the shape (and a call keeps the XLA path)."""
+    if d % 8 != 0 or value_dim % 8 != 0:
+        return 0
+    return _ring_block(t, block_t, _interpret(interpret), preferred=512)
 
 
 def mla_decode_attention(
@@ -962,6 +999,9 @@ def mla_decode_attention(
     value_dim: int,
     block_t: int | None = None,
     interpret: bool | None = None,
+    chosen: jax.Array | None = None,
+    window: int = 0,
+    live_only: bool = False,
 ):
     """One layer's share of a decode step of latent attention in the absorbed
     form, against the one latent ring ``cache`` [L, S, 1, Dl, T]: write each
@@ -980,14 +1020,23 @@ def mla_decode_attention(
     step, not once for keys and once for values, and not once a head. The
     tile that holds ring row ``lens % T`` gets the new row as a column and
     goes back through the aliased output. A shape it cannot tile keeps the
-    XLA path per call."""
+    XLA path per call (:func:`mla_decode_plan` says beforehand).
+
+    ``chosen`` [S, T] bool (an indexer over latent rows): one more operand,
+    the selection a tile at a time; the kernel reads the tiles as ever and lets
+    only the chosen rows into the softmax, as :func:`paged_decode_attention`
+    does under its own. ``window``: the ring wraps and a slot reads the rows
+    of its last ``window`` positions (a sliding layer's ring of a few tiles,
+    every one visited). ``live_only``: a slot at ``lens`` 0 is written nothing
+    (it may be one whose prompt is arriving in chunks)."""
     s_, h, d = q.shape
     t = ring_rows(cache)
     interp = _interpret(interpret)
-    bt = _ring_block(t, block_t, interp, preferred=512)
-    if d % 8 != 0 or value_dim % 8 != 0 or not bt:
+    bt = mla_decode_plan(d, value_dim, t, block_t=block_t, interpret=interp)
+    if not bt:
         return latent_decode_step_attention(
-            q, row, cache, lens, layer, scale=scale, value_dim=value_dim
+            q, row, cache, lens, layer, scale=scale, value_dim=value_dim,
+            chosen=chosen, window=window, live_only=live_only,
         )
     num_t = t // bt
     lanes = 128  # slots as lanes, so that a slot's new row is a column
@@ -1004,6 +1053,10 @@ def mla_decode_attention(
     def slot_map(si, ti, lr, yr):
         return (si, 0, 0)
 
+    def chosen_map(si, ti, lens_ref, layer_ref):
+        return (si, 0, jnp.minimum(ti, jnp.minimum(lens_ref[si], t - 1) // bt))
+
+    selection = [] if chosen is None else [chosen.astype(jnp.int32).reshape(s_, 1, t)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_, num_t),
@@ -1011,6 +1064,7 @@ def mla_decode_attention(
             pl.BlockSpec((None, h, d), slot_map),
             pl.BlockSpec((d, lanes), lambda si, ti, lr, yr: (0, si // lanes)),
             pl.BlockSpec((None, None, None, d, bt), page_map),
+            *([pl.BlockSpec((None, 1, bt), chosen_map)] if selection else []),
         ],
         out_specs=[
             pl.BlockSpec((None, h, value_dim), slot_map),
@@ -1026,6 +1080,9 @@ def mla_decode_attention(
         functools.partial(
             _mla_decode_kernel,
             scale=float(scale), block_t=bt, t=t, num_t=num_t, value_dim=value_dim,
+            **({"window": int(window)} if window else {}),
+            **({"with_selection": True} if selection else {}),
+            **({"live_only": True} if live_only else {}),
         ),
         name="odtp_mla_decode_attn",
         grid_spec=grid_spec,
@@ -1042,6 +1099,6 @@ def mla_decode_attention(
         interpret=interp,
     )(
         lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        q, new, cache,
+        q, new, cache, *selection,
     )
     return out, cache

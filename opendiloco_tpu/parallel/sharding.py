@@ -53,6 +53,7 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "kv_a_proj": (None, 0),  # [D, R+rope]
     "kv_a_norm": (None, -1),
     "kv_b_proj": (1, 0),  # [R, Nh*(nope+v)]
+    "attn_gate": (1, 0),  # [D, Nh]: a value a head, tp with the heads it scales
     # the shared SwiGLU beside a routed FFN (granite hybrid): as a dense FFN's
     "shared_gate_proj": (1, 0),  # [D, Fs]
     "shared_up_proj": (1, 0),

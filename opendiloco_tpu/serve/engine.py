@@ -117,6 +117,7 @@ from opendiloco_tpu.ops.decode_kernels import (
     eva_prefill_form,
     mla_decode_attention,
     paged_decode_attention,
+    mla_decode_plan,
     resolve_decode_kernel,
 )
 from opendiloco_tpu.serve.kvcache import pick_bucket
@@ -401,6 +402,7 @@ class ServeEngine:
         epoch_fn: Optional[Callable[[], int]] = None,
         max_stale_rounds: int = 0,
         decode_kernel: Optional[str] = None,
+        adopt_params: bool = False,
     ):
         self.cfg = cfg
         self.num_slots = int(num_slots)
@@ -420,7 +422,10 @@ class ServeEngine:
         self._shapes = [tuple(x.shape) for x in leaves]
         # bindings of a weight tree so far: 1 here, +1 a swap
         self.weight_binds = 0
-        self._bind(leaves, epoch)
+        # leaves of the first tree that the engine took as they were (``adopt_params``)
+        self.weights_adopted = 0
+        self._bind(leaves, epoch, adopt=adopt_params)
+        del leaves, params
         self.swap_seconds = 0.0
         # wall-clock per decode stage (loop-thread only, mirrored to obs
         # spans when a tracer is armed; the bench reads this directly)
@@ -476,7 +481,13 @@ class ServeEngine:
                 f"{cfg.q_chunk_size}: a prompt admitted in chunks writes each as one "
                 "block of ring rows"
             )
-        # a latent cache is the one ring, in ``cache_k``; ``cache_v`` is None
+        if cfg.sliding and not cfg.sparse:
+            raise ValueError(
+                "a latent stack with sliding layers is served with its full layers under "
+                "an indexer (index_topk > 0): its prompts go in chunks beside an index ring"
+            )
+        # a latent cache is the one ring, in ``cache_k``; ``cache_v`` is None, or,
+        # for a latent stack with sliding layers, those layers' ring, which wraps
         if cfg.sparse:
             self._index = (
                 init_index_cache(cfg, self.num_slots, self.max_context, compute_dtype),
@@ -501,6 +512,34 @@ class ServeEngine:
         self._latent_row_bytes = (
             cfg.latent_row_dim * self.cache_k.dtype.itemsize if cfg.latent else 0
         )
+        # the sliding layers' ring (0 without them): what it holds, the rows a
+        # step or a chunk read of it over layers (each slot's window's rows),
+        # and which form each kind of latent layer's decode step and chunk take
+        # ({} without sliding layers): the decode step's by ``decode_kernel``
+        # (the kernel has a tile for both rings or the engine is refused here,
+        # never a step that quietly takes the XLA form); a chunk's is the
+        # absorbed form in XLA, a tile of ring rows at a time
+        self.swa_cache_resident_bytes = self.cache_v.nbytes if cfg.sliding else 0
+        self.swa_rows_read = 0
+        self.swa_bytes_moved = 0
+        self.latent_forms: dict = {}
+        if cfg.sliding:
+            full, swa = self.cache_k.shape, self.cache_v.shape
+            plans = {
+                "full": mla_decode_plan(full[3], cfg.kv_lora_rank, full[4]),
+                "sliding": mla_decode_plan(swa[3], cfg.swa_kv_lora_rank, swa[4]),
+            }
+            if self.decode_kernel == "pallas" and not all(plans.values()):
+                raise ValueError(
+                    "decode_kernel 'pallas' has no tile for this stack's latent rings "
+                    f"(full {full[3:]}, sliding {swa[3:]} as (row, ring rows): {plans}); "
+                    "decode_kernel 'xla' runs the XLA form"
+                )
+            self.latent_forms = {
+                kind: {"decode": self.decode_kernel, "chunk": "absorbed-xla",
+                       "block_t": plans[kind] if self.decode_kernel == "pallas" else 0}
+                for kind in plans
+            }
         # the slots' second kind of state: empty for a stack of attention layers
         self._ssm: tuple = ()
         if cfg.hybrid:
@@ -729,15 +768,29 @@ class ServeEngine:
 
     # -- weight residency ---------------------------------------------------
 
-    def _bind(self, leaves, epoch: int) -> None:
+    def _bind(self, leaves, epoch: int, adopt: bool = False) -> None:
         """The one door weights come through: flat leaves (original flatten
         order; float32 masters on the device or the host) become
         ``self.params``, the tree every program of the engine reads. Every
         leaf lands in a fresh buffer in ``compute_dtype``, rounded here once,
-        and the engine keeps nothing else of it."""
-        self.params = jax.tree.unflatten(
-            self._treedef, _fresh_copy(list(leaves), self.compute_dtype)
-        )
+        and the engine keeps nothing else of it.
+
+        ``adopt`` (``ServeEngine(adopt_params=True)``: the caller gives the
+        tree up, and neither donates nor changes a leaf of it afterwards): a
+        leaf that arrives as a device array in ``compute_dtype`` is taken as it
+        is, no copy made, so that a tree of which the chip holds one copy and
+        not two is served at all; every other leaf is copied as ever."""
+        leaves = list(leaves)
+        copied = [
+            i for i, x in enumerate(leaves)
+            if not (adopt and isinstance(x, jax.Array) and x.dtype == self.compute_dtype)
+        ]
+        if copied:
+            fresh = _fresh_copy([leaves[i] for i in copied], self.compute_dtype)
+            for i, x in zip(copied, fresh):
+                leaves[i] = x
+        self.weights_adopted = len(leaves) - len(copied)
+        self.params = jax.tree.unflatten(self._treedef, leaves)
         self.weights_epoch = int(epoch)
         self.weight_binds += 1
         self.weights_resident_bytes = sum(
@@ -878,6 +931,8 @@ class ServeEngine:
     def needs_chunks(self, n: int) -> bool:
         """Is a prompt of ``n`` tokens admitted in chunks (learned sparse
         attention, and no bucket holds it)?"""
+        if self.cfg.sliding:  # every prompt: no whole-prompt insert into a ring that wraps
+            return True
         return self._chunk is not None and pick_bucket(n, self.prefill_buckets) is None
 
     def admit_begin(self, slot: int, prompt: Sequence[int], positions=None) -> Admission:
@@ -945,6 +1000,11 @@ class ServeEngine:
         token a blocking caller waited for) -> the span's attributes."""
         _, attrs = self._split_counts(np.asarray(chunk.tokd), 1)
         attrs.update(self._count_dsa(rows_before=chunk.rows_before, count=chunk.count))
+        if self._latent_row_bytes:  # the slot's rows so far and the chunk's own
+            attrs.update(self._count_latent(
+                read=chunk.rows_before + chunk.count, written=chunk.count,
+                swa_read=min(chunk.rows_before + chunk.count, self.cache_v.shape[-1]),
+            ))
         attrs.update(chunk=chunk.index, rows_before=chunk.rows_before)
         self.prefill_chunks += 1
         self.prefill_chunk_tokens += chunk.count
@@ -1086,7 +1146,7 @@ class ServeEngine:
         if not self._index:
             return {}
         cfg = self.cfg
-        layers, topk = cfg.num_hidden_layers, cfg.index_topk
+        layers, topk = self._index[0].shape[0], cfg.index_topk  # the layers under an indexer
         if lens is None:
             live = rows_before + 1 + np.arange(count, dtype=np.int64)
             rows_read = rows_before + count
@@ -1101,14 +1161,15 @@ class ServeEngine:
         selected = int(np.minimum(live, topk).sum())
         item = self.cache_k.dtype.itemsize
         index_bytes = layers * (rows_read if scored else 0) * cfg.index_head_dim * item
-        kv_bytes = layers * rows_read * 2 * cfg.kv_heads * cfg.head_dim * item
+        row = cfg.latent_row_dim if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
+        kv_bytes = layers * rows_read * row * item
         self.dsa_rows_scored += layers * scored
         self.dsa_rows_selected += layers * selected
         self.dsa_index_bytes_read += index_bytes
         self.dsa_kv_bytes_read += kv_bytes
         return {"dsa_rows_scored": layers * scored, "dsa_rows_selected": layers * selected}
 
-    def _count_latent(self, read: int, written: int) -> dict:
+    def _count_latent(self, read: int, written: int, swa_read: int = 0) -> dict:
         """Add one call's traffic with the latent ring to the engine's
         counters: ``read`` and ``written`` rows of one layer's pages, the
         same in every layer -> the same as span attributes, ``latent_rows``
@@ -1117,11 +1178,19 @@ class ServeEngine:
         without a latent cache)."""
         if not self._latent_row_bytes:
             return {}
-        layers = self.cfg.num_attention_layers
+        layers = self.cfg.num_full_layers
         moved = layers * (read + written) * self._latent_row_bytes
         self.latent_rows_read += layers * read
         self.latent_bytes_moved += moved
-        return {"latent_rows": layers * max(read, written), "latent_bytes": moved}
+        attrs = {"latent_rows": layers * max(read, written), "latent_bytes": moved}
+        if self.swa_cache_resident_bytes:  # the sliding layers' ring: ``swa_read`` rows of it
+            swa = self.cfg.num_sliding_layers
+            self.swa_rows_read += swa * swa_read
+            self.swa_bytes_moved += (
+                swa * (swa_read + written) * self.cfg.sliding_row_dim * self.cache_v.dtype.itemsize
+            )
+            attrs["swa_rows"] = swa * swa_read
+        return attrs
 
     def _split_counts(self, fetched: np.ndarray, n: int) -> tuple[np.ndarray, dict]:
         """One program's fetched token output -> (its ``n`` tokens, span
@@ -1356,7 +1425,8 @@ class ServeEngine:
             # a live slot's rows [0, lens] (the ring's T once it has wrapped),
             # the step's own among them
             moe.update(self._count_latent(
-                read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size
+                read=int(np.minimum(held + 1, self.max_context).sum()), written=held.size,
+                swa_read=int(np.minimum(held + 1, self.cfg.sliding_window_size).sum()),
             ))
         moe.update(self._count_eva(lens))
         if self._index:
@@ -1405,10 +1475,17 @@ class ServeEngine:
                     scale=cfg.qk_head_dim**-0.5, value_dim=cfg.kv_lora_rank,
                 )
 
+            beside = {}
+            if self._index:  # the indexer's and the chunks' counters, as gauges
+                beside = {name: float(getattr(self, name)) for name in (
+                    "index_cache_resident_bytes", "dsa_rows_scored", "dsa_rows_selected",
+                    "dsa_index_bytes_read", "dsa_kv_bytes_read", "prefill_chunks",
+                    "prefill_chunk_tokens",
+                )}
             return self._publish_probe({"decode_attn_us": _best_us(
                 _latent, ql, jnp.full((S,), T // 2, jnp.int32), self.cache_k[:1],
                 carried=1, iters=iters,
-            ), **self.decode_plan_stats()})
+            ), **beside, **self.decode_plan_stats()})
         if cfg.sparse:
             # a decode step's indexing and attention over the three rings of one
             # layer, every slot three quarters full
@@ -1513,6 +1590,17 @@ class ServeEngine:
         if cfg.eva:
             out["eva_pooled_plan_heads"] = float(rings[-1][0].heads)
             out["eva_pooled_plan_block_t"] = float(rings[-1][0].block_t)
+        for kind, ring in (("mla", self.cache_k), ("swa", self.cache_v)) if cfg.sliding else ():
+            # each kind of latent layer's ring and its tile of ``odtp_mla_decode_attn``
+            # (0: the XLA form), all the heads of a slot a grid step
+            form = self.latent_forms["full" if kind == "mla" else "sliding"]
+            out[f"decode_plan_{kind}_block_t"] = float(form["block_t"])
+            out[f"{kind}_ring_rows"] = float(ring.shape[-1])
+            out[f"{kind}_ring_bytes"] = float(ring.nbytes)
+            if form["block_t"]:
+                out["decode_grid_steps"] += float(
+                    ring.shape[0] * S * (ring.shape[-1] // form["block_t"])
+                )
         return out
 
     def _publish_probe(self, out: dict) -> dict:

@@ -1317,6 +1317,16 @@ class ContinuousBatcher:
                     "prefill_chunk_tokens",
                 )
             },
+            # what latent attention did with its rings by kind of layer: the
+            # full layers' (zeros without latent attention), the sliding layers'
+            # (zeros without them), and which form each kind's programs take
+            "latent": {
+                **{name: getattr(self.engine, name) for name in (
+                    "latent_rows_read", "latent_bytes_moved", "latent_cache_resident_bytes",
+                    "swa_rows_read", "swa_bytes_moved", "swa_cache_resident_bytes",
+                )},
+                "forms": self.engine.latent_forms,
+            },
             # what EVA attention did with its two rings (zeros without it)
             "eva": {
                 **{name: getattr(self.engine, f"eva_{name}") for name in (

@@ -389,6 +389,54 @@ def test_mla_decode_attention_parity(layer, block_t, dtype):
         assert set(touched) <= {int(rows_written[s_])}
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("block_t", [8, 16])
+@pytest.mark.parametrize("under", ["selection", "window", "window_of_a_tile"])
+def test_mla_decode_attention_under_a_selection_and_under_a_window(under, block_t, dtype):
+    """The latent kernel's two further operands, interpreted, against the XLA
+    absorbed path (PR 54): under ``chosen`` only the indexer's rows of a slot's
+    live rows enter the softmax (the step's own row among them or not; a tile
+    with no chosen row); under ``window`` the ring wraps and a slot reads the
+    rows of its last positions alone, before the wrap, across it, and where the
+    window is as long as a tile; with ``live_only`` a slot at ``lens`` 0 is
+    written nothing and its blocks come back as they were."""
+    L, S, H, Dl, Dv, T = 2, 7, 4, 24, 16, 32
+    keys = jax.random.split(jax.random.key(block_t), 4)
+    cache = jax.random.normal(keys[0], cache_shape(L, S, T, 1, Dl), dtype)
+    q = jax.random.normal(keys[1], (S, H, Dl), dtype)
+    row = jax.random.normal(keys[2], (S, Dl), dtype)
+    lens = jnp.array([0, 5, 15, 16, 31, 40, 77], jnp.int32)
+    extra = {"live_only": True}
+    if under == "selection":
+        lens = jnp.minimum(lens, T - 1)  # a ring under an indexer holds its context
+        live = jnp.arange(T)[None] <= lens[:, None]
+        chosen = (jax.random.uniform(keys[3], (S, T)) < 0.4) & live
+        # never an empty set (the selection keeps min(topk, live) rows), and the
+        # step's own row in some slots, not in others
+        own = jnp.arange(T)[None] == lens[:, None]
+        chosen = jnp.where((jnp.arange(S) % 2 == 0)[:, None], chosen | own, chosen & ~own)
+        extra["chosen"] = chosen.at[:, 0].set(True)
+    else:
+        extra["window"] = 5 if under == "window" else block_t
+    want, ring_x = latent_decode_step_attention(
+        q, row, cache, lens, 1, scale=0.25, value_dim=Dv, **extra)
+    got, ring_p = mla_decode_attention(
+        q, row, cache, lens, 1, scale=0.25, value_dim=Dv, block_t=block_t, interpret=True, **extra)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(  # slot 0 holds no sequence: its output is read by no one
+        np.asarray(got[1:], np.float32), np.asarray(want[1:], np.float32), rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(ring_p, np.float32), np.asarray(ring_x, np.float32))
+    np.testing.assert_array_equal(np.asarray(ring_p[:, 0], np.float32), np.asarray(cache[:, 0], np.float32))
+    if under != "selection":  # what the window's mask is: the rows of the last positions
+        from opendiloco_tpu.ops.attention import ring_window_rows
+
+        reads = np.asarray(ring_window_rows(lens, T, extra["window"]))
+        for s_ in range(1, S):
+            at = int(lens[s_])
+            rows = {p % T for p in range(max(0, at - extra["window"] + 1), at + 1)}
+            assert set(np.flatnonzero(reads[s_])) == rows
+
+
 def test_mla_decode_attention_untileable_shape_falls_back():
     """A ring no tile divides (interpreted: 30 rows, tile 8), or a row off
     the sublanes, keeps the XLA path: same results, no kernel."""

@@ -144,7 +144,7 @@ def test_the_latent_projection_alone():
     w = expert_layer(params)
     x = jax.random.normal(jax.random.key(2), (2, 19, cfg.hidden_size), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(19), (2, 19))
-    q, rows = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos))
+    q, rows, _ = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos))
     want_q, want_rows = reference.latent_rows(x, w, raw)
     assert q.shape == (2, 19, 4, 20) and rows.shape == (2, 19, 24)
     assert rel_l2(q, want_q) < REL_L2 and rel_l2(rows, want_rows) < REL_L2
@@ -165,7 +165,7 @@ def test_absorbed_against_rebuilt_attention_on_the_same_rows(kernel, monkeypatch
     n = 21
     x = jax.random.normal(jax.random.key(4), (1, n, cfg.hidden_size), jnp.float32)
     pos = jnp.arange(n)[None]
-    q, rows = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos))
+    q, rows, _ = llama._latent_qkv(cfg, x, w, *llama._rope(cfg, pos))
     rebuilt = xla_attention(q, *llama.latent_keys_values(cfg, rows, w["kv_b_proj"]), causal=True)
     # rows 0..n-2 in slot 2 of a ring, layer 1; the last row arrives with the step
     ring = init_kv_cache(cfg, 3, RING, jnp.float32)["k"]

@@ -293,10 +293,32 @@ def _eva_model():
     return cfg, init_params(jax.random.key(7), cfg)
 
 
+def _two_latent_kinds_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "dots3_note", "vocab_size": 256, "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "num_hidden_layers": 4, "num_attention_heads": 4,
+        "layer_types": ["full_attention", "full_attention", "sliding_attention", "sliding_attention"],
+        "first_k_dense_replace": 1, "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 12,
+        "qk_rope_head_dim": 8, "v_head_dim": 16, "index_n_heads": 2, "index_head_dim": 16,
+        "index_topk": 6, "q_chunk_size": 4, "sliding_window_size": 3, "swa_num_attention_heads": 2,
+        "swa_q_lora_rank": 16, "swa_kv_lora_rank": 24, "swa_qk_nope_head_dim": 8,
+        "swa_qk_rope_head_dim": 8, "swa_v_head_dim": 8, "swa_rope_theta": 5e4,
+        "attention_gate_type": "headwise", "swa_attention_gate_type": "headwise",
+        "apply_mla_qkv_lora_rescale": True, "n_routed_experts": 8, "n_shared_experts": 1,
+        "num_experts_per_tok": 2, "topk_method": "noaux_tc", "norm_topk_prob": True,
+        "rope_theta": 8e7, "max_position_embeddings": 128,
+    })
+    assert cfg.sliding and cfg.layer_kinds == ("dense", "attention", "sliding", "sliding")
+    params = init_params(jax.random.key(9), cfg)
+    for kind in ("attention", "sliding"):  # no top-k choice near a tie
+        params["layers"][kind]["router"] = params["layers"][kind]["router"] * 25.0
+    return cfg, params
+
+
 @pytest.mark.parametrize(
     "model",
     [_dense_model, _routed_qk_norm_model, _hybrid_model, _latent_routed_model, _zaya_model,
-     _eva_model],
+     _eva_model, _two_latent_kinds_model],
 )
 def test_the_four_forwards_agree(model):
     """One block under four drivers: in float32 the training forward, the
@@ -334,7 +356,27 @@ def test_the_four_forwards_agree(model):
     logits, ks, vs, *left = prefill_forward(params, padded, jnp.int32(P), cfg, **f32)
     close(logits[0], full(prompt)[P - 1])
     tok = int(jnp.argmax(logits[0, : cfg.vocab_size]))
-    assert (vs is None) == cfg.latent  # the latent rows alone are kept
+    assert (vs is None) == (cfg.latent and not cfg.sliding)  # the latent rows alone are kept
+    if cfg.sliding:
+        # two kinds of latent layer (the stack of two geometries, PR 54): the
+        # sliding layers' rows stand in the values' place, and their ring wraps,
+        # so no whole prompt is inserted: the prompt goes in as chunks (the
+        # continued prefill over latent rows, which this block is the first to
+        # run), and the decode step over the three rings gives the forward's next row
+        from opendiloco_tpu.models.ring_cache import init_index_cache
+
+        cache = init_kv_cache(cfg, 2, 32, jnp.float32)
+        ck, cv, ci = cache["k"], cache["v"], init_index_cache(cfg, 2, 32, jnp.float32)
+        assert cv.shape[-1] == 8  # a chunk of 4 beside the window's rows before it, in whole chunks
+        for plen in range(0, P, 4):
+            count = min(4, P - plen)
+            ids = jnp.asarray([(prompt[plen : plen + count] + [0] * 4)[:4]], jnp.int32)
+            chunked, ck, cv, ci = chunk_prefill_forward(params, ids, plen, count, 1, ck, cv, ci, cfg, **f32)
+        close(chunked[0], logits[0])
+        tokens, lens = jnp.asarray([0, tok], jnp.int32), jnp.asarray([0, P], jnp.int32)
+        step, *_ = decode_forward(params, tokens, lens, ck, cv, cfg, index_cache=ci, **f32)
+        close(step[1], full(prompt + [tok])[P])
+        return
 
     # slot 1 of two holds the prompt; one decode step = a continued prefill
     # over a tail of one = the forward's next row

@@ -1,0 +1,162 @@
+"""The serving programs of three configurations the benchmark measures lower
+to the text they lowered to before dots3-note-prev's layers came (PR 54): the
+decode step, a whole-prompt prefill and the continued prefill (a chunk, or the
+suffix behind a prefix) of ``serve-360m-batch``'s, ``serve-glm-flash-agent``'s
+and ``serve-keye-videoqa``'s configurations, lowered for the TPU at the cells'
+shapes with the decode kernels in (shapes alone: nothing is compiled or run).
+A model PR that adds work to a shared program hands those cells a reason to
+move; this holds the programs' text to a digest recorded from the parent
+commit (``python tests/test_program_text.py`` prints a tree's digests: run it
+with the parent's checkout first on ``PYTHONPATH`` to record).
+
+The loop is held the same way: ``ContinuousBatcher``'s iteration for a
+configuration without sliding layers calls no function of the engine that the
+parent's did not."""
+
+import hashlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELLS = {
+    "360m": ("smollm2-360m", "serve-360m-batch"),
+    "glm": ("glm-4.7-flash", "serve-glm-flash-agent"),
+    "keye": ("keye-vl-2.0-30b-a3b", "serve-keye-videoqa"),
+}
+# recorded from commit f83e3d2 (PR 52's tree, PR 54's parent)
+PARENT = {
+    "360m": {"decode": "60bc61211abd1b80", "prefill": "c0dc8cf8a5e066b8",
+             "chunk": "a3efda827d689e95"},
+    "glm": {"decode": "2d6902c25c19c003", "prefill": "111348d7d79011c6"},
+    "keye": {"decode": "c115bdfaa18a765f", "prefill": "fccbb3f9f6585ff8",
+             "chunk": "1a2463f7d2aefe97"},
+}
+# the engine's methods that the batcher's loop (and a submit) called at that
+# commit while it served two requests of a dense, a latent and an indexed
+# configuration at a tiny size, each past the other in the queue
+_LOOP = (
+    "_bucket_of", "_count_cca", "_count_eva", "_count_latent", "_count_phases", "_count_ssm",
+    "_enqueue_step", "_finish_step", "_read", "_refuse_positions", "_split_counts",
+    "admit_enqueue", "maybe_swap", "needs_chunks", "prompt_fits", "staleness", "step_ahead",
+)
+PARENT_CALLS = {
+    "360m": _LOOP, "glm": _LOOP,
+    "keye": (*_LOOP, "_close_chunk", "_count_dsa", "admit_begin", "admit_chunk"),
+}
+
+
+def _cell(config, workload):
+    from opendiloco_tpu.models.llama import LlamaConfig
+
+    bench = os.path.join(ROOT, "benchmark")
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
+        cfg = LlamaConfig.from_dict(json.load(f))
+    with open(os.path.join(bench, "workloads", f"{workload}.json")) as f:
+        return cfg, json.load(f)["engine"]
+
+
+def digests(name: str) -> dict:
+    """{program: sha256 of its StableHLO text, lowered for the TPU} of one
+    cell's configuration at the cell's slots and context."""
+    from opendiloco_tpu.models import llama, ring_cache
+    from opendiloco_tpu.serve.engine import chunk_program, serving_programs
+
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    cfg, opts = _cell(*CELLS[name])
+    bf = jnp.bfloat16
+    sds = jax.ShapeDtypeStruct
+    params = jax.tree.map(lambda x: sds(x.shape, bf), llama.shapes(cfg))
+    slots, rows = opts["num_slots"], opts["max_context"]
+    cache = jax.eval_shape(lambda: ring_cache.init_kv_cache(cfg, slots, rows, bf))
+    rings = [cache["k"], cache["v"]]
+    if cfg.sparse:
+        rings.append(jax.eval_shape(lambda: ring_cache.init_index_cache(cfg, slots, rows, bf)))
+    vec, scalar = sds((slots,), jnp.int32), sds((), jnp.int32)
+    prefill, decode, _, n = serving_programs(cfg, compute_dtype=bf, decode_kernel="pallas")
+    lower = lambda fn, *args, **kw: jax.jit(fn, **kw).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    texts = {
+        "decode": lower(decode, params, vec, vec, vec, *rings,
+                        donate_argnums=tuple(range(4, 4 + n))),
+        "prefill": lower(prefill, params, sds((1, 512), jnp.int32), scalar),
+    }
+    if cfg.sparse:
+        texts["chunk"] = lower(
+            chunk_program(cfg, compute_dtype=bf), params, sds((1, cfg.q_chunk_size), jnp.int32),
+            scalar, scalar, scalar, sds((), jnp.bool_), vec, *rings, donate_argnums=(6, 7, 8, 9),
+        )
+    elif not cfg.latent:  # the suffix behind a reused prefix
+        texts["chunk"] = lower(
+            lambda p, tail, plen, count, slot, ck, cv: llama.chunk_prefill_forward(
+                p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=bf),
+            params, sds((1, 128), jnp.int32), scalar, scalar, scalar, *rings,
+            donate_argnums=(5, 6),
+        )
+    return {k: hashlib.sha256(v.encode()).hexdigest()[:16] for k, v in texts.items()}
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_the_programs_lower_to_the_parents_text(name):
+    assert digests(name) == PARENT[name]
+
+
+TINY = {
+    "360m": dict(hidden_size=32, intermediate_size=64, num_hidden_layers=2, num_attention_heads=4,
+                 num_key_value_heads=2, vocab_size=64),
+    "glm": dict(hidden_size=32, intermediate_size=64, num_hidden_layers=2, num_attention_heads=4,
+                vocab_size=64, q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
+                qk_rope_head_dim=4, v_head_dim=8),
+    "keye": dict(hidden_size=32, intermediate_size=64, num_hidden_layers=2, num_attention_heads=4,
+                 num_key_value_heads=2, vocab_size=64, index_n_heads=2, index_head_dim=8,
+                 index_topk=6, q_chunk_size=8),
+}
+
+
+def loop_calls(name: str) -> list:
+    """The names of the engine's methods called while a batcher serves two
+    requests (the second longer than every bucket where the configuration
+    admits in chunks)."""
+    import numpy as np
+
+    from opendiloco_tpu.models.llama import LlamaConfig, init_params
+    from opendiloco_tpu.serve import ContinuousBatcher, ServeEngine
+
+    cfg = LlamaConfig.from_dict(TINY[name])
+    engine = ServeEngine(
+        cfg, init_params(jax.random.key(0), cfg), num_slots=2, max_context=32,
+        prefill_buckets=(16,), compute_dtype=jnp.float32,
+    )
+    called = set()
+    for attr in dir(ServeEngine):
+        fn = getattr(ServeEngine, attr)
+        if attr.startswith("__") or not callable(fn) or isinstance(fn, type):
+            continue
+
+        def wrapped(*a, _fn=getattr(engine, attr), _name=attr, **kw):
+            called.add(_name)
+            return _fn(*a, **kw)
+
+        setattr(engine, attr, wrapped)
+    batcher = ContinuousBatcher(engine).start()
+    rng = np.random.default_rng(0)
+    lens = (9, 20 if name == "keye" else 12)
+    reqs = [batcher.submit(rng.integers(3, 64, n).tolist(), max_new_tokens=4) for n in lens]
+    for r in reqs:
+        assert r.wait(120) and r.error is None, r.error
+    batcher.stop()
+    return sorted(called)
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_the_loop_calls_nothing_new(name):
+    assert set(loop_calls(name)) <= set(PARENT_CALLS[name])
+
+
+if __name__ == "__main__":
+    print(json.dumps({name: digests(name) for name in sorted(CELLS)}, indent=1))
+    print(json.dumps({name: loop_calls(name) for name in sorted(CELLS)}))

@@ -1424,3 +1424,80 @@ def test_keye_index_ring_write_kernel(chip, monkeypatch):
     assert "odtp_index_ring_write" in compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes >= rings[2].size * 2
     assert not _ring_copies(compiled.as_text(), rings[2].shape)
+
+
+# --- dots3-note-prev: two kinds of latent attention, three rings (PR 54) ----
+
+
+def _dots3_cell(chip):
+    """-> (configuration, engine options, the three rings as shapes: the full
+    layers' latent ring, the sliding layers' ring that wraps, the index ring)."""
+    from opendiloco_tpu.models.ring_cache import sliding_ring_rows
+
+    cfg, engine = _serve_cell("dots3-note-prev", "serve-dots3-notes")
+    slots, rows = engine["num_slots"], engine["max_context"]
+    full = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_full_layers, slots, rows, 1, cfg.latent_row_dim), BF16, sharding=chip)
+    sliding = jax.ShapeDtypeStruct(
+        cache_shape(cfg.num_sliding_layers, slots, sliding_ring_rows(cfg), 1, cfg.sliding_row_dim),
+        BF16, sharding=chip)
+    index = jax.ShapeDtypeStruct(
+        (cfg.num_full_layers, slots, cfg.index_head_dim, rows), BF16, sharding=chip)
+    return cfg, engine, (full, sliding, index)
+
+
+def _dots3_program(chip, monkeypatch, which):
+    from opendiloco_tpu.serve.engine import chunk_program, serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine, rings = _dots3_cell(chip)
+    params = _bound(chip, cfg)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    if which == "decode":
+        _, decode, _, n = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+        assert n == 3
+        lowered = jax.jit(decode, donate_argnums=(4, 5, 6)).lower(params, vec, vec, vec, *rings)
+    else:
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+        ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
+        last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
+        lowered = jax.jit(
+            chunk_program(cfg, compute_dtype=BF16), donate_argnums=(6, 7, 8, 9)
+        ).lower(params, ids, scalar, scalar, scalar, last, vec, *rings)
+    return cfg, params, rings, lowered.compile()
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_dots3_programs_copy_no_ring_and_cast_no_weight(chip, monkeypatch, which):
+    """The engine's decode and chunk programs for dots3-note-prev at 12 slots
+    of 25,088 rows, published widths: 4,087,154,176 parameters held once in
+    bf16; the three rings (the full layers' 576-wide latent ring, the sliding
+    layers' 1,088-wide ring of 1,024 rows, the index ring) alias the outputs
+    and none is copied; no weight is cast; the program fits the chip. The
+    decode step holds ``odtp_mla_decode_attn`` (under the selection and under
+    the window: the kernel, not its XLA form) and the index ring's column
+    write; the chunk holds its index scores [512, 25088] once and no float32
+    block over 512 MB."""
+    cfg, params, rings, compiled = _dots3_program(chip, monkeypatch, which)
+    assert (cfg.num_full_layers, cfg.num_sliding_layers, cfg.latent_row_dim, cfg.sliding_row_dim) == (
+        2, 3, 576, 1088)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in rings)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 8_174_308_352 and held == 927_989_760
+    print(f"dots3 {which}: arguments {mem.argument_size_in_bytes} temporaries "
+          f"{mem.temp_size_in_bytes} aliased {mem.alias_size_in_bytes} "
+          f"program {_program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held
+    assert _program_bytes(compiled) < HBM_BYTES
+    for ring in rings:
+        assert not _ring_copies(text, ring.shape), which
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+    if which == "decode":
+        assert "odtp_mla_decode_attn" in text and "odtp_index_ring_write" in text
+        assert mem.temp_size_in_bytes < 256e6
+        assert not _f32_blocks_over(text, 256e6)
+    else:
+        assert "f32[512,25088]" in text
+        assert mem.temp_size_in_bytes < 2e9
+        assert not _f32_blocks_over(text, 512e6)
