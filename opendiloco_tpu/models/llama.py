@@ -57,6 +57,7 @@ from opendiloco_tpu.ops.attention import (
     xla_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
+    causal_prefill_attention,
     eva_decode_attention,
     eva_prefill_attention,
     index_ring_write,
@@ -1585,6 +1586,20 @@ def latent_attend(cfg: LlamaConfig, attn_fn):
     return rebuilt_attend(cfg, attn_fn)
 
 
+def causal_prefill_heads(cfg: LlamaConfig) -> Optional[tuple]:
+    """(query heads, KV heads, the keys' head size, the values') of the
+    attention that ``prefill_forward`` runs as plain causal attention over a
+    whole prompt, the rebuilt latent form's among them; None where it runs none
+    (EVA, an indexer, a stack with sliding layers: each has its own
+    ``attend``). What ``decode_kernels.prefill_form`` is asked with."""
+    if cfg.eva or cfg.sparse or cfg.sliding:
+        return None
+    heads = cfg.num_attention_heads
+    if cfg.latent:
+        return heads, heads, cfg.qk_head_dim, cfg.v_head_dim
+    return heads, cfg.kv_heads, cfg.head_dim, cfg.head_dim
+
+
 def latent_absorb(cfg: LlamaConfig, q: jax.Array, w_kvb: jax.Array) -> jax.Array:
     """q [S, Nh, nope + rope] -> the query against a cached latent row, [S,
     Nh, R + rope]: head i's unrotated part through W_UK_i^T (the key half of
@@ -2316,6 +2331,7 @@ def prefill_forward(
     cfg: LlamaConfig,
     *,
     compute_dtype: jnp.dtype = jnp.bfloat16,
+    decode_kernel: str = "xla",
     return_moe_counts: bool = False,
     return_expert_choices: bool = False,
 ):
@@ -2347,14 +2363,20 @@ def prefill_forward(
     the decode mask stops at the live length and every ring write
     overwrites index ``len % T`` before index ``len`` becomes visible. A
     recurrent state has no rows to mask: the mixer itself stops at
-    ``length`` (``mamba.ssm_chunked``)."""
+    ``length`` (``mamba.ssm_chunked``).
+
+    ``decode_kernel`` (the engine's, resolved: "pallas" | "xla") decides with
+    the bucket's rows and the heads which form a whole prompt's plain causal
+    attention takes (``decode_kernels.prefill_form``: the flash forward kernel
+    or ``xla_attention``); the rebuilt latent form's goes the same way. EVA's,
+    the indexer's and a window's attention are their own."""
     B, P = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
     cparams = _serving_boundary(params, compute_dtype)
     rope = _rope(kind_view(cfg, "attention"), positions)
     index_rope = _index_rope(cfg, positions)
     live = positions < length
-    causal = lambda q, k, v: xla_attention(q, k, v, causal=True)
+    causal = functools.partial(causal_prefill_attention, decode_kernel=decode_kernel)
 
     def attention_body(carry, layer, li, view=cfg, rope=rope):  # the run's kind's
         h, r = carry

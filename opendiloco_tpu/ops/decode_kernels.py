@@ -76,6 +76,7 @@ from opendiloco_tpu.ops.attention import (
     eva_decode_step_attention,
     latent_decode_step_attention,
     sparse_decode_step_attention,
+    xla_attention,
 )
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
@@ -741,6 +742,71 @@ def index_ring_write(
         input_output_aliases={2: 0},
         interpret=_interpret(interpret),
     )(at, keys.astype(cache_i.dtype)[..., None], cache_i)
+
+
+# A whole prompt's causal attention in XLA has float32 scores of query heads x
+# rows^2 x 4 bytes. While they are small XLA keeps them in two fusions a layer
+# and is the faster form; past about 100 MB it writes them out and passes over
+# them several times, and the flash forward kernel, which holds a tile of them
+# in VMEM, wins. Measured on the v5e, us a call, XLA beside the kernel (PERF.md
+# section 6, PR 55): 8 heads of 128 x 512 rows (8 MB) 10.5 / 24.1; 32/8 x 512
+# (32 MB) 37.9 / 91.9; 20 heads of 256 x 768 (45 MB) 79.2 / 109.4; 32 heads of
+# 64 x 768 (72 MB) 65.9 / 138.8; 20 of 256 x 1,280 (125 MB) 392.0 / 264.4; 32/8
+# x 1,024 (128 MB) 473.1 / 168.2; 16 of 128 x 3,072 (576 MB) 4,523 / 494. The
+# line lies between 72 and 125 MB; under it, and under the floor of rows, the
+# XLA form stays
+_PREFILL_SCORE_BYTES = 96 * 1024 * 1024
+_PREFILL_FLOOR_ROWS = 512
+
+
+def prefill_form(
+    rows: int, hq: int, hkv: int, dk: int, dv: int, decode_kernel: str | None = None
+) -> str:
+    """Which form a whole prompt's causal attention takes in a serving
+    prefill, from what the call can see: "flash" (the training forward kernel,
+    no scores in memory) where ``decode_kernel`` resolves to the kernels (the
+    chip, or a test that asks for them interpreted), a multiple of 128 divides
+    the bucket's ``rows``, keys' and values' heads are of one size the kernel
+    takes, and the scores XLA would write are worth it; else "xla". The engine
+    reports it (``ServeEngine.prefill_forms``)."""
+    if resolve_decode_kernel(decode_kernel) != "pallas":
+        return "xla"
+    if rows < _PREFILL_FLOOR_ROWS or rows % 128 or dk != dv or dk % 8 or hq % hkv:
+        return "xla"
+    return "flash" if hq * rows * rows * 4 >= _PREFILL_SCORE_BYTES else "xla"
+
+
+def prefill_block(rows: int) -> int:
+    """The block, of queries and of keys alike, a serving prefill of ``rows``
+    runs the flash forward kernel in: 512 where it divides the bucket, else the
+    largest multiple of 128 up to 1,024 that does (640 / 896 for 1,280 / 1,792,
+    where ``pick_block`` would say 256: half the speed). Larger blocks are
+    faster (16 heads of 128 over 3,072 rows on the v5e: 494 us a call at 1,024,
+    804 at 512, 1,577 at 256) and dearer to trace (433 / 267 / 197 equations),
+    and a prefill program is traced once a bucket a process at about 2 ms an
+    equation: at 1,024 the hybrid cell's ``setup_s`` read up to 3.8 s over its
+    parent's 33, against a bound of 10% (PERF.md section 6, PR 55)."""
+    if rows % 512 == 0:
+        return 512
+    return max((b for b in range(128, 1025, 128) if rows % b == 0), default=0)
+
+
+def causal_prefill_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, decode_kernel: str | None = "xla"
+) -> jax.Array:
+    """A whole prompt's causal attention from position 0 (``xla_attention``'s
+    signature and result, ``causal=True``): q [1, P, Hq, D], k and v [1, P,
+    Hkv, D], rotated and normed, in the form :func:`prefill_form` names. No
+    gradient."""
+    from opendiloco_tpu.ops.flash_attention import flash_attention_lse
+
+    rows = q.shape[1]
+    if prefill_form(rows, q.shape[2], k.shape[2], k.shape[3], v.shape[3], decode_kernel) == "xla":
+        return xla_attention(q, k, v, causal=True)
+    block = prefill_block(rows)
+    return flash_attention_lse(
+        q, k, v, causal=True, block_q=block, block_k=block, interpret=_interpret(None)
+    )[0]
 
 
 def eva_prefill_form(window: int, d: int, interpret: bool | None = None) -> str:

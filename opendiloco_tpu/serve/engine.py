@@ -80,6 +80,7 @@ from opendiloco_tpu.models.llama import (
     LlamaConfig,
     chunk_prefill_forward,
     decode_forward,
+    causal_prefill_heads,
     prefill_forward,
     refuse_eva,
     refuse_latent,
@@ -118,6 +119,7 @@ from opendiloco_tpu.ops.decode_kernels import (
     mla_decode_attention,
     paged_decode_attention,
     mla_decode_plan,
+    prefill_form,
     resolve_decode_kernel,
 )
 from opendiloco_tpu.serve.kvcache import pick_bucket
@@ -214,7 +216,7 @@ def serving_programs(
     def prefill(p, ids, length):
         with jax.named_scope("odtp_serve_prefill"):
             logits, ks, vs, *rest = prefill_forward(
-                p, ids, length, cfg, compute_dtype=cd,
+                p, ids, length, cfg, compute_dtype=cd, decode_kernel=dkn,
                 return_moe_counts=moe, return_expert_choices=chosen,
             )
             left, counts = rest[:n_state], rest[n_state : n_state + 1]
@@ -334,6 +336,7 @@ class Admission:
     token: Optional[int] = None
     t_token: Optional[float] = None
     fed: bool = False  # a decode step that takes the token on the device is enqueued
+    form: str = "xla"  # the prompt's causal attention (``ServeEngine.prefill_forms``)
     # a prompt admitted in chunks (``ServeEngine.admit_begin``): its tokens, the
     # rows enqueued so far, and the newest chunk, whose counts are unread.
     # ``tokd``, ``rowd`` and the stamps are the last chunk's once it is enqueued
@@ -586,6 +589,17 @@ class ServeEngine:
             state = init_eva_state(cfg, self.num_slots, self.max_context, compute_dtype)
             self._eva = (state["pool_k"], state["pool_v"], state["stats"])
         self.eva_cache_resident_bytes = sum(x.nbytes for x in self._eva)
+        # which form each bucket's whole-prompt causal attention takes, "flash"
+        # (the training forward kernel: no scores in memory) | "xla", from the
+        # bucket's rows, the heads and ``decode_kernel`` alone; {} where a
+        # prefill runs no plain causal attention (EVA, an indexer, sliding
+        # layers); and the cold admissions that took the kernel, beside all of
+        # them in ``phase_calls["prefill"]``
+        heads = causal_prefill_heads(cfg)
+        self.prefill_forms: dict = {} if heads is None else {
+            b: prefill_form(b, *heads, self.decode_kernel) for b in self.prefill_buckets
+        }
+        self.prefill_flash_admissions = 0
         # what EVA attention did with its two rings (always on; stay 0 without
         # it): the window's rows and the pooled rows the decode steps read,
         # over layers (a step at position p reads p % window + 1 and p //
@@ -916,6 +930,7 @@ class ServeEngine:
             slot=int(slot), tokens=n, tokd=tokd, rowd=rowd,
             state_bytes=sum(x.nbytes for x in left),
             t0=t0, t_args=t_args, t_dispatch=time.perf_counter(),
+            form=self.prefill_forms.get(bucket, "xla"),
         )
         self._unread.append(adm)
         return adm
@@ -1050,7 +1065,9 @@ class ServeEngine:
                 attrs.update(self._count_dsa(rows_before=0, count=adm.tokens, whole=True))
         t1 = time.perf_counter()
         self.stage_seconds["prefill"] += (adm.t_dispatch - adm.t0) + (t1 - t_fetch)
+        self.prefill_flash_admissions += adm.form == "flash"
         if tr is not None and adm.prompt is None:
+            tr.count(f"serve_prefill_{adm.form}")
             tr.add_span(
                 "serve_prefill", adm.t0, t1 if t_from is None else adm.t_dispatch,
                 tokens=adm.tokens, **attrs,

@@ -1335,6 +1335,12 @@ class ContinuousBatcher:
                 )},
                 "forms": self.engine.eva_forms,
             },
+            # which form each bucket's whole-prompt causal attention takes, and
+            # the cold admissions that went through the flash forward kernel
+            "prefill": {
+                "forms": {str(b): form for b, form in self.engine.prefill_forms.items()},
+                "flash_admissions": self.engine.prefill_flash_admissions,
+            },
             "prefix": {
                 "hits": self.prefix_hits,
                 "host_hits": self.host_prefix_hits,

@@ -14,6 +14,16 @@ is no kernel time.
     python3 scripts/flash_kernel_bench.py --blocks 512,512 --causal 1
     python3 scripts/flash_kernel_bench.py --sub-tiles 128,256,512,1024 --check
 
+``--serving`` times a whole prompt's causal attention as a serving prefill
+runs it (PR 55): the forward kernel alone over heads ``[1, P, H, D]``, batch 1,
+at each block of ``--blocks`` that divides the bucket (``bq,bk`` pairs; with
+none, the block ``decode_kernels.prefill_block`` ships), beside the XLA form of
+the same attention (``xla_attention``: its scores written out), each as the
+device's time a call over every operation of the program:
+
+    python3 scripts/flash_kernel_bench.py --serving --check \
+        --blocks "512,512;1024,1024" [--shapes serve-olmoe-fewshot --rows 2560]
+
 ``--blocks`` goes through ``OPENDILOCO_TPU_FLASH_BLOCKS``; ``--sub-tiles``
 replaces ``flash_attention._SUB_TILE`` for the sweep that settled it (PR 42;
 a value that does not divide the block is skipped). One JSON line a case, on
@@ -43,6 +53,15 @@ SHAPES = {
     "train-1.7b-fsdp4-h8": (4, 2048, 32, 32, 64),
     "serve-evabyte-complete": (2, 2048, 32, 32, 128),
 }
+# name: (query heads, kv heads, head size, the cell's prefill buckets)
+SERVING = {
+    "serve-olmoe-fewshot": (16, 16, 128, (1536, 2048, 2560, 3072)),
+    "serve-glm-flash-agent": (20, 20, 256, (768, 1280, 1792)),  # the rebuilt latent form
+    "serve-granite-h-docqa": (32, 8, 128, (512, 1024, 1536, 2048)),
+    "serve-zaya1-reason": (8, 2, 128, (512, 1024)),
+    "serve-1.7b-chat": (32, 32, 64, (512, 768)),
+    "serve-360m-batch": (15, 5, 64, (128,)),
+}
 
 
 def kernel_us(fn, args, calls: int) -> dict:
@@ -61,6 +80,7 @@ def kernel_us(fn, args, calls: int) -> dict:
         jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb"))
         sums = {k: [0.0, 0] for k in KERNELS}
+        every = 0.0  # all the device's operations: what a form without a kernel is timed by
         for plane in ProfileData.from_file(path).planes:
             if not plane.name.startswith("/device:TPU:"):
                 continue
@@ -69,6 +89,7 @@ def kernel_us(fn, args, calls: int) -> dict:
                     continue
                 for ev in line.events:
                     result = ev.name.partition(" = ")[0]  # "%jvp_odtp_flash_fwd_.1"
+                    every += ev.duration_ns
                     for k in KERNELS:
                         if k in result:
                             sums[k][0] += ev.duration_ns
@@ -80,7 +101,80 @@ def kernel_us(fn, args, calls: int) -> dict:
         if n:
             assert n == calls, f"{k}: {n} events for {calls} calls"
             out[k] = round(ns / n / 1e3, 2)
+    out["device_us_a_call"] = round(every / calls / 1e3, 2)
     return out
+
+
+def serving(args, sink) -> None:
+    """``--serving``: one JSON line a (cell's heads, bucket, form)."""
+    import jax
+    import jax.numpy as jnp
+
+    from opendiloco_tpu.ops import decode_kernels as dk
+    from opendiloco_tpu.ops import flash_attention as fa
+    from opendiloco_tpu.ops.attention import xla_attention
+
+    names = args.shapes.split(",") if args.shapes else list(SERVING)
+    asked = [int(b.split(",")[0]) for b in args.blocks.split(";") if b]
+    rows_asked = [int(r) for r in args.rows.split(",") if r]
+    for name in names:
+        hq, hkv, d, buckets = SERVING[name]
+        for rows in rows_asked or buckets:
+            keys = jax.random.split(jax.random.key(rows), 3)
+            q = jax.random.normal(keys[0], (1, rows, hq, d), jnp.bfloat16)
+            k = jax.random.normal(keys[1], (1, rows, hkv, d), jnp.bfloat16)
+            v = jax.random.normal(keys[2], (1, rows, hkv, d), jnp.bfloat16)
+            shipped = dk.prefill_block(rows)
+            blocks = [b for b in dict.fromkeys(asked or [shipped]) if b and rows % b == 0]
+            forms = [("xla", 0)] + [("flash", b) for b in blocks]
+            ref = None
+            for form, block in forms:
+                if form == "xla":
+                    one = lambda q, k, v: xla_attention(q, k, v, causal=True)
+                else:
+                    one = lambda q, k, v, b=block: fa.flash_attention_lse(
+                        q, k, v, causal=True, block_q=b, block_k=b)[0]
+
+                def program(q, k, v, one=one):
+                    outs = []  # each call reads the one before through one element
+                    for _ in range(args.calls):
+                        o = one(q, k, v)
+                        outs.append(o[0, -1, 0, :8])
+                        q = q.at[0, 0, 0, 0].add(o[0, 0, 0, 0] * 0)
+                    return outs
+
+                us = kernel_us(jax.jit(program), (q, k, v), args.calls)
+                row = {
+                    "shape": name, "qkv": [1, rows, hq, hkv, d], "form": form, "block": block,
+                    "scores_mb": round(hq * rows * rows * 4 / 2**20, 1),
+                    "ships": [dk.prefill_form(rows, hq, hkv, d, d), shipped],
+                    "device_kind": jax.devices()[0].device_kind, "calls": args.calls, "us": us,
+                }
+                if args.check:
+                    got = jax.jit(one)(q, k, v).astype(jnp.float32)
+                    ref = got if ref is None else ref
+                    row["rel_err_vs_xla"] = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+                if form == "flash":
+                    jaxpr = jax.make_jaxpr(one)(q, k, v)
+                    row["equations"] = _equations(jaxpr.jaxpr)
+                line = json.dumps(row)
+                print(line, flush=True)
+                sink.write(line + "\n")
+                sink.flush()
+
+
+def _equations(jaxpr) -> int:
+    """The equations of a jaxpr and of every jaxpr inside it: what a program's
+    trace costs a process grows with them."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    total += _equations(inner)
+    return total
 
 
 def _check(one, causal, q, k, v, rope, d, args) -> list:
@@ -120,7 +214,8 @@ def _check(one, causal, q, k, v, rope, d, args) -> list:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", default="train-360m-h16,train-1.7b-fsdp4-h8")
+    ap.add_argument("--shapes", default="",
+                    help="comma list; empty: both train cells' (--serving: every serve cell's)")
     ap.add_argument("--causal", default="1,0", help="comma list of 1 / 0")
     ap.add_argument("--blocks", default="", help="'bq,bk' or empty; ';' separates several")
     ap.add_argument("--sub-tiles", default="", help="comma list; empty: the code's own rule")
@@ -131,6 +226,9 @@ def main() -> None:
                     help="keep the scale on the float32 scores whatever the head size")
     ap.add_argument("--check", action="store_true",
                     help="each case against xla_attention on one batch row first")
+    ap.add_argument("--serving", action="store_true",
+                    help="a serving prefill's causal attention, the kernel beside the XLA form")
+    ap.add_argument("--rows", default="", help="--serving: comma list of buckets; empty: the cells' own")
     args = ap.parse_args()
 
     import jax
@@ -144,12 +242,14 @@ def main() -> None:
         sys.exit(f"flash_kernel_bench: needs a TPU, found {dev.platform}")
     os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
     sink = open(os.path.join(_ROOT, "chiprun_out", "flash_kernel_bench.jsonl"), "a")
+    if args.serving:
+        return serving(args, sink)
     own_sub_tile = getattr(fa, "_SUB_TILE", None)
     if args.scale_on_scores:
         fa._scale_on_operand = lambda scale: False
     sub_tiles = [int(c) for c in args.sub_tiles.split(",") if c] or [None]
 
-    for name in args.shapes.split(","):
+    for name in (args.shapes or "train-360m-h16,train-1.7b-fsdp4-h8").split(","):
         b, t, hq, hkv, d = SHAPES[name]
         keys = jax.random.split(jax.random.key(0), 3)
         q = jax.random.normal(keys[0], (b, t, hq * d), jnp.bfloat16)

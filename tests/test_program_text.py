@@ -9,6 +9,14 @@ move; this holds the programs' text to a digest recorded from the parent
 commit (``python tests/test_program_text.py`` prints a tree's digests: run it
 with the parent's checkout first on ``PYTHONPATH`` to record).
 
+Since PR 55 a whole-prompt prefill's causal attention takes the flash forward
+kernel where ``decode_kernels.prefill_form`` says so. ``prefill`` here is the
+512-row program in the XLA form (``decode_kernel`` "xla"), which stays the
+parent's to the letter; ``prefill/<bucket>`` are the cell's own buckets that
+keep the XLA form under the kernels (the batch cell's 32 and 128, under the
+floor of 512 rows; the agent cell's 768, 45 MB of scores), lowered as the
+engine lowers them, and they are the parent's too (recorded from ``c8ccf9c``, PR 55's parent).
+
 The loop is held the same way: ``ContinuousBatcher``'s iteration for a
 configuration without sliding layers calls no function of the engine that the
 parent's did not."""
@@ -31,8 +39,10 @@ CELLS = {
 # recorded from commit f83e3d2 (PR 52's tree, PR 54's parent)
 PARENT = {
     "360m": {"decode": "60bc61211abd1b80", "prefill": "c0dc8cf8a5e066b8",
-             "chunk": "a3efda827d689e95"},
-    "glm": {"decode": "2d6902c25c19c003", "prefill": "111348d7d79011c6"},
+             "chunk": "a3efda827d689e95", "prefill/32": "09f6602a6c323cac",
+             "prefill/128": "173e5ce5277ff946"},
+    "glm": {"decode": "2d6902c25c19c003", "prefill": "111348d7d79011c6",
+            "prefill/768": "0d22cf03759c402a"},
     "keye": {"decode": "c115bdfaa18a765f", "prefill": "fccbb3f9f6585ff8",
              "chunk": "1a2463f7d2aefe97"},
 }
@@ -64,6 +74,7 @@ def digests(name: str) -> dict:
     """{program: sha256 of its StableHLO text, lowered for the TPU} of one
     cell's configuration at the cell's slots and context."""
     from opendiloco_tpu.models import llama, ring_cache
+    from opendiloco_tpu.ops.decode_kernels import prefill_form
     from opendiloco_tpu.serve.engine import chunk_program, serving_programs
 
     jax.config.update("jax_traceback_in_locations_limit", 0)
@@ -83,8 +94,15 @@ def digests(name: str) -> dict:
     texts = {
         "decode": lower(decode, params, vec, vec, vec, *rings,
                         donate_argnums=tuple(range(4, 4 + n))),
-        "prefill": lower(prefill, params, sds((1, 512), jnp.int32), scalar),
+        "prefill": lower(
+            serving_programs(cfg, compute_dtype=bf, decode_kernel="xla")[0],
+            params, sds((1, 512), jnp.int32), scalar),
     }
+    heads = llama.causal_prefill_heads(cfg)
+    for bucket in opts["prefill_buckets"] if heads else ():
+        if prefill_form(bucket, *heads, "pallas") == "xla":
+            texts[f"prefill/{bucket}"] = lower(
+                prefill, params, sds((1, bucket), jnp.int32), scalar)
     if cfg.sparse:
         texts["chunk"] = lower(
             chunk_program(cfg, compute_dtype=bf), params, sds((1, cfg.q_chunk_size), jnp.int32),

@@ -854,8 +854,8 @@ ROUTED_CELLS = {
 }
 
 
-def _engine_program(chip, config, workload, program):
-    """A serve cell's decode step or its largest prefill, published widths
+def _engine_program(chip, config, workload, program, bucket=None):
+    """A serve cell's decode step or a prefill (its largest, or ``bucket``'s), published widths
     and the cell's cuts, lowered as the engine lowers it (kernel ``pallas``,
     counts where routed, caches and state donated) with the bf16 tree the
     engine holds, compiled for the described chip -> (the compiled program,
@@ -867,10 +867,10 @@ def _engine_program(chip, config, workload, program):
     params = _bound(chip, cfg)
     moe = bool(cfg.num_experts)
     if program == "prefill":
-        bucket = max(engine["prefill_buckets"])
+        bucket = bucket or max(engine["prefill_buckets"])
         compiled = (
             jax.jit(lambda p, ids, n: prefill_forward(
-                p, ids, n, cfg, return_moe_counts=moe))
+                p, ids, n, cfg, decode_kernel="pallas", return_moe_counts=moe))
             .lower(
                 params,
                 jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip),
@@ -936,6 +936,49 @@ def test_serving_programs_cast_no_weights(chip, workload, program, monkeypatch):
     assert mem.temp_size_in_bytes < weights / (2 if program == "prefill" else 4)
     assert mem.alias_size_in_bytes >= carried
     assert _program_bytes(compiled) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# a whole-prompt prefill attends through the flash forward kernel (ISSUE 55):
+# where ``decode_kernels.prefill_form`` says "flash" the program holds the
+# kernel and no array over the bucket's positions twice (the scores of every
+# head, [heads, P, P] in float32, were 0.86 GB of temporaries at OLMoE's 2,560
+# and 0.31 GB at GLM's 1,792); the batch cell's buckets, under the floor, keep
+# the XLA form
+# ---------------------------------------------------------------------------
+
+
+def _spans_twice(text: str, rows: int) -> list[str]:
+    """The array shapes of a compiled text with ``rows`` in two dimensions."""
+    shapes = set(re.findall(r"\b\w+\[([\d,]+)\]", text))
+    return sorted(s for s in shapes if s.split(",").count(str(rows)) >= 2)
+
+
+@pytest.mark.parametrize("workload,config,bucket", [
+    ("serve-olmoe-fewshot", "olmoe-1b-7b", 3072),
+    ("serve-glm-flash-agent", "glm-4.7-flash", 1792),
+])
+def test_a_whole_prompt_prefill_holds_the_flash_kernel_and_no_scores(
+    chip, workload, config, bucket, monkeypatch
+):
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    compiled, cfg, _, _ = _engine_program(chip, config, workload, "prefill", bucket)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "odtp_flash_fwd" in text and "tpu_custom_call" in text
+    assert not _spans_twice(text, bucket), _spans_twice(text, bucket)
+    # compiled here: 0.115 GB (OLMoE, 0.86 at the parent's 2,560), 0.087 (GLM, 0.31)
+    assert mem.temp_size_in_bytes < 0.15e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("bucket", [32, 128])
+def test_the_batch_cells_prefill_keeps_the_xla_form(chip, bucket, monkeypatch):
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    compiled, cfg, _, _ = _engine_program(
+        chip, "smollm2-360m", "serve-360m-batch", "prefill", bucket
+    )
+    text = compiled.as_text()
+    assert "odtp_flash_fwd" not in text and "tpu_custom_call" not in text
+    assert _spans_twice(text, bucket)  # the scores, written out: 15 heads x 128 x 128
 
 
 # ---------------------------------------------------------------------------
@@ -1040,11 +1083,12 @@ def test_mla_decode_attention(chip, slots):
     assert mem.alias_size_in_bytes >= ring_bytes and mem.temp_size_in_bytes < ring_bytes // 24
 
 
-def test_glm_prefill_program_at_the_largest_bucket(chip):
+def test_glm_prefill_program_at_the_largest_bucket(chip, monkeypatch):
     """Bucket 1,792 in the rebuilt form: the grouped matmuls over the 8 held
-    experts are in it, it casts no weight, its temporaries (the scores of 20
-    heads over 1,792 x 1,792 among them) stay under half the weights, and it
-    fits beside the resident ring."""
+    experts are in it, it casts no weight, its temporaries (since PR 55 without
+    the scores of 20 heads over 1,792 x 1,792: the flash forward kernel) stay
+    under half the weights, and it fits beside the resident ring."""
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
     cfg, ring = _glm_cell(chip)
     assert (cfg.leading_dense, cfg.held_experts, cfg.num_experts, cfg.latent_row_dim) == (1, 8, 64, 576)
     compiled, _, params, _ = _engine_program(
