@@ -218,6 +218,9 @@ class ServeConfig(BaseModel):
     # prefill compile-size buckets (prompts pad up to the smallest fit;
     # prompts beyond the largest bucket are rejected, not truncated)
     prefill_buckets: list[int] = [64, 256, 1024]
+    # the chunk a prompt is admitted in where the model admits in chunks and
+    # its configuration names none (a stack with sliding layers); 0: none given
+    prefill_chunk: int = 0
     max_queue: int = 1024  # backpressure: submits beyond this are rejected
     # weight hot-swap policy: check every N decode steps; swap when the
     # serving weights lag the trainer's masters by MORE than

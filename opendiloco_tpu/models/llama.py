@@ -21,14 +21,17 @@ import contextlib
 import dataclasses
 import functools
 import json
+import math
 import os
 from typing import Any, Literal, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from opendiloco_tpu.models import mamba
 from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
+    RingPair,
     cache_insert,
     eva_window_rows,
     init_kv_cache,
@@ -40,6 +43,8 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     slot_layer_pages,
 )
 from opendiloco_tpu.ops.attention import (
+    band_block,
+    banded_chunk_attention,
     ring_window_rows,
     tiled_latent_attention,
     window_attention,
@@ -68,6 +73,10 @@ from opendiloco_tpu.ops.decode_kernels import (
 
 # what ``LlamaConfig.layer_types`` may name (``LlamaConfig.layer_kinds``)
 _LAYER_KINDS = ("attention", "mamba", "dense", "sliding")
+# what ``LlamaConfig.rope_yarn`` holds of a published YaRN entry
+_YARN_KEYS = (
+    "factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -263,6 +272,21 @@ class LlamaConfig:
     attention_gate_type: str = "none"
     swa_attention_gate_type: str = "none"
     apply_mla_qkv_lora_rescale: bool = False
+    # Two kinds of grouped-query attention in one stack, the block of a
+    # published ``laguna`` ``config.json``: ``layer_types`` as above, over K and
+    # V rows (no latent). A sliding layer has ``swa_num_attention_heads`` query
+    # heads over the same ``num_key_value_heads`` (``num_attention_heads_per_layer``),
+    # its own rope base ``swa_rope_theta`` and rotated share
+    # ``swa_partial_rotary_factor`` (``rope_parameters`` by kind of layer) and
+    # reads under ``sliding_window_size`` a ``(k, v)`` ring of its own that
+    # wraps; both kinds may carry the head-wise gate. ``rope_yarn``: the full
+    # layers' YaRN (``rope_type`` "yarn": ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``attention_factor``, held as sorted pairs), under which the rotated pairs'
+    # frequencies are stretched along a ramp and cos and sin carry the factor
+    # (``_yarn_frequencies``); None: plain rope
+    swa_partial_rotary_factor: float = 1.0
+    rope_yarn: Optional[tuple] = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -366,12 +390,41 @@ class LlamaConfig:
                     f"layer_types must name {self.num_hidden_layers} layers, each one of "
                     f"{_LAYER_KINDS}; got {len(kinds)}: {sorted(set(kinds))}"
                 )
-            if set(kinds) & {"sliding", "dense"} and not (self.latent and "mamba" not in kinds):
+            if set(kinds) & {"sliding", "dense"} and (
+                "mamba" in kinds or not (self.latent or "sliding" in kinds)
+            ):
                 raise ValueError(
-                    "layer_types 'sliding' and 'dense' are the kinds of a latent stack "
-                    "(kv_lora_rank > 0, no Mamba-2 layers)"
+                    "layer_types 'sliding' and 'dense' are the kinds of a stack of full and "
+                    "sliding attention layers (latent, kv_lora_rank > 0, or grouped-query; "
+                    "no Mamba-2 layers)"
                 )
-        if self.sliding:
+        if self.rope_yarn is not None:
+            scaling = dict(self.rope_yarn)
+            object.__setattr__(self, "rope_yarn", tuple(sorted(scaling.items())))
+            if scaling.get("rope_type", "yarn") != "yarn" or not all(
+                scaling.get(key, 0) > 0 for key in _YARN_KEYS
+            ) or self.latent:
+                raise ValueError(
+                    f"rope_yarn {scaling!r}: written for YaRN over K and V rows "
+                    f"(rope_type 'yarn' and positive {_YARN_KEYS})"
+                )
+        if self.sliding and not self.latent:
+            if (
+                self.cca or self.eva or self.sparse or self.qk_norm or self.qk_norm_per_head
+                or self.position_embedding_type != "rope" or self.mrope_section is not None
+                or self.sliding_window_size <= 0 or self.swa_num_attention_heads <= 0
+                or self.swa_num_attention_heads % self.kv_heads
+                or self.num_attention_heads % self.kv_heads
+                or int(self.head_dim * self.swa_partial_rotary_factor) < 2
+                or int(self.head_dim * self.swa_partial_rotary_factor) % 2
+            ):
+                raise ValueError(
+                    "sliding grouped-query layers need swa_num_attention_heads over the "
+                    "same KV heads, a sliding_window_size and an even rotated part, in a "
+                    "stack of plain rotated attention: no CCA, EVA, indexer, QK norm, "
+                    "'nope' or sectioned rotation"
+                )
+        elif self.sliding:
             if not (
                 self.swa_num_attention_heads and self.swa_q_lora_rank and self.swa_kv_lora_rank
                 and self.swa_qk_nope_head_dim and self.swa_v_head_dim
@@ -385,9 +438,14 @@ class LlamaConfig:
         for gate in (self.attention_gate_type, self.swa_attention_gate_type):
             if gate not in ("none", "headwise"):
                 raise ValueError(f"attention gate {gate!r}: 'none' or 'headwise'")
-        if (self.attention_gate_type != "none" or self.apply_mla_qkv_lora_rescale) and not self.latent:
+        if self.apply_mla_qkv_lora_rescale and not self.latent:
+            raise ValueError("the latents' rescale is written for latent attention")
+        if "headwise" in (self.attention_gate_type, self.swa_attention_gate_type) and (
+            self.cca or self.eva or self.sparse and not self.latent
+        ):
             raise ValueError(
-                "the head-wise gate and the latents' rescale are written for latent attention"
+                "the head-wise gate is written for latent attention and for plain "
+                "grouped-query attention: no CCA, no EVA, no indexer over K and V rows"
             )
         if self.hybrid:
             if self.mamba_n_groups != 1 or not self.mamba_conv_bias or self.mamba_proj_bias:
@@ -721,6 +779,11 @@ class LlamaConfig:
                 known.pop("num_local_experts", None)
             known.setdefault("q_chunk_size", raw.get("q_chunk_size", 512))
             known.setdefault("router_aux_loss_coef", 0.0)
+        if raw.get("model_type") == "laguna":
+            known.update(_laguna_keys(raw, known.get("num_hidden_layers", cls.num_hidden_layers)))
+            if known.get("num_local_experts") == known.get("num_experts"):
+                known.pop("num_local_experts", None)
+            known.setdefault("router_aux_loss_coef", 0.0)
         return cls(**known)
 
     def to_dict(self) -> dict[str, Any]:
@@ -747,6 +810,32 @@ class LlamaConfig:
                 model_type="glm4_moe_lite",
                 n_routed_experts=self.held_experts,
             )
+        if d["rope_yarn"] is not None:
+            d["rope_yarn"] = dict(self.rope_yarn)
+        if self.sliding and not self.latent:
+            kinds = self.layer_kinds
+            heads = {"sliding": self.swa_num_attention_heads}
+            full = {"rope_type": "default", "rope_theta": self.rope_theta,
+                    "partial_rotary_factor": self.partial_rotary_factor}
+            if self.rope_yarn is not None:
+                full.update(dict(self.rope_yarn), rope_type="yarn")
+            d.update(
+                architectures=["LagunaForCausalLM"], model_type="laguna",
+                layer_types=["sliding_attention" if k == "sliding" else "full_attention"
+                             for k in kinds],
+                mlp_layer_types=["dense" if k == "dense" else "sparse" for k in kinds],
+                mlp_only_layers=[i for i, k in enumerate(kinds) if k == "dense"],
+                num_attention_heads_per_layer=[
+                    heads.get(k, self.num_attention_heads) for k in kinds],
+                gating="per-head", gating_types=["per_head"] * len(kinds),
+                sliding_window=self.sliding_window_size,
+                moe_routed_scaling_factor=self.routed_scaling_factor,
+                shared_expert_intermediate_size=self.shared_intermediate_size,
+                rope_parameters={"full_attention": full, "sliding_attention": {
+                    "rope_type": "default", "rope_theta": self.swa_rope_theta,
+                    "partial_rotary_factor": self.swa_partial_rotary_factor}},
+            )
+            return d
         if self.sliding:
             names = {"sliding": "sliding_attention"}
             d.update(
@@ -789,11 +878,96 @@ class LlamaConfig:
         return sum(x.size for x in jax.tree.leaves(shapes(self)))
 
 
+def _laguna_keys(raw: dict, depth: int) -> dict:
+    """A published ``laguna`` config's keys as ``LlamaConfig``'s, for the
+    leading ``depth`` layers: the kinds from ``layer_types``, ``mlp_layer_types``
+    / ``mlp_only_layers`` and ``num_attention_heads_per_layer`` read together and
+    refused where they disagree (a dense layer is a full layer; the full layers
+    have ``num_attention_heads``, the sliding ones one other count); the rope
+    tables by kind from ``rope_parameters`` (YaRN over the full layers' rotated
+    pairs, plain rope in the sliding ones); a gate per head in every layer.
+    What the block is not written for is refused by name and never read past.
+    No key names a scoring function, a selection bias or an aux loss: softmax
+    scores ("greedy"), none, 0."""
+    for key, want in (("attention_bias", False), ("decoder_sparse_step", 1),
+                      ("moe_apply_router_weight_on_input", False),
+                      ("moe_router_logit_softcapping", 0), ("hidden_act", "silu")):
+        if raw.get(key, want) != want:
+            raise ValueError(f"a laguna stack is written for {key} {want!r}; got {raw[key]!r}")
+    gating = [raw.get("gating", "per-head"), *(raw.get("gating_types") or ())[:depth]]
+    if set(gating) - {"per-head", "per_head", True}:
+        raise ValueError(f"a laguna stack is written for a gate per head; got {sorted(map(str, set(gating)))}")
+    names = tuple(raw.get("layer_types") or ())[:depth]
+    ffns = tuple(raw.get("mlp_layer_types") or ("sparse",) * depth)[:depth]
+    heads = tuple(raw.get("num_attention_heads_per_layer") or ())[:depth]
+    only = sorted(i for i in raw.get("mlp_only_layers") or () if i < depth)
+    if len(names) != depth or len(ffns) != depth or len(heads) != depth:
+        raise ValueError(
+            f"a laguna stack names {depth} layers in layer_types, mlp_layer_types and "
+            f"num_attention_heads_per_layer; got {len(names)}, {len(ffns)}, {len(heads)}"
+        )
+    if only != [i for i, f in enumerate(ffns) if f == "dense"] or set(ffns) - {"dense", "sparse"}:
+        raise ValueError(
+            f"mlp_only_layers {only} and mlp_layer_types disagree on the dense layers"
+        )
+    kinds, swa_heads = [], set()
+    for i, (name, ffn, h) in enumerate(zip(names, ffns, heads)):
+        if name not in ("full_attention", "sliding_attention") or (
+            ffn == "dense" and name != "full_attention"
+        ):
+            raise ValueError(
+                "a laguna stack is written for 'full_attention' and 'sliding_attention' "
+                f"layers, a dense FFN under a full one; got {name!r} over {ffn!r} in layer {i}"
+            )
+        if name == "sliding_attention":
+            swa_heads.add(h)
+        elif h != raw.get("num_attention_heads"):
+            raise ValueError(
+                f"layer {i} is a full layer of {h} heads in num_attention_heads_per_layer "
+                f"and num_attention_heads is {raw.get('num_attention_heads')}"
+            )
+        kinds.append("sliding" if name == "sliding_attention" else "dense" if ffn == "dense"
+                     else "attention")
+    if len(swa_heads) > 1:
+        raise ValueError(f"the sliding layers have one head count; got {sorted(swa_heads)}")
+    rope = raw.get("rope_parameters") or {}
+    full, swa = rope.get("full_attention") or {}, rope.get("sliding_attention") or {}
+    if full.get("rope_type", "default") not in ("default", "yarn") or (
+        swa.get("rope_type", "default") != "default"
+    ):
+        raise ValueError(
+            "a laguna stack is written for YaRN or plain rope in the full layers and "
+            f"plain rope in the sliding ones; got {full.get('rope_type')!r}, {swa.get('rope_type')!r}"
+        )
+    keys = dict(
+        layer_types=tuple(kinds),
+        rope_theta=float(full.get("rope_theta", LlamaConfig.rope_theta)),
+        partial_rotary_factor=float(full.get("partial_rotary_factor", 1.0)),
+        swa_rope_theta=float(swa.get("rope_theta", LlamaConfig.swa_rope_theta)),
+        swa_partial_rotary_factor=float(swa.get("partial_rotary_factor", 1.0)),
+        sliding_window_size=int(raw.get("sliding_window", 0)),
+        swa_num_attention_heads=int(swa_heads.pop()) if swa_heads else 0,
+        attention_gate_type="headwise", swa_attention_gate_type="headwise",
+        routed_scaling_factor=float(raw.get("moe_routed_scaling_factor", 1.0)),
+        shared_intermediate_size=int(raw.get("shared_expert_intermediate_size", 0)),
+    )
+    if full.get("rope_type") == "yarn":
+        keys["rope_yarn"] = tuple((key, full[key]) for key in _YARN_KEYS)
+    return keys
+
+
 @functools.lru_cache(maxsize=None)
 def kind_view(cfg: LlamaConfig, kind: str) -> LlamaConfig:
-    """The configuration as the layers of ``kind`` see it: latent geometry is
-    a function of the kind. For a stack without sliding layers that is ``cfg``
-    itself. For one with them, a "sliding" layer's view holds the ``swa_*``
+    """The configuration as the layers of ``kind`` see it: attention's geometry
+    is a function of the kind. For a stack without sliding layers that is
+    ``cfg`` itself. For a grouped-query stack with them, a "sliding" layer's
+    view holds its head count (``swa_num_attention_heads``, over the same KV
+    heads), its rope (``swa_rope_theta``, ``swa_partial_rotary_factor``, no
+    YaRN) and its gate under the top-level names and keeps
+    ``sliding_window_size``; a full layer's ("dense", "attention") keeps the
+    top-level ones and has no window: so ``_qkv``, ``_rotate_heads``, ``_rope``,
+    ``decoder_block``'s gate, ``shapes`` and ``init_params`` serve both kinds.
+    For a latent stack with them, a "sliding" layer's view holds the ``swa_*``
     sizes under the top-level names (heads, ranks, head sizes, rope base, gate),
     keeps ``sliding_window_size`` and has no indexer; a full layer's ("dense",
     "attention") keeps the top-level sizes and the indexer and has no window. So
@@ -804,6 +978,18 @@ def kind_view(cfg: LlamaConfig, kind: str) -> LlamaConfig:
     if not cfg.sliding:
         return cfg
     own = dict(layer_types=None, first_k_dense_replace=0)
+    if not cfg.latent:
+        # grouped-query kinds: a sliding layer's head count, rope (base, rotated
+        # share, no stretching) and gate under the top-level names, and its
+        # window; a full layer's has no window
+        if kind != "sliding":
+            return dataclasses.replace(cfg, **own, sliding_window_size=0)
+        return dataclasses.replace(
+            cfg, **own, num_attention_heads=cfg.swa_num_attention_heads,
+            rope_theta=cfg.swa_rope_theta, rope_yarn=None,
+            partial_rotary_factor=cfg.swa_partial_rotary_factor,
+            attention_gate_type=cfg.swa_attention_gate_type,
+        )
     if kind == "sliding":
         return dataclasses.replace(
             cfg, **own,
@@ -906,15 +1092,20 @@ def shapes(cfg: LlamaConfig) -> dict:
             )
         return leaves
 
-    if cfg.latent:
-        attention = latent_leaves(kind_view(cfg, "attention"))
-    else:
-        attention = {
-            "q_proj": (D, Nh * Dh),
+    def gqa_leaves(view):  # one kind's grouped-query attention and its gate
+        H = view.num_attention_heads
+        leaves = {
+            "q_proj": (D, H * Dh),
             "k_proj": (D, Nkv * Dh),
             "v_proj": (D, Nkv * Dh),
-            "o_proj": (Nh * Dh, D),
+            "o_proj": (H * Dh, D),
         }
+        if view.attention_gate_type == "headwise":
+            leaves["attn_gate"] = (D, H)
+        return leaves
+
+    of_kind = latent_leaves if cfg.latent else gqa_leaves
+    attention = of_kind(kind_view(cfg, "attention"))
     if cfg.qk_norm:
         attention.update(q_norm=(Nh * Dh,), k_norm=(Nkv * Dh,))
     if cfg.qk_norm_per_head:  # one weight a head's value, shared by the heads
@@ -958,7 +1149,7 @@ def shapes(cfg: LlamaConfig) -> dict:
             layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
     elif cfg.sliding:
         kinds = cfg.layer_kinds
-        sliding = latent_leaves(kind_view(cfg, "sliding"))
+        sliding = of_kind(kind_view(cfg, "sliding"))
         layers = {"sliding": stack(kinds.count("sliding"), norms, sliding, ffn)}
         if "dense" in kinds:
             layers["dense"] = stack(kinds.count("dense"), norms, attention, dense_ffn)
@@ -995,7 +1186,7 @@ class Run(NamedTuple):
 
 def mixer_of(kind: str) -> str:
     """A kind of layer's mixer, which is what names its past's store: "mamba",
-    "sliding" (latent attention over a ring of its own that wraps) or
+    "sliding" (attention under a window over a ring of its own that wraps) or
     "attention" (the "dense" kind differs from "attention" in its FFN alone)."""
     return kind if kind in ("mamba", "sliding") else "attention"
 
@@ -1277,8 +1468,34 @@ def _block_norm(cfg: LlamaConfig, h: jax.Array, weight: jax.Array) -> jax.Array:
     return x.astype(weight.dtype) if cfg.fp32_skip_add else x
 
 
+def _yarn_frequencies(d: int, theta: float, scaling: tuple) -> tuple:
+    """YaRN's (arXiv 2309.00071) d / 2 rotation frequencies as a published
+    ``rope_parameters`` entry states them (``LlamaConfig.rope_yarn``), and
+    the factor its cos and sin carry: pair i turns at ``f_i = theta^(-2i/d)``
+    below the ramp (wavelengths that fit ``original_max_position_embeddings``
+    ``beta_fast`` times and more), at ``f_i / factor`` above it (those that fit
+    ``beta_slow`` times and fewer), and in between at ``f_i (1 - r_i) + f_i /
+    factor r_i``, ``r_i = clip((i - low) / (high - low), 0, 1)``; ``low`` and
+    ``high`` the pairs whose wavelengths fit ``beta_fast`` and ``beta_slow``
+    times, rounded down and up and held to [0, d - 1]. -> ([d / 2] float32,
+    ``attention_factor``)."""
+    p = dict(scaling)
+
+    def pair_of(turns):  # the pair whose wavelength fits the original context ``turns`` times
+        fit = p["original_max_position_embeddings"] / (turns * 2 * math.pi)
+        return d * math.log(fit) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(p["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(p["beta_slow"])), d - 1)
+    high = high + 0.001 if high == low else high
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    r = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    return jnp.asarray(f * (1 - r) + f / p["factor"] * r, jnp.float32), p["attention_factor"]
+
+
 def _rope_tables(
-    positions: jax.Array, d: int, theta: float, sections: Optional[tuple] = None
+    positions: jax.Array, d: int, theta: float, sections: Optional[tuple] = None,
+    scaling: Optional[tuple] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(cos, sin) [B, T, 1, D/2] float32 for the given positions.
 
@@ -1288,7 +1505,14 @@ def _rope_tables(
 
     ``positions`` [3, B, T] with ``sections`` (``mrope_section``): frequency
     pair i turns by the first row for i < sections[0], by the second for the
-    next sections[1] pairs, by the third for the rest."""
+    next sections[1] pairs, by the third for the rest. ``scaling``: YaRN's
+    frequencies in the plain ones' place, cos and sin times its factor
+    (``_yarn_frequencies``; the Hugging Face reading: the tables carry it, so
+    the rotated part of q and of k does and the rest of the head does not)."""
+    if scaling is not None:
+        inv_freq, factor = _yarn_frequencies(d, theta, scaling)
+        angles = positions[..., None].astype(jnp.float32) * inv_freq
+        return (jnp.cos(angles) * factor)[:, :, None, :], (jnp.sin(angles) * factor)[:, :, None, :]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [(3,) B, T, D/2]
     if positions.ndim == 3:
@@ -1320,7 +1544,7 @@ def _rope(cfg: LlamaConfig, positions: jax.Array):
         raise ValueError(
             "three rows of positions [3, B, T] need a configuration with an mrope_section"
         )
-    return _rope_tables(positions, d, cfg.rope_theta, cfg.mrope_section)
+    return _rope_tables(positions, d, cfg.rope_theta, cfg.mrope_section, cfg.rope_yarn)
 
 
 def _index_rope(cfg: LlamaConfig, positions: jax.Array):
@@ -1586,6 +1810,27 @@ def latent_attend(cfg: LlamaConfig, attn_fn):
     return rebuilt_attend(cfg, attn_fn)
 
 
+def kinds_attend(cfg: LlamaConfig, attn_fn):
+    """The ``attend(q, k, v)`` of one kind of grouped-query layer in a stack of
+    full and sliding ones (``cfg`` its ``kind_view``) over a whole sequence
+    from position 0 (training, evaluation, a whole-prompt prefill): a full
+    layer's is ``attn_fn`` (scope ``odtp_full_attn``), a sliding layer's each
+    query over the rows of its last ``sliding_window_size`` positions in the
+    banded XLA form (``odtp_swa``)."""
+    if cfg.sliding_window_size:
+        def under_window(q, k, v):
+            with jax.named_scope("odtp_swa"):
+                return window_attention(q, k, v, cfg.sliding_window_size)
+
+        return under_window
+
+    def over_every_row(q, k, v):
+        with jax.named_scope("odtp_full_attn"):
+            return attn_fn(q, k, v)
+
+    return over_every_row
+
+
 def causal_prefill_heads(cfg: LlamaConfig) -> Optional[tuple]:
     """(query heads, KV heads, the keys' head size, the values') of the
     attention that ``prefill_forward`` runs as plain causal attention over a
@@ -1822,6 +2067,8 @@ def _routed_ffn(
             gate, expert = jax.lax.top_k(probs, K)  # [N, K]; ties go to the lower index
         if cfg.norm_topk_prob:
             gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        if cfg.routed_scaling_factor != 1.0:
+            gate = gate * cfg.routed_scaling_factor
 
     if chosen is not None:
         chosen.append(expert.astype(jnp.int32))
@@ -2005,7 +2252,12 @@ def decoder_block(
             if cfg.sparse:  # its attend also scores and chooses: by the indexer's three
                 pool = _index_qkw(cfg, x, layer, *index_rope)
                 index_k = pool[1]
-            attn_out = attend(q, k, v, *pool).reshape(B, T, -1) @ layer["o_proj"]
+            o = attend(q, k, v, *pool)
+            if "attn_gate" in layer:  # one value a head, from the layer's normed input
+                with jax.named_scope("odtp_attn_gate"):
+                    gate = jax.nn.sigmoid(x @ layer["attn_gate"])  # [B, T, Nh]
+                    o = o.reshape(B, T, cfg.num_attention_heads, -1) * gate[..., None].astype(o.dtype)
+            attn_out = o.reshape(B, T, -1) @ layer["o_proj"]
     else:
         k = v = None
         with jax.named_scope("odtp_ssm"):
@@ -2050,6 +2302,8 @@ def training_block(
         attend = sparse_attend(cfg)
     elif cfg.eva:  # its own attention over the sequence: ``attn_fn`` is not asked
         attend = eva_attend(cfg)
+    elif cfg.sliding:  # grouped-query kinds: the full layers' ``attn_fn``, the band in XLA
+        attend = kinds_attend(view, attn_fn)
     else:  # as rows where the configuration and ``attn_fn`` allow
         attend = rows_attend(cfg, attn_fn, cos, sin) or attend
     cfg = view
@@ -2160,6 +2414,12 @@ def forward(
             "attention: training runs it in the rebuilt form through XLA's "
             f"attention (heads of {cfg.qk_head_dim}); the flash and ring kernels "
             "have not been run at that head size"
+        )
+    if cfg.sliding and attn_impl != "xla":
+        raise ValueError(
+            f"attn_impl={attn_impl!r} is refused for a stack with sliding layers: the "
+            "flash and ring kernels know a causal edge and no band (their backward "
+            "neither); training runs the band in XLA's form"
         )
     if attn_impl != "xla":
         refuse_eva(cfg, f"attn_impl={attn_impl!r} (a kernel of causal attention over one run of rows)")
@@ -2386,6 +2646,8 @@ def prefill_forward(
             attend = latent_attend(view, causal)
         elif cfg.sparse:
             attend = sparse_attend(cfg)
+        elif cfg.sliding:
+            attend = kinds_attend(view, causal)
         pooling: list = []  # EVA: the chunks pooled, and each chunk's pooling as stats
         h, out = decoder_block(
             view, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
@@ -2438,7 +2700,9 @@ def prefill_forward(
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = _logits(cfg, cparams, h_last)
     out = [logits[:, 0], *map(_stacked, kept["attention"][:2])]
-    if cfg.sliding:  # the sliding layers' rows, in the values' place
+    if cfg.sliding and not cfg.latent:  # the rows by kind: the full layers' pair, the sliding layers'
+        out[1:3] = RingPair(*out[1:3]), RingPair(*map(_stacked, kept["sliding"]))
+    elif cfg.sliding:  # the sliding layers' rows, in the values' place
         out[2] = _stacked(kept["sliding"][0])
     if cfg.cca or cfg.sparse:
         out.append(_stacked(kept["attention"][2]))
@@ -2599,6 +2863,25 @@ def decode_forward(
             )
             return out
 
+        def over_the_kinds_ring(q, k, v):
+            # grouped-query kinds: a sliding layer over its pair of rings, which
+            # wrap, under the window; a full layer over its own. A slot at
+            # ``lens`` 0 may be one whose prompt is arriving in chunks, and is
+            # written nothing
+            nonlocal ck, cv
+            step = (q[:, 0], k[:, 0], v[:, 0])
+            if view.sliding_window_size:
+                with jax.named_scope("odtp_swa"):
+                    out, *ring = step_attention(
+                        *step, *cv, lens, li, window=view.sliding_window_size, live_only=True
+                    )
+                cv = RingPair(*ring)
+            else:
+                with jax.named_scope("odtp_full_attn"):
+                    out, *ring = step_attention(*step, *ck, lens, li, live_only=True)
+                ck = RingPair(*ring)
+            return out
+
         def over_two_rings(q, k, v, phi, mu):
             nonlocal ck, cv, eva
             out, ck, cv, *eva = eva_attention_step(
@@ -2637,7 +2920,8 @@ def decode_forward(
         h, out = decoder_block(
             view, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
             attend=absorbed if cfg.latent else over_two_rings if cfg.eva
-            else over_chosen_rows if cfg.sparse else attend,
+            else over_chosen_rows if cfg.sparse else over_the_kinds_ring if cfg.sliding
+            else attend,
             past=None if tails is None else tails[li],
         )
         if tails is not None:
@@ -2757,11 +3041,13 @@ def chunk_prefill_forward(
     token read in each layer [L, T] bool."""
     for refuse in (refuse_recurrent, refuse_eva):
         refuse(cfg, "the continued prefill (a prompt's chunks, the suffix behind a reused prefix)")
-    if cfg.latent and not cfg.q_chunk_size:
+    if (cfg.latent or cfg.sliding) and not cfg.q_chunk_size:
         raise ValueError(
             "the continued prefill (a prompt's chunks, the suffix behind a reused prefix) is "
-            "refused for a configuration with latent attention that states no q_chunk_size: "
-            "over latent rows it goes in whole chunks from row 0 (a prompt admitted in chunks)"
+            "refused for a configuration with latent attention or sliding layers that states "
+            "no q_chunk_size: over latent rows and over a ring that wraps it goes in whole "
+            "chunks from row 0 (a prompt admitted in chunks; a serving engine lays its own "
+            "chunk over a configuration that names none)"
         )
     if cfg.sparse != (index_cache is not None):
         raise ValueError("the index ring goes with learned sparse attention, and only with it")
@@ -2774,6 +3060,8 @@ def chunk_prefill_forward(
     live = jnp.arange(C)[None] < count
     T = ring_rows(cache_k)
     tile = min(cfg.q_chunk_size or _SUFFIX_TILE, T)
+    if cfg.sliding and not cfg.latent:  # the chunk is the engine's: the tile stays a tile
+        tile = min(_SUFFIX_TILE, T)
     tile = tile if T % tile == 0 else T  # a ring of no whole tiles: one tile
     seen = jnp.arange(T)[None] <= positions[0][:, None]  # [C, T]: the rows up to a query's own
     dsa = jax.named_scope if cfg.sparse else (lambda name: contextlib.nullcontext())
@@ -2857,15 +3145,52 @@ def chunk_prefill_forward(
             out.counts, last_row[0] if last_row else None, own_keys[0] if own_keys else None
         )
 
+    def kinds_body(carry, layer, li, view, rope):
+        # one kind of grouped-query layer over its own pair of rings: the chunk's
+        # K and V rows go in (a sliding layer's at ``plen`` modulo its ring), then
+        # its queries over the slot's pages: a full layer's a tile at a time up
+        # to the chunk's last row, a sliding layer's over the band alone
+        # (``banded_chunk_attention``; where the ring cannot be cut into its
+        # blocks, every tile under the window's mask: ``swa_chunk_form``)
+        h, ck, cv = carry
+
+        def attend(q, k, v):
+            nonlocal ck, cv
+            if view.sliding_window_size:
+                Tw, window = ring_rows(cv), view.sliding_window_size
+                cv = RingPair(*layer_rows_insert(
+                    *cv, li, slot, k[0], v[0], jnp.mod(plen, Tw), count
+                ))
+                pages = (slot_layer_pages(cv.k, li, slot), slot_layer_pages(cv.v, li, slot))
+                block = band_block(C, Tw, window)
+                with jax.named_scope("odtp_swa"):
+                    if block:
+                        return banded_chunk_attention(q[0], *pages, plen, window, block)[None]
+                    return tiled_sparse_attention(
+                        q[0], *pages, ring_window_rows(positions[0], Tw, window), Tw,
+                        tile if Tw % tile == 0 else Tw,
+                    )[None]
+            ck = RingPair(*layer_rows_insert(*ck, li, slot, k[0], v[0], plen, count))
+            with jax.named_scope("odtp_full_attn"):
+                return tiled_sparse_attention(
+                    q[0], slot_layer_pages(ck.k, li, slot), slot_layer_pages(ck.v, li, slot),
+                    seen, plen + count, tile,
+                )[None]
+
+        h, out = decoder_block(view, h, layer, *rope, live=live, attend=attend)
+        return (h, ck, cv), (out.counts, None, None)
+
     ci = index_cache  # read by every layer as the run found it
     h = _embed(cfg, cparams, ids)
     counts, rows, keys = [], [], []
     for run in layer_runs(cfg):
         of_kind = body
-        if cfg.latent:  # each run under its kind's view and rope tables
+        if cfg.latent or cfg.sliding:  # each run under its kind's view and rope tables
             view = kind_view(cfg, run.kind)
             rope = _rope(view, positions) if run.kind == "sliding" else (cos, sin)
-            of_kind = functools.partial(latent_body, view=view, rope=rope)
+            of_kind = functools.partial(
+                latent_body if cfg.latent else kinds_body, view=view, rope=rope
+            )
         (h, cache_k, cache_v), (c, chose, wrote) = scan_layers(
             cfg, of_kind, (h, cache_k, cache_v), cparams["layers"], run, experts_in_place=True,
         )

@@ -96,10 +96,40 @@ other functions serve what is refused for such a configuration
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
 from opendiloco_tpu.models import mamba
+
+
+class RingPair(NamedTuple):
+    """The K ring and the V ring of one kind of layer, where a stack keeps
+    ``(k, v)`` rings by kind (full and sliding grouped-query layers: two rings
+    of two lifetimes in one cache): a pytree of the two arrays that stands
+    where one ring stands for every other configuration (``cache_k``: the full
+    layers' pair, ``cache_v``: the sliding layers'), so that the programs carry
+    and donate both kinds' rings as they carry one, and whoever asks a ring for
+    its rows, dtype, bytes or device gets the pair's."""
+
+    k: jax.Array
+    v: jax.Array
+
+    @property
+    def shape(self) -> tuple:
+        return self.k.shape
+
+    @property
+    def dtype(self):
+        return self.k.dtype
+
+    @property
+    def nbytes(self) -> int:
+        return self.k.nbytes + self.v.nbytes
+
+    def devices(self):
+        return self.k.devices()
 
 
 def init_kv_cache(
@@ -112,7 +142,18 @@ def init_kv_cache(
     and head size): ``num_slots`` rings of ``max_context`` rows a layer. For
     latent attention ``k`` is the one latent ring and ``v`` None; for a latent
     stack with sliding layers ``k`` is the full layers' ring and ``v`` the
-    sliding layers' (:func:`sliding_ring_rows` rows of their own row width)."""
+    sliding layers' (:func:`sliding_ring_rows` rows of their own row width).
+    For a grouped-query stack with sliding layers ``k`` is the full layers'
+    :class:`RingPair` (K and V rings as long as the context) and ``v`` the
+    sliding layers' (K and V rings of :func:`sliding_ring_rows` rows, which
+    wrap): two ``(k, v)`` rings of two lifetimes, the same KV heads in both."""
+    if cfg.sliding and not cfg.latent:
+        def pair(layers, rows):
+            shape = cache_shape(layers, num_slots, rows, cfg.kv_heads, cfg.head_dim)
+            return RingPair(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+        return {"k": pair(cfg.num_full_layers, max_context),
+                "v": pair(cfg.num_sliding_layers, sliding_ring_rows(cfg))}
     if cfg.latent and cfg.sliding:  # rings by kind: the sliding one in ``v``'s place
         full = cache_shape(cfg.num_full_layers, num_slots, max_context, 1, cfg.latent_row_dim)
         sliding = cache_shape(

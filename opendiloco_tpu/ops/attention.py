@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from opendiloco_tpu.models.ring_cache import (
     layer_pages,
+    ring_rows,
     rows_first,
     write_live_row,
     write_row,
@@ -123,14 +124,23 @@ def decode_step_attention(
     cache_v: jax.Array,
     lens: jax.Array,
     layer,
+    *,
+    window: int = 0,
+    live_only: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One layer's share of a decode step in XLA: the step's rows (k, v [S,
     Kh, D]) written at ring row ``lens % T`` of ``layer``'s pages, then
-    :func:`decode_attention` over them -> (out, cache_k, cache_v). The
+    :func:`decode_attention` over them -> (out, cache_k, cache_v). Under
+    ``window`` the ring wraps and a slot reads the rows of its last ``window``
+    positions (:func:`ring_window_rows`); with ``live_only`` a slot at ``lens``
+    0 is written nothing (``ring_cache.write_live_row``: it may be a slot whose
+    prompt is arriving in chunks). The
     reference of ``decode_kernels.paged_decode_attention``, which has this
     signature, and its per-call fallback."""
-    cache_k, cache_v = write_row(cache_k, cache_v, layer, k, v, lens)
-    out = decode_attention(q, *layer_pages(cache_k, cache_v, layer), lens)
+    write = write_live_row if live_only else write_row
+    cache_k, cache_v = write(cache_k, cache_v, layer, k, v, lens)
+    chosen = ring_window_rows(lens, ring_rows(cache_k), window) if window else None
+    out = decode_attention(q, *layer_pages(cache_k, cache_v, layer), lens, chosen)
     return out, cache_k, cache_v
 
 
@@ -198,11 +208,66 @@ def window_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int) -> j
     0 <= t - s < ``window`` -> [B, T, H, Dv]. Scores and softmax in float32 as
     :func:`xla_attention`'s."""
     t, d = q.shape[1], q.shape[-1]
+    k, v = _repeat_kv(k, q.shape[2]), _repeat_kv(v, q.shape[2])  # grouped-query: Kh < H
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * d**-0.5
     back = jnp.arange(t)[:, None] - jnp.arange(t)[None]
     scores = jnp.where((back >= 0) & (back < window), scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def band_block(chunk: int, ring: int, window: int, block: int = 512) -> int:
+    """The query block :func:`banded_chunk_attention` cuts a chunk of ``chunk``
+    queries into over a ring of ``ring`` rows: ``block`` where it divides the
+    chunk, else the chunk whole; 0 where that cuts neither the ring evenly nor
+    leaves it room for a chunk beside the blocks before it that a window reaches
+    (the caller then keeps the tiled form under a mask)."""
+    b = block if chunk % block == 0 else chunk
+    before = -(-(window - 1) // b) * b
+    return b if ring % b == 0 and chunk + before <= ring else 0
+
+
+def banded_chunk_attention(
+    q: jax.Array, pages_k: jax.Array, pages_v: jax.Array, plen, window: int, block: int,
+) -> jax.Array:
+    """A prefill chunk's attention under a window over one slot's pages of a
+    ring that wraps, visiting the band alone: q [C, H, D] at positions ``plen +
+    i`` (``plen`` traced, a multiple of ``block``), pages_k and pages_v [Kh, D,
+    T] (one layer's pages of the slot, position p at row p % T, the chunk's own
+    rows in them), query t reading rows s with 0 <= t - s < ``window`` -> [C,
+    H, D]. The chunk goes ``block`` queries at a time (:func:`band_block`), and
+    a block reads its own ``block`` rows and the ceil((window - 1) / block)
+    blocks of rows before them, cut from the ring as whole aligned tiles (the
+    ring is whole blocks), under one softmax: the scores held at once are one
+    block's [H, block, rows read], never [H, C, T], and no tile outside the
+    band is touched. Scores and softmax in float32 as :func:`xla_attention`'s;
+    a query head reads its KV head's rows in place."""
+    c, h, d = q.shape
+    kh, _, t = pages_k.shape
+    f32 = jnp.float32
+    before = -(-(window - 1) // block)  # blocks of rows before a block's own
+    span, tiles = (before + 1) * block, t // block
+    qb = jnp.moveaxis(q.reshape(c // block, block, kh, h // kh, d), 1, 3)  # [.., Kh, rep, block, D]
+    first = jnp.asarray(plen, jnp.int32) // block  # the chunk's first block, in blocks of positions
+    # t - s for query row r and key column c of a block's span: static
+    back = before * block + jnp.arange(block)[:, None] - jnp.arange(span)[None]
+    band = (back >= 0) & (back < window)
+
+    def one(xs):
+        j, qj = xs  # [Kh, rep, block, D]
+        at = first + j - before  # the span's first block of positions (may lie before 0)
+        cut = lambda pages: jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(pages, jnp.mod(at + m, tiles) * block, block, 2)
+            for m in range(before + 1)], axis=2)  # [Kh, D, span]
+        kt, vt = cut(pages_k), cut(pages_v)
+        s = jnp.einsum("grqd,gdk->grqk", qj, kt, preferred_element_type=f32) * d**-0.5
+        seen = band & ((at * block + jnp.arange(span)) >= 0)[None]
+        s = jnp.where(seen, s, jnp.finfo(f32).min)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("grqk,gdk->grqd", p, vt, preferred_element_type=f32).astype(q.dtype)
+
+    out = jax.lax.map(one, (jnp.arange(c // block, dtype=jnp.int32), qb))  # [.., Kh, rep, block, D]
+    return jnp.moveaxis(out, 3, 1).reshape(c, h, d)
 
 
 def tiled_latent_attention(

@@ -317,14 +317,15 @@ def _decode_attn_kernel(lens_ref, layer_ref, *rest, slots=1, interpreted=False, 
 def _decode_slot_step(
     lens_ref, layer_ref, *rest,
     scale, block_t, t, num_t, rep, with_stats, eva_ring=None, with_selection=False,
-    slot=None, hand_back=None,
+    slot=None, hand_back=None, window=0, live_only=False,
 ):
     rest = list(rest)
     # under a selection (learned sparse attention): a third prefetched vector,
     # the ring row each slot's new row goes to (-1: the slot holds no sequence
     # and is written nothing), and behind the caches one more operand, the
-    # rows of this tile that the slot's indexer chose
-    at_ref = rest.pop(0) if with_selection else None
+    # rows of this tile that the slot's indexer chose. ``live_only`` brings the
+    # vector alone: a slot at ``lens`` 0 is written nothing
+    at_ref = rest.pop(0) if with_selection or live_only else None
     q_ref, kn_ref, vn_ref, knt_ref, vnt_ref, k_ref, v_ref = rest[:7]
     sel_ref = rest[7] if with_selection else None
     o_ref, ko_ref, vo_ref = rest[7 + with_selection : 10 + with_selection]
@@ -384,7 +385,18 @@ def _decode_slot_step(
             )
 
     lens_s = lens_ref[si]
-    if pooled:
+    if window:
+        # a ring that wraps under a window: the slot reads positions [first,
+        # lens], which lie in the tiles first // block_t .. lens // block_t of
+        # the positions (``num_t`` of them at most: the grid's), tile u of the
+        # positions being tile u % (t // block_t) of the ring; ``base`` is the
+        # position of this grid step's lane 0, past the last live tile for a
+        # step that has none (its block is the last live one's: no DMA)
+        first = jnp.maximum(lens_s - (window - 1), 0)
+        first_tile = jax.lax.div(first, block_t)
+        live_tiles = jax.lax.div(lens_s, block_t) - first_tile + 1
+        base = (first_tile + ti) * block_t
+    elif pooled:
         # EVA's pooled ring, ``pooled`` rows a window: row lens is where the
         # step's row goes and is not read; the rows read are those of the
         # windows before the one it lies in, whole tiles (block_t divides a
@@ -398,12 +410,18 @@ def _decode_slot_step(
     # always live (it is the last live one until the ring wraps); new_at is
     # its lane in this tile, if it lies here
     row_at = jax.lax.rem(lens_s, t) if at_ref is None else at_ref[si]
-    new_at = row_at - ti * block_t
+    if window:  # the row's lane in the tile of the positions that holds ``lens``
+        new_at = jnp.where(row_at < 0, -1, lens_s - base)
+    else:
+        new_at = row_at - ti * block_t
 
-    @pl.when(ti < live_tiles if pooled else ti <= last_live)
+    @pl.when(ti < live_tiles if pooled or window else ti <= last_live)
     def _step():
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, block_t), 1)
-        valid = (ti * block_t + lane <= lens_s) | (lens_s >= t)
+        if window:  # every live tile holds a row of [first, lens]
+            valid = (base + lane >= first) & (base + lane <= lens_s)
+        else:
+            valid = (ti * block_t + lane <= lens_s) | (lens_s >= t)
         at_row = lane == new_at  # nowhere, in a tile that does not hold it
         # the tiles as the cache holds them: the row's own score and value are
         # patched into s and acc, never into a tile. What lies at the row's
@@ -457,7 +475,7 @@ def _decode_slot_step(
     for b in range(block_t // back):
         at = new_at - b * back
         here_it_lies = (at >= 0) & (at < back)
-        if with_selection and b == 0:
+        if (with_selection or live_only) and b == 0:
             # a slot that is written nothing hands its first block back as it was
             here_it_lies = here_it_lies | ((row_at < 0) & (ti == 0))
 
@@ -519,6 +537,8 @@ def paged_decode_attention(
     return_stats: bool = False,
     eva_ring: int | None = None,
     chosen: jax.Array | None = None,
+    window: int = 0,
+    live_only: bool = False,
 ):
     """One layer's share of a decode step against the ring cache: write
     each slot's new row (k, v [S, Nkv, D]) at ring row ``lens % T`` of
@@ -550,7 +570,19 @@ def paged_decode_attention(
     of the selection beside each ``(heads, Dh, block_t)`` tile of K and V. A
     slot at ``lens`` 0 is then written nothing (its first block goes back as
     it was): it may be one whose prompt is arriving in chunks. The XLA
-    stand-in is ``sparse_decode_step_attention``."""
+    stand-in is ``sparse_decode_step_attention``.
+
+    ``window`` (0 for every configuration but one with sliding grouped-query
+    layers): the ring wraps (position p at row p % T, whatever ``lens``) and a
+    slot reads the rows of its last ``window`` positions, [max(lens - window + 1,
+    0), lens]. The grid's last dimension is then the tiles a window can cross
+    (ceil((window - 1) / block_t) + 1, whatever the ring's length), each grid
+    step's block the ring tile that holds its part of those positions; a slot
+    whose window crosses fewer has its other steps skipped and their DMAs
+    elided (the index map holds them to the last live tile), as ``lens`` elides
+    dead tiles without a window. ``live_only``: a slot at ``lens`` 0 is written
+    nothing, as under ``chosen``. The XLA stand-in of both is
+    ``decode_step_attention`` under the same arguments."""
     # Mosaic requires the last two dims of every block to be (8, 128)-
     # aligned OR equal to the array's own dims. The cache's two minor dims
     # are (D, T), so a (d, bt) tile is legal for bt a multiple of 128. The
@@ -562,7 +594,9 @@ def paged_decode_attention(
     t = ring_rows(cache_k)
     h = q.shape[1]
     interp = _interpret(interpret)
-    one_slot_a_step = eva_ring is not None or chosen is not None
+    if window and (eva_ring is not None or chosen is not None or return_stats):
+        raise ValueError("a window goes with neither EVA's rings, a selection nor the stats")
+    one_slot_a_step = eva_ring is not None or chosen is not None or window or live_only
     plan = h % nkv == 0 and decode_plan(
         nkv, d, t, cache_k.dtype.itemsize,
         num_slots=1 if one_slot_a_step else s_, block_t=block_t, interpret=interp,
@@ -575,12 +609,18 @@ def paged_decode_attention(
         )
     if not plan and chosen is not None:
         return sparse_decode_step_attention(q, k, v, chosen, cache_k, cache_v, lens, layer)
+    if not plan and (window or live_only):
+        return decode_step_attention(
+            q, k, v, cache_k, cache_v, lens, layer, window=window, live_only=live_only
+        )
     if not plan:
         res = decode_step_attention(q, k, v, cache_k, cache_v, lens, layer)
         return (*res, None) if return_stats else res
     hb, bt, n = plan
     rep = h // nkv
     num_t = t // bt
+    if window:  # the tiles of positions a window can cross, not the ring's
+        ring_tiles, num_t = num_t, -(-(window - 1) // bt) + 1
     back = min(bt, _LANES)
     # the slots of a grid step: theirs is a leading dimension of its blocks
     # and scratch where they are several, and none where it is the one
@@ -595,6 +635,11 @@ def paged_decode_attention(
 
     # the prefetched vectors: lens, layer and, under a selection, the row written
     def kv_map(si, gi, ti, lens_ref, layer_ref, *_):
+        if window:  # the ring tile of the ti-th tile of positions the window crosses
+            lens_s = lens_ref[si]
+            tile = jax.lax.div(jnp.maximum(lens_s - (window - 1), 0), bt) + ti
+            tile = jnp.minimum(tile, jax.lax.div(lens_s, bt))  # held to the last live one
+            return (layer_ref[0], si, gi, 0, jax.lax.rem(tile, ring_tiles))
         if n > 1:  # the whole rings of the step's slots
             return (layer_ref[0], si, gi, 0, 0)
         # clamp dead blocks to the last live one: unchanged index = no DMA
@@ -653,12 +698,13 @@ def paged_decode_attention(
 
     prefetched = [lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1)]
     selection = []
-    if chosen is not None:
+    if chosen is not None or live_only:
         prefetched.append(jnp.where(lens > 0, jax.lax.rem(lens, t), -1).astype(jnp.int32))
+    if chosen is not None:
         selection = [chosen.astype(jnp.int32).reshape(s_, 1, t)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetched),
-        grid=plan.grid(s_, nkv, t),
+        grid=(*plan.grid(s_, nkv, t)[:2], num_t),
         in_specs=[
             queries,
             row,
@@ -680,6 +726,8 @@ def paged_decode_attention(
             **({} if eva_ring is None else {"eva_ring": int(eva_ring)}),
             **({"with_selection": True} if selection else {}),
             **({} if n == 1 else {"slots": n, "interpreted": interp}),
+            **({"window": int(window)} if window else {}),
+            **({"live_only": True} if live_only and not selection else {}),
         ),
         name="odtp_eva_pooled_attn" if eva_ring else "odtp_paged_decode_attn",
         grid_spec=grid_spec,

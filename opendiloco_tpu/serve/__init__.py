@@ -100,6 +100,7 @@ def build_serving(
         epoch_fn=epoch_fn,
         max_stale_rounds=serve_cfg.max_stale_rounds,
         decode_kernel=decode_kernel,
+        prefill_chunk=serve_cfg.prefill_chunk,
     )
     env_tier = os.environ.get("ODTP_KV_TIER")
     kv_tier_on = bool(int(env_tier)) if env_tier else serve_cfg.kv_tier

@@ -41,8 +41,10 @@ admissions that the step it reads fed on the device, before that step's tokens:
 the prompts enqueued between two steps are read by the call after the one
 that reads the first of the two.
 
-A prompt longer than every bucket, under learned sparse attention, is
-admitted in chunks of ``q_chunk_size`` tokens: ``admit_begin`` names the slot
+A prompt longer than every bucket, under learned sparse attention, and every
+prompt of a stack with sliding layers (no whole prompt is inserted into a ring
+that wraps), is admitted in chunks of ``q_chunk_size`` tokens (the model's
+configuration's or, where that names none, the engine's own: ``prefill_chunk``): ``admit_begin`` names the slot
 and enqueues nothing, each ``admit_chunk`` enqueues one program over the
 slot's rows so far (one compile for every prompt length), and the last chunk's
 leaves the first token on the device as a cold admission's insert does, so the
@@ -101,9 +103,11 @@ from opendiloco_tpu.models.ring_cache import (
     init_ssm_state,
     layer_pages,
     prefix_copy,
+    sliding_ring_rows,
     state_insert,
 )
 from opendiloco_tpu.ops.attention import (
+    band_block,
     decode_step_attention,
     eva_decode_step_attention,
     decode_selection,
@@ -406,7 +410,27 @@ class ServeEngine:
         max_stale_rounds: int = 0,
         decode_kernel: Optional[str] = None,
         adopt_params: bool = False,
+        prefill_chunk: int = 0,
     ):
+        # the chunk a prompt is admitted in is the model's where its
+        # configuration names one (``q_chunk_size``); a stack with sliding
+        # layers whose configuration names none takes the engine's, laid over
+        # the engine's own view of the configuration, which sizes the sliding
+        # rings and the chunk program by it
+        if prefill_chunk and cfg.sliding and not cfg.q_chunk_size:
+            cfg = dataclasses.replace(cfg, q_chunk_size=int(prefill_chunk))
+        elif prefill_chunk and int(prefill_chunk) != cfg.q_chunk_size:
+            raise ValueError(
+                f"prefill_chunk {prefill_chunk} is the engine's to give only where the model's "
+                f"configuration admits in chunks and names none (a stack with sliding layers); "
+                f"this one's q_chunk_size is {cfg.q_chunk_size}"
+            )
+        if cfg.sliding and not cfg.q_chunk_size:
+            raise ValueError(
+                "a stack with sliding layers is admitted in chunks (no whole prompt is inserted "
+                "into a ring that wraps) and its configuration names none: give the engine a "
+                "prefill_chunk"
+            )
         self.cfg = cfg
         self.num_slots = int(num_slots)
         self.max_context = int(max_context)
@@ -478,13 +502,13 @@ class ServeEngine:
         # ``q_chunk_size``, each written as one aligned block, so the ring is
         # whole chunks
         self._index: tuple = ()
-        if cfg.sparse and self.max_context % cfg.q_chunk_size:
+        if (cfg.sparse or cfg.sliding) and self.max_context % cfg.q_chunk_size:
             raise ValueError(
                 f"max_context {self.max_context} is not whole chunks of q_chunk_size "
                 f"{cfg.q_chunk_size}: a prompt admitted in chunks writes each as one "
                 "block of ring rows"
             )
-        if cfg.sliding and not cfg.sparse:
+        if cfg.sliding and cfg.latent and not cfg.sparse:
             raise ValueError(
                 "a latent stack with sliding layers is served with its full layers under "
                 "an indexer (index_topk > 0): its prompts go in chunks beside an index ring"
@@ -522,11 +546,11 @@ class ServeEngine:
         # (the kernel has a tile for both rings or the engine is refused here,
         # never a step that quietly takes the XLA form); a chunk's is the
         # absorbed form in XLA, a tile of ring rows at a time
-        self.swa_cache_resident_bytes = self.cache_v.nbytes if cfg.sliding else 0
+        self.swa_cache_resident_bytes = self.cache_v.nbytes if cfg.sliding and cfg.latent else 0
         self.swa_rows_read = 0
         self.swa_bytes_moved = 0
         self.latent_forms: dict = {}
-        if cfg.sliding:
+        if cfg.sliding and cfg.latent:
             full, swa = self.cache_k.shape, self.cache_v.shape
             plans = {
                 "full": mla_decode_plan(full[3], cfg.kv_lora_rank, full[4]),
@@ -543,6 +567,45 @@ class ServeEngine:
                        "block_t": plans[kind] if self.decode_kernel == "pallas" else 0}
                 for kind in plans
             }
+        # a grouped-query stack with sliding layers: ``cache_k`` is the full
+        # layers' pair of rings, ``cache_v`` the sliding layers', which wrap
+        # (``ring_cache.RingPair``). What the two kinds read of them (always on;
+        # stay 0 without such a stack), over layers: the rows of a decode step's
+        # and a chunk's sliding layers (each slot's window's rows: at most
+        # ``sliding_window_size`` a layer and query) and of their full layers
+        # (each live row), the bytes of K and V rows the calls moved; and which
+        # form each kind's decode step and chunk take ({} without such a stack):
+        # the decode step's by ``decode_kernel`` (the kernel has a plan for both
+        # rings or the engine is refused here, never a step that quietly takes
+        # the XLA form), a chunk's from shapes alone (the full layers' the tiled
+        # XLA form; the sliding layers' the band alone, "banded-xla", where
+        # ``band_block`` cuts the ring, else every tile under the window's mask)
+        self.full_rows_read = 0
+        self.kinds_bytes_moved = 0
+        self.kind_forms: dict = {}
+        self._kinds_row_bytes = 0
+        if cfg.sliding and not cfg.latent:
+            self._kinds_row_bytes = 2 * cfg.kv_heads * cfg.head_dim * self.cache_k.dtype.itemsize
+            size, swa_rows = self.cache_k.dtype.itemsize, self.cache_v.shape[-1]
+            plans = {
+                "full": decode_plan(cfg.kv_heads, cfg.head_dim, self.max_context, size),
+                "sliding": decode_plan(cfg.kv_heads, cfg.head_dim, swa_rows, size),
+            }
+            if self.decode_kernel == "pallas" and not all(plans.values()):
+                raise ValueError(
+                    "decode_kernel 'pallas' has no plan for this stack's rings "
+                    f"({cfg.kv_heads} KV heads of {cfg.head_dim} over {self.max_context} and "
+                    f"{swa_rows} rows: {plans}); decode_kernel 'xla' runs the XLA form"
+                )
+            banded = band_block(cfg.q_chunk_size, swa_rows, cfg.sliding_window_size)
+            chunk_forms = {"full": "tiled-xla", "sliding": "banded-xla" if banded else "tiled-xla"}
+            self.kind_forms = {
+                kind: {"decode": self.decode_kernel, "chunk": chunk_forms[kind],
+                       "block_t": plan.block_t if self.decode_kernel == "pallas" else 0,
+                       "heads": plan.heads if self.decode_kernel == "pallas" else 0}
+                for kind, plan in plans.items()
+            }
+            self.kind_forms["sliding"]["band_block"] = banded
         # the slots' second kind of state: empty for a stack of attention layers
         self._ssm: tuple = ()
         if cfg.hybrid:
@@ -663,7 +726,7 @@ class ServeEngine:
         self._prefill, self._decode, self._admit_insert = programs(False)
         # a prompt admitted in chunks: one program, compiled once
         self._chunk = None
-        if cfg.sparse:
+        if cfg.sparse or cfg.sliding:
             self._chunk_programs = lambda rows: jax.jit(
                 chunk_program(cfg, compute_dtype=cd, rows=rows), donate_argnums=(6, 7, 8, 9)
             )
@@ -738,7 +801,8 @@ class ServeEngine:
         if "chunk" in self._ran:
             ids = sds((1, self.cfg.q_chunk_size), jnp.int32)
             note("chunk", id(chunk), lambda: chunk.lower(
-                params, ids, scalar, scalar, scalar, sds((), jnp.bool_), first, *rings, *beside))
+                params, ids, scalar, scalar, scalar, sds((), jnp.bool_), first, *rings,
+                *(beside or (None,))))
         for bucket in sorted(b for b in self._ran if b != "chunk"):
             ids = sds((1, bucket), jnp.int32)
             note(f"prefill/{bucket}", id(prefill),
@@ -988,9 +1052,11 @@ class ServeEngine:
         )
         t_args = time.perf_counter()
         tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
-            self.params, *args, self._first, self.cache_k, self.cache_v, *self._index
+            self.params, *args, self._first, self.cache_k, self.cache_v,
+            *(self._index or (None,)),
         )
-        self._index = (rest[0],)
+        if self._index:
+            self._index = (rest[0],)
         if self._keeps_rows:
             self.row_choices = rest[1]
         t_dispatch = time.perf_counter()
@@ -1019,6 +1085,12 @@ class ServeEngine:
             attrs.update(self._count_latent(
                 read=chunk.rows_before + chunk.count, written=chunk.count,
                 swa_read=min(chunk.rows_before + chunk.count, self.cache_v.shape[-1]),
+            ))
+        if self._kinds_row_bytes:
+            window = self.cfg.sliding_window_size
+            attrs.update(self._count_kinds(
+                full=chunk.rows_before + chunk.count, written=chunk.count,
+                swa=min(chunk.rows_before, window - 1) + chunk.count,
             ))
         attrs.update(chunk=chunk.index, rows_before=chunk.rows_before)
         self.prefill_chunks += 1
@@ -1208,6 +1280,19 @@ class ServeEngine:
             )
             attrs["swa_rows"] = swa * swa_read
         return attrs
+
+    def _count_kinds(self, full: int, swa: int, written: int) -> dict:
+        """Add one call's traffic with the rings by kind of a grouped-query
+        stack with sliding layers to the engine's counters: ``full`` distinct
+        rows of a full layer's pages and ``swa`` of a sliding layer's that the
+        equations read (a slot's live rows; its window's rows), ``written`` the
+        rows the call wrote a layer -> the same over layers as span attributes
+        (``full_rows``, ``swa_rows``). Its callers ask only for such a stack."""
+        lf, lw = self.cfg.num_full_layers, self.cfg.num_sliding_layers
+        self.full_rows_read += lf * full
+        self.swa_rows_read += lw * swa
+        self.kinds_bytes_moved += (lf * (full + written) + lw * (swa + written)) * self._kinds_row_bytes
+        return {"full_rows": lf * full, "swa_rows": lw * swa}
 
     def _split_counts(self, fetched: np.ndarray, n: int) -> tuple[np.ndarray, dict]:
         """One program's fetched token output -> (its ``n`` tokens, span
@@ -1448,6 +1533,11 @@ class ServeEngine:
         moe.update(self._count_eva(lens))
         if self._index:
             moe.update(self._count_dsa(lens))
+        if self._kinds_row_bytes:
+            moe.update(self._count_kinds(
+                full=int(np.minimum(held + 1, self.max_context).sum()), written=held.size,
+                swa=int(np.minimum(held + 1, self.cfg.sliding_window_size).sum()),
+            ))
         t1 = time.perf_counter()
         # the step's own seconds: not those of the admissions read inside it
         self.stage_seconds["decode"] += (t1 - t0) - (t_fetch - t_dispatch)
@@ -1556,6 +1646,26 @@ class ServeEngine:
             )}
             out["eva_cache_resident_bytes"] = float(self.eva_cache_resident_bytes)
             return self._publish_probe({**out, **self.decode_plan_stats()})
+        if cfg.sliding:
+            # a decode step's attention of one layer of each kind over its pair
+            # of rings, every slot half full: the full layer's, then the sliding
+            # layer's under its window
+            k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
+            step = paged_decode_attention if pallas else decode_step_attention
+            lens = jnp.full((S,), T // 2, jnp.int32)
+            out = {}
+            for kind, ring, heads, window in (
+                ("full", self.cache_k, Nh, 0),
+                ("swa", self.cache_v, cfg.swa_num_attention_heads, cfg.sliding_window_size),
+            ):
+                q1 = jax.random.normal(key, (S, heads, Dh), cd)
+                out[f"decode_{kind}_attn_us"] = _best_us(
+                    lambda q1, k1, lens, rk, rv, window=window: step(
+                        q1, k1, k1, rk, rv, lens, 0, window=window, live_only=True),
+                    q1, k1, lens, ring.k[:1], ring.v[:1], carried=2, iters=iters,
+                )
+            out["decode_attn_us"] = sum(out.values())
+            return self._publish_probe({**out, **self.decode_plan_stats()})
         q1 = jax.random.normal(key, (S, Nh, Dh), cd)
         ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
         lens = jnp.full((S,), T // 2, jnp.int32)
@@ -1591,8 +1701,9 @@ class ServeEngine:
             plans = eva_plans(Nkv, Dh, cfg.window_size, cfg.chunk_size, pooled_rows, size)
             rings = list(zip(plans or (none, none), (T, pooled_rows)))
         elif self.decode_kernel == "pallas" and not cfg.latent:
-            # under a selection (learned sparse attention) a step is one slot's
-            plan = decode_plan(Nkv, Dh, T, size, num_slots=1 if cfg.sparse else S)
+            # under a selection (learned sparse attention) and over rings by
+            # kind a step is one slot's
+            plan = decode_plan(Nkv, Dh, T, size, num_slots=1 if cfg.sparse or cfg.sliding else S)
             rings = [(plan or none, T)]
         plan = rings[0][0]
         out = {
@@ -1607,7 +1718,26 @@ class ServeEngine:
         if cfg.eva:
             out["eva_pooled_plan_heads"] = float(rings[-1][0].heads)
             out["eva_pooled_plan_block_t"] = float(rings[-1][0].block_t)
-        for kind, ring in (("mla", self.cache_k), ("swa", self.cache_v)) if cfg.sliding else ():
+        if self.kind_forms:
+            # each kind of grouped-query layer's pair of rings, its plan of
+            # ``odtp_paged_decode_attn`` (zeros: the XLA form) and the grid steps
+            # it makes a decode step: a sliding layer's grid is the tiles a
+            # window can cross, whatever its ring's length
+            steps = 0.0
+            for kind, ring in (("full", self.cache_k), ("swa", self.cache_v)):
+                form = self.kind_forms["full" if kind == "full" else "sliding"]
+                out[f"decode_plan_{kind}_block_t"] = float(form["block_t"])
+                out[f"decode_plan_{kind}_heads"] = float(form["heads"])
+                out[f"{kind}_ring_rows"] = float(ring.shape[-1])
+                out[f"{kind}_ring_bytes"] = float(ring.nbytes)
+                if form["block_t"]:
+                    tiles = ring.shape[-1] // form["block_t"]
+                    if kind == "swa":
+                        tiles = -(-(cfg.sliding_window_size - 1) // form["block_t"]) + 1
+                    steps += ring.shape[0] * S * (Nkv // form["heads"]) * tiles
+            out["decode_grid_steps"] = steps
+            return out
+        for kind, ring in (("mla", self.cache_k), ("swa", self.cache_v)) if self.latent_forms else ():
             # each kind of latent layer's ring and its tile of ``odtp_mla_decode_attn``
             # (0: the XLA form), all the heads of a slot a grid step
             form = self.latent_forms["full" if kind == "mla" else "sliding"]

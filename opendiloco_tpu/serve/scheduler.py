@@ -198,6 +198,13 @@ class ContinuousBatcher:
                 "slot's past as (k, v) rows, and a token here also keeps an index key, "
                 "in a ring of its own that neither snapshots"
             )
+        if engine.cfg.sliding and (prefix_cache or kv_tier is not None):
+            refused = "prefix_cache" if prefix_cache else "kv_tier"
+            raise ValueError(
+                f"{refused} is refused for a configuration with sliding layers: prefix reuse "
+                "and the host tier copy, cut and restore a slot's past as the rows of one "
+                "ring from row 0, and a sliding layer's ring wraps and keeps a window's rows"
+            )
         if engine.cfg.latent and (prefix_cache or kv_tier is not None):
             refused = "prefix_cache" if prefix_cache else "kv_tier"
             raise ValueError(
@@ -1326,6 +1333,15 @@ class ContinuousBatcher:
                     "swa_rows_read", "swa_bytes_moved", "swa_cache_resident_bytes",
                 )},
                 "forms": self.engine.latent_forms,
+            },
+            # what a grouped-query stack with sliding layers did with its rings
+            # by kind (zeros without one), and which form each kind's decode
+            # step and chunk take
+            "kinds": {
+                **{name: getattr(self.engine, name) for name in (
+                    "full_rows_read", "swa_rows_read", "kinds_bytes_moved",
+                )},
+                "forms": self.engine.kind_forms,
             },
             # what EVA attention did with its two rings (zeros without it)
             "eva": {

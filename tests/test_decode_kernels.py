@@ -710,3 +710,68 @@ def test_engine_plan_of_several_slots_a_step(tiny_cfg, monkeypatch):
     assert _generate(eng, prompt, 6, slot=1) == _generate(
         _make_engine(tiny_cfg, "xla", **kw), prompt, 6, slot=1
     )
+
+
+# --- a window over a ring that wraps; the band of a chunk (PR 56) -------------
+
+
+@pytest.mark.parametrize("rep, window, t, block_t", [
+    (6, 0, 32, 8), (9, 5, 16, 4), (9, 12, 32, 8), (6, 9, 32, 8), (9, 7, 8, 8), (6, 8, 8, 4),
+])
+def test_paged_decode_under_a_window_is_the_xla_form(rep, window, t, block_t):
+    """``paged_decode_attention(window=, live_only=)`` interpreted against
+    ``decode_step_attention`` under the same arguments, at 6 and 9 query heads
+    a KV head: a ring that has not wrapped, one that has several times, a
+    window that crosses one, two and three tiles, a ring of one tile, and a
+    slot at ``lens`` 0, which is written nothing; the rings to the bit."""
+    from opendiloco_tpu.ops.attention import decode_step_attention
+    from opendiloco_tpu.ops.decode_kernels import paged_decode_attention
+
+    rng = np.random.default_rng(rep * 100 + window)
+    s, kh, d, layers = 5, 2, 16, 2
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    ck, cv = draw(layers, s, kh, d, t), draw(layers, s, kh, d, t)
+    for lens in ([0, 3, t - 1, t, 2 * t + 3], [2, 0, 9, 5 * t - 1, 33]):
+        lens = jnp.asarray(lens, jnp.int32)
+        q, k, v = draw(s, kh * rep, d), draw(s, kh, d), draw(s, kh, d)
+        want = decode_step_attention(q, k, v, ck, cv, lens, 1, window=window, live_only=True)
+        got = paged_decode_attention(
+            q, k, v, ck, cv, lens, 1, window=window, live_only=True, block_t=block_t, interpret=True)
+        live = np.asarray(lens) > 0
+        np.testing.assert_allclose(np.asarray(got[0])[live], np.asarray(want[0])[live], atol=2e-6)
+        for ours, theirs, before in zip(got[1:], want[1:], (ck, cv)):
+            np.testing.assert_array_equal(ours, theirs)
+            np.testing.assert_array_equal(ours[:, ~live], before[:, ~live])  # nothing written
+
+
+@pytest.mark.parametrize("rep, chunk, window, ring, block", [
+    (6, 8, 5, 16, 8), (9, 16, 5, 32, 8), (9, 16, 12, 48, 4), (6, 8, 9, 24, 8),
+])
+def test_the_band_of_a_chunk_is_the_masked_tiles(rep, chunk, window, ring, block):
+    """``banded_chunk_attention`` against ``tiled_sparse_attention`` over every
+    tile of the ring under the window's mask, and both against
+    ``window_attention`` over the whole sequence: chunk after chunk into a ring
+    that wraps, at 6 and 9 query heads a KV head, a window within a block and
+    one that reaches three blocks back."""
+    from opendiloco_tpu.models.ring_cache import layer_rows_insert
+    from opendiloco_tpu.ops.attention import (
+        band_block, banded_chunk_attention, ring_window_rows, tiled_sparse_attention,
+        window_attention,
+    )
+
+    assert band_block(chunk, ring, window, block) == block
+    rng = np.random.default_rng(rep + window)
+    kh, d, total = 2, 16, 5 * chunk
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k, v = draw(total, kh * rep, d), draw(total, kh, d), draw(total, kh, d)
+    want = window_attention(q[None], k[None], v[None], window)[0]
+    ck, cv = jnp.zeros((1, 1, kh, d, ring)), jnp.zeros((1, 1, kh, d, ring))
+    for plen in range(0, total, chunk):
+        rows = slice(plen, plen + chunk)
+        ck, cv = layer_rows_insert(ck, cv, 0, 0, k[rows], v[rows], plen % ring, chunk)
+        got = banded_chunk_attention(q[rows], ck[0, 0], cv[0, 0], plen, window, block)
+        reads = ring_window_rows(jnp.arange(plen, plen + chunk), ring, window)
+        masked = tiled_sparse_attention(q[rows], ck[0, 0], cv[0, 0], reads, ring, block)
+        np.testing.assert_allclose(got, want[rows], atol=3e-6)
+        np.testing.assert_allclose(masked, want[rows], atol=3e-6)
+    assert band_block(2048, 4096, 512) == 512 and band_block(8, 12, 5) == 0  # a ring of no whole blocks

@@ -315,10 +315,35 @@ def _two_latent_kinds_model():
     return cfg, params
 
 
+def _two_gqa_kinds_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "laguna", "vocab_size": 256, "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32, "num_hidden_layers": 4,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "sliding_window": 3,
+        "layer_types": ["full_attention", "sliding_attention", "full_attention", "sliding_attention"],
+        "mlp_layer_types": ["dense", "sparse", "sparse", "sparse"], "mlp_only_layers": [0],
+        "num_attention_heads_per_layer": [4, 6, 4, 6], "gating": "per-head",
+        "rope_parameters": {
+            "full_attention": {"rope_theta": 5e5, "rope_type": "yarn", "factor": 8, "beta_fast": 4,
+                               "original_max_position_embeddings": 16, "beta_slow": 1,
+                               "attention_factor": 1.2, "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 1e4, "partial_rotary_factor": 1},
+        },
+        "num_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": True,
+        "moe_routed_scaling_factor": 2.5, "q_chunk_size": 4, "max_position_embeddings": 128,
+    })
+    assert cfg.sliding and not cfg.latent
+    assert cfg.layer_kinds == ("dense", "sliding", "attention", "sliding")
+    params = init_params(jax.random.key(10), cfg)
+    for kind in ("attention", "sliding"):  # no top-k choice near a tie
+        params["layers"][kind]["router"] = params["layers"][kind]["router"] * 25.0
+    return cfg, params
+
+
 @pytest.mark.parametrize(
     "model",
     [_dense_model, _routed_qk_norm_model, _hybrid_model, _latent_routed_model, _zaya_model,
-     _eva_model, _two_latent_kinds_model],
+     _eva_model, _two_latent_kinds_model, _two_gqa_kinds_model],
 )
 def test_the_four_forwards_agree(model):
     """One block under four drivers: in float32 the training forward, the
@@ -357,6 +382,28 @@ def test_the_four_forwards_agree(model):
     close(logits[0], full(prompt)[P - 1])
     tok = int(jnp.argmax(logits[0, : cfg.vocab_size]))
     assert (vs is None) == (cfg.latent and not cfg.sliding)  # the latent rows alone are kept
+    if cfg.sliding and not cfg.latent:
+        # two kinds of grouped-query layer (PR 56): the rows come by kind, each a
+        # (k, v) pair; the sliding layers' rings wrap, so the prompt goes in as
+        # chunks over both pairs of rings and the decode steps over them (the
+        # sliding layers' under the window) give the forward's next rows, past
+        # the point where the ring of 8 rows wraps
+        cache = init_kv_cache(cfg, 2, 32, jnp.float32)
+        ck, cv = cache["k"], cache["v"]
+        assert ks.k.shape == (2, 16, 2, 16) and vs.v.shape == (2, 16, 2, 16) and cv.shape[-1] == 8
+        for plen in range(0, P, 4):
+            count = min(4, P - plen)
+            ids = jnp.asarray([(prompt[plen : plen + count] + [0] * 4)[:4]], jnp.int32)
+            chunked, ck, cv, _ = chunk_prefill_forward(params, ids, plen, count, 1, ck, cv, None, cfg, **f32)
+        close(chunked[0], logits[0])
+        seq, steps = prompt + [tok], []
+        for _ in range(4):
+            tokens, lens = jnp.asarray([0, seq[-1]], jnp.int32), jnp.asarray([0, len(seq) - 1], jnp.int32)
+            step, ck, cv = decode_forward(params, tokens, lens, ck, cv, cfg, **f32)
+            steps.append(step[1])
+            seq.append(int(jnp.argmax(step[1])))
+        close(jnp.stack(steps), full(seq[:-1])[P:])
+        return
     if cfg.sliding:
         # two kinds of latent layer (the stack of two geometries, PR 54): the
         # sliding layers' rows stand in the values' place, and their ring wraps,

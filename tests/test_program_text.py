@@ -1,8 +1,11 @@
-"""The serving programs of three configurations the benchmark measures lower
-to the text they lowered to before dots3-note-prev's layers came (PR 54): the
+"""The serving programs of five configurations the benchmark measures lower
+to the text they lowered to before dots3-note-prev's layers came (PR 54) and
+before Laguna-S-2.1's (PR 56, which added ``serve-olmoe-fewshot``'s and
+``serve-dots3-notes``'s configurations, recorded from its parent ``b964f0b``): the
 decode step, a whole-prompt prefill and the continued prefill (a chunk, or the
-suffix behind a prefix) of ``serve-360m-batch``'s, ``serve-glm-flash-agent``'s
-and ``serve-keye-videoqa``'s configurations, lowered for the TPU at the cells'
+suffix behind a prefix) of ``serve-360m-batch``'s, ``serve-glm-flash-agent``'s,
+``serve-keye-videoqa``'s, ``serve-olmoe-fewshot``'s and ``serve-dots3-notes``'s
+configurations, lowered for the TPU at the cells'
 shapes with the decode kernels in (shapes alone: nothing is compiled or run).
 A model PR that adds work to a shared program hands those cells a reason to
 move; this holds the programs' text to a digest recorded from the parent
@@ -35,6 +38,8 @@ CELLS = {
     "360m": ("smollm2-360m", "serve-360m-batch"),
     "glm": ("glm-4.7-flash", "serve-glm-flash-agent"),
     "keye": ("keye-vl-2.0-30b-a3b", "serve-keye-videoqa"),
+    "olmoe": ("olmoe-1b-7b", "serve-olmoe-fewshot"),
+    "dots3": ("dots3-note-prev", "serve-dots3-notes"),
 }
 # recorded from commit f83e3d2 (PR 52's tree, PR 54's parent)
 PARENT = {
@@ -45,6 +50,11 @@ PARENT = {
             "prefill/768": "0d22cf03759c402a"},
     "keye": {"decode": "c115bdfaa18a765f", "prefill": "fccbb3f9f6585ff8",
              "chunk": "1a2463f7d2aefe97"},
+    # recorded from commit b964f0b (PR 55's tree, PR 56's parent)
+    "olmoe": {"decode": "80be10d23e498851", "prefill": "c01c547432729285",
+              "chunk": "2be097d15cf0759f"},
+    "dots3": {"decode": "4b291eeac5a6b303", "prefill": "643fffdf8cd98c94",
+              "chunk": "1496f43173c333a6"},
 }
 # the engine's methods that the batcher's loop (and a submit) called at that
 # commit while it served two requests of a dense, a latent and an indexed
@@ -54,9 +64,10 @@ _LOOP = (
     "_enqueue_step", "_finish_step", "_read", "_refuse_positions", "_split_counts",
     "admit_enqueue", "maybe_swap", "needs_chunks", "prompt_fits", "staleness", "step_ahead",
 )
+_CHUNKS = ("_close_chunk", "_count_dsa", "admit_begin", "admit_chunk")
 PARENT_CALLS = {
-    "360m": _LOOP, "glm": _LOOP,
-    "keye": (*_LOOP, "_close_chunk", "_count_dsa", "admit_begin", "admit_chunk"),
+    "360m": _LOOP, "glm": _LOOP, "olmoe": _LOOP,
+    "keye": (*_LOOP, *_CHUNKS), "dots3": (*_LOOP, *_CHUNKS),
 }
 
 
@@ -132,6 +143,18 @@ TINY = {
     "keye": dict(hidden_size=32, intermediate_size=64, num_hidden_layers=2, num_attention_heads=4,
                  num_key_value_heads=2, vocab_size=64, index_n_heads=2, index_head_dim=8,
                  index_topk=6, q_chunk_size=8),
+    "olmoe": dict(model_type="olmoe", hidden_size=32, intermediate_size=32, num_hidden_layers=2,
+                  num_attention_heads=4, vocab_size=64, num_experts=8, num_experts_per_tok=2),
+    "dots3": dict(
+        model_type="dots3_note", hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        num_hidden_layers=3, num_attention_heads=4, vocab_size=64,
+        layer_types=["full_attention", "full_attention", "sliding_attention"],
+        first_k_dense_replace=1, q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, index_n_heads=2, index_head_dim=8, index_topk=6,
+        q_chunk_size=8, sliding_window_size=3, swa_num_attention_heads=2, swa_q_lora_rank=16,
+        swa_kv_lora_rank=8, swa_qk_nope_head_dim=8, swa_qk_rope_head_dim=4, swa_v_head_dim=8,
+        n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2, topk_method="noaux_tc",
+    ),
 }
 
 
@@ -162,7 +185,7 @@ def loop_calls(name: str) -> list:
         setattr(engine, attr, wrapped)
     batcher = ContinuousBatcher(engine).start()
     rng = np.random.default_rng(0)
-    lens = (9, 20 if name == "keye" else 12)
+    lens = (9, 20 if cfg.sparse else 12)
     reqs = [batcher.submit(rng.integers(3, 64, n).tolist(), max_new_tokens=4) for n in lens]
     for r in reqs:
         assert r.wait(120) and r.error is None, r.error

@@ -1545,3 +1545,69 @@ def test_dots3_programs_copy_no_ring_and_cast_no_weight(chip, monkeypatch, which
         assert "f32[512,25088]" in text
         assert mem.temp_size_in_bytes < 2e9
         assert not _f32_blocks_over(text, 512e6)
+
+
+# --- Laguna-S-2.1: two kinds of grouped-query attention, rings by kind (PR 56) ----
+
+
+def _laguna_program(chip, monkeypatch, which):
+    import dataclasses
+
+    from opendiloco_tpu.models.ring_cache import init_kv_cache
+    from opendiloco_tpu.serve.engine import chunk_program, serving_programs
+
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: False)
+    cfg, engine = _serve_cell("laguna-s-2.1", "serve-laguna-repoedit")
+    cfg = dataclasses.replace(cfg, q_chunk_size=engine["prefill_chunk"])  # as the engine lays it
+    cache = jax.eval_shape(
+        lambda: init_kv_cache(cfg, engine["num_slots"], engine["max_context"], BF16))
+    rings = _on_chip(chip, (cache["k"], cache["v"]))
+    params = _bound(chip, cfg)
+    vec = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=chip)
+    if which == "decode":
+        _, decode, _, n = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+        assert n == 2
+        lowered = jax.jit(decode, donate_argnums=(4, 5)).lower(params, vec, vec, vec, *rings)
+    else:
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+        ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
+        last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
+        lowered = jax.jit(
+            chunk_program(cfg, compute_dtype=BF16), donate_argnums=(6, 7, 8, 9)
+        ).lower(params, ids, scalar, scalar, scalar, last, vec, *rings, None)
+    return cfg, params, rings, lowered.compile()
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_laguna_programs_copy_no_ring_and_cast_no_weight(chip, monkeypatch, which):
+    """The engine's decode and chunk programs for Laguna-S-2.1 at 12 slots of
+    18,432 rows under chunks of 2,048, published widths: 5,034,052,608
+    parameters held once in bf16; the four rings (the full layers' K and V as
+    long as the context, the sliding layers' of 4,096 rows that wrap) alias the
+    outputs and none is copied; no weight is cast; the program fits the chip.
+    The decode step holds ``odtp_paged_decode_attn`` for both kinds (under the
+    window for the sliding layers: the kernel, not its XLA form); the chunk
+    holds no float32 block over 512 MB (no [72, 2048, 4096] scores: the band's
+    blocks and the full layers' tiles)."""
+    cfg, params, rings, compiled = _laguna_program(chip, monkeypatch, which)
+    assert (cfg.num_full_layers, cfg.num_sliding_layers) == (2, 6)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    flat = jax.tree.leaves(rings)
+    held = sum(x.size * x.dtype.itemsize for x in flat)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 10_068_105_216 and held == 1_811_939_328 + 1_207_959_552
+    print(f"laguna {which}: arguments {mem.argument_size_in_bytes} temporaries "
+          f"{mem.temp_size_in_bytes} aliased {mem.alias_size_in_bytes} "
+          f"program {_program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held
+    assert _program_bytes(compiled) < HBM_BYTES
+    for ring in flat:
+        assert not _ring_copies(text, ring.shape), which
+    assert not _leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+    if which == "decode":
+        assert text.count("odtp_paged_decode_attn") >= 2
+        assert mem.temp_size_in_bytes < 512e6  # the dense layer's FFN, cut from its stack of one
+        assert not _f32_blocks_over(text, 256e6)
+    else:
+        assert mem.temp_size_in_bytes < 2.5e9
+        assert not _f32_blocks_over(text, 512e6)
