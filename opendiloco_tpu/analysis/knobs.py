@@ -215,11 +215,6 @@ KNOBS: tuple[Knob, ...] = (
          "Ring-page tile size for the Pallas decode kernels (must divide "
          "the slot context); unset = the shared block heuristic.",
          doc_default="auto"),
-    Knob("ODTP_DECODE_KERNEL", "str", "", "serve",
-         "Decode-path kernel dispatch: `auto` picks the Pallas serving "
-         "kernels (paged decode attention) on TPU and the stock XLA ops elsewhere; "
-         "`pallas`/`xla` force a path. Token-bit-exact either way.",
-         doc_default="config"),
     Knob("ODTP_KV_HOST_SLOTS", "int", "", "serve",
          "Host KV-tier budget: paused slot pages + prefix-store entries it "
          "may hold at once (page-outs beyond it are declined and the slot "

@@ -227,12 +227,6 @@ class ServeConfig(BaseModel):
     # max_stale_rounds outer rounds (0 = adopt every new round)
     swap_every_steps: int = 16
     max_stale_rounds: int = 0
-    # decode-path kernel dispatch: "auto" picks the Pallas serving kernels
-    # (paged decode attention over a slot's rings) on
-    # TPU backends and the stock XLA ops elsewhere; "pallas" / "xla" force a
-    # path (forced pallas off-TPU runs interpreted — test rigs only).
-    # Token-bit-exact either way.
-    decode_kernel: Literal["auto", "pallas", "xla"] = "auto"
     # shared-prefix KV reuse: prefill a common prompt prefix once and
     # ring-copy its K/V into joining slots
     prefix_cache: bool = False

@@ -42,6 +42,7 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     ring_rows,
     slot_layer_pages,
 )
+from opendiloco_tpu.models.traits import TRAITS, refuse
 from opendiloco_tpu.ops.attention import (
     band_block,
     banded_chunk_attention,
@@ -622,6 +623,12 @@ class LlamaConfig:
         """Does a learned indexer choose the rows each query's attention reads
         (so an index-key ring beside K and V)?"""
         return self.index_topk > 0
+
+    @property
+    def traits(self) -> tuple:
+        """What a slot's past is here beyond the rows of one (k, v) ring, as
+        ``models.traits`` names it: the table of which feature can take it."""
+        return tuple(trait for trait in TRAITS if getattr(self, trait))
 
     @property
     def eva_chunks_per_window(self) -> int:
@@ -1864,48 +1871,6 @@ def latent_expand(cfg: LlamaConfig, o_lat: jax.Array, w_kvb: jax.Array) -> jax.A
     return jnp.einsum("shr,rhv->shv", o_lat, w_uv).astype(o_lat.dtype)
 
 
-def refuse_latent(cfg: LlamaConfig, what: str) -> None:
-    """``what`` reads or writes a slot's past as a (k, v) pair of rows of one
-    head size; a latent cache is one array of latent rows and has neither."""
-    if cfg.latent:
-        raise ValueError(
-            f"{what} is refused for a configuration with latent attention "
-            f"(kv_lora_rank {cfg.kv_lora_rank}): it handles a slot's past as "
-            "(k, v) rows of one head size, and the latent ring holds one row of "
-            f"{cfg.latent_row_dim} values a token, from which k and v are not rebuilt"
-        )
-
-
-def refuse_eva(cfg: LlamaConfig, what: str) -> None:
-    """``what`` takes a slot's ring for its context: rows that can be copied,
-    cut, paged out or extended by a tail. Under EVA attention the ring is one
-    window that restarts, and the past before it is pooled rows and the
-    pooling under way."""
-    if cfg.eva:
-        raise ValueError(
-            f"{what} is refused for a configuration with EVA attention "
-            f"(attention_class 'eva', window_size {cfg.window_size}, chunk_size "
-            f"{cfg.chunk_size}): it handles a slot's past as the rows of one ring, "
-            "and EVA keeps a window of rows that restarts beside a ring of pooled "
-            "chunks and the pooling of the chunk under way, which it neither "
-            "copies nor could un-pool"
-        )
-
-
-def refuse_sparse(cfg: LlamaConfig, what: str) -> None:
-    """``what`` handles a slot's past as (k, v) rows alone, or runs an attention
-    that reads every row before a query; under learned sparse attention a token
-    also keeps an index key, and a query reads the rows its indexer chose."""
-    if cfg.sparse:
-        raise ValueError(
-            f"{what} is refused for a configuration with learned sparse attention "
-            f"(index_topk {cfg.index_topk}, {cfg.index_n_heads} index heads of "
-            f"{cfg.index_head_dim}): a token keeps an index key beside its K and V, in "
-            "a ring of its own that this neither copies nor snapshots, and a query's "
-            "attention reads the rows its indexer chose, which this does not compute"
-        )
-
-
 INDEXER_LEAVES = ("index_q", "index_k", "index_k_norm", "index_k_norm_bias", "index_w")
 
 
@@ -2408,25 +2373,8 @@ def forward(
 
     cparams = jax.tree.map(lambda x: x.astype(compute_dtype), params)
 
-    if cfg.latent and attn_impl != "xla":
-        raise ValueError(
-            f"attn_impl={attn_impl!r} is refused for a configuration with latent "
-            "attention: training runs it in the rebuilt form through XLA's "
-            f"attention (heads of {cfg.qk_head_dim}); the flash and ring kernels "
-            "have not been run at that head size"
-        )
-    if cfg.sliding and attn_impl != "xla":
-        raise ValueError(
-            f"attn_impl={attn_impl!r} is refused for a stack with sliding layers: the "
-            "flash and ring kernels know a causal edge and no band (their backward "
-            "neither); training runs the band in XLA's form"
-        )
     if attn_impl != "xla":
-        refuse_eva(cfg, f"attn_impl={attn_impl!r} (a kernel of causal attention over one run of rows)")
-        refuse_sparse(cfg, f"attn_impl={attn_impl!r} (a kernel of causal attention over every row)")
-    if pp_mesh is not None:
-        refuse_eva(cfg, "the pp pipeline (its stages' attention is the caller's attn_fn over rows)")
-        refuse_sparse(cfg, "the pp pipeline (its stages' attention is the caller's attn_fn over rows)")
+        refuse(cfg, "attn_impl", f"attn_impl={attn_impl!r}")
     if attn_impl == "xla":
         attn_fn = lambda q, k, v: xla_attention(q, k, v, causal=True)
     elif attn_impl == "pallas":
@@ -2547,27 +2495,6 @@ def _serving_boundary(params, compute_dtype):
     its weights in the compute dtype, so its programs hold no cast, and a
     caller with a float32 tree gets the same rounding here, per call."""
     return jax.tree.map(lambda x: x.astype(compute_dtype), params)
-
-
-def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
-    """A slot's past as rows that can be copied, cut or left out is what
-    ``what`` rests on; a Mamba-2 layer's past is one state, which is none
-    of those, and so is what CCA keeps of a slot's last token beside its
-    rows."""
-    if cfg.cca:
-        raise ValueError(
-            f"{what} is refused for a configuration with CCA (cca_time0 "
-            f"{cfg.cca_time0}): it treats a slot's past as cache rows, and CCA's "
-            "projections read the token before through a per-slot state beside "
-            "the ring, which is not rows"
-        )
-    if cfg.hybrid:
-        raise ValueError(
-            f"{what} is refused for a configuration with Mamba-2 layers "
-            f"({cfg.num_mamba_layers} of {cfg.num_hidden_layers}): it treats a "
-            "slot's past as cache rows, and a recurrent state cannot be cut at "
-            "a row"
-        )
 
 
 def _logits(cfg: LlamaConfig, cparams: dict, h: jax.Array) -> jax.Array:
@@ -3039,8 +2966,7 @@ def chunk_prefill_forward(
     With ``return_moe_counts`` the routed FFN's counts over the real tokens
     come after, and with ``return_row_choices`` then the rows the last real
     token read in each layer [L, T] bool."""
-    for refuse in (refuse_recurrent, refuse_eva):
-        refuse(cfg, "the continued prefill (a prompt's chunks, the suffix behind a reused prefix)")
+    refuse(cfg, "continued_prefill")
     if (cfg.latent or cfg.sliding) and not cfg.q_chunk_size:
         raise ValueError(
             "the continued prefill (a prompt's chunks, the suffix behind a reused prefix) is "

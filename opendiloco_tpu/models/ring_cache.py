@@ -91,7 +91,7 @@ from which keys and values are never rebuilt in decode, so the ring is
 ``[L, rows, R + rope]``. :func:`init_kv_cache`, :func:`cache_insert` and
 :func:`write_row` take and return the pair with None in ``v``'s place; the
 other functions serve what is refused for such a configuration
-(``llama.refuse_latent``) and are not written for it.
+(``models.traits``: the table's ``latent``) and are not written for it.
 """
 
 from __future__ import annotations

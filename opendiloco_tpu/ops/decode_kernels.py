@@ -44,12 +44,12 @@ cache-aware decode, arXiv 2309.06180).
   first ``R`` rows, so a live row is read once a layer and step. It checks
   against ``latent_decode_step_attention`` to rounding, not to the bit (the
   row reaches its tile through a one-hot product on the MXU).
-Dispatch: ``ODTP_DECODE_KERNEL=auto|pallas|xla`` (``ServeConfig.
-decode_kernel``). ``auto`` — the default — selects Pallas only when the
-backend is TPU; off-TPU it always resolves to the XLA paths, so CPU rigs
-keep today's exact code. Forcing ``pallas`` off-TPU runs the kernels in
-Pallas interpret mode (slow, but semantically the kernel) — that is how
-the parity tests pin token-bit-exactness on a CPU rig. Shapes a kernel
+Dispatch: by what the code sees. ``ServeEngine`` runs these kernels on a TPU
+backend and the XLA paths elsewhere (:func:`resolve_decode_kernel`), so CPU
+rigs keep the stock XLA code; no option or environment name chooses. A test
+asks the engine for ``"pallas"`` by name and the kernels run in Pallas
+interpret mode (slow, but semantically the kernel): that is how the parity
+tests pin token-bit-exactness on a CPU rig. Shapes a kernel
 cannot tile (head_dim not a multiple of 8, a ring whose rows are not a
 multiple of the 128 lanes) fall
 back to the XLA path per call, mirroring ``flash_attention``'s fallback
@@ -80,22 +80,15 @@ from opendiloco_tpu.ops.attention import (
 )
 from opendiloco_tpu.ops.pallas_util import NEG_INF, pick_block
 
-DECODE_KERNELS = ("auto", "pallas", "xla")
-
-
 def resolve_decode_kernel(spec: str | None = None) -> str:
-    """Resolve a dispatch spec to the concrete path ("pallas" | "xla").
-
-    ``spec`` is ``ServeConfig.decode_kernel`` or the ``ODTP_DECODE_KERNEL``
-    env knob (unset/empty = "auto"). ``auto`` NEVER selects Pallas off-TPU:
-    the CPU rig keeps the stock XLA decode path bit-for-bit."""
-    spec = spec or os.environ.get("ODTP_DECODE_KERNEL") or "auto"
-    if spec not in DECODE_KERNELS:
-        raise ValueError(
-            f"unknown decode kernel {spec!r}; expected one of {DECODE_KERNELS}"
-        )
-    if spec == "auto":
+    """The decode path ("pallas" | "xla") for ``spec``: None, which every
+    caller but a test passes, is the kernels on a TPU backend and the XLA
+    paths elsewhere; a name is itself (a test's way to the kernels
+    interpreted, beside their XLA reference)."""
+    if spec is None:
         return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if spec not in ("pallas", "xla"):
+        raise ValueError(f"unknown decode kernel {spec!r}; expected 'pallas' or 'xla'")
     return spec
 
 

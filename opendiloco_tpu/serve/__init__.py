@@ -86,8 +86,6 @@ def build_serving(
         snapshot_fn = diloco_opt.master_snapshot_wire
         epoch_fn = lambda: diloco_opt.epoch
         epoch = diloco_opt.epoch
-    env_dk = os.environ.get("ODTP_DECODE_KERNEL")
-    decode_kernel = env_dk if env_dk else serve_cfg.decode_kernel
     engine = ServeEngine(
         model_cfg,
         params,
@@ -99,7 +97,6 @@ def build_serving(
         snapshot_fn=snapshot_fn,
         epoch_fn=epoch_fn,
         max_stale_rounds=serve_cfg.max_stale_rounds,
-        decode_kernel=decode_kernel,
         prefill_chunk=serve_cfg.prefill_chunk,
     )
     env_tier = os.environ.get("ODTP_KV_TIER")

@@ -84,10 +84,6 @@ from opendiloco_tpu.models.llama import (
     decode_forward,
     causal_prefill_heads,
     prefill_forward,
-    refuse_eva,
-    refuse_latent,
-    refuse_recurrent,
-    refuse_sparse,
 )
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
@@ -101,27 +97,16 @@ from opendiloco_tpu.models.ring_cache import (
     init_index_cache,
     init_kv_cache,
     init_ssm_state,
-    layer_pages,
     prefix_copy,
-    sliding_ring_rows,
     state_insert,
 )
-from opendiloco_tpu.ops.attention import (
-    band_block,
-    decode_step_attention,
-    eva_decode_step_attention,
-    decode_selection,
-    latent_decode_step_attention,
-    sparse_decode_step_attention,
-)
+from opendiloco_tpu.models.traits import refuse
+from opendiloco_tpu.ops.attention import band_block
 from opendiloco_tpu.ops.decode_kernels import (
     DecodePlan,
     decode_plan,
-    eva_decode_attention,
     eva_plans,
     eva_prefill_form,
-    mla_decode_attention,
-    paged_decode_attention,
     mla_decode_plan,
     prefill_form,
     resolve_decode_kernel,
@@ -136,23 +121,6 @@ def _fresh_copy(leaves, dtype):
     # idiom as the outer plane). The rounding is ``astype``'s, the one the
     # forwards' boundary applies to a tree that has not met it yet
     return [x.astype(dtype) + jnp.zeros((), dtype) for x in leaves]
-
-
-def _best_us(fn, *argv, carried: int = 0, iters: int = 3) -> float:
-    """Best of ``iters`` timed calls of ``jit(fn)`` after one that compiles,
-    in microseconds; the last ``carried`` arguments are donated and taken
-    from the call's trailing outputs each time."""
-    keep = len(argv) - carried
-    f = jax.jit(fn, donate_argnums=tuple(range(keep, len(argv))))
-    best = float("inf")
-    for i in range(1 + max(1, int(iters))):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(f(*argv))
-        if i:  # the first call compiled
-            best = min(best, time.perf_counter() - t0)
-        if carried:
-            argv = (*argv[:keep], *out[-carried:])
-    return best * 1e6
 
 
 def _with_counts(tok, counts):
@@ -442,8 +410,9 @@ class ServeEngine:
         self.epoch_fn = epoch_fn
         self.max_stale_rounds = int(max_stale_rounds)
 
-        # "auto"/None resolves to pallas only on TPU backends; tests force
-        # "pallas" explicitly and the kernels run interpreted off-TPU
+        # None, which every caller but a test passes, is the kernels on a TPU
+        # backend and the XLA forms elsewhere; a test asks for "pallas" by name
+        # and the kernels run interpreted beside their XLA reference
         self.decode_kernel = resolve_decode_kernel(decode_kernel)
         leaves, self._treedef = jax.tree.flatten(params)
         self._shapes = [tuple(x.shape) for x in leaves]
@@ -556,12 +525,10 @@ class ServeEngine:
                 "full": mla_decode_plan(full[3], cfg.kv_lora_rank, full[4]),
                 "sliding": mla_decode_plan(swa[3], cfg.swa_kv_lora_rank, swa[4]),
             }
-            if self.decode_kernel == "pallas" and not all(plans.values()):
-                raise ValueError(
-                    "decode_kernel 'pallas' has no tile for this stack's latent rings "
-                    f"(full {full[3:]}, sliding {swa[3:]} as (row, ring rows): {plans}); "
-                    "decode_kernel 'xla' runs the XLA form"
-                )
+            self._need_plans(
+                all(plans.values()), "tile for this stack's latent rings",
+                f"full {full[3:]}, sliding {swa[3:]} as (row, ring rows): {plans}",
+            )
             self.latent_forms = {
                 kind: {"decode": self.decode_kernel, "chunk": "absorbed-xla",
                        "block_t": plans[kind] if self.decode_kernel == "pallas" else 0}
@@ -591,12 +558,11 @@ class ServeEngine:
                 "full": decode_plan(cfg.kv_heads, cfg.head_dim, self.max_context, size),
                 "sliding": decode_plan(cfg.kv_heads, cfg.head_dim, swa_rows, size),
             }
-            if self.decode_kernel == "pallas" and not all(plans.values()):
-                raise ValueError(
-                    "decode_kernel 'pallas' has no plan for this stack's rings "
-                    f"({cfg.kv_heads} KV heads of {cfg.head_dim} over {self.max_context} and "
-                    f"{swa_rows} rows: {plans}); decode_kernel 'xla' runs the XLA form"
-                )
+            self._need_plans(
+                all(plans.values()), "plan for this stack's rings",
+                f"{cfg.kv_heads} KV heads of {cfg.head_dim} over {self.max_context} and "
+                f"{swa_rows} rows: {plans}",
+            )
             banded = band_block(cfg.q_chunk_size, swa_rows, cfg.sliding_window_size)
             chunk_forms = {"full": "tiled-xla", "sliding": "banded-xla" if banded else "tiled-xla"}
             self.kind_forms = {
@@ -636,15 +602,12 @@ class ServeEngine:
         if cfg.eva:
             window, chunk = cfg.window_size, cfg.chunk_size
             pooled = eva_pooled_rows(cfg, self.max_context)
-            if self.decode_kernel == "pallas" and not eva_plans(
-                cfg.kv_heads, cfg.head_dim, window, chunk, pooled, self.cache_k.dtype.itemsize
-            ):
-                raise ValueError(
-                    "decode_kernel 'pallas' has no plan for EVA's rings at these shapes "
-                    f"({cfg.kv_heads} KV heads of {cfg.head_dim}, a window of {window} rows "
-                    f"and {pooled} pooled rows, {window // chunk} a window); decode_kernel "
-                    "'xla' runs the XLA form"
-                )
+            self._need_plans(
+                eva_plans(cfg.kv_heads, cfg.head_dim, window, chunk, pooled, self.cache_k.dtype.itemsize),
+                "plan for EVA's rings at these shapes",
+                f"{cfg.kv_heads} KV heads of {cfg.head_dim}, a window of {window} rows "
+                f"and {pooled} pooled rows, {window // chunk} a window",
+            )
             self.eva_forms = {
                 "decode": self.decode_kernel,
                 "prefill": eva_prefill_form(window, cfg.head_dim),
@@ -759,6 +722,15 @@ class ServeEngine:
         self._ran: set = set()
         self._recipes = obs.programs.Recipes()
         obs.programs.register(self)
+
+    def _need_plans(self, plans, what: str, shapes: str) -> None:
+        """Under the kernels every ring has a plan or the engine is refused
+        here: never a step that quietly takes the XLA form."""
+        if self.decode_kernel == "pallas" and not plans:
+            raise ValueError(
+                f"decode_kernel 'pallas' has no {what} ({shapes}); decode_kernel 'xla' "
+                "runs the XLA form"
+            )
 
     def program_recipes(self):
         """How to lower again, at the engine's own shapes, the programs a cold
@@ -916,11 +888,8 @@ class ServeEngine:
                 adm = self.admit_enqueue(slot, prompt)
             logits = self._read(adm, row=True)
             return adm.token, logits
+        refuse(self.cfg, "prefix_reuse")
         self._bucket_of(n)
-        refuse_sparse(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
-        refuse_eva(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
-        refuse_recurrent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
-        refuse_latent(self.cfg, "prefix reuse (a continued prefill over copied cache rows)")
         t0 = time.perf_counter()
         if from_host:
             hk, hv, plen = host_prefix
@@ -1365,10 +1334,7 @@ class ServeEngine:
         iteration so the transfer overlaps the next decode step instead
         of blocking the loop. The gather is by value: the slot can be
         re-tenanted immediately."""
-        refuse_sparse(self.cfg, "the host tier's page-out")
-        refuse_eva(self.cfg, "the host tier's page-out")
-        refuse_recurrent(self.cfg, "the host tier's page-out")
-        refuse_latent(self.cfg, "the host tier's page-out")
+        refuse(self.cfg, "page_out")
         t0 = time.perf_counter()
         pk, pv = self._fetch_pages(
             self.cache_k, self.cache_v, jnp.int32(slot), self.page_rows(rows)
@@ -1386,10 +1352,7 @@ class ServeEngine:
         of ``slot`` are rewritten from the host arrays. Dispatch is
         async — the next decode step queues behind it on-stream, so the
         scheduler thread never blocks on the transfer."""
-        refuse_sparse(self.cfg, "the host tier's page-in")
-        refuse_eva(self.cfg, "the host tier's page-in")
-        refuse_recurrent(self.cfg, "the host tier's page-in")
-        refuse_latent(self.cfg, "the host tier's page-in")
+        refuse(self.cfg, "page_in")
         t0 = time.perf_counter()
         self.cache_k, self.cache_v = self._insert(
             self.cache_k, self.cache_v,
@@ -1554,142 +1517,12 @@ class ServeEngine:
         self._count_phases("decode", (*front, (t_fetch, t_fetched)), tr)
         return tok
 
-    # -- kernel attribution -------------------------------------------------
-
-    def kernel_probe(self, iters: int = 3) -> dict:
-        """Time the decode step's attention in isolation on the engine's live
-        shapes and publish it as a gauge (serve_decode_attn_us), per dispatch
-        path; and, for a keys-and-values ring, the decode kernel's plan at
-        those shapes (serve_decode_plan_heads, _block_t, _block_diagonal,
-        _slots) and the grid steps it makes a decode step
-        (serve_decode_grid_steps).
-
-        Best-of-``iters`` steady-state timings on the resolved path
-        (``self.decode_kernel``), beside :meth:`decode_plan_stats`."""
-        cfg, cd = self.cfg, self.compute_dtype
-        S, T = self.num_slots, self.max_context
-        Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-        key = jax.random.PRNGKey(0)
-        pallas = self.decode_kernel == "pallas"
-        if cfg.latent:
-            # the one attention of a latent cache: the absorbed decode step
-            ql = jax.random.normal(key, (S, Nh, cfg.latent_row_dim), cd)
-
-            def _latent(ql, lens, cache):
-                step = mla_decode_attention if pallas else latent_decode_step_attention
-                return step(
-                    ql, ql[:, 0], cache, lens, 0,
-                    scale=cfg.qk_head_dim**-0.5, value_dim=cfg.kv_lora_rank,
-                )
-
-            beside = {}
-            if self._index:  # the indexer's and the chunks' counters, as gauges
-                beside = {name: float(getattr(self, name)) for name in (
-                    "index_cache_resident_bytes", "dsa_rows_scored", "dsa_rows_selected",
-                    "dsa_index_bytes_read", "dsa_kv_bytes_read", "prefill_chunks",
-                    "prefill_chunk_tokens",
-                )}
-            return self._publish_probe({"decode_attn_us": _best_us(
-                _latent, ql, jnp.full((S,), T // 2, jnp.int32), self.cache_k[:1],
-                carried=1, iters=iters,
-            ), **beside, **self.decode_plan_stats()})
-        if cfg.sparse:
-            # a decode step's indexing and attention over the three rings of one
-            # layer, every slot three quarters full
-            q1 = jax.random.normal(key, (S, Nh, Dh), cd)
-            k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
-            qi = jax.random.normal(key, (S, cfg.index_n_heads, cfg.index_head_dim), cd)
-
-            def _sparse(q1, k1, qi, lens, ck, cv, ci):
-                rows = decode_selection(qi, qi[..., 0], qi[:, 0], ci[0], lens, cfg.index_topk)
-                if pallas:
-                    out = paged_decode_attention(q1, k1, k1, ck, cv, lens, 0, chosen=rows)
-                else:
-                    out = sparse_decode_step_attention(q1, k1, k1, rows, ck, cv, lens, 0)
-                return (*out, ci)
-
-            return self._publish_probe({
-                "decode_attn_us": _best_us(
-                    _sparse, q1, k1, qi, jnp.full((S,), 3 * T // 4, jnp.int32),
-                    self.cache_k[:1], self.cache_v[:1], self._index[0][:1],
-                    carried=3, iters=iters,
-                ),
-                "index_cache_resident_bytes": float(self.index_cache_resident_bytes),
-                "dsa_rows_scored": float(self.dsa_rows_scored),
-                "dsa_rows_selected": float(self.dsa_rows_selected),
-                "dsa_index_bytes_read": float(self.dsa_index_bytes_read),
-                "dsa_kv_bytes_read": float(self.dsa_kv_bytes_read),
-                "prefill_chunks": float(self.prefill_chunks),
-                "prefill_chunk_tokens": float(self.prefill_chunk_tokens),
-                **self.decode_plan_stats(),
-            })
-        if cfg.eva:
-            # a decode step's attention over both rings of the one layer, at
-            # a position half-way through the second window, and the kernel's
-            # plan for each
-            q1 = jax.random.normal(key, (S, Nh, Dh), cd)
-            k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
-            step = eva_decode_attention if pallas else eva_decode_step_attention
-            window, chunk = cfg.window_size, cfg.chunk_size
-
-            def _eva(q1, k1, lens, ck, cv, pk, pv, stats):
-                return step(
-                    q1, k1, k1, self.params["layers"]["adaptive_phi"][0],
-                    self.params["layers"]["adaptive_mu_k"][0], ck, cv, pk, pv, stats,
-                    lens, 0, window=window, chunk=chunk,
-                )
-
-            at = min(T - 1, window + window // 2)
-            out = {"decode_attn_us": _best_us(
-                _eva, q1, k1, jnp.full((S,), at, jnp.int32), self.cache_k[:1],
-                self.cache_v[:1], *(x[:1] for x in self._eva), carried=5, iters=iters,
-            )}
-            out["eva_cache_resident_bytes"] = float(self.eva_cache_resident_bytes)
-            return self._publish_probe({**out, **self.decode_plan_stats()})
-        if cfg.sliding:
-            # a decode step's attention of one layer of each kind over its pair
-            # of rings, every slot half full: the full layer's, then the sliding
-            # layer's under its window
-            k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
-            step = paged_decode_attention if pallas else decode_step_attention
-            lens = jnp.full((S,), T // 2, jnp.int32)
-            out = {}
-            for kind, ring, heads, window in (
-                ("full", self.cache_k, Nh, 0),
-                ("swa", self.cache_v, cfg.swa_num_attention_heads, cfg.sliding_window_size),
-            ):
-                q1 = jax.random.normal(key, (S, heads, Dh), cd)
-                out[f"decode_{kind}_attn_us"] = _best_us(
-                    lambda q1, k1, lens, rk, rv, window=window: step(
-                        q1, k1, k1, rk, rv, lens, 0, window=window, live_only=True),
-                    q1, k1, lens, ring.k[:1], ring.v[:1], carried=2, iters=iters,
-                )
-            out["decode_attn_us"] = sum(out.values())
-            return self._publish_probe({**out, **self.decode_plan_stats()})
-        q1 = jax.random.normal(key, (S, Nh, Dh), cd)
-        ck, cv = layer_pages(self.cache_k, self.cache_v, 0)  # live ring pages
-        lens = jnp.full((S,), T // 2, jnp.int32)
-        k1 = jax.random.normal(key, (S, Nkv, Dh), cd)
-
-        def _attn(q1, k1, lens, ck, cv):
-            # a decode step's attention over a cache of the one layer: the
-            # row write and the read, the caches handed on to the next call
-            step = paged_decode_attention if pallas else decode_step_attention
-            return step(q1, k1, k1, ck, cv, lens, 0)
-
-        out = {
-            "decode_attn_us": _best_us(
-                _attn, q1, k1, lens, ck[None], cv[None], carried=2, iters=iters
-            ),
-        }
-        return self._publish_probe({**out, **self.decode_plan_stats()})
-
     def decode_plan_stats(self) -> dict:
         """Which form of ``odtp_paged_decode_attn`` the engine's shapes take
         (``decode_kernels.decode_plan``: KV heads, ring rows and slots a grid
         step; EVA's pooled ring's beside the window's) and the grid steps
         that makes a decode step over all attention layers. From shapes
-        alone, so always there (``GET /stats``, ``kernel_probe``); zeros where
+        alone, so always there (``GET /stats``); zeros where
         that kernel does not run: the XLA path, a latent ring."""
         cfg = self.cfg
         layers, S, Nkv, Dh, T = self.cache_k.shape
@@ -1748,14 +1581,6 @@ class ServeEngine:
                 out["decode_grid_steps"] += float(
                     ring.shape[0] * S * (ring.shape[-1] // form["block_t"])
                 )
-        return out
-
-    def _publish_probe(self, out: dict) -> dict:
-        for name, us in out.items():
-            obs.gauge(f"serve_{name}", us)
-        obs.gauge(
-            "serve_decode_kernel_pallas", 1.0 if self.decode_kernel == "pallas" else 0.0
-        )
         return out
 
     # -- weight hot-swap ---------------------------------------------------

@@ -47,6 +47,7 @@ import numpy as np
 
 from opendiloco_tpu import obs
 from opendiloco_tpu.models.ring_cache import ring_live_rows
+from opendiloco_tpu.models.traits import refuse
 from opendiloco_tpu.obs import reqtrace
 from opendiloco_tpu.serve.engine import PREV_TOKEN_ON_DEVICE, Admission, ServeEngine
 from opendiloco_tpu.serve.kvcache import (
@@ -166,53 +167,13 @@ class ContinuousBatcher:
         tier_min_resident_steps: int = 2,
     ):
         self.engine = engine
-        if engine.cfg.hybrid and (prefix_cache or kv_tier is not None):
-            refused = "prefix_cache" if prefix_cache else "kv_tier"
-            raise ValueError(
-                f"{refused} is refused for a configuration with Mamba-2 layers: "
-                "prefix reuse and the host tier copy, cut and restore a slot's "
-                "past as cache rows, and a recurrent state is not rows"
-            )
-        if engine.cfg.cca and (prefix_cache or kv_tier is not None):
-            refused = "prefix_cache" if prefix_cache else "kv_tier"
-            raise ValueError(
-                f"{refused} is refused for a configuration with CCA: prefix reuse "
-                "and the host tier copy, cut and restore a slot's past as cache "
-                "rows, and CCA keeps a state of the slot's last token beside them "
-                "that neither snapshots"
-            )
-        if engine.cfg.eva and (prefix_cache or kv_tier is not None):
-            refused = "prefix_cache" if prefix_cache else "kv_tier"
-            raise ValueError(
-                f"{refused} is refused for a configuration with EVA attention: "
-                "prefix reuse and the host tier copy, cut and restore a slot's "
-                "past as the rows of one ring, and EVA's ring is one window that "
-                "restarts, beside pooled chunks and the pooling of the chunk under "
-                "way that neither snapshots"
-            )
-        if engine.cfg.sparse and (prefix_cache or kv_tier is not None):
-            refused = "prefix_cache" if prefix_cache else "kv_tier"
-            raise ValueError(
-                f"{refused} is refused for a configuration with learned sparse "
-                "attention: prefix reuse and the host tier copy, cut and restore a "
-                "slot's past as (k, v) rows, and a token here also keeps an index key, "
-                "in a ring of its own that neither snapshots"
-            )
-        if engine.cfg.sliding and (prefix_cache or kv_tier is not None):
-            refused = "prefix_cache" if prefix_cache else "kv_tier"
-            raise ValueError(
-                f"{refused} is refused for a configuration with sliding layers: prefix reuse "
-                "and the host tier copy, cut and restore a slot's past as the rows of one "
-                "ring from row 0, and a sliding layer's ring wraps and keeps a window's rows"
-            )
-        if engine.cfg.latent and (prefix_cache or kv_tier is not None):
-            refused = "prefix_cache" if prefix_cache else "kv_tier"
-            raise ValueError(
-                f"{refused} is refused for a configuration with latent attention: "
-                "prefix reuse and the host tier copy, cut and restore a slot's "
-                "past as (k, v) rows, and the latent ring is one array of latent "
-                "rows with no continued prefill over it"
-            )
+        # prefix reuse and the host tier copy, cut and restore a slot's past
+        # as the rows of one ring: whether this configuration's is that is the
+        # table's to say (``models.traits``)
+        if prefix_cache:
+            refuse(engine.cfg, "prefix_reuse", "prefix_cache")
+        if kv_tier is not None:
+            refuse(engine.cfg, "page_out", "kv_tier")
         self.max_queue = int(max_queue)
         self.swap_every_steps = max(1, int(swap_every_steps))
         self.gauge_every_steps = max(1, int(gauge_every_steps))
@@ -226,11 +187,6 @@ class ContinuousBatcher:
         self.kv_tier = kv_tier
         self.tier_quantum_steps = max(1, int(tier_quantum_steps))
         self.tier_min_resident_steps = max(1, int(tier_min_resident_steps))
-        # the one-time kernel probe feeds gauges alone: it runs only in a
-        # process whose tracer ODTP_OBS had armed by now, never because a
-        # capture arms one later (a probe compiles, and a capture is a stretch
-        # in which nothing may)
-        self._kernel_probed = obs.tracer() is None
         self.slots = SlotAllocator(engine.num_slots)
         self._active: dict[int, _Slot] = {}  # slot id -> state
         # (slot, tenant) admitted since the last decode step was enqueued, whose
@@ -1181,15 +1137,6 @@ class ContinuousBatcher:
             # be no rate of now: the next armed call starts the mark anew
             self._rate_mark = None
             return
-        if not self._kernel_probed:
-            # one-time per-kernel isolation probe on the live shapes (the
-            # path and shapes are fixed per process, so once is enough);
-            # attribution only — never take down the serving loop
-            self._kernel_probed = True
-            try:
-                self.engine.kernel_probe()
-            except Exception:
-                obs.count("serve_kernel_probe_errors")
         lat = np.asarray(self._latencies, np.float64)
         if lat.size:
             obs.gauge("serve_p50_ms", float(np.percentile(lat, 50)) * 1e3)
@@ -1309,8 +1256,7 @@ class ContinuousBatcher:
             "steps_ahead": self.engine.steps_ahead,
             "step_drains": dict(self.step_drains),
             # what a grid step of the decode kernel holds at the engine's shapes,
-            # and the grid steps that makes a decode step (zeros: no such kernel),
-            # under the names of ``kernel_probe``'s gauges
+            # and the grid steps that makes a decode step (zeros: no such kernel)
             "decode_plan": {
                 f"serve_{name}": int(value)
                 for name, value in self.engine.decode_plan_stats().items()

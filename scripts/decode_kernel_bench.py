@@ -30,7 +30,7 @@ grid) and, on a TPU, checks the kernel against
 ``decode_step_attention`` there (outputs to rounding, both caches bit for
 bit, half the slots wrapped) and gives the time of a call over a
 cache of one layer's size that the calls hand on (donated, as the engine's
-decode scan and ``ServeEngine.kernel_probe`` do): ``pallas_us`` and
+decode scan does): ``pallas_us`` and
 ``xla_us``, each the difference of a program of 128 calls and one of 32,
 over 96, so that starting a program and waiting for it is not in the number.
 
@@ -129,7 +129,7 @@ def _parity_and_skip(doc: dict, *, small: bool) -> None:
         "xla_us": _timeit(jax.jit(xla_step), q1, k1, v1, ck, cv, lens),
     }
     if on_tpu:
-        # both arms alike: the caches donated and handed on, as kernel_probe does
+        # both arms alike: the caches donated and handed on, as the decode scan does
         shape = cache_shape(8, S, T, Nkv, D)
         doc["decode_attention"]["pallas_us"] = _us_a_call(
             functools.partial(paged_decode_attention, block_t=bt),

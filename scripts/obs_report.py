@@ -538,23 +538,14 @@ def merge_report(trace_dir: str) -> tuple[dict, dict]:
     serve: dict = {}
     if serve_stages or serve_counters:
         serve = {"stages_s": serve_stages, "counters": serve_counters}
-        # decode-kernel attribution: engine steps by dispatch path plus the
-        # batcher's one-shot isolation probe (µs on live shapes)
+        # decode-kernel attribution: engine steps by dispatch path
         kernel_steps = {
             k[len("serve_decode_kernel_"):]: counters[k]
             for k in sorted(counters)
             if k.startswith("serve_decode_kernel_")
         }
-        probe_us: dict[str, float] = {}
-        for _wid, _events, meta in workers:
-            for k, v in (meta.get("gauges") or {}).items():
-                if k == "serve_decode_attn_us":
-                    probe_us[k[len("serve_"):]] = round(float(v), 2)
-        if kernel_steps or probe_us:
-            serve["decode_kernel"] = {
-                **({"steps_by_path": kernel_steps} if kernel_steps else {}),
-                **({"probe_us": probe_us} if probe_us else {}),
-            }
+        if kernel_steps:
+            serve["decode_kernel"] = {"steps_by_path": kernel_steps}
         # host KV-tier surface: cold-tier load (last gauge sample per
         # worker) plus the page-transfer byte/event counters
         tier_gauges: dict[str, dict] = {}

@@ -1,6 +1,6 @@
 """Pallas serving-kernel parity tests (ops/decode_kernels.py).
 
-The dispatch contract is token-bit-exact: `ODTP_DECODE_KERNEL=pallas`
+The dispatch contract is token-bit-exact: ``decode_kernel="pallas"``
 must emit exactly the token stream the stock XLA path emits. On this
 CPU rig the kernels run in Pallas interpret mode — slower, but it is the
 kernel's own dataflow (masks, online softmax, in-register dequant, the
@@ -546,17 +546,21 @@ def test_a_runs_rows_land_where_its_bucket_would_pass_the_rings_end(start, count
 # ---------------------------------------------------------------------------
 
 
-def test_auto_never_selects_pallas_off_tpu(monkeypatch):
+def test_the_platform_chooses_where_nothing_is_passed(monkeypatch):
+    """No name: the kernels on a TPU backend, the XLA paths elsewhere. A name is
+    itself (the tests' seam), and no environment name is read."""
     assert jax.default_backend() != "tpu"
-    assert resolve_decode_kernel() == "xla"
-    assert resolve_decode_kernel("auto") == "xla"
+    assert resolve_decode_kernel() == resolve_decode_kernel(None) == "xla"
     assert resolve_decode_kernel("xla") == "xla"
     assert resolve_decode_kernel("pallas") == "pallas"
     monkeypatch.setenv("ODTP_DECODE_KERNEL", "pallas")
-    assert resolve_decode_kernel() == "pallas"  # env wins when arg unset
-    assert resolve_decode_kernel("xla") == "xla"  # explicit arg wins
-    with pytest.raises(ValueError):
-        resolve_decode_kernel("mosaic")
+    assert resolve_decode_kernel() == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_decode_kernel() == "pallas"
+    assert resolve_decode_kernel("xla") == "xla"
+    for gone in ("auto", "mosaic", ""):
+        with pytest.raises(ValueError, match="unknown decode kernel"):
+            resolve_decode_kernel(gone)
 
 
 # ---------------------------------------------------------------------------
@@ -657,24 +661,22 @@ def test_batcher_token_streams_identical(tiny_cfg, family, small_tiles):
     assert results[0] == results[1]
 
 
-def test_engine_kernel_probe_gauges(tiny_cfg):
+def test_engine_plan_stats_are_zeros_on_the_xla_path(tiny_cfg):
     eng = _make_engine(tiny_cfg, "xla")
-    out = eng.kernel_probe(iters=1)
-    plan = {
+    out = eng.decode_plan_stats()
+    assert set(out) == {
         "decode_plan_heads", "decode_plan_block_t", "decode_plan_block_diagonal",
         "decode_plan_slots", "decode_grid_steps",
     }
-    assert set(out) == {"decode_attn_us"} | plan
-    assert all(out[k] > 0 for k in set(out) - plan)
-    assert all(out[k] == 0 for k in plan)  # the XLA path: no kernel, no plan
+    assert all(v == 0 for v in out.values())  # the XLA path: no kernel, no plan
 
 
-def test_engine_kernel_probe_carries_the_plan(tiny_cfg, small_tiles):
-    """Under ``pallas`` the probe's result (and so the gauges behind ``GET
-    /stats``) says which form of the decode kernel the engine's shapes take."""
+def test_engine_plan_stats_carry_the_plan(tiny_cfg, small_tiles):
+    """Under ``pallas`` ``decode_plan_stats()`` (and so ``GET /stats``) says
+    which form of the decode kernel the engine's shapes take."""
     eng = _make_engine(tiny_cfg, "pallas")
     assert _runs_the_decode_kernel(eng)
-    out = eng.kernel_probe(iters=1)
+    out = eng.decode_plan_stats()
     want = decode_kernels.decode_plan(
         tiny_cfg.kv_heads, tiny_cfg.head_dim, eng.max_context, 4,
     )
@@ -687,7 +689,7 @@ def test_engine_kernel_probe_carries_the_plan(tiny_cfg, small_tiles):
     assert out["decode_plan_slots"] == want.slots == 1
     steps = tiny_cfg.num_hidden_layers * 2 * (tiny_cfg.kv_heads // want.heads) * 3
     assert out["decode_grid_steps"] == steps
-    # always on, with no probe run: what ``GET /stats`` carries
+    # what ``GET /stats`` carries
     assert ContinuousBatcher(eng).stats()["decode_plan"] == {
         "serve_decode_plan_heads": want.heads, "serve_decode_plan_block_t": 8,
         "serve_decode_plan_block_diagonal": int(want.block_diagonal),
@@ -696,7 +698,7 @@ def test_engine_kernel_probe_carries_the_plan(tiny_cfg, small_tiles):
 
 
 def test_engine_plan_of_several_slots_a_step(tiny_cfg, monkeypatch):
-    """An engine whose ring is one tile: the probe and ``GET /stats`` say how
+    """An engine whose ring is one tile: ``decode_plan_stats()`` and ``GET /stats`` say how
     many slots share a grid step and how many grid steps that leaves, and the
     engine's tokens under that plan are the XLA path's."""
     kw = dict(num_slots=4, max_context=128, prefill_buckets=(8,))

@@ -468,7 +468,7 @@ def test_counters_are_zero_for_every_other_configuration_and_spans_carry_them():
     """A dense engine reads 0 on every EVA counter and its spans carry no EVA
     attribute; an EVA engine's ``serve_prefill`` and ``serve_decode`` spans
     carry ``eva_local_rows``, ``eva_pooled_rows`` and ``eva_bytes`` while a
-    tracer is armed, and the kernel probe publishes the rings' plans."""
+    tracer is armed, and ``decode_plan_stats()`` carries the rings' plans."""
     from opendiloco_tpu import obs
 
     dense = LlamaConfig(vocab_size=64, hidden_size=32, intermediate_size=48,
@@ -497,6 +497,6 @@ def test_counters_are_zero_for_every_other_configuration_and_spans_carry_them():
     assert spans["serve_decode"]["args"]["eva_bytes"] > 0
     assert engine.eva_cache_bytes_moved == (
         spans["serve_prefill"]["args"]["eva_bytes"] + spans["serve_decode"]["args"]["eva_bytes"])
-    probe = engine.kernel_probe(iters=1)
-    assert probe["decode_attn_us"] > 0 and probe["eva_cache_resident_bytes"] > 0
-    assert "eva_pooled_plan_block_t" in probe and "decode_plan_heads" in probe
+    plan = engine.decode_plan_stats()
+    assert engine.eva_cache_resident_bytes > 0
+    assert "eva_pooled_plan_block_t" in plan and "decode_plan_heads" in plan

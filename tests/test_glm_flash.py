@@ -492,8 +492,8 @@ def test_what_cannot_hold_a_latent_row_says_so(tmp_path):
         LlamaConfig.from_dict(published(q_lora_rank=0))
     with pytest.raises(ValueError, match="are not among the router's 16"):
         LlamaConfig.from_dict(published(first_local_expert=12))
-    # what works unchanged is not refused: the kernel probe, a weight swap
-    assert engine.kernel_probe(iters=1)["decode_attn_us"] > 0
+    # what works unchanged is not refused: the plan's numbers, a weight swap
+    assert "decode_plan_heads" in engine.decode_plan_stats()
     engine.install_params(1, params)
     assert engine.weight_binds == 2
 

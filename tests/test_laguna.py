@@ -292,6 +292,23 @@ def test_the_engine_takes_its_own_chunk_and_serves_through_the_batcher(model, mo
         ContinuousBatcher(engine, prefix_cache=True)
 
 
+@pytest.mark.parametrize("feature", ["prefix reuse", "page-out", "page-in"])
+def test_what_handles_one_ring_from_row_0_refuses_the_stack_by_name(model, feature):
+    """The engine's three sites ask the table the scheduler asks, so each refuses
+    by name before it reaches a ``take`` over a ``RingPair`` or a list of no buckets."""
+    cfg, params, _, _ = model
+    engine = ServeEngine(cfg, params, num_slots=2, max_context=48, prefill_buckets=(),
+                         prefill_chunk=CHUNK, **F32)
+    rows = np.zeros((5, 16, 2, 16), np.float32)
+    call = {
+        "prefix reuse": lambda: engine.admit(1, list(range(3, 19)), prefix_src=0, prefix_len=12),
+        "page-out": lambda: engine.fetch_slot_pages(0, 16),
+        "page-in": lambda: engine.install_slot_pages(0, rows, rows),
+    }[feature]
+    with pytest.raises(ValueError, match=f"{feature}.* is refused for a configuration with sliding layers"):
+        call()
+
+
 def test_a_ring_the_kernel_cannot_tile_is_refused_at_construction(model, monkeypatch):
     cfg, params, _, _ = model
     monkeypatch.delenv("ODTP_DECODE_BLOCK_T", raising=False)

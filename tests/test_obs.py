@@ -1027,18 +1027,6 @@ def test_the_last_capture_stays_readable():
     assert obs.capture.last() is None
 
 
-class _CountedProbe:
-    """``engine.kernel_probe`` counted and not run."""
-
-    def __init__(self, engine):
-        self.calls = 0
-        engine.kernel_probe = self
-
-    def __call__(self, iters=3):
-        self.calls += 1
-        return {}
-
-
 def _decode_past_the_gauges(batcher, steps):
     """One request of ``steps`` decode steps (its first token is the
     prefill's); the wait returns from inside the last of them."""
@@ -1046,11 +1034,10 @@ def _decode_past_the_gauges(batcher, steps):
     assert r.wait(120) and r.error is None
 
 
-def test_no_tracer_at_start_means_no_kernel_probe_ever(tiny_cfg, monkeypatch):
+def test_no_tracer_means_no_gauge_is_computed(tiny_cfg, monkeypatch):
     from opendiloco_tpu.serve import ContinuousBatcher
 
     engine = _tiny_engine(tiny_cfg)
-    probe = _CountedProbe(engine)
     batcher = ContinuousBatcher(engine, gauge_every_steps=4)
     percentiles = []
     real = np.percentile
@@ -1058,34 +1045,31 @@ def test_no_tracer_at_start_means_no_kernel_probe_ever(tiny_cfg, monkeypatch):
     batcher.start()
     try:
         _decode_past_the_gauges(batcher, 9)  # the gauges came due twice
-        assert probe.calls == 0 and not percentiles
-        # nor because a capture arms the tracer later: a probe compiles
+        assert not percentiles
+        # a capture arms the tracer later
         obs.capture.start()
         _decode_past_the_gauges(batcher, 18)
         cap = obs.capture.stop()
     finally:
         batcher.stop()
-    assert probe.calls == 0
     assert percentiles  # armed, the gauges are computed as they were
     assert any(k.startswith("serve_tokens_generated") for k in cap.counters)
 
 
-def test_a_tracer_at_start_means_one_kernel_probe(tiny_cfg, monkeypatch):
+def test_a_tracer_at_start_means_the_gauges_are_published(tiny_cfg, monkeypatch):
     from opendiloco_tpu.serve import ContinuousBatcher
 
     tr = _arm(monkeypatch)
     engine = _tiny_engine(tiny_cfg)
-    probe = _CountedProbe(engine)
     batcher = ContinuousBatcher(engine, gauge_every_steps=4).start()
     try:
         _decode_past_the_gauges(batcher, 9)
     finally:
         batcher.stop()
-    assert probe.calls == 1
-    # and the gauges are published as they were at the parent
-    assert {"serve_batch_occupancy", "serve_tokens_per_s", "serve_queue_depth"} <= {
-        name for name, _labels in tr.gauges()
-    }
+    # the gauges are published as they were at the parent, and none is a probe's
+    names = {name for name, _labels in tr.gauges()}
+    assert {"serve_batch_occupancy", "serve_tokens_per_s", "serve_queue_depth"} <= names
+    assert "serve_decode_attn_us" not in names
 
 
 def test_the_staleness_watchdog_is_called_with_no_tracer(tiny_cfg, monkeypatch):

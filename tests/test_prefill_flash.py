@@ -108,10 +108,9 @@ def test_the_form_is_a_function_of_what_the_call_sees(rows, heads, form):
 
 def test_the_form_follows_the_platform_as_the_decode_kernel_does(monkeypatch):
     heads = (16, 16, 128, 128)
-    assert prefill_form(2048, *heads, None) == "xla"  # auto, off the TPU
-    monkeypatch.setenv("ODTP_DECODE_KERNEL", "pallas")
-    assert prefill_form(2048, *heads, None) == "flash"
-    monkeypatch.delenv("ODTP_DECODE_KERNEL")
+    assert prefill_form(2048, *heads, None) == "xla"  # nothing passed, off the TPU
+    monkeypatch.setenv("ODTP_DECODE_KERNEL", "pallas")  # a name nothing reads (PR 58)
+    assert prefill_form(2048, *heads, None) == "xla"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert prefill_form(2048, *heads, None) == "flash"
     assert prefill_form(2048, *heads, "xla") == "xla"

@@ -670,11 +670,13 @@ def test_prefix_batcher_hits_and_parity(tiny_cfg):
     ("config", "spec_decode_k"), ("config", "draft_layers"), ("config", "weight_format"),
     ("engine", "spec_k"), ("engine", "draft_layers"), ("engine", "weight_format"),
     ("env", "ODTP_SPEC_K"), ("env", "ODTP_DECODE_WEIGHT_FORMAT"),
+    ("config", "decode_kernel"), ("env", "ODTP_DECODE_KERNEL"),
 ])
 def test_the_removed_decode_options_are_unknown_names(tiny_cfg, where, name):
     """Speculative decode and 4-bit resident weights went with their eight
-    settable values (PR 44): each is refused as any unknown key or argument
-    is, and the two environment variables are declared and read nowhere."""
+    settable values (PR 44), the decode kernel's option and environment name
+    with PR 58 (the platform chooses): each is refused as any unknown key or
+    argument is, and the environment variables are declared and read nowhere."""
     import pathlib
 
     import pydantic
@@ -684,7 +686,7 @@ def test_the_removed_decode_options_are_unknown_names(tiny_cfg, where, name):
 
     if where == "config":
         with pytest.raises(pydantic.ValidationError, match=name):
-            ServeConfig(**{name: 0 if name != "weight_format" else "fp32"})
+            ServeConfig(**{name: {"weight_format": "fp32", "decode_kernel": "xla"}.get(name, 0)})
     elif where == "engine":
         with pytest.raises(TypeError, match=name):
             make_engine(tiny_cfg, **{name: 0 if name != "weight_format" else "fp32"})
@@ -692,6 +694,16 @@ def test_the_removed_decode_options_are_unknown_names(tiny_cfg, where, name):
         assert name not in {k.name for k in knobs.KNOBS}
         package = pathlib.Path(opendiloco_tpu.__file__).parent
         assert not [p for p in package.rglob("*.py") if name in p.read_text()]
+
+
+def test_the_engine_takes_a_kernel_by_name_or_the_platforms(tiny_cfg):
+    """``decode_kernel`` is the tests' seam: "pallas" | "xla" by name, None for
+    what the platform runs (off the TPU, the XLA forms); "auto" went with the
+    option that spelled it."""
+    assert make_engine(tiny_cfg)[0].decode_kernel == "xla"
+    assert make_engine(tiny_cfg, decode_kernel="pallas")[0].decode_kernel == "pallas"
+    with pytest.raises(ValueError, match="unknown decode kernel 'auto'"):
+        make_engine(tiny_cfg, decode_kernel="auto")
 
 
 def test_build_serving_with_diloco_swaps_live(tiny_cfg):

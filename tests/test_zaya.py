@@ -495,10 +495,8 @@ def test_what_cannot_follow_the_state_says_so(tmp_path):
         pipeline_hidden(params, jnp.zeros((2, 8, 32)), None, cfg, None, microbatches=2, attn_fn=None)
     with pytest.raises(ValueError, match="no CCA"):
         hf_io.save_params(params, cfg, str(tmp_path))
-    # what works unchanged is not refused: the kernel probe (with the plan for
-    # these heads), a weight swap
-    probe = engine.kernel_probe(iters=1)
-    assert probe["decode_attn_us"] > 0 and "decode_plan_heads" in probe
+    # what works unchanged is not refused: the plan for these heads, a weight swap
+    assert "decode_plan_heads" in engine.decode_plan_stats()
     engine.install_params(1, params)
     assert engine.weight_binds == 2
 
