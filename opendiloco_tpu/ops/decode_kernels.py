@@ -985,48 +985,66 @@ def eva_decode_attention(
 
 
 def _mla_decode_kernel(
-    lens_ref, layer_ref, q_ref, new_ref, c_ref, *rest,
+    lens_ref, layer_ref, q_ref, row_ref, new_ref, c_ref, *rest,
     scale, block_t, t, num_t, value_dim, window=0, with_selection=False, live_only=False,
 ):
     # under a selection (an indexer over latent rows): one more operand behind
     # the cache, the rows of this tile that the slot's indexer chose
     sel_ref = rest[0] if with_selection else None
-    o_ref, co_ref, m_scr, l_scr, acc_scr = rest[with_selection:]
+    o_ref, co_ref, snew_scr, m_scr, l_scr, acc_scr = rest[with_selection:]
     heads, d = q_ref.shape  # every head of the slot: they share its rows
     si, ti = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
 
     @pl.when(ti == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, f32)
+        l_scr[:] = jnp.zeros(l_scr.shape, f32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, f32)
+        # the step's own scores, q . row: a row sum, once a slot
+        snew_scr[:] = scale * jnp.sum(
+            q_ref[:].astype(f32) * row_ref[:].astype(f32), axis=1, keepdims=True
+        )
 
     lens_s = lens_ref[si]
     last_live = jnp.minimum(lens_s, t - 1) // block_t
     # the step's own row goes to ring row lens % t, in a block that is
-    # always live (the last live one until the ring wraps)
-    new_at = jax.lax.rem(lens_s, t) - ti * block_t  # its lane in this tile
+    # always live (the last live one until the ring wraps). ``live_only``: a
+    # slot at lens 0 holds no sequence that decodes (it may be one whose
+    # prompt is arriving in chunks); it is written nothing, reads nothing, and
+    # hands the block the output maps to back as it was
+    row_at = jax.lax.rem(lens_s, t)
+    holds = ti <= last_live
+    if live_only:
+        row_at = jnp.where(lens_s > 0, row_at, -1)
+        holds = holds & (lens_s > 0)
+    new_at = row_at - ti * block_t  # its lane in this tile, if it lies here
 
-    def attend(tile):  # [d, block_t]: one read serves scores and values
-        idx = ti * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, (heads, block_t), 1
-        )
+    @pl.when(holds)
+    def _attend():
+        # the tile as the ring holds it, one read for scores and values: the
+        # row's own score and value are patched into s and acc, never into a
+        # tile. What lies at the row's place is finite (zeros, or the row it
+        # evicts)
+        tile = c_ref[:]  # [d, block_t]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (heads, block_t), 1)
+        idx = ti * block_t + lane
         if window:
             # a ring that wraps under a window: row r holds the newest position
             # r modulo t, lens - ((lens - r) mod t), and the slot reads the rows
             # of its last ``window`` positions
-            back = jax.lax.rem(lens_s, t) - idx
-            back = jnp.where(back < 0, back + t, back)
-            valid = back < jnp.minimum(window, lens_s + 1)
+            ago = jax.lax.rem(lens_s, t) - idx
+            ago = jnp.where(ago < 0, ago + t, ago)
+            valid = ago < jnp.minimum(window, lens_s + 1)
         else:
             valid = (idx <= lens_s) | (lens_s >= t)
         if with_selection:  # of the live rows, those the indexer chose
             valid = valid & (sel_ref[:] > 0)
+        at_row = lane == new_at  # nowhere, in a tile that does not hold it
         s = scale * jax.lax.dot_general(
-            q_ref[:], tile, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            q_ref[:], tile, (((1,), (0,)), ((), ())), preferred_element_type=f32,
         )  # [heads, block_t]
-        s = jnp.where(valid, s, NEG_INF)
+        s = jnp.where(valid, jnp.where(at_row, snew_scr[:], s), NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -1035,48 +1053,41 @@ def _mla_decode_kernel(
         corr = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(tile.dtype), tile[:value_dim], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        # the row's own weight (0 where it lies in another tile, or is not
+        # among the chosen), rounded as the MXU's operand is
+        own = jnp.sum(jnp.where(at_row, p, 0.0), axis=1, keepdims=True).astype(tile.dtype)
+        acc_scr[:] = (
+            acc_scr[:] * corr
+            + jax.lax.dot_general(
+                jnp.where(at_row, 0.0, p).astype(tile.dtype), tile[:value_dim],
+                (((1,), (1,)), ((), ())), preferred_element_type=f32,
+            )
+            + own.astype(f32) * row_ref[:, :value_dim].astype(f32)
         )
 
-    # ``live_only``: a slot at lens 0 holds no sequence that decodes (it may be
-    # one whose prompt is arriving in chunks); it is written nothing, reads
-    # nothing, and hands the block the output maps to back as it was
-    holds = (lambda cond: cond & (lens_s > 0)) if live_only else (lambda cond: cond)
+    # what goes back: the one block of min(block_t, 128) rows that holds the
+    # step's row, as ``_decode_slot_step`` hands it back. The rows of all slots
+    # arrive a second time transposed, slots as lanes, so this slot's is a
+    # column already: rolled from lane si % 128 to the row's lane and selected
+    # into the block (Mosaic has no [1, d] -> [d, 1] reshape)
+    back = co_ref.shape[-1]
+    for b in range(block_t // back):
+        at = new_at - b * back
+        here_it_lies = (at >= 0) & (at < back)
+        if live_only and b == 0:
+            here_it_lies = here_it_lies | ((row_at < 0) & (ti == 0))
 
-    if live_only:
-        @pl.when((lens_s == 0) & (ti == 0))
-        def _hand_back():
-            co_ref[:] = c_ref[:]
-
-    @pl.when(holds((new_at >= 0) & (new_at < block_t)))
-    def _write_and_attend():
-        # the new row as a column of the rows-minor tile. The rows of all
-        # slots arrive transposed, slots as lanes ([d, 128] here); picking
-        # this slot's lane and moving it to lane ``new_at`` is one product
-        # with a [block_t, 128] matrix that holds a single 1: exact, and on
-        # the MXU (a [1, d] -> [d, 1] reshape Mosaic has not, and
-        # ``_as_column``'s [d, d] diagonal is 1.3 MB at d 576)
-        lanes = new_ref.shape[1]
-        pick = (
-            jax.lax.broadcasted_iota(jnp.int32, (block_t, lanes), 0) == new_at
-        ) & (
-            jax.lax.broadcasted_iota(jnp.int32, (block_t, lanes), 1)
-            == jax.lax.rem(si, lanes)
-        )
-        moved = jax.lax.dot_general(
-            new_ref[:], pick.astype(new_ref.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [d, block_t]: column new_at is the row, the rest 0
-        here = jax.lax.broadcasted_iota(jnp.int32, (d, block_t), 1) == new_at
-        tile = jnp.where(here, moved.astype(c_ref.dtype), c_ref[:])
-        co_ref[:] = tile
-        attend(tile)
-
-    @pl.when(holds((ti <= last_live) & ((new_at < 0) | (new_at >= block_t))))
-    def _attend():
-        attend(c_ref[:])
+        @pl.when(here_it_lies)
+        def _write():
+            lanes = new_ref.shape[-1]
+            shift = jax.lax.rem(at - jax.lax.rem(si, lanes) + lanes, lanes)
+            col = pltpu.roll(_lanes32(new_ref[:]), shift, 1)[:, :back]
+            old = _lanes32(c_ref[:, b * back:(b + 1) * back])
+            here = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1) == at
+            patched = jnp.where(here, col, old)
+            if patched.dtype != co_ref.dtype:
+                patched = pltpu.bitcast(patched, co_ref.dtype)
+            co_ref[:] = patched
 
     @pl.when(ti == num_t - 1)
     def _finish():
@@ -1093,6 +1104,13 @@ def mla_decode_plan(
     if d % 8 != 0 or value_dim % 8 != 0:
         return 0
     return _ring_block(t, block_t, _interpret(interpret), preferred=512)
+
+
+def mla_rows_written_back(block_t: int) -> int:
+    """The ring rows a slot's step of ``odtp_mla_decode_attn`` hands back through
+    the aliased output, of a tile of ``block_t``: the 128 that hold the step's
+    row, or the tile where it is smaller (0 for no tile: the XLA form)."""
+    return min(block_t, _LANES)
 
 
 def mla_decode_attention(
@@ -1125,9 +1143,12 @@ def mla_decode_attention(
     DMAs elided), scores all H heads against it and takes the values from
     its first ``value_dim`` rows, so each live row is read once a layer and
     step, not once for keys and once for values, and not once a head. The
-    tile that holds ring row ``lens % T`` gets the new row as a column and
-    goes back through the aliased output. A shape it cannot tile keeps the
-    XLA path per call (:func:`mla_decode_plan` says beforehand).
+    tiles are attended as the ring holds them, the new row's own score and
+    value patched into the softmax, and of the tile that holds ring row
+    ``lens % T`` only the block of :func:`mla_rows_written_back` rows around
+    it goes back through the aliased output, the row in it as a column. A
+    shape it cannot tile keeps the XLA path per call (:func:`mla_decode_plan`
+    says beforehand).
 
     ``chosen`` [S, T] bool (an indexer over latent rows): one more operand,
     the selection a tile at a time; the kernel reads the tiles as ever and lets
@@ -1146,8 +1167,10 @@ def mla_decode_attention(
             chosen=chosen, window=window, live_only=live_only,
         )
     num_t = t // bt
-    lanes = 128  # slots as lanes, so that a slot's new row is a column
-    new = jnp.pad(row.astype(cache.dtype).T, ((0, 0), (0, -s_ % lanes)))
+    back = mla_rows_written_back(bt)
+    row = row.astype(cache.dtype)
+    # and slots as lanes, so that a slot's new row is a column
+    new = jnp.pad(row.T, ((0, 0), (0, -s_ % _LANES)))
 
     def page_map(si, ti, lens_ref, layer_ref):
         # clamp dead blocks to the last live one: unchanged index = no DMA
@@ -1155,7 +1178,8 @@ def mla_decode_attention(
         return (layer_ref[0], si, 0, 0, jnp.minimum(ti, last))
 
     def written_map(si, ti, lens_ref, layer_ref):
-        return (layer_ref[0], si, 0, 0, jax.lax.rem(lens_ref[si], t) // bt)
+        # the one block of the slot that goes back: the row's
+        return (layer_ref[0], si, 0, 0, jax.lax.rem(lens_ref[si], t) // back)
 
     def slot_map(si, ti, lr, yr):
         return (si, 0, 0)
@@ -1169,15 +1193,17 @@ def mla_decode_attention(
         grid=(s_, num_t),
         in_specs=[
             pl.BlockSpec((None, h, d), slot_map),
-            pl.BlockSpec((d, lanes), lambda si, ti, lr, yr: (0, si // lanes)),
+            pl.BlockSpec((None, 1, d), slot_map),
+            pl.BlockSpec((d, _LANES), lambda si, ti, lr, yr: (0, si // _LANES)),
             pl.BlockSpec((None, None, None, d, bt), page_map),
             *([pl.BlockSpec((None, 1, bt), chosen_map)] if selection else []),
         ],
         out_specs=[
             pl.BlockSpec((None, h, value_dim), slot_map),
-            pl.BlockSpec((None, None, None, d, bt), written_map),
+            pl.BlockSpec((None, None, None, d, back), written_map),
         ],
         scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),  # the step's own scores
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, value_dim), jnp.float32),
@@ -1198,14 +1224,14 @@ def mla_decode_attention(
             jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         ],
         # operands count the two scalar-prefetch vectors: the cache is input
-        # 4, and comes back as output 1
-        input_output_aliases={4: 1},
+        # 5, and comes back as output 1
+        input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interp,
     )(
         lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        q, new, cache, *selection,
+        q, row.reshape(s_, 1, d), new, cache, *selection,
     )
     return out, cache

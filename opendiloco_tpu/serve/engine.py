@@ -108,6 +108,7 @@ from opendiloco_tpu.ops.decode_kernels import (
     eva_plans,
     eva_prefill_form,
     mla_decode_plan,
+    mla_rows_written_back,
     prefill_form,
     resolve_decode_kernel,
 )
@@ -513,8 +514,9 @@ class ServeEngine:
         # and which form each kind of latent layer's decode step and chunk take
         # ({} without sliding layers): the decode step's by ``decode_kernel``
         # (the kernel has a tile for both rings or the engine is refused here,
-        # never a step that quietly takes the XLA form); a chunk's is the
-        # absorbed form in XLA, a tile of ring rows at a time
+        # never a step that quietly takes the XLA form), its tile and the ring
+        # rows a slot's step hands back of it; a chunk's is the absorbed form
+        # in XLA, a tile of ring rows at a time
         self.swa_cache_resident_bytes = self.cache_v.nbytes if cfg.sliding and cfg.latent else 0
         self.swa_rows_read = 0
         self.swa_bytes_moved = 0
@@ -529,10 +531,12 @@ class ServeEngine:
                 all(plans.values()), "tile for this stack's latent rings",
                 f"full {full[3:]}, sliding {swa[3:]} as (row, ring rows): {plans}",
             )
+            if self.decode_kernel != "pallas":
+                plans = dict.fromkeys(plans, 0)
             self.latent_forms = {
-                kind: {"decode": self.decode_kernel, "chunk": "absorbed-xla",
-                       "block_t": plans[kind] if self.decode_kernel == "pallas" else 0}
-                for kind in plans
+                kind: {"decode": self.decode_kernel, "chunk": "absorbed-xla", "block_t": tile,
+                       "rows_written_back": mla_rows_written_back(tile)}
+                for kind, tile in plans.items()
             }
         # a grouped-query stack with sliding layers: ``cache_k`` is the full
         # layers' pair of rings, ``cache_v`` the sliding layers', which wrap
@@ -1523,7 +1527,10 @@ class ServeEngine:
         step; EVA's pooled ring's beside the window's) and the grid steps
         that makes a decode step over all attention layers. From shapes
         alone, so always there (``GET /stats``); zeros where
-        that kernel does not run: the XLA path, a latent ring."""
+        that kernel does not run: the XLA path, a latent ring, which says its
+        own kernel's tile and the rows a slot's step hands back of it
+        (``decode_plan_mla_block_t``, ``decode_plan_mla_rows_written_back``; a
+        stack with sliding latent layers ``..._swa_...`` too)."""
         cfg = self.cfg
         layers, S, Nkv, Dh, T = self.cache_k.shape
         size = self.cache_k.dtype.itemsize
@@ -1570,17 +1577,25 @@ class ServeEngine:
                     steps += ring.shape[0] * S * (Nkv // form["heads"]) * tiles
             out["decode_grid_steps"] = steps
             return out
-        for kind, ring in (("mla", self.cache_k), ("swa", self.cache_v)) if self.latent_forms else ():
-            # each kind of latent layer's ring and its tile of ``odtp_mla_decode_attn``
-            # (0: the XLA form), all the heads of a slot a grid step
-            form = self.latent_forms["full" if kind == "mla" else "sliding"]
-            out[f"decode_plan_{kind}_block_t"] = float(form["block_t"])
+        latent_rings = [("mla", self.cache_k, "full")] if cfg.latent else []
+        if self.latent_forms:
+            latent_rings.append(("swa", self.cache_v, "sliding"))
+        for kind, ring, form in latent_rings:
+            # each kind of latent layer's ring, its tile of ``odtp_mla_decode_attn``
+            # (0: the XLA form), all the heads of a slot a grid step, and the
+            # ring rows a slot's step hands back of the tile that holds its row
+            if self.latent_forms:
+                block_t = self.latent_forms[form]["block_t"]
+            else:  # one kind of latent layer: a ring without a tile keeps the XLA form
+                block_t = self.decode_kernel == "pallas" and mla_decode_plan(
+                    ring.shape[3], cfg.kv_lora_rank, ring.shape[4]
+                )
+            out[f"decode_plan_{kind}_block_t"] = float(block_t)
+            out[f"decode_plan_{kind}_rows_written_back"] = float(mla_rows_written_back(block_t))
             out[f"{kind}_ring_rows"] = float(ring.shape[-1])
             out[f"{kind}_ring_bytes"] = float(ring.nbytes)
-            if form["block_t"]:
-                out["decode_grid_steps"] += float(
-                    ring.shape[0] * S * (ring.shape[-1] // form["block_t"])
-                )
+            if block_t:
+                out["decode_grid_steps"] += float(ring.shape[0] * S * (ring.shape[-1] // block_t))
         return out
 
     # -- weight hot-swap ---------------------------------------------------

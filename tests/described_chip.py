@@ -105,6 +105,33 @@ def narrow_kernel_arrays(text):
     return narrow
 
 
+def kernel_windows(text: str, name: str) -> list[list[tuple]]:
+    """The blocks of each call of the Mosaic kernel ``name`` in a compiled
+    text, a list a call: its operands' (behind the scalar-prefetch vectors),
+    then its results'. A ``tpu_custom_call`` carries its kernel serialized in
+    its ``backend_config`` (MLIR bytecode, base64), whose ``window_params``
+    hold each block's ``window_bounds``, squeezed dimensions as 1."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    calls = []
+    for line in text.splitlines():
+        if not re.match(rf"\s*(?:ROOT )?%{name}[\w.]* = .*custom-call\(", line):
+            continue
+        body = re.search(r'"custom_call_config":\{"body":"([A-Za-z0-9+/=]+)"', line).group(1)
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True  # Mosaic's own, stable_mosaic
+        with ctx:
+            kernel = str(ir.Module.parse(base64.b64decode(body)))
+        params = re.search(r"window_params = \[(.*?)\]\}", kernel).group(1)
+        calls.append([
+            tuple(int(d) for d in bounds.split(","))
+            for bounds in re.findall(r"window_bounds = array<i64: ([\d, ]+)>", params)
+        ])
+    return calls
+
+
 def serve_cell(config, workload, **cut):
     """-> (a benchmark configuration as ``LlamaConfig``, with ``cut`` laid
     over the published values, and its cell's engine options)."""

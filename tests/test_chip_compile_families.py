@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from described_chip import (
-    BF16, HBM_BYTES, RESULT, bound, cache_shaped_results, f32_blocks_over,
+    BF16, HBM_BYTES, RESULT, bound, cache_shaped_results, f32_blocks_over, kernel_windows,
     leaf_shaped_casts, olmoe_cell, on_chip, once_a_session, program_bytes, ring_copies, serve_cell,
 )
 
@@ -450,6 +450,10 @@ def test_dots3_programs_copy_no_ring_and_cast_no_weight(chip, which):
     assert not leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
     if which == "decode":
         assert "odtp_mla_decode_attn" in text and "odtp_index_ring_write" in text
+        # what a slot's step hands back of either latent ring: the 128 rows
+        # that hold its row, of a tile of 512 (PR 60)
+        assert {blocks[-1] for blocks in kernel_windows(text, "odtp_mla_decode_attn")} == {
+            (1, 1, 1, 576, 128), (1, 1, 1, 1088, 128)}
         assert mem.temp_size_in_bytes < 256e6
         assert not f32_blocks_over(text, 256e6)
     else:

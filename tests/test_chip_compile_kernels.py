@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from described_chip import BF16, compiled_text, flash_kernels, narrow_kernel_arrays, olmoe_cell
+from described_chip import (
+    BF16, compiled_text, flash_kernels, kernel_windows, narrow_kernel_arrays, olmoe_cell, ring_copies,
+)
 
 from opendiloco_tpu.models.ring_cache import cache_shape, layer_rows_insert, slot_layer_pages
 from opendiloco_tpu.ops import decode_kernels
@@ -357,7 +359,9 @@ def test_olmoe_routed_ffn_is_the_grouped_matmul(chip, rows):
 def test_mla_decode_attention(chip, slots):
     """The kernel at the published sizes (20 heads over rows of 512 + 64, a
     ring of 2,048 rows, 24 layers): the Mosaic kernel and not its XLA
-    stand-in, the ring aliased to the output, no temporary."""
+    stand-in, the ring aliased to the output, no temporary. It reads a
+    ``576 x 512`` tile of the ring a grid step, and what it hands back is the
+    ``576 x 128`` block that holds the step's row, not the tile (PR 60)."""
     from opendiloco_tpu.ops.decode_kernels import mla_decode_attention
 
     ring = cache_shape(24, slots, 2048, 1, 576)
@@ -375,3 +379,6 @@ def test_mla_decode_attention(chip, slots):
     assert "odtp_mla_decode_attn" in text and "tpu_custom_call" in text
     ring_bytes = 2 * 24 * slots * 576 * 2048
     assert mem.alias_size_in_bytes >= ring_bytes and mem.temp_size_in_bytes < ring_bytes // 24
+    (blocks,) = kernel_windows(text, "odtp_mla_decode_attn")
+    assert blocks[-3:] == [(1, 1, 1, 576, 512), (1, 20, 512), (1, 1, 1, 576, 128)]
+    assert not ring_copies(text, ring)

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from described_chip import (
     BF16, COMPILES, HBM_BYTES, MOVES_NOTHING, RESULT, cache_shaped_results, engine_program,
-    leaf_shaped_casts, olmoe_cell, on_chip, program_bytes, serve_cell, top_level,
+    kernel_windows, leaf_shaped_casts, olmoe_cell, on_chip, program_bytes, ring_copies, serve_cell,
+    top_level,
 )
 
 from opendiloco_tpu.models.ring_cache import cache_shape
@@ -383,7 +384,9 @@ def test_glm_decode_step_reads_the_latent_ring_in_place(chip):
     """64 slots (or what the cell's file says): the latent kernel over the
     one ring, the grouped matmuls, no cast of a weight, the ring aliased to
     the output, and no copy, transpose, scatter, slice, update or fresh
-    buffer of the ring's shape or of one layer's pages."""
+    buffer of the ring's shape or of one layer's pages. Each call of the
+    kernel reads ``576 x 512`` tiles and hands back the ``576 x 128`` block
+    that holds the step's row, not the tile (PR 60)."""
     _, ring = _glm_cell(chip)
     compiled, _, params, carried = engine_program(
         chip, "glm-4.7-flash", "serve-glm-flash-agent", "decode"
@@ -392,6 +395,11 @@ def test_glm_decode_step_reads_the_latent_ring_in_place(chip):
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "odtp_mla_decode_attn" in text and "%ragged-dot" in text
     assert "odtp_paged_decode_attn" not in text
+    calls = kernel_windows(text, "odtp_mla_decode_attn")
+    assert calls and all(
+        blocks[-3:] == [(1, 1, 1, 576, 512), (1, 20, 512), (1, 1, 1, 576, 128)] for blocks in calls
+    )
+    assert not ring_copies(text, ring.shape)
     leaves = jax.tree.leaves(params)
     assert not leaf_shaped_casts(text, {tuple(x.shape) for x in leaves})
     weights = sum(x.size * x.dtype.itemsize for x in leaves)

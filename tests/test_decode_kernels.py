@@ -356,21 +356,33 @@ def test_paged_decode_attention_untileable_shape_falls_back(T, D):
     np.testing.assert_array_equal(np.asarray(ov), np.asarray(rv))
 
 
+# ten slots over a latent ring of 512 rows whose tile holds several 128-row
+# blocks: empty, on both sides of a block's edge inside a tile and of a tile's
+# edge, at the ring's last row, and past the wrap once and twice
+_LENS_OVER_BLOCKS = (0, 127, 128, 255, 256, 383, 384, 511, 512 + 129, 1024 + 300)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("block_t", [8, 16, 32])
+@pytest.mark.parametrize("block_t", [8, 16, 32, 256, 512])
 @pytest.mark.parametrize("layer", [0, 2])
 def test_mla_decode_attention_parity(layer, block_t, dtype):
     """The latent kernel, interpreted, against the XLA absorbed path: slots
     that are empty, mid-page, at a tile's edge on both sides, at the ring's
     last row, and past the wrap (the row written at ``lens % T``, the whole
     ring live); more slots than one lane-block's share is not needed, but the
-    slot's lane is picked by its index, so a few are enough to show it."""
+    slot's lane is picked by its index, so a few are enough to show it. A tile
+    of 256 or 512 rows (a ring of 512) holds several of the 128-row blocks of
+    which the row's alone goes back: ``lens`` on both sides of a block's edge
+    inside a tile, at a tile's edge, at the ring's last row and past the wrap."""
     L, S, H, Dl, Dv, T = 3, 7, 4, 24, 16, 32
+    lens = jnp.array([0, 5, 15, 16, 31, 40, 17], jnp.int32)
+    if block_t > 32:
+        S, T = 10, 512
+        lens = jnp.array(_LENS_OVER_BLOCKS, jnp.int32)
     keys = jax.random.split(jax.random.key(layer * 10 + block_t), 3)
     cache = jax.random.normal(keys[0], cache_shape(L, S, T, 1, Dl), dtype)
     q = jax.random.normal(keys[1], (S, H, Dl), dtype)
     row = jax.random.normal(keys[2], (S, Dl), dtype)
-    lens = jnp.array([0, 5, 15, 16, 31, 40, 17], jnp.int32)
     want, ring_x = latent_decode_step_attention(
         q, row, cache, lens, layer, scale=0.25, value_dim=Dv)
     got, ring_p = mla_decode_attention(
@@ -390,7 +402,7 @@ def test_mla_decode_attention_parity(layer, block_t, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("block_t", [8, 16])
+@pytest.mark.parametrize("block_t", [8, 16, 256, 512])
 @pytest.mark.parametrize("under", ["selection", "window", "window_of_a_tile"])
 def test_mla_decode_attention_under_a_selection_and_under_a_window(under, block_t, dtype):
     """The latent kernel's two further operands, interpreted, against the XLA
@@ -399,13 +411,19 @@ def test_mla_decode_attention_under_a_selection_and_under_a_window(under, block_
     with no chosen row); under ``window`` the ring wraps and a slot reads the
     rows of its last positions alone, before the wrap, across it, and where the
     window is as long as a tile; with ``live_only`` a slot at ``lens`` 0 is
-    written nothing and its blocks come back as they were."""
+    written nothing and its blocks come back as they were. Under a tile of 256
+    or 512 rows (a ring of 512) the block that goes back is one of the tile's
+    several: the row on both sides of a block's and of a tile's edge, a window
+    that crosses either, and the slot at ``lens`` 0 handing block 0 back."""
     L, S, H, Dl, Dv, T = 2, 7, 4, 24, 16, 32
+    lens, window = jnp.array([0, 5, 15, 16, 31, 40, 77], jnp.int32), 5
+    if block_t > 16:
+        S, T, window = 10, 512, 131
+        lens = jnp.array(_LENS_OVER_BLOCKS, jnp.int32)
     keys = jax.random.split(jax.random.key(block_t), 4)
     cache = jax.random.normal(keys[0], cache_shape(L, S, T, 1, Dl), dtype)
     q = jax.random.normal(keys[1], (S, H, Dl), dtype)
     row = jax.random.normal(keys[2], (S, Dl), dtype)
-    lens = jnp.array([0, 5, 15, 16, 31, 40, 77], jnp.int32)
     extra = {"live_only": True}
     if under == "selection":
         lens = jnp.minimum(lens, T - 1)  # a ring under an indexer holds its context
@@ -417,7 +435,7 @@ def test_mla_decode_attention_under_a_selection_and_under_a_window(under, block_
         chosen = jnp.where((jnp.arange(S) % 2 == 0)[:, None], chosen | own, chosen & ~own)
         extra["chosen"] = chosen.at[:, 0].set(True)
     else:
-        extra["window"] = 5 if under == "window" else block_t
+        extra["window"] = window if under == "window" else block_t
     want, ring_x = latent_decode_step_attention(
         q, row, cache, lens, 1, scale=0.25, value_dim=Dv, **extra)
     got, ring_p = mla_decode_attention(
@@ -435,6 +453,29 @@ def test_mla_decode_attention_under_a_selection_and_under_a_window(under, block_
             at = int(lens[s_])
             rows = {p % T for p in range(max(0, at - extra["window"] + 1), at + 1)}
             assert set(np.flatnonzero(reads[s_])) == rows
+
+
+@pytest.mark.parametrize(
+    "block_t,back", [(512, 128), (256, 128), (128, 128), (8, 8), (0, 0)],
+    ids=["tile_512", "tile_256", "tile_128", "interpreted_8", "no_tile"],
+)
+def test_mla_rows_written_back(block_t, back):
+    """What a slot's step hands back of the tile that holds its row: the 128
+    rows around it, the tile where it is smaller, nothing without a tile; and
+    the kernel's aliased output block is that many rows wide."""
+    assert decode_kernels.mla_rows_written_back(block_t) == back
+    if not block_t:
+        return
+    T, Dl = 4 * max(block_t, 8), 24
+    fn = lambda q, row, cache, lens: mla_decode_attention(
+        q, row, cache, lens, 0, scale=0.5, value_dim=16, block_t=block_t, interpret=True)
+    args = (jnp.ones((3, 2, Dl)), jnp.ones((3, Dl)), jnp.zeros(cache_shape(1, 3, T, 1, Dl)),
+            jnp.array([0, 4, 9], jnp.int32))
+    (call,) = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    blocks = [tuple(getattr(b, "block_size", None) for b in m.block_shape) for m in grid.block_mappings]
+    assert blocks[grid.num_inputs - 1] == (None, None, None, Dl, block_t)  # the tile read
+    assert blocks[-1] == (None, None, None, Dl, back)  # the block handed back
 
 
 def test_mla_decode_attention_untileable_shape_falls_back():

@@ -225,7 +225,7 @@ def test_the_engine_holds_one_copy_and_serves_through_the_batcher(model, monkeyp
     assert engine.params["final_norm"].dtype == jnp.float32
     assert ServeEngine(cfg, params, num_slots=1, max_context=16, **F32).weights_adopted == 0
     assert engine.needs_chunks(4) and engine.latent_forms["sliding"] == {
-        "decode": "pallas", "chunk": "absorbed-xla", "block_t": 8}
+        "decode": "pallas", "chunk": "absorbed-xla", "block_t": 8, "rows_written_back": 8}
     batcher = ContinuousBatcher(engine).start()
     rng = np.random.default_rng(0)
     prompts = [rng.integers(3, 128, n).tolist() for n in (43, 9, 30, 17)]
@@ -243,6 +243,8 @@ def test_the_engine_holds_one_copy_and_serves_through_the_batcher(model, monkeyp
     assert stats["dsa"]["prefill_chunks"] == sum(-(-len(p) // 8) for p in prompts)
     plan = stats["decode_plan"]
     assert plan["serve_decode_plan_mla_block_t"] == plan["serve_decode_plan_swa_block_t"] == 8
+    assert plan["serve_decode_plan_mla_rows_written_back"] == 8
+    assert plan["serve_decode_plan_swa_rows_written_back"] == 8
     assert plan["serve_swa_ring_rows"] == 16 and plan["serve_mla_ring_rows"] == 64
 
 

@@ -322,6 +322,13 @@ def test_engine_prefill_then_decode_at_every_bucket_edge(kernel, monkeypatch):
         worst = max(worst, against_reference(raw, params, prompts, seqs, rows, steps))
     assert worst < REL_L2
     assert runs_the_latent_kernel(engine) == (kernel == "pallas")
+    # which form the step takes, as ``GET /stats`` carries it: the kernel's tile
+    # and the ring rows a slot's step hands back of it (the tile, under 128)
+    plan = engine.decode_plan_stats()
+    tile = 8 if kernel == "pallas" else 0
+    assert plan["decode_plan_mla_block_t"] == plan["decode_plan_mla_rows_written_back"] == tile
+    assert plan["decode_grid_steps"] == (4 * 4 * RING // 8 if tile else 0)
+    assert plan["mla_ring_rows"] == RING and plan["decode_plan_heads"] == 0
     # the last engine served one prompt of 16 and five steps of one live slot
     assert engine.cache_v is None and engine.cache_k.shape == (4, 4, 1, 24, RING)
     row = 24 * 4  # bytes of a float32 row

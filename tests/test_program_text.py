@@ -20,6 +20,10 @@ keep the XLA form under the kernels (the batch cell's 32 and 128, under the
 floor of 512 rows; the agent cell's 768, 45 MB of scores), lowered as the
 engine lowers them, and they are the parent's too (recorded from ``c8ccf9c``, PR 55's parent).
 
+PR 60 changed the latent decode kernel (``odtp_mla_decode_attn``: what a slot's
+step writes back), which only ``glm``'s and ``dots3``'s ``decode`` hold: those two
+digests are PR 60's own tree's, every other one is as it was.
+
 The loop is held the same way: ``ContinuousBatcher``'s iteration for a
 configuration without sliding layers calls no function of the engine that the
 parent's did not."""
@@ -46,14 +50,17 @@ PARENT = {
     "360m": {"decode": "60bc61211abd1b80", "prefill": "c0dc8cf8a5e066b8",
              "chunk": "a3efda827d689e95", "prefill/32": "09f6602a6c323cac",
              "prefill/128": "173e5ce5277ff946"},
-    "glm": {"decode": "2d6902c25c19c003", "prefill": "111348d7d79011c6",
+    # ``decode``: PR 60's own (the latent decode kernel hands back the 128-row
+    # block that holds the step's row; the parent's read 2d6902c25c19c003)
+    "glm": {"decode": "6a11d9027cd2cc97", "prefill": "111348d7d79011c6",
             "prefill/768": "0d22cf03759c402a"},
     "keye": {"decode": "c115bdfaa18a765f", "prefill": "fccbb3f9f6585ff8",
              "chunk": "1a2463f7d2aefe97"},
     # recorded from commit b964f0b (PR 55's tree, PR 56's parent)
     "olmoe": {"decode": "80be10d23e498851", "prefill": "c01c547432729285",
               "chunk": "2be097d15cf0759f"},
-    "dots3": {"decode": "4b291eeac5a6b303", "prefill": "643fffdf8cd98c94",
+    # ``decode``: PR 60's own, as ``glm``'s (the parent's read 4b291eeac5a6b303)
+    "dots3": {"decode": "8b9e121900f07068", "prefill": "643fffdf8cd98c94",
               "chunk": "1496f43173c333a6"},
 }
 # the engine's methods that the batcher's loop (and a submit) called at that
