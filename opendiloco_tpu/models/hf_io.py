@@ -61,6 +61,16 @@ def load_config(path_model: str) -> LlamaConfig:
 
 
 def _reject_moe(cfg: LlamaConfig, op: str) -> None:
+    if cfg.linear or cfg.blocks:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama "
+            "layout has no lightning linear-attention layers (their five "
+            "projections, two head norms and output norm, and the decays their "
+            "code computes), no gates and no norms per head, and HF's "
+            "minicpm_sala layout is not mapped here. Such models train, serve "
+            "and checkpoint through the framework checkpointer "
+            "(opendiloco_tpu.ckpt); only this import/export is refused"
+        )
     if cfg.eva or cfg.num_pred_heads > 1 or cfg.norm_add_unit_offset or cfg.fp32_skip_add:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

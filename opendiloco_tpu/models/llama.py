@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from opendiloco_tpu.models import mamba
+from opendiloco_tpu.models import lightning, mamba
 from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     RingPair,
     cache_insert,
@@ -38,6 +38,7 @@ from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     index_chunk_insert,
     index_write_rows,
     layer_rows_insert,
+    pooled_chunk_insert,
     prefix_copy,
     ring_rows,
     slot_layer_pages,
@@ -61,19 +62,31 @@ from opendiloco_tpu.ops.attention import (
     sparse_decode_step_attention,
     tiled_sparse_attention,
     xla_attention,
+    BlockSizes,
+    block_decode_step_attention,
+    block_selection,
+    block_sparse_attention,
+    causal_block_selection,
+    closing_pooled_key,
+    pool_pages,
+    tiled_block_attention,
 )
 from opendiloco_tpu.ops.decode_kernels import (
+    block_decode_attention,
+    block_tiles_held,
     causal_prefill_attention,
     eva_decode_attention,
     eva_prefill_attention,
     index_ring_write,
     mla_decode_attention,
     paged_decode_attention,
+    ring_rows_sum,
+    xla_ring_rows_sum,
 )
 
 
 # what ``LlamaConfig.layer_types`` may name (``LlamaConfig.layer_kinds``)
-_LAYER_KINDS = ("attention", "mamba", "dense", "sliding")
+_LAYER_KINDS = ("attention", "mamba", "dense", "sliding", "lightning")
 # what ``LlamaConfig.rope_yarn`` holds of a published YaRN entry
 _YARN_KEYS = (
     "factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor",
@@ -288,6 +301,30 @@ class LlamaConfig:
     # (``_yarn_frequencies``); None: plain rope
     swa_partial_rotary_factor: float = 1.0
     rope_yarn: Optional[tuple] = None
+    # Lightning linear attention beside grouped-query attention under a
+    # selection by blocks, the block of a published ``minicpm_sala``
+    # ``config.json``: ``mixer_types`` names each layer "lightning-attn" (held
+    # here as the kind "lightning": ``models/lightning.py``, a decaying state
+    # [heads, head_dim, head_dim] a layer and slot in place of rows, heads of
+    # ``head_dim`` without grouping, rotated by ``rope_theta``) or "minicpm4"
+    # (the kind "attention": ``num_key_value_heads`` KV heads, unrotated:
+    # ``position_embedding_type`` "nope"). ``lightning_decays``: the lightning
+    # layers' rates g, a row of ``num_attention_heads`` floats a layer (lambda
+    # = exp(-g): data, float32 whatever the compute dtype; by
+    # ``lightning.decay_rates`` where a file states none). ``sparse_config``:
+    # the sizes of the "minicpm4" layers' selection (``ops.attention.BlockSizes``,
+    # held as sorted pairs; MiniCPM4's ``sparse_config``, arXiv 2506.07900):
+    # beside K and V a KV head keeps a pooled key per window of ``kernel_size``
+    # rows every ``kernel_stride``, and a query reads ``topk`` blocks of
+    # ``block_size`` rows chosen by the pooled keys' scores, one choice a KV
+    # group; None: every row. ``attention_gate_type`` "elementwise": the
+    # attention's output is scaled value by value by sigmoid(x W_g) before
+    # ``o_proj`` (``attn_use_output_gate``). The family's constant scalings are
+    # the fields a granite hybrid's have: ``embedding_multiplier`` (``scale_emb``),
+    # ``residual_multiplier`` (``scale_depth`` / sqrt of the published depth),
+    # ``logits_scaling`` (``hidden_size`` / ``dim_model_base``)
+    sparse_config: Optional[tuple] = None
+    lightning_decays: Optional[tuple] = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -437,8 +474,16 @@ class LlamaConfig:
                     "three head sizes, an even rotated part) and a sliding_window_size"
                 )
         for gate in (self.attention_gate_type, self.swa_attention_gate_type):
-            if gate not in ("none", "headwise"):
-                raise ValueError(f"attention gate {gate!r}: 'none' or 'headwise'")
+            if gate not in ("none", "headwise", "elementwise"):
+                raise ValueError(f"attention gate {gate!r}: 'none', 'headwise' or 'elementwise'")
+        if "elementwise" in (self.attention_gate_type, self.swa_attention_gate_type) and (
+            self.latent or self.cca or self.eva or self.sparse or self.sliding
+        ):
+            raise ValueError(
+                "the attention gate 'elementwise' is written for one kind of grouped-query "
+                "attention over K and V rows: no latent attention, CCA, EVA, indexer or "
+                "sliding layers"
+            )
         if self.apply_mla_qkv_lora_rescale and not self.latent:
             raise ValueError("the latents' rescale is written for latent attention")
         if "headwise" in (self.attention_gate_type, self.swa_attention_gate_type) and (
@@ -448,6 +493,44 @@ class LlamaConfig:
                 "the head-wise gate is written for latent attention and for plain "
                 "grouped-query attention: no CCA, no EVA, no indexer over K and V rows"
             )
+        if self.sparse_config is not None:
+            sizes = dict(self.sparse_config)
+            object.__setattr__(self, "sparse_config", tuple(sorted(sizes.items())))
+            b = BlockSizes(**{key: int(sizes.get(key, 0)) for key in BlockSizes._fields})
+            if set(sizes) != set(BlockSizes._fields) or min(b) < 1 or (
+                b.kernel_size % b.kernel_stride or b.block_size % b.kernel_stride
+                or b.window_size % b.block_size or b.window_size // b.block_size + b.init_blocks > b.topk
+            ):
+                raise ValueError(
+                    f"sparse_config {sizes!r}: {BlockSizes._fields}, each positive; windows and "
+                    "blocks of whole strides, a window_size of whole blocks, and a topk that "
+                    "holds the forced blocks"
+                )
+        if self.linear or self.blocks:
+            decays = self.lightning_decays or ()
+            object.__setattr__(self, "lightning_decays", tuple(tuple(map(float, r)) for r in decays))
+            if self.linear and (
+                len(decays) != self.num_lightning_layers
+                or any(len(r) != self.num_attention_heads for r in decays)
+            ):
+                raise ValueError(
+                    f"lightning_decays: a row of {self.num_attention_heads} rates for each of "
+                    f"the {self.num_lightning_layers} lightning layers; got {len(decays)} rows"
+                )
+            if (
+                self.latent or self.cca or self.eva or self.sparse or self.sliding or self.hybrid
+                or self.qk_norm or self.mrope_section is not None or self.rope_yarn is not None
+                or self.num_attention_heads % self.kv_heads or "dense" in self.layer_kinds
+                or self.attention_gate_type == "headwise" or self.residual_scaling
+                or self.num_experts
+            ):
+                raise ValueError(
+                    "lightning layers and a selection by blocks are written for a stack of "
+                    "'lightning' and grouped-query 'attention' layers whose query heads divide "
+                    "over the KV heads, each over a dense SwiGLU: no latent attention, CCA, EVA, "
+                    "indexer, sliding or Mamba-2 layers, no routed experts, no qk_norm over the "
+                    "whole projection, no head-wise gate"
+                )
         if self.hybrid:
             if self.mamba_n_groups != 1 or not self.mamba_conv_bias or self.mamba_proj_bias:
                 raise ValueError(
@@ -533,7 +616,27 @@ class LlamaConfig:
     def layers_by_kind(self) -> bool:
         """Are the layers' weights one stack per kind (a dict of stacks)
         and not one stack of like layers?"""
-        return self.hybrid or bool(self.leading_dense) or self.sliding
+        return self.hybrid or bool(self.leading_dense) or self.sliding or self.linear
+
+    @property
+    def linear(self) -> bool:
+        """Does any layer hold a lightning linear-attention mixer (and so a
+        decaying state a slot, which is not rows)?"""
+        return "lightning" in self.layer_kinds
+
+    @property
+    def num_lightning_layers(self) -> int:
+        return self.layer_kinds.count("lightning")
+
+    @property
+    def blocks(self) -> bool:
+        """Do the attention layers read blocks chosen by pooled keys' scores
+        (so a ring of pooled keys beside K and V)?"""
+        return self.sparse_config is not None
+
+    @property
+    def block_sizes(self) -> BlockSizes:
+        return BlockSizes(**dict(self.sparse_config))
 
     @property
     def sliding(self) -> bool:
@@ -561,7 +664,7 @@ class LlamaConfig:
 
     @property
     def num_attention_layers(self) -> int:
-        return self.num_hidden_layers - self.num_mamba_layers
+        return self.num_hidden_layers - self.num_mamba_layers - self.num_lightning_layers
 
     @property
     def latent(self) -> bool:
@@ -786,6 +889,8 @@ class LlamaConfig:
                 known.pop("num_local_experts", None)
             known.setdefault("q_chunk_size", raw.get("q_chunk_size", 512))
             known.setdefault("router_aux_loss_coef", 0.0)
+        if raw.get("model_type") == "minicpm_sala":
+            known.update(_sala_keys(raw, known.get("num_hidden_layers", cls.num_hidden_layers), known))
         if raw.get("model_type") == "laguna":
             known.update(_laguna_keys(raw, known.get("num_hidden_layers", cls.num_hidden_layers)))
             if known.get("num_local_experts") == known.get("num_experts"):
@@ -819,6 +924,20 @@ class LlamaConfig:
             )
         if d["rope_yarn"] is not None:
             d["rope_yarn"] = dict(self.rope_yarn)
+        if self.linear or self.blocks:
+            names = {"lightning": "lightning-attn", "attention": "minicpm4"}
+            d.update(
+                architectures=["MiniCPMSALAForCausalLM"], model_type="minicpm_sala",
+                mixer_types=[names[k] for k in self.layer_kinds], qk_norm=self.qk_norm_per_head,
+                attn_use_rope=False, lightning_use_rope=True, lightning_scale="1/sqrt(d)",
+                lightning_nh=self.num_attention_heads, lightning_nkv=self.num_attention_heads,
+                lightning_head_dim=self.head_dim, use_output_gate=True, use_output_norm=True,
+                attn_use_output_gate=self.attention_gate_type == "elementwise",
+                sparse_config=None if self.sparse_config is None else dict(self.sparse_config),
+                lightning_decays=[list(r) for r in self.lightning_decays],
+            )
+            del d["layer_types"]
+            return d
         if self.sliding and not self.latent:
             kinds = self.layer_kinds
             heads = {"sliding": self.swa_num_attention_heads}
@@ -883,6 +1002,55 @@ class LlamaConfig:
 
     def num_params(self) -> int:
         return sum(x.size for x in jax.tree.leaves(shapes(self)))
+
+
+def _sala_keys(raw: dict, depth: int, known: dict) -> dict:
+    """A published ``minicpm_sala`` config's keys as ``LlamaConfig``'s, for the
+    leading ``depth`` layers: the kinds from ``mixer_types`` (a file cut in
+    depth keeps the published list whole: its length is the published depth,
+    which the residual scaling ``scale_depth / sqrt(depth)`` and the decays'
+    rule keep); the MiniCPM family's three constant scalings under the fields
+    a granite hybrid's have; ``qk_norm`` as the RMSNorm per head it is in this
+    family; the selection's sizes from ``sparse_config`` (MiniCPM4's, no key of
+    the catalog's row: a file states them under that key) and the decays from
+    ``lightning_decays`` or, where a file states none, by the family's rule.
+    What the block is not written for is refused by name and never read past."""
+    mixers = tuple(raw.get("mixer_types") or ())
+    heads = raw.get("num_attention_heads")
+    head_dim = raw.get("head_dim") or raw.get("hidden_size", 0) // max(heads or 1, 1)
+    for key, want in (
+        ("attention_bias", False), ("hidden_act", "silu"), ("attn_use_rope", False),
+        ("lightning_use_rope", True), ("lightning_scale", "1/sqrt(d)"),
+        ("lightning_nh", heads), ("lightning_nkv", heads), ("lightning_head_dim", head_dim),
+        ("use_output_gate", True), ("use_output_norm", True), ("rope_scaling", None),
+    ):
+        if raw.get(key, want) != want:
+            raise ValueError(f"a minicpm_sala stack is written for {key} {want!r}; got {raw[key]!r}")
+    if len(mixers) < depth or set(mixers) - {"minicpm4", "lightning-attn"}:
+        raise ValueError(
+            f"a minicpm_sala stack names its {depth} layers in mixer_types, each 'minicpm4' or "
+            f"'lightning-attn'; got {len(mixers)}: {sorted(set(mixers))}"
+        )
+    published = len(mixers)
+    lightning_at = [i for i, m in enumerate(mixers[:depth]) if m == "lightning-attn"]
+    keys = dict(
+        layer_types=tuple("lightning" if m == "lightning-attn" else "attention" for m in mixers[:depth]),
+        qk_norm=False, qk_norm_per_head=bool(raw.get("qk_norm", False)),
+        position_embedding_type="nope",
+        attention_gate_type="elementwise" if raw.get("attn_use_output_gate", False) else "none",
+    )
+    if "scale_emb" in raw:  # h_0 = scale_emb E[id]
+        keys["embedding_multiplier"] = float(raw["scale_emb"])
+    if "scale_depth" in raw:  # a branch enters under scale_depth / sqrt(the published depth)
+        keys["residual_multiplier"] = float(raw["scale_depth"]) / math.sqrt(published)
+    if "dim_model_base" in raw:  # the head reads norm(h) / (hidden_size / dim_model_base)
+        keys["logits_scaling"] = raw["hidden_size"] / float(raw["dim_model_base"])
+    if raw.get("sparse_config") is not None:
+        keys["sparse_config"] = tuple(dict(raw["sparse_config"]).items())
+    keys["lightning_decays"] = known.get("lightning_decays") or lightning.decay_rates(
+        lightning_at, int(heads), published
+    )
+    return keys
 
 
 def _laguna_keys(raw: dict, depth: int) -> dict:
@@ -1010,6 +1178,17 @@ def kind_view(cfg: LlamaConfig, kind: str) -> LlamaConfig:
     return dataclasses.replace(cfg, **own, sliding_window_size=0)
 
 
+@functools.lru_cache(maxsize=None)
+def lightning_view(cfg: LlamaConfig) -> LlamaConfig:
+    """The configuration as a lightning layer's projections see it: heads of
+    ``head_dim`` without grouping, rotated by ``rope_theta`` (``_qkv``, ``_rope``
+    serve it); a view sizes one layer, not a stack."""
+    return dataclasses.replace(
+        cfg, layer_types=None, num_key_value_heads=None, position_embedding_type="rope",
+        sparse_config=None, lightning_decays=None, attention_gate_type="none",
+    )
+
+
 def latent_rescale(cfg: LlamaConfig) -> tuple[float, float]:
     """(s_q, s_kv): what the normed query and key-value latents are scaled by
     under ``apply_mla_qkv_lora_rescale``, (hidden_size / rank)^1/2 each; (1, 1)
@@ -1109,6 +1288,8 @@ def shapes(cfg: LlamaConfig) -> dict:
         }
         if view.attention_gate_type == "headwise":
             leaves["attn_gate"] = (D, H)
+        if view.attention_gate_type == "elementwise":  # a value each of the output's
+            leaves["attn_gate"] = (D, H * Dh)
         return leaves
 
     of_kind = latent_leaves if cfg.latent else gqa_leaves
@@ -1154,6 +1335,15 @@ def shapes(cfg: LlamaConfig) -> dict:
         layers = {"mamba": stack(cfg.num_mamba_layers, norms, mixer, ffn)}
         if cfg.num_attention_layers:
             layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
+    elif cfg.linear:
+        mixer = {
+            "q_proj": (D, Nh * Dh), "k_proj": (D, Nh * Dh), "v_proj": (D, Nh * Dh),
+            "out_gate": (D, Nh * Dh), "o_proj": (Nh * Dh, D), "q_norm": (Dh,), "k_norm": (Dh,),
+            "out_norm": (Nh * Dh,),
+        }
+        layers = {"lightning": stack(cfg.num_lightning_layers, norms, mixer, ffn)}
+        if cfg.num_attention_layers:
+            layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
     elif cfg.sliding:
         kinds = cfg.layer_kinds
         sliding = of_kind(kind_view(cfg, "sliding"))
@@ -1195,7 +1385,7 @@ def mixer_of(kind: str) -> str:
     """A kind of layer's mixer, which is what names its past's store: "mamba",
     "sliding" (attention under a window over a ring of its own that wraps) or
     "attention" (the "dense" kind differs from "attention" in its FFN alone)."""
-    return kind if kind in ("mamba", "sliding") else "attention"
+    return kind if kind in ("mamba", "sliding", "lightning") else "attention"
 
 
 def layer_runs(cfg: LlamaConfig) -> list[Run]:
@@ -1203,7 +1393,7 @@ def layer_runs(cfg: LlamaConfig) -> list[Run]:
     attention layers, 5 Mamba / 1 attention / 4 Mamba for a period of the
     granite hybrid, 1 dense / 23 attention for a routed stack behind a
     leading dense layer. Each forward scans each run."""
-    runs, seen, past = [], {}, {"attention": 0, "mamba": 0, "sliding": 0}
+    runs, seen, past = [], {}, {"attention": 0, "mamba": 0, "sliding": 0, "lightning": 0}
     for kind in cfg.layer_kinds:
         if runs and runs[-1].kind == kind:
             runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
@@ -1595,14 +1785,19 @@ def _qkv_rows(cfg: LlamaConfig, x: jax.Array, layer: dict):
     return q, k, v
 
 
-def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin):
+def _qkv(cfg: LlamaConfig, x: jax.Array, layer: dict, cos, sin, whole_rows: bool = False):
     """``_qkv_rows`` split into heads: q [B, T, Nh, Dh] and k [B, T, Nkv, Dh]
     rotated by position (as they are where ``cos`` is None), and v; q and k
     under an RMSNorm per head first where the configuration has one
-    (``qk_norm_per_head``)."""
+    (``qk_norm_per_head``). ``whole_rows``: the projections' rows are made
+    whole before a head is split off (a decode step's lightning layers: fused
+    with the norm per head, the chip's compiler wants each projection's stack
+    of all layers in another order and copies it in every step)."""
     B, T, _ = x.shape
     Nh, Nkv, Dh = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     q, k, v = _qkv_rows(cfg, x, layer)
+    if whole_rows:
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
     q, k, v = q.reshape(B, T, Nh, Dh), k.reshape(B, T, Nkv, Dh), v.reshape(B, T, Nkv, Dh)
     if cfg.qk_norm_per_head:
         q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
@@ -1897,6 +2092,51 @@ def sparse_attend(cfg: LlamaConfig):
     return attend
 
 
+def block_attend(cfg: LlamaConfig, attn_fn, length=None):
+    """The ``attend(q, k, v)`` of attention under a selection by blocks
+    (``cfg.blocks``) over a whole sequence from position 0 (training,
+    evaluation, a whole-prompt prefill of ``length`` real tokens): the pooled
+    keys, the scores and the choice (scope ``odtp_block_select``; under
+    ``stop_gradient``, as the family trains it), then attention over the
+    chosen blocks' rows (``odtp_block_attn``). A sequence of fewer than
+    ``dense_len`` tokens reads every row and scores nothing (``attn_fn``)."""
+    sizes = cfg.block_sizes
+
+    def attend(q, k, v):
+        t = q.shape[1]
+        if t < sizes.dense_len:
+            with jax.named_scope("odtp_block_attn"):
+                return attn_fn(q, k, v)
+        dense = jnp.broadcast_to(False if length is None else length < sizes.dense_len, (t,))
+        with jax.named_scope("odtp_block_select"):
+            chosen = jax.lax.stop_gradient(causal_block_selection(q, k, sizes, dense))
+        with jax.named_scope("odtp_block_attn"):
+            return block_sparse_attention(q, k, v, chosen, sizes.block_size)
+
+    return attend
+
+
+def lightning_mix(
+    cfg: LlamaConfig, rope, li, state=None, length=None, left: Optional[list] = None,
+    whole_rows: bool = False,
+):
+    """The ``mix(x, layer)`` of lightning layer ``li`` (traced: its index among
+    the lightning layers, which names its decays) over runs of tokens x [B, T,
+    D] that enter with ``state`` [B, H, D, D] (None: a sequence's start), of
+    which ``length`` are real; what the run leaves goes into ``left``."""
+    view = lightning_view(cfg)
+
+    def mix(x, layer):
+        q, k, v = _qkv(view, x, layer, *rope, whole_rows=whole_rows)
+        with jax.named_scope("odtp_lightning"):  # the recurrence alone: the projections lie around it
+            o, new = lightning.chunked(q, k, v, lightning.rates(cfg, li), state, length)
+        if left is not None:
+            left.append(new)
+        return lightning.gated_out(cfg, o, x, layer)
+
+    return mix
+
+
 def eva_attend(cfg: LlamaConfig, length=None, kept: Optional[list] = None, prefill: bool = False):
     """The ``attend(q, k, v, adaptive_phi, adaptive_mu_k)`` of EVA over a whole
     sequence from position 0 (training, prefill): the chunks pooled (scope
@@ -2136,6 +2376,7 @@ def decoder_block(
     router_in: Optional[jax.Array] = None,
     past: Optional[jax.Array] = None,
     index_rope: Optional[tuple] = None,
+    mix_scope: str = "odtp_ssm",
 ) -> tuple[jax.Array, BlockOut]:
     """One decoder layer over h [B, T, D], the only statement of its
     skeleton: RMSNorm, the mixer, residual; RMSNorm, FFN, residual. The
@@ -2218,14 +2459,18 @@ def decoder_block(
                 pool = _index_qkw(cfg, x, layer, *index_rope)
                 index_k = pool[1]
             o = attend(q, k, v, *pool)
-            if "attn_gate" in layer:  # one value a head, from the layer's normed input
+            if cfg.attention_gate_type == "elementwise":  # a value each, from the normed input
+                with jax.named_scope("odtp_attn_gate"):
+                    gate = jax.nn.sigmoid(x @ layer["attn_gate"])  # [B, T, Nh * Dh]
+                    o = o.reshape(B, T, -1) * gate.astype(o.dtype)
+            elif "attn_gate" in layer:  # one value a head, from the layer's normed input
                 with jax.named_scope("odtp_attn_gate"):
                     gate = jax.nn.sigmoid(x @ layer["attn_gate"])  # [B, T, Nh]
                     o = o.reshape(B, T, cfg.num_attention_heads, -1) * gate[..., None].astype(o.dtype)
             attn_out = o.reshape(B, T, -1) @ layer["o_proj"]
     else:
         k = v = None
-        with jax.named_scope("odtp_ssm"):
+        with jax.named_scope(mix_scope):
             x = _rms_norm(h, layer["input_norm"], cfg.rms_norm_eps)
             attn_out = mix(x, layer)
     h = residual(h, attn_out, "attn")
@@ -2259,8 +2504,14 @@ def training_block(
     cos, sin = _rope(view, positions)
     index_rope = _index_rope(view, positions)
     mix, attend = None, attn_fn
+    scope = "odtp_lightning_proj" if kind == "lightning" else "odtp_ssm"
+    lightning_rope = _rope(lightning_view(cfg), positions) if kind == "lightning" else None
     if kind == "mamba":
         mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer)[0]
+    elif kind == "lightning":  # its decays are the layer's: the mix is made in the body
+        pass
+    elif cfg.blocks:  # its own attention over the sequence; ``attn_fn`` under ``dense_len``
+        attend = block_attend(cfg, attn_fn)
     elif view.latent:  # the rebuilt form: multi-head attention over k and v
         attend = latent_attend(view, attn_fn)
     elif cfg.sparse:  # its own attention over the sequence: ``attn_fn`` is not asked
@@ -2276,8 +2527,9 @@ def training_block(
     def body(carry, layer, li=None):
         h, r = carry
         h, out = decoder_block(
-            cfg, h, layer, cos, sin, attend=attend, mix=mix, router_in=r,
-            index_rope=index_rope,
+            cfg, h, layer, cos, sin, attend=attend, router_in=r, index_rope=index_rope,
+            mix=lightning_mix(cfg, lightning_rope, li) if kind == "lightning" else mix,
+            mix_scope=scope,
         )
         with jax.named_scope("odtp_attention"):
             attn_norm = jnp.sqrt(jnp.sum(out.attn_out.astype(jnp.float32) ** 2))
@@ -2313,6 +2565,10 @@ def _final_norm_and_head(
     h = _block_norm(cfg, h, cparams["final_norm"])
     if cfg.logits_scaling != 1.0:  # the logits are h @ head wherever taken
         h = h / jnp.asarray(cfg.logits_scaling, h.dtype)
+        if cfg.linear:
+            # (of one row the chip's compiler would scale the head in h's place:
+            # the whole matrix widened and rounded again, in every chunk)
+            h = jax.lax.optimization_barrier(h)
     head = (
         cparams["embed_tokens"].T
         if cfg.tie_word_embeddings
@@ -2575,6 +2831,8 @@ def prefill_forward(
             attend = sparse_attend(cfg)
         elif cfg.sliding:
             attend = kinds_attend(view, causal)
+        elif cfg.blocks:
+            attend = block_attend(cfg, causal, length)
         pooling: list = []  # EVA: the chunks pooled, and each chunk's pooling as stats
         h, out = decoder_block(
             view, h, layer, cos, sin, live=live, router_in=r, index_rope=index_rope,
@@ -2609,14 +2867,25 @@ def prefill_forward(
         h, out = decoder_block(cfg, h, layer, *rope, mix=mix, live=live, router_in=r)
         return (h, out.router), (*left, (out.counts, out.experts))
 
+    def lightning_body(carry, layer, li):
+        h, r = carry
+        left: list = []
+        h, out = decoder_block(
+            cfg, h, layer, None, None, live=live, router_in=r, mix_scope="odtp_lightning_proj",
+            mix=lightning_mix(cfg, lightning_rope, li, length=length, left=left, whole_rows=True),
+        )
+        return (h, out.router), (left[0][0], (out.counts, out.experts))
+
+    lightning_rope = _rope(lightning_view(cfg), positions) if cfg.linear else None
     h = _embed(cfg, cparams, input_ids)
     r = router_carry(cfg, h)
-    kept = {"attention": ([], [], [], [], []), "mamba": ([], []), "sliding": ([], [])}
+    kept = {"attention": ([], [], [], [], []), "mamba": ([], []), "sliding": ([], []),
+            "lightning": ([],)}
     counts, experts = [], []
     for run in layer_runs(cfg):
-        body = mamba_body if run.mixer == "mamba" else _of_the_runs_kind(
-            cfg, attention_body, run, positions, rope
-        )
+        body = mamba_body if run.mixer == "mamba" else lightning_body if (
+            run.mixer == "lightning"
+        ) else _of_the_runs_kind(cfg, attention_body, run, positions, rope)
         (h, r), (*left, (c, e)) = scan_layers(
             cfg, body, (h, r), cparams["layers"], run, experts_in_place=True
         )
@@ -2637,6 +2906,8 @@ def prefill_forward(
         out.extend(map(_stacked, kept["attention"][2:]))
     if cfg.hybrid:
         out.extend(map(_stacked, kept["mamba"]))
+    if cfg.linear:  # the lightning layers' states as the last real token left them
+        out.append(_stacked(kept["lightning"][0]))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_expert_choices:
@@ -2675,6 +2946,9 @@ def decode_forward(
     eva_state: Optional[tuple] = None,
     index_cache: Optional[jax.Array] = None,
     return_row_choices: bool = False,
+    pooled_cache: Optional[jax.Array] = None,
+    lightning_state: Optional[jax.Array] = None,
+    return_block_tiles: bool = False,
 ):
     """One incremental decode step over all S slots.
 
@@ -2732,6 +3006,26 @@ def decode_forward(
     ``ops.attention.sparse_decode_step_attention``). The ring comes back after
     the caches; no program copies a ring. With ``return_row_choices`` each slot's chosen rows in each layer [L,
     S, T] bool come last of all.
+
+    A stack of lightning layers and attention under a selection by blocks
+    takes ``lightning_state`` [Ll, S, H, D, D] float32
+    (``ring_cache.init_lightning_state``) and ``pooled_cache`` (the pooled-key
+    ring beside K and V, ``ring_cache.init_pooled_cache``) and returns both
+    after the caches. A lightning layer reads its part of the state, decays
+    it, adds the step's k^T v and writes it back in place; a slot at ``lens``
+    0 (it may be one whose prompt is arriving in chunks) keeps its state. An
+    attention layer reads all three rings **as the step found them**: where the
+    step's key closes a pooling window its pooled key is made from the ring's
+    rows and the key itself and enters the scores beside the ring
+    (``ops.attention.closing_pooled_key``), the chosen blocks' rows before the
+    step's own are read (``decode_kernels.block_decode_attention``: tiles that
+    hold no chosen block stay unread; off the TPU the gather of
+    ``ops.attention.block_decode_step_attention``) and the step's own row is
+    merged in under the one softmax; the layers' K, V and pooled keys are
+    written behind the scan (``index_ring_write``), nothing for a slot at
+    ``lens`` 0. With ``return_block_tiles`` the ring tiles that held a chosen
+    block, over slots, KV heads and layers ([1] int32), come after the counts;
+    with ``return_row_choices`` the chosen blocks [Ls, S, Kh, blocks] last.
 
     With ``return_moe_counts`` the routed FFN's counts over the slots that
     hold a sequence (``lens > 0``), summed over layers, come last, and with
@@ -2875,11 +3169,73 @@ def decode_forward(
         h, out = decoder_block(cfg, h, layer, *rope, mix=mix, live=live, router_in=r)
         return (h, out.router, states, tails), (out.counts, out.experts, None, None)
 
+    def lightning_body(carry, layer, li):
+        h, r, states = carry  # every lightning layer's
+
+        def mix(x, layer):
+            nonlocal states
+            q, k, v = _qkv(lightning_view(cfg), x, layer, *lightning_rope, whole_rows=True)
+            with jax.named_scope("odtp_lightning"):
+                o, new = lightning.step(
+                    q[:, 0], k[:, 0], v[:, 0], lightning.rates(cfg, li), states[li], live
+                )
+                states = jax.lax.dynamic_update_index_in_dim(states, new, li, 0)
+            return lightning.gated_out(cfg, o, x[:, 0], layer)[:, None]
+
+        h, out = decoder_block(
+            cfg, h, layer, None, None, mix=mix, live=live, router_in=r, mix_scope="odtp_lightning_proj"
+        )
+        return (h, out.router, states), (out.counts, out.experts)
+
+    def block_body(carry, layer, li):
+        # the three rings as the step found them; what the step writes goes out
+        h, r = carry
+        wrote: list = []
+
+        def attend(q, k, v):
+            sizes = cfg.block_sizes
+            q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
+            with jax.named_scope("odtp_block_select"):
+                own, j, closes = closing_pooled_key(
+                    cache_k, li, k1, lens, sizes, ring_rows_sum if pallas else xla_ring_rows_sum
+                )
+                own = own.astype(pooled_cache.dtype)
+                page = pooled_cache[li]  # [S, Kh, D, Tp]
+                here = (jnp.arange(page.shape[-1]) == j[:, None]) & closes[:, None]
+                page = jnp.where(here[:, None, None], own[..., None], page)
+                chosen = jax.vmap(
+                    lambda qs, ps, at, dn: block_selection(
+                        qs[None], ps, at[None], dn[None], sizes, sizes.blocks(ring_rows(cache_k))
+                    )[:, 0]
+                )(q1, page, lens, lens + 1 < sizes.dense_len)  # [S, Kh, blocks]
+                chosen = chosen & live[:, None, None]
+            with jax.named_scope("odtp_block_attn"):
+                over_blocks = block_decode_attention if pallas else block_decode_step_attention
+                out = over_blocks(q1, k1, v1, chosen, cache_k, cache_v, lens, li, sizes)
+            at = jnp.where(closes & live, j + page.shape[-1], 0)  # ``index_ring_write``'s: 0 writes nothing
+            wrote.append((k1, v1, own, at, chosen))
+            return out[:, None]
+
+        h, out = decoder_block(cfg, h, layer, None, None, live=live, router_in=r, attend=attend)
+        return (h, out.router), (out.counts, out.experts, wrote[0])
+
+    lightning_rope = _rope(lightning_view(cfg), positions) if cfg.linear else None
     h = _embed(cfg, cparams, tokens)[:, None]  # [S, 1, D]
     r = router_carry(cfg, h)
     counts, experts, rows, keys = [], [], [], []
+    written: list = []  # the block layers' (k, v, pooled key, its place, chosen blocks)
     for run in layer_runs(cfg):
-        if run.mixer != "mamba":
+        if run.mixer == "lightning":
+            (h, r, lightning_state), (c, e) = scan_layers(
+                cfg, lightning_body, (h, r, lightning_state), cparams["layers"], run,
+                experts_in_place=True,
+            )
+        elif cfg.blocks:
+            (h, r), (c, e, wrote) = scan_layers(
+                cfg, block_body, (h, r), cparams["layers"], run, experts_in_place=True,
+            )
+            written.append(wrote)
+        elif run.mixer != "mamba":
             (h, r, cache_k, cache_v, cca_state, eva_state), (c, e, chose, wrote) = scan_layers(
                 cfg, _of_the_runs_kind(cfg, attention_body, run, positions, rope),
                 (h, r, cache_k, cache_v, cca_state, eva_state),
@@ -2906,10 +3262,28 @@ def decode_forward(
             out.append(write(index_cache, _stacked([k for k in keys if k is not None]), lens))
     if cfg.hybrid:
         out.extend((ssm_state, conv_state))
+    block_tiles = None
+    if cfg.blocks:  # the step's rows and pooled keys, every block layer's, behind the layers
+        ks, vs, pooled, at, chose = (jnp.concatenate(x) for x in zip(*written))
+        write = index_ring_write if pallas else index_write_rows
+        merged = lambda x: x.reshape(*x.shape[:2], -1, *x.shape[4:])  # KV heads and their values as one axis
+        with jax.named_scope("odtp_block_attn"):
+            out[1] = write(merged(cache_k), merged(ks), lens).reshape(cache_k.shape)
+            out[2] = write(merged(cache_v), merged(vs), lens).reshape(cache_v.shape)
+        with jax.named_scope("odtp_block_select"):
+            # a window closes at the same step in every layer: the first layer's place
+            out.append(write(merged(pooled_cache), merged(pooled), at[0]).reshape(pooled_cache.shape))
+            block_tiles = block_tiles_held(chose, lens, cfg.block_sizes, ring_rows(cache_k))
+        if return_row_choices:
+            rows = [chose]
+    if cfg.linear:
+        out.append(lightning_state)
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_expert_choices:
         out.append(_chosen(cfg, experts))
+    if return_block_tiles:
+        out.append(jnp.zeros((1,), jnp.int32) if block_tiles is None else block_tiles)
     if return_row_choices:  # the layers with an indexer, in order
         out.append(_stacked([x for x in rows if x is not None]))
     return tuple(out)
@@ -2932,6 +3306,10 @@ def chunk_prefill_forward(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     return_moe_counts: bool = False,
     return_row_choices: bool = False,
+    pooled_cache: Optional[jax.Array] = None,
+    lightning_state: Optional[jax.Array] = None,
+    total=None,
+    return_block_tiles: bool = False,
 ):
     """A run of a prompt's tokens over a slot that holds the rows before them:
     a chunk of a prompt admitted in chunks, or the suffix behind a reused
@@ -2963,6 +3341,25 @@ def chunk_prefill_forward(
     that holds nothing of it yet; at ``plen + count <= index_topk`` every row
     is chosen.
 
+    A stack of lightning layers and attention under a selection by blocks
+    also takes ``pooled_cache`` and ``lightning_state`` (``decode_forward``) and
+    ``total``, the whole prompt's length (traced; it decides ``dense_len``'s
+    side for every chunk of the prompt), and returns both after the index
+    ring's place. **A chunk enters with the state the chunk before left**: a
+    lightning layer reads ``slot``'s state (nothing where ``plen`` is 0: a
+    prompt starts from zeros, whatever the slot's last tenant left), runs the
+    chunked form over the chunk's real tokens and writes the state back in
+    place. An attention layer writes the chunk's K and V rows, pools the
+    windows that close inside the chunk **from the ring's own rows** (a window
+    may start in the chunk before), scores the slot's pooled keys, those
+    among them, chooses each query's blocks and attends over the slot's pages
+    a tile at a time (``ops.attention.tiled_block_attention``); the new pooled
+    keys of all layers are written behind the scan
+    (``ring_cache.pooled_chunk_insert``). The chunk is whole strides and goes in
+    from row 0 in whole chunks. With ``return_block_tiles`` the tiles the
+    attention visited, over layers ([1] int32), come after the counts; with
+    ``return_row_choices`` the last real token's chosen blocks [Ls, Kh, blocks].
+
     With ``return_moe_counts`` the routed FFN's counts over the real tokens
     come after, and with ``return_row_choices`` then the rows the last real
     token read in each layer [L, T] bool."""
@@ -2986,7 +3383,7 @@ def chunk_prefill_forward(
     live = jnp.arange(C)[None] < count
     T = ring_rows(cache_k)
     tile = min(cfg.q_chunk_size or _SUFFIX_TILE, T)
-    if cfg.sliding and not cfg.latent:  # the chunk is the engine's: the tile stays a tile
+    if (cfg.sliding and not cfg.latent) or cfg.blocks:  # the chunk is the engine's: the tile stays a tile
         tile = min(_SUFFIX_TILE, T)
     tile = tile if T % tile == 0 else T  # a ring of no whole tiles: one tile
     seen = jnp.arange(T)[None] <= positions[0][:, None]  # [C, T]: the rows up to a query's own
@@ -3106,11 +3503,89 @@ def chunk_prefill_forward(
         h, out = decoder_block(view, h, layer, *rope, live=live, attend=attend)
         return (h, ck, cv), (out.counts, None, None)
 
+    def lightning_body(carry, layer, li):
+        h, states = carry  # every lightning layer's, every slot's
+        H, Dh = cfg.num_attention_heads, cfg.head_dim
+        where = (li, jnp.asarray(slot, jnp.int32), *(jnp.int32(0),) * 3)
+        entering = jax.lax.dynamic_slice(states, where, (1, 1, H, Dh, Dh))[0]
+        left: list = []
+        h, out = decoder_block(
+            cfg, h, layer, None, None, live=live, mix_scope="odtp_lightning_proj",
+            mix=lightning_mix(
+                cfg, lightning_rope, li, jnp.where(plen > 0, entering, 0.0), count, left,
+                whole_rows=True,
+            ),
+        )
+        return (h, jax.lax.dynamic_update_slice(states, left[0][None], where)), out.counts
+
+    def block_body(carry, layer, li):
+        h, ck, cv = carry
+        sizes = cfg.block_sizes
+        stride, before = sizes.kernel_stride, sizes.kernel_size // sizes.kernel_stride - 1
+        kept: list = []
+
+        def attend(q, k, v):
+            nonlocal ck, cv
+            ck, cv = layer_rows_insert(ck, cv, li, slot, k[0], v[0], plen, count)
+            with jax.named_scope("odtp_block_select"):
+                # the windows that close inside the chunk, C / stride of them
+                # from window ``first`` on, pooled from the ring's own rows: a
+                # slice from a whole tile of lanes before the chunk on (one cut
+                # between two lanes makes the compiler re-lay the whole ring)
+                first = jnp.maximum(plen // stride - before, 0)
+                zero = jnp.int32(0)
+                lanes = math.gcd(C, 128)
+                lead = -(-before * stride // lanes) * lanes
+                start = jnp.maximum(plen - lead, 0)
+                rows = jax.lax.dynamic_slice(
+                    ck, (li, jnp.asarray(slot, jnp.int32), zero, zero, start),
+                    (1, 1, *ck.shape[2:4], C + lead),
+                )[0, 0]
+                own = pool_pages(rows, sizes, first * stride - start, C // stride)  # [Kh, D, C / stride]
+                windows = first + jnp.arange(C // stride)
+                closes = stride * windows + sizes.kernel_size - 1 < plen + count
+                page = slot_layer_pages(pooled_cache, li, slot)  # [Kh, D, Tp]
+                old = jax.lax.dynamic_slice_in_dim(page, first, C // stride, 2)
+                new = jnp.where(closes, own.astype(page.dtype), old)
+                page = jax.lax.dynamic_update_slice_in_dim(page, new, first, 2)
+                chosen = block_selection(
+                    q[0], page, positions[0], jnp.broadcast_to(total < sizes.dense_len, (C,)),
+                    sizes, sizes.blocks(T),
+                )
+            with jax.named_scope("odtp_block_attn"):
+                out, visited = tiled_block_attention(
+                    q[0], slot_layer_pages(ck, li, slot), slot_layer_pages(cv, li, slot), chosen,
+                    positions[0], plen + count, tile, sizes.block_size,
+                )
+            kept.append((new, first, visited, jax.lax.dynamic_index_in_dim(chosen, count - 1, 1, False)))
+            return out[None]
+
+        h, out = decoder_block(cfg, h, layer, None, None, live=live, attend=attend)
+        return (h, ck, cv), (out.counts, None, kept[0])
+
     ci = index_cache  # read by every layer as the run found it
     h = _embed(cfg, cparams, ids)
     counts, rows, keys = [], [], []
+    if cfg.linear or cfg.blocks:
+        if cfg.blocks and (
+            C % cfg.block_sizes.kernel_stride or tile % cfg.block_sizes.block_size or T <= C
+        ):
+            raise ValueError(
+                f"a chunk of {C} tokens over a ring of {T} rows in tiles of {tile}: under a "
+                "selection by blocks a chunk is whole strides, a tile whole blocks and the "
+                "ring longer than a chunk"
+            )
+        lightning_rope = _rope(lightning_view(cfg), positions)
+        total = jnp.asarray(plen + count if total is None else total, jnp.int32)
     for run in layer_runs(cfg):
-        of_kind = body
+        if run.mixer == "lightning":
+            (h, lightning_state), c = scan_layers(
+                cfg, lightning_body, (h, lightning_state), cparams["layers"], run,
+                experts_in_place=True,
+            )
+            counts.append(c)
+            continue
+        of_kind = block_body if cfg.blocks else body
         if cfg.latent or cfg.sliding:  # each run under its kind's view and rope tables
             view = kind_view(cfg, run.kind)
             rope = _rope(view, positions) if run.kind == "sliding" else (cos, sin)
@@ -3129,8 +3604,19 @@ def chunk_prefill_forward(
             index_cache = index_chunk_insert(index_cache, slot, wrote, plen, count)
     h_last = jax.lax.dynamic_slice_in_dim(h, count - 1, 1, axis=1)
     out = [_logits(cfg, cparams, h_last)[:, 0], cache_k, cache_v, index_cache]
+    block_tiles = None
+    if cfg.blocks:  # the windows the chunk closed, every block layer's, behind the layers
+        new, first, visited, last = (jnp.concatenate(x) for x in zip(*keys))
+        with jax.named_scope("odtp_block_select"):
+            out.append(pooled_chunk_insert(pooled_cache, slot, new, first[0]))
+        block_tiles = jnp.sum(visited).astype(jnp.int32).reshape(1)
+        rows = [last]
+    if cfg.linear:
+        out.append(lightning_state)
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
+    if return_block_tiles:
+        out.append(jnp.zeros((1,), jnp.int32) if block_tiles is None else block_tiles)
     if return_row_choices:  # the layers with an indexer, in order
         out.append(_stacked([x for x in rows if x is not None]))
     return tuple(out)

@@ -212,6 +212,46 @@ def state_insert(
     return put(ssm, states), put(conv, tails)
 
 
+def init_lightning_state(cfg, num_slots: int) -> jax.Array:
+    """Zeroed [Ll, S, H, D, D] float32 for ``cfg``'s lightning layers
+    (``models/lightning.py``): per layer and slot the decaying state, float32
+    whatever the compute dtype. Zero is a sequence's start; a prompt's first
+    chunk starts from zeros whatever the slot holds, so nothing clears a
+    slot between tenants."""
+    from opendiloco_tpu.models import lightning
+
+    return jnp.zeros(lightning.state_shape(cfg, num_slots), jnp.float32)
+
+
+def init_pooled_cache(
+    cfg, num_slots: int, max_context: int, dtype: jnp.dtype = jnp.bfloat16
+) -> jax.Array:
+    """Zeroed pooled-key ring for attention under a selection by blocks
+    (``cfg.blocks``), ``[Ls, S, Nkv, Dh, T / kernel_stride]`` beside the
+    attention layers' ``(k, v)`` ring, rows minor-most as theirs: window j (the
+    mean of the keys of rows [stride j, stride j + kernel)) at row j for the
+    sequence's whole life, written when row ``stride j + kernel - 1`` is (by
+    the chunk that holds it, by the decode step at it) **from the K ring's own
+    rows**: no slot keeps a half-pooled window, and a reader at position p
+    takes the windows that have closed, ``stride j + kernel - 1 <= p``. As the
+    index ring, no program writes it inside its scan over the layers."""
+    sizes = cfg.block_sizes
+    return jnp.zeros(
+        cache_shape(cfg.num_attention_layers, num_slots, sizes.pooled_rows(int(max_context)),
+                    cfg.kv_heads, cfg.head_dim), dtype,
+    )
+
+
+def pooled_chunk_insert(cache_p: jax.Array, slot, pooled: jax.Array, first) -> jax.Array:
+    """A prefill chunk's pooled keys of all layers, pooled [Ls, Nkv, Dh, n] in
+    storage order (the windows from ``first`` on; those the chunk did not
+    close already hold what the ring held), into ``slot`` at ring rows
+    [first, first + n) (``slot``, ``first`` traced)."""
+    zero = jnp.int32(0)
+    where = (zero, jnp.asarray(slot, jnp.int32), zero, zero, jnp.asarray(first, jnp.int32))
+    return jax.lax.dynamic_update_slice(cache_p, pooled[:, None].astype(cache_p.dtype), where)
+
+
 def init_cca_state(cfg, num_slots: int, dtype: jnp.dtype = jnp.bfloat16) -> jax.Array:
     """Zeroed [L, S, ``cfg.cca_state_dim``]: per layer and slot what CCA's
     projection reads of the token before (``llama._cca_qkv``: q and k before

@@ -10,7 +10,7 @@ configuration with several, the first is the one a refusal names.
 from __future__ import annotations
 
 # a ``LlamaConfig`` property each, in the order ``LlamaConfig.traits`` keeps
-TRAITS = ("hybrid", "cca", "eva", "sparse", "sliding", "latent")
+TRAITS = ("hybrid", "cca", "eva", "sparse", "sliding", "latent", "linear", "blocks")
 
 # features that copy, cut, page out or restore a slot's past as the rows of
 # one (k, v) ring from row 0
@@ -50,6 +50,18 @@ _ROWS = {
         "holds one row of {cfg.latent_row_dim} values a token, from which k and v are "
         "not rebuilt"
     ),
+    "linear": (
+        "a configuration with lightning linear-attention layers "
+        "({cfg.num_lightning_layers} of {cfg.num_hidden_layers}): it treats a slot's past "
+        "as cache rows, and a decaying state is not rows and cannot be cut at one"
+    ),
+    "blocks": (
+        "a configuration with attention under a selection by blocks (sparse_config, "
+        "{cfg.block_sizes.topk} blocks of {cfg.block_sizes.block_size} rows): a KV head keeps "
+        "a ring of pooled keys beside K and V that this neither copies nor rebuilds, and a "
+        "query's attention reads the blocks those keys' scores chose, which this does not "
+        "compute"
+    ),
 }
 
 # feature -> (what a refusal calls it where the caller gives no name of its
@@ -60,7 +72,10 @@ REFUSALS = {
     "page_out": ("the host tier's page-out", _ROWS),
     "page_in": ("the host tier's page-in", _ROWS),
     # over an index ring, latent rows and a ring that wraps it goes in whole
-    # chunks from row 0 (``chunk_prefill_forward``)
+    # chunks from row 0 (``chunk_prefill_forward``); a lightning layer's chunk
+    # enters with the slot's state and leaves the next one's, and a selection
+    # by blocks pools the windows a chunk closes from the ring's own rows:
+    # ``linear`` and ``blocks`` have no row here
     "continued_prefill": (
         "the continued prefill (a prompt's chunks, the suffix behind a reused prefix)",
         {trait: _ROWS[trait] for trait in ("cca", "hybrid", "eva")},
@@ -80,6 +95,11 @@ REFUSALS = {
         ),
         "eva": _ROWS["eva"],
         "sparse": _ROWS["sparse"],
+        "linear": (
+            "a configuration with lightning linear-attention layers: the kernels are causal "
+            "softmax attention over every row, and training runs this stack in the XLA forms"
+        ),
+        "blocks": _ROWS["blocks"],
     }),
 }
 

@@ -18,6 +18,10 @@ arrays with grouped-query support (num_q_heads % num_kv_heads == 0).
 
 from __future__ import annotations
 
+import functools
+import math
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -28,6 +32,7 @@ from opendiloco_tpu.models.ring_cache import (
     write_live_row,
     write_row,
 )
+from opendiloco_tpu.ops.pallas_util import NEG_INF
 
 
 def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
@@ -698,3 +703,310 @@ def sparse_decode_step_attention(
     cache_k, cache_v = write_live_row(cache_k, cache_v, layer, k, v, lens)
     out = decode_attention(q, *layer_pages(cache_k, cache_v, layer), lens, chosen)
     return out, cache_k, cache_v
+
+
+# ---------------------------------------------------------------------------
+# Attention under a selection by blocks (MiniCPM4's trainable sparse attention,
+# arXiv 2506.07900, as a ``minicpm_sala`` stack's ``minicpm4`` layers run it):
+# beside K and V a KV head keeps one *pooled key* per window of ``kernel`` rows
+# every ``stride`` rows (their mean); a query scores the windows that have
+# closed before it under a softmax per head, the heads of a KV group add up,
+# a block of ``block`` rows takes the largest score of the windows that overlap
+# it, and the query's attention reads ``topk`` blocks: the first
+# ``init_blocks``, the ``window // block`` that end with its own, and the
+# best-scored of the others, one choice for all the heads of a group. While a
+# sequence holds fewer than ``dense_len`` rows every row is read. The plain
+# forms: pooling, scores, the exact choice, attention under it over a whole
+# sequence (training, a whole prompt), over a slot's ring in row tiles (a
+# prompt's chunk; a tile no query chose is stepped over) and a decode step's
+# over the chosen blocks alone, gathered (the XLA stand-in and reference of
+# ``decode_kernels.block_decode_attention``).
+# ---------------------------------------------------------------------------
+
+
+# query heads of a KV group whose scores over the pooled keys are held at once
+# (a chunk of 2,048 queries over 2,176 pooled keys: 71 MB of float32 a head group)
+_SCORE_HEADS = 4
+
+
+class BlockSizes(NamedTuple):
+    """The sizes of a selection by blocks (``LlamaConfig.sparse_config``)."""
+
+    kernel_size: int  # rows a pooled key is the mean of
+    kernel_stride: int  # rows between two windows' starts
+    block_size: int  # rows a block
+    topk: int  # blocks a query reads, the forced ones among them
+    init_blocks: int  # leading blocks every query reads
+    window_size: int  # rows before a query, as whole blocks ending with its own, always read
+    dense_len: int  # rows a sequence holds before the selection starts
+
+    @property
+    def pooled_a_block(self) -> int:
+        return self.block_size // self.kernel_stride
+
+    def pooled_rows(self, rows: int) -> int:
+        """Rows of a pooled ring beside a ring of ``rows`` rows."""
+        return rows // self.kernel_stride
+
+    def blocks(self, rows: int) -> int:
+        return -(-rows // self.block_size)
+
+    def gathered(self, rows: int) -> int:
+        """Blocks a decode step's gather holds: ``topk``, or a sequence's
+        blocks while it reads every row (under ``dense_len``)."""
+        return min(self.blocks(rows), max(self.topk, self.blocks(self.dense_len)))
+
+
+def pool_pages(pages: jax.Array, sizes: BlockSizes, first=0, windows: int | None = None) -> jax.Array:
+    """Rows-minor keys [Kh, D, T] -> the pooled keys of ``windows`` windows
+    from the one that starts at row ``first`` (traced) on (None: every window
+    that lies within the T rows from row 0) [Kh, D, windows] float32: window i
+    the mean of rows [first + stride i, first + stride i + kernel). One product
+    with the windows' 0 / 1 matrix: the rows stay on the lanes, as a ring
+    holds them."""
+    stride, kernel = sizes.kernel_stride, sizes.kernel_size
+    if windows is None:
+        windows = max((pages.shape[-1] - kernel) // stride + 1, 0)
+    row = jnp.arange(pages.shape[-1])[:, None]
+    start = first + stride * jnp.arange(windows)[None]
+    inside = ((row >= start) & (row < start + kernel)).astype(pages.dtype)
+    return jnp.einsum("gdt,tw->gdw", pages, inside, preferred_element_type=jnp.float32) / kernel
+
+
+def block_scores(q: jax.Array, pooled_t: jax.Array, at: jax.Array, sizes: BlockSizes) -> jax.Array:
+    """Queries q [C, H, D] at positions ``at`` [C] over a KV head's pooled keys
+    ``pooled_t`` [Kh, D, J] (rows minor-most, as the pooled ring holds them;
+    window j at row j) -> the groups' scores [Kh, C, J] float32: per head a
+    softmax over the windows the query sees (``stride j + kernel - 1 <= at``),
+    added up over the heads of a group; zeros where it sees none."""
+    c, h, d = q.shape
+    kh, _, j = pooled_t.shape
+    f32 = jnp.float32
+    qg = jnp.moveaxis(q.reshape(c, kh, h // kh, d), 0, 2)  # [Kh, rep, C, D]
+    closes = sizes.kernel_stride * jnp.arange(j) + sizes.kernel_size - 1
+    seen = closes[None] <= at[:, None]  # [C, J]
+
+    hb = math.gcd(h // kh, _SCORE_HEADS)
+
+    def group(xs):
+        qh, keys = xs  # [rep, C, D], [D, J]
+
+        def heads(total, qs):  # a few heads' softmaxes at a time, added up
+            s = jnp.einsum("rcd,dj->rcj", qs, keys, preferred_element_type=f32) * d**-0.5
+            s = jnp.where(seen, s, jnp.finfo(f32).min)
+            p = jnp.where(seen, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+            norm = jnp.sum(p, axis=-1, keepdims=True)
+            return total + jnp.sum(p / jnp.where(norm > 0, norm, 1.0), axis=0), None
+
+        return jax.lax.scan(heads, jnp.zeros((c, j), f32), qh.reshape(-1, hb, c, d))[0]
+
+    return jax.lax.map(group, (qg, pooled_t))
+
+
+def block_maxima(scores: jax.Array, sizes: BlockSizes, blocks: int) -> jax.Array:
+    """Window scores [..., J] -> block scores [..., blocks]: a block takes the
+    largest score of the windows that overlap it, ``j stride < (b + 1) block``
+    and ``j stride + kernel > b block``."""
+    r = sizes.pooled_a_block
+    e = -(-(sizes.kernel_size - sizes.kernel_stride) // sizes.kernel_stride)  # windows that start before it
+    j = scores.shape[-1]
+    lead = [(0, 0)] * (scores.ndim - 1)
+    padded = jnp.pad(scores, (*lead, (e, max(0, r * blocks + e - (j + e)))))
+    return functools.reduce(
+        jnp.maximum,
+        [padded[..., i : i + r * blocks : r] for i in range(r + e)],
+    )
+
+
+def choose_blocks(scores: jax.Array, at: jax.Array, dense, sizes: BlockSizes) -> jax.Array:
+    """Block scores [Kh, C, blocks] of queries at positions ``at`` [C] -> the
+    blocks each reads, bool [Kh, C, blocks]: the first ``init_blocks``, the
+    ``window_size // block_size`` that end with the query's own, and by
+    largest score (ties to the lower block) as many of the others up to its
+    own as make ``topk`` in all; every block up to its own where those are
+    fewer, and for a query that ``dense`` ([C] bool) says reads every row."""
+    b = jnp.arange(scores.shape[-1])
+    own = (at // sizes.block_size)[:, None]
+    causal = b[None] <= own  # [C, blocks]
+    forced = (b[None] < sizes.init_blocks) | (b[None] > own - sizes.window_size // sizes.block_size)
+    ranked = jnp.where(forced[None], jnp.float32(1e30), scores)
+    chosen = select_rows(ranked, jnp.broadcast_to(causal[None], scores.shape), sizes.topk)
+    return jnp.where(jnp.asarray(dense)[None, :, None], causal[None], chosen)
+
+
+def block_selection(q, pooled_t, at, dense, sizes: BlockSizes, blocks: int) -> jax.Array:
+    """:func:`block_scores`, :func:`block_maxima` and :func:`choose_blocks` in
+    one: q [C, H, D] at ``at`` [C] over pooled_t [Kh, D, J] -> bool [Kh, C,
+    blocks]."""
+    scores = block_maxima(block_scores(q, pooled_t, at, sizes), sizes, blocks)
+    return choose_blocks(scores, at, dense, sizes)
+
+
+def causal_block_selection(q: jax.Array, k: jax.Array, sizes: BlockSizes, dense) -> jax.Array:
+    """The blocks each position of whole sequences from position 0 reads: q
+    [B, T, H, D] over the sequence's own keys k [B, T, Kh, D] -> bool [B, Kh,
+    T, blocks]; ``dense`` [T] bool: the positions that read every row (the
+    caller keeps ``dense_len``)."""
+    t = k.shape[1]
+    at = jnp.arange(t)
+
+    def one(qb, kb):
+        pooled = pool_pages(jnp.moveaxis(kb, 0, -1), sizes).astype(kb.dtype)  # [Kh, D, J]
+        return block_selection(qb, pooled, at, dense, sizes, sizes.blocks(t))
+
+    return jax.vmap(one)(q, k)
+
+
+def block_sparse_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, chosen: jax.Array, block: int
+) -> jax.Array:
+    """Attention under a selection by blocks over whole sequences: q [B, T, H,
+    D], k and v [B, T, Kh, D], ``chosen`` [B, Kh, T, blocks] bool, one choice
+    for the heads of a group: a query reads the rows up to its own of its
+    chosen blocks -> [B, T, H, D]. Scores and softmax in float32."""
+    b, t, h, d = q.shape
+    kh = k.shape[2]
+    rows = jnp.repeat(chosen, block, axis=-1)[..., :t] & jnp.tril(jnp.ones((t, t), bool))
+    qg = q.reshape(b, t, kh, h // kh, d)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=jnp.float32)
+    scores = jnp.where(rows[:, :, None], scores * d**-0.5, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(b, t, h, v.shape[-1])
+
+
+def tiled_block_attention(
+    q: jax.Array, pages_k: jax.Array, pages_v: jax.Array, chosen: jax.Array, at: jax.Array,
+    live_rows, tile: int, block: int,
+):
+    """A prefill chunk's attention under its selection by blocks over one
+    slot's pages, ``tile`` rows at a time under an online softmax: q [C, H, D]
+    at positions ``at`` [C], pages_k and pages_v [Kh, D, T] (the chunk's own
+    rows in them), ``chosen`` [Kh, C, blocks] bool -> (out [C, H, D], the tiles
+    visited). Tiles from ``live_rows`` (traced) on are not visited, and of the
+    others one in which no query of the chunk chose a block is stepped over."""
+    c, h, d = q.shape
+    kh, _, t = pages_k.shape
+    f32, neg = jnp.float32, jnp.finfo(jnp.float32).min
+    qg = jnp.moveaxis(q.reshape(c, kh, h // kh, d), 0, 2)  # [Kh, rep, C, D]
+    per = tile // block
+
+    def visit(i, carry):
+        m, l, acc, visited = carry
+        first = i * tile
+        cb = jax.lax.dynamic_slice_in_dim(chosen, i * per, per, 2)  # [Kh, C, per]
+
+        def attend(_):
+            kt = jax.lax.dynamic_slice_in_dim(pages_k, first, tile, 2)  # [Kh, D, tile]
+            vt = jax.lax.dynamic_slice_in_dim(pages_v, first, tile, 2)
+            ct = jnp.repeat(cb, block, axis=-1) & (first + jnp.arange(tile)[None] <= at[:, None])
+            ct = ct[:, None]  # [Kh, 1, C, tile]
+            s = jnp.einsum("grcd,gdt->grct", qg, kt, preferred_element_type=f32) * d**-0.5
+            s = jnp.where(ct, s, neg)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.where(ct, jnp.exp(s - m_new[..., None]), 0.0)
+            keep = jnp.exp(m - m_new)
+            return m_new, l * keep + jnp.sum(p, axis=-1), acc * keep[..., None] + jnp.einsum(
+                "grct,gdt->grcd", p.astype(q.dtype), vt, preferred_element_type=f32
+            ), visited + 1
+
+        return jax.lax.cond(jnp.any(cb), attend, lambda _: carry, None)
+
+    shape = (kh, h // kh, c)
+    init = (jnp.full(shape, neg, f32), jnp.zeros(shape, f32), jnp.zeros((*shape, d), f32),
+            jnp.int32(0))
+    tiles = (jnp.asarray(live_rows, jnp.int32) + tile - 1) // tile
+    _, l, acc, visited = jax.lax.fori_loop(0, jnp.minimum(tiles, t // tile), visit, init)
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return jnp.moveaxis(out, 2, 0).reshape(c, h, d).astype(q.dtype), visited
+
+
+def ring_rows_sum(cache_k: jax.Array, layer, first: jax.Array, count: int) -> jax.Array:
+    """The sum over ring rows [first, first + count) of ``layer``'s pages, a
+    slot each (``first`` [S], within the ring) -> [S, Kh, D] float32, in XLA (a
+    gather of the slots' rows): the reference of
+    ``decode_kernels.ring_rows_sum`` and the path off the TPU (on the chip the
+    gather makes the compiler re-lay the whole ring rows major-most)."""
+    zero, li = jnp.int32(0), jnp.asarray(layer, jnp.int32)
+
+    def rows(slot, start):  # [Kh, D, count] of one slot's page, where it lies
+        shape = (1, 1, *cache_k.shape[2:4], count)
+        return jax.lax.dynamic_slice(cache_k, (li, slot, zero, zero, start), shape)[0, 0]
+
+    past = jax.vmap(rows)(jnp.arange(cache_k.shape[1], dtype=jnp.int32), first)
+    return jnp.sum(past.astype(jnp.float32), axis=-1)
+
+
+def closing_pooled_key(
+    cache_k: jax.Array, layer, k: jax.Array, lens: jax.Array, sizes: BlockSizes, rows_sum=ring_rows_sum,
+):
+    """The pooled key a decode step closes, where it closes one: each slot's
+    step's key k [S, Kh, D] at position ``lens`` [S] ends the window of rows
+    [lens - kernel + 1, lens] when ``lens + 1`` is a multiple of the stride
+    past a first whole window -> (the window's mean [S, Kh, D] float32 from
+    ``layer``'s ring rows before ``lens`` (``rows_sum``: :func:`ring_rows_sum`
+    or the kernel of its name) and the step's own key, which the ring need not
+    hold yet; the window j it is; whether the slot closes one)."""
+    kernel, stride = sizes.kernel_size, sizes.kernel_stride
+    closes = (lens >= kernel - 1) & (jnp.mod(lens + 1, stride) == 0)
+    first = jnp.clip(lens - (kernel - 1), 0, ring_rows(cache_k) - kernel)
+    total = rows_sum(cache_k, layer, first, kernel - 1) + k.astype(jnp.float32)
+    return total / kernel, (lens - (kernel - 1)) // stride, closes
+
+
+def merge_own_row(out, m, l, q, k, v):
+    """A decode step's attention over the ring's rows *before* the step's own
+    (``out`` [S, H, D] float32 under the softmax's maximum ``m`` and sum ``l``
+    [S, H]; ``l`` 0 where no row was read) merged with the step's own row (k, v
+    [S, Kh, D]) under the one softmax -> [S, H, D] in q's dtype."""
+    s_, h, d = q.shape
+    kh = k.shape[1]
+    f32 = jnp.float32
+    qg = q.reshape(s_, kh, h // kh, d).astype(f32)
+    own = (jnp.sum(qg * k.astype(f32)[:, :, None], axis=-1) * d**-0.5).reshape(s_, h)
+    top = jnp.maximum(m, own)
+    w_ring, w_own = l * jnp.exp(m - top), jnp.exp(own - top)
+    vg = jnp.repeat(v.astype(f32), h // kh, axis=1)  # [S, H, D]
+    merged = (out * w_ring[..., None] + vg * w_own[..., None]) / (w_ring + w_own)[..., None]
+    return merged.astype(q.dtype)
+
+
+def block_decode_step_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, chosen: jax.Array,
+    cache_k: jax.Array, cache_v: jax.Array, lens: jax.Array, layer, sizes: BlockSizes,
+):
+    """One layer's share of a decode step under a selection by blocks, in XLA:
+    q [S, H, D] over the rows before ``lens`` of the blocks ``chosen`` [S, Kh,
+    blocks] of ``layer``'s pages, gathered (``sizes.gathered`` blocks a slot and
+    KV head), and the step's own row (k, v [S, Kh, D]: the rings are written
+    behind the layers and need not hold it) -> out [S, H, D]. The reference of
+    ``decode_kernels.block_decode_attention`` and the path off the TPU."""
+    s_, h, d = q.shape
+    kh, t = k.shape[1], ring_rows(cache_k)
+    bs, nb = sizes.block_size, chosen.shape[-1]
+    n = min(nb, sizes.gathered(t))
+    f32, neg = jnp.float32, jnp.finfo(jnp.float32).min
+    idx = jnp.sort(jnp.where(chosen, jnp.arange(nb), nb), axis=-1)[..., :n]  # [S, Kh, n]
+    held = idx < nb
+    idx = jnp.minimum(idx, nb - 1)
+
+    def gather(cache):  # [S, Kh, D, n * bs]
+        pages = cache[layer][..., : nb * bs] if t >= nb * bs else jnp.pad(
+            cache[layer], ((0, 0),) * 3 + ((0, nb * bs - t),))
+        pages = pages.reshape(s_, kh, d, nb, bs)
+        got = jnp.take_along_axis(pages, idx[:, :, None, :, None], axis=3)
+        return got.reshape(s_, kh, d, n * bs)
+
+    row = (idx[..., None] * bs + jnp.arange(bs)).reshape(s_, kh, n * bs)
+    ok = jnp.repeat(held, bs, axis=-1) & (row < lens[:, None, None])
+    qg = q.reshape(s_, kh, h // kh, d)
+    s = jnp.einsum("sgrd,sgdt->sgrt", qg, gather(cache_k), preferred_element_type=f32) * d**-0.5
+    s = jnp.where(ok[:, :, None], s, neg)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(ok[:, :, None], jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.sum(p, axis=-1)
+    out = jnp.einsum("sgrt,sgdt->sgrd", p.astype(q.dtype), gather(cache_v), preferred_element_type=f32)
+    out = out / jnp.where(l > 0, l, 1.0)[..., None]
+    m = jnp.where(l > 0, m, NEG_INF)
+    return merge_own_row(
+        out.reshape(s_, h, d), m.reshape(s_, h), l.reshape(s_, h), q, k, v
+    )

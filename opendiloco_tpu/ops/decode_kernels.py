@@ -69,12 +69,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from opendiloco_tpu.models.ring_cache import index_write_rows, ring_rows
 from opendiloco_tpu.ops.attention import (
+    BlockSizes,
     _repeat_kv,
     decode_step_attention,
     eva_accumulate,
     eva_attention,
     eva_decode_step_attention,
     latent_decode_step_attention,
+    merge_own_row,
+    ring_rows_sum as xla_ring_rows_sum,
     sparse_decode_step_attention,
     xla_attention,
 )
@@ -1235,3 +1238,232 @@ def mla_decode_attention(
         q, row.reshape(s_, 1, d), new, cache, *selection,
     )
     return out, cache
+
+
+# ---------------------------------------------------------------------------
+# (c) a decode step over chosen blocks: the tiles that hold none stay unread
+# ---------------------------------------------------------------------------
+
+# ring rows a tile of :func:`block_decode_attention`: the lanes' 128, which is
+# two blocks of 64 rows, the finest a tile of a rows-minor page can be cut
+BLOCK_TILE = 128
+
+
+def block_tile_plan(d: int, t: int, sizes: BlockSizes, interpret: bool | None = None) -> int:
+    """The tile of :func:`block_decode_attention` over rings of ``t`` rows of
+    heads of ``d`` under ``sizes``: whole blocks, a divisor of the ring, whole
+    lanes on the chip; 0 where the kernel cannot tile the shape."""
+    bt = _asked_block(t, None, _interpret(interpret)) or (BLOCK_TILE if t % BLOCK_TILE == 0 else 0)
+    if not bt or d % 8 or bt % sizes.block_size or bt // sizes.block_size > 31:
+        return 0
+    return bt
+
+
+def _rows_sum_kernel(first_ref, tiles_ref, layer_ref, a_ref, b_ref, o_ref, *, count, lanes):
+    si = pl.program_id(0)
+    first = first_ref[si]
+    total = jnp.zeros(o_ref.shape, jnp.float32)
+    for i, ref in enumerate((a_ref, b_ref)):  # the tile that holds ``first`` and the next
+        tile = tiles_ref[2 * si + i]
+        at = tile * lanes + jax.lax.broadcasted_iota(jnp.int32, ref.shape, 2)
+        wanted = (at >= first) & (at < first + count)
+        if i:  # at the ring's end the next tile is the same one again
+            wanted = wanted & (tile != tiles_ref[2 * si])
+        total += jnp.sum(jnp.where(wanted, ref[:].astype(jnp.float32), 0.0), axis=2, keepdims=True)
+    o_ref[:] = total
+
+
+def ring_rows_sum(
+    cache_k: jax.Array, layer, first: jax.Array, count: int, *, interpret: bool | None = None
+) -> jax.Array:
+    """``ops.attention.ring_rows_sum`` with the ring read where it lies: a grid
+    step a slot takes the 128-row tile that holds row ``first`` and the one
+    behind it (``count`` rows cross one tile's edge at most) and sums the rows
+    wanted. A ring that 128 rows do not divide, or a ``count`` past a tile,
+    keeps the XLA path."""
+    L, S, kh, d, t = cache_k.shape
+    if t % _LANES or count > _LANES or d % 8:
+        return xla_ring_rows_sum(cache_k, layer, first, count)
+    first = first.astype(jnp.int32)
+    tile = first // _LANES
+    tiles = jnp.stack((tile, jnp.minimum(tile + 1, t // _LANES - 1)), axis=1).reshape(-1)
+    block = lambda i: pl.BlockSpec(
+        (None, None, kh, d, _LANES),
+        lambda si, first_ref, tiles_ref, layer_ref: (layer_ref[0], si, 0, 0, tiles_ref[2 * si + i]),
+    )
+    out = pl.pallas_call(
+        functools.partial(_rows_sum_kernel, count=count, lanes=_LANES),
+        name="odtp_ring_rows_sum",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(S,), in_specs=[block(0), block(1)],
+            out_specs=pl.BlockSpec((None, kh, d, 1), lambda si, *_: (si, 0, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, kh, d, 1), jnp.float32),
+        interpret=_interpret(interpret),
+    )(first, tiles, jnp.asarray(layer, jnp.int32).reshape(1), cache_k, cache_k)
+    return out[..., 0]
+
+
+def block_most_tiles(t: int, tile: int, sizes: BlockSizes) -> int:
+    """The most tiles of ``tile`` rows a slot and KV head reads of a ring of
+    ``t`` rows: ``topk`` blocks each in a tile of its own, or every tile of a
+    sequence still under ``dense_len``."""
+    return min(t // tile, max(sizes.topk, -(-sizes.dense_len // tile)))
+
+
+def _tile_bits(chosen: jax.Array, lens: jax.Array, block: int, per: int) -> jax.Array:
+    """Chosen blocks [..., S, Kh, blocks] -> which blocks of each tile of ``per``
+    blocks are chosen and hold a row before ``lens`` [S], a bit a block [...,
+    S, Kh, tiles] int32 (0: the tile stays unread)."""
+    nb = chosen.shape[-1]
+    chosen = chosen & (jnp.arange(nb) * block < lens[:, None, None])
+    lead = [(0, 0)] * (chosen.ndim - 1)
+    by_tile = jnp.pad(chosen, (*lead, (0, -nb % per))).reshape(*chosen.shape[:-1], -1, per)
+    return jnp.sum(by_tile.astype(jnp.int32) << jnp.arange(per, dtype=jnp.int32), axis=-1)
+
+
+def block_tiles_held(chosen: jax.Array, lens: jax.Array, sizes: BlockSizes, t: int) -> jax.Array:
+    """The ring tiles that hold a chosen block with a row before ``lens``,
+    summed over ``chosen`` [..., S, Kh, blocks] -> [1] int32: what a decode
+    step over chosen blocks moves of rings of ``t`` rows, in tiles of
+    :func:`block_tile_plan` rows (a block a tile where the kernel has none)."""
+    per = (block_tile_plan(8, t, sizes) or sizes.block_size) // sizes.block_size
+    held = _tile_bits(chosen, lens, sizes.block_size, per) > 0
+    return jnp.sum(held).astype(jnp.int32).reshape(1)
+
+
+def block_tile_lists(chosen: jax.Array, lens: jax.Array, block: int, tile: int, most: int):
+    """Chosen blocks [S, Kh, blocks] (bool, up to each slot's own) -> what the
+    kernel's grid walks, a slot and KV head: the ring tiles of ``tile`` rows
+    that hold a chosen block with a row before ``lens``, in order [S, Kh,
+    ``most``] (the places behind the last hold it again: an unchanged index
+    moves nothing), how many they are [S, Kh], and which of each tile's blocks
+    are chosen, a bit a block [S, Kh, ``most``]."""
+    bits = _tile_bits(chosen, lens, block, tile // block)
+    nt = bits.shape[-1]
+    held = bits > 0
+    counts = jnp.sum(held.astype(jnp.int32), axis=-1)
+    order = jnp.sort(jnp.where(held, jnp.arange(nt), nt), axis=-1)[..., :most]
+    if order.shape[-1] < most:
+        order = jnp.pad(order, ((0, 0), (0, 0), (0, most - order.shape[-1])), constant_values=nt)
+    last = jnp.take_along_axis(order, jnp.maximum(jnp.minimum(counts, most) - 1, 0)[..., None], -1)
+    tiles = jnp.where(order < nt, order, jnp.where(last < nt, last, 0)).astype(jnp.int32)
+    return tiles, jnp.minimum(counts, most), jnp.take_along_axis(bits, tiles, axis=-1)
+
+
+def _block_decode_kernel(
+    lens_ref, layer_ref, tiles_ref, counts_ref, bits_ref, q_ref, k_ref, v_ref,
+    o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr, *, scale, block, block_t, most, nkv,
+):
+    si, gi, ti = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    flat = si * nkv + gi
+    f32 = jnp.float32
+
+    @pl.when(ti == 0)
+    def _init():
+        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, f32)
+        l_scr[:] = jnp.zeros(l_scr.shape, f32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, f32)
+
+    @pl.when(ti < counts_ref[flat])
+    def _step():
+        at = flat * most + ti
+        rows = q_ref.shape[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, block_t), 1)
+        of_block = _head_of(lane, block, block_t // block)  # the lane's block in the tile
+        picked = (jax.lax.shift_right_logical(
+            jnp.full((rows, block_t), bits_ref[at], jnp.int32), of_block) & 1) > 0
+        valid = picked & (tiles_ref[at] * block_t + lane < lens_ref[si])
+        s = scale * jax.lax.dot_general(
+            q_ref[:], k_ref[:], (((1,), (0,)), ((), ())), preferred_element_type=f32
+        )  # [rep, block_t]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[:], (((1,), (1,)), ((), ())), preferred_element_type=f32
+        )
+
+    @pl.when(ti == most - 1)
+    def _finish():
+        l = l_scr[:]
+        o_ref[:] = acc_scr[:] / jnp.where(l == 0, 1.0, l)
+        m_ref[:] = m_scr[:]
+        l_ref[:] = l
+
+
+def block_decode_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, chosen: jax.Array,
+    cache_k: jax.Array, cache_v: jax.Array, lens: jax.Array, layer, sizes: BlockSizes,
+    *, interpret: bool | None = None,
+):
+    """One layer's share of a decode step under a selection by blocks: what
+    ``ops.attention.block_decode_step_attention`` gives, reading of ``layer``'s
+    pages ``[L, S, Kh, D, T]`` only the tiles of :func:`block_tile_plan` rows
+    that hold a chosen block. A grid step is one tile of one slot and KV head
+    under the head's ``rep`` queries; the tiles' indices, their number and each
+    tile's chosen blocks ride the grid as scalar-prefetch vectors
+    (:func:`block_tile_lists`), so a tile that holds no chosen block is neither
+    visited nor moved (the steps behind a slot's last tile are skipped, their
+    index unchanged: no DMA, as ``lens`` elides dead tiles in
+    :func:`paged_decode_attention`). The rings are read as the step found them:
+    the step's own row (k, v [S, Kh, D]) is merged in under the one softmax
+    (``merge_own_row``) and written behind the layers. -> out [S, H, D]. A
+    shape the kernel cannot tile raises: the caller asks ``block_tile_plan``
+    first (the engine at construction)."""
+    s_, h, d = q.shape
+    nkv, t = k.shape[1], ring_rows(cache_k)
+    interp = _interpret(interpret)
+    bt = block_tile_plan(d, t, sizes, interp)
+    if not bt or h % nkv:
+        raise ValueError(
+            f"no tile for a decode step over chosen blocks of {sizes.block_size} rows: "
+            f"{nkv} KV heads of {d} under {h} query heads over {t} rows"
+        )
+    rep = h // nkv
+    most = block_most_tiles(t, bt, sizes)
+    tiles, counts, bits = block_tile_lists(chosen, lens, sizes.block_size, bt, most)
+
+    def kv_map(si, gi, ti, lens_ref, layer_ref, tiles_ref, *_):
+        return (layer_ref[0], si, gi, 0, tiles_ref[(si * nkv + gi) * most + ti])
+
+    head = lambda si, gi, ti, *_: (si, gi, 0, 0)
+    stat = jax.ShapeDtypeStruct((s_, nkv, rep, 1), jnp.float32)
+    out, m, l = pl.pallas_call(
+        functools.partial(
+            _block_decode_kernel, scale=d**-0.5, block=sizes.block_size, block_t=bt,
+            most=most, nkv=nkv,
+        ),
+        name="odtp_block_decode_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(s_, nkv, most),
+            in_specs=[
+                pl.BlockSpec((None, None, rep, d), head),
+                pl.BlockSpec((None, None, None, d, bt), kv_map),
+                pl.BlockSpec((None, None, None, d, bt), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, rep, d), head),
+                pl.BlockSpec((None, None, rep, 1), head),
+                pl.BlockSpec((None, None, rep, 1), head),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((rep, 1), jnp.float32), pltpu.VMEM((rep, 1), jnp.float32),
+                pltpu.VMEM((rep, d), jnp.float32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((s_, nkv, rep, d), jnp.float32), stat, stat],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interp,
+    )(
+        lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1), tiles.reshape(-1),
+        counts.reshape(-1), bits.reshape(-1), q.reshape(s_, nkv, rep, d), cache_k, cache_v,
+    )
+    return merge_own_row(out.reshape(s_, h, d), m.reshape(s_, h), l.reshape(s_, h), q, k, v)

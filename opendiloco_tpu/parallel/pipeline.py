@@ -73,6 +73,13 @@ def pipeline_hidden(
             "layers: it stages one homogeneous [L, ...] stack, and a hybrid's "
             "layers are stacked per kind of mixer (llama.layer_runs)"
         )
+    if cfg.linear or cfg.blocks:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration with lightning "
+            "linear-attention layers or a selection by blocks: it stages one "
+            "homogeneous [L, ...] stack under the caller's attn_fn, and these layers "
+            "are stacked per kind of mixer, each with an attention of its own"
+        )
     if cfg.eva:
         raise ValueError(
             "the pp pipeline is refused for a configuration with EVA attention "

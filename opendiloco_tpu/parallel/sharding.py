@@ -54,6 +54,12 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     "kv_a_norm": (None, -1),
     "kv_b_proj": (1, 0),  # [R, Nh*(nope+v)]
     "attn_gate": (1, 0),  # [D, Nh]: a value a head, tp with the heads it scales
+    # (elementwise, [D, Nh*Dh]: a value each of the output's, tp as q_proj).
+    # A lightning layer's (models/lightning.py): its five projections are per
+    # head and take tp like q/k/v/o; its output norm runs over all heads'
+    # values under one weight vector, replicated like the norms
+    "out_gate": (1, 0),  # [D, Nh*Dh]
+    "out_norm": (None, -1),  # [Nh*Dh]
     # the shared SwiGLU beside a routed FFN (granite hybrid): as a dense FFN's
     "shared_gate_proj": (1, 0),  # [D, Fs]
     "shared_up_proj": (1, 0),

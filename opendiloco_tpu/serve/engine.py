@@ -96,6 +96,8 @@ from opendiloco_tpu.models.ring_cache import (
     index_insert,
     init_index_cache,
     init_kv_cache,
+    init_lightning_state,
+    init_pooled_cache,
     init_ssm_state,
     prefix_copy,
     state_insert,
@@ -103,6 +105,8 @@ from opendiloco_tpu.models.ring_cache import (
 from opendiloco_tpu.models.traits import refuse
 from opendiloco_tpu.ops.attention import band_block
 from opendiloco_tpu.ops.decode_kernels import (
+    block_most_tiles,
+    block_tile_plan,
     DecodePlan,
     decode_plan,
     eva_plans,
@@ -180,6 +184,13 @@ def serving_programs(
         ("cca_state",) if cfg.cca else ("index_cache",) if cfg.sparse
         else ("ssm_state", "conv_state")
     )
+    # lightning layers beside a selection by blocks: the pooled-key ring and the
+    # decaying states ride behind the caches, and the tiles the step's attention
+    # held come back behind the tokens, as a routed model's counts do
+    tiles = {}
+    if cfg.linear and cfg.blocks:
+        n_state, state_names = 2, ("pooled_cache", "lightning_state")
+        tiles = {"return_block_tiles": True}
 
     def sample(logits):  # greedy, from the next token's head
         if cfg.num_pred_heads > 1:
@@ -208,12 +219,13 @@ def serving_programs(
             logits, ck, cv, *rest = decode_forward(
                 p, tokens, lens, ck, cv, cfg, compute_dtype=cd,
                 decode_kernel=dkn, return_moe_counts=moe,
-                return_expert_choices=chosen, **state,
+                return_expert_choices=chosen, **state, **tiles,
                 **({"return_row_choices": True} if rows else {}),
             )
-            left, counts = rest[:n_state], rest[n_state : n_state + 1]
+            behind = int(moe) + 1 if tiles else 1  # the counts' place, the tiles' behind them
+            left, counts = rest[:n_state], rest[n_state : n_state + behind]
             tok = sample(logits)
-        return (_with_counts(tok, counts), logits, ck, cv, *left, *rest[n_state + 1 :])
+        return (_with_counts(tok, counts), logits, ck, cv, *left, *rest[n_state + behind :])
 
     def admit_insert(ck, cv, first, ks, vs, tok, slot):
         ck, cv = cache_insert(ck, cv, ks, vs, slot)
@@ -256,6 +268,31 @@ def chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             first = jnp.where(last, first.at[slot].set(tok[0]), first)
         return (_with_counts(tok, rest[:moe]), logits[0], first, ck, cv, ci, *rest[moe:])
+
+    return chunk
+
+
+def state_chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
+    """``chunk_program`` for a stack of lightning layers beside attention under
+    a selection by blocks: ``chunk(params, ids [1, C], plen, count, total,
+    slot, last, first, ck, cv, pc, ls) -> (the chunk's last real token's greedy
+    successor [1] and the ring tiles its attention visited [1], its logits row,
+    first, ck, cv, pc, ls)``; with ``rows`` then the blocks that token read in
+    each layer. ``total`` is the whole prompt's length (``dense_len``'s side);
+    ``pc`` the pooled-key ring, ``ls`` the lightning layers' states: the chunk
+    enters with ``slot``'s and leaves the next chunk's. The trailing five
+    arguments are updated and a jit donates them."""
+
+    def chunk(p, ids, plen, count, total, slot, last, first, ck, cv, pc, ls):
+        with jax.named_scope("odtp_serve_prefill"):
+            logits, ck, cv, _, pc, ls, tiles, *rest = chunk_prefill_forward(
+                p, ids, plen, count, slot, ck, cv, None, cfg, compute_dtype=compute_dtype,
+                pooled_cache=pc, lightning_state=ls, total=total, return_block_tiles=True,
+                return_row_choices=rows,
+            )
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            first = jnp.where(last, first.at[slot].set(tok[0]), first)
+        return (_with_counts(tok, [tiles]), logits[0], first, ck, cv, pc, ls, *rest)
 
     return chunk
 
@@ -334,6 +371,7 @@ class _Chunk:
     t0: float
     t_args: float
     t_dispatch: float
+    total: int = 0  # the whole prompt's tokens
 
 
 @dataclasses.dataclass(eq=False)
@@ -386,13 +424,19 @@ class ServeEngine:
         # layers whose configuration names none takes the engine's, laid over
         # the engine's own view of the configuration, which sizes the sliding
         # rings and the chunk program by it
-        if prefill_chunk and cfg.sliding and not cfg.q_chunk_size:
+        if prefill_chunk and (cfg.sliding or cfg.linear or cfg.blocks) and not cfg.q_chunk_size:
             cfg = dataclasses.replace(cfg, q_chunk_size=int(prefill_chunk))
         elif prefill_chunk and int(prefill_chunk) != cfg.q_chunk_size:
             raise ValueError(
                 f"prefill_chunk {prefill_chunk} is the engine's to give only where the model's "
                 f"configuration admits in chunks and names none (a stack with sliding layers); "
                 f"this one's q_chunk_size is {cfg.q_chunk_size}"
+            )
+        if (cfg.linear or cfg.blocks) and not (cfg.linear and cfg.blocks and cfg.q_chunk_size):
+            raise ValueError(
+                "lightning layers and attention under a selection by blocks are served together "
+                "(a minicpm_sala stack) and every prompt of theirs is admitted in chunks, a chunk "
+                "entering with the state the chunk before left: give the engine a prefill_chunk"
             )
         if cfg.sliding and not cfg.q_chunk_size:
             raise ValueError(
@@ -472,7 +516,7 @@ class ServeEngine:
         # ``q_chunk_size``, each written as one aligned block, so the ring is
         # whole chunks
         self._index: tuple = ()
-        if (cfg.sparse or cfg.sliding) and self.max_context % cfg.q_chunk_size:
+        if (cfg.sparse or cfg.sliding or cfg.blocks) and self.max_context % cfg.q_chunk_size:
             raise ValueError(
                 f"max_context {self.max_context} is not whole chunks of q_chunk_size "
                 f"{cfg.q_chunk_size}: a prompt admitted in chunks writes each as one "
@@ -576,6 +620,62 @@ class ServeEngine:
                 for kind, plan in plans.items()
             }
             self.kind_forms["sliding"]["band_block"] = banded
+        # lightning layers beside a selection by blocks: the pooled-key ring
+        # beside K and V and the lightning layers' decaying states, float32
+        # (``ring_cache``); empty for every other stack. What the two did
+        # (always on; stay 0 without them), over layers, decode steps and
+        # chunks alike: the tokens that passed the lightning mix and the bytes
+        # of state the calls read and wrote (a step every slot's, there and
+        # back; a chunk one slot's); the pooled keys scored (each query's
+        # windows that have closed), the blocks chosen and the rows the
+        # equations read of them (at most ``topk`` blocks a query and KV
+        # head), the ring tiles the form moved (``block_tiles_read``: a decode
+        # step's tiles that held a chosen block, a chunk's tiles that some
+        # query chose) against the tiles the live rows lie in
+        # (``block_tiles_live``), and the calls whose queries read every row
+        # (``dense_len_calls``: a slot of a step, a chunk). ``block_forms``
+        # names the form the step and the chunk take ({} without the stack):
+        # the step's by ``decode_kernel`` (the kernel has a tile for the ring or
+        # the engine is refused here), its tile and the most tiles a slot and
+        # KV head walks; a chunk's are the XLA forms
+        self._sala: tuple = ()
+        self.lightning_tokens = 0
+        self.lightning_state_bytes_moved = 0
+        self.pooled_keys_scored = 0
+        self.blocks_chosen = 0
+        self.block_rows_read = 0
+        self.block_tiles_read = 0
+        self.block_tiles_live = 0
+        self.dense_len_calls = 0
+        self.block_forms: dict = {}
+        if cfg.linear:
+            if self.max_context <= cfg.q_chunk_size or cfg.q_chunk_size % cfg.block_sizes.kernel_stride:
+                raise ValueError(
+                    f"max_context {self.max_context} under chunks of {cfg.q_chunk_size}: a slot "
+                    "holds more than a chunk, and a chunk is whole strides of the pooling"
+                )
+            tile = block_tile_plan(cfg.head_dim, self.max_context, cfg.block_sizes)
+            self._need_plans(
+                tile, "tile for a decode step over chosen blocks",
+                f"{cfg.kv_heads} KV heads of {cfg.head_dim} over {self.max_context} rows in "
+                f"blocks of {cfg.block_sizes.block_size}",
+            )
+            self._block_tile = tile or cfg.block_sizes.block_size
+            # the chunk's attention's tile, as ``chunk_prefill_forward`` cuts the ring
+            self._chunk_tile = 512 if self.max_context % 512 == 0 else self.max_context
+            self._sala = (
+                init_pooled_cache(cfg, self.num_slots, self.max_context, compute_dtype),
+                init_lightning_state(cfg, self.num_slots),
+            )
+            pallas = self.decode_kernel == "pallas"
+            self.block_forms = {
+                "decode": "block-tiles-pallas" if pallas else "block-gather-xla",
+                "chunk": "tiled-xla", "selection": "xla", "lightning_chunk": "chunked-xla",
+                "lightning_step": "xla", "block_t": tile if pallas else 0,
+                "most_tiles": block_most_tiles(self.max_context, tile, cfg.block_sizes) if pallas else 0,
+            }
+        self.pooled_cache_resident_bytes = self._sala[0].nbytes if self._sala else 0
+        self.lightning_state_resident_bytes = self._sala[1].nbytes if self._sala else 0
         # the slots' second kind of state: empty for a stack of attention layers
         self._ssm: tuple = ()
         if cfg.hybrid:
@@ -672,7 +772,7 @@ class ServeEngine:
         # ``step_ahead`` enqueued and has not read; and the steps it enqueued
         # while the step before them was unread, beside all steps in
         # ``phase_calls["decode"]``
-        counts = cfg.moe_counts if cfg.num_experts else 0
+        counts = (cfg.moe_counts if cfg.num_experts else 0) + bool(self._sala)
         self._prev = jnp.zeros((self.num_slots + counts,), jnp.int32)
         self._ahead: Optional[_Step] = None
         self.steps_ahead = 0
@@ -693,7 +793,13 @@ class ServeEngine:
         self._prefill, self._decode, self._admit_insert = programs(False)
         # a prompt admitted in chunks: one program, compiled once
         self._chunk = None
-        if cfg.sparse or cfg.sliding:
+        if self._sala:
+            self._chunk_programs = lambda rows: jax.jit(
+                state_chunk_program(cfg, compute_dtype=cd, rows=rows),
+                donate_argnums=(7, 8, 9, 10, 11),
+            )
+            self._chunk = self._chunk_programs(False)
+        elif cfg.sparse or cfg.sliding:
             self._chunk_programs = lambda rows: jax.jit(
                 chunk_program(cfg, compute_dtype=cd, rows=rows), donate_argnums=(6, 7, 8, 9)
             )
@@ -753,7 +859,7 @@ class ServeEngine:
         params, first = shaped(self.params), shaped(self._first)
         rings = shaped((self.cache_k, self.cache_v))
         beside = shaped((*self._eva, *self._index))
-        state = shaped((*self._ssm, *self._cca))
+        state = shaped((*self._ssm, *self._cca, *self._sala))
         prefill, decode, insert, chunk = (
             self._prefill, self._decode, self._admit_insert, self._chunk
         )
@@ -774,7 +880,12 @@ class ServeEngine:
         if self.phase_calls["decode"]:
             note("decode", id(decode),
                  lambda: decode.lower(params, vec, vec, *rings, *state, *beside))
-        if "chunk" in self._ran:
+        if "chunk" in self._ran and self._sala:
+            ids = sds((1, self.cfg.q_chunk_size), jnp.int32)
+            note("chunk", id(chunk), lambda: chunk.lower(
+                params, ids, scalar, scalar, scalar, scalar, sds((), jnp.bool_), first, *rings,
+                *state))
+        elif "chunk" in self._ran:
             ids = sds((1, self.cfg.q_chunk_size), jnp.int32)
             note("chunk", id(chunk), lambda: chunk.lower(
                 params, ids, scalar, scalar, scalar, sds((), jnp.bool_), first, *rings,
@@ -809,8 +920,11 @@ class ServeEngine:
         their indexer chose, and the newest call's stay in ``row_choices``.
         Programs of their own: called before the first request, nothing
         compiles twice."""
-        if not self.cfg.sparse:
-            raise ValueError("keep_row_choices needs learned sparse attention (index_topk > 0)")
+        if not (self.cfg.sparse or self.cfg.blocks):
+            raise ValueError(
+                "keep_row_choices needs learned sparse attention (index_topk > 0) or a "
+                "selection by blocks"
+            )
         self._keeps_rows = True
         self._prefill, self._decode, self._admit_insert = self._programs(self._keeps_choices, True)
         self._chunk = self._chunk_programs(True)
@@ -983,8 +1097,8 @@ class ServeEngine:
     def needs_chunks(self, n: int) -> bool:
         """Is a prompt of ``n`` tokens admitted in chunks (learned sparse
         attention, and no bucket holds it)?"""
-        if self.cfg.sliding:  # every prompt: no whole-prompt insert into a ring that wraps
-            return True
+        if self.cfg.sliding or self._sala:  # every prompt: no whole prompt goes into a ring
+            return True  # that wraps, and a state's hand-over has the one path
         return self._chunk is not None and pick_bucket(n, self.prefill_buckets) is None
 
     def admit_begin(self, slot: int, prompt: Sequence[int], positions=None) -> Admission:
@@ -1023,18 +1137,27 @@ class ServeEngine:
             jnp.asarray(ids), jnp.int32(plen), jnp.int32(count), jnp.int32(adm.slot),
             jnp.asarray(last),
         )
+        if self._sala:  # what the chunk's lightning layers read and write of the slot's state
+            adm.state_bytes += 2 * self.lightning_state_resident_bytes // self.num_slots
         t_args = time.perf_counter()
-        tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
-            self.params, *args, self._first, self.cache_k, self.cache_v,
-            *(self._index or (None,)),
-        )
+        if self._sala:  # the pooled ring and the states ride with the rings
+            tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
+                self.params, *args[:3], jnp.int32(n), *args[3:], self._first, self.cache_k,
+                self.cache_v, *self._sala,
+            )
+            self._sala, rest = tuple(rest[:2]), [None, *rest[2:]]
+        else:
+            tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
+                self.params, *args, self._first, self.cache_k, self.cache_v,
+                *(self._index or (None,)),
+            )
         if self._index:
             self._index = (rest[0],)
         if self._keeps_rows:
             self.row_choices = rest[1]
         t_dispatch = time.perf_counter()
         before, index = adm.chunk, 0 if adm.chunk is None else adm.chunk.index + 1
-        adm.chunk = _Chunk(tokd, index, plen, count, t0, t_args, t_dispatch)
+        adm.chunk = _Chunk(tokd, index, plen, count, t0, t_args, t_dispatch, n)
         adm.rows_done += count
         if before is not None:
             self._close_chunk(before)
@@ -1052,7 +1175,15 @@ class ServeEngine:
         the last chunk's to use), count its work, and close its
         ``serve_prefill`` span over its enqueue (to ``t_end`` for the chunk whose
         token a blocking caller waited for) -> the span's attributes."""
-        _, attrs = self._split_counts(np.asarray(chunk.tokd), 1)
+        fetched = np.asarray(chunk.tokd)
+        if self._sala:
+            attrs = self._count_sala(
+                rows_before=chunk.rows_before, count=chunk.count, total=chunk.total,
+                tiles=int(fetched[-1]),
+            )
+            fetched = fetched[:-1]
+        else:
+            _, attrs = self._split_counts(fetched, 1)
         attrs.update(self._count_dsa(rows_before=chunk.rows_before, count=chunk.count))
         if self._latent_row_bytes:  # the slot's rows so far and the chunk's own
             attrs.update(self._count_latent(
@@ -1230,6 +1361,61 @@ class ServeEngine:
         self.dsa_index_bytes_read += index_bytes
         self.dsa_kv_bytes_read += kv_bytes
         return {"dsa_rows_scored": layers * scored, "dsa_rows_selected": layers * selected}
+
+    def _count_sala(self, lens=None, rows_before: int = 0, count: int = 0, total: int = 0,
+                    tiles: int = 0) -> dict:
+        """Add one call's lightning mix and its attention under the selection
+        by blocks to the engine's counters -> the same as span attributes, each
+        over layers (its callers ask only for such a stack). A decode step over
+        the live slots' positions ``lens``: a query a slot at position p, p + 1
+        rows behind it; a chunk of ``count`` tokens behind ``rows_before`` rows
+        of a prompt of ``total``: a query a token. A query scores the windows
+        that have closed before it, chooses min(topk, its blocks) blocks a KV
+        head and reads their rows up to its own (every row up to its own under
+        ``dense_len``); ``tiles`` is what the program counted."""
+        cfg, sizes = self.cfg, self.cfg.block_sizes
+        bs, ls, ll = sizes.block_size, cfg.num_attention_layers, cfg.num_lightning_layers
+        slot_state = self.lightning_state_resident_bytes // self.num_slots
+        if lens is None:
+            at = rows_before + np.arange(count, dtype=np.int64)
+            dense = np.full(count, total < sizes.dense_len)
+            tokens, state_bytes = count, 2 * slot_state
+            live_tiles = ls * -(-(rows_before + count) // self._chunk_tile)
+        else:
+            at = np.asarray(lens, np.int64)
+            at = at[at > 0]
+            dense = at + 1 < sizes.dense_len
+            tokens, state_bytes = at.size, 2 * self.lightning_state_resident_bytes
+            live_tiles = ls * cfg.kv_heads * int((-(-at // self._block_tile)).sum())
+        seen = np.maximum((at - (sizes.kernel_size - 1)) // sizes.kernel_stride + 1, 0)
+        seen = np.where(dense, 0, seen)
+        blocks = at // bs + 1
+        chosen = np.where(dense, blocks, np.minimum(blocks, sizes.topk))
+        # the chosen blocks are whole but the query's own, which ends with it
+        rows = np.where(dense, at + 1, (chosen - 1) * bs + at % bs + 1)
+        # what the queries read between them: a step's slots each their own, a
+        # chunk's queries the slot's one set of rows and of pooled keys
+        keys, distinct = int(seen.sum()), int(rows.sum())
+        if lens is None and count:
+            keys, distinct = int(seen.max()), min(distinct, rows_before + count)
+        attrs = {
+            "lightning_tokens": ll * tokens,
+            "lightning_state_bytes": state_bytes,
+            "pooled_keys_scored": ls * cfg.kv_heads * int(seen.sum()),
+            "pooled_keys_read": ls * cfg.kv_heads * keys,
+            "block_rows_distinct": ls * cfg.kv_heads * distinct,
+            "blocks_chosen": ls * cfg.kv_heads * int(chosen.sum()),
+            "block_rows_read": ls * cfg.kv_heads * int(rows.sum()),
+            "block_tiles_read": tiles, "block_tiles_live": live_tiles,
+            "dense_len_calls": int(dense.sum()) if lens is not None else int(dense[:1].sum()),
+            "block_form": self.block_forms["decode" if lens is not None else "chunk"],
+        }
+        self.lightning_tokens += attrs["lightning_tokens"]
+        self.lightning_state_bytes_moved += state_bytes
+        for name in ("pooled_keys_scored", "blocks_chosen", "block_rows_read", "block_tiles_read",
+                     "block_tiles_live", "dense_len_calls"):
+            setattr(self, name, getattr(self, name) + attrs[name])
+        return attrs
 
     def _count_latent(self, read: int, written: int, swa_read: int = 0) -> dict:
         """Add one call's traffic with the latent ring to the engine's
@@ -1440,7 +1626,7 @@ class ServeEngine:
         t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *state = self._decode(
             self.params, tokensd, lensd, self.cache_k, self.cache_v,
-            *self._ssm, *self._cca, *self._eva, *self._index,
+            *self._ssm, *self._cca, *self._eva, *self._index, *self._sala,
             first=self._first, prev=self._prev,
         )
         self._prev = tok
@@ -1448,7 +1634,9 @@ class ServeEngine:
             self.row_choices = state.pop()
         if self._keeps_choices:
             self.expert_choices = state.pop()
-        if self._eva:
+        if self._sala:
+            self._sala = tuple(state)
+        elif self._eva:
             self._eva = tuple(state)
         elif self._index:
             self._index = tuple(state)
@@ -1485,7 +1673,10 @@ class ServeEngine:
                 t_fetch = time.perf_counter()
         fetched = np.asarray(read.tokd)
         t_fetched = time.perf_counter()
-        tok, moe = self._split_counts(fetched, self.num_slots)
+        if self._sala:  # the tiles the step's attention held ride behind the tokens
+            tok, moe = fetched[: self.num_slots], self._count_sala(read.lens, tiles=int(fetched[-1]))
+        else:
+            tok, moe = self._split_counts(fetched, self.num_slots)
         lens = read.lens
         held = lens[lens > 0]
         moe.update(self._count_ssm(held.size, 2 * self.ssm_state_resident_bytes))
@@ -1540,6 +1731,21 @@ class ServeEngine:
             pooled_rows = self._eva[0].shape[-1]
             plans = eva_plans(Nkv, Dh, cfg.window_size, cfg.chunk_size, pooled_rows, size)
             rings = list(zip(plans or (none, none), (T, pooled_rows)))
+        elif self._sala:
+            # a decode step over chosen blocks (``odtp_block_decode_attn``): a grid
+            # step a tile, ``most_tiles`` of them a slot and KV head, those
+            # behind the last tile that holds a chosen block skipped
+            form = self.block_forms
+            return {
+                "decode_plan_heads": float(bool(form["block_t"])),
+                "decode_plan_block_t": float(form["block_t"]),
+                "decode_plan_block_diagonal": 0.0, "decode_plan_slots": float(bool(form["block_t"])),
+                "decode_plan_most_tiles": float(form["most_tiles"]),
+                "decode_grid_steps": float(layers * S * Nkv * form["most_tiles"]),
+                "pooled_ring_rows": float(self._sala[0].shape[-1]),
+                "pooled_ring_bytes": float(self._sala[0].nbytes),
+                "lightning_state_bytes": float(self._sala[1].nbytes),
+            }
         elif self.decode_kernel == "pallas" and not cfg.latent:
             # under a selection (learned sparse attention) and over rings by
             # kind a step is one slot's

@@ -314,11 +314,12 @@ class ContinuousBatcher:
             self._trace_terminal(req, "retire", "failed", error=req.error)
             return req
         if (
-            self.engine.cfg.eva
+            (self.engine.cfg.eva or self.engine.cfg.blocks)
             and len(req.prompt) + req.max_new_tokens > self.engine.max_context
         ):
             # a ring of rows slides past its context; EVA's pooled ring holds a
-            # row per chunk of max_context positions and none wraps
+            # row per chunk of max_context positions and none wraps, and a
+            # selection by blocks names a block by its rows from position 0
             self.rejected += 1
             req.finish(
                 f"prompt length {len(req.prompt)} and {req.max_new_tokens} new "
@@ -1288,6 +1289,18 @@ class ContinuousBatcher:
                     "full_rows_read", "swa_rows_read", "kinds_bytes_moved",
                 )},
                 "forms": self.engine.kind_forms,
+            },
+            # what the lightning mix and the attention under a selection by
+            # blocks did (zeros without such a stack), what their state and
+            # pooled ring hold, and which form the step and the chunk take
+            "sala": {
+                **{name: getattr(self.engine, name) for name in (
+                    "lightning_tokens", "lightning_state_bytes_moved", "pooled_keys_scored",
+                    "blocks_chosen", "block_rows_read", "block_tiles_read", "block_tiles_live",
+                    "dense_len_calls", "lightning_state_resident_bytes",
+                    "pooled_cache_resident_bytes",
+                )},
+                "forms": self.engine.block_forms,
             },
             # what EVA attention did with its two rings (zeros without it)
             "eva": {

@@ -526,3 +526,80 @@ def test_laguna_programs_copy_no_ring_and_cast_no_weight(chip, which):
     else:
         assert mem.temp_size_in_bytes < 2.5e9
         assert not f32_blocks_over(text, 512e6)
+
+
+# --- MiniCPM-SALA: lightning states beside a selection by blocks (PR 61) ----------
+
+
+@once_a_session
+def _sala_program(chip, which):
+    import dataclasses
+
+    from opendiloco_tpu.models.ring_cache import (
+        init_kv_cache, init_lightning_state, init_pooled_cache,
+    )
+    from opendiloco_tpu.serve.engine import serving_programs, state_chunk_program
+
+    cfg, engine = serve_cell("minicpm-sala", "serve-sala-longdoc")
+    cfg = dataclasses.replace(cfg, q_chunk_size=engine["prefill_chunk"])  # as the engine lays it
+    slots, rows = engine["num_slots"], engine["max_context"]
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, slots, rows, BF16))
+    rings = on_chip(chip, (
+        cache["k"], cache["v"],
+        jax.eval_shape(lambda: init_pooled_cache(cfg, slots, rows, BF16)),
+        jax.eval_shape(lambda: init_lightning_state(cfg, slots)),
+    ))
+    params = bound(chip, cfg)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    if which == "decode":
+        _, decode, _, n = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+        assert n == 4
+        lowered = jax.jit(decode, donate_argnums=(4, 5, 6, 7)).lower(params, vec, vec, vec, *rings)
+    else:
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+        ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
+        last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
+        lowered = jax.jit(
+            state_chunk_program(cfg, compute_dtype=BF16), donate_argnums=(7, 8, 9, 10, 11)
+        ).lower(params, ids, scalar, scalar, scalar, scalar, last, vec, *rings)
+    return cfg, params, rings, lowered.compile()
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_sala_programs_copy_no_ring_and_no_state_and_cast_no_weight(chip, which):
+    """The engine's decode and chunk programs for MiniCPM-SALA at 12 slots of
+    34,816 rows under chunks of 2,048, published widths, 18 of 32 layers:
+    5,609,898,496 parameters held once in bf16; the K and V rings of the four
+    sparse layers, their pooled-key ring and the fourteen lightning layers'
+    float32 states alias the outputs and none is copied; no weight is cast; the
+    program fits the chip. The decode step holds ``odtp_block_decode_attn`` (the
+    kernel over the chosen blocks' tiles, a tile of 128 rows of one KV head a
+    grid step under its 16 query heads) and the rings' writers behind the
+    layers; the chunk's temporaries stay under 1 GB (a sparse layer's tile of
+    scores [2, 16, 2048, 512] float32 is 134 MB, a head group's scores over the
+    pooled keys 71 MB)."""
+    cfg, params, rings, compiled = _sala_program(chip, which)
+    assert (cfg.num_attention_layers, cfg.num_lightning_layers) == (4, 14)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in rings)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 11_219_796_992
+    assert held == 2 * 855_638_016 + 53_477_376 + 352_321_536 == 12 * 176_422_912
+    print(f"sala {which}: arguments {mem.argument_size_in_bytes} temporaries "
+          f"{mem.temp_size_in_bytes} aliased {mem.alias_size_in_bytes} "
+          f"program {program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held
+    assert program_bytes(compiled) < HBM_BYTES
+    for ring in rings:
+        assert not ring_copies(text, ring.shape), which
+    assert not leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+    if which == "decode":
+        assert "odtp_block_decode_attn" in text and text.count("odtp_index_ring_write") >= 3
+        blocks = kernel_windows(text, "odtp_block_decode_attn")
+        assert {b[1] for b in blocks} == {(1, 1, 1, 128, 128)} and {b[0] for b in blocks} == {(1, 1, 16, 128)}
+        assert "odtp_ring_rows_sum" in text  # the closing window's rows, no gather of the ring
+        assert mem.temp_size_in_bytes < 256e6
+    else:
+        # (the temporaries and not ``f32_blocks_over``: the float32 states are updated in
+        # place at their own shape, and a fusion's inside holds the head widened for one row)
+        assert mem.temp_size_in_bytes < 1e9

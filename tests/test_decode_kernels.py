@@ -818,3 +818,90 @@ def test_the_band_of_a_chunk_is_the_masked_tiles(rep, chunk, window, ring, block
         np.testing.assert_allclose(got, want[rows], atol=3e-6)
         np.testing.assert_allclose(masked, want[rows], atol=3e-6)
     assert band_block(2048, 4096, 512) == 512 and band_block(8, 12, 5) == 0  # a ring of no whole blocks
+
+
+# --- a decode step over chosen blocks (PR 61): the tiles that hold none stay unread ---
+
+
+def _chosen_blocks(rng, lens, sizes, kh, blocks):
+    """A choice as ``ops.attention.choose_blocks`` makes it: the forced blocks
+    and random others up to ``topk``, every block up to the slot's own under
+    ``dense_len``; nothing for a slot that holds no sequence."""
+    chosen = np.zeros((len(lens), kh, blocks), bool)
+    for s, n in enumerate(lens):
+        own = n // sizes.block_size
+        for g in range(kh):
+            if n == 0:
+                continue
+            if n + 1 < sizes.dense_len:
+                chosen[s, g, : own + 1] = True
+                continue
+            forced = {0, *range(max(own - sizes.window_size // sizes.block_size + 1, 0), own + 1)}
+            free = [b for b in range(own + 1) if b not in forced]
+            more = rng.choice(free, min(len(free), sizes.topk - len(forced)), replace=False)
+            chosen[s, g, list(forced | set(int(b) for b in more))] = True
+    return jnp.asarray(chosen)
+
+
+@pytest.mark.parametrize("rep, lens", [
+    (16, (0, 37, 200, 255)),  # an empty slot, one under ``dense_len``, two past it; 16 heads a KV head
+    (4, (47, 48, 129, 7)),  # both sides of ``dense_len`` (the rows at the call: lens + 1) and of a tile's edge
+])
+def test_block_decode_attention_reads_the_chosen_tiles_as_its_gather(rep, lens, monkeypatch):
+    """``odtp_block_decode_attn`` interpreted against the XLA gather of the
+    chosen blocks: the same output to rounding, the step's own row merged in
+    under the one softmax, the rings untouched; and the lists its grid walks
+    hold the tiles with a chosen block and no other."""
+    from opendiloco_tpu.ops.attention import BlockSizes, block_decode_step_attention
+    from opendiloco_tpu.ops.decode_kernels import (
+        block_decode_attention, block_tile_lists, block_tile_plan, block_tiles_held,
+    )
+
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "16")
+    sizes = BlockSizes(kernel_size=8, kernel_stride=4, block_size=8, topk=6, init_blocks=1,
+                       window_size=16, dense_len=48)
+    kh, d, t, layers = 2, 16, 256, 3
+    s_ = len(lens)
+    rng = np.random.default_rng(rep)
+    keys = jax.random.split(jax.random.key(rep), 5)
+    q = jax.random.normal(keys[0], (s_, kh * rep, d), jnp.float32)
+    k, v = (jax.random.normal(kk, (s_, kh, d), jnp.float32) for kk in keys[1:3])
+    ck, cv = (jax.random.normal(kk, (layers, s_, kh, d, t), jnp.float32) for kk in keys[3:])
+    lens = jnp.asarray(lens, jnp.int32)
+    chosen = _chosen_blocks(rng, np.asarray(lens), sizes, kh, t // 8)
+    assert block_tile_plan(d, t, sizes, interpret=True) == 16
+    want = block_decode_step_attention(q, k, v, chosen, ck, cv, lens, 1, sizes)
+    got = block_decode_attention(q, k, v, chosen, ck, cv, lens, 1, sizes, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    # a slot that holds nothing reads nothing: its own row alone
+    empty = np.flatnonzero(np.asarray(lens) == 0)
+    for s in empty:
+        np.testing.assert_allclose(got[s], jnp.repeat(v[s], rep, axis=0), rtol=1e-6)
+    tiles, counts, bits = (np.asarray(x) for x in block_tile_lists(chosen, lens, 8, 16, 16))
+    by_tile = np.asarray(chosen).reshape(s_, kh, -1, 2)
+    for s in range(s_):
+        for g in range(kh):
+            held = [i for i in range(t // 16)
+                    if (by_tile[s, g, i] & (np.arange(2) * 8 + i * 16 < int(lens[s]))).any()]
+            assert counts[s, g] == len(held) and list(tiles[s, g, : len(held)]) == held
+            assert all(tiles[s, g, len(held):] == (held[-1] if held else 0))  # no index moves: no DMA
+            for i, tile in enumerate(held):
+                want_bits = sum(int(by_tile[s, g, tile, b] and tile * 16 + b * 8 < int(lens[s])) << b
+                                for b in range(2))
+                assert bits[s, g, i] == want_bits
+    assert int(block_tiles_held(chosen, lens, sizes, t)[0]) == int(counts.sum())
+    live = sum(kh * -(-int(n) // 16) for n in np.asarray(lens))
+    assert int(counts.sum()) < live  # tiles stay unread
+
+
+def test_ring_rows_sum_kernel_is_the_gather():
+    """The rows a closing window is pooled from, summed where the ring lies:
+    across a tile's edge, at the ring's start and at its end."""
+    from opendiloco_tpu.ops.attention import ring_rows_sum as gather
+    from opendiloco_tpu.ops.decode_kernels import ring_rows_sum
+
+    ring = jax.random.normal(jax.random.key(3), (2, 5, 2, 16, 256), jnp.float32)
+    first = jnp.asarray([0, 100, 120, 225, 128], jnp.int32)  # 120 + 31 crosses row 128; 225 + 31 ends the ring
+    got = ring_rows_sum(ring, 1, first, 31, interpret=True)
+    np.testing.assert_allclose(got, gather(ring, 1, first, 31), rtol=1e-5, atol=1e-5)
+    assert got.shape == (5, 2, 16)

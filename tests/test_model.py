@@ -340,10 +340,24 @@ def _two_gqa_kinds_model():
     return cfg, params
 
 
+def _lightning_and_blocks_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "minicpm_sala", "vocab_size": 256, "hidden_size": 64, "intermediate_size": 96,
+        "num_hidden_layers": 3, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+        "mixer_types": ["minicpm4", "lightning-attn", "minicpm4", "lightning-attn"], "qk_norm": True,
+        "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 16, "attn_use_output_gate": True,
+        "max_position_embeddings": 128, "norm_init_std": 0.1,
+        "sparse_config": {"kernel_size": 4, "kernel_stride": 2, "block_size": 4, "topk": 3,
+                          "init_blocks": 1, "window_size": 4, "dense_len": 8},
+    })
+    assert cfg.traits == ("linear", "blocks") and cfg.layer_kinds == ("attention", "lightning", "attention")
+    return cfg, init_params(jax.random.key(11), cfg)
+
+
 @pytest.mark.parametrize(
     "model",
     [_dense_model, _routed_qk_norm_model, _hybrid_model, _latent_routed_model, _zaya_model,
-     _eva_model, _two_latent_kinds_model, _two_gqa_kinds_model],
+     _eva_model, _two_latent_kinds_model, _two_gqa_kinds_model, _lightning_and_blocks_model],
 )
 def test_the_four_forwards_agree(model):
     """One block under four drivers: in float32 the training forward, the
@@ -382,6 +396,35 @@ def test_the_four_forwards_agree(model):
     close(logits[0], full(prompt)[P - 1])
     tok = int(jnp.argmax(logits[0, : cfg.vocab_size]))
     assert (vs is None) == (cfg.latent and not cfg.sliding)  # the latent rows alone are kept
+    if cfg.linear:
+        # lightning layers beside a selection by blocks (PR 61): the prompt goes in
+        # as chunks, each entering with the state the chunk before left (the prompt
+        # of 9 is past ``dense_len`` 8: the selection runs), and the decode steps
+        # through both rings and the state give the forward's next rows
+        from opendiloco_tpu.models.ring_cache import init_lightning_state, init_pooled_cache
+
+        cache = init_kv_cache(cfg, 2, 32, jnp.float32)
+        rings = (cache["k"], cache["v"], init_pooled_cache(cfg, 2, 32, jnp.float32),
+                 init_lightning_state(cfg, 2))
+        assert left[0].shape == (1, 4, 16, 16) and rings[2].shape[-1] == 16
+        for plen in range(0, P, 4):
+            count = min(4, P - plen)
+            ids = jnp.asarray([(prompt[plen : plen + count] + [0] * 4)[:4]], jnp.int32)
+            chunked, ck, cv, _, pc, ls = chunk_prefill_forward(
+                params, ids, plen, count, 1, *rings[:2], None, cfg, pooled_cache=rings[2],
+                lightning_state=rings[3], total=P, **f32)
+            rings = (ck, cv, pc, ls)
+        close(chunked[0], logits[0])
+        close(rings[3][:, 1], left[0])
+        seq, steps = prompt + [tok], []
+        for _ in range(4):
+            tokens, lens = jnp.asarray([0, seq[-1]], jnp.int32), jnp.asarray([0, len(seq) - 1], jnp.int32)
+            step, *rings = decode_forward(
+                params, tokens, lens, *rings[:2], cfg, pooled_cache=rings[2], lightning_state=rings[3], **f32)
+            steps.append(step[1])
+            seq.append(int(jnp.argmax(step[1])))
+        close(jnp.stack(steps), full(seq[:-1])[P:])
+        return
     if cfg.sliding and not cfg.latent:
         # two kinds of grouped-query layer (PR 56): the rows come by kind, each a
         # (k, v) pair; the sliding layers' rings wrap, so the prompt goes in as

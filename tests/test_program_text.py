@@ -1,4 +1,4 @@
-"""The serving programs of five configurations the benchmark measures lower
+"""The serving programs of seven configurations the benchmark measures lower
 to the text they lowered to before dots3-note-prev's layers came (PR 54) and
 before Laguna-S-2.1's (PR 56, which added ``serve-olmoe-fewshot``'s and
 ``serve-dots3-notes``'s configurations, recorded from its parent ``b964f0b``): the
@@ -24,10 +24,15 @@ PR 60 changed the latent decode kernel (``odtp_mla_decode_attn``: what a slot's
 step writes back), which only ``glm``'s and ``dots3``'s ``decode`` hold: those two
 digests are PR 60's own tree's, every other one is as it was.
 
+PR 61 (MiniCPM-SALA: lightning layers' states and a selection by blocks in the
+shared forwards, engine and scheduler) added ``serve-granite-h-docqa``'s and
+``serve-laguna-repoedit``'s configurations, recorded from its parent ``c311252``.
+
 The loop is held the same way: ``ContinuousBatcher``'s iteration for a
 configuration without sliding layers calls no function of the engine that the
 parent's did not."""
 
+import dataclasses
 import hashlib
 import json
 import os
@@ -44,6 +49,8 @@ CELLS = {
     "keye": ("keye-vl-2.0-30b-a3b", "serve-keye-videoqa"),
     "olmoe": ("olmoe-1b-7b", "serve-olmoe-fewshot"),
     "dots3": ("dots3-note-prev", "serve-dots3-notes"),
+    "granite": ("granite-4.0-h-small", "serve-granite-h-docqa"),
+    "laguna": ("laguna-s-2.1", "serve-laguna-repoedit"),
 }
 # recorded from commit f83e3d2 (PR 52's tree, PR 54's parent)
 PARENT = {
@@ -62,6 +69,13 @@ PARENT = {
     # ``decode``: PR 60's own, as ``glm``'s (the parent's read 4b291eeac5a6b303)
     "dots3": {"decode": "8b9e121900f07068", "prefill": "643fffdf8cd98c94",
               "chunk": "1496f43173c333a6"},
+    # recorded from commit c311252 (PR 60's tree, PR 61's parent): the Mamba-2
+    # hybrid, whose decode step hands a state over as the lightning layers' does,
+    # and the stack of two grouped-query kinds, whose chunks are the engine's
+    "granite": {"decode": "139da673d719abfa", "prefill": "dff0fa32301a6561",
+                "prefill/512": "dff0fa32301a6561"},
+    "laguna": {"decode": "1ca47f13e8229877", "prefill": "4bab75441ed0072b",
+               "chunk": "b7704d4b7c53f06e"},
 }
 # the engine's methods that the batcher's loop (and a submit) called at that
 # commit while it served two requests of a dense, a latent and an indexed
@@ -75,6 +89,9 @@ _CHUNKS = ("_close_chunk", "_count_dsa", "admit_begin", "admit_chunk")
 PARENT_CALLS = {
     "360m": _LOOP, "glm": _LOOP, "olmoe": _LOOP,
     "keye": (*_LOOP, *_CHUNKS), "dots3": (*_LOOP, *_CHUNKS),
+    # recorded from commit c311252 (PR 61's parent)
+    "granite": _LOOP,
+    "laguna": tuple(sorted({*_LOOP, *_CHUNKS, "_count_kinds"} - {"_bucket_of", "_count_latent", "admit_enqueue"})),
 }
 
 
@@ -101,8 +118,13 @@ def digests(name: str) -> dict:
     sds = jax.ShapeDtypeStruct
     params = jax.tree.map(lambda x: sds(x.shape, bf), llama.shapes(cfg))
     slots, rows = opts["num_slots"], opts["max_context"]
+    if cfg.sliding and not cfg.q_chunk_size:  # the engine's chunk, as the engine lays it
+        cfg = dataclasses.replace(cfg, q_chunk_size=opts["prefill_chunk"])
     cache = jax.eval_shape(lambda: ring_cache.init_kv_cache(cfg, slots, rows, bf))
     rings = [cache["k"], cache["v"]]
+    if cfg.hybrid:
+        state = jax.eval_shape(lambda: ring_cache.init_ssm_state(cfg, slots, bf))
+        rings += [state["ssm"], state["conv"]]
     if cfg.sparse:
         rings.append(jax.eval_shape(lambda: ring_cache.init_index_cache(cfg, slots, rows, bf)))
     vec, scalar = sds((slots,), jnp.int32), sds((), jnp.int32)
@@ -121,12 +143,13 @@ def digests(name: str) -> dict:
         if prefill_form(bucket, *heads, "pallas") == "xla":
             texts[f"prefill/{bucket}"] = lower(
                 prefill, params, sds((1, bucket), jnp.int32), scalar)
-    if cfg.sparse:
+    if cfg.sparse or cfg.sliding:
         texts["chunk"] = lower(
             chunk_program(cfg, compute_dtype=bf), params, sds((1, cfg.q_chunk_size), jnp.int32),
-            scalar, scalar, scalar, sds((), jnp.bool_), vec, *rings, donate_argnums=(6, 7, 8, 9),
+            scalar, scalar, scalar, sds((), jnp.bool_), vec, *rings,
+            *([] if cfg.sparse else [None]), donate_argnums=(6, 7, 8, 9),
         )
-    elif not cfg.latent:  # the suffix behind a reused prefix
+    elif not (cfg.latent or cfg.hybrid):  # the suffix behind a reused prefix
         texts["chunk"] = lower(
             lambda p, tail, plen, count, slot, ck, cv: llama.chunk_prefill_forward(
                 p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=bf),
@@ -162,6 +185,27 @@ TINY = {
         swa_kv_lora_rank=8, swa_qk_nope_head_dim=8, swa_qk_rope_head_dim=4, swa_v_head_dim=8,
         n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2, topk_method="noaux_tc",
     ),
+    "granite": dict(
+        model_type="granitemoehybrid", hidden_size=32, intermediate_size=16, vocab_size=64,
+        shared_intermediate_size=24, num_hidden_layers=3, num_attention_heads=4,
+        num_key_value_heads=2, layer_types=["mamba", "attention", "mamba"],
+        position_embedding_type="nope", mamba_n_heads=8, mamba_d_head=8, mamba_d_state=16,
+        mamba_d_conv=4, mamba_n_groups=1, mamba_chunk_size=8, mamba_expand=2, mamba_conv_bias=True,
+        mamba_proj_bias=False, num_experts=8, num_experts_per_tok=2,
+    ),
+    "laguna": dict(
+        model_type="laguna", hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, vocab_size=64, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8, sliding_window=3,
+        layer_types=["full_attention", "sliding_attention", "full_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse"], mlp_only_layers=[0],
+        num_attention_heads_per_layer=[4, 6, 4], gating="per-head",
+        rope_parameters={
+            "full_attention": {"rope_theta": 5e5, "rope_type": "default", "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 1e4, "partial_rotary_factor": 1},
+        },
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+    ),
 }
 
 
@@ -178,6 +222,7 @@ def loop_calls(name: str) -> list:
     engine = ServeEngine(
         cfg, init_params(jax.random.key(0), cfg), num_slots=2, max_context=32,
         prefill_buckets=(16,), compute_dtype=jnp.float32,
+        **({"prefill_chunk": 8} if cfg.sliding and not cfg.q_chunk_size else {}),
     )
     called = set()
     for attr in dir(ServeEngine):

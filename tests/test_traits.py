@@ -59,15 +59,29 @@ CONFIGS = {
         "first_k_dense_replace": 1, "n_routed_experts": 8, "n_shared_experts": 1,
         "num_experts_per_tok": 2, "topk_method": "noaux_tc", "norm_topk_prob": True,
     },
+    # a minicpm_sala stack brings two: lightning layers beside plain grouped-query
+    # ones (no ``sparse_config``) bring the one, ``minicpm4`` layers alone the other
+    "linear": {
+        **_SMALL, "model_type": "minicpm_sala", "num_key_value_heads": 2, "head_dim": 16,
+        "mixer_types": ["lightning-attn", "minicpm4", "lightning-attn"], "qk_norm": True,
+        "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 16, "attn_use_output_gate": True,
+    },
+    "blocks": {
+        **_SMALL, "model_type": "minicpm_sala", "num_key_value_heads": 2, "head_dim": 16,
+        "mixer_types": ["minicpm4"] * 3, "qk_norm": True, "attn_use_output_gate": True,
+        "sparse_config": {"kernel_size": 8, "kernel_stride": 4, "block_size": 8, "topk": 4,
+                          "init_blocks": 1, "window_size": 16, "dense_len": 32},
+    },
 }
 # what a refusal calls each trait
 NAMED = {"hybrid": "Mamba-2 layers", "cca": "CCA", "eva": "EVA attention",
          "sparse": "learned sparse attention", "sliding": "sliding layers",
-         "latent": "latent attention"}
+         "latent": "latent attention", "linear": "lightning linear-attention layers",
+         "blocks": "attention under a selection by blocks"}
 # the traits each feature handles, so refuses nothing for: every other is refused
 TAKES = {
     "prefix_reuse": (), "page_out": (), "page_in": (),
-    "continued_prefill": ("sparse", "sliding", "latent"),
+    "continued_prefill": ("sparse", "sliding", "latent", "linear", "blocks"),
     "attn_impl": ("hybrid", "cca"),
 }
 
