@@ -75,6 +75,8 @@ from opendiloco_tpu.ops.decode_kernels import (
     block_decode_attention,
     block_tiles_held,
     causal_prefill_attention,
+    chunk_attention,
+    chunk_form,
     eva_decode_attention,
     eva_prefill_attention,
     index_ring_write,
@@ -3292,6 +3294,27 @@ def decode_forward(
 _SUFFIX_TILE = 512  # ring rows a tile of a run's attention where no ``q_chunk_size`` says
 
 
+def chunk_tile(cfg: LlamaConfig, rows: int) -> int:
+    """Ring rows a tile of ``chunk_prefill_forward``'s attention over a ring
+    of ``rows``: ``q_chunk_size`` where the configuration names it (a chunk
+    that is the engine's stays out of it: the tile stays a tile), and the
+    whole ring where tiles do not cut it."""
+    tile = min(cfg.q_chunk_size or _SUFFIX_TILE, rows)
+    if (cfg.sliding and not cfg.latent) or cfg.blocks:  # the chunk is the engine's
+        tile = min(_SUFFIX_TILE, rows)
+    return tile if rows % tile == 0 else rows
+
+
+def chunk_attn_form(cfg: LlamaConfig, chunk: int, rows: int, decode_kernel: str | None) -> str:
+    """The form ``chunk_prefill_forward`` runs a chunk of ``chunk`` tokens'
+    grouped-query attention in (a stack's full layers) over a ring of ``rows``
+    (``decode_kernels.chunk_form`` at the configuration's heads)."""
+    return chunk_form(
+        chunk, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim, rows, chunk_tile(cfg, rows),
+        decode_kernel,
+    )
+
+
 def chunk_prefill_forward(
     params: dict,
     ids: jax.Array,
@@ -3310,6 +3333,7 @@ def chunk_prefill_forward(
     lightning_state: Optional[jax.Array] = None,
     total=None,
     return_block_tiles: bool = False,
+    decode_kernel: str = "xla",
 ):
     """A run of a prompt's tokens over a slot that holds the rows before them:
     a chunk of a prompt admitted in chunks, or the suffix behind a reused
@@ -3327,7 +3351,13 @@ def chunk_prefill_forward(
     scores is held where the ring is whole tiles, and tiles past the run's
     last row are not visited. The scan carries the rings; jitted with them
     donated the rows are written in place. ``plen + count`` lies within the
-    ring: a prompt fits its slot, and nothing wraps.
+    ring: a prompt fits its slot, and nothing wraps. ``decode_kernel`` (the
+    engine's, resolved: "pallas" | "xla") decides with the shapes which form a
+    grouped-query layer's walk over the tiles takes
+    (``decode_kernels.chunk_form``: the kernel ``chunk_attention``, whose score
+    tile stays in VMEM, where the XLA form's would be written to memory at
+    every tile; the XLA form elsewhere, and over latent rows, a sliding
+    layer's ring and without the argument).
 
     Which rows a query reads: every row up to its own, or, under learned
     sparse attention (``index_cache``: the slot's index ring beside K and V,
@@ -3353,8 +3383,8 @@ def chunk_prefill_forward(
     windows that close inside the chunk **from the ring's own rows** (a window
     may start in the chunk before), scores the slot's pooled keys, those
     among them, chooses each query's blocks and attends over the slot's pages
-    a tile at a time (``ops.attention.tiled_block_attention``); the new pooled
-    keys of all layers are written behind the scan
+    a tile at a time (``ops.attention.tiled_block_attention``, or the kernel);
+    the new pooled keys of all layers are written behind the scan
     (``ring_cache.pooled_chunk_insert``). The chunk is whole strides and goes in
     from row 0 in whole chunks. With ``return_block_tiles`` the tiles the
     attention visited, over layers ([1] int32), come after the counts; with
@@ -3382,12 +3412,25 @@ def chunk_prefill_forward(
     index_rope = _index_rope(cfg, positions) if cfg.sparse else None
     live = jnp.arange(C)[None] < count
     T = ring_rows(cache_k)
-    tile = min(cfg.q_chunk_size or _SUFFIX_TILE, T)
-    if (cfg.sliding and not cfg.latent) or cfg.blocks:  # the chunk is the engine's: the tile stays a tile
-        tile = min(_SUFFIX_TILE, T)
-    tile = tile if T % tile == 0 else T  # a ring of no whole tiles: one tile
+    tile = chunk_tile(cfg, T)
     seen = jnp.arange(T)[None] <= positions[0][:, None]  # [C, T]: the rows up to a query's own
     dsa = jax.named_scope if cfg.sparse else (lambda name: contextlib.nullcontext())
+
+    def in_kernel(q, pages):  # q [C, H, D] over pages [Kh, D, T]: ``chunk_attention`` takes them
+        form = chunk_form(C, q.shape[1], pages.shape[0], q.shape[2], T, tile, decode_kernel)
+        return form == "tiles-pallas"
+
+    def over_pages(q, pages_k, pages_v, rows=None):
+        # the chunk's queries over the slot's rows up to each query's own; under
+        # ``rows`` [C, T], a selection, over those it holds
+        if in_kernel(q, pages_k):
+            return chunk_attention(
+                q, pages_k, pages_v, positions[0], plen + count, tile,
+                None if rows is None else rows[None],
+            )[0]
+        return tiled_sparse_attention(
+            q, pages_k, pages_v, seen if rows is None else rows, plen + count, tile
+        )
 
     def body(carry, layer, li):
         h, ck, cv = carry
@@ -3399,7 +3442,7 @@ def chunk_prefill_forward(
             ck, cv = layer_rows_insert(
                 ck, cv, li, slot, k[0], v[0], plen, count, whole_chunks=cfg.sparse
             )
-            rows = seen
+            rows = None
             if cfg.sparse:
                 own_keys.append(ki[0])
                 with dsa("odtp_dsa_index"):
@@ -3407,11 +3450,12 @@ def chunk_prefill_forward(
                         qi[0], wi[0], ki[0], slot_layer_pages(ci, li, slot), plen, cfg.index_topk
                     )
             if return_row_choices:
-                last_row.append(jax.lax.dynamic_index_in_dim(rows, count - 1, 0, False))
+                last_row.append(
+                    jax.lax.dynamic_index_in_dim(seen if rows is None else rows, count - 1, 0, False)
+                )
             with dsa("odtp_dsa_attn"):
-                out = tiled_sparse_attention(
-                    q[0], slot_layer_pages(ck, li, slot), slot_layer_pages(cv, li, slot),
-                    rows, plen + count, tile,
+                out = over_pages(
+                    q[0], slot_layer_pages(ck, li, slot), slot_layer_pages(cv, li, slot), rows
                 )
             return out[None]
 
@@ -3495,9 +3539,8 @@ def chunk_prefill_forward(
                     )[None]
             ck = RingPair(*layer_rows_insert(*ck, li, slot, k[0], v[0], plen, count))
             with jax.named_scope("odtp_full_attn"):
-                return tiled_sparse_attention(
-                    q[0], slot_layer_pages(ck.k, li, slot), slot_layer_pages(ck.v, li, slot),
-                    seen, plen + count, tile,
+                return over_pages(
+                    q[0], slot_layer_pages(ck.k, li, slot), slot_layer_pages(ck.v, li, slot)
                 )[None]
 
         h, out = decoder_block(view, h, layer, *rope, live=live, attend=attend)
@@ -3553,10 +3596,15 @@ def chunk_prefill_forward(
                     sizes, sizes.blocks(T),
                 )
             with jax.named_scope("odtp_block_attn"):
-                out, visited = tiled_block_attention(
-                    q[0], slot_layer_pages(ck, li, slot), slot_layer_pages(cv, li, slot), chosen,
-                    positions[0], plen + count, tile, sizes.block_size,
-                )
+                pages = (slot_layer_pages(ck, li, slot), slot_layer_pages(cv, li, slot))
+                if in_kernel(q[0], pages[0]):
+                    out, visited = chunk_attention(
+                        q[0], *pages, positions[0], plen + count, tile, chosen, sizes.block_size
+                    )
+                else:
+                    out, visited = tiled_block_attention(
+                        q[0], *pages, chosen, positions[0], plen + count, tile, sizes.block_size
+                    )
             kept.append((new, first, visited, jax.lax.dynamic_index_in_dim(chosen, count - 1, 1, False)))
             return out[None]
 

@@ -623,7 +623,9 @@ def tiled_sparse_attention(
     (one layer's pages of the slot, the chunk's own rows in them), ``chosen``
     [C, T] bool -> [C, H, D]. Tiles from ``live_rows`` (traced) on hold nothing
     chosen and are not visited. A query with no chosen row (a bucket's
-    padding) comes out zero."""
+    padding) comes out zero. The reference of
+    ``decode_kernels.chunk_attention``, which keeps the score tile in VMEM,
+    and the form off the TPU and under ``decode_kernels.chunk_form``'s line."""
     c, h, d = q.shape
     kh, _, t = pages_k.shape
     f32, neg = jnp.float32, jnp.finfo(jnp.float32).min
@@ -883,7 +885,9 @@ def tiled_block_attention(
     at positions ``at`` [C], pages_k and pages_v [Kh, D, T] (the chunk's own
     rows in them), ``chosen`` [Kh, C, blocks] bool -> (out [C, H, D], the tiles
     visited). Tiles from ``live_rows`` (traced) on are not visited, and of the
-    others one in which no query of the chunk chose a block is stepped over."""
+    others one in which no query of the chunk chose a block is stepped over.
+    The reference of ``decode_kernels.chunk_attention`` under its ``chosen``
+    operand, and the form off the TPU."""
     c, h, d = q.shape
     kh, _, t = pages_k.shape
     f32, neg = jnp.float32, jnp.finfo(jnp.float32).min

@@ -1467,3 +1467,202 @@ def block_decode_attention(
         counts.reshape(-1), bits.reshape(-1), q.reshape(s_, nkv, rep, d), cache_k, cache_v,
     )
     return merge_own_row(out.reshape(s_, h, d), m.reshape(s_, h), l.reshape(s_, h), q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# (d) a prefill chunk's attention over a slot's pages: the scores stay in VMEM
+# ---------------------------------------------------------------------------
+
+# The XLA forms (``ops.attention.tiled_sparse_attention``,
+# ``tiled_block_attention``) walk the ring a tile at a time under the whole
+# chunk's queries: a float32 score tile of query heads x chunk x tile, written
+# to memory and passed over for the mask, the maximum, the exponential, the sum
+# and the cast. Past :data:`_PREFILL_SCORE_BYTES` (what a whole prompt's scores
+# were measured at, above) that tile is what a visit costs: 134 MB for 32 heads
+# x 2,048 queries x 512 rows, some 0.8 ms a visit for 87 us of MXU work. The
+# kernel holds a block of queries of one KV head's group and one K and V tile a
+# grid step, and the score tile of one head at a time in VMEM.
+# Measured on the v5e (PERF.md section 6, PR 62), ms a layer's call, 2,048
+# queries at row 16,384 of a slot: 32 / 2 heads of 128 under a selection by
+# blocks (MiniCPM-SALA) the XLA form 25.8, the kernel at 512 queries x 512 rows
+# a grid step 8.3, 1,024 x 512 9.2, 256 x 512 9.1, 512 x 1,024 5.8, 1,024 x
+# 1,024 5.6; 48 / 8 heads under the causal mask alone (Laguna, row 6,144) 13.9 /
+# 5.0 / 5.6 / 5.6 / 3.4 / 3.2.
+_CHUNK_QUERIES = 512  # queries a grid step
+_CHUNK_ROWS = 1024  # ring rows a grid step, where the ring is whole tiles of them
+
+
+def _chunk_queries(c: int) -> int:
+    return _CHUNK_QUERIES if c % _CHUNK_QUERIES == 0 else c
+
+
+def _chunk_rows(t: int, tile: int) -> int:
+    """Ring rows a grid step of :func:`chunk_attention` holds: whole tiles of
+    the XLA form's ``tile``, :data:`_CHUNK_ROWS` where they cut the ring."""
+    return _CHUNK_ROWS if _CHUNK_ROWS % tile == 0 and t % _CHUNK_ROWS == 0 else tile
+
+
+def chunk_form(
+    c: int, hq: int, hkv: int, d: int, t: int, tile: int, decode_kernel: str | None = None
+) -> str:
+    """Which form a prefill chunk's grouped-query attention over a slot's
+    pages takes, from what the call can see: "tiles-pallas"
+    (:func:`chunk_attention`) where ``decode_kernel`` resolves to the kernels
+    (the chip, or a test that asks for them interpreted), the heads are whole
+    lanes, the ring of ``t`` rows is whole tiles of ``tile`` and the float32
+    scores of a tile under the chunk's ``c`` queries, which the XLA form
+    writes to memory, are worth it; else "tiled-xla". The engine reports it
+    (``ServeEngine.block_forms``, ``kind_forms``)."""
+    if resolve_decode_kernel(decode_kernel) != "pallas" or hq % hkv or t % tile:
+        return "tiled-xla"
+    bq = _chunk_queries(c)  # on the chip whole int8 sublanes, and a block VMEM holds
+    if not _interpret(None) and (d % 128 or tile % 128 or bq % 32 or bq > 1024):
+        return "tiled-xla"
+    return "tiles-pallas" if hq * c * tile * 4 >= _PREFILL_SCORE_BYTES else "tiled-xla"
+
+
+def chunk_tiles_held(visit: jax.Array) -> jax.Array:
+    """Which tile a grid step holds, from the tiles a row of the grid visits
+    (``visit`` [..., tiles] bool) -> int32 of its shape: a visited tile itself,
+    a tile stepped over the next one visited (fetched ahead), the steps behind
+    the last the last (an unchanged index moves nothing), -1 where the row
+    visits none. A step is taken where it holds its own tile."""
+    nk = visit.shape[-1]
+    ki = jnp.arange(nk, dtype=jnp.int32)
+    ahead = jax.lax.cummin(jnp.where(visit, ki, nk), axis=visit.ndim - 1, reverse=True)
+    last = jnp.max(jnp.where(visit, ki, -1), axis=-1, keepdims=True)
+    return jnp.where(ahead < nk, ahead, last).astype(jnp.int32)
+
+
+def _chunk_attn_kernel(held_ref, q_ref, at_ref, k_ref, v_ref, *rest, scale, step, rep):
+    picked_ref = rest[0] if len(rest) == 5 else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
+    g, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nk = pl.num_programs(2)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    f32 = jnp.float32
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, f32)
+        l_scr[:] = jnp.zeros(l_scr.shape, f32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, f32)
+
+    @pl.when(held_ref[step(g, qi, ki)] == ki)
+    def _visit():
+        row = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        reads = row <= at_ref[:]  # the rows up to a query's own
+        if picked_ref is not None:
+            reads = reads & (picked_ref[:].astype(jnp.int32) > 0)
+
+        def head(r, carry):
+            s = scale * jax.lax.dot_general(
+                q_ref[r], k_ref[:], (((1,), (0,)), ((), ())), preferred_element_type=f32
+            )  # [bq, bk]
+            s = jnp.where(reads, s, NEG_INF)
+            m_prev = m_scr[r]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(reads, jnp.exp(s - m_new), 0.0)
+            keep = jnp.exp(m_prev - m_new)
+            m_scr[r] = m_new
+            l_scr[r] = l_scr[r] * keep + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[r] = acc_scr[r] * keep + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[:], (((1,), (1,)), ((), ())), preferred_element_type=f32
+            )
+            return carry
+
+        jax.lax.fori_loop(0, rep, head, 0)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        def head(r, carry):
+            l = l_scr[r]
+            o_ref[r] = (acc_scr[r] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, rep, head, 0)
+
+
+def chunk_attention(
+    q: jax.Array, pages_k: jax.Array, pages_v: jax.Array, at: jax.Array, live_rows, tile: int,
+    chosen: jax.Array | None = None, block: int = 1, *, interpret: bool | None = None,
+):
+    """A prefill chunk's attention over one slot's pages with the scores in
+    VMEM: what ``ops.attention.tiled_sparse_attention`` and
+    ``tiled_block_attention`` compute, by their equations (float32 scores from
+    the operands as they are, masked entries at ``NEG_INF``, the running
+    maximum, sum and accumulator in float32, the probabilities cast to the
+    values' dtype, a query that reads no row zero). q [C, H, D] at positions
+    ``at`` [C], pages_k and pages_v [Kh, D, T] (the chunk's own rows in them)
+    -> (out [C, H, D], the tiles of ``tile`` rows visited [] int32, counted as
+    the XLA form counts them: the tiles before ``live_rows`` (traced) in which
+    some query chose something).
+
+    A query reads the rows up to its own; under ``chosen`` [G, C, T / block]
+    bool (G the KV heads, or 1: one choice for all) those of them whose block
+    of ``block`` rows it chose (a selection by blocks; ``block`` 1: a selection
+    by rows). The kernel takes the choice a row, int8 [G, C, T], expanded here.
+
+    A grid step is one tile of K and of V of one KV head under a block of
+    queries of its ``rep`` heads, which a loop walks (one trace of the body);
+    the ring's tiles are the grid's last dimension. A step is neither taken
+    nor its tiles fetched (:func:`chunk_tiles_held`, a scalar-prefetch table
+    made here) where the tile starts at or past ``live_rows``, where it starts
+    behind the block's last query, and where no query of the block chose
+    anything in it."""
+    from opendiloco_tpu.ops.flash_attention import _vmem_limit
+
+    c, h, d = q.shape
+    kh, _, t = pages_k.shape
+    rep, bq, bk = h // kh, _chunk_queries(c), _chunk_rows(t, tile)
+    nq, nk = c // bq, t // bk
+    at = at.astype(jnp.int32)
+    live = jnp.asarray(live_rows, jnp.int32)
+    walked = jnp.arange(t // tile) < (live + tile - 1) // tile  # the XLA form's tiles
+    last = jnp.max(at.reshape(nq, bq), axis=1)  # a block's last query
+    first = jnp.arange(nk, dtype=jnp.int32) * bk
+    visit = ((first < live)[None] & (first[None] <= last[:, None]))[None]  # [1, nq, nk]
+    picked = []
+    if chosen is not None:
+        by_tile = lambda tiles, *lead: chosen.reshape(chosen.shape[0], *lead, tiles, -1)
+        visit = visit & jnp.any(by_tile(nk, nq, bq), axis=(2, 4))
+        walked = walked & jnp.any(by_tile(t // tile, c), axis=(0, 1, 3))
+        picked = [jnp.repeat(chosen.astype(jnp.int8), block, axis=-1)]  # [G, C, T]
+    group = (lambda g: g) if visit.shape[0] > 1 else (lambda g: 0)  # one choice for all: one row
+    held = chunk_tiles_held(visit).reshape(-1)
+
+    def step(g, qi, ki):  # its place in ``held``
+        return (group(g) * nq + qi) * nk + ki
+
+    def tile_of(g, qi, ki, held_ref):
+        return jnp.maximum(held_ref[step(g, qi, ki)], 0)
+
+    queries = pl.BlockSpec((None, rep, bq, d), lambda g, qi, ki, held_ref: (g, 0, qi, 0))
+    page = pl.BlockSpec((None, d, bk), lambda g, qi, ki, held_ref: (g, 0, tile_of(g, qi, ki, held_ref)))
+    choice = pl.BlockSpec(
+        (None, bq, bk), lambda g, qi, ki, held_ref: (group(g), qi, tile_of(g, qi, ki, held_ref))
+    )
+    blocks = [((rep, bq, d), q.dtype), ((bq, 1), jnp.int32), ((d, bk), pages_k.dtype),
+              ((d, bk), pages_v.dtype), ((bq, bk), jnp.int8), ((rep, bq, d), q.dtype)]
+    scratch = [((rep, bq, 1), jnp.float32), ((rep, bq, 1), jnp.float32), ((rep, bq, d), jnp.float32)]
+    out = pl.pallas_call(
+        functools.partial(_chunk_attn_kernel, scale=d**-0.5, step=step, rep=rep),
+        name="odtp_chunk_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kh, nq, nk),
+            in_specs=[
+                queries, pl.BlockSpec((bq, 1), lambda g, qi, ki, held_ref: (qi, 0)), page, page,
+                *[choice] * len(picked),
+            ],
+            out_specs=queries,
+            scratch_shapes=[pltpu.VMEM(shape, dtype) for shape, dtype in scratch],
+        ),
+        out_shape=jax.ShapeDtypeStruct((kh, rep, c, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(*blocks, *blocks, *scratch, ((bq, bk), jnp.float32)),
+        ),
+        interpret=_interpret(interpret),
+    )(held, jnp.moveaxis(q.reshape(c, kh, rep, d), 0, 2), at[:, None], pages_k, pages_v, *picked)
+    return jnp.moveaxis(out, 2, 0).reshape(c, h, d), jnp.sum(walked).astype(jnp.int32)

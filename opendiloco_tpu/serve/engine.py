@@ -80,7 +80,9 @@ from opendiloco_tpu import obs
 from opendiloco_tpu.diloco.compression import get_codec
 from opendiloco_tpu.models.llama import (
     LlamaConfig,
+    chunk_attn_form,
     chunk_prefill_forward,
+    chunk_tile,
     decode_forward,
     causal_prefill_heads,
     prefill_forward,
@@ -247,7 +249,7 @@ def serving_programs(
     return prefill, decode, admit_insert, 2 + n_state
 
 
-def chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
+def chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False, decode_kernel: str = "xla"):
     """The one function a prompt admitted in chunks runs, unjitted:
     ``chunk(params, ids [1, C], plen, count, slot, last, first, ck, cv, ci) ->
     (the chunk's last real token's greedy successor [1] and a routed model's
@@ -256,14 +258,14 @@ def chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
     compile serves every prompt and every chunk of it. ``last`` says whether
     the token is the prompt's first (then it goes into ``first[slot]``, where
     the next decode step takes it); the trailing four arguments are updated
-    and a jit donates them."""
+    and a jit donates them. ``decode_kernel`` as ``serving_programs`` takes it."""
     moe = bool(cfg.num_experts)
 
     def chunk(p, ids, plen, count, slot, last, first, ck, cv, ci):
         with jax.named_scope("odtp_serve_prefill"):
             logits, ck, cv, ci, *rest = chunk_prefill_forward(
                 p, ids, plen, count, slot, ck, cv, ci, cfg, compute_dtype=compute_dtype,
-                return_moe_counts=moe, return_row_choices=rows,
+                return_moe_counts=moe, return_row_choices=rows, decode_kernel=decode_kernel,
             )
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             first = jnp.where(last, first.at[slot].set(tok[0]), first)
@@ -272,7 +274,9 @@ def chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
     return chunk
 
 
-def state_chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
+def state_chunk_program(
+    cfg: LlamaConfig, *, compute_dtype, rows: bool = False, decode_kernel: str = "xla"
+):
     """``chunk_program`` for a stack of lightning layers beside attention under
     a selection by blocks: ``chunk(params, ids [1, C], plen, count, total,
     slot, last, first, ck, cv, pc, ls) -> (the chunk's last real token's greedy
@@ -288,7 +292,7 @@ def state_chunk_program(cfg: LlamaConfig, *, compute_dtype, rows: bool = False):
             logits, ck, cv, _, pc, ls, tiles, *rest = chunk_prefill_forward(
                 p, ids, plen, count, slot, ck, cv, None, cfg, compute_dtype=compute_dtype,
                 pooled_cache=pc, lightning_state=ls, total=total, return_block_tiles=True,
-                return_row_choices=rows,
+                return_row_choices=rows, decode_kernel=decode_kernel,
             )
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             first = jnp.where(last, first.at[slot].set(tok[0]), first)
@@ -549,6 +553,15 @@ class ServeEngine:
         self.dsa_kv_bytes_read = 0
         self.prefill_chunks = 0
         self.prefill_chunk_tokens = 0
+        # the form a chunk's grouped-query attention over a slot's pages takes,
+        # by ``decode_kernel`` and the shapes (``llama.chunk_attn_form``:
+        # "tiles-pallas", the kernel that keeps its scores in VMEM, where the XLA
+        # form's score tile would be written to memory, else "tiled-xla"); ""
+        # where no prompt goes in chunks over K and V rows
+        chunked = (cfg.sparse or cfg.sliding or cfg.blocks) and not cfg.latent
+        self.chunk_form = chunk_attn_form(
+            cfg, cfg.q_chunk_size, self.max_context, self.decode_kernel
+        ) if chunked else ""
         self.latent_cache_resident_bytes = self.cache_k.nbytes if cfg.latent else 0
         self._latent_row_bytes = (
             cfg.latent_row_dim * self.cache_k.dtype.itemsize if cfg.latent else 0
@@ -592,8 +605,8 @@ class ServeEngine:
         # form each kind's decode step and chunk take ({} without such a stack):
         # the decode step's by ``decode_kernel`` (the kernel has a plan for both
         # rings or the engine is refused here, never a step that quietly takes
-        # the XLA form), a chunk's from shapes alone (the full layers' the tiled
-        # XLA form; the sliding layers' the band alone, "banded-xla", where
+        # the XLA form), a chunk's from what the code sees (the full layers'
+        # ``chunk_form``; the sliding layers' the band alone, "banded-xla", where
         # ``band_block`` cuts the ring, else every tile under the window's mask)
         self.full_rows_read = 0
         self.kinds_bytes_moved = 0
@@ -612,7 +625,7 @@ class ServeEngine:
                 f"{swa_rows} rows: {plans}",
             )
             banded = band_block(cfg.q_chunk_size, swa_rows, cfg.sliding_window_size)
-            chunk_forms = {"full": "tiled-xla", "sliding": "banded-xla" if banded else "tiled-xla"}
+            chunk_forms = {"full": self.chunk_form, "sliding": "banded-xla" if banded else "tiled-xla"}
             self.kind_forms = {
                 kind: {"decode": self.decode_kernel, "chunk": chunk_forms[kind],
                        "block_t": plan.block_t if self.decode_kernel == "pallas" else 0,
@@ -637,7 +650,8 @@ class ServeEngine:
         # names the form the step and the chunk take ({} without the stack):
         # the step's by ``decode_kernel`` (the kernel has a tile for the ring or
         # the engine is refused here), its tile and the most tiles a slot and
-        # KV head walks; a chunk's are the XLA forms
+        # KV head walks; the chunk's attention's is ``chunk_form``, its
+        # selection's and the lightning layers' are the XLA forms
         self._sala: tuple = ()
         self.lightning_tokens = 0
         self.lightning_state_bytes_moved = 0
@@ -662,7 +676,7 @@ class ServeEngine:
             )
             self._block_tile = tile or cfg.block_sizes.block_size
             # the chunk's attention's tile, as ``chunk_prefill_forward`` cuts the ring
-            self._chunk_tile = 512 if self.max_context % 512 == 0 else self.max_context
+            self._chunk_tile = chunk_tile(cfg, self.max_context)
             self._sala = (
                 init_pooled_cache(cfg, self.num_slots, self.max_context, compute_dtype),
                 init_lightning_state(cfg, self.num_slots),
@@ -670,7 +684,7 @@ class ServeEngine:
             pallas = self.decode_kernel == "pallas"
             self.block_forms = {
                 "decode": "block-tiles-pallas" if pallas else "block-gather-xla",
-                "chunk": "tiled-xla", "selection": "xla", "lightning_chunk": "chunked-xla",
+                "chunk": self.chunk_form, "selection": "xla", "lightning_chunk": "chunked-xla",
                 "lightning_step": "xla", "block_t": tile if pallas else 0,
                 "most_tiles": block_most_tiles(self.max_context, tile, cfg.block_sizes) if pallas else 0,
             }
@@ -795,13 +809,14 @@ class ServeEngine:
         self._chunk = None
         if self._sala:
             self._chunk_programs = lambda rows: jax.jit(
-                state_chunk_program(cfg, compute_dtype=cd, rows=rows),
+                state_chunk_program(cfg, compute_dtype=cd, rows=rows, decode_kernel=dkn),
                 donate_argnums=(7, 8, 9, 10, 11),
             )
             self._chunk = self._chunk_programs(False)
         elif cfg.sparse or cfg.sliding:
             self._chunk_programs = lambda rows: jax.jit(
-                chunk_program(cfg, compute_dtype=cd, rows=rows), donate_argnums=(6, 7, 8, 9)
+                chunk_program(cfg, compute_dtype=cd, rows=rows, decode_kernel=dkn),
+                donate_argnums=(6, 7, 8, 9),
             )
             self._chunk = self._chunk_programs(False)
         # a slot's pages coming back from the host tier (one compile per row count)
@@ -814,7 +829,7 @@ class ServeEngine:
         # runs, over the slot's rows [0, plen), its own rows written in place
         def _suffix(p, tail, plen, count, slot, ck, cv):
             logits, ck, cv, _ = chunk_prefill_forward(
-                p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=cd
+                p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=cd, decode_kernel=dkn
             )
             return logits[0], ck, cv
 

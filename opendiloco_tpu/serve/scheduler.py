@@ -1263,12 +1263,13 @@ class ContinuousBatcher:
                 for name, value in self.engine.decode_plan_stats().items()
             },
             # what the indexer and the attention under its selection did, and the
-            # prompts admitted in chunks (zeros without learned sparse attention)
+            # prompts admitted in chunks (zeros without learned sparse attention);
+            # the form a chunk's grouped-query attention takes ("": no such chunk)
             "dsa": {
                 name: getattr(self.engine, name) for name in (
                     "dsa_rows_scored", "dsa_rows_selected", "dsa_index_bytes_read",
                     "dsa_kv_bytes_read", "index_cache_resident_bytes", "prefill_chunks",
-                    "prefill_chunk_tokens",
+                    "prefill_chunk_tokens", "chunk_form",
                 )
             },
             # what latent attention did with its rings by kind of layer: the

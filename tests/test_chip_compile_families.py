@@ -339,7 +339,10 @@ def test_keye_chunk_program_writes_its_rows_in_place(chip):
     ring's size (a first form whose K and V pages kept a row contiguous was
     re-laid rows minor-most by the compiler, 3.09 GB a ring, and did not fit
     the chip); the index scores and the attention's tiles are the only large
-    float32 blocks and stay under 256 MB; no weight is cast."""
+    float32 blocks and stay under 256 MB; no weight is cast. Asked for the
+    kernels as the engine asks on the chip, the chunk's attention stays the
+    tiled XLA form: 32 heads x 512 queries x 512 rows of float32 scores are
+    33.5 MB, under the line of ``chunk_form``."""
     from opendiloco_tpu.serve.engine import chunk_program
 
     cfg, engine, rings = _keye_cell(chip)
@@ -349,7 +352,8 @@ def test_keye_chunk_program_writes_its_rows_in_place(chip):
     ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
     last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
     compiled = (
-        jax.jit(chunk_program(cfg, compute_dtype=BF16), donate_argnums=(6, 7, 8, 9))
+        jax.jit(chunk_program(cfg, compute_dtype=BF16, decode_kernel="pallas"),
+                donate_argnums=(6, 7, 8, 9))
         .lower(params, ids, scalar, scalar, scalar, last, vec, *rings).compile()
     )
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -363,6 +367,7 @@ def test_keye_chunk_program_writes_its_rows_in_place(chip):
     assert not leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
     assert not f32_blocks_over(text, 256e6)
     assert "f32[512,16896]" in text  # a chunk's index scores: 35 MB, never a block a head
+    assert "odtp_chunk_attn" not in text
 
 
 def test_keye_index_ring_write_kernel(chip):
@@ -417,7 +422,8 @@ def _dots3_program(chip, which):
         ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
         last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
         lowered = jax.jit(
-            chunk_program(cfg, compute_dtype=BF16), donate_argnums=(6, 7, 8, 9)
+            chunk_program(cfg, compute_dtype=BF16, decode_kernel="pallas"),
+            donate_argnums=(6, 7, 8, 9),
         ).lower(params, ids, scalar, scalar, scalar, last, vec, *rings)
     return cfg, params, rings, lowered.compile()
 
@@ -457,7 +463,7 @@ def test_dots3_programs_copy_no_ring_and_cast_no_weight(chip, which):
         assert mem.temp_size_in_bytes < 256e6
         assert not f32_blocks_over(text, 256e6)
     else:
-        assert "f32[512,25088]" in text
+        assert "f32[512,25088]" in text and "odtp_chunk_attn" not in text  # latent rows: the XLA form
         assert mem.temp_size_in_bytes < 2e9
         assert not f32_blocks_over(text, 512e6)
 
@@ -488,7 +494,8 @@ def _laguna_program(chip, which):
         ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
         last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
         lowered = jax.jit(
-            chunk_program(cfg, compute_dtype=BF16), donate_argnums=(6, 7, 8, 9)
+            chunk_program(cfg, compute_dtype=BF16, decode_kernel="pallas"),
+            donate_argnums=(6, 7, 8, 9),
         ).lower(params, ids, scalar, scalar, scalar, last, vec, *rings, None)
     return cfg, params, rings, lowered.compile()
 
@@ -503,7 +510,9 @@ def test_laguna_programs_copy_no_ring_and_cast_no_weight(chip, which):
     The decode step holds ``odtp_paged_decode_attn`` for both kinds (under the
     window for the sliding layers: the kernel, not its XLA form); the chunk
     holds no float32 block over 512 MB (no [72, 2048, 4096] scores: the band's
-    blocks and the full layers' tiles)."""
+    blocks) and its full layers run ``odtp_chunk_attn`` (PR 62: 512 queries of
+    a KV head's 6 heads over 1,024 rows a grid step, and no [8, 6, 2048, 512]
+    float32 tile of scores, 201 MB, in memory)."""
     cfg, params, rings, compiled = _laguna_program(chip, which)
     assert (cfg.num_full_layers, cfg.num_sliding_layers) == (2, 6)
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -526,6 +535,9 @@ def test_laguna_programs_copy_no_ring_and_cast_no_weight(chip, which):
     else:
         assert mem.temp_size_in_bytes < 2.5e9
         assert not f32_blocks_over(text, 512e6)
+        assert "f32[8,6,2048,512]" not in text
+        assert {tuple(b[:4]) for b in kernel_windows(text, "odtp_chunk_attn")} == {
+            ((1, 6, 512, 128), (512, 1), (1, 128, 1024), (1, 128, 1024))}
 
 
 # --- MiniCPM-SALA: lightning states beside a selection by blocks (PR 61) ----------
@@ -560,7 +572,8 @@ def _sala_program(chip, which):
         ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
         last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
         lowered = jax.jit(
-            state_chunk_program(cfg, compute_dtype=BF16), donate_argnums=(7, 8, 9, 10, 11)
+            state_chunk_program(cfg, compute_dtype=BF16, decode_kernel="pallas"),
+            donate_argnums=(7, 8, 9, 10, 11),
         ).lower(params, ids, scalar, scalar, scalar, scalar, last, vec, *rings)
     return cfg, params, rings, lowered.compile()
 
@@ -575,9 +588,11 @@ def test_sala_programs_copy_no_ring_and_no_state_and_cast_no_weight(chip, which)
     program fits the chip. The decode step holds ``odtp_block_decode_attn`` (the
     kernel over the chosen blocks' tiles, a tile of 128 rows of one KV head a
     grid step under its 16 query heads) and the rings' writers behind the
-    layers; the chunk's temporaries stay under 1 GB (a sparse layer's tile of
-    scores [2, 16, 2048, 512] float32 is 134 MB, a head group's scores over the
-    pooled keys 71 MB)."""
+    layers; the chunk's temporaries stay under 1 GB (a head group's scores over
+    the pooled keys are 71 MB, a layer's choice a row in int8 142 MB) and its
+    attention is ``odtp_chunk_attn`` (PR 62: 512 queries of a KV head's 16 heads
+    over 1,024 rows and their choice a grid step; no [2, 16, 2048, 512] float32
+    tile of scores, 134 MB, in memory)."""
     cfg, params, rings, compiled = _sala_program(chip, which)
     assert (cfg.num_attention_layers, cfg.num_lightning_layers) == (4, 14)
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -603,3 +618,6 @@ def test_sala_programs_copy_no_ring_and_no_state_and_cast_no_weight(chip, which)
         # (the temporaries and not ``f32_blocks_over``: the float32 states are updated in
         # place at their own shape, and a fusion's inside holds the head widened for one row)
         assert mem.temp_size_in_bytes < 1e9
+        assert "f32[2,16,2048,512]" not in text
+        assert {tuple(b[:5]) for b in kernel_windows(text, "odtp_chunk_attn")} == {
+            ((1, 16, 512, 128), (512, 1), (1, 128, 1024), (1, 128, 1024), (1, 512, 1024))}

@@ -905,3 +905,120 @@ def test_ring_rows_sum_kernel_is_the_gather():
     got = ring_rows_sum(ring, 1, first, 31, interpret=True)
     np.testing.assert_allclose(got, gather(ring, 1, first, 31), rtol=1e-5, atol=1e-5)
     assert got.shape == (5, 2, 16)
+
+
+# --- a chunk's attention over a slot's pages with the scores in VMEM (PR 62) ---
+
+
+def _block_choice(rng, kh, at, blocks, block, *, dense=False, leave_out=()):
+    """Each query's blocks as ``choose_blocks`` would: block 0, its own and the
+    one before, and a random one of the others up to its own (every block up
+    to its own: ``dense``), none of ``leave_out``."""
+    chosen = np.zeros((kh, len(at), blocks), bool)
+    for g in range(kh):
+        for i, pos in enumerate(at):
+            own = pos // block
+            if dense:
+                chosen[g, i, : own + 1] = True
+                continue
+            chosen[g, i, [0, own, max(own - 1, 0), int(rng.integers(0, own + 1))]] = True
+    chosen[..., list(leave_out)] = False
+    return chosen
+
+
+# what; heads (H, Kh); plen, count (of 16 queries); queries and ring rows a grid step
+CHUNK_CASES = {
+    "sala_first_chunk": ("blocks", (8, 2), 0, 16, 8, 8),
+    "sala_mid_prompt": ("blocks", (8, 2), 32, 16, 8, 8),
+    "sala_padded_last_chunk": ("blocks", (8, 2), 32, 5, 8, 8),
+    "sala_two_tiles_a_step": ("blocks", (8, 2), 32, 16, 16, 16),
+    "sala_dense_len_side": ("dense", (8, 2), 32, 16, 8, 8),
+    "sala_a_tile_nobody_chose": ("skipped", (8, 2), 32, 16, 8, 8),
+    "laguna_first_chunk": ("seen", (16, 8), 0, 16, 8, 8),
+    "laguna_mid_prompt": ("seen", (16, 8), 24, 16, 8, 16),
+    "laguna_padded_last_chunk": ("seen", (16, 8), 40, 3, 16, 8),
+    "keye_rows_and_a_query_with_none": ("rows", (8, 2), 32, 16, 8, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_chunk_attention_is_the_tiled_forms(case, monkeypatch):
+    """``odtp_chunk_attn`` interpreted against ``tiled_block_attention`` (a
+    selection by blocks of 4 rows, one a KV head) and ``tiled_sparse_attention``
+    (the rows up to a query's own; a selection by rows): the same outputs to
+    the rounding of a reordered float32 sum, the same ``visited``. 16 queries
+    over a ring of 64 rows in tiles of 8. The grid steps it takes are the tiles
+    before the chunk's last row, up to a block of queries' last, in which a
+    query of the block chose something: a tile stepped over is never read (its
+    rows hold NaN)."""
+    from opendiloco_tpu.ops.attention import tiled_block_attention
+    from opendiloco_tpu.ops.decode_kernels import chunk_attention, chunk_tiles_held
+
+    what, (H, Kh), plen, count, bq, bk = CHUNK_CASES[case]
+    monkeypatch.setattr(decode_kernels, "_CHUNK_QUERIES", bq)
+    monkeypatch.setattr(decode_kernels, "_CHUNK_ROWS", bk)
+    C, D, T, tile, block = 16, 16, 64, 8, 4
+    rng = _rng(len(case))
+    q = _randn(rng, C, H, D)
+    ck, cv = (np.array(x[0]) for x in _pages(rng, 1, Kh, D, T))  # [Kh, D, T]
+    at = plen + np.arange(C)
+    live = plen + count
+    seen = np.arange(T)[None] <= at[:, None]
+    skipped = []
+    if what == "seen":
+        chosen, unit, rows = None, 1, seen
+    elif what == "rows":
+        rows = seen & (rng.random((C, T)) < 0.3)
+        rows[5] = False  # a query that reads no row
+        rows[:, 16:24] = False  # and a tile nobody chose
+        chosen, unit, skipped = rows[None], 1, [2]
+    else:
+        skipped = [3] if what == "skipped" else []
+        leave_out = [b for i in skipped for b in range(i * tile // block, (i + 1) * tile // block)]
+        chosen = _block_choice(rng, Kh, at, T // block, block, dense=what == "dense", leave_out=leave_out)
+        unit = block
+    at = jnp.asarray(at, jnp.int32)
+    if what in ("seen", "rows"):
+        want = tiled_sparse_attention(q, jnp.asarray(ck), jnp.asarray(cv), jnp.asarray(rows), live, tile)
+        want_visited = min(-(-live // tile), T // tile) - len(skipped)
+    else:
+        want, want_visited = tiled_block_attention(
+            q, jnp.asarray(ck), jnp.asarray(cv), jnp.asarray(chosen), at, live, tile, block
+        )
+    ck, cv = ck.copy(), cv.copy()  # (``jnp.asarray`` may share a numpy buffer on the CPU)
+    for i in skipped:  # never read: not masked, stepped over
+        ck[..., i * tile : (i + 1) * tile] = np.nan
+        cv[..., i * tile : (i + 1) * tile] = np.nan
+    ck, cv = jnp.asarray(ck), jnp.asarray(cv)
+    got, visited = chunk_attention(
+        q, ck, cv, at, live, tile, None if chosen is None else jnp.asarray(chosen), unit, interpret=True
+    )
+    assert np.isfinite(np.asarray(got)[:count]).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-6)
+    assert int(visited) == int(want_visited)
+    if what == "rows":
+        assert not np.asarray(got)[5].any()  # no row read: zero
+    if what == "dense":  # every row up to a query's own: the plain suffix's attention
+        plain = tiled_sparse_attention(q, ck, cv, jnp.asarray(seen), live, tile)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(plain), rtol=2e-6, atol=2e-6)
+    # the table the grid walks, from the same three rules
+    nq, nk = C // bq, T // bk
+    reads = np.broadcast_to(seen[None] if chosen is None else np.repeat(chosen, unit, -1) & seen, (Kh, C, T))
+    by_step = reads.reshape(Kh, nq, bq, nk, bk).any(axis=(2, 4))
+    last = np.asarray(at).reshape(nq, bq).max(axis=1)
+    first = np.arange(nk) * bk
+    by_step = by_step & (first < live)[None, None] & (first[None] <= np.asarray(last)[:, None])[None]
+    if chosen is None or chosen.shape[0] == 1:
+        by_step = by_step[:1]
+    held = np.asarray(chunk_tiles_held(jnp.asarray(by_step)))
+    taken = held == np.arange(nk)
+    assert (taken == by_step).all()
+    for row, steps in zip(held.reshape(-1, nk), by_step.reshape(-1, nk)):
+        tiles = np.flatnonzero(steps)
+        if not len(tiles):
+            assert (row == -1).all()
+            continue
+        for ki in range(nk):  # the next tile it will read, fetched ahead; then nothing moves
+            ahead = tiles[tiles >= ki]
+            assert row[ki] == (ahead[0] if len(ahead) else tiles[-1])
+    assert taken.sum() < Kh * nq * nk  # steps are skipped

@@ -537,6 +537,35 @@ def test_batcher_interleaves_chunks_with_decode_steps():
     assert stats["admissions_deferred"] >= 3  # the long prompts' first tokens stayed on the device
 
 
+def test_a_chunk_under_a_selection_by_rows_keeps_the_xla_form_by_its_bytes(monkeypatch):
+    """A chunk of 8 queries of 4 heads over a tile of 8 rows is 1 KB of scores:
+    an engine asked for the kernels keeps the tiled XLA form (as the published
+    widths do: 32 heads x 512 x 512 x 4 B = 33.5 MB under the line of 96 MB).
+    With the line at 0 the selection goes through ``odtp_chunk_attn`` as its
+    choice-a-row operand, interpreted, and the tokens are the XLA engine's."""
+    from opendiloco_tpu.ops import decode_kernels
+
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")
+    _, cfg, params = model(seed=11)
+    assert decode_kernels.chunk_form(512, 32, 4, 128, 35840, 512, "pallas") == "tiled-xla"
+    assert engine_for(cfg, params, decode_kernel="pallas").chunk_form == "tiled-xla"
+    monkeypatch.setattr(decode_kernels, "_PREFILL_SCORE_BYTES", 0)
+    prompt = tokens(12, (37,)).tolist()  # past the bucket: in chunks, past ``index_topk`` rows
+    got = {}
+    for kernel in ("xla", "pallas"):
+        engine = engine_for(cfg, params, decode_kernel=kernel)
+        assert engine.chunk_form == ("tiles-pallas" if kernel == "pallas" else "tiled-xla")
+        tok, logits = engine.admit(0, prompt)
+        toks, lens, out = np.array([tok, 0, 0], np.int32), np.array([37, 0, 0], np.int32), [tok]
+        for _ in range(4):
+            nxt, _ = engine.decode_step(toks, lens)
+            toks[0], lens[0] = nxt[0], lens[0] + 1
+            out.append(int(nxt[0]))
+        got[kernel] = (out, np.asarray(logits))
+    assert got["pallas"][0] == got["xla"][0]
+    assert rel(got["pallas"][1], got["xla"][1]) < 1e-5
+
+
 def test_the_five_forwards_agree_on_this_block():
     """Training forward, whole-prompt prefill, chunked prefill, the decode step
     through the three rings and the engine's programs: one block, one set of

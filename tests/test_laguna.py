@@ -292,6 +292,38 @@ def test_the_engine_takes_its_own_chunk_and_serves_through_the_batcher(model, mo
         ContinuousBatcher(engine, prefix_cache=True)
 
 
+def test_an_engine_asked_for_the_kernels_runs_its_full_layers_chunks_through_the_chunk_kernel(
+    model, monkeypatch
+):
+    """``odtp_chunk_attn`` interpreted under the engine's full layers (a ring of
+    three tiles of 16 rows, the causal mask alone): the XLA engine's greedy
+    tokens, and ``kind_forms`` says which form ran; the sliding layers keep the
+    band."""
+    from opendiloco_tpu.ops import decode_kernels
+
+    cfg, params, _, _ = model
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")
+    monkeypatch.setattr(llama, "_SUFFIX_TILE", 16)
+    monkeypatch.setattr(decode_kernels, "_PREFILL_SCORE_BYTES", 0)
+    prompt = np.random.default_rng(3).integers(3, 128, 29).tolist()
+    got = {}
+    for kernel in ("xla", "pallas"):
+        engine = ServeEngine(cfg, params, num_slots=3, max_context=48, prefill_buckets=(),
+                             prefill_chunk=CHUNK, decode_kernel=kernel, **F32)
+        form = "tiles-pallas" if kernel == "pallas" else "tiled-xla"
+        assert engine.kind_forms["full"]["chunk"] == engine.chunk_form == form
+        assert engine.kind_forms["sliding"]["chunk"] == "banded-xla"
+        tok, logits = engine.admit(1, prompt)
+        toks, lens, out = np.asarray([0, tok, 0]), np.asarray([0, 29, 0]), [tok]
+        for _ in range(4):
+            nxt, _ = engine.decode_step(toks, lens)
+            toks, lens = np.asarray([0, nxt[1], 0]), lens + np.asarray([0, 1, 0])
+            out.append(int(nxt[1]))
+        got[kernel] = (out, np.asarray(logits))
+    assert got["pallas"][0] == got["xla"][0]
+    close(got["pallas"][1], got["xla"][1])
+
+
 @pytest.mark.parametrize("feature", ["prefix reuse", "page-out", "page-in"])
 def test_what_handles_one_ring_from_row_0_refuses_the_stack_by_name(model, feature):
     """The engine's three sites ask the table the scheduler asks, so each refuses

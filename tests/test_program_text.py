@@ -28,6 +28,13 @@ PR 61 (MiniCPM-SALA: lightning layers' states and a selection by blocks in the
 shared forwards, engine and scheduler) added ``serve-granite-h-docqa``'s and
 ``serve-laguna-repoedit``'s configurations, recorded from its parent ``c311252``.
 
+PR 62 gave ``chunk_prefill_forward`` the kernel ``odtp_chunk_attn`` where the XLA
+form's tile of scores would pass 96 MB (``decode_kernels.chunk_form``): of these
+cells Laguna's full layers alone. ``chunk`` is lowered as the engine lowers it on
+the chip (``decode_kernel`` "pallas") wherever the form stays the XLA one there,
+Keye's and dots3's among them, and for Laguna as it lowers off the chip: every
+digest is the parent's.
+
 The loop is held the same way: ``ContinuousBatcher``'s iteration for a
 configuration without sliding layers calls no function of the engine that the
 parent's did not."""
@@ -143,17 +150,27 @@ def digests(name: str) -> dict:
         if prefill_form(bucket, *heads, "pallas") == "xla":
             texts[f"prefill/{bucket}"] = lower(
                 prefill, params, sds((1, bucket), jnp.int32), scalar)
+    # the continued prefill as the engine lowers it on the chip where its
+    # attention keeps the tiled XLA form there (PR 62: ``chunk_form``'s bytes
+    # rule; latent rows never take the kernel), else (Laguna's full layers) as
+    # it lowers off the chip: the parent's text either way
+    chunk = cfg.q_chunk_size or 128
+    xla_there = cfg.latent or llama.chunk_attn_form(cfg, chunk, rows, "pallas") == "tiled-xla"
+    assert xla_there == (name != "laguna")
+    kernel = "pallas" if xla_there else "xla"
     if cfg.sparse or cfg.sliding:
         texts["chunk"] = lower(
-            chunk_program(cfg, compute_dtype=bf), params, sds((1, cfg.q_chunk_size), jnp.int32),
+            chunk_program(cfg, compute_dtype=bf, decode_kernel=kernel), params,
+            sds((1, cfg.q_chunk_size), jnp.int32),
             scalar, scalar, scalar, sds((), jnp.bool_), vec, *rings,
             *([] if cfg.sparse else [None]), donate_argnums=(6, 7, 8, 9),
         )
     elif not (cfg.latent or cfg.hybrid):  # the suffix behind a reused prefix
         texts["chunk"] = lower(
             lambda p, tail, plen, count, slot, ck, cv: llama.chunk_prefill_forward(
-                p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=bf),
-            params, sds((1, 128), jnp.int32), scalar, scalar, scalar, *rings,
+                p, tail, plen, count, slot, ck, cv, None, cfg, compute_dtype=bf,
+                decode_kernel=kernel),
+            params, sds((1, chunk), jnp.int32), scalar, scalar, scalar, *rings,
             donate_argnums=(5, 6),
         )
     return {k: hashlib.sha256(v.encode()).hexdigest()[:16] for k, v in texts.items()}

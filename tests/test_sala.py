@@ -286,6 +286,37 @@ def test_the_engine_admits_every_prompt_in_chunks_and_counts(model, engine):
     assert stats["pooled_ring_rows"] == 24 and stats["lightning_state_bytes"] == engine.lightning_state_resident_bytes
 
 
+def test_an_engine_asked_for_the_kernels_runs_its_chunks_through_the_chunk_kernel(model, monkeypatch):
+    """``odtp_chunk_attn`` interpreted under the engine (rings of three tiles of
+    32 rows): the XLA engine's greedy tokens and its tiles, and the forms say
+    which ran. By the bytes rule a stack this small keeps the XLA form."""
+    from opendiloco_tpu.ops import decode_kernels
+
+    cfg, params = model
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "16")
+    monkeypatch.setattr(llama, "_SUFFIX_TILE", 32)
+    make = lambda kernel: ServeEngine(
+        cfg, params, num_slots=SLOTS, max_context=RING, prefill_buckets=(), prefill_chunk=CHUNK,
+        decode_kernel=kernel, **F32)
+    assert make("pallas").block_forms["chunk"] == "tiled-xla"  # 4 heads x 16 x 32 float32 scores
+    monkeypatch.setattr(decode_kernels, "_PREFILL_SCORE_BYTES", 0)
+    engines = {kernel: make(kernel) for kernel in ("xla", "pallas")}
+    assert engines["xla"].block_forms["chunk"] == engines["xla"].chunk_form == "tiled-xla"
+    assert engines["pallas"].block_forms["chunk"] == engines["pallas"].chunk_form == "tiles-pallas"
+    prompt = tokens(9, 53).tolist()
+    got = {}
+    for kernel, eng in engines.items():
+        tok, logits = eng.admit(1, prompt)
+        toks, lens, out = np.asarray([0, tok, 0]), np.asarray([0, 53, 0]), [tok]
+        for _ in range(4):
+            nxt, _ = eng.decode_step(toks, lens)
+            toks, lens = np.asarray([0, nxt[1], 0]), lens + np.asarray([0, 1, 0])
+            out.append(int(nxt[1]))
+        got[kernel] = (out, np.asarray(logits), eng.block_tiles_read, eng.block_tiles_live)
+    assert got["pallas"][0] == got["xla"][0] and got["pallas"][2:] == got["xla"][2:]
+    assert rel(got["pallas"][1], got["xla"][1]) < 1e-5
+
+
 def test_the_batcher_serves_it_and_says_so_on_stats(model, engine):
     cfg, params = model
     batcher = ContinuousBatcher(engine).start()
