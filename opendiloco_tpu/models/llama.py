@@ -80,6 +80,8 @@ from opendiloco_tpu.ops.decode_kernels import (
     eva_decode_attention,
     eva_prefill_attention,
     index_ring_write,
+    latent_chunk_attention,
+    latent_chunk_form,
     mla_decode_attention,
     paged_decode_attention,
     ring_rows_sum,
@@ -3315,6 +3317,18 @@ def chunk_attn_form(cfg: LlamaConfig, chunk: int, rows: int, decode_kernel: str 
     )
 
 
+def latent_chunk_attn_form(cfg: LlamaConfig, chunk: int, rows: int, decode_kernel: str | None) -> str:
+    """The form ``chunk_prefill_forward`` runs a chunk of ``chunk`` tokens'
+    latent attention in over a ring of ``rows`` that does not wrap (a latent
+    stack's full layers; ``decode_kernels.latent_chunk_form`` at their heads
+    and their row)."""
+    view = kind_view(cfg, "attention")
+    return latent_chunk_form(
+        chunk, view.num_attention_heads, view.latent_row_dim, view.kv_lora_rank, rows,
+        chunk_tile(cfg, rows), decode_kernel,
+    )
+
+
 def chunk_prefill_forward(
     params: dict,
     ids: jax.Array,
@@ -3356,8 +3370,9 @@ def chunk_prefill_forward(
     grouped-query layer's walk over the tiles takes
     (``decode_kernels.chunk_form``: the kernel ``chunk_attention``, whose score
     tile stays in VMEM, where the XLA form's would be written to memory at
-    every tile; the XLA form elsewhere, and over latent rows, a sliding
-    layer's ring and without the argument).
+    every tile; the XLA form elsewhere, over a sliding layer's ring and without
+    the argument), and likewise a latent layer's over its page
+    (``decode_kernels.latent_chunk_form``: ``latent_chunk_attention``).
 
     Which rows a query reads: every row up to its own, or, under learned
     sparse attention (``index_cache``: the slot's index ring beside K and V,
@@ -3469,8 +3484,9 @@ def chunk_prefill_forward(
     def latent_body(carry, layer, li, view, rope):
         # one kind of latent layer over its own ring, in the absorbed form: the
         # chunk's rows go in, then its queries over the slot's page a tile at a
-        # time, under the selection (a full layer) or the window's rows of a
-        # ring that wraps (a sliding one); no key or value is rebuilt
+        # time, under the selection (a full layer: in the kernel where
+        # ``latent_chunk_form`` says) or the window's rows of a ring that wraps
+        # (a sliding one); no key or value is rebuilt
         h, ck, cv = carry
         last_row: list = []
         own_keys: list = []
@@ -3501,8 +3517,12 @@ def chunk_prefill_forward(
                     )
                 if return_row_choices:
                     last_row.append(jax.lax.dynamic_index_in_dim(reads, count - 1, 0, False))
+            form = latent_chunk_form(C, *q_lat.shape[1:], view.kv_lora_rank, T, tile, decode_kernel)
+            over_page = (
+                latent_chunk_attention if form == "absorbed-pallas" else tiled_latent_attention
+            )
             with dsa("odtp_dsa_attn"):
-                o_lat = tiled_latent_attention(
+                o_lat = over_page(
                     q_lat, slot_layer_pages(ck, li, slot)[0], reads, plen + count, tile, **sizes
                 )
             return latent_expand(view, o_lat, w_kvb)[None]

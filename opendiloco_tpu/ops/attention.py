@@ -288,7 +288,10 @@ def tiled_latent_attention(
     weighted sum over a row's first ``value_dim`` values. No key or value is
     rebuilt and no [C, T] block of scores a head is held; tiles from
     ``live_rows`` (traced) on hold nothing read and are not visited. A query
-    that reads no row (a bucket's padding) comes out zero."""
+    that reads no row (a bucket's padding) comes out zero. The reference of
+    ``decode_kernels.latent_chunk_attention``, which keeps the score tile in
+    VMEM, and the form off the TPU, over a sliding layer's ring and under
+    ``decode_kernels.latent_chunk_form``'s line."""
     c, h, dl = q.shape
     t = page.shape[-1]
     f32, neg = jnp.float32, jnp.finfo(jnp.float32).min

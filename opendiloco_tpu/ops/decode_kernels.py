@@ -1534,6 +1534,37 @@ def chunk_tiles_held(visit: jax.Array) -> jax.Array:
     return jnp.where(ahead < nk, ahead, last).astype(jnp.int32)
 
 
+def _softmax_start(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+
+def _softmax_visit(r, s, reads, v_ref, m_scr, l_scr, acc_scr):
+    """A tile into head ``r``'s online softmax: its scores ``s`` [bq, bk]
+    float32, of which a query reads the entries of ``reads``, and the tile's
+    values ``v_ref`` [dv, bk], into the running maximum, sum and accumulator."""
+    s = jnp.where(reads, s, NEG_INF)
+    m_prev = m_scr[r]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(reads, jnp.exp(s - m_new), 0.0)
+    keep = jnp.exp(m_prev - m_new)
+    m_scr[r] = m_new
+    l_scr[r] = l_scr[r] * keep + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[r] = acc_scr[r] * keep + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[:], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _softmax_finish(heads, o_ref, l_scr, acc_scr):
+    def head(r, carry):
+        l = l_scr[r]
+        o_ref[r] = (acc_scr[r] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, heads, head, 0)
+
+
 def _chunk_attn_kernel(held_ref, q_ref, at_ref, k_ref, v_ref, *rest, scale, step, rep):
     picked_ref = rest[0] if len(rest) == 5 else None
     o_ref, m_scr, l_scr, acc_scr = rest[-4:]
@@ -1544,9 +1575,7 @@ def _chunk_attn_kernel(held_ref, q_ref, at_ref, k_ref, v_ref, *rest, scale, step
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, f32)
-        l_scr[:] = jnp.zeros(l_scr.shape, f32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, f32)
+        _softmax_start(m_scr, l_scr, acc_scr)
 
     @pl.when(held_ref[step(g, qi, ki)] == ki)
     def _visit():
@@ -1559,28 +1588,14 @@ def _chunk_attn_kernel(held_ref, q_ref, at_ref, k_ref, v_ref, *rest, scale, step
             s = scale * jax.lax.dot_general(
                 q_ref[r], k_ref[:], (((1,), (0,)), ((), ())), preferred_element_type=f32
             )  # [bq, bk]
-            s = jnp.where(reads, s, NEG_INF)
-            m_prev = m_scr[r]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(reads, jnp.exp(s - m_new), 0.0)
-            keep = jnp.exp(m_prev - m_new)
-            m_scr[r] = m_new
-            l_scr[r] = l_scr[r] * keep + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[r] = acc_scr[r] * keep + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[:], (((1,), (1,)), ((), ())), preferred_element_type=f32
-            )
+            _softmax_visit(r, s, reads, v_ref, m_scr, l_scr, acc_scr)
             return carry
 
         jax.lax.fori_loop(0, rep, head, 0)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        def head(r, carry):
-            l = l_scr[r]
-            o_ref[r] = (acc_scr[r] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
-            return carry
-
-        jax.lax.fori_loop(0, rep, head, 0)
+        _softmax_finish(rep, o_ref, l_scr, acc_scr)
 
 
 def chunk_attention(
@@ -1666,3 +1681,133 @@ def chunk_attention(
         interpret=_interpret(interpret),
     )(held, jnp.moveaxis(q.reshape(c, kh, rep, d), 0, 2), at[:, None], pages_k, pages_v, *picked)
     return jnp.moveaxis(out, 2, 0).reshape(c, h, d), jnp.sum(walked).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# (d') a prefill chunk's latent attention over a slot's page, absorbed form
+# ---------------------------------------------------------------------------
+
+# Every head reads the one page of latent rows, so this is the kernel above
+# with one KV head and all the heads its group: but no step holds the group (128
+# heads' absorbed queries of a chunk of 512 are 75 MB, their accumulator 134),
+# so the grid has a dimension of head blocks before the query blocks and the
+# ring's tiles, and the page is fetched once a head block and query block.
+_LATENT_HEADS = 8  # heads a grid step
+
+
+def _latent_heads(h: int) -> int:
+    return _LATENT_HEADS if h % _LATENT_HEADS == 0 else h
+
+
+def latent_chunk_form(
+    c: int, h: int, dl: int, value_dim: int, t: int, tile: int, decode_kernel: str | None = None
+) -> str:
+    """Which form a prefill chunk's latent attention in the absorbed form over
+    a slot's page takes, from what the call can see, as :func:`chunk_form`
+    chooses: "absorbed-pallas" (:func:`latent_chunk_attention`) where
+    ``decode_kernel`` resolves to the kernels, the ring of ``t`` rows is whole
+    tiles of ``tile``, the blocks are ones the chip tiles, and the float32
+    scores of a tile under the chunk's ``c`` queries of ``h`` heads, which the
+    XLA form writes to memory beside a running sum as large, reach
+    :data:`_PREFILL_SCORE_BYTES`; else "absorbed-xla"
+    (``ops.attention.tiled_latent_attention``). The engine reports it
+    (``ServeEngine.latent_forms``)."""
+    if resolve_decode_kernel(decode_kernel) != "pallas" or t % tile:
+        return "absorbed-xla"
+    bq = _chunk_queries(c)  # on the chip whole int8 sublanes, and a block VMEM holds
+    if not _interpret(None) and (dl % 16 or value_dim % 128 or tile % 128 or bq % 32 or bq > 512):
+        return "absorbed-xla"
+    return "absorbed-pallas" if h * c * tile * 4 >= _PREFILL_SCORE_BYTES else "absorbed-xla"
+
+
+def _latent_chunk_kernel(held_ref, q_ref, page_ref, reads_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, value_dim):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    nk = pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        _softmax_start(m_scr, l_scr, acc_scr)
+
+    @pl.when(held_ref[qi * nk + ki] == ki)
+    def _visit():
+        reads = reads_ref[:].astype(jnp.int32) > 0  # [bq, bk]: one set a query for all heads
+
+        def head(r, carry):
+            s = scale * jax.lax.dot_general(
+                q_ref[r], page_ref[:], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )  # [bq, bk]: all Dl values of a row, the contraction as it is
+            # the values: the same tile's first ``value_dim`` rows
+            _softmax_visit(r, s, reads, page_ref.at[:value_dim], m_scr, l_scr, acc_scr)
+            return carry
+
+        jax.lax.fori_loop(0, q_ref.shape[0], head, 0)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        _softmax_finish(q_ref.shape[0], o_ref, l_scr, acc_scr)
+
+
+def latent_chunk_attention(
+    q: jax.Array, page: jax.Array, reads: jax.Array, live_rows, tile: int,
+    *, scale: float, value_dim: int, interpret: bool | None = None,
+) -> jax.Array:
+    """A prefill chunk's latent attention in the absorbed form over one slot's
+    page with the scores in VMEM: what ``ops.attention.tiled_latent_attention``
+    computes, which has this signature, by its equations (float32 scores from
+    the operands as they are, times ``scale``; entries a query does not read at
+    ``NEG_INF``; the running maximum, sum and accumulator in float32; the
+    probabilities cast to the page's dtype; the values the same tile's first
+    ``value_dim`` rows; a query that reads no row zero). q [C, H, Dl], ``page``
+    [Dl, T], ``reads`` [C, T] bool (one set a query for all its heads; the
+    kernel takes it int8 a row) -> o_lat [C, H, value_dim].
+
+    A grid step is one tile of ``tile`` ring rows, fetched once for scores and
+    values, under a block of queries of :data:`_LATENT_HEADS` heads, which a
+    loop walks (one trace of the body); the head blocks are the grid's first
+    dimension, the ring's tiles its last. ``Dl`` need be no whole number of
+    lanes (576 = 512 + 64): the scores are one product over all of it, which
+    Mosaic pads as ``odtp_mla_decode_attn``'s. A step is neither taken nor its
+    tile fetched (:func:`chunk_tiles_held`) where the tile starts at or past
+    ``live_rows`` (traced) and where no query of the block reads anything in
+    it: the tiles behind the block's last row among them."""
+    from opendiloco_tpu.ops.flash_attention import _vmem_limit
+
+    c, h, dl = q.shape
+    t = page.shape[-1]
+    bh, bq, bk = _latent_heads(h), _chunk_queries(c), tile
+    nq, nk = c // bq, t // bk
+    first = jnp.arange(nk, dtype=jnp.int32) * bk
+    visit = (first < jnp.asarray(live_rows, jnp.int32))[None] & jnp.any(
+        reads.reshape(nq, bq, nk, bk), axis=(1, 3)
+    )
+    held = chunk_tiles_held(visit).reshape(-1)
+
+    def tile_of(hi, qi, ki, held_ref):
+        return jnp.maximum(held_ref[qi * nk + ki], 0)
+
+    queries = lambda width: pl.BlockSpec((bh, bq, width), lambda hi, qi, ki, held_ref: (hi, qi, 0))
+    blocks = [((bh, bq, dl), q.dtype), ((dl, bk), page.dtype), ((bq, bk), jnp.int8),
+              ((bh, bq, value_dim), q.dtype)]
+    scratch = [((bh, bq, 1), jnp.float32), ((bh, bq, 1), jnp.float32), ((bh, bq, value_dim), jnp.float32)]
+    out = pl.pallas_call(
+        functools.partial(_latent_chunk_kernel, scale=float(scale), value_dim=value_dim),
+        name="odtp_latent_chunk_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h // bh, nq, nk),
+            in_specs=[
+                queries(dl),
+                pl.BlockSpec((dl, bk), lambda hi, qi, ki, held_ref: (0, tile_of(hi, qi, ki, held_ref))),
+                pl.BlockSpec((bq, bk), lambda hi, qi, ki, held_ref: (qi, tile_of(hi, qi, ki, held_ref))),
+            ],
+            out_specs=queries(value_dim),
+            scratch_shapes=[pltpu.VMEM(shape, dtype) for shape, dtype in scratch],
+        ),
+        out_shape=jax.ShapeDtypeStruct((h, c, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(*blocks, *blocks, *scratch, ((bq, bk), jnp.float32)),
+        ),
+        interpret=_interpret(interpret),
+    )(held, jnp.swapaxes(q, 0, 1), page, reads.astype(jnp.int8))
+    return jnp.swapaxes(out, 0, 1)

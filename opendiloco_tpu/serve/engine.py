@@ -85,6 +85,7 @@ from opendiloco_tpu.models.llama import (
     chunk_tile,
     decode_forward,
     causal_prefill_heads,
+    latent_chunk_attn_form,
     prefill_forward,
 )
 from opendiloco_tpu.models.ring_cache import (
@@ -572,8 +573,12 @@ class ServeEngine:
         # ({} without sliding layers): the decode step's by ``decode_kernel``
         # (the kernel has a tile for both rings or the engine is refused here,
         # never a step that quietly takes the XLA form), its tile and the ring
-        # rows a slot's step hands back of it; a chunk's is the absorbed form
-        # in XLA, a tile of ring rows at a time
+        # rows a slot's step hands back of it; a chunk's is the absorbed form a
+        # tile of ring rows at a time: the full layers' from what the code sees
+        # (``llama.latent_chunk_attn_form``: "absorbed-pallas", the kernel that
+        # keeps its scores in VMEM, where the XLA form's score tile would be
+        # written to memory), the sliding layers' over their ring that wraps
+        # "absorbed-xla"
         self.swa_cache_resident_bytes = self.cache_v.nbytes if cfg.sliding and cfg.latent else 0
         self.swa_rows_read = 0
         self.swa_bytes_moved = 0
@@ -590,8 +595,14 @@ class ServeEngine:
             )
             if self.decode_kernel != "pallas":
                 plans = dict.fromkeys(plans, 0)
+            chunk_forms = {
+                "full": latent_chunk_attn_form(
+                    cfg, cfg.q_chunk_size, self.max_context, self.decode_kernel
+                ),
+                "sliding": "absorbed-xla",
+            }
             self.latent_forms = {
-                kind: {"decode": self.decode_kernel, "chunk": "absorbed-xla", "block_t": tile,
+                kind: {"decode": self.decode_kernel, "chunk": chunk_forms[kind], "block_t": tile,
                        "rows_written_back": mla_rows_written_back(tile)}
                 for kind, tile in plans.items()
             }

@@ -1265,12 +1265,16 @@ class ContinuousBatcher:
             # what the indexer and the attention under its selection did, and the
             # prompts admitted in chunks (zeros without learned sparse attention);
             # the form a chunk's grouped-query attention takes ("": no such chunk)
+            # and a chunk's latent attention by kind of layer ({}: no such kinds)
             "dsa": {
-                name: getattr(self.engine, name) for name in (
+                **{name: getattr(self.engine, name) for name in (
                     "dsa_rows_scored", "dsa_rows_selected", "dsa_index_bytes_read",
                     "dsa_kv_bytes_read", "index_cache_resident_bytes", "prefill_chunks",
                     "prefill_chunk_tokens", "chunk_form",
-                )
+                )},
+                "latent_chunk_forms": {
+                    kind: forms["chunk"] for kind, forms in self.engine.latent_forms.items()
+                },
             },
             # what latent attention did with its rings by kind of layer: the
             # full layers' (zeros without latent attention), the sliding layers'

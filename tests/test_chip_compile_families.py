@@ -463,9 +463,26 @@ def test_dots3_programs_copy_no_ring_and_cast_no_weight(chip, which):
         assert mem.temp_size_in_bytes < 256e6
         assert not f32_blocks_over(text, 256e6)
     else:
-        assert "f32[512,25088]" in text and "odtp_chunk_attn" not in text  # latent rows: the XLA form
+        assert "f32[512,25088]" in text and "odtp_chunk_attn" not in text  # latent rows: their own kernel
         assert mem.temp_size_in_bytes < 2e9
         assert not f32_blocks_over(text, 512e6)
+
+
+def test_dots3_chunk_runs_its_full_layers_through_the_latent_kernel(chip):
+    """The notes cell's chunk program with the kernels as the engine asks on
+    the chip (PR 63): both runs of full layers attend through
+    ``odtp_latent_chunk_attn`` (8 heads' 512 absorbed queries of 576 values, a
+    tile of 512 rows of the page, the selection's int8 tile, 8 heads' 512
+    outputs of 512 a grid step), so neither the float32 score tile [512, 128,
+    512] nor the running sum of the same shape (134 MB each) is anywhere in
+    the program; the sliding layers keep the XLA form (their tile, 64 heads:
+    67 MB); the temporaries are 482 MB where the XLA form's were 610."""
+    _, _, _, compiled = _dots3_program(chip, "chunk")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert kernel_windows(text, "odtp_latent_chunk_attn") == [
+        [(8, 512, 576), (576, 512), (512, 512), (8, 512, 512)]] * 2
+    assert "f32[512,128,512]" not in text and "f32[512,64,512]" in text
+    assert mem.temp_size_in_bytes < 500e6
 
 
 # --- Laguna-S-2.1: two kinds of grouped-query attention, rings by kind (PR 56) ----

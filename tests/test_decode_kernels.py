@@ -1022,3 +1022,101 @@ def test_chunk_attention_is_the_tiled_forms(case, monkeypatch):
             ahead = tiles[tiles >= ki]
             assert row[ki] == (ahead[0] if len(ahead) else tiles[-1])
     assert taken.sum() < Kh * nq * nk  # steps are skipped
+
+
+# --- a chunk's latent attention over a slot's page with the scores in VMEM (PR 63) ---
+
+# what the queries read; heads, heads a grid step; plen, count (of 16 queries); queries a grid step
+LATENT_CHUNK_CASES = {
+    "selection_mid_prompt": ("rows", 16, 8, 32, 16, 8),
+    "seen_alone_mid_prompt": ("seen", 16, 8, 32, 16, 8),
+    "selection_live_rows_inside_a_tile": ("rows", 16, 8, 32, 5, 16),
+    "seen_alone_live_rows_at_a_tiles_edge": ("seen", 16, 8, 32, 8, 16),
+    "selection_padded_last_chunk": ("padded", 16, 8, 40, 3, 8),
+    "seen_alone_padded_last_chunk": ("seen_padded", 16, 8, 40, 11, 8),
+    "selection_first_chunk": ("rows", 16, 8, 0, 16, 8),
+    "seen_alone_first_chunk": ("seen", 16, 8, 0, 16, 16),
+    "four_head_blocks": ("rows", 16, 4, 24, 16, 8),
+    "heads_no_block_cuts": ("rows", 6, 8, 24, 16, 16),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_CHUNK_CASES))
+def test_latent_chunk_attention_is_the_tiled_form(case, monkeypatch):
+    """``odtp_latent_chunk_attn`` interpreted against ``tiled_latent_attention``:
+    the same o_lat to the rounding of a reordered float32 sum. 16 queries of
+    rows of 24 values, the first 16 of them the values (``Dl`` no multiple of
+    the value width), over a page of 64 rows in tiles of 8, under a selection
+    and under the rows up to a query's own alone. The grid steps it takes are
+    the tiles before ``live_rows`` in which a query of the block reads
+    something: a tile stepped over is never read (its rows hold NaN), and a
+    query that reads no row (a bucket's padding) comes out zero."""
+    from opendiloco_tpu.ops.attention import tiled_latent_attention
+    from opendiloco_tpu.ops.decode_kernels import chunk_tiles_held, latent_chunk_attention
+
+    what, H, heads, plen, count, bq = LATENT_CHUNK_CASES[case]
+    monkeypatch.setattr(decode_kernels, "_LATENT_HEADS", heads)
+    monkeypatch.setattr(decode_kernels, "_CHUNK_QUERIES", bq)
+    C, Dl, V, T, tile = 16, 24, 16, 64, 8
+    rng = _rng(len(case))
+    q = _randn(rng, C, H, Dl)
+    page = np.array(_randn(rng, Dl, T))
+    at = plen + np.arange(C)
+    live = plen + count
+    reads = np.arange(T)[None] <= at[:, None]
+    skipped = []
+    if what in ("rows", "padded"):
+        reads = reads & (rng.random((C, T)) < 0.3)
+        reads[min(5, count - 1)] = False  # a query that reads no row
+        if plen >= 24:
+            reads[:, 16:24] = False  # and a tile nobody reads
+            skipped = [2]
+    if what in ("padded", "seen_padded"):
+        reads[count:] = False  # the bucket's padding
+    sizes = dict(scale=Dl**-0.5, value_dim=V)
+    want = tiled_latent_attention(q, jnp.asarray(page), jnp.asarray(reads), live, tile, **sizes)
+    page = page.copy()  # (``jnp.asarray`` may share a numpy buffer on the CPU)
+    for i in [*skipped, *range(-(-live // tile), T // tile)]:  # never read: not masked, stepped over
+        page[:, i * tile : (i + 1) * tile] = np.nan
+    got = latent_chunk_attention(
+        q, jnp.asarray(page), jnp.asarray(reads), live, tile, **sizes, interpret=True
+    )
+    assert got.shape == (C, H, V) and got.dtype == q.dtype
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-6)
+    nothing = ~reads[:, : -(-live // tile) * tile].any(axis=1)
+    assert nothing.any() == (what != "seen") and not np.asarray(got)[nothing].any()  # no row read: zero
+    assert np.asarray(got)[~nothing].any(axis=(1, 2)).all()
+    # the table the grid walks: the same for every head block
+    nq, nk = C // bq, T // tile
+    by_step = reads.reshape(nq, bq, nk, tile).any(axis=(1, 3)) & (np.arange(nk) * tile < live)[None]
+    held = np.asarray(chunk_tiles_held(jnp.asarray(by_step)))
+    assert ((held == np.arange(nk)) == by_step).all()
+    assert by_step.sum() < nq * nk  # steps are skipped
+
+
+# c, h, dl, value_dim, t, tile, decode_kernel, on the chip -> the form
+LATENT_FORM_CASES = {
+    "dots3_full_layers": ((512, 128, 576, 512, 25088, 512, "pallas"), True, "absorbed-pallas"),
+    "dots3_full_layers_interpreted": ((512, 128, 576, 512, 25088, 512, "pallas"), False, "absorbed-pallas"),
+    "dots3_sliding_layers_67_mb": ((512, 64, 1088, 1024, 1024, 512, "pallas"), True, "absorbed-xla"),
+    "a_ring_tiles_do_not_cut": ((512, 128, 576, 512, 25088 + 256, 512, "pallas"), True, "absorbed-xla"),
+    "decode_kernel_xla": ((512, 128, 576, 512, 25088, 512, "xla"), True, "absorbed-xla"),
+    "off_the_chip_unasked": ((512, 128, 576, 512, 25088, 512, None), False, "absorbed-xla"),
+    "rehearsal_widths": ((8, 4, 24, 16, 128, 8, "pallas"), False, "absorbed-xla"),
+    "a_row_the_chip_does_not_tile": ((512, 128, 520, 512, 25088, 512, "pallas"), True, "absorbed-xla"),
+    "a_chunk_past_a_block_of_queries": ((1000, 128, 576, 512, 24000, 1000, "pallas"), True, "absorbed-xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_FORM_CASES))
+def test_latent_chunk_form_follows_from_what_the_call_sees(case, monkeypatch):
+    """The rule that chooses a chunk's latent attention's form
+    (``latent_chunk_form``): the kernel where ``decode_kernel`` resolves to the
+    kernels, the ring is whole tiles, the chip tiles the blocks and the XLA
+    form's float32 score tile (heads x chunk x tile x 4 B) reaches
+    ``_PREFILL_SCORE_BYTES``: dots3-note-prev's full layers (134 MB) and not
+    its sliding layers (67 MB)."""
+    args, on_chip, form = LATENT_FORM_CASES[case]
+    monkeypatch.setattr(decode_kernels, "_interpret", lambda interpret=None: not on_chip)
+    assert decode_kernels.latent_chunk_form(*args) == form

@@ -248,6 +248,40 @@ def test_the_engine_holds_one_copy_and_serves_through_the_batcher(model, monkeyp
     assert plan["serve_swa_ring_rows"] == 16 and plan["serve_mla_ring_rows"] == 64
 
 
+def test_an_engine_asked_for_the_kernels_runs_its_full_layers_chunks_through_the_latent_kernel(model, monkeypatch):
+    """``odtp_latent_chunk_attn`` interpreted under the engine (PR 63): a prompt
+    admitted in chunks gives the XLA engine's logits and chosen rows, and
+    ``latent_forms`` says which form each kind's chunk took: the full layers'
+    the kernel, the sliding layers' over their ring that wraps the XLA form. By
+    the bytes rule a stack this small keeps the XLA form for both."""
+    from opendiloco_tpu.ops import decode_kernels
+
+    cfg, params, ids, want = model
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "8")
+    make = lambda kernel: ServeEngine(
+        cfg, params, num_slots=3, max_context=64, prefill_buckets=(), decode_kernel=kernel, **F32)
+    assert make("pallas").latent_forms["full"]["chunk"] == "absorbed-xla"  # 4 heads x 8 x 8 float32 scores
+    monkeypatch.setattr(decode_kernels, "_PREFILL_SCORE_BYTES", 0)
+    engines = {kernel: make(kernel) for kernel in ("xla", "pallas")}
+    forms = {kernel: {kind: f["chunk"] for kind, f in eng.latent_forms.items()} for kernel, eng in engines.items()}
+    assert forms["xla"] == {"full": "absorbed-xla", "sliding": "absorbed-xla"}
+    assert forms["pallas"] == {"full": "absorbed-pallas", "sliding": "absorbed-xla"}
+    got, traced = {}, []  # the kernel's calls as the chunk program is traced: the queries' shapes
+    real = llama.latent_chunk_attention
+    monkeypatch.setattr(
+        llama, "latent_chunk_attention", lambda q, *a, **kw: traced.append(q.shape) or real(q, *a, **kw))
+    for kernel, eng in engines.items():
+        eng.keep_row_choices()
+        tok, logits = eng.admit(1, ids[0, :P].tolist())
+        got[kernel] = (tok, np.asarray(logits), np.asarray(eng.row_choices))
+        assert eng.prefill_chunks == -(-P // cfg.q_chunk_size)
+        assert set(traced) == ({(8, 4, 24)} if kernel == "pallas" else set())  # the full layers' alone
+    close(got["pallas"][1], want[P - 1])
+    close(got["pallas"][1], got["xla"][1])
+    assert got["pallas"][0] == got["xla"][0] == int(np.argmax(want[P - 1]))
+    assert (got["pallas"][2] == got["xla"][2]).all() and got["pallas"][2].sum() == 2 * cfg.index_topk
+
+
 def test_a_ring_the_kernel_cannot_tile_is_refused_at_construction(model):
     cfg = LlamaConfig.from_dict({**TINY, "qk_rope_head_dim": 4})  # rows of 20 values
     params = llama.init_params(jax.random.key(0), cfg)

@@ -35,6 +35,13 @@ the chip (``decode_kernel`` "pallas") wherever the form stays the XLA one there,
 Keye's and dots3's among them, and for Laguna as it lowers off the chip: every
 digest is the parent's.
 
+PR 63 gave the same function the kernel ``odtp_latent_chunk_attn`` for a latent
+layer's chunk where the XLA form's tile of scores would reach the same line
+(``decode_kernels.latent_chunk_form``): of these cells dots3's full layers alone
+(128 heads: 134 MB; its sliding layers and Keye keep the XLA form). dots3's
+``chunk`` is lowered as it lowers off the chip, as Laguna's is, and no digest was
+re-recorded: every one is as PR 62 left it.
+
 The loop is held the same way: ``ContinuousBatcher``'s iteration for a
 configuration without sliding layers calls no function of the engine that the
 parent's did not."""
@@ -152,11 +159,15 @@ def digests(name: str) -> dict:
                 prefill, params, sds((1, bucket), jnp.int32), scalar)
     # the continued prefill as the engine lowers it on the chip where its
     # attention keeps the tiled XLA form there (PR 62: ``chunk_form``'s bytes
-    # rule; latent rows never take the kernel), else (Laguna's full layers) as
-    # it lowers off the chip: the parent's text either way
+    # rule; PR 63: ``latent_chunk_form``'s, the same line over latent rows), else
+    # (Laguna's full layers, dots3's) as it lowers off the chip: the parent's
+    # text either way
     chunk = cfg.q_chunk_size or 128
-    xla_there = cfg.latent or llama.chunk_attn_form(cfg, chunk, rows, "pallas") == "tiled-xla"
-    assert xla_there == (name != "laguna")
+    if cfg.latent:
+        xla_there = llama.latent_chunk_attn_form(cfg, chunk, rows, "pallas") == "absorbed-xla"
+    else:
+        xla_there = llama.chunk_attn_form(cfg, chunk, rows, "pallas") == "tiled-xla"
+    assert xla_there == (name not in ("laguna", "dots3"))
     kernel = "pallas" if xla_there else "xla"
     if cfg.sparse or cfg.sliding:
         texts["chunk"] = lower(
