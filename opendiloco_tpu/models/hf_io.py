@@ -71,6 +71,14 @@ def _reject_moe(cfg: LlamaConfig, op: str) -> None:
             "and checkpoint through the framework checkpointer "
             "(opendiloco_tpu.ckpt); only this import/export is refused"
         )
+    if cfg.kda:
+        raise ValueError(
+            f"cannot {op} this model as HF llama safetensors: the llama layout has no "
+            "kda linear-attention layers (their convolution, decay, beta and gate leaves), and "
+            "HF's solar_open2 layout is not mapped here. Such models train, serve and "
+            "checkpoint through the framework checkpointer (opendiloco_tpu.ckpt); only this "
+            "import/export is refused"
+        )
     if cfg.eva or cfg.num_pred_heads > 1 or cfg.norm_add_unit_offset or cfg.fp32_skip_add:
         raise ValueError(
             f"cannot {op} this model as HF llama safetensors: the llama "

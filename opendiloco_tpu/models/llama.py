@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from opendiloco_tpu.models import lightning, mamba
+from opendiloco_tpu.models import kda, lightning, mamba
 from opendiloco_tpu.models.ring_cache import (  # noqa: F401 (re-exported)
     RingPair,
     cache_insert,
@@ -90,7 +90,7 @@ from opendiloco_tpu.ops.decode_kernels import (
 
 
 # what ``LlamaConfig.layer_types`` may name (``LlamaConfig.layer_kinds``)
-_LAYER_KINDS = ("attention", "mamba", "dense", "sliding", "lightning")
+_LAYER_KINDS = ("attention", "mamba", "dense", "sliding", "lightning", "kda")
 # what ``LlamaConfig.rope_yarn`` holds of a published YaRN entry
 _YARN_KEYS = (
     "factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor",
@@ -329,6 +329,22 @@ class LlamaConfig:
     # ``logits_scaling`` (``hidden_size`` / ``dim_model_base``)
     sparse_config: Optional[tuple] = None
     lightning_decays: Optional[tuple] = None
+    # Kimi-delta linear attention beside gated NoPE grouped-query attention,
+    # the block of a published ``solar_open2`` ``config.json``: the layers in
+    # ``gqa_layers`` are the kind "attention" (``num_key_value_heads`` KV heads,
+    # ``use_gqa_gate``: ``attention_gate_type`` "elementwise"), every other the
+    # kind "kda" (``models/kda.py``: a gated delta rule under a decay for every
+    # key channel, made from the input; a state [heads, head_dim, head_dim]
+    # float32 and the tail of a short convolution on q, k and v a layer and
+    # slot in place of rows; heads of ``head_dim`` without grouping, never
+    # rotated). ``kda_short_conv``: the convolution's taps
+    # (``linear_attn_config.short_conv_kernel_size``); ``kda_allow_neg_eigval``:
+    # beta is 2 sigmoid, so that the state's transition may have eigenvalues
+    # down to -1. The decay's and the output gate's projections are low-rank
+    # pairs through ``head_dim`` (``kda_use_full_proj`` false, the one form
+    # written)
+    kda_short_conv: int = 4
+    kda_allow_neg_eigval: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -535,6 +551,21 @@ class LlamaConfig:
                     "indexer, sliding or Mamba-2 layers, no routed experts, no qk_norm over the "
                     "whole projection, no head-wise gate"
                 )
+        if self.kda and (
+            self.latent or self.cca or self.eva or self.sparse or self.sliding or self.hybrid
+            or self.linear or self.blocks or self.qk_norm or self.qk_norm_per_head
+            or self.mrope_section is not None or self.rope_yarn is not None
+            or self.num_attention_heads % self.kv_heads or "dense" in self.layer_kinds
+            or self.attention_gate_type == "headwise" or self.residual_scaling
+            or self.router_hidden_size or self.kda_short_conv < 2
+        ):
+            raise ValueError(
+                "kda layers are written for a stack of 'kda' and grouped-query 'attention' "
+                "layers whose query heads divide over the KV heads, under a convolution of two "
+                "taps or more: no latent attention, CCA, EVA, indexer, sliding, Mamba-2 or "
+                "lightning layers, no selection by blocks, no QK norm, no head-wise gate, no "
+                "router that reads the layer before"
+            )
         if self.hybrid:
             if self.mamba_n_groups != 1 or not self.mamba_conv_bias or self.mamba_proj_bias:
                 raise ValueError(
@@ -620,7 +651,17 @@ class LlamaConfig:
     def layers_by_kind(self) -> bool:
         """Are the layers' weights one stack per kind (a dict of stacks)
         and not one stack of like layers?"""
-        return self.hybrid or bool(self.leading_dense) or self.sliding or self.linear
+        return self.hybrid or bool(self.leading_dense) or self.sliding or self.linear or self.kda
+
+    @property
+    def kda(self) -> bool:
+        """Does any layer hold a Kimi-delta mixer (and so a state and a
+        convolution's tail a slot, which are not rows)?"""
+        return "kda" in self.layer_kinds
+
+    @property
+    def num_kda_layers(self) -> int:
+        return self.layer_kinds.count("kda")
 
     @property
     def linear(self) -> bool:
@@ -668,7 +709,10 @@ class LlamaConfig:
 
     @property
     def num_attention_layers(self) -> int:
-        return self.num_hidden_layers - self.num_mamba_layers - self.num_lightning_layers
+        return (
+            self.num_hidden_layers - self.num_mamba_layers - self.num_lightning_layers
+            - self.num_kda_layers
+        )
 
     @property
     def latent(self) -> bool:
@@ -895,6 +939,10 @@ class LlamaConfig:
             known.setdefault("router_aux_loss_coef", 0.0)
         if raw.get("model_type") == "minicpm_sala":
             known.update(_sala_keys(raw, known.get("num_hidden_layers", cls.num_hidden_layers), known))
+        if raw.get("model_type") == "solar_open2":
+            known.update(_solar2_keys(raw, known.get("num_hidden_layers", cls.num_hidden_layers)))
+            if known.get("num_local_experts") == known["num_experts"]:
+                known.pop("num_local_experts", None)
         if raw.get("model_type") == "laguna":
             known.update(_laguna_keys(raw, known.get("num_hidden_layers", cls.num_hidden_layers)))
             if known.get("num_local_experts") == known.get("num_experts"):
@@ -939,6 +987,19 @@ class LlamaConfig:
                 attn_use_output_gate=self.attention_gate_type == "elementwise",
                 sparse_config=None if self.sparse_config is None else dict(self.sparse_config),
                 lightning_decays=[list(r) for r in self.lightning_decays],
+            )
+            del d["layer_types"]
+            return d
+        if self.kda:
+            d.update(
+                architectures=["SolarOpen2ForCausalLM"], model_type="solar_open2",
+                gqa_layers=[i for i, k in enumerate(self.layer_kinds) if k == "attention"],
+                use_rope=False, use_gqa_gate=self.attention_gate_type == "elementwise",
+                kda_use_full_proj=False, n_routed_experts=self.held_experts,
+                linear_attn_config={
+                    "short_conv_kernel_size": self.kda_short_conv, "head_dim": self.head_dim,
+                    "num_heads": self.num_attention_heads, "num_kv_heads": None,
+                },
             )
             del d["layer_types"]
             return d
@@ -1054,6 +1115,47 @@ def _sala_keys(raw: dict, depth: int, known: dict) -> dict:
     keys["lightning_decays"] = known.get("lightning_decays") or lightning.decay_rates(
         lightning_at, int(heads), published
     )
+    return keys
+
+
+def _solar2_keys(raw: dict, depth: int) -> dict:
+    """A published ``solar_open2`` config's keys as ``LlamaConfig``'s, for the
+    leading ``depth`` layers: the kinds from ``gqa_layers`` (a file cut in depth
+    keeps the published list whole: the entries under the depth are read); the
+    expert layer's keys are the DeepSeek-V3 / GLM-4.5 family's, whose router
+    ``topk_method`` "noaux_tc" computes (no key states the scoring function);
+    ``n_routed_experts`` counts the experts, and a file cut to one chip's share
+    gives the held count there and the router's width (``num_experts``) beside
+    it; ``use_gqa_gate`` is the elementwise gate. ``intermediate_size`` sizes
+    dense layers, of which ``first_k_dense_replace`` 0 leaves none. What the
+    block is not written for is refused by name and never read past."""
+    linear = dict(raw.get("linear_attn_config") or {})
+    heads = raw.get("num_attention_heads")
+    head_dim = raw.get("head_dim") or raw.get("hidden_size", 0) // max(heads or 1, 1)
+    for key, want, got in (
+        ("kda_use_full_proj", False, raw.get("kda_use_full_proj", False)),
+        ("use_rope", False, raw.get("use_rope", False)),
+        ("first_k_dense_replace", 0, raw.get("first_k_dense_replace", 0)),
+        ("linear_attn_config.num_kv_heads", None, linear.get("num_kv_heads")),
+        ("linear_attn_config.num_heads", heads, linear.get("num_heads", heads)),
+        ("linear_attn_config.head_dim", head_dim, linear.get("head_dim", head_dim)),
+        ("hidden_act", "silu", raw.get("hidden_act", "silu")),
+        ("attention_bias", False, raw.get("attention_bias", False)),
+    ):
+        if got != want:
+            raise ValueError(f"a solar_open2 stack is written for {key} {want!r}; got {got!r}")
+    gqa = set(raw.get("gqa_layers") or ())
+    held = raw.get("n_routed_experts", 0)
+    keys = dict(
+        layer_types=tuple("attention" if i in gqa else "kda" for i in range(depth)),
+        position_embedding_type="nope",
+        attention_gate_type="elementwise" if raw.get("use_gqa_gate", False) else "none",
+        topk_method="noaux_tc", router_aux_loss_coef=0.0,
+        num_experts=raw.get("num_experts", held),
+        kda_short_conv=int(linear.get("short_conv_kernel_size", 4)),
+    )
+    if held != keys["num_experts"]:
+        keys["num_local_experts"] = raw.get("num_local_experts", held)
     return keys
 
 
@@ -1348,6 +1450,17 @@ def shapes(cfg: LlamaConfig) -> dict:
         layers = {"lightning": stack(cfg.num_lightning_layers, norms, mixer, ffn)}
         if cfg.num_attention_layers:
             layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
+    elif cfg.kda:
+        mixer = {
+            "q_proj": (D, Nh * Dh), "k_proj": (D, Nh * Dh), "v_proj": (D, Nh * Dh),
+            "o_proj": (Nh * Dh, D), "conv_weight": (cfg.kda_short_conv, 3 * Nh * Dh),
+            "f_a_proj": (D, Dh), "f_b_proj": (Dh, Nh * Dh), "dt_bias": (Nh * Dh,), "A_log": (Nh,),
+            "b_proj": (D, Nh), "g_a_proj": (D, Dh), "g_b_proj": (Dh, Nh * Dh),
+            "g_bias": (Nh * Dh,), "out_norm": (Dh,),
+        }
+        layers = {"kda": stack(cfg.num_kda_layers, norms, mixer, ffn)}
+        if cfg.num_attention_layers:
+            layers["attention"] = stack(cfg.num_attention_layers, norms, attention, ffn)
     elif cfg.sliding:
         kinds = cfg.layer_kinds
         sliding = of_kind(kind_view(cfg, "sliding"))
@@ -1389,7 +1502,7 @@ def mixer_of(kind: str) -> str:
     """A kind of layer's mixer, which is what names its past's store: "mamba",
     "sliding" (attention under a window over a ring of its own that wraps) or
     "attention" (the "dense" kind differs from "attention" in its FFN alone)."""
-    return kind if kind in ("mamba", "sliding", "lightning") else "attention"
+    return kind if kind in ("mamba", "sliding", "lightning", "kda") else "attention"
 
 
 def layer_runs(cfg: LlamaConfig) -> list[Run]:
@@ -1397,7 +1510,7 @@ def layer_runs(cfg: LlamaConfig) -> list[Run]:
     attention layers, 5 Mamba / 1 attention / 4 Mamba for a period of the
     granite hybrid, 1 dense / 23 attention for a routed stack behind a
     leading dense layer. Each forward scans each run."""
-    runs, seen, past = [], {}, {"attention": 0, "mamba": 0, "sliding": 0, "lightning": 0}
+    runs, seen, past = [], {}, {"attention": 0, "mamba": 0, "sliding": 0, "lightning": 0, "kda": 0}
     for kind in cfg.layer_kinds:
         if runs and runs[-1].kind == kind:
             runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
@@ -2141,6 +2254,24 @@ def lightning_mix(
     return mix
 
 
+def kda_mix(cfg: LlamaConfig, state=None, tail=None, length=None, left: Optional[list] = None):
+    """The ``mix(x, layer)`` of a kda layer over runs of tokens x [B, T, D] that
+    enter with ``state`` [B, H, D, D] and ``tail`` [B, taps - 1, 3 H D] (None: a
+    sequence's start), of which ``length`` are real; the state and the tail the
+    run leaves go into ``left``."""
+
+    def mix(x, layer):
+        with jax.named_scope("odtp_kda_conv"):  # convolution, SiLU, the two norms, decay and beta
+            q, k, v, g, beta, new_tail = kda.conv_inputs(cfg, x, layer, tail, length)
+        with jax.named_scope("odtp_kda"):  # the recurrence alone: the projections lie around it
+            o, new = kda.chunked(q, k, v, g, beta, state, length)
+        if left is not None:
+            left.extend((new, new_tail))
+        return kda.gated_out(cfg, o, x, layer)
+
+    return mix
+
+
 def eva_attend(cfg: LlamaConfig, length=None, kept: Optional[list] = None, prefill: bool = False):
     """The ``attend(q, k, v, adaptive_phi, adaptive_mu_k)`` of EVA over a whole
     sequence from position 0 (training, prefill): the chunks pooled (scope
@@ -2508,12 +2639,14 @@ def training_block(
     cos, sin = _rope(view, positions)
     index_rope = _index_rope(view, positions)
     mix, attend = None, attn_fn
-    scope = "odtp_lightning_proj" if kind == "lightning" else "odtp_ssm"
+    scope = {"lightning": "odtp_lightning_proj", "kda": "odtp_kda_proj"}.get(kind, "odtp_ssm")
     lightning_rope = _rope(lightning_view(cfg), positions) if kind == "lightning" else None
     if kind == "mamba":
         mix = lambda x, layer: mamba.ssm_chunked(cfg, x, layer)[0]
     elif kind == "lightning":  # its decays are the layer's: the mix is made in the body
         pass
+    elif kind == "kda":
+        mix = kda_mix(cfg)
     elif cfg.blocks:  # its own attention over the sequence; ``attn_fn`` under ``dense_len``
         attend = block_attend(cfg, attn_fn)
     elif view.latent:  # the rebuilt form: multi-head attention over k and v
@@ -2799,7 +2932,8 @@ def prefill_forward(
     same); for a
     hybrid stack then the Mamba-2 layers' recurrent states
     [Lm, H, P, N] float32 and conv tails [Lm, K - 1, C], as the last real
-    token left them; and with ``return_moe_counts`` last the routed FFN's
+    token left them; for a stack with kda layers then their states [Lk, H, D, D]
+    float32 and their convolutions' tails [Lk, taps - 1, 3 H D], likewise; and with ``return_moe_counts`` last the routed FFN's
     counts over the live prompt tokens summed over layers (int32, see
     ``_routed_ffn``), and with ``return_expert_choices`` after them each
     position's experts in each layer [L, P, K] int32.
@@ -2880,16 +3014,25 @@ def prefill_forward(
         )
         return (h, out.router), (left[0][0], (out.counts, out.experts))
 
+    def kda_body(carry, layer, li):
+        h, r = carry
+        left: list = []
+        h, out = decoder_block(
+            cfg, h, layer, None, None, live=live, router_in=r, mix_scope="odtp_kda_proj",
+            mix=kda_mix(cfg, length=length, left=left),
+        )
+        return (h, out.router), (left[0][0], left[1][0], (out.counts, out.experts))
+
     lightning_rope = _rope(lightning_view(cfg), positions) if cfg.linear else None
     h = _embed(cfg, cparams, input_ids)
     r = router_carry(cfg, h)
     kept = {"attention": ([], [], [], [], []), "mamba": ([], []), "sliding": ([], []),
-            "lightning": ([],)}
+            "lightning": ([],), "kda": ([], [])}
     counts, experts = [], []
     for run in layer_runs(cfg):
-        body = mamba_body if run.mixer == "mamba" else lightning_body if (
-            run.mixer == "lightning"
-        ) else _of_the_runs_kind(cfg, attention_body, run, positions, rope)
+        body = {"mamba": mamba_body, "lightning": lightning_body, "kda": kda_body}.get(
+            run.mixer
+        ) or _of_the_runs_kind(cfg, attention_body, run, positions, rope)
         (h, r), (*left, (c, e)) = scan_layers(
             cfg, body, (h, r), cparams["layers"], run, experts_in_place=True
         )
@@ -2912,6 +3055,8 @@ def prefill_forward(
         out.extend(map(_stacked, kept["mamba"]))
     if cfg.linear:  # the lightning layers' states as the last real token left them
         out.append(_stacked(kept["lightning"][0]))
+    if cfg.kda:  # the kda layers' states and tails as the last real token left them
+        out.extend(map(_stacked, kept["kda"]))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_expert_choices:
@@ -2953,6 +3098,8 @@ def decode_forward(
     pooled_cache: Optional[jax.Array] = None,
     lightning_state: Optional[jax.Array] = None,
     return_block_tiles: bool = False,
+    kda_state: Optional[jax.Array] = None,
+    kda_tail: Optional[jax.Array] = None,
 ):
     """One incremental decode step over all S slots.
 
@@ -3031,6 +3178,14 @@ def decode_forward(
     block, over slots, KV heads and layers ([1] int32), come after the counts;
     with ``return_row_choices`` the chosen blocks [Ls, S, Kh, blocks] last.
 
+    A stack with kda layers takes ``kda_state`` [Lk, S, H, D, D] float32 and
+    ``kda_tail`` [Lk, taps - 1, S, 3 H D] (``ring_cache.init_kda_state``) and
+    returns both after the caches. A kda layer reads its part of both, runs
+    the one-step form (``kda.step``: the tail shifted by a row, the state
+    decayed and updated by the delta rule) and writes both back in place; a
+    slot at ``lens`` 0 (it may be one whose prompt is arriving in chunks) keeps
+    its state and tail, and its attention layers' rings are written nothing.
+
     With ``return_moe_counts`` the routed FFN's counts over the slots that
     hold a sequence (``lens > 0``), summed over layers, come last, and with
     ``return_expert_choices`` after them each slot's experts in each layer [L,
@@ -3048,6 +3203,8 @@ def decode_forward(
     # a latent stack whose prompts arrive in chunks: a slot at ``lens`` 0 may be
     # one of those, and is written nothing
     chunked = {"live_only": True} if cfg.latent and cfg.q_chunk_size else {}
+    # the same for the plain ring of a stack with kda layers, whose every prompt does
+    live_rows = {"live_only": True} if cfg.kda else {}
 
     def attention_body(carry, layer, li, view=cfg, rope=rope):  # the run's kind's
         # the whole caches, every layer's tails, EVA's pooled ring and stats
@@ -3084,7 +3241,7 @@ def decode_forward(
         def attend(q, k, v):
             nonlocal ck, cv
             out, ck, cv = step_attention(
-                q[:, 0], k[:, 0], v[:, 0], ck, cv, lens, li
+                q[:, 0], k[:, 0], v[:, 0], ck, cv, lens, li, **live_rows
             )
             return out
 
@@ -3191,6 +3348,23 @@ def decode_forward(
         )
         return (h, out.router, states), (out.counts, out.experts)
 
+    def kda_body(carry, layer, li):
+        h, r, states, tails = carry  # every kda layer's
+
+        def mix(x, layer):
+            nonlocal states, tails
+            o, state, tail = kda.step(cfg, x[:, 0], layer, states[li], tails[li], live)
+            with jax.named_scope("odtp_kda"):
+                states = jax.lax.dynamic_update_index_in_dim(states, state, li, 0)
+            with jax.named_scope("odtp_kda_conv"):
+                tails = jax.lax.dynamic_update_index_in_dim(tails, tail, li, 0)
+            return kda.gated_out(cfg, o, x[:, 0], layer)[:, None]
+
+        h, out = decoder_block(
+            cfg, h, layer, None, None, mix=mix, live=live, router_in=r, mix_scope="odtp_kda_proj"
+        )
+        return (h, out.router, states, tails), (out.counts, out.experts)
+
     def block_body(carry, layer, li):
         # the three rings as the step found them; what the step writes goes out
         h, r = carry
@@ -3232,6 +3406,11 @@ def decode_forward(
         if run.mixer == "lightning":
             (h, r, lightning_state), (c, e) = scan_layers(
                 cfg, lightning_body, (h, r, lightning_state), cparams["layers"], run,
+                experts_in_place=True,
+            )
+        elif run.mixer == "kda":
+            (h, r, kda_state, kda_tail), (c, e) = scan_layers(
+                cfg, kda_body, (h, r, kda_state, kda_tail), cparams["layers"], run,
                 experts_in_place=True,
             )
         elif cfg.blocks:
@@ -3282,6 +3461,8 @@ def decode_forward(
             rows = [chose]
     if cfg.linear:
         out.append(lightning_state)
+    if cfg.kda:
+        out.extend((kda_state, kda_tail))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_expert_choices:
@@ -3302,7 +3483,7 @@ def chunk_tile(cfg: LlamaConfig, rows: int) -> int:
     that is the engine's stays out of it: the tile stays a tile), and the
     whole ring where tiles do not cut it."""
     tile = min(cfg.q_chunk_size or _SUFFIX_TILE, rows)
-    if (cfg.sliding and not cfg.latent) or cfg.blocks:  # the chunk is the engine's
+    if (cfg.sliding and not cfg.latent) or cfg.blocks or cfg.kda:  # the chunk is the engine's
         tile = min(_SUFFIX_TILE, rows)
     return tile if rows % tile == 0 else rows
 
@@ -3348,6 +3529,8 @@ def chunk_prefill_forward(
     total=None,
     return_block_tiles: bool = False,
     decode_kernel: str = "xla",
+    kda_state: Optional[jax.Array] = None,
+    kda_tail: Optional[jax.Array] = None,
 ):
     """A run of a prompt's tokens over a slot that holds the rows before them:
     a chunk of a prompt admitted in chunks, or the suffix behind a reused
@@ -3404,6 +3587,15 @@ def chunk_prefill_forward(
     from row 0 in whole chunks. With ``return_block_tiles`` the tiles the
     attention visited, over layers ([1] int32), come after the counts; with
     ``return_row_choices`` the last real token's chosen blocks [Ls, Kh, blocks].
+
+    A stack with kda layers also takes ``kda_state`` and ``kda_tail``
+    (``decode_forward``) and returns both after the index ring's place. **A
+    chunk enters with the state and the tail the chunk before left**: a kda
+    layer reads ``slot``'s (nothing where ``plen`` is 0: a prompt starts from
+    zeros, whatever the slot's last tenant left), runs the convolution over
+    the tail and the chunk and the chunked form over the chunk's real tokens,
+    and writes both back in place; its attention layers are the plain ones
+    over the slot's K and V rows.
 
     With ``return_moe_counts`` the routed FFN's counts over the real tokens
     come after, and with ``return_row_choices`` then the rows the last real
@@ -3581,6 +3773,27 @@ def chunk_prefill_forward(
         )
         return (h, jax.lax.dynamic_update_slice(states, left[0][None], where)), out.counts
 
+    def kda_body(carry, layer, li):
+        h, states = carry  # every kda layer's, every slot's
+        zero, at = jnp.int32(0), jnp.asarray(slot, jnp.int32)
+        # ``slot``'s part of this layer's state [1, H, D, D] and tail [taps - 1, 1,
+        # 3 H D] (the tails hold a row of the window before the slots), zeros at a
+        # prompt's start. The slot's tails are cut out before the layers and
+        # written behind them, as the index ring is: cut or carried inside the
+        # scan the chip's compiler keeps all the slots' tails in another order
+        # there and copies them in and out, 57 MB each way at 128 slots
+        where_s = (li, at, zero, zero, zero)
+        state = jax.lax.dynamic_slice(states, where_s, (1, 1, *states.shape[2:]))[0]
+        tail = jax.lax.dynamic_index_in_dim(slot_tails, li, 0, keepdims=False)[None]
+        fresh = lambda x: jnp.where(plen > 0, x, jnp.zeros_like(x))
+        left: list = []
+        h, out = decoder_block(
+            cfg, h, layer, None, None, live=live, mix_scope="odtp_kda_proj",
+            mix=kda_mix(cfg, fresh(state), fresh(tail), count, left),
+        )
+        states = jax.lax.dynamic_update_slice(states, left[0][None], where_s)
+        return (h, states), (out.counts, left[1][0])
+
     def block_body(carry, layer, li):
         h, ck, cv = carry
         sizes = cfg.block_sizes
@@ -3634,6 +3847,13 @@ def chunk_prefill_forward(
     ci = index_cache  # read by every layer as the run found it
     h = _embed(cfg, cparams, ids)
     counts, rows, keys = [], [], []
+    new_tails: list = []  # the kda layers' [run's layers, taps - 1, 3 H D]
+    if cfg.kda:
+        with jax.named_scope("odtp_kda_conv"):  # ``slot``'s tails [Lk, taps - 1, 3 H D]
+            where_t = (jnp.int32(0), jnp.int32(0), jnp.asarray(slot, jnp.int32), jnp.int32(0))
+            slot_tails = jax.lax.dynamic_slice(
+                kda_tail, where_t, (*kda_tail.shape[:2], 1, kda_tail.shape[3])
+            )[:, :, 0]
     if cfg.linear or cfg.blocks:
         if cfg.blocks and (
             C % cfg.block_sizes.kernel_stride or tile % cfg.block_sizes.block_size or T <= C
@@ -3652,6 +3872,13 @@ def chunk_prefill_forward(
                 experts_in_place=True,
             )
             counts.append(c)
+            continue
+        if run.mixer == "kda":
+            (h, kda_state), (c, tail) = scan_layers(
+                cfg, kda_body, (h, kda_state), cparams["layers"], run, experts_in_place=True,
+            )
+            counts.append(c)
+            new_tails.append(tail)
             continue
         of_kind = block_body if cfg.blocks else body
         if cfg.latent or cfg.sliding:  # each run under its kind's view and rope tables
@@ -3681,6 +3908,11 @@ def chunk_prefill_forward(
         rows = [last]
     if cfg.linear:
         out.append(lightning_state)
+    if cfg.kda:  # the chunk's tails, every kda layer's, behind the layers
+        with jax.named_scope("odtp_kda_conv"):
+            tails = jnp.concatenate(new_tails)[:, :, None].astype(kda_tail.dtype)  # [Lk, taps - 1, 1, 3 H D]
+            kda_tail = jax.lax.dynamic_update_slice(kda_tail, tails, where_t)
+        out.extend((kda_state, kda_tail))
     if return_moe_counts:
         out.append(jnp.sum(_stacked(counts), axis=0))
     if return_block_tiles:

@@ -223,6 +223,23 @@ def init_lightning_state(cfg, num_slots: int) -> jax.Array:
     return jnp.zeros(lightning.state_shape(cfg, num_slots), jnp.float32)
 
 
+def init_kda_state(cfg, num_slots: int, dtype: jnp.dtype = jnp.bfloat16) -> dict:
+    """Zeroed {"state", "tail"} for ``cfg``'s kda layers (``models/kda.py``),
+    the dict form ``init_ssm_state`` has: per layer and slot the delta-rule
+    state [H, D, D] (float32 always: [Lk, S, H, D, D]) and the tail of the
+    convolution on q, k and v [taps - 1, 3 H D] (its last inputs, channels
+    minor-most, stored a row of the window before the slots: [Lk, taps - 1, S,
+    3 H D], ``kda.state_shapes`` says why). Zero is a sequence's start; a
+    prompt's first chunk starts from zeros whatever the slot holds, so nothing
+    clears a slot between tenants. (:func:`state_insert` takes slots-leading
+    stores and is not shared: every prompt of such a stack goes in chunks,
+    which write the slot's state and tail themselves.)"""
+    from opendiloco_tpu.models import kda
+
+    state, tail = kda.state_shapes(cfg, num_slots)
+    return {"state": jnp.zeros(state, jnp.float32), "tail": jnp.zeros(tail, dtype)}
+
+
 def init_pooled_cache(
     cfg, num_slots: int, max_context: int, dtype: jnp.dtype = jnp.bfloat16
 ) -> jax.Array:

@@ -10,7 +10,7 @@ configuration with several, the first is the one a refusal names.
 from __future__ import annotations
 
 # a ``LlamaConfig`` property each, in the order ``LlamaConfig.traits`` keeps
-TRAITS = ("hybrid", "cca", "eva", "sparse", "sliding", "latent", "linear", "blocks")
+TRAITS = ("hybrid", "cca", "eva", "sparse", "sliding", "latent", "linear", "blocks", "kda")
 
 # features that copy, cut, page out or restore a slot's past as the rows of
 # one (k, v) ring from row 0
@@ -55,6 +55,12 @@ _ROWS = {
         "({cfg.num_lightning_layers} of {cfg.num_hidden_layers}): it treats a slot's past "
         "as cache rows, and a decaying state is not rows and cannot be cut at one"
     ),
+    "kda": (
+        "a configuration with kda linear-attention layers ({cfg.num_kda_layers} of "
+        "{cfg.num_hidden_layers}): it treats a slot's past as cache rows, and a delta-rule "
+        "state with its convolution's tail is not rows, cannot be cut at one and is kept at "
+        "no position but the slot's last"
+    ),
     "blocks": (
         "a configuration with attention under a selection by blocks (sparse_config, "
         "{cfg.block_sizes.topk} blocks of {cfg.block_sizes.block_size} rows): a KV head keeps "
@@ -75,7 +81,9 @@ REFUSALS = {
     # chunks from row 0 (``chunk_prefill_forward``); a lightning layer's chunk
     # enters with the slot's state and leaves the next one's, and a selection
     # by blocks pools the windows a chunk closes from the ring's own rows:
-    # ``linear`` and ``blocks`` have no row here
+    # ``linear`` and ``blocks`` have no row here, nor has ``kda``, whose chunk
+    # enters with the slot's state and its convolution's tail (``hybrid`` keeps
+    # its row: a Mamba-2 layer's tail is handed to no chunk)
     "continued_prefill": (
         "the continued prefill (a prompt's chunks, the suffix behind a reused prefix)",
         {trait: _ROWS[trait] for trait in ("cca", "hybrid", "eva")},
@@ -100,6 +108,10 @@ REFUSALS = {
             "softmax attention over every row, and training runs this stack in the XLA forms"
         ),
         "blocks": _ROWS["blocks"],
+        "kda": (
+            "a configuration with kda linear-attention layers: the kernels are causal "
+            "softmax attention over every row, and training runs this stack in the XLA forms"
+        ),
     }),
 }
 

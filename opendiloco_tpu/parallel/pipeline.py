@@ -80,6 +80,12 @@ def pipeline_hidden(
             "homogeneous [L, ...] stack under the caller's attn_fn, and these layers "
             "are stacked per kind of mixer, each with an attention of its own"
         )
+    if cfg.kda:
+        raise ValueError(
+            "the pp pipeline is refused for a configuration with kda linear-attention "
+            "layers: it stages one homogeneous [L, ...] stack under the caller's attn_fn, "
+            "and these layers are stacked per kind of mixer"
+        )
     if cfg.eva:
         raise ValueError(
             "the pp pipeline is refused for a configuration with EVA attention "

@@ -60,6 +60,16 @@ _LAYOUT: dict[str, tuple[Optional[int], int]] = {
     # values under one weight vector, replicated like the norms
     "out_gate": (1, 0),  # [D, Nh*Dh]
     "out_norm": (None, -1),  # [Nh*Dh]
+    # A kda layer's (models/kda.py): q/k/v/o as any attention's; its decay's
+    # and its output gate's low-rank pairs go down whole (fsdp only) and up per
+    # head (tp like q_proj), beta is a value a head, the gate's bias a vector;
+    # conv_weight, dt_bias and A_log take the Mamba-2 mixer's rows below
+    "f_a_proj": (None, 0),  # [D, Dh]
+    "f_b_proj": (1, 0),  # [Dh, Nh*Dh]
+    "g_a_proj": (None, 0),
+    "g_b_proj": (1, 0),
+    "g_bias": (None, -1),  # [Nh*Dh]
+    "b_proj": (1, 0),  # [D, Nh]
     # the shared SwiGLU beside a routed FFN (granite hybrid): as a dense FFN's
     "shared_gate_proj": (1, 0),  # [D, Fs]
     "shared_up_proj": (1, 0),

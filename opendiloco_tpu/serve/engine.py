@@ -88,6 +88,7 @@ from opendiloco_tpu.models.llama import (
     latent_chunk_attn_form,
     prefill_forward,
 )
+from opendiloco_tpu.models import kda
 from opendiloco_tpu.models.ring_cache import (
     cache_insert,
     cca_state_insert,
@@ -98,6 +99,7 @@ from opendiloco_tpu.models.ring_cache import (
     init_eva_state,
     index_insert,
     init_index_cache,
+    init_kda_state,
     init_kv_cache,
     init_lightning_state,
     init_pooled_cache,
@@ -194,6 +196,8 @@ def serving_programs(
     if cfg.linear and cfg.blocks:
         n_state, state_names = 2, ("pooled_cache", "lightning_state")
         tiles = {"return_block_tiles": True}
+    if cfg.kda:  # the delta-rule states and the convolutions' tails, as a hybrid's two
+        n_state, state_names = 2, ("kda_state", "kda_tail")
 
     def sample(logits):  # greedy, from the next token's head
         if cfg.num_pred_heads > 1:
@@ -298,6 +302,29 @@ def state_chunk_program(
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             first = jnp.where(last, first.at[slot].set(tok[0]), first)
         return (_with_counts(tok, [tiles]), logits[0], first, ck, cv, pc, ls, *rest)
+
+    return chunk
+
+
+def kda_chunk_program(cfg: LlamaConfig, *, compute_dtype, decode_kernel: str = "xla"):
+    """``chunk_program`` for a stack with kda layers: ``chunk(params, ids [1,
+    C], plen, count, slot, last, first, ck, cv, ks, kt) -> (the chunk's last
+    real token's greedy successor [1] and the routed FFN's counts, its logits
+    row, first, ck, cv, ks, kt)``. ``ks`` the kda layers' states, ``kt`` their
+    convolutions' tails: the chunk enters with ``slot``'s and leaves the next
+    chunk's, or the decode step's. The trailing five arguments are updated and
+    a jit donates them."""
+    moe = bool(cfg.num_experts)
+
+    def chunk(p, ids, plen, count, slot, last, first, ck, cv, ks, kt):
+        with jax.named_scope("odtp_serve_prefill"):
+            logits, ck, cv, _, ks, kt, *rest = chunk_prefill_forward(
+                p, ids, plen, count, slot, ck, cv, None, cfg, compute_dtype=compute_dtype,
+                kda_state=ks, kda_tail=kt, return_moe_counts=moe, decode_kernel=decode_kernel,
+            )
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            first = jnp.where(last, first.at[slot].set(tok[0]), first)
+        return (_with_counts(tok, rest[:moe]), logits[0], first, ck, cv, ks, kt)
 
     return chunk
 
@@ -429,7 +456,7 @@ class ServeEngine:
         # layers whose configuration names none takes the engine's, laid over
         # the engine's own view of the configuration, which sizes the sliding
         # rings and the chunk program by it
-        if prefill_chunk and (cfg.sliding or cfg.linear or cfg.blocks) and not cfg.q_chunk_size:
+        if prefill_chunk and (cfg.sliding or cfg.linear or cfg.blocks or cfg.kda) and not cfg.q_chunk_size:
             cfg = dataclasses.replace(cfg, q_chunk_size=int(prefill_chunk))
         elif prefill_chunk and int(prefill_chunk) != cfg.q_chunk_size:
             raise ValueError(
@@ -442,6 +469,12 @@ class ServeEngine:
                 "lightning layers and attention under a selection by blocks are served together "
                 "(a minicpm_sala stack) and every prompt of theirs is admitted in chunks, a chunk "
                 "entering with the state the chunk before left: give the engine a prefill_chunk"
+            )
+        if cfg.kda and not cfg.q_chunk_size:
+            raise ValueError(
+                "a stack with kda layers is admitted in chunks, a chunk entering with the state "
+                "and the convolution's tail the chunk before left (the one hand-over to the "
+                "decode step), and its configuration names none: give the engine a prefill_chunk"
             )
         if cfg.sliding and not cfg.q_chunk_size:
             raise ValueError(
@@ -559,7 +592,7 @@ class ServeEngine:
         # "tiles-pallas", the kernel that keeps its scores in VMEM, where the XLA
         # form's score tile would be written to memory, else "tiled-xla"); ""
         # where no prompt goes in chunks over K and V rows
-        chunked = (cfg.sparse or cfg.sliding or cfg.blocks) and not cfg.latent
+        chunked = (cfg.sparse or cfg.sliding or cfg.blocks or cfg.kda) and not cfg.latent
         self.chunk_form = chunk_attn_form(
             cfg, cfg.q_chunk_size, self.max_context, self.decode_kernel
         ) if chunked else ""
@@ -701,6 +734,47 @@ class ServeEngine:
             }
         self.pooled_cache_resident_bytes = self._sala[0].nbytes if self._sala else 0
         self.lightning_state_resident_bytes = self._sala[1].nbytes if self._sala else 0
+        # kda layers beside grouped-query attention: the delta-rule states,
+        # float32, and the tails of the convolutions on q, k and v
+        # (``ring_cache.init_kda_state``); empty for every other stack. What
+        # the mixer did (always on; stay 0 without it), over layers: the tokens
+        # that passed its one-step form (a decode step's live slots) and its
+        # chunked form (a chunk's real tokens), the blocks of the chunked form
+        # that were solved (a triangular system a block and head), and the
+        # bytes of state the equations move (a step every live slot's, there
+        # and back; a chunk one slot's, there and back). ``kda_forms`` names the
+        # form the step and the chunk take ({} without the stack): both are
+        # the XLA forms, on every platform, and the attention layers' are the
+        # plain ring's (the step's by ``decode_kernel``, with a plan for the
+        # ring or the engine is refused here; the chunk's ``chunk_form``)
+        self._kda: tuple = ()
+        self.kda_step_tokens = 0
+        self.kda_chunk_tokens = 0
+        self.kda_blocks_solved = 0
+        self.kda_state_bytes_moved = 0
+        self.kda_forms: dict = {}
+        if cfg.kda:
+            if self.max_context < cfg.q_chunk_size:
+                raise ValueError(
+                    f"max_context {self.max_context} under chunks of {cfg.q_chunk_size}: a "
+                    "slot's ring holds a chunk's rows as one block"
+                )
+            plan = decode_plan(
+                cfg.kv_heads, cfg.head_dim, self.max_context, self.cache_k.dtype.itemsize,
+                num_slots=1,
+            )
+            self._need_plans(
+                plan, "plan for this stack's ring",
+                f"{cfg.kv_heads} KV heads of {cfg.head_dim} over {self.max_context} rows",
+            )
+            state = init_kda_state(cfg, self.num_slots, compute_dtype)
+            self._kda = (state["state"], state["tail"])
+            self.kda_forms = {
+                "step": "xla", "chunk": "chunked-xla", "block": kda.BLOCK, "sub_block": kda.SUB,
+                "attention_step": self.decode_kernel, "attention_chunk": self.chunk_form,
+            }
+        self.kda_state_resident_bytes = self._kda[0].nbytes if self._kda else 0
+        self.kda_tail_resident_bytes = self._kda[1].nbytes if self._kda else 0
         # the slots' second kind of state: empty for a stack of attention layers
         self._ssm: tuple = ()
         if cfg.hybrid:
@@ -824,6 +898,11 @@ class ServeEngine:
                 donate_argnums=(7, 8, 9, 10, 11),
             )
             self._chunk = self._chunk_programs(False)
+        elif self._kda:
+            self._chunk = jax.jit(
+                kda_chunk_program(cfg, compute_dtype=cd, decode_kernel=dkn),
+                donate_argnums=(6, 7, 8, 9, 10),
+            )
         elif cfg.sparse or cfg.sliding:
             self._chunk_programs = lambda rows: jax.jit(
                 chunk_program(cfg, compute_dtype=cd, rows=rows, decode_kernel=dkn),
@@ -885,7 +964,7 @@ class ServeEngine:
         params, first = shaped(self.params), shaped(self._first)
         rings = shaped((self.cache_k, self.cache_v))
         beside = shaped((*self._eva, *self._index))
-        state = shaped((*self._ssm, *self._cca, *self._sala))
+        state = shaped((*self._ssm, *self._cca, *self._sala, *self._kda))
         prefill, decode, insert, chunk = (
             self._prefill, self._decode, self._admit_insert, self._chunk
         )
@@ -915,7 +994,7 @@ class ServeEngine:
             ids = sds((1, self.cfg.q_chunk_size), jnp.int32)
             note("chunk", id(chunk), lambda: chunk.lower(
                 params, ids, scalar, scalar, scalar, sds((), jnp.bool_), first, *rings,
-                *(beside or (None,))))
+                *(state if self._kda else beside or (None,))))
         for bucket in sorted(b for b in self._ran if b != "chunk"):
             ids = sds((1, bucket), jnp.int32)
             note(f"prefill/{bucket}", id(prefill),
@@ -1123,8 +1202,8 @@ class ServeEngine:
     def needs_chunks(self, n: int) -> bool:
         """Is a prompt of ``n`` tokens admitted in chunks (learned sparse
         attention, and no bucket holds it)?"""
-        if self.cfg.sliding or self._sala:  # every prompt: no whole prompt goes into a ring
-            return True  # that wraps, and a state's hand-over has the one path
+        if self.cfg.sliding or self._sala or self._kda:  # every prompt: no whole prompt goes
+            return True  # into a ring that wraps, and a state's hand-over has the one path
         return self._chunk is not None and pick_bucket(n, self.prefill_buckets) is None
 
     def admit_begin(self, slot: int, prompt: Sequence[int], positions=None) -> Admission:
@@ -1165,6 +1244,10 @@ class ServeEngine:
         )
         if self._sala:  # what the chunk's lightning layers read and write of the slot's state
             adm.state_bytes += 2 * self.lightning_state_resident_bytes // self.num_slots
+        if self._kda:  # the slot's states and tails, there and back
+            adm.state_bytes += 2 * (
+                self.kda_state_resident_bytes + self.kda_tail_resident_bytes
+            ) // self.num_slots
         t_args = time.perf_counter()
         if self._sala:  # the pooled ring and the states ride with the rings
             tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
@@ -1172,6 +1255,11 @@ class ServeEngine:
                 self.cache_v, *self._sala,
             )
             self._sala, rest = tuple(rest[:2]), [None, *rest[2:]]
+        elif self._kda:  # the states and the tails ride with the rings
+            tokd, rowd, self._first, self.cache_k, self.cache_v, *state = self._chunk(
+                self.params, *args, self._first, self.cache_k, self.cache_v, *self._kda,
+            )
+            self._kda, rest = tuple(state), [None]
         else:
             tokd, rowd, self._first, self.cache_k, self.cache_v, *rest = self._chunk(
                 self.params, *args, self._first, self.cache_k, self.cache_v,
@@ -1211,6 +1299,8 @@ class ServeEngine:
         else:
             _, attrs = self._split_counts(fetched, 1)
         attrs.update(self._count_dsa(rows_before=chunk.rows_before, count=chunk.count))
+        if self._kda:
+            attrs.update(self._count_kda(chunk=chunk.count))
         if self._latent_row_bytes:  # the slot's rows so far and the chunk's own
             attrs.update(self._count_latent(
                 read=chunk.rows_before + chunk.count, written=chunk.count,
@@ -1443,6 +1533,27 @@ class ServeEngine:
             setattr(self, name, getattr(self, name) + attrs[name])
         return attrs
 
+    def _count_kda(self, step: int = 0, chunk: int = 0) -> dict:
+        """Add one call's kda work to the engine's counters -> the same as
+        span attributes, each over layers (its callers ask only for a stack
+        with the mixer): a decode step over ``step`` live slots, or a chunk of
+        ``chunk`` real tokens. The bytes are what the equations move: each
+        token of a step its slot's state there and back, a chunk its slot's
+        once."""
+        layers = self.cfg.num_kda_layers
+        slot_state = self.kda_state_resident_bytes // self.num_slots  # every kda layer's
+        blocks = layers * self.cfg.num_attention_heads * -(-chunk // kda.BLOCK)
+        moved = 2 * slot_state * (1 if chunk else step)
+        self.kda_step_tokens += layers * step
+        self.kda_chunk_tokens += layers * chunk
+        self.kda_blocks_solved += blocks
+        self.kda_state_bytes_moved += moved
+        return {
+            "kda_step_tokens": layers * step, "kda_chunk_tokens": layers * chunk,
+            "kda_blocks_solved": blocks, "kda_state_bytes": moved,
+            "kda_form": self.kda_forms["chunk" if chunk else "step"],
+        }
+
     def _count_latent(self, read: int, written: int, swa_read: int = 0) -> dict:
         """Add one call's traffic with the latent ring to the engine's
         counters: ``read`` and ``written`` rows of one layer's pages, the
@@ -1652,7 +1763,7 @@ class ServeEngine:
         t_args = time.perf_counter()
         tok, logits, self.cache_k, self.cache_v, *state = self._decode(
             self.params, tokensd, lensd, self.cache_k, self.cache_v,
-            *self._ssm, *self._cca, *self._eva, *self._index, *self._sala,
+            *self._ssm, *self._cca, *self._eva, *self._index, *self._sala, *self._kda,
             first=self._first, prev=self._prev,
         )
         self._prev = tok
@@ -1662,6 +1773,8 @@ class ServeEngine:
             self.expert_choices = state.pop()
         if self._sala:
             self._sala = tuple(state)
+        elif self._kda:
+            self._kda = tuple(state)
         elif self._eva:
             self._eva = tuple(state)
         elif self._index:
@@ -1707,6 +1820,8 @@ class ServeEngine:
         held = lens[lens > 0]
         moe.update(self._count_ssm(held.size, 2 * self.ssm_state_resident_bytes))
         moe.update(self._count_cca(held.size, 2 * self.cca_state_resident_bytes))
+        if self._kda:
+            moe.update(self._count_kda(step=held.size))
         if self._latent_row_bytes:
             # a live slot's rows [0, lens] (the ring's T once it has wrapped),
             # the step's own among them
@@ -1775,7 +1890,8 @@ class ServeEngine:
         elif self.decode_kernel == "pallas" and not cfg.latent:
             # under a selection (learned sparse attention) and over rings by
             # kind a step is one slot's
-            plan = decode_plan(Nkv, Dh, T, size, num_slots=1 if cfg.sparse or cfg.sliding else S)
+            one_slot = cfg.sparse or cfg.sliding or cfg.kda  # (a step that writes live slots alone)
+            plan = decode_plan(Nkv, Dh, T, size, num_slots=1 if one_slot else S)
             rings = [(plan or none, T)]
         plan = rings[0][0]
         out = {
@@ -1787,6 +1903,9 @@ class ServeEngine:
                 math.prod(p.grid(S, Nkv, rows)) for p, rows in rings if p.heads
             )),
         }
+        if self._kda:  # what the kda layers' states and their convolutions' tails hold
+            out["kda_state_bytes"] = float(self.kda_state_resident_bytes)
+            out["kda_tail_bytes"] = float(self.kda_tail_resident_bytes)
         if cfg.eva:
             out["eva_pooled_plan_heads"] = float(rings[-1][0].heads)
             out["eva_pooled_plan_block_t"] = float(rings[-1][0].block_t)

@@ -1307,6 +1307,15 @@ class ContinuousBatcher:
                 )},
                 "forms": self.engine.block_forms,
             },
+            # what the kda mixer did (zeros without it), what its states and
+            # tails hold, and which form the step and the chunk take
+            "kda": {
+                **{name: getattr(self.engine, f"kda_{name}") for name in (
+                    "step_tokens", "chunk_tokens", "blocks_solved", "state_bytes_moved",
+                    "state_resident_bytes", "tail_resident_bytes",
+                )},
+                "forms": self.engine.kda_forms,
+            },
             # what EVA attention did with its two rings (zeros without it)
             "eva": {
                 **{name: getattr(self.engine, f"eva_{name}") for name in (

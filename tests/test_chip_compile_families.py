@@ -638,3 +638,77 @@ def test_sala_programs_copy_no_ring_and_no_state_and_cast_no_weight(chip, which)
         assert "f32[2,16,2048,512]" not in text
         assert {tuple(b[:5]) for b in kernel_windows(text, "odtp_chunk_attn")} == {
             ((1, 16, 512, 128), (512, 1), (1, 128, 1024), (1, 128, 1024), (1, 512, 1024))}
+
+
+# --- Solar-Open2: kda states and tails beside one (k, v) ring, 40 of 320 experts (PR 64) ---
+
+
+@once_a_session
+def _solar2_program(chip, which):
+    import dataclasses
+
+    from opendiloco_tpu.models.ring_cache import init_kda_state, init_kv_cache
+    from opendiloco_tpu.serve.engine import kda_chunk_program, serving_programs
+
+    cfg, engine = serve_cell("solar-open2-250b", "serve-solar2-reason")
+    cfg = dataclasses.replace(cfg, q_chunk_size=engine["prefill_chunk"])  # as the engine lays it
+    slots, rows = engine["num_slots"], engine["max_context"]
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, slots, rows, BF16))
+    state = jax.eval_shape(lambda: init_kda_state(cfg, slots, BF16))
+    rings = on_chip(chip, (cache["k"], cache["v"], state["state"], state["tail"]))
+    params = bound(chip, cfg)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    if which == "decode":
+        _, decode, _, n = serving_programs(cfg, compute_dtype=BF16, decode_kernel="pallas")
+        assert n == 4
+        lowered = jax.jit(decode, donate_argnums=(4, 5, 6, 7)).lower(params, vec, vec, vec, *rings)
+    else:
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+        ids = jax.ShapeDtypeStruct((1, cfg.q_chunk_size), jnp.int32, sharding=chip)
+        last = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
+        lowered = jax.jit(
+            kda_chunk_program(cfg, compute_dtype=BF16, decode_kernel="pallas"),
+            donate_argnums=(6, 7, 8, 9, 10),
+        ).lower(params, ids, scalar, scalar, scalar, last, vec, *rings)
+    return cfg, params, rings, lowered.compile()
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_solar2_programs_copy_no_ring_no_state_no_tail_and_cast_no_weight(chip, which):
+    """The engine's decode and chunk programs for Solar-Open2-250B at 128 slots
+    of 5,120 rows under chunks of 2,048, published widths, 4 of 48 layers with
+    40 of 320 experts: 3,308,377,920 parameters held once in bf16; the gqa
+    layer's K and V ring, the three kda layers' float32 states and their
+    convolutions' tails alias the outputs and none is copied; no weight is
+    cast; no layer's experts are cut out of their stack; the program fits the
+    chip. The decode step holds ``odtp_paged_decode_attn`` (a slot a grid step:
+    a slot at ``lens`` 0 is written nothing); the chunk's gqa attention is
+    ``odtp_chunk_attn`` (no [8, 8, 2048, 512] float32 tile of scores, 268 MB,
+    in memory)."""
+    cfg, params, rings, compiled = _solar2_program(chip, which)
+    assert (cfg.num_attention_layers, cfg.num_kda_layers) == (1, 3)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in rings)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 6_616_755_840
+    assert held == 128 * 33_996_800
+    print(f"solar2 {which}: arguments {mem.argument_size_in_bytes} temporaries "
+          f"{mem.temp_size_in_bytes} aliased {mem.alias_size_in_bytes} "
+          f"program {program_bytes(compiled):.0f}")
+    assert mem.alias_size_in_bytes >= held
+    assert program_bytes(compiled) < HBM_BYTES
+    whole = {line.split(" = ")[0].strip(): line for line in text.splitlines() if " = " in line}
+    for ring in rings:
+        # (the step's shift of the tails is a slice update in place under a plain fusion's name)
+        assert not [line for line in ring_copies(text, ring.shape)
+                    if 'odtp_kda_conv/dynamic_update_slice"' not in whole[line.split(" = ")[0].strip()]], which
+    assert not leaf_shaped_casts(text, {tuple(x.shape) for x in jax.tree.leaves(params)})
+    assert not [line for line in text.splitlines()
+                if "dynamic-slice_bitcast_fusion" in line and "bf16[40,4096,1280]" in line]
+    if which == "decode":
+        assert "odtp_paged_decode_attn" in text
+        assert mem.temp_size_in_bytes < 1e9
+    else:
+        assert mem.temp_size_in_bytes < 3e9
+        assert "f32[8,8,2048,512]" not in text
+        assert kernel_windows(text, "odtp_chunk_attn")

@@ -354,10 +354,29 @@ def _lightning_and_blocks_model():
     return cfg, init_params(jax.random.key(11), cfg)
 
 
+def _kda_and_gqa_model():
+    cfg = LlamaConfig.from_dict({
+        "model_type": "solar_open2", "vocab_size": 256, "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "num_hidden_layers": 4, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "gqa_layers": [1, 5], "use_gqa_gate": True,
+        "kda_allow_neg_eigval": True, "n_routed_experts": 8, "n_shared_experts": 1,
+        "num_experts_per_tok": 2, "norm_topk_prob": True, "max_position_embeddings": 128,
+        "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4,
+                               "num_kv_heads": None},
+        "norm_init_std": 0.1,
+    })
+    assert cfg.traits == ("kda",) and cfg.layer_kinds == ("kda", "attention", "kda", "kda")
+    params = init_params(jax.random.key(12), cfg)
+    for kind in ("attention", "kda"):  # no top-k choice near a tie
+        params["layers"][kind]["router"] = params["layers"][kind]["router"] * 25.0
+    return cfg, params
+
+
 @pytest.mark.parametrize(
     "model",
     [_dense_model, _routed_qk_norm_model, _hybrid_model, _latent_routed_model, _zaya_model,
-     _eva_model, _two_latent_kinds_model, _two_gqa_kinds_model, _lightning_and_blocks_model],
+     _eva_model, _two_latent_kinds_model, _two_gqa_kinds_model, _lightning_and_blocks_model,
+     _kda_and_gqa_model],
 )
 def test_the_four_forwards_agree(model):
     """One block under four drivers: in float32 the training forward, the
@@ -421,6 +440,35 @@ def test_the_four_forwards_agree(model):
             tokens, lens = jnp.asarray([0, seq[-1]], jnp.int32), jnp.asarray([0, len(seq) - 1], jnp.int32)
             step, *rings = decode_forward(
                 params, tokens, lens, *rings[:2], cfg, pooled_cache=rings[2], lightning_state=rings[3], **f32)
+            steps.append(step[1])
+            seq.append(int(jnp.argmax(step[1])))
+        close(jnp.stack(steps), full(seq[:-1])[P:])
+        return
+    if cfg.kda:
+        # kda layers beside gated grouped-query ones (PR 64): the prompt goes in as
+        # chunks, each entering with the state and the convolution's tail the chunk
+        # before left, and the decode steps through the ring, the states and the
+        # tails give the forward's next rows
+        from opendiloco_tpu.models.ring_cache import init_kda_state
+
+        cache, held = init_kv_cache(cfg, 2, 32, jnp.float32), init_kda_state(cfg, 2, jnp.float32)
+        rings = (cache["k"], cache["v"], held["state"], held["tail"])
+        assert left[0].shape == (3, 4, 16, 16) and left[1].shape == (3, 3, 192)
+        for plen in range(0, P, 4):
+            count = min(4, P - plen)
+            ids = jnp.asarray([(prompt[plen : plen + count] + [0] * 4)[:4]], jnp.int32)
+            chunked, ck, cv, _, ks_, kt_ = chunk_prefill_forward(
+                params, ids, plen, count, 1, *rings[:2], None, cfg, kda_state=rings[2],
+                kda_tail=rings[3], **f32)
+            rings = (ck, cv, ks_, kt_)
+        close(chunked[0], logits[0])
+        close(rings[2][:, 1], left[0])
+        close(rings[3][:, :, 1], left[1])
+        seq, steps = prompt + [tok], []
+        for _ in range(4):
+            tokens, lens = jnp.asarray([0, seq[-1]], jnp.int32), jnp.asarray([0, len(seq) - 1], jnp.int32)
+            step, *rings = decode_forward(
+                params, tokens, lens, *rings[:2], cfg, kda_state=rings[2], kda_tail=rings[3], **f32)
             steps.append(step[1])
             seq.append(int(jnp.argmax(step[1])))
         close(jnp.stack(steps), full(seq[:-1])[P:])

@@ -42,6 +42,12 @@ layer's chunk where the XLA form's tile of scores would reach the same line
 ``chunk`` is lowered as it lowers off the chip, as Laguna's is, and no digest was
 re-recorded: every one is as PR 62 left it.
 
+PR 64 (Solar-Open2: kda layers' states and tails in the shared forwards, engine
+and scheduler) added ``serve-sala-longdoc``'s configuration, whose decode step
+hands a state over as the kda layers' does and whose chunks enter with one,
+recorded from its parent ``4984b84``; its ``chunk`` (the engine's
+``state_chunk_program``) is lowered as it lowers off the chip, as Laguna's is.
+
 The loop is held the same way: ``ContinuousBatcher``'s iteration for a
 configuration without sliding layers calls no function of the engine that the
 parent's did not."""
@@ -65,6 +71,7 @@ CELLS = {
     "dots3": ("dots3-note-prev", "serve-dots3-notes"),
     "granite": ("granite-4.0-h-small", "serve-granite-h-docqa"),
     "laguna": ("laguna-s-2.1", "serve-laguna-repoedit"),
+    "sala": ("minicpm-sala", "serve-sala-longdoc"),
 }
 # recorded from commit f83e3d2 (PR 52's tree, PR 54's parent)
 PARENT = {
@@ -90,6 +97,10 @@ PARENT = {
                 "prefill/512": "dff0fa32301a6561"},
     "laguna": {"decode": "1ca47f13e8229877", "prefill": "4bab75441ed0072b",
                "chunk": "b7704d4b7c53f06e"},
+    # recorded from commit 4984b84 (PR 63's tree, PR 64's parent): the stack whose
+    # state rides from chunk to chunk and to the step as the kda layers' does
+    "sala": {"decode": "b4f4c18d3646ea97", "prefill": "0aceaeb71cc78b05",
+             "chunk": "bb2d459ff0653fb9"},
 }
 # the engine's methods that the batcher's loop (and a submit) called at that
 # commit while it served two requests of a dense, a latent and an indexed
@@ -106,6 +117,9 @@ PARENT_CALLS = {
     # recorded from commit c311252 (PR 61's parent)
     "granite": _LOOP,
     "laguna": tuple(sorted({*_LOOP, *_CHUNKS, "_count_kinds"} - {"_bucket_of", "_count_latent", "admit_enqueue"})),
+    # recorded from commit 4984b84 (PR 64's parent)
+    "sala": tuple(sorted({*_LOOP, *_CHUNKS, "_count_sala"}
+                         - {"_bucket_of", "_count_latent", "_split_counts", "admit_enqueue"})),
 }
 
 
@@ -124,7 +138,7 @@ def digests(name: str) -> dict:
     cell's configuration at the cell's slots and context."""
     from opendiloco_tpu.models import llama, ring_cache
     from opendiloco_tpu.ops.decode_kernels import prefill_form
-    from opendiloco_tpu.serve.engine import chunk_program, serving_programs
+    from opendiloco_tpu.serve.engine import chunk_program, serving_programs, state_chunk_program
 
     jax.config.update("jax_traceback_in_locations_limit", 0)
     cfg, opts = _cell(*CELLS[name])
@@ -132,10 +146,13 @@ def digests(name: str) -> dict:
     sds = jax.ShapeDtypeStruct
     params = jax.tree.map(lambda x: sds(x.shape, bf), llama.shapes(cfg))
     slots, rows = opts["num_slots"], opts["max_context"]
-    if cfg.sliding and not cfg.q_chunk_size:  # the engine's chunk, as the engine lays it
+    if (cfg.sliding or cfg.linear) and not cfg.q_chunk_size:  # the engine's chunk, as the engine lays it
         cfg = dataclasses.replace(cfg, q_chunk_size=opts["prefill_chunk"])
     cache = jax.eval_shape(lambda: ring_cache.init_kv_cache(cfg, slots, rows, bf))
     rings = [cache["k"], cache["v"]]
+    if cfg.linear:
+        rings += [jax.eval_shape(lambda: ring_cache.init_pooled_cache(cfg, slots, rows, bf)),
+                  jax.eval_shape(lambda: ring_cache.init_lightning_state(cfg, slots))]
     if cfg.hybrid:
         state = jax.eval_shape(lambda: ring_cache.init_ssm_state(cfg, slots, bf))
         rings += [state["ssm"], state["conv"]]
@@ -167,9 +184,16 @@ def digests(name: str) -> dict:
         xla_there = llama.latent_chunk_attn_form(cfg, chunk, rows, "pallas") == "absorbed-xla"
     else:
         xla_there = llama.chunk_attn_form(cfg, chunk, rows, "pallas") == "tiled-xla"
-    assert xla_there == (name not in ("laguna", "dots3"))
+    assert xla_there == (name not in ("laguna", "dots3", "sala"))
     kernel = "pallas" if xla_there else "xla"
-    if cfg.sparse or cfg.sliding:
+    if cfg.linear:
+        texts["chunk"] = lower(
+            state_chunk_program(cfg, compute_dtype=bf, decode_kernel=kernel), params,
+            sds((1, cfg.q_chunk_size), jnp.int32),
+            scalar, scalar, scalar, scalar, sds((), jnp.bool_), vec, *rings,
+            donate_argnums=(7, 8, 9, 10, 11),
+        )
+    elif cfg.sparse or cfg.sliding:
         texts["chunk"] = lower(
             chunk_program(cfg, compute_dtype=bf, decode_kernel=kernel), params,
             sds((1, cfg.q_chunk_size), jnp.int32),
@@ -234,6 +258,14 @@ TINY = {
         },
         num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
     ),
+    "sala": dict(
+        model_type="minicpm_sala", hidden_size=32, intermediate_size=64, vocab_size=64,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        mixer_types=["minicpm4", "lightning-attn", "minicpm4"], qk_norm=True, scale_emb=12,
+        scale_depth=1.4, dim_model_base=16, attn_use_output_gate=True,
+        sparse_config=dict(kernel_size=4, kernel_stride=2, block_size=4, topk=3, init_blocks=1,
+                           window_size=4, dense_len=8),
+    ),
 }
 
 
@@ -250,7 +282,7 @@ def loop_calls(name: str) -> list:
     engine = ServeEngine(
         cfg, init_params(jax.random.key(0), cfg), num_slots=2, max_context=32,
         prefill_buckets=(16,), compute_dtype=jnp.float32,
-        **({"prefill_chunk": 8} if cfg.sliding and not cfg.q_chunk_size else {}),
+        **({"prefill_chunk": 8} if (cfg.sliding or cfg.linear) and not cfg.q_chunk_size else {}),
     )
     called = set()
     for attr in dir(ServeEngine):

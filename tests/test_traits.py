@@ -72,16 +72,26 @@ CONFIGS = {
         "sparse_config": {"kernel_size": 8, "kernel_stride": 4, "block_size": 8, "topk": 4,
                           "init_blocks": 1, "window_size": 16, "dense_len": 32},
     },
+    # a solar_open2 stack: kda layers beside plain gated grouped-query ones
+    "kda": {
+        **_SMALL, "model_type": "solar_open2", "num_key_value_heads": 2, "head_dim": 16,
+        "moe_intermediate_size": 32, "gqa_layers": [0], "use_gqa_gate": True,
+        "kda_allow_neg_eigval": True, "n_routed_experts": 8, "n_shared_experts": 1,
+        "num_experts_per_tok": 2, "norm_topk_prob": True,
+        "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4,
+                               "num_kv_heads": None},
+    },
 }
 # what a refusal calls each trait
 NAMED = {"hybrid": "Mamba-2 layers", "cca": "CCA", "eva": "EVA attention",
          "sparse": "learned sparse attention", "sliding": "sliding layers",
          "latent": "latent attention", "linear": "lightning linear-attention layers",
-         "blocks": "attention under a selection by blocks"}
+         "blocks": "attention under a selection by blocks",
+         "kda": "kda linear-attention layers"}
 # the traits each feature handles, so refuses nothing for: every other is refused
 TAKES = {
     "prefix_reuse": (), "page_out": (), "page_in": (),
-    "continued_prefill": ("sparse", "sliding", "latent", "linear", "blocks"),
+    "continued_prefill": ("sparse", "sliding", "latent", "linear", "blocks", "kda"),
     "attn_impl": ("hybrid", "cca"),
 }
 
