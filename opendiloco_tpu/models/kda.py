@@ -3,7 +3,9 @@ outside ``gqa_layers``, held as the kind "kda"), in the forms the program
 runs: the chunked form over a run of tokens that *enters with a state and a
 convolution's tail* (training, a whole prompt, a prompt's chunk), the
 token-by-token recurrence (the definition: the tests' check of the chunked
-form) and the one-step form over the slots (decode).
+form) and the one-step form over the slots (decode: :func:`step` in XLA, and
+on the chip ``ops.decode_kernels.kda_step``, the same equations as one kernel
+over the stacked states).
 
 Per token t and head h, with x the block's normed input (Kimi Delta Attention,
 arXiv 2510.26692; the ``kda_*`` and ``linear_attn_config`` keys of a
@@ -71,7 +73,9 @@ _L2_EPS = 1e-6
 def state_shapes(cfg, slots: int) -> tuple[tuple, tuple]:
     """Per kda layer and slot the state [H, D, D] and the convolution's tail
     [taps - 1, 3 H D] -> the two storage shapes, layers leading: the states'
-    [Lk, S, H, D, D], the tails' [Lk, taps - 1, S, 3 H D], **a row of the
+    [Lk, S, H, D, D] (a block of a slot's heads is what a grid step of
+    ``ops.decode_kernels.kda_step`` takes from the stack and hands back), the
+    tails' [Lk, taps - 1, S, 3 H D], **a row of the
     window before the slots** (the decode step shifts every slot's tail by a
     row: with the slots leading the chip's compiler keeps the tails in this
     order inside the scan over the layers all the same, and re-lays all of
@@ -256,21 +260,32 @@ def recurrence(q, k, v, g, beta, state=None):
     return jnp.moveaxis(o, 0, 1), state
 
 
-def step(cfg, x: jax.Array, layer: dict, state: jax.Array, tail: jax.Array, live: jax.Array):
-    """One token a slot: x [S, D_model], the slots' states [S, H, D, D]
-    float32 and tails [taps - 1, S, 3 H D] (``state_shapes``) -> (o [S, H, D]
-    float32, the new states, the new tails: shifted by a row). A slot that holds no sequence
-    (``live`` [S] false: it may be one whose prompt is arriving in chunks, and
-    its state and tail are that prompt's) keeps both.
-
-    The state is read twice and written once: ``S'^T k`` and ``S'^T q`` in one
-    pass over it (``o = S'^T q + (k . q) u``), the update in a second."""
+def step_inputs(cfg, x: jax.Array, layer: dict, tail: jax.Array, live: jax.Array):
+    """What the one-step form reads of one token a slot, x [S, D_model], behind
+    the slots' tails [taps - 1, S, 3 H D] (``state_shapes``) -> (q, k, v, g [S,
+    H, D] float32, g the log of the decay a key channel, beta [S, H] float32,
+    the new tails: shifted by a row; a slot that holds no sequence, ``live`` [S]
+    false, keeps its tail)."""
     f32 = jnp.float32
     with jax.named_scope("odtp_kda_conv"):
         window = jnp.concatenate([tail, project(x, layer)[None].astype(tail.dtype)], axis=0)
         conv = jnp.sum(window.astype(f32) * layer["conv_weight"].astype(f32)[:, None], axis=0)
         q, k, v = (a.astype(f32) for a in _heads(cfg, conv, x.dtype))
         g, beta = decay_and_beta(cfg, x, layer)
+    return q, k, v, g, beta, jnp.where(live[None, :, None], window[1:], tail)
+
+
+def step_state(q, k, v, g, beta, state: jax.Array, live: jax.Array):
+    """The one-step form's recurrence in XLA: a token's rows (:func:`step_inputs`)
+    and the slots' states [S, H, D, D] float32 -> (o [S, H, D] float32, the new
+    states; a slot that holds no sequence keeps its own).
+
+    This form reads the state twice and writes it once: ``S'^T k`` and ``S'^T
+    q`` in one pass over it (``o = S'^T q + (k . q) u``), the update in a
+    second, and its caller writes the layer's states into the stack. It is the
+    decode step off the chip and where a head's state is no whole tile, and the
+    tests' reference of ``ops.decode_kernels.kda_step``, which visits a live
+    slot's state once where it lies in the stack."""
     with jax.named_scope("odtp_kda"):
         a = jnp.exp(g)
         read = jnp.einsum("shkv,shjk->shjv", state, jnp.stack([a * k, a * q], axis=2))
@@ -278,7 +293,17 @@ def step(cfg, x: jax.Array, layer: dict, state: jax.Array, tail: jax.Array, live
         new = a[..., None] * state + k[..., :, None] * u[..., None, :]
         o = read[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
         new = jnp.where(live[:, None, None, None], new, state)
-    return o, new, jnp.where(live[None, :, None], window[1:], tail)
+    return o, new
+
+
+def step(cfg, x: jax.Array, layer: dict, state: jax.Array, tail: jax.Array, live: jax.Array):
+    """One token a slot in XLA: x [S, D_model], the slots' states [S, H, D, D]
+    float32 and tails (:func:`step_inputs`, :func:`step_state`) -> (o [S, H, D]
+    float32, the new states, the new tails). A slot that holds no sequence
+    (``live`` [S] false: it may be one whose prompt is arriving in chunks, and
+    its state and tail are that prompt's) keeps both."""
+    *rows, tail = step_inputs(cfg, x, layer, tail, live)
+    return (*step_state(*rows, state, live), tail)
 
 
 def gated_out(cfg, o: jax.Array, x: jax.Array, layer: dict) -> jax.Array:

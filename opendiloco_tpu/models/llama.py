@@ -80,6 +80,8 @@ from opendiloco_tpu.ops.decode_kernels import (
     eva_decode_attention,
     eva_prefill_attention,
     index_ring_write,
+    kda_step,
+    kda_step_form,
     latent_chunk_attention,
     latent_chunk_form,
     mla_decode_attention,
@@ -3180,11 +3182,15 @@ def decode_forward(
 
     A stack with kda layers takes ``kda_state`` [Lk, S, H, D, D] float32 and
     ``kda_tail`` [Lk, taps - 1, S, 3 H D] (``ring_cache.init_kda_state``) and
-    returns both after the caches. A kda layer reads its part of both, runs
-    the one-step form (``kda.step``: the tail shifted by a row, the state
-    decayed and updated by the delta rule) and writes both back in place; a
-    slot at ``lens`` 0 (it may be one whose prompt is arriving in chunks) keeps
-    its state and tail, and its attention layers' rings are written nothing.
+    returns both after the caches. A kda layer shifts its part of the tails by
+    a row and runs the one-step form on its part of the states, decayed and
+    updated by the delta rule: on the Pallas path where a head's state is whole
+    tiles (``decode_kernels.kda_step_form``) one kernel over the stacked
+    states, a live slot's read once and written once where it lies
+    (``decode_kernels.kda_step``); else ``kda.step_state``, which passes over
+    the layer's states three times, and a slice update of the stack. A slot at
+    ``lens`` 0 (it may be one whose prompt is arriving in chunks) keeps its
+    state and tail, and its attention layers' rings are written nothing.
 
     With ``return_moe_counts`` the routed FFN's counts over the slots that
     hold a sequence (``lens > 0``), summed over layers, come last, and with
@@ -3205,6 +3211,7 @@ def decode_forward(
     chunked = {"live_only": True} if cfg.latent and cfg.q_chunk_size else {}
     # the same for the plain ring of a stack with kda layers, whose every prompt does
     live_rows = {"live_only": True} if cfg.kda else {}
+    kda_kernel = cfg.kda and kda_step_form(decode_kernel, cfg.head_dim) == "pallas"
 
     def attention_body(carry, layer, li, view=cfg, rope=rope):  # the run's kind's
         # the whole caches, every layer's tails, EVA's pooled ring and stats
@@ -3353,9 +3360,14 @@ def decode_forward(
 
         def mix(x, layer):
             nonlocal states, tails
-            o, state, tail = kda.step(cfg, x[:, 0], layer, states[li], tails[li], live)
-            with jax.named_scope("odtp_kda"):
-                states = jax.lax.dynamic_update_index_in_dim(states, state, li, 0)
+            *rows, tail = kda.step_inputs(cfg, x[:, 0], layer, tails[li], live)
+            if kda_kernel:  # a live slot's state visited once, where it lies in the stack
+                with jax.named_scope("odtp_kda"):
+                    o, states = kda_step(*rows, states, li, live)
+            else:
+                o, state = kda.step_state(*rows, states[li], live)
+                with jax.named_scope("odtp_kda"):
+                    states = jax.lax.dynamic_update_index_in_dim(states, state, li, 0)
             with jax.named_scope("odtp_kda_conv"):
                 tails = jax.lax.dynamic_update_index_in_dim(tails, tail, li, 0)
             return kda.gated_out(cfg, o, x[:, 0], layer)[:, None]

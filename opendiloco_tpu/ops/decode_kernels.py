@@ -44,6 +44,10 @@ cache-aware decode, arXiv 2309.06180).
   first ``R`` rows, so a live row is read once a layer and step. It checks
   against ``latent_decode_step_attention`` to rounding, not to the bit (the
   row reaches its tile through a one-hot product on the MXU).
+- :func:`kda_step` is a Kimi-delta layer's decode step against the stacked
+  float32 states as the engine holds them: decay, both reads, the rank-one
+  update and the write in one visit of a live slot's state, where the XLA
+  form (``models.kda.step_state``) passes over a layer's states three times.
 Dispatch: by what the code sees. ``ServeEngine`` runs these kernels on a TPU
 backend and the XLA paths elsewhere (:func:`resolve_decode_kernel`), so CPU
 rigs keep the stock XLA code; no option or environment name chooses. A test
@@ -1811,3 +1815,134 @@ def latent_chunk_attention(
         interpret=_interpret(interpret),
     )(held, jnp.swapaxes(q, 0, 1), page, reads.astype(jnp.int8))
     return jnp.swapaxes(out, 0, 1)
+
+
+# ---------------------------------------------------------------------------
+# (f) the Kimi-delta mixer's decode step: a slot's state visited once
+# ---------------------------------------------------------------------------
+
+# The states of a grid step: as many of a slot's heads as stay under this (16
+# heads of [128, 128] float32), the block in and the block out both
+# double-buffered: 4 MB of VMEM. Measured on the v5e at the solar2 cell's shapes
+# (PERF.md section 6, PR 65; ``scripts/kda_step_bench.py``), us a layer of 126
+# live slots x 64 heads, 1.06 GB there and back: 8 heads a step 1,762.5 (600
+# GB/s), 16 1,625.1 (650), 32 1,626.5 (650), beside the XLA form's 2,383.4.
+_KDA_STATE_BYTES = 1024 * 1024
+
+# what a grid step does with its slot (the kernel's third prefetched vector)
+_KDA_LIVE, _KDA_KEEP, _KDA_PASS = 1, 0, 2
+
+
+def kda_step_form(decode_kernel: str | None, head_dim: int) -> str:
+    """Which form a kda layer's decode step takes, from what the call can see:
+    "pallas" (:func:`kda_step`) where ``decode_kernel`` resolves to the kernels
+    and a head's state [D, D] is whole tiles of 128 lanes; else "xla"
+    (``models.kda.step_state``, the tests' reference). The engine reports it
+    (``ServeEngine.kda_forms``)."""
+    pallas = resolve_decode_kernel(decode_kernel) == "pallas"
+    return "pallas" if pallas and head_dim % _LANES == 0 else "xla"
+
+
+def _kda_heads(h: int, d: int) -> int:
+    """Heads a grid step of :func:`kda_step`: the most (a divisor of ``h``)
+    whose float32 states stay under :data:`_KDA_STATE_BYTES`, and whose three
+    columns a head (decay, key, query) are lanes of one tile."""
+    fit = max(1, min(_KDA_STATE_BYTES // (d * d * 4), _LANES // 3))
+    return max(n for n in range(1, h + 1) if h % n == 0 and n <= fit)
+
+
+def _kda_step_kernel(layer_ref, src_ref, what_ref, x_ref, s_ref, o_ref, out_ref, rows_scr, cols_scr):
+    what = what_ref[pl.program_id(1)]
+    heads = s_ref.shape[0]
+
+    @pl.when(what == _KDA_LIVE)
+    def _step():
+        # decay, key and query vary along a state's rows: their rows [heads, D]
+        # side by side, turned once a step, are a column [D, 1] a head each
+        rows_scr[0:heads] = jnp.exp(x_ref[0])
+        rows_scr[heads : 2 * heads] = x_ref[1]
+        rows_scr[2 * heads : 3 * heads] = x_ref[2]
+        cols_scr[:] = rows_scr[:].T
+        kq = jnp.sum(x_ref[1] * x_ref[2], axis=-1, keepdims=True)  # [heads, 1]
+        for j in range(heads):
+            a, k, q = (cols_scr[:, i * heads + j : i * heads + j + 1] for i in range(3))
+            decayed = a * s_ref[j]  # S' = Diag(a) S, [D (key), D (value)]
+            r_k = jnp.sum(decayed * k, axis=0, keepdims=True)  # S'^T k, [1, D]
+            r_q = jnp.sum(decayed * q, axis=0, keepdims=True)
+            u = x_ref[4, j : j + 1] * (x_ref[3, j : j + 1] - r_k)  # beta (v - S'^T k)
+            o_ref[j : j + 1] = r_q + kq[j : j + 1] * u  # S_new^T q
+            out_ref[j] = decayed + k * u
+
+    @pl.when(what != _KDA_LIVE)
+    def _dead():
+        o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    # a dead slot's step is mapped to the block of the live slot before it,
+    # which stays where it is (no fetch, nothing written back: its state keeps
+    # its bytes in memory); before the first live slot it is mapped to that
+    # slot's block (or, where none is live, slot 0's), which goes out as it came
+    @pl.when(what == _KDA_PASS)
+    def _pass():
+        out_ref[:] = s_ref[:]
+
+
+def kda_step(
+    q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+    states: jax.Array, layer, live: jax.Array, *, interpret: bool | None = None,
+):
+    """One kda layer's share of a decode step against the stacked states
+    ``[Lk, S, H, D, D]`` float32, read and written where they lie (the output
+    aliases the input; callers donate it): what ``models.kda.step_state``
+    computes and the write of the layer's slice into the stack, by the
+    module's equations in float32, in **one read and one write of each live
+    slot's state**. q, k, v and g (the log of the decay a key channel) [S, H,
+    D], beta [S, H], ``layer`` the layer's index in the stack (traced: a
+    scalar-prefetch operand that the index maps read), ``live`` [S] bool ->
+    (o [S, H, D] float32, the states).
+
+    A grid step holds :func:`_kda_heads` heads of a slot in VMEM: ``S' =
+    Diag(a) S``, ``r_k = S'^T k`` and ``r_q = S'^T q`` as sums over the rows of
+    one pass over the block, ``u = beta (v - r_k)``, ``S_new = S' + k u^T``, ``o
+    = r_q + (k . q) u``. A slot that holds no sequence (it may be one whose
+    prompt is arriving in chunks) is neither computed nor fetched nor written:
+    its grid steps are mapped to the block the step before them holds (the
+    nearest live slot's before it, resident and already computed), so its
+    bytes stay what they were through the alias; ``o`` is zero there."""
+    _, s_, h, d, _ = states.shape
+    f32 = jnp.float32
+    hb = _kda_heads(h, d)
+    groups = h // hb
+    # a slot's five rows a head: log decay, key, query, value, beta (over D)
+    x = jnp.stack([g, k, q, v, jnp.broadcast_to(beta[..., None], g.shape)], axis=1)
+    x = x.astype(f32).reshape(s_, 5, groups, hb, d)
+    at = jnp.arange(s_, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, at, -1))  # the nearest live slot up to and with each
+    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    what = jnp.where(live, _KDA_LIVE, jnp.where(before >= 0, _KDA_KEEP, _KDA_PASS)).astype(jnp.int32)
+    block = pl.BlockSpec(
+        (None, None, hb, d, d),
+        lambda gi, si, layer_ref, src_ref, what_ref: (layer_ref[0], src_ref[si], gi, 0, 0),
+    )
+    o, states = pl.pallas_call(
+        _kda_step_kernel,
+        name="odtp_kda_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(groups, s_),
+            in_specs=[
+                pl.BlockSpec((None, 5, None, hb, d), lambda gi, si, *_: (si, 0, gi, 0, 0)),
+                block,
+            ],
+            out_specs=[pl.BlockSpec((None, None, hb, d), lambda gi, si, *_: (si, gi, 0, 0)), block],
+            scratch_shapes=[pltpu.VMEM((_LANES, d), f32), pltpu.VMEM((d, _LANES), f32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((s_, groups, hb, d), f32),
+            jax.ShapeDtypeStruct(states.shape, states.dtype),
+        ],
+        # operands count the three prefetched vectors: the states follow the rows
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(interpret),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), src, what, x, states)
+    return o.reshape(s_, h, d), states

@@ -116,6 +116,7 @@ from opendiloco_tpu.ops.decode_kernels import (
     decode_plan,
     eva_plans,
     eva_prefill_form,
+    kda_step_form,
     mla_decode_plan,
     mla_rows_written_back,
     prefill_form,
@@ -743,10 +744,13 @@ class ServeEngine:
         # that were solved (a triangular system a block and head), and the
         # bytes of state the equations move (a step every live slot's, there
         # and back; a chunk one slot's, there and back). ``kda_forms`` names the
-        # form the step and the chunk take ({} without the stack): both are
-        # the XLA forms, on every platform, and the attention layers' are the
-        # plain ring's (the step's by ``decode_kernel``, with a plan for the
-        # ring or the engine is refused here; the chunk's ``chunk_form``)
+        # form the step and the chunk take ({} without the stack): the step's is
+        # ``kda_step_form``'s ("pallas": one kernel over the stacked states, a
+        # live slot's visited once; "xla" off the chip and where a head's state
+        # is no whole tile), the chunk's the XLA form on every platform, and the
+        # attention layers' are the plain ring's (the step's by
+        # ``decode_kernel``, with a plan for the ring or the engine is refused
+        # here; the chunk's ``chunk_form``)
         self._kda: tuple = ()
         self.kda_step_tokens = 0
         self.kda_chunk_tokens = 0
@@ -770,7 +774,8 @@ class ServeEngine:
             state = init_kda_state(cfg, self.num_slots, compute_dtype)
             self._kda = (state["state"], state["tail"])
             self.kda_forms = {
-                "step": "xla", "chunk": "chunked-xla", "block": kda.BLOCK, "sub_block": kda.SUB,
+                "step": kda_step_form(self.decode_kernel, cfg.head_dim), "chunk": "chunked-xla",
+                "block": kda.BLOCK, "sub_block": kda.SUB,
                 "attention_step": self.decode_kernel, "attention_chunk": self.chunk_form,
             }
         self.kda_state_resident_bytes = self._kda[0].nbytes if self._kda else 0

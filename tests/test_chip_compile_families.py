@@ -682,7 +682,9 @@ def test_solar2_programs_copy_no_ring_no_state_no_tail_and_cast_no_weight(chip, 
     convolutions' tails alias the outputs and none is copied; no weight is
     cast; no layer's experts are cut out of their stack; the program fits the
     chip. The decode step holds ``odtp_paged_decode_attn`` (a slot a grid step:
-    a slot at ``lens`` 0 is written nothing); the chunk's gqa attention is
+    a slot at ``lens`` 0 is written nothing) and ``odtp_kda_step`` (a slot's
+    block of heads a grid step, its states aliased in and out of the stack,
+    no layer's states beside them); the chunk's gqa attention is
     ``odtp_chunk_attn`` (no [8, 8, 2048, 512] float32 tile of scores, 268 MB,
     in memory)."""
     cfg, params, rings, compiled = _solar2_program(chip, which)
@@ -708,7 +710,15 @@ def test_solar2_programs_copy_no_ring_no_state_no_tail_and_cast_no_weight(chip, 
     if which == "decode":
         assert "odtp_paged_decode_attn" in text
         assert mem.temp_size_in_bytes < 1e9
+        # the kda layers' step (PR 65): one kernel inside the scan over the three layers,
+        # 16 heads of a slot's states in and out a grid step beside their five rows a head;
+        # nothing of a layer's states' size, 537 MB, is cut out, copied or kept beside it
+        assert kernel_windows(text, "odtp_kda_step") == [[
+            (1, 5, 1, 16, 128), (1, 1, 16, 128, 128), (1, 1, 16, 128), (1, 1, 16, 128, 128)]]
+        assert "f32[128,64,128,128]" not in text
+        assert all("get-tuple-element(" in line for line in f32_blocks_over(text, 256e6))  # the stack handed on
     else:
         assert mem.temp_size_in_bytes < 3e9
         assert "f32[8,8,2048,512]" not in text
         assert kernel_windows(text, "odtp_chunk_attn")
+        assert "odtp_kda_step" not in text  # the chunk's form is the chunked one

@@ -282,11 +282,11 @@ def test_the_engine_serves_it_in_chunks_through_the_batcher(model):
     reqs = [batcher.submit(p, max_new_tokens=5) for p in prompts]
     for r, p in zip(reqs, prompts):
         assert r.wait(120) and r.error is None, r.error
-        seq = list(p)
-        for _ in range(5):
-            nxt = llama.forward(params, jnp.asarray([seq], jnp.int32), cfg, remat=False, **F32)[0][-1]
-            seq.append(int(jnp.argmax(nxt)))
-        assert list(r.tokens) == seq[len(p):]
+        # greedy, token by token: each is the training forward's choice behind the ones before
+        # it (one forward over the prompt and the stream, not one a token: a program a length)
+        seq = list(p) + list(r.tokens)
+        logits = llama.forward(params, jnp.asarray([seq[:-1]], jnp.int32), cfg, remat=False, **F32)[0]
+        assert len(r.tokens) == 5 and list(r.tokens) == jnp.argmax(logits[len(p) - 1 :], axis=-1).tolist()
     stats = batcher.stats()["kda"]
     batcher.stop()
     chunks = sum(-(-len(p) // CHUNK) for p in prompts)
@@ -318,3 +318,111 @@ def test_what_is_refused_is_refused_by_name(model):
         hf_io._reject_moe(cfg, "export")
     with pytest.raises(ValueError, match="written for a stack of 'kda'"):
         LlamaConfig.from_dict({**TINY, "qk_norm": True})
+
+
+# --- the decode step's kernel (PR 65): a live slot's state visited once, where it lies ---
+
+WIDE = {**TINY, "head_dim": 128, "linear_attn_config": {**TINY["linear_attn_config"], "head_dim": 128}}
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The file's stack with heads of 128: a head's state is a whole tile, the
+    shape at which the decode step takes the kernel."""
+    cfg = LlamaConfig.from_dict(WIDE)
+    return cfg, spread(cfg, llama.init_params(jax.random.key(1), cfg))
+
+
+def step_rows(seed, slots, heads, d=128):
+    """A decode step's rows as ``kda.step_inputs`` hands them, at the family's
+    strongest: unit keys, queries by D^-1/2, decays of down to exp(-3) a token
+    and channel, beta up to 2; and the states a long sequence leaves."""
+    rng = np.random.default_rng(seed)
+    q, k = (rng.standard_normal((slots, heads, d)).astype(np.float32) for _ in range(2))
+    q, k = q / np.linalg.norm(q, axis=-1, keepdims=True) * d**-0.5, k / np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.standard_normal((slots, heads, d)).astype(np.float32)
+    g = -rng.uniform(1e-3, 3.0, (slots, heads, d)).astype(np.float32)
+    beta = rng.uniform(0, 2, (slots, heads)).astype(np.float32)
+    states = rng.standard_normal((3, slots, heads, d, d)).astype(np.float32)
+    return (q, k, v, g, beta), states
+
+
+@pytest.mark.parametrize("heads_a_step", [4, 2])
+@pytest.mark.parametrize("live", [(1, 1, 1, 1, 1), (1, 0, 1, 1, 0), (0, 0, 1, 0, 1), (0, 0, 0, 0, 0)])
+def test_the_step_kernel_is_the_recurrences_token(live, heads_a_step, monkeypatch):
+    """``decode_kernels.kda_step`` interpreted beside ``kda.recurrence``'s one
+    token: ``o`` and the live slots' states to float32's rounding; a dead
+    slot's state (before the first live one, between two, behind the last,
+    and where none is live) and every other layer's states bit for bit what
+    went in; one block of all four heads a grid step, and two of two."""
+    from opendiloco_tpu.ops import decode_kernels
+
+    monkeypatch.setattr(decode_kernels, "_KDA_STATE_BYTES", heads_a_step * 128 * 128 * 4)
+    assert decode_kernels._kda_heads(4, 128) == heads_a_step
+    rows, states = step_rows(sum(live), 5, 4)
+    live = np.asarray(live, bool)
+    o, new = jax.jit(lambda *a: decode_kernels.kda_step(*a))(*rows, states, 1, jnp.asarray(live))
+    want_o, want = kda.recurrence(*(x[:, None] for x in rows), states[1])
+    np.testing.assert_array_equal(new[0], states[0])
+    np.testing.assert_array_equal(new[2], states[2])
+    np.testing.assert_array_equal(new[1][~live], states[1][~live])
+    np.testing.assert_array_equal(o[~live], 0.0)
+    if live.any():
+        assert rel(o[live], want_o[live, 0]) < 1e-5 and rel(new[1][live], want[live]) < 1e-5
+        assert rel(new[1][live], states[1][live]) > 0.1  # and it is another state
+
+
+def test_the_step_kernel_is_the_xla_step_under_a_scan_over_the_layers(wide):
+    """Through the model's own rows (``kda.step_inputs`` of a layer's weights)
+    the kernel is ``kda.step``, the XLA form and the tests' reference, with the
+    layer's index traced: a ``lax.scan`` over two of the three layers updates
+    those two layers' states in the stack, each to its own ``kda.step``, and
+    leaves the third's and a dead slot's as they were."""
+    from opendiloco_tpu.ops import decode_kernels
+
+    cfg, params = wide
+    stack = {name: x[:2] for name, x in params["layers"]["kda"].items()}
+    _, states = step_rows(11, SLOTS, 4)
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.standard_normal((SLOTS, 64)), jnp.float32)
+    tails = jnp.asarray(rng.standard_normal((2, 3, SLOTS, 3 * 4 * 128)), jnp.float32)
+    live = jnp.asarray([True, False, True])
+
+    def body(states, xs):
+        layer, li, tail = xs
+        *rows, tail = kda.step_inputs(cfg, x, layer, tail, live)
+        o, states = decode_kernels.kda_step(*rows, states, li, live)
+        return states, (o, tail)
+
+    new, (o, new_tails) = jax.jit(lambda s: jax.lax.scan(body, s, (stack, jnp.arange(2), tails)))(states)
+    for li in range(2):
+        layer = {name: x[li] for name, x in stack.items()}
+        want_o, want, want_tail = kda.step(cfg, x, layer, states[li], tails[li], live)
+        assert rel(o[li][live], want_o[live]) < 1e-5 and rel(new[li][live], want[live]) < 1e-5
+        np.testing.assert_array_equal(new[li][1], states[li][1])
+        np.testing.assert_array_equal(new_tails[li], want_tail)
+    np.testing.assert_array_equal(new[2], states[2])
+
+
+def test_the_engine_takes_the_step_kernel_where_a_heads_state_is_whole_tiles(model, wide, monkeypatch):
+    """Which form the step takes is read from what the engine sees: the kernel
+    under ``decode_kernel="pallas"`` (here interpreted) at heads of 128, where
+    the stream is the XLA engine's token for token; the XLA form at this
+    file's heads of 16 whatever the decode kernel, and off the chip."""
+    monkeypatch.setenv("ODTP_DECODE_BLOCK_T", "32")
+    assert engine_of(model, decode_kernel="pallas").kda_forms["step"] == "xla"
+    prompts = [tokens(i, n).tolist() for i, n in enumerate((9, 21))]
+    streams = {}
+    for kernel in ("xla", "pallas"):
+        engine = engine_of(wide, decode_kernel=kernel)
+        assert engine.kda_forms["step"] == kernel
+        batcher = ContinuousBatcher(engine).start()
+        reqs = [batcher.submit(p, max_new_tokens=4) for p in prompts]
+        assert all(r.wait(240) and r.error is None for r in reqs), [r.error for r in reqs]
+        streams[kernel] = [list(r.tokens) for r in reqs]
+        assert batcher.stats()["kda"]["forms"]["step"] == kernel
+        batcher.stop()
+        assert engine.kda_step_tokens == 3 * sum(len(s) - 1 for s in streams[kernel])
+    assert streams["pallas"] == streams["xla"]
+    assert ServeEngine(*wide, num_slots=2, max_context=RING, prefill_buckets=(), prefill_chunk=CHUNK,
+                       compute_dtype=jnp.float32).kda_forms["step"] == "xla"
